@@ -898,9 +898,9 @@ CATALOG: Tuple[BlockSchema, ...] = (
             Field("gate_rows", "int", nullable=True),
             Field("gate_stats", "dict", nullable=True),
             Field("session_gate_ok", "bool", nullable=True,
-                  emit_note="stamped by the archived round-5 session "
-                            "driver (scripts/archive/tpu_session.py); "
-                            "declared so r05 history lines sweep "
+                  emit_note="stamped by the round-5 session driver "
+                            "(2026-07-31, deleted in PR 21); declared "
+                            "so history lines of that round sweep "
                             "clean, no live emitter writes it"),
             Field("recall_at_k", "number", nullable=True),
             Field("recall_unverified", "bool", nullable=True),
@@ -923,8 +923,8 @@ CATALOG: Tuple[BlockSchema, ...] = (
             Field("devices", "int", nullable=True),
             Field("device_kind", "str", nullable=True),
             Field("backend", "str", nullable=True),
-            Field("cpu_fallback_shrunk", "bool", nullable=True),
-            Field("curated_tpu_line", "dict", nullable=True),
+            Field("cpu_baseline_cache_file", "str", nullable=True),
+            Field("cpu_baseline_error", "str", nullable=True),
             Field("batch", "int", nullable=True),
             Field("train_tile", "int", nullable=True),
             Field("pallas_knobs", "dict", nullable=True),

@@ -285,17 +285,11 @@ SWITCHES: Tuple[Switch, ...] = (
        "Placement compute dtype (bfloat16 | float32)."),
     # --- bench.py: environment/bring-up --------------------------------
     _s("KNN_BENCH_PLATFORM", "str", "bench.py", _PERF,
-       "Force a JAX platform (e.g. cpu) instead of auto-detect."),
+       "The JAX platform to run on; unset means TPU, and a run that "
+       "finds another backend fails (cpu is what the CPU tests ask "
+       "for)."),
     _s("KNN_BENCH_PEAK_FLOPS", "float", "bench.py", _PERF,
        "Override the per-chip peak FLOP/s used for MFU."),
-    _s("KNN_BENCH_INIT_TIMEOUT", "int", "bench.py", _PERF,
-       "Seconds before backend init is declared hung (default 480)."),
-    _s("KNN_BENCH_INIT_ATTEMPTS", "int", "bench.py", _PERF,
-       "Backend-init retry attempts."),
-    _s("KNN_BENCH_INIT_WAIT", "int", "bench.py", _PERF,
-       "Seconds between backend-init retries."),
-    _s("KNN_BENCH_FALLBACK_CPU", "flag", "bench.py", _PERF,
-       "Run on CPU when accelerator init fails (default on)."),
     _s("KNN_BENCH_CPU_CACHE", "flag", "bench.py", _PERF,
        "0 forces a fresh CPU-oracle measurement instead of the cached "
        "one."),

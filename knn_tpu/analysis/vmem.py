@@ -1,41 +1,53 @@
-"""Analytic per-launch VMEM model of the Pallas kernels — the budget
-side of the roofline story.
+"""Per-launch VMEM model of the Pallas kernels — the budget side of the
+roofline story.
 
 The roofline model (knn_tpu.obs.roofline) prices a knob set's TIME;
-nothing priced its per-launch VMEM footprint, yet VMEM is the binding
-resource that decides whether a config RUNS AT ALL: an over-VMEM knob
-combination fails at Mosaic compile time, on hardware, at the worst
-possible moment (mid-tune on a TPU session).  ``ops.pallas_knn``
-already computes per-launch byte budgets inline to size its
-``vmem_limit_bytes`` compiler hints — this module lifts the SAME
-arithmetic into a jax-free home so
+VMEM is the resource that decides whether a config RUNS AT ALL: an
+over-VMEM knob combination is refused by Mosaic at compile time.  This
+jax-free module is the ONE home of that arithmetic:
 
-- ``autotune()`` can refuse (or flag) over-budget candidates BEFORE
-  timing, with provenance recorded like roofline pruning,
-- the ``vmem-budget`` checker (knn_tpu.analysis.check_vmem) can prove
-  statically that the default knobs fit the target device and that the
-  knob grid carries no candidate that fits NO known device,
-- ``knob_grid`` can bound its enumeration to configurations that fit
-  at least one known device kind at the headline shape.
+- ``ops.pallas_knn`` sizes its ``vmem_limit_bytes`` request from
+  :func:`kernel_bytes` / :func:`limit_bytes` and refuses a geometry
+  that cannot fit (:func:`fits`) with a message, before Mosaic is
+  asked;
+- ``autotune()`` refuses over-budget candidates BEFORE timing, with
+  provenance recorded like roofline pruning;
+- ``knob_grid`` and the ``vmem-budget`` checker
+  (knn_tpu.analysis.check_vmem) keep the grid free of candidates that
+  fit NO known device.
+
+Calibration: the buffers the kernel declares (pipelined operand and
+output blocks, scratch) are exact; what Mosaic keeps live ON TOP of
+them is not declared anywhere, so the score-tile multipliers below were
+fitted to the scoped-VMEM need Mosaic itself reported (libtpu 0.0.34,
+v5e, deviceless AOT — ``scripts/aot_compile_check.py --probe``) for the
+flagship bf16x3 arm at the three benchmark shapes, both block_q and
+both tile sizes, all three kernels.  The model tracked the compiler
+within +7%/-1% at every probed geometry (tiled GIST bq256: 82.5 MiB
+modeled, 81.94 reported; streaming SIFT bq256: 126.75 / 126.55); the
+other precisions were observed to need LESS than bf16x3 at the same
+geometry (fewer live matmul partials), so for them the model is an
+upper bound.  :func:`limit_bytes` adds an eighth for the model's
+error.  The "lane" binning and the "pq" one-hot expansion are not modeled; the
+compiler has the last word on those.
 
 Geometry constants mirror ``ops.pallas_knn`` (TILE_N/BLOCK_Q/BIN_W/
 DIM_CHUNK/MAX_CARRY_DEPTH), pinned by tests/test_analysis.py.  The
-per-precision operand widths live since PR 17 in the ONE shared table
+per-precision operand widths live in the ONE shared table
 :mod:`knn_tpu.analysis.widths` (this module's ``DB_PARTS``/``AUX_ROWS``
 are ``is``-identity views of it, shared with ``obs.roofline`` and
-``analysis.hbm``) — the lockstep is now structural, not test-enforced
-mirroring.
+``analysis.hbm``).
 
 Capacity provenance: TPU v2/v3 cores carry ~16 MiB of VMEM; v4 and
-every later announced generation carry 128 MiB (the number
-``ops.pallas_knn``'s tiled-path comment already relies on for v5e).
-An unknown TPU kind gets the 128 MiB default flagged ``estimated``;
-CPU backends have no VMEM and are never budget-checked.
+every later announced generation carry 128 MiB (Mosaic's own refusal on
+a v5e reads "Used 133.75M of 128.00M vmem").  A TPU kind that is not
+in the table is an error, not a default; CPU backends have no VMEM and
+are never budget-checked.
 """
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 from knn_tpu.analysis import widths as _widths
 
@@ -77,7 +89,6 @@ VMEM_BYTES_BY_KIND: Dict[str, int] = {
     "TPU v7": 128 * MIB,
     "TPU v7x": 128 * MIB,
 }
-DEFAULT_VMEM_BYTES = 128 * MIB
 
 #: the repo's target hardware (every headline number is v5e) and the
 #: headline problem shape (SIFT1M) the static checker prices at
@@ -86,26 +97,42 @@ HEADLINE_SHAPE = {"n": 1_000_000, "d": 128, "k": 100, "margin": 28}
 
 
 def budget_for(device_kind: Optional[str],
-               backend: Optional[str] = None
-               ) -> Tuple[Optional[int], bool]:
-    """(vmem bytes, estimated) for a device kind; (None, False) when
-    there is no VMEM to budget (cpu / interpret mode / unknown
-    non-TPU backend) — the autotuner's gate disarms there instead of
-    refusing on a number that doesn't exist.  An explicit TPU
-    ``device_kind`` wins over ``backend``: a caller modeling (or
+               backend: Optional[str] = None) -> Optional[int]:
+    """VMEM bytes of a device kind; None when there is no VMEM to
+    budget (cpu / interpret mode) — the autotuner's gate disarms there
+    instead of refusing on a number that doesn't exist.  An explicit
+    TPU ``device_kind`` wins over ``backend``: a caller modeling (or
     keying a cache for) a specific chip gets that chip's budget even
-    when the tune itself runs in CPU interpret mode."""
+    when the tune itself runs in CPU interpret mode.  A TPU whose kind
+    is not in the table raises: a device the table does not know is an
+    error, not a default."""
     if device_kind in VMEM_BYTES_BY_KIND:
-        return VMEM_BYTES_BY_KIND[device_kind], False
-    if str(device_kind or "").startswith("TPU"):
-        return DEFAULT_VMEM_BYTES, True
-    if device_kind is None and str(backend or "").lower() == "tpu":
-        # TPU backend whose device-kind string is unavailable: the
-        # backend evidence says there IS a VMEM to overflow, so arm the
-        # gate at the unknown-kind default rather than disarming on
-        # missing metadata
-        return DEFAULT_VMEM_BYTES, True
-    return None, False
+        return VMEM_BYTES_BY_KIND[device_kind]
+    if str(device_kind or "").startswith("TPU") or (
+            device_kind is None and str(backend or "").lower() == "tpu"):
+        raise ValueError(
+            f"device kind {device_kind!r} is not in "
+            f"analysis.vmem.VMEM_BYTES_BY_KIND; add its VMEM size (with "
+            f"its source) before budgeting kernels for it")
+    return None
+
+
+def fits(estimate_bytes: int, budget_bytes: int) -> bool:
+    """Whether a launch of ``estimate_bytes`` fits a ``budget_bytes``
+    VMEM — the ONE rule the kernel's own refusal, the autotuner gate
+    and the grid checker share.  No reserve is held back: Mosaic on a
+    v5e compiled the streaming kernel at a reported 126.55 MiB under a
+    128 MiB limit."""
+    return int(estimate_bytes) <= int(budget_bytes)
+
+
+def limit_bytes(estimate_bytes: int, budget_bytes: int) -> int:
+    """The scoped-VMEM limit a launch that :func:`fits` requests: the
+    estimate plus an eighth for the model's error (module docstring:
+    the compiler's need ran up to 1% over the model), floored at 64 MiB
+    for the arms the model does not cover, capped at the device."""
+    want = int(estimate_bytes) + int(estimate_bytes) // 8
+    return min(int(budget_bytes), max(64 * MIB, want))
 
 
 def _ceil_div(a: int, b: int) -> int:
@@ -138,6 +165,60 @@ def _geometry(n: int, d: int, precision: str, kernel: str,
     return tile, bq, n_tiles, dim_p, nd, out_w, bound_w
 
 
+def kernel_bytes(
+    *, kernel: str, block_q: int, tile_n: int, n_tiles: int, nd: int,
+    out_w: int, bound_w: int, db_block: int, aux_rows: int,
+    q_block: int, q_extra: int = 0, carry_depth: int = 0,
+) -> Dict[str, int]:
+    """Per-buffer VMEM bytes of ONE launch from the kernel's RESOLVED
+    geometry — what ``ops.pallas_knn`` sizes its scoped-VMEM request
+    from, and what :func:`launch_estimate` prices a knob set with.
+
+    ``db_block`` is one db tile across all its parts, ``q_block`` one
+    query operand block (tiled: one DIM_CHUNK slice; streaming/fused:
+    the full-dim block), ``q_extra`` the quantized arms' query-scale
+    block, ``carry_depth`` the fused arm's armed carry depth (0 =
+    disarmed).
+
+    - **tiled**: every grid-mapped operand and output block is
+      double-buffered by the Pallas pipeline; the multi-chunk
+      accumulator scratch lives once.
+    - **streaming/fused**: the kernel OWNS its db double buffering (two
+      scratch slots per part + aux); the full-width candidate output
+      block is a pipelined (double-buffered) output all the same.
+    - ``score_tiles``: the [block_q, tile_n] f32 tiles Mosaic keeps
+      live around the select (partial dots, the score, one temporary)
+      — 2 at one dim chunk, 3 when chunks accumulate; fitted to the
+      compiler's reported need (module docstring), not declared by the
+      kernel."""
+    score = block_q * tile_n * 4
+    aux_block = aux_rows * tile_n * 4
+    live = 2 if nd == 1 else 3
+    if kernel == "tiled":
+        return {
+            "db_blocks_x2": 2 * db_block,
+            "aux_x2": 2 * aux_block,
+            "query_x2": 2 * (q_block + q_extra),
+            "outputs_x2": 2 * block_q * (2 * out_w + bound_w) * 4,
+            "score_tiles": live * score,
+            "accum_scratch": score if nd > 1 else 0,
+        }
+    if kernel not in ("streaming", "fused"):
+        raise ValueError(
+            f"kernel {kernel!r} not in ('tiled', 'streaming', 'fused')")
+    return {
+        "outputs_fullwidth_x2":
+            2 * block_q * n_tiles * (2 * out_w + bound_w) * 4,
+        "stream_scratch_x2": 2 * (db_block + aux_block),
+        "query_x2": 2 * (q_block + q_extra),
+        "score_tiles": live * score,
+        # the early-out's lane minima / skip-branch temporaries
+        # (fitted: half a score tile) plus the per-lane f32 carry
+        "fused_select": (score // 2 + block_q * carry_depth * BIN_W * 4
+                         if kernel == "fused" else 0),
+    }
+
+
 def launch_estimate(
     *, n: int, d: int, k: int, margin: int = 28,
     precision: Optional[str] = None, kernel: Optional[str] = None,
@@ -146,29 +227,12 @@ def launch_estimate(
     pq_dsub: Optional[int] = None, pq_ncodes: Optional[int] = None,
 ) -> dict:
     """Estimated VMEM high-water bytes of ONE kernel launch for this
-    knob set, with the per-buffer breakdown.
-
-    Mirrors the budgets ``ops.pallas_knn`` computes when sizing its
-    ``vmem_limit_bytes`` hints, plus the pipelined double-buffering of
-    grid-mapped blocks the compiler adds on top:
-
-    - **tiled**: pipeline inputs/outputs are double-buffered block
-      specs (db tile parts, aux rows, query block, candidate outputs);
-      the [block_q, tile_n] score tile (and the multi-chunk int32/f32
-      accumulator scratch) live once.
-    - **streaming/fused**: the kernel OWNS its double buffering — two
-      explicit scratch slots per db part + aux — and carries the
-      full-width candidate output block in VMEM for the whole launch;
-      the fused arm adds its per-lane order-statistic carry
-      (``ceil((m+2)/128)`` stats per lane, disarmed past
-      MAX_CARRY_DEPTH).
-    """
+    knob set at this problem shape, with the per-buffer breakdown
+    (:func:`kernel_bytes` over the geometry the kernel would
+    resolve)."""
     precision = precision or "bf16x3"
     kernel = kernel or "tiled"
     binning = binning or "grouped"
-    if kernel not in ("tiled", "streaming", "fused"):
-        raise ValueError(
-            f"kernel {kernel!r} not in ('tiled', 'streaming', 'fused')")
     tile, bq, n_tiles, dim_p, nd, out_w, bound_w = _geometry(
         n, d, precision, kernel, tile_n, block_q, survivors, binning)
     lut_w = 0
@@ -185,52 +249,26 @@ def launch_estimate(
         nd = 1
     else:
         n_parts, chunk_w, part_b = DB_PARTS[precision]
-    aux_rows = AUX_ROWS.get(precision, AUX_ROWS_DEFAULT)
-    q_elem = 1 if precision in ("int8", "int4") else 4
-    q_extra_b = bq * BIN_W * 4 if precision in ("int8", "int4") else 0
-
-    db_block = n_parts * tile * chunk_w * part_b
-    aux_block = aux_rows * tile * 4
-    score = bq * tile * 4
-    accum = bq * tile * 4 if nd > 1 else 0
-
-    if kernel == "tiled":
-        q_block = bq * lut_w * 4 if precision == "pq" \
-            else bq * DIM_CHUNK * q_elem
-        out_block = bq * (out_w * 8 + bound_w * 4)
-        inputs = db_block + aux_block + q_block + q_extra_b
-        total = 2 * inputs + 2 * out_block + score + accum
-        breakdown = {
-            "db_blocks_x2": 2 * db_block,
-            "aux_x2": 2 * aux_block,
-            "query_x2": 2 * (q_block + q_extra_b),
-            "outputs_x2": 2 * out_block,
-            "score_tile": score,
-            "accum_scratch": accum,
-        }
+    quantized = precision in ("int8", "int4")
+    if precision == "pq":
+        q_block = bq * lut_w * 4
     else:
-        q_block = bq * lut_w * 4 if precision == "pq" \
-            else bq * dim_p * q_elem
-        out_block = bq * (2 * n_tiles * out_w + n_tiles * bound_w) * 4
-        buf = 2 * (db_block + aux_block)  # the explicit scratch slots
-        carry = 0
-        if kernel == "fused":
-            keep = min(int(k) + int(margin), max(1, int(n) - 1)) + 2
-            depth = _ceil_div(keep, BIN_W)
-            if depth <= MAX_CARRY_DEPTH:
-                carry = bq * depth * BIN_W * 8  # f32 stats + i32 ids
-        total = out_block + buf + 2 * score + accum + \
-            2 * (q_block + q_extra_b) + carry
-        breakdown = {
-            "outputs_fullwidth": out_block,
-            "stream_scratch_x2": buf,
-            "score_tile_x2": 2 * score,
-            "accum_scratch": accum,
-            "query_x2": 2 * (q_block + q_extra_b),
-            "fused_carry": carry,
-        }
+        q_block = bq * (DIM_CHUNK if kernel == "tiled" else dim_p) * (
+            1 if quantized else 4)
+    carry_depth = 0
+    if kernel == "fused":
+        keep = min(int(k) + int(margin), max(1, int(n) - 1)) + 2
+        depth = _ceil_div(keep, BIN_W)
+        carry_depth = depth if depth <= MAX_CARRY_DEPTH else 0
+    breakdown = kernel_bytes(
+        kernel=kernel, block_q=bq, tile_n=tile, n_tiles=n_tiles, nd=nd,
+        out_w=out_w, bound_w=bound_w,
+        db_block=n_parts * tile * chunk_w * part_b,
+        aux_rows=AUX_ROWS.get(precision, AUX_ROWS_DEFAULT),
+        q_block=q_block, q_extra=bq * BIN_W * 4 if quantized else 0,
+        carry_depth=carry_depth)
     return {
-        "total_bytes": int(total),
+        "total_bytes": int(sum(breakdown.values())),
         "breakdown": {kk: int(v) for kk, v in breakdown.items()},
         "geometry": {
             "tile_n": tile, "block_q": bq, "n_tiles": n_tiles,
@@ -238,6 +276,17 @@ def launch_estimate(
             "kernel": kernel, "precision": precision,
         },
     }
+
+
+def _estimate_for(knobs: dict, *, n: int, d: int, k: int,
+                  margin: int) -> int:
+    return launch_estimate(
+        n=n, d=d, k=k, margin=margin,
+        precision=knobs.get("precision"), kernel=knobs.get("kernel"),
+        tile_n=knobs.get("tile_n"), block_q=knobs.get("block_q"),
+        survivors=knobs.get("survivors"), binning=knobs.get("binning"),
+        pq_dsub=knobs.get("pq_dsub"),
+        pq_ncodes=knobs.get("pq_ncodes"))["total_bytes"]
 
 
 def check_candidate(
@@ -248,43 +297,26 @@ def check_candidate(
     ``{"checked", "fits", "estimate_bytes", "budget_bytes", ...}``.
     ``checked=False`` (cpu / no-VMEM backend) means the verdict is
     N/A, never a refusal."""
-    budget, estimated = budget_for(device_kind, backend)
-    est = launch_estimate(
-        n=n, d=d, k=k, margin=margin,
-        precision=knobs.get("precision"), kernel=knobs.get("kernel"),
-        tile_n=knobs.get("tile_n"), block_q=knobs.get("block_q"),
-        survivors=knobs.get("survivors"), binning=knobs.get("binning"),
-        pq_dsub=knobs.get("pq_dsub"), pq_ncodes=knobs.get("pq_ncodes"))
-    out = {
+    budget = budget_for(device_kind, backend)
+    est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin)
+    return {
         "checked": budget is not None,
-        "estimate_bytes": est["total_bytes"],
+        "estimate_bytes": est,
         "budget_bytes": budget,
         "device_kind": device_kind,
-        "estimated_budget": estimated,
-        "fits": None if budget is None
-        else est["total_bytes"] <= budget,
+        "fits": None if budget is None else fits(est, budget),
     }
-    return out
 
 
 def fits_some_kind(knobs: dict, *, n: int, d: int, k: int,
                    margin: int = 28) -> bool:
     """Whether the knob set fits AT LEAST ONE known device kind's VMEM
     at this shape.  A candidate that fits nowhere is dead grid weight:
-    on every real device the autotuner's budget gate would refuse it,
-    so enumerating it only burns model time and review attention —
-    ``knob_grid`` excludes such combinations at the headline shape and
-    the ``vmem-budget`` checker enforces the same bound."""
+    on every real device the kernel itself would refuse it, so
+    ``knob_grid`` drops such combinations at the headline shape and the
+    ``vmem-budget`` checker enforces the same bound."""
     try:
-        est = launch_estimate(
-            n=n, d=d, k=k, margin=margin,
-            precision=knobs.get("precision"),
-            kernel=knobs.get("kernel"), tile_n=knobs.get("tile_n"),
-            block_q=knobs.get("block_q"),
-            survivors=knobs.get("survivors"),
-            binning=knobs.get("binning"),
-            pq_dsub=knobs.get("pq_dsub"),
-            pq_ncodes=knobs.get("pq_ncodes"))["total_bytes"]
+        est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin)
     except ValueError:
         return True  # unpriceable: never exclude on a model gap
-    return est <= max(VMEM_BYTES_BY_KIND.values())
+    return fits(est, max(VMEM_BYTES_BY_KIND.values()))

@@ -438,6 +438,12 @@ def _hardware_arm(arm: str, *, out_dir: str, round_no: Optional[int],
                             "KNN_TPU_CALIBRATION")})
 
     def run(cmd, stage_name, timeout):
+        # One process per chip: on this path the parent never
+        # initialises a JAX backend — its imports (obs.profiler,
+        # obs.calibrate, obs.traceread, bench's env parsing) stay off
+        # JAX until called into it, and nothing here does — so each
+        # child is the only process that takes the chip, and
+        # subprocess.run returns before the next one starts.
         t0 = time.perf_counter()
         r = subprocess.run(cmd, cwd=_REPO, env=env,
                            capture_output=True, text=True,

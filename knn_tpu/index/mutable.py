@@ -494,7 +494,7 @@ class MutableIndex:
         prog = _hosttier_program(
             self.mesh, self._k_tail, snap.main.metric, snap.main.merge,
             self._ctor["train_tile"], snap.main._dtype_key,
-            dcn_merge=snap.main.dcn_merge, donate=False)
+            dcn_merge=snap.main.dcn_merge)
         qp, n_q = snap.main._place_queries(q_np)
         out = _retry_transient(
             lambda: prog(qp, dev["tp"], dev["nv"]),

@@ -12,7 +12,7 @@ bytes per query fall as 1/superblock_rows until the bound flips off
 hbm_bound (obs.roofline MODEL_VERSION 7's join model prices exactly
 this).  Query-side double buffering — superblock i+1's host->device
 transfer overlapping block i's device compute under the bounded-depth
-drain-oldest discipline, with donated query buffers — turns the
+drain-oldest discipline — turns the
 h2d query stream into an amortized cost too.
 
 Entry points: :func:`knn_join` (one call, any ShardedKNN placement —

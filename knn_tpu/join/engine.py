@@ -9,7 +9,7 @@ Two modes (:data:`JOIN_MODES`):
   plan_superblocks` > the library default); each superblock places
   h2d and dispatches through
   :func:`knn_tpu.parallel.sharded.query_stream_program` (the exact
-  search program with the query operand donated off-CPU) under the
+  search program) under the
   bounded-depth drain-oldest discipline — block i+1's transfer +
   dispatch overlaps block i's fetch, measured by the same
   dispatch-timeline ``overlap_ratio`` the certified pipeline reports.
@@ -156,26 +156,22 @@ def _pad_block(q: np.ndarray, lo: int, hi: int, rows: int) -> np.ndarray:
 def _stream_resident(program, q: np.ndarray, k: int, sb_rows: int,
                      depth: int, d_out, i_out) -> dict:
     """Resident-B stream: double-buffer query superblocks through the
-    donated-query search program, drain-oldest at ``depth``."""
-    import jax
-
+    search program, drain-oldest at ``depth``."""
     from knn_tpu.parallel.sharded import (
         _fetch_or_redispatch, _overlap_ratio, _retry_transient,
         query_stream_program)
 
-    donate = jax.default_backend() != "cpu"
     prog = query_stream_program(
         program.mesh, k, program.n_train, program.metric, program.merge,
         train_tile=program.train_tile, compute_dtype=program._dtype_key,
-        dcn_merge=program.dcn_merge, donate=donate)
+        dcn_merge=program.dcn_merge)
     n_a = q.shape[0]
     blocks = [(lo, min(lo + sb_rows, n_a))
               for lo in range(0, n_a, sb_rows)]
 
     def launch(lo: int, hi: int):
-        # h2d placement + async dispatch: with donation the device
-        # recycles the previous superblock's query buffer, so at most
-        # ``depth`` placements coexist
+        # h2d placement + async dispatch: each placement is freed when
+        # its block is collected, so at most ``depth`` coexist
         qp, _ = program._place_queries(_pad_block(q, lo, hi, sb_rows))
         return prog(qp, program._tp)
 

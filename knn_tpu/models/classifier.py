@@ -190,14 +190,8 @@ class KNNClassifier:
         if len(outs) == 1:
             cat = outs[0]
         else:
-            # host-side concatenate: XLA GSPMD (jax 0.4.x) miscompiles
-            # jnp.concatenate of query-sharded batch outputs on a 2-D
-            # mesh — it psums the db-replicated copies, returning labels
-            # db_shards x too large — while fetch-then-concat is immune
-            # (the estimator's consumers cross to host anyway)
             cat = tuple(
-                jnp.asarray(np.concatenate(
-                    [np.asarray(o[i]) for o in outs], axis=0))
+                jnp.concatenate([o[i] for o in outs], axis=0)
                 for i in range(n_out)
             )
         return cat if n_out > 1 else cat[0]

@@ -2,9 +2,13 @@
 
 This is the framework's C++ parity oracle — the role the reference's whole
 program plays (SURVEY.md §2: the single native component).  The library
-builds on demand via the Makefile next to this file; when no C++ toolchain
-is available, :func:`available` returns False and every caller falls back
-to the pure-Python/JAX paths.
+is built from what the checkout holds: the first use runs ``make`` next
+to this file, whose rule depends on ``src/knn_native.cpp``, so a
+``libknn_native.so`` left over from an earlier session is rebuilt when
+the source moved on and never trusted for merely existing.  When the
+build fails (no C++ toolchain), :func:`available` returns False for the
+callers that only PREFER native (the CSV fast path), and every function
+that was ASKED for native raises with make's own error.
 
 API mirrors the JAX ops one-to-one so parity tests can swap backends:
   knn_search / knn_predict      <-> ops.topk.knn_search / models knn_predict
@@ -35,31 +39,34 @@ _METRIC_CODES = {
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_build_failed = False
+#: why the build failed (make's stderr tail), once it has; None before
+_build_error: Optional[str] = None
 
 
-def _try_build() -> bool:
+def _build() -> Optional[str]:
+    """Run ``make`` (it decides whether the library is current) and
+    return None, or the reason it failed."""
     try:
-        subprocess.run(
-            ["make", "-C", _DIR, "-s"],
-            check=True,
-            capture_output=True,
+        r = subprocess.run(
+            ["make", "-C", _DIR, "-s"], capture_output=True, text=True,
             timeout=300,
         )
-        return os.path.exists(_LIB_PATH)
-    except Exception:
-        return False
+    except (OSError, subprocess.TimeoutExpired) as e:
+        return f"make did not run: {e!r}"
+    if r.returncode != 0 or not os.path.exists(_LIB_PATH):
+        return (f"make -C {_DIR} failed (rc {r.returncode}): "
+                f"{r.stderr.strip()[-400:]}")
+    return None
 
 
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _build_failed
+    global _lib, _build_error
     with _lock:
         if _lib is not None:
             return _lib
-        if _build_failed:
-            return None
-        if not os.path.exists(_LIB_PATH) and not _try_build():
-            _build_failed = True
+        if _build_error is None:
+            _build_error = _build()
+        if _build_error is not None:
             return None
         lib = ctypes.CDLL(_LIB_PATH)
         f64p = ctypes.POINTER(ctypes.c_double)
@@ -97,6 +104,15 @@ def available() -> bool:
     return _load() is not None
 
 
+def require() -> ctypes.CDLL:
+    """The loaded library, for a caller that asked for native: a failed
+    build is an error carrying make's own message, not a quiet None."""
+    lib = _load()
+    if lib is None:
+        raise RuntimeError(f"native library unavailable: {_build_error}")
+    return lib
+
+
 def _f32p(a: np.ndarray):
     return a.ctypes.data_as(ctypes.POINTER(ctypes.c_float))
 
@@ -117,9 +133,7 @@ def knn_search(
 ) -> Tuple[np.ndarray, np.ndarray]:
     """(distances [Q,k] float64, indices [Q,k] int64), lexicographic
     (dist, index) order — same contract as ops.topk.knn_search."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
+    lib = require()
     train = _as_f32c(train)
     queries = _as_f32c(queries)
     n_train, dim = train.shape
@@ -144,9 +158,7 @@ def knn_predict(
     num_threads: int = 0,
 ) -> np.ndarray:
     """Predicted labels [Q] int32 with the reference vote semantics."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
+    lib = require()
     train = _as_f32c(train)
     queries = _as_f32c(queries)
     labels = np.ascontiguousarray(labels, dtype=np.int32)
@@ -169,9 +181,7 @@ def knn_predict(
 def minmax_stats(arrays: Sequence) -> Tuple[np.ndarray, np.ndarray]:
     """Joint per-dim (min, max) over several [N, D] arrays — the
     transductive extrema of knn_mpi.cpp:245-274 with ±inf init."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
+    lib = require()
     arrays = [_as_f32c(a) for a in arrays]
     if not arrays:
         raise ValueError("minmax_stats needs at least one array")
@@ -190,9 +200,7 @@ def minmax_stats(arrays: Sequence) -> Tuple[np.ndarray, np.ndarray]:
 def minmax_apply(x, mins, maxs) -> np.ndarray:
     """(x - min) / (max - min) with constant dims passed through
     (knn_mpi.cpp:284 guard).  Returns a new array."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
+    lib = require()
     out = _as_f32c(x).copy()
     mins = _as_f32c(mins)
     maxs = _as_f32c(maxs)
@@ -209,9 +217,7 @@ _CSV_ERRORS = {-1: "I/O error", -2: "ragged rows", -3: "parse error", -4: "empty
 
 def read_csv(path: str) -> np.ndarray:
     """Fast CSV parse to [rows, cols] float32 (uniform-width rows)."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
+    lib = require()
     rows = ctypes.c_int64()
     cols = ctypes.c_int64()
     ptr = lib.knn_native_read_csv(path.encode(), ctypes.byref(rows), ctypes.byref(cols))
@@ -228,9 +234,7 @@ def read_csv(path: str) -> np.ndarray:
 
 def accuracy(pred, real) -> float:
     """acc_calc (knn_mpi.cpp:69-84)."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
+    lib = require()
     pred = np.ascontiguousarray(pred, dtype=np.int32)
     real = np.ascontiguousarray(real, dtype=np.int32)
     if pred.shape != real.shape:

@@ -149,8 +149,7 @@ BOUND_CLASSES = ("hbm_bound", "mxu_bound", "vpu_select_bound",
 #: for int8 there); ``vpu_ops`` is the vector-unit element-op rate —
 #: ESTIMATED: v5e is anchored at the ~3.9 Tops/s the measured cost
 #: model in docs/PERF.md calibrated, other kinds scale by their MXU
-#: ratio.  An unknown kind gets no silent default — callers fall back
-#: to GENERIC_CPU_PEAKS with ``estimated`` set.
+#: ratio.  An unknown kind gets no default: peaks_for raises.
 PEAKS_BY_KIND: Dict[str, Dict[str, float]] = {
     "TPU v2":      {"bf16_flops": 46e12,   "int8_flops": 92e12,
                     "hbm_gbps": 700.0,  "vpu_ops": 0.9e12},
@@ -327,12 +326,21 @@ def bf16_peak_by_kind() -> Dict[str, float]:
 def peaks_for(device_kind: Optional[str] = None,
               backend: Optional[str] = None) -> Tuple[Dict[str, float], bool]:
     """(peaks, estimated): the device's peak record, or the generic CPU
-    fallback with ``estimated=True`` when the kind is unknown or the
-    backend is cpu — a flagged estimate beats an attribution-blind
-    line."""
-    if backend != "cpu" and device_kind in PEAKS_BY_KIND:
+    estimate with ``estimated=True`` on a cpu backend (and for a
+    device-less model call that names no kind at all) — a flagged
+    estimate beats an attribution-blind CPU line.  An accelerator whose
+    ``device_kind`` is not in :data:`PEAKS_BY_KIND` is an error, not a
+    default: a roofline share against somebody else's peaks is wrong
+    in a way nothing downstream can see."""
+    if backend == "cpu" or (backend is None
+                            and device_kind in (None, "", "cpu")):
+        return dict(GENERIC_CPU_PEAKS), True
+    if device_kind in PEAKS_BY_KIND:
         return dict(PEAKS_BY_KIND[device_kind]), False
-    return dict(GENERIC_CPU_PEAKS), True
+    raise ValueError(
+        f"device kind {device_kind!r} (backend {backend!r}) is not in "
+        f"obs.roofline.PEAKS_BY_KIND; add its peaks (with their source) "
+        f"before modeling it")
 
 
 def _ceil_div(a: int, b: int) -> int:

@@ -35,7 +35,7 @@ Two bin LAYOUTS share this contract (``binning``, see ``BINNINGS``):
   passed the 200k-row float64-oracle soundness gate AND bench.py's
   embedded tie-stressed gate on a v5e chip, and measured 1.8-3.1x
   faster than lane at the SIFT shape (kernel-only 171 -> 96/55.9 ms
-  per 4096 queries; tpu_bench_lines.jsonl kernel A/B).
+  per 4096 queries; 2026-07-31, before PR 1 — docs/PERF.md).
 - ``"lane"`` (round-3): bins are contiguous 128-lane spans; min/argmin
   reduce over lanes (~7 shuffle rounds each).  Kept for A/B.
 
@@ -96,9 +96,9 @@ see ``KERNELS``):
   through); the autotuner (knn_tpu.tuning) carries it in the default
   knob grid so the next TPU session measures it.
 
-Runs in interpret mode off-TPU so the CPU test suite covers it; the TPU
-session script (scripts/archive/tpu_session.py) gates the *compiled* kernel against
-the float64 oracle before any benchmark run.
+Runs in interpret mode off-TPU so the CPU test suite covers it;
+``chip_smoke.py`` gates the *compiled* kernel against a float64 oracle
+on the chip (round 3 showed interpret-pass is not hardware-sound).
 """
 
 from __future__ import annotations
@@ -126,7 +126,7 @@ BLOCK_Q = 128
 #: while halving the final-select width vs tile 8192 (62 tiles x 256 =
 #: 15.9k candidates vs 123 x 256 = 31.5k); every production shape
 #: compile-checks for v5e at this tile (scripts/aot_compile_check.py).
-#: Lane-mode round-3 measurements used 8192 (TUNING_r03).
+#: Lane-mode round-3 measurements used 8192.
 TILE_N = 16384
 #: dim is processed in chunks so arbitrarily wide features (GIST's 960)
 #: never blow VMEM; qt accumulates in scratch across chunks
@@ -828,12 +828,37 @@ def _stream_kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
     lax.fori_loop(0, n_tiles, tile_body, init)
 
 
-def _compiler_params(**kwargs):
-    """pltpu.CompilerParams across jax versions (0.4.x ships it as
-    TPUCompilerParams); only reached on compiled (non-interpret)
-    builds."""
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+def default_backend_is_tpu() -> bool:
+    """Whether kernels compile (True) or run in Pallas interpret mode
+    (False, the CPU test suite) when the caller leaves ``interpret`` at
+    None — decided at trace time from ``jax.default_backend()``.
+    ShardedKNN never passes ``interpret``; it reports this value in
+    ``stats["pallas_knobs"]["interpret"]``."""
+    return jax.default_backend() == "tpu"
+
+
+def _vmem_limit_bytes(kernel: str, **geometry) -> int:
+    """The scoped-VMEM limit a compiled launch requests, from the
+    modeled footprint of this geometry (knn_tpu.analysis.vmem — the ONE
+    home of the arithmetic, calibrated against what Mosaic reports).
+    A geometry the model says cannot fit the device is refused HERE,
+    naming the knobs to change, instead of by Mosaic's allocator dump.
+    Deviceless AOT compiles trace off-TPU and budget for the target
+    device kind."""
+    from knn_tpu.analysis import vmem
+
+    need = sum(vmem.kernel_bytes(kernel=kernel, **geometry).values())
+    kind = (jax.devices()[0].device_kind if default_backend_is_tpu()
+            else vmem.TARGET_DEVICE_KIND)
+    budget = vmem.budget_for(kind)
+    if not vmem.fits(need, budget):
+        raise ValueError(
+            f"kernel={kernel!r} at block_q={geometry['block_q']}, "
+            f"tile_n={geometry['tile_n']} over {geometry['n_tiles']} db "
+            f"tiles needs ~{need // vmem.MIB} MiB of VMEM; {kind} has "
+            f"{budget // vmem.MIB} MiB.  Lower block_q or tile_n"
+            + ("" if kernel == "tiled" else ", or use kernel='tiled'"))
+    return vmem.limit_bytes(need, budget)
 
 
 def _pad_axis(x, multiple: int, axis: int, fill: float = 0.0):
@@ -842,10 +867,6 @@ def _pad_axis(x, multiple: int, axis: int, fill: float = 0.0):
     from knn_tpu.parallel.mesh import pad_to_multiple
 
     return pad_to_multiple(x, multiple, axis, fill=fill)[0]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(
@@ -1146,21 +1167,20 @@ def _bin_candidates(
         o_idx = lambda q, t, d: (q, t)      # noqa: E731
     kwargs = {}
     if not interpret:
-        # the [block_q, tile_n] f32 score tile + double-buffered db
-        # tiles overflow the default 16 MB scoped-vmem budget.  64 MB
-        # covers the production geometries up to tile_n=16384; the
-        # budget scales with the score tile so tile_n=32768 (which cuts
-        # the final-select width 25% at survivors=3) can compile —
-        # v5e has 128 MB of VMEM, and a geometry that genuinely
-        # overflows still fails at compile time, never silently.
-        score_mb = block_q * tile_n * 4 // (1024 * 1024)
-        kwargs["compiler_params"] = _compiler_params(
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             # db_major: the outer axis is the db tile, whose input block
             # is revisited across inner steps — it must stay sequential
             dimension_semantics=(
                 ("arbitrary", "arbitrary", "arbitrary") if db_major
                 else ("parallel", "arbitrary", "arbitrary")),
-            vmem_limit_bytes=max(64, 3 * score_mb + 24) * 1024 * 1024,
+            vmem_limit_bytes=_vmem_limit_bytes(
+                "tiled", block_q=block_q, tile_n=tile_n, n_tiles=n_tiles,
+                nd=nd, out_w=out_w, bound_w=bound_w,
+                db_block=sum(tile_n * chunk_w * x.dtype.itemsize
+                             for x in db_inputs),
+                aux_rows=aux_rows,
+                q_block=block_q * q_block_w * queries_in.dtype.itemsize,
+                q_extra=len(q_extra) * block_q * BIN_W * 4),
         )
     db_specs = [pl.BlockSpec((tile_n, chunk_w), t_idx) for _ in db_inputs]
     if db_major:
@@ -1219,22 +1239,21 @@ def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
         n_parts=n_parts, chunk_w=chunk_w, aux_rows=aux_rows,
         fused=fused, keep=keep, pq_shape=pq_shape,
     )
-    any_space = getattr(pltpu, "ANY", None) or pltpu.TPUMemorySpace.ANY
     part_dtype = db_inputs[0].dtype
     kwargs = {}
     if not interpret:
-        # VMEM high-water: the full-width output blocks (the carried
-        # candidate list), the double-buffered db/norm slots, and the
-        # live [block_q, tile_n] score tile.  A geometry that genuinely
-        # overflows the chip still fails at compile time, never silently.
-        out_b = block_q * (2 * n_tiles * out_w + n_tiles * bound_w) * 4
-        buf_b = 2 * (n_parts * tile_n * chunk_w * part_dtype.itemsize
-                     + aux_rows * tile_n * 4)
-        score_b = block_q * tile_n * 4
-        budget = min(120, (out_b + buf_b + 2 * score_b) // 2 ** 20 + 32)
-        kwargs["compiler_params"] = _compiler_params(
+        depth = -(-int(keep) // BIN_W) if fused and keep is not None else 0
+        kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=budget * 1024 * 1024,
+            vmem_limit_bytes=_vmem_limit_bytes(
+                "fused" if fused else "streaming", block_q=block_q,
+                tile_n=tile_n, n_tiles=n_tiles, nd=nd, out_w=out_w,
+                bound_w=bound_w,
+                db_block=n_parts * tile_n * chunk_w * part_dtype.itemsize,
+                aux_rows=aux_rows,
+                q_block=block_q * dim * queries.dtype.itemsize,
+                q_extra=len(q_extra) * block_q * BIN_W * 4,
+                carry_depth=depth if depth <= MAX_CARRY_DEPTH else 0),
         )
     return pl.pallas_call(
         body,
@@ -1243,8 +1262,8 @@ def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
             pl.BlockSpec((block_q, dim), lambda q: (q, 0)),
             *[pl.BlockSpec((block_q, BIN_W), lambda q: (q, 0))
               for _ in q_extra],
-            *[pl.BlockSpec(memory_space=any_space) for _ in db_inputs],
-            pl.BlockSpec(memory_space=any_space),
+            *[pl.BlockSpec(memory_space=pl.ANY) for _ in db_inputs],
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
             pl.BlockSpec((block_q, n_tiles * out_w), lambda q: (q, 0)),
@@ -1332,7 +1351,7 @@ def local_certified_candidates(
     whenever their candidates cover the true top-k — and certified
     fallback material otherwise."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not default_backend_is_tpu()
     cd, ci, bounds = local_coarse_candidates(
         q, t, m, tile_n=tile_n, block_q=block_q, bin_w=bin_w,
         survivors=survivors, precision=precision, interpret=interpret,
@@ -1382,7 +1401,7 @@ def local_coarse_candidates(
     running the two stages back to back IS the one-shot function —
     bitwise, by construction."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not default_backend_is_tpu()
     if final_select not in ("exact", "approx"):
         raise ValueError(
             f"final_select {final_select!r} not in ('exact', 'approx')")

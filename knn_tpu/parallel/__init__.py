@@ -43,7 +43,6 @@ _EXPORTS = {
     "allreduce_min": "knn_tpu.parallel.collectives",
     "allreduce_max": "knn_tpu.parallel.collectives",
     "barrier": "knn_tpu.parallel.collectives",
-    "shard_map_compat": "knn_tpu.parallel.collectives",
     "ShardedKNN": "knn_tpu.parallel.sharded",
     "sharded_knn": "knn_tpu.parallel.sharded",
     "sharded_knn_predict": "knn_tpu.parallel.sharded",

@@ -38,25 +38,6 @@ from knn_tpu.parallel.crossover import (  # noqa: F401  (re-export)
 )
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` across the API move: top-level ``jax.shard_map``
-    (new JAX, ``check_vma`` kwarg) when present, else
-    ``jax.experimental.shard_map.shard_map`` (``check_rep`` kwarg — the
-    same switch under its pre-rename name).  Every SPMD program in
-    parallel.sharded routes through this one shim."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
-
-
 def replicate(x, mesh: Mesh) -> jax.Array:
     """MPI_Bcast (knn_mpi.cpp:224-225): one copy of ``x`` on every device."""
     return jax.device_put(x, NamedSharding(mesh, P()))

@@ -63,13 +63,10 @@ def initialize(
     # already-joined guard WITHOUT jax.process_count(): that call would
     # initialize the local backend first, after which distributed init
     # can no longer succeed
-    try:
-        from jax._src import distributed as _distributed
+    from jax._src import distributed as _distributed
 
-        if getattr(_distributed.global_state, "client", None) is not None:
-            return
-    except ImportError:  # internal layout moved; fall through to init
-        pass
+    if _distributed.global_state.client is not None:
+        return
     jax.distributed.initialize(
         coordinator_address=coordinator_address,
         num_processes=num_processes,
@@ -232,14 +229,10 @@ def dcn_allgather_arrays(arrays: Sequence[np.ndarray], *, tag: str,
     # reclaim coordinator memory: once EVERY process has read every
     # list (the barrier), each deletes its own key — without this a
     # long-lived replica grows the coordinator by one payload per
-    # search forever.  Older jaxlibs without barrier/delete degrade to
-    # leaving the keys (bounded only by process lifetime — documented).
-    try:
-        client.wait_at_barrier(f"knn_tpu/dcn/{tag}/read",
-                               int(timeout_s * 1000))
-        client.key_value_delete(own_key)
-    except AttributeError:
-        pass
+    # search forever.
+    client.wait_at_barrier(f"knn_tpu/dcn/{tag}/read",
+                           int(timeout_s * 1000))
+    client.key_value_delete(own_key)
     return out
 
 

@@ -219,15 +219,9 @@ def _run_jax(cfg: JobConfig, timer: PhaseTimer, train, train_labels, test, val,
 
 def _run_native(cfg: JobConfig, timer: PhaseTimer, train, train_labels, test, val,
                 val_labels_real):
-    try:
-        from knn_tpu import native
-    except ImportError:
-        native = None
-    if native is None or not native.available():
-        raise RuntimeError(
-            "native backend requested but the C++ library is not built; "
-            "run `make -C knn_tpu/native` (see knn_tpu/native/README.md)"
-        )
+    from knn_tpu import native
+
+    native.require()  # a failed build raises here, with make's error
     num_classes = _infer_num_classes(cfg, train_labels, val_labels_real)
     arrays = [a for a in (train, test, val) if a is not None]
     if cfg.normalize:

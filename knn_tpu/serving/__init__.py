@@ -9,7 +9,7 @@ between dispatches.  This package turns it into a throughput engine:
   bounds the compile cache at O(log(max/min)) executables;
 - :mod:`~knn_tpu.serving.engine` — :class:`ServingEngine`: precompiled
   (AOT) per-bucket executables with ``warmup()``, async dispatch-ahead
-  handles, donated query placements, trace replay, and full
+  handles, trace replay, and full
   compile/dispatch/latency accounting;
 - :mod:`~knn_tpu.serving.queue` — :class:`QueryQueue`: dynamic
   micro-batching of concurrent small requests under a max-wait deadline.

@@ -27,9 +27,7 @@ Three mechanisms turn the batch library into a throughput engine:
   :class:`PendingSearch` handle — JAX dispatch is asynchronous, so the
   host can pad/place/dispatch request N+1 while the device executes
   request N (double-buffered via :meth:`replay`'s bounded in-flight
-  window).  Query placements are DONATED to the program on non-CPU
-  backends, so each bucket's input buffer is recycled instead of
-  accumulating.
+  window).
 """
 
 from __future__ import annotations
@@ -177,10 +175,6 @@ class ServingEngine:
     ``locked-mutation`` checker, knn_tpu.analysis); the lock is never
     held across an XLA compile or a device dispatch (see
     :meth:`_executable`).
-
-    ``donate_queries=None`` donates the query placement to the program on
-    non-CPU backends (buffer reuse; CPU XLA rejects the donation with a
-    warning, so it defaults off there).
     """
 
     def __init__(
@@ -191,21 +185,15 @@ class ServingEngine:
         min_bucket: int = DEFAULT_MIN_BUCKET,
         max_bucket: int = DEFAULT_MAX_BUCKET,
         k: Optional[int] = None,
-        donate_queries: Optional[bool] = None,
         aot: bool = True,
         latency_window: int = 4096,
     ):
-        import jax
-
         self.program = program
         self.k = program.k if k is None else int(k)
         self.buckets = (
             bucket_ladder(min_bucket, max_bucket) if buckets is None
             else normalize_ladder(buckets)
         )
-        if donate_queries is None:
-            donate_queries = jax.default_backend() != "cpu"
-        self.donate_queries = bool(donate_queries)
         self._aot = bool(aot)
         if getattr(program, "_tp", None) is None:
             # a host-RAM-tier placement has no resident database to
@@ -254,8 +242,7 @@ class ServingEngine:
         if op == "search":
             return _knn_program(
                 p.mesh, self.k, p.metric, p.merge, p.n_train, p.train_tile,
-                p._dtype_key, donate=self.donate_queries,
-                dcn_merge=p.dcn_merge,
+                p._dtype_key, dcn_merge=p.dcn_merge,
             )
         if p._labels is None:
             raise RuntimeError(
@@ -263,8 +250,7 @@ class ServingEngine:
                 "labels")
         return _predict_program(
             p.mesh, self.k, p.num_classes, p.metric, p.merge, p.n_train,
-            p.train_tile, p._dtype_key, donate=self.donate_queries,
-            dcn_merge=p.dcn_merge,
+            p.train_tile, p._dtype_key, dcn_merge=p.dcn_merge,
         )
 
     def _placed_rows(self, bucket: int) -> int:
@@ -318,13 +304,7 @@ class ServingEngine:
                         (key[1], self._placed_dim), np.float32,
                         sharding=NamedSharding(self.program.mesh, P(QUERY_AXIS)),
                     )
-                    try:
-                        ex = fn.lower(q_spec, *self._tail_args(op)).compile()
-                    except Exception:
-                        # AOT API drift: fall back to the plain jitted callable
-                        # (still exactly one compile per placed shape, paid on
-                        # the first dispatch instead of here)
-                        ex = fn
+                    ex = fn.lower(q_spec, *self._tail_args(op)).compile()
                 else:
                     ex = fn
             with self._lock:
@@ -387,8 +367,6 @@ class ServingEngine:
             padded = chunk
 
         def go():
-            # re-place on every attempt: with donation the previous
-            # placement's buffer is consumed by the failed dispatch
             qp, _ = self.program._place_queries(padded)
             return self._executable(op, bucket, trace_id)(
                 qp, *self._tail_args(op))
@@ -686,6 +664,5 @@ class ServingEngine:
                 "requests_total": self._requests,
                 "queries_total": self._queries,
                 "errors_total": self._errors,
-                "donate_queries": self.donate_queries,
                 "latency_ms": latency_summary(self._latencies_s),
             }

@@ -22,8 +22,7 @@ The public entry points:
   re-timing (``counters()["candidates_timed"]`` pins that in tests and
   in the CLI's JSON output).
 - ``python -m knn_tpu.cli tune`` — the command a TPU session runs once
-  per shape, replacing the per-session hand search of
-  ``scripts/archive/tpu_session_r5b.py``.
+  per shape, replacing a per-session hand search.
 """
 
 from __future__ import annotations
@@ -232,23 +231,18 @@ def knob_grid(level: str = "standard",
       (streaming + db_major) are skipped at enumeration, duplicates
       dropped, order deterministic.
 
-    The grid does NOT model-censor on VMEM: every combination that
-    fits at least one known device kind is enumerated and the two
-    explicit gates judge it — the ``vmem-budget`` checker in ``cli
-    lint`` fails loudly at authoring time if a fits-NOWHERE arm is
-    added, and the runtime gate in :func:`autotune` refuses
+    VMEM: a combination that fits NO known device kind at the headline
+    shape (knn_tpu.analysis.vmem — the model the kernel sizes its own
+    request from, calibrated against Mosaic's reported need) is dropped
+    at enumeration; the ``vmem-budget`` checker in ``cli lint`` holds
+    the grid to that, and the runtime gate in :func:`autotune` refuses
     over-budget candidates at the REAL shape/device with provenance.
-    The one authored exclusion below (bf16x3f x streaming/fused x
-    tile_n>=32768 x block_q>=256, ~140 MB/launch — over every known
-    device kind) is itself pinned by that checker; a generic
-    model-driven cut here would hide fitting candidates with no
-    provenance, which is exactly what the gates exist to prevent.
 
     ``final_select`` is part of every level (the exact/approx deviation
     at the otherwise-winning geometries): a cached winner's
     final_select is therefore a MEASURED choice, never a default copied
     into the cache — consumers with their own final_select preference
-    (bench.py's historical relay-side "approx") yield to a cache hit
+    (bench.py's historical "approx") yield to a cache hit
     precisely because the hit measured it.
 
     ``profile`` (:data:`knn_tpu.tuning.cache.PROFILES`) picks the
@@ -286,27 +280,11 @@ def knob_grid(level: str = "standard",
         if knobs["precision"] == "pq" and knobs["kernel"] == "fused":
             return  # ops.pallas_knn refuses: carry soundness unproven
             # for reconstruction-space scores
-        if (knobs["precision"] == "bf16x3f"
-                and knobs["kernel"] in ("streaming", "fused")
-                and (knobs["tile_n"] or 0) >= 32768
-                and (knobs["block_q"] or 128) >= 256):
-            # widest streamed db precision (6 B/elem) x largest tile x
-            # block_q>=256: ~140 MB/launch at the headline shape —
-            # over EVERY known device kind's VMEM, so the arm can
-            # never be timed anywhere (knn_tpu.analysis.vmem; the
-            # vmem-budget checker fails the lint if a fits-nowhere arm
-            # like this sneaks back in).  The block_q=128 variants
-            # price at ~96 MB, fit v4+, and stay in the grid.
-            return
-        if (knobs["kernel"] in ("streaming", "fused")
-                and (knobs["block_q"] or 128) >= 512):
-            # throughput-ladder block_q: the streaming/fused per-launch
-            # score block alone (block_q x tile x 4 B plus the resident
-            # db slab) prices over EVERY known device kind's VMEM at
-            # every authored tile_n/precision (same fits-nowhere
-            # analysis as above; vmem-budget checker-pinned).  The
-            # tiled kernel re-blocks queries against a single db tile
-            # and is the only kernel the 512/1024 ladder can reach.
+        if not _vmem.fits_some_kind(knobs, **_vmem.HEADLINE_SHAPE):
+            # fits NO known device kind's VMEM at the headline shape:
+            # the kernel itself would refuse it everywhere
+            # (ops.pallas_knn._vmem_limit_bytes prices with the same
+            # model), so timing it can only record an error
             return
         lbl = _label(knobs)
         if lbl not in seen:
@@ -314,8 +292,8 @@ def knob_grid(level: str = "standard",
             out.append(knobs)
 
     def extend_throughput():
-        # the bulk-join regime's large-block arms (tiled only — see the
-        # authored exclusion above): block_q deviations alone, their
+        # the bulk-join regime's large-block arms (tiled only — the
+        # streaming/fused ones fit nowhere): block_q deviations alone, their
         # approx-select cross, the tile ladder, and the quantized-db
         # precisions whose smaller streamed bytes pair naturally with
         # deeper query blocks.  Every arm fits at least one device kind
@@ -326,15 +304,14 @@ def knob_grid(level: str = "standard",
             add(block_q=bq, tile_n=8192)
             for prec in ("bf16x3f", "int8", "int4"):
                 add(block_q=bq, precision=prec)
-        # the largest-tile cross stops at block_q=512: at 1024 the f32
-        # score block alone is 1024 x 32768 x 4 B = 128 MB — the WHOLE
-        # largest known VMEM before operands/carry, fits nowhere
-        add(block_q=512, tile_n=32768)
-        add(block_q=512, precision="int8", tile_n=32768)
 
     for kern in ("tiled", "streaming", "fused"):
         for order in ("query_major", "db_major"):
             add(kernel=kern, grid_order=order)
+    # the fused arm holds the streaming kernel's full-width candidate
+    # block plus its carry: at the headline shape it fits VMEM at
+    # block_q=128, not at the default 256 (dropped by the cut above)
+    add(kernel="fused", block_q=128)
     add(final_select="approx")
     if level == "quick":
         if profile == "throughput":
@@ -738,8 +715,7 @@ def autotune(
     # Mosaic compile time on hardware, mid-tune, so it is refused here
     # with provenance — recorded like roofline pruning (entry["vmem"] +
     # a "vmem-refused: ..." errors line), never silently
-    budget_bytes, budget_estimated = _vmem.budget_for(device_kind,
-                                                      backend)
+    budget_bytes = _vmem.budget_for(device_kind, backend)
     vmem_info = None
     if budget_bytes is not None:
         refused_rec: Dict[str, dict] = {}
@@ -776,7 +752,6 @@ def autotune(
         vmem_info = {
             "device_kind": device_kind,
             "budget_bytes": budget_bytes,
-            "estimated_budget": budget_estimated,
             "candidates_refused": len(refused_rec),
             "refused": refused_rec,
         }

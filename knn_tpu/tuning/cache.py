@@ -3,9 +3,8 @@
 One JSON file maps ``cache_key(device_kind, n, d, k, metric, dtype)``
 to the measured winning knob set plus its provenance (timings, gate
 verdict, jax version, timestamp).  The point is operational: every
-hand-tuned TPU-session knob search so far died with the session
-(TUNING_r03.jsonl, scripts/archive/tpu_session_r5b.py) — a persisted winner
-keyed by the exact problem shape survives the session, so the next
+hand-tuned TPU-session knob search so far died with the session — a
+persisted winner keyed by the exact problem shape survives the session, so the next
 ``ShardedKNN.search_certified`` / bench run on the same chip resolves
 its knobs from disk with ZERO re-timing.
 

@@ -15,8 +15,7 @@ roofline model's terms, persist per-term calibration factors
 validated campaign JSONL artifact — which hardware runs also append to
 ``tpu_bench_lines.jsonl`` for ``refresh_bench_artifacts.py`` to curate
 and the sentinel to baseline.  Runbook: docs/PERF.md "Calibration &
-measured ceilings"; this supersedes the hand-driven TPU session
-scripts now archived under scripts/archive/."""
+measured ceilings"."""
 
 import os
 import sys

@@ -5,10 +5,11 @@ for every real-multi-process test (tests/test_multihost.py) and for the
 Two capabilities, probed separately because they fail separately:
 
 - ``multiprocess_cpu_supported()`` — whether this jaxlib can EXECUTE
-  XLA computations spanning jax.distributed CPU processes (0.4.3x
-  builds raise "Multiprocess computations aren't implemented on the
-  CPU backend").  Tests that run process-spanning SPMD programs skip
-  with the probe's actual error when red.
+  XLA computations spanning jax.distributed CPU processes (green on
+  the installed jaxlib 0.9.0; a build without it raises "Multiprocess
+  computations aren't implemented on the CPU backend").  Tests that
+  run process-spanning SPMD programs skip with the probe's actual
+  error when red.
 - ``distributed_init_supported()`` — whether ``jax.distributed``
   processes can merely JOIN a coordinator and use its key-value store.
   This holds on every supported jaxlib (the store lives beside XLA,

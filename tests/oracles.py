@@ -73,3 +73,23 @@ def knn_classify(train, labels, queries, k, num_classes, metric="l2"):
     d = sq_l2(queries, train) if metric in ("l2", "sql2", "euclidean") else l1(queries, train)
     _, idx = topk_lowindex(d, k)
     return running_argmax_vote(labels[idx], num_classes)
+
+
+def assert_same_neighbors(d, i, ref_d, ref_i, q, db):
+    """What the search contract promises between two DIFFERENTLY SHAPED
+    programs (a flat and a hierarchical mesh, a resident and a streamed
+    placement, a superblock and a looped batch): equal indices, and
+    squared-L2 f32 distances within the rounding of the expanded-square
+    form — 8 eps_f32 (||q||^2 + max||t||^2) per query, the slack
+    ops.certified budgets for the same arithmetic.  XLA may order a
+    gemm's K-reduction differently for every operand shape, so bitwise
+    f32 distances are promised only between runs of the same program
+    (docs/serving.md); tests of that keep assert_array_equal."""
+    from knn_tpu.ops.certified import certification_tolerance
+
+    np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_i))
+    tol = certification_tolerance(np.asarray(q), np.asarray(db))
+    err = np.abs(np.asarray(d, np.float64) - np.asarray(ref_d, np.float64))
+    assert (err <= tol[:, None]).all(), (
+        f"distances differ by up to {err.max():.3e}, over the f32 "
+        f"rounding bound {tol.min():.3e}")

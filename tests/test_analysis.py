@@ -627,13 +627,54 @@ def test_launch_estimate_breakdown_and_monotonicity():
 
 
 def test_budget_for_provenance():
-    assert vmem.budget_for("TPU v5e") == (128 * vmem.MIB, False)
-    assert vmem.budget_for("TPU v3") == (16 * vmem.MIB, False)
-    # unknown TPU generations get the modern default, flagged estimated
-    assert vmem.budget_for("TPU v9x") == (vmem.DEFAULT_VMEM_BYTES, True)
+    assert vmem.budget_for("TPU v5e") == 128 * vmem.MIB
+    assert vmem.budget_for("TPU v3") == 16 * vmem.MIB
+    # a TPU the table does not know is an error, not a default
+    with pytest.raises(ValueError, match="VMEM_BYTES_BY_KIND"):
+        vmem.budget_for("TPU v9x")
+    with pytest.raises(ValueError, match="VMEM_BYTES_BY_KIND"):
+        vmem.budget_for(None, "tpu")
     # no VMEM to budget on host backends: N/A, never a refusal
-    assert vmem.budget_for(None, "cpu") == (None, False)
-    assert vmem.budget_for("cpu") == (None, False)
+    assert vmem.budget_for(None, "cpu") is None
+    assert vmem.budget_for("cpu") is None
+
+
+def test_vmem_model_tracks_the_compiler_at_the_benchmark_shapes():
+    """The scoped-VMEM need Mosaic reported (libtpu 0.0.34, v5e,
+    ``scripts/aot_compile_check.py --probe``; bf16x3, 4,096 queries)
+    against the model, in MiB: the model may run up to 8% over and 1%
+    under, and must refuse exactly what the compiler refused."""
+    shapes = {"sift": (1_000_000, 128, 100), "gist": (1_000_000, 960, 100),
+              "glove": (1_183_514, 300, 50)}
+    reported = [
+        ("sift", "tiled", 128, 16384, 31.51),
+        ("sift", "tiled", 256, 16384, 47.18),
+        ("sift", "tiled", 128, 32768, 62.47),
+        ("sift", "tiled", 256, 32768, 93.09),
+        ("gist", "tiled", 128, 16384, 49.10),
+        ("gist", "tiled", 256, 16384, 81.94),
+        ("gist", "tiled", 128, 32768, 99.60),
+        ("glove", "tiled", 256, 16384, 82.94),
+        ("sift", "streaming", 128, 16384, 71.65),
+        ("sift", "streaming", 256, 16384, 126.55),
+        ("gist", "streaming", 128, 16384, 80.07),
+        ("glove", "streaming", 128, 16384, 86.63),
+        ("sift", "fused", 128, 16384, 73.32),
+        ("sift", "fused", 256, 16384, 133.75),  # > 128 MiB physical
+        ("gist", "fused", 128, 16384, 82.64),
+        ("gist", "tiled", 256, 32768, 162.41),  # > 128 MiB physical
+    ]
+    budget = vmem.budget_for(vmem.TARGET_DEVICE_KIND)
+    for shape, kernel, bq, tile, need in reported:
+        n, d, k = shapes[shape]
+        est = vmem.launch_estimate(
+            n=n, d=d, k=k, kernel=kernel, block_q=bq,
+            tile_n=tile)["total_bytes"]
+        assert 0.99 * need <= est / vmem.MIB <= 1.08 * need, (
+            shape, kernel, bq, tile, est / vmem.MIB, need)
+        assert vmem.fits(est, budget) == (need <= 128), (shape, kernel, bq)
+        if vmem.fits(est, budget):
+            assert vmem.limit_bytes(est, budget) >= need * vmem.MIB
 
 
 def test_check_candidate_verdicts():

@@ -251,7 +251,7 @@ def test_tuning_cache_entry_schema_accepts_a_real_entry_shape():
         "errors": {}, "roofline_per_candidate": {},
         "gate": "bitwise-vs-reference", "runs": 2, "n_queries": 8,
         "margin": 4, "device_kind": "cpu", "backend": "cpu",
-        "jax_version": "0.4.37", "measured_at": "2026-08-04T00:00:00Z",
+        "jax_version": "0.9.0", "measured_at": "2026-08-04T00:00:00Z",
         "roofline": good_roofline(), "roofline_pct": 0.5,
         "bound_class": "hbm_bound",
     }

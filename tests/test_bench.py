@@ -74,12 +74,7 @@ def test_bench_bad_config_still_emits_json_line():
 
 
 def test_bench_bad_platform_still_emits_json_line():
-    rc, lines = _run({
-        "KNN_BENCH_PLATFORM": "bogus",
-        "KNN_BENCH_INIT_ATTEMPTS": "1",
-        "KNN_BENCH_INIT_TIMEOUT": "30",
-        "KNN_BENCH_FALLBACK_CPU": "0",  # default-on fallback would succeed
-    }, timeout=120)
+    rc, lines = _run({"KNN_BENCH_PLATFORM": "bogus"}, timeout=120)
     assert rc == 1
     assert len(lines) == 1
     rec = json.loads(lines[0])
@@ -87,74 +82,47 @@ def test_bench_bad_platform_still_emits_json_line():
     assert "backend_init" in rec["error"]
 
 
+def test_bench_without_a_tpu_fails_instead_of_falling_back():
+    # no KNN_BENCH_PLATFORM: the bench wants a TPU, this host has none,
+    # and a CPU number must never stand in for it
+    rc, lines = _run({}, timeout=120)
+    assert rc == 1
+    assert len(lines) == 1
+    rec = json.loads(lines[0])
+    assert rec["value"] is None
+    assert "backend_init" in rec["error"] and "'cpu'" in rec["error"]
+
+
+def test_bench_refuses_more_chips_than_the_host_has():
+    env = dict(os.environ)
+    env.pop("JAX_PLATFORMS", None)
+    env.pop("XLA_FLAGS", None)  # one CPU device, not the suite's eight
+    env.update({"KNN_BENCH_PLATFORM": "cpu", "KNN_BENCH_N": "4000",
+                "KNN_BENCH_NQ": "32", "KNN_BENCH_CPU_QUERIES": "4"})
+    proc = subprocess.run(
+        [sys.executable, BENCH, "--chips", "4"], capture_output=True,
+        text=True, timeout=120, env=env, cwd=REPO)
+    assert proc.returncode == 1
+    rec = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert "--chips 4" in rec["error"]
+
+
 @pytest.mark.slow
-def test_bench_falls_back_to_cpu_by_default():
-    # the round-3 lesson: a flagged CPU number beats a null round record.
-    # A bogus accelerator platform + the default-on fallback must yield a
-    # real measurement honestly stamped backend=cpu.
+def test_bench_failed_requested_mode_fails_the_run_after_the_line():
+    # one good mode, one that cannot run: the line is still printed
+    # (with the bad mode's error on it), and the exit code is non-zero
     rc, lines = _run({
-        "KNN_BENCH_PLATFORM": "bogus",
-        "KNN_BENCH_INIT_ATTEMPTS": "1",
-        "KNN_BENCH_INIT_TIMEOUT": "30",
+        "KNN_BENCH_PLATFORM": "cpu",
         "KNN_BENCH_N": "4000", "KNN_BENCH_NQ": "32", "KNN_BENCH_BATCH": "32",
         "KNN_BENCH_K": "5", "KNN_BENCH_MARGIN": "4", "KNN_BENCH_TILE": "2048",
         "KNN_BENCH_CPU_QUERIES": "8", "KNN_BENCH_RUNS": "1",
-        "KNN_BENCH_MODES": "exact",
+        "KNN_BENCH_MODES": "exact,no_such_mode",
     })
-    assert rc == 0, lines
-    assert len(lines) == 1, lines  # the one-JSON-line stdout contract
+    assert rc == 1, lines
+    assert len(lines) == 1, lines
     rec = json.loads(lines[0])
-    assert rec["value"] > 0
-    assert rec["backend"] == "cpu"
-
-
-def test_probe_hang_is_killed_and_reported(monkeypatch, tmp_path):
-    """The round-3 failure mode: backend init hangs forever.  The probe
-    child must be KILLED at the timeout (parent lock untouched) and the
-    hang reported distinctly from a fast failure."""
-    import importlib
-    import bench as bench_mod
-
-    bench = importlib.reload(bench_mod)
-    # a child that sleeps forever stands in for the stale-claim hang
-    hang = tmp_path / "hang.py"
-    hang.write_text("import time\ntime.sleep(3600)\n")
-    real_exe = sys.executable
-    real_run = subprocess.run
-
-    def fake_run(cmd, **kw):
-        # substitute the hanging child for the probe's -c payload
-        return real_run([real_exe, str(hang)], **{
-            k: v for k, v in kw.items() if k != "env"})
-
-    monkeypatch.setattr(subprocess, "run", fake_run)
-    import time as _time
-
-    t0 = _time.monotonic()
-    ok, err, hung = bench._probe_backend_subprocess(timeout=2)
-    took = _time.monotonic() - t0
-    assert not ok and hung
-    assert "hung" in err
-    assert took < 30  # the child was killed at the timeout, not awaited
-
-
-def test_probe_fast_failure_not_flagged_as_hang(monkeypatch, tmp_path):
-    import importlib
-    import bench as bench_mod
-
-    bench = importlib.reload(bench_mod)
-    boom = tmp_path / "boom.py"
-    boom.write_text("raise SystemExit('no accelerator')\n")
-    real_run = subprocess.run
-
-    def fake_run(cmd, **kw):
-        return real_run([sys.executable, str(boom)], **{
-            k: v for k, v in kw.items() if k != "env"})
-
-    monkeypatch.setattr(subprocess, "run", fake_run)
-    ok, err, hung = bench._probe_backend_subprocess(timeout=30)
-    assert not ok and not hung
-    assert "rc=" in err
+    assert rec["value"] > 0 and rec["mode"] == "exact"
+    assert "error" in rec["selectors"]["no_such_mode"]
 
 
 @pytest.mark.slow

@@ -1,0 +1,81 @@
+"""Bring-up contracts that hold without a chip: where the compile cache
+goes, that ``chip_smoke.py`` refuses to stand in for a chip run on CPU,
+and (slow) that the library-default geometries still compile for the
+v5e — ``scripts/aot_compile_check.py``, deviceless — so the next default
+promotion meets GIST and GloVe before it meets the chip."""
+
+import os
+import subprocess
+import sys
+import tempfile
+
+import jax
+import pytest
+
+from knn_tpu.utils import compat
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture
+def config_writes(monkeypatch):
+    """Record jax.config.update calls instead of applying them."""
+    writes = {}
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: writes.__setitem__(name, value))
+    return writes
+
+
+def test_compile_cache_env_var_set_means_no_path_in_code(
+        monkeypatch, config_writes):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert compat.enable_compile_cache() == "/somewhere/else"
+    assert "jax_compilation_cache_dir" not in config_writes
+    # the thresholds still drop, so serving buckets are cached there too
+    assert config_writes["jax_persistent_cache_min_compile_time_secs"] == 0.0
+    assert config_writes["jax_persistent_cache_min_entry_size_bytes"] == -1
+
+
+def test_compile_cache_unset_is_the_fixed_checkout_path(
+        monkeypatch, config_writes):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = compat.enable_compile_cache()
+    assert first == os.path.join(REPO, ".jax_cache")
+    assert config_writes["jax_compilation_cache_dir"] == first
+    # fixed: a second call (another process, another day) names the same
+    # directory, and it is never a temp path
+    assert compat.enable_compile_cache() == first
+    assert not first.startswith(tempfile.gettempdir())
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
+
+
+def test_chip_smoke_refuses_to_run_on_cpu():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, env=env, cwd=REPO)
+    assert proc.returncode != 0
+    assert "needs a TPU" in proc.stderr and "'cpu'" in proc.stderr
+    assert "Traceback" not in proc.stderr
+    # no verdict line: the last stdout line is the device report
+    last = proc.stdout.strip().splitlines()[-1]
+    assert "platform cpu" in last and '"ok"' not in proc.stdout
+
+
+@pytest.mark.slow
+def test_default_geometries_compile_for_v5e_deviceless():
+    """The table a bare ``aot_compile_check.py`` prints: defaults at SIFT,
+    GIST and GloVe, streaming at its default, fused at block_q=128 — and
+    fused at its default REFUSED by the library before Mosaic is asked.
+    Any Mosaic refusal (or a library refusal that went away) fails."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "scripts",
+                                      "aot_compile_check.py")],
+        capture_output=True, text=True, timeout=1500, env=env, cwd=REPO)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
+    lines = [ln for ln in proc.stdout.splitlines() if ln[:4] in ("OK  ",
+                                                                 "FAIL")]
+    assert len(lines) == 6 and all(ln.startswith("OK") for ln in lines)
+    assert "sift fused defaults: refused" in proc.stdout

@@ -148,25 +148,15 @@ def test_malformed_traces_error_loudly(tmp_path):
         traceread.find_trace_files(str(tmp_path / "absent"))
 
 
-def test_host_phase_sample_excludes_relay_transport():
-    """The structured transport field (bench satellite): dev-relay
-    h2d/d2h latency is harness time and lands in the exclusion record,
-    never in the device sample; a breakdown without device_s is loudly
+def test_host_phase_sample_takes_only_the_device_phase():
+    """Only the fenced device phase enters the sample — the h2d/d2h
+    phases are host-link time; a breakdown without device_s is loudly
     unusable."""
     pb = {"device_s": 0.5, "device_qps": 8192.0,
-          "h2d_queries_s": 1.2, "d2h_transfer_s": 2.4,
-          "transport": {"kind": "dev_relay",
-                        "latency_corrected": False}}
+          "h2d_queries_s": 1.2, "d2h_transfer_s": 2.4}
     s = traceread.sample_from_phases(pb, nq=4096)
-    assert s["source"] == "host_phase"
-    assert s["device_s"] == 0.5
-    assert s["relay_phases_excluded_s"] == {"h2d_queries_s": 1.2,
-                                            "d2h_transfer_s": 2.4}
-    # pcie transport: nothing excluded (the transfers are chip-real)
-    s2 = traceread.sample_from_phases(
-        dict(pb, transport={"kind": "pcie",
-                            "latency_corrected": True}), nq=4096)
-    assert s2["relay_phases_excluded_s"] is None
+    assert s == {"source": "host_phase", "device_s": 0.5, "nq": 4096,
+                 "qps": 8192.0}
     with pytest.raises(traceread.TraceReadError, match="device_s"):
         traceread.sample_from_phases({"note": "no probe"}, nq=4096)
 
@@ -339,13 +329,9 @@ def test_r05_curated_line_rerenders_with_explicit_calibration_absent():
     current-MODEL_VERSION block whose calibration verdict is EXPLICITLY
     absent — pre-calibration history re-renders honestly instead of
     silently claiming calibrated."""
-    rec = None
-    for line in open(os.path.join(REPO, "TPU_BENCH_r05.jsonl")):
-        cand = json.loads(line)
-        if cand.get("metric", "").startswith("knn_qps_sift1m"):
-            rec = cand
-            break
-    assert rec is not None
+    with open(os.path.join(REPO, "tests", "fixtures",
+                           "bench_line_sift1m_v5e.json")) as f:
+        rec = json.load(f)
     block = roofline.block_for_bench_line(rec)
     assert block["model_version"] == roofline.MODEL_VERSION
     assert block["calibration"] == {"applied": False}

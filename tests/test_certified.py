@@ -6,7 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from knn_tpu.ops.certified import count_below, knn_search_certified
+from knn_tpu.ops.certified import (
+    certification_tolerance,
+    count_below,
+    knn_search_certified,
+)
 
 
 def _oracle(db, queries, k):
@@ -28,21 +32,18 @@ def test_count_below_matches_numpy(data):
     d64 = ((db.astype(np.float64)[None] - queries.astype(np.float64)[:, None]) ** 2).sum(-1)
     thr = np.quantile(d64, 0.1, axis=-1).astype(np.float32)
     got = np.asarray(count_below(jnp.asarray(db), jnp.asarray(queries), jnp.asarray(thr), tile=100))
-    # the documented contract is FLOAT32 expanded-square arithmetic
-    # ("computed exactly like the fast path"): compare against the same
-    # f32 formulation — an f64 oracle flips rows whose f32 rounding
-    # crosses the threshold, backend-dependently
-    d32 = np.maximum(
-        (queries.astype(np.float32) ** 2).sum(-1)[:, None]
-        + (db.astype(np.float32) ** 2).sum(-1)[None]
-        - 2.0 * (queries.astype(np.float32) @ db.astype(np.float32).T),
-        0.0,
-    )
-    want32 = (d32 < thr[:, None]).sum(-1)
-    np.testing.assert_array_equal(got, want32)
-    # f64 sanity: only boundary rows may differ, and only by a few
+    # the documented contract is FLOAT32 expanded-square arithmetic: a
+    # row whose true distance sits within the f32 error of the threshold
+    # (certification_tolerance, the slack the certificate itself budgets
+    # for this pass) may be counted on either side — by this backend's
+    # program or any other — and no other row may.  The count is held
+    # to the float64 truth within the rows that rounding can flip.
     want64 = (d64 < thr[:, None]).sum(-1)
-    assert np.abs(got - want64).max() <= 3
+    tol = certification_tolerance(queries, db)
+    flippable = (np.abs(d64 - thr[:, None]) <= tol[:, None]).sum(-1)
+    assert (np.abs(got - want64) <= flippable).all(), (
+        got - want64, flippable)
+    assert flippable.max() <= 3  # the band stays a boundary effect
 
 
 def test_certified_matches_oracle(data):

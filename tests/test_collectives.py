@@ -21,7 +21,6 @@ from knn_tpu.parallel import (
     make_mesh,
     replicate,
     shard,
-    shard_map_compat,
 )
 
 
@@ -50,7 +49,7 @@ def test_gather_reassembles_shards(rng):
     x = rng.normal(size=(24, 4)).astype(np.float32)
 
     fn = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             lambda q: gather(q, QUERY_AXIS),
             mesh=mesh,
             in_specs=P(QUERY_AXIS),
@@ -66,7 +65,7 @@ def test_gather_stacked_gives_device_axis(rng):
     x = np.arange(8, dtype=np.float32)[:, None]
 
     fn = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             lambda q: gather(q, QUERY_AXIS, tiled=False),
             mesh=mesh,
             in_specs=P(QUERY_AXIS),
@@ -87,7 +86,7 @@ def test_allreduce_extrema_match_global(rng):
         return lo, hi
 
     fn = jax.jit(
-        shard_map_compat(
+        jax.shard_map(
             spmd, mesh=mesh,
             in_specs=P((QUERY_AXIS, DB_AXIS)),
             out_specs=(P(), P()),

@@ -9,6 +9,7 @@ budget (resident, no tier), ONE ROW over (2 sweeps), and many-x over
 
 import numpy as np
 import pytest
+from oracles import assert_same_neighbors
 
 from knn_tpu.analysis import hbm
 from knn_tpu.parallel import ShardedKNN, make_mesh
@@ -75,8 +76,9 @@ def test_many_times_over_budget_matches_byte_model_and_is_bitwise(rng):
     assert expect >= 6  # genuinely many-x over
     assert st["sweeps"] == expect
     d, i = prog.search(q)
-    np.testing.assert_array_equal(i, np.asarray(ref_i))
-    np.testing.assert_array_equal(d, np.asarray(ref_d))
+    # the tier's segment program and the resident program are different
+    # shapes: same neighbours, f32 distances within rounding
+    assert_same_neighbors(d, i, ref_d, ref_i, q, db)
     last = prog.hosttier_stats()["last_search"]
     assert last["sweeps"] == expect
     assert len(last["sweep_walls_s"]) == expect
@@ -95,11 +97,11 @@ def test_many_times_over_budget_matches_byte_model_and_is_bitwise(rng):
 
 
 def test_host_tier_on_hierarchical_mesh(rng):
-    # tier-vs-resident on the SAME hierarchical mesh: the bitwise
-    # contract is placement-invariance of per-pair distances, which on
-    # CPU holds per mesh shape (XLA's gemm strategy varies with operand
-    # shape in the last float bits — serving.engine docstring; TPU MXU
-    # is shape-invariant)
+    # tier-vs-resident on the SAME hierarchical mesh: the segment
+    # program's operands are shaped differently from the resident
+    # program's, so the contract is equal neighbours and f32 distances
+    # within rounding (XLA's gemm strategy varies with operand shape in
+    # the last float bits — serving.engine docstring)
     db = _db(rng, 240)
     q = _db(rng, 8)
     ref_d, ref_i = ShardedKNN(db, mesh=make_host_mesh(2, 2, 2),
@@ -109,8 +111,7 @@ def test_host_tier_on_hierarchical_mesh(rng):
     st = prog.hosttier_stats()
     assert st is not None and st["sweeps"] >= 2
     d, i = prog.search(q)
-    np.testing.assert_array_equal(i, np.asarray(ref_i))
-    np.testing.assert_array_equal(d, np.asarray(ref_d))
+    assert_same_neighbors(d, i, ref_d, ref_i, q, db)
 
 
 def test_host_tier_k_override_and_cosine(rng):

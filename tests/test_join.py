@@ -24,6 +24,7 @@ import time
 
 import numpy as np
 import pytest
+from oracles import assert_same_neighbors
 
 from knn_tpu.analysis import hbm
 from knn_tpu.join import (JOIN_MODES, JOIN_VERSION, default_plan,
@@ -225,8 +226,9 @@ def test_superhbm_b_join_db_major_matches_byte_model_and_resident(rng):
     assert st["db_segments"] == plan["db_segments"] == segs
     assert st["dispatches"] == plan["dispatches"] == 3 * segs
     ref_d, ref_i = _looped_search(resident, q, 16)
-    np.testing.assert_array_equal(i, ref_i)
-    np.testing.assert_array_equal(d, ref_d)
+    # streamed segments vs the resident placement: differently shaped
+    # programs — same neighbours, f32 distances within rounding
+    assert_same_neighbors(d, i, ref_d, ref_i, q, db)
 
 
 def test_superhbm_b_join_query_major_single_superblock(rng):
@@ -243,8 +245,7 @@ def test_superhbm_b_join_query_major_single_superblock(rng):
     assert st["db_segments"] > 1
     assert st["dispatches"] == st["db_segments"]
     ref_d, ref_i = _looped_search(resident, q, 48)
-    np.testing.assert_array_equal(i, ref_i)
-    np.testing.assert_array_equal(d, ref_d)
+    assert_same_neighbors(d, i, ref_d, ref_i, q, db)
 
 
 # -- throughput acceptance (CPU) ------------------------------------------

@@ -5,8 +5,9 @@ that a real pod run relies on.
 
 The three REAL-multi-process tests additionally need a jaxlib whose CPU
 backend can execute computations spanning jax.distributed processes;
-not every jaxlib build can (0.4.37 raises "Multiprocess computations
-aren't implemented on the CPU backend").  A one-shot capability probe
+not every jaxlib build can (the installed 0.9.0 can; one that cannot
+raises "Multiprocess computations aren't implemented on the CPU
+backend").  A one-shot capability probe
 (``_multiprocess_cpu_supported``) decides ONCE per session and those
 tests skip with the probe's actual error as the reason — tier-1 stays
 green on such builds instead of carrying known-red entries, and the
@@ -15,6 +16,7 @@ tests reactivate by themselves on a jaxlib that grows the capability."""
 import jax
 import numpy as np
 import pytest
+from oracles import assert_same_neighbors
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from knn_tpu.parallel import DB_AXIS, ShardedKNN, make_mesh
@@ -278,8 +280,10 @@ def test_multihost_2x2_mesh_four_processes(rng, tmp_path):
 # --- hierarchical mesh: per-chip -> per-host -> global merge tree ------
 # Single-process over the 8 virtual CPU devices: the 3-axis
 # make_host_mesh placement runs the SAME SPMD programs a real pod runs,
-# and every result must be bitwise-identical to the flat mesh — the
-# merge tree is associative, so the hierarchy is free.
+# and every result must name the same neighbours as the flat mesh — the
+# merge tree is associative, so the hierarchy is free.  The local shard
+# shapes differ between the two meshes, so f32 distances are held to
+# rounding (oracles.assert_same_neighbors), not to the bit.
 
 def test_host_mesh_search_bitwise_vs_flat(rng):
     from knn_tpu.parallel.mesh import make_host_mesh
@@ -290,8 +294,7 @@ def test_host_mesh_search_bitwise_vs_flat(rng):
     for hosts, chips in ((2, 2), (4, 1), (2, 1)):
         prog = ShardedKNN(db, mesh=make_host_mesh(2, hosts, chips), k=7)
         d, i = prog.search(q)
-        np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_i))
-        np.testing.assert_array_equal(np.asarray(d), np.asarray(ref_d))
+        assert_same_neighbors(d, i, ref_d, ref_i, q, db)
 
 
 def test_host_mesh_merge_strategy_combinations_bitwise(rng):
@@ -308,8 +311,7 @@ def test_host_mesh_merge_strategy_combinations_bitwise(rng):
             assert (prog.merge, prog.dcn_merge) == (intra, dcn)
             assert prog.merge_source == prog.dcn_merge_source == "explicit"
             d, i = prog.search(q)
-            np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_i))
-            np.testing.assert_array_equal(np.asarray(d), np.asarray(ref_d))
+            assert_same_neighbors(d, i, ref_d, ref_i, q, db)
 
 
 def test_host_mesh_certified_bitwise_across_selectors(rng):

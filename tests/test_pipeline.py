@@ -110,8 +110,8 @@ def test_cli_parsing_does_not_import_jax():
     # config cannot pull JAX into the process
     import subprocess, sys
 
-    # NB: a sitecustomize hook may pre-import jax at interpreter start, so
-    # spy on *new* imports rather than inspecting sys.modules
+    # spy on *new* imports rather than inspecting sys.modules (an
+    # interpreter start-up hook may have pre-imported jax)
     code = (
         "import sys\n"
         "class Spy:\n"

@@ -182,7 +182,7 @@ def test_unknown_repeating_failure_gives_up_early(no_backoff):
 
 
 def test_known_transient_gets_full_retry_window(no_backoff):
-    # relay-vocabulary errors (UNAVAILABLE etc.) keep the full bounded
+    # known-transient errors (UNAVAILABLE etc.) keep the full bounded
     # window even when attempts fail identically — that is the hiccup
     # the backoff exists to outlast
     calls = {"n": 0}
@@ -190,7 +190,7 @@ def test_known_transient_gets_full_retry_window(no_backoff):
     def flaky():
         calls["n"] += 1
         if calls["n"] < 3:
-            raise RuntimeError("UNAVAILABLE: connection reset by relay")
+            raise RuntimeError("UNAVAILABLE: connection reset by peer")
         return "ok"
 
     assert sh._retry_transient(flaky, "probe") == "ok"

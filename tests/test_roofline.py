@@ -223,14 +223,9 @@ def test_r05_sift1m_curated_line_is_hbm_bound():
     query_major on a v5e) attributes its MFU gap to the db-streaming
     term — hbm_bound, at a small measured fraction of the ceiling.
     This is THE named gap ROADMAP item 1's kernel campaign attacks."""
-    path = os.path.join(REPO, "TPU_BENCH_r05.jsonl")
-    rec = None
-    for line in open(path):
-        cand = json.loads(line)
-        if cand.get("metric", "").startswith("knn_qps_sift1m"):
-            rec = cand
-            break
-    assert rec is not None, "r05 SIFT1M curated line missing"
+    with open(os.path.join(REPO, "tests", "fixtures",
+                           "bench_line_sift1m_v5e.json")) as f:
+        rec = json.load(f)
     block = roofline.block_for_bench_line(rec)
     assert block is not None
     assert block["estimated"] is False
@@ -267,9 +262,11 @@ def test_bound_class_moves_with_the_config():
 
 
 def test_cpu_fallback_peaks_flag_estimated():
-    m = roofline.pallas_cost_model(
-        n=10_000, d=32, k=5, nq=64, device_kind="TPU v99", backend="tpu")
-    assert m["estimated"] is True  # unknown kind -> generic fallback
+    # an accelerator the table does not know is an error, not a default
+    with pytest.raises(ValueError, match="PEAKS_BY_KIND"):
+        roofline.pallas_cost_model(
+            n=10_000, d=32, k=5, nq=64, device_kind="TPU v99",
+            backend="tpu")
     m2 = roofline.pallas_cost_model(
         n=10_000, d=32, k=5, nq=64, device_kind="TPU v5e", backend="cpu")
     assert m2["estimated"] is True  # cpu backend beats a known kind

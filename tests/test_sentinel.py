@@ -126,15 +126,19 @@ def test_short_history_yields_no_baseline():
     assert v["verdict"] == "no_baseline"
 
 
-def test_iter_history_reads_real_repo_artifacts():
-    records = list(sentinel.iter_history_lines(REPO))
-    assert any(r.get("metric", "").startswith("knn_qps_sift1m")
-               for r in records)
+def test_iter_history_reads_round_files(tmp_path):
+    _write_history(tmp_path, {i + 1: [HISTORY[i]] for i in range(4)})
+    records = list(sentinel.iter_history_lines(str(tmp_path)))
+    assert len(records) == 4
+    assert all(r["metric"].startswith("knn_qps_sift1m") for r in records)
     # max_round excludes the round being judged
-    bounded = list(sentinel.iter_history_lines(REPO, max_round=4))
+    bounded = list(sentinel.iter_history_lines(str(tmp_path), max_round=4))
+    assert len(bounded) == 3
     assert all(sentinel._file_round(r["_source"]) < 4 for r in bounded)
-    # the real history builds baselines without raising
+    # the history builds baselines without raising
     sentinel.build_baselines(records)
+    # a checkout with no round files is an empty history, not an error
+    assert list(sentinel.iter_history_lines(str(tmp_path / "none"))) == []
 
 
 def _write_history(tmp_path, rounds):
