@@ -1272,7 +1272,7 @@ def main() -> None:
 
         # the same program+geometry the timed sweep ran (ONE source of
         # truth: ShardedKNN._pallas_setup, fed the same resolved KNOBS)
-        pp, m, w = prog._pallas_setup(
+        pp, m, w, _ = prog._pallas_setup(
             MARGIN, KNOBS["tile_n"], KNOBS["precision"],
             bin_w=KNOBS["bin_w"],
             survivors=KNOBS["survivors"], block_q=KNOBS["block_q"],

@@ -347,8 +347,6 @@ def four_chip_legs(jax) -> None:
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
-    ap.add_argument("--skip-width", action="store_true",
-                    help="skip the GIST and GloVe width legs")
     args = ap.parse_args(argv)
 
     import jax
@@ -380,9 +378,8 @@ def main(argv=None) -> int:
     else:
         mesh = make_mesh(1, 1, devices=jax.devices()[:1])
         sweep_and_serving_legs(jax, mesh)
-        if not args.skip_width:
-            width_leg(jax, mesh, "GIST", GIST)
-            width_leg(jax, mesh, "GloVe", GLOVE)
+        width_leg(jax, mesh, "GIST", GIST)
+        width_leg(jax, mesh, "GloVe", GLOVE)
 
     say(f"all legs passed; XLA compile {_COMPILE['backend_compile_s']:.1f} "
         f"s over {_COMPILE['compiles']} programs, persistent cache "

@@ -11,9 +11,11 @@ and enforces three invariants at the headline shape (SIFT1M):
 1. ``DEFAULT_KNOBS`` fit the target device kind (TPU v5e) — the
    untuned configuration every ``search_certified`` call runs must
    never be the one that overflows;
-2. every autotuner grid candidate (``knob_grid("full")``) fits AT
-   LEAST ONE known device kind — a candidate that fits nowhere is dead
-   grid weight the runtime gate would refuse on every real device;
+2. every autotuner grid candidate (``knob_grid("full")``) of the arm
+   the model is calibrated for (``vmem.calibrated``) fits AT LEAST ONE
+   known device kind — a candidate that fits nowhere is dead grid
+   weight the runtime gate would refuse on every real device (other
+   arms are Mosaic's to judge, never the model's);
 3. the runtime gate is actually wired: ``tuning/autotune.py`` imports
    the vmem model (the lockstep check that keeps invariant 2
    meaningful — pricing before timing, provenance recorded like

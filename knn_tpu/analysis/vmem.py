@@ -7,29 +7,38 @@ over-VMEM knob combination is refused by Mosaic at compile time.  This
 jax-free module is the ONE home of that arithmetic:
 
 - ``ops.pallas_knn`` sizes its ``vmem_limit_bytes`` request from
-  :func:`kernel_bytes` / :func:`limit_bytes` and refuses a geometry
-  that cannot fit (:func:`fits`) with a message, before Mosaic is
-  asked;
+  :func:`kernel_bytes` / :func:`limit_bytes`;
 - ``autotune()`` refuses over-budget candidates BEFORE timing, with
   provenance recorded like roofline pruning;
 - ``knob_grid`` and the ``vmem-budget`` checker
   (knn_tpu.analysis.check_vmem) keep the grid free of candidates that
   fit NO known device.
 
+ONE rule decides who has the last word (:func:`calibrated`): where the
+model was fitted to the compiler it REFUSES what cannot fit — the
+kernel raises with the knobs to change before Mosaic is asked, the
+autotuner and the grid drop the candidate; everywhere else the model
+only describes, the kernel requests the device's whole VMEM, and
+Mosaic decides.
+
 Calibration: the buffers the kernel declares (pipelined operand and
 output blocks, scratch) are exact; what Mosaic keeps live ON TOP of
 them is not declared anywhere, so the score-tile multipliers below were
 fitted to the scoped-VMEM need Mosaic itself reported (libtpu 0.0.34,
 v5e, deviceless AOT — ``scripts/aot_compile_check.py --probe``) for the
-flagship bf16x3 arm at the three benchmark shapes, both block_q and
-both tile sizes, all three kernels.  The model tracked the compiler
-within +7%/-1% at every probed geometry (tiled GIST bq256: 82.5 MiB
-modeled, 81.94 reported; streaming SIFT bq256: 126.75 / 126.55); the
-other precisions were observed to need LESS than bf16x3 at the same
-geometry (fewer live matmul partials), so for them the model is an
-upper bound.  :func:`limit_bytes` adds an eighth for the model's
-error.  The "lane" binning and the "pq" one-hot expansion are not modeled; the
-compiler has the last word on those.
+flagship bf16x3 arm with grouped binning at the three benchmark shapes,
+both block_q and both tile sizes, all three kernels.  There the model
+tracked the compiler within +7%/-1% at every probed geometry (tiled
+GIST bq256: 82.5 MiB modeled, 81.94 reported; streaming SIFT bq256:
+126.75 / 126.55), and :func:`limit_bytes` adds an eighth for that
+error.  It is NOT calibrated anywhere else.  Probed the same way at
+bq256 (SIFT tiled / GIST tiled / SIFT streaming, MiB): "highest" needs
+18.46 / 66.29 / 111.19 where the model says 50.5 / 82.5 / 126.75,
+"bf16x3f" 26.02 / 73.88 / 119.19 against 58.5 / 90.5 / 134.75, "int8"
+<=8 / 55.66 / 100.06 against 39.56 / 71.56 / 115.81 — an upper bound,
+by up to 2.7x; the "lane" binning needs 107.23 / 154.38 / 183.60
+against 50.5 / 82.5 / 126.75 — far UNDER; and the "pq" one-hot
+expansion is not modeled at all.
 
 Geometry constants mirror ``ops.pallas_knn`` (TILE_N/BLOCK_Q/BIN_W/
 DIM_CHUNK/MAX_CARRY_DEPTH), pinned by tests/test_analysis.py.  The
@@ -117,20 +126,22 @@ def budget_for(device_kind: Optional[str],
     return None
 
 
-def fits(estimate_bytes: int, budget_bytes: int) -> bool:
-    """Whether a launch of ``estimate_bytes`` fits a ``budget_bytes``
-    VMEM — the ONE rule the kernel's own refusal, the autotuner gate
-    and the grid checker share.  No reserve is held back: Mosaic on a
-    v5e compiled the streaming kernel at a reported 126.55 MiB under a
-    128 MiB limit."""
-    return int(estimate_bytes) <= int(budget_bytes)
+def calibrated(precision: Optional[str], binning: Optional[str]) -> bool:
+    """Whether the model was fitted to the compiler for this arm (module
+    docstring) — the ONE switch between "the model refuses" and "Mosaic
+    decides", shared by the kernel, the autotuner gate and the grid."""
+    return ((precision or "bf16x3") == "bf16x3"
+            and (binning or "grouped") == "grouped")
 
 
 def limit_bytes(estimate_bytes: int, budget_bytes: int) -> int:
-    """The scoped-VMEM limit a launch that :func:`fits` requests: the
-    estimate plus an eighth for the model's error (module docstring:
-    the compiler's need ran up to 1% over the model), floored at 64 MiB
-    for the arms the model does not cover, capped at the device."""
+    """The scoped-VMEM limit a :func:`calibrated` launch whose estimate
+    is within the budget requests: the estimate plus an eighth for the
+    model's error (the compiler's need ran up to 1% over it), capped at
+    the device (no reserve is held back: Mosaic on a v5e compiled the
+    streaming kernel at a reported 126.55 MiB under a 128 MiB limit),
+    floored at 64 MiB because the fit covers the benchmark geometries
+    (31 MiB and up), not the small ones."""
     want = int(estimate_bytes) + int(estimate_bytes) // 8
     return min(int(budget_bytes), max(64 * MIB, want))
 
@@ -295,16 +306,19 @@ def check_candidate(
 ) -> dict:
     """Price one knob set against one device kind's VMEM:
     ``{"checked", "fits", "estimate_bytes", "budget_bytes", ...}``.
-    ``checked=False`` (cpu / no-VMEM backend) means the verdict is
-    N/A, never a refusal."""
+    ``checked=False`` (cpu / no-VMEM backend, or an arm the model is
+    not :func:`calibrated` for) means the verdict is N/A, never a
+    refusal."""
     budget = budget_for(device_kind, backend)
     est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin)
+    checked = budget is not None and calibrated(
+        knobs.get("precision"), knobs.get("binning"))
     return {
-        "checked": budget is not None,
+        "checked": checked,
         "estimate_bytes": est,
         "budget_bytes": budget,
         "device_kind": device_kind,
-        "fits": None if budget is None else fits(est, budget),
+        "fits": est <= budget if checked else None,
     }
 
 
@@ -314,9 +328,9 @@ def fits_some_kind(knobs: dict, *, n: int, d: int, k: int,
     at this shape.  A candidate that fits nowhere is dead grid weight:
     on every real device the kernel itself would refuse it, so
     ``knob_grid`` drops such combinations at the headline shape and the
-    ``vmem-budget`` checker enforces the same bound."""
-    try:
-        est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin)
-    except ValueError:
-        return True  # unpriceable: never exclude on a model gap
-    return fits(est, max(VMEM_BYTES_BY_KIND.values()))
+    ``vmem-budget`` checker enforces the same bound.  An arm the model
+    is not :func:`calibrated` for is never excluded on it."""
+    if not calibrated(knobs.get("precision"), knobs.get("binning")):
+        return True
+    est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin)
+    return est <= max(VMEM_BYTES_BY_KIND.values())

@@ -832,26 +832,33 @@ def default_backend_is_tpu() -> bool:
     """Whether kernels compile (True) or run in Pallas interpret mode
     (False, the CPU test suite) when the caller leaves ``interpret`` at
     None — decided at trace time from ``jax.default_backend()``.
-    ShardedKNN never passes ``interpret``; it reports this value in
-    ``stats["pallas_knobs"]["interpret"]``."""
+    ShardedKNN resolves it once (``_pallas_setup``), passes the value
+    down to the kernel and reports that same value in
+    ``stats["pallas_knobs"]["interpret"]``; it takes no ``interpret``
+    argument from its callers."""
     return jax.default_backend() == "tpu"
 
 
-def _vmem_limit_bytes(kernel: str, **geometry) -> int:
-    """The scoped-VMEM limit a compiled launch requests, from the
-    modeled footprint of this geometry (knn_tpu.analysis.vmem — the ONE
-    home of the arithmetic, calibrated against what Mosaic reports).
-    A geometry the model says cannot fit the device is refused HERE,
-    naming the knobs to change, instead of by Mosaic's allocator dump.
-    Deviceless AOT compiles trace off-TPU and budget for the target
-    device kind."""
+def _vmem_limit_bytes(kernel: str, precision: str, binning: str,
+                      **geometry) -> int:
+    """The scoped-VMEM limit a compiled launch requests
+    (knn_tpu.analysis.vmem — the ONE home of the arithmetic and of the
+    rule).  Where the model is calibrated against what Mosaic reports
+    (bf16x3, grouped binning) the request is the modeled footprint of
+    this geometry plus its error, and a geometry that cannot fit the
+    device is refused HERE, naming the knobs to change, instead of by
+    Mosaic's allocator dump.  Every other arm asks for the device's
+    whole VMEM and Mosaic decides.  Deviceless AOT compiles trace
+    off-TPU and budget for the target device kind."""
     from knn_tpu.analysis import vmem
 
-    need = sum(vmem.kernel_bytes(kernel=kernel, **geometry).values())
     kind = (jax.devices()[0].device_kind if default_backend_is_tpu()
             else vmem.TARGET_DEVICE_KIND)
     budget = vmem.budget_for(kind)
-    if not vmem.fits(need, budget):
+    if not vmem.calibrated(precision, binning):
+        return budget
+    need = sum(vmem.kernel_bytes(kernel=kernel, **geometry).values())
+    if need > budget:
         raise ValueError(
             f"kernel={kernel!r} at block_q={geometry['block_q']}, "
             f"tile_n={geometry['tile_n']} over {geometry['n_tiles']} db "
@@ -1174,7 +1181,8 @@ def _bin_candidates(
                 ("arbitrary", "arbitrary", "arbitrary") if db_major
                 else ("parallel", "arbitrary", "arbitrary")),
             vmem_limit_bytes=_vmem_limit_bytes(
-                "tiled", block_q=block_q, tile_n=tile_n, n_tiles=n_tiles,
+                "tiled", precision, binning,
+                block_q=block_q, tile_n=tile_n, n_tiles=n_tiles,
                 nd=nd, out_w=out_w, bound_w=bound_w,
                 db_block=sum(tile_n * chunk_w * x.dtype.itemsize
                              for x in db_inputs),
@@ -1246,9 +1254,9 @@ def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_vmem_limit_bytes(
-                "fused" if fused else "streaming", block_q=block_q,
-                tile_n=tile_n, n_tiles=n_tiles, nd=nd, out_w=out_w,
-                bound_w=bound_w,
+                "fused" if fused else "streaming", precision, binning,
+                block_q=block_q, tile_n=tile_n, n_tiles=n_tiles, nd=nd,
+                out_w=out_w, bound_w=bound_w,
                 db_block=n_parts * tile_n * chunk_w * part_dtype.itemsize,
                 aux_rows=aux_rows,
                 q_block=block_q * dim * queries.dtype.itemsize,

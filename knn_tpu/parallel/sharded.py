@@ -1570,7 +1570,7 @@ class ShardedKNN:
                     grid_order=grid_order, kernel=kernel,
                 ),
             )
-            bad, n_corrected = self._certify_pallas(
+            bad, n_corrected, interpret = self._certify_pallas(
                 batches, bs, m, d, i, q_np, db_np, db_norm_max,
                 want_distances=return_distances, overlap=overlap,
                 overlap_depth=overlap_depth, **knobs,
@@ -1610,14 +1610,9 @@ class ShardedKNN:
         }
         if selector == "pallas":
             stats["rank_corrected_queries"] = n_corrected
-            from knn_tpu.ops.pallas_knn import default_backend_is_tpu
-
-            # the one knob nobody passes: compiled on a TPU backend,
-            # Pallas interpret mode elsewhere (the CPU tests) — resolved
-            # by the kernel at trace time, reported here so a caller can
-            # tell which one answered
-            stats["pallas_knobs"] = {
-                **knobs, "interpret": not default_backend_is_tpu()}
+            # interpret: the value _pallas_setup resolved and the kernel
+            # ran with, so a caller can tell which one answered
+            stats["pallas_knobs"] = {**knobs, "interpret": interpret}
             stats["tuning"] = tune_info
             if overlap and self._last_pipeline is not None:
                 stats["pipeline"] = dict(self._last_pipeline)
@@ -1777,15 +1772,22 @@ class ShardedKNN:
                       grid_order: str = "query_major",
                       kernel: str = "tiled",
                       split: bool = False):
-        """(program, m, analysis_window) for the one-pass certified
-        path — the ONE home of the kernel-geometry margin cap and the
-        packed-output window, shared by :meth:`_certify_pallas` and
-        bench.py's phase breakdown so they can never measure different
-        programs or unpack different column layouts."""
+        """(program, m, analysis_window, interpret) for the one-pass
+        certified path — the ONE home of the kernel-geometry margin cap
+        and the packed-output window, shared by :meth:`_certify_pallas`
+        and bench.py's phase breakdown so they can never measure
+        different programs or unpack different column layouts.
+
+        ``interpret`` is the one knob nobody passes: resolved HERE
+        (compiled on a TPU backend, Pallas interpret mode elsewhere —
+        the CPU tests), handed to the program builders, which hand it to
+        the kernel, and returned so ``stats["pallas_knobs"]`` reports
+        the value the kernel was actually given."""
         from knn_tpu.ops.pallas_knn import (
             BIN_W,
             TILE_N,
             _geometry,
+            default_backend_is_tpu,
             effective_tile,
         )
 
@@ -1799,6 +1801,7 @@ class ShardedKNN:
                 f"precision {precision!r} has no certified tolerance "
                 f"model; use one of {CERTIFIED_PRECISIONS}"
             )
+        interpret = not default_backend_is_tpu()
         quant_offset = 0.0
         if precision in ("int8", "int4"):
             # builds (and caches) the quantized placement: the program
@@ -1840,7 +1843,7 @@ class ShardedKNN:
                 survivors=survivors, block_q=block_q,
                 final_select=final_select, binning=binning,
                 grid_order=grid_order, kernel=kernel,
-                quant_offset=quant_offset,
+                quant_offset=quant_offset, interpret=interpret,
             )
             tail = _pallas_tail_program(
                 self.mesh, m, self.k, self.merge, precision,
@@ -1850,7 +1853,7 @@ class ShardedKNN:
                 quant_offset=quant_offset,
                 dcn_merge=self.dcn_merge,
             )
-            return (coarse, tail), m, _analysis_window(self.k, m)
+            return (coarse, tail), m, _analysis_window(self.k, m), interpret
         prog = _pallas_certified_program(
             self.mesh, m, self.k, self.merge, eff_tile, precision,
             n_train=self.n_train, bin_w=bin_w, survivors=survivors,
@@ -1859,8 +1862,9 @@ class ShardedKNN:
             final_recall_target=final_recall_target,
             grid_order=grid_order, kernel=kernel,
             quant_offset=quant_offset, dcn_merge=self.dcn_merge,
+            interpret=interpret,
         )
-        return prog, m, _analysis_window(self.k, m)
+        return prog, m, _analysis_window(self.k, m), interpret
 
     def _certify_pallas(
         self, batches, bs, m, d, i, q_np, db_np, db_norm_max, *,
@@ -1876,7 +1880,8 @@ class ShardedKNN:
         the top-k distance block when ``want_distances``) — nothing wider
         crosses the slow device->host link — then repairs tie runs in
         float64 (ops.refine.rank_correct_runs).  Returns (flagged query
-        indices, rank-corrected query count).
+        indices, rank-corrected query count, the ``interpret`` value the
+        kernel ran with).
 
         ``overlap=True`` runs the TWO-STAGE pipeline instead of the
         one-shot program: the certified program is split at the
@@ -1897,15 +1902,12 @@ class ShardedKNN:
         from knn_tpu.ops.refine import rank_correct_runs
 
         k = self.k
-        prog, m, w = self._pallas_setup(m - self.k, tile_n, precision,
-                                        bin_w=bin_w, survivors=survivors,
-                                        block_q=block_q,
-                                        final_select=final_select,
-                                        include_distances=want_distances,
-                                        binning=binning,
-                                        final_recall_target=final_recall_target,
-                                        grid_order=grid_order,
-                                        kernel=kernel, split=overlap)
+        prog, m, w, interpret = self._pallas_setup(
+            m - self.k, tile_n, precision, bin_w=bin_w,
+            survivors=survivors, block_q=block_q,
+            final_select=final_select, include_distances=want_distances,
+            binning=binning, final_recall_target=final_recall_target,
+            grid_order=grid_order, kernel=kernel, split=overlap)
 
         # stage 1: dispatch every batch (async on device).  The operand
         # tail is precision-shaped (int8: the quantized placement; f32:
@@ -1998,7 +2000,7 @@ class ShardedKNN:
             obs.record_span("certified.pipeline", None, wall,
                             batches=len(batches), depth=depth,
                             overlap_ratio=round(ratio, 4))
-            return np.flatnonzero(bad_mask), n_corrected
+            return np.flatnonzero(bad_mask), n_corrected, interpret
 
         outs = []
         for lo, chunk, pad in batches:
@@ -2011,7 +2013,7 @@ class ShardedKNN:
         for (lo, chunk, pad), (qp, packed) in zip(batches, outs):
             repair(lo, pad, packed,
                    lambda q=qp: prog(q, self._tp, *ops_tail))
-        return np.flatnonzero(bad_mask), n_corrected
+        return np.flatnonzero(bad_mask), n_corrected, interpret
 
     def predict_certified(
         self, queries, *, margin: int = 28, selector: str = "approx",
@@ -2159,6 +2161,7 @@ def _pallas_certified_program(
     kernel: str = "tiled",
     quant_offset: float = 0.0,
     dcn_merge: Optional[str] = None,
+    interpret: Optional[bool] = None,
 ):
     """ONE-pass sharded self-certifying coarse select + device rank +
     device certificate (ops.pallas_knn.local_certified_candidates per
@@ -2219,7 +2222,7 @@ def _pallas_certified_program(
             q, t, m, tile_n=eff_tile, bin_w=eff_bin, survivors=survivors,
             block_q=eff_bq, final_select=final_select, precision=precision,
             binning=binning, final_recall_target=final_recall_target,
-            grid_order=grid_order, kernel=kernel,
+            grid_order=grid_order, kernel=kernel, interpret=interpret,
             db_int8=db_q if precision == "int8" else None,
             db_int4=db_q if precision == "int4" else None,
             db_pq=db_pq, offset=quant_offset,
@@ -2379,6 +2382,7 @@ def _pallas_coarse_program(
     block_q: Optional[int] = None, final_select: str = "exact",
     binning: str = "grouped", grid_order: str = "query_major",
     kernel: str = "tiled", quant_offset: float = 0.0,
+    interpret: Optional[bool] = None,
 ):
     """Stage 1 of the two-stage certified pipeline: the db-streaming
     coarse pass alone (ops.pallas_knn.local_coarse_candidates per
@@ -2402,7 +2406,7 @@ def _pallas_coarse_program(
             q, t, m, tile_n=tile_n or TILE_N, bin_w=bin_w or BIN_W,
             survivors=survivors, block_q=block_q or BLOCK_Q,
             precision=precision, binning=binning,
-            grid_order=grid_order, kernel=kernel,
+            grid_order=grid_order, kernel=kernel, interpret=interpret,
             db_int8=db_q if precision == "int8" else None,
             db_int4=db_q if precision == "int4" else None,
             db_pq=db_pq,

@@ -8,7 +8,9 @@ Every Pallas-kernel knob consumer resolves through ONE call::
                            overrides={"tile_n": explicit_or_None, ...})
 
 Precedence: explicit overrides > the persisted winner for this exact
-``(device_kind, n, d, k, metric, dtype)`` > library defaults.  Winners
+``(device_kind, n, d, k, metric, dtype)`` > library defaults
+(``DEFAULT_KNOBS``; the streaming and fused kernels take
+``FULL_WIDTH_BLOCK_Q`` for a block_q nobody chose).  Winners
 come from :func:`autotune` (``python -m knn_tpu.cli tune`` on a TPU
 session) and live in one JSON file (:mod:`knn_tpu.tuning.cache`;
 ``KNN_TPU_TUNE_CACHE`` overrides the location).  Candidates must pass a
@@ -18,6 +20,7 @@ they may win — a fast wrong kernel can never be selected.
 
 from knn_tpu.tuning.autotune import (
     DEFAULT_KNOBS,
+    FULL_WIDTH_BLOCK_Q,
     PRUNE_ENV,
     autotune,
     autotune_ivf,
@@ -40,6 +43,7 @@ from knn_tpu.tuning.cache import (
 
 __all__ = [
     "DEFAULT_KNOBS",
+    "FULL_WIDTH_BLOCK_Q",
     "PRUNE_ENV",
     "autotune",
     "autotune_ivf",

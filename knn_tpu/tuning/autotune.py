@@ -65,6 +65,15 @@ DEFAULT_KNOBS: Dict[str, object] = {
     "final_recall_target": None,
 }
 
+#: block_q where ``kernel`` is "streaming" or "fused" and neither the
+#: caller nor a cached winner chose one.  Those kernels hold EVERY db
+#: tile's candidate block in VMEM at once, so the tiled kernel's 256
+#: does not carry over: Mosaic (libtpu 0.0.34, v5e, deviceless) puts
+#: streaming bq256 at 126.55 of 128 MiB at SIFT and over the device at
+#: GIST/GloVe, and fused bq256 over it everywhere; at 128 both compile
+#: at all three benchmark shapes (71.65-86.63 MiB).
+FULL_WIDTH_BLOCK_Q = 128
+
 #: env switch for roofline-model candidate pruning in :func:`autotune`
 #: — a fraction in (0, 1]: candidates whose MODELED ceiling sits below
 #: ``threshold x best modeled ceiling`` are skipped before timing
@@ -137,7 +146,9 @@ def resolve_full(
 ) -> Tuple[Dict[str, object], Dict[str, object]]:
     """(knobs, info): the knob set for one problem shape plus its
     provenance.  Precedence: explicit overrides (non-None values) >
-    cached winner > ``DEFAULT_KNOBS``.  ``info`` carries ``source``
+    cached winner > ``DEFAULT_KNOBS`` (with ``FULL_WIDTH_BLOCK_Q`` in
+    place of its block_q for the streaming and fused kernels).
+    ``info`` carries ``source``
     ("cache" | "default"), the cache key/path, and which knobs an
     override pinned — the observability bench/serving surface.
     ``profile`` selects the tuning regime's cache row (latency =
@@ -167,6 +178,9 @@ def resolve_full(
         if v is not None:
             knobs[kk] = v
             overridden.append(kk)
+    if (knobs["kernel"] in ("streaming", "fused") and source == "default"
+            and "block_q" not in overridden):
+        knobs["block_q"] = FULL_WIDTH_BLOCK_Q
     info = {
         "source": source,
         "cache_key": key,
@@ -233,10 +247,14 @@ def knob_grid(level: str = "standard",
 
     VMEM: a combination that fits NO known device kind at the headline
     shape (knn_tpu.analysis.vmem — the model the kernel sizes its own
-    request from, calibrated against Mosaic's reported need) is dropped
-    at enumeration; the ``vmem-budget`` checker in ``cli lint`` holds
-    the grid to that, and the runtime gate in :func:`autotune` refuses
+    request from) is dropped at enumeration where that model is
+    calibrated against Mosaic's reported need (bf16x3, grouped
+    binning); the ``vmem-budget`` checker in ``cli lint`` holds the
+    grid to that, and the runtime gate in :func:`autotune` refuses
     over-budget candidates at the REAL shape/device with provenance.
+    Arms the model is not calibrated for are never dropped or refused
+    on it: Mosaic decides, and a compile refusal is that candidate's
+    recorded error.
 
     ``final_select`` is part of every level (the exact/approx deviation
     at the otherwise-winning geometries): a cached winner's
@@ -255,8 +273,8 @@ def knob_grid(level: str = "standard",
     streaming/fused score blocks alone price block_q x tile_n x 4 B
     over EVERY known device kind's VMEM at block_q >= 512
     (knn_tpu.analysis.vmem at the headline shape; the ``vmem-budget``
-    checker sweeps this profile's full grid too, so a fits-nowhere arm
-    added here fails the lint at authoring time).
+    checker sweeps this profile's full grid too, so a calibrated
+    fits-nowhere arm added here fails the lint at authoring time).
     """
     if level not in ("quick", "standard", "full"):
         raise ValueError(f"grid level {level!r} not in "
@@ -281,10 +299,10 @@ def knob_grid(level: str = "standard",
             return  # ops.pallas_knn refuses: carry soundness unproven
             # for reconstruction-space scores
         if not _vmem.fits_some_kind(knobs, **_vmem.HEADLINE_SHAPE):
-            # fits NO known device kind's VMEM at the headline shape:
-            # the kernel itself would refuse it everywhere
-            # (ops.pallas_knn._vmem_limit_bytes prices with the same
-            # model), so timing it can only record an error
+            # a calibrated arm that fits NO known device kind's VMEM at
+            # the headline shape: the kernel itself would refuse it
+            # everywhere (ops.pallas_knn._vmem_limit_bytes prices with
+            # the same model), so timing it can only record an error
             return
         lbl = _label(knobs)
         if lbl not in seen:
@@ -308,10 +326,12 @@ def knob_grid(level: str = "standard",
     for kern in ("tiled", "streaming", "fused"):
         for order in ("query_major", "db_major"):
             add(kernel=kern, grid_order=order)
-    # the fused arm holds the streaming kernel's full-width candidate
-    # block plus its carry: at the headline shape it fits VMEM at
-    # block_q=128, not at the default 256 (dropped by the cut above)
-    add(kernel="fused", block_q=128)
+    # the full-width kernels at the block_q they resolve to when nobody
+    # picks one (resolve_full): fused fits VMEM at the headline shape
+    # only there (its 256 arm is dropped by the cut above), streaming
+    # fits at both
+    for kern in ("streaming", "fused"):
+        add(kernel=kern, block_q=FULL_WIDTH_BLOCK_Q)
     add(final_select="approx")
     if level == "quick":
         if profile == "throughput":
@@ -627,9 +647,10 @@ def autotune(
     device kind has a VMEM budget — cpu/interpret backends disarm it):
     also before any timing, every candidate's per-launch VMEM footprint
     is priced against the device kind's capacity; over-budget
-    candidates are REFUSED — they would fail at Mosaic compile time on
-    hardware, mid-tune, the worst place to discover it — with each
-    refusal recorded in ``entry["vmem"]["refused"]`` and mirrored as a
+    candidates of the arm the model is calibrated for
+    (``vmem.calibrated``) are REFUSED — they would fail at Mosaic
+    compile time on hardware, mid-tune, the worst place to discover it
+    — with each refusal recorded in ``entry["vmem"]["refused"]`` and mirrored as a
     ``vmem-refused: ...`` entry in ``errors`` (provenance like roofline
     pruning; the ``vmem-budget`` checker in ``cli lint`` statically
     enforces the same model over the grid).

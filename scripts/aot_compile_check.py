@@ -6,12 +6,12 @@ promotion or a new geometry meets the compiler here before it spends
 chip time.  A compile is not a run — the chip has the last word
 (``chip_smoke.py``).
 
-With no arguments it compiles the library-default certified coarse pass
-(``tuning.DEFAULT_KNOBS``, compiled, 4,096 queries) at the three
-benchmark shapes, and the streaming and fused kernels at SIFT: streaming
-compiles at the defaults, fused only at block_q=128 — its block_q=256
-default must be refused by the library's own VMEM model before Mosaic
-is asked.  Flags pick one geometry instead:
+With no arguments it compiles the certified coarse pass (compiled,
+4,096 queries) at the three benchmark shapes with the knobs the library
+resolves when nobody picks any (``tuning.resolve_full``, no winner
+cache), for each of the three kernels; and asks for fused at
+block_q=256 at SIFT, which the library's own VMEM model must refuse
+before Mosaic is asked.  Flags pick one geometry instead:
 
     python scripts/aot_compile_check.py --shape gist --block-q 128
     python scripts/aot_compile_check.py --shape sift --kernel streaming \\
@@ -98,7 +98,7 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str):
           if kk not in ("tile_n", "precision")}
     prog = _pallas_certified_program(
         mesh, k + MARGIN, k, merge, knobs["tile_n"] or TILE_N,
-        knobs["precision"], n_train=n, **kw)
+        knobs["precision"], n_train=n, interpret=False, **kw)
     q = jax.ShapeDtypeStruct(
         (NQ, d), jnp.float32, sharding=NamedSharding(mesh, P(QUERY_AXIS)))
     db = jax.ShapeDtypeStruct(
@@ -147,23 +147,23 @@ def default_cases():
     """The table a bare run prints: (name, shape, knob overrides,
     expectation) — "compiles", or "refused" = the library's own VMEM
     model must refuse it with a ValueError before Mosaic is asked."""
-    return [
-        ("sift 1Mx128 defaults", "sift", {}, "compiles"),
-        ("gist 1Mx960 defaults", "gist", {}, "compiles"),
-        ("glove 1.18Mx300 defaults", "glove", {}, "compiles"),
-        ("sift streaming defaults", "sift", {"kernel": "streaming"},
-         "compiles"),
-        ("sift fused bq128", "sift",
-         {"kernel": "fused", "block_q": 128}, "compiles"),
-        ("sift fused defaults", "sift", {"kernel": "fused"}, "refused"),
-    ]
+    cases = [(f"{shape} {kernel} defaults", shape, {"kernel": kernel},
+              "compiles")
+             for kernel in ("tiled", "streaming", "fused")
+             for shape in ("sift", "gist", "glove")]
+    cases.append(("sift fused block_q=256", "sift",
+                  {"kernel": "fused", "block_q": 256}, "refused"))
+    return cases
 
 
 def run_case(name, shape, overrides, expect, devices, *, mesh=None,
              merge="ring", probe=False) -> bool:
-    from knn_tpu.tuning import DEFAULT_KNOBS
+    from knn_tpu import tuning
 
-    knobs = {**DEFAULT_KNOBS, **overrides}
+    # the knobs search_certified would resolve for these overrides with
+    # no winner cache (os.devnull reads as an empty one)
+    knobs, _ = tuning.resolve_full(
+        *SHAPES[shape], overrides=overrides, cache_path=os.devnull)
 
     def make_case():
         if mesh is not None:
@@ -199,6 +199,7 @@ def main(argv=None) -> int:
     ap.add_argument("--tile-n", type=int)
     ap.add_argument("--precision")
     ap.add_argument("--grid-order")
+    ap.add_argument("--binning")
     ap.add_argument("--final-select")
     ap.add_argument("--mesh", help="QxD, e.g. 1x4: compile the full "
                     "SPMD certified program on that topology mesh")
@@ -218,7 +219,7 @@ def main(argv=None) -> int:
         overrides = {
             "kernel": args.kernel, "block_q": args.block_q,
             "tile_n": args.tile_n, "precision": args.precision,
-            "grid_order": args.grid_order,
+            "grid_order": args.grid_order, "binning": args.binning,
             "final_select": args.final_select,
         }
         overrides = {k: v for k, v in overrides.items() if v is not None}

@@ -672,9 +672,33 @@ def test_vmem_model_tracks_the_compiler_at_the_benchmark_shapes():
             tile_n=tile)["total_bytes"]
         assert 0.99 * need <= est / vmem.MIB <= 1.08 * need, (
             shape, kernel, bq, tile, est / vmem.MIB, need)
-        assert vmem.fits(est, budget) == (need <= 128), (shape, kernel, bq)
-        if vmem.fits(est, budget):
+        assert (est <= budget) == (need <= 128), (shape, kernel, bq)
+        if est <= budget:
             assert vmem.limit_bytes(est, budget) >= need * vmem.MIB
+
+
+def test_vmem_model_refuses_only_where_it_is_calibrated():
+    """ONE rule: the model has the last word on bf16x3 with grouped
+    binning, where it was fitted; on every other arm it only describes
+    (an upper bound at best: Mosaic put "highest" / "bf16x3f" streaming
+    at SIFT bq256 at 111.19 / 119.19 MiB where the model says 126.75 /
+    134.75), the verdict is N/A and the compiler decides."""
+    assert vmem.calibrated("bf16x3", "grouped")
+    assert vmem.calibrated(None, None)  # the defaults
+    for precision, binning in (("bf16x3f", "grouped"), ("highest", "grouped"),
+                               ("int8", "grouped"), ("int4", "grouped"),
+                               ("pq", "grouped"), ("bf16x3", "lane")):
+        assert not vmem.calibrated(precision, binning)
+    shape = dict(vmem.HEADLINE_SHAPE)
+    over = {"kernel": "streaming", "block_q": 256, "precision": "bf16x3f"}
+    verdict = vmem.check_candidate(over, **shape, device_kind="TPU v5e")
+    assert verdict["estimate_bytes"] > verdict["budget_bytes"]
+    assert verdict["checked"] is False and verdict["fits"] is None
+    assert vmem.fits_some_kind(over, **shape)
+    fitted = {"kernel": "fused", "block_q": 256}
+    verdict = vmem.check_candidate(fitted, **shape, device_kind="TPU v5e")
+    assert verdict["checked"] and verdict["fits"] is False
+    assert not vmem.fits_some_kind(fitted, **shape)
 
 
 def test_check_candidate_verdicts():
@@ -716,8 +740,7 @@ def test_vmem_checker_flags_seeded_over_budget_candidate():
     must produce a vmem-budget finding (and would flip cli lint red)."""
     from knn_tpu.tuning.autotune import DEFAULT_KNOBS
 
-    bad = {"kernel": "streaming", "precision": "bf16x3f",
-           "tile_n": 32768}
+    bad = {"kernel": "streaming", "tile_n": 32768}
     findings = grid_findings([bad], DEFAULT_KNOBS)
     assert findings and findings[0].checker == "vmem-budget"
     assert "over EVERY known device kind" in findings[0].message
@@ -738,7 +761,7 @@ def test_vmem_checker_red_when_grid_regresses(tmp_path, monkeypatch):
     def rigged(level="standard"):
         out = real(level)
         out.append({**at.DEFAULT_KNOBS, "kernel": "streaming",
-                    "precision": "bf16x3f", "tile_n": 32768})
+                    "tile_n": 32768})
         return out
 
     monkeypatch.setattr(at, "knob_grid", rigged)

@@ -65,10 +65,11 @@ def test_chip_smoke_refuses_to_run_on_cpu():
 
 @pytest.mark.slow
 def test_default_geometries_compile_for_v5e_deviceless():
-    """The table a bare ``aot_compile_check.py`` prints: defaults at SIFT,
-    GIST and GloVe, streaming at its default, fused at block_q=128 — and
-    fused at its default REFUSED by the library before Mosaic is asked.
-    Any Mosaic refusal (or a library refusal that went away) fails."""
+    """The table a bare ``aot_compile_check.py`` prints: the knobs the
+    library resolves on its own, for each of the three kernels at SIFT,
+    GIST and GloVe — and fused at block_q=256 REFUSED by the library
+    before Mosaic is asked.  Any Mosaic refusal (or a library refusal
+    that went away) fails."""
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts",
@@ -77,5 +78,36 @@ def test_default_geometries_compile_for_v5e_deviceless():
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
     lines = [ln for ln in proc.stdout.splitlines() if ln[:4] in ("OK  ",
                                                                  "FAIL")]
-    assert len(lines) == 6 and all(ln.startswith("OK") for ln in lines)
-    assert "sift fused defaults: refused" in proc.stdout
+    assert len(lines) == 10 and all(ln.startswith("OK") for ln in lines)
+    assert "sift fused block_q=256: refused" in proc.stdout
+
+
+def test_stats_report_the_interpret_value_the_kernel_was_given(monkeypatch):
+    """``stats["pallas_knobs"]["interpret"]`` is the value
+    ``ShardedKNN._pallas_setup`` resolved and handed down to the kernel,
+    not a second reading of the backend beside it."""
+    import numpy as np
+
+    from knn_tpu.parallel import ShardedKNN, make_mesh
+    from knn_tpu.parallel import sharded as sh
+
+    given = []
+    real = sh._pallas_certified_program
+
+    def spy(*args, **kwargs):
+        given.append(kwargs["interpret"])
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(sh, "_pallas_certified_program", spy)
+    rng = np.random.default_rng(3)
+    db = (rng.random((600, 16)) * 64).astype(np.float32)
+    q = (rng.random((8, 16)) * 64).astype(np.float32)
+    prog = ShardedKNN(db, mesh=make_mesh(1, 1), k=5)
+    _, _, stats = prog.search_certified(q, selector="pallas", margin=6)
+    # the CPU suite runs Pallas in interpret mode; chip_smoke.py asserts
+    # False for the same key on the chip
+    assert given == [True]
+    assert stats["pallas_knobs"]["interpret"] is given[0]
+    # and nothing on ShardedKNN's surface lets a caller choose it
+    with pytest.raises(TypeError):
+        prog.search_certified(q, selector="pallas", interpret=True)

@@ -122,6 +122,28 @@ def test_gate_failed_candidate_can_never_win(data, cache_path, monkeypatch):
     assert knobs["kernel"] != "streaming"
 
 
+def test_full_width_kernels_default_to_their_own_block_q(cache_path):
+    """streaming/fused hold every db tile's candidates in VMEM at once:
+    left alone they resolve block_q=128 (the tiled default's 256 does
+    not fit them beyond SIFT); a caller's or a cached winner's block_q
+    still wins."""
+    assert tuning.resolve(700, 16, 5, cache_path=cache_path)["block_q"] == 256
+    for kern in ("streaming", "fused"):
+        knobs, info = tuning.resolve_full(
+            700, 16, 5, cache_path=cache_path, overrides={"kernel": kern})
+        assert info["source"] == "default"
+        assert knobs["block_q"] == tuning.FULL_WIDTH_BLOCK_Q == 128
+        pinned = tuning.resolve(700, 16, 5, cache_path=cache_path,
+                                overrides={"kernel": kern, "block_q": 256})
+        assert pinned["block_q"] == 256
+    key = tuning.cache_key("cpu", 700, 16, 5, "l2", None)
+    tuning.TuneCache(cache_path).put(key, {
+        "knobs": {**tuning.DEFAULT_KNOBS, "kernel": "streaming"}})
+    knobs, info = tuning.resolve_full(700, 16, 5, cache_path=cache_path)
+    assert info["source"] == "cache"
+    assert (knobs["kernel"], knobs["block_q"]) == ("streaming", 256)
+
+
 def test_explicit_knobs_beat_cache(data, cache_path, rng):
     db, q = data
     # seed the cache with a NON-default winner so the override direction
