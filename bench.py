@@ -103,8 +103,9 @@ def _parse_args(argv=None):
     p.add_argument(
         "--trace-dir", default=os.environ.get("KNN_BENCH_TRACE"),
         metavar="DIR",
-        help="capture a jax.profiler trace artifact (utils.timing.trace, "
-        "TensorBoard-loadable) of one extra per-mode run under DIR, "
+        help="capture a jax.profiler trace artifact "
+        "(obs.profiler.device_trace, TensorBoard-loadable) of one extra "
+        "per-mode run under DIR, "
         "alongside the bench JSON; equivalent to KNN_BENCH_TRACE",
     )
     args, _ = p.parse_known_args(argv)

@@ -12,7 +12,8 @@ writes through here instead of keeping private ad-hoc counters:
 - **Spans + events** (:mod:`knn_tpu.obs.trace`): request-scoped trace
   ids minted at submit and propagated through micro-batching; a bounded
   in-memory event ring plus an optional JSONL sink
-  (``KNN_TPU_OBS_LOG``).
+  (``KNN_TPU_OBS_LOG``).  A scoped span is also a ``knn.<span>``
+  profiler annotation, on the clock of a device trace.
 - **Exporters** (:mod:`knn_tpu.obs.export`): Prometheus text served
   from a stdlib-HTTP endpoint (``--metrics-port``), an atomic JSON
   snapshot writer, and ``python -m knn_tpu.cli metrics`` to read

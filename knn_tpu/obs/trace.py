@@ -13,11 +13,22 @@ trace_id=tid, op="search"):`` records wall duration into the
 ``knn_tpu_span_seconds{span=...}`` histogram and emits one structured
 event.  Events land in a bounded in-memory ring (always, when enabled)
 and, when ``KNN_TPU_OBS_LOG`` names a path, as JSON lines on disk —
-machine-scrapable, one object per line, append-only.
+machine-scrapable, one object per line, append-only.  A child span
+carries its parent's name as a ``parent`` attribute, so the log
+rebuilds the tree of one trace id and a stage's self time is its
+length less its children's.
+
+The same scope is also a ``jax.profiler.TraceAnnotation`` named
+``knn.<span>``: in a profiler capture (``obs.profiler.device_trace``)
+the program's stages lie on the host planes of the ``.xplane.pb``, on
+the clock of the device's ``XLA Ops`` line, so a device idle gap can be
+laid against the stage the host was in.  Outside a capture an
+annotation costs one flag test.  The class is looked up only once JAX
+is imported: this package imports without it.
 
 Disabled mode (``KNN_TPU_OBS=0``): :func:`span` yields a shared inert
-span, :func:`new_trace_id` returns None, and :func:`emit_event` drops —
-zero allocation on the hot path.
+span and opens no annotation, :func:`new_trace_id` returns None, and
+:func:`emit_event` drops — zero allocation on the hot path.
 """
 
 from __future__ import annotations
@@ -25,6 +36,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 import uuid
@@ -219,11 +231,28 @@ def record_span(name: str, trace_id: Optional[str], dur_s: float,
     get_event_log().emit(evt)
 
 
+#: prefix of every span's profiler annotation
+ANNOTATION_PREFIX = "knn."
+
+_NO_ANNOTATION = contextlib.nullcontext()
+
+
+def _annotation(name: str):
+    """The profiler-clock twin of a span: a ``TraceAnnotation`` named
+    ``knn.<name>``, or a shared null scope while JAX is not imported
+    (nothing this package does imports it)."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None:
+        return _NO_ANNOTATION
+    return prof.TraceAnnotation(ANNOTATION_PREFIX + name)
+
+
 @contextlib.contextmanager
 def span(name: str, trace_id: Optional[str] = None, **attrs):
-    """Timed scope -> ``knn_tpu_span_seconds{span=name}`` + one event.
-    Yields the :class:`Span` (``.trace_id``, ``.set``); disabled mode
-    yields the shared inert span and records nothing.
+    """Timed scope -> ``knn_tpu_span_seconds{span=name}`` + one event +
+    a ``knn.<name>`` profiler annotation.  Yields the :class:`Span`
+    (``.trace_id``, ``.set``); disabled mode yields the shared inert
+    span and records nothing.
 
     ``trace_id`` is PROPAGATED, never minted here: ids are created where
     a request enters the system (``new_trace_id()`` at the submit
@@ -236,7 +265,8 @@ def span(name: str, trace_id: Optional[str] = None, **attrs):
     sp = Span(name, trace_id, dict(attrs))
     t0 = time.perf_counter()
     try:
-        yield sp
+        with _annotation(name):
+            yield sp
     finally:
         record_span(name, sp.trace_id, time.perf_counter() - t0,
                     **sp.attrs)

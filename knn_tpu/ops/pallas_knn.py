@@ -198,6 +198,19 @@ KERNEL_VERSION = 5
 RANK_SLACK = 2.0 ** -18
 
 
+#: ``jax.named_scope`` names of the certified program's device stages.
+#: A scope is metadata on the ops traced inside it (their HLO
+#: ``op_name``; the profiler shows it beside each ``XLA Ops`` event):
+#: the compiled program and its instruction names do not change, and a
+#: trace reduction can split the device time outside the kernel by
+#: stage without leaning on names XLA numbers (``%fusion.2``).  The
+#: fifth, ``knn.certify_pack``, is parallel.sharded's.
+SCOPE_OPERAND_PREP = "knn.operand_prep"  # per-call row pad + bf16 split
+SCOPE_KERNEL = "knn.kernel"              # the _bin_candidates call
+SCOPE_FINAL_SELECT = "knn.final_select"  # top-(m+2) over the candidates
+SCOPE_RESCORE = "knn.rescore"            # survivor gather + f32 rescore
+
+
 def _round_up(x: int, multiple: int) -> int:
     return -(-x // multiple) * multiple
 
@@ -939,8 +952,9 @@ def _bin_candidates(
     queries = _pad_axis(queries.astype(jnp.float32), block_q, 0)
     queries = _pad_axis(queries, DIM_CHUNK, 1)
     n_rows = db.shape[0]
-    db = _pad_axis(db.astype(jnp.float32), tile_n, 0, fill=PAD_VAL)
-    db = _pad_axis(db, DIM_CHUNK, 1)
+    with jax.named_scope(SCOPE_OPERAND_PREP):
+        db = _pad_axis(db.astype(jnp.float32), tile_n, 0, fill=PAD_VAL)
+        db = _pad_axis(db, DIM_CHUNK, 1)
     qp, dim = queries.shape
     n_tiles = db.shape[0] // tile_n
     nd = dim // DIM_CHUNK
@@ -989,8 +1003,9 @@ def _bin_candidates(
     if precision in ("bf16x3", "bf16x3f"):
         # the high/low split of the db happens ONCE in XLA; the kernel
         # streams bf16 tiles and never re-derives them per query block
-        th = db.astype(jnp.bfloat16)
-        tl = (db - th.astype(jnp.float32)).astype(jnp.bfloat16)
+        with jax.named_scope(SCOPE_OPERAND_PREP):
+            th = db.astype(jnp.bfloat16)
+            tl = (db - th.astype(jnp.float32)).astype(jnp.bfloat16)
         if precision == "bf16x3":
             db_inputs = [th, tl]
             chunk_w = DIM_CHUNK
@@ -1128,9 +1143,10 @@ def _bin_candidates(
     else:
         # full-dim db row norms, f32, broadcast to 8 sublanes so the
         # kernel reads them as a lane-major [8, tile_n] block
-        tnorm = jnp.broadcast_to(
-            jnp.sum(db * db, axis=-1)[None, :], (8, db.shape[0])
-        )
+        with jax.named_scope(SCOPE_OPERAND_PREP):
+            tnorm = jnp.broadcast_to(
+                jnp.sum(db * db, axis=-1)[None, :], (8, db.shape[0])
+            )
     out_shape = [
         jax.ShapeDtypeStruct((qp, n_tiles * out_w), jnp.float32),
         jax.ShapeDtypeStruct((qp, n_tiles * out_w), jnp.int32),
@@ -1425,14 +1441,15 @@ def local_coarse_candidates(
             "early-out's bitwise contract is an exact-boundary argument)")
     eff_tile = effective_tile(t.shape[0], tile_n, bin_w, survivors,
                               binning, m + 2)
-    cd, ci, bounds = _bin_candidates(
-        q, t, block_q=min(block_q, max(8, q.shape[0])), tile_n=eff_tile,
-        bin_w=bin_w, survivors=survivors, precision=precision,
-        interpret=interpret, binning=binning, grid_order=grid_order,
-        kernel=kernel, db_int8=db_int8, offset=offset,
-        keep=m + 2 if kernel == "fused" else None,
-        db_int4=db_int4, db_pq=db_pq,
-    )
+    with jax.named_scope(SCOPE_KERNEL):
+        cd, ci, bounds = _bin_candidates(
+            q, t, block_q=min(block_q, max(8, q.shape[0])),
+            tile_n=eff_tile, bin_w=bin_w, survivors=survivors,
+            precision=precision, interpret=interpret, binning=binning,
+            grid_order=grid_order, kernel=kernel, db_int8=db_int8,
+            offset=offset, keep=m + 2 if kernel == "fused" else None,
+            db_int4=db_int4, db_pq=db_pq,
+        )
     n_q = q.shape[0]
     return cd[:n_q], ci[:n_q], bounds[:n_q]
 
@@ -1468,41 +1485,46 @@ def local_select_rescore(
     if final_select not in ("exact", "approx"):
         raise ValueError(
             f"final_select {final_select!r} not in ('exact', 'approx')")
-    if final_select == "approx":
-        # hardware ApproxTopK over the candidate array, with the exclusion
-        # value restored EXACTLY: every de-selected candidate joins the
-        # bound via a masked min, so a recall miss here can only cause a
-        # fallback, never a wrong certificate.  (~40% cheaper than the
-        # full top_k at SIFT candidate widths.)  ``final_recall_target``
-        # tunes the fallback rate of this one-pass path the same way
-        # ``recall_target`` tunes the counted selector (ADVICE r3).
-        _, sel = lax.approx_max_k(
-            -cd, m + 1, recall_target=final_recall_target or 0.999)
-        lidx = jnp.take_along_axis(ci, sel, axis=-1)
-        masked = cd.at[jnp.arange(n_q)[:, None], sel].set(jnp.inf)
-        excl = jnp.min(masked, axis=-1)
-        lb = jnp.minimum(jnp.min(bounds, axis=-1), excl)
-    else:
-        # exact top-(m+2) by kernel score: the last value is the exclusion
-        # value over every de-selected survivor
-        neg, sel = lax.top_k(-cd, m + 2)
-        vals = -neg
-        lidx = jnp.take_along_axis(ci, sel, axis=-1)[:, : m + 1]
-        lb = jnp.minimum(jnp.min(bounds, axis=-1), vals[:, m + 1])
+    with jax.named_scope(SCOPE_FINAL_SELECT):
+        if final_select == "approx":
+            # hardware ApproxTopK over the candidate array, with the
+            # exclusion value restored EXACTLY: every de-selected
+            # candidate joins the bound via a masked min, so a recall
+            # miss here can only cause a fallback, never a wrong
+            # certificate.  (~40% cheaper than the full top_k at SIFT
+            # candidate widths.)  ``final_recall_target`` tunes the
+            # fallback rate of this one-pass path the same way
+            # ``recall_target`` tunes the counted selector (ADVICE r3).
+            _, sel = lax.approx_max_k(
+                -cd, m + 1, recall_target=final_recall_target or 0.999)
+            lidx = jnp.take_along_axis(ci, sel, axis=-1)
+            masked = cd.at[jnp.arange(n_q)[:, None], sel].set(jnp.inf)
+            excl = jnp.min(masked, axis=-1)
+            lb = jnp.minimum(jnp.min(bounds, axis=-1), excl)
+        else:
+            # exact top-(m+2) by kernel score: the last value is the
+            # exclusion value over every de-selected survivor
+            neg, sel = lax.top_k(-cd, m + 2)
+            vals = -neg
+            lidx = jnp.take_along_axis(ci, sel, axis=-1)[:, : m + 1]
+            lb = jnp.minimum(jnp.min(bounds, axis=-1), vals[:, m + 1])
 
-    # kernel-padding rows carry real-looking indices in [rows, padded);
-    # clip-gathering them would hand a PAD candidate the LAST REAL row's
-    # finite distance — mask them to sentinel BEFORE the rescore
-    valid = lidx < t.shape[0]
-    lidx = jnp.where(valid, lidx, _I32MAX)
+    with jax.named_scope(SCOPE_RESCORE):
+        # kernel-padding rows carry real-looking indices in [rows,
+        # padded); clip-gathering them would hand a PAD candidate the
+        # LAST REAL row's finite distance — mask them to sentinel BEFORE
+        # the rescore
+        valid = lidx < t.shape[0]
+        lidx = jnp.where(valid, lidx, _I32MAX)
 
-    # device rank stage: direct-difference f32 rescore of the selected rows
-    safe = jnp.clip(lidx, 0, t.shape[0] - 1)
-    rows = t[safe]  # [Q, m+1, D] gather
-    diff = q[:, None, :].astype(jnp.float32) - rows.astype(jnp.float32)
-    d32 = jnp.sum(diff * diff, axis=-1)
-    d32 = jnp.where(valid, d32, jnp.inf)
-    d32, lidx = topk_pairs(d32, lidx, m + 1)
+        # device rank stage: direct-difference f32 rescore of the
+        # selected rows
+        safe = jnp.clip(lidx, 0, t.shape[0] - 1)
+        rows = t[safe]  # [Q, m+1, D] gather
+        diff = q[:, None, :].astype(jnp.float32) - rows.astype(jnp.float32)
+        d32 = jnp.sum(diff * diff, axis=-1)
+        d32 = jnp.where(valid, d32, jnp.inf)
+        d32, lidx = topk_pairs(d32, lidx, m + 1)
     return d32, lidx, lb
 
 

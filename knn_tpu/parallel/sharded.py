@@ -448,13 +448,16 @@ def _retry_transient(fn, what: str = "device call",
 
 
 def _fetch_or_redispatch(out, redo, what: str = "device fetch",
-                         attempts: int = _RETRY_ATTEMPTS):
+                         attempts: int = _RETRY_ATTEMPTS,
+                         fetch=np.asarray):
     """``np.asarray(out)``, re-dispatching via ``redo()`` on transient
     failure — the fetch-side half: async device errors surface at the
     host transfer, after the original dispatch call already returned.
-    Same give-up policy as :func:`_retry_transient`."""
+    Same give-up policy as :func:`_retry_transient`.  ``fetch`` is the
+    blocking device->host read itself (:func:`_staged_fetch` splits it
+    into its wait and its copy); every attempt goes through it."""
     try:
-        return np.asarray(out)
+        return fetch(out)
     except (ValueError, TypeError):
         raise
     except Exception as e:
@@ -464,7 +467,7 @@ def _fetch_or_redispatch(out, redo, what: str = "device fetch",
     for attempt in range(attempts - 1):
         _retry_wait(attempt)
         try:
-            return np.asarray(redo())
+            return fetch(redo())
         except (ValueError, TypeError):
             raise
         except Exception as e:
@@ -477,6 +480,28 @@ def _fetch_or_redispatch(out, redo, what: str = "device fetch",
                     f"(identical error repeated)") from e
             err = e
     raise RuntimeError(f"{what} failed after {attempts} attempts") from err
+
+
+#: root span of one ``search_certified`` call; its stages name it as
+#: their ``parent`` (docs/OBSERVABILITY.md "Span lifecycle")
+_CALL_SPAN = "certified.call"
+
+
+def _staged_fetch(trace_id):
+    """A ``fetch`` for :func:`_fetch_or_redispatch` that reads a
+    certified batch's packed output in two stages at the one point where
+    the host blocks anyway: ``certified.device_wait`` until the device
+    has finished the batch, then ``certified.d2h`` for the copy."""
+
+    def fetch(out):
+        with obs.span("certified.device_wait", trace_id, parent=_CALL_SPAN):
+            jax.block_until_ready(out)
+        with obs.span("certified.d2h", trace_id, parent=_CALL_SPAN) as sp:
+            arr = np.asarray(out)
+            sp.set("d2h_bytes", arr.nbytes)
+        return arr
+
+    return fetch
 
 
 def _row_normalize_f64(x: np.ndarray) -> np.ndarray:
@@ -1509,148 +1534,180 @@ class ShardedKNN:
             raise ValueError(f"unknown selector {selector!r}; expected {SELECTORS}")
         from knn_tpu.ops.certified import repair_uncertified
 
-        q_np = np.asarray(queries, dtype=np.float32)
-        if self.metric == "cosine":
-            q_np = _row_normalize_f64(q_np)
-        q_norm2 = None
-        if self.metric == "dot":
-            # augment queries with the zero column matching the placed
-            # rows' augmentation; keep per-query f64 ||q||^2 for the
-            # score back-map at the end
-            q64 = q_np.astype(np.float64)
-            q_norm2 = np.einsum("nd,nd->n", q64, q64)
-            q_np = np.concatenate(
-                [q_np, np.zeros((q_np.shape[0], 1), np.float32)], axis=1)
-        # every certified stage runs in squared-L2 space (for cosine: on
-        # the unit vectors placed at construction / normalized above;
-        # for dot: on the norm-augmented vectors)
-        cert_metric = ("l2" if self.metric in ("cosine", "dot")
-                       else self.metric)
-        n_q = q_np.shape[0]
-        shard_rows = self._shard_rows()
-        # margin is bounded by both the db size and the per-shard rows the
-        # coarse/fallback programs select from (k itself fits: __init__
-        # checks k <= shard_rows)
-        m = min(self.k + margin, self.n_train, shard_rows)
-        db_np = self._host_train()
+        tid = obs.new_trace_id()
+        with obs.span(_CALL_SPAN, tid, selector=selector) as call:
+            with obs.span("certified.prepare", tid, parent=_CALL_SPAN,
+                          first_call=self._db_norm_max_cache is None):
+                q_np = np.asarray(queries, dtype=np.float32)
+                if self.metric == "cosine":
+                    q_np = _row_normalize_f64(q_np)
+                q_norm2 = None
+                if self.metric == "dot":
+                    # augment queries with the zero column matching the
+                    # placed rows' augmentation; keep per-query f64
+                    # ||q||^2 for the score back-map at the end
+                    q64 = q_np.astype(np.float64)
+                    q_norm2 = np.einsum("nd,nd->n", q64, q64)
+                    q_np = np.concatenate(
+                        [q_np, np.zeros((q_np.shape[0], 1), np.float32)],
+                        axis=1)
+                # every certified stage runs in squared-L2 space (for
+                # cosine: on the unit vectors placed at construction /
+                # normalized above; for dot: on the norm-augmented vectors)
+                cert_metric = ("l2" if self.metric in ("cosine", "dot")
+                               else self.metric)
+                n_q = q_np.shape[0]
+                shard_rows = self._shard_rows()
+                # margin is bounded by both the db size and the per-shard
+                # rows the coarse/fallback programs select from (k itself
+                # fits: __init__ checks k <= shard_rows)
+                m = min(self.k + margin, self.n_train, shard_rows)
+                db_np = self._host_train()
 
-        if batch_size is not None and batch_size < 1:
-            raise ValueError(f"batch_size must be >= 1, got {batch_size}")
-        bs = n_q if batch_size is None else batch_size
-        # the db-side term of the certificate tolerance is query-independent
-        # and cached across calls (a float64 pass over all N rows)
-        db_norm_max = self._db_norm_max()
-        batches = []
-        for lo in range(0, n_q, bs):
-            chunk = q_np[lo : lo + bs]
-            pad = bs - chunk.shape[0]
-            if pad:  # one compiled shape for the tail too
-                chunk = np.pad(chunk, ((0, pad), (0, 0)))
-            batches.append((lo, chunk, pad))
+                if batch_size is not None and batch_size < 1:
+                    raise ValueError(
+                        f"batch_size must be >= 1, got {batch_size}")
+                bs = n_q if batch_size is None else batch_size
+                # the db-side term of the certificate tolerance is
+                # query-independent and cached across calls (a float64
+                # pass over all N rows)
+                db_norm_max = self._db_norm_max()
+                batches = []
+                for lo in range(0, n_q, bs):
+                    chunk = q_np[lo : lo + bs]
+                    pad = bs - chunk.shape[0]
+                    if pad:  # one compiled shape for the tail too
+                        chunk = np.pad(chunk, ((0, pad), (0, 0)))
+                    batches.append((lo, chunk, pad))
 
-        d = np.empty((n_q, self.k))
-        i = np.empty((n_q, self.k), dtype=np.int64)
+                d = np.empty((n_q, self.k))
+                i = np.empty((n_q, self.k), dtype=np.int64)
 
-        tune_info = None
-        if selector == "pallas":
-            # ONE knob-resolution home (knn_tpu.tuning): explicit args >
-            # the persisted autotuner winner for this placement's shape >
-            # library defaults
-            from knn_tpu import tuning
+                tune_info = None
+                if selector == "pallas":
+                    # ONE knob-resolution home (knn_tpu.tuning): explicit
+                    # args > the persisted autotuner winner for this
+                    # placement's shape > library defaults
+                    from knn_tpu import tuning
 
-            knobs, tune_info = tuning.resolve_full(
-                self.n_train, self._tp.shape[1], self.k,
-                metric=cert_metric, dtype=self._dtype_key,
-                cache_path=tune_cache,
-                overrides=dict(
-                    tile_n=tile_n, precision=precision, bin_w=bin_w,
-                    survivors=survivors, block_q=block_q,
-                    final_select=final_select, binning=binning,
-                    final_recall_target=final_recall_target,
-                    grid_order=grid_order, kernel=kernel,
-                ),
-            )
-            bad, n_corrected, interpret = self._certify_pallas(
-                batches, bs, m, d, i, q_np, db_np, db_norm_max,
-                want_distances=return_distances, overlap=overlap,
-                overlap_depth=overlap_depth, **knobs,
-            )
-        else:
-            bad = self._certify_counted(
-                batches, bs, m, d, i, q_np, db_np, db_norm_max, selector,
-                recall_target=recall_target, metric=cert_metric,
-            )
+                    knobs, tune_info = tuning.resolve_full(
+                        self.n_train, self._tp.shape[1], self.k,
+                        metric=cert_metric, dtype=self._dtype_key,
+                        cache_path=tune_cache,
+                        overrides=dict(
+                            tile_n=tile_n, precision=precision,
+                            bin_w=bin_w,
+                            survivors=survivors, block_q=block_q,
+                            final_select=final_select, binning=binning,
+                            final_recall_target=final_recall_target,
+                            grid_order=grid_order, kernel=kernel,
+                        ),
+                    )
+                    # kernel geometry, the compiled program (or the
+                    # two-stage pair) and its operand tail: resolved in
+                    # this stage, so the spans below time batches only
+                    prog, _, w, interpret = self._pallas_setup(
+                        m - self.k, include_distances=return_distances,
+                        split=overlap, **knobs)
+                    ops_tail = self._pallas_operands(knobs["precision"])
+            call.set("queries", n_q)
+            call.set("batches", len(batches))
+            if selector == "pallas":
+                bad, n_corrected = self._certify_pallas(
+                    batches, bs, d, i, q_np, db_np, prog=prog, w=w,
+                    ops_tail=ops_tail, precision=knobs["precision"],
+                    trace_id=tid, want_distances=return_distances,
+                    overlap=overlap, overlap_depth=overlap_depth,
+                )
+            else:
+                bad = self._certify_counted(
+                    batches, bs, m, d, i, q_np, db_np, db_norm_max,
+                    selector, recall_target=recall_target,
+                    metric=cert_metric,
+                )
 
-        def _select(qb, widen):
-            # widened exact-selector re-select (bounded by the per-shard
-            # rows the SPMD select can fetch); the returned f32 scores
-            # carry the re-certification exclusion value, so the select
-            # must run in f32 (dtype_key None) even when the main path is
-            # bf16 — certification_tolerance only covers f32 error
-            exact = _knn_program(
-                self.mesh, widen, cert_metric, self.merge, self.n_train,
-                self.train_tile, None, "exact",
-                dcn_merge=self.dcn_merge,
-            )
-            bq, _ = self._place_queries(qb)
-            fs, fi = exact(bq, self._tp)
-            n_b = qb.shape[0]
-            return np.asarray(fs)[:n_b], np.asarray(fi)[:n_b]
+            def _select(qb, widen):
+                # widened exact-selector re-select (bounded by the
+                # per-shard rows the SPMD select can fetch); the returned
+                # f32 scores carry the re-certification exclusion value,
+                # so the select must run in f32 (dtype_key None) even when
+                # the main path is bf16 — certification_tolerance only
+                # covers f32 error
+                exact = _knn_program(
+                    self.mesh, widen, cert_metric, self.merge, self.n_train,
+                    self.train_tile, None, "exact",
+                    dcn_merge=self.dcn_merge,
+                )
+                with obs.span("certified.repair.reselect", tid,
+                              parent="certified.repair", widen=widen,
+                              rows=qb.shape[0]):
+                    bq, _ = self._place_queries(qb)
+                    fs, fi = exact(bq, self._tp)
+                    n_b = qb.shape[0]
+                    return np.asarray(fs)[:n_b], np.asarray(fi)[:n_b]
 
-        repair = repair_uncertified(
-            d, i, self.k, m, bad, q_np, db_np,
-            select_fn=_select,
-            max_widen=min(self.n_train, shard_rows),
-            db_norm_max=db_norm_max,
-        )
-        stats = {
-            "fallback_queries": int(bad.size),
-            "certified": n_q - int(bad.size),
-            **repair,
-        }
-        if selector == "pallas":
-            stats["rank_corrected_queries"] = n_corrected
-            # interpret: the value _pallas_setup resolved and the kernel
-            # ran with, so a caller can tell which one answered
-            stats["pallas_knobs"] = {**knobs, "interpret": interpret}
-            stats["tuning"] = tune_info
-            if overlap and self._last_pipeline is not None:
-                stats["pipeline"] = dict(self._last_pipeline)
-        # mirror the quality signals into the telemetry registry — the
-        # per-call stats dict stays the API, the registry accumulates the
-        # process-lifetime truth a scraper reads (docs/OBSERVABILITY.md)
-        obs.counter(_mn.CERTIFIED_QUERIES, selector=selector).inc(n_q)
-        obs.counter(_mn.CERTIFIED_FALLBACKS, selector=selector).inc(
-            int(bad.size))
-        obs.counter(_mn.CERTIFIED_GENUINE_MISSES, selector=selector).inc(
-            repair.get("fallback_genuine_misses", 0))
-        obs.counter(_mn.CERTIFIED_FALSE_ALARMS, selector=selector).inc(
-            repair.get("fallback_false_alarms", 0))
-        obs.counter(_mn.CERTIFIED_HOST_EXACT, selector=selector).inc(
-            repair.get("host_exact_queries", 0))
-        if selector == "pallas":
-            obs.counter(_mn.CERTIFIED_RANK_CORRECTED).inc(n_corrected)
-        if return_distances and self.metric == "cosine":
-            # unit-vector squared L2 -> cosine distance values, exactly
-            # (matches pairwise_cosine's 1 - similarity convention)
-            d *= 0.5
-        if return_distances and self.metric == "dot":
-            # augmented-space squared L2 -> pairwise_dot values (negative
-            # inner product): invert the affine map in f64 —
-            # ||q'-t'||^2 = ||q||^2 + M - 2 q.t, so
-            # -q.t = (||q'-t'||^2 - ||q||^2 - M) / 2.  Indices and
-            # certification are unaffected (the map is monotone per
-            # query); values then flow through metric_values like any
-            # other metric (dot passes through).
-            d -= q_norm2[:, None] + self._dot_shift
-            d *= 0.5
-        if return_distances and return_sqrt:
-            # true Euclidean values (knn_mpi.cpp:48 / sklearn convention);
-            # indices and certification are unaffected (monotone map)
-            from knn_tpu.ops.distance import metric_values
+            with obs.span("certified.repair", tid, parent=_CALL_SPAN,
+                          fallback_queries=int(bad.size)) as sp:
+                repair = repair_uncertified(
+                    d, i, self.k, m, bad, q_np, db_np,
+                    select_fn=_select,
+                    max_widen=min(self.n_train, shard_rows),
+                    db_norm_max=db_norm_max,
+                )
+                sp.set("host_exact_queries",
+                       repair.get("host_exact_queries", 0))
+            stats = {
+                "fallback_queries": int(bad.size),
+                "certified": n_q - int(bad.size),
+                **repair,
+            }
+            if selector == "pallas":
+                stats["rank_corrected_queries"] = n_corrected
+                # interpret: the value _pallas_setup resolved and the
+                # kernel ran with, so a caller can tell which one answered
+                stats["pallas_knobs"] = {**knobs, "interpret": interpret}
+                stats["tuning"] = tune_info
+                if overlap and self._last_pipeline is not None:
+                    stats["pipeline"] = dict(self._last_pipeline)
+            # mirror the quality signals into the telemetry registry —
+            # the per-call stats dict stays the API, the registry
+            # accumulates the process-lifetime truth a scraper reads
+            # (docs/OBSERVABILITY.md)
+            obs.counter(_mn.CERTIFIED_QUERIES, selector=selector).inc(n_q)
+            obs.counter(_mn.CERTIFIED_FALLBACKS, selector=selector).inc(
+                int(bad.size))
+            obs.counter(_mn.CERTIFIED_GENUINE_MISSES,
+                        selector=selector).inc(
+                repair.get("fallback_genuine_misses", 0))
+            obs.counter(_mn.CERTIFIED_FALSE_ALARMS,
+                        selector=selector).inc(
+                repair.get("fallback_false_alarms", 0))
+            obs.counter(_mn.CERTIFIED_HOST_EXACT, selector=selector).inc(
+                repair.get("host_exact_queries", 0))
+            if selector == "pallas":
+                obs.counter(_mn.CERTIFIED_RANK_CORRECTED).inc(n_corrected)
+            if return_distances and self.metric == "cosine":
+                # unit-vector squared L2 -> cosine distance values, exactly
+                # (matches pairwise_cosine's 1 - similarity convention)
+                d *= 0.5
+            if return_distances and self.metric == "dot":
+                # augmented-space squared L2 -> pairwise_dot values
+                # (negative inner product): invert the affine map in f64 —
+                # ||q'-t'||^2 = ||q||^2 + M - 2 q.t, so
+                # -q.t = (||q'-t'||^2 - ||q||^2 - M) / 2.  Indices and
+                # certification are unaffected (the map is monotone per
+                # query); values then flow through metric_values like any
+                # other metric (dot passes through).
+                d -= q_norm2[:, None] + self._dot_shift
+                d *= 0.5
+            if return_distances and return_sqrt:
+                # true Euclidean values (knn_mpi.cpp:48 / sklearn
+                # convention); indices and certification are unaffected
+                # (monotone map)
+                from knn_tpu.ops.distance import metric_values
 
-            d = metric_values(d, self.metric)
-        return (d if return_distances else None), i, stats
+                d = metric_values(d, self.metric)
+            return (d if return_distances else None), i, stats
 
     def _certify_counted(
         self, batches, bs, m, d, i, q_np, db_np, db_norm_max, selector,
@@ -1867,11 +1924,9 @@ class ShardedKNN:
         return prog, m, _analysis_window(self.k, m), interpret
 
     def _certify_pallas(
-        self, batches, bs, m, d, i, q_np, db_np, db_norm_max, *,
-        tile_n, precision, want_distances=True, bin_w=None, survivors=None,
-        block_q=None, final_select="exact", binning="grouped",
-        final_recall_target=None, grid_order="query_major",
-        kernel="tiled", overlap=False, overlap_depth=2,
+        self, batches, bs, d, i, q_np, db_np, *, prog, w, ops_tail,
+        precision, trace_id=None, want_distances=True, overlap=False,
+        overlap_depth=2,
     ):
         """One-pass certificate, host side.  The device already ranked the
         candidates, flagged uncertified rows, and marked near-tie pairs
@@ -1880,8 +1935,11 @@ class ShardedKNN:
         the top-k distance block when ``want_distances``) — nothing wider
         crosses the slow device->host link — then repairs tie runs in
         float64 (ops.refine.rank_correct_runs).  Returns (flagged query
-        indices, rank-corrected query count, the ``interpret`` value the
-        kernel ran with).
+        indices, rank-corrected query count).  ``prog`` and ``w`` are
+        :meth:`_pallas_setup`'s (the program pair when ``overlap``),
+        ``ops_tail`` :meth:`_pallas_operands`'s; each batch's stages are
+        spans of the caller's ``trace_id`` (``certified.dispatch``,
+        ``.device_wait``, ``.d2h``, ``.unpack``, ``.rank_correct``).
 
         ``overlap=True`` runs the TWO-STAGE pipeline instead of the
         one-shot program: the certified program is split at the
@@ -1902,17 +1960,7 @@ class ShardedKNN:
         from knn_tpu.ops.refine import rank_correct_runs
 
         k = self.k
-        prog, m, w, interpret = self._pallas_setup(
-            m - self.k, tile_n, precision, bin_w=bin_w,
-            survivors=survivors, block_q=block_q,
-            final_select=final_select, include_distances=want_distances,
-            binning=binning, final_recall_target=final_recall_target,
-            grid_order=grid_order, kernel=kernel, split=overlap)
-
-        # stage 1: dispatch every batch (async on device).  The operand
-        # tail is precision-shaped (int8: the quantized placement; f32:
-        # the scalar norm bound) — ONE home, _pallas_operands
-        ops_tail = self._pallas_operands(precision)
+        fetch = _staged_fetch(trace_id)
         if precision in ("int8", "int4", "pq") and obs.enabled():
             # the per-query certified quantization bound ε — the quality
             # signal the device certificate computes and discards
@@ -1942,14 +1990,20 @@ class ShardedKNN:
             paths."""
             nonlocal n_corrected
             take = bs - pad
-            packed_np = _fetch_or_redispatch(packed, redo, "pallas fetch")
-            gi_np, tight_np, bad_np, dk_np = unpack_certified(
-                packed_np[:take], k, w, want_distances
-            )
-            dc, ic, n_c = rank_correct_runs(
-                gi_np, tight_np, k, q_np[lo : lo + take], db_np,
-                d32k=None if dk_np is None else dk_np.astype(np.float64),
-            )
+            packed_np = _fetch_or_redispatch(packed, redo, "pallas fetch",
+                                             fetch=fetch)
+            with obs.span("certified.unpack", trace_id, parent=_CALL_SPAN):
+                gi_np, tight_np, bad_np, dk_np = unpack_certified(
+                    packed_np[:take], k, w, want_distances
+                )
+            with obs.span("certified.rank_correct", trace_id,
+                          parent=_CALL_SPAN) as sp:
+                dc, ic, n_c = rank_correct_runs(
+                    gi_np, tight_np, k, q_np[lo : lo + take], db_np,
+                    d32k=(None if dk_np is None
+                          else dk_np.astype(np.float64)),
+                )
+                sp.set("queries_corrected", n_c)
             n_corrected += n_c
             if dc is not None:
                 d[lo : lo + take] = dc
@@ -1976,15 +2030,18 @@ class ShardedKNN:
                 while len(pending) >= depth:
                     finalize(pending.pop(0))
                 t0 = _time.perf_counter()
-                qp, _ = self._place_queries(chunk)
+                with obs.span("certified.dispatch", trace_id,
+                              parent=_CALL_SPAN, h2d_bytes=chunk.nbytes):
+                    qp, _ = self._place_queries(chunk)
 
-                def launch(q=qp):
-                    # one dispatch unit: a retry re-runs the coarse
-                    # pass together with the tail that consumes it
-                    cand = coarse(q, self._tp, *ops_tail)
-                    return tail(q, self._tp, *cand, *ops_tail)
+                    def launch(q=qp):
+                        # one dispatch unit: a retry re-runs the coarse
+                        # pass together with the tail that consumes it
+                        cand = coarse(q, self._tp, *ops_tail)
+                        return tail(q, self._tp, *cand, *ops_tail)
 
-                packed = _retry_transient(launch, "pallas pipeline dispatch")
+                    packed = _retry_transient(
+                        launch, "pallas pipeline dispatch")
                 pending.append((lo, pad, launch, packed, t0))
             while pending:
                 finalize(pending.pop(0))
@@ -2000,20 +2057,23 @@ class ShardedKNN:
             obs.record_span("certified.pipeline", None, wall,
                             batches=len(batches), depth=depth,
                             overlap_ratio=round(ratio, 4))
-            return np.flatnonzero(bad_mask), n_corrected, interpret
+            return np.flatnonzero(bad_mask), n_corrected
 
+        # stage 1: dispatch every batch (async on device)
         outs = []
         for lo, chunk, pad in batches:
-            qp, _ = self._place_queries(chunk)
-            outs.append((qp, _retry_transient(
-                lambda q=qp: prog(q, self._tp, *ops_tail),
-                "pallas dispatch")))
+            with obs.span("certified.dispatch", trace_id,
+                          parent=_CALL_SPAN, h2d_bytes=chunk.nbytes):
+                qp, _ = self._place_queries(chunk)
+                outs.append((qp, _retry_transient(
+                    lambda q=qp: prog(q, self._tp, *ops_tail),
+                    "pallas dispatch")))
 
         # stage 2: per batch — fetch + repair, in dispatch order
         for (lo, chunk, pad), (qp, packed) in zip(batches, outs):
             repair(lo, pad, packed,
                    lambda q=qp: prog(q, self._tp, *ops_tail))
-        return np.flatnonzero(bad_mask), n_corrected, interpret
+        return np.flatnonzero(bad_mask), n_corrected
 
     def predict_certified(
         self, queries, *, margin: int = 28, selector: str = "approx",
@@ -2278,6 +2338,12 @@ def _split_operand_tail(precision: str, tail):
     return None, None, None, db_norm_max
 
 
+#: device scope of the certify/pack tail; its four siblings (operand
+#: prep, kernel, final select, rescore) are ops.pallas_knn's SCOPE_*
+SCOPE_CERTIFY_PACK = "knn.certify_pack"
+
+
+@jax.named_scope(SCOPE_CERTIFY_PACK)
 def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
                        precision, quant_offset, m, k, w, merge, n_train,
                        hosts, chips, include_distances,

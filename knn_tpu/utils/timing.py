@@ -22,8 +22,8 @@ first-start/last-stop total silently double-count under re-entrant
 numbers.  Distinct threads timing concurrent phases are fine (their
 wall intervals legitimately overlap).
 
-For deep dives, :func:`trace` wraps ``jax.profiler.trace`` to drop a
-TensorBoard-loadable XLA trace.
+For deep dives, ``knn_tpu.obs.profiler.device_trace`` is the one wrapper
+of ``jax.profiler.trace``.
 """
 
 from __future__ import annotations
@@ -103,10 +103,3 @@ class PhaseTimer:
             out = dict(self.phases)
         out["total"] = self.total
         return out
-
-
-@contextlib.contextmanager
-def trace(log_dir: str):
-    """XLA profiler trace (TensorBoard format) around a code block."""
-    with jax.profiler.trace(log_dir):
-        yield

@@ -1,0 +1,309 @@
+#!/usr/bin/env python
+"""Where a certified batch's time goes, read from the program's own
+tracing (docs/OBSERVABILITY.md "Span lifecycle"), in two sub-commands:
+
+    python scripts/certified_stage_report.py spans <events.jsonl> [--skip-calls N]
+    python scripts/certified_stage_report.py idle <trace dir or .xplane.pb>
+
+``spans`` reads a ``KNN_TPU_OBS_LOG`` file: per ``certified.*`` stage
+the mean ms a call and a batch, the self time of ``certified.call`` (its
+length less its children's) and the share of it the children cover.
+``--skip-calls`` leaves out the first N calls (a benchmark's warm-up).
+
+``idle`` reads a profiler capture (``obs.profiler.device_trace``, or a
+benchmark run with ``--trace 1``): every moment the device's ``XLA Ops``
+line is idle inside the traced window is laid against the innermost
+``knn.certified.*`` annotation the host was in (the same spans, on the
+profiler's clock); it also says how much of each ``bench.call``
+annotation ``knn.certified.call`` covers, and splits the device's busy
+time by the program's ``knn.*`` device scopes.  A scope is the ``tf_op``
+stat of an op's event METADATA in the device plane, which
+``jax.profiler.ProfileData`` does not hand out (its ``event.stats`` are
+the per-event ones: offsets and durations), so :func:`op_scopes` walks
+the protobuf's wire format for just that.
+
+Prints one JSON object.  The arithmetic (:func:`stage_table`,
+:func:`attribute`) is plain Python over lists, tested on the CPU.
+"""
+
+from __future__ import annotations
+
+import argparse
+import glob
+import json
+import os
+import re
+import sys
+from collections import defaultdict
+from typing import Dict, Iterable, List, Sequence, Tuple
+
+CALL = "certified.call"
+PREFIX = "knn."
+DEVICE_PLANE = "/device:TPU:0"
+SCOPE_STAT = "tf_op"
+Interval = Tuple[float, float]
+
+
+# --- spans: the JSONL event log ---------------------------------------------
+def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
+    """Per-stage means over the ``certified.*`` span events of whole
+    calls (grouped by trace id, in the order their ``certified.call``
+    closed), leaving out the first ``skip_calls``."""
+    by_tid: Dict[str, List[dict]] = defaultdict(list)
+    order: List[str] = []
+    for e in events:
+        if e.get("type") != "span" or "trace_id" not in e:
+            continue
+        if not e["span"].startswith("certified."):
+            continue
+        by_tid[e["trace_id"]].append(e)
+        if e["span"] == CALL:
+            order.append(e["trace_id"])
+    calls = order[skip_calls:]
+    if not calls:
+        raise SystemExit(f"no whole {CALL} left after skipping "
+                         f"{skip_calls} of {len(order)}")
+    total: Dict[str, float] = defaultdict(float)
+    count: Dict[str, int] = defaultdict(int)
+    attrs: Dict[str, float] = defaultdict(float)
+    batches = children = 0.0
+    for tid in calls:
+        for e in by_tid[tid]:
+            total[e["span"]] += e["dur_s"]
+            count[e["span"]] += 1
+            if e["span"] == CALL:
+                batches += e.get("batches", 1)
+            elif e.get("parent") == CALL:
+                children += e["dur_s"]
+            for key in ("h2d_bytes", "d2h_bytes", "queries_corrected",
+                        "fallback_queries", "host_exact_queries"):
+                if key in e and e["span"] != CALL:
+                    attrs[key] += e[key]
+    n = len(calls)
+    ms = lambda s: round(1e3 * s, 4)  # noqa: E731
+    return {
+        "calls": n, "batches": batches,
+        "stages_ms": {
+            name: {"per_call": ms(total[name] / n),
+                   "per_batch": ms(total[name] / batches),
+                   "spans": count[name]}
+            for name in sorted(total)},
+        "call_self_ms_per_call": ms((total[CALL] - children) / n),
+        "children_share_of_call": round(children / total[CALL], 5),
+        "per_batch": {k: round(v / batches, 3) for k, v in attrs.items()},
+    }
+
+
+def read_jsonl(path: str) -> List[dict]:
+    with open(path) as f:
+        return [json.loads(line) for line in f if line.strip()]
+
+
+# --- idle: the profiler capture ---------------------------------------------
+def union(intervals: Sequence[Interval]) -> List[Interval]:
+    out: List[Interval] = []
+    for lo, hi in sorted(intervals):
+        if out and lo <= out[-1][1]:
+            out[-1] = (out[-1][0], max(hi, out[-1][1]))
+        else:
+            out.append((lo, hi))
+    return out
+
+
+def clipped(intervals: Sequence[Interval], window: Interval
+            ) -> List[Interval]:
+    """The union of ``intervals``, cut to ``window``."""
+    lo_w, hi_w = window
+    return [(max(lo, lo_w), min(hi, hi_w)) for lo, hi in union(intervals)
+            if min(hi, hi_w) > max(lo, lo_w)]
+
+
+def gaps_of(busy: Sequence[Interval], window: Interval) -> List[Interval]:
+    out, at = [], window[0]
+    for lo, hi in clipped(busy, window):
+        if lo > at:
+            out.append((at, lo))
+        at = hi
+    if window[1] > at:
+        out.append((at, window[1]))
+    return out
+
+
+def attribute(gaps: Sequence[Interval],
+              spans: Sequence[Tuple[str, float, float]]) -> Dict[str, float]:
+    """Idle time per span name: every moment of every gap goes to the
+    innermost (shortest) span that covers it, else to ``outside``.
+    ``spans`` are ``(name, start, end)``."""
+    cuts = sorted({t for _, lo, hi in spans for t in (lo, hi)})
+    out: Dict[str, float] = defaultdict(float)
+    for g_lo, g_hi in gaps:
+        edges = [g_lo] + [t for t in cuts if g_lo < t < g_hi] + [g_hi]
+        for lo, hi in zip(edges, edges[1:]):
+            mid = (lo + hi) / 2
+            inner = min((s for s in spans if s[1] <= mid < s[2]),
+                        key=lambda s: s[2] - s[1], default=None)
+            out[inner[0] if inner else "outside"] += hi - lo
+    return dict(out)
+
+
+def _varint(buf: bytes, at: int) -> Tuple[int, int]:
+    value = shift = 0
+    while True:
+        byte = buf[at]
+        at += 1
+        value |= (byte & 0x7F) << shift
+        shift += 7
+        if byte < 0x80:
+            return value, at
+
+
+def _fields(buf: bytes) -> Iterable[Tuple[int, object]]:
+    """(field number, value) of one protobuf message: an int for a
+    varint, the bytes for a length-delimited or fixed-width field."""
+    at = 0
+    while at < len(buf):
+        key, at = _varint(buf, at)
+        wire = key & 7
+        if wire == 0:
+            value, at = _varint(buf, at)
+        elif wire == 2:
+            size, at = _varint(buf, at)
+            value, at = buf[at:at + size], at + size
+        elif wire in (1, 5):
+            size = 8 if wire == 1 else 4
+            value, at = buf[at:at + size], at + size
+        else:
+            raise ValueError(f"wire type {wire} at byte {at}")
+        yield key >> 3, value
+
+
+def op_scopes(xspace: bytes, plane_name: str = DEVICE_PLANE,
+              stat: str = SCOPE_STAT) -> Dict[str, str]:
+    """``{op's event name: its tf_op}`` from the event metadata of one
+    plane of a serialized ``XSpace`` (tsl/profiler/protobuf/xplane.proto:
+    XSpace.planes=1; XPlane.name=2, .event_metadata=4, .stat_metadata=5
+    (maps: key=1, value=2); XEventMetadata.name=2, .stats=5;
+    XStatMetadata.name=2; XStat.metadata_id=1, .str_value=5)."""
+    out: Dict[str, str] = {}
+    for field, plane in _fields(xspace):
+        if field != 1:
+            continue
+        parts = list(_fields(plane))
+        if not any(f == 2 and v == plane_name.encode() for f, v in parts):
+            continue
+        stat_ids = set()
+        for f, v in parts:
+            if f == 5:
+                entry = dict(_fields(v))
+                if dict(_fields(entry[2])).get(2) == stat.encode():
+                    stat_ids.add(entry[1])
+        for f, v in parts:
+            if f != 4:
+                continue
+            meta = list(_fields(dict(_fields(v))[2]))
+            name = next((x for g, x in meta if g == 2), b"").decode()
+            for g, x in meta:
+                if g == 5:
+                    st = dict(_fields(x))
+                    if st.get(1) in stat_ids and 5 in st:
+                        out[name] = st[5].decode()
+    return out
+
+
+def innermost_scope(op_name: str) -> str:
+    """The last ``knn.*`` component of an HLO ``op_name``, else
+    ``unscoped``."""
+    hits = re.findall(r"(?:^|/)(knn\.[a-z_]+)(?=/|:|$)", op_name)
+    return hits[-1] if hits else "unscoped"
+
+
+def idle_report(path: str, window_span: str, outer_span: str) -> dict:
+    from jax.profiler import ProfileData
+
+    if os.path.isdir(path):
+        path = sorted(glob.glob(os.path.join(
+            path, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    with open(path, "rb") as f:
+        raw = f.read()
+    scopes = op_scopes(raw)
+    data = ProfileData.from_serialized_xspace(raw)
+    busy: List[Interval] = []
+    host: List[Tuple[str, float, float]] = []
+    by_scope: Dict[str, List[Interval]] = defaultdict(list)
+    example = None
+    for plane in data.planes:
+        device = plane.name == DEVICE_PLANE
+        for line in plane.lines:
+            for e in line.events:
+                lo, hi = float(e.start_ns), float(e.start_ns + e.duration_ns)
+                if device and line.name == "XLA Ops":
+                    busy.append((lo, hi))
+                    scope = innermost_scope(scopes.get(e.name, ""))
+                    by_scope[scope].append((lo, hi))
+                    if example is None and scope == "knn.final_select":
+                        example = {
+                            "event": e.name[:120],
+                            "event_stats": {k: str(v) for k, v in e.stats},
+                            f"metadata_{SCOPE_STAT}": scopes[e.name]}
+                elif e.name.startswith((PREFIX, "bench.")):
+                    host.append((e.name, lo, hi))
+    windows = [s for s in host if s[0] == window_span]
+    if not windows or not busy:
+        raise SystemExit(
+            f"no {window_span} span or no XLA Ops event on /device:TPU:0 "
+            f"(planes: {[p.name for p in data.planes]})")
+    _, lo_w, hi_w = max(windows, key=lambda s: s[2] - s[1])
+    inside = [s for s in host if lo_w <= s[1] and s[2] <= hi_w]
+    gaps = gaps_of(busy, (lo_w, hi_w))
+    idle = sum(hi - lo for lo, hi in gaps)
+    stages = [s for s in inside if s[0].startswith(PREFIX + "certified.")]
+    by = attribute(gaps, stages)
+    calls = [s for s in inside if s[0] == PREFIX + CALL]
+    outers = [s for s in inside if s[0] == outer_span]
+    sec = lambda ns: round(ns / 1e9, 6)  # noqa: E731
+    return {
+        "xplane": path, "window_s": sec(hi_w - lo_w), "idle_s": sec(idle),
+        "idle_pct": round(100 * idle / (hi_w - lo_w), 3),
+        "calls_in_window": len(calls),
+        "idle_by_stage_s": {k: sec(v) for k, v in sorted(
+            by.items(), key=lambda kv: -kv[1])},
+        "idle_outside_stages_pct_of_idle": round(
+            100 * by.get("outside", 0.0) / idle, 3) if idle else 0.0,
+        "stage_ms_per_call": {
+            name[len(PREFIX):]: round(
+                sum(hi - lo for n, lo, hi in stages if n == name)
+                / 1e6 / max(len(calls), 1), 4)
+            for name in sorted({s[0] for s in stages})},
+        f"{CALL}_share_of_{outer_span}": round(
+            sum(hi - lo for _, lo, hi in calls)
+            / sum(hi - lo for _, lo, hi in outers), 5) if outers else None,
+        "device_ms_per_call_by_scope": {
+            k: round(sum(hi - lo for lo, hi in clipped(v, (lo_w, hi_w)))
+                     / 1e6 / max(len(calls), 1), 4)
+            for k, v in sorted(by_scope.items())},
+        "scoped_event_example": example,
+    }
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    sub = ap.add_subparsers(dest="cmd", required=True)
+    sp = sub.add_parser("spans")
+    sp.add_argument("jsonl")
+    sp.add_argument("--skip-calls", type=int, default=0)
+    ip = sub.add_parser("idle")
+    ip.add_argument("trace")
+    ip.add_argument("--window-span", default="bench.trace_window")
+    ip.add_argument("--outer-span", default="bench.call")
+    args = ap.parse_args(argv)
+    if args.cmd == "spans":
+        out = stage_table(read_jsonl(args.jsonl), args.skip_calls)
+    else:
+        out = idle_report(args.trace, args.window_span, args.outer_span)
+    json.dump(out, sys.stdout, indent=1)
+    print()
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

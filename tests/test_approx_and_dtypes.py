@@ -6,8 +6,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from knn_tpu.obs.profiler import device_trace
 from knn_tpu.ops.topk import knn_search, knn_search_approx
-from knn_tpu.utils.timing import PhaseTimer, trace
+from knn_tpu.utils.timing import PhaseTimer
 
 
 def _recall(pred, true):
@@ -57,6 +58,7 @@ def test_phase_timer_and_trace(tmp_path):
         pass
     s = timer.summary()
     assert set(s) == {"a", "b", "total"} and s["total"] >= s["a"] >= 0
-    with trace(str(tmp_path / "prof")):
+    with device_trace("prof", base_dir=str(tmp_path)) as path:
         jnp.ones(4).block_until_ready()
+    assert path == str(tmp_path / "prof")
     assert any((tmp_path / "prof").iterdir())

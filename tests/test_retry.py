@@ -6,9 +6,12 @@ without killing the job or changing the exact result.  Caller bugs
 (ValueError/TypeError) must NOT be retried.
 """
 
+from collections import Counter
+
 import numpy as np
 import pytest
 
+from knn_tpu import obs
 from knn_tpu.parallel import sharded as sh
 from knn_tpu.parallel.mesh import make_mesh
 from knn_tpu.parallel.sharded import ShardedKNN
@@ -116,6 +119,73 @@ def test_certified_pallas_retries_fetch_failure(data, monkeypatch):
     d, i, stats = prog.search_certified(q, selector="pallas", margin=6)
     np.testing.assert_array_equal(i, ref_i)
     assert state["tripped"]
+
+
+class _FlakyReady:
+    """A device output whose failure surfaces ONCE at the stage named:
+    while the host waits for the device (``block_until_ready``) or at
+    the copy to the host (``__array__``)."""
+
+    def __init__(self, arr, state, stage):
+        self._arr, self._state, self._stage = arr, state, stage
+
+    def _trip(self, stage):
+        if self._stage == stage and not self._state["tripped"]:
+            self._state["tripped"] = True
+            raise RuntimeError(f"injected async failure at {stage}")
+
+    def block_until_ready(self):
+        self._trip("certified.device_wait")
+        self._arr.block_until_ready()
+        return self
+
+    def __array__(self, dtype=None, copy=None):
+        self._trip("certified.d2h")
+        a = np.asarray(self._arr)
+        return a.astype(dtype) if dtype is not None else a
+
+
+@pytest.mark.parametrize("stage", ["certified.device_wait", "certified.d2h"])
+def test_certified_pallas_staged_fetch_retries_either_stage(
+        data, monkeypatch, no_backoff, stage):
+    """The fetch is two spans at one blocking point; a transient failure
+    in either still goes through ``_fetch_or_redispatch``: the batch is
+    dispatched again, the answer is exact, and the failed attempt's
+    span is on record beside the good one."""
+    db, q = data
+    real = sh._pallas_certified_program
+    state = {"tripped": False, "calls": 0}
+
+    def flaky_pallas_program(*a, **kw):
+        prog = real(*a, **kw)
+
+        def wrapper(*pa, **pkw):
+            state["calls"] += 1
+            out = prog(*pa, **pkw)
+            if not state["tripped"]:
+                return _FlakyReady(out, state, stage)
+            return out
+
+        return wrapper
+
+    monkeypatch.setattr(sh, "_pallas_certified_program", flaky_pallas_program)
+    obs.reset(enabled=True)
+    obs.reset_event_log(None)
+    try:
+        prog = ShardedKNN(db, mesh=make_mesh(2, 2), k=5)
+        _, ref_i = _oracle(db, q, 5)
+        d, i, stats = prog.search_certified(q, selector="pallas", margin=6)
+        spans = Counter(e["span"] for e in obs.get_event_log().recent()
+                        if e.get("type") == "span")
+    finally:
+        obs.reset()
+        obs.reset_event_log(from_env=True)
+    np.testing.assert_array_equal(i, ref_i)
+    assert state["tripped"] and state["calls"] == 2  # dispatched again
+    assert spans[stage] == 2
+    assert spans["certified.device_wait"] + spans["certified.d2h"] == (
+        4 if stage == "certified.d2h" else 3)
+    assert spans["certified.dispatch"] == 1 and spans["certified.call"] == 1
 
 
 def test_retry_gives_up_after_bounded_attempts(data, monkeypatch):
