@@ -102,6 +102,7 @@ from knn_tpu.obs.registry import (  # noqa: F401
 )
 from knn_tpu.obs.trace import (  # noqa: F401
     EventLog,
+    current_span,
     emit_event,
     get_event_log,
     new_trace_id,
@@ -114,7 +115,7 @@ __all__ = [
     "NOOP", "Counter", "EventLog", "Gauge", "Histogram",
     "MetricsRegistry", "Objective", "SLOEngine", "audit", "blackbox",
     "compact_snapshot", "drift",
-    "counter", "emit_event", "enabled", "fleet", "gauge",
+    "counter", "current_span", "emit_event", "enabled", "fleet", "gauge",
     "get_event_log",
     "get_registry", "get_slo_engine", "health", "histogram", "ident",
     "install_compile_hook", "load_objectives", "names", "new_trace_id",

@@ -34,6 +34,7 @@ span and opens no annotation, :func:`new_trace_id` returns None, and
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import json
 import os
 import sys
@@ -217,6 +218,17 @@ class _NoopSpan:
 
 NOOP_SPAN = _NoopSpan()
 
+_CURRENT: contextvars.ContextVar = contextvars.ContextVar(
+    "current_span", default=NOOP_SPAN)
+
+
+def current_span():
+    """The innermost :func:`span` scope open on this thread, for code
+    below a stage that alone knows how much work the stage did (it
+    ``.set``s attributes; the scope's owner still emits the event).  The
+    inert span when none is open or tracing is off."""
+    return _CURRENT.get()
+
 
 def record_span(name: str, trace_id: Optional[str], dur_s: float,
                 **attrs) -> None:
@@ -263,10 +275,12 @@ def span(name: str, trace_id: Optional[str] = None, **attrs):
         yield NOOP_SPAN
         return
     sp = Span(name, trace_id, dict(attrs))
+    token = _CURRENT.set(sp)
     t0 = time.perf_counter()
     try:
         with _annotation(name):
             yield sp
     finally:
+        _CURRENT.reset(token)
         record_span(name, sp.trace_id, time.perf_counter() - t0,
                     **sp.attrs)

@@ -11,14 +11,55 @@ to the O(Q·N·D) coarse pass), and re-select the exact lexicographic top-k.
 Exactness condition: every true top-k member appears in the coarse
 top-(k+margin).  The coarse pass's worst-case distance error is a few
 float32 ulps of the squared-norm magnitude, so a margin of a few dozen
-covers it at SIFT1M scale; recall checks in bench.py verify empirically.
+covers it at SIFT1M scale; the benchmark's float64 oracle
+(benchmark/reference.py) checks every run's sampled answers against it.
+
+The float64 temporaries are made in blocks of at most ``_BLOCK_ELEMS``
+elements (:func:`_block_rows`), so that each is served from the heap and
+none grows with the batch; :func:`rank_correct_runs` also spreads its
+blocks over a small pool of host threads, made at the first call that
+has more than one block (numpy releases the GIL in the gather, the cast,
+the subtraction and the einsum).
 """
 
 from __future__ import annotations
 
+import os
+import threading
+from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Tuple
 
 import numpy as np
+
+from knn_tpu import obs
+
+#: float64 elements one re-score temporary may hold (8 MB): at SIFT
+#: bench shape the unchunked refine allocated ~1 GB twice over and ran
+#: ~40% slower (measured chunk sweep, 2026-07)
+_BLOCK_ELEMS = 1 << 20
+
+#: host threads that share one call's blocks in rank_correct_runs
+_POOL_THREADS = min(4, os.cpu_count() or 1)
+
+_pool: Optional[ThreadPoolExecutor] = None
+_pool_lock = threading.Lock()
+
+
+def _block_rows(row_elems: int) -> int:
+    """How many rows of ``row_elems`` elements make one block."""
+    return max(1, _BLOCK_ELEMS // max(1, row_elems))
+
+
+def _shared_pool() -> ThreadPoolExecutor:
+    """The process's one re-score pool, made on first use: a batch must
+    not pay thread start-up, and concurrent callers share the threads."""
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(
+                max_workers=_POOL_THREADS,
+                thread_name_prefix="knn-rank-correct")
+        return _pool
 
 
 def _pairwise_f64(queries: np.ndarray, cand: np.ndarray, metric: str) -> np.ndarray:
@@ -67,6 +108,14 @@ def rank_correct_runs(
     patched in and the array is returned — None skips distance output
     entirely (callers that only need indices save the transfer).
 
+    The float64 distances are computed a block of members at a time
+    (:func:`_block_rows` of the row width), the blocks shared among the
+    pool's threads when there is more than one; each writes its own slice
+    of one array and each member's sum is its own, so the answer does
+    not depend on the blocking or on thread timing.  The innermost span
+    open on the calling thread (the caller's ``certified.rank_correct``)
+    is told ``members`` and ``blocks``.
+
     Returns (d_out or None, i_out [Q, k] int64, corrected_row_count).
     """
     n_q, m1 = gi.shape
@@ -78,15 +127,30 @@ def rank_correct_runs(
     inv[:, 1:] |= tight
     d_out = d32k.copy() if d32k is not None else None
     rows, cols = np.nonzero(inv)
+    block = _block_rows(db_np.shape[1])
+    starts = range(0, rows.size, block)
+    sp = obs.current_span()
+    sp.set("members", int(rows.size))
+    sp.set("blocks", len(starts))
     if rows.size == 0:
         return d_out, gi[:, :k].astype(np.int64), 0
     gw = gi[:, :w].astype(np.int64).copy()
     cand = gw[rows, cols]
     safe = np.clip(cand, 0, db_np.shape[0] - 1)
-    diff = db_np[safe].astype(np.float64) - queries_np[rows].astype(
-        np.float64
-    )
-    d64 = np.einsum("nd,nd->n", diff, diff)
+    d64 = np.empty(rows.size)
+
+    def score(lo: int) -> None:
+        # f32 -> f64 is exact, so the in-place subtraction of the f32
+        # query rows equals widening both sides first
+        diff = db_np[safe[lo : lo + block]].astype(np.float64)
+        diff -= queries_np[rows[lo : lo + block]]
+        np.einsum("nd,nd->n", diff, diff, out=d64[lo : lo + block])
+
+    if len(starts) == 1:
+        score(0)
+    else:
+        # list(): reading every result re-raises a worker's exception
+        list(_shared_pool().map(score, starts))
     d64 = np.where(cand < db_np.shape[0], d64, np.inf)
     # maximal runs of consecutive involved positions; (rows, cols) comes
     # position-sorted from nonzero, so each run is one contiguous block
@@ -125,12 +189,9 @@ def refine_exact(
         raise ValueError(f"need >= {k} candidates, got {m}")
     valid = cand_idx < db.shape[0]
     safe_idx = np.where(valid, cand_idx, 0)
-    # chunk the [Qc, m, D] float64 gather+diff temporaries to a ~8 MB
-    # budget so they live in cache: at SIFT bench shape the unchunked
-    # form allocated ~1 GB twice over and ran ~40% slower (measured
-    # chunk sweep, 2026-07)
+    # the [Qc, m, D] float64 gather+diff temporaries, a block at a time
     d = np.empty((n_q, m))
-    chunk = max(1, (1 << 20) // max(1, m * db.shape[1]))
+    chunk = _block_rows(m * db.shape[1])
     for lo in range(0, n_q, chunk):
         d[lo : lo + chunk] = _pairwise_f64(
             queries[lo : lo + chunk], db[safe_idx[lo : lo + chunk]], metric

@@ -119,9 +119,14 @@ def test_a_certified_call_emits_exactly_its_stage_spans(placed, corpus, kw,
     assert by["certified.dispatch"]["h2d_bytes"] == (
         N_QUERIES // batches * 32 * 4)
     assert by["certified.d2h"]["d2h_bytes"] > 0
-    assert sum(e["queries_corrected"] for e in spans
-               if e["span"] == "certified.rank_correct") == stats[
-                   "rank_corrected_queries"]
+    corrections = [e for e in spans if e["span"] == "certified.rank_correct"]
+    assert sum(e["queries_corrected"] for e in corrections) == stats[
+        "rank_corrected_queries"]
+    for e in corrections:
+        # a tight pair involves two positions; at 32 columns one block
+        # holds 32,768 members, so a batch here is one block or none
+        assert e["members"] >= 2 * e["queries_corrected"]
+        assert e["blocks"] == (1 if e["members"] else 0)
     assert by["certified.repair"]["fallback_queries"] == 0
     assert by["certified.repair"]["host_exact_queries"] == 0
     # the same spans feed the histogram an operator scrapes
@@ -295,6 +300,9 @@ def test_the_stage_report_reads_the_jsonl_log(placed, corpus, report,
     assert 0 < table["children_share_of_call"] <= 1
     assert table["call_self_ms_per_call"] >= 0
     assert table["per_batch"]["h2d_bytes"] == 32 * 32 * 4
+    assert table["per_batch"]["members"] >= 2 * table["per_batch"][
+        "queries_corrected"]
+    assert 0 <= table["per_batch"]["blocks"] <= 1
 
 
 def test_the_stage_report_lays_idle_time_on_the_innermost_span(report):

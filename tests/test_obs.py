@@ -217,6 +217,31 @@ def test_jsonl_event_log_sink(tmp_path):
                         "name": "queue.dispatch", "rows": 4}
 
 
+def test_current_span_is_the_innermost_open_scope_of_its_thread():
+    from knn_tpu.obs.trace import NOOP_SPAN
+
+    assert obs.current_span() is NOOP_SPAN
+    with obs.span("certified.call") as outer:
+        with obs.span("certified.rank_correct", parent="certified.call"):
+            # what code below a stage does: it never sees the scope
+            obs.current_span().set("members", 12)
+            seen = []
+            t = threading.Thread(
+                target=lambda: seen.append(obs.current_span()))
+            t.start()
+            t.join(timeout=30)
+            assert seen == [NOOP_SPAN]  # a scope belongs to its thread
+        assert obs.current_span() is outer
+    assert obs.current_span() is NOOP_SPAN
+    inner, call = obs.get_event_log().recent()[-2:]
+    assert (inner["span"], inner["members"]) == ("certified.rank_correct", 12)
+    assert "members" not in call
+    obs.reset(enabled=False)
+    with obs.span("certified.call"):
+        assert obs.current_span() is NOOP_SPAN
+        obs.current_span().set("members", 1)  # inert, as the scope is
+
+
 # --- PhaseTimer (thin view over the registry) ---------------------------
 def test_phase_timer_feeds_registry_and_rejects_nesting():
     from knn_tpu.utils.timing import PhaseTimer

@@ -5,10 +5,15 @@ whose f32 values are within the slack band of the true distances, the
 output (on rows the device would NOT flag bad) must equal refine_exact on
 the same candidates."""
 
+import os
+import sys
+import threading
+import tracemalloc
+
 import numpy as np
 import pytest
 
-from knn_tpu.ops.refine import rank_correct_runs, refine_exact
+from knn_tpu.ops.refine import _block_rows, rank_correct_runs, refine_exact
 
 SLACK = 2.0 ** -18
 
@@ -95,3 +100,168 @@ def test_rank_correct_runs_clean_rows_untouched(rng):
     ref_d, ref_i = refine_exact(db, queries, gi, 4)
     ok = ~unresolved
     np.testing.assert_array_equal(i[ok], ref_i[ok])
+
+
+# --- the blocked, pooled re-score against the whole-array formula -----------
+def _involved(tight):
+    """[Q, W] bool: the positions either side of a tight pair."""
+    inv = np.zeros((tight.shape[0], tight.shape[1] + 1), dtype=bool)
+    inv[:, :-1] |= tight
+    inv[:, 1:] |= tight
+    return inv
+
+
+def _whole_array(gi, tight, k, queries_np, db_np, d32k=None):
+    """rank_correct_runs as it stood before the re-score was cut into
+    blocks: every member's float64 distance from ONE gather, ONE widening
+    and ONE einsum over all of them.  The reference the blocked form must
+    equal bit for bit."""
+    d_out = d32k.copy() if d32k is not None else None
+    rows, cols = np.nonzero(_involved(tight))
+    if rows.size == 0:
+        return d_out, gi[:, :k].astype(np.int64), 0
+    gw = gi[:, : tight.shape[1] + 1].astype(np.int64).copy()
+    cand = gw[rows, cols]
+    safe = np.clip(cand, 0, db_np.shape[0] - 1)
+    diff = db_np[safe].astype(np.float64) - queries_np[rows].astype(
+        np.float64)
+    d64 = np.einsum("nd,nd->n", diff, diff)
+    d64 = np.where(cand < db_np.shape[0], d64, np.inf)
+    new_run = np.ones(rows.size, dtype=bool)
+    new_run[1:] = (rows[1:] != rows[:-1]) | (cols[1:] != cols[:-1] + 1)
+    run_id = np.cumsum(new_run) - 1
+    order = np.lexsort((cand, d64, run_id))
+    gw[rows, cols] = cand[order]
+    if d_out is not None:
+        in_k = cols < k
+        d_out[rows[in_k], cols[in_k]] = d64[order][in_k]
+    return d_out, gw[:, :k], int(len(np.unique(rows)))
+
+
+W, K_TIE, N_DB = 40, 30, 4000
+
+
+def _tie_batch(members, dim, rng):
+    """A batch whose tie mask involves exactly ``members`` candidate
+    positions (runs of 2...9 positions, a clean position between two runs
+    of one query), over rows with duplicates (exactly tied distances, so
+    the index breaks the tie) and sentinel candidates inside runs."""
+    runs, left = [], members
+    while left:
+        n = min(int(rng.integers(2, 10)), left)
+        if left - n == 1:  # a run is two positions at the least
+            n += 1
+        runs.append(n)
+        left -= n
+    masks, row, col = [], np.zeros(W - 1, dtype=bool), 0
+    for n in runs:
+        if col + n > W:
+            masks.append(row)
+            row, col = np.zeros(W - 1, dtype=bool), 0
+        row[col : col + n - 1] = True
+        col += n + 1
+    masks.append(row)
+    tight = np.array(masks)
+    n_q = tight.shape[0]
+    db = rng.normal(size=(N_DB, dim)).astype(np.float32)
+    db[N_DB // 2 : N_DB // 2 + 200] = db[:200]
+    queries = rng.normal(size=(n_q, dim)).astype(np.float32)
+    gi = rng.integers(0, N_DB // 2 + 200, size=(n_q, W + 5)).astype(np.int32)
+    gi[rng.random(gi.shape) < 0.02] = N_DB + 7  # the kernel's sentinel
+    for r in range(0, n_q, 7):  # a row and its copy, the higher index first
+        c = int(tight[r].argmax())
+        gi[r, c : c + 2] = (r % 200 + N_DB // 2, r % 200)
+    return gi, tight, queries, db, rng.random((n_q, K_TIE))
+
+
+@pytest.mark.parametrize("with_distances", [True, False],
+                         ids=["d32k", "indices_only"])
+@pytest.mark.parametrize("dim", [128, 960])
+@pytest.mark.parametrize("members", [
+    pytest.param(lambda b: 0, id="none"),
+    # one tight pair involves two positions: no mask gives one member
+    pytest.param(lambda b: 2, id="one_pair"),
+    pytest.param(lambda b: b - 1, id="block_less_one"),
+    pytest.param(lambda b: b, id="one_block"),
+    pytest.param(lambda b: b + 1, id="block_plus_one"),
+    pytest.param(lambda b: 3 * b + 17, id="blocks_ragged_last"),
+])
+def test_blocked_rescore_equals_the_whole_array_formula(
+        rng, members, dim, with_distances):
+    n = members(_block_rows(dim))
+    gi, tight, queries, db, d32k = _tie_batch(n, dim, rng)
+    inv = _involved(tight)
+    assert int(inv.sum()) == n  # the fixture gives the count it was asked
+    if n > 1000:
+        assert (gi[:, :W][inv] >= N_DB).any(), "no sentinel inside a run"
+    d32k = d32k if with_distances else None
+    want = _whole_array(gi, tight, K_TIE, queries, db, d32k)
+    got = rank_correct_runs(gi, tight, K_TIE, queries, db, d32k)
+    if with_distances:
+        assert got[0].dtype == np.float64
+        np.testing.assert_array_equal(got[0], want[0])
+    else:
+        assert got[0] is None and want[0] is None
+    assert got[1].dtype == np.int64
+    np.testing.assert_array_equal(got[1], want[1])
+    assert got[2] == want[2]
+
+
+def _peak_bytes(fn):
+    """Most bytes numpy held at once inside ``fn()`` over what it held
+    before (numpy reports its data allocations to tracemalloc, from every
+    thread)."""
+    ours = not tracemalloc.is_tracing()
+    if ours:
+        tracemalloc.start()
+    try:
+        before = tracemalloc.get_traced_memory()[0]
+        tracemalloc.reset_peak()
+        fn()
+        return tracemalloc.get_traced_memory()[1] - before
+    finally:
+        if ours:
+            tracemalloc.stop()
+
+
+@pytest.mark.parametrize("blocks", [6, 24])
+def test_rescore_temporaries_do_not_grow_with_the_batch(rng, blocks):
+    # gist1m's width, a batch and four times the batch under ONE budget.
+    # A block's temporaries are 12.6 MB at the most (its float64
+    # difference and one float32 gather) and four threads hold a block
+    # each, whatever the batch; what grows is a few arrays of 8 bytes a
+    # member (5 MB at 24 blocks).  The whole-array form held 3 x 8 x 960
+    # bytes a member: 150 MB at 6 blocks, 600 MB at 24
+    gi, tight, queries, db, d32k = _tie_batch(
+        blocks * _block_rows(960), 960, rng)
+    peak = _peak_bytes(
+        lambda: rank_correct_runs(gi, tight, K_TIE, queries, db, d32k))
+    assert peak < 64 << 20
+
+
+def test_callers_on_other_threads_share_the_pool(rng):
+    gi, tight, queries, db, d32k = _tie_batch(
+        3 * _block_rows(128) + 17, 128, rng)
+    want = _whole_array(gi, tight, K_TIE, queries, db, d32k)
+    callers = (os.cpu_count() or 1) + 2
+    got = [None] * callers
+
+    def call(slot):
+        got[slot] = rank_correct_runs(gi, tight, K_TIE, queries, db, d32k)
+
+    threads = [threading.Thread(target=call, args=(s,))
+               for s in range(callers)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    for d, i, n_c in got:
+        np.testing.assert_array_equal(d, want[0])
+        np.testing.assert_array_equal(i, want[1])
+        assert n_c == want[2]
