@@ -204,7 +204,8 @@ RANK_SLACK = 2.0 ** -18
 #: the compiled program and its instruction names do not change, and a
 #: trace reduction can split the device time outside the kernel by
 #: stage without leaning on names XLA numbers (``%fusion.2``).  The
-#: fifth, ``knn.certify_pack``, is parallel.sharded's.
+#: fifth, ``knn.certify_pack``, and the cross-shard ``knn.merge`` inside
+#: it are parallel.sharded's.
 SCOPE_OPERAND_PREP = "knn.operand_prep"  # per-call row pad + bf16 split
 SCOPE_KERNEL = "knn.kernel"              # the _bin_candidates call
 SCOPE_FINAL_SELECT = "knn.final_select"  # top-(m+2) over the candidates

@@ -113,18 +113,21 @@ def _merge_shards(d, gi, keep: int, hosts: int, chips: int,
     (hosts == 1) run the single-level merge unchanged.  The
     lexicographic (distance, index) merge is associative + commutative
     (ops.topk), so the two-level tree is bitwise-identical to the flat
-    merge — pinned in tests/test_multihost.py."""
-    if chips > 1:
-        if merge == "ring":
-            d, gi = _ring_merge(d, gi, keep, DB_AXIS, chips)
-        else:
-            d, gi = _allgather_merge(d, gi, keep, DB_AXIS)
-    if hosts > 1:
-        strat = dcn_merge or merge
-        if strat == "ring":
-            d, gi = _ring_merge(d, gi, keep, HOST_AXIS, hosts)
-        else:
-            d, gi = _allgather_merge(d, gi, keep, HOST_AXIS)
+    merge — pinned in tests/test_multihost.py.  Every op of it carries
+    the device scope ``knn.merge``; on one shard that scope holds
+    nothing."""
+    with jax.named_scope(SCOPE_MERGE):
+        if chips > 1:
+            if merge == "ring":
+                d, gi = _ring_merge(d, gi, keep, DB_AXIS, chips)
+            else:
+                d, gi = _allgather_merge(d, gi, keep, DB_AXIS)
+        if hosts > 1:
+            strat = dcn_merge or merge
+            if strat == "ring":
+                d, gi = _ring_merge(d, gi, keep, HOST_AXIS, hosts)
+            else:
+                d, gi = _allgather_merge(d, gi, keep, HOST_AXIS)
     return d, gi
 
 
@@ -811,19 +814,23 @@ class ShardedKNN:
                 f"exceeds the {self._host_tier['budget_bytes']}-byte "
                 f"per-host HBM budget); use search(), or raise the budget")
 
-    def _record_merge_bytes(self, n_rows: int, k: int) -> None:
+    def _record_merge_bytes(self, n_rows: int, k: int) -> int:
         """Mirror the modeled per-level merge volume into the registry
         (crossover.merge_bytes — the same model the roofline's DCN term
-        prices)."""
+        prices); returns the bytes counted, over both levels."""
         hosts, chips = db_topology(self.mesh)
+        total = 0
         if chips > 1:
+            intra = crossover.merge_bytes(n_rows, k, chips, self.merge)
             obs.counter(_mn.MERGE_BYTES, level="intra",
-                        strategy=self.merge).inc(
-                crossover.merge_bytes(n_rows, k, chips, self.merge))
+                        strategy=self.merge).inc(intra)
+            total += intra
         if hosts > 1 and self.dcn_merge is not None:
+            dcn = crossover.merge_bytes(n_rows, k, hosts, self.dcn_merge)
             obs.counter(_mn.MERGE_BYTES, level="dcn",
-                        strategy=self.dcn_merge).inc(
-                crossover.merge_bytes(n_rows, k, hosts, self.dcn_merge))
+                        strategy=self.dcn_merge).inc(dcn)
+            total += dcn
+        return total
 
     def hosttier_stats(self) -> Optional[dict]:
         """The host-RAM tier plan plus the last sweep's measurements
@@ -1606,12 +1613,20 @@ class ShardedKNN:
                     # kernel geometry, the compiled program (or the
                     # two-stage pair) and its operand tail: resolved in
                     # this stage, so the spans below time batches only
-                    prog, _, w, interpret = self._pallas_setup(
+                    prog, m_prog, w, interpret = self._pallas_setup(
                         m - self.k, include_distances=return_distances,
                         split=overlap, **knobs)
                     ops_tail = self._pallas_operands(knobs["precision"])
             call.set("queries", n_q)
             call.set("batches", len(batches))
+            # what the cross-shard merges of this call move: every batch
+            # is one program whose merge keeps m+1 columns a query (the
+            # pallas program, setup's m) or m (the counted coarse
+            # select), over the query rows as placed
+            q_shards = self.mesh.shape[QUERY_AXIS]
+            merge_bytes = self._record_merge_bytes(
+                len(batches) * (-(-bs // q_shards) * q_shards),
+                m_prog + 1 if selector == "pallas" else m)
             if selector == "pallas":
                 bad, n_corrected = self._certify_pallas(
                     batches, bs, d, i, q_np, db_np, prog=prog, w=w,
@@ -1638,10 +1653,13 @@ class ShardedKNN:
                     self.train_tile, None, "exact",
                     dcn_merge=self.dcn_merge,
                 )
+                nonlocal merge_bytes
                 with obs.span("certified.repair.reselect", tid,
                               parent="certified.repair", widen=widen,
                               rows=qb.shape[0]):
                     bq, _ = self._place_queries(qb)
+                    merge_bytes += self._record_merge_bytes(
+                        bq.shape[0], widen)
                     fs, fi = exact(bq, self._tp)
                     n_b = qb.shape[0]
                     return np.asarray(fs)[:n_b], np.asarray(fi)[:n_b]
@@ -1656,10 +1674,18 @@ class ShardedKNN:
                 )
                 sp.set("host_exact_queries",
                        repair.get("host_exact_queries", 0))
+            # which merge answered, where the choice came from, and what
+            # it moved: on the call's event and in the caller's stats
+            merged = {"db_shards": self.db_shards, "merge": self.merge,
+                      "merge_source": self.merge_source,
+                      "merge_bytes": merge_bytes}
+            for key, value in merged.items():
+                call.set(key, value)
             stats = {
                 "fallback_queries": int(bad.size),
                 "certified": n_q - int(bad.size),
                 **repair,
+                **merged,
             }
             if selector == "pallas":
                 stats["rank_corrected_queries"] = n_corrected
@@ -2341,6 +2367,10 @@ def _split_operand_tail(precision: str, tail):
 #: device scope of the certify/pack tail; its four siblings (operand
 #: prep, kernel, final select, rescore) are ops.pallas_knn's SCOPE_*
 SCOPE_CERTIFY_PACK = "knn.certify_pack"
+#: device scope of the cross-shard merge (:func:`_merge_shards` wherever
+#: it runs, and the certified program's ``pmin`` of the exclusion bound):
+#: the collectives and the re-selects between them
+SCOPE_MERGE = "knn.merge"
 
 
 @jax.named_scope(SCOPE_CERTIFY_PACK)
@@ -2376,9 +2406,10 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
         # every db-sharding axis in one reduction
         d32, gi = _merge_shards(d32, gi, m + 1, hosts, chips, merge,
                                 dcn_merge)
-        lb = lax.pmin(
-            lb,
-            axis_name=(HOST_AXIS, DB_AXIS) if hosts > 1 else DB_AXIS)
+        with jax.named_scope(SCOPE_MERGE):
+            lb = lax.pmin(
+                lb,
+                axis_name=(HOST_AXIS, DB_AXIS) if hosts > 1 else DB_AXIS)
 
     # --- device rank analysis over the window [0, w) ---------------
     dw = d32[:, :w]

@@ -16,7 +16,7 @@ before Mosaic is asked.  Flags pick one geometry instead:
     python scripts/aot_compile_check.py --shape gist --block-q 128
     python scripts/aot_compile_check.py --shape sift --kernel streaming \\
         --block-q 256 --probe          # the compiler's scoped-VMEM need
-    python scripts/aot_compile_check.py --shape sift --mesh 1x4 \\
+    python scripts/aot_compile_check.py --shape bigann20m --mesh 1x4 \\
         --merge ring                   # the full SPMD certified program
 
 Prints one line per case; exits non-zero if any case did not do what
@@ -49,6 +49,9 @@ SHAPES = {
     "sift": (1_000_000, 128, 100),
     "gist": (1_000_000, 960, 100),
     "glove": (1_183_514, 300, 50),
+    # the four-chip cell's corpus (benchmark/configs/bigann20m-x4.json);
+    # asked for by name with --mesh 1x4, not part of a bare run
+    "bigann20m": (20_000_000, 128, 100),
 }
 
 

@@ -3,7 +3,7 @@
 tracing (docs/OBSERVABILITY.md "Span lifecycle"), in two sub-commands:
 
     python scripts/certified_stage_report.py spans <events.jsonl> [--skip-calls N]
-    python scripts/certified_stage_report.py idle <trace dir or .xplane.pb>
+    python scripts/certified_stage_report.py idle <trace dir or .xplane.pb> [--device-plane /device:TPU:N]
 
 ``spans`` reads a ``KNN_TPU_OBS_LOG`` file: per ``certified.*`` stage
 the mean ms a call and a batch, the self time of ``certified.call`` (its
@@ -66,6 +66,7 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
     total: Dict[str, float] = defaultdict(float)
     count: Dict[str, int] = defaultdict(int)
     attrs: Dict[str, float] = defaultdict(float)
+    merge: Dict[str, object] = {}
     batches = children = 0.0
     for tid in calls:
         for e in by_tid[tid]:
@@ -73,6 +74,10 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
             count[e["span"]] += 1
             if e["span"] == CALL:
                 batches += e.get("batches", 1)
+                # which cross-shard merge answered and what it moved
+                attrs["merge_bytes"] += e.get("merge_bytes", 0)
+                merge = {k: e[k] for k in ("db_shards", "merge",
+                                           "merge_source") if k in e}
             elif e.get("parent") == CALL:
                 children += e["dur_s"]
             for key in ("h2d_bytes", "d2h_bytes", "queries_corrected",
@@ -92,6 +97,7 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
         "call_self_ms_per_call": ms((total[CALL] - children) / n),
         "children_share_of_call": round(children / total[CALL], 5),
         "per_batch": {k: round(v / batches, 3) for k, v in attrs.items()},
+        "merge": merge,
     }
 
 
@@ -218,7 +224,8 @@ def innermost_scope(op_name: str) -> str:
     return hits[-1] if hits else "unscoped"
 
 
-def idle_report(path: str, window_span: str, outer_span: str) -> dict:
+def idle_report(path: str, window_span: str, outer_span: str,
+                device_plane: str = DEVICE_PLANE) -> dict:
     from jax.profiler import ProfileData
 
     if os.path.isdir(path):
@@ -226,14 +233,14 @@ def idle_report(path: str, window_span: str, outer_span: str) -> dict:
             path, "plugins", "profile", "*", "*.xplane.pb")))[-1]
     with open(path, "rb") as f:
         raw = f.read()
-    scopes = op_scopes(raw)
+    scopes = op_scopes(raw, device_plane)
     data = ProfileData.from_serialized_xspace(raw)
     busy: List[Interval] = []
     host: List[Tuple[str, float, float]] = []
     by_scope: Dict[str, List[Interval]] = defaultdict(list)
     example = None
     for plane in data.planes:
-        device = plane.name == DEVICE_PLANE
+        device = plane.name == device_plane
         for line in plane.lines:
             for e in line.events:
                 lo, hi = float(e.start_ns), float(e.start_ns + e.duration_ns)
@@ -251,7 +258,7 @@ def idle_report(path: str, window_span: str, outer_span: str) -> dict:
     windows = [s for s in host if s[0] == window_span]
     if not windows or not busy:
         raise SystemExit(
-            f"no {window_span} span or no XLA Ops event on /device:TPU:0 "
+            f"no {window_span} span or no XLA Ops event on {device_plane} "
             f"(planes: {[p.name for p in data.planes]})")
     _, lo_w, hi_w = max(windows, key=lambda s: s[2] - s[1])
     inside = [s for s in host if lo_w <= s[1] and s[2] <= hi_w]
@@ -263,7 +270,8 @@ def idle_report(path: str, window_span: str, outer_span: str) -> dict:
     outers = [s for s in inside if s[0] == outer_span]
     sec = lambda ns: round(ns / 1e9, 6)  # noqa: E731
     return {
-        "xplane": path, "window_s": sec(hi_w - lo_w), "idle_s": sec(idle),
+        "xplane": path, "device_plane": device_plane,
+        "window_s": sec(hi_w - lo_w), "idle_s": sec(idle),
         "idle_pct": round(100 * idle / (hi_w - lo_w), 3),
         "calls_in_window": len(calls),
         "idle_by_stage_s": {k: sec(v) for k, v in sorted(
@@ -296,11 +304,14 @@ def main(argv=None) -> int:
     ip.add_argument("trace")
     ip.add_argument("--window-span", default="bench.trace_window")
     ip.add_argument("--outer-span", default="bench.call")
+    ip.add_argument("--device-plane", default=DEVICE_PLANE,
+                    help="one chip's plane (a sharded run has one a chip)")
     args = ap.parse_args(argv)
     if args.cmd == "spans":
         out = stage_table(read_jsonl(args.jsonl), args.skip_calls)
     else:
-        out = idle_report(args.trace, args.window_span, args.outer_span)
+        out = idle_report(args.trace, args.window_span, args.outer_span,
+                          args.device_plane)
     json.dump(out, sys.stdout, indent=1)
     print()
     return 0
