@@ -129,6 +129,7 @@ CAMPAIGN_STAGES = "knn_tpu_campaign_stages_total"
 MERGE_SELECTED = "knn_tpu_merge_strategy_selected_total"
 MERGE_BYTES = "knn_tpu_merge_bytes_total"
 MERGE_STRAGGLER_GAP = "knn_tpu_merge_straggler_gap_seconds"
+SELECT_MERGE_CALLS = "knn_tpu_select_merge_calls_total"
 
 # --- host-RAM shard tier (knn_tpu.parallel.sharded) --------------------
 HOSTTIER_SWEEPS = "knn_tpu_hosttier_sweeps_total"
@@ -406,6 +407,13 @@ CATALOG = {
         "Modeled candidate bytes moved by top-k merges "
         "(parallel.crossover.merge_bytes), by level and strategy — "
         "the DCN volume the roofline's dcn term prices."),
+    SELECT_MERGE_CALLS: (
+        "counter", ("engaged",),
+        "Batches of search_certified(selector='pallas'), by whether "
+        "the final select's bin-merge engaged (ops.pallas_knn."
+        "select_merge_geometry: the candidate width at least twice the "
+        "merged width) or the top-(m+2) ran over the kernel's "
+        "candidates as they are."),
     MERGE_STRAGGLER_GAP: (
         "gauge", (),
         "Max-minus-min per-host local search wall time of the last "

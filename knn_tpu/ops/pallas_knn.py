@@ -58,11 +58,28 @@ constantly (the round-2 failure mode).  Three sharing one bin happens
 case, and the bound makes every miss *detectable*:
 
   a point t outside the candidate set either (a) lost its bin's top-s —
-  then s32(t) >= bound_b >= B, or (b) its bin entry lost the final
-  top-(m+1) — then s32(t) >= v_excl >= B, where B = min(all bin bounds,
+  then s32(t) >= bound_b >= B, or (b) survived its kernel bin but lost
+  its MERGE bin's smallest few — then s32(t) >= that merge bin's bound
+  >= B, or (c) its entry lost the final top-(m+1) — then s32(t) >=
+  v_excl >= B, where B = min(all bin bounds, all merge-bin bounds,
   v_excl).  With |s32 - s_true| <= tol, ``s_k_true < B - tol`` proves no
   true neighbor is missing — certified exact, NO separate count pass
   (ops.certified's count-below matmul becomes redundant on this path).
+
+Case (b) is the final select's own bin-merge (``select_merge_geometry``,
+``_select_merge``).  The kernel's candidate width grows with the corpus
+(``n_tiles * survivors`` lane-rows of 128: 78,336 columns a query at 5M
+rows) and a top-(m+2) over it was longer than the kernel.  From twice
+the merged width up, the lane-rows are cut into ``ceil((m+2)/8)``
+contiguous groups and (group, lane) is a merge bin that keeps its
+``SELECT_MERGE_SURVIVORS`` smallest and gives the next as its bound —
+the kernel's own elementwise per-lane reduction once more, so the
+top-(m+2) scans 8,704 columns at m+2 = 130 whatever the corpus.  A
+query whose true neighbours put five in one merge bin reads a lower B,
+fails its certificate and is repaired like any other miss: a counted
+fallback, never a different answer.  Narrower candidate arrays (1M rows
+and every small shape) run the select over the kernel's candidates as
+they are.
 
 The kernel computes in float32 (precision configurable) because the
 certificate's tolerance must be float32-tight; a bf16 coarse pass would
@@ -128,6 +145,14 @@ BLOCK_Q = 128
 #: compile-checks for v5e at this tile (scripts/aot_compile_check.py).
 #: Lane-mode round-3 measurements used 8192.
 TILE_N = 16384
+#: the final select's second bin-merge (``select_merge_geometry``): the
+#: candidates a merge bin keeps; its next smallest is the bin's bound.
+#: Five of a query's true top-k in one of 2,176 bins happen 3e-6 of the
+#: time at k=100 (three kept would be 3e-4: a fallback in most batches)
+SELECT_MERGE_SURVIVORS = 4
+#: merge bins per slot of the top-(m+2) that follows: groups x 128 lanes
+#: >= 16 x (m + 2), so the merged width follows m and not the corpus
+SELECT_MERGE_BINS_PER_SLOT = 16
 #: dim is processed in chunks so arbitrarily wide features (GIST's 960)
 #: never blow VMEM; qt accumulates in scratch across chunks
 DIM_CHUNK = 128
@@ -186,7 +211,9 @@ PRECISIONS = ("bf16x3", "bf16x3f", "int8", "int4", "pq", "highest",
 #: reference runs self-invalidate.  5 = sub-int8 arms (int4 nibble
 #: unpack prologue + PQ LUT/one-hot scoring, PR 17): the precision knob
 #: domain widened, so winners tuned on the v4 grid self-invalidate.
-KERNEL_VERSION = 5
+#: 6 = the final select's bin-merge (PR 28): the tuner times
+#: local_certified_candidates, whose tail changed at wide shards.
+KERNEL_VERSION = 6
 
 #: relative slack of the device rank stage's direct-difference f32
 #: distances: per-term (q-t)^2 rounding plus the depth-7 tree reduce give
@@ -209,6 +236,7 @@ RANK_SLACK = 2.0 ** -18
 SCOPE_OPERAND_PREP = "knn.operand_prep"  # per-call row pad + bf16 split
 SCOPE_KERNEL = "knn.kernel"              # the _bin_candidates call
 SCOPE_FINAL_SELECT = "knn.final_select"  # top-(m+2) over the candidates
+SCOPE_SELECT_MERGE = "knn.select_merge"  # its bin-merge (inside the above)
 SCOPE_RESCORE = "knn.rescore"            # survivor gather + f32 rescore
 
 
@@ -263,7 +291,12 @@ GRID_ORDERS = ("query_major", "db_major")
 #: tile-min > carry threshold, threshold an upper bound on the final
 #: (m+2)-th smallest EMITTED candidate) guarantees neither the final
 #: select, its tie-breaks, nor the exclusion bound can see the
-#: difference (tests/test_fused_overlap.py).  Grouped binning +
+#: difference (tests/test_fused_overlap.py).  Where the final select's
+#: bin-merge engages (``select_merge_geometry``) the skip stays sound:
+#: a skipped tile's scores exceed the (m+2)-th smallest EMITTED
+#: candidate e; if the merge dropped any of the emitted top-(m+2), that
+#: merge bin's bound is at most e, else the exclusion value is e —
+#: either way lb <= e, below every skipped score.  Grouped binning +
 #: query-major only, like streaming.
 KERNELS = ("tiled", "streaming", "fused")
 
@@ -358,6 +391,30 @@ def effective_tile(
     while eff > bin_w and width(eff) < min_width:
         eff = max(bin_w, -(-(eff // 2) // bin_w) * bin_w)
     return eff
+
+
+def select_merge_geometry(
+    width: int, m: int,
+) -> Optional[Tuple[int, int, int]]:
+    """``(groups, rows, merged_width)`` of the bin-merge that runs
+    between the kernel and the final top-(m+2)
+    (:func:`local_select_rescore`), or ``None`` where the final select
+    runs over the kernel's candidates as they are.  The candidate array
+    is ``width // 128`` lane-rows; they are cut into ``groups``
+    contiguous runs of ``rows`` (the last padded with +inf) and
+    ``(group, lane)`` is a merge bin that keeps its
+    ``SELECT_MERGE_SURVIVORS`` smallest: ``groups * 128`` bins, at least
+    ``SELECT_MERGE_BINS_PER_SLOT`` for each of the m+2 slots, so
+    ``merged_width = groups * SELECT_MERGE_SURVIVORS * 128`` follows m
+    and not the corpus (8,704 at m+2 = 130).  Engages only where that
+    at least halves the width, from what the call can see and by no
+    knob: 78,336 columns (5M rows at tile 16,384) do, 15,872 (1M rows)
+    and every narrower shape run today's program."""
+    groups = -(-(m + 2) * SELECT_MERGE_BINS_PER_SLOT // BIN_W)
+    merged = groups * SELECT_MERGE_SURVIVORS * BIN_W
+    if width % BIN_W or width < 2 * merged:
+        return None
+    return groups, -(-(width // BIN_W) // groups), merged
 
 
 def _unpack_nibble_chunk(tb):
@@ -587,25 +644,34 @@ def _emit_select_grouped(ti, qt, tn, *,
 
 
 def _emit_select_grouped_scores(ti, s, *, tile_n: int, survivors: int,
-                                out_w: int, bound_w: int):
+                                out_w: int, bound_w: int, payload=None):
     """The grouped emitter on a PRECOMPUTED score tile ``s`` — split out
     so the fused kernel (which needs ``s`` for its early-out predicate
     before deciding whether to run the select at all) shares the EXACT
     ops with the tiled/streaming paths: ``_emit_select_grouped`` computes
     ``s = tn[0:1, :] - 2.0 * qt`` and delegates here, the fused tile
     body computes the identical expression and calls this directly —
-    one arithmetic, bitwise-identical emissions."""
+    one arithmetic, bitwise-identical emissions.
+
+    A survivor's index is its db row, rebuilt from the group it came
+    from (``ti * tile_n + group * 128 + lane``; sentinel where +inf) —
+    or, where ``s`` is itself an array of candidates with their indices
+    in ``payload`` (same shape, int32, sentinel where +inf: the final
+    select's bin-merge, ``_select_merge``), the payload riding with
+    it."""
     del bound_w  # grouped bounds are one [BQ, 128] block
     bq = s.shape[0]
     n_groups = tile_n // BIN_W
     lane = lax.broadcasted_iota(jnp.int32, (bq, BIN_W), 1)
     inf = jnp.full((bq, BIN_W), jnp.inf, jnp.float32)
-    zero = jnp.zeros((bq, BIN_W), jnp.int32)
+    none = jnp.full((bq, BIN_W), 0 if payload is None else _I32MAX,
+                    jnp.int32)
     vals = [inf] * (survivors + 1)  # running sorted smallest per lane
-    gidx = [zero] * survivors       # group index of each survivor
+    gidx = [none] * survivors       # group index of each survivor
     for g in range(n_groups):
         cur_v = s[:, g * BIN_W : (g + 1) * BIN_W]
-        cur_g = jnp.full((bq, BIN_W), g, jnp.int32)
+        cur_g = (jnp.full((bq, BIN_W), g, jnp.int32) if payload is None
+                 else payload[:, g * BIN_W : (g + 1) * BIN_W])
         for j in range(survivors):
             less = cur_v < vals[j]
             disp_v = jnp.maximum(cur_v, vals[j])
@@ -617,8 +683,11 @@ def _emit_select_grouped_scores(ti, s, *, tile_n: int, survivors: int,
     ds, is_ = [], []
     for j in range(survivors):
         ds.append(vals[j])
-        is_.append(jnp.where(jnp.isfinite(vals[j]),
-                             ti * tile_n + gidx[j] * BIN_W + lane, _I32MAX))
+        # +inf never displaces (strict <), so an unfilled payload slot
+        # still reads the sentinel it started with
+        is_.append(gidx[j] if payload is not None else jnp.where(
+            jnp.isfinite(vals[j]),
+            ti * tile_n + gidx[j] * BIN_W + lane, _I32MAX))
     cd = jnp.concatenate(ds, axis=-1)   # [BQ, survivors * 128] = out_w
     ci = jnp.concatenate(is_, axis=-1)
     return cd, ci, vals[survivors]      # bound: [BQ, 128] = bound_w
@@ -1455,6 +1524,66 @@ def local_coarse_candidates(
     return cd[:n_q], ci[:n_q], bounds[:n_q]
 
 
+def _select_merge_kernel(cd_ref, ci_ref, v_ref, i_ref, b_ref, *, rows: int):
+    """One (query block, group) cell of :func:`_select_merge`: the
+    grouped emitter on the group's ``rows`` lane-rows of ``cd`` as one
+    precomputed score tile, each candidate's row index riding with it."""
+    v, i, b = _emit_select_grouped_scores(
+        None, cd_ref[...], tile_n=rows * BIN_W,
+        survivors=SELECT_MERGE_SURVIVORS,
+        out_w=SELECT_MERGE_SURVIVORS * BIN_W, bound_w=BIN_W,
+        payload=ci_ref[...])
+    v_ref[...] = v
+    i_ref[...] = i
+    b_ref[...] = b
+
+
+def _select_merge(cd: jax.Array, ci: jax.Array, groups: int, rows: int,
+                  *, interpret: bool):
+    """The second bin-merge (``select_merge_geometry``): per merge bin
+    (group of ``rows`` lane-rows, lane) the ``SELECT_MERGE_SURVIVORS``
+    smallest scores of ``cd`` with their indices from ``ci``, and the
+    next smallest score as the bin's exclusion bound.  Returns
+    ``(scores, indices [Q, groups * survivors * 128], bounds
+    [Q, groups * 128])``.  Strict ``<`` keeps the earlier column on ties
+    and +inf (kernel padding, the last group's own) never enters: its
+    slot keeps the sentinel index.  Every (query block, group) cell
+    writes its own disjoint output blocks, like the kernel's."""
+    n_q, w = cd.shape
+    pad = groups * rows * BIN_W - w
+    if pad:
+        cd = jnp.pad(cd, ((0, 0), (0, pad)), constant_values=jnp.inf)
+        ci = jnp.pad(ci, ((0, 0), (0, pad)), constant_values=_I32MAX)
+    # two [block_q, rows * 128] input blocks of at most 4 MiB each
+    # (double buffered by the pipeline): 128 query rows at the 36
+    # lane-rows of a 5M-row shard, fewer as the shard and so ``rows``
+    # grows
+    block_q = min(n_q, max(8, min(
+        BLOCK_Q, (4 << 20) // (rows * BIN_W * 4) // 8 * 8)))
+    out_w = SELECT_MERGE_SURVIVORS * BIN_W
+    cell = lambda i, g: (i, g)  # noqa: E731
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel"))
+    return pl.pallas_call(
+        functools.partial(_select_merge_kernel, rows=rows),
+        grid=(-(-n_q // block_q), groups),
+        in_specs=[pl.BlockSpec((block_q, rows * BIN_W), cell)] * 2,
+        out_specs=[pl.BlockSpec((block_q, out_w), cell),
+                   pl.BlockSpec((block_q, out_w), cell),
+                   pl.BlockSpec((block_q, BIN_W), cell)],
+        out_shape=[
+            jax.ShapeDtypeStruct((n_q, groups * out_w), jnp.float32),
+            jax.ShapeDtypeStruct((n_q, groups * out_w), jnp.int32),
+            jax.ShapeDtypeStruct((n_q, groups * BIN_W), jnp.float32),
+        ],
+        interpret=interpret,
+        name="select_merge",
+        **kwargs,
+    )(cd, ci)
+
+
 @functools.partial(
     jax.jit, static_argnames=("m", "final_select", "final_recall_target"),
 )
@@ -1470,7 +1599,9 @@ def local_select_rescore(
     final_recall_target: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Stage 2 of :func:`local_certified_candidates`: final top-(m+2)
-    select over the packed candidates, exclusion-value restoration, the
+    select over the packed candidates (over their bin-merge's survivors
+    where ``select_merge_geometry`` engages, every merge bin's bound
+    joining ``lb``), exclusion-value restoration, the
     direct-difference f32 rescore gather, and lexicographic ordering —
     the rescore/certify tail the pipeline-overlap path runs as its own
     device program while the NEXT batch's coarse pass streams the
@@ -1487,6 +1618,19 @@ def local_select_rescore(
         raise ValueError(
             f"final_select {final_select!r} not in ('exact', 'approx')")
     with jax.named_scope(SCOPE_FINAL_SELECT):
+        merge = select_merge_geometry(w, m)
+        if merge is not None:
+            # a wide candidate array: keep the smallest few of each merge
+            # bin, select among those, and let every merge bin's bound
+            # join lb (module docstring, case (b))
+            with jax.named_scope(SCOPE_SELECT_MERGE):
+                cd, ci, merge_bounds = _select_merge(
+                    cd, ci, *merge[:2],
+                    interpret=not default_backend_is_tpu())
+                # kernel bins' and merge bins' bounds: one column now
+                bounds = jnp.minimum(
+                    jnp.min(bounds, axis=-1),
+                    jnp.min(merge_bounds, axis=-1))[:, None]
         if final_select == "approx":
             # hardware ApproxTopK over the candidate array, with the
             # exclusion value restored EXACTLY: every de-selected

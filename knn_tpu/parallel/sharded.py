@@ -771,6 +771,9 @@ class ShardedKNN:
         #: overlap_ratio, wall_s) — surfaced by search_certified stats
         #: and ServingEngine.stats(); None until an overlap run happens
         self._last_pipeline: Optional[dict] = None
+        # (kernel's candidate width, width the final top-k sees) of the
+        # last resolved pallas program, per shard (_pallas_setup)
+        self._select_widths: Tuple[int, int] = (0, 0)
         #: lazily built serving engines, keyed by ladder spec
         #: (buckets, min_bucket, max_bucket) — search_bucketed; the lock
         #: keeps concurrent cold calls from double-building an engine
@@ -1679,6 +1682,17 @@ class ShardedKNN:
             merged = {"db_shards": self.db_shards, "merge": self.merge,
                       "merge_source": self.merge_source,
                       "merge_bytes": merge_bytes}
+            if selector == "pallas":
+                # the width the kernel handed each shard's final select
+                # and the width its top-k scanned: equal unless the
+                # bin-merge engaged (ops.pallas_knn.select_merge_geometry)
+                width, merged_width = self._select_widths
+                merged["select_width"] = width
+                merged["select_merged_width"] = merged_width
+                obs.counter(
+                    _mn.SELECT_MERGE_CALLS,
+                    engaged="true" if merged_width < width else "false",
+                ).inc(len(batches))
             for key, value in merged.items():
                 call.set(key, value)
             stats = {
@@ -1872,6 +1886,7 @@ class ShardedKNN:
             _geometry,
             default_backend_is_tpu,
             effective_tile,
+            select_merge_geometry,
         )
 
         from knn_tpu.utils.config import CERTIFIED_PRECISIONS
@@ -1904,14 +1919,20 @@ class ShardedKNN:
         # m is bounded by the db, the per-shard rows, and the kernel's
         # per-shard candidate width minus the two slots the exclusion
         # value needs (ops.pallas_knn.local_certified_candidates)
-        m = min(self.k + margin, self.n_train, shard_rows,
-                -(-shard_rows // eff_tile) * out_w - 2)
+        select_width = -(-shard_rows // eff_tile) * out_w
+        m = min(self.k + margin, self.n_train, shard_rows, select_width - 2)
         if m <= self.k:
             raise ValueError(
                 f"pallas selector: margin headroom m={m} <= k={self.k} on "
                 f"{shard_rows}-row shards; lower tile_n or use "
                 f"selector='approx'"
             )
+        # what the final top-(m+2) of each shard scans: the kernel's
+        # width, or the bin-merge's where it engages (the same helper
+        # local_select_rescore asks)
+        merge = select_merge_geometry(select_width, m)
+        self._select_widths = (
+            select_width, select_width if merge is None else merge[2])
         # the program gets setup's RESOLVED tile, not the raw request:
         # m was capped so that width(eff_tile) >= m+2, which makes the
         # kernel's own effective_tile(min_width=m+2) a fixpoint — the

@@ -9,9 +9,11 @@ chip time.  A compile is not a run — the chip has the last word
 With no arguments it compiles the certified coarse pass (compiled,
 4,096 queries) at the three benchmark shapes with the knobs the library
 resolves when nobody picks any (``tuning.resolve_full``, no winner
-cache), for each of the three kernels; and asks for fused at
+cache), for each of the three kernels; asks for fused at
 block_q=256 at SIFT, which the library's own VMEM model must refuse
-before Mosaic is asked.  Flags pick one geometry instead:
+before Mosaic is asked; and compiles the final select's bin-merge
+kernel at a 5M-row chip's candidate width (``bigann20m``).  Flags pick
+one geometry instead:
 
     python scripts/aot_compile_check.py --shape gist --block-q 128
     python scripts/aot_compile_check.py --shape sift --kernel streaming \\
@@ -109,6 +111,31 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str):
     norm = jax.ShapeDtypeStruct(
         (), jnp.float32, sharding=NamedSharding(mesh, P()))
     return prog, (q, db, norm)
+
+
+def _merge_case(shape: str, db_shards: int, devices):
+    """(fn, avals) of the final select's bin-merge kernel alone, compiled,
+    at the candidate width of one of ``db_shards`` chips, or None where
+    that width does not engage it.  The certified program holds it too,
+    but there it asks the backend and not its caller whether to compile,
+    and the backend here is the CPU: the other cases carry it
+    interpreted."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from knn_tpu.ops import pallas_knn as pk
+
+    n, _, k = SHAPES[shape]
+    width = -(-(-(-n // db_shards)) // pk.TILE_N) * 2 * pk.BIN_W
+    geo = pk.select_merge_geometry(width, k + MARGIN)
+    if geo is None:
+        return None
+    sh = SingleDeviceSharding(devices[0])
+    fn = jax.jit(lambda cd, ci: pk._select_merge(
+        cd, ci, *geo[:2], interpret=False))
+    return fn, (jax.ShapeDtypeStruct((NQ, width), jnp.float32, sharding=sh),
+                jax.ShapeDtypeStruct((NQ, width), jnp.int32, sharding=sh))
 
 
 def _compile(fn, avals):
@@ -236,6 +263,22 @@ def main(argv=None) -> int:
                  for name, *rest in cases]
     ok = [run_case(*case, devices, mesh=mesh, merge=args.merge,
                    probe=args.probe) for case in cases]
+    # the final select's bin-merge kernel, where the shape's width a chip
+    # engages it: of SHAPES only bigann20m does, which a bare run asks for
+    # over its four chips
+    shape, db_shards = ((args.shape, mesh[1] if mesh else 1)
+                        if args.shape else ("bigann20m", 4))
+    case = _merge_case(shape, db_shards, devices)
+    if case is not None:
+        t0 = time.time()
+        name = f"{shape} select-merge kernel {case[1][0].shape}"
+        try:
+            _compile(*case)
+            print(f"OK   {name}: compiles  ({time.time() - t0:.0f}s)",
+                  flush=True)
+        except Exception as e:  # noqa: BLE001 — Mosaic refusal, reported
+            ok.append(False)
+            print(f"FAIL {name}: {str(e)[-400:]}", flush=True)
     return 0 if all(ok) else 1
 
 

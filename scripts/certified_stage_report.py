@@ -67,6 +67,7 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
     count: Dict[str, int] = defaultdict(int)
     attrs: Dict[str, float] = defaultdict(float)
     merge: Dict[str, object] = {}
+    select: Dict[str, int] = {}
     batches = children = 0.0
     for tid in calls:
         for e in by_tid[tid]:
@@ -78,6 +79,11 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
                 attrs["merge_bytes"] += e.get("merge_bytes", 0)
                 merge = {k: e[k] for k in ("db_shards", "merge",
                                            "merge_source") if k in e}
+                # the final select's width a shard, as the kernel gave
+                # it and as its top-k scanned it (less where the
+                # bin-merge engaged)
+                select = {k: e[k] for k in (
+                    "select_width", "select_merged_width") if k in e}
             elif e.get("parent") == CALL:
                 children += e["dur_s"]
             for key in ("h2d_bytes", "d2h_bytes", "queries_corrected",
@@ -98,6 +104,7 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
         "children_share_of_call": round(children / total[CALL], 5),
         "per_batch": {k: round(v / batches, 3) for k, v in attrs.items()},
         "merge": merge,
+        "select": select,
     }
 
 

@@ -78,8 +78,12 @@ def test_default_geometries_compile_for_v5e_deviceless():
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
     lines = [ln for ln in proc.stdout.splitlines() if ln[:4] in ("OK  ",
                                                                  "FAIL")]
-    assert len(lines) == 10 and all(ln.startswith("OK") for ln in lines)
+    assert len(lines) == 11 and all(ln.startswith("OK") for ln in lines)
     assert "sift fused block_q=256: refused" in proc.stdout
+    # the one kernel outside the coarse pass: the final select's
+    # bin-merge at a 5M-row chip's 78,336 candidate columns
+    assert "bigann20m select-merge kernel (4096, 78336): compiles" in (
+        proc.stdout)
 
 
 def test_stats_report_the_interpret_value_the_kernel_was_given(monkeypatch):

@@ -303,6 +303,9 @@ def test_the_stage_report_reads_the_jsonl_log(placed, corpus, report,
     assert table["per_batch"]["members"] >= 2 * table["per_batch"][
         "queries_corrected"]
     assert 0 <= table["per_batch"]["blocks"] <= 1
+    # a narrow shard: the final select ran over the kernel's candidates
+    assert (table["select"]["select_width"]
+            == table["select"]["select_merged_width"] > 0)
 
 
 def test_the_stage_report_lays_idle_time_on_the_innermost_span(report):
@@ -363,7 +366,10 @@ def test_the_stage_report_finds_the_scope_in_the_event_metadata(report):
         op(2, b"%pad.0 = f32[8] pad()",
            b"jit(spmd)/knn.kernel/jit(_bin_candidates)/knn.operand_prep/"
            b"jit(_pad)/pad:"),
-        op(3, b"%copy.1 = f32[8] copy()", None))
+        op(3, b"%copy.1 = f32[8] copy()", None),
+        op(4, b"%select_merge.1 = (f32[8], s32[8], f32[8]) custom-call()",
+           b"jit(spmd)/jit(f)/knn.final_select/knn.select_merge/"
+           b"pallas_call:"))
     other = _msg((1, 4), (2, b"/host:CPU"),
                  (5, entry(300, _msg((1, 300), (2, b"tf_op")))),
                  op(1, b"%fusion.2 = f32[8] fusion()", b"knn.rescore/x:"))
@@ -373,10 +379,17 @@ def test_the_stage_report_finds_the_scope_in_the_event_metadata(report):
             "jit(spmd)/jit(f)/knn.final_select/top_k:",
         "%pad.0 = f32[8] pad()":
             "jit(spmd)/knn.kernel/jit(_bin_candidates)/knn.operand_prep/"
-            "jit(_pad)/pad:"}
+            "jit(_pad)/pad:",
+        "%select_merge.1 = (f32[8], s32[8], f32[8]) custom-call()":
+            "jit(spmd)/jit(f)/knn.final_select/knn.select_merge/"
+            "pallas_call:"}
+    # the bin-merge inside the final select is a row of its own (the
+    # innermost scope wins), so merge pass and top-k read apart
     assert [report.innermost_scope(scopes.get(n, "")) for n in (
         "%fusion.2 = f32[8] fusion()", "%pad.0 = f32[8] pad()",
-        "%copy.1 = f32[8] copy()")] == [
-            "knn.final_select", "knn.operand_prep", "unscoped"]
+        "%copy.1 = f32[8] copy()",
+        "%select_merge.1 = (f32[8], s32[8], f32[8]) custom-call()")] == [
+            "knn.final_select", "knn.operand_prep", "unscoped",
+            "knn.select_merge"]
     assert report.clipped([(0, 4), (2, 6), (9, 12)], (1, 10)) == [
         (1, 6), (9, 10)]

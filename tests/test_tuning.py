@@ -247,10 +247,14 @@ def test_stale_kernel_version_entry_falls_back_to_defaults(cache_path):
     # it even though "precision": "int4" is a perfectly current knob
     from knn_tpu.ops.pallas_knn import KERNEL_VERSION
 
-    assert KERNEL_VERSION == 5
+    assert KERNEL_VERSION == 6
     cache.put(base + "|kv4", {"knobs": {**tuning.DEFAULT_KNOBS,
                                         "precision": "int4",
                                         "kernel": "streaming"}})
+    # ... and a version-5 winner: timed before the final select's
+    # bin-merge (5 -> 6) changed the tail its timing loop runs
+    cache.put(base + "|kv5", {"knobs": {**tuning.DEFAULT_KNOBS,
+                                        "block_q": 128}})
     knobs, info = tuning.resolve_full(700, 16, 5, cache_path=cache_path)
     assert info["source"] == "default"
     assert knobs == tuning.DEFAULT_KNOBS
