@@ -155,13 +155,9 @@ try:
     #: the library can never run different knobs for the same request.
     PALLAS_PRECISION = os.environ.get("KNN_BENCH_PALLAS_PRECISION")
     PALLAS_TILE = _env_opt_int("KNN_BENCH_PALLAS_TILE")
-    PALLAS_BIN_W = _env_opt_int("KNN_BENCH_PALLAS_BIN_W")
     PALLAS_SURVIVORS = _env_opt_int("KNN_BENCH_PALLAS_SURVIVORS")
     PALLAS_BLOCK_Q = _env_opt_int("KNN_BENCH_PALLAS_BLOCK_Q")
     PALLAS_FINAL = os.environ.get("KNN_BENCH_PALLAS_FINAL")
-    #: select-phase layout (ops.pallas_knn.BINNINGS): "grouped" = lane-
-    #: indexed bins, shuffle-free select (round-4); "lane" = round-3
-    PALLAS_BINNING = os.environ.get("KNN_BENCH_PALLAS_BINNING")
     #: grid iteration order (ops.pallas_knn.GRID_ORDERS): "db_major"
     #: streams each db tile once per sweep instead of once per query
     #: block (r5 cost model); opt-in pending the hardware gate + A/B
@@ -525,9 +521,9 @@ def main() -> None:
         N, DIM, K, metric="l2" if METRIC == "cosine" else METRIC,
         dtype=DTYPE, cache_path=TUNE_CACHE,
         overrides=dict(
-            tile_n=PALLAS_TILE, block_q=PALLAS_BLOCK_Q, bin_w=PALLAS_BIN_W,
+            tile_n=PALLAS_TILE, block_q=PALLAS_BLOCK_Q,
             survivors=PALLAS_SURVIVORS, precision=PALLAS_PRECISION,
-            final_select=PALLAS_FINAL, binning=PALLAS_BINNING,
+            final_select=PALLAS_FINAL,
             grid_order=PALLAS_GRID, final_recall_target=PALLAS_FINAL_RT,
             kernel=PALLAS_KERNEL,
         ),
@@ -1222,7 +1218,7 @@ def main() -> None:
             model = _rl.pallas_cost_model(
                 nq=NQ, precision=KNOBS["precision"],
                 kernel=KNOBS["kernel"], grid_order=KNOBS["grid_order"],
-                binning=KNOBS["binning"], tile_n=KNOBS["tile_n"],
+                tile_n=KNOBS["tile_n"],
                 block_q=KNOBS["block_q"], survivors=KNOBS["survivors"],
                 margin=MARGIN, **pq_kw, **common)
             measured = pb.get("device_qps") or entry.get("qps_mean")
@@ -1275,10 +1271,8 @@ def main() -> None:
         # truth: ShardedKNN._pallas_setup, fed the same resolved KNOBS)
         pp, m, w, _ = prog._pallas_setup(
             MARGIN, KNOBS["tile_n"], KNOBS["precision"],
-            bin_w=KNOBS["bin_w"],
             survivors=KNOBS["survivors"], block_q=KNOBS["block_q"],
             final_select=KNOBS["final_select"],
-            binning=KNOBS["binning"],
             final_recall_target=KNOBS["final_recall_target"],
             grid_order=KNOBS["grid_order"], kernel=KNOBS["kernel"],
         )
@@ -1322,7 +1316,6 @@ def main() -> None:
         # once per train tile; the streaming kernel is one launch per
         # (batch, shard) whose in-kernel DMA loop covers every tile
         from knn_tpu.ops.pallas_knn import (
-            BIN_W as _BIN_W,
             TILE_N as _TILE_N,
             effective_tile,
             kernel_launches_per_batch,
@@ -1331,8 +1324,7 @@ def main() -> None:
         shard_rows = prog._tp.shape[0] // prog.mesh.shape[DB_AXIS]
         eff = effective_tile(
             shard_rows, KNOBS["tile_n"] or _TILE_N,
-            KNOBS["bin_w"] or _BIN_W, KNOBS["survivors"],
-            KNOBS["binning"], m + 2)
+            KNOBS["survivors"], m + 2)
         return {
             "kernel": KNOBS["kernel"],
             "db_tiles_per_shard": -(-shard_rows // eff),
@@ -1438,10 +1430,8 @@ def main() -> None:
         _, idx, g_stats = knn_search_pallas(
             g_q, g_db, g_k, precision=KNOBS["precision"],
             tile_n=KNOBS["tile_n"] or TILE_N_DEFAULT,
-            bin_w=KNOBS["bin_w"],
             survivors=KNOBS["survivors"], block_q=KNOBS["block_q"],
             final_select=KNOBS["final_select"],
-            binning=KNOBS["binning"],
             final_recall_target=KNOBS["final_recall_target"],
             grid_order=KNOBS["grid_order"], kernel=KNOBS["kernel"],
         )
@@ -1696,18 +1686,17 @@ def main() -> None:
             rl_top = {"error": f"{type(e).__name__}: {e}"}
     rl_fields = {"roofline": rl_top}
     # quantization provenance: precision rides top-level on EVERY line so
-    # the precision-ladder A/B lines (int8 / int4 / pq vs the f32 family)
+    # the precision-ladder A/B lines (int8 / pq vs the f32 family)
     # are self-describing and the artifact refresher can curate them
     # separately per arm; quantized lines add the certified bound's worst
     # case over this query set and the scales dtype (the reproducibility
     # trio the ISSUE names), and pq lines carry their codebook geometry
     quant_prov = {"precision": KNOBS["precision"]}
-    if KNOBS["precision"] in ("int8", "int4"):
+    if KNOBS["precision"] == "int8":
         try:
             from knn_tpu.ops import quantize as _qz
 
-            plq = (prog._int8_placement() if KNOBS["precision"] == "int8"
-                   else prog._int4_placement())
+            plq = prog._int8_placement()
             qb_prov = queries
             if METRIC == "cosine":
                 from knn_tpu.parallel.sharded import _row_normalize_f64

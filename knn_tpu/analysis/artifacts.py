@@ -1277,8 +1277,8 @@ CATALOG: Tuple[BlockSchema, ...] = (
             Curated("recall_at_k", "higher", 9),
             Curated("ivf_qps", "higher", 10),
             # the compressed-tier headline: fraction of the brute-force
-            # byte stream actually touched — the number the int4/PQ
-            # arms exist to shrink, so the sentinel baselines it
+            # byte stream actually touched — the number the PQ
+            # arm exists to shrink, so the sentinel baselines it
             # lower-is-better
             Curated("bytes_streamed_ratio", "lower", 11),
         ),
@@ -1330,7 +1330,7 @@ CATALOG: Tuple[BlockSchema, ...] = (
     BlockSchema(
         name="pq",
         block_path="pq",
-        doc="docs/PERF.md#Compressed tiers: int4 & PQ",
+        doc="docs/PERF.md#Compressed tier: PQ",
         validator="knn_tpu.ops.pq_artifact:validate_pq_block",
         emitters=("bench.py",),
         fingerprints=(frozenset({"pq_version", "dsub"}),),

@@ -75,8 +75,7 @@ def grid_findings(grid: Sequence[Dict[str, object]],
             precision=knobs.get("precision"),
             kernel=knobs.get("kernel"), tile_n=knobs.get("tile_n"),
             block_q=knobs.get("block_q"),
-            survivors=knobs.get("survivors"),
-            binning=knobs.get("binning"))
+            survivors=knobs.get("survivors"))
         name = label(knobs) if label else str(sorted(cand.items()))
         findings.append(Finding(
             checker="vmem-budget", path=grid_path, line=0, symbol=name,

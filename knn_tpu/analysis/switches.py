@@ -141,13 +141,6 @@ SWITCHES: Tuple[Switch, ...] = (
     _s("KNN_TPU_TUNE_PRUNE", "float", "knn_tpu/tuning/autotune.py", _OBS,
        "Roofline-model candidate-pruning fraction in (0, 1]; unset = "
        "exhaustive search."),
-    # --- certified pipeline overlap (knn_tpu.parallel.sharded) ---------
-    _s("KNN_TPU_PIPELINE_OVERLAP", "flag", "knn_tpu/parallel/sharded.py",
-       _OBS, "1 runs search_certified as the two-stage coarse/rescore "
-       "pipeline (bitwise-identical results)."),
-    _s("KNN_TPU_PIPELINE_DEPTH", "int", "knn_tpu/parallel/sharded.py",
-       _OBS, "Bounded in-flight batch depth of the pipelined path "
-       "(default 2)."),
     # --- multi-host merge tree (knn_tpu.parallel.crossover) ------------
     _s("KNN_TPU_MERGE", "str", "knn_tpu/parallel/crossover.py", _PERF,
        "Override the measured ring/allgather crossover for the "
@@ -317,8 +310,6 @@ SWITCHES: Tuple[Switch, ...] = (
        "Kernel matmul precision (bf16x3 | bf16x3f | int8 | highest)."),
     _s("KNN_BENCH_PALLAS_TILE", "int", "bench.py", _PERF,
        "Kernel db tile rows (tile_n)."),
-    _s("KNN_BENCH_PALLAS_BIN_W", "int", "bench.py", _PERF,
-       "Kernel bin width."),
     _s("KNN_BENCH_PALLAS_SURVIVORS", "int", "bench.py", _PERF,
        "Per-bin survivor count."),
     _s("KNN_BENCH_PALLAS_BLOCK_Q", "int", "bench.py", _PERF,
@@ -327,8 +318,6 @@ SWITCHES: Tuple[Switch, ...] = (
        "Final select: exact | approx."),
     _s("KNN_BENCH_PALLAS_FINAL_RT", "float", "bench.py", _PERF,
        "Approx final-select recall target."),
-    _s("KNN_BENCH_PALLAS_BINNING", "str", "bench.py", _PERF,
-       "Binning strategy: grouped | lane."),
     _s("KNN_BENCH_PALLAS_GRID", "str", "bench.py", _PERF,
        "Grid order: query_major | db_major."),
     _s("KNN_BENCH_PALLAS_KERNEL", "str", "bench.py", _PERF,

@@ -26,7 +26,7 @@ output blocks, scratch) are exact; what Mosaic keeps live ON TOP of
 them is not declared anywhere, so the score-tile multipliers below were
 fitted to the scoped-VMEM need Mosaic itself reported (libtpu 0.0.34,
 v5e, deviceless AOT — ``scripts/aot_compile_check.py --probe``) for the
-flagship bf16x3 arm with grouped binning at the three benchmark shapes,
+flagship bf16x3 arm at the three benchmark shapes,
 both block_q and both tile sizes, all three kernels.  There the model
 tracked the compiler within +7%/-1% at every probed geometry (tiled
 GIST bq256: 82.5 MiB modeled, 81.94 reported; streaming SIFT bq256:
@@ -36,9 +36,7 @@ bq256 (SIFT tiled / GIST tiled / SIFT streaming, MiB): "highest" needs
 18.46 / 66.29 / 111.19 where the model says 50.5 / 82.5 / 126.75,
 "bf16x3f" 26.02 / 73.88 / 119.19 against 58.5 / 90.5 / 134.75, "int8"
 <=8 / 55.66 / 100.06 against 39.56 / 71.56 / 115.81 — an upper bound,
-by up to 2.7x; the "lane" binning needs 107.23 / 154.38 / 183.60
-against 50.5 / 82.5 / 126.75 — far UNDER; and the "pq" one-hot
-expansion is not modeled at all.
+by up to 2.7x; and the "pq" one-hot expansion is not modeled at all.
 
 Geometry constants mirror ``ops.pallas_knn`` (TILE_N/BLOCK_Q/BIN_W/
 DIM_CHUNK/MAX_CARRY_DEPTH), pinned by tests/test_analysis.py.  The
@@ -126,12 +124,11 @@ def budget_for(device_kind: Optional[str],
     return None
 
 
-def calibrated(precision: Optional[str], binning: Optional[str]) -> bool:
+def calibrated(precision: Optional[str]) -> bool:
     """Whether the model was fitted to the compiler for this arm (module
     docstring) — the ONE switch between "the model refuses" and "Mosaic
     decides", shared by the kernel, the autotuner gate and the grid."""
-    return ((precision or "bf16x3") == "bf16x3"
-            and (binning or "grouped") == "grouped")
+    return (precision or "bf16x3") == "bf16x3"
 
 
 def limit_bytes(estimate_bytes: int, budget_bytes: int) -> int:
@@ -152,7 +149,7 @@ def _ceil_div(a: int, b: int) -> int:
 
 def _geometry(n: int, d: int, precision: str, kernel: str,
               tile_n: Optional[int], block_q: Optional[int],
-              survivors: Optional[int], binning: str):
+              survivors: Optional[int]):
     if precision != "pq" and precision not in DB_PARTS:
         raise ValueError(
             f"precision {precision!r} not in {sorted(DB_PARTS) + ['pq']}")
@@ -164,15 +161,8 @@ def _geometry(n: int, d: int, precision: str, kernel: str,
     n_tiles = _ceil_div(n, tile)
     dim_p = _ceil_div(d, DIM_CHUNK) * DIM_CHUNK
     nd = dim_p // DIM_CHUNK
-    if binning == "grouped":
-        surv = int(survivors or SURVIVORS_GROUPED_DEFAULT)
-        out_w = surv * BIN_W
-        bound_w = BIN_W
-    else:
-        surv = int(survivors or 2)
-        n_bins = max(1, tile // BIN_W)
-        out_w = _ceil_div(n_bins * surv, BIN_W) * BIN_W
-        bound_w = _ceil_div(n_bins, BIN_W) * BIN_W
+    out_w = int(survivors or SURVIVORS_GROUPED_DEFAULT) * BIN_W
+    bound_w = BIN_W
     return tile, bq, n_tiles, dim_p, nd, out_w, bound_w
 
 
@@ -234,7 +224,7 @@ def launch_estimate(
     *, n: int, d: int, k: int, margin: int = 28,
     precision: Optional[str] = None, kernel: Optional[str] = None,
     tile_n: Optional[int] = None, block_q: Optional[int] = None,
-    survivors: Optional[int] = None, binning: Optional[str] = None,
+    survivors: Optional[int] = None,
     pq_dsub: Optional[int] = None, pq_ncodes: Optional[int] = None,
 ) -> dict:
     """Estimated VMEM high-water bytes of ONE kernel launch for this
@@ -243,9 +233,8 @@ def launch_estimate(
     resolve)."""
     precision = precision or "bf16x3"
     kernel = kernel or "tiled"
-    binning = binning or "grouped"
     tile, bq, n_tiles, dim_p, nd, out_w, bound_w = _geometry(
-        n, d, precision, kernel, tile_n, block_q, survivors, binning)
+        n, d, precision, kernel, tile_n, block_q, survivors)
     lut_w = 0
     if precision == "pq":
         # one db block is the [tile_n, m] byte code tensor; the
@@ -260,7 +249,7 @@ def launch_estimate(
         nd = 1
     else:
         n_parts, chunk_w, part_b = DB_PARTS[precision]
-    quantized = precision in ("int8", "int4")
+    quantized = precision == "int8"
     if precision == "pq":
         q_block = bq * lut_w * 4
     else:
@@ -295,7 +284,7 @@ def _estimate_for(knobs: dict, *, n: int, d: int, k: int,
         n=n, d=d, k=k, margin=margin,
         precision=knobs.get("precision"), kernel=knobs.get("kernel"),
         tile_n=knobs.get("tile_n"), block_q=knobs.get("block_q"),
-        survivors=knobs.get("survivors"), binning=knobs.get("binning"),
+        survivors=knobs.get("survivors"),
         pq_dsub=knobs.get("pq_dsub"),
         pq_ncodes=knobs.get("pq_ncodes"))["total_bytes"]
 
@@ -311,8 +300,7 @@ def check_candidate(
     refusal."""
     budget = budget_for(device_kind, backend)
     est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin)
-    checked = budget is not None and calibrated(
-        knobs.get("precision"), knobs.get("binning"))
+    checked = budget is not None and calibrated(knobs.get("precision"))
     return {
         "checked": checked,
         "estimate_bytes": est,
@@ -330,7 +318,7 @@ def fits_some_kind(knobs: dict, *, n: int, d: int, k: int,
     ``knob_grid`` drops such combinations at the headline shape and the
     ``vmem-budget`` checker enforces the same bound.  An arm the model
     is not :func:`calibrated` for is never excluded on it."""
-    if not calibrated(knobs.get("precision"), knobs.get("binning")):
+    if not calibrated(knobs.get("precision")):
         return True
     est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin)
     return est <= max(VMEM_BYTES_BY_KIND.values())

@@ -4,8 +4,8 @@ Before PR 17 the db-operand stream widths lived three times over —
 ``obs.roofline.DB_ELEM_BYTES`` (the cost model), ``analysis.vmem.DB_PARTS``
 (the launch budget), and ``analysis.hbm``'s itemsize arithmetic (the
 placement budget) — pinned against each other by tests but still three
-places to edit.  With the sub-int8 arms (int4 nibble-packed rows, PQ
-byte codes whose row width depends on ``ceil(d / dsub)``) a drifted
+places to edit.  With the sub-int8 arm (PQ byte codes whose row width
+depends on ``ceil(d / dsub)``) a drifted
 mirror would mis-price exactly the byte term those arms exist to
 shrink, so the widths now live HERE and all three consumers import
 them; tests/test_analysis.py pins the identity (``is``, not ``==``) so
@@ -19,14 +19,10 @@ Layout provenance (what the kernels actually stream,
 - ``bf16x3``  : precomputed bf16 hi+lo db parts, 2+2 B/elem.
 - ``bf16x3f`` : one 3x-wide bf16 contraction, 6 B/elem.
 - ``int8``    : per-row symmetric int8 rows, 1 B/elem.
-- ``int4``    : per-row symmetric 4-bit rows packed two-nibbles-per-byte
-  (``ops.quantize.pack_nibbles``), 0.5 B/elem — the db-stream halving
-  the PR 17 roofline target prices.  Dims pad to DIM_CHUNK first, so
-  bytes/row = ``ceil_to(d, 128) / 2`` exactly.
 - ``pq``      : one byte code per subspace, ``ceil(d / dsub)`` B/row
   (``ops.pq``); per-element width is shape-dependent, so consumers call
   :func:`db_row_bytes` instead of indexing ``DB_ELEM_BYTES``.
-- ``highest`` / ``default``: the raw f32 rows, 4 B/elem.
+- ``highest``: the raw f32 rows, 4 B/elem.
 """
 
 from __future__ import annotations
@@ -37,38 +33,30 @@ from typing import Dict, Optional, Tuple
 #: ops.pallas_knn.DIM_CHUNK, pinned by test)
 DIM_CHUNK = 128
 
-#: db stream width per element by kernel matmul precision.  int4 is the
-#: only fractional entry (two dims per byte); "pq" is deliberately
-#: ABSENT — its row width is ``ceil(d / dsub)`` bytes, shape-dependent,
-#: served by :func:`db_row_bytes`.
+#: db stream width per element by kernel matmul precision.  "pq" is
+#: deliberately ABSENT — its row width is ``ceil(d / dsub)`` bytes,
+#: shape-dependent, served by :func:`db_row_bytes`.
 DB_ELEM_BYTES: Dict[str, float] = {
-    "bf16x3": 4, "bf16x3f": 6, "int8": 1, "int4": 0.5,
-    "highest": 4, "default": 4,
+    "bf16x3": 4, "bf16x3f": 6, "int8": 1, "highest": 4,
 }
 
 #: f32 sublane rows of the per-tile aux block: 8 rows of broadcast row
 #: norms, and int8 stacks 8 broadcast scale rows under them (16).
-#: int4 instead PACKS norms (row 0) + scales (row 1) into the default
-#: 8-row block — the kernel reads exactly one row of each, and the
-#: packed layout halves an aux stream that would otherwise weigh as
-#: much as the nibble-packed values at d=128.  PQ needs no db-side
+#: PQ needs no db-side
 #: norms (the per-query LUT carries the reconstruction's norm term),
 #: so its aux block is the 8-row pad-fill carrier only.
 AUX_ROWS: Dict[str, int] = {"int8": 16}
 AUX_ROWS_DEFAULT = 8
 
-#: query operand width per element: the quantized arms stream int8
-#: queries (int4 dbs score against int8 queries — the query side is
-#: tiny, so halving IT buys nothing and would double the query
-#: residual term of the bound).  PQ is absent here too: its query-side
+#: query operand width per element: the int8 arm streams int8
+#: queries.  PQ is absent here too: its query-side
 #: operand is the per-query LUT, priced by :func:`pq_lut_bytes`.
-QUERY_ELEM_BYTES: Dict[str, int] = {"int8": 1, "int4": 1}
+QUERY_ELEM_BYTES: Dict[str, int] = {"int8": 1}
 QUERY_ELEM_BYTES_DEFAULT = 4
 
 #: db operand parts per precision for the VMEM launch model:
 #: (n_parts, chunk_w, bytes/elem) — one db block of ONE part occupies
-#: (tile_n, chunk_w) at the part dtype.  int4's packed chunk is 64
-#: bytes wide (two dims per byte over a 128-dim chunk).  "pq" is
+#: (tile_n, chunk_w) at the part dtype.  "pq" is
 #: absent: its chunk width is the shape-dependent code width
 #: ``ceil(d / dsub)`` (analysis.vmem special-cases it via
 #: :func:`db_row_bytes`).
@@ -76,9 +64,7 @@ DB_PARTS: Dict[str, Tuple[int, int, int]] = {
     "bf16x3": (2, DIM_CHUNK, 2),
     "bf16x3f": (1, 3 * DIM_CHUNK, 2),
     "int8": (1, DIM_CHUNK, 1),
-    "int4": (1, DIM_CHUNK // 2, 1),
     "highest": (1, DIM_CHUNK, 4),
-    "default": (1, DIM_CHUNK, 4),
 }
 
 #: f32 aux bytes beside each placed row (the hoisted squared norm) —
@@ -105,14 +91,11 @@ def pq_nsub(d: int, dsub: Optional[int] = None) -> int:
 def db_row_bytes(d: int, precision: str, *,
                  dsub: Optional[int] = None) -> int:
     """EXACT bytes one db row streams at this precision — the one
-    entry point that covers the shape-dependent arms: int4 rounds the
-    (DIM_CHUNK-padded) dim up to an even nibble pair, PQ streams
+    entry point that covers the shape-dependent arm: PQ streams
     ``ceil(d / dsub)`` code bytes."""
     d = int(d)
     if precision == "pq":
         return pq_nsub(d, dsub)
-    if precision == "int4":
-        return _ceil_div(_ceil_div(d, DIM_CHUNK) * DIM_CHUNK, 2)
     if precision not in DB_ELEM_BYTES:
         raise ValueError(
             f"precision {precision!r} not in "

@@ -152,7 +152,6 @@ def _knobs_for_model(knobs: Dict[str, object]) -> Dict[str, object]:
         "precision": knobs.get("precision"),
         "kernel": knobs.get("kernel"),
         "grid_order": knobs.get("grid_order"),
-        "binning": knobs.get("binning"),
         "tile_n": knobs.get("tile_n"),
         "block_q": knobs.get("block_q"),
         "survivors": knobs.get("survivors"),
@@ -240,9 +239,9 @@ def _rehearse_arm(arm: str, *, out_dir: str, shape: Dict[str, int],
     log("bench ...")
     kw = dict(
         precision=knobs["precision"], kernel=knobs["kernel"],
-        tile_n=knobs["tile_n"] or tile, bin_w=knobs["bin_w"],
+        tile_n=knobs["tile_n"] or tile,
         survivors=knobs["survivors"], block_q=knobs["block_q"],
-        final_select=knobs["final_select"], binning=knobs["binning"],
+        final_select=knobs["final_select"],
         final_recall_target=knobs["final_recall_target"],
         grid_order=knobs["grid_order"])
     q = queries[:nq]

@@ -748,14 +748,12 @@ def build_roofline_parser() -> argparse.ArgumentParser:
                    f"{', '.join(sorted(PEAKS_BY_KIND))}; unset/unknown "
                    "= generic-CPU fallback peaks flagged estimated")
     p.add_argument("--precision", default=None,
-                   choices=("bf16x3", "bf16x3f", "int8", "int4", "pq",
-                            "highest", "default"),
+                   choices=CERTIFIED_PRECISIONS,
                    help="kernel matmul precision (pallas selector)")
     p.add_argument("--kernel", default=None,
                    choices=("tiled", "streaming", "fused"))
     p.add_argument("--grid-order", default=None,
                    choices=("query_major", "db_major"))
-    p.add_argument("--binning", default=None, choices=("grouped", "lane"))
     p.add_argument("--tile-n", type=int, default=None)
     p.add_argument("--block-q", type=int, default=None)
     p.add_argument("--survivors", type=int, default=None)
@@ -816,8 +814,7 @@ def _run_roofline_best(args) -> int:
         # dedupe to the model-relevant knob tuple so each geometry
         # prints once
         mkey = (knobs["precision"], knobs["kernel"], knobs["grid_order"],
-                knobs["binning"], knobs["tile_n"], knobs["block_q"],
-                knobs["survivors"])
+                knobs["tile_n"], knobs["block_q"], knobs["survivors"])
         if mkey in seen:
             continue
         seen.add(mkey)
@@ -825,7 +822,7 @@ def _run_roofline_best(args) -> int:
             model = roofline.pallas_cost_model(
                 n=args.n, d=args.dim, k=args.k, nq=args.nq,
                 precision=knobs["precision"], kernel=knobs["kernel"],
-                grid_order=knobs["grid_order"], binning=knobs["binning"],
+                grid_order=knobs["grid_order"],
                 tile_n=knobs["tile_n"], block_q=knobs["block_q"],
                 survivors=knobs["survivors"], margin=args.margin,
                 device_kind=args.device_kind, num_devices=args.devices,
@@ -888,7 +885,7 @@ def run_roofline(args: argparse.Namespace) -> int:
         model = roofline.pallas_cost_model(
             n=args.n, d=args.dim, k=args.k, nq=args.nq,
             precision=args.precision, kernel=args.kernel,
-            grid_order=args.grid_order, binning=args.binning,
+            grid_order=args.grid_order,
             tile_n=args.tile_n, block_q=args.block_q,
             survivors=args.survivors, margin=args.margin,
             device_kind=args.device_kind, num_devices=args.devices,

@@ -11,8 +11,8 @@ Two modes (:data:`JOIN_MODES`):
   :func:`knn_tpu.parallel.sharded.query_stream_program` (the exact
   search program) under the
   bounded-depth drain-oldest discipline — block i+1's transfer +
-  dispatch overlaps block i's fetch, measured by the same
-  dispatch-timeline ``overlap_ratio`` the certified pipeline reports.
+  dispatch overlaps block i's fetch, measured as the
+  dispatch-timeline ``overlap_ratio``.
   When B itself exceeds HBM (a host-RAM-tier placement), the sweep
   nesting order comes from :func:`knn_tpu.analysis.hbm.plan_join`:
   ``db_major`` outer streams each db segment h2d ONCE and serves every

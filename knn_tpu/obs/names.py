@@ -86,9 +86,6 @@ TUNING_CANDIDATES_PRUNED = "knn_tpu_tuning_candidates_pruned_total"
 TUNING_CANDIDATES_VMEM_REFUSED = \
     "knn_tpu_tuning_candidates_vmem_refused_total"
 
-# --- certified pipeline overlap (knn_tpu.parallel.sharded) -------------
-PIPELINE_OVERLAP_RATIO = "knn_tpu_pipeline_overlap_ratio"
-
 # --- JAX compile events (knn_tpu.obs.jax_hooks) ------------------------
 JAX_COMPILES = "knn_tpu_jax_compiles_total"
 JAX_COMPILE_SECONDS = "knn_tpu_jax_compile_seconds_total"
@@ -310,12 +307,6 @@ CATALOG = {
         "estimated per-launch footprint exceeds the device kind's VMEM, "
         "so they would fail at Mosaic compile time; every refusal is "
         "recorded in the tune entry's vmem provenance."),
-    PIPELINE_OVERLAP_RATIO: (
-        "gauge", (),
-        "Fraction of the last certified pipeline-overlap run's wall "
-        "time with >= 2 batches in flight (coarse-dispatch start to "
-        "result-repair end) — the two-stage coarse/rescore pipeline's "
-        "measured dispatch-timeline overlap."),
     JAX_COMPILES: (
         "counter", ("event",),
         "JAX/XLA compile events observed via jax.monitoring."),

@@ -110,10 +110,8 @@ from knn_tpu.obs import names, registry, trace
 #: store so pre-IVF attributions self-invalidate.
 #: 6 = the sub-int8 byte widths (PR 17): the per-precision width
 #: tables move to :mod:`knn_tpu.analysis.widths` (ONE shared home with
-#: analysis.vmem / analysis.hbm) and the model prices the new arms —
-#: "int4" streams nibble-packed rows at 0.5 B/elem (db_row_bytes
-#: rounds the DIM_CHUNK-padded dim to whole bytes) and scores at the
-#: int8 MXU rate; "pq" streams ``ceil(d / dsub)`` code bytes per row,
+#: analysis.vmem / analysis.hbm) and the model prices the new arm —
+#: "pq" streams ``ceil(d / dsub)`` code bytes per row,
 #: re-fetches the per-query [nq, m·ncodes] f32 LUT per db tile in
 #: place of the query blocks, and its executed MXU flops are the
 #: one-hot expansion dot the kernel actually runs
@@ -245,12 +243,12 @@ def h2d_gbps_for(device_kind, peaks) -> float:
 #: them against the actual operand arrays' nbytes.
 DB_ELEM_BYTES = _widths.DB_ELEM_BYTES
 
-#: f32 sublane rows of the per-tile aux block (norms; int8/int4 stack
+#: f32 sublane rows of the per-tile aux block (norms; int8 stacks
 #: scales under norms) — ops.pallas_knn's aux_rows
 AUX_ROWS = _widths.AUX_ROWS
 AUX_ROWS_DEFAULT = _widths.AUX_ROWS_DEFAULT
 
-#: query operand width per element (int8/int4 queries quantize in the
+#: query operand width per element (int8 queries quantize in the
 #: XLA prologue and stream as int8 + a [block_q, 128] f32 scale block;
 #: pq's query-side operand is the per-query LUT — pq_lut_bytes)
 QUERY_ELEM_BYTES = _widths.QUERY_ELEM_BYTES
@@ -258,22 +256,19 @@ QUERY_ELEM_BYTES_DEFAULT = _widths.QUERY_ELEM_BYTES_DEFAULT
 
 #: executed MXU passes over the 2·nq·n·d useful flops, by precision:
 #: bf16x3/bf16x3f reconstruct the f32 product in three bf16 passes,
-#: "highest" is the native six-pass f32 path, int8/int4 and "default"
-#: are one pass (int8/int4 at the int8 MXU rate — int4 unpacks to int8
-#: operands in the kernel prologue).  "pq" is nominally one pass but
+#: "highest" is the native six-pass f32 path, int8 is one pass at
+#: the int8 MXU rate.  "pq" is nominally one pass but
 #: its executed flops are shape-dependent (the one-hot dot's
 #: ``m·ncodes`` contraction width) — pallas_cost_model prices that
 #: directly.
 MXU_PASSES: Dict[str, int] = {
-    "bf16x3": 3, "bf16x3f": 3, "highest": 6, "default": 1, "int8": 1,
-    "int4": 1, "pq": 1,
+    "bf16x3": 3, "bf16x3f": 3, "highest": 6, "int8": 1, "pq": 1,
 }
 
-#: VPU element-ops per score element for the in-kernel selects — the
+#: VPU element-ops per score element for the in-kernel select — the
 #: measured cost model's calibration (docs/PERF.md: "grouped select
-#: ~12 VPU ops x 4.1e9 score elements"); lane pays ~7 shuffle rounds
-#: per reduction, ~5x more
-SELECT_OPS: Dict[str, float] = {"grouped": 12.0, "lane": 60.0}
+#: ~12 VPU ops x 4.1e9 score elements")
+SELECT_OPS = 12.0
 
 #: VPU element-ops per score element for the XLA selectors: a full
 #: ``lax.top_k`` over a db-wide row measured ~30x the distance matmul
@@ -352,9 +347,8 @@ def db_operand_nbytes(n: int, d: int, precision: str, *,
     """Bytes of the db-side operands ONE full-db stream moves — the
     values array(s) plus the lane-major aux block — matching the arrays
     ``ops.pallas_knn._bin_candidates`` actually builds (the property
-    test compares against their ``nbytes``).  The shape-dependent arms
-    route through ``widths.db_row_bytes``: int4 streams the nibble-
-    packed (DIM_CHUNK-padded) rows at 0.5 B/elem, "pq" streams
+    test compares against their ``nbytes``).  The shape-dependent arm
+    routes through ``widths.db_row_bytes``: "pq" streams
     ``ceil(d / dsub)`` code bytes per row."""
     return {
         "db_values": int(n) * _widths.db_row_bytes(d, precision,
@@ -537,7 +531,7 @@ def _dcn_term(nq: int, k: int, db_hosts: int, dcn_merge: Optional[str],
 def pallas_cost_model(
     *, n: int, d: int, k: int, nq: int,
     precision: Optional[str] = None, kernel: Optional[str] = None,
-    grid_order: Optional[str] = None, binning: Optional[str] = None,
+    grid_order: Optional[str] = None,
     tile_n: Optional[int] = None, block_q: Optional[int] = None,
     survivors: Optional[int] = None, margin: int = 28,
     device_kind: Optional[str] = None, backend: Optional[str] = None,
@@ -569,7 +563,6 @@ def pallas_cost_model(
         raise ValueError(
             f"kernel {kernel!r} not in ('tiled', 'streaming', 'fused')")
     grid_order = grid_order or "query_major"
-    binning = binning or "grouped"
     tile = int(tile_n or TILE_N_DEFAULT)
     bq = int(block_q or BLOCK_Q_DEFAULT)
     estimated = False
@@ -582,17 +575,10 @@ def pallas_cost_model(
     tile = min(tile, max(BIN_W, _ceil_div(n_dev, BIN_W) * BIN_W))
     n_tiles = _ceil_div(n_dev, tile)
     q_blocks = _ceil_div(nq, bq)
-    if binning == "grouped":
-        surv = int(survivors or SURVIVORS_GROUPED_DEFAULT)
-        out_w = surv * BIN_W
-        bound_w = BIN_W
-        sel_ops = SELECT_OPS["grouped"]
-    else:
-        surv = int(survivors or 2)
-        n_bins = max(1, tile // BIN_W)
-        out_w = _ceil_div(n_bins * surv, BIN_W) * BIN_W
-        bound_w = _ceil_div(n_bins, BIN_W) * BIN_W
-        sel_ops = SELECT_OPS["lane"]
+    surv = int(survivors or SURVIVORS_GROUPED_DEFAULT)
+    out_w = surv * BIN_W
+    bound_w = BIN_W
+    sel_ops = SELECT_OPS
 
     # --- HBM bytes ------------------------------------------------------
     # db stream passes: query_major (and the inherently query-major
@@ -610,7 +596,7 @@ def pallas_cost_model(
     db_stream = db_passes * opnd["db_values"]
     db_aux = db_passes * opnd["db_aux"]
     # query blocks re-fetch once per db tile (their mapped index cycles
-    # with the dim-chunk axis); int8/int4 add the [block_q, 128] f32
+    # with the dim-chunk axis); int8 adds the [block_q, 128] f32
     # per-query scale block per cell; pq's query-side operand is the
     # per-query LUT ([nq, m·ncodes] f32), re-fetched per db tile in
     # place of the raw query blocks (the raw queries are consumed ONCE
@@ -621,7 +607,7 @@ def pallas_cost_model(
     else:
         q_elem = QUERY_ELEM_BYTES.get(precision, QUERY_ELEM_BYTES_DEFAULT)
         queries_b = n_tiles * nq * d * q_elem
-    if precision in ("int8", "int4"):
+    if precision == "int8":
         queries_b += n_tiles * nq * BIN_W * 4
     # candidate outputs: every (query block, db tile) cell writes its
     # disjoint (block_q, out_w) f32+i32 candidates and bound_w bounds
@@ -652,7 +638,7 @@ def pallas_cost_model(
     if probe is not None:
         useful += probe["assign_flops"]
         executed += probe["assign_flops"]
-    mxu_rate = peaks["int8_flops"] if precision in ("int8", "int4") \
+    mxu_rate = peaks["int8_flops"] if precision == "int8" \
         else peaks["bf16_flops"]
     # executed flops are per-device work summed over the (perfectly
     # scaled) mesh: each device runs executed/num_devices in parallel
@@ -672,7 +658,7 @@ def pallas_cost_model(
         "config": {
             "n": n_total, "d": int(d), "k": int(k), "nq": int(nq),
             "precision": precision, "kernel": kernel,
-            "grid_order": grid_order, "binning": binning,
+            "grid_order": grid_order,
             "tile_n": tile, "block_q": bq, "survivors": surv,
             "margin": int(margin), "num_devices": int(num_devices),
             "db_hosts": max(1, int(db_hosts)),
@@ -1060,7 +1046,7 @@ def block_for_bench_line(rec: dict) -> Optional[dict]:
                 precision=knobs.get("precision") or rec.get("precision"),
                 kernel=knobs.get("kernel"),
                 grid_order=knobs.get("grid_order"),
-                binning=knobs.get("binning"), tile_n=knobs.get("tile_n"),
+                tile_n=knobs.get("tile_n"),
                 block_q=knobs.get("block_q"),
                 survivors=knobs.get("survivors"),
                 margin=int(knobs.get("margin") or 28),

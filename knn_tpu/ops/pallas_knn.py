@@ -21,39 +21,31 @@ ONE database pass:
              (candidates) AND the (s+1)-th smallest value (the *exclusion
              bound*: no non-candidate in this bin can score below it)
 
-Two bin LAYOUTS share this contract (``binning``, see ``BINNINGS``):
+The bin LAYOUT is grouped: bin b = lane b of every 128-wide column group
+of the score tile (128 bins/tile, members strided 128 apart).  The
+per-bin reduction runs across column groups as elementwise vreg
+min/compare/select chains — ZERO cross-lane shuffles; a single fused
+pass maintains the running (s+1)-smallest per lane plus survivor group
+indices (``_emit_select_grouped``).  A layout whose bins are contiguous
+128-lane spans reduces over lanes (~7 shuffle rounds for each min and
+argmin): its select dominated the kernel and it measured 1.8-3.1x
+slower at the SIFT shape on a v5e.  The compiled grouped kernel passed
+the 200k-row float64-oracle soundness gate and bench.py's embedded
+tie-stressed gate on a v5e chip.
 
-- ``"grouped"`` (default): bin b = lane b of every 128-wide
-  column group of the score tile (128 bins/tile, members strided 128
-  apart).  The per-bin reduction runs across column groups as
-  elementwise vreg min/compare/select chains — ZERO cross-lane
-  shuffles; a single fused pass maintains the running (s+1)-smallest
-  per lane plus survivor group indices (``_emit_select_grouped``),
-  ~5x fewer VPU ops than the lane layout whose select dominated the
-  round-3 kernel (device MFU 2.25%).  Hardware-validated round 5
-  (ADVICE r4 conditioned the default on this): the compiled kernel
-  passed the 200k-row float64-oracle soundness gate AND bench.py's
-  embedded tie-stressed gate on a v5e chip, and measured 1.8-3.1x
-  faster than lane at the SIFT shape (kernel-only 171 -> 96/55.9 ms
-  per 4096 queries; 2026-07-31, before PR 1 — docs/PERF.md).
-- ``"lane"`` (round-3): bins are contiguous 128-lane spans; min/argmin
-  reduce over lanes (~7 shuffle rounds each).  Kept for A/B.
-
-Outputs per (i, j) cell are lane-aligned blocks (``s * 128`` lanes in
-grouped mode; ``round_up(s * n_bins, 128)`` in lane mode — the round-2
-kernel's (256, 16) output block failed to lower for exactly this rule).
+Outputs per (i, j) cell are lane-aligned blocks (``s * 128`` lanes: a
+(256, 16) output block fails to lower for exactly this rule).
 Each (query block, db tile) cell writes its per-bin exclusion bounds to
 its own disjoint output block; the min over tiles happens in XLA after
-the kernel.  (The bounds were originally min-accumulated in-place across
-tiles via output revisiting; the round-3 compiled-soundness gate
-recorded an inflated bound on hardware with that design, and per-tile
-emission costs ~0.3 ms of HBM writes while depending on no revisiting
-semantics at all.)
+the kernel.  (Min-accumulating the bounds in-place across tiles via
+output revisiting recorded an inflated bound on hardware in a
+compiled-soundness gate, and per-tile emission costs ~0.3 ms of HBM
+writes while depending on no revisiting semantics at all.)
 
 Why top-2 per bin (the default): with 1M rows in ~7900 128-member bins
-(either layout at the default geometry), two true top-100 neighbors
+(the default geometry), two true top-100 neighbors
 share a bin for ~47% of queries — a 1-survivor kernel falls back
-constantly (the round-2 failure mode).  Three sharing one bin happens
+constantly.  Three sharing one bin happens
 ~0.3% of the time: top-2 makes the certified fast path the common
 case, and the bound makes every miss *detectable*:
 
@@ -109,8 +101,7 @@ see ``KERNELS``):
   on the same per-tile scores — so the downstream certified pipeline
   is unchanged and interpret-mode equality is testable
   (tests/test_pallas_streaming.py).  Opt-in until the on-hardware gate
-  + A/B pass on it (the same discipline grouped/db_major went
-  through); the autotuner (knn_tpu.tuning) carries it in the default
+  + A/B pass on it; the autotuner (knn_tpu.tuning) carries it in the default
   knob grid so the next TPU session measures it.
 
 Runs in interpret mode off-TPU so the CPU test suite covers it;
@@ -131,19 +122,19 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from knn_tpu.ops.topk import topk_pairs
+from knn_tpu.utils.config import CERTIFIED_PRECISIONS
 
 #: bin width — the lane count; `survivors` candidates + one bound per bin
 BIN_W = 128
 #: query rows per grid cell (VMEM: the [BLOCK_Q, TILE_N] f32 score tile;
 #: 128 fills the MXU's M dimension — measured best on v5e)
 BLOCK_Q = 128
-#: database rows per grid cell.  16384 is the grouped-binning sweet spot
-#: at 1M rows: 128 lane-bins of 128 members per tile reproduce the
-#: round-3 candidate statistics (~0.3% three-share at survivors=2)
-#: while halving the final-select width vs tile 8192 (62 tiles x 256 =
-#: 15.9k candidates vs 123 x 256 = 31.5k); every production shape
-#: compile-checks for v5e at this tile (scripts/aot_compile_check.py).
-#: Lane-mode round-3 measurements used 8192.
+#: database rows per grid cell.  16384 is the sweet spot at 1M rows:
+#: 128 lane-bins of 128 members per tile keep the three-share rate at
+#: ~0.3% (survivors=2) while halving the final-select width vs tile 8192
+#: (62 tiles x 256 = 15.9k candidates vs 123 x 256 = 31.5k); every
+#: production shape compile-checks for v5e at this tile
+#: (scripts/aot_compile_check.py).
 TILE_N = 16384
 #: the final select's second bin-merge (``select_merge_geometry``): the
 #: candidates a merge bin keeps; its next smallest is the bin's bound.
@@ -180,13 +171,7 @@ _I32MAX = jnp.iinfo(jnp.int32).max
 #: throughput, 1/4 the db streaming bytes) rescaled to f32 by the
 #: per-query x per-row scale product — its certified tolerance is the
 #: PROVABLE per-query quantization bound ε (quantize.score_error_bound),
-#: so misses fall back, never leak.  "int4" takes that one rung further
-#: down the byte ladder (PR 17): the db streams 4-bit rows packed
-#: two-nibbles-per-byte (ops.quantize.pack_nibbles — 0.5 B/elem, HALF
-#: int8's stream), unpacked in the kernel prologue into int8 lanes and
-#: scored against the SAME int8 queries with the same exact-int32
-#: accumulation; only the db residual widens, and the certificate's ε
-#: widens with it through the identical actual-residual bound.  "pq"
+#: so misses fall back, never leak.  "pq"
 #: drops below bits-per-dim entirely: product-quantization codes (one
 #: byte per ``dsub``-dim subspace, ops.pq) stream as the db operand and
 #: the query side arrives as a per-query LOOKUP TABLE
@@ -195,11 +180,11 @@ _I32MAX = jnp.iinfo(jnp.int32).max
 #: expansion — s = tn - 2·qt then equals ||t̂||² - 2 q·t̂, the exact
 #: kernel score against the RECONSTRUCTION t̂, and the per-subspace
 #: Cauchy–Schwarz bound (ops.pq.score_error_bound_pq) certifies the
-#: distance to the true rows.  "highest" is the native f32 path;
-#: "default" is for experiments only — its error is certificate-hostile
-#: (~2^-10 relative, measured).
-PRECISIONS = ("bf16x3", "bf16x3f", "int8", "int4", "pq", "highest",
-              "default")
+#: distance to the true rows.  "highest" is the native f32 path.
+#: Every mode has a certified tolerance model (``kernel_tolerance``);
+#: a single-pass DEFAULT-precision f32 dot has none (~2^-10 relative
+#: error, measured: certificate-hostile) and is no mode.
+PRECISIONS = CERTIFIED_PRECISIONS
 
 #: kernel/emitter code version: BUMP whenever the kernel arithmetic, the
 #: emitters, or the knob semantics change — the autotuner's persisted
@@ -208,12 +193,15 @@ PRECISIONS = ("bf16x3", "bf16x3f", "int8", "int4", "pq", "highest",
 #: a changed kernel.  3 = int8 emitter path added (PR 3); 4 = fused
 #: in-loop select arm + the r05-proven block_q=256 default promotion
 #: (tuning.DEFAULT_KNOBS) — old winners measured against block_q=128
-#: reference runs self-invalidate.  5 = sub-int8 arms (int4 nibble
-#: unpack prologue + PQ LUT/one-hot scoring, PR 17): the precision knob
-#: domain widened, so winners tuned on the v4 grid self-invalidate.
+#: reference runs self-invalidate.  5 = sub-int8 arms (PQ LUT/one-hot
+#: scoring, PR 17): the precision knob domain widened, so winners tuned
+#: on the v4 grid self-invalidate.
 #: 6 = the final select's bin-merge (PR 28): the tuner times
 #: local_certified_candidates, whose tail changed at wide shards.
-KERNEL_VERSION = 6
+#: 7 = the knob domain narrowed (PR 29): no select-layout or bin-width
+#: knob, no 4-bit and no single-pass DEFAULT precision — a persisted
+#: winner that names one is never looked up again.
+KERNEL_VERSION = 7
 
 #: relative slack of the device rank stage's direct-difference f32
 #: distances: per-term (q-t)^2 rounding plus the depth-7 tree reduce give
@@ -244,17 +232,6 @@ def _round_up(x: int, multiple: int) -> int:
     return -(-x // multiple) * multiple
 
 
-#: select-phase layouts.  "grouped": bins are indexed by LANE (128 bins
-#: per tile, members strided 128 apart); the per-bin reduction runs over
-#: the vreg-group axis as ELEMENTWISE vector min/compare/select chains —
-#: no cross-lane shuffles at all.  "lane": the round-3 layout (bins are
-#: contiguous 128-lane spans; min/argmin reduce over lanes, ~7 shuffle
-#: rounds per reduction) — kept for A/B and as a fallback.  The select
-#: phase was the kernel's bottleneck (device MFU 2.25%, VERDICT r3
-#: item 2): the same math as a lane reduction costs ~5x fewer VPU ops
-#: when the reduced axis is the sublane-group axis.
-BINNINGS = ("grouped", "lane")
-
 #: grid iteration orders.  "query_major" (default): grid =
 #: (q_blocks, db_tiles, dim_chunks) — every query block streams the
 #: FULL db through VMEM, so db HBM traffic scales with the query-block
@@ -269,9 +246,8 @@ BINNINGS = ("grouped", "lane")
 #: per query block — db traffic identical to query_major; the variant
 #: buys nothing there (gist/glove).  Candidate/bound
 #: outputs stay disjoint per (query block, db tile) cell in both orders
-#: — no output revisiting (the round-3 soundness lesson) either way.
-#: db_major is opt-in until the on-hardware gate + A/B pass on it
-#: (the same discipline the grouped select went through).
+#: — no output revisiting (module docstring) either way.
+#: db_major is opt-in until the on-hardware gate + A/B pass on it.
 GRID_ORDERS = ("query_major", "db_major")
 
 #: db-streaming strategies (module docstring).  "tiled" = the Pallas
@@ -296,8 +272,8 @@ GRID_ORDERS = ("query_major", "db_major")
 #: a skipped tile's scores exceed the (m+2)-th smallest EMITTED
 #: candidate e; if the merge dropped any of the emitted top-(m+2), that
 #: merge bin's bound is at most e, else the exclusion value is e —
-#: either way lb <= e, below every skipped score.  Grouped binning +
-#: query-major only, like streaming.
+#: either way lb <= e, below every skipped score.  Query-major only,
+#: like streaming.
 KERNELS = ("tiled", "streaming", "fused")
 
 #: early-out carry depth cap: the threshold needs ceil(min_keep / 128)
@@ -320,54 +296,32 @@ def kernel_launches_per_batch(kernel: str, rows: int, tile_n: int) -> int:
 
 
 def _geometry(
-    tile_n: int, bin_w: int = BIN_W, survivors: Optional[int] = None,
-    binning: str = "grouped",
+    tile_n: int, survivors: Optional[int] = None,
 ) -> Tuple[int, int, int, int]:
-    """(n_bins, survivors, out_w, bound_w) for a db tile.  Output blocks
-    are lane-aligned: ``out_w = round_up(n_bins * survivors, 128)`` lanes
-    of candidates per cell (padded with +inf/sentinel), ``bound_w`` lanes
-    of per-bin exclusion bounds.  ``survivors=None`` picks the largest
-    count that fits one 128-lane block in "lane" mode, and 2 (the
-    collision-rate sweet spot, module docstring) in "grouped" mode.
-
-    In "grouped" mode bins are the 128 lanes; ``bin_w`` does not shape
-    the binning (each bin has ``tile_n // 128`` members, strided 128
-    apart), but the tile must still be a multiple of 128."""
-    if binning not in BINNINGS:
-        raise ValueError(f"binning {binning!r} not in {BINNINGS}")
-    if tile_n % bin_w:
-        raise ValueError(f"tile_n={tile_n} must be a multiple of bin_w={bin_w}")
-    if bin_w % BIN_W:
-        raise ValueError(f"bin_w={bin_w} must be a multiple of {BIN_W} lanes")
-    if binning == "grouped":
-        n_bins = BIN_W  # one bin per lane
-        if survivors is None:
-            survivors = 2
-        survivors = min(survivors, MAX_SURVIVORS)
-        return n_bins, survivors, survivors * BIN_W, BIN_W
-    n_bins = tile_n // bin_w
+    """(n_bins, survivors, out_w, bound_w) for a db tile: the 128 lanes
+    are the bins (each has ``tile_n // 128`` members, strided 128
+    apart), so the tile must be a multiple of 128.  Output blocks are
+    lane-aligned: ``out_w = survivors * 128`` lanes of candidates per
+    cell, ``bound_w = 128`` lanes of per-bin exclusion bounds.
+    ``survivors=None`` picks 2 (the collision-rate sweet spot, module
+    docstring); the MAX_SURVIVORS cap applies to explicit requests too
+    (each survivor is an unrolled insertion step in the kernel trace)."""
+    if tile_n % BIN_W:
+        raise ValueError(
+            f"tile_n={tile_n} must be a multiple of {BIN_W} lanes")
     if survivors is None:
-        # floor at 2: a 1-survivor kernel loses the second of two true
-        # neighbors sharing a bin — at 1M rows that is ~47% of queries
-        # (module docstring), the round-2 constant-fallback failure.
-        # Multi-block outputs are supported, so exceeding one 128-lane
-        # block is fine.
-        survivors = min(max(2, 128 // n_bins), MAX_SURVIVORS, bin_w)
-    # the MAX_SURVIVORS cap applies to explicit requests too: each
-    # survivor is an unrolled min/argmin sweep in the kernel trace
-    survivors = min(survivors, MAX_SURVIVORS, bin_w)
-    return n_bins, survivors, _round_up(n_bins * survivors, 128), _round_up(
-        n_bins, 128)
+        survivors = 2
+    survivors = min(survivors, MAX_SURVIVORS)
+    return BIN_W, survivors, survivors * BIN_W, BIN_W
 
 
 def effective_tile(
-    rows: int, tile_n: int, bin_w: int, survivors: Optional[int],
-    binning: str, min_width: int,
+    rows: int, tile_n: int, survivors: Optional[int], min_width: int,
 ) -> int:
     """The db tile the kernel will actually run: capped to the (padded)
     db, then HALVED until the total candidate width ``n_tiles * out_w``
     covers ``min_width`` (= m+2 for certified callers) or the tile
-    bottoms out at ``bin_w``.  Mid-size databases would otherwise lose
+    bottoms out at ``BIN_W``.  Mid-size databases would otherwise lose
     candidate width to a large default tile (one 16384-tile over a 10k
     db emits 256 lanes where two 8192-tiles emitted 512) and raise the
     m+2-exceeds-width ValueError on margins that a smaller tile serves
@@ -376,20 +330,20 @@ def effective_tile(
     program, so local_certified_candidates' own call (min_width = m+2,
     guaranteed covered by setup's m-cap) is a fixpoint — the two can
     never run different tiles."""
-    if tile_n % bin_w:
+    if tile_n % BIN_W:
         # the caller's REQUESTED tile must be well-formed (the halving
         # below rounds its own internal steps, but never repairs an
         # invalid request silently)
         raise ValueError(
-            f"tile_n={tile_n} must be a multiple of bin_w={bin_w}")
-    eff = min(tile_n, max(bin_w, -(-rows // bin_w) * bin_w))
+            f"tile_n={tile_n} must be a multiple of {BIN_W} lanes")
+    eff = min(tile_n, max(BIN_W, -(-rows // BIN_W) * BIN_W))
 
     def width(t: int) -> int:
-        _, _, out_w, _ = _geometry(t, bin_w, survivors, binning)
+        _, _, out_w, _ = _geometry(t, survivors)
         return -(-rows // t) * out_w
 
-    while eff > bin_w and width(eff) < min_width:
-        eff = max(bin_w, -(-(eff // 2) // bin_w) * bin_w)
+    while eff > BIN_W and width(eff) < min_width:
+        eff = max(BIN_W, -(-(eff // 2) // BIN_W) * BIN_W)
     return eff
 
 
@@ -417,19 +371,6 @@ def select_merge_geometry(
     return groups, -(-(width // BIN_W) // groups), merged
 
 
-def _unpack_nibble_chunk(tb):
-    """Kernel-prologue unpack of one packed int4 db chunk block
-    ([T, 64] uint8 -> [T, 128] int8): the chunk-paired layout
-    (ops.quantize.pack_nibbles) puts dims [0, 64) of the 128-dim chunk
-    in the low nibbles and [64, 128) in the high nibbles of the SAME
-    bytes, so two vectorized mask/shift ops plus one lane-axis concat
-    reassemble the chunk in dim order — no element interleave, no
-    gather.  Biased +8 at pack time, un-biased here."""
-    lo = (tb & 0xF).astype(jnp.int8) - 8
-    hi = (tb >> 4).astype(jnp.int8) - 8
-    return jnp.concatenate([lo, hi], axis=1)
-
-
 def _pq_onehot_qt(lut, codes_u8, *, tile_n: int, pq_shape):
     """The PQ scoring dot shared by the tiled and streaming kernels —
     ONE arithmetic, which the bitwise contract across db-streaming
@@ -452,10 +393,8 @@ def _pq_onehot_qt(lut, codes_u8, *, tile_n: int, pq_shape):
                            preferred_element_type=jnp.float32)
 
 
-def _kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
-            survivors: int, out_w: int, bound_w: int, nd: int,
-            precision: str, binning: str, ti_axis: int = 1,
-            pq_shape=None):
+def _kernel(q_ref, *refs, tile_n: int, survivors: int, nd: int,
+            precision: str, ti_axis: int = 1, pq_shape=None):
     ti = pl.program_id(ti_axis)  # 1 = query_major grid, 0 = db_major
     di = pl.program_id(2)
     q = q_ref[:]
@@ -500,17 +439,6 @@ def _kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
         tn_ref = aux_ref
         qt = lax.dot_general(q, ti_ref[:], dn,
                              preferred_element_type=jnp.int32)
-    elif precision == "int4":
-        # the int8 path one rung down: the db chunk arrives PACKED
-        # ([T, 64] uint8, two 4-bit dims per byte) and unpacks here into
-        # int8 lanes; queries are the SAME int8 quantization as the int8
-        # arm, so the dot is the identical exact-int32 accumulation
-        # (|qi·ti| <= 127·7·d — overflow-free far past any real dim) and
-        # the one f32 rescale at select time is shared with int8
-        ti_ref, qsc_ref, aux_ref, d_ref, i_ref, b_ref, *scratch = refs
-        tn_ref = aux_ref
-        qt = lax.dot_general(q, _unpack_nibble_chunk(ti_ref[:]), dn,
-                             preferred_element_type=jnp.int32)
     elif precision == "pq":
         # product-quantization scoring: q_ref carries the per-query LUT
         # block (one block, nd == 1 always), the db operand is the byte
@@ -521,34 +449,25 @@ def _kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
         codes_ref, tn_ref, d_ref, i_ref, b_ref, *scratch = refs
         qt = _pq_onehot_qt(q, codes_ref[:], tile_n=tile_n,
                            pq_shape=pq_shape)
-    else:
+    else:  # "highest"
         t_ref, tn_ref, d_ref, i_ref, b_ref, *scratch = refs
-        prec = (lax.Precision.HIGHEST if precision == "highest"
-                else lax.Precision.DEFAULT)
         qt = lax.dot_general(q, t_ref[:], dn,
                              preferred_element_type=jnp.float32,
-                             precision=prec)  # [BQ, T]
+                             precision=lax.Precision.HIGHEST)  # [BQ, T]
     # db row norms arrive precomputed ([8, T] broadcast, row 0 used): an
     # XLA f32 reduction once per call instead of a per-cell ones-matmul
     # (which cost ~12% of the qt matmul as a 6-pass f32 HIGHEST dot)
-    emit = _emit_select_grouped if binning == "grouped" else _emit_select
 
     def write(qt_acc):
-        if precision in ("int8", "int4"):
+        if precision == "int8":
             # the one rescale: full int32 dot -> f32 (rounded for
             # d > 1040, covered by the bound's f32 slack), times the
-            # per-query [BQ, 1] and per-row [1, T] scales.  int8's aux
-            # stacks 8 norm rows over 8 scale rows (scales at row 8);
-            # int4 packs norms (row 0) + scales (row 1) into ONE 8-row
-            # block — half the aux stream, which is what lets its db
-            # side hit the 2x-under-int8 byte budget the roofline pins
-            scale_row = 8 if precision == "int8" else 1
+            # per-query [BQ, 1] and per-row [1, T] scales.  The aux
+            # stacks 8 norm rows over 8 scale rows (scales at row 8)
             qt_acc = ((qt_acc.astype(jnp.float32) * qsc_ref[:, 0:1])
-                      * aux_ref[scale_row:scale_row + 1, :])
-        cd, ci, bound = emit(
-            ti, qt_acc, tn_ref[:], tile_n=tile_n, bin_w=bin_w,
-            n_bins=n_bins, survivors=survivors, out_w=out_w,
-            bound_w=bound_w)
+                      * aux_ref[8:9, :])
+        cd, ci, bound = _emit_select_grouped(
+            ti, qt_acc, tn_ref[:], tile_n=tile_n, survivors=survivors)
         d_ref[:] = cd
         i_ref[:] = ci
         b_ref[:] = bound
@@ -574,77 +493,35 @@ def _kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
         write(qt_ref[:])
 
 
-def _emit_select(ti, qt, tn, *,
-                 tile_n: int, bin_w: int, n_bins: int, survivors: int,
-                 out_w: int, bound_w: int):
-    """Binning + survivor/bound selection from an accumulated score
-    tile: returns ``(cand_d, cand_i, bounds)`` arrays for the caller to
-    write (the tiled kernel stores them to its per-cell output blocks;
-    the streaming kernel stores them at the tile's dynamic column
-    offset) — ONE emitter per binning serves both db-streaming
-    strategies, which is what makes them bitwise-identical.  ``ti`` is
-    the db-tile index, hoisted by the caller because ``pl.program_id``
-    is unavailable inside a ``pl.when`` branch in interpret mode."""
-    s = tn[0:1, :] - 2.0 * qt  # [BQ, T], ||q||^2 dropped
-    bq = s.shape[0]
-    d3 = s.reshape(bq, n_bins, bin_w)
-    lane = lax.broadcasted_iota(jnp.int32, d3.shape, 2)
-    base = (ti * tile_n
-            + lax.broadcasted_iota(jnp.int32, (bq, n_bins), 1) * bin_w)
-    ds, is_ = [], []
-    work = d3
-    for _ in range(survivors):
-        mj = jnp.min(work, axis=-1)  # [BQ, n_bins]
-        aj = jnp.argmin(work, axis=-1).astype(jnp.int32)
-        ds.append(mj)
-        is_.append(jnp.where(jnp.isfinite(mj), base + aj, _I32MAX))
-        work = jnp.where(lane == aj[:, :, None], jnp.inf, work)
-    bound = jnp.min(work, axis=-1)  # (survivors+1)-th smallest per bin
-    cd = jnp.concatenate(ds, axis=-1)
-    ci = jnp.concatenate(is_, axis=-1)
-    pad = out_w - survivors * n_bins
-    if pad:
-        cd = jnp.concatenate(
-            [cd, jnp.full((bq, pad), jnp.inf, jnp.float32)], axis=-1)
-        ci = jnp.concatenate(
-            [ci, jnp.full((bq, pad), _I32MAX, jnp.int32)], axis=-1)
-    bpad = bound_w - n_bins
-    if bpad:
-        bound = jnp.concatenate(
-            [bound, jnp.full((bq, bpad), jnp.inf, jnp.float32)], axis=-1)
-    # every (qi, ti) cell owns its own disjoint bounds block; the min
-    # over tiles happens in XLA after the kernel.  (The previous design
-    # min-accumulated in-place across db tiles via output revisiting —
-    # the mechanism under suspicion in the round-3 compiled-soundness
-    # gate failure, and ~0.3 ms of HBM writes buys not depending on it.)
-    return cd, ci, bound
+def _emit_select_grouped(ti, qt, tn, *, tile_n: int, survivors: int):
+    """Survivor/bound emission from an accumulated score tile: returns
+    ``(cand_d, cand_i, bounds)`` for the caller to write (the tiled
+    kernel stores them to its per-cell output blocks; the streaming
+    kernel stores them at the tile's dynamic column offset) — ONE
+    emitter serves every db-streaming strategy, which is what makes
+    them bitwise-identical.  ``ti`` is the db-tile index, hoisted by the
+    caller because ``pl.program_id`` is unavailable inside a ``pl.when``
+    branch in interpret mode.
 
+    Bin b = lane b of every 128-wide column group, so the per-bin
+    reduction runs over the GROUP axis — a chain of elementwise vector
+    min/compare/select over [BQ, 128] vregs, zero cross-lane shuffles.
+    One fused pass maintains the running (survivors+1) smallest values
+    per lane (a sorted insertion network) plus the group index of each
+    survivor; the (survivors+1)-th value is the bin's exclusion bound.
 
-def _emit_select_grouped(ti, qt, tn, *,
-                         tile_n: int, bin_w: int, n_bins: int,
-                         survivors: int, out_w: int, bound_w: int):
-    """Lane-binned survivor/bound emission: bin b = lane b of every
-    128-wide column group, so the per-bin reduction runs over the GROUP
-    axis — a chain of elementwise vector min/compare/select over
-    [BQ, 128] vregs, zero cross-lane shuffles.  One fused pass maintains
-    the running (survivors+1) smallest values per lane (a sorted
-    insertion network) plus the group index of each survivor; the
-    (survivors+1)-th value is the bin's exclusion bound.
-
-    Same soundness contract as ``_emit_select``: every tile row not
-    emitted as a candidate scores >= its bin's bound (rows other than a
-    bin's ``survivors`` smallest score >= the (survivors+1)-th
-    smallest).  ``bin_w`` is unused (bins are lanes); kept for signature
-    parity with the lane-mode emitter."""
-    del bin_w, n_bins  # grouped mode: 128 bins of tile_n // 128 members
+    Soundness contract: every tile row not emitted as a candidate scores
+    >= its bin's bound (rows other than a bin's ``survivors`` smallest
+    score >= the (survivors+1)-th smallest).  Every (qi, ti) cell owns
+    its own disjoint bounds block; the min over tiles happens in XLA
+    after the kernel (module docstring)."""
     s = tn[0:1, :] - 2.0 * qt  # [BQ, T], ||q||^2 dropped
     return _emit_select_grouped_scores(
-        ti, s, tile_n=tile_n, survivors=survivors, out_w=out_w,
-        bound_w=bound_w)
+        ti, s, tile_n=tile_n, survivors=survivors)
 
 
 def _emit_select_grouped_scores(ti, s, *, tile_n: int, survivors: int,
-                                out_w: int, bound_w: int, payload=None):
+                                payload=None):
     """The grouped emitter on a PRECOMPUTED score tile ``s`` — split out
     so the fused kernel (which needs ``s`` for its early-out predicate
     before deciding whether to run the select at all) shares the EXACT
@@ -658,8 +535,7 @@ def _emit_select_grouped_scores(ti, s, *, tile_n: int, survivors: int,
     or, where ``s`` is itself an array of candidates with their indices
     in ``payload`` (same shape, int32, sentinel where +inf: the final
     select's bin-merge, ``_select_merge``), the payload riding with
-    it."""
-    del bound_w  # grouped bounds are one [BQ, 128] block
+    it.  Returns ``(cd, ci [BQ, survivors * 128], bound [BQ, 128])``."""
     bq = s.shape[0]
     n_groups = tile_n // BIN_W
     lane = lax.broadcasted_iota(jnp.int32, (bq, BIN_W), 1)
@@ -668,18 +544,23 @@ def _emit_select_grouped_scores(ti, s, *, tile_n: int, survivors: int,
                     jnp.int32)
     vals = [inf] * (survivors + 1)  # running sorted smallest per lane
     gidx = [none] * survivors       # group index of each survivor
+    # lax primitives, not their jnp twins, inside the unrolled loop: it
+    # binds ~1,500 ops a tile, and every jnp call re-enters jit's Python
+    # machinery (a nested trace some 15 frames deep) to emit the same op
+    # — seconds of each process's first call (root PERF.md, PR 29)
     for g in range(n_groups):
-        cur_v = s[:, g * BIN_W : (g + 1) * BIN_W]
-        cur_g = (jnp.full((bq, BIN_W), g, jnp.int32) if payload is None
-                 else payload[:, g * BIN_W : (g + 1) * BIN_W])
+        cur_v = lax.slice_in_dim(s, g * BIN_W, (g + 1) * BIN_W, axis=1)
+        cur_g = (lax.full((bq, BIN_W), g, jnp.int32) if payload is None
+                 else lax.slice_in_dim(payload, g * BIN_W, (g + 1) * BIN_W,
+                                       axis=1))
         for j in range(survivors):
-            less = cur_v < vals[j]
-            disp_v = jnp.maximum(cur_v, vals[j])
-            disp_g = jnp.where(less, gidx[j], cur_g)
-            vals[j] = jnp.minimum(cur_v, vals[j])
-            gidx[j] = jnp.where(less, cur_g, gidx[j])
+            less = lax.lt(cur_v, vals[j])
+            disp_v = lax.max(cur_v, vals[j])
+            disp_g = lax.select(less, gidx[j], cur_g)
+            vals[j] = lax.min(cur_v, vals[j])
+            gidx[j] = lax.select(less, cur_g, gidx[j])
             cur_v, cur_g = disp_v, disp_g
-        vals[survivors] = jnp.minimum(vals[survivors], cur_v)
+        vals[survivors] = lax.min(vals[survivors], cur_v)
     ds, is_ = [], []
     for j in range(survivors):
         ds.append(vals[j])
@@ -688,16 +569,16 @@ def _emit_select_grouped_scores(ti, s, *, tile_n: int, survivors: int,
         is_.append(gidx[j] if payload is not None else jnp.where(
             jnp.isfinite(vals[j]),
             ti * tile_n + gidx[j] * BIN_W + lane, _I32MAX))
-    cd = jnp.concatenate(ds, axis=-1)   # [BQ, survivors * 128] = out_w
+    cd = jnp.concatenate(ds, axis=-1)   # [BQ, survivors * 128]
     ci = jnp.concatenate(is_, axis=-1)
-    return cd, ci, vals[survivors]      # bound: [BQ, 128] = bound_w
+    return cd, ci, vals[survivors]      # bound: [BQ, 128]
 
 
-def _stream_kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
-                   survivors: int, out_w: int, bound_w: int, n_tiles: int,
-                   nd: int, precision: str, binning: str, n_parts: int,
-                   chunk_w: int, aux_rows: int = 8, fused: bool = False,
-                   keep: Optional[int] = None, pq_shape=None):
+def _stream_kernel(q_ref, *refs, tile_n: int, survivors: int, out_w: int,
+                   bound_w: int, n_tiles: int, nd: int, precision: str,
+                   n_parts: int, chunk_w: int, aux_rows: int = 8,
+                   fused: bool = False, keep: Optional[int] = None,
+                   pq_shape=None):
     """One launch per (batch, shard): the db-side arrays stay in HBM and
     stream tile-by-tile through TWO VMEM scratch slots via explicit
     async copies — tile i+1's HBM->VMEM copy overlaps tile i's MXU
@@ -720,7 +601,7 @@ def _stream_kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
       sem                           DMA semaphores (2, n_parts + 1)
     """
     qsc_ref = None
-    if precision in ("int8", "int4"):
+    if precision == "int8":
         qsc_ref, refs = refs[0], refs[1:]
     parts_hbm = refs[:n_parts]
     tn_hbm = refs[n_parts]
@@ -730,7 +611,6 @@ def _stream_kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
     sem = refs[2 * n_parts + 5]
     q = q_ref[:]
     dn = (((1,), (1,)), ((), ()))
-    emit = _emit_select_grouped if binning == "grouped" else _emit_select
 
     def part_dma(j, ti, c, slot):
         return pltpu.make_async_copy(
@@ -770,10 +650,6 @@ def _stream_kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
             t, = bufs
             return lax.dot_general(qc, t, dn,
                                    preferred_element_type=jnp.int32)
-        if precision == "int4":
-            t, = bufs  # [tile_n, 64] packed uint8 chunk
-            return lax.dot_general(qc, _unpack_nibble_chunk(t), dn,
-                                   preferred_element_type=jnp.int32)
         if precision == "bf16x3":
             th, tl = bufs
             qh = qc.astype(jnp.bfloat16)
@@ -791,12 +667,10 @@ def _stream_kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
             q3 = jnp.concatenate([qh, qh, ql], axis=1)
             return lax.dot_general(q3, t3, dn,
                                    preferred_element_type=jnp.float32)
-        t, = bufs
-        prec = (lax.Precision.HIGHEST if precision == "highest"
-                else lax.Precision.DEFAULT)
+        t, = bufs  # "highest"
         return lax.dot_general(qc, t, dn,
                                preferred_element_type=jnp.float32,
-                               precision=prec)
+                               precision=lax.Precision.HIGHEST)
 
     # warm-up: tile 0's first chunk + row norms start before the loop
     start_parts(0, 0, 0)
@@ -833,28 +707,23 @@ def _stream_kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
             # (int8: exact int32 adds — order-independent by construction)
             qt = qt_c if qt is None else qt + qt_c
         tn_dma(ti, ti % 2).wait()
-        if precision in ("int8", "int4"):
+        if precision == "int8":
             # the one f32 rescale, same op sequence as the tiled
-            # write() — including the per-precision scale row (int8:
-            # row 8 of the 16-row stacked aux; int4: row 1 of its
-            # packed 8-row aux)
-            scale_row = 8 if precision == "int8" else 1
+            # write() (scales at row 8 of the 16-row stacked aux)
             qt = ((qt.astype(jnp.float32) * qsc_ref[:, 0:1])
-                  * tn_buf[ti % 2][scale_row:scale_row + 1, :])
+                  * tn_buf[ti % 2][8:9, :])
         off = pl.multiple_of(ti * out_w, out_w)
         boff = pl.multiple_of(ti * bound_w, bound_w)
         if not armed:
-            cd, ci, bound = emit(
-                ti, qt, tn_buf[ti % 2], tile_n=tile_n, bin_w=bin_w,
-                n_bins=n_bins, survivors=survivors, out_w=out_w,
-                bound_w=bound_w)
+            cd, ci, bound = _emit_select_grouped(
+                ti, qt, tn_buf[ti % 2], tile_n=tile_n, survivors=survivors)
             d_ref[:, pl.ds(off, out_w)] = cd
             i_ref[:, pl.ds(off, out_w)] = ci
             b_ref[:, pl.ds(boff, bound_w)] = bound
             return carry
 
-        # ---- fused early-out path (grouped binning only) --------------
-        # the SAME score expression the grouped emitter computes — the
+        # ---- fused early-out path --------------------------------------
+        # the SAME score expression the emitter computes — the
         # bitwise contract of the non-skipped tiles rests on this
         s = tn_buf[ti % 2][0:1, :] - 2.0 * qt  # [BQ, T]
         n_groups = tile_n // BIN_W
@@ -875,8 +744,7 @@ def _stream_kernel(q_ref, *refs, tile_n: int, bin_w: int, n_bins: int,
         @pl.when(jnp.logical_not(skip))
         def _select():
             cd, ci, bound = _emit_select_grouped_scores(
-                ti, s, tile_n=tile_n, survivors=survivors, out_w=out_w,
-                bound_w=bound_w)
+                ti, s, tile_n=tile_n, survivors=survivors)
             d_ref[:, pl.ds(off, out_w)] = cd
             i_ref[:, pl.ds(off, out_w)] = ci
             b_ref[:, pl.ds(boff, bound_w)] = bound
@@ -922,12 +790,11 @@ def default_backend_is_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _vmem_limit_bytes(kernel: str, precision: str, binning: str,
-                      **geometry) -> int:
+def _vmem_limit_bytes(kernel: str, precision: str, **geometry) -> int:
     """The scoped-VMEM limit a compiled launch requests
     (knn_tpu.analysis.vmem — the ONE home of the arithmetic and of the
     rule).  Where the model is calibrated against what Mosaic reports
-    (bf16x3, grouped binning) the request is the modeled footprint of
+    (bf16x3) the request is the modeled footprint of
     this geometry plus its error, and a geometry that cannot fit the
     device is refused HERE, naming the knobs to change, instead of by
     Mosaic's allocator dump.  Every other arm asks for the device's
@@ -938,7 +805,7 @@ def _vmem_limit_bytes(kernel: str, precision: str, binning: str,
     kind = (jax.devices()[0].device_kind if default_backend_is_tpu()
             else vmem.TARGET_DEVICE_KIND)
     budget = vmem.budget_for(kind)
-    if not vmem.calibrated(precision, binning):
+    if not vmem.calibrated(precision):
         return budget
     need = sum(vmem.kernel_bytes(kernel=kernel, **geometry).values())
     if need > budget:
@@ -960,9 +827,9 @@ def _pad_axis(x, multiple: int, axis: int, fill: float = 0.0):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_q", "tile_n", "bin_w", "survivors",
-                              "precision", "interpret", "binning",
-                              "grid_order", "kernel", "offset", "keep")
+    jax.jit, static_argnames=("block_q", "tile_n", "survivors",
+                              "precision", "interpret", "grid_order",
+                              "kernel", "offset", "keep")
 )
 def _bin_candidates(
     queries: jax.Array,
@@ -970,17 +837,14 @@ def _bin_candidates(
     *,
     block_q: int,
     tile_n: int,
-    bin_w: int,
     survivors: Optional[int],
     precision: str,
     interpret: bool,
-    binning: str = "grouped",
     grid_order: str = "query_major",
     kernel: str = "tiled",
     db_int8: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     offset: float = 0.0,
     keep: Optional[int] = None,
-    db_int4: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Kernel launch on padded shapes.  Returns
@@ -1006,13 +870,6 @@ def _bin_candidates(
     for the coarse pass.  ``offset`` is the translation-invariance shift
     both sides subtract before quantizing (128.0 for bvecs payloads).
 
-    ``precision="int4"`` mirrors the int8 contract one byte-width rung
-    down: ``db_int4=(packed uint8 [N, ceil(D, 128)/2], scales f32 [N],
-    row_norms f32 [N])`` streams nibble-packed rows unpacked in the
-    kernel prologue (``db_int4=None`` quantizes + packs here).  Queries
-    stay int8 — their bytes are negligible and halving them would only
-    widen the certificate's query-residual terms.
-
     ``precision="pq"`` REQUIRES ``db_pq=(codes uint8 [N, m], codebooks
     f32 [m, C, dsub])`` (codebooks train on data — ops.pq.train_pq;
     there is no quantize-on-the-fly arm).  The query operand becomes
@@ -1028,8 +885,7 @@ def _bin_candidates(
     qp, dim = queries.shape
     n_tiles = db.shape[0] // tile_n
     nd = dim // DIM_CHUNK
-    n_bins, survivors, out_w, bound_w = _geometry(
-        tile_n, bin_w, survivors, binning)
+    _, survivors, out_w, bound_w = _geometry(tile_n, survivors)
 
     if precision not in PRECISIONS:
         raise ValueError(f"precision {precision!r} not in {PRECISIONS}")
@@ -1045,13 +901,6 @@ def _bin_candidates(
         raise ValueError(
             f"kernel={kernel!r} streams the db inside one launch; "
             f"grid_order='db_major' does not apply")
-    if kernel == "fused" and binning != "grouped":
-        # the early-out carry is a per-LANE order-statistic network —
-        # it has no lane-binning analogue (the lane select's cross-lane
-        # shuffles are what grouped exists to avoid in the first place)
-        raise ValueError(
-            "kernel='fused' requires binning='grouped' (the early-out "
-            "carry is per-lane)")
     if kernel == "fused" and precision == "pq":
         # the fused early-out's bitwise argument (a skipped tile's
         # scores all strictly exceed an upper bound on the final
@@ -1117,42 +966,6 @@ def _bin_candidates(
         # 0-7 tn broadcast, 8-15 scales broadcast) so BOTH stream through
         # the one lane-major aux slot the f32 path already has
         aux_rows = 16
-    elif precision == "int4":
-        from knn_tpu.ops.quantize import (pack_nibbles_t, quantize_rows,
-                                          quantize_rows_int4)
-
-        # queries: the SAME int8 quantization as the int8 arm (the
-        # certificate's query residual terms are computed against it)
-        qi, qsc = quantize_rows(queries - offset)
-        queries_in = qi
-        q_extra = [jnp.broadcast_to(qsc[:, None], (qp, BIN_W))]
-        if db_int4 is None:
-            db_sh = db - offset
-            tq, ts = quantize_rows_int4(db_sh)
-            tp = pack_nibbles_t(tq)
-            tn_rows = jnp.sum(db_sh * db_sh, axis=-1)
-        else:
-            tp, ts, tn_rows = db_int4
-            # same pre-quantized padding contract as int8: zero packed
-            # bytes at zero scale dequantize harmlessly, PAD_VAL norms
-            # keep pads out of every bin
-            tp = _pad_axis(tp, tile_n, 0)
-            tp = _pad_axis(tp, DIM_CHUNK // 2, 1)
-            ts = _pad_axis(ts[:, None], tile_n, 0)[:, 0]
-            tn_rows = _pad_axis(tn_rows[:, None], tile_n, 0,
-                                fill=PAD_VAL)[:, 0]
-        db_inputs = [tp]
-        # the packed chunk is HALF a dim chunk of bytes: the layout
-        # pairs dims c*128+j / c*128+64+j in one byte, so chunk c of
-        # the feature axis is exactly packed columns [c*64, (c+1)*64)
-        chunk_w = DIM_CHUNK // 2
-        # unlike int8 (16 rows: norms broadcast over scales broadcast),
-        # int4 packs norms at row 0 and scales at row 1 of the DEFAULT
-        # 8-row aux block: the kernel reads exactly one row of each, so
-        # the broadcast buys nothing and the packed layout halves the
-        # aux stream — without it the [16, N] aux would weigh as much
-        # as the nibble-packed values themselves at d=128
-        aux_rows = 8
     elif precision == "pq":
         if db_pq is None:
             raise ValueError(
@@ -1198,14 +1011,6 @@ def _bin_candidates(
             jnp.broadcast_to(ts[None, :].astype(jnp.float32),
                              (8, db.shape[0])),
         ], axis=0)
-    elif precision == "int4":
-        # norms row 0, scales row 1, zero fill rows 2-7: one 8-row aux
-        # block instead of int8's 16 (the kernel reads one row of each)
-        tnorm = jnp.concatenate([
-            tn_rows[None, :],
-            ts[None, :].astype(jnp.float32),
-            jnp.zeros((6, db.shape[0]), jnp.float32),
-        ], axis=0)
     elif precision == "pq":
         # pad-fill carrier only: 0 on valid rows (the LUT carries the
         # reconstruction norm term), PAD_VAL on tile padding
@@ -1227,20 +1032,18 @@ def _bin_candidates(
         return _stream_call(
             queries_in, db_inputs, tnorm, out_shape, qp=qp,
             dim=queries_in.shape[1],
-            block_q=block_q, tile_n=tile_n, bin_w=bin_w, n_bins=n_bins,
-            survivors=survivors, out_w=out_w, bound_w=bound_w,
-            n_tiles=n_tiles, nd=nd, precision=precision, binning=binning,
-            chunk_w=chunk_w, interpret=interpret,
+            block_q=block_q, tile_n=tile_n, survivors=survivors,
+            out_w=out_w, bound_w=bound_w, n_tiles=n_tiles, nd=nd,
+            precision=precision, chunk_w=chunk_w, interpret=interpret,
             q_extra=q_extra, aux_rows=aux_rows,
             fused=kernel == "fused", keep=keep, pq_shape=pq_shape,
         )
 
     db_major = grid_order == "db_major"
     body = functools.partial(
-        _kernel, tile_n=tile_n, bin_w=bin_w, n_bins=n_bins,
-        survivors=survivors, out_w=out_w, bound_w=bound_w, nd=nd,
-        precision=precision, binning=binning,
-        ti_axis=0 if db_major else 1, pq_shape=pq_shape,
+        _kernel, tile_n=tile_n, survivors=survivors, nd=nd,
+        precision=precision, ti_axis=0 if db_major else 1,
+        pq_shape=pq_shape,
     )
     # the query operand block: one DIM_CHUNK slice per grid step for the
     # feature-chunked arms; PQ's LUT has no chunk loop (nd == 1) and
@@ -1267,7 +1070,7 @@ def _bin_candidates(
                 ("arbitrary", "arbitrary", "arbitrary") if db_major
                 else ("parallel", "arbitrary", "arbitrary")),
             vmem_limit_bytes=_vmem_limit_bytes(
-                "tiled", precision, binning,
+                "tiled", precision,
                 block_q=block_q, tile_n=tile_n, n_tiles=n_tiles,
                 nd=nd, out_w=out_w, bound_w=bound_w,
                 db_block=sum(tile_n * chunk_w * x.dtype.itemsize
@@ -1304,8 +1107,7 @@ def _bin_candidates(
         # the f32 paths accumulate the scaled f32 score
         scratch_shapes=[] if nd == 1 else [
             pltpu.VMEM((block_q, tile_n),
-                       jnp.int32 if precision in ("int8", "int4")
-                       else jnp.float32),
+                       jnp.int32 if precision == "int8" else jnp.float32),
         ],
         interpret=interpret,
         **kwargs,
@@ -1313,10 +1115,9 @@ def _bin_candidates(
 
 
 def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
-                 tile_n, bin_w, n_bins, survivors, out_w, bound_w, n_tiles,
-                 nd, precision, binning, chunk_w, interpret,
-                 q_extra=(), aux_rows=8, fused=False, keep=None,
-                 pq_shape=None):
+                 tile_n, survivors, out_w, bound_w, n_tiles, nd, precision,
+                 chunk_w, interpret, q_extra=(), aux_rows=8, fused=False,
+                 keep=None, pq_shape=None):
     """The streaming ``pallas_call``: grid over query blocks only, db
     parts + row norms left in compiler-chosen (HBM) memory and streamed
     by the kernel's own double-buffered DMA loop (``_stream_kernel``).
@@ -1327,9 +1128,8 @@ def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
     sizes its carry (the final select's m+2)."""
     n_parts = len(db_inputs)
     body = functools.partial(
-        _stream_kernel, tile_n=tile_n, bin_w=bin_w, n_bins=n_bins,
-        survivors=survivors, out_w=out_w, bound_w=bound_w,
-        n_tiles=n_tiles, nd=nd, precision=precision, binning=binning,
+        _stream_kernel, tile_n=tile_n, survivors=survivors, out_w=out_w,
+        bound_w=bound_w, n_tiles=n_tiles, nd=nd, precision=precision,
         n_parts=n_parts, chunk_w=chunk_w, aux_rows=aux_rows,
         fused=fused, keep=keep, pq_shape=pq_shape,
     )
@@ -1340,7 +1140,7 @@ def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_vmem_limit_bytes(
-                "fused" if fused else "streaming", precision, binning,
+                "fused" if fused else "streaming", precision,
                 block_q=block_q, tile_n=tile_n, n_tiles=n_tiles, nd=nd,
                 out_w=out_w, bound_w=bound_w,
                 db_block=n_parts * tile_n * chunk_w * part_dtype.itemsize,
@@ -1378,8 +1178,8 @@ def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("m", "tile_n", "block_q", "bin_w", "survivors",
-                     "precision", "final_select", "interpret", "binning",
+    static_argnames=("m", "tile_n", "block_q", "survivors",
+                     "precision", "final_select", "interpret",
                      "final_recall_target", "grid_order", "kernel",
                      "offset"),
 )
@@ -1390,18 +1190,15 @@ def local_certified_candidates(
     *,
     tile_n: int = TILE_N,
     block_q: int = BLOCK_Q,
-    bin_w: int = BIN_W,
     survivors: Optional[int] = None,
     precision: str = "bf16x3",
     final_select: str = "exact",
     interpret: Optional[bool] = None,
-    binning: str = "grouped",
     final_recall_target: Optional[float] = None,
     grid_order: str = "query_major",
     kernel: str = "tiled",
     db_int8: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     offset: float = 0.0,
-    db_int4: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The whole device-side certified coarse pass against one db (shard):
@@ -1438,20 +1235,18 @@ def local_certified_candidates(
     ``_bin_candidates``); the stage-3 rescore ALWAYS gathers the f32
     ``t`` rows, so the returned d32 values and the near-tie analysis are
     precision-independent — the quantization only steers which
-    candidates surface, never what their distances read.  The "int4"
-    and "pq" arms follow the same contract (``db_int4`` / ``db_pq``
-    plug their placements in); the rescore's precision-independence is
-    what makes ALL quantized arms bitwise-equal to the exact reference
+    candidates surface, never what their distances read.  The "pq"
+    arm follows the same contract (``db_pq`` plugs its placement in);
+    the rescore's precision-independence is what makes ALL quantized arms bitwise-equal to the exact reference
     whenever their candidates cover the true top-k — and certified
     fallback material otherwise."""
     if interpret is None:
         interpret = not default_backend_is_tpu()
     cd, ci, bounds = local_coarse_candidates(
-        q, t, m, tile_n=tile_n, block_q=block_q, bin_w=bin_w,
-        survivors=survivors, precision=precision, interpret=interpret,
-        binning=binning, final_select=final_select,
-        grid_order=grid_order, kernel=kernel, db_int8=db_int8,
-        offset=offset, db_int4=db_int4, db_pq=db_pq,
+        q, t, m, tile_n=tile_n, block_q=block_q, survivors=survivors,
+        precision=precision, interpret=interpret,
+        final_select=final_select, grid_order=grid_order, kernel=kernel,
+        db_int8=db_int8, offset=offset, db_pq=db_pq,
     )
     return local_select_rescore(
         q, t, cd, ci, bounds, m, final_select=final_select,
@@ -1461,8 +1256,8 @@ def local_certified_candidates(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("m", "tile_n", "block_q", "bin_w", "survivors",
-                     "precision", "interpret", "binning", "final_select",
+    static_argnames=("m", "tile_n", "block_q", "survivors",
+                     "precision", "interpret", "final_select",
                      "grid_order", "kernel", "offset"),
 )
 def local_coarse_candidates(
@@ -1472,28 +1267,21 @@ def local_coarse_candidates(
     *,
     tile_n: int = TILE_N,
     block_q: int = BLOCK_Q,
-    bin_w: int = BIN_W,
     survivors: Optional[int] = None,
     precision: str = "bf16x3",
     interpret: Optional[bool] = None,
-    binning: str = "grouped",
     grid_order: str = "query_major",
     kernel: str = "tiled",
     db_int8: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     offset: float = 0.0,
     final_select: str = "exact",
-    db_int4: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Stage 1 of :func:`local_certified_candidates` — the db-streaming
     coarse pass alone: resolve the effective tile, launch the kernel,
     trim the query padding.  Returns the packed candidates
-    ``(cd [Q, W], ci [Q, W], bounds [Q, T*B])`` at the boundary the
-    pipeline-overlap path splits the certified program on
-    (parallel.sharded._pallas_coarse_program): stage 2
-    (:func:`local_select_rescore`) is everything after the kernel, so
-    running the two stages back to back IS the one-shot function —
-    bitwise, by construction."""
+    ``(cd [Q, W], ci [Q, W], bounds [Q, T*B])``; stage 2
+    (:func:`local_select_rescore`) is everything after the kernel."""
     if interpret is None:
         interpret = not default_backend_is_tpu()
     if final_select not in ("exact", "approx"):
@@ -1509,16 +1297,15 @@ def local_coarse_candidates(
         raise ValueError(
             "kernel='fused' requires final_select='exact' (the "
             "early-out's bitwise contract is an exact-boundary argument)")
-    eff_tile = effective_tile(t.shape[0], tile_n, bin_w, survivors,
-                              binning, m + 2)
+    eff_tile = effective_tile(t.shape[0], tile_n, survivors, m + 2)
     with jax.named_scope(SCOPE_KERNEL):
         cd, ci, bounds = _bin_candidates(
             q, t, block_q=min(block_q, max(8, q.shape[0])),
-            tile_n=eff_tile, bin_w=bin_w, survivors=survivors,
-            precision=precision, interpret=interpret, binning=binning,
+            tile_n=eff_tile, survivors=survivors,
+            precision=precision, interpret=interpret,
             grid_order=grid_order, kernel=kernel, db_int8=db_int8,
             offset=offset, keep=m + 2 if kernel == "fused" else None,
-            db_int4=db_int4, db_pq=db_pq,
+            db_pq=db_pq,
         )
     n_q = q.shape[0]
     return cd[:n_q], ci[:n_q], bounds[:n_q]
@@ -1530,9 +1317,7 @@ def _select_merge_kernel(cd_ref, ci_ref, v_ref, i_ref, b_ref, *, rows: int):
     precomputed score tile, each candidate's row index riding with it."""
     v, i, b = _emit_select_grouped_scores(
         None, cd_ref[...], tile_n=rows * BIN_W,
-        survivors=SELECT_MERGE_SURVIVORS,
-        out_w=SELECT_MERGE_SURVIVORS * BIN_W, bound_w=BIN_W,
-        payload=ci_ref[...])
+        survivors=SELECT_MERGE_SURVIVORS, payload=ci_ref[...])
     v_ref[...] = v
     i_ref[...] = i
     b_ref[...] = b
@@ -1602,10 +1387,7 @@ def local_select_rescore(
     select over the packed candidates (over their bin-merge's survivors
     where ``select_merge_geometry`` engages, every merge bin's bound
     joining ``lb``), exclusion-value restoration, the
-    direct-difference f32 rescore gather, and lexicographic ordering —
-    the rescore/certify tail the pipeline-overlap path runs as its own
-    device program while the NEXT batch's coarse pass streams the
-    database."""
+    direct-difference f32 rescore gather, and lexicographic ordering."""
     n_q = q.shape[0]
     w = cd.shape[1]
     if m + 2 > w:
@@ -1742,12 +1524,11 @@ def kernel_tolerance(
     base = 4.0 * certification_tolerance(
         queries_np, db_np, db_norm_max=db_norm_max, q_norm=q_norm
     )
-    if precision in ("int8", "int4"):
+    if precision == "int8":
         from knn_tpu.ops import quantize as qz
 
         if quant is None:
-            quant = (qz.quantize_rows_np(db_np) if precision == "int8"
-                     else qz.quantize_rows_int4_np(db_np))
+            quant = qz.quantize_rows_np(db_np)
         stats = qz.db_bound_stats(quant, db_np)
         return np.maximum(
             base,
@@ -1768,7 +1549,7 @@ def kernel_tolerance(
         return base
     raise ValueError(
         f"precision {precision!r} has no certified tolerance model; "
-        f"use 'bf16x3', 'bf16x3f', 'int8', 'int4', 'pq', or 'highest'"
+        f"use one of {PRECISIONS}"
     )
 
 
@@ -1780,11 +1561,9 @@ def knn_search_pallas(
     margin: int = 28,
     tile_n: int = TILE_N,
     precision: str = "bf16x3",
-    bin_w: Optional[int] = None,
     survivors: Optional[int] = None,
     block_q: Optional[int] = None,
     final_select: str = "exact",
-    binning: str = "grouped",
     final_recall_target: Optional[float] = None,
     grid_order: str = "query_major",
     kernel: str = "tiled",
@@ -1818,9 +1597,8 @@ def knn_search_pallas(
     return prog.search_certified(
         np.asarray(queries, dtype=np.float32), margin=margin,
         selector="pallas", tile_n=tile_n, precision=precision,
-        bin_w=bin_w, survivors=survivors, block_q=block_q,
-        final_select=final_select,
-        binning=binning, final_recall_target=final_recall_target,
+        survivors=survivors, block_q=block_q, final_select=final_select,
+        final_recall_target=final_recall_target,
         grid_order=grid_order, kernel=kernel,
     )
 

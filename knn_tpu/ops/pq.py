@@ -1,13 +1,14 @@
 """Product quantization with a *certified* per-subspace error bound —
 the arithmetic behind the kernel's ``precision="pq"`` arm.
 
-Below int4 the per-dim ladder runs out: a 2-bit row is 16 levels of the
-WHOLE dynamic range per dim and its certified ε stops excluding
-anything.  Product quantization changes the axis instead — split the
-dim into ``m = ceil(d / dsub)`` subspaces, train a ``C``-codeword
-codebook per subspace, and a row becomes ``m`` bytes: at SIFT's d=128
+Below int8 the per-dim ladder runs out: a few bits a dim are a handful
+of levels of the WHOLE dynamic range and the certified ε stops
+excluding anything.  Product quantization changes the axis instead —
+split the dim into ``m = ceil(d / dsub)`` subspaces, train a
+``C``-codeword codebook per subspace, and a row becomes ``m`` bytes: at
+SIFT's d=128
 with the classic (dsub=4, C=256) point that is 32 B/row, 1/16 the f32
-stream and 1/4 int4's, which is exactly the byte term the calibrated
+stream and 1/4 int8's, which is exactly the byte term the calibrated
 roofline says is the ceiling (ISSUE 17 / ROADMAP item 4).
 
 Training is the SEEDED DETERMINISTIC k-means the IVF tier already

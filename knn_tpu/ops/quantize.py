@@ -48,19 +48,6 @@ pipeline (the int8→f32 conversion is EXACT per 128-wide dim chunk:
 random draws across dims and dtypes; ``uint8`` data (SIFT-style bvecs)
 takes :func:`from_uint8` — the byte payload itself, re-centered by the
 L2-invariant -128 shift at unit scale, so ε_quant is exactly zero.
-
-The ``precision="int4"`` arm (PR 17) rides the SAME machinery one
-rung down: per-row symmetric 4-bit quantization (``scale = max|x|/7``,
-:func:`quantize_rows_int4_np`) packed two-nibbles-per-byte
-(:func:`pack_nibbles` — 0.5 B/elem of db stream, HALF the int8 arm's
-binding HBM term), unpacked in the kernel prologue and scored against
-int8 queries with the identical exact-int32 accumulation
-(|qi·ti| <= 127·7·d — overflow-free far past any real dim).  Because
-the bound above is built from the ACTUAL residual norms, not worst
-cases, the wider int4 residual needs no new derivation: ``db_bound_stats``
-on the int4 ``QuantizedRows`` yields a (larger) certified ε through the
-very same :func:`score_error_bound` / :func:`score_error_bound_device`
-pair, and the property test pins its soundness alongside int8.
 """
 
 from __future__ import annotations
@@ -140,89 +127,6 @@ def from_uint8(x: np.ndarray) -> QuantizedRows:
     vals = (x.astype(np.int16) - 128).astype(np.int8)
     scales = np.ones(x.shape[0], dtype=np.float32)
     return QuantizedRows(vals, scales, 128.0)
-
-
-#: symmetric int4 magnitude: values live in [-7, 7] so the biased
-#: nibble (v + 8) lands in [1, 15] and a zero byte can never be a
-#: valid packed pair — cheap corruption tripwire for placements
-_INT4_RANGE = 7.0
-
-
-def quantize_rows_int4_np(x: np.ndarray, offset: float = 0.0) -> QuantizedRows:
-    """Host-side per-row symmetric **4-bit** quantization: ``scale =
-    max|x|/7``, values clipped to [-7, 7] (stored UNPACKED as int8 so
-    :func:`db_bound_stats` / :func:`dequantize` apply verbatim — the
-    bound machinery never sees nibbles; :func:`pack_nibbles` produces
-    the 0.5 B/elem kernel operand separately)."""
-    xs = np.asarray(x, dtype=np.float32) - np.float32(offset)
-    amax = np.abs(xs).max(axis=-1)
-    scales = np.where(amax > 0, amax / np.float32(_INT4_RANGE),
-                      np.float32(1.0)).astype(np.float32)
-    q = np.clip(np.round(xs / scales[:, None]), -7, 7).astype(np.int8)
-    return QuantizedRows(q, scales, float(offset))
-
-
-def quantize_rows_int4(x):
-    """Traceable twin of :func:`quantize_rows_int4_np` (minus offset
-    handling) — the db side of the on-the-fly int4 path.  The QUERY
-    side of the int4 arm stays :func:`quantize_rows` (int8): queries
-    are a few KB, so halving them buys no bandwidth and would double
-    the ``||eq||`` terms of the certificate for nothing."""
-    import jax.numpy as jnp
-
-    amax = jnp.max(jnp.abs(x), axis=-1)
-    scales = jnp.where(amax > 0, amax / _INT4_RANGE, 1.0).astype(jnp.float32)
-    q = jnp.clip(jnp.round(x / scales[:, None]), -7, 7).astype(jnp.int8)
-    return q, scales
-
-
-def pack_nibbles(values: np.ndarray, dim_chunk: int = 128) -> np.ndarray:
-    """Pack int4 row values (int8 in [-7, 7], dim a multiple of
-    ``dim_chunk``) two-per-byte, **chunk-paired**: within each 128-dim
-    kernel chunk c, packed byte ``c*64 + j`` carries dim ``c*128 + j``
-    in its low nibble and dim ``c*128 + 64 + j`` in its high nibble,
-    both biased +8.  The pairing is deliberate: the kernel's unpack is
-    then two vectorized mask/shift ops plus ONE lane-axis concat —
-    ``[lo | hi]`` reassembles the chunk in dim order with no element
-    interleave — and the layout is independent of tile size, so one
-    packed placement serves every (tile_n, block_q) the tuner tries.
-    Returns uint8 [N, D/2]."""
-    v = np.asarray(values)
-    n, d = v.shape
-    if d % dim_chunk:
-        raise ValueError(f"pack_nibbles needs dim % {dim_chunk} == 0, got {d}")
-    half = dim_chunk // 2
-    r = v.reshape(n, d // dim_chunk, 2, half).astype(np.int16)
-    lo, hi = r[:, :, 0, :] + 8, r[:, :, 1, :] + 8
-    return (lo | (hi << 4)).astype(np.uint8).reshape(n, d // 2)
-
-
-def pack_nibbles_t(values, dim_chunk: int = 128):
-    """Traceable (jax.numpy) twin of :func:`pack_nibbles` for the
-    quantize-on-the-fly path."""
-    import jax.numpy as jnp
-
-    n, d = values.shape
-    if d % dim_chunk:
-        raise ValueError(f"pack_nibbles needs dim % {dim_chunk} == 0, got {d}")
-    half = dim_chunk // 2
-    r = values.reshape(n, d // dim_chunk, 2, half).astype(jnp.int32)
-    lo, hi = r[:, :, 0, :] + 8, r[:, :, 1, :] + 8
-    return (lo | (hi << 4)).astype(jnp.uint8).reshape(n, d // 2)
-
-
-def unpack_nibbles(packed: np.ndarray, dim: int,
-                   dim_chunk: int = 128) -> np.ndarray:
-    """Host-side inverse of :func:`pack_nibbles` (tests / debugging;
-    the kernel unpacks per 64-byte chunk block in its prologue).
-    Returns int8 [N, dim]."""
-    p = np.asarray(packed)
-    n = p.shape[0]
-    half = dim_chunk // 2
-    r = p.reshape(n, dim // dim_chunk, half)
-    lo = (r & 0xF).astype(np.int16) - 8
-    hi = (r >> 4).astype(np.int16) - 8
-    return np.stack([lo, hi], axis=2).reshape(n, dim).astype(np.int8)
 
 
 def _f32_up(v: float) -> np.float32:

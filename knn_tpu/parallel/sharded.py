@@ -160,12 +160,12 @@ def _analysis_window(k: int, m: int) -> int:
 
 
 def _overlap_ratio(intervals) -> float:
-    """Fraction of the pipeline's wall time during which >= 2 batches
-    were simultaneously in flight (interval = coarse-dispatch start to
-    result-repair end) — the honest, host-measurable overlap number:
-    it reports dispatch-timeline concurrency (what the bounded-depth
-    pipeline creates), not device-internal overlap (which needs a
-    hardware trace; obs.profiler).  0.0 for < 2 batches."""
+    """Fraction of a bounded-depth dispatch loop's wall time during
+    which >= 2 batches were simultaneously in flight (interval =
+    dispatch start to result-repair end; join.engine's superblock
+    pipelines) — the honest, host-measurable overlap number: it reports
+    dispatch-timeline concurrency, not device-internal overlap (which
+    needs a hardware trace; obs.profiler).  0.0 for < 2 batches."""
     if len(intervals) < 2:
         return 0.0
     events = []
@@ -586,10 +586,8 @@ class ShardedKNN:
         #: row norms + bound consts), cached per instance — "quantize
         #: once at placement time", the int8 arm's whole HBM story
         self._int8_cache = None
-        #: the sub-int8 arms' placements, same lazy discipline: int4 is
-        #: one nibble-packed placement; pq keys a small dict by the
+        #: the pq arm's placements, same lazy discipline, keyed by the
         #: (dsub, ncodes) codebook geometry so two grids can coexist
-        self._int4_cache = None
         self._pq_cache: dict = {}
         db_shards = hosts * chips
         pre_placed = (
@@ -767,10 +765,6 @@ class ShardedKNN:
         #: (k, placed query rows) -> dispatch count: every distinct pair is
         #: one traced/compiled XLA program shape (compile_cache_stats)
         self._dispatch_shapes: dict = {}
-        #: last pipeline-overlap run's measurements (depth, batches,
-        #: overlap_ratio, wall_s) — surfaced by search_certified stats
-        #: and ServingEngine.stats(); None until an overlap run happens
-        self._last_pipeline: Optional[dict] = None
         # (kernel's candidate width, width the final top-k sees) of the
         # last resolved pallas program, per shard (_pallas_setup)
         self._select_widths: Tuple[int, int] = (0, 0)
@@ -1275,52 +1269,6 @@ class ShardedKNN:
                 }
         return self._int8_cache
 
-    def _int4_placement(self) -> dict:
-        """The nibble-packed db placement for the int4 coarse pass —
-        :meth:`_int8_placement` one byte-width rung down, same lazy
-        cache discipline.  Rows quantize per-row symmetric to [-7, 7]
-        (ops.quantize.quantize_rows_int4_np), dims zero-pad to a
-        DIM_CHUNK multiple, then pack two-nibbles-per-byte
-        (ops.quantize.pack_nibbles) — HALF the int8 stream.  The bound
-        machinery is shared VERBATIM with int8: the unpacked int8-range
-        values feed db_bound_stats (actual residuals), so the
-        certificate's ε needs no new derivation.  No uint8 byte-exact
-        shortcut here — bytes don't fit 4 bits."""
-        if self._int4_cache is None:
-            from knn_tpu.ops import quantize as qz
-            from knn_tpu.ops.pallas_knn import DIM_CHUNK, PAD_VAL
-
-            with self._engines_lock:
-                if self._int4_cache is not None:
-                    return self._int4_cache
-                host = self._host_train()
-                qr = qz.quantize_rows_int4_np(host)
-                stats = qz.db_bound_stats(qr, host)
-                rows = self._tp.shape[0]
-                pad = rows - qr.values.shape[0]
-                d = qr.values.shape[1]
-                dpad = -(-d // DIM_CHUNK) * DIM_CHUNK - d
-                # zero-padded dims pack to the biased-zero nibble (8)
-                # and decode back to 0; zero pad ROWS pack to zero
-                # bytes, killed by zero scale + PAD_VAL norm like int8
-                vals = np.pad(qr.values, ((0, pad), (0, dpad)))
-                packed = qz.pack_nibbles(vals)
-                scl = np.pad(qr.scales, (0, pad)).astype(np.float32)
-                tn = np.empty(rows, dtype=np.float32)
-                for lo in range(0, host.shape[0], 65536):
-                    hs = host[lo : lo + 65536].astype(np.float64)
-                    tn[lo : lo + hs.shape[0]] = (hs ** 2).sum(-1)
-                tn[host.shape[0]:] = PAD_VAL
-                self._int4_cache = {
-                    "values": shard(packed, self.mesh, DB_AXIS),
-                    "scales": shard(scl, self.mesh, DB_AXIS),
-                    "norms": shard(tn, self.mesh, DB_AXIS),
-                    "consts": replicate(qz.bound_consts(stats), self.mesh),
-                    "offset": float(qr.offset),
-                    "stats": stats,
-                }
-        return self._int4_cache
-
     def _pq_placement(self, dsub: Optional[int] = None,
                       ncodes: Optional[int] = None) -> dict:
         """The product-quantized db placement for the pq coarse pass:
@@ -1381,12 +1329,11 @@ class ShardedKNN:
         """The operand tail of the pallas certified program after
         ``(queries, db)`` — ONE home shared by :meth:`_certify_pallas`
         and bench.py's phase breakdown so neither can call the program
-        with the wrong arity: int8/int4 pass the quantized placement
-        (packed values for int4); pq passes (codes, codebooks, consts);
+        with the wrong arity: int8 passes the quantized placement;
+        pq passes (codes, codebooks, consts);
         the f32 precisions pass the scalar db-norm bound."""
-        if precision in ("int8", "int4"):
-            pl = (self._int8_placement() if precision == "int8"
-                  else self._int4_placement())
+        if precision == "int8":
+            pl = self._int8_placement()
             return (pl["values"], pl["scales"], pl["norms"],
                     pl["consts"])
         if precision == "pq":
@@ -1398,17 +1345,14 @@ class ShardedKNN:
         self, queries, *, margin: int = 28, selector: str = "approx",
         batch_size: Optional[int] = None, tile_n: Optional[int] = None,
         precision: Optional[str] = None, return_distances: bool = True,
-        bin_w: Optional[int] = None, survivors: Optional[int] = None,
+        survivors: Optional[int] = None,
         block_q: Optional[int] = None, final_select: Optional[str] = None,
         recall_target: Optional[float] = None,
-        binning: Optional[str] = None,
         final_recall_target: Optional[float] = None,
         grid_order: Optional[str] = None,
         kernel: Optional[str] = None,
         tune_cache: Optional[str] = None,
         return_sqrt: bool = False,
-        overlap: Optional[bool] = None,
-        overlap_depth: Optional[int] = None,
     ):
         """Exact lexicographic top-k via the certified pipeline, sharded.
         Returns (dists_f64, idx, stats).  L2, cosine and dot (the
@@ -1459,8 +1403,8 @@ class ShardedKNN:
         device work of batches > b.  None = one batch (all queries at
         once).
 
-        Pallas-selector tuning knobs (``tile_n``, ``block_q``, ``bin_w``,
-        ``survivors``, ``precision``, ``final_select``, ``binning``,
+        Pallas-selector tuning knobs (``tile_n``, ``block_q``,
+        ``survivors``, ``precision``, ``final_select``,
         ``grid_order``, ``final_recall_target``, ``kernel``): any knob
         left at None resolves through ``knn_tpu.tuning.resolve`` — the
         persisted autotuner winner for this exact
@@ -1475,37 +1419,8 @@ class ShardedKNN:
         ``margin`` to push the fallback rate below 1%).  The resolved
         knob set and its provenance land in
         ``stats["pallas_knobs"]`` / ``stats["tuning"]``.
-
-        ``overlap`` (pallas selector only) runs the certified program as
-        a TWO-STAGE device pipeline split at the packed-candidate
-        boundary: batch i's select/rescore/certify tail executes while
-        batch i+1's coarse pass streams the database, with at most
-        ``overlap_depth`` (default 2; KNN_TPU_PIPELINE_DEPTH) batches in
-        flight.
-        Results are BITWISE-identical to the sequential path (pinned in
-        tests/test_fused_overlap.py); ``stats["pipeline"]`` reports the
-        measured dispatch-timeline overlap ratio, mirrored by the
-        ``knn_tpu_pipeline_overlap_ratio`` gauge and a
-        ``certified.pipeline`` span.  None resolves the
-        ``KNN_TPU_PIPELINE_OVERLAP`` env switch (off by default — it is
-        a scheduling choice, never a result change, so it is NOT an
-        autotuner knob).
         """
-        import os as _os
-
         self._require_resident("search_certified")
-        if overlap is None:
-            # strict opt-in vocabulary, like serving.admission's env
-            # knobs: anything else (off/no/typos) stays sequential
-            overlap = _os.environ.get(
-                "KNN_TPU_PIPELINE_OVERLAP", "").strip().lower() in (
-                    "1", "true", "on", "yes")
-        if overlap_depth is None:
-            try:
-                overlap_depth = int(_os.environ.get(
-                    "KNN_TPU_PIPELINE_DEPTH", "2"))
-            except ValueError:
-                overlap_depth = 2
         if self.metric == "cosine":
             # runs the l2 certificate on unit vectors (db rows were
             # normalized at placement): EXACT for the f32-row-normalized
@@ -1606,19 +1521,18 @@ class ShardedKNN:
                         cache_path=tune_cache,
                         overrides=dict(
                             tile_n=tile_n, precision=precision,
-                            bin_w=bin_w,
                             survivors=survivors, block_q=block_q,
-                            final_select=final_select, binning=binning,
+                            final_select=final_select,
                             final_recall_target=final_recall_target,
                             grid_order=grid_order, kernel=kernel,
                         ),
                     )
-                    # kernel geometry, the compiled program (or the
-                    # two-stage pair) and its operand tail: resolved in
-                    # this stage, so the spans below time batches only
+                    # kernel geometry, the compiled program and its
+                    # operand tail: resolved in this stage, so the spans
+                    # below time batches only
                     prog, m_prog, w, interpret = self._pallas_setup(
                         m - self.k, include_distances=return_distances,
-                        split=overlap, **knobs)
+                        **knobs)
                     ops_tail = self._pallas_operands(knobs["precision"])
             call.set("queries", n_q)
             call.set("batches", len(batches))
@@ -1635,7 +1549,6 @@ class ShardedKNN:
                     batches, bs, d, i, q_np, db_np, prog=prog, w=w,
                     ops_tail=ops_tail, precision=knobs["precision"],
                     trace_id=tid, want_distances=return_distances,
-                    overlap=overlap, overlap_depth=overlap_depth,
                 )
             else:
                 bad = self._certify_counted(
@@ -1707,8 +1620,6 @@ class ShardedKNN:
                 # kernel ran with, so a caller can tell which one answered
                 stats["pallas_knobs"] = {**knobs, "interpret": interpret}
                 stats["tuning"] = tune_info
-                if overlap and self._last_pipeline is not None:
-                    stats["pipeline"] = dict(self._last_pipeline)
             # mirror the quality signals into the telemetry registry —
             # the per-call stats dict stays the API, the registry
             # accumulates the process-lifetime truth a scraper reads
@@ -1859,16 +1770,14 @@ class ShardedKNN:
         return np.concatenate(flagged) if flagged else np.empty(0, np.int64)
 
     def _pallas_setup(self, margin: int, tile_n: Optional[int],
-                      precision: str, bin_w: Optional[int] = None,
+                      precision: str,
                       survivors: Optional[int] = None,
                       block_q: Optional[int] = None,
                       final_select: str = "exact",
                       include_distances: bool = True,
-                      binning: str = "grouped",
                       final_recall_target: Optional[float] = None,
                       grid_order: str = "query_major",
-                      kernel: str = "tiled",
-                      split: bool = False):
+                      kernel: str = "tiled"):
         """(program, m, analysis_window, interpret) for the one-pass
         certified path — the ONE home of the kernel-geometry margin cap
         and the packed-output window, shared by :meth:`_certify_pallas`
@@ -1881,7 +1790,6 @@ class ShardedKNN:
         the kernel, and returned so ``stats["pallas_knobs"]`` reports
         the value the kernel was actually given."""
         from knn_tpu.ops.pallas_knn import (
-            BIN_W,
             TILE_N,
             _geometry,
             default_backend_is_tpu,
@@ -1892,30 +1800,24 @@ class ShardedKNN:
         from knn_tpu.utils.config import CERTIFIED_PRECISIONS
 
         if precision not in CERTIFIED_PRECISIONS:
-            # "default" has no certified tolerance model (its matmul error
-            # is ~2^-10 relative — certificate-hostile); refuse rather
-            # than silently certify garbage
             raise ValueError(
                 f"precision {precision!r} has no certified tolerance "
                 f"model; use one of {CERTIFIED_PRECISIONS}"
             )
         interpret = not default_backend_is_tpu()
         quant_offset = 0.0
-        if precision in ("int8", "int4"):
+        if precision == "int8":
             # builds (and caches) the quantized placement: the program
             # needs the translation-invariance shift as a static constant
-            quant_offset = (self._int8_placement() if precision == "int8"
-                            else self._int4_placement())["offset"]
+            quant_offset = self._int8_placement()["offset"]
 
-        eff_bin = bin_w or BIN_W
         shard_rows = self._shard_rows()
         # same tile the kernel will pick (ONE home for the arithmetic:
         # ops.pallas_knn.effective_tile), so the m-cap below matches the
         # kernel's real candidate width
-        eff_tile = effective_tile(shard_rows, tile_n or TILE_N, eff_bin,
-                                  survivors, binning,
+        eff_tile = effective_tile(shard_rows, tile_n or TILE_N, survivors,
                                   min(self.k + margin, shard_rows) + 2)
-        _, _, out_w, _ = _geometry(eff_tile, eff_bin, survivors, binning)
+        _, _, out_w, _ = _geometry(eff_tile, survivors)
         # m is bounded by the db, the per-shard rows, and the kernel's
         # per-shard candidate width minus the two slots the exclusion
         # value needs (ops.pallas_knn.local_certified_candidates)
@@ -1939,30 +1841,11 @@ class ShardedKNN:
         # tile the kernel runs is provably the tile this m-cap assumed
         # (ADVICE r4: the raw-tile plumbing let the two diverge on small
         # padded dbs where m is capped by n_train)
-        if split:
-            # the two-stage pipeline's program pair, split at the
-            # packed-candidate boundary
-            coarse = _pallas_coarse_program(
-                self.mesh, m, eff_tile, precision, bin_w=bin_w,
-                survivors=survivors, block_q=block_q,
-                final_select=final_select, binning=binning,
-                grid_order=grid_order, kernel=kernel,
-                quant_offset=quant_offset, interpret=interpret,
-            )
-            tail = _pallas_tail_program(
-                self.mesh, m, self.k, self.merge, precision,
-                n_train=self.n_train, final_select=final_select,
-                include_distances=include_distances,
-                final_recall_target=final_recall_target,
-                quant_offset=quant_offset,
-                dcn_merge=self.dcn_merge,
-            )
-            return (coarse, tail), m, _analysis_window(self.k, m), interpret
         prog = _pallas_certified_program(
             self.mesh, m, self.k, self.merge, eff_tile, precision,
-            n_train=self.n_train, bin_w=bin_w, survivors=survivors,
+            n_train=self.n_train, survivors=survivors,
             block_q=block_q, final_select=final_select,
-            include_distances=include_distances, binning=binning,
+            include_distances=include_distances,
             final_recall_target=final_recall_target,
             grid_order=grid_order, kernel=kernel,
             quant_offset=quant_offset, dcn_merge=self.dcn_merge,
@@ -1972,8 +1855,7 @@ class ShardedKNN:
 
     def _certify_pallas(
         self, batches, bs, d, i, q_np, db_np, *, prog, w, ops_tail,
-        precision, trace_id=None, want_distances=True, overlap=False,
-        overlap_depth=2,
+        precision, trace_id=None, want_distances=True,
     ):
         """One-pass certificate, host side.  The device already ranked the
         candidates, flagged uncertified rows, and marked near-tie pairs
@@ -1983,32 +1865,15 @@ class ShardedKNN:
         crosses the slow device->host link — then repairs tie runs in
         float64 (ops.refine.rank_correct_runs).  Returns (flagged query
         indices, rank-corrected query count).  ``prog`` and ``w`` are
-        :meth:`_pallas_setup`'s (the program pair when ``overlap``),
-        ``ops_tail`` :meth:`_pallas_operands`'s; each batch's stages are
-        spans of the caller's ``trace_id`` (``certified.dispatch``,
-        ``.device_wait``, ``.d2h``, ``.unpack``, ``.rank_correct``).
-
-        ``overlap=True`` runs the TWO-STAGE pipeline instead of the
-        one-shot program: the certified program is split at the
-        packed-candidate boundary (coarse kernel | select/rescore/
-        certify tail — _pallas_setup(split=True)), with at most
-        ``overlap_depth`` batches in flight (the PR-1 dispatch-ahead
-        discipline: drain the oldest before admitting a new one) so
-        batch i's rescore/certify/fetch/host-repair overlaps batch
-        i+1's coarse db stream.  Results are bitwise-identical to the
-        sequential path — both run the same kernel, the same
-        select/rescore ops, and the SAME certify/pack tail
-        (_certify_pack_spmd) — pinned in tests/test_fused_overlap.py.
-        The measured dispatch-timeline overlap lands in
-        ``self._last_pipeline`` + the knn_tpu_pipeline_overlap_ratio
-        gauge + a certified.pipeline span."""
-        import time as _time
-
+        :meth:`_pallas_setup`'s, ``ops_tail`` :meth:`_pallas_operands`'s;
+        each batch's stages are spans of the caller's ``trace_id``
+        (``certified.dispatch``, ``.device_wait``, ``.d2h``, ``.unpack``,
+        ``.rank_correct``)."""
         from knn_tpu.ops.refine import rank_correct_runs
 
         k = self.k
         fetch = _staged_fetch(trace_id)
-        if precision in ("int8", "int4", "pq") and obs.enabled():
+        if precision in ("int8", "pq") and obs.enabled():
             # the per-query certified quantization bound ε — the quality
             # signal the device certificate computes and discards
             # (quantize.score_error_bound_device / pq's twin):
@@ -2023,8 +1888,7 @@ class ShardedKNN:
             else:
                 from knn_tpu.ops.quantize import score_error_bound
 
-                pl = (self._int8_placement() if precision == "int8"
-                      else self._int4_placement())
+                pl = self._int8_placement()
                 eps = score_error_bound(q_np, pl["stats"],
                                         offset=pl["offset"])
             obs.histogram(_mn.CERTIFIED_QUANT_BOUND).observe_many(eps)
@@ -2033,8 +1897,7 @@ class ShardedKNN:
 
         def repair(lo, pad, packed, redo):
             """ONE fetch of the packed output, then float64 tie-run
-            repair — shared verbatim by the sequential and pipelined
-            paths."""
+            repair."""
             nonlocal n_corrected
             take = bs - pad
             packed_np = _fetch_or_redispatch(packed, redo, "pallas fetch",
@@ -2056,55 +1919,6 @@ class ShardedKNN:
                 d[lo : lo + take] = dc
             i[lo : lo + take] = ic
             bad_mask[lo : lo + take] = bad_np
-
-        if overlap:
-            coarse, tail = prog
-            depth = max(1, int(overlap_depth))
-            intervals = []
-            pending = []
-            t_wall0 = _time.perf_counter()
-
-            def finalize(rec):
-                lo, pad, redo, packed, t0 = rec
-                repair(lo, pad, packed, redo)
-                intervals.append((t0, _time.perf_counter()))
-
-            for lo, chunk, pad in batches:
-                # the bounded in-flight window: drain the oldest batch
-                # (its tail already executed while later coarse passes
-                # streamed) before admitting a new one — the same
-                # depth discipline ServingEngine.replay() runs
-                while len(pending) >= depth:
-                    finalize(pending.pop(0))
-                t0 = _time.perf_counter()
-                with obs.span("certified.dispatch", trace_id,
-                              parent=_CALL_SPAN, h2d_bytes=chunk.nbytes):
-                    qp, _ = self._place_queries(chunk)
-
-                    def launch(q=qp):
-                        # one dispatch unit: a retry re-runs the coarse
-                        # pass together with the tail that consumes it
-                        cand = coarse(q, self._tp, *ops_tail)
-                        return tail(q, self._tp, *cand, *ops_tail)
-
-                    packed = _retry_transient(
-                        launch, "pallas pipeline dispatch")
-                pending.append((lo, pad, launch, packed, t0))
-            while pending:
-                finalize(pending.pop(0))
-            wall = _time.perf_counter() - t_wall0
-            ratio = _overlap_ratio(intervals)
-            self._last_pipeline = {
-                "depth": depth,
-                "batches": len(batches),
-                "overlap_ratio": round(ratio, 4),
-                "wall_s": round(wall, 4),
-            }
-            obs.gauge(_mn.PIPELINE_OVERLAP_RATIO).set(ratio)
-            obs.record_span("certified.pipeline", None, wall,
-                            batches=len(batches), depth=depth,
-                            overlap_ratio=round(ratio, 4))
-            return np.flatnonzero(bad_mask), n_corrected
 
         # stage 1: dispatch every batch (async on device)
         outs = []
@@ -2260,9 +2074,9 @@ def sharded_knn_predict(
 def _pallas_certified_program(
     mesh: Mesh, m: int, k: int, merge: str, tile_n: Optional[int],
     precision: str, n_train: Optional[int] = None,
-    bin_w: Optional[int] = None, survivors: Optional[int] = None,
+    survivors: Optional[int] = None,
     block_q: Optional[int] = None, final_select: str = "exact",
-    include_distances: bool = True, binning: str = "grouped",
+    include_distances: bool = True,
     final_recall_target: Optional[float] = None,
     grid_order: str = "query_major",
     kernel: str = "tiled",
@@ -2310,7 +2124,6 @@ def _pallas_certified_program(
     distances d32 are space-independent up to RANK_SLACK, which the
     derivation already budgets)."""
     from knn_tpu.ops.pallas_knn import (
-        BIN_W,
         BLOCK_Q,
         TILE_N,
         local_certified_candidates,
@@ -2318,7 +2131,6 @@ def _pallas_certified_program(
 
     hosts, chips = db_topology(mesh)
     eff_tile = tile_n or TILE_N
-    eff_bin = bin_w or BIN_W
     eff_bq = block_q or BLOCK_Q
     w = _analysis_window(k, m)
 
@@ -2326,13 +2138,11 @@ def _pallas_certified_program(
         db_q, db_pq, consts, db_norm_max = _split_operand_tail(
             precision, tail)
         d32, li, lb = local_certified_candidates(
-            q, t, m, tile_n=eff_tile, bin_w=eff_bin, survivors=survivors,
+            q, t, m, tile_n=eff_tile, survivors=survivors,
             block_q=eff_bq, final_select=final_select, precision=precision,
-            binning=binning, final_recall_target=final_recall_target,
+            final_recall_target=final_recall_target,
             grid_order=grid_order, kernel=kernel, interpret=interpret,
-            db_int8=db_q if precision == "int8" else None,
-            db_int4=db_q if precision == "int4" else None,
-            db_pq=db_pq, offset=quant_offset,
+            db_int8=db_q, db_pq=db_pq, offset=quant_offset,
         )
         return _certify_pack_spmd(
             q, t, d32, li, lb, consts=consts, db_norm_max=db_norm_max,
@@ -2357,12 +2167,12 @@ def _pallas_certified_program(
 
 def _tail_specs(precision: str, mesh: Mesh):
     """shard_map in_specs of the precision-shaped operand tail
-    (ShardedKNN._pallas_operands): int8/int4 = the quantized placement
+    (ShardedKNN._pallas_operands): int8 = the quantized placement
     (db-sharded values/scales/norms + replicated bound consts), pq =
     db-sharded codes + replicated codebooks + replicated per-subspace
     bound consts, f32 = the replicated scalar db-norm bound."""
     dbp = db_axes(mesh)
-    if precision in ("int8", "int4"):
+    if precision == "int8":
         return (P(dbp), P(dbp), P(dbp), P())
     if precision == "pq":
         return (P(dbp), P(), P())
@@ -2372,10 +2182,9 @@ def _tail_specs(precision: str, mesh: Mesh):
 def _split_operand_tail(precision: str, tail):
     """(db_quant, db_pq, consts, db_norm_max) from the operand tail —
     the per-precision unpacking every pallas-certified program shares.
-    ``db_quant`` is the (values, scales, norms) triple of the int8 OR
-    int4 arm (packed bytes for int4 — the kernel keyword decides which
-    contract it rides); ``db_pq`` is (codes, codebooks)."""
-    if precision in ("int8", "int4"):
+    ``db_quant`` is the (values, scales, norms) triple of the int8 arm;
+    ``db_pq`` is (codes, codebooks)."""
+    if precision == "int8":
         tq, ts, tnr, consts = tail
         return (tq, ts, tnr), None, consts, None
     if precision == "pq":
@@ -2401,12 +2210,7 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
                        dcn_merge=None, pq_dsub=None):
     """The certify/pack tail of the pallas certified program, from one
     shard's ranked candidates ``(d32, li, lb)`` to the packed host-facing
-    int32 array — ONE home shared by the one-shot program
-    (:func:`_pallas_certified_program`) and the pipeline-overlap tail
-    stage (:func:`_pallas_tail_program`), which is what makes the
-    two-stage path bitwise-identical to the sequential one: same merge,
-    same rank analysis, same certificate, same packing, running inside
-    either program."""
+    int32 array: merge, rank analysis, certificate, packing."""
     from knn_tpu.ops.pallas_knn import RANK_SLACK
 
     db_shards = hosts * chips
@@ -2451,13 +2255,13 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
     # the extra f32 reduction this on-device path adds (q_norm +
     # s_k arithmetic, <= ~12 eps of the norm scale): "highest" budgets
     # 32 eps total; bf16x3's 2^-14 dwarfs the f32 terms either way.
-    # int8/int4 tolerances are the per-query PROVABLE quantization
+    # int8's tolerance is the per-query PROVABLE quantization
     # bound ε from the ACTUAL residual norms — byte-exact data (bvecs)
     # gets an ε of pure f32 slack, tighter than bf16x3's; pq's is the
     # per-subspace Cauchy-Schwarz bound (ops.pq, same actual-residual
     # discipline hoisted per subspace at encode time).
     q32 = q.astype(jnp.float32)
-    if precision in ("int8", "int4"):
+    if precision == "int8":
         from knn_tpu.ops.quantize import score_error_bound_device
 
         q_norm, tol = score_error_bound_device(
@@ -2491,103 +2295,6 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
     if include_distances:
         cols.append(lax.bitcast_convert_type(d32[:, :k], jnp.int32))
     return jnp.concatenate(cols, axis=1)
-
-
-@functools.lru_cache(maxsize=32)
-def _pallas_coarse_program(
-    mesh: Mesh, m: int, tile_n: Optional[int], precision: str,
-    bin_w: Optional[int] = None, survivors: Optional[int] = None,
-    block_q: Optional[int] = None, final_select: str = "exact",
-    binning: str = "grouped", grid_order: str = "query_major",
-    kernel: str = "tiled", quant_offset: float = 0.0,
-    interpret: Optional[bool] = None,
-):
-    """Stage 1 of the two-stage certified pipeline: the db-streaming
-    coarse pass alone (ops.pallas_knn.local_coarse_candidates per
-    shard), returning the packed per-shard candidate blocks
-    ``(cd, ci, bounds)`` concatenated along the db axis — the
-    packed-candidate boundary the pipeline overlap splits the certified
-    program on.  Takes the SAME operand tail as the one-shot program
-    (unused pieces ignored) so callers keep ONE operand home."""
-    from knn_tpu.ops.pallas_knn import (
-        BIN_W,
-        BLOCK_Q,
-        TILE_N,
-        local_coarse_candidates,
-    )
-
-    dbp = db_axes(mesh)
-
-    def spmd(q, t, *tail):
-        db_q, db_pq, _, _ = _split_operand_tail(precision, tail)
-        return local_coarse_candidates(
-            q, t, m, tile_n=tile_n or TILE_N, bin_w=bin_w or BIN_W,
-            survivors=survivors, block_q=block_q or BLOCK_Q,
-            precision=precision, binning=binning,
-            grid_order=grid_order, kernel=kernel, interpret=interpret,
-            db_int8=db_q if precision == "int8" else None,
-            db_int4=db_q if precision == "int4" else None,
-            db_pq=db_pq,
-            offset=quant_offset, final_select=final_select,
-        )
-
-    return jax.jit(
-        jax.shard_map(
-            spmd,
-            mesh=mesh,
-            in_specs=(P(QUERY_AXIS), P(dbp), *_tail_specs(precision, mesh)),
-            out_specs=(P(QUERY_AXIS, dbp), P(QUERY_AXIS, dbp),
-                       P(QUERY_AXIS, dbp)),
-            check_vma=False,
-        )
-    )
-
-
-@functools.lru_cache(maxsize=32)
-def _pallas_tail_program(
-    mesh: Mesh, m: int, k: int, merge: str, precision: str,
-    n_train: Optional[int] = None, final_select: str = "exact",
-    include_distances: bool = True,
-    final_recall_target: Optional[float] = None,
-    quant_offset: float = 0.0,
-    dcn_merge: Optional[str] = None,
-):
-    """Stage 2 of the two-stage certified pipeline: final select +
-    rescore gather (ops.pallas_knn.local_select_rescore) + the shared
-    certify/pack tail (:func:`_certify_pack_spmd`)."""
-    from knn_tpu.ops.pallas_knn import local_select_rescore
-
-    hosts, chips = db_topology(mesh)
-    dbp = db_axes(mesh)
-    w = _analysis_window(k, m)
-
-    def spmd(q, t, cd, ci, bounds, *tail):
-        _, db_pq, consts, db_norm_max = _split_operand_tail(
-            precision, tail)
-        d32, li, lb = local_select_rescore(
-            q, t, cd, ci, bounds, m, final_select=final_select,
-            final_recall_target=final_recall_target,
-        )
-        return _certify_pack_spmd(
-            q, t, d32, li, lb, consts=consts, db_norm_max=db_norm_max,
-            precision=precision, quant_offset=quant_offset, m=m, k=k, w=w,
-            merge=merge, n_train=n_train, hosts=hosts, chips=chips,
-            dcn_merge=dcn_merge,
-            include_distances=include_distances,
-            pq_dsub=None if db_pq is None else int(db_pq[1].shape[2]),
-        )
-
-    return jax.jit(
-        jax.shard_map(
-            spmd,
-            mesh=mesh,
-            in_specs=(P(QUERY_AXIS), P(dbp), P(QUERY_AXIS, dbp),
-                      P(QUERY_AXIS, dbp), P(QUERY_AXIS, dbp),
-                      *_tail_specs(precision, mesh)),
-            out_specs=P(QUERY_AXIS),
-            check_vma=False,
-        )
-    )
 
 
 def unpack_certified(

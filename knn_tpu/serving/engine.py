@@ -625,11 +625,6 @@ class ServingEngine:
                 slowest = waterfall.slowest_table(with_waterfalls=False)
             except Exception:  # pragma: no cover - stats must not die
                 slowest = []
-        # the placed program's last certified pipeline-overlap run (the
-        # two-stage coarse/rescore pipeline, ShardedKNN._certify_pallas
-        # overlap=True): absent until one happened on this placement, so
-        # the default stats() shape is untouched
-        pipeline = getattr(self.program, "_last_pipeline", None)
         # the shadow audit sampler's quality section: present only when
         # the sampler is armed (rate > 0 AND telemetry on), so both the
         # obs-off and the audit-off stats() shapes are unchanged
@@ -643,7 +638,6 @@ class ServingEngine:
         with self._lock:
             return {
                 **({"tuning": tuning_info} if tuning_info else {}),
-                **({"pipeline": dict(pipeline)} if pipeline else {}),
                 **({"slo": slo_section} if slo_section else {}),
                 **({"quality": quality} if quality else {}),
                 **({"slowest_requests": slowest}

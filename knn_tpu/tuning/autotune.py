@@ -56,11 +56,9 @@ DEFAULT_KNOBS: Dict[str, object] = {
     "kernel": "tiled",
     "tile_n": None,
     "block_q": 256,
-    "bin_w": None,
     "survivors": None,
     "precision": "bf16x3",
     "final_select": "exact",
-    "binning": "grouped",
     "grid_order": "query_major",
     "final_recall_target": None,
 }
@@ -248,8 +246,8 @@ def knob_grid(level: str = "standard",
     VMEM: a combination that fits NO known device kind at the headline
     shape (knn_tpu.analysis.vmem — the model the kernel sizes its own
     request from) is dropped at enumeration where that model is
-    calibrated against Mosaic's reported need (bf16x3, grouped
-    binning); the ``vmem-budget`` checker in ``cli lint`` holds the
+    calibrated against Mosaic's reported need (bf16x3); the
+    ``vmem-budget`` checker in ``cli lint`` holds the
     grid to that, and the runtime gate in :func:`autotune` refuses
     over-budget candidates at the REAL shape/device with provenance.
     Arms the model is not calibrated for are never dropped or refused
@@ -291,10 +289,9 @@ def knob_grid(level: str = "standard",
         if (knobs["kernel"] in ("streaming", "fused")
                 and knobs["grid_order"] != "query_major"):
             return  # no db grid axis to reorder (ops.pallas_knn refuses)
-        if knobs["kernel"] == "fused" and (
-                knobs["final_select"] == "approx"
-                or knobs["binning"] != "grouped"):
-            return  # the early-out's bitwise contract is exact+grouped
+        if (knobs["kernel"] == "fused"
+                and knobs["final_select"] == "approx"):
+            return  # the early-out's bitwise contract is an exact select
         if knobs["precision"] == "pq" and knobs["kernel"] == "fused":
             return  # ops.pallas_knn refuses: carry soundness unproven
             # for reconstruction-space scores
@@ -320,7 +317,7 @@ def knob_grid(level: str = "standard",
             add(block_q=bq)
             add(block_q=bq, final_select="approx")
             add(block_q=bq, tile_n=8192)
-            for prec in ("bf16x3f", "int8", "int4"):
+            for prec in ("bf16x3f", "int8"):
                 add(block_q=bq, precision=prec)
 
     for kern in ("tiled", "streaming", "fused"):
@@ -342,16 +339,14 @@ def knob_grid(level: str = "standard",
     add(block_q=128)  # the pre-r05 default, kept as the A/B deviation
     add(tile_n=32768)  # the r5-projected winner cross (bq256 is default)
     add(tile_n=32768, final_select="approx")
-    for prec in ("bf16x3f", "highest", "int8", "int4"):
+    for prec in ("bf16x3f", "highest", "int8"):
         add(precision=prec)
     add(precision="int8", kernel="streaming")  # the HBM-bound cross
-    # the sub-int8 byte arms (PR 17): int4 x streaming is the headline
-    # hbm_bound attack (half the int8 db stream at the same MXU rate);
-    # pq streams ceil(d/dsub) code bytes — its candidates ride the SAME
-    # bitwise end-result gate (the certified fallback repairs every
-    # reconstruction-space miss), so an arm whose repaired answer
-    # drifts from the reference is ineligible, never a silent winner
-    add(precision="int4", kernel="streaming")
+    # the sub-int8 byte arm (PR 17): pq streams ceil(d/dsub) code
+    # bytes — its candidates ride the SAME bitwise end-result gate (the
+    # certified fallback repairs every reconstruction-space miss), so
+    # an arm whose repaired answer drifts from the reference is
+    # ineligible, never a silent winner
     add(precision="pq", kernel="streaming")
     add(precision="pq")
     # the vpu_select_bound attack the fused arm exists for, plus its
@@ -368,7 +363,7 @@ def knob_grid(level: str = "standard",
     for tile, bq, order, prec, kern in itertools.product(
             (None, 8192, 32768), (256, 128),
             ("query_major", "db_major"),
-            ("bf16x3", "bf16x3f", "int8", "int4"),
+            ("bf16x3", "bf16x3f", "int8"),
             ("tiled", "streaming", "fused")):
         add(tile_n=tile, block_q=bq, grid_order=order, precision=prec,
             kernel=kern)
@@ -428,7 +423,7 @@ def prune_candidates(
             model = roofline.pallas_cost_model(
                 n=n, d=d, k=k, nq=nq, precision=knobs["precision"],
                 kernel=knobs["kernel"], grid_order=knobs["grid_order"],
-                binning=knobs["binning"], tile_n=knobs["tile_n"],
+                tile_n=knobs["tile_n"],
                 block_q=knobs["block_q"], survivors=knobs["survivors"],
                 margin=margin, device_kind=device_kind, backend=backend)
             if not model.get("ceiling_qps"):
@@ -479,26 +474,6 @@ def _row_norms(db) -> np.ndarray:
     return tn
 
 
-def _quantized_db_int4(db):
-    """int4 twin of :func:`_quantized_db`: nibble-packed rows + scales
-    + norms, built ONCE per autotune() — same no-per-candidate-charge
-    discipline (production quantizes at placement time,
-    ShardedKNN._int4_placement)."""
-    import jax.numpy as jnp
-
-    from knn_tpu.ops import quantize as qz
-    from knn_tpu.ops.pallas_knn import DIM_CHUNK
-
-    host = np.asarray(db, np.float32)
-    qr = qz.quantize_rows_int4_np(host)
-    vals = qr.values
-    dpad = -(-vals.shape[1] // DIM_CHUNK) * DIM_CHUNK - vals.shape[1]
-    if dpad:
-        vals = np.pad(vals, ((0, 0), (0, dpad)))
-    return (jnp.asarray(qz.pack_nibbles(vals)), jnp.asarray(qr.scales),
-            jnp.asarray(_row_norms(host)))
-
-
 def _pq_db(db):
     """Shared PQ placement for the pq candidates: train the per-subspace
     codebooks ONCE (deterministic seeded k-means on a 1x1 mesh — the
@@ -514,16 +489,15 @@ def _pq_db(db):
 
 
 def _timed_program(m: int, knobs: Dict[str, object], db_int8=None,
-                   db_int4=None, db_pq=None):
+                   db_pq=None):
     """The device hot path one candidate is timed on —
     ``local_certified_candidates`` (kernel + final select + rescore);
     it is itself jitted with static knob arguments, so repeated timing
-    calls hit the jit cache.  ``db_int8``/``db_int4``/``db_pq`` are the
+    calls hit the jit cache.  ``db_int8``/``db_pq`` are the
     shared pre-quantized placements for the quantized candidates
     (:func:`_quantized_db` and twins) — only the one matching the
     candidate's precision is threaded through."""
     from knn_tpu.ops.pallas_knn import (
-        BIN_W,
         BLOCK_Q,
         TILE_N,
         local_certified_candidates,
@@ -531,8 +505,6 @@ def _timed_program(m: int, knobs: Dict[str, object], db_int8=None,
 
     if knobs["precision"] != "int8":
         db_int8 = None
-    if knobs["precision"] != "int4":
-        db_int4 = None
     if knobs["precision"] != "pq":
         db_pq = None
 
@@ -541,16 +513,13 @@ def _timed_program(m: int, knobs: Dict[str, object], db_int8=None,
             q, t, m,
             tile_n=knobs["tile_n"] or TILE_N,
             block_q=knobs["block_q"] or BLOCK_Q,
-            bin_w=knobs["bin_w"] or BIN_W,
             survivors=knobs["survivors"],
             precision=knobs["precision"],
             final_select=knobs["final_select"],
-            binning=knobs["binning"],
             final_recall_target=knobs["final_recall_target"],
             grid_order=knobs["grid_order"],
             kernel=knobs["kernel"],
             db_int8=db_int8,
-            db_int4=db_int4,
             db_pq=db_pq,
         )
 
@@ -569,7 +538,7 @@ def _candidate_roofline(knobs: Dict[str, object], n: int, d: int, k: int,
     model = roofline.pallas_cost_model(
         n=n, d=d, k=k, nq=nq,
         precision=knobs["precision"], kernel=knobs["kernel"],
-        grid_order=knobs["grid_order"], binning=knobs["binning"],
+        grid_order=knobs["grid_order"],
         tile_n=knobs["tile_n"], block_q=knobs["block_q"],
         survivors=knobs["survivors"],
         device_kind=device_kind, backend=backend)
@@ -584,9 +553,9 @@ def _search_once(queries, db, k, margin, knobs):
     d, i, _ = knn_search_pallas(
         queries, db, k, margin=margin,
         tile_n=knobs["tile_n"] or TILE_N,
-        precision=knobs["precision"], bin_w=knobs["bin_w"],
+        precision=knobs["precision"],
         survivors=knobs["survivors"], block_q=knobs["block_q"],
-        final_select=knobs["final_select"], binning=knobs["binning"],
+        final_select=knobs["final_select"],
         final_recall_target=knobs["final_recall_target"],
         grid_order=knobs["grid_order"], kernel=knobs["kernel"],
     )
@@ -693,7 +662,6 @@ def autotune(
     # the quantized candidates' placements, built lazily ONCE each and
     # shared — they depend only on the db, never on the knobs
     shared_int8 = None
-    shared_int4 = None
     shared_pq = None
     timings: Dict[str, Optional[float]] = {}
     errors: Dict[str, str] = {}
@@ -795,12 +763,10 @@ def autotune(
                     continue
             if knobs["precision"] == "int8" and shared_int8 is None:
                 shared_int8 = _quantized_db(db)
-            if knobs["precision"] == "int4" and shared_int4 is None:
-                shared_int4 = _quantized_db_int4(db)
             if knobs["precision"] == "pq" and shared_pq is None:
                 shared_pq = _pq_db(db)
             prog = _timed_program(m, knobs, db_int8=shared_int8,
-                                  db_int4=shared_int4, db_pq=shared_pq)
+                                  db_pq=shared_pq)
             out = prog(qj, tj)
             jax.block_until_ready(out)  # warm: compile outside the clock
             reps = []
@@ -848,7 +814,7 @@ def autotune(
     if _profiler.profile_dir():
         try:
             prog = _timed_program(m, best_knobs, db_int8=shared_int8,
-                                  db_int4=shared_int4, db_pq=shared_pq)
+                                  db_pq=shared_pq)
             with _profiler.device_trace(f"tune_{key}") as tdir:
                 jax.block_until_ready(prog(qj, tj))
             trace_dir = tdir

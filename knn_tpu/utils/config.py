@@ -20,13 +20,12 @@ from knn_tpu.ops.metrics import METRICS
 #: oracle (knn_tpu.native, SURVEY.md §7 step 3).
 BACKENDS = ("jax", "native")
 
-#: kernel matmul precisions with a certified tolerance model —
-#: ops.pallas_knn.PRECISIONS minus the uncertifiable "default".  ONE
-#: home (jax-free, so the CLI can build its --help without importing
-#: JAX); cli.py's choices, this module's validation, and
+#: the kernel matmul precisions (ops.pallas_knn.PRECISIONS is this
+#: tuple): each has a certified tolerance model.  ONE home (jax-free,
+#: so the CLI can build its --help without importing JAX); cli.py's
+#: choices, this module's validation, the kernel's and
 #: parallel.sharded's _pallas_setup check all consume it.
-CERTIFIED_PRECISIONS = ("bf16x3", "bf16x3f", "highest", "int8", "int4",
-                        "pq")
+CERTIFIED_PRECISIONS = ("bf16x3", "bf16x3f", "highest", "int8", "pq")
 
 
 @dataclass
@@ -91,9 +90,9 @@ class JobConfig:
     #: resolved set lands in metrics()["certified_stats"]["pallas_knobs"].
     tune_cache: Optional[str] = None
     #: explicit kernel matmul precision for the certified pallas
-    #: selector (ops.pallas_knn.PRECISIONS minus the uncertifiable
-    #: "default"): "bf16x3" | "bf16x3f" | "highest" | "int8" | "int4"
-    #: (the quantized MXU arms — ops.quantize) | "pq" (product-quantized
+    #: selector (CERTIFIED_PRECISIONS): "bf16x3" | "bf16x3f" |
+    #: "highest" | "int8"
+    #: (the quantized MXU arm — ops.quantize) | "pq" (product-quantized
     #: codes — ops.pq).  None = resolve through the autotuner cache /
     #: library default; an explicit value beats both.
     pallas_precision: Optional[str] = None

@@ -229,7 +229,6 @@ def main(argv=None) -> int:
     ap.add_argument("--tile-n", type=int)
     ap.add_argument("--precision")
     ap.add_argument("--grid-order")
-    ap.add_argument("--binning")
     ap.add_argument("--final-select")
     ap.add_argument("--mesh", help="QxD, e.g. 1x4: compile the full "
                     "SPMD certified program on that topology mesh")
@@ -249,7 +248,7 @@ def main(argv=None) -> int:
         overrides = {
             "kernel": args.kernel, "block_q": args.block_q,
             "tile_n": args.tile_n, "precision": args.precision,
-            "grid_order": args.grid_order, "binning": args.binning,
+            "grid_order": args.grid_order,
             "final_select": args.final_select,
         }
         overrides = {k: v for k, v in overrides.items() if v is not None}

@@ -678,17 +678,15 @@ def test_vmem_model_tracks_the_compiler_at_the_benchmark_shapes():
 
 
 def test_vmem_model_refuses_only_where_it_is_calibrated():
-    """ONE rule: the model has the last word on bf16x3 with grouped
-    binning, where it was fitted; on every other arm it only describes
+    """ONE rule: the model has the last word on bf16x3, where it was
+    fitted; on every other arm it only describes
     (an upper bound at best: Mosaic put "highest" / "bf16x3f" streaming
     at SIFT bq256 at 111.19 / 119.19 MiB where the model says 126.75 /
     134.75), the verdict is N/A and the compiler decides."""
-    assert vmem.calibrated("bf16x3", "grouped")
-    assert vmem.calibrated(None, None)  # the defaults
-    for precision, binning in (("bf16x3f", "grouped"), ("highest", "grouped"),
-                               ("int8", "grouped"), ("int4", "grouped"),
-                               ("pq", "grouped"), ("bf16x3", "lane")):
-        assert not vmem.calibrated(precision, binning)
+    assert vmem.calibrated("bf16x3")
+    assert vmem.calibrated(None)  # the default
+    for precision in ("bf16x3f", "highest", "int8", "pq"):
+        assert not vmem.calibrated(precision)
     shape = dict(vmem.HEADLINE_SHAPE)
     over = {"kernel": "streaming", "block_q": 256, "precision": "bf16x3f"}
     verdict = vmem.check_candidate(over, **shape, device_kind="TPU v5e")

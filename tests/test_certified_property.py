@@ -63,11 +63,11 @@ def test_counted_certified_matches_oracle(seed, n, dim, k, margin, dup_frac,
     dim=st.integers(2, 16),
     k=st.integers(1, 9),
     final_select=st.sampled_from(["exact", "approx"]),
-    binning=st.sampled_from(["grouped", "lane"]),
+    survivors=st.sampled_from([None, 1, 3]),
     grid_order=st.sampled_from(["query_major", "db_major"]),
 )
 def test_pallas_certified_matches_oracle_property(seed, n_tiles, extra, dim,
-                                                  k, final_select, binning,
+                                                  k, final_select, survivors,
                                                   grid_order):
     rng = np.random.default_rng(seed)
     n = n_tiles * 128 + extra
@@ -78,7 +78,8 @@ def test_pallas_certified_matches_oracle_property(seed, n_tiles, extra, dim,
     prog = ShardedKNN(db, mesh=make_mesh(1, 1), k=k)
     d, i, stats = prog.search_certified(
         queries, selector="pallas", margin=8, tile_n=256,
-        final_select=final_select, binning=binning, grid_order=grid_order,
+        final_select=final_select, survivors=survivors,
+        grid_order=grid_order,
     )
     np.testing.assert_array_equal(i, ref_i)
     np.testing.assert_allclose(d, ref_d, rtol=5e-5)

@@ -93,14 +93,12 @@ def _expected(batches: int, reselects: int = 0) -> Counter:
 @pytest.mark.parametrize("kw,batches", [
     pytest.param({}, 1, id="one_batch"),
     pytest.param({"batch_size": 32}, 3, id="three_batches"),
-    pytest.param({"batch_size": 32, "overlap": True}, 3,
-                 id="three_batches_overlap"),
 ])
 def test_a_certified_call_emits_exactly_its_stage_spans(placed, corpus, kw,
                                                         batches):
     d, i, stats = placed.search_certified(corpus[1], selector="pallas", **kw)
     assert stats["fallback_queries"] == 0  # so no re-select is expected
-    spans = [e for e in _spans() if e["span"] != "certified.pipeline"]
+    spans = _spans()
     assert Counter(e["span"] for e in spans) == _expected(batches)
 
     tids = {e.get("trace_id") for e in spans}
@@ -173,8 +171,7 @@ class _CountingAnnotation(jax.profiler.TraceAnnotation):
 
 @pytest.mark.parametrize("kw", [
     pytest.param({}, id="one_batch"),
-    pytest.param({"batch_size": 32, "overlap": True},
-                 id="three_batches_overlap"),
+    pytest.param({"batch_size": 32}, id="three_batches"),
 ])
 def test_obs_off_makes_nothing_and_changes_no_answer(placed, corpus,
                                                      monkeypatch, kw):
@@ -247,28 +244,27 @@ SCOPES = (pallas_knn.SCOPE_OPERAND_PREP, pallas_knn.SCOPE_KERNEL,
 
 @pytest.fixture(scope="module")
 def lowered(placed, corpus):
-    """The certified program's lowered text, one-shot and as the
-    pipeline's coarse + tail pair."""
+    """The certified program's lowered text, by db-streaming kernel:
+    the grid-tiled one the cells run and the one-launch one whose chip
+    time is still to be taken (a trace splits either by these scopes)."""
     qp, _ = placed._place_queries(corpus[1])
     tail = placed._pallas_operands("bf16x3")
-    prog, _, _, _ = placed._pallas_setup(28, None, "bf16x3")
-    one = prog.lower(qp, placed._tp, *tail).as_text(debug_info=True)
-    (coarse, rest), _, _, _ = placed._pallas_setup(28, None, "bf16x3",
-                                                   split=True)
-    cand = coarse(qp, placed._tp, *tail)
-    two = (coarse.lower(qp, placed._tp, *tail).as_text(debug_info=True)
-           + rest.lower(qp, placed._tp, *cand, *tail).as_text(
-               debug_info=True))
-    return {"one_shot": one, "pipeline": two}
+    out = {}
+    for kernel in ("tiled", "streaming"):
+        prog, _, _, _ = placed._pallas_setup(28, None, "bf16x3",
+                                             kernel=kernel)
+        out[kernel] = prog.lower(qp, placed._tp, *tail).as_text(
+            debug_info=True)
+    return out
 
 
 @pytest.mark.parametrize("scope", SCOPES)
-@pytest.mark.parametrize("program", ["one_shot", "pipeline"])
-def test_the_lowered_program_names_each_device_scope(lowered, program,
+@pytest.mark.parametrize("kernel", ["tiled", "streaming"])
+def test_the_lowered_program_names_each_device_scope(lowered, kernel,
                                                      scope):
     assert scope.startswith("knn.")
-    assert f"{scope}/" in lowered[program] or f"{scope}\"" in lowered[
-        program]
+    assert f"{scope}/" in lowered[kernel] or f"{scope}\"" in lowered[
+        kernel]
 
 
 # --- scripts/certified_stage_report.py ----------------------------------

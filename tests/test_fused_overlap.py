@@ -1,9 +1,7 @@
 """The roofline-gap campaign's acceptance surface: the fused-select
 kernel arm (ops.pallas_knn kernel="fused" — in-loop carry + sound
 exclusion-bound early-out, bitwise-identical final results), the
-two-stage coarse/rescore pipeline overlap
-(ShardedKNN.search_certified(overlap=True) — bitwise vs the sequential
-path, measurable overlap ratio), the select-overlap roofline semantics
+select-overlap roofline semantics
 (serialized select for non-fused kernels, overlapped for fused —
 introduced at MODEL_VERSION 2, carried by 3), and
 the roofline-pruned autotuner (auditable, winner-safe, off by
@@ -15,8 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from knn_tpu import obs, tuning
-from knn_tpu.obs import names as mn
+from knn_tpu import tuning
 from knn_tpu.obs import roofline, sentinel
 from knn_tpu.ops.pallas_knn import (
     BIN_W,
@@ -72,7 +69,7 @@ def test_fused_disarmed_bin_candidates_match_streaming(rng, dim):
     for kern in ("streaming", "fused"):
         outs[kern] = _bin_candidates(
             jnp.asarray(queries), jnp.asarray(db), block_q=8,
-            tile_n=2 * BIN_W, bin_w=BIN_W, survivors=2,
+            tile_n=2 * BIN_W, survivors=2,
             precision="bf16x3", interpret=True, kernel=kern)
     for a, b in zip(outs["streaming"], outs["fused"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
@@ -89,14 +86,14 @@ def test_fused_early_out_fires_and_stays_bitwise(rng):
     queries = db[:9] + rng.normal(size=(9, 16)).astype(np.float32) * 1e-2
     cd_f, _, b_f = _bin_candidates(
         jnp.asarray(queries), jnp.asarray(db), block_q=16,
-        tile_n=2 * BIN_W, bin_w=BIN_W, survivors=2, precision="bf16x3",
+        tile_n=2 * BIN_W, survivors=2, precision="bf16x3",
         interpret=True, kernel="fused", keep=15)
     cd_s, _, b_s = _bin_candidates(
         jnp.asarray(queries), jnp.asarray(db), block_q=16,
-        tile_n=2 * BIN_W, bin_w=BIN_W, survivors=2, precision="bf16x3",
+        tile_n=2 * BIN_W, survivors=2, precision="bf16x3",
         interpret=True, kernel="streaming")
     cd_f, cd_s = np.asarray(cd_f), np.asarray(cd_s)
-    out_w = 2 * BIN_W  # survivors=2 in grouped mode
+    out_w = 2 * BIN_W  # survivors=2
     skipped = [t for t in range(3)
                if np.isinf(cd_f[:, t * out_w:(t + 1) * out_w]).all()
                and not np.isinf(cd_s[:, t * out_w:(t + 1) * out_w]).all()]
@@ -170,129 +167,8 @@ def test_fused_refuses_incompatible_knobs(rng):
         local_certified_candidates(jnp.asarray(q), jnp.asarray(db), m=5,
                                    interpret=True, kernel="fused",
                                    grid_order="db_major")
-    with pytest.raises(ValueError, match="grouped"):
-        local_certified_candidates(jnp.asarray(q), jnp.asarray(db), m=5,
-                                   interpret=True, kernel="fused",
-                                   binning="lane")
     # launch accounting: fused is ONE launch like streaming
     assert kernel_launches_per_batch("fused", 1_000_000, 16384) == 1
-
-
-# --- pipeline overlap ----------------------------------------------------
-
-
-@pytest.fixture
-def obs_reset():
-    yield
-    obs.reset()
-
-
-def test_pipeline_overlap_bitwise_with_fallbacks_and_ratio(rng, obs_reset):
-    """ACCEPTANCE: the two-stage pipelined certified path is
-    bitwise-identical to the sequential one — on noisy near-tie int8
-    data that actually TRIPS the fallback/repair machinery — and the
-    measured overlap ratio is > 0, published to the
-    knn_tpu_pipeline_overlap_ratio gauge and surfaced through
-    ServingEngine.stats()."""
-    from knn_tpu.parallel import ShardedKNN, make_mesh
-    from knn_tpu.serving.engine import ServingEngine
-
-    db = rng.normal(size=(1500, 12)).astype(np.float32) * 10
-    queries = rng.normal(size=(40, 12)).astype(np.float32) * 10
-    # an exact-tie run WIDER than the rank-analysis window: the tie has
-    # no provable top-k boundary, so the device flags it unresolved and
-    # the widened-re-select repair must run — in both execution modes
-    db[100:125] = db[99]
-    queries[1] = db[99] + 1e-4
-    prog = ShardedKNN(db, mesh=make_mesh(2, 4), k=5)
-    d0, i0, s0 = prog.search_certified(
-        queries, selector="pallas", margin=8, tile_n=256,
-        precision="int8", batch_size=8, overlap=False)
-    d1, i1, s1 = prog.search_certified(
-        queries, selector="pallas", margin=8, tile_n=256,
-        precision="int8", batch_size=8, overlap=True)
-    assert s0["fallback_queries"] > 0  # the repair path really ran
-    np.testing.assert_array_equal(d0, d1)
-    np.testing.assert_array_equal(i0, i1)
-    strip = lambda s: {k: v for k, v in s.items()  # noqa: E731
-                       if k != "pipeline"}
-    assert strip(s0) == strip(s1)
-    _, ref_i = _oracle(db, queries, 5)
-    np.testing.assert_array_equal(i1, ref_i)
-    # the overlap instrumentation
-    pipe = s1["pipeline"]
-    assert pipe["batches"] == 5 and pipe["depth"] == 2
-    assert pipe["overlap_ratio"] > 0
-    snap = obs.snapshot()
-    (series,) = snap[mn.PIPELINE_OVERLAP_RATIO]["series"]
-    assert series["value"] == pytest.approx(pipe["overlap_ratio"],
-                                            abs=5e-4)
-    # the span the waterfall layer attributes the hidden tail with
-    spans = [e for e in obs.get_event_log().recent()
-             if e.get("span") == "certified.pipeline"]
-    assert spans and spans[-1]["overlap_ratio"] == pipe["overlap_ratio"]
-    # the serving engine surfaces the placement's last pipeline run
-    eng = ServingEngine(prog, aot=False)
-    assert eng.stats()["pipeline"]["overlap_ratio"] == \
-        pipe["overlap_ratio"]
-    # the sequential stats shape is untouched (no pipeline section)
-    assert "pipeline" not in s0
-
-
-def test_pipeline_overlap_fused_cross_and_env_switch(rng, monkeypatch):
-    """kernel='fused' composes with the pipeline split, and the
-    KNN_TPU_PIPELINE_OVERLAP env switch turns the path on without a
-    code change (overlap=None resolves it)."""
-    from knn_tpu.parallel import ShardedKNN, make_mesh
-
-    db = rng.normal(size=(900, 10)).astype(np.float32) * 20
-    queries = rng.normal(size=(24, 10)).astype(np.float32) * 20
-    prog = ShardedKNN(db, mesh=make_mesh(1, 2), k=4)
-    d0, i0, _ = prog.search_certified(
-        queries, selector="pallas", margin=6, tile_n=256, batch_size=8,
-        overlap=False, kernel="fused")
-    monkeypatch.setenv("KNN_TPU_PIPELINE_OVERLAP", "1")
-    monkeypatch.setenv("KNN_TPU_PIPELINE_DEPTH", "3")
-    d1, i1, s1 = prog.search_certified(
-        queries, selector="pallas", margin=6, tile_n=256, batch_size=8,
-        kernel="fused")
-    np.testing.assert_array_equal(d0, d1)
-    np.testing.assert_array_equal(i0, i1)
-    assert s1["pipeline"]["depth"] == 3
-
-
-def test_pipeline_overlap_wall_time_within_noise(rng):
-    """The CPU-measurable half of the acceptance bar: the pipelined
-    path's wall time is <= the sequential path's within noise (the
-    actual speedup is a hardware claim, gated on TPU rounds with the
-    sentinel baselining device_phase_qps)."""
-    import time
-
-    from knn_tpu.parallel import ShardedKNN, make_mesh
-
-    # big enough that per-batch device work amortizes the split path's
-    # second program dispatch (at toy sizes the extra launch IS the
-    # wall time and the comparison measures dispatch overhead, not the
-    # pipeline)
-    db = rng.normal(size=(20_000, 16)).astype(np.float32) * 10
-    queries = rng.normal(size=(64, 16)).astype(np.float32) * 10
-    prog = ShardedKNN(db, mesh=make_mesh(1, 1), k=5)
-
-    def run(overlap):
-        return prog.search_certified(
-            queries, selector="pallas", margin=8, tile_n=2048,
-            batch_size=16, overlap=overlap)
-
-    run(False), run(True)  # warm/compile both paths outside the clocks
-    seq, pipe = [], []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        run(False)
-        seq.append(time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        run(True)
-        pipe.append(time.perf_counter() - t0)
-    assert min(pipe) <= min(seq) * 1.15, (seq, pipe)
 
 
 # --- roofline select-overlap semantics (MODEL_VERSION 2, kept by 3) -----

@@ -87,20 +87,19 @@ def test_db_major_grid_bitwise_equal_query_major(rng):
         for go in ("query_major", "db_major"):
             outs[go] = _bin_candidates(
                 jnp.asarray(queries), jnp.asarray(db), block_q=8,
-                tile_n=2 * BIN_W, bin_w=BIN_W, survivors=2,
-                precision="bf16x3", interpret=True, binning="grouped",
-                grid_order=go)
+                tile_n=2 * BIN_W, survivors=2,
+                precision="bf16x3", interpret=True, grid_order=go)
         for a, b in zip(outs["query_major"], outs["db_major"]):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 @pytest.mark.parametrize("precision", ["highest", "bf16x3", "bf16x3f",
                                        "int8"])
-@pytest.mark.parametrize("binning,grid_order", [
-    ("grouped", "query_major"), ("lane", "query_major"),
-    ("grouped", "db_major"),
+@pytest.mark.parametrize("kernel,grid_order", [
+    ("tiled", "query_major"), ("streaming", "query_major"),
+    ("tiled", "db_major"),
 ])
-def test_exclusion_bound_is_sound(rng, precision, binning, grid_order):
+def test_exclusion_bound_is_sound(rng, precision, kernel, grid_order):
     # THE property the one-pass certificate rests on: every db point
     # outside the candidate set must have kernel-space score >= lb
     # (within the precision mode's tolerance), and the returned d32 must
@@ -111,7 +110,7 @@ def test_exclusion_bound_is_sound(rng, precision, binning, grid_order):
     d32, idx, lb = local_certified_candidates(
         jnp.asarray(queries), jnp.asarray(db), m=m, block_q=8,
         tile_n=2 * BIN_W, precision=precision, interpret=True,
-        binning=binning, grid_order=grid_order,
+        kernel=kernel, grid_order=grid_order,
     )
     d32 = np.asarray(d32)[:7]
     idx, lb = np.asarray(idx)[:7], np.asarray(lb)[:7]
@@ -158,29 +157,20 @@ def test_pallas_certified_matches_oracle(rng):
             + stats["fallback_false_alarms"]) == stats["fallback_queries"]
 
 
-@pytest.mark.parametrize("binning", ["lane", "grouped"])
-def test_pallas_certified_survives_adversarial_bins(rng, binning):
+def test_pallas_certified_survives_adversarial_bins(rng):
     # cram the ENTIRE true top-k into ONE kernel bin with k >
     # MAX_SURVIVORS: the kernel keeps only the bin's top 8, the bound
     # certificate must flag the loss and the fallback must still return
-    # the exact answer.  A bin is a contiguous 128-lane span in "lane"
-    # mode, but one LANE across a tile's column groups in "grouped" mode
-    # — each layout gets its own adversarial packing
+    # the exact answer.  A bin is one LANE across a tile's column groups
     dim, k = 12, 10
-    if binning == "lane":
-        tile_n = 2 * BIN_W
-        db = rng.normal(size=(4 * BIN_W, dim)).astype(np.float32) * 50
-        hot = [2 * BIN_W + 3 * j for j in range(k)]  # one 128-lane bin
-    else:
-        tile_n = 12 * BIN_W  # 12 groups of 128 lanes per tile
-        db = rng.normal(size=(tile_n, dim)).astype(np.float32) * 50
-        hot = [7 + BIN_W * g for g in range(k)]  # lane 7 of groups 0..9
+    tile_n = 12 * BIN_W  # 12 groups of 128 lanes per tile
+    db = rng.normal(size=(tile_n, dim)).astype(np.float32) * 50
+    hot = [7 + BIN_W * g for g in range(k)]  # lane 7 of groups 0..9
     query = rng.normal(size=(1, dim)).astype(np.float32)
     for j, r in enumerate(hot):
         db[r] = query[0] + (j + 1) * 1e-3
     ref_d, ref_i = _oracle(db, query, k)
-    d, i, stats = knn_search_pallas(query, db, k, tile_n=tile_n, margin=4,
-                                    binning=binning)
+    d, i, stats = knn_search_pallas(query, db, k, tile_n=tile_n, margin=4)
     np.testing.assert_array_equal(i, ref_i)
     assert stats["fallback_queries"] >= 1
     assert stats["fallback_genuine_misses"] >= 1
@@ -253,61 +243,45 @@ def test_candidate_fn_composition_on_tiny_db(rng):
     np.testing.assert_allclose(d, ref_d, rtol=1e-9)
 
 
-@pytest.mark.parametrize("bin_w,survivors", [(2 * BIN_W, 3), (2 * BIN_W, 2),
-                                             (BIN_W, 4)])
-def test_wide_bin_geometry_matches_oracle(rng, bin_w, survivors):
-    # the tunable geometry (wider bins x more survivors shrinks the
-    # candidate array the final select scans): certified exactness must
-    # hold for every (bin_w, survivors) the bench sweeps
+@pytest.mark.parametrize("survivors", [1, 3, 4, 8])
+def test_grouped_survivors_match_oracle(rng, survivors):
+    # the tunable geometry (more survivors per bin widens the candidate
+    # array the final select scans and cuts the fallbacks): certified
+    # exactness must hold at every survivor count, not only the default
+    # 2 — one survivor loses the second of two neighbours that share a
+    # lane and has to repair it, eight is the unrolled network's cap
     db = rng.normal(size=(9 * BIN_W + 45, 16)).astype(np.float32) * 20
     queries = rng.normal(size=(11, 16)).astype(np.float32) * 20
     ref_d, ref_i = _oracle(db, queries, 7)
-    # bin_w only shapes LANE-mode binning (inert in grouped mode)
     d, i, stats = knn_search_pallas(
-        queries, db, 7, tile_n=4 * BIN_W, margin=8, bin_w=bin_w,
-        survivors=survivors, binning="lane",
+        queries, db, 7, tile_n=4 * BIN_W, margin=8, survivors=survivors,
     )
     np.testing.assert_array_equal(i, ref_i)
     np.testing.assert_allclose(d, ref_d, rtol=5e-5)
+    assert stats["pallas_knobs"]["survivors"] == survivors
+    assert stats["certified"] + stats["fallback_queries"] == 11
 
 
 def test_multi_block_output_lanes_match_oracle(rng):
-    # n_bins * survivors > 128 forces a multiple-of-128-lane output block:
-    # the lowering rule the round-2 kernel broke, now exercised as a
-    # first-class geometry — both the _geometry arithmetic AND a real
-    # kernel run at out_w = 256
+    # survivors > 1 forces a multiple-of-128-lane output block (a
+    # narrower one does not lower): both the _geometry arithmetic AND a
+    # real kernel run at out_w = 1024
     from knn_tpu.ops.pallas_knn import _geometry
 
-    assert _geometry(4 * BIN_W, BIN_W, 64, "lane") == (4, 8, 128, 128)
-    assert _geometry(16 * BIN_W, BIN_W, 2, "lane") == (16, 2, 128, 128)
-    assert _geometry(32 * BIN_W, BIN_W, 8, "lane") == (32, 8, 256, 128)
-    assert _geometry(160 * BIN_W, BIN_W, 1, "lane") == (160, 1, 256, 256)
-    # grouped: always 128 lane-bins; out_w = survivors * 128; bin_w inert
-    assert _geometry(4 * BIN_W, BIN_W, None, "grouped") == (128, 2, 256, 128)
-    assert _geometry(32 * BIN_W, BIN_W, 64, "grouped") == (128, 8, 1024, 128)
-    assert _geometry(160 * BIN_W, 2 * BIN_W, 1, "grouped") == (128, 1, 128, 128)
+    # always 128 lane-bins; out_w = survivors * 128, capped at 8
+    assert _geometry(4 * BIN_W) == (128, 2, 256, 128)
+    assert _geometry(32 * BIN_W, 64) == (128, 8, 1024, 128)
+    assert _geometry(160 * BIN_W, 1) == (128, 1, 128, 128)
 
-    # out_w = 256 LANE-mode kernel run: 32 bins x 8 survivors per tile
-    # (explicit binning: the grouped default would change the geometry
-    # and stop exercising the round-2 multi-block lowering regression)
     db = rng.normal(size=(2 * 32 * BIN_W + 77, 8)).astype(np.float32) * 5
     queries = rng.normal(size=(5, 8)).astype(np.float32) * 5
     k = 5
     ref_d, ref_i = _oracle(db, queries, k)
+    # 8 survivors -> out_w = 1024 (8 blocks)
     d, i, _ = knn_search_pallas(queries, db, k, tile_n=32 * BIN_W, margin=6,
-                                survivors=8, binning="lane")
+                                survivors=8)
     np.testing.assert_array_equal(i, ref_i)
     np.testing.assert_allclose(d, ref_d, rtol=5e-5)
-
-    # bound_w = 256 lane-mode kernel run: 160 bins per tile
-    d, i, _ = knn_search_pallas(queries, db, k, tile_n=160 * BIN_W, margin=6,
-                                survivors=1, binning="lane")
-    np.testing.assert_array_equal(i, ref_i)
-
-    # grouped multi-block out_w: 8 survivors -> out_w = 1024 (8 blocks)
-    d, i, _ = knn_search_pallas(queries, db, k, tile_n=32 * BIN_W, margin=6,
-                                survivors=8, binning="grouped")
-    np.testing.assert_array_equal(i, ref_i)
 
 
 def test_final_select_approx_stays_exact(rng):
@@ -345,25 +319,22 @@ def test_effective_tile_halves_for_midsize_dbs():
     from knn_tpu.ops.pallas_knn import _geometry, effective_tile
 
     # 10k rows, need 302 lanes: one 10112-tile gives 256 -> halve
-    t = effective_tile(10_000, 16384, BIN_W, None, "grouped", 302)
+    t = effective_tile(10_000, 16384, None, 302)
     assert t % BIN_W == 0
     n_tiles = -(-10_000 // t)
-    assert n_tiles * _geometry(t, BIN_W, None, "grouped")[2] >= 302
+    assert n_tiles * _geometry(t)[2] >= 302
 
     # huge db: no halving needed, the request is honored
-    assert effective_tile(1_000_000, 16384, BIN_W, None, "grouped", 130) \
-        == 16384
+    assert effective_tile(1_000_000, 16384, None, 130) == 16384
     # tiny db: tile caps at the padded rows
-    assert effective_tile(200, 16384, BIN_W, None, "grouped", 4) == 256
-    # bottoms out at bin_w even when the width can never be met
-    assert effective_tile(100, 16384, BIN_W, None, "grouped", 10**6) == BIN_W
+    assert effective_tile(200, 16384, None, 4) == 256
+    # bottoms out at BIN_W even when the width can never be met
+    assert effective_tile(100, 16384, None, 10**6) == BIN_W
     # an explicitly invalid request still raises, never silently repaired
     with pytest.raises(ValueError, match="multiple"):
-        effective_tile(10_000, 100, BIN_W, None, "grouped", 10)
-    # lane mode: halving interacts with the survivors floor monotonically
-    t = effective_tile(10_000, 16384, BIN_W, None, "lane", 600)
-    n_tiles = -(-10_000 // t)
-    assert n_tiles * _geometry(t, BIN_W, None, "lane")[2] >= 600
+        effective_tile(10_000, 100, None, 10)
+    # more survivors widen a tile's block, so fewer halvings are needed
+    assert effective_tile(10_000, 16384, 4, 302) > t
 
 
 def test_default_tile_wide_margin_midsize_end_to_end(rng):

@@ -29,25 +29,26 @@ def _oracle(db, queries, k):
 
 
 @pytest.mark.parametrize("dim", [24, 300])
-@pytest.mark.parametrize("precision,binning", [
-    ("bf16x3", "grouped"), ("bf16x3f", "grouped"), ("highest", "grouped"),
-    ("bf16x3", "lane"), ("default", "grouped"),
-    ("int8", "grouped"), ("int8", "lane"),
+@pytest.mark.parametrize("precision,survivors", [
+    ("bf16x3", 2), ("bf16x3f", 2), ("highest", 2), ("int8", 2),
+    ("bf16x3", 1), ("bf16x3", 4), ("int8", 1), ("int8", 4),
 ])
 def test_streaming_bitwise_equals_tiled_bin_candidates(rng, dim, precision,
-                                                       binning):
+                                                       survivors):
     # raw kernel outputs (candidates, indices, per-tile bounds) across
-    # uneven tile counts (n % tile_n != 0 -> PAD_VAL padding) and both
-    # single- and multi-chunk dims (300 spans 3 DIM_CHUNKs)
+    # uneven tile counts (n % tile_n != 0 -> PAD_VAL padding), both
+    # single- and multi-chunk dims (300 spans 3 DIM_CHUNKs), and output
+    # blocks of one, two and four lane-rows a tile (the streaming kernel
+    # writes each tile's block at a dynamic column offset of that width)
     db = rng.normal(size=(3 * BIN_W + 41, dim)).astype(np.float32) * 10
     queries = rng.normal(size=(11, dim)).astype(np.float32) * 10
     outs = {}
     for kern in ("tiled", "streaming"):
         outs[kern] = _bin_candidates(
             jnp.asarray(queries), jnp.asarray(db), block_q=8,
-            tile_n=2 * BIN_W, bin_w=BIN_W, survivors=2,
-            precision=precision, interpret=True, binning=binning,
-            kernel=kern)
+            tile_n=2 * BIN_W, survivors=survivors,
+            precision=precision, interpret=True, kernel=kern)
+    assert outs["tiled"][0].shape[1] == 2 * survivors * BIN_W
     for a, b in zip(outs["tiled"], outs["streaming"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -150,7 +151,7 @@ def test_streaming_rejects_db_major():
     with pytest.raises(ValueError, match="db_major"):
         _bin_candidates(
             jnp.zeros((4, 8), jnp.float32), jnp.zeros((256, 8), jnp.float32),
-            block_q=8, tile_n=2 * BIN_W, bin_w=BIN_W, survivors=2,
+            block_q=8, tile_n=2 * BIN_W, survivors=2,
             precision="bf16x3", interpret=True, grid_order="db_major",
             kernel="streaming")
 

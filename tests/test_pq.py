@@ -186,9 +186,9 @@ def test_pq_certified_matches_oracle_across_kernels(mesh, monkeypatch):
 
 
 def test_pq_forced_miss_is_detected_and_repaired(monkeypatch):
-    """Cram the entire true top-k into ONE kernel bin with k >
-    MAX_SURVIVORS: the kernel keeps only the bin's top 8, so the
-    certificate MUST flag the loss and the fallback must still return
+    """Cram the entire true top-k into ONE kernel bin (lane 7 of ten
+    column groups of one tile): the kernel keeps only the bin's top 2,
+    so the certificate MUST flag the loss and the fallback must still return
     the float64 oracle's answer — a pq miss is repaired, never
     silent."""
     monkeypatch.setenv("KNN_TPU_PQ_NCODES", "32")
@@ -196,16 +196,15 @@ def test_pq_forced_miss_is_detected_and_repaired(monkeypatch):
 
     rng = np.random.default_rng(2)
     dim, k = 12, 10
-    tile_n = 2 * BIN_W
-    db = (rng.normal(size=(4 * BIN_W, dim)) * 50).astype(np.float32)
+    tile_n = 12 * BIN_W
+    db = (rng.normal(size=(tile_n, dim)) * 50).astype(np.float32)
     query = rng.normal(size=(1, dim)).astype(np.float32)
-    hot = [2 * BIN_W + 3 * j for j in range(k)]
+    hot = [7 + BIN_W * g for g in range(k)]
     for j, r in enumerate(hot):
         db[r] = query[0] + (j + 1) * 1e-3
     ref_d, ref_i = _oracle(db, query, k)
     d, i, stats = knn_search_pallas(query, db, k, tile_n=tile_n,
-                                    margin=4, precision="pq",
-                                    binning="lane")
+                                    margin=4, precision="pq")
     np.testing.assert_array_equal(i, ref_i)
     np.testing.assert_allclose(d, ref_d, rtol=5e-5)
     assert stats["fallback_queries"] >= 1

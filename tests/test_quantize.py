@@ -164,103 +164,3 @@ def test_uint8_sharded_int8_search_is_exact(rng):
     assert pl8["offset"] == 128.0
     assert pl8["stats"]["et2_max"] == 0.0  # byte-exact, no residuals
     assert stats["fallback_queries"] + stats["certified"] == q.shape[0]
-
-
-# --- the int4 arm ---------------------------------------------------------
-def test_int4_bound_dominates_observed_error_property():
-    """Same proof obligation one rung down: the int8 bound machinery is
-    shared VERBATIM by the int4 arm (db rows quantize to [-7, 7],
-    queries stay int8), so ε from the int4 residual stats must dominate
-    the observed error across dims/dtypes/magnitudes, f64 and f32
-    rescale arithmetic both."""
-    rng = np.random.default_rng(20260806)
-    kinds = ("normal", "big", "tiny", "integer", "skewed")
-    for trial in range(40):
-        kind = kinds[trial % len(kinds)]
-        dim = int(rng.choice([3, 8, 17, 64, 130]))
-        n = int(rng.choice([20, 97, 256]))
-        db, q = _draw(rng, kind, n, dim)
-        qr = qz.quantize_rows_int4_np(db)
-        stats = qz.db_bound_stats(qr, db, chunk=50)
-        eps = qz.score_error_bound(q, stats, offset=qr.offset)
-        t_sh = db.astype(np.float64) - qr.offset
-        for f32_arith in (False, True):
-            err = _observed_errors(q, qr, t_sh, f32_arith=f32_arith)
-            assert (eps >= err).all(), (
-                f"trial {trial} kind={kind} dim={dim} f32={f32_arith}: "
-                f"eps {eps} < observed {err}")
-
-
-def test_int4_quantize_ranges_and_zero_rows():
-    rng = np.random.default_rng(2)
-    x = rng.normal(size=(40, 16)).astype(np.float32) * 25
-    qr = qz.quantize_rows_int4_np(x)
-    assert qr.values.dtype == np.int8
-    assert np.abs(qr.values.astype(np.int16)).max() <= 7
-    err = np.abs(x - qr.scales[:, None] * qr.values.astype(np.float32))
-    assert (err <= qr.scales[:, None] * 0.5 + 1e-6).all()
-    z = qz.quantize_rows_int4_np(np.zeros((3, 8), np.float32))
-    np.testing.assert_array_equal(z.scales, np.ones(3, np.float32))
-    np.testing.assert_array_equal(z.values, np.zeros((3, 8), np.int8))
-
-
-def test_int4_device_and_host_quantization_agree():
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(4)
-    x = rng.normal(size=(9, 33)).astype(np.float32) * 7
-    host = qz.quantize_rows_int4_np(x)
-    dv, ds = qz.quantize_rows_int4(jnp.asarray(x))
-    np.testing.assert_array_equal(np.asarray(dv), host.values)
-    np.testing.assert_array_equal(np.asarray(ds), host.scales)
-
-
-def test_pack_nibbles_roundtrip_and_chunk_pair_layout():
-    import jax.numpy as jnp
-
-    rng = np.random.default_rng(6)
-    vals = rng.integers(-7, 8, size=(10, 256)).astype(np.int8)
-    packed = qz.pack_nibbles(vals)
-    assert packed.dtype == np.uint8 and packed.shape == (10, 128)
-    np.testing.assert_array_equal(qz.unpack_nibbles(packed, 256), vals)
-    # the chunk-paired layout contract the kernel's unpack relies on:
-    # byte c*64 + j = (v[c*128 + j] + 8) | ((v[c*128 + 64 + j] + 8) << 4)
-    for c in (0, 1):
-        for j in (0, 5, 63):
-            lo = int(vals[3, c * 128 + j]) + 8
-            hi = int(vals[3, c * 128 + 64 + j]) + 8
-            assert int(packed[3, c * 64 + j]) == (lo | (hi << 4))
-    # a valid packed pair can never be a zero byte (biased nibbles live
-    # in [1, 15]) -- the placement corruption tripwire
-    assert (packed != 0).all()
-    # traceable twin agrees bitwise
-    np.testing.assert_array_equal(
-        np.asarray(qz.pack_nibbles_t(jnp.asarray(vals))), packed)
-    with pytest.raises(ValueError, match="dim"):
-        qz.pack_nibbles(vals[:, :100])
-
-
-def test_int4_sharded_search_matches_oracle(rng):
-    """End to end: ShardedKNN(precision='int4') certified results equal
-    the float64 oracle — indices bitwise, any quantization-induced miss
-    repaired by the fallback, never silent."""
-    from knn_tpu.parallel import ShardedKNN, make_mesh
-
-    db = (rng.normal(size=(900, 16)) * 10).astype(np.float32)
-    q = (rng.normal(size=(7, 16)) * 10).astype(np.float32)
-    d64 = ((db.astype(np.float64)[None]
-            - q.astype(np.float64)[:, None]) ** 2).sum(-1)
-    ref_i = np.argsort(d64, axis=-1, kind="stable")[:, :4]
-    ref_d = np.take_along_axis(d64, ref_i, axis=-1)
-    prog = ShardedKNN(db, mesh=make_mesh(2, 4), k=4)
-    out = {}
-    for kern in ("tiled", "streaming"):
-        d, i, stats = prog.search_certified(
-            q, selector="pallas", margin=8, tile_n=256,
-            precision="int4", kernel=kern)
-        out[kern] = (np.asarray(d), np.asarray(i))
-        np.testing.assert_array_equal(out[kern][1], ref_i)
-        np.testing.assert_allclose(out[kern][0], ref_d, rtol=5e-5)
-        assert stats["fallback_queries"] + stats["certified"] == q.shape[0]
-    np.testing.assert_array_equal(out["tiled"][0], out["streaming"][0])
-    np.testing.assert_array_equal(out["tiled"][1], out["streaming"][1])

@@ -57,18 +57,6 @@ def _actual_operand_nbytes(db, precision):
             jnp.broadcast_to(tn[None, :], (8, n)),
             jnp.broadcast_to(ts[None, :].astype(jnp.float32), (8, n)),
         ], axis=0).nbytes
-    elif precision == "int4":
-        from knn_tpu.ops.quantize import pack_nibbles_t, quantize_rows_int4
-
-        tq, ts = quantize_rows_int4(db)
-        values = pack_nibbles_t(tq).nbytes
-        # norms row 0, scales row 1, zero fill rows 2-7: the ONE 8-row
-        # aux block (kernel reads one row of each; no broadcast)
-        aux = jnp.concatenate([
-            jnp.sum(db * db, axis=-1)[None, :],
-            ts[None, :].astype(jnp.float32),
-            jnp.zeros((6, n), jnp.float32),
-        ], axis=0).nbytes
     elif precision == "pq":
         # the streamed operand is the [N, ceil(d/dsub)] uint8 code
         # array (shape-determined — training moves no extra bytes)
@@ -77,7 +65,7 @@ def _actual_operand_nbytes(db, precision):
         values = jnp.zeros((n, m_sub), jnp.uint8).nbytes
         aux = jnp.broadcast_to(
             jnp.zeros((n,), jnp.float32)[None, :], (8, n)).nbytes
-    else:  # highest / default stream the raw f32 rows
+    else:  # highest streams the raw f32 rows
         values = db.astype(jnp.float32).nbytes
         aux = jnp.broadcast_to(
             jnp.sum(db * db, axis=-1)[None, :], (8, n)).nbytes
@@ -85,8 +73,7 @@ def _actual_operand_nbytes(db, precision):
 
 
 @pytest.mark.parametrize("precision",
-                         ["bf16x3", "bf16x3f", "int8", "int4", "pq",
-                          "highest"])
+                         ["bf16x3", "bf16x3f", "int8", "pq", "highest"])
 @pytest.mark.parametrize("kernel", ["tiled", "streaming"])
 def test_db_byte_terms_match_actual_operand_nbytes(rng, precision, kernel):
     """Property: the model's per-pass db byte terms equal the nbytes of
@@ -133,44 +120,26 @@ def test_bench_peak_table_is_a_view_over_roofline():
     assert bench._PEAK_BY_KIND["TPU v5 lite"] == 197e12
 
 
-# --- MODEL_VERSION 6: the sub-int8 compressed tiers ---------------------
+# --- MODEL_VERSION 6: the sub-int8 compressed tier ----------------------
 
 
 def test_sub_int8_row_bytes_pinned():
     """Pinned byte ratios at SIFT dims (docs/PERF.md precision
-    ladder): int4 streams HALF int8's row (an eighth of f32), pq at
+    ladder): int8 streams a quarter of the f32 row, pq at
     the default dsub=4 streams m = ceil(d/4) code bytes — m/(4d) of
     the f32 row, 1/16 at d=128."""
     from knn_tpu.analysis import widths
 
     f32 = widths.db_row_bytes(128, "highest")
     i8 = widths.db_row_bytes(128, "int8")
-    i4 = widths.db_row_bytes(128, "int4")
     pq = widths.db_row_bytes(128, "pq", dsub=4)
-    assert (f32, i8, i4, pq) == (512, 128, 64, 32)
-    assert i4 / i8 == 0.5 and i4 / f32 == 0.125
+    assert (f32, i8, pq) == (512, 128, 32)
     assert pq / f32 == widths.pq_nsub(128, 4) / (4 * 128) == 1 / 16
-    # int4's packed aux (norms row 0 + scales row 1 in ONE 8-row
-    # block) also halves int8's 16-row broadcast block
-    a = roofline.db_operand_nbytes(1000, 128, "int4")
+    # int8's aux stacks 8 scale rows under the 8 norm rows every other
+    # arm streams
+    a = roofline.db_operand_nbytes(1000, 128, "bf16x3")
     b = roofline.db_operand_nbytes(1000, 128, "int8")
     assert 2 * a["db_aux"] == b["db_aux"]
-
-
-def test_int4_streaming_breaks_the_int8_hbm_ceiling():
-    """THE acceptance pin of the compressed-tier ISSUE: at the
-    hbm-bound operating point (small nq, block_q=8, SIFT1M on a v5e)
-    both int8 and int4 streaming hit the HBM wall, and halving the
-    streamed bytes lifts the modeled ceiling >= 1.8x."""
-    assert roofline.MODEL_VERSION == 7
-    kw = dict(n=1_000_000, d=128, k=10, nq=8, kernel="streaming",
-              block_q=8, device_kind="TPU v5e", backend="tpu")
-    m8 = roofline.pallas_cost_model(precision="int8", **kw)
-    m4 = roofline.pallas_cost_model(precision="int4", **kw)
-    assert m8["bound_class"] == "hbm_bound"
-    assert m4["bound_class"] == "hbm_bound"
-    assert m4["ceiling_qps"] >= 1.8 * m8["ceiling_qps"]
-    assert roofline.validate_block(m4) == []
 
 
 def test_pq_model_prices_lut_width_and_composes_with_probes():

@@ -135,6 +135,54 @@ def test_sharded_certified_batched_matches_unbatched(data, batch_size):
     assert stats["certified"] + stats["fallback_queries"] == queries.shape[0]
 
 
+@pytest.mark.parametrize("ties", [False, True], ids=["clean", "fallbacks"])
+@pytest.mark.parametrize("batch_size", [16, 37, 64])
+def test_pallas_batched_matches_unbatched(rng, batch_size, ties):
+    # the one-pass selector's schedule (dispatch every sub-batch, then
+    # fetch and repair each in order) is an execution strategy too:
+    # three sub-batches with a ragged tail, two, and one padded batch
+    # answer bitwise like one call — also when rows fall back, which
+    # the repair then re-selects over the whole call's flagged queries
+    db = rng.normal(size=(1500, 12)).astype(np.float32) * 10
+    queries = rng.normal(size=(40, 12)).astype(np.float32) * 10
+    if ties:
+        # an exact-tie run WIDER than the rank-analysis window: no
+        # provable top-k boundary, so the device flags the query
+        # unresolved and the widened re-select must repair it
+        db[100:125] = db[99]
+        queries[1] = db[99] + 1e-4
+    _, ref_i = _oracle(db, queries, 5)
+    prog = ShardedKNN(db, mesh=make_mesh(2, 4), k=5)
+    kw = dict(selector="pallas", margin=8, tile_n=256)
+    d0, i0, s0 = prog.search_certified(queries, **kw)
+    d1, i1, s1 = prog.search_certified(queries, batch_size=batch_size, **kw)
+    if ties:
+        assert s0["fallback_queries"] > 0  # the repair path really ran
+    np.testing.assert_array_equal(i0, ref_i)
+    np.testing.assert_array_equal(i1, i0)
+    np.testing.assert_array_equal(d1, d0)
+    for key in ("fallback_queries", "certified", "rank_corrected_queries",
+                "pallas_knobs", "select_width"):
+        assert s1[key] == s0[key], key
+    assert s1["certified"] + s1["fallback_queries"] == queries.shape[0]
+
+
+@pytest.mark.parametrize("kernel", ["streaming", "fused"])
+def test_pallas_batched_full_width_kernels_match_tiled(rng, kernel):
+    # the one-launch kernels under sub-batching on a db-sharded mesh:
+    # bitwise the tiled kernel's one-batch answer
+    db = rng.normal(size=(900, 10)).astype(np.float32) * 20
+    queries = rng.normal(size=(24, 10)).astype(np.float32) * 20
+    prog = ShardedKNN(db, mesh=make_mesh(1, 2), k=4)
+    kw = dict(selector="pallas", margin=6, tile_n=256)
+    d0, i0, _ = prog.search_certified(queries, **kw)
+    d1, i1, s1 = prog.search_certified(queries, batch_size=8, kernel=kernel,
+                                       **kw)
+    np.testing.assert_array_equal(d1, d0)
+    np.testing.assert_array_equal(i1, i0)
+    assert s1["pallas_knobs"]["kernel"] == kernel
+
+
 def test_pallas_certified_beats_f32_cancellation(rng):
     # at tiny distances vs large norms the expanded-square f32 "exact"
     # path loses ~all its bits (catastrophic cancellation); the pallas

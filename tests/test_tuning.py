@@ -243,13 +243,13 @@ def test_stale_kernel_version_entry_falls_back_to_defaults(cache_path):
     cache.put(base + "|kv-stale", {"knobs": {**tuning.DEFAULT_KNOBS,
                                              "tile_n": 256}})
     # ... and a KERNEL_VERSION-4 entry carrying a sub-int8 winner: the
-    # 4 -> 5 bump (the int4/pq arms changed the kernel) must invalidate
-    # it even though "precision": "int4" is a perfectly current knob
+    # 4 -> 5 bump (the pq arm changed the kernel) must invalidate
+    # it even though "precision": "pq" is a perfectly current knob
     from knn_tpu.ops.pallas_knn import KERNEL_VERSION
 
-    assert KERNEL_VERSION == 6
+    assert KERNEL_VERSION == 7
     cache.put(base + "|kv4", {"knobs": {**tuning.DEFAULT_KNOBS,
-                                        "precision": "int4",
+                                        "precision": "pq",
                                         "kernel": "streaming"}})
     # ... and a version-5 winner: timed before the final select's
     # bin-merge (5 -> 6) changed the tail its timing loop runs
@@ -265,6 +265,60 @@ def test_stale_kernel_version_entry_falls_back_to_defaults(cache_path):
     assert knobs["block_q"] == 16
 
 
+@pytest.mark.parametrize("knob,value", [
+    ("binning", "lane"), ("precision", "int4"),
+])
+def test_version_6_winner_naming_a_removed_knob_is_never_used(
+        cache_path, rng, knob, value):
+    """The knob domain narrowed at KERNEL_VERSION 7: a winner persisted
+    by version 6 may name a select layout or a precision the kernel no
+    longer has.  Its key carries ``kv6``, so the lookup misses, the
+    defaults answer (``source == "default"``), and the removed value is
+    never handed to a function that no longer takes it."""
+    from knn_tpu.parallel import ShardedKNN, make_mesh
+
+    key = tuning.cache_key("cpu", 700, 16, 5, "l2", None)
+    v6_key = key.rsplit("|kv", 1)[0] + "|kv6"
+    assert v6_key != key
+    v6_knobs = {**tuning.DEFAULT_KNOBS, "binning": "grouped",
+                "bin_w": None, knob: value}
+    tuning.TuneCache(cache_path).put(
+        v6_key, {"knobs": v6_knobs, "winner_ms": 1.0})
+    knobs, info = tuning.resolve_full(700, 16, 5, cache_path=cache_path)
+    assert info["source"] == "default"
+    assert knobs == tuning.DEFAULT_KNOBS
+    # and the search that resolves through that cache file runs on them
+    db = rng.normal(size=(700, 16)).astype(np.float32)
+    prog = ShardedKNN(db, mesh=make_mesh(1, 1), k=5)
+    _, _, stats = prog.search_certified(
+        db[:4], selector="pallas", tune_cache=cache_path)
+    assert stats["tuning"]["source"] == "default"
+    assert {kk: v for kk, v in stats["pallas_knobs"].items()
+            if kk != "interpret"} == tuning.DEFAULT_KNOBS
+
+
+def test_default_knobs_are_the_kernel_shaping_arguments():
+    """The knob list has ONE home: ``DEFAULT_KNOBS`` names exactly the
+    kernel-shaping keyword arguments of ``search_certified`` and of
+    ``_pallas_setup`` (what is left of their signatures once the
+    arguments that shape the call, not the kernel, are taken out), so a
+    knob added to one of the three and not the others fails here."""
+    import inspect
+
+    from knn_tpu.parallel import ShardedKNN
+
+    def kwargs_of(fn, *not_knobs):
+        return set(inspect.signature(fn).parameters) - {"self", *not_knobs}
+
+    assert kwargs_of(
+        ShardedKNN.search_certified, "queries", "margin", "selector",
+        "batch_size", "return_distances", "recall_target", "tune_cache",
+        "return_sqrt") == set(tuning.DEFAULT_KNOBS)
+    assert kwargs_of(
+        ShardedKNN._pallas_setup, "margin", "include_distances",
+    ) == set(tuning.DEFAULT_KNOBS)
+
+
 def test_standard_grid_includes_int8_candidate():
     grid = tuning.knob_grid("standard")
     assert any(c["precision"] == "int8" for c in grid)
@@ -276,27 +330,21 @@ def test_standard_grid_includes_int8_candidate():
 
 
 def test_grid_covers_sub_int8_arms_and_refuses_pq_fused():
-    """The compressed tiers enter the grid where the roofline says
-    they pay: int4 x streaming (the headline hbm_bound attack) and
-    both pq db-streaming strategies sit in standard; full adds the
-    int4 x fused cross.  pq x fused appears at NO level — the kernel
+    """The compressed tier enters the grid where the roofline says
+    it pays: both pq db-streaming strategies sit in standard.
+    pq x fused appears at NO level — the kernel
     refuses it (carry soundness unproven for reconstruction-space
     scores), so a grid that emitted it would crash the tuner."""
     std = tuning.knob_grid("standard")
-    assert any(c["precision"] == "int4" and c["kernel"] == "streaming"
-               for c in std)
     assert any(c["precision"] == "pq" and c["kernel"] == "streaming"
                for c in std)
     assert any(c["precision"] == "pq" and c["kernel"] == "tiled"
                for c in std)
-    full = tuning.knob_grid("full")
-    assert any(c["precision"] == "int4" and c["kernel"] == "fused"
-               for c in full)
     for level in ("quick", "standard", "full"):
         assert all(not (c["precision"] == "pq" and c["kernel"] == "fused")
                    for c in tuning.knob_grid(level)), level
     # quick stays sub-int8-free (CPU-interpret friendly minimal set)
-    assert all(c["precision"] not in ("int4", "pq")
+    assert all(c["precision"] != "pq"
                for c in tuning.knob_grid("quick"))
 
 
