@@ -127,6 +127,7 @@ MERGE_SELECTED = "knn_tpu_merge_strategy_selected_total"
 MERGE_BYTES = "knn_tpu_merge_bytes_total"
 MERGE_STRAGGLER_GAP = "knn_tpu_merge_straggler_gap_seconds"
 SELECT_MERGE_CALLS = "knn_tpu_select_merge_calls_total"
+KERNEL_TERMS = "knn_tpu_kernel_terms_total"
 
 # --- host-RAM shard tier (knn_tpu.parallel.sharded) --------------------
 HOSTTIER_SWEEPS = "knn_tpu_hosttier_sweeps_total"
@@ -405,6 +406,13 @@ CATALOG = {
         "select_merge_geometry: the candidate width at least twice the "
         "merged width) or the top-(m+2) ran over the kernel's "
         "candidates as they are."),
+    KERNEL_TERMS: (
+        "counter", ("terms",),
+        "Batches of search_certified(selector='pallas'), by the "
+        "products of the bf16x3 split their kernel formed "
+        "(ops.pallas_knn.BF16X3_TERMS): 'hh+hl+lh' the full sum, "
+        "'hh+lh' where every row is bf16-exact, 'hh' where the batch "
+        "is too — 3, 2 or 1 MXU passes."),
     MERGE_STRAGGLER_GAP: (
         "gauge", (),
         "Max-minus-min per-host local search wall time of the last "

@@ -76,6 +76,10 @@ they are.
 The kernel computes in float32 (precision configurable) because the
 certificate's tolerance must be float32-tight; a bf16 coarse pass would
 blur v_excl by ~1000x the k-th/(k+1)-th distance gap and never certify.
+The default reaches it by splitting both operands into bf16 halves
+(three MXU passes); on rows whose float32 values ARE their bf16 cast
+(byte corpora) the low half is all zeros, and the passes that would
+multiply by it are not made (``BF16X3_TERMS``): the same bits, one pass.
 
 This is the ApproxTopK/PartialReduce shape (TPU-KNN paper, PAPERS.md) made
 exact: fused with the distance matmul, two survivors instead of one, and a
@@ -186,6 +190,61 @@ _I32MAX = jnp.iinfo(jnp.int32).max
 #: error, measured: certificate-hostile) and is no mode.
 PRECISIONS = CERTIFIED_PRECISIONS
 
+#: the products of the "bf16x3" split a launch forms, named by their
+#: (query, row) halves: h = the bf16 cast, l = the bf16 of what the cast
+#: left.  A float32 whose low 16 bits are zero IS its bf16 cast, so its l
+#: half is exactly zero: where every row is such a value (byte corpora:
+#: whole numbers 0...255) ``qh.tl`` is a matrix of exact zeros, and where
+#: the batch is too so is ``ql.th``.  Adding one changes no bit of the
+#: sum, so the launch leaves it out, with the ``tl`` stream and its
+#: prologue: one MXU pass and one row stream for three and two.  Read
+#: off the data by the caller (``bf16x3_terms``), never set by one; rows
+#: with any inexact value run the full sum whatever the batch.
+BF16X3_TERMS = ("hh+hl+lh", "hh+lh", "hh")
+
+
+def lo_halves_zero(x: np.ndarray) -> bool:
+    """Whether every value of a host array is bf16-exact as float32: the
+    low 16 bits of each are zero.  One OR-reduction over the values taken
+    as uint32, no temporary of the array's size (float32 input: none)."""
+    bits = np.ascontiguousarray(x, dtype=np.float32).reshape(-1).view(
+        np.uint32)
+    return not int(np.bitwise_or.reduce(bits)) & 0xFFFF
+
+
+def bf16x3_terms(rows_lo_zero: bool, batch_lo_zero: bool) -> str:
+    """The entry of ``BF16X3_TERMS`` for a placement whose rows are all
+    bf16-exact (or not) and a batch that is (or not)."""
+    if not rows_lo_zero:
+        return BF16X3_TERMS[0]
+    return BF16X3_TERMS[2] if batch_lo_zero else BF16X3_TERMS[1]
+
+
+def _split_qt(q, th, tl, terms: str):
+    """``q.t`` of one dim chunk by the "bf16x3" split, for the tiled and
+    the streaming kernel alike — ONE arithmetic, which their bitwise
+    contract rests on.  ``q`` [BQ, chunk] f32 splits here; ``th`` is the
+    rows' high half, ``tl`` their low half — the tiled body's block ref,
+    read where ``terms`` keeps ``hl`` and where it always was, or the
+    streaming loop's loaded buffer — and unused where it does not.
+    The full sum is qh.th + qh.tl + ql.th (ql.tl dropped: <= 2^-18
+    |q||t|, covered by kernel_tolerance's 2^-14 factor); a term left out
+    by ``terms`` is one whose low operand the caller saw to be all zero
+    (``BF16X3_TERMS``)."""
+    dn = (((1,), (1,)), ((), ()))
+    qh = q.astype(jnp.bfloat16)
+    if "lh" in terms:
+        ql = (q - qh.astype(jnp.float32)).astype(jnp.bfloat16)
+    qt = lax.dot_general(qh, th, dn, preferred_element_type=jnp.float32)
+    if "hl" in terms:
+        qt = qt + lax.dot_general(qh, tl[:], dn,
+                                  preferred_element_type=jnp.float32)
+    if "lh" in terms:
+        qt = qt + lax.dot_general(ql, th, dn,
+                                  preferred_element_type=jnp.float32)
+    return qt
+
+
 #: kernel/emitter code version: BUMP whenever the kernel arithmetic, the
 #: emitters, or the knob semantics change — the autotuner's persisted
 #: winner cache keys on it (tuning.cache.cache_key), so winners measured
@@ -201,7 +260,9 @@ PRECISIONS = CERTIFIED_PRECISIONS
 #: 7 = the knob domain narrowed (PR 29): no select-layout or bin-width
 #: knob, no 4-bit and no single-pass DEFAULT precision — a persisted
 #: winner that names one is never looked up again.
-KERNEL_VERSION = 7
+#: 8 = the bf16x3 product drops the terms whose low operand is all zero
+#: (PR 30, ``BF16X3_TERMS``): the tuner's timings depend on its rows.
+KERNEL_VERSION = 8
 
 #: relative slack of the device rank stage's direct-difference f32
 #: distances: per-term (q-t)^2 rounding plus the depth-7 tree reduce give
@@ -394,7 +455,8 @@ def _pq_onehot_qt(lut, codes_u8, *, tile_n: int, pq_shape):
 
 
 def _kernel(q_ref, *refs, tile_n: int, survivors: int, nd: int,
-            precision: str, ti_axis: int = 1, pq_shape=None):
+            precision: str, ti_axis: int = 1, pq_shape=None,
+            terms: str = BF16X3_TERMS[0]):
     ti = pl.program_id(ti_axis)  # 1 = query_major grid, 0 = db_major
     di = pl.program_id(2)
     q = q_ref[:]
@@ -402,18 +464,14 @@ def _kernel(q_ref, *refs, tile_n: int, survivors: int, nd: int,
     if precision == "bf16x3":
         # db high/low bf16 parts arrive PRECOMPUTED (one XLA pass per
         # call instead of a per-cell VPU split redone for every query
-        # block); only the small q block splits in-kernel
-        th_ref, tl_ref, tn_ref, d_ref, i_ref, b_ref, *scratch = refs
-        th = th_ref[:]
-        qh = q.astype(jnp.bfloat16)
-        ql = (q - qh.astype(jnp.float32)).astype(jnp.bfloat16)
-        # q.t = qh.th + qh.tl + ql.th (+ ql.tl dropped: <= 2^-18 |q||t|,
-        # covered by kernel_tolerance's 2^-14 factor)
-        qt = (lax.dot_general(qh, th, dn, preferred_element_type=jnp.float32)
-              + lax.dot_general(qh, tl_ref[:], dn,
-                                preferred_element_type=jnp.float32)
-              + lax.dot_general(ql, th, dn,
-                                preferred_element_type=jnp.float32))
+        # block); only the small q block splits in-kernel.  The low part
+        # is no operand where ``terms`` drops its product
+        if "hl" in terms:
+            th_ref, tl_ref, tn_ref, d_ref, i_ref, b_ref, *scratch = refs
+        else:
+            th_ref, tn_ref, d_ref, i_ref, b_ref, *scratch = refs
+            tl_ref = None
+        qt = _split_qt(q, th_ref[:], tl_ref, terms)
     elif precision == "bf16x3f":
         # fused form of the same sum: ONE dot over a 3x contraction
         t3_ref, tn_ref, d_ref, i_ref, b_ref, *scratch = refs
@@ -578,7 +636,7 @@ def _stream_kernel(q_ref, *refs, tile_n: int, survivors: int, out_w: int,
                    bound_w: int, n_tiles: int, nd: int, precision: str,
                    n_parts: int, chunk_w: int, aux_rows: int = 8,
                    fused: bool = False, keep: Optional[int] = None,
-                   pq_shape=None):
+                   pq_shape=None, terms: str = BF16X3_TERMS[0]):
     """One launch per (batch, shard): the db-side arrays stay in HBM and
     stream tile-by-tile through TWO VMEM scratch slots via explicit
     async copies — tile i+1's HBM->VMEM copy overlaps tile i's MXU
@@ -591,8 +649,9 @@ def _stream_kernel(q_ref, *refs, tile_n: int, survivors: int, out_w: int,
 
     Ref layout (inputs, then outputs, then scratch):
       [qsc VMEM ref]                int8 only: [BQ, 128] query scales
-      [db part HBM refs x n_parts]  bf16x3: th, tl | bf16x3f: t3 |
-                                    int8: quantized db | else: db
+      [db part HBM refs x n_parts]  bf16x3: th, tl (th alone where
+                                    ``terms`` has no hl) | bf16x3f: t3
+                                    | int8: quantized db | else: db
       tn HBM ref                    [aux_rows, n_tiles * tile_n] row
                                     norms (int8: norms over scales)
       d_ref, i_ref, b_ref           full-width VMEM output blocks
@@ -651,15 +710,8 @@ def _stream_kernel(q_ref, *refs, tile_n: int, survivors: int, out_w: int,
             return lax.dot_general(qc, t, dn,
                                    preferred_element_type=jnp.int32)
         if precision == "bf16x3":
-            th, tl = bufs
-            qh = qc.astype(jnp.bfloat16)
-            ql = (qc - qh.astype(jnp.float32)).astype(jnp.bfloat16)
-            return (lax.dot_general(qh, th, dn,
-                                    preferred_element_type=jnp.float32)
-                    + lax.dot_general(qh, tl, dn,
-                                      preferred_element_type=jnp.float32)
-                    + lax.dot_general(ql, th, dn,
-                                      preferred_element_type=jnp.float32))
+            # [th, tl], or [th] where ``terms`` drops the low part
+            return _split_qt(qc, bufs[0], bufs[-1], terms)
         if precision == "bf16x3f":
             t3, = bufs
             qh = qc.astype(jnp.bfloat16)
@@ -829,7 +881,7 @@ def _pad_axis(x, multiple: int, axis: int, fill: float = 0.0):
 @functools.partial(
     jax.jit, static_argnames=("block_q", "tile_n", "survivors",
                               "precision", "interpret", "grid_order",
-                              "kernel", "offset", "keep")
+                              "kernel", "offset", "keep", "terms")
 )
 def _bin_candidates(
     queries: jax.Array,
@@ -846,6 +898,7 @@ def _bin_candidates(
     offset: float = 0.0,
     keep: Optional[int] = None,
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
+    terms: str = BF16X3_TERMS[0],
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Kernel launch on padded shapes.  Returns
 
@@ -875,7 +928,15 @@ def _bin_candidates(
     there is no quantize-on-the-fly arm).  The query operand becomes
     the per-query LUT built in the XLA prologue; scores are against the
     RECONSTRUCTION t̂ (see ``_pq_onehot_qt``), certified by the
-    per-subspace bound in ops.pq."""
+    per-subspace bound in ops.pq.
+
+    ``terms`` (``BF16X3_TERMS``; "bf16x3" only) names the products of
+    the split the launch forms.  A caller that has SEEN every row (and
+    the batch) to be bf16-exact drops the products of their zero low
+    halves: without ``hl`` the rows' low half is neither computed here
+    nor streamed, without ``lh`` the kernel never forms the batch's.
+    Outputs are the full sum's bit for bit on such data, and wrong on
+    any other: nothing here checks."""
     queries = _pad_axis(queries.astype(jnp.float32), block_q, 0)
     queries = _pad_axis(queries, DIM_CHUNK, 1)
     n_rows = db.shape[0]
@@ -893,6 +954,11 @@ def _bin_candidates(
         raise ValueError(f"grid_order {grid_order!r} not in {GRID_ORDERS}")
     if kernel not in KERNELS:
         raise ValueError(f"kernel {kernel!r} not in {KERNELS}")
+    if terms not in BF16X3_TERMS or (
+            terms != BF16X3_TERMS[0] and precision != "bf16x3"):
+        raise ValueError(
+            f"terms {terms!r} not in {BF16X3_TERMS}, or dropped from a "
+            f"product that precision={precision!r} does not form")
     if kernel in ("streaming", "fused") and grid_order != "query_major":
         # the streaming/fused launches have no db grid axis to reorder:
         # their tile loop is inherently query-major.  Refuse rather than
@@ -924,9 +990,10 @@ def _bin_candidates(
         # streams bf16 tiles and never re-derives them per query block
         with jax.named_scope(SCOPE_OPERAND_PREP):
             th = db.astype(jnp.bfloat16)
-            tl = (db - th.astype(jnp.float32)).astype(jnp.bfloat16)
+            if "hl" in terms:
+                tl = (db - th.astype(jnp.float32)).astype(jnp.bfloat16)
         if precision == "bf16x3":
-            db_inputs = [th, tl]
+            db_inputs = [th, tl] if "hl" in terms else [th]
             chunk_w = DIM_CHUNK
         else:
             # per dim chunk c the fused contraction reads [th_c|tl_c|th_c]
@@ -1037,13 +1104,14 @@ def _bin_candidates(
             precision=precision, chunk_w=chunk_w, interpret=interpret,
             q_extra=q_extra, aux_rows=aux_rows,
             fused=kernel == "fused", keep=keep, pq_shape=pq_shape,
+            terms=terms,
         )
 
     db_major = grid_order == "db_major"
     body = functools.partial(
         _kernel, tile_n=tile_n, survivors=survivors, nd=nd,
         precision=precision, ti_axis=0 if db_major else 1,
-        pq_shape=pq_shape,
+        pq_shape=pq_shape, terms=terms,
     )
     # the query operand block: one DIM_CHUNK slice per grid step for the
     # feature-chunked arms; PQ's LUT has no chunk loop (nd == 1) and
@@ -1117,7 +1185,7 @@ def _bin_candidates(
 def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
                  tile_n, survivors, out_w, bound_w, n_tiles, nd, precision,
                  chunk_w, interpret, q_extra=(), aux_rows=8, fused=False,
-                 keep=None, pq_shape=None):
+                 keep=None, pq_shape=None, terms=BF16X3_TERMS[0]):
     """The streaming ``pallas_call``: grid over query blocks only, db
     parts + row norms left in compiler-chosen (HBM) memory and streamed
     by the kernel's own double-buffered DMA loop (``_stream_kernel``).
@@ -1131,7 +1199,7 @@ def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
         _stream_kernel, tile_n=tile_n, survivors=survivors, out_w=out_w,
         bound_w=bound_w, n_tiles=n_tiles, nd=nd, precision=precision,
         n_parts=n_parts, chunk_w=chunk_w, aux_rows=aux_rows,
-        fused=fused, keep=keep, pq_shape=pq_shape,
+        fused=fused, keep=keep, pq_shape=pq_shape, terms=terms,
     )
     part_dtype = db_inputs[0].dtype
     kwargs = {}
@@ -1181,7 +1249,7 @@ def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
     static_argnames=("m", "tile_n", "block_q", "survivors",
                      "precision", "final_select", "interpret",
                      "final_recall_target", "grid_order", "kernel",
-                     "offset"),
+                     "offset", "terms"),
 )
 def local_certified_candidates(
     q: jax.Array,
@@ -1200,6 +1268,7 @@ def local_certified_candidates(
     db_int8: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
     offset: float = 0.0,
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
+    terms: str = BF16X3_TERMS[0],
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The whole device-side certified coarse pass against one db (shard):
 
@@ -1246,7 +1315,7 @@ def local_certified_candidates(
         q, t, m, tile_n=tile_n, block_q=block_q, survivors=survivors,
         precision=precision, interpret=interpret,
         final_select=final_select, grid_order=grid_order, kernel=kernel,
-        db_int8=db_int8, offset=offset, db_pq=db_pq,
+        db_int8=db_int8, offset=offset, db_pq=db_pq, terms=terms,
     )
     return local_select_rescore(
         q, t, cd, ci, bounds, m, final_select=final_select,
@@ -1258,7 +1327,7 @@ def local_certified_candidates(
     jax.jit,
     static_argnames=("m", "tile_n", "block_q", "survivors",
                      "precision", "interpret", "final_select",
-                     "grid_order", "kernel", "offset"),
+                     "grid_order", "kernel", "offset", "terms"),
 )
 def local_coarse_candidates(
     q: jax.Array,
@@ -1276,6 +1345,7 @@ def local_coarse_candidates(
     offset: float = 0.0,
     final_select: str = "exact",
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
+    terms: str = BF16X3_TERMS[0],
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Stage 1 of :func:`local_certified_candidates` — the db-streaming
     coarse pass alone: resolve the effective tile, launch the kernel,
@@ -1305,7 +1375,7 @@ def local_coarse_candidates(
             precision=precision, interpret=interpret,
             grid_order=grid_order, kernel=kernel, db_int8=db_int8,
             offset=offset, keep=m + 2 if kernel == "fused" else None,
-            db_pq=db_pq,
+            db_pq=db_pq, terms=terms,
         )
     n_q = q.shape[0]
     return cd[:n_q], ci[:n_q], bounds[:n_q]

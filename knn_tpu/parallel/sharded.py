@@ -747,6 +747,9 @@ class ShardedKNN:
         self.k = k
         self.metric = metric
         self._db_norm_max_cache: Optional[float] = None
+        # whether every placed row value is bf16-exact as float32: the
+        # same walk's (_db_norm_max), read by _kernel_terms
+        self._rows_lo_zero = False
         self.train_tile = train_tile
         self.n_train = n_train
         #: user-facing query/input dim — dot placements append one norm-
@@ -1207,15 +1210,37 @@ class ShardedKNN:
         query-independent half of the certificate tolerance; a full-DB
         float64 pass, so computed once per placement and cached."""
         if self._db_norm_max_cache is None:
+            from knn_tpu.ops.pallas_knn import lo_halves_zero
+
             db = self._host_train()
             # row chunks: the same per-row arithmetic, without float64
             # temporaries the size of the whole database (15 GB and a
-            # minute of page faults at GIST 1M x 960)
-            self._db_norm_max_cache = max(
-                float((db[lo:lo + 8192].astype(np.float64) ** 2)
-                      .sum(-1).max())
-                for lo in range(0, db.shape[0], 8192))
+            # minute of page faults at GIST 1M x 960).  The same walk
+            # asks each chunk whether its values are all bf16-exact,
+            # until one is not (uniform floats: the first), for the
+            # kernel's choice of products (_kernel_terms)
+            best, exact = 0.0, True
+            for lo in range(0, db.shape[0], 8192):
+                chunk = db[lo:lo + 8192]
+                exact = exact and lo_halves_zero(chunk)
+                sq = chunk.astype(np.float64)
+                np.multiply(sq, sq, out=sq)
+                best = max(best, float(sq.sum(-1).max()))
+            self._rows_lo_zero = exact
+            self._db_norm_max_cache = best
         return self._db_norm_max_cache
+
+    def _kernel_terms(self, q_np: np.ndarray, precision: str) -> str:
+        """Which products of the "bf16x3" split this call's kernel forms
+        (ops.pallas_knn.BF16X3_TERMS), from what the placement's walk
+        saw of the rows and what ``q_np`` (the call's queries as the
+        program will get them: normalized, augmented) says of itself.
+        Every other precision forms what it always did."""
+        from knn_tpu.ops.pallas_knn import bf16x3_terms, lo_halves_zero
+
+        self._db_norm_max()
+        rows = precision == "bf16x3" and self._rows_lo_zero
+        return bf16x3_terms(rows, rows and lo_halves_zero(q_np))
 
     def _int8_placement(self) -> dict:
         """The quantized db placement for the int8 coarse pass, built
@@ -1530,9 +1555,10 @@ class ShardedKNN:
                     # kernel geometry, the compiled program and its
                     # operand tail: resolved in this stage, so the spans
                     # below time batches only
+                    terms = self._kernel_terms(q_np, knobs["precision"])
                     prog, m_prog, w, interpret = self._pallas_setup(
                         m - self.k, include_distances=return_distances,
-                        **knobs)
+                        terms=terms, **knobs)
                     ops_tail = self._pallas_operands(knobs["precision"])
             call.set("queries", n_q)
             call.set("batches", len(batches))
@@ -1606,6 +1632,13 @@ class ShardedKNN:
                     _mn.SELECT_MERGE_CALLS,
                     engaged="true" if merged_width < width else "false",
                 ).inc(len(batches))
+                # the products the kernel's bf16 split formed, read off
+                # the rows and the batch (_kernel_terms): 3 MXU passes,
+                # or 2 or 1 where a low half was all zero
+                merged["terms"] = terms
+                merged["mxu_passes"] = terms.count("+") + 1
+                obs.counter(_mn.KERNEL_TERMS, terms=terms).inc(
+                    len(batches))
             for key, value in merged.items():
                 call.set(key, value)
             stats = {
@@ -1618,7 +1651,9 @@ class ShardedKNN:
                 stats["rank_corrected_queries"] = n_corrected
                 # interpret: the value _pallas_setup resolved and the
                 # kernel ran with, so a caller can tell which one answered
-                stats["pallas_knobs"] = {**knobs, "interpret": interpret}
+                stats["pallas_knobs"] = {
+                    **knobs, "interpret": interpret, "terms": terms,
+                    "mxu_passes": merged["mxu_passes"]}
                 stats["tuning"] = tune_info
             # mirror the quality signals into the telemetry registry —
             # the per-call stats dict stays the API, the registry
@@ -1777,7 +1812,8 @@ class ShardedKNN:
                       include_distances: bool = True,
                       final_recall_target: Optional[float] = None,
                       grid_order: str = "query_major",
-                      kernel: str = "tiled"):
+                      kernel: str = "tiled",
+                      terms: str = "hh+hl+lh"):
         """(program, m, analysis_window, interpret) for the one-pass
         certified path — the ONE home of the kernel-geometry margin cap
         and the packed-output window, shared by :meth:`_certify_pallas`
@@ -1788,7 +1824,9 @@ class ShardedKNN:
         (compiled on a TPU backend, Pallas interpret mode elsewhere —
         the CPU tests), handed to the program builders, which hand it to
         the kernel, and returned so ``stats["pallas_knobs"]`` reports
-        the value the kernel was actually given."""
+        the value the kernel was actually given.  ``terms`` is
+        :meth:`_kernel_terms`'s reading of the data, no knob: left out,
+        the program forms every product and is right for any rows."""
         from knn_tpu.ops.pallas_knn import (
             TILE_N,
             _geometry,
@@ -1849,7 +1887,7 @@ class ShardedKNN:
             final_recall_target=final_recall_target,
             grid_order=grid_order, kernel=kernel,
             quant_offset=quant_offset, dcn_merge=self.dcn_merge,
-            interpret=interpret,
+            interpret=interpret, terms=terms,
         )
         return prog, m, _analysis_window(self.k, m), interpret
 
@@ -2083,6 +2121,7 @@ def _pallas_certified_program(
     quant_offset: float = 0.0,
     dcn_merge: Optional[str] = None,
     interpret: Optional[bool] = None,
+    terms: str = "hh+hl+lh",
 ):
     """ONE-pass sharded self-certifying coarse select + device rank +
     device certificate (ops.pallas_knn.local_certified_candidates per
@@ -2142,7 +2181,7 @@ def _pallas_certified_program(
             block_q=eff_bq, final_select=final_select, precision=precision,
             final_recall_target=final_recall_target,
             grid_order=grid_order, kernel=kernel, interpret=interpret,
-            db_int8=db_q, db_pq=db_pq, offset=quant_offset,
+            db_int8=db_q, db_pq=db_pq, offset=quant_offset, terms=terms,
         )
         return _certify_pack_spmd(
             q, t, d32, li, lb, consts=consts, db_norm_max=db_norm_max,

@@ -11,15 +11,21 @@ With no arguments it compiles the certified coarse pass (compiled,
 resolves when nobody picks any (``tuning.resolve_full``, no winner
 cache), for each of the three kernels; asks for fused at
 block_q=256 at SIFT, which the library's own VMEM model must refuse
-before Mosaic is asked; and compiles the final select's bin-merge
-kernel at a 5M-row chip's candidate width (``bigann20m``).  Flags pick
-one geometry instead:
+before Mosaic is asked; compiles the whole certified program with the
+kernel's one-product form (``--terms hh``: what a byte corpus and a
+byte batch run, ``ops.pallas_knn.BF16X3_TERMS``) at 5M x 128 on one
+chip and at 20M x 128 on the 1x4 mesh, printing what each keeps on a
+chip; and compiles the final select's bin-merge kernel at a 5M-row
+chip's candidate width (``bigann20m``).  Flags pick one geometry
+instead:
 
     python scripts/aot_compile_check.py --shape gist --block-q 128
     python scripts/aot_compile_check.py --shape sift --kernel streaming \\
         --block-q 256 --probe          # the compiler's scoped-VMEM need
     python scripts/aot_compile_check.py --shape bigann20m --mesh 1x4 \\
         --merge ring                   # the full SPMD certified program
+    python scripts/aot_compile_check.py --shape bigann5m --mesh 1x1 \\
+        --terms hh                     # ... of a byte corpus and batch
 
 Prints one line per case; exits non-zero if any case did not do what
 was expected of it.
@@ -51,6 +57,8 @@ SHAPES = {
     "sift": (1_000_000, 128, 100),
     "gist": (1_000_000, 960, 100),
     "glove": (1_183_514, 300, 50),
+    # one chip of the corpus below (benchmark/configs/bigann5m.json)
+    "bigann5m": (5_000_000, 128, 100),
     # the four-chip cell's corpus (benchmark/configs/bigann20m-x4.json);
     # asked for by name with --mesh 1x4, not part of a bare run
     "bigann20m": (20_000_000, 128, 100),
@@ -64,7 +72,7 @@ def _topology_devices():
         topology_name=TOPOLOGY, platform="tpu").devices
 
 
-def _kernel_case(shape: str, knobs: dict, devices):
+def _kernel_case(shape: str, knobs: dict, devices, terms=None):
     """(fn, avals) of the compiled certified coarse pass on ONE chip."""
     import jax
     import jax.numpy as jnp
@@ -77,12 +85,15 @@ def _kernel_case(shape: str, knobs: dict, devices):
     q = jax.ShapeDtypeStruct((NQ, d), jnp.float32, sharding=sh)
     db = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=sh)
     kw = {kk: v for kk, v in knobs.items() if v is not None}
+    if terms:
+        kw["terms"] = terms
     fn = jax.jit(functools.partial(
         local_certified_candidates, m=k + MARGIN, interpret=False, **kw))
     return fn, (q, db)
 
 
-def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str):
+def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
+               terms=None):
     """(fn, avals) of the full sharded certified program
     (parallel.sharded._pallas_certified_program) on a topology mesh."""
     import jax
@@ -101,6 +112,8 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str):
     rows = -(-n // ds) * ds
     kw = {kk: v for kk, v in knobs.items()
           if kk not in ("tile_n", "precision")}
+    if terms:
+        kw["terms"] = terms
     prog = _pallas_certified_program(
         mesh, k + MARGIN, k, merge, knobs["tile_n"] or TILE_N,
         knobs["precision"], n_train=n, interpret=False, **kw)
@@ -138,8 +151,11 @@ def _merge_case(shape: str, db_shards: int, devices):
                 jax.ShapeDtypeStruct((NQ, width), jnp.int32, sharding=sh))
 
 
-def _compile(fn, avals):
-    fn.lower(*avals).compile()
+def _compile(fn, avals) -> str:
+    """Compile, and say what the program keeps on a chip."""
+    mem = fn.lower(*avals).compile().memory_analysis()
+    return (f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB + "
+            f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB a chip")
 
 
 def _probe_need(make_case):
@@ -175,18 +191,26 @@ def _probe_need(make_case):
 
 def default_cases():
     """The table a bare run prints: (name, shape, knob overrides,
-    expectation) — "compiles", or "refused" = the library's own VMEM
-    model must refuse it with a ValueError before Mosaic is asked."""
+    expectation[, mesh, terms]) — "compiles", or "refused" = the
+    library's own VMEM model must refuse it with a ValueError before
+    Mosaic is asked."""
     cases = [(f"{shape} {kernel} defaults", shape, {"kernel": kernel},
               "compiles")
              for kernel in ("tiled", "streaming", "fused")
              for shape in ("sift", "gist", "glove")]
     cases.append(("sift fused block_q=256", "sift",
                   {"kernel": "fused", "block_q": 256}, "refused"))
+    # the whole program of a byte corpus and a byte batch: one product,
+    # one row stream (2.56 + 4.66 GB a chip where the full sum's program
+    # keeps 2.56 + 5.94)
+    cases += [(f"{shape} program mesh={mesh[0]}x{mesh[1]} terms=hh", shape,
+               {}, "compiles", mesh, "hh")
+              for shape, mesh in (("bigann5m", (1, 1)),
+                                  ("bigann20m", (1, 4)))]
     return cases
 
 
-def run_case(name, shape, overrides, expect, devices, *, mesh=None,
+def run_case(name, shape, overrides, expect, mesh, terms, devices, *,
              merge="ring", probe=False) -> bool:
     from knn_tpu import tuning
 
@@ -197,8 +221,8 @@ def run_case(name, shape, overrides, expect, devices, *, mesh=None,
 
     def make_case():
         if mesh is not None:
-            return _spmd_case(shape, knobs, devices, mesh, merge)
-        return _kernel_case(shape, knobs, devices)
+            return _spmd_case(shape, knobs, devices, mesh, merge, terms)
+        return _kernel_case(shape, knobs, devices, terms)
 
     t0 = time.time()
     if probe:
@@ -208,8 +232,7 @@ def run_case(name, shape, overrides, expect, devices, *, mesh=None,
               f"({time.time() - t0:.0f}s)", flush=True)
         return ok_at is not None
     try:
-        _compile(*make_case())
-        got, detail = "compiles", ""
+        got, detail = "compiles", ": " + _compile(*make_case())
     except ValueError as e:
         got, detail = "refused", f": {e}"
     except Exception as e:  # noqa: BLE001 — Mosaic/XLA refusal, reported
@@ -234,6 +257,10 @@ def main(argv=None) -> int:
                     "SPMD certified program on that topology mesh")
     ap.add_argument("--merge", default="ring",
                     choices=("ring", "allgather"))
+    ap.add_argument("--terms", choices=("hh+hl+lh", "hh+lh", "hh"),
+                    help="the products of the bf16x3 split the kernel "
+                    "forms; the library reads this off the data "
+                    "(ops.pallas_knn.BF16X3_TERMS), here it is asked for")
     ap.add_argument("--probe", action="store_true",
                     help="report the scoped-VMEM need Mosaic names "
                     "instead of compiling at the library's own limit")
@@ -252,7 +279,9 @@ def main(argv=None) -> int:
             "final_select": args.final_select,
         }
         overrides = {k: v for k, v in overrides.items() if v is not None}
-        label = " ".join(f"{k}={v}" for k, v in overrides.items())
+        label = " ".join(f"{k}={v}" for k, v in {
+            **overrides, **({"terms": args.terms} if args.terms else {}),
+        }.items())
         cases = [(f"{args.shape} {label or 'defaults'}", args.shape,
                   overrides, "compiles")]
     mesh = None
@@ -260,8 +289,10 @@ def main(argv=None) -> int:
         mesh = tuple(int(x) for x in args.mesh.lower().split("x"))
         cases = [(f"{name} mesh={args.mesh} merge={args.merge}", *rest)
                  for name, *rest in cases]
-    ok = [run_case(*case, devices, mesh=mesh, merge=args.merge,
-                   probe=args.probe) for case in cases]
+    # a case names its own mesh and terms, or takes the command line's
+    cases = [(*case, mesh, args.terms)[:6] for case in cases]
+    ok = [run_case(*case, devices, merge=args.merge, probe=args.probe)
+          for case in cases]
     # the final select's bin-merge kernel, where the shape's width a chip
     # engages it: of SHAPES only bigann20m does, which a bare run asks for
     # over its four chips

@@ -247,7 +247,7 @@ def test_stale_kernel_version_entry_falls_back_to_defaults(cache_path):
     # it even though "precision": "pq" is a perfectly current knob
     from knn_tpu.ops.pallas_knn import KERNEL_VERSION
 
-    assert KERNEL_VERSION == 7
+    assert KERNEL_VERSION == 8
     cache.put(base + "|kv4", {"knobs": {**tuning.DEFAULT_KNOBS,
                                         "precision": "pq",
                                         "kernel": "streaming"}})
@@ -255,6 +255,10 @@ def test_stale_kernel_version_entry_falls_back_to_defaults(cache_path):
     # bin-merge (5 -> 6) changed the tail its timing loop runs
     cache.put(base + "|kv5", {"knobs": {**tuning.DEFAULT_KNOBS,
                                         "block_q": 128}})
+    # ... and a version-7 winner: timed when the bf16x3 product formed
+    # all three terms on every corpus (7 -> 8)
+    cache.put(base + "|kv7", {"knobs": {**tuning.DEFAULT_KNOBS,
+                                        "tile_n": 512}})
     knobs, info = tuning.resolve_full(700, 16, 5, cache_path=cache_path)
     assert info["source"] == "default"
     assert knobs == tuning.DEFAULT_KNOBS
@@ -293,8 +297,11 @@ def test_version_6_winner_naming_a_removed_knob_is_never_used(
     _, _, stats = prog.search_certified(
         db[:4], selector="pallas", tune_cache=cache_path)
     assert stats["tuning"]["source"] == "default"
+    # beside the knobs: what the program resolved for itself, from the
+    # backend (interpret) and from the data (terms, mxu_passes)
     assert {kk: v for kk, v in stats["pallas_knobs"].items()
-            if kk != "interpret"} == tuning.DEFAULT_KNOBS
+            if kk not in ("interpret", "terms", "mxu_passes")
+            } == tuning.DEFAULT_KNOBS
 
 
 def test_default_knobs_are_the_kernel_shaping_arguments():
@@ -315,7 +322,7 @@ def test_default_knobs_are_the_kernel_shaping_arguments():
         "batch_size", "return_distances", "recall_target", "tune_cache",
         "return_sqrt") == set(tuning.DEFAULT_KNOBS)
     assert kwargs_of(
-        ShardedKNN._pallas_setup, "margin", "include_distances",
+        ShardedKNN._pallas_setup, "margin", "include_distances", "terms",
     ) == set(tuning.DEFAULT_KNOBS)
 
 
