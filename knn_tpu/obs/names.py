@@ -73,6 +73,7 @@ CERTIFIED_GENUINE_MISSES = "knn_tpu_certified_fallback_genuine_misses_total"
 CERTIFIED_FALSE_ALARMS = "knn_tpu_certified_fallback_false_alarms_total"
 CERTIFIED_HOST_EXACT = "knn_tpu_certified_host_exact_queries_total"
 CERTIFIED_RANK_CORRECTED = "knn_tpu_certified_rank_corrected_queries_total"
+CERTIFIED_METRIC_QUERIES = "knn_tpu_certified_metric_queries_total"
 CERTIFIED_QUANT_BOUND = "knn_tpu_certified_quant_bound"
 
 # --- autotuner (knn_tpu.tuning) ----------------------------------------
@@ -277,6 +278,11 @@ CATALOG = {
         "counter", (),
         "Pallas-selector queries whose near-tie runs were re-ranked in "
         "float64."),
+    CERTIFIED_METRIC_QUERIES: (
+        "counter", ("metric",),
+        "Queries processed by ShardedKNN.search_certified, by the "
+        "placement's metric (l2 / cosine / dot): which contract the "
+        "answers were held to."),
     CERTIFIED_QUANT_BOUND: (
         "histogram", (),
         "Per-query int8 certified quantization error bound epsilon "

@@ -140,12 +140,14 @@ def certification_tolerance(
 
 def host_exact_knn(
     db_np: np.ndarray, q_np: np.ndarray, k: int, *, tile: Optional[int] = None,
-    q_chunk: int = 8,
+    q_chunk: int = 8, metric: str = "l2",
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Unconditional last-resort exact KNN: tiled float64 direct-difference
     full scan on host (no expanded-square cancellation, no approximation,
     no certificate needed).  O(Q*N*D) host FLOPs — only for the handful of
-    queries that fail re-certification after the widened fallback."""
+    queries that fail re-certification after the widened fallback.
+    ``metric="dot"`` scans by the negated float64 inner product instead
+    (each product of two float32 values is exact in float64)."""
     n = db_np.shape[0]
     n_q = q_np.shape[0]
     k = min(k, n)
@@ -160,7 +162,10 @@ def host_exact_knn(
         cd, ci = bd[qlo : qlo + q_chunk], bi[qlo : qlo + q_chunk]
         for lo in range(0, n, tile):
             t = db_np[lo : lo + tile].astype(np.float64)
-            dt = ((qf[:, None, :] - t[None, :, :]) ** 2).sum(-1)
+            if metric == "dot":
+                dt = -(qf[:, None, :] * t[None, :, :]).sum(-1)
+            else:
+                dt = ((qf[:, None, :] - t[None, :, :]) ** 2).sum(-1)
             it = np.broadcast_to(
                 np.arange(lo, lo + t.shape[0], dtype=np.int64)[None, :], dt.shape
             )
@@ -185,6 +190,8 @@ def repair_uncertified(
     select_fn,
     max_widen: int,
     db_norm_max: Optional[float] = None,
+    dot_shift: Optional[float] = None,
+    dot_slack: float = 0.0,
 ) -> dict:
     """Shared fallback repair for both certified pipelines (single-device
     :func:`knn_search_certified` and the sharded
@@ -205,6 +212,19 @@ def repair_uncertified(
        queries whose k-th/widen-th gap is inside the f32 tolerance
        (heavy duplicate ties) — structurally rare.
 
+    ``dot_shift`` (inner-product placements, parallel.sharded: rows and
+    queries arrive norm-augmented, ``dot_shift`` = M, the largest squared
+    row norm): the refine and the host scan rank by the float64 NEGATED
+    INNER PRODUCT s = -q.t on the arrays as given (the query's appended
+    column is an exact zero), so ``d`` holds s at the repaired rows.  The
+    selection still scores in the augmented squared-L2 space, where a
+    row's exact value is D' = |q|^2 + M + 2 s + c_t with |c_t| <=
+    ``dot_slack`` / 2 (the appended column's float32 rounding).  So step
+    2 compares there: a row NOT selected has D'(u) >= v_w - tol, hence
+    2 s(u) >= v_w - tol - dot_slack / 2 - |q|^2 - M, and
+    ``|q|^2 + M + 2 s_k + tol + dot_slack < v_w`` proves s(u) > s_k
+    with dot_slack / 2 to spare.
+
     ``select_fn(q_bad [B,D], widen) -> (f32 scores [B, widen] ascending,
     candidate indices [B, widen])``.
     Mutates ``d``/``i`` in place at rows ``bad``; returns a stats dict:
@@ -222,17 +242,24 @@ def repair_uncertified(
     widen = min(max(2 * m, m + 64), max_widen)
     fs, fi = select_fn(q_np[bad], widen)
     fs = np.asarray(fs, dtype=np.float64)
-    fd2, fi2 = refine_exact(db_np, q_np[bad], np.asarray(fi), k)
+    metric = "l2" if dot_shift is None else "dot"
+    fd2, fi2 = refine_exact(db_np, q_np[bad], np.asarray(fi), k, metric)
     d[bad], i[bad] = fd2, fi2
+    q_norm = (q_np[bad].astype(np.float64) ** 2).sum(-1)
     tol = certification_tolerance(
-        q_np[bad], db_np, db_norm_max=db_norm_max
+        q_np[bad], db_np, db_norm_max=db_norm_max, q_norm=q_norm
     )
+    d_k = fd2[:, k - 1]
+    if dot_shift is not None:
+        # the k-th score in the selection's own space (docstring)
+        d_k = q_norm + dot_shift + 2.0 * d_k
+        tol = tol + dot_slack
     v_w = fs[:, -1]  # exclusion value of the widened f32 selection
-    still = np.flatnonzero(fd2[:, k - 1] + tol >= v_w)
+    still = np.flatnonzero(d_k + tol >= v_w)
     host_exact = 0
     if still.size:
         sb = bad[still]
-        d[sb], i[sb] = host_exact_knn(db_np, q_np[sb], k)
+        d[sb], i[sb] = host_exact_knn(db_np, q_np[sb], k, metric=metric)
         host_exact = int(sb.size)
     genuine = int((i[bad] != orig_i).any(axis=-1).sum())
     out = {

@@ -1409,12 +1409,16 @@ def _select_merge(cd: jax.Array, ci: jax.Array, groups: int, rows: int,
     if pad:
         cd = jnp.pad(cd, ((0, 0), (0, pad)), constant_values=jnp.inf)
         ci = jnp.pad(ci, ((0, 0), (0, pad)), constant_values=_I32MAX)
-    # two [block_q, rows * 128] input blocks of at most 4 MiB each
-    # (double buffered by the pipeline): 128 query rows at the 36
-    # lane-rows of a 5M-row shard, fewer as the shard and so ``rows``
-    # grows
-    block_q = min(n_q, max(8, min(
-        BLOCK_Q, (4 << 20) // (rows * BIN_W * 4) // 8 * 8)))
+    # two [block_q, rows * 128] input blocks of at most 3 MiB each,
+    # double buffered by the pipeline: Mosaic's 16 MiB of scoped VMEM
+    # hold them up to 3.1 MiB a block and not at 3.4 (16.14 MiB asked
+    # for, compiled for a v5e with no chip; 3.9 MiB was refused on the
+    # chip at the 62 lane-rows of a 2.5M-row shard at m+2 = 40).  128
+    # query rows at the 36 lane-rows of a 5M-row shard at m+2 = 130,
+    # fewer as ``rows`` grows: a power of two, so that a batch that is
+    # one splits evenly
+    fit = (3 << 20) // (rows * BIN_W * 4)
+    block_q = min(n_q, max(8, min(BLOCK_Q, 1 << (fit.bit_length() - 1))))
     out_w = SELECT_MERGE_SURVIVORS * BIN_W
     cell = lambda i, g: (i, g)  # noqa: E731
     kwargs = {}

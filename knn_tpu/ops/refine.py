@@ -82,6 +82,54 @@ def _pairwise_f64(queries: np.ndarray, cand: np.ndarray, metric: str) -> np.ndar
     raise ValueError(f"unknown metric {metric!r}")
 
 
+def _score_members(db_np: np.ndarray, queries_np: np.ndarray,
+                   cand: np.ndarray, rows: np.ndarray, metric: str,
+                   out: np.ndarray) -> None:
+    """``out[j]`` = the float64 ``metric`` value between query
+    ``rows[j]`` and db row ``cand[j]``: squared L2 by direct difference,
+    or the negated inner product (``"dot"``).  f32 -> f64 is exact, so
+    the in-place arithmetic on the widened rows equals widening both
+    sides first; each member's sum is its own."""
+    acc = db_np[cand].astype(np.float64)
+    if metric == "dot":
+        # products of two float32 values are exact in float64; only the
+        # sum rounds (pairwise: under (D+1) * 2^-53 * sum |q_i t_i|)
+        acc *= queries_np[rows]
+        np.sum(acc, axis=-1, out=out)
+        np.negative(out, out=out)
+    else:
+        acc -= queries_np[rows]
+        np.einsum("nd,nd->n", acc, acc, out=out)
+
+
+def exact_scores(db_np: np.ndarray, queries_np: np.ndarray,
+                 idx: np.ndarray, metric: str) -> np.ndarray:
+    """[Q, k] float64 ``metric`` values between each query and the db
+    rows ``idx`` [Q, k] names (:func:`_score_members`' arithmetic), made
+    a block of members at a time and shared among the pool's threads
+    like :func:`rank_correct_runs`' re-score.  Indices past the db (the
+    sentinel) read +inf."""
+    n_q, k = idx.shape
+    flat = np.asarray(idx, np.int64).reshape(-1)
+    safe = np.clip(flat, 0, db_np.shape[0] - 1)
+    rows = np.repeat(np.arange(n_q), k)
+    out = np.empty(flat.size)
+    block = _block_rows(db_np.shape[1])
+    starts = range(0, flat.size, block)
+
+    def score(lo: int) -> None:
+        _score_members(db_np, queries_np, safe[lo : lo + block],
+                       rows[lo : lo + block], metric, out[lo : lo + block])
+
+    if len(starts) > 1:
+        # list(): reading every result re-raises a worker's exception
+        list(_shared_pool().map(score, starts))
+    else:
+        for lo in starts:
+            score(lo)
+    return np.where(flat < db_np.shape[0], out, np.inf).reshape(n_q, k)
+
+
 def rank_correct_runs(
     gi: np.ndarray,
     tight: np.ndarray,
@@ -89,6 +137,7 @@ def rank_correct_runs(
     queries_np: np.ndarray,
     db_np: np.ndarray,
     d32k: Optional[np.ndarray] = None,
+    metric: str = "l2",
 ) -> Tuple[Optional[np.ndarray], np.ndarray, int]:
     """Float64 repair of a device-ranked candidate list from the near-tie
     mask ALONE — no distance matrix crosses the device->host link.
@@ -102,6 +151,15 @@ def rank_correct_runs(
     in float64 and re-sorted lexicographically IN PLACE: a correction can
     never cross an uninvolved neighbor, because the gap there exceeds the
     slack while corrections move less than a third of it.
+
+    ``metric`` is what the members are re-scored and re-sorted by:
+    squared L2 between the arrays as given, or ``"dot"``, the negated
+    float64 inner product.  A dot placement's rows and queries arrive
+    norm-augmented (parallel.sharded): the query's appended column is an
+    exact zero, so that product IS the inner product of the original
+    columns, and the run is ordered by (-q.t, index) of the problem as
+    posed, not by the augmented difference, whose appended column was
+    rounded to float32.
 
     ``d32k`` [Q, k] float64 (optional): the device's top-k distances;
     when given, corrected positions < k get their exact float64 values
@@ -140,11 +198,8 @@ def rank_correct_runs(
     d64 = np.empty(rows.size)
 
     def score(lo: int) -> None:
-        # f32 -> f64 is exact, so the in-place subtraction of the f32
-        # query rows equals widening both sides first
-        diff = db_np[safe[lo : lo + block]].astype(np.float64)
-        diff -= queries_np[rows[lo : lo + block]]
-        np.einsum("nd,nd->n", diff, diff, out=d64[lo : lo + block])
+        _score_members(db_np, queries_np, safe[lo : lo + block],
+                       rows[lo : lo + block], metric, d64[lo : lo + block])
 
     if len(starts) == 1:
         score(0)

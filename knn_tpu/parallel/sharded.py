@@ -29,8 +29,10 @@ The reference's distributed min-max normalize (knn_mpi.cpp:229-306) maps to
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
+import time
 from typing import Optional, Sequence, Tuple
 
 import jax
@@ -488,6 +490,22 @@ def _fetch_or_redispatch(out, redo, what: str = "device fetch",
 #: root span of one ``search_certified`` call; its stages name it as
 #: their ``parent`` (docs/OBSERVABILITY.md "Span lifecycle")
 _CALL_SPAN = "certified.call"
+#: what a metric other than l2 adds to a call on either side of the l2
+#: machinery: ONE span a call, the sum of both sides (inner product only
+#: today: the zero column before, the float64 scores after)
+_METRIC_SPAN = "certified.metric_map"
+
+
+@contextlib.contextmanager
+def _metric_side(seconds: dict, side: str):
+    """One side of :data:`_METRIC_SPAN`: a ``knn.certified.metric_map``
+    profiler annotation around the scope, its length kept in
+    ``seconds[side]`` for the call's one span."""
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation(
+            obs.trace.ANNOTATION_PREFIX + _METRIC_SPAN):
+        yield
+    seconds[side] = time.perf_counter() - t0
 
 
 def _staged_fetch(trace_id):
@@ -505,6 +523,47 @@ def _staged_fetch(trace_id):
         return arr
 
     return fetch
+
+
+#: inner-product placements: the most, as a share of M (the largest
+#: squared row norm), by which the exact augmented squared distances of
+#: two placed rows can disagree with the order of their inner products.
+#: :func:`_augment_dot` appends a_t = fl32(sqrt(M - n_t)), n_t the
+#: float64 squared norm, so a_t = sqrt(M - n_t) (1 + x) with |x| <=
+#: 2^-24 and the placed row's squared norm is M + c_t,
+#:   c_t = (M - n_t)(2x + x^2) + (|t|^2 - n_t),  |c_t| < 2^-23 M (1 + 2^-20)
+#: (the float64 sum, difference and root err by under 2^-45 M together).
+#: The exact distance between the augmented query (q, 0) and row is then
+#:   D'(t) = |q|^2 + M - 2 q.t + c_t,
+#: and two rows with D'(u) - D'(v) > |c_u - c_v| have q.u < q.v.  Per
+#: row 2^-22 M is twice the bound; a pair gets 2^-21 M.
+DOT_AUG_SLACK = 2.0 ** -21
+#: rows a block of :func:`_augment_dot`'s float64 norm pass widens
+_NORM_BLOCK_ROWS = 8192
+
+
+def _augment_dot(train: np.ndarray):
+    """MIPS -> squared L2 by norm augmentation: ``train`` [n, d] float32
+    with one more column ``sqrt(M - |t|^2)``, M the largest squared row
+    norm, so that against a query with a zero appended
+    ``|q' - t'|^2 = |q|^2 + M - 2 q.t`` but for the appended column's
+    rounding (``DOT_AUG_SLACK``).  Norms are taken in float64 a block of
+    rows at a time (the only float64 temporaries, 8192 rows each); the
+    augmented array is the one copy made.  Returns (augmented rows, M,
+    the largest squared norm of an augmented row, in float64)."""
+    train = np.asarray(train, np.float32)
+    n, d = train.shape
+    norm2 = np.empty(n)
+    for lo in range(0, n, _NORM_BLOCK_ROWS):
+        sq = train[lo:lo + _NORM_BLOCK_ROWS].astype(np.float64)
+        np.multiply(sq, sq, out=sq)
+        np.sum(sq, axis=-1, out=norm2[lo:lo + _NORM_BLOCK_ROWS])
+    shift = float(norm2.max()) if n else 0.0
+    out = np.empty((n, d + 1), np.float32)
+    out[:, :d] = train
+    out[:, d] = np.sqrt(np.maximum(shift - norm2, 0.0))
+    norm2 += out[:, d].astype(np.float64) ** 2
+    return out, shift, float(norm2.max()) if n else 0.0
 
 
 def _row_normalize_f64(x: np.ndarray) -> np.ndarray:
@@ -631,26 +690,27 @@ class ShardedKNN:
                 train = _row_normalize_f64(train)
                 self._cosine_unit = True
             elif metric == "dot" and isinstance(train, np.ndarray):
-                # MIPS -> L2 by norm augmentation, ONCE at placement:
-                # appending sqrt(M - ||t||^2) to every row (M = max f64
-                # squared row norm) and a zero column to every query makes
-                # the augmented squared L2
+                # MIPS -> L2 by norm augmentation, ONCE at placement
+                # (_augment_dot): against a query with a zero appended,
+                # the augmented squared L2 is
                 #   ||q'-t'||^2 = ||q||^2 + M - 2 q.t
-                # an affine, strictly decreasing map of the inner product
-                # per query — the augmented-L2 ranking IS the MIPS
-                # ranking, so the whole certified-exact machinery
-                # (search_certified, any precision x kernel) applies.
-                # Plain search rides too: _place_queries appends the zero
-                # column and the extra 0*aug term leaves pairwise_dot
-                # values mathematically unchanged.
-                train = np.asarray(train, np.float32)
-                t64 = train.astype(np.float64)
-                norm2 = np.einsum("nd,nd->n", t64, t64)
-                self._dot_shift = float(norm2.max()) if norm2.size else 0.0
-                aug = np.sqrt(np.maximum(self._dot_shift - norm2, 0.0))
-                train = np.concatenate(
-                    [train, aug[:, None].astype(np.float32)], axis=1)
+                # but for the appended column's float32 rounding — an
+                # affine, decreasing map of the inner product per query,
+                # so the whole certified machinery (search_certified,
+                # any precision x kernel) finds the candidates and
+                # proves none is missing, with DOT_AUG_SLACK * M added
+                # wherever it compares two rows; the host then ranks
+                # by the float64 inner product itself.  Plain search
+                # rides too: _place_queries appends the zero column and
+                # the extra 0*aug term leaves pairwise_dot values
+                # unchanged.
+                t_aug = time.perf_counter()
+                train, self._dot_shift, dot_norm_max = _augment_dot(train)
                 self._dot_aug = True
+                obs.emit_event(
+                    "placement.dot_augment", rows=int(train.shape[0]),
+                    dim=int(train.shape[1]) - 1, shift=self._dot_shift,
+                    seconds=time.perf_counter() - t_aug)
             # host copy (unpadded) for certified-path float64 refinement
             self._train_host = train if isinstance(train, np.ndarray) else None
             # pad rows with a huge fill: every selector also masks them by
@@ -746,7 +806,12 @@ class ShardedKNN:
         self.mesh = mesh
         self.k = k
         self.metric = metric
-        self._db_norm_max_cache: Optional[float] = None
+        # an inner-product placement has just taken every row's norm
+        # (the appended column is no bf16-exact value, so its kernel
+        # forms every product); any other walks its rows at the first
+        # certified call (_db_norm_max)
+        self._db_norm_max_cache: Optional[float] = (
+            dot_norm_max if self._dot_aug else None)
         # whether every placed row value is bf16-exact as float32: the
         # same walk's (_db_norm_max), read by _kernel_terms
         self._rows_lo_zero = False
@@ -1356,15 +1421,29 @@ class ShardedKNN:
         and bench.py's phase breakdown so neither can call the program
         with the wrong arity: int8 passes the quantized placement;
         pq passes (codes, codebooks, consts);
-        the f32 precisions pass the scalar db-norm bound."""
+        the f32 precisions pass the scalar db-norm bound; an
+        inner-product placement appends its augmentation slack to any
+        of them."""
         if precision == "int8":
             pl = self._int8_placement()
-            return (pl["values"], pl["scales"], pl["norms"],
+            tail = (pl["values"], pl["scales"], pl["norms"],
                     pl["consts"])
-        if precision == "pq":
+        elif precision == "pq":
             plq = self._pq_placement()
-            return (plq["codes"], plq["books"], plq["consts"])
-        return (np.float32(self._db_norm_max()),)
+            tail = (plq["codes"], plq["books"], plq["consts"])
+        else:
+            tail = (np.float32(self._db_norm_max()),)
+        if self._dot_aug:
+            # _certify_pack_spmd's aug_slack, rounded up to float32
+            tail += (np.nextafter(np.float32(self._dot_slack()),
+                                  np.float32(np.inf)),)
+        return tail
+
+    def _dot_slack(self) -> float:
+        """``DOT_AUG_SLACK * M`` of an inner-product placement: what
+        every comparison of two placed rows' augmented distances allows
+        for the appended column's rounding."""
+        return DOT_AUG_SLACK * self._dot_shift
 
     def search_certified(
         self, queries, *, margin: int = 28, selector: str = "approx",
@@ -1384,12 +1463,30 @@ class ShardedKNN:
         certificate is a squared-L2 bound; cosine runs it on unit
         vectors — rows are normalized at placement, queries here — and
         is exact for the f32-row-normalized problem, distances returned
-        as 1-similarity; dot/MIPS runs it on the norm-AUGMENTED vectors
-        placed at construction — one extra column per row — and is
-        exact for the f32-augmented problem, distances mapped back to
-        pairwise_dot's negative-inner-product values).  L1 has no
-        squared-L2-style bound and stays uncertified.  Two certificate
-        strategies by ``selector``:
+        as 1-similarity).  L1 has no squared-L2-style bound and stays
+        uncertified.
+
+        **dot / MIPS** has l2's contract: the INDICES equal float64
+        brute force in lexicographic (-q.t, index) order over the
+        float32 rows and queries AS GIVEN, whatever the selector.  The
+        device runs the l2 machinery on the norm-augmented rows placed
+        at construction (one more column, ``_augment_dot``) to find the
+        candidates and to prove none is missing; every inequality that
+        compares two rows there allows ``DOT_AUG_SLACK * M`` for the
+        appended column's float32 rounding (M the largest squared row
+        norm), and whatever it cannot tell apart the host ranks by the
+        float64 inner product itself (``rank_correct_runs``,
+        ``repair_uncertified``), never by the augmented difference.
+        The returned SCORES are ``-q.t`` computed on the host in
+        float64 from the float32 values (every product exact, pairwise
+        sum): off by less than ``(D + 1) * 2^-53 * |q| |t|``, which is
+        under ``(D + 1) * 2^-54 * (|q|^2 + M)``; the device's distance
+        block is not fetched.  The span ``certified.metric_map``, one
+        a call, is what the metric adds on both sides of the l2
+        machinery (``before_s``: the zero column; ``after_s``: the
+        scores).
+
+        Two certificate strategies by ``selector``:
 
         - ``"approx"`` / ``"exact"``: coarse top-(k+margin), float64 host
           refine, then a distributed count-below pass (psum over the db
@@ -1463,11 +1560,9 @@ class ShardedKNN:
                 )
         elif self.metric == "dot":
             # MIPS runs the l2 certificate in the norm-augmented space
-            # built at placement (__init__): the augmented-L2 ranking is
-            # the inner-product ranking per query (affine map), so the
-            # certificate is EXACT for the f32-augmented problem; scores
-            # map back to pairwise_dot values (negative inner product)
-            # below.
+            # built at placement (__init__), with the augmentation's
+            # rounding allowed for wherever two rows are compared; the
+            # host ranks and scores by float64 inner product (docstring)
             if not self._dot_aug:
                 raise ValueError(
                     "dot search_certified needs the norm-augmented "
@@ -1485,22 +1580,21 @@ class ShardedKNN:
         from knn_tpu.ops.certified import repair_uncertified
 
         tid = obs.new_trace_id()
+        dot = self.metric == "dot"
         with obs.span(_CALL_SPAN, tid, selector=selector) as call:
-            with obs.span("certified.prepare", tid, parent=_CALL_SPAN,
-                          first_call=self._db_norm_max_cache is None):
-                q_np = np.asarray(queries, dtype=np.float32)
-                if self.metric == "cosine":
-                    q_np = _row_normalize_f64(q_np)
-                q_norm2 = None
-                if self.metric == "dot":
-                    # augment queries with the zero column matching the
-                    # placed rows' augmentation; keep per-query f64
-                    # ||q||^2 for the score back-map at the end
-                    q64 = q_np.astype(np.float64)
-                    q_norm2 = np.einsum("nd,nd->n", q64, q64)
+            q_np = np.asarray(queries, dtype=np.float32)
+            map_s = {"before_s": 0.0, "after_s": 0.0}
+            if dot:
+                with _metric_side(map_s, "before_s"):
+                    # the zero column matching the placed rows'
+                    # augmentation
                     q_np = np.concatenate(
                         [q_np, np.zeros((q_np.shape[0], 1), np.float32)],
                         axis=1)
+            with obs.span("certified.prepare", tid, parent=_CALL_SPAN,
+                          first_call=self._db_norm_max_cache is None):
+                if self.metric == "cosine":
+                    q_np = _row_normalize_f64(q_np)
                 # every certified stage runs in squared-L2 space (for
                 # cosine: on the unit vectors placed at construction /
                 # normalized above; for dot: on the norm-augmented vectors)
@@ -1556,12 +1650,16 @@ class ShardedKNN:
                     # operand tail: resolved in this stage, so the spans
                     # below time batches only
                     terms = self._kernel_terms(q_np, knobs["precision"])
+                    # an inner-product call's scores are made on the
+                    # host (below): no distance block leaves the device
+                    device_d = return_distances and not dot
                     prog, m_prog, w, interpret = self._pallas_setup(
-                        m - self.k, include_distances=return_distances,
+                        m - self.k, include_distances=device_d,
                         terms=terms, **knobs)
                     ops_tail = self._pallas_operands(knobs["precision"])
             call.set("queries", n_q)
             call.set("batches", len(batches))
+            call.set("metric", self.metric)
             # what the cross-shard merges of this call move: every batch
             # is one program whose merge keeps m+1 columns a query (the
             # pallas program, setup's m) or m (the counted coarse
@@ -1574,13 +1672,14 @@ class ShardedKNN:
                 bad, n_corrected = self._certify_pallas(
                     batches, bs, d, i, q_np, db_np, prog=prog, w=w,
                     ops_tail=ops_tail, precision=knobs["precision"],
-                    trace_id=tid, want_distances=return_distances,
+                    trace_id=tid, want_distances=device_d,
+                    rank_metric="dot" if dot else "l2",
                 )
             else:
                 bad = self._certify_counted(
                     batches, bs, m, d, i, q_np, db_np, db_norm_max,
                     selector, recall_target=recall_target,
-                    metric=cert_metric,
+                    metric=cert_metric, dot=dot,
                 )
 
             def _select(qb, widen):
@@ -1613,6 +1712,8 @@ class ShardedKNN:
                     select_fn=_select,
                     max_widen=min(self.n_train, shard_rows),
                     db_norm_max=db_norm_max,
+                    dot_shift=self._dot_shift if dot else None,
+                    dot_slack=self._dot_slack(),
                 )
                 sp.set("host_exact_queries",
                        repair.get("host_exact_queries", 0))
@@ -1660,6 +1761,8 @@ class ShardedKNN:
             # accumulates the process-lifetime truth a scraper reads
             # (docs/OBSERVABILITY.md)
             obs.counter(_mn.CERTIFIED_QUERIES, selector=selector).inc(n_q)
+            obs.counter(_mn.CERTIFIED_METRIC_QUERIES,
+                        metric=self.metric).inc(n_q)
             obs.counter(_mn.CERTIFIED_FALLBACKS, selector=selector).inc(
                 int(bad.size))
             obs.counter(_mn.CERTIFIED_GENUINE_MISSES,
@@ -1676,16 +1779,20 @@ class ShardedKNN:
                 # unit-vector squared L2 -> cosine distance values, exactly
                 # (matches pairwise_cosine's 1 - similarity convention)
                 d *= 0.5
-            if return_distances and self.metric == "dot":
-                # augmented-space squared L2 -> pairwise_dot values
-                # (negative inner product): invert the affine map in f64 —
-                # ||q'-t'||^2 = ||q||^2 + M - 2 q.t, so
-                # -q.t = (||q'-t'||^2 - ||q||^2 - M) / 2.  Indices and
-                # certification are unaffected (the map is monotone per
-                # query); values then flow through metric_values like any
-                # other metric (dot passes through).
-                d -= q_norm2[:, None] + self._dot_shift
-                d *= 0.5
+            if return_distances and dot:
+                # pairwise_dot values (negative inner product) of the
+                # rows the indices name, in float64 on the host: the
+                # query's appended column is an exact zero, so the
+                # product over the placed columns is q.t itself.  No
+                # back-map of a float32 squared distance (a difference
+                # of nearly equal numbers) is involved.
+                from knn_tpu.ops.refine import exact_scores
+
+                with _metric_side(map_s, "after_s"):
+                    d = exact_scores(db_np, q_np, i, "dot")
+            if dot:
+                obs.record_span(_METRIC_SPAN, tid, sum(map_s.values()),
+                                parent=_CALL_SPAN, metric="dot", **map_s)
             if return_distances and return_sqrt:
                 # true Euclidean values (knn_mpi.cpp:48 / sklearn
                 # convention); indices and certification are unaffected
@@ -1698,6 +1805,7 @@ class ShardedKNN:
     def _certify_counted(
         self, batches, bs, m, d, i, q_np, db_np, db_norm_max, selector,
         recall_target: Optional[float] = None, metric: Optional[str] = None,
+        dot: bool = False,
     ):
         """Two-pass certificate: coarse select + refine, then the
         distributed count-below program proves completeness.  Returns the
@@ -1714,7 +1822,17 @@ class ShardedKNN:
         (2026-07-30 probe: 100/4096 fallbacks, all false alarms at
         recall_target 0.9999); a gap beyond which the midpoint clears
         tol almost always exists inside the margin window, so the
-        adaptive form certifies those queries instead."""
+        adaptive form certifies those queries instead.
+
+        ``dot`` (an inner-product placement: rows and queries
+        norm-augmented, ``metric`` l2): the refine ranks by the float64
+        negated inner product s, and the thresholds are made from
+        ``|q|^2 + M + 2 s``, s in the count program's own space.  A
+        placed row's exact augmented distance is that plus c_t, |c_t| <=
+        ``_dot_slack()`` / 2 (``DOT_AUG_SLACK``), so the tolerance grows
+        by ``_dot_slack()``: a row the count found at or above a
+        threshold is then above every refined candidate below it in
+        inner product too."""
         from knn_tpu.ops.certified import certification_tolerance
         from knn_tpu.ops.refine import refine_exact
 
@@ -1745,12 +1863,18 @@ class ShardedKNN:
             )[:take]
             m_avail = ci.shape[1]
             # refine ALL candidates: ranks k..m feed the gap search
-            d_m, i_m = refine_exact(db_np, q_np[lo : lo + take], ci, m_avail)
-            d_b, i_b = d_m[:, :k], i_m[:, :k]
-            d[lo : lo + take], i[lo : lo + take] = d_b, i_b
+            d_m, i_m = refine_exact(db_np, q_np[lo : lo + take], ci, m_avail,
+                                    "dot" if dot else "l2")
             tol = certification_tolerance(
                 q_np[lo : lo + take], db_np, db_norm_max=db_norm_max
             )
+            if dot:
+                q_norm = (q_np[lo : lo + take].astype(np.float64) ** 2
+                          ).sum(-1)
+                d_m = q_norm[:, None] + self._dot_shift + 2.0 * d_m
+                tol = tol + self._dot_slack()
+            d_b, i_b = d_m[:, :k], i_m[:, :k]
+            d[lo : lo + take], i[lo : lo + take] = d_b, i_b
             # first rank j in [k, m_avail) whose gap d[j] - d[j-1]
             # exceeds 2*tol (js = that j, or k when none does — the
             # fixed-threshold behavior)
@@ -1887,13 +2011,14 @@ class ShardedKNN:
             final_recall_target=final_recall_target,
             grid_order=grid_order, kernel=kernel,
             quant_offset=quant_offset, dcn_merge=self.dcn_merge,
-            interpret=interpret, terms=terms,
+            interpret=interpret, terms=terms, augmented=self._dot_aug,
         )
         return prog, m, _analysis_window(self.k, m), interpret
 
     def _certify_pallas(
         self, batches, bs, d, i, q_np, db_np, *, prog, w, ops_tail,
         precision, trace_id=None, want_distances=True,
+        rank_metric="l2",
     ):
         """One-pass certificate, host side.  The device already ranked the
         candidates, flagged uncertified rows, and marked near-tie pairs
@@ -1901,7 +2026,9 @@ class ShardedKNN:
         indices, the bit-packed tight-pair mask, and the bad flags (plus
         the top-k distance block when ``want_distances``) — nothing wider
         crosses the slow device->host link — then repairs tie runs in
-        float64 (ops.refine.rank_correct_runs).  Returns (flagged query
+        float64 (ops.refine.rank_correct_runs, by ``rank_metric``: an
+        inner-product placement's runs are ordered by inner product, not
+        by the augmented difference).  Returns (flagged query
         indices, rank-corrected query count).  ``prog`` and ``w`` are
         :meth:`_pallas_setup`'s, ``ops_tail`` :meth:`_pallas_operands`'s;
         each batch's stages are spans of the caller's ``trace_id``
@@ -1950,6 +2077,7 @@ class ShardedKNN:
                     gi_np, tight_np, k, q_np[lo : lo + take], db_np,
                     d32k=(None if dk_np is None
                           else dk_np.astype(np.float64)),
+                    metric=rank_metric,
                 )
                 sp.set("queries_corrected", n_c)
             n_corrected += n_c
@@ -2122,6 +2250,7 @@ def _pallas_certified_program(
     dcn_merge: Optional[str] = None,
     interpret: Optional[bool] = None,
     terms: str = "hh+hl+lh",
+    augmented: bool = False,
 ):
     """ONE-pass sharded self-certifying coarse select + device rank +
     device certificate (ops.pallas_knn.local_certified_candidates per
@@ -2161,7 +2290,12 @@ def _pallas_certified_program(
     ``quant_offset``-shifted space, so the comparison uses the shifted
     query norm (squared L2 is translation invariant; the f32 rescore
     distances d32 are space-independent up to RANK_SLACK, which the
-    derivation already budgets)."""
+    derivation already budgets).
+
+    ``augmented`` (an inner-product placement's rows, never a caller's
+    choice) appends one replicated scalar to the operand tail, whatever
+    the precision: ``_certify_pack_spmd``'s ``aug_slack``.  Without it
+    the program is the one it always was, operation for operation."""
     from knn_tpu.ops.pallas_knn import (
         BLOCK_Q,
         TILE_N,
@@ -2174,6 +2308,9 @@ def _pallas_certified_program(
     w = _analysis_window(k, m)
 
     def spmd(q, t, *tail):
+        aug_slack = None
+        if augmented:
+            *tail, aug_slack = tail
         db_q, db_pq, consts, db_norm_max = _split_operand_tail(
             precision, tail)
         d32, li, lb = local_certified_candidates(
@@ -2190,6 +2327,7 @@ def _pallas_certified_program(
             dcn_merge=dcn_merge,
             include_distances=include_distances,
             pq_dsub=None if db_pq is None else int(db_pq[1].shape[2]),
+            aug_slack=aug_slack,
         )
 
     return jax.jit(
@@ -2197,7 +2335,8 @@ def _pallas_certified_program(
             spmd,
             mesh=mesh,
             in_specs=(P(QUERY_AXIS), P(db_axes(mesh)),
-                      *_tail_specs(precision, mesh)),
+                      *_tail_specs(precision, mesh),
+                      *((P(),) if augmented else ())),
             out_specs=P(QUERY_AXIS),
             check_vma=False,
         )
@@ -2246,10 +2385,29 @@ SCOPE_MERGE = "knn.merge"
 def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
                        precision, quant_offset, m, k, w, merge, n_train,
                        hosts, chips, include_distances,
-                       dcn_merge=None, pq_dsub=None):
+                       dcn_merge=None, pq_dsub=None, aug_slack=None):
     """The certify/pack tail of the pallas certified program, from one
     shard's ranked candidates ``(d32, li, lb)`` to the packed host-facing
-    int32 array: merge, rank analysis, certificate, packing."""
+    int32 array: merge, rank analysis, certificate, packing.
+
+    ``aug_slack`` (a traced scalar; inner-product placements only, None
+    and no operation otherwise) is ``DOT_AUG_SLACK * M``: the most by
+    which the exact augmented distances D' of two placed rows can
+    disagree with the order of their inner products (``_augment_dot``
+    derives it).  With e = aug_slack, r = RANK_SLACK and |d32 - D'| <=
+    r D' / 3 (ops.pallas_knn.RANK_SLACK), the three inequalities below
+    carry it:
+
+    - near-tie: a pair with d32 gap > r d_hi + e has D' gap > r d_hi / 3
+      + e > e, so its inner products are ordered as the device ranked
+      them; every other pair is marked tight and re-scored by the host
+      in float64 INNER PRODUCT (ops.refine.rank_correct_runs);
+    - exclusion: a row outside the candidates has kernel score >= lb,
+      so D' - |q|^2 >= lb - tol; ``s_k + r d_k + tol + e < lb`` puts it
+      more than e above D'(c) <= d_k (1 + r) of every row c the host may
+      keep, hence below each in inner product;
+    - merge-drop: a dropped candidate has D' >= d32[:, m] (1 - r), and
+      ``d_k + r d_k + e < d32[:, m] (1 - r)`` says the same of it."""
     from knn_tpu.ops.pallas_knn import RANK_SLACK
 
     db_shards = hosts * chips
@@ -2280,7 +2438,10 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
     gaps = dw[:, 1:] - dw[:, :-1]  # [Q, w-1]
     # isfinite guard: an (x, inf-sentinel) pair yields inf <= inf,
     # which must not count as a near-tie
-    tight = (gaps <= RANK_SLACK * dw[:, 1:]) & jnp.isfinite(dw[:, 1:])
+    near = RANK_SLACK * dw[:, 1:]
+    if aug_slack is not None:
+        near = near + aug_slack
+    tight = (gaps <= near) & jnp.isfinite(dw[:, 1:])
     pair = lax.broadcasted_iota(jnp.int32, tight.shape, 1)
     big_after = (~tight) & (pair >= k - 1)
     has_stop = big_after.any(axis=-1)
@@ -2319,12 +2480,17 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
             q_norm + db_norm_max)
     d_k = dw[:, k - 1]
     s_k = d_k - q_norm
-    bad = s_k + RANK_SLACK * d_k + tol >= lb
+    reach = s_k + RANK_SLACK * d_k + tol
+    if aug_slack is not None:
+        reach = reach + aug_slack
+    bad = reach >= lb
     if db_shards > 1:
         # merge-dropped candidates have direct-diff f32 distance
         # >= the (m+1)-th kept; require true-distance clearance
-        bad = bad | (d_k + RANK_SLACK * d_k
-                     >= d32[:, m] * (1.0 - RANK_SLACK))
+        kept = d_k + RANK_SLACK * d_k
+        if aug_slack is not None:
+            kept = kept + aug_slack
+        bad = bad | (kept >= d32[:, m] * (1.0 - RANK_SLACK))
     bad = bad | unresolved
     cols = [
         gi[:, :w],

@@ -15,9 +15,11 @@ before Mosaic is asked; compiles the whole certified program with the
 kernel's one-product form (``--terms hh``: what a byte corpus and a
 byte batch run, ``ops.pallas_knn.BF16X3_TERMS``) at 5M x 128 on one
 chip and at 20M x 128 on the 1x4 mesh, printing what each keeps on a
-chip; and compiles the final select's bin-merge kernel at a 5M-row
-chip's candidate width (``bigann20m``).  Flags pick one geometry
-instead:
+chip; compiles the inner-product cell's program (2.5M x 201 placed
+columns, k=10: one more operand, no distance block); and compiles the
+final select's bin-merge kernel at a 5M-row chip's candidate width
+(``bigann20m``) and at ``text2image2m5``'s (39,168 columns at m+2 = 40:
+62 lane-rows a merge bin).  Flags pick one geometry instead:
 
     python scripts/aot_compile_check.py --shape gist --block-q 128
     python scripts/aot_compile_check.py --shape sift --kernel streaming \\
@@ -62,7 +64,14 @@ SHAPES = {
     # the four-chip cell's corpus (benchmark/configs/bigann20m-x4.json);
     # asked for by name with --mesh 1x4, not part of a bare run
     "bigann20m": (20_000_000, 128, 100),
+    # one chip of text2image-10M as PLACED: inner product, 200 columns
+    # and the appended norm column (benchmark/configs/text2image2m5.json)
+    "text2image2m5": (2_500_000, 201, 10),
 }
+#: shapes whose rows are norm-augmented at placement (metric "dot"): the
+#: certified program takes the augmentation's slack as one more scalar
+#: and sends no distance block back
+AUGMENTED = ("text2image2m5",)
 
 
 def _topology_devices():
@@ -114,6 +123,8 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
           if kk not in ("tile_n", "precision")}
     if terms:
         kw["terms"] = terms
+    if shape in AUGMENTED:
+        kw.update(augmented=True, include_distances=False)
     prog = _pallas_certified_program(
         mesh, k + MARGIN, k, merge, knobs["tile_n"] or TILE_N,
         knobs["precision"], n_train=n, interpret=False, **kw)
@@ -123,7 +134,7 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
         (rows, d), jnp.float32, sharding=NamedSharding(mesh, P(DB_AXIS)))
     norm = jax.ShapeDtypeStruct(
         (), jnp.float32, sharding=NamedSharding(mesh, P()))
-    return prog, (q, db, norm)
+    return prog, (q, db, norm) + ((norm,) if shape in AUGMENTED else ())
 
 
 def _merge_case(shape: str, db_shards: int, devices):
@@ -207,6 +218,9 @@ def default_cases():
                {}, "compiles", mesh, "hh")
               for shape, mesh in (("bigann5m", (1, 1)),
                                   ("bigann20m", (1, 4)))]
+    # the inner-product cell's program: 201 placed columns, k=10
+    cases.append(("text2image2m5 program mesh=1x1", "text2image2m5", {},
+                  "compiles", (1, 1), None))
     return cases
 
 
@@ -294,12 +308,15 @@ def main(argv=None) -> int:
     ok = [run_case(*case, devices, merge=args.merge, probe=args.probe)
           for case in cases]
     # the final select's bin-merge kernel, where the shape's width a chip
-    # engages it: of SHAPES only bigann20m does, which a bare run asks for
-    # over its four chips
-    shape, db_shards = ((args.shape, mesh[1] if mesh else 1)
-                        if args.shape else ("bigann20m", 4))
-    case = _merge_case(shape, db_shards, devices)
-    if case is not None:
+    # engages it: a bare run asks for bigann20m over its four chips (36
+    # lane-rows a merge bin) and text2image2m5 on its one (62: the shape
+    # whose blocks overran Mosaic's scoped VMEM on the chip, PR 31)
+    merges = ([(args.shape, mesh[1] if mesh else 1)] if args.shape
+              else [("bigann20m", 4), ("text2image2m5", 1)])
+    for shape, db_shards in merges:
+        case = _merge_case(shape, db_shards, devices)
+        if case is None:
+            continue
         t0 = time.time()
         name = f"{shape} select-merge kernel {case[1][0].shape}"
         try:
