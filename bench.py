@@ -1275,6 +1275,7 @@ def main() -> None:
             final_select=KNOBS["final_select"],
             final_recall_target=KNOBS["final_recall_target"],
             grid_order=KNOBS["grid_order"], kernel=KNOBS["kernel"],
+            batch_rows=queries.shape[0],
         )
         pb_queries = queries
         if METRIC == "cosine":

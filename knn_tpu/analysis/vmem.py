@@ -38,6 +38,34 @@ bq256 (SIFT tiled / GIST tiled / SIFT streaming, MiB): "highest" needs
 <=8 / 55.66 / 100.06 against 39.56 / 71.56 / 115.81 — an upper bound,
 by up to 2.7x; and the "pq" one-hot expansion is not modeled at all.
 
+THE WIDTH OF A DIM CHUNK is part of the launch geometry and has its
+one rule here (:func:`dim_chunking`, PR 32): where a row tile's whole
+padded width fits the device beside everything else the launch keeps,
+with :func:`limit_bytes`' eighth to spare, the tile is ONE chunk (one
+grid step, no accumulator scratch, the select in the matmul's own
+step); otherwise the chunk is ``DIM_CHUNK`` columns, the padding grain.
+The kernel, :func:`launch_estimate` and ``obs.roofline`` all ask it.
+The one-chunk geometries it chooses were probed like the rest (bf16x3,
+bq256, tile 16384; the least limit that compiles, by bisection, in MiB
+against the model): 256 columns 59 / 66.75, 384 79 / 83.0, 512 99 /
+99.25, beside 48 / 50.5 at 128 — the model over by 0.3% to 13%, 7.75
+MiB at the most, inside the eighth (8.3) its limit adds.  It was NOT
+re-fitted: what Mosaic keeps beside the declared buffers is 1.8, 1.5,
+1.7 and 2.0 score tiles at 128, 256, 384 and 512 columns, no function
+of the width a multiplier could follow, and ``live = 2`` bounds all
+four.  (Under its need Mosaic may schedule otherwise and name another
+size: 98.32 MiB at a limit of 58 for the 256-column chunk, which
+compiles from 59 up — the probe bisects on the limit, it does not trust
+the size.)  With the low row half dropped (``terms`` "hh": one part)
+the model is an upper bound by far: 18 reported against 50.75 at 256
+columns, 70 against 100.25 at 1,024.  The rule is the TILED kernel's
+alone: the streaming and fused kernels hold a whole query block, both
+row buffers and every tile's output at once, so one wide chunk that the
+tiled kernel has room for overruns them (1M rows of 512 columns at
+bq128: 120.2 and 124.4 MiB modeled, and Mosaic refuses both; of 640,
+136 and 140) where their 128-column chunks compile at about 80.  They
+keep ``DIM_CHUNK`` at every width, the parent's programs.
+
 Geometry constants mirror ``ops.pallas_knn`` (TILE_N/BLOCK_Q/BIN_W/
 DIM_CHUNK/MAX_CARRY_DEPTH), pinned by tests/test_analysis.py.  The
 per-precision operand widths live in the ONE shared table
@@ -54,7 +82,7 @@ are never budget-checked.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 from knn_tpu.analysis import widths as _widths
 
@@ -160,10 +188,9 @@ def _geometry(n: int, d: int, precision: str, kernel: str,
     bq = int(block_q or BLOCK_Q_DEFAULT)
     n_tiles = _ceil_div(n, tile)
     dim_p = _ceil_div(d, DIM_CHUNK) * DIM_CHUNK
-    nd = dim_p // DIM_CHUNK
     out_w = int(survivors or SURVIVORS_GROUPED_DEFAULT) * BIN_W
     bound_w = BIN_W
-    return tile, bq, n_tiles, dim_p, nd, out_w, bound_w
+    return tile, bq, n_tiles, dim_p, out_w, bound_w
 
 
 def kernel_bytes(
@@ -176,7 +203,7 @@ def kernel_bytes(
     from, and what :func:`launch_estimate` prices a knob set with.
 
     ``db_block`` is one db tile across all its parts, ``q_block`` one
-    query operand block (tiled: one DIM_CHUNK slice; streaming/fused:
+    query operand block (tiled: one dim-chunk slice; streaming/fused:
     the full-dim block), ``q_extra`` the quantized arms' query-scale
     block, ``carry_depth`` the fused arm's armed carry depth (0 =
     disarmed).
@@ -220,21 +247,74 @@ def kernel_bytes(
     }
 
 
+def dim_chunking(
+    dim_padded: int, *, tile_n: int, block_q: int,
+    precision: str = "bf16x3", kernel: str = "tiled",
+    db_parts: Optional[int] = None,
+    out_w: int = SURVIVORS_GROUPED_DEFAULT * BIN_W,
+    budget_bytes: Optional[int] = None,
+) -> Tuple[int, int]:
+    """``(chunk_w, nd)``: the columns of one dim chunk and the chunks a
+    row tile is cut into, from what a launch can see of itself — the
+    padded width, the tile, the query block, the precision's db parts
+    (``db_parts``: the parts actually streamed, where the bf16x3 split
+    drops its low half; None = the precision's own).  No knob.
+
+    ONE chunk (``chunk_w = dim_padded``) wherever the tiled kernel's
+    one-chunk geometry, by the model, plus :func:`limit_bytes`' eighth
+    fits ``budget_bytes`` (None = the target device's); ``DIM_CHUNK``
+    columns otherwise — GIST's 1,024 would be 128 MiB of row blocks
+    alone — and under ``kernel`` "streaming" or "fused" always (module
+    docstring: what the tiled kernel has room for, they do not).
+    ``pq`` has no chunk loop and a width of 128 or less is one chunk
+    already.  The model is calibrated for bf16x3 and an upper bound for
+    the other arms (module docstring), so what it lets through fits
+    there too."""
+    dim_padded = int(dim_padded)
+    if dim_padded % DIM_CHUNK:
+        raise ValueError(
+            f"dim_padded={dim_padded} is no multiple of {DIM_CHUNK}")
+    if precision == "pq" or dim_padded <= DIM_CHUNK:
+        return dim_padded, 1
+    if kernel != "tiled":
+        return DIM_CHUNK, dim_padded // DIM_CHUNK
+    if budget_bytes is None:
+        budget_bytes = budget_for(TARGET_DEVICE_KIND)
+    n_parts, chunk_w, part_b = DB_PARTS[precision]
+    need = sum(kernel_bytes(
+        kernel="tiled", block_q=block_q, tile_n=tile_n, n_tiles=1, nd=1,
+        out_w=out_w, bound_w=BIN_W,
+        db_block=((n_parts if db_parts is None else int(db_parts))
+                  * tile_n * dim_padded * (chunk_w // DIM_CHUNK) * part_b),
+        aux_rows=AUX_ROWS.get(precision, AUX_ROWS_DEFAULT),
+        q_block=block_q * dim_padded * _widths.query_elem_bytes(precision),
+        q_extra=block_q * BIN_W * 4 if precision == "int8" else 0,
+    ).values())
+    if need + need // 8 <= budget_bytes:
+        return dim_padded, 1
+    return DIM_CHUNK, dim_padded // DIM_CHUNK
+
+
 def launch_estimate(
     *, n: int, d: int, k: int, margin: int = 28,
     precision: Optional[str] = None, kernel: Optional[str] = None,
     tile_n: Optional[int] = None, block_q: Optional[int] = None,
     survivors: Optional[int] = None,
     pq_dsub: Optional[int] = None, pq_ncodes: Optional[int] = None,
+    budget_bytes: Optional[int] = None,
 ) -> dict:
     """Estimated VMEM high-water bytes of ONE kernel launch for this
     knob set at this problem shape, with the per-buffer breakdown
     (:func:`kernel_bytes` over the geometry the kernel would
-    resolve)."""
+    resolve, its dim chunks by :func:`dim_chunking` against
+    ``budget_bytes``: None = the target device's)."""
     precision = precision or "bf16x3"
     kernel = kernel or "tiled"
-    tile, bq, n_tiles, dim_p, nd, out_w, bound_w = _geometry(
+    tile, bq, n_tiles, dim_p, out_w, bound_w = _geometry(
         n, d, precision, kernel, tile_n, block_q, survivors)
+    dim_chunk, nd = dim_chunking(
+        dim_p, tile_n=tile, block_q=bq, precision=precision, kernel=kernel,
+        out_w=out_w, budget_bytes=budget_bytes)
     lut_w = 0
     if precision == "pq":
         # one db block is the [tile_n, m] byte code tensor; the
@@ -249,11 +329,12 @@ def launch_estimate(
         nd = 1
     else:
         n_parts, chunk_w, part_b = DB_PARTS[precision]
+        chunk_w = chunk_w // DIM_CHUNK * dim_chunk
     quantized = precision == "int8"
     if precision == "pq":
         q_block = bq * lut_w * 4
     else:
-        q_block = bq * (DIM_CHUNK if kernel == "tiled" else dim_p) * (
+        q_block = bq * (dim_chunk if kernel == "tiled" else dim_p) * (
             1 if quantized else 4)
     carry_depth = 0
     if kernel == "fused":
@@ -272,16 +353,17 @@ def launch_estimate(
         "breakdown": {kk: int(v) for kk, v in breakdown.items()},
         "geometry": {
             "tile_n": tile, "block_q": bq, "n_tiles": n_tiles,
-            "dim_padded": dim_p, "out_w": out_w, "bound_w": bound_w,
+            "dim_padded": dim_p, "dim_chunk": dim_chunk, "dim_chunks": nd,
+            "out_w": out_w, "bound_w": bound_w,
             "kernel": kernel, "precision": precision,
         },
     }
 
 
 def _estimate_for(knobs: dict, *, n: int, d: int, k: int,
-                  margin: int) -> int:
+                  margin: int, budget_bytes: Optional[int] = None) -> int:
     return launch_estimate(
-        n=n, d=d, k=k, margin=margin,
+        n=n, d=d, k=k, margin=margin, budget_bytes=budget_bytes,
         precision=knobs.get("precision"), kernel=knobs.get("kernel"),
         tile_n=knobs.get("tile_n"), block_q=knobs.get("block_q"),
         survivors=knobs.get("survivors"),
@@ -299,7 +381,8 @@ def check_candidate(
     not :func:`calibrated` for) means the verdict is N/A, never a
     refusal."""
     budget = budget_for(device_kind, backend)
-    est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin)
+    est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin,
+                        budget_bytes=budget)
     checked = budget is not None and calibrated(knobs.get("precision"))
     return {
         "checked": checked,
@@ -320,5 +403,7 @@ def fits_some_kind(knobs: dict, *, n: int, d: int, k: int,
     is not :func:`calibrated` for is never excluded on it."""
     if not calibrated(knobs.get("precision")):
         return True
-    est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin)
-    return est <= max(VMEM_BYTES_BY_KIND.values())
+    roomiest = max(VMEM_BYTES_BY_KIND.values())
+    est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin,
+                        budget_bytes=roomiest)
+    return est <= roomiest

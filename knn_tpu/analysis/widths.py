@@ -29,8 +29,10 @@ from __future__ import annotations
 
 from typing import Dict, Optional, Tuple
 
-#: dim-chunk width every kernel slices the feature axis by (mirror of
-#: ops.pallas_knn.DIM_CHUNK, pinned by test)
+#: the padding grain of the feature axis, and the width of a dim chunk
+#: wherever a row tile's whole padded width does not fit VMEM
+#: (analysis.vmem.dim_chunking; mirror of ops.pallas_knn.DIM_CHUNK,
+#: pinned by test)
 DIM_CHUNK = 128
 
 #: db stream width per element by kernel matmul precision.  "pq" is
@@ -56,7 +58,8 @@ QUERY_ELEM_BYTES_DEFAULT = 4
 
 #: db operand parts per precision for the VMEM launch model:
 #: (n_parts, chunk_w, bytes/elem) — one db block of ONE part occupies
-#: (tile_n, chunk_w) at the part dtype.  "pq" is
+#: (tile_n, chunk_w) at the part dtype, per DIM_CHUNK columns of the
+#: launch's dim chunk (analysis.vmem scales it by the resolved width).  "pq" is
 #: absent: its chunk width is the shape-dependent code width
 #: ``ceil(d / dsub)`` (analysis.vmem special-cases it via
 #: :func:`db_row_bytes`).

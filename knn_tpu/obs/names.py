@@ -129,6 +129,7 @@ MERGE_BYTES = "knn_tpu_merge_bytes_total"
 MERGE_STRAGGLER_GAP = "knn_tpu_merge_straggler_gap_seconds"
 SELECT_MERGE_CALLS = "knn_tpu_select_merge_calls_total"
 KERNEL_TERMS = "knn_tpu_kernel_terms_total"
+KERNEL_DIM_CHUNKS = "knn_tpu_kernel_dim_chunks_total"
 
 # --- host-RAM shard tier (knn_tpu.parallel.sharded) --------------------
 HOSTTIER_SWEEPS = "knn_tpu_hosttier_sweeps_total"
@@ -419,6 +420,13 @@ CATALOG = {
         "(ops.pallas_knn.BF16X3_TERMS): 'hh+hl+lh' the full sum, "
         "'hh+lh' where every row is bf16-exact, 'hh' where the batch "
         "is too — 3, 2 or 1 MXU passes."),
+    KERNEL_DIM_CHUNKS: (
+        "counter", ("chunks",),
+        "Batches of search_certified(selector='pallas'), by the dim "
+        "chunks their kernel cut a row tile into "
+        "(ops.pallas_knn.dim_chunking): '1' wherever the whole padded "
+        "width fits VMEM (one grid step a tile, no accumulator "
+        "scratch), else the padded width over 128."),
     MERGE_STRAGGLER_GAP: (
         "gauge", (),
         "Max-minus-min per-host local search wall time of the last "

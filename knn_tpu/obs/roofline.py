@@ -65,6 +65,7 @@ import re
 import threading
 from typing import Dict, Optional, Tuple
 
+from knn_tpu.analysis import vmem as _vmem
 from knn_tpu.analysis import widths as _widths
 from knn_tpu.obs import names, registry, trace
 
@@ -586,7 +587,14 @@ def pallas_cost_model(
     # block; db_major streams it ONCE at single-chunk dims but
     # degenerates to query_major traffic when the innermost chunk axis
     # cycles between query blocks (ops.pallas_knn.GRID_ORDERS)
-    if grid_order == "db_major" and d <= DIM_CHUNK and kernel == "tiled":
+    # (the chunks of the full product on the modeled device: a knob
+    # set is priced before any data is seen, like ``passes`` below; a
+    # kind the VMEM table lacks, a cpu's, is priced as the target's)
+    _, nd = _vmem.dim_chunking(
+        _ceil_div(d, DIM_CHUNK) * DIM_CHUNK, tile_n=tile, block_q=bq,
+        precision=precision, kernel=kernel, out_w=out_w,
+        budget_bytes=_vmem.VMEM_BYTES_BY_KIND.get(device_kind))
+    if grid_order == "db_major" and nd == 1 and kernel == "tiled":
         db_passes = 1
     else:
         db_passes = q_blocks

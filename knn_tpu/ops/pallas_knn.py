@@ -91,7 +91,14 @@ see ``KERNELS``):
 - ``"tiled"`` (default): grid = (q_blocks, db_tiles, dim_chunks); the
   Pallas pipeline re-launches the kernel body once per train tile and
   each (query block, db tile) cell round-trips its survivor block
-  through HBM before the XLA final select.
+  through HBM before the XLA final select.  ``dim_chunks`` is 1
+  wherever a row tile's whole padded width fits VMEM
+  (``dim_chunking``: 128, 256, 384 and 512 columns at the default
+  tile and query block): the grid's third axis has one step, no
+  accumulator scratch exists and the bin-select sits in the matmul's
+  own step.  Wider rows (GIST's 1,024) are cut into 128-column chunks
+  whose partial products add up in a VMEM scratch, the select behind
+  the last one.
 - ``"streaming"``: grid = (q_blocks,) — ONE kernel launch per
   (batch, shard).  The db tiles stay in HBM and stream through a
   double-buffered pair of VMEM scratch buffers via explicit async
@@ -100,10 +107,15 @@ see ``KERNELS``):
   The per-tile survivor blocks accumulate in the VMEM-resident output
   block across the whole in-kernel tile loop (the running
   (distance, index) candidate list) and flush to HBM once per query
-  block, instead of once per (query block, db tile) cell.  Outputs are
-  BITWISE-IDENTICAL to the tiled kernel — both run the same emitters
-  on the same per-tile scores — so the downstream certified pipeline
-  is unchanged and interpret-mode equality is testable
+  block, instead of once per (query block, db tile) cell.  Its rows
+  stay cut into 128-column chunks at every width (it holds a whole
+  query block, both row buffers and every tile's output at once: the
+  one wide chunk the tiled kernel has room for overruns it).  Outputs
+  are BITWISE-IDENTICAL to the tiled kernel's wherever the two cut the
+  rows alike (up to 128 columns, wider rows the tiled kernel does not
+  collapse, and any width handed to both) — both run the same
+  emitters on the same per-tile scores — so the downstream certified
+  pipeline is unchanged and interpret-mode equality is testable
   (tests/test_pallas_streaming.py).  Opt-in until the on-hardware gate
   + A/B pass on it; the autotuner (knn_tpu.tuning) carries it in the default
   knob grid so the next TPU session measures it.
@@ -148,8 +160,12 @@ SELECT_MERGE_SURVIVORS = 4
 #: merge bins per slot of the top-(m+2) that follows: groups x 128 lanes
 #: >= 16 x (m + 2), so the merged width follows m and not the corpus
 SELECT_MERGE_BINS_PER_SLOT = 16
-#: dim is processed in chunks so arbitrarily wide features (GIST's 960)
-#: never blow VMEM; qt accumulates in scratch across chunks
+#: the padding grain of the feature axis (columns are zero-padded to a
+#: multiple of it) and the FALLBACK width of a dim chunk: a row tile is
+#: one chunk of its whole padded width where that fits VMEM and is cut
+#: into chunks of this many columns where it does not (GIST's 960), qt
+#: accumulating in scratch across them.  Which, is ``dim_chunking``'s
+#: reading of the launch's shape, never a caller's choice
 DIM_CHUNK = 128
 #: cap on survivors per bin (tiny tile_n in tests would otherwise unroll
 #: a 128-step trace); capped cells just pad their output block
@@ -262,7 +278,9 @@ def _split_qt(q, th, tl, terms: str):
 #: winner that names one is never looked up again.
 #: 8 = the bf16x3 product drops the terms whose low operand is all zero
 #: (PR 30, ``BF16X3_TERMS``): the tuner's timings depend on its rows.
-KERNEL_VERSION = 8
+#: 9 = a row tile whose padded width fits VMEM is one dim chunk (PR 32,
+#: ``dim_chunking``): the kernel at 129...512 columns is another program.
+KERNEL_VERSION = 9
 
 #: relative slack of the device rank stage's direct-difference f32
 #: distances: per-term (q-t)^2 rounding plus the depth-7 tree reduce give
@@ -300,7 +318,8 @@ def _round_up(x: int, multiple: int) -> int:
 #: term of the measured cost model in docs/PERF.md).  "db_major": grid =
 #: (db_tiles, q_blocks, dim_chunks) — consecutive steps revisit the
 #: same db tile (Pallas re-fetches an input block only when its mapped
-#: index changes), so AT dim <= DIM_CHUNK (nd == 1, e.g. SIFT's 128)
+#: index changes), so AT ONE DIM CHUNK (nd == 1: SIFT's 128 columns,
+#: and every wider tile ``dim_chunking`` keeps whole)
 #: each db tile streams ONCE per sweep and only the small query blocks
 #: re-stream (~2 MB x n_tiles).  For multi-chunk dims the innermost
 #: chunk axis cycles between query blocks, so every chunk re-fetches
@@ -477,7 +496,7 @@ def _kernel(q_ref, *refs, tile_n: int, survivors: int, nd: int,
         t3_ref, tn_ref, d_ref, i_ref, b_ref, *scratch = refs
         qh = q.astype(jnp.bfloat16)
         ql = (q - qh.astype(jnp.float32)).astype(jnp.bfloat16)
-        q3 = jnp.concatenate([qh, qh, ql], axis=1)  # [BQ, 3*DIM_CHUNK]
+        q3 = jnp.concatenate([qh, qh, ql], axis=1)  # [BQ, 3 * chunk]
         qt = lax.dot_general(q3, t3_ref[:], dn,
                              preferred_element_type=jnp.float32)
     elif precision == "int8":
@@ -704,7 +723,10 @@ def _stream_kernel(q_ref, *refs, tile_n: int, survivors: int, out_w: int,
             codes_buf, = bufs
             return _pq_onehot_qt(q, codes_buf, tile_n=tile_n,
                                  pq_shape=pq_shape)
-        qc = q[:, c * DIM_CHUNK : (c + 1) * DIM_CHUNK]
+        # the launch's dim chunk: DIM_CHUNK by the rule (dim_chunking),
+        # another width only where a test hands one to every strategy
+        qw = q.shape[1] // nd
+        qc = q[:, c * qw : (c + 1) * qw]
         if precision == "int8":
             t, = bufs
             return lax.dot_general(qc, t, dn,
@@ -842,6 +864,22 @@ def default_backend_is_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _vmem_device_kind() -> str:
+    """The device kind whose VMEM a launch is budgeted for: the chip's
+    own on a TPU backend; off it (interpret mode, deviceless AOT
+    compiles) the target kind's."""
+    from knn_tpu.analysis import vmem
+
+    return (jax.devices()[0].device_kind if default_backend_is_tpu()
+            else vmem.TARGET_DEVICE_KIND)
+
+
+def effective_block_q(block_q: int, query_rows: int) -> int:
+    """The query block a launch over ``query_rows`` queries runs: the
+    requested one, capped to the batch (8 sublanes at the least)."""
+    return min(block_q, max(8, query_rows))
+
+
 def _vmem_limit_bytes(kernel: str, precision: str, **geometry) -> int:
     """The scoped-VMEM limit a compiled launch requests
     (knn_tpu.analysis.vmem — the ONE home of the arithmetic and of the
@@ -854,8 +892,7 @@ def _vmem_limit_bytes(kernel: str, precision: str, **geometry) -> int:
     off-TPU and budget for the target device kind."""
     from knn_tpu.analysis import vmem
 
-    kind = (jax.devices()[0].device_kind if default_backend_is_tpu()
-            else vmem.TARGET_DEVICE_KIND)
+    kind = _vmem_device_kind()
     budget = vmem.budget_for(kind)
     if not vmem.calibrated(precision):
         return budget
@@ -870,6 +907,34 @@ def _vmem_limit_bytes(kernel: str, precision: str, **geometry) -> int:
     return vmem.limit_bytes(need, budget)
 
 
+def dim_chunking(dim: int, *, tile_n: int, block_q: int, precision: str,
+                 kernel: str = "tiled", terms: str = BF16X3_TERMS[0],
+                 survivors: Optional[int] = None) -> Tuple[int, int]:
+    """``(chunk_w, nd)`` of a launch over ``dim`` columns (padded here
+    to the ``DIM_CHUNK`` grain): the width of one dim chunk and how
+    many a row tile has.  knn_tpu.analysis.vmem.dim_chunking is the ONE
+    home of the rule (under the tiled kernel one chunk wherever the
+    whole padded width fits the device's VMEM beside the rest of the
+    launch; 128 columns otherwise, and under the other two kernels
+    always); this hands it what the launch sees — the RESOLVED tile
+    and query block, the row parts ``terms`` leaves to stream — and the
+    budget of the device it compiles for (off-TPU, the target kind's:
+    interpret mode cuts the rows as the chip would).  Nothing sets it:
+    a bare launch asks here, and ShardedKNN asks here ONCE for its
+    program (``_pallas_setup``), hands the kernel the answer and
+    reports that answer (``dim_chunk``, ``dim_chunks`` on the
+    ``certified.call`` event)."""
+    from knn_tpu.analysis import vmem
+
+    return vmem.dim_chunking(
+        _round_up(dim, DIM_CHUNK), tile_n=tile_n, block_q=block_q,
+        precision=precision, kernel=kernel,
+        db_parts=(1 if precision == "bf16x3" and "hl" not in terms
+                  else None),
+        out_w=_geometry(tile_n, survivors)[2],
+        budget_bytes=vmem.budget_for(_vmem_device_kind()))
+
+
 def _pad_axis(x, multiple: int, axis: int, fill: float = 0.0):
     """parallel.mesh.pad_to_multiple without the size return (imported
     lazily: ops must not import the parallel package at module scope)."""
@@ -881,7 +946,8 @@ def _pad_axis(x, multiple: int, axis: int, fill: float = 0.0):
 @functools.partial(
     jax.jit, static_argnames=("block_q", "tile_n", "survivors",
                               "precision", "interpret", "grid_order",
-                              "kernel", "offset", "keep", "terms")
+                              "kernel", "offset", "keep", "terms",
+                              "dim_chunk")
 )
 def _bin_candidates(
     queries: jax.Array,
@@ -899,6 +965,7 @@ def _bin_candidates(
     keep: Optional[int] = None,
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
     terms: str = BF16X3_TERMS[0],
+    dim_chunk: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Kernel launch on padded shapes.  Returns
 
@@ -936,7 +1003,19 @@ def _bin_candidates(
     halves: without ``hl`` the rows' low half is neither computed here
     nor streamed, without ``lh`` the kernel never forms the batch's.
     Outputs are the full sum's bit for bit on such data, and wrong on
-    any other: nothing here checks."""
+    any other: nothing here checks.
+
+    ``dim_chunk`` is the width of one dim chunk, a multiple of
+    ``DIM_CHUNK`` that divides the padded width: :func:`dim_chunking`'s
+    reading of this launch's shape, made here where it is None and by
+    the caller where a program reports it (ShardedKNN, through
+    :func:`local_certified_candidates`); tests pass one to hold the
+    multi-chunk path at shapes small enough to interpret.  Never a
+    knob.  The rule collapses a tile under ``kernel="tiled"`` alone, so
+    outputs are bitwise-identical across ``kernel`` where the width is
+    the same (up to 128 columns, where the rule keeps 128, and wherever
+    one width is handed to all three); across widths they agree to the
+    f32 accumulation order (``kernel_tolerance`` covers it)."""
     queries = _pad_axis(queries.astype(jnp.float32), block_q, 0)
     queries = _pad_axis(queries, DIM_CHUNK, 1)
     n_rows = db.shape[0]
@@ -945,7 +1024,6 @@ def _bin_candidates(
         db = _pad_axis(db, DIM_CHUNK, 1)
     qp, dim = queries.shape
     n_tiles = db.shape[0] // tile_n
-    nd = dim // DIM_CHUNK
     _, survivors, out_w, bound_w = _geometry(tile_n, survivors)
 
     if precision not in PRECISIONS:
@@ -981,6 +1059,16 @@ def _bin_candidates(
             "kernel='fused' is not certified for precision='pq': the "
             "early-out carry-soundness argument has not been extended "
             "to reconstruction-space scores; use 'streaming' or 'tiled'")
+    if dim_chunk is None:
+        dim_chunk, nd = dim_chunking(
+            dim, tile_n=tile_n, block_q=block_q, precision=precision,
+            kernel=kernel, terms=terms, survivors=survivors)
+    elif dim_chunk % DIM_CHUNK or dim % dim_chunk:
+        raise ValueError(
+            f"dim_chunk={dim_chunk} must be a multiple of {DIM_CHUNK} "
+            f"that divides the padded width {dim}")
+    else:
+        nd = dim // dim_chunk
     pq_shape = None
     queries_in = queries
     q_extra = []  # int8: the per-query-row scale block rides as an input
@@ -994,15 +1082,15 @@ def _bin_candidates(
                 tl = (db - th.astype(jnp.float32)).astype(jnp.bfloat16)
         if precision == "bf16x3":
             db_inputs = [th, tl] if "hl" in terms else [th]
-            chunk_w = DIM_CHUNK
+            chunk_w = dim_chunk
         else:
             # per dim chunk c the fused contraction reads [th_c|tl_c|th_c]
-            th3 = th.reshape(db.shape[0], nd, DIM_CHUNK)
-            tl3 = tl.reshape(db.shape[0], nd, DIM_CHUNK)
+            th3 = th.reshape(db.shape[0], nd, dim_chunk)
+            tl3 = tl.reshape(db.shape[0], nd, dim_chunk)
             t3 = jnp.concatenate([th3, tl3, th3], axis=2).reshape(
-                db.shape[0], nd * 3 * DIM_CHUNK)
+                db.shape[0], nd * 3 * dim_chunk)
             db_inputs = [t3]
-            chunk_w = 3 * DIM_CHUNK
+            chunk_w = 3 * dim_chunk
     elif precision == "int8":
         from knn_tpu.ops.quantize import quantize_rows
 
@@ -1028,7 +1116,7 @@ def _bin_candidates(
             tn_rows = _pad_axis(tn_rows[:, None], tile_n, 0,
                                 fill=PAD_VAL)[:, 0]
         db_inputs = [ti]
-        chunk_w = DIM_CHUNK
+        chunk_w = dim_chunk
         # the db-side aux block stacks norms over scales ([16, N]: rows
         # 0-7 tn broadcast, 8-15 scales broadcast) so BOTH stream through
         # the one lane-major aux slot the f32 path already has
@@ -1071,7 +1159,7 @@ def _bin_candidates(
         nd = 1  # the LUT scores in ONE dot; there is no dim-chunk loop
     else:
         db_inputs = [db]
-        chunk_w = DIM_CHUNK
+        chunk_w = dim_chunk
     if precision == "int8":
         tnorm = jnp.concatenate([
             jnp.broadcast_to(tn_rows[None, :], (8, db.shape[0])),
@@ -1113,10 +1201,10 @@ def _bin_candidates(
         precision=precision, ti_axis=0 if db_major else 1,
         pq_shape=pq_shape, terms=terms,
     )
-    # the query operand block: one DIM_CHUNK slice per grid step for the
+    # the query operand block: one dim-chunk slice per grid step for the
     # feature-chunked arms; PQ's LUT has no chunk loop (nd == 1) and
     # rides as ONE lane-padded block
-    q_block_w = queries_in.shape[1] if precision == "pq" else DIM_CHUNK
+    q_block_w = queries_in.shape[1] if precision == "pq" else dim_chunk
     if db_major:
         grid = (n_tiles, qp // block_q, nd)
         q_idx = lambda t, q, d: (q, d)      # noqa: E731
@@ -1169,8 +1257,9 @@ def _bin_candidates(
         ],
         out_shape=out_shape,
         # the qt accumulation scratch is only touched when dim spans
-        # multiple chunks; at dim <= 128 (the headline shape) skipping it
-        # returns VMEM to the pipeline
+        # multiple chunks; at one chunk (dim <= 128, and every wider tile
+        # dim_chunking found room for) skipping it returns VMEM to the
+        # pipeline
         # int8 accumulates the raw int32 dot across chunks (exact);
         # the f32 paths accumulate the scaled f32 score
         scratch_shapes=[] if nd == 1 else [
@@ -1249,7 +1338,7 @@ def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
     static_argnames=("m", "tile_n", "block_q", "survivors",
                      "precision", "final_select", "interpret",
                      "final_recall_target", "grid_order", "kernel",
-                     "offset", "terms"),
+                     "offset", "terms", "dim_chunk"),
 )
 def local_certified_candidates(
     q: jax.Array,
@@ -1269,6 +1358,7 @@ def local_certified_candidates(
     offset: float = 0.0,
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
     terms: str = BF16X3_TERMS[0],
+    dim_chunk: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The whole device-side certified coarse pass against one db (shard):
 
@@ -1316,6 +1406,7 @@ def local_certified_candidates(
         precision=precision, interpret=interpret,
         final_select=final_select, grid_order=grid_order, kernel=kernel,
         db_int8=db_int8, offset=offset, db_pq=db_pq, terms=terms,
+        dim_chunk=dim_chunk,
     )
     return local_select_rescore(
         q, t, cd, ci, bounds, m, final_select=final_select,
@@ -1327,7 +1418,8 @@ def local_certified_candidates(
     jax.jit,
     static_argnames=("m", "tile_n", "block_q", "survivors",
                      "precision", "interpret", "final_select",
-                     "grid_order", "kernel", "offset", "terms"),
+                     "grid_order", "kernel", "offset", "terms",
+                     "dim_chunk"),
 )
 def local_coarse_candidates(
     q: jax.Array,
@@ -1346,12 +1438,14 @@ def local_coarse_candidates(
     final_select: str = "exact",
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
     terms: str = BF16X3_TERMS[0],
+    dim_chunk: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Stage 1 of :func:`local_certified_candidates` — the db-streaming
     coarse pass alone: resolve the effective tile, launch the kernel,
     trim the query padding.  Returns the packed candidates
     ``(cd [Q, W], ci [Q, W], bounds [Q, T*B])``; stage 2
-    (:func:`local_select_rescore`) is everything after the kernel."""
+    (:func:`local_select_rescore`) is everything after the kernel.
+    ``dim_chunk`` goes to the kernel as given (``_bin_candidates``)."""
     if interpret is None:
         interpret = not default_backend_is_tpu()
     if final_select not in ("exact", "approx"):
@@ -1370,12 +1464,12 @@ def local_coarse_candidates(
     eff_tile = effective_tile(t.shape[0], tile_n, survivors, m + 2)
     with jax.named_scope(SCOPE_KERNEL):
         cd, ci, bounds = _bin_candidates(
-            q, t, block_q=min(block_q, max(8, q.shape[0])),
+            q, t, block_q=effective_block_q(block_q, q.shape[0]),
             tile_n=eff_tile, survivors=survivors,
             precision=precision, interpret=interpret,
             grid_order=grid_order, kernel=kernel, db_int8=db_int8,
             offset=offset, keep=m + 2 if kernel == "fused" else None,
-            db_pq=db_pq, terms=terms,
+            db_pq=db_pq, terms=terms, dim_chunk=dim_chunk,
         )
     n_q = q.shape[0]
     return cd[:n_q], ci[:n_q], bounds[:n_q]

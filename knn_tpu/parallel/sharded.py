@@ -836,6 +836,9 @@ class ShardedKNN:
         # (kernel's candidate width, width the final top-k sees) of the
         # last resolved pallas program, per shard (_pallas_setup)
         self._select_widths: Tuple[int, int] = (0, 0)
+        # (columns of one dim chunk, chunks a row tile is cut into) that
+        # _pallas_setup handed its last program's kernel
+        self._dim_chunking: Tuple[int, int] = (0, 0)
         #: lazily built serving engines, keyed by ladder spec
         #: (buckets, min_bucket, max_bucket) — search_bucketed; the lock
         #: keeps concurrent cold calls from double-building an engine
@@ -1655,7 +1658,7 @@ class ShardedKNN:
                     device_d = return_distances and not dot
                     prog, m_prog, w, interpret = self._pallas_setup(
                         m - self.k, include_distances=device_d,
-                        terms=terms, **knobs)
+                        terms=terms, batch_rows=bs, **knobs)
                     ops_tail = self._pallas_operands(knobs["precision"])
             call.set("queries", n_q)
             call.set("batches", len(batches))
@@ -1740,6 +1743,15 @@ class ShardedKNN:
                 merged["mxu_passes"] = terms.count("+") + 1
                 obs.counter(_mn.KERNEL_TERMS, terms=terms).inc(
                     len(batches))
+                # how the kernel cut a row tile's columns, as setup
+                # handed it to the program: one chunk wherever the padded
+                # width fits VMEM, 128-column chunks accumulated in
+                # scratch where it does not
+                merged["dim_chunk"], merged["dim_chunks"] = (
+                    self._dim_chunking)
+                obs.counter(_mn.KERNEL_DIM_CHUNKS,
+                            chunks=str(merged["dim_chunks"])).inc(
+                    len(batches))
             for key, value in merged.items():
                 call.set(key, value)
             stats = {
@@ -1754,7 +1766,9 @@ class ShardedKNN:
                 # kernel ran with, so a caller can tell which one answered
                 stats["pallas_knobs"] = {
                     **knobs, "interpret": interpret, "terms": terms,
-                    "mxu_passes": merged["mxu_passes"]}
+                    "mxu_passes": merged["mxu_passes"],
+                    "dim_chunk": merged["dim_chunk"],
+                    "dim_chunks": merged["dim_chunks"]}
                 stats["tuning"] = tune_info
             # mirror the quality signals into the telemetry registry —
             # the per-call stats dict stays the API, the registry
@@ -1937,7 +1951,8 @@ class ShardedKNN:
                       final_recall_target: Optional[float] = None,
                       grid_order: str = "query_major",
                       kernel: str = "tiled",
-                      terms: str = "hh+hl+lh"):
+                      terms: str = "hh+hl+lh",
+                      batch_rows: Optional[int] = None):
         """(program, m, analysis_window, interpret) for the one-pass
         certified path — the ONE home of the kernel-geometry margin cap
         and the packed-output window, shared by :meth:`_certify_pallas`
@@ -1950,11 +1965,22 @@ class ShardedKNN:
         the kernel, and returned so ``stats["pallas_knobs"]`` reports
         the value the kernel was actually given.  ``terms`` is
         :meth:`_kernel_terms`'s reading of the data, no knob: left out,
-        the program forms every product and is right for any rows."""
+        the program forms every product and is right for any rows.
+
+        The width of a dim chunk is resolved HERE too, once
+        (ops.pallas_knn.dim_chunking over the resolved tile, the query
+        block a shard runs for batches of ``batch_rows`` queries — left
+        out, a full ``block_q`` — and the parts ``terms`` streams), and
+        handed to the program as the kernel's static ``dim_chunk``: what
+        ``search_certified`` reports (``self._dim_chunking``) is what the
+        kernel was given, not a second reading of the shape."""
         from knn_tpu.ops.pallas_knn import (
+            BLOCK_Q,
             TILE_N,
             _geometry,
             default_backend_is_tpu,
+            dim_chunking,
+            effective_block_q,
             effective_tile,
             select_merge_geometry,
         )
@@ -1997,6 +2023,14 @@ class ShardedKNN:
         merge = select_merge_geometry(select_width, m)
         self._select_widths = (
             select_width, select_width if merge is None else merge[2])
+        bq = block_q or BLOCK_Q
+        if batch_rows is not None:
+            bq = effective_block_q(
+                bq, -(-batch_rows // self.mesh.shape[QUERY_AXIS]))
+        self._dim_chunking = dim_chunking(
+            self._tp.shape[1], tile_n=eff_tile, block_q=bq,
+            precision=precision, kernel=kernel, terms=terms,
+            survivors=survivors)
         # the program gets setup's RESOLVED tile, not the raw request:
         # m was capped so that width(eff_tile) >= m+2, which makes the
         # kernel's own effective_tile(min_width=m+2) a fixpoint — the
@@ -2012,6 +2046,7 @@ class ShardedKNN:
             grid_order=grid_order, kernel=kernel,
             quant_offset=quant_offset, dcn_merge=self.dcn_merge,
             interpret=interpret, terms=terms, augmented=self._dot_aug,
+            dim_chunk=self._dim_chunking[0],
         )
         return prog, m, _analysis_window(self.k, m), interpret
 
@@ -2251,6 +2286,7 @@ def _pallas_certified_program(
     interpret: Optional[bool] = None,
     terms: str = "hh+hl+lh",
     augmented: bool = False,
+    dim_chunk: Optional[int] = None,
 ):
     """ONE-pass sharded self-certifying coarse select + device rank +
     device certificate (ops.pallas_knn.local_certified_candidates per
@@ -2295,7 +2331,10 @@ def _pallas_certified_program(
     ``augmented`` (an inner-product placement's rows, never a caller's
     choice) appends one replicated scalar to the operand tail, whatever
     the precision: ``_certify_pack_spmd``'s ``aug_slack``.  Without it
-    the program is the one it always was, operation for operation."""
+    the program is the one it always was, operation for operation.
+
+    ``dim_chunk`` is the kernel's static of that name, ``_pallas_setup``'s
+    resolution (None: the kernel reads its own launch's shape)."""
     from knn_tpu.ops.pallas_knn import (
         BLOCK_Q,
         TILE_N,
@@ -2319,6 +2358,7 @@ def _pallas_certified_program(
             final_recall_target=final_recall_target,
             grid_order=grid_order, kernel=kernel, interpret=interpret,
             db_int8=db_q, db_pq=db_pq, offset=quant_offset, terms=terms,
+            dim_chunk=dim_chunk,
         )
         return _certify_pack_spmd(
             q, t, d32, li, lb, consts=consts, db_norm_max=db_norm_max,

@@ -9,8 +9,14 @@ chip time.  A compile is not a run — the chip has the last word
 With no arguments it compiles the certified coarse pass (compiled,
 4,096 queries) at the three benchmark shapes with the knobs the library
 resolves when nobody picks any (``tuning.resolve_full``, no winner
-cache), for each of the three kernels; asks for fused at
-block_q=256 at SIFT, which the library's own VMEM model must refuse
+cache), for each of the three kernels, and again at 512 columns, the
+widest rows whose tile the tiled kernel keeps as ONE dim chunk
+(``analysis.vmem.dim_chunking``; GloVe's 384 and ``text2image2m5``'s
+256 padded columns are the other two such widths in the list), and at
+640, the narrowest it does not (the streaming and fused kernels keep
+128-column chunks at every width: both rows are there so that a rule
+which collapsed them where they have no room fails here); asks for
+fused at block_q=256 at SIFT, which the library's own VMEM model must refuse
 before Mosaic is asked; compiles the whole certified program with the
 kernel's one-product form (``--terms hh``: what a byte corpus and a
 byte batch run, ``ops.pallas_knn.BF16X3_TERMS``) at 5M x 128 on one
@@ -67,6 +73,11 @@ SHAPES = {
     # one chip of text2image-10M as PLACED: inner product, 200 columns
     # and the appended norm column (benchmark/configs/text2image2m5.json)
     "text2image2m5": (2_500_000, 201, 10),
+    # no data set: the widest rows whose tile is still ONE dim chunk at
+    # the default tile and query block (analysis.vmem.dim_chunking), and
+    # the narrowest whose tile is not
+    "wide512": (1_000_000, 512, 100),
+    "wide640": (1_000_000, 640, 100),
 }
 #: shapes whose rows are norm-augmented at placement (metric "dot"): the
 #: certified program takes the augmentation's slack as one more scalar
@@ -170,9 +181,14 @@ def _compile(fn, avals) -> str:
 
 
 def _probe_need(make_case):
-    """The scoped-VMEM need Mosaic reports: climb the kernel's limit
-    from 8 MiB by the size each refusal names until it compiles.
-    Returns (MiB it compiled at, last refused size in MiB)."""
+    """The scoped-VMEM need Mosaic shows: climb the kernel's limit from
+    8 MiB by the size each refusal names until it compiles, then bisect
+    between the highest limit refused and the lowest that compiled.  A
+    refusal's size alone misleads: under its need Mosaic may schedule
+    otherwise and name what THAT would take (one 256-column chunk:
+    98.32 MiB named at a limit of 58, compiles at 62), or spill past
+    the whole VMEM and name nothing.  Returns (least MiB it compiled
+    at, highest MiB refused, last size named)."""
     import jax
 
     import knn_tpu.ops.pallas_knn as pk
@@ -180,24 +196,43 @@ def _probe_need(make_case):
     limit = [8]
     real = pk._vmem_limit_bytes
     pk._vmem_limit_bytes = lambda *a, **kw: limit[0] << 20
-    last = None
+    named = None
+
+    def compiles():
+        nonlocal named
+        jax.clear_caches()
+        try:
+            _compile(*make_case())
+            return True
+        except Exception as e:  # noqa: BLE001 — parsed below
+            m = re.search(r"Scoped allocation with size ([\d.]+)M", str(e))
+            if m:
+                named = float(m.group(1))
+            elif "memory space vmem" not in str(e):
+                raise
+            return False
+
     try:
-        for _ in range(16):
-            jax.clear_caches()
-            try:
-                _compile(*make_case())
-                return limit[0], last
-            except Exception as e:  # noqa: BLE001 — parsed below
-                m = re.search(
-                    r"Scoped allocation with size ([\d.]+)M", str(e))
-                if not m:
-                    raise
-                last = float(m.group(1))
-                limit[0] = max(math.ceil(last), limit[0] + 4)
+        refused = 0
+        while not compiles():
+            refused = limit[0]
+            if refused >= 128:
+                return None, refused, named
+            step = refused + 8
+            if named is not None and named > refused:
+                step = max(math.ceil(named), refused + 4)
+            limit[0] = min(128, step)
+        ok = limit[0]
+        while ok - refused > 1:
+            limit[0] = (ok + refused) // 2
+            if compiles():
+                ok = limit[0]
+            else:
+                refused = limit[0]
+        return ok, refused, named
     finally:
         pk._vmem_limit_bytes = real
         jax.clear_caches()
-    return None, last
 
 
 def default_cases():
@@ -208,7 +243,13 @@ def default_cases():
     cases = [(f"{shape} {kernel} defaults", shape, {"kernel": kernel},
               "compiles")
              for kernel in ("tiled", "streaming", "fused")
-             for shape in ("sift", "gist", "glove")]
+             # the widths whose row tile is ONE dim chunk under the
+             # tiled kernel at these knobs (analysis.vmem.dim_chunking)
+             # are GloVe's 384 padded columns, text2image2m5's 256
+             # (below) and the widest, 512; at 640 it is five.  The
+             # other two kernels must compile as they did at 128-column
+             # chunks
+             for shape in ("sift", "gist", "glove", "wide512", "wide640")]
     cases.append(("sift fused block_q=256", "sift",
                   {"kernel": "fused", "block_q": 256}, "refused"))
     # the whole program of a byte corpus and a byte batch: one product,
@@ -240,9 +281,9 @@ def run_case(name, shape, overrides, expect, mesh, terms, devices, *,
 
     t0 = time.time()
     if probe:
-        ok_at, last = _probe_need(make_case)
-        print(f"NEED {name}: compiles at limit {ok_at} MiB "
-              f"(last refused size {last} MiB)  "
+        ok_at, refused, named = _probe_need(make_case)
+        print(f"NEED {name}: compiles at limit {ok_at} MiB, refused at "
+              f"{refused} (last size named {named} MiB)  "
               f"({time.time() - t0:.0f}s)", flush=True)
         return ok_at is not None
     try:

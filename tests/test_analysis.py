@@ -579,6 +579,18 @@ def test_vmem_geometry_mirrors_pallas_kernel():
     assert vmem.BIN_W == pk.BIN_W
     assert vmem.DIM_CHUNK == pk.DIM_CHUNK
     assert vmem.MAX_CARRY_DEPTH == pk.MAX_CARRY_DEPTH
+    # DIM_CHUNK is the padding grain and the fallback width; the width a
+    # launch runs is the rule's, and the kernel asks the model's rule
+    budget = vmem.budget_for(vmem.TARGET_DEVICE_KIND)
+    for dim in (8, 128, 201, 300, 512, 640, 960, 4096):
+        for bq, tile in ((256, 16384), (128, 32768), (8, 256)):
+            for terms, parts in (("hh+hl+lh", None), ("hh", 1)):
+                assert pk.dim_chunking(
+                    dim, tile_n=tile, block_q=bq, precision="bf16x3",
+                    terms=terms) == vmem.dim_chunking(
+                    -(-dim // vmem.DIM_CHUNK) * vmem.DIM_CHUNK,
+                    tile_n=tile, block_q=bq, db_parts=parts,
+                    budget_bytes=budget), (dim, bq, tile, terms)
 
 
 def test_vmem_operand_widths_mirror_roofline():
@@ -654,7 +666,6 @@ def test_vmem_model_tracks_the_compiler_at_the_benchmark_shapes():
         ("gist", "tiled", 128, 16384, 49.10),
         ("gist", "tiled", 256, 16384, 81.94),
         ("gist", "tiled", 128, 32768, 99.60),
-        ("glove", "tiled", 256, 16384, 82.94),
         ("sift", "streaming", 128, 16384, 71.65),
         ("sift", "streaming", 256, 16384, 126.55),
         ("gist", "streaming", 128, 16384, 80.07),
@@ -675,6 +686,43 @@ def test_vmem_model_tracks_the_compiler_at_the_benchmark_shapes():
         assert (est <= budget) == (need <= 128), (shape, kernel, bq)
         if est <= budget:
             assert vmem.limit_bytes(est, budget) >= need * vmem.MIB
+
+
+def test_vmem_model_bounds_the_one_chunk_geometries():
+    """The geometries ``dim_chunking`` collapses to ONE chunk (PR 32),
+    probed the same way but by bisection on the limit: the least MiB the
+    kernel compiled at.  GloVe's rows (384 padded columns) were three
+    128-column chunks under the tiled kernel when the model was fitted
+    (82.94 at bq256); now they and ``text2image2m5``'s 256 are one.
+    The model may run 14% over, the limit it asks for (an eighth more)
+    must cover the need, and the rule must have chosen one chunk for
+    every one of them — and 128 columns under the other two kernels,
+    whose one-chunk geometry at 512 columns Mosaic refuses."""
+    shapes = {"text2image2m5": (2_500_000, 201, 10),
+              "glove": (1_183_514, 300, 50),
+              "wide512": (1_000_000, 512, 100)}
+    compiled_at = [
+        ("text2image2m5", "tiled", 256, 59),   # refused at 58
+        ("glove", "tiled", 256, 79),
+        ("wide512", "tiled", 256, 99),
+    ]
+    budget = vmem.budget_for(vmem.TARGET_DEVICE_KIND)
+    for shape, kernel, bq, need in compiled_at:
+        n, d, k = shapes[shape]
+        est = vmem.launch_estimate(n=n, d=d, k=k, kernel=kernel, block_q=bq)
+        padded = -(-d // vmem.DIM_CHUNK) * vmem.DIM_CHUNK
+        assert est["geometry"]["dim_chunk"] == padded, shape
+        assert est["geometry"]["dim_chunks"] == 1, shape
+        total = est["total_bytes"]
+        assert need <= total / vmem.MIB <= 1.14 * need, (
+            shape, kernel, total / vmem.MIB, need)
+        assert total <= budget
+        assert vmem.limit_bytes(total, budget) >= need * vmem.MIB
+        for other in ("streaming", "fused"):
+            geo = vmem.launch_estimate(n=n, d=d, k=k, kernel=other,
+                                       block_q=128)["geometry"]
+            assert geo["dim_chunk"] == vmem.DIM_CHUNK, (shape, other)
+            assert geo["dim_chunks"] == padded // vmem.DIM_CHUNK
 
 
 def test_vmem_model_refuses_only_where_it_is_calibrated():

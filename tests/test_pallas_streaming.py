@@ -16,6 +16,7 @@ import pytest
 
 from knn_tpu.ops.pallas_knn import (
     BIN_W,
+    DIM_CHUNK,
     _bin_candidates,
     kernel_launches_per_batch,
     knn_search_pallas,
@@ -39,7 +40,11 @@ def test_streaming_bitwise_equals_tiled_bin_candidates(rng, dim, precision,
     # uneven tile counts (n % tile_n != 0 -> PAD_VAL padding), both
     # single- and multi-chunk dims (300 spans 3 DIM_CHUNKs), and output
     # blocks of one, two and four lane-rows a tile (the streaming kernel
-    # writes each tile's block at a dynamic column offset of that width)
+    # writes each tile's block at a dynamic column offset of that width).
+    # Equal where the chunking is equal: the streaming kernel keeps
+    # 128-column chunks at every width, the tiled one would run a tile
+    # this small as ONE chunk (ops.pallas_knn.dim_chunking), so it is
+    # held to the streaming kernel's width
     db = rng.normal(size=(3 * BIN_W + 41, dim)).astype(np.float32) * 10
     queries = rng.normal(size=(11, dim)).astype(np.float32) * 10
     outs = {}
@@ -47,7 +52,8 @@ def test_streaming_bitwise_equals_tiled_bin_candidates(rng, dim, precision,
         outs[kern] = _bin_candidates(
             jnp.asarray(queries), jnp.asarray(db), block_q=8,
             tile_n=2 * BIN_W, survivors=survivors,
-            precision=precision, interpret=True, kernel=kern)
+            precision=precision, interpret=True, kernel=kern,
+            dim_chunk=DIM_CHUNK)
     assert outs["tiled"][0].shape[1] == 2 * survivors * BIN_W
     for a, b in zip(outs["tiled"], outs["streaming"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -247,7 +247,7 @@ def test_stale_kernel_version_entry_falls_back_to_defaults(cache_path):
     # it even though "precision": "pq" is a perfectly current knob
     from knn_tpu.ops.pallas_knn import KERNEL_VERSION
 
-    assert KERNEL_VERSION == 8
+    assert KERNEL_VERSION == 9
     cache.put(base + "|kv4", {"knobs": {**tuning.DEFAULT_KNOBS,
                                         "precision": "pq",
                                         "kernel": "streaming"}})
@@ -298,10 +298,13 @@ def test_version_6_winner_naming_a_removed_knob_is_never_used(
         db[:4], selector="pallas", tune_cache=cache_path)
     assert stats["tuning"]["source"] == "default"
     # beside the knobs: what the program resolved for itself, from the
-    # backend (interpret) and from the data (terms, mxu_passes)
+    # backend (interpret), from the data (terms, mxu_passes) and from
+    # the launch's shape (dim_chunk, dim_chunks)
     assert {kk: v for kk, v in stats["pallas_knobs"].items()
-            if kk not in ("interpret", "terms", "mxu_passes")
-            } == tuning.DEFAULT_KNOBS
+            if kk not in ("interpret", "terms", "mxu_passes", "dim_chunk",
+                          "dim_chunks")} == tuning.DEFAULT_KNOBS
+    assert (stats["pallas_knobs"]["dim_chunk"],
+            stats["pallas_knobs"]["dim_chunks"]) == (128, 1)
 
 
 def test_default_knobs_are_the_kernel_shaping_arguments():
@@ -323,7 +326,7 @@ def test_default_knobs_are_the_kernel_shaping_arguments():
         "return_sqrt") == set(tuning.DEFAULT_KNOBS)
     assert kwargs_of(
         ShardedKNN._pallas_setup, "margin", "include_distances", "terms",
-    ) == set(tuning.DEFAULT_KNOBS)
+        "batch_rows") == set(tuning.DEFAULT_KNOBS)
 
 
 def test_standard_grid_includes_int8_candidate():
