@@ -75,6 +75,8 @@ CERTIFIED_HOST_EXACT = "knn_tpu_certified_host_exact_queries_total"
 CERTIFIED_RANK_CORRECTED = "knn_tpu_certified_rank_corrected_queries_total"
 CERTIFIED_METRIC_QUERIES = "knn_tpu_certified_metric_queries_total"
 CERTIFIED_QUANT_BOUND = "knn_tpu_certified_quant_bound"
+RANGE_QUERIES = "knn_tpu_range_queries_total"
+RANGE_RESULTS = "knn_tpu_range_results_total"
 
 # --- autotuner (knn_tpu.tuning) ----------------------------------------
 TUNING_RESOLVES = "knn_tpu_tuning_resolve_total"
@@ -289,6 +291,18 @@ CATALOG = {
         "Per-query int8 certified quantization error bound epsilon "
         "(score units) — the quality signal the int8 coarse pass "
         "computes."),
+    RANGE_QUERIES: (
+        "counter", ("outcome",),
+        "Queries answered by ShardedKNN.range_search_certified, by how "
+        "their result list was finished: 'complete' by the first pass "
+        "alone (k-th distance over the radius), 'truncated' by the "
+        "device completion, 'host_scan' by the exact host scan (count "
+        "over the collect width).  Every outcome exists from the first "
+        "call."),
+    RANGE_RESULTS: (
+        "counter", (),
+        "Rows returned by ShardedKNN.range_search_certified, over all "
+        "its queries."),
     TUNING_RESOLVES: (
         "counter", (), "tuning.resolve() invocations."),
     TUNING_CACHE_HITS: (

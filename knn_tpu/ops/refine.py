@@ -102,23 +102,20 @@ def _score_members(db_np: np.ndarray, queries_np: np.ndarray,
         np.einsum("nd,nd->n", acc, acc, out=out)
 
 
-def exact_scores(db_np: np.ndarray, queries_np: np.ndarray,
-                 idx: np.ndarray, metric: str) -> np.ndarray:
-    """[Q, k] float64 ``metric`` values between each query and the db
-    rows ``idx`` [Q, k] names (:func:`_score_members`' arithmetic), made
-    a block of members at a time and shared among the pool's threads
-    like :func:`rank_correct_runs`' re-score.  Indices past the db (the
-    sentinel) read +inf."""
-    n_q, k = idx.shape
-    flat = np.asarray(idx, np.int64).reshape(-1)
-    safe = np.clip(flat, 0, db_np.shape[0] - 1)
-    rows = np.repeat(np.arange(n_q), k)
-    out = np.empty(flat.size)
+def exact_pair_scores(db_np: np.ndarray, queries_np: np.ndarray,
+                      rows: np.ndarray, cand: np.ndarray,
+                      metric: str = "l2") -> np.ndarray:
+    """[P] float64 ``metric`` values of the flat pairs (query
+    ``rows[j]``, db row ``cand[j]``), by :func:`_score_members`'
+    arithmetic, made a block of pairs at a time and shared among the
+    pool's threads like :func:`rank_correct_runs`' re-score.  Every
+    ``cand`` names a db row."""
+    out = np.empty(cand.size)
     block = _block_rows(db_np.shape[1])
-    starts = range(0, flat.size, block)
+    starts = range(0, cand.size, block)
 
     def score(lo: int) -> None:
-        _score_members(db_np, queries_np, safe[lo : lo + block],
+        _score_members(db_np, queries_np, cand[lo : lo + block],
                        rows[lo : lo + block], metric, out[lo : lo + block])
 
     if len(starts) > 1:
@@ -127,7 +124,47 @@ def exact_scores(db_np: np.ndarray, queries_np: np.ndarray,
     else:
         for lo in starts:
             score(lo)
+    return out
+
+
+def exact_scores(db_np: np.ndarray, queries_np: np.ndarray,
+                 idx: np.ndarray, metric: str) -> np.ndarray:
+    """[Q, k] float64 ``metric`` values between each query and the db
+    rows ``idx`` [Q, k] names (:func:`exact_pair_scores` over the
+    flattened pairs).  Indices past the db (the sentinel) read +inf."""
+    n_q, k = idx.shape
+    flat = np.asarray(idx, np.int64).reshape(-1)
+    out = exact_pair_scores(
+        db_np, queries_np, np.repeat(np.arange(n_q), k),
+        np.clip(flat, 0, db_np.shape[0] - 1), metric)
     return np.where(flat < db_np.shape[0], out, np.inf).reshape(n_q, k)
+
+
+def host_exact_range(db_np: np.ndarray, q_np: np.ndarray, radius_sq: float
+                     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Unconditional last-resort exact range scan on the host: every
+    pair (query row of ``q_np``, db row) whose float64 direct-difference
+    squared distance is ``<= radius_sq``, as flat ``(query positions,
+    db rows, distances)`` in no particular order.  A block of rows at a
+    time (:func:`_block_rows`); O(Q*N*D) host arithmetic, for the few
+    queries whose result count passes the device completion's collect
+    width."""
+    block = _block_rows(db_np.shape[1])
+    qs, ts, ds = [], [], []
+    for qi in range(q_np.shape[0]):
+        q64 = q_np[qi].astype(np.float64)
+        for lo in range(0, db_np.shape[0], block):
+            diff = db_np[lo : lo + block].astype(np.float64)
+            diff -= q64
+            d = np.einsum("nd,nd->n", diff, diff)
+            hit = np.flatnonzero(d <= radius_sq)
+            if hit.size:
+                qs.append(np.full(hit.size, qi, np.int64))
+                ts.append(hit + lo)
+                ds.append(d[hit])
+    if not qs:
+        return (np.empty(0, np.int64), np.empty(0, np.int64), np.empty(0))
+    return np.concatenate(qs), np.concatenate(ts), np.concatenate(ds)
 
 
 def rank_correct_runs(

@@ -490,6 +490,10 @@ def _fetch_or_redispatch(out, redo, what: str = "device fetch",
 #: root span of one ``search_certified`` call; its stages name it as
 #: their ``parent`` (docs/OBSERVABILITY.md "Span lifecycle")
 _CALL_SPAN = "certified.call"
+#: root span of one ``range_search_certified`` call: the first pass's
+#: ``certified.call`` tree hangs under it (same trace id), beside
+#: ``certified.range_complete`` and ``certified.range_pack``
+_RANGE_SPAN = "certified.range_call"
 #: what a metric other than l2 adds to a call on either side of the l2
 #: machinery: ONE span a call, the sum of both sides (inner product only
 #: today: the zero column before, the float64 scores after)
@@ -1137,6 +1141,13 @@ class ShardedKNN:
     def radius_search(self, queries, radius: float, *, max_neighbors: int):
         """All db rows within ``radius`` per query, bounded at
         ``max_neighbors`` — the sharded form of ops.radius.radius_search.
+        (Which of the two range calls a caller wants:
+        this one for a bounded, fixed-shape answer in float32 — at most
+        ``max_neighbors`` rows a query, truncation reported, a Euclidean
+        radius, membership at the boundary by float32 arithmetic;
+        :meth:`range_search_certified` for the COMPLETE answer — every
+        row at or under a squared radius, nothing capped, membership
+        and distances decided in float64, variable-length lists.)
 
         Returns ``(dists [Q, M], idx [Q, M], counts [Q])``: the sharded
         nearest-M select masked to the radius (beyond-radius slots
@@ -1460,6 +1471,7 @@ class ShardedKNN:
         kernel: Optional[str] = None,
         tune_cache: Optional[str] = None,
         return_sqrt: bool = False,
+        _under: Optional[Tuple[str, str]] = None,
     ):
         """Exact lexicographic top-k via the certified pipeline, sharded.
         Returns (dists_f64, idx, stats).  L2, cosine and dot (the
@@ -1582,9 +1594,12 @@ class ShardedKNN:
             raise ValueError(f"unknown selector {selector!r}; expected {SELECTORS}")
         from knn_tpu.ops.certified import repair_uncertified
 
-        tid = obs.new_trace_id()
+        # a call that is another's first pass runs under that call's
+        # trace id and names its span as parent (_under, private)
+        tid, parent = _under or (obs.new_trace_id(), None)
         dot = self.metric == "dot"
-        with obs.span(_CALL_SPAN, tid, selector=selector) as call:
+        with obs.span(_CALL_SPAN, tid, selector=selector,
+                      **({"parent": parent} if parent else {})) as call:
             q_np = np.asarray(queries, dtype=np.float32)
             map_s = {"before_s": 0.0, "after_s": 0.0}
             if dot:
@@ -1815,6 +1830,240 @@ class ShardedKNN:
 
                 d = metric_values(d, self.metric)
             return (d if return_distances else None), i, stats
+
+    def range_search_certified(self, queries, *, radius_sq: float,
+                               selector: str = "pallas"):
+        """Exact, complete range search: every db row within a squared
+        radius of each query, through the certified path.  Returns
+        ``(lims, idx, dist, stats)`` in big-ann-benchmarks' range-search
+        format: ``lims`` int64 ``[Q + 1]``, query ``i``'s results are
+        ``idx[lims[i]:lims[i + 1]]`` (int64) with ``dist`` (float64)
+        beside them, each list in (distance, index) order.
+
+        **Contract.**  For every query the list is exactly ``{t :
+        d64(q, t) <= radius_sq}``, INCLUSIVE, ``d64`` the float64
+        squared L2 distance over the float32 rows and queries as given.
+        Nothing is capped and nothing is dropped; ``radius_sq`` is the
+        threshold in ranking space (squared) and is compared as given,
+        never squared from a root.  l2 family only: the certificate is
+        a squared-L2 bound and a cosine or inner-product radius has no
+        part in it.  (:meth:`radius_search` is the bounded, float32
+        counterpart: a Euclidean radius, at most ``max_neighbors`` rows
+        a query, truncation reported, membership at the boundary by
+        float32 arithmetic.)
+
+        Three steps, and no knob (the one collect width is read off the
+        placement's ``k``):
+
+        1. **First pass**: :meth:`search_certified` at the placed ``k``,
+           as it stands.  That top-k is exact in float64 (distance,
+           index) order, so every row it does NOT return is at least as
+           far as its k-th: a query whose k-th distance is over
+           ``radius_sq`` is **known complete**, and its answer is the
+           prefix at or under the radius.
+        2. **Completion** of the truncated queries (k-th distance at or
+           under ``radius_sq``): gathered into sub-batches of
+           ``ops.radius.RANGE_SUB_BATCH`` and finished on the device by
+           ONE program (``_range_program``): a pass over the placed
+           rows (``within_words``: count and mark every row whose
+           float32 distance is at or under ``radius_sq`` plus
+           ``certification_tolerance``, a superset) and the marked
+           words compacted at ``ops.radius.range_width(k)``
+           (``compact_words``), compiled by the first call that has a
+           truncated query.  A query whose count passes that width is
+           finished by an exact host scan
+           (``ops.refine.host_exact_range``) and counted as such.
+        3. **Pack**: every returned pair's distance is float64
+           (``ops.refine.exact_pair_scores``) and membership is decided
+           on that value, so rows inside float32's error band of the
+           threshold are decided in float64 too.  The complete queries'
+           prefixes are scored while the completion's first sub-batch
+           is on the device.
+
+        ``stats`` is the first pass's (``certified``,
+        ``fallback_queries``, ``pallas_knobs``, ``tuning`` ...) plus
+        ``stats["range"]``: ``queries``, ``complete``, ``truncated``,
+        ``host_scan`` (each query is one of the three), ``results``,
+        ``width`` (the collect width; 0 where nothing was truncated),
+        ``sub_batches`` and ``radius_sq``.  One span
+        ``certified.range_call`` a call, with the first pass's
+        ``certified.call`` tree, ``certified.range_complete`` and
+        ``certified.range_pack`` under it (docs/OBSERVABILITY.md)."""
+        self._require_resident("range_search_certified")
+        if self.metric not in ("l2", "sql2", "euclidean"):
+            raise ValueError(
+                f"range_search_certified supports the l2 family only, not "
+                f"{self.metric!r}: radius_sq is a squared-L2 threshold")
+        radius_sq = float(radius_sq)
+        if not 0.0 <= radius_sq < np.inf:
+            raise ValueError(
+                f"radius_sq must be finite and >= 0, got {radius_sq}")
+        from knn_tpu.ops.pallas_knn import RANK_SLACK
+        from knn_tpu.ops.radius import RANGE_SUB_BATCH
+        from knn_tpu.ops.refine import exact_pair_scores
+
+        tid = obs.new_trace_id()
+        with obs.span(_RANGE_SPAN, tid, selector=selector,
+                      radius_sq=radius_sq) as call:
+            q_np = np.asarray(queries, dtype=np.float32)
+            n_q, k = q_np.shape[0], self.k
+            d, i, stats = self.search_certified(
+                q_np, selector=selector, _under=(tid, _RANGE_SPAN))
+            db_np = self._host_train()
+            # the k-th row decides: in float32 where its value is clear
+            # of the threshold by the device's rank slack, in float64
+            # where it is not (the first pass's near-tied and repaired
+            # entries are float64 already)
+            maybe = d <= radius_sq / (1.0 - 2.0 * RANK_SLACK)
+            inside = d[:, k - 1] <= radius_sq / (1.0 + 2.0 * RANK_SLACK)
+            band = np.flatnonzero(~inside & maybe[:, k - 1])
+            if band.size:
+                inside[band] = exact_pair_scores(
+                    db_np, q_np, band, i[band, k - 1]) <= radius_sq
+            # all the rows there are leave nothing to complete
+            truncated = inside if k < self.n_train else np.zeros_like(inside)
+            tq = np.flatnonzero(truncated)
+            # the completion's first sub-batch is sent off before the
+            # pack, which then has the host while the device works
+            first = self._range_launch(
+                q_np, db_np, tq[:RANGE_SUB_BATCH], radius_sq)
+            with obs.span("certified.range_pack", tid,
+                          parent=_RANGE_SPAN) as sp:
+                # the complete queries' prefix: whatever could be in by
+                # the float32 value is scored and decided in float64;
+                # nonzero() walks it in the first pass's own (distance,
+                # index) order
+                pq, cols = np.nonzero(maybe & ~truncated[:, None])
+                pi = i[pq, cols]
+                pd = exact_pair_scores(db_np, q_np, pq, pi)
+                keep = pd <= radius_sq
+                pq, pi, pd = pq[keep], pi[keep], pd[keep]
+                sp.set("rows_returned", int(pi.size))
+            with obs.span("certified.range_complete", tid,
+                          parent=_RANGE_SPAN, queries=int(tq.size)) as sp:
+                cq, ci, cd, done = self._range_complete(
+                    q_np, db_np, tq, radius_sq, first)
+                sp.set("rung", done["width"])
+                sp.set("sub_batches", done["sub_batches"])
+                sp.set("host_scan_queries", done["host_scan"])
+                sp.set("rows_returned", int(ci.size))
+            # both are sorted by query, so a stable sort of the queries
+            # alone interleaves them
+            rows = np.concatenate([pq, cq])
+            order = np.argsort(rows, kind="stable")
+            idx = np.concatenate([pi, ci])[order]
+            dist = np.concatenate([pd, cd])[order]
+            lims = np.zeros(n_q + 1, np.int64)
+            np.cumsum(np.bincount(rows, minlength=n_q), out=lims[1:])
+            n_long = int(tq.size)
+            rng_stats = {
+                "queries": n_q, "radius_sq": radius_sq,
+                "complete": n_q - n_long,
+                "truncated": n_long - done["host_scan"],
+                "host_scan": done["host_scan"],
+                "results": int(idx.size), "width": done["width"],
+                "sub_batches": done["sub_batches"],
+            }
+            for key in ("complete", "truncated", "host_scan", "results"):
+                call.set(key, rng_stats[key])
+            for outcome in ("complete", "truncated", "host_scan"):
+                obs.counter(_mn.RANGE_QUERIES, outcome=outcome).inc(
+                    rng_stats[outcome])
+            obs.counter(_mn.RANGE_RESULTS).inc(idx.size)
+            return lims, idx, dist, {**stats, "range": rng_stats}
+
+    def _range_launch(self, q_np, db_np, sub, radius_sq: float):
+        """The completion's device program (``_range_program``) for the
+        queries ``sub`` (at most a sub-batch of positions in ``q_np``),
+        sent to the device and not waited for: its ``(counts,
+        compact)``, or None for no query."""
+        from knn_tpu.ops.certified import certification_tolerance
+        from knn_tpu.ops.radius import RANGE_SUB_BATCH, range_width
+
+        if not sub.size:
+            return None
+        chunk = np.zeros((RANGE_SUB_BATCH, q_np.shape[1]), np.float32)
+        chunk[:sub.size] = q_np[sub]
+        # a negative threshold marks nothing (distances are clamped at
+        # 0): the sub-batch's unused rows
+        thr = np.full(RANGE_SUB_BATCH, -1.0, np.float32)
+        # the float32 pass errs by under this (ops.certified), so the
+        # widened threshold marks a superset; rounded up to float32
+        tol = certification_tolerance(
+            q_np[sub], db_np, db_norm_max=self._db_norm_max())
+        thr[:sub.size] = np.nextafter(
+            (radius_sq + tol).astype(np.float32), np.float32(np.inf))
+        # the pass's row tile, which the decode has to know too
+        prog = _range_program(self.mesh, self.n_train,
+                              self.train_tile or 131072,
+                              range_width(self.k))
+        qp, _ = self._place_queries(chunk)
+        thr_p, _ = self._place_queries(thr)
+        return _retry_transient(
+            lambda: prog(qp, self._tp, thr_p), "range completion dispatch")
+
+    def _range_complete(self, q_np, db_np, tq, radius_sq: float, first):
+        """Step 2 of :meth:`range_search_certified`: the complete result
+        lists of the truncated queries ``tq`` (positions in ``q_np``),
+        as flat ``(query positions, db rows, float64 distances)`` sorted
+        by (query, distance, index), and what was done: the collect
+        width (0 where nothing was sent), the sub-batches sent and the
+        queries finished by the host scan.  ``first`` is the first
+        sub-batch's answer, already on its way (:meth:`_range_launch`)."""
+        from knn_tpu.ops.radius import (RANGE_SUB_BATCH, decode_words,
+                                        range_width)
+        from knn_tpu.ops.refine import exact_pair_scores, host_exact_range
+
+        width = range_width(self.k)
+        shards, shard_rows = self.db_shards, self._shard_rows()
+        tile = self.train_tile or 131072
+        done = {"width": width if tq.size else 0, "sub_batches": 0,
+                "host_scan": 0}
+        found = []  # (query positions, db rows, float64 distances)
+        for lo in range(0, tq.size, RANGE_SUB_BATCH):
+            sub = tq[lo:lo + RANGE_SUB_BATCH]
+            counts, compact = first if lo == 0 else self._range_launch(
+                q_np, db_np, sub, radius_sq)
+            done["sub_batches"] += 1
+            # a query that marks no more rows than the width marks no
+            # more words than it on any shard
+            over = np.asarray(counts)[:sub.size] > width
+            if not over.all():
+                compact = np.asarray(compact)
+                per = compact.shape[2] // shards
+                qs, ts = [], []
+                for s in range(shards):
+                    qi, ri = decode_words(
+                        compact[:, :, s * per:(s + 1) * per], shard_rows,
+                        tile)
+                    qs.append(qi)
+                    ts.append(ri + s * shard_rows)
+                qi, ti = np.concatenate(qs), np.concatenate(ts)
+                sel = ~over[qi]
+                qi, ti = sub[qi[sel]], ti[sel]
+                dd = exact_pair_scores(db_np, q_np, qi, ti)
+                sel = dd <= radius_sq
+                found.append((qi[sel], ti[sel], dd[sel]))
+            if over.any():
+                # last resort: more marked rows than the width holds
+                hq = sub[over]
+                done["host_scan"] += int(hq.size)
+                qi, ti, dd = host_exact_range(db_np, q_np[hq], radius_sq)
+                found.append((hq[qi], ti, dd))
+        if not found:
+            return (np.empty(0, np.int64), np.empty(0, np.int64),
+                    np.empty(0), done)
+        cq, ci, cd = (np.concatenate(x) for x in zip(*found))
+        ci = ci.astype(np.int64)
+        # (distance, index) order within each query: one short sort a
+        # query, cheaper than one three-key sort over all of them
+        order = np.argsort(cq, kind="stable")
+        cq, ci, cd = cq[order], ci[order], cd[order]
+        starts = np.flatnonzero(np.diff(cq, prepend=-1))
+        for a, b in zip(starts, np.append(starts[1:], cq.size)):
+            seg = np.lexsort((ci[a:b], cd[a:b]))
+            ci[a:b], cd[a:b] = ci[a:b][seg], cd[a:b][seg]
+        return cq, ci, cd, done
 
     def _certify_counted(
         self, batches, bs, m, d, i, q_np, db_np, db_norm_max, selector,
@@ -2591,6 +2840,39 @@ def _count_program(mesh: Mesh, n_train: int, train_tile: Optional[int]):
             mesh=mesh,
             in_specs=(P(QUERY_AXIS), P(dbp), P(QUERY_AXIS)),
             out_specs=P(QUERY_AXIS),
+            check_vma=False,
+        )
+    )
+
+
+@functools.lru_cache(maxsize=32)
+def _range_program(mesh: Mesh, n_train: int, tile: int, width: int):
+    """The range completion's device program: on every db shard one
+    pass over the placed rows, ``tile`` rows a step
+    (ops.radius.within_words), and the marked words compacted to
+    ``width`` a query (ops.radius.compact_words).  Per query the count
+    of rows at or under its threshold, summed over the db axis, and the
+    shards' compacted words side by side along the last axis."""
+    from knn_tpu.ops.radius import compact_words, within_words
+
+    hosts, chips = db_topology(mesh)
+    dbp = db_axes(mesh)
+
+    def spmd(q, t, thr):
+        db_idx = _db_shard_index(hosts, chips)
+        n_local_valid = jnp.clip(n_train - db_idx * t.shape[0], 0, t.shape[0])
+        counts, words = within_words(t, q, thr, tile=tile,
+                                     n_valid=n_local_valid)
+        if hosts * chips > 1:
+            counts = lax.psum(counts, dbp if hosts > 1 else DB_AXIS)
+        return counts, compact_words(words, width)
+
+    return jax.jit(
+        jax.shard_map(
+            spmd,
+            mesh=mesh,
+            in_specs=(P(QUERY_AXIS), P(dbp), P(QUERY_AXIS)),
+            out_specs=(P(QUERY_AXIS), P(QUERY_AXIS, None, dbp)),
             check_vma=False,
         )
     )

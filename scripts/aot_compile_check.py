@@ -22,10 +22,14 @@ kernel's one-product form (``--terms hh``: what a byte corpus and a
 byte batch run, ``ops.pallas_knn.BF16X3_TERMS``) at 5M x 128 on one
 chip and at 20M x 128 on the 1x4 mesh, printing what each keeps on a
 chip; compiles the inner-product cell's program (2.5M x 201 placed
-columns, k=10: one more operand, no distance block); and compiles the
+columns, k=10: one more operand, no distance block) and the range
+cell's first pass (2.5M x 256, one product, ``ssnpp2m5``); compiles the
 final select's bin-merge kernel at a 5M-row chip's candidate width
-(``bigann20m``) and at ``text2image2m5``'s (39,168 columns at m+2 = 40:
-62 lane-rows a merge bin).  Flags pick one geometry instead:
+(``bigann20m``), at ``text2image2m5``'s (39,168 columns at m+2 = 40:
+62 lane-rows a merge bin) and at ``ssnpp2m5``'s (39,168 at m+2 = 130);
+and compiles the range completion's program at ``ssnpp2m5`` (the pass
+over the rows and the compaction at ``ops.radius.range_width``).
+Flags pick one geometry instead:
 
     python scripts/aot_compile_check.py --shape gist --block-q 128
     python scripts/aot_compile_check.py --shape sift --kernel streaming \\
@@ -76,6 +80,9 @@ SHAPES = {
     # no data set: the widest rows whose tile is still ONE dim chunk at
     # the default tile and query block (analysis.vmem.dim_chunking), and
     # the narrowest whose tile is not
+    # one chip of ssnpp-10M: 256 byte-valued columns, range search over
+    # a top-100 first pass (benchmark/configs/ssnpp2m5.json)
+    "ssnpp2m5": (2_500_000, 256, 100),
     "wide512": (1_000_000, 512, 100),
     "wide640": (1_000_000, 640, 100),
 }
@@ -83,6 +90,11 @@ SHAPES = {
 #: certified program takes the augmentation's slack as one more scalar
 #: and sends no distance block back
 AUGMENTED = ("text2image2m5",)
+#: shapes answered by ``range_search_certified``: its completion's
+#: program is compiled too
+RANGE = ("ssnpp2m5",)
+#: the exact path's row tile the benchmark's configurations place with
+TRAIN_TILE = 131072
 
 
 def _topology_devices():
@@ -173,6 +185,37 @@ def _merge_case(shape: str, db_shards: int, devices):
                 jax.ShapeDtypeStruct((NQ, width), jnp.int32, sharding=sh))
 
 
+def _range_case(shape: str, devices, mesh_shape):
+    """(name, fn, avals) of the range completion's program
+    (parallel.sharded._range_program) over the shape's rows on a
+    topology mesh."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from knn_tpu.ops.radius import RANGE_SUB_BATCH, range_width
+    from knn_tpu.parallel.mesh import DB_AXIS, QUERY_AXIS
+    from knn_tpu.parallel.sharded import _range_program
+
+    n, d, k = SHAPES[shape]
+    qs, ds = mesh_shape
+    mesh = Mesh(np.asarray(devices[:qs * ds]).reshape(qs, ds),
+                (QUERY_AXIS, DB_AXIS))
+    rows = -(-n // ds) * ds
+
+    def aval(shp, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shp, dtype, sharding=NamedSharding(mesh, spec))
+
+    return (f"{shape} range completion {RANGE_SUB_BATCH} x {rows} at width "
+            f"{range_width(k)}",
+            _range_program(mesh, n, TRAIN_TILE, range_width(k)),
+            (aval((RANGE_SUB_BATCH, d), jnp.float32, P(QUERY_AXIS)),
+             aval((rows, d), jnp.float32, P(DB_AXIS)),
+             aval((RANGE_SUB_BATCH,), jnp.float32, P(QUERY_AXIS))))
+
+
 def _compile(fn, avals) -> str:
     """Compile, and say what the program keeps on a chip."""
     mem = fn.lower(*avals).compile().memory_analysis()
@@ -259,6 +302,10 @@ def default_cases():
                {}, "compiles", mesh, "hh")
               for shape, mesh in (("bigann5m", (1, 1)),
                                   ("bigann20m", (1, 4)))]
+    # the range cell's first pass: 256 byte-valued columns in ONE dim
+    # chunk, one product
+    cases.append(("ssnpp2m5 program mesh=1x1 terms=hh", "ssnpp2m5", {},
+                  "compiles", (1, 1), "hh"))
     # the inner-product cell's program: 201 placed columns, k=10
     cases.append(("text2image2m5 program mesh=1x1", "text2image2m5", {},
                   "compiles", (1, 1), None))
@@ -353,7 +400,7 @@ def main(argv=None) -> int:
     # lane-rows a merge bin) and text2image2m5 on its one (62: the shape
     # whose blocks overran Mosaic's scoped VMEM on the chip, PR 31)
     merges = ([(args.shape, mesh[1] if mesh else 1)] if args.shape
-              else [("bigann20m", 4), ("text2image2m5", 1)])
+              else [("bigann20m", 4), ("text2image2m5", 1), ("ssnpp2m5", 1)])
     for shape, db_shards in merges:
         case = _merge_case(shape, db_shards, devices)
         if case is None:
@@ -365,6 +412,18 @@ def main(argv=None) -> int:
             print(f"OK   {name}: compiles  ({time.time() - t0:.0f}s)",
                   flush=True)
         except Exception as e:  # noqa: BLE001 — Mosaic refusal, reported
+            ok.append(False)
+            print(f"FAIL {name}: {str(e)[-400:]}", flush=True)
+    # the range completion's program
+    for shape in ([args.shape] if args.shape else RANGE):
+        if shape not in RANGE:
+            continue
+        name, fn, avals = _range_case(shape, devices, mesh or (1, 1))
+        t0 = time.time()
+        try:
+            print(f"OK   {name}: compiles: {_compile(fn, avals)}  "
+                  f"({time.time() - t0:.0f}s)", flush=True)
+        except Exception as e:  # noqa: BLE001 — XLA refusal, reported
             ok.append(False)
             print(f"FAIL {name}: {str(e)[-400:]}", flush=True)
     return 0 if all(ok) else 1
