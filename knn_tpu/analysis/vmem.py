@@ -295,6 +295,57 @@ def dim_chunking(
     return DIM_CHUNK, dim_padded // DIM_CHUNK
 
 
+#: the final select's Pallas stage (ops.pallas_knn._select_final): the
+#: query rows of a block it prefers — eight vregs an array: its pop
+#: rounds each wait on a cross-lane min, so they run as fast as the
+#: block has independent vregs to keep in flight (timed on the v5e at
+#: 8,704 columns, m+2 = 130, its lane-row loops still unrolled: 4.73 ms
+#: a 4,096-query batch in blocks of 32, 3.49 at 64, 3.25 at 128, which
+#: read 13.4 on heavily tied scores; root PERF.md, PR 35) — and the least it runs at (one vreg's sublanes)
+FINAL_SELECT_BLOCK_Q = 64
+FINAL_SELECT_MIN_BLOCK_Q = 8
+
+
+def final_select_bytes(block_q: int, width: int, keep: int) -> Dict[str, int]:
+    """Per-buffer VMEM bytes of one grid step of the final select's
+    Pallas stage over ``width`` candidate columns, ``keep`` = m+2: the
+    score and index blocks ``[block_q, width]`` the pipeline double
+    buffers, the int32 key scratch of one block, the index and bound
+    output blocks, and what Mosaic keeps beside them (the vregs it
+    spills around the loops over the lane-rows: 20 KiB a query row
+    bounds the least limit it compiled at, deviceless for a v5e, at 256
+    to 15,872 columns in blocks of 32, 64 and 128 — 12 MiB against
+    10.81 declared at 8,704 columns in blocks of 64, 42 against 39.12
+    at 15,872 in blocks of 128; ``scripts/aot_compile_check.py`` prints
+    the reading beside this model at every cell's shape)."""
+    block = int(block_q) * int(width) * 4
+    out_w = _ceil_div(keep - 1, BIN_W) * BIN_W + BIN_W
+    return {
+        "inputs_x2": 4 * block,
+        "key_scratch": block,
+        "outputs_x2": 2 * int(block_q) * out_w * 4,
+        "live": int(block_q) * 20 * 1024,
+    }
+
+
+def final_select_block_q(width: int, keep: int,
+                         budget_bytes: Optional[int] = None) -> Optional[int]:
+    """The query rows of a block of the final select's Pallas stage at
+    this shape: ``FINAL_SELECT_BLOCK_Q``, halved while the modelled need
+    plus :func:`limit_bytes`' eighth overruns ``budget_bytes`` (None =
+    the target device's); None where not even
+    ``FINAL_SELECT_MIN_BLOCK_Q`` rows fit (the stage is not run)."""
+    if budget_bytes is None:
+        budget_bytes = budget_for(TARGET_DEVICE_KIND)
+    block_q = FINAL_SELECT_BLOCK_Q
+    while block_q >= FINAL_SELECT_MIN_BLOCK_Q:
+        need = sum(final_select_bytes(block_q, width, keep).values())
+        if need + need // 8 <= budget_bytes:
+            return block_q
+        block_q //= 2
+    return None
+
+
 def launch_estimate(
     *, n: int, d: int, k: int, margin: int = 28,
     precision: Optional[str] = None, kernel: Optional[str] = None,

@@ -132,6 +132,7 @@ MERGE_STRAGGLER_GAP = "knn_tpu_merge_straggler_gap_seconds"
 SELECT_MERGE_CALLS = "knn_tpu_select_merge_calls_total"
 KERNEL_TERMS = "knn_tpu_kernel_terms_total"
 KERNEL_DIM_CHUNKS = "knn_tpu_kernel_dim_chunks_total"
+FINAL_SELECT_CALLS = "knn_tpu_final_select_calls_total"
 
 # --- host-RAM shard tier (knn_tpu.parallel.sharded) --------------------
 HOSTTIER_SWEEPS = "knn_tpu_hosttier_sweeps_total"
@@ -441,6 +442,14 @@ CATALOG = {
         "(ops.pallas_knn.dim_chunking): '1' wherever the whole padded "
         "width fits VMEM (one grid step a tile, no accumulator "
         "scratch), else the padded width over 128."),
+    FINAL_SELECT_CALLS: (
+        "counter", ("stage",),
+        "Batches of search_certified(selector='pallas'), by what ran "
+        "each shard's final top-(m+2): 'pallas' the one Pallas call "
+        "that carries the indices with the scores (ops.pallas_knn."
+        "final_select_geometry: an exact final select at a shape it "
+        "was timed at), 'xla' lax.top_k and the gather after it (every "
+        "other shape, and final_select='approx')."),
     MERGE_STRAGGLER_GAP: (
         "gauge", (),
         "Max-minus-min per-host local search wall time of the last "

@@ -73,6 +73,19 @@ fallback, never a different answer.  Narrower candidate arrays (1M rows
 and every small shape) run the select over the kernel's candidates as
 they are.
 
+Case (c)'s top-(m+2) is itself a Pallas call wherever the shape is one
+it was timed at (``final_select_geometry``, ``_select_final``: whole
+lane-rows, m+2 at most 256, (m+2) x width at most 2^21 — every cell of
+the benchmark).  XLA's ``TopK`` costs 28 ms + 1.42 us a column inside
+the certified program and the gather of the selected indices 7 more;
+the stage finds the (m+2)-th smallest score by 32 halvings on the
+scores' monotone int32 keys (compare-and-count passes over the block's
+lane-rows), cuts ties at it by column, and compacts the m+1 selected
+row indices lane-wise and then by m+1 cross-lane pops: 4 ms at 8,704
+columns.  It returns the set and the value ``lax.top_k`` would, bit for
+bit, so nothing after it can tell; other shapes and
+``final_select="approx"`` run XLA's ops as they did.
+
 The kernel computes in float32 (precision configurable) because the
 certificate's tolerance must be float32-tight; a bf16 coarse pass would
 blur v_excl by ~1000x the k-th/(k+1)-th distance gap and never certify.
@@ -160,6 +173,25 @@ SELECT_MERGE_SURVIVORS = 4
 #: merge bins per slot of the top-(m+2) that follows: groups x 128 lanes
 #: >= 16 x (m + 2), so the merged width follows m and not the corpus
 SELECT_MERGE_BINS_PER_SLOT = 16
+#: the final select as a Pallas stage (``final_select_geometry``,
+#: ``_select_final``): the sorted slots a lane keeps in one compaction
+#: pass.  A lane holds more than eight of a query's m+1 selected in
+#: about 1% of the blocks at 8,704 columns (Poisson(1) a lane); those
+#: run a second pass
+FINAL_SELECT_SLOTS = 8
+#: ... the lane-rows a step of its loops over them takes (the loops are
+#: in the program, not unrolled in its trace)
+FINAL_SELECT_UNROLL = 4
+#: ... and the shapes it runs at: (m+2) x width at most this, m+2 at
+#: most two vregs of output lanes.  Timed on the v5e against the
+#: ``lax.top_k`` + gather it replaces at the three widths the
+#: benchmark's cells have (root PERF.md, PR 35): 8,704 x 130, 15,872 x
+#: 130 (the largest product timed, which sets the bound) and 2,560 x
+#: 40 all win several times over; its pop rounds are serial in m, XLA's
+#: cost is not, and nothing wider or deeper was timed (the k = 2,048
+#: selection keeps XLA's path)
+FINAL_SELECT_MAX_WORK = 1 << 21
+FINAL_SELECT_MAX_KEEP = 2 * BIN_W
 #: the padding grain of the feature axis (columns are zero-padded to a
 #: multiple of it) and the FALLBACK width of a dim chunk: a row tile is
 #: one chunk of its whole padded width where that fits VMEM and is cut
@@ -177,6 +209,7 @@ MAX_SURVIVORS = 8
 PAD_VAL = 1.5e17
 
 _I32MAX = jnp.iinfo(jnp.int32).max
+_I32MIN = jnp.iinfo(jnp.int32).min
 
 #: kernel matmul modes.  "bf16x3" is the default: q and t split into
 #: bf16 high/low parts, three MXU passes reconstruct the f32 product to
@@ -280,7 +313,10 @@ def _split_qt(q, th, tl, terms: str):
 #: (PR 30, ``BF16X3_TERMS``): the tuner's timings depend on its rows.
 #: 9 = a row tile whose padded width fits VMEM is one dim chunk (PR 32,
 #: ``dim_chunking``): the kernel at 129...512 columns is another program.
-KERNEL_VERSION = 9
+#: 10 = the final top-(m+2) and its index gather are one Pallas stage
+#: where ``final_select_geometry`` engages (PR 35): the tail the tuner
+#: times with the kernel changed.
+KERNEL_VERSION = 10
 
 #: relative slack of the device rank stage's direct-difference f32
 #: distances: per-term (q-t)^2 rounding plus the depth-7 tree reduce give
@@ -449,6 +485,27 @@ def select_merge_geometry(
     if width % BIN_W or width < 2 * merged:
         return None
     return groups, -(-(width // BIN_W) // groups), merged
+
+
+def final_select_geometry(width: int, m: int) -> Optional[int]:
+    """The query rows of a block of the Pallas stage that is the exact
+    final top-(m+2) (:func:`_select_final`), or ``None`` where
+    :func:`local_select_rescore` keeps XLA's ``lax.top_k`` and gather.
+    ``width`` is what the select scans: the bin-merge's width where
+    ``select_merge_geometry`` engages, the kernel's where it does not.
+    By shape, from what the call can see and by no knob: whole
+    lane-rows, m+2 and (m+2) x width inside what was timed
+    (``FINAL_SELECT_MAX_KEEP``, ``FINAL_SELECT_MAX_WORK``), and a block
+    of at least eight queries inside the device's VMEM by
+    knn_tpu.analysis.vmem's model, which also sizes the block."""
+    from knn_tpu.analysis import vmem
+
+    keep = m + 2
+    if (width % BIN_W or keep > min(width, FINAL_SELECT_MAX_KEEP)
+            or keep * width > FINAL_SELECT_MAX_WORK):
+        return None
+    return vmem.final_select_block_q(
+        width, keep, vmem.budget_for(_vmem_device_kind()))
 
 
 def _pq_onehot_qt(lut, codes_u8, *, tile_n: int, pq_shape):
@@ -1410,7 +1467,7 @@ def local_certified_candidates(
     )
     return local_select_rescore(
         q, t, cd, ci, bounds, m, final_select=final_select,
-        final_recall_target=final_recall_target,
+        final_recall_target=final_recall_target, interpret=interpret,
     )
 
 
@@ -1537,8 +1594,223 @@ def _select_merge(cd: jax.Array, ci: jax.Array, groups: int, rows: int,
     )(cd, ci)
 
 
+def _select_final_kernel(cd_ref, ci_ref, idx_ref, excl_ref, key_ref, *,
+                         keep: int, rows: int):
+    """One query block of :func:`_select_final`.  Every array is
+    ``[block_q, 128]``: a query a sublane, one lane-row of its
+    candidates at a time.
+
+    1. each score becomes its monotone int32 key (the float's bits, the
+       low 31 flipped where the sign is set: the total order of
+       ``lax.top_k``, -0 before +0) in ``key_ref``;
+    2. 32 halvings of the int32 range find ``thr``, the least key with
+       at least ``keep`` keys at or under it: the ``keep``-th smallest
+       score, which is the exclusion value;
+    3. ``keep - 1`` candidates are selected: every key under ``thr`` and
+       the first ``need`` columns AT it, ``cut`` their last column, by
+       halvings of the column range where any query of the block has
+       ties to cut (-1 where none is taken);
+    4. the selected indices are compacted: down the lane-rows a lane
+       keeps its ``FINAL_SELECT_SLOTS`` smallest in a sorted stack (a
+       min/max insertion network, no cross-lane op), then ``keep - 1``
+       rounds pop the block's least index a query (one cross-lane min)
+       into the next output column.  A lane that held more selected
+       than slots runs the pass again above the last index it kept.
+
+    An index is written once because a query's finite candidates are
+    distinct rows below the sentinel, which +inf padding carries: a
+    selected pad leaves the output's sentinel fill as it is."""
+    shape = (cd_ref.shape[0], BIN_W)
+    out_v = idx_ref.shape[1] // BIN_W
+    i32 = np.int32
+
+    # lax primitives throughout, not their jnp twins: every jnp call
+    # re-enters jit's Python machinery to emit the same op, which each
+    # process's first call pays for (``_emit_select_grouped_scores``)
+    def full(v):
+        return lax.full(shape, v, jnp.int32)
+
+    def lanes(x):  # a [block_q] reduction over the lanes, on every lane
+        return lax.broadcast_in_dim(x, shape, (0,))
+
+    zero, one, top = full(0), full(1), full(_I32MAX)
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+
+    def over_rows(body, init):
+        """``body(columns of lane-row r, r's first column on every lane,
+        carry)`` down the lane-rows, ``FINAL_SELECT_UNROLL`` of them a
+        step of a loop that is in the program and not in its trace:
+        the trace and the lowering are paid in every process's first
+        call (root PERF.md, PRs 29 and 35)."""
+        unroll = min(FINAL_SELECT_UNROLL, rows)
+
+        def at(start, c):
+            return body(pl.ds(pl.multiple_of(start, BIN_W), BIN_W),
+                        lax.broadcast_in_dim(start, shape, ()), c)
+
+        def step(i, c):
+            for j in range(unroll):
+                c = at(lax.add(lax.mul(i, i32(unroll * BIN_W)),
+                               i32(j * BIN_W)), c)
+            return c
+
+        c = lax.fori_loop(0, rows // unroll, step, init)
+        for r in range(rows - rows % unroll, rows):
+            c = at(i32(r * BIN_W), c)
+        return c
+
+    def to_keys(cols, _, c):
+        bits = lax.bitcast_convert_type(cd_ref[:, cols], jnp.int32)
+        key_ref[:, cols] = lax.select(
+            lax.lt(bits, zero), lax.bitwise_xor(bits, top), bits)
+        return c
+
+    over_rows(to_keys, 0)
+
+    def count(pred):
+        """Of each query's keys, how many ``pred(keys, their columns)``
+        holds for, on every lane."""
+        return lanes(lax.reduce_sum(over_rows(
+            lambda cols, first, acc: lax.add(acc, lax.select(
+                pred(key_ref[:, cols], lax.add(lane, first)), one, zero)),
+            zero), (1,)))
+
+    def least(enough, lo, hi, steps):
+        """The least value in [lo, hi] that ``enough`` accepts (it
+        accepts ``hi`` and is monotone), a query a sublane."""
+        def step(_, c):
+            lo, hi = c
+            # the floor of the mean, with no overflow
+            mid = lax.add(lax.bitwise_and(lo, hi), lax.shift_right_arithmetic(
+                lax.bitwise_xor(lo, hi), one))
+            ok = enough(mid)
+            return (lax.select(ok, lo, lax.add(mid, one)),
+                    lax.select(ok, mid, hi))
+
+        return lax.fori_loop(0, steps, step, (full(lo), full(hi)))[1]
+
+    thr = least(lambda mid: lax.ge(
+        count(lambda k, _: lax.le(k, mid)), full(keep)), _I32MIN, _I32MAX, 32)
+    need = lax.sub(full(keep - 1), count(lambda k, _: lax.lt(k, thr)))
+
+    def tie_cut():
+        last = rows * BIN_W - 1
+        cut = least(
+            lambda mid: lax.ge(count(lambda k, col: lax.bitwise_and(
+                lax.eq(k, thr), lax.le(col, mid))), need),
+            0, last, last.bit_length())
+        return lax.select(lax.gt(need, zero), cut, full(-1))
+
+    cut = lax.cond(lax.gt(lax.reduce_max(need, (0, 1)), i32(0)),
+                   tie_cut, lambda: full(-1))
+
+    def compact(floor):
+        def push(cols, first, c):
+            stack, over = c
+            k = key_ref[:, cols]
+            idx = ci_ref[:, cols]
+            sel = lax.bitwise_or(lax.lt(k, thr), lax.bitwise_and(
+                lax.eq(k, thr), lax.le(lax.add(lane, first), cut)))
+            cur = lax.select(
+                lax.bitwise_and(sel, lax.gt(idx, floor)), idx, top)
+            kept = []
+            for held in stack:
+                kept.append(lax.min(held, cur))
+                cur = lax.max(held, cur)
+            return kept, lax.min(over, cur)
+
+        return over_rows(push, ([top] * FINAL_SELECT_SLOTS, top))
+
+    def pop(_, c):
+        stack, out, n = c
+        first = lanes(lax.reduce_min(stack[0], (1,)))
+        hit = lax.eq(stack[0], first)
+        stack = [lax.select(hit, below, held)
+                 for held, below in zip(stack, stack[1:] + [top])]
+        out = [lax.select(lax.eq(lax.add(lane, full(o * BIN_W)), n),
+                          first, held) for o, held in enumerate(out)]
+        return stack, out, lax.add(n, lax.select(lax.ne(first, top),
+                                                 one, zero))
+
+    def one_pass(c):
+        floor, out, n, _, passes = c
+        stack, over = compact(floor)
+        _, out, n = lax.fori_loop(
+            0, lax.sub(i32(keep - 1), lax.reduce_min(n, (0, 1))), pop,
+            (stack, out, n))
+        return (stack[-1], out, n, lax.convert_element_type(lax.ne(
+            lax.reduce_min(over, (0, 1)), i32(_I32MAX)), jnp.int32),
+            lax.add(passes, i32(1)))
+
+    # a pass takes at least FINAL_SELECT_SLOTS indices off every lane it
+    # leaves unfinished, so the pass count is bounded whatever a partial
+    # block's padding rows hold
+    _, out, _, _, _ = lax.while_loop(
+        lambda c: lax.bitwise_and(lax.ne(c[3], i32(0)), lax.le(
+            c[4], i32(rows // FINAL_SELECT_SLOTS + 1))),
+        one_pass, (full(-1), [top] * out_v, zero, i32(1), i32(0)))
+    for o, held in enumerate(out):
+        idx_ref[:, o * BIN_W:(o + 1) * BIN_W] = held
+    excl_ref[...] = lax.bitcast_convert_type(
+        lax.select(lax.lt(thr, zero), lax.bitwise_xor(thr, top), thr),
+        jnp.float32)
+
+
+def _final_select_vmem_limit(block_q: int, width: int, keep: int) -> int:
+    """The scoped-VMEM limit :func:`_select_final` requests: the model's
+    need of the block (knn_tpu.analysis.vmem.final_select_bytes) plus an
+    eighth, no less than Mosaic's own default and no more than the
+    device has."""
+    from knn_tpu.analysis import vmem
+
+    need = sum(vmem.final_select_bytes(block_q, width, keep).values())
+    return min(vmem.budget_for(_vmem_device_kind()),
+               max(16 * vmem.MIB, need + need // 8))
+
+
+def _select_final(cd: jax.Array, ci: jax.Array, m: int, block_q: int,
+                  *, interpret: bool):
+    """The exact final select as ONE Pallas call (``final_select_geometry``
+    gives ``block_q``): ``(lidx [Q, m+1], excl [Q])``, the row indices
+    ``ci`` of the m+1 smallest scores of ``cd`` (ascending within a
+    compaction pass, sentinels last) and the (m+2)-th smallest score — the SET and the
+    value of ``lax.top_k(-cd, m + 2)`` and the gather after it, bit for
+    bit: smallest score first, the earlier column on equal scores, -0
+    before +0, a +inf pad taken only where fewer than m+2 scores are
+    finite.  What follows orders by (distance, index) whatever the
+    order here (``topk_pairs``).  Outputs are lane-padded blocks, cut
+    to size outside; int32 throughout (no ``uint32`` array reaches HLO:
+    the range completion's trace pattern reads those)."""
+    n_q, w = cd.shape
+    keep = m + 2
+    block_q = min(block_q, _round_up(n_q, 8))
+    out_w = _round_up(keep - 1, BIN_W)
+    row = lambda i: (i, 0)  # noqa: E731
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_final_select_vmem_limit(block_q, w, keep))
+    idx, excl = pl.pallas_call(
+        functools.partial(_select_final_kernel, keep=keep,
+                          rows=w // BIN_W),
+        grid=(-(-n_q // block_q),),
+        in_specs=[pl.BlockSpec((block_q, w), row)] * 2,
+        out_specs=[pl.BlockSpec((block_q, out_w), row),
+                   pl.BlockSpec((block_q, BIN_W), row)],
+        out_shape=[jax.ShapeDtypeStruct((n_q, out_w), jnp.int32),
+                   jax.ShapeDtypeStruct((n_q, BIN_W), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, w), jnp.int32)],
+        interpret=interpret,
+        name="select_final",
+        **kwargs,
+    )(cd, ci)
+    return idx[:, :m + 1], excl[:, 0]
+
+
 @functools.partial(
-    jax.jit, static_argnames=("m", "final_select", "final_recall_target"),
+    jax.jit, static_argnames=("m", "final_select", "final_recall_target",
+                              "interpret"),
 )
 def local_select_rescore(
     q: jax.Array,
@@ -1550,12 +1822,17 @@ def local_select_rescore(
     *,
     final_select: str = "exact",
     final_recall_target: Optional[float] = None,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Stage 2 of :func:`local_certified_candidates`: final top-(m+2)
     select over the packed candidates (over their bin-merge's survivors
     where ``select_merge_geometry`` engages, every merge bin's bound
-    joining ``lb``), exclusion-value restoration, the
-    direct-difference f32 rescore gather, and lexicographic ordering."""
+    joining ``lb``; as one Pallas call where ``final_select_geometry``
+    engages), exclusion-value restoration, the direct-difference f32
+    rescore gather, and lexicographic ordering.  ``interpret`` is the
+    kernel's (None: by the backend) and goes to both Pallas stages."""
+    if interpret is None:
+        interpret = not default_backend_is_tpu()
     n_q = q.shape[0]
     w = cd.shape[1]
     if m + 2 > w:
@@ -1575,8 +1852,7 @@ def local_select_rescore(
             # join lb (module docstring, case (b))
             with jax.named_scope(SCOPE_SELECT_MERGE):
                 cd, ci, merge_bounds = _select_merge(
-                    cd, ci, *merge[:2],
-                    interpret=not default_backend_is_tpu())
+                    cd, ci, *merge[:2], interpret=interpret)
                 # kernel bins' and merge bins' bounds: one column now
                 bounds = jnp.minimum(
                     jnp.min(bounds, axis=-1),
@@ -1598,11 +1874,22 @@ def local_select_rescore(
             lb = jnp.minimum(jnp.min(bounds, axis=-1), excl)
         else:
             # exact top-(m+2) by kernel score: the last value is the
-            # exclusion value over every de-selected survivor
-            neg, sel = lax.top_k(-cd, m + 2)
-            vals = -neg
-            lidx = jnp.take_along_axis(ci, sel, axis=-1)[:, : m + 1]
-            lb = jnp.minimum(jnp.min(bounds, axis=-1), vals[:, m + 1])
+            # exclusion value over every de-selected survivor.  One
+            # Pallas call that carries the indices with the scores
+            # where the shape is one it was timed at, XLA's top_k and
+            # the gather after it elsewhere: the same set and value
+            block_q = final_select_geometry(cd.shape[1], m)
+            if block_q is not None:
+                lidx, excl = _select_final(
+                    cd, ci, m, block_q, interpret=interpret)
+                lb = jnp.minimum(jnp.min(bounds, axis=-1), excl)
+            else:
+                # op for op what stood here before the stage: a shape
+                # the rule refuses lowers to the text it always did
+                neg, sel = lax.top_k(-cd, m + 2)
+                vals = -neg
+                lidx = jnp.take_along_axis(ci, sel, axis=-1)[:, : m + 1]
+                lb = jnp.minimum(jnp.min(bounds, axis=-1), vals[:, m + 1])
 
     with jax.named_scope(SCOPE_RESCORE):
         # kernel-padding rows carry real-looking indices in [rows,

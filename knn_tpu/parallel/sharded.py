@@ -843,6 +843,9 @@ class ShardedKNN:
         # (columns of one dim chunk, chunks a row tile is cut into) that
         # _pallas_setup handed its last program's kernel
         self._dim_chunking: Tuple[int, int] = (0, 0)
+        # what runs the last program's final top-(m+2) on each shard:
+        # "pallas" or "xla" (_pallas_setup)
+        self._final_select_stage = "xla"
         #: lazily built serving engines, keyed by ladder spec
         #: (buckets, min_bucket, max_bucket) — search_bucketed; the lock
         #: keeps concurrent cold calls from double-building an engine
@@ -1767,6 +1770,13 @@ class ShardedKNN:
                 obs.counter(_mn.KERNEL_DIM_CHUNKS,
                             chunks=str(merged["dim_chunks"])).inc(
                     len(batches))
+                # what ran the top-(m+2) over that width: the Pallas
+                # stage or XLA's top_k and gather
+                # (ops.pallas_knn.final_select_geometry)
+                merged["final_select_stage"] = self._final_select_stage
+                obs.counter(_mn.FINAL_SELECT_CALLS,
+                            stage=self._final_select_stage).inc(
+                    len(batches))
             for key, value in merged.items():
                 call.set(key, value)
             stats = {
@@ -1783,7 +1793,8 @@ class ShardedKNN:
                     **knobs, "interpret": interpret, "terms": terms,
                     "mxu_passes": merged["mxu_passes"],
                     "dim_chunk": merged["dim_chunk"],
-                    "dim_chunks": merged["dim_chunks"]}
+                    "dim_chunks": merged["dim_chunks"],
+                    "final_select_stage": merged["final_select_stage"]}
                 stats["tuning"] = tune_info
             # mirror the quality signals into the telemetry registry —
             # the per-call stats dict stays the API, the registry
@@ -2231,6 +2242,7 @@ class ShardedKNN:
             dim_chunking,
             effective_block_q,
             effective_tile,
+            final_select_geometry,
             select_merge_geometry,
         )
 
@@ -2272,6 +2284,9 @@ class ShardedKNN:
         merge = select_merge_geometry(select_width, m)
         self._select_widths = (
             select_width, select_width if merge is None else merge[2])
+        self._final_select_stage = (
+            "pallas" if final_select == "exact" and final_select_geometry(
+                self._select_widths[1], m) is not None else "xla")
         bq = block_q or BLOCK_Q
         if batch_rows is not None:
             bq = effective_block_q(

@@ -27,8 +27,16 @@ cell's first pass (2.5M x 256, one product, ``ssnpp2m5``); compiles the
 final select's bin-merge kernel at a 5M-row chip's candidate width
 (``bigann20m``), at ``text2image2m5``'s (39,168 columns at m+2 = 40:
 62 lane-rows a merge bin) and at ``ssnpp2m5``'s (39,168 at m+2 = 130);
-and compiles the range completion's program at ``ssnpp2m5`` (the pass
-over the rows and the compaction at ``ops.radius.range_width``).
+compiles the range completion's program at ``ssnpp2m5`` (the pass
+over the rows and the compaction at ``ops.radius.range_width``); and
+compiles the final select's Pallas stage (``select_final``, PR 35)
+alone at every cell's (width, m) — 8,704 x 130, 15,872 x 130, 2,560 x
+40 — reading the least scoped-VMEM limit Mosaic takes it at against
+``analysis.vmem.final_select_bytes``.  The whole programs above carry
+that stage and the bin-merge COMPILED (``interpret=False`` reaches
+both), ``gist`` among them, and the range cell's first pass is checked
+to hold still exactly one line with a ``uint32`` array of two
+dimensions or more (the range completion's trace pattern).
 Flags pick one geometry instead:
 
     python scripts/aot_compile_check.py --shape gist --block-q 128
@@ -160,6 +168,15 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
     return prog, (q, db, norm) + ((norm,) if shape in AUGMENTED else ())
 
 
+def _shard_width(shape: str, db_shards: int) -> int:
+    """The candidate columns a query the kernel hands the final select
+    on one of ``db_shards`` chips, at the default tile."""
+    from knn_tpu.ops import pallas_knn as pk
+
+    rows = -(-SHAPES[shape][0] // db_shards)
+    return -(-rows // pk.TILE_N) * 2 * pk.BIN_W
+
+
 def _merge_case(shape: str, db_shards: int, devices):
     """(fn, avals) of the final select's bin-merge kernel alone, compiled,
     at the candidate width of one of ``db_shards`` chips, or None where
@@ -173,8 +190,8 @@ def _merge_case(shape: str, db_shards: int, devices):
 
     from knn_tpu.ops import pallas_knn as pk
 
-    n, _, k = SHAPES[shape]
-    width = -(-(-(-n // db_shards)) // pk.TILE_N) * 2 * pk.BIN_W
+    k = SHAPES[shape][2]
+    width = _shard_width(shape, db_shards)
     geo = pk.select_merge_geometry(width, k + MARGIN)
     if geo is None:
         return None
@@ -183,6 +200,39 @@ def _merge_case(shape: str, db_shards: int, devices):
         cd, ci, *geo[:2], interpret=False))
     return fn, (jax.ShapeDtypeStruct((NQ, width), jnp.float32, sharding=sh),
                 jax.ShapeDtypeStruct((NQ, width), jnp.int32, sharding=sh))
+
+
+def _final_case(shape: str, db_shards: int, devices):
+    """(fn, avals, (block_q, width, keep)) of the final select's Pallas
+    stage alone at the width one of ``db_shards`` chips hands it (the
+    bin-merge's where that engages), or None where the shape rule keeps
+    XLA's top_k."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from knn_tpu.ops import pallas_knn as pk
+
+    m = SHAPES[shape][2] + MARGIN
+    width = _shard_width(shape, db_shards)
+    merge = pk.select_merge_geometry(width, m)
+    if merge is not None:
+        width = merge[2]
+    block_q = pk.final_select_geometry(width, m)
+    if block_q is None:
+        return None
+    sh = SingleDeviceSharding(devices[0])
+    fn = jax.jit(lambda cd, ci: pk._select_final(
+        cd, ci, m, block_q, interpret=False))
+    return (fn, (jax.ShapeDtypeStruct((NQ, width), jnp.float32, sharding=sh),
+                 jax.ShapeDtypeStruct((NQ, width), jnp.int32, sharding=sh)),
+            (block_q, width, m + 2))
+
+
+#: an HLO line that holds a uint32 array of two or more dimensions: what
+#: the benchmark's range-completion metrics read a device op by
+#: (benchmark/layers/range_complete_device_ms.json)
+U32_LINE = re.compile(r"\bu32\[[0-9]+,")
 
 
 def _range_case(shape: str, devices, mesh_shape):
@@ -216,15 +266,55 @@ def _range_case(shape: str, devices, mesh_shape):
              aval((RANGE_SUB_BATCH,), jnp.float32, P(QUERY_AXIS))))
 
 
-def _compile(fn, avals) -> str:
-    """Compile, and say what the program keeps on a chip."""
-    mem = fn.lower(*avals).compile().memory_analysis()
-    return (f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB + "
-            f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB a chip")
+def _executed_lines(hlo_text: str):
+    """The instruction lines of a compiled module that run as device ops
+    of their own, which is what a TPU trace names an op by: those of the
+    entry computation and of loop and branch bodies, not the insides of
+    a fusion or of a reducer (computations some ``calls=`` or
+    ``to_apply=`` names)."""
+    inner = set(re.findall(r"(?:calls|to_apply)=(%[\w.-]+)", hlo_text))
+    lines, keep = [], False
+    for ln in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?(%[\w.-]+) \(.*\{$", ln)
+        if head:
+            keep = head.group(1) not in inner
+        elif ln.startswith("}"):
+            keep = False
+        elif keep and " = " in ln:
+            lines.append(ln.strip())
+    return lines
 
 
-def _probe_need(make_case):
-    """The scoped-VMEM need Mosaic shows: climb the kernel's limit from
+def _compile(fn, avals, u32_lines=None) -> str:
+    """Compile, and say what the program keeps on a chip.  With
+    ``u32_lines`` the compiled text must hold that many lines matching
+    ``U32_LINE`` (ValueError otherwise)."""
+    compiled = fn.lower(*avals).compile()
+    mem = compiled.memory_analysis()
+    detail = (f"arguments {mem.argument_size_in_bytes / 1e9:.2f} GB + "
+              f"temporaries {mem.temp_size_in_bytes / 1e9:.2f} GB a chip")
+    text = compiled.as_text()
+    # the tail's Pallas calls, as the trace names them (compiled: an
+    # interpreted one leaves no such op)
+    stages = [name for name in ("select_merge", "select_final")
+              if re.search(rf"%{name}(\.\d+)? = ", text)]
+    if stages:
+        detail += "; compiled " + " + ".join(stages)
+    if u32_lines is not None:
+        got = [ln[:60] for ln in _executed_lines(text)
+               if U32_LINE.search(ln)]
+        if len(got) != u32_lines:
+            raise ValueError(
+                f"{len(got)} lines hold a uint32 array of two dimensions "
+                f"or more, expected {u32_lines}: {got[:4]}")
+        detail += f"; {len(got)} uint32 line"
+    return detail
+
+
+def _probe_need(make_case, limit_fn: str = "_vmem_limit_bytes"):
+    """The scoped-VMEM need Mosaic shows: climb the limit that
+    ``ops.pallas_knn``'s ``limit_fn`` hands a launch (the kernel's, or
+    ``_final_select_vmem_limit`` for the final select's stage) from
     8 MiB by the size each refusal names until it compiles, then bisect
     between the highest limit refused and the lowest that compiled.  A
     refusal's size alone misleads: under its need Mosaic may schedule
@@ -237,8 +327,8 @@ def _probe_need(make_case):
     import knn_tpu.ops.pallas_knn as pk
 
     limit = [8]
-    real = pk._vmem_limit_bytes
-    pk._vmem_limit_bytes = lambda *a, **kw: limit[0] << 20
+    real = getattr(pk, limit_fn)
+    setattr(pk, limit_fn, lambda *a, **kw: limit[0] << 20)
     named = None
 
     def compiles():
@@ -274,7 +364,7 @@ def _probe_need(make_case):
                 refused = limit[0]
         return ok, refused, named
     finally:
-        pk._vmem_limit_bytes = real
+        setattr(pk, limit_fn, real)
         jax.clear_caches()
 
 
@@ -309,6 +399,10 @@ def default_cases():
     # the inner-product cell's program: 201 placed columns, k=10
     cases.append(("text2image2m5 program mesh=1x1", "text2image2m5", {},
                   "compiles", (1, 1), None))
+    # gist1m's: the final select's Pallas stage over the kernel's own
+    # 15,872 columns (no bin-merge), the widest it runs at
+    cases.append(("gist program mesh=1x1", "gist", {}, "compiles", (1, 1),
+                  None))
     return cases
 
 
@@ -334,7 +428,11 @@ def run_case(name, shape, overrides, expect, mesh, terms, devices, *,
               f"({time.time() - t0:.0f}s)", flush=True)
         return ok_at is not None
     try:
-        got, detail = "compiles", ": " + _compile(*make_case())
+        # a range cell's first pass holds ONE line the range completion's
+        # trace pattern matches (the certificate's packed bits)
+        got, detail = "compiles", ": " + _compile(
+            *make_case(),
+            u32_lines=1 if mesh is not None and shape in RANGE else None)
     except ValueError as e:
         got, detail = "refused", f": {e}"
     except Exception as e:  # noqa: BLE001 — Mosaic/XLA refusal, reported
@@ -411,6 +509,36 @@ def main(argv=None) -> int:
             _compile(*case)
             print(f"OK   {name}: compiles  ({time.time() - t0:.0f}s)",
                   flush=True)
+        except Exception as e:  # noqa: BLE001 — Mosaic refusal, reported
+            ok.append(False)
+            print(f"FAIL {name}: {str(e)[-400:]}", flush=True)
+    # the final select's Pallas stage alone, at each cell's (width, m):
+    # it compiles at the limit the library asks for, and the least limit
+    # Mosaic takes it at is inside the model's need plus an eighth
+    from knn_tpu.analysis import vmem
+
+    finals = ([(args.shape, mesh[1] if mesh else 1)] if args.shape
+              else [("bigann20m", 4), ("gist", 1), ("text2image2m5", 1),
+                    ("ssnpp2m5", 1)])
+    for shape, db_shards in finals:
+        case = _final_case(shape, db_shards, devices)
+        if case is None:
+            continue
+        t0 = time.time()
+        block_q, width, keep = case[2]
+        name = (f"{shape} select-final stage {width} x {keep} in blocks of "
+                f"{block_q}")
+        try:
+            _compile(*case[:2])
+            model = sum(vmem.final_select_bytes(
+                block_q, width, keep).values())
+            need, _, _ = _probe_need(lambda: case[:2],
+                                     "_final_select_vmem_limit")
+            fits = need * vmem.MIB <= model + model // 8
+            ok.append(fits)
+            print(f"{'OK  ' if fits else 'FAIL'} {name}: compiles; least "
+                  f"limit {need} MiB, model {model / vmem.MIB:.2f} MiB + an "
+                  f"eighth  ({time.time() - t0:.0f}s)", flush=True)
         except Exception as e:  # noqa: BLE001 — Mosaic refusal, reported
             ok.append(False)
             print(f"FAIL {name}: {str(e)[-400:]}", flush=True)

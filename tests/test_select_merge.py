@@ -187,8 +187,10 @@ def test_approx_arm_runs_over_the_merged_candidates():
 
 # --- engagement, from shapes alone -------------------------------------------
 def traced_stage(width: int, m: int, n_q: int = 4096):
-    """(number of Pallas calls, top-k operand width) of the traced
-    stage; nothing is computed."""
+    """(number of bin-merge Pallas calls, width the top-(m+2) scans) of
+    the traced stage; nothing is computed.  The top-(m+2) is XLA's
+    ``top_k`` or, where ``final_select_geometry`` engages, the Pallas
+    call ``select_final`` (tests/test_final_select.py)."""
     sds = jax.ShapeDtypeStruct
     jaxpr = jax.make_jaxpr(
         functools.partial(pk.local_select_rescore, m=m))(
@@ -200,7 +202,11 @@ def traced_stage(width: int, m: int, n_q: int = 4096):
     def walk(jp):
         for eqn in jp.eqns:
             if eqn.primitive.name == "pallas_call":
-                found["pallas_call"] += 1
+                if eqn.params["name"] == "select_final":
+                    found["top_k"].append(eqn.invars[0].aval.shape[-1])
+                else:
+                    assert eqn.params["name"] == "select_merge"
+                    found["pallas_call"] += 1
                 continue
             if eqn.primitive.name == "top_k":
                 found["top_k"].append(eqn.invars[0].aval.shape[-1])

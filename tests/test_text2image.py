@@ -19,6 +19,7 @@ interpreted, at sizes a test can hold:
 
 import json
 import os
+import re
 import sys
 import time
 
@@ -266,6 +267,49 @@ def test_the_bin_merge_compiles_at_the_cells_widths(one_chip, rows_n, m,
         jax.ShapeDtypeStruct((4096, width), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((4096, width), jnp.int32, sharding=one_chip),
     ).compile()
+
+
+@pytest.mark.parametrize("width,m", [
+    (8_704, 128),     # both BIGANN cells and ssnpp2m5, after the bin-merge
+    (15_872, 128),    # gist1m: the kernel's own candidates
+    (2_560, 38)])     # text2image2m5, after the bin-merge
+def test_the_final_select_stage_compiles_at_the_cells_widths(
+        one_chip, monkeypatch, width, m):
+    """The final top-(m+2) as one Pallas call (PR 35), compiled for a
+    described v5e in the blocks ``final_select_geometry`` gives: Mosaic
+    takes it at the scoped-VMEM limit the model asks for
+    (``analysis.vmem.final_select_bytes`` plus an eighth) and refuses it
+    under the buffers the model says the call declares, so the model
+    neither lets through what cannot fit nor counts what is not there.
+    (This file holds the described chip: the stage's own tests are
+    tests/test_final_select.py.)"""
+    import jax.numpy as jnp
+
+    from knn_tpu.analysis import vmem
+    from knn_tpu.ops import pallas_knn as pk
+
+    block_q = pk.final_select_geometry(width, m)
+    assert block_q == vmem.FINAL_SELECT_BLOCK_Q
+    avals = (
+        jax.ShapeDtypeStruct((4096, width), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((4096, width), jnp.int32, sharding=one_chip))
+
+    def compile_stage():
+        return jax.jit(lambda cd, ci: pk._select_final(
+            cd, ci, m, block_q, interpret=False)).lower(*avals).compile()
+
+    text = compile_stage().as_text()
+    assert "%select_final" in text
+    # nothing of it can be read as the range completion's work
+    assert not [ln for ln in text.splitlines()
+                if re.search(r"\bu32\[[0-9]+,", ln)]
+    declared = vmem.final_select_bytes(block_q, width, m + 2)
+    declared = declared["inputs_x2"] + declared["key_scratch"]
+    monkeypatch.setattr(pk, "_final_select_vmem_limit",
+                        lambda *a: declared // 2)
+    jax.clear_caches()
+    with pytest.raises(Exception, match="(?i)vmem"):
+        compile_stage()
 
 
 # --- the plain reference ------------------------------------------------------

@@ -247,7 +247,7 @@ def test_stale_kernel_version_entry_falls_back_to_defaults(cache_path):
     # it even though "precision": "pq" is a perfectly current knob
     from knn_tpu.ops.pallas_knn import KERNEL_VERSION
 
-    assert KERNEL_VERSION == 9
+    assert KERNEL_VERSION == 10
     cache.put(base + "|kv4", {"knobs": {**tuning.DEFAULT_KNOBS,
                                         "precision": "pq",
                                         "kernel": "streaming"}})
@@ -259,6 +259,10 @@ def test_stale_kernel_version_entry_falls_back_to_defaults(cache_path):
     # all three terms on every corpus (7 -> 8)
     cache.put(base + "|kv7", {"knobs": {**tuning.DEFAULT_KNOBS,
                                         "tile_n": 512}})
+    # ... and a version-9 winner: timed when the final top-(m+2) was
+    # XLA's top_k and gather at every shape (9 -> 10)
+    cache.put(base + "|kv9", {"knobs": {**tuning.DEFAULT_KNOBS,
+                                        "block_q": 128}})
     knobs, info = tuning.resolve_full(700, 16, 5, cache_path=cache_path)
     assert info["source"] == "default"
     assert knobs == tuning.DEFAULT_KNOBS
@@ -299,10 +303,11 @@ def test_version_6_winner_naming_a_removed_knob_is_never_used(
     assert stats["tuning"]["source"] == "default"
     # beside the knobs: what the program resolved for itself, from the
     # backend (interpret), from the data (terms, mxu_passes) and from
-    # the launch's shape (dim_chunk, dim_chunks)
+    # the launch's shape (dim_chunk, dim_chunks, final_select_stage)
     assert {kk: v for kk, v in stats["pallas_knobs"].items()
             if kk not in ("interpret", "terms", "mxu_passes", "dim_chunk",
-                          "dim_chunks")} == tuning.DEFAULT_KNOBS
+                          "dim_chunks", "final_select_stage")
+            } == tuning.DEFAULT_KNOBS
     assert (stats["pallas_knobs"]["dim_chunk"],
             stats["pallas_knobs"]["dim_chunks"]) == (128, 1)
 
