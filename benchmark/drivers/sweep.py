@@ -3,7 +3,9 @@ query batches, cycled from a pool drawn from the seed, go through
 ``ShardedKNN.search_certified(selector=...)`` with no knob passed; each
 batch's answer is back on the host as numpy arrays before the next is
 sent.  ``sweep_qps`` is all the queries answered over all the time from
-the window's start to the last answer.
+the window's start to the last answer.  The harness gets the registry's
+change over the window (``system.registry_delta``), so ``span`` and
+``counter`` readers find the program's own series.
 
 Traffic file: ``batch_rows``, ``pool_batches``, ``selector``,
 ``check_rows`` (queries compared with the oracle), ``trace_seconds``.
@@ -96,6 +98,8 @@ def run(ctx: Ctx) -> Outcome:
     if ctx.traced:
         jax.profiler.start_trace(ctx.trace_dir)
     setup_s = system.now() - ctx.t_found
+    # after the stamp and before the window reads its clock: in neither
+    before = system.registry_snapshot()
     try:
         with jax.profiler.TraceAnnotation("bench.trace_window"):
             batches, elapsed, totals, last, changed = _window(
@@ -103,6 +107,7 @@ def run(ctx: Ctx) -> Outcome:
     finally:
         if ctx.traced:
             jax.profiler.stop_trace()
+    registry = system.registry_delta(before, system.registry_snapshot())
     compiled = system.COMPILES["backend_compiles"] - compiles_before
     resident = resident_bytes(ctx.cell.chips)
     say(f"window: {batches} batches, {totals['queries']} queries in "
@@ -140,4 +145,4 @@ def run(ctx: Ctx) -> Outcome:
         checks=checks,
         bench={"batches": float(batches), **{
             key: float(v) for key, v in totals.items()}},
-        registry={}, resident_bytes=resident)
+        registry=registry, resident_bytes=resident)

@@ -70,10 +70,11 @@ def run(ctx: Ctx) -> Outcome:
     seconds = min(ctx.seconds, float(tr["trace_seconds"])) if ctx.traced \
         else ctx.seconds
     compiles_before = system.COMPILES["backend_compiles"]
-    before = system.registry_snapshot()
     if ctx.traced:
         jax.profiler.start_trace(ctx.trace_dir)
     setup_s = system.now() - ctx.t_found
+    # after the stamp and before the window reads its clock: in neither
+    before = system.registry_snapshot()
     try:
         with jax.profiler.TraceAnnotation("bench.trace_window"):
             batches, elapsed, totals, last, changed = sweep._window(

@@ -329,9 +329,15 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             correct=outcome.checks.correct, attempted=outcome.attempted,
             failed=outcome.failed, values=values,
             units={k: declared[k] for k in values}, device=device,
-            breakdown=breakdown)
+            breakdown=breakdown, compared=outcome.checks.rows)
         parsed = lastline.validate(line, cell.bench, workload, traced)
     except lastline.LastLineError as e:
         raise BenchError(f"no valid last line: {e}")
+    # each number compared beside its limit: the last lines on standard
+    # error, as they are the last key of the line
+    for r in outcome.checks.rows:
+        print(f"compared {r['check']} = {r['value']!r} {r['rule']} "
+              f"{r['limit']!r}{'' if r['ok'] else '  OUTSIDE'}",
+              file=sys.stderr, flush=True)
     emit(line)
     return parsed

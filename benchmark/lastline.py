@@ -3,7 +3,8 @@ printed by ``run.py`` only after :func:`validate` passed it.
 
 The rules are the benchmark contract's: one JSON object with the keys
 ``correct``, ``attempted``, ``failed``, ``metrics`` and ``device`` (and
-``breakdown`` only in a traced run); ``metrics`` gives every metric
+``breakdown`` only in a traced run, and last of all ``compared``: each
+number ``correct`` was decided by, beside its limit); ``metrics`` gives every metric
 ``BENCHMARK.json`` lists for this cell in this mode (``--trace 0``: its
 end-to-end metrics; ``--trace 1``: its per-layer metrics) as a finite
 ``value`` with the declared ``unit``; ``device`` gives ``platform``,
@@ -76,10 +77,13 @@ def _count(what: str, v) -> int:
 
 def build(*, correct: bool, attempted: int, failed: int,
           values: Dict[str, float], units: Dict[str, str], device: dict,
-          breakdown: Optional[dict] = None) -> str:
+          breakdown: Optional[dict] = None,
+          compared: Optional[List[dict]] = None) -> str:
     """The line as a string.  ``values`` maps metric name to the number
-    as measured; ``units`` to the declared unit.  Raises where a value
-    cannot be written as JSON (NaN, infinity, None)."""
+    as measured; ``units`` to the declared unit; ``compared`` is
+    ``reference.Checks.rows``.  Raises where a metric's value cannot be
+    written as JSON (NaN, infinity, None); a compared number that is not
+    finite is written as text, since it is what made the run incorrect."""
     line = {
         "correct": bool(correct),
         "attempted": int(attempted),
@@ -91,6 +95,12 @@ def build(*, correct: bool, attempted: int, failed: int,
     }
     if breakdown is not None:
         line["breakdown"] = breakdown
+    if compared is not None:
+        line["compared"] = {
+            r["check"]: {"value": (r["value"] if math.isfinite(r["value"])
+                                   else repr(r["value"])),
+                         "limit": r["limit"], "rule": r["rule"]}
+            for r in compared}
     try:
         return json.dumps(line, allow_nan=False)
     except (TypeError, ValueError) as e:
@@ -202,4 +212,18 @@ def validate(line: str, bench: dict, workload: str, traced: bool) -> dict:
                         f"breakdown.{key} row {row!r} is not "
                         f"[name, seconds]")
                 _number(f"breakdown.{key} {row[0]!r}", row[1])
+    if "compared" in obj:
+        if list(obj)[-1] != "compared":
+            raise LastLineError("compared is not the line's last key")
+        cmp = obj["compared"]
+        if not isinstance(cmp, dict) or not cmp:
+            raise LastLineError("compared is not an object with entries")
+        for name, row in cmp.items():
+            if not isinstance(row, dict) or set(row) != {
+                    "value", "limit", "rule"}:
+                raise LastLineError(
+                    f"compared.{name} is not {{value, limit, rule}}")
+            _number(f"compared.{name} limit", row["limit"])
+            if not isinstance(row["value"], str):
+                _number(f"compared.{name} value", row["value"])
     return obj

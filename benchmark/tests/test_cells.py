@@ -62,6 +62,13 @@ def test_cell_end_to_end(root, workload, traced):
     if traced:
         assert 0 < out["device"]["busy_s"] <= out["device"]["window_s"]
         assert out["breakdown"]["device_ops"]
+        # the idle time is laid against the program's own spans (the
+        # serving path opens none: PERF.md section 7)
+        gaps = dict(out["breakdown"]["idle_gaps"])
+        if harness.load_cell(root, workload).traffic["kind"] != "openloop":
+            assert any(name.startswith("knn.certified.") for name in gaps)
+        assert sum(gaps.values()) <= (out["device"]["window_s"]
+                                      - out["device"]["busy_s"]) * (1 + 1e-9)
     else:
         assert "breakdown" not in out and "window_s" not in out["device"]
 
@@ -82,6 +89,28 @@ def _break_sweep(monkeypatch):
     monkeypatch.setattr(ShardedKNN, "search_certified", broken)
 
 
+def _break_sweep_range(monkeypatch):
+    """An answer altered where it is produced: every list that holds a
+    row loses its last one.  (``_break_sweep`` would not do: it alters
+    the first pass's k-th index, which a range answer holds only where
+    the completion does not replace it.)"""
+    from knn_tpu.parallel import ShardedKNN
+
+    real = ShardedKNN.range_search_certified
+
+    def broken(self, queries, **kw):
+        lims, idx, dist, stats = real(self, queries, **kw)
+        lims = np.asarray(lims)
+        sizes = np.diff(lims)
+        keep = np.ones(len(idx), dtype=bool)
+        keep[lims[1:][sizes > 0] - 1] = False
+        cut = np.concatenate([[0], np.cumsum(sizes - (sizes > 0))])
+        return (cut.astype(lims.dtype), np.asarray(idx)[keep],
+                np.asarray(dist)[keep], stats)
+
+    monkeypatch.setattr(ShardedKNN, "range_search_certified", broken)
+
+
 def _break_serve(monkeypatch):
     """The engine answers from a placement whose rows were halved: every
     distance is off, which the recall may survive and the distance gap
@@ -99,11 +128,13 @@ def _break_serve(monkeypatch):
     monkeypatch.setattr(squeue.QueryQueue, "_resolve", staticmethod(broken))
 
 
+BREAKERS = {"sweep": _break_sweep, "sweep_ip": _break_sweep,
+            "sweep_range": _break_sweep_range, "openloop": _break_serve}
+
+
 @pytest.mark.parametrize("workload", CELLS)
-def test_a_broken_timed_path_comes_out_not_correct(root, workload,
-                                                    monkeypatch):
-    kind = harness.load_cell(root, workload).traffic["kind"]
-    {"sweep": _break_sweep, "openloop": _break_serve}[kind](monkeypatch)
+def test_a_broken_timed_path_reads_not_correct(root, workload, monkeypatch):
+    BREAKERS[harness.load_cell(root, workload).traffic["kind"]](monkeypatch)
     out = run(root, workload, False)
     assert out["correct"] is False
 

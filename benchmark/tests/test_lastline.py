@@ -17,14 +17,17 @@ CELLS = [c["name"] for c in BENCH["workloads"]]
 def good(workload: str, traced: bool) -> dict:
     metrics = {m["name"]: {"value": 12.5, "unit": m["unit"]}
                for m in lastline.required_metrics(BENCH, workload, traced)}
-    device = {"platform": "tpu", "kind": "TPU v5 lite", "count": 1,
+    device = {"platform": "tpu", "kind": "TPU v5 lite",
+              "count": lastline.cell_of(BENCH, workload)["chips"],
               "memory_peak_bytes": 8_500_000_000}
     line = {"correct": True, "attempted": 400, "failed": 0,
             "metrics": metrics, "device": device}
     if traced:
         device.update(window_s=4.0, busy_s=2.5)
         line["breakdown"] = {"device_ops": [["fusion.1", 1.5]],
-                             "idle_gaps": [["call", 0.7]]}
+                             "idle_gaps": [["knn.certified.unpack", 0.7]]}
+    line["compared"] = {"mismatched_rows": {
+        "value": 0.0, "limit": 0.0, "rule": "<="}}
     return line
 
 
@@ -93,6 +96,13 @@ BAD_ANY_MODE = {
     "device.memory_peak_bytes zero": _set(["device", "memory_peak_bytes"], 0),
     "device.memory_peak_bytes a float":
         _set(["device", "memory_peak_bytes"], 8.5e9),
+    "compared not the last key":
+        lambda line: line.update(correct=line.pop("correct")),
+    "compared empty": _set(["compared"], {}),
+    "a compared number without its limit":
+        lambda line: line["compared"]["mismatched_rows"].pop("limit"),
+    "a compared limit that is no number":
+        _set(["compared", "mismatched_rows", "limit"], "0"),
 }
 BAD_TRACED = {
     "busy_s zero": _set(["device", "busy_s"], 0.0),
@@ -169,8 +179,24 @@ def test_build_then_validate_round_trips():
         correct=True, attempted=400, failed=0,
         values={k: v["value"] for k, v in line["metrics"].items()},
         units={k: v["unit"] for k, v in line["metrics"].items()},
-        device=line["device"], breakdown=line["breakdown"])
+        device=line["device"], breakdown=line["breakdown"],
+        compared=[{"check": "mismatched_rows", "value": 0.0, "limit": 0.0,
+                   "rule": "<=", "ok": True}])
     assert lastline.validate(text, BENCH, workload, traced) == line
+    assert list(json.loads(text))[-1] == "compared"
+
+
+def test_a_compared_number_that_is_not_finite_is_written_as_text():
+    line = good(CELLS[0], False)
+    text = lastline.build(
+        correct=False, attempted=400, failed=0,
+        values={k: v["value"] for k, v in line["metrics"].items()},
+        units={k: v["unit"] for k, v in line["metrics"].items()},
+        device=line["device"],
+        compared=[{"check": "dist_rel_err_max", "value": float("nan"),
+                   "limit": 3.8e-6, "rule": "<=", "ok": False}])
+    out = lastline.validate(text, BENCH, CELLS[0], False)
+    assert out["compared"]["dist_rel_err_max"]["value"] == "nan"
 
 
 def test_a_roofline_share_over_105_is_refused():

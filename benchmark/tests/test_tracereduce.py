@@ -68,16 +68,96 @@ def test_top_ops_give_a_loop_its_own_time_only():
     assert red.busy_s == pytest.approx(2.4)
 
 
-def test_idle_gaps_are_named_by_the_innermost_host_span():
+def test_idle_gaps_are_cut_at_the_host_spans_edges():
     ev = [("a", 1.0 * S, 1.0 * S), ("b", 3.0 * S, 0.5 * S)]
     spans = [WINDOW, ("bench.call", 1.0 * S, 2.6 * S),
              ("bench.host-after-batch", 3.6 * S, 0.3 * S)]
     red = tr.reduce(extracted(ev, spans), "^XLA Ops$")
     gaps = dict(red.idle_gaps())
-    assert gaps["call"] == pytest.approx(1.0)           # [2, 3]
-    # [3.5, 5]: one gap, its middle (4.25) under no span
-    assert gaps["outside-spans"] == pytest.approx(1.5)
+    # [2, 3], and of the gap [3.5, 5] the 0.1 s before bench.call ends
+    assert gaps["call"] == pytest.approx(1.0 + 0.1)
+    assert gaps["host-after-batch"] == pytest.approx(0.3)
+    # a moment under no span but the window's own
+    assert gaps["outside-spans"] == pytest.approx(1.5 - 0.1 - 0.3)
+    assert tr.WINDOW_SPAN not in gaps and "trace_window" not in gaps
     assert sum(gaps.values()) == pytest.approx(red.window_s - red.busy_s)
+
+
+#: one certified call as the program annotates it, inside the benchmark's
+#: own span: three spans deep over the gap [2, 3]
+NESTED = [("bench.call", 1.0 * S, 2.6 * S),
+          ("knn.certified.call", 1.1 * S, 2.4 * S),
+          ("knn.certified.device_wait", 1.2 * S, 1.0 * S),     # to 2.2
+          ("knn.certified.unpack", 2.2 * S, 0.3 * S),          # to 2.5
+          ("knn.certified.repair", 2.6 * S, 0.6 * S),          # to 3.2
+          ("knn.certified.repair.reselect", 2.7 * S, 0.2 * S)]  # to 2.9
+
+
+def test_a_gap_across_nested_spans_is_split_at_their_edges():
+    ev = [("a", 1.0 * S, 1.0 * S), ("b", 3.0 * S, 2.0 * S)]
+    red = tr.reduce(extracted(ev, [WINDOW, *NESTED]), "^XLA Ops$")
+    gaps = dict(red.idle_gaps())
+    assert gaps == pytest.approx({
+        "knn.certified.device_wait": 0.2, "knn.certified.unpack": 0.3,
+        # [2.5, 2.6] lies under the call alone: a knn. span inside
+        # bench.call wins, and the bench. span holds nothing here
+        "knn.certified.call": 0.1,
+        "knn.certified.repair": 0.1 + 0.1,
+        "knn.certified.repair.reselect": 0.2})
+    assert sum(gaps.values()) == pytest.approx(red.window_s - red.busy_s)
+
+
+def test_equal_lengths_go_to_the_later_start():
+    spans = [("knn.first", 1.0, 2.0), ("knn.second", 2.0, 2.0)]
+    assert tr.attribute([(0.5, 4.5)], spans) == pytest.approx({
+        tr.OUTSIDE: 0.5 + 0.5, "knn.first": 1.0, "knn.second": 2.0})
+    assert tr.attribute([(2.0, 3.0)], spans[::-1]) == {"knn.second": 1.0}
+
+
+def test_the_rows_are_the_ten_longest_and_sum_to_the_first_chips_idle_time():
+    ev = [("a", 1.0 * S, 0.5 * S)]
+    spans = [WINDOW] + [(f"knn.certified.s{j}", (2.0 + 0.2 * j) * S,
+                         (0.01 + 0.01 * j) * S) for j in range(12)]
+    ex = extracted(ev, spans)
+    second = json.loads(json.dumps(ex["planes"][0]))
+    second["name"] = "/device:TPU:1"
+    second["lines"][1]["events"] = [["a", 1.0 * S, 3.5 * S]]
+    ex["planes"].append(second)
+    red = tr.reduce(ex, "^XLA Ops$")
+    assert len(red.idle_gaps()) == 10
+    gaps = dict(red.idle_gaps(20))
+    assert len(gaps) == 13 and [n for n, _ in red.idle_gaps(2)] == [
+        "outside-spans", "knn.certified.s11"]
+    # of the first chip's plane, not the mean's
+    assert sum(gaps.values()) == pytest.approx(4.0 - 0.5)
+    assert red.busy_s == pytest.approx((0.5 + 3.5) / 2)
+
+
+def _stage_report():
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.dirname(HERE)), "scripts",
+                        "certified_stage_report.py")
+    spec = importlib.util.spec_from_file_location("stage_report", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("gaps", [
+    [(2.0 * S, 3.0 * S)], [(0.5 * S, 1.05 * S), (2.45 * S, 2.65 * S)],
+    [(0.0, 5.0 * S)], [(3.3 * S, 3.4 * S), (3.7 * S, 4.0 * S)]],
+    ids=["nested", "edges", "everything", "outside"])
+def test_the_programs_own_report_lays_the_same_gaps_alike(gaps):
+    """``scripts/certified_stage_report.py idle`` is the arithmetic this
+    was taken from; until it calls this one, the two agree."""
+    theirs = _stage_report().attribute(
+        gaps, [(name, lo, lo + dur) for name, lo, dur in NESTED])
+    theirs[tr.OUTSIDE] = theirs.pop("outside", 0.0)
+    mine = tr.attribute(gaps, NESTED)
+    assert {k: v for k, v in theirs.items() if v} == pytest.approx(mine)
+    assert sum(mine.values()) == pytest.approx(
+        sum(hi - lo for lo, hi in gaps))
 
 
 @pytest.mark.parametrize("change,why", [
@@ -116,10 +196,15 @@ def test_read_xplane_on_a_trace_written_here(tmp_path):
     with jax.profiler.TraceAnnotation(tr.WINDOW_SPAN):
         for _ in range(3):
             with jax.profiler.TraceAnnotation("bench.call"):
-                f(x).block_until_ready()
+                with jax.profiler.TraceAnnotation("knn.certified.call"):
+                    with jax.profiler.TraceAnnotation("other.span"):
+                        f(x).block_until_ready()
     jax.profiler.stop_trace()
     ex = tr.read_xplane(tr.find_xplane(str(tmp_path)), "^/host:CPU$")
-    assert {s[0] for s in ex["host_spans"]} == {tr.WINDOW_SPAN, "bench.call"}
+    assert {s[0] for s in ex["host_spans"]} == {
+        tr.WINDOW_SPAN, "bench.call", "knn.certified.call"}
+    assert tr.describe(ex)["host_spans"] == sorted({
+        tr.WINDOW_SPAN, "bench.call", "knn.certified.call"})
     red = tr.reduce(ex, "^tf_XLAPjRtCpuClient")
     assert 0 < red.busy_s <= red.window_s
     assert red.op_seconds("dot") is not None

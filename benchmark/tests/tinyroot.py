@@ -13,13 +13,18 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 BENCH_DIR = os.path.dirname(HERE)
 ROOT = os.path.dirname(BENCH_DIR)
 
-#: what a tiny run changes, per file
+#: what a tiny run changes: in every configuration, and in a traffic file
+#: by its driver ``kind``
 TINY_CONFIG = {"rows_n": 3000, "train_tile": 1024,
                "require": {"tuning_source": "default", "interpret": True}}
+TINY_SWEEP = {"batch_rows": 64, "pool_batches": 2, "check_rows": 8,
+              "trace_seconds": 1}
 TINY_TRAFFIC = {
-    "sweep": {"batch_rows": 64, "pool_batches": 2, "check_rows": 8,
-              "trace_seconds": 1},
-    "serve": {"rate_rps": 40, "buckets": [8, 64], "pool_rows": 256,
+    "sweep": TINY_SWEEP,
+    "sweep_ip": TINY_SWEEP,
+    "sweep_range": {**TINY_SWEEP, "check_heavy_rows": 2, "shares": {
+        "unrelated": 44, "small_family": 16, "heavy_family": 4}},
+    "openloop": {"rate_rps": 40, "buckets": [8, 64], "pool_rows": 256,
               "mix": [{"rows": 1, "weight": 60}, {"rows": 8, "weight": 25},
                       {"rows": 64, "weight": 15}],
               "check_requests": 6, "check_rows_per_request": 8,
@@ -66,10 +71,9 @@ def make(tmp: str) -> str:
     for name in os.listdir(os.path.join(data, "configs")):
         edit(os.path.join(data, "configs", name),
              lambda o: o.update(TINY_CONFIG))
-    for name, tiny in TINY_TRAFFIC.items():
-        path = os.path.join(data, "traffic", f"{name}.json")
-        if os.path.exists(path):
-            edit(path, lambda o: o.update(tiny))
+    for name in os.listdir(os.path.join(data, "traffic")):
+        edit(os.path.join(data, "traffic", name),
+             lambda o: o.update(TINY_TRAFFIC[o["kind"]]))
     for name in os.listdir(os.path.join(data, "layers")):
         def cpu_names(o):
             for key in ("pattern", "minus_pattern"):

@@ -1,7 +1,9 @@
 """From a profiler trace (``.xplane.pb``) to the numbers the per-layer
 metrics read: device busy time, time in the operations a pattern names,
-the operations that took most time, and the longest idle gaps with what
-the benchmark's host code was doing in each.
+the operations that took most time, and the device's idle time by what
+the host was doing at each moment of it: the benchmark's own ``bench.``
+spans and the program's ``knn.`` spans (``knn_tpu/obs/trace.py``: every
+scoped span of the program is also a ``knn.<span>`` annotation).
 
 Two steps, so that the arithmetic can be pinned on a small recorded
 trace kept as JSON (``tests/data/``): :func:`read_xplane` pulls the
@@ -23,10 +25,14 @@ from __future__ import annotations
 import glob
 import os
 import re
+from bisect import bisect_right
 from typing import Dict, List, Optional, Sequence, Tuple
 
 WINDOW_SPAN = "bench.trace_window"
-HOST_PREFIX = "bench."
+BENCH_PREFIX = "bench."
+#: the host spans kept from a trace: the benchmark's own and the program's
+HOST_PREFIXES = (BENCH_PREFIX, "knn.")
+OUTSIDE = "outside-spans"
 NAME_CHARS = 96
 
 Event = Tuple[str, float, float]  # name, start_ns, duration_ns
@@ -47,7 +53,7 @@ def find_xplane(trace_dir: str) -> str:
 
 def read_xplane(path: str, plane_re: str) -> dict:
     """Events of every line of the planes ``plane_re`` names, and the
-    benchmark's own host spans (names starting ``bench.``) from every
+    host spans (names starting with one of ``HOST_PREFIXES``) from every
     plane.  ``{"planes": [{"name", "lines": [{"name", "events":
     [[name, start_ns, dur_ns], ...]}]}], "host_spans": [[name, start_ns,
     dur_ns], ...], "seen": {plane: [line, ...]}}``."""
@@ -68,7 +74,7 @@ def read_xplane(path: str, plane_re: str) -> dict:
         for ln in lines:
             host.extend([e.name, float(e.start_ns), float(e.duration_ns)]
                         for e in ln.events
-                        if e.name.startswith(HOST_PREFIX))
+                        if e.name.startswith(HOST_PREFIXES))
     return {"planes": planes, "host_spans": host, "seen": seen}
 
 
@@ -101,6 +107,34 @@ def _self_times(events: Sequence[Tuple[str, float, float]]) -> Dict[str, float]:
             stack[-1][2] -= min(hi, stack[-1][1]) - lo
         stack.append([name, hi, hi - lo])
     close(float("inf"))
+    return out
+
+
+def attribute(gaps: Sequence[Tuple[float, float]],
+              spans: Sequence[Event]) -> Dict[str, float]:
+    """The length of ``gaps`` (``(lo, hi)`` pairs that do not overlap)
+    per span name: each gap is cut at every span edge inside it and
+    every piece goes to the innermost of ``spans`` (``(name, start,
+    length)``) that covers it, which is the shortest one, the later
+    start where two are equally long; a piece under no span goes to
+    ``OUTSIDE``.  Same unit as given; the values sum to the gaps'."""
+    ends = [(name, lo, lo + dur) for name, lo, dur in spans if dur > 0]
+    cuts = sorted({t for _, lo, hi in ends for t in (lo, hi)})
+    # no span starts or ends between two neighbouring edges, so one span
+    # is the innermost all through
+    owner: List[Optional[str]] = []
+    for lo, hi in zip(cuts, cuts[1:]):
+        cover = [s for s in ends if s[1] <= lo and hi <= s[2]]
+        owner.append(min(cover, key=lambda s: (s[2] - s[1], -s[1]))[0]
+                     if cover else None)
+    out: Dict[str, float] = {}
+    for at, g_hi in gaps:
+        i = bisect_right(cuts, at)  # cuts[i - 1] <= at < cuts[i]
+        while at < g_hi:
+            upto = min(cuts[i], g_hi) if i < len(cuts) else g_hi
+            key = (owner[i - 1] if 0 < i < len(cuts) else None) or OUTSIDE
+            out[key] = out.get(key, 0.0) + (upto - at)
+            at, i = upto, i + 1
     return out
 
 
@@ -142,28 +176,26 @@ class Reduced:
         return [[name[:NAME_CHARS], s] for name, s in rows]
 
     def idle_gaps(self, n: int = 10) -> List[list]:
-        """Idle time of the first chip by what the benchmark's host code
-        was doing at the middle of each gap (the innermost ``bench.``
-        span there, else ``outside-spans``), longest first."""
+        """Idle time of the first chip by what the host was doing at
+        each moment of it (:func:`attribute` over the kept host spans
+        but the window's own), longest first.  A program span goes
+        under its full name (``knn.certified.rank_correct``), a
+        ``bench.`` span with the prefix stripped (``call``,
+        ``host-after-batch``)."""
         lo_w, hi_w = self._window
-        busy = self._chips[0]["union"]
         gaps, at = [], lo_w
-        for lo, hi in busy:
+        for lo, hi in self._chips[0]["union"]:
             if lo > at:
                 gaps.append((at, lo))
             at = max(at, hi)
         if hi_w > at:
             gaps.append((at, hi_w))
         by: Dict[str, float] = {}
-        for lo, hi in gaps:
-            mid = (lo + hi) / 2
-            inner = None
-            for name, s_lo, s_dur in self._host:
-                if name != WINDOW_SPAN and s_lo <= mid <= s_lo + s_dur:
-                    if inner is None or s_dur < inner[1]:
-                        inner = (name, s_dur)
-            key = inner[0][len(HOST_PREFIX):] if inner else "outside-spans"
-            by[key] = by.get(key, 0.0) + (hi - lo) / 1e9
+        for name, ns in attribute(
+                gaps, [s for s in self._host if s[0] != WINDOW_SPAN]).items():
+            if name.startswith(BENCH_PREFIX):
+                name = name[len(BENCH_PREFIX):]
+            by[name] = by.get(name, 0.0) + ns / 1e9
         rows = sorted(by.items(), key=lambda kv: -kv[1])[:n]
         return [[name, s] for name, s in rows]
 
