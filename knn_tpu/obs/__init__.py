@@ -13,13 +13,17 @@ writes through here instead of keeping private ad-hoc counters:
   ids minted at submit and propagated through micro-batching; a bounded
   in-memory event ring plus an optional JSONL sink
   (``KNN_TPU_OBS_LOG``).  A scoped span is also a ``knn.<span>``
-  profiler annotation, on the clock of a device trace.
+  profiler annotation, on the clock of a device trace.  A bulk call
+  keeps one account of the device programs it launched and records it
+  once when it ends (``trace.CallAccount``).
 - **Exporters** (:mod:`knn_tpu.obs.export`): Prometheus text served
   from a stdlib-HTTP endpoint (``--metrics-port``), an atomic JSON
   snapshot writer, and ``python -m knn_tpu.cli metrics`` to read
   either.
-- **Compile hook** (:mod:`knn_tpu.obs.jax_hooks`): every XLA compile's
-  count + seconds via ``jax.monitoring``.
+- **Compile hook** (:mod:`knn_tpu.obs.jax_hooks`): every trace,
+  lowering, backend compile and persistent-cache lookup's count +
+  seconds via ``jax.monitoring``, and the record of a device program's
+  first call (which one traced, compiled or loaded, and for how long).
 - **Roofline model** (:mod:`knn_tpu.obs.roofline`): the analytic
   per-config HBM/MXU/VPU cost model behind every ``roofline_pct`` /
   ``bound_class`` the bench, autotuner, sentinel, and /statusz report —

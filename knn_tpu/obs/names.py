@@ -77,6 +77,7 @@ CERTIFIED_METRIC_QUERIES = "knn_tpu_certified_metric_queries_total"
 CERTIFIED_QUANT_BOUND = "knn_tpu_certified_quant_bound"
 RANGE_QUERIES = "knn_tpu_range_queries_total"
 RANGE_RESULTS = "knn_tpu_range_results_total"
+PROGRAM_LAUNCHES = "knn_tpu_program_launches_total"
 
 # --- autotuner (knn_tpu.tuning) ----------------------------------------
 TUNING_RESOLVES = "knn_tpu_tuning_resolve_total"
@@ -304,6 +305,14 @@ CATALOG = {
         "counter", (),
         "Rows returned by ShardedKNN.range_search_certified, over all "
         "its queries."),
+    PROGRAM_LAUNCHES: (
+        "counter", ("program",),
+        "Device program launches by the certified and range calls, by "
+        "the program's name: 'certified' (the one-pass program), "
+        "'reselect' (the repair's widened exact select), 'range' (the "
+        "range completion), 'counted' and 'count' (the counted "
+        "selectors' two passes).  Moved once a call, by the call's "
+        "account (obs.trace.CallAccount)."),
     TUNING_RESOLVES: (
         "counter", (), "tuning.resolve() invocations."),
     TUNING_CACHE_HITS: (
@@ -332,10 +341,14 @@ CATALOG = {
         "recorded in the tune entry's vmem provenance."),
     JAX_COMPILES: (
         "counter", ("event",),
-        "JAX/XLA compile events observed via jax.monitoring."),
+        "JAX/XLA compile and compilation-cache events observed via "
+        "jax.monitoring (every /jax/core/compile/ and "
+        "/jax/compilation_cache/ key: traces, lowerings, backend "
+        "compiles, cache hits, misses and retrievals)."),
     JAX_COMPILE_SECONDS: (
         "counter", ("event",),
-        "Cumulative seconds spent in observed JAX/XLA compile events."),
+        "Cumulative seconds spent in the observed JAX/XLA compile and "
+        "compilation-cache events that carry a duration."),
     PHASE_SECONDS: (
         "histogram", ("phase",),
         "PhaseTimer phase durations (seconds), by phase name."),

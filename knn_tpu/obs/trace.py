@@ -26,8 +26,16 @@ laid against the stage the host was in.  Outside a capture an
 annotation costs one flag test.  The class is looked up only once JAX
 is imported: this package imports without it.
 
+A bulk call that launches device programs keeps a :class:`CallAccount`
+(:func:`call_account`): told of every launch and of every result's
+arrival, it records once, when the call ends, the seconds in flight by
+program and the exposed seconds in which nothing was, with the sums of
+any stage pieces measured in parts (:func:`phase`) — one record a call
+however many sub-batches the call was cut into.
+
 Disabled mode (``KNN_TPU_OBS=0``): :func:`span` yields a shared inert
-span and opens no annotation, :func:`new_trace_id` returns None, and
+span and opens no annotation, :func:`new_trace_id` returns None,
+:func:`call_account` hands out the shared inert account, and
 :func:`emit_event` drops — zero allocation on the hot path.
 """
 
@@ -40,6 +48,7 @@ import os
 import sys
 import threading
 import time
+import types
 import uuid
 from collections import deque
 from typing import Optional
@@ -211,6 +220,7 @@ class _NoopSpan:
     __slots__ = ()
     name = None
     trace_id = None
+    attrs = types.MappingProxyType({})  # nothing is ever set
 
     def set(self, key: str, value) -> None:
         pass
@@ -257,6 +267,174 @@ def _annotation(name: str):
     if prof is None:
         return _NO_ANNOTATION
     return prof.TraceAnnotation(ANNOTATION_PREFIX + name)
+
+
+@contextlib.contextmanager
+def phase(seconds: dict, key: str, annotation: Optional[str] = None):
+    """A piece of a stage that is summed and recorded once a call, not
+    once a scope: the scope's length is ADDED to ``seconds[key]`` (made
+    at 0.0 where missing), and with ``annotation`` the scope is a
+    ``knn.<annotation>`` profiler annotation on the calling thread, so a
+    device idle gap can be laid against the piece.  No span, no event:
+    the owner of ``seconds`` records the sum (:func:`record_span`,
+    :class:`CallAccount`).  Disabled mode runs the body and touches
+    nothing."""
+    if not registry.enabled():
+        yield
+        return
+    t0 = time.perf_counter()
+    try:
+        if annotation is None:
+            yield
+        else:
+            with _annotation(annotation):
+                yield
+    finally:
+        seconds[key] = seconds.get(key, 0.0) + time.perf_counter() - t0
+
+
+class CallAccount:
+    """One OUTERMOST call's account of the device programs it launched,
+    kept by the call itself (a local of the call, handed down beside its
+    trace id: two threads' calls never share one) and recorded ONCE when
+    the call ends, however many sub-batches it was cut into.
+
+    Two entry points: :meth:`launched` at the moment a program call has
+    returned to the host, :meth:`ready` where the host learns its result
+    is there.  From these, per call: the seconds during which at least
+    one launch of a program was outstanding (``inflight``, by program),
+    the launches by program, and the **exposed** seconds, in which
+    nothing the call launched was in flight: call start to the first
+    launch, every stretch from the moment the last outstanding result
+    was ready to the next launch, and from then to the call's end.  By
+    construction ``exposed + (union of the in-flight intervals) = the
+    call's length``; exposed is at most the device's idle time of the
+    call, since the device may also idle while a program is "in
+    flight" (launch latency, the gap before the host asks).
+
+    :meth:`add` sums any other piece of the call that is measured in
+    parts (a stage's phases over the sub-batches), for the same
+    once-a-call record.  :meth:`close` records everything through
+    :func:`record_span`: ``<root>.exposed``, ``<root>.inflight.<program>``
+    for every program named at construction, which are the programs the
+    call may launch (0.0 where one did not run, so a reader never finds
+    a series missing), one span for every summed
+    piece, and ``knn_tpu_program_launches_total``."""
+
+    __slots__ = ("root", "_t0", "_idle_from", "_busy_from", "_out",
+                 "_n_out", "_since", "inflight", "launches", "exposed",
+                 "union", "before_first", "between", "sums", "sum_attrs")
+
+    def __init__(self, root: str, programs, pieces=()):
+        self.root = root
+        self._t0 = self._idle_from = time.perf_counter()
+        self._busy_from = 0.0
+        self._out = dict.fromkeys(programs, 0)  # outstanding, by program
+        self._n_out = 0  # outstanding, all programs
+        self._since = {}
+        self.inflight = dict.fromkeys(programs, 0.0)
+        self.launches = dict.fromkeys(programs, 0)
+        self.exposed = self.union = self.between = 0.0
+        self.before_first = None
+        self.sums = dict.fromkeys(pieces, 0.0)
+        self.sum_attrs = {}
+
+    def launched(self, program: str) -> None:
+        now = time.perf_counter()
+        if not self._n_out:
+            gap = now - self._idle_from
+            self.exposed += gap
+            if self.before_first is None:
+                self.before_first = gap
+            else:
+                self.between += gap
+            self._busy_from = now
+        if not self._out[program]:
+            self._since[program] = now
+        self._out[program] += 1
+        self._n_out += 1
+        self.launches[program] += 1
+
+    def ready(self, program: str) -> None:
+        # a fetch that failed and was re-dispatched reports once: a
+        # result nobody launched here is nobody's
+        if not self._out[program]:
+            return
+        now = time.perf_counter()
+        self._out[program] -= 1
+        self._n_out -= 1
+        if not self._out[program]:
+            self.inflight[program] += now - self._since[program]
+        if not self._n_out:
+            self.union += now - self._busy_from
+            self._idle_from = now
+
+    def add(self, piece: str, seconds: float, **attr_seconds) -> None:
+        self.sums[piece] = self.sums.get(piece, 0.0) + seconds
+        if attr_seconds:
+            kept = self.sum_attrs.setdefault(piece, {})
+            for k, v in attr_seconds.items():
+                kept[k] = kept.get(k, 0.0) + v
+
+    def close(self, trace_id: Optional[str], of: str) -> None:
+        """Record the account (class docstring).  ``of`` is the call's
+        own span: the records name it as ``account_of``, not as
+        ``parent``, since they are sums over the call that overlap its
+        stages, and a reader of self times must not subtract them."""
+        now = time.perf_counter()
+        after = 0.0
+        if self._n_out:  # launched and never waited for
+            for program, n in self._out.items():
+                if n:
+                    self.inflight[program] += now - self._since[program]
+            self.union += now - self._busy_from
+        else:
+            after = now - self._idle_from
+            self.exposed += after
+        before = self.before_first
+        if before is None:  # nothing was launched: all of it came "before"
+            before, after = after, 0.0
+        record_span(
+            f"{self.root}.exposed", trace_id, self.exposed, account_of=of,
+            call_s=now - self._t0, inflight_union_s=self.union,
+            before_first_launch_s=before, after_last_ready_s=after,
+            between_s=self.between, launches=sum(self.launches.values()))
+        for program, seconds in self.inflight.items():
+            record_span(f"{self.root}.inflight.{program}", trace_id,
+                        seconds, account_of=of,
+                        launches=self.launches[program])
+            registry.counter(names.PROGRAM_LAUNCHES, program=program).inc(
+                self.launches[program])
+        for piece, seconds in self.sums.items():
+            record_span(piece, trace_id, seconds, account_of=of,
+                        **self.sum_attrs.get(piece, {}))
+
+
+class _NoopAccount:
+    __slots__ = ()
+
+    def launched(self, program: str) -> None:
+        pass
+
+    def ready(self, program: str) -> None:
+        pass
+
+    def add(self, piece: str, seconds: float, **attr_seconds) -> None:
+        pass
+
+    def close(self, trace_id, of: str) -> None:
+        pass
+
+
+NOOP_ACCOUNT = _NoopAccount()
+
+
+def call_account(root: str, programs, pieces=()):
+    """A :class:`CallAccount` whose clock starts now, or the shared inert
+    one when the subsystem is off."""
+    if not registry.enabled():
+        return NOOP_ACCOUNT
+    return CallAccount(root, programs, pieces)
 
 
 @contextlib.contextmanager

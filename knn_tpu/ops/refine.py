@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import os
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional, Tuple
 
@@ -84,13 +85,16 @@ def _pairwise_f64(queries: np.ndarray, cand: np.ndarray, metric: str) -> np.ndar
 
 def _score_members(db_np: np.ndarray, queries_np: np.ndarray,
                    cand: np.ndarray, rows: np.ndarray, metric: str,
-                   out: np.ndarray) -> None:
+                   out: np.ndarray) -> float:
     """``out[j]`` = the float64 ``metric`` value between query
     ``rows[j]`` and db row ``cand[j]``: squared L2 by direct difference,
     or the negated inner product (``"dot"``).  f32 -> f64 is exact, so
     the in-place arithmetic on the widened rows equals widening both
-    sides first; each member's sum is its own."""
+    sides first; each member's sum is its own.  Returns the moment the
+    rows had been gathered and widened (``time.perf_counter``): before
+    it the gather, after it the arithmetic."""
     acc = db_np[cand].astype(np.float64)
+    gathered = time.perf_counter()
     if metric == "dot":
         # products of two float32 values are exact in float64; only the
         # sum rounds (pairwise: under (D+1) * 2^-53 * sum |q_i t_i|)
@@ -100,6 +104,7 @@ def _score_members(db_np: np.ndarray, queries_np: np.ndarray,
     else:
         acc -= queries_np[rows]
         np.einsum("nd,nd->n", acc, acc, out=out)
+    return gathered
 
 
 def exact_pair_scores(db_np: np.ndarray, queries_np: np.ndarray,
@@ -167,6 +172,20 @@ def host_exact_range(db_np: np.ndarray, q_np: np.ndarray, radius_sq: float
     return np.concatenate(qs), np.concatenate(ts), np.concatenate(ds)
 
 
+#: the three phases of :func:`rank_correct_runs`: names of its profiler
+#: annotations (``knn.`` before them) and of the once-a-call spans a
+#: certified call records of their sums (parallel.sharded)
+PHASE_BUFFERS = "certified.rank_correct.buffers"
+PHASE_SCORE = "certified.rank_correct.score"
+PHASE_ORDER = "certified.rank_correct.order"
+
+
+def _tell(sp, secs: dict) -> None:
+    """Hand a stage's span the seconds of its phases."""
+    for key, value in secs.items():
+        sp.set(key, value)
+
+
 def rank_correct_runs(
     gi: np.ndarray,
     tight: np.ndarray,
@@ -209,7 +228,15 @@ def rank_correct_runs(
     of one array and each member's sum is its own, so the answer does
     not depend on the blocking or on thread timing.  The innermost span
     open on the calling thread (the caller's ``certified.rank_correct``)
-    is told ``members`` and ``blocks``.
+    is told ``members`` and ``blocks``, and where this call's seconds
+    went, in three phases that are also ``knn.<phase>`` profiler
+    annotations on this thread: ``buffers_s`` (the copies of the
+    distances and of the windowed indices), ``score_s`` (the wall time
+    of the re-score; inside it, summed over the blocks whichever thread
+    ran them, ``gather_s``, the fancy-index gather of the members' rows
+    with its widening, and ``arith_s``, the subtraction or product and
+    the sum) and ``order_s`` (everything else: mask, ``nonzero``, run
+    ids, ``lexsort``, scatter, ``unique``).
 
     Returns (d_out or None, i_out [Q, k] int64, corrected_row_count).
     """
@@ -217,48 +244,68 @@ def rank_correct_runs(
     w = tight.shape[1] + 1
     if w < k:
         raise ValueError(f"tie mask window {w} < k={k}")
-    inv = np.zeros((n_q, w), dtype=bool)
-    inv[:, :-1] |= tight
-    inv[:, 1:] |= tight
-    d_out = d32k.copy() if d32k is not None else None
-    rows, cols = np.nonzero(inv)
-    block = _block_rows(db_np.shape[1])
-    starts = range(0, rows.size, block)
+    secs = {"buffers_s": 0.0, "score_s": 0.0, "order_s": 0.0,
+            "gather_s": 0.0, "arith_s": 0.0}
     sp = obs.current_span()
+    with obs.trace.phase(secs, "order_s", PHASE_ORDER):
+        inv = np.zeros((n_q, w), dtype=bool)
+        inv[:, :-1] |= tight
+        inv[:, 1:] |= tight
+        rows, cols = np.nonzero(inv)
+        block = _block_rows(db_np.shape[1])
+        starts = range(0, rows.size, block)
     sp.set("members", int(rows.size))
     sp.set("blocks", len(starts))
+    with obs.trace.phase(secs, "buffers_s", PHASE_BUFFERS):
+        d_out = d32k.copy() if d32k is not None else None
+        if rows.size == 0:
+            i_out = gi[:, :k].astype(np.int64)
+        else:
+            gw = gi[:, :w].astype(np.int64).copy()
     if rows.size == 0:
-        return d_out, gi[:, :k].astype(np.int64), 0
-    gw = gi[:, :w].astype(np.int64).copy()
-    cand = gw[rows, cols]
-    safe = np.clip(cand, 0, db_np.shape[0] - 1)
-    d64 = np.empty(rows.size)
+        _tell(sp, secs)
+        return d_out, i_out, 0
+    with obs.trace.phase(secs, "order_s", PHASE_ORDER):
+        cand = gw[rows, cols]
+        safe = np.clip(cand, 0, db_np.shape[0] - 1)
+        d64 = np.empty(rows.size)
 
-    def score(lo: int) -> None:
-        _score_members(db_np, queries_np, safe[lo : lo + block],
-                       rows[lo : lo + block], metric, d64[lo : lo + block])
+    def score(lo: int) -> Tuple[float, float]:
+        t0 = time.perf_counter()
+        gathered = _score_members(
+            db_np, queries_np, safe[lo : lo + block],
+            rows[lo : lo + block], metric, d64[lo : lo + block])
+        return gathered - t0, time.perf_counter() - gathered
 
-    if len(starts) == 1:
-        score(0)
-    else:
-        # list(): reading every result re-raises a worker's exception
-        list(_shared_pool().map(score, starts))
-    d64 = np.where(cand < db_np.shape[0], d64, np.inf)
-    # maximal runs of consecutive involved positions; (rows, cols) comes
-    # position-sorted from nonzero, so each run is one contiguous block
-    new_run = np.ones(rows.size, dtype=bool)
-    new_run[1:] = (rows[1:] != rows[:-1]) | (cols[1:] != cols[:-1] + 1)
-    run_id = np.cumsum(new_run) - 1
-    # lexicographic sort within each run; runs are contiguous ascending in
-    # both the original flat order and the (run_id-primary) sorted order,
-    # so flat positions realign block-for-block
-    order = np.lexsort((cand, d64, run_id))
-    gw[rows, cols] = cand[order]
-    if d_out is not None:
-        in_k = cols < k
-        d_sorted = d64[order]
-        d_out[rows[in_k], cols[in_k]] = d_sorted[in_k]
-    return d_out, gw[:, :k], int(len(np.unique(rows)))
+    with obs.trace.phase(secs, "score_s", PHASE_SCORE):
+        if len(starts) == 1:
+            parts = [score(0)]
+        else:
+            # list(): reading every result re-raises a worker's exception
+            parts = list(_shared_pool().map(score, starts))
+    secs["gather_s"] = sum(g for g, _ in parts)
+    secs["arith_s"] = sum(a for _, a in parts)
+    with obs.trace.phase(secs, "order_s", PHASE_ORDER):
+        d64 = np.where(cand < db_np.shape[0], d64, np.inf)
+        # maximal runs of consecutive involved positions; (rows, cols)
+        # comes position-sorted from nonzero, so each run is one
+        # contiguous block
+        new_run = np.ones(rows.size, dtype=bool)
+        new_run[1:] = (rows[1:] != rows[:-1]) | (cols[1:] != cols[:-1] + 1)
+        run_id = np.cumsum(new_run) - 1
+        # lexicographic sort within each run; runs are contiguous
+        # ascending in both the original flat order and the
+        # (run_id-primary) sorted order, so flat positions realign
+        # block-for-block
+        order = np.lexsort((cand, d64, run_id))
+        gw[rows, cols] = cand[order]
+        if d_out is not None:
+            in_k = cols < k
+            d_sorted = d64[order]
+            d_out[rows[in_k], cols[in_k]] = d_sorted[in_k]
+        n_rows = int(len(np.unique(rows)))
+    _tell(sp, secs)
+    return d_out, gw[:, :k], n_rows
 
 
 def refine_exact(

@@ -29,7 +29,6 @@ The reference's distributed min-max normalize (knn_mpi.cpp:229-306) maps to
 
 from __future__ import annotations
 
-import contextlib
 import functools
 import threading
 import time
@@ -42,7 +41,9 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from knn_tpu import obs
+from knn_tpu.obs import jax_hooks as _hooks
 from knn_tpu.obs import names as _mn
+from knn_tpu.ops import refine as _refine
 from knn_tpu.ops.normalize import local_minmax, minmax_apply
 from knn_tpu.ops.topk import knn_search_tiled, merge_topk, topk_pairs
 from knn_tpu.ops.vote import majority_vote
@@ -259,7 +260,7 @@ def _knn_program(
             hosts, chips, selector, recall_target, dcn_merge,
         )
 
-    return jax.jit(
+    prog = jax.jit(
         jax.shard_map(
             spmd,
             mesh=mesh,
@@ -268,6 +269,9 @@ def _knn_program(
             check_vma=False,  # merged output is replicated along db by construction
         ),
     )
+    # at the repair's re-select k is the widened width
+    _hooks.mark_built(prog, f"k={k},selector={selector},tile={train_tile}")
+    return prog
 
 
 @functools.lru_cache(maxsize=32)
@@ -500,27 +504,39 @@ _RANGE_SPAN = "certified.range_call"
 _METRIC_SPAN = "certified.metric_map"
 
 
-@contextlib.contextmanager
-def _metric_side(seconds: dict, side: str):
-    """One side of :data:`_METRIC_SPAN`: a ``knn.certified.metric_map``
-    profiler annotation around the scope, its length kept in
-    ``seconds[side]`` for the call's one span."""
-    t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation(
-            obs.trace.ANNOTATION_PREFIX + _METRIC_SPAN):
-        yield
-    seconds[side] = time.perf_counter() - t0
+#: root of the once-a-call records of a call's account
+#: (``obs.trace.CallAccount``): ``certified.exposed``,
+#: ``certified.inflight.<program>``
+_ACCOUNT_ROOT = "certified"
+#: the stage pieces a ``selector="pallas"`` call sums over its
+#: sub-batches and records once, at 0.0 where a piece did not run
+_UNPACK_COPIES = "certified.unpack.copies"
+_PALLAS_PIECES = (_refine.PHASE_BUFFERS, _refine.PHASE_SCORE,
+                  _refine.PHASE_ORDER, _UNPACK_COPIES)
 
 
-def _staged_fetch(trace_id):
+def _call_account(selector: str, *more: str):
+    """The account of one outermost certified call: the device programs
+    a call of this ``selector`` can launch (``more``: those of the call
+    it is the first pass of), and the pieces it sums."""
+    if selector == "pallas":
+        return obs.trace.call_account(
+            _ACCOUNT_ROOT, ("certified", "reselect") + more, _PALLAS_PIECES)
+    return obs.trace.call_account(
+        _ACCOUNT_ROOT, ("counted", "count", "reselect") + more)
+
+
+def _staged_fetch(trace_id, acct=obs.trace.NOOP_ACCOUNT):
     """A ``fetch`` for :func:`_fetch_or_redispatch` that reads a
     certified batch's packed output in two stages at the one point where
     the host blocks anyway: ``certified.device_wait`` until the device
-    has finished the batch, then ``certified.d2h`` for the copy."""
+    has finished the batch (which the call's account ``acct`` is told),
+    then ``certified.d2h`` for the copy."""
 
     def fetch(out):
         with obs.span("certified.device_wait", trace_id, parent=_CALL_SPAN):
             jax.block_until_ready(out)
+            acct.ready("certified")
         with obs.span("certified.d2h", trace_id, parent=_CALL_SPAN) as sp:
             arr = np.asarray(out)
             sp.set("d2h_bytes", arr.nbytes)
@@ -832,8 +848,13 @@ class ShardedKNN:
             self._last_hosttier: Optional[dict] = None
         else:
             # the reference's Scatter, once (host-major over hosts x
-            # chips on hierarchical meshes)
+            # chips on hierarchical meshes); the call returns with the
+            # transfer on its way, and the wait for it is the caller's
+            t0 = time.perf_counter()
             self._tp = shard(tp, mesh, db_axes(mesh))
+            obs.emit_event("placement.device_put", rows=int(tp.shape[0]),
+                           bytes=int(tp.nbytes),
+                           seconds=time.perf_counter() - t0)
         #: (k, placed query rows) -> dispatch count: every distinct pair is
         #: one traced/compiled XLA program shape (compile_cache_stats)
         self._dispatch_shapes: dict = {}
@@ -1284,7 +1305,10 @@ class ShardedKNN:
                     "the pre-placed global array spans multiple processes; "
                     "construct ShardedKNN from a host array instead"
                 )
+            t0 = time.perf_counter()
             self._train_host = np.asarray(self._tp)[: self.n_train]
+            obs.emit_event("placement.host_copy", rows=int(self.n_train),
+                           seconds=time.perf_counter() - t0)
         return self._train_host
 
     def _db_norm_max(self) -> float:
@@ -1295,6 +1319,7 @@ class ShardedKNN:
             from knn_tpu.ops.pallas_knn import lo_halves_zero
 
             db = self._host_train()
+            t0 = time.perf_counter()
             # row chunks: the same per-row arithmetic, without float64
             # temporaries the size of the whole database (15 GB and a
             # minute of page faults at GIST 1M x 960).  The same walk
@@ -1310,6 +1335,9 @@ class ShardedKNN:
                 best = max(best, float(sq.sum(-1).max()))
             self._rows_lo_zero = exact
             self._db_norm_max_cache = best
+            obs.emit_event("placement.norm_walk", rows=int(db.shape[0]),
+                           rows_lo_zero=exact,
+                           seconds=time.perf_counter() - t0)
         return self._db_norm_max_cache
 
     def _kernel_terms(self, q_np: np.ndarray, precision: str) -> str:
@@ -1474,7 +1502,7 @@ class ShardedKNN:
         kernel: Optional[str] = None,
         tune_cache: Optional[str] = None,
         return_sqrt: bool = False,
-        _under: Optional[Tuple[str, str]] = None,
+        _under: Optional[tuple] = None,
     ):
         """Exact lexicographic top-k via the certified pipeline, sharded.
         Returns (dists_f64, idx, stats).  L2, cosine and dot (the
@@ -1598,15 +1626,18 @@ class ShardedKNN:
         from knn_tpu.ops.certified import repair_uncertified
 
         # a call that is another's first pass runs under that call's
-        # trace id and names its span as parent (_under, private)
-        tid, parent = _under or (obs.new_trace_id(), None)
+        # trace id, names its span as parent and adds to its account
+        # (_under, private); any other keeps one of its own
+        tid, parent, acct = _under or (obs.new_trace_id(), None, None)
         dot = self.metric == "dot"
         with obs.span(_CALL_SPAN, tid, selector=selector,
                       **({"parent": parent} if parent else {})) as call:
+            if _under is None:
+                acct = _call_account(selector)
             q_np = np.asarray(queries, dtype=np.float32)
             map_s = {"before_s": 0.0, "after_s": 0.0}
             if dot:
-                with _metric_side(map_s, "before_s"):
+                with obs.trace.phase(map_s, "before_s", _METRIC_SPAN):
                     # the zero column matching the placed rows'
                     # augmentation
                     q_np = np.concatenate(
@@ -1694,13 +1725,13 @@ class ShardedKNN:
                     batches, bs, d, i, q_np, db_np, prog=prog, w=w,
                     ops_tail=ops_tail, precision=knobs["precision"],
                     trace_id=tid, want_distances=device_d,
-                    rank_metric="dot" if dot else "l2",
+                    rank_metric="dot" if dot else "l2", acct=acct,
                 )
             else:
                 bad = self._certify_counted(
                     batches, bs, m, d, i, q_np, db_np, db_norm_max,
                     selector, recall_target=recall_target,
-                    metric=cert_metric, dot=dot,
+                    metric=cert_metric, dot=dot, trace_id=tid, acct=acct,
                 )
 
             def _select(qb, widen):
@@ -1722,9 +1753,15 @@ class ShardedKNN:
                     bq, _ = self._place_queries(qb)
                     merge_bytes += self._record_merge_bytes(
                         bq.shape[0], widen)
+                    begun = _hooks.first_call_begin()
                     fs, fi = exact(bq, self._tp)
+                    acct.launched("reselect")
+                    _hooks.first_call_end(begun, exact, "reselect", tid,
+                                          rows=bq.shape[0])
                     n_b = qb.shape[0]
-                    return np.asarray(fs)[:n_b], np.asarray(fi)[:n_b]
+                    fs = np.asarray(fs)
+                    acct.ready("reselect")
+                    return fs[:n_b], np.asarray(fi)[:n_b]
 
             with obs.span("certified.repair", tid, parent=_CALL_SPAN,
                           fallback_queries=int(bad.size)) as sp:
@@ -1828,7 +1865,7 @@ class ShardedKNN:
                 # of nearly equal numbers) is involved.
                 from knn_tpu.ops.refine import exact_scores
 
-                with _metric_side(map_s, "after_s"):
+                with obs.trace.phase(map_s, "after_s", _METRIC_SPAN):
                     d = exact_scores(db_np, q_np, i, "dot")
             if dot:
                 obs.record_span(_METRIC_SPAN, tid, sum(map_s.values()),
@@ -1840,6 +1877,8 @@ class ShardedKNN:
                 from knn_tpu.ops.distance import metric_values
 
                 d = metric_values(d, self.metric)
+            if _under is None:
+                acct.close(tid, _CALL_SPAN)
             return (d if return_distances else None), i, stats
 
     def range_search_certified(self, queries, *, radius_sq: float,
@@ -1916,10 +1955,12 @@ class ShardedKNN:
         tid = obs.new_trace_id()
         with obs.span(_RANGE_SPAN, tid, selector=selector,
                       radius_sq=radius_sq) as call:
+            # the outermost call's account: the first pass adds to it
+            acct = _call_account(selector, "range")
             q_np = np.asarray(queries, dtype=np.float32)
             n_q, k = q_np.shape[0], self.k
             d, i, stats = self.search_certified(
-                q_np, selector=selector, _under=(tid, _RANGE_SPAN))
+                q_np, selector=selector, _under=(tid, _RANGE_SPAN, acct))
             db_np = self._host_train()
             # the k-th row decides: in float32 where its value is clear
             # of the threshold by the device's rank slack, in float64
@@ -1937,7 +1978,7 @@ class ShardedKNN:
             # the completion's first sub-batch is sent off before the
             # pack, which then has the host while the device works
             first = self._range_launch(
-                q_np, db_np, tq[:RANGE_SUB_BATCH], radius_sq)
+                q_np, db_np, tq[:RANGE_SUB_BATCH], radius_sq, acct, tid)
             with obs.span("certified.range_pack", tid,
                           parent=_RANGE_SPAN) as sp:
                 # the complete queries' prefix: whatever could be in by
@@ -1953,7 +1994,7 @@ class ShardedKNN:
             with obs.span("certified.range_complete", tid,
                           parent=_RANGE_SPAN, queries=int(tq.size)) as sp:
                 cq, ci, cd, done = self._range_complete(
-                    q_np, db_np, tq, radius_sq, first)
+                    q_np, db_np, tq, radius_sq, first, acct, tid)
                 sp.set("rung", done["width"])
                 sp.set("sub_batches", done["sub_batches"])
                 sp.set("host_scan_queries", done["host_scan"])
@@ -1981,13 +2022,16 @@ class ShardedKNN:
                 obs.counter(_mn.RANGE_QUERIES, outcome=outcome).inc(
                     rng_stats[outcome])
             obs.counter(_mn.RANGE_RESULTS).inc(idx.size)
+            acct.close(tid, _RANGE_SPAN)
             return lims, idx, dist, {**stats, "range": rng_stats}
 
-    def _range_launch(self, q_np, db_np, sub, radius_sq: float):
+    def _range_launch(self, q_np, db_np, sub, radius_sq: float, acct,
+                      trace_id=None):
         """The completion's device program (``_range_program``) for the
         queries ``sub`` (at most a sub-batch of positions in ``q_np``),
         sent to the device and not waited for: its ``(counts,
-        compact)``, or None for no query."""
+        compact)``, or None for no query.  The call's account ``acct``
+        is told of the launch."""
         from knn_tpu.ops.certified import certification_tolerance
         from knn_tpu.ops.radius import RANGE_SUB_BATCH, range_width
 
@@ -2010,17 +2054,24 @@ class ShardedKNN:
                               range_width(self.k))
         qp, _ = self._place_queries(chunk)
         thr_p, _ = self._place_queries(thr)
-        return _retry_transient(
+        begun = _hooks.first_call_begin()
+        out = _retry_transient(
             lambda: prog(qp, self._tp, thr_p), "range completion dispatch")
+        acct.launched("range")
+        _hooks.first_call_end(begun, prog, "range", trace_id,
+                              rows=qp.shape[0])
+        return out
 
-    def _range_complete(self, q_np, db_np, tq, radius_sq: float, first):
+    def _range_complete(self, q_np, db_np, tq, radius_sq: float, first,
+                        acct, trace_id=None):
         """Step 2 of :meth:`range_search_certified`: the complete result
         lists of the truncated queries ``tq`` (positions in ``q_np``),
         as flat ``(query positions, db rows, float64 distances)`` sorted
         by (query, distance, index), and what was done: the collect
         width (0 where nothing was sent), the sub-batches sent and the
         queries finished by the host scan.  ``first`` is the first
-        sub-batch's answer, already on its way (:meth:`_range_launch`)."""
+        sub-batch's answer, already on its way (:meth:`_range_launch`);
+        ``acct`` learns when each sub-batch's answer is there."""
         from knn_tpu.ops.radius import (RANGE_SUB_BATCH, decode_words,
                                         range_width)
         from knn_tpu.ops.refine import exact_pair_scores, host_exact_range
@@ -2034,11 +2085,13 @@ class ShardedKNN:
         for lo in range(0, tq.size, RANGE_SUB_BATCH):
             sub = tq[lo:lo + RANGE_SUB_BATCH]
             counts, compact = first if lo == 0 else self._range_launch(
-                q_np, db_np, sub, radius_sq)
+                q_np, db_np, sub, radius_sq, acct, trace_id)
             done["sub_batches"] += 1
+            counts = np.asarray(counts)  # the host waits here
+            acct.ready("range")
             # a query that marks no more rows than the width marks no
             # more words than it on any shard
-            over = np.asarray(counts)[:sub.size] > width
+            over = counts[:sub.size] > width
             if not over.all():
                 compact = np.asarray(compact)
                 per = compact.shape[2] // shards
@@ -2079,11 +2132,13 @@ class ShardedKNN:
     def _certify_counted(
         self, batches, bs, m, d, i, q_np, db_np, db_norm_max, selector,
         recall_target: Optional[float] = None, metric: Optional[str] = None,
-        dot: bool = False,
+        dot: bool = False, trace_id=None, acct=obs.trace.NOOP_ACCOUNT,
     ):
         """Two-pass certificate: coarse select + refine, then the
         distributed count-below program proves completeness.  Returns the
-        flagged query indices.
+        flagged query indices.  The call's account ``acct`` is told of
+        both passes' launches and fetches (programs ``counted`` and
+        ``count``).
 
         The count threshold is ADAPTIVE: the refine already produced the
         float64 distances of every candidate, so each query counts
@@ -2123,9 +2178,13 @@ class ShardedKNN:
         coarse_out = []
         for lo, chunk, pad in batches:
             qp, _ = self._place_queries(chunk)
+            begun = _hooks.first_call_begin()
             coarse_out.append((
                 qp, _retry_transient(lambda q=qp: coarse(q, self._tp),
                                      "coarse dispatch")))
+            acct.launched("counted")
+            _hooks.first_call_end(begun, coarse, "counted", trace_id,
+                                  rows=qp.shape[0])
 
         # stage 2: per batch — sync its candidates, float64 host refine
         # (overlapping later batches' device work), dispatch its count
@@ -2135,6 +2194,7 @@ class ShardedKNN:
             ci = _fetch_or_redispatch(
                 ci, lambda q=qp: coarse(q, self._tp)[1], "coarse fetch"
             )[:take]
+            acct.ready("counted")
             m_avail = ci.shape[1]
             # refine ALL candidates: ranks k..m feed the gap search
             d_m, i_m = refine_exact(db_np, q_np[lo : lo + take], ci, m_avail,
@@ -2176,11 +2236,15 @@ class ShardedKNN:
             thr_p = np.full(qp.shape[0], -np.inf, dtype=np.float32)
             thr_p[:take] = mid
             thr_s = shard(thr_p, self.mesh, QUERY_AXIS)
+            begun = _hooks.first_call_begin()
             count_out.append((
                 lo, take, js, qp, thr_s, mid, d_m[:, k - 1].copy(),
                 _retry_transient(lambda q=qp, t=thr_s: count_fn(q, self._tp, t),
                                  "count dispatch"),
             ))
+            acct.launched("count")
+            _hooks.first_call_end(begun, count_fn, "count", trace_id,
+                                  rows=qp.shape[0])
 
         # stage 3: collect certificates (count <= per-query rank bound)
         flagged = []
@@ -2188,6 +2252,7 @@ class ShardedKNN:
             c_np = _fetch_or_redispatch(
                 c, lambda q=qp, t=thr_s: count_fn(q, self._tp, t),
                 "count fetch")
+            acct.ready("count")
             over = c_np[:take] > js
             flagged.append(lo + np.flatnonzero(over))
             # certificate-margin telemetry: per certified query, the
@@ -2317,7 +2382,7 @@ class ShardedKNN:
     def _certify_pallas(
         self, batches, bs, d, i, q_np, db_np, *, prog, w, ops_tail,
         precision, trace_id=None, want_distances=True,
-        rank_metric="l2",
+        rank_metric="l2", acct=obs.trace.NOOP_ACCOUNT,
     ):
         """One-pass certificate, host side.  The device already ranked the
         candidates, flagged uncertified rows, and marked near-tie pairs
@@ -2332,11 +2397,15 @@ class ShardedKNN:
         :meth:`_pallas_setup`'s, ``ops_tail`` :meth:`_pallas_operands`'s;
         each batch's stages are spans of the caller's ``trace_id``
         (``certified.dispatch``, ``.device_wait``, ``.d2h``, ``.unpack``,
-        ``.rank_correct``)."""
+        ``.rank_correct``).  The call's account ``acct`` is told of every
+        launch and fetch, and is handed what ``unpack_certified`` and
+        ``rank_correct_runs`` say of their own insides (the copies; the
+        buffers, the re-score and the ordering), summed over the batches
+        for the call's once-a-call records."""
         from knn_tpu.ops.refine import rank_correct_runs
 
         k = self.k
-        fetch = _staged_fetch(trace_id)
+        fetch = _staged_fetch(trace_id, acct)
         if precision in ("int8", "pq") and obs.enabled():
             # the per-query certified quantization bound ε — the quality
             # signal the device certificate computes and discards
@@ -2366,19 +2435,31 @@ class ShardedKNN:
             take = bs - pad
             packed_np = _fetch_or_redispatch(packed, redo, "pallas fetch",
                                              fetch=fetch)
-            with obs.span("certified.unpack", trace_id, parent=_CALL_SPAN):
+            with obs.span("certified.unpack", trace_id,
+                          parent=_CALL_SPAN) as sp:
                 gi_np, tight_np, bad_np, dk_np = unpack_certified(
                     packed_np[:take], k, w, want_distances
                 )
+            acct.add(_UNPACK_COPIES, sp.attrs.get("copies_s", 0.0))
             with obs.span("certified.rank_correct", trace_id,
                           parent=_CALL_SPAN) as sp:
+                own = {}  # the caller's share of the buffers
+                with obs.trace.phase(own, "buffers_s",
+                                     _refine.PHASE_BUFFERS):
+                    d32k = (None if dk_np is None
+                            else dk_np.astype(np.float64))
                 dc, ic, n_c = rank_correct_runs(
                     gi_np, tight_np, k, q_np[lo : lo + take], db_np,
-                    d32k=(None if dk_np is None
-                          else dk_np.astype(np.float64)),
-                    metric=rank_metric,
+                    d32k=d32k, metric=rank_metric,
                 )
                 sp.set("queries_corrected", n_c)
+            told = sp.attrs  # what rank_correct_runs said of its insides
+            acct.add(_refine.PHASE_BUFFERS, told.get("buffers_s", 0.0)
+                     + own.get("buffers_s", 0.0))
+            acct.add(_refine.PHASE_SCORE, told.get("score_s", 0.0),
+                     gather_s=told.get("gather_s", 0.0),
+                     arith_s=told.get("arith_s", 0.0))
+            acct.add(_refine.PHASE_ORDER, told.get("order_s", 0.0))
             n_corrected += n_c
             if dc is not None:
                 d[lo : lo + take] = dc
@@ -2391,9 +2472,13 @@ class ShardedKNN:
             with obs.span("certified.dispatch", trace_id,
                           parent=_CALL_SPAN, h2d_bytes=chunk.nbytes):
                 qp, _ = self._place_queries(chunk)
+                begun = _hooks.first_call_begin()
                 outs.append((qp, _retry_transient(
                     lambda q=qp: prog(q, self._tp, *ops_tail),
                     "pallas dispatch")))
+                acct.launched("certified")
+                _hooks.first_call_end(begun, prog, "certified", trace_id,
+                                      rows=qp.shape[0])
 
         # stage 2: per batch — fetch + repair, in dispatch order
         for (lo, chunk, pad), (qp, packed) in zip(batches, outs):
@@ -2634,7 +2719,7 @@ def _pallas_certified_program(
             aug_slack=aug_slack,
         )
 
-    return jax.jit(
+    prog = jax.jit(
         jax.shard_map(
             spmd,
             mesh=mesh,
@@ -2645,6 +2730,10 @@ def _pallas_certified_program(
             check_vma=False,
         )
     )
+    _hooks.mark_built(
+        prog, f"m={m},k={k},tile={eff_tile},terms={terms},"
+              f"dim_chunk={dim_chunk},precision={precision}")
+    return prog
 
 
 def _tail_specs(precision: str, mesh: Mesh):
@@ -2811,17 +2900,23 @@ def unpack_certified(
 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray, Optional[np.ndarray]]:
     """Host inverse of ``_pallas_certified_program``'s packed output:
     (gi [Q, w] i32, tight [Q, w-1] bool, bad [Q] bool, dk [Q, k] f32 or
-    None)."""
-    arr = np.ascontiguousarray(np.asarray(packed))
+    None).  The innermost span open on the calling thread (the caller's
+    ``certified.unpack``) is told ``copies_s``, the seconds of its two
+    contiguous copies."""
+    copies = {}
+    with obs.trace.phase(copies, "s"):
+        arr = np.ascontiguousarray(np.asarray(packed))
     nw = -(-(w - 1) // 32)
     gi = arr[:, :w]
     tight = unpack_bits_u32(arr[:, w : w + nw].view(np.uint32), w - 1)
     bad = arr[:, w + nw] != 0
     dk = None
     if with_distances:
-        dk = np.ascontiguousarray(
-            arr[:, w + nw + 1 : w + nw + 1 + k]
-        ).view(np.float32)
+        with obs.trace.phase(copies, "s"):
+            dk = np.ascontiguousarray(
+                arr[:, w + nw + 1 : w + nw + 1 + k]
+            ).view(np.float32)
+    obs.current_span().set("copies_s", copies.get("s", 0.0))
     return gi, tight, bad, dk
 
 
@@ -2849,7 +2944,7 @@ def _count_program(mesh: Mesh, n_train: int, train_tile: Optional[int]):
             local = lax.psum(local, dbp if hosts > 1 else DB_AXIS)
         return local
 
-    return jax.jit(
+    prog = jax.jit(
         jax.shard_map(
             spmd,
             mesh=mesh,
@@ -2858,6 +2953,8 @@ def _count_program(mesh: Mesh, n_train: int, train_tile: Optional[int]):
             check_vma=False,
         )
     )
+    _hooks.mark_built(prog, f"tile={tile}")
+    return prog
 
 
 @functools.lru_cache(maxsize=32)
@@ -2882,7 +2979,7 @@ def _range_program(mesh: Mesh, n_train: int, tile: int, width: int):
             counts = lax.psum(counts, dbp if hosts > 1 else DB_AXIS)
         return counts, compact_words(words, width)
 
-    return jax.jit(
+    prog = jax.jit(
         jax.shard_map(
             spmd,
             mesh=mesh,
@@ -2891,6 +2988,8 @@ def _range_program(mesh: Mesh, n_train: int, tile: int, width: int):
             check_vma=False,
         )
     )
+    _hooks.mark_built(prog, f"tile={tile},width={width}")
+    return prog
 
 
 @functools.lru_cache(maxsize=16)

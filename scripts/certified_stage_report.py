@@ -1,14 +1,27 @@
 #!/usr/bin/env python
 """Where a certified batch's time goes, read from the program's own
-tracing (docs/OBSERVABILITY.md "Span lifecycle"), in two sub-commands:
+tracing (docs/OBSERVABILITY.md "Span lifecycle"), in three sub-commands:
 
     python scripts/certified_stage_report.py spans <events.jsonl> [--skip-calls N]
+    python scripts/certified_stage_report.py startup <events.jsonl> [--run-log <the run's output>]
     python scripts/certified_stage_report.py idle <trace dir or .xplane.pb> [--device-plane /device:TPU:N]
 
 ``spans`` reads a ``KNN_TPU_OBS_LOG`` file: per ``certified.*`` stage
 the mean ms a call and a batch, the self time of ``certified.call`` (its
-length less its children's) and the share of it the children cover.
-``--skip-calls`` leaves out the first N calls (a benchmark's warm-up).
+length less its children's) and the share of it the children cover;
+beside them the call's once-a-call account (``account_ms``: the exposed
+seconds, the seconds in flight by device program, the insides of
+``rank_correct`` and ``unpack``, each a mean per CALL whatever the
+sub-batches).  ``--skip-calls`` leaves out the first N calls (a
+benchmark's warm-up).
+
+``startup`` reads the same file for what happened before the first
+measured call: every device program the process built, once, with its
+key, whether the persistent cache answered, and its trace / lower /
+compile / load seconds (``program.first_call.*``), and the placement's
+host passes (``placement.*``).  With ``--run-log`` (a benchmark run's
+output) it lays them against that run's own ``set-up:`` lines and its
+``setup_s``, the remainder as a row of its own.
 
 ``idle`` reads a profiler capture (``obs.profiler.device_trace``, or a
 benchmark run with ``--trace 1``): every moment the device's ``XLA Ops``
@@ -23,7 +36,10 @@ the per-event ones: offsets and durations), so :func:`op_scopes` walks
 the protobuf's wire format for just that.
 
 Prints one JSON object.  The arithmetic (:func:`stage_table`,
-:func:`attribute`) is plain Python over lists, tested on the CPU.
+:func:`startup_table`) is plain Python over lists, tested on the CPU;
+laying gaps against spans and an event's own time are the benchmark's
+(``benchmark/tracereduce.py``), so the two readers of a trace cannot
+disagree.
 """
 
 from __future__ import annotations
@@ -37,7 +53,13 @@ import sys
 from collections import defaultdict
 from typing import Dict, Iterable, List, Sequence, Tuple
 
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark"))
+import tracereduce  # noqa: E402 - benchmark/tracereduce.py, path set above
+
 CALL = "certified.call"
+RANGE_CALL = "certified.range_call"
+FIRST_CALL = "program.first_call."
 PREFIX = "knn."
 DEVICE_PLANE = "/device:TPU:0"
 SCOPE_STAT = "tf_op"
@@ -66,11 +88,24 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
     total: Dict[str, float] = defaultdict(float)
     count: Dict[str, int] = defaultdict(int)
     attrs: Dict[str, float] = defaultdict(float)
+    account: Dict[str, Dict[str, float]] = defaultdict(
+        lambda: defaultdict(float))
     merge: Dict[str, object] = {}
     select: Dict[str, int] = {}
     batches = children = 0.0
     for tid in calls:
         for e in by_tid[tid]:
+            if "account_of" in e:
+                # a sum over the call, recorded once when it ended: not
+                # a stage, and no child of the call's
+                row = account[e["span"]]
+                row["ms"] += 1e3 * e["dur_s"]
+                for key, v in e.items():
+                    if key.endswith("_s") and key != "dur_s":
+                        row[key[:-2] + "_ms"] += 1e3 * v
+                    elif key == "launches":
+                        row[key] += v
+                continue
             total[e["span"]] += e["dur_s"]
             count[e["span"]] += 1
             if e["span"] == CALL:
@@ -100,12 +135,103 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
                    "per_batch": ms(total[name] / batches),
                    "spans": count[name]}
             for name in sorted(total)},
+        "account_ms": {
+            name: {k: round(v / n, 4) for k, v in row.items()}
+            for name, row in sorted(account.items())},
         "call_self_ms_per_call": ms((total[CALL] - children) / n),
         "children_share_of_call": round(children / total[CALL], 5),
         "per_batch": {k: round(v / batches, 3) for k, v in attrs.items()},
         "merge": merge,
         "select": select,
     }
+
+
+# --- startup: what happened before the first measured call ------------------
+_SETUP_LINE = re.compile(
+    r"set-up: (drew|placed|first batch|warmed)\b.*?: ([0-9.]+) s")
+
+
+def run_log_setup(text: str) -> dict:
+    """A benchmark run's own account of its set-up, from its output: the
+    ``set-up:`` lines (``drew``, ``placed``, ``first batch``, ``warmed``:
+    seconds; the last counts from before the first batch) and
+    ``setup_s`` from its result line (an untraced run's)."""
+    out = {key.replace(" ", "_") + "_s": float(sec)
+           for key, sec in _SETUP_LINE.findall(text)}
+    for line in reversed(text.splitlines()):
+        if line.startswith("{") and '"setup_s"' in line:
+            out["setup_s"] = json.loads(line)["metrics"]["setup_s"]["value"]
+            break
+    return out
+
+
+def startup_table(events: Iterable[dict], run: dict = None) -> dict:
+    """Every ``program.first_call.*`` span (one a program object the
+    process built and called) and every ``placement.*`` event of a log,
+    in order; those up to the end of the first outermost call are that
+    call's.  With ``run`` (:func:`run_log_setup`) also ``setup``: the
+    run's set-up seconds by row, the rows of this log beside the run's
+    own lines, what no row covers as ``unaccounted_s``."""
+    programs, placement = [], []
+    first_call_over = False
+    for e in events:
+        if e.get("type") == "event" and str(e.get("name", "")).startswith(
+                "placement."):
+            placement.append({
+                "event": e["name"], "seconds": round(e["seconds"], 4),
+                "in_first_call": not first_call_over,
+                **{k: e[k] for k in ("rows", "dim", "bytes") if k in e}})
+        elif e.get("type") != "span":
+            continue
+        elif e["span"].startswith(FIRST_CALL):
+            hit = e["cache_hits"] > 0 and e["cache_misses"] == 0
+            programs.append({
+                "program": e["program"], "key": e["key"],
+                "seconds": e["dur_s"],
+                "cache": ("hit" if hit else "miss" if e["cache_misses"]
+                          else "off"),
+                "traces": e["traces"], "trace_s": e["trace_s"],
+                "lower_s": e["lower_s"], "compile_s": e["compile_s"],
+                "load_s": e["cache_load_s"],
+                "in_first_call": not first_call_over})
+        elif e["span"] == RANGE_CALL or (
+                e["span"] == CALL and "parent" not in e):
+            first_call_over = True
+    out = {"programs": programs, "placement": placement}
+    if run:
+        def total(rows, first):
+            return sum(r["seconds"] for r in rows
+                       if r["in_first_call"] is first)
+
+        placed = sum(r["seconds"] for r in placement
+                     if r["event"] in ("placement.dot_augment",
+                                       "placement.device_put"))
+        walk = sum(r["seconds"] for r in placement
+                   if r["event"] in ("placement.norm_walk",
+                                     "placement.host_copy"))
+        rows = {
+            "drawn_s": run.get("drew_s", 0.0),
+            "placement_host_passes_s": round(placed, 4),
+            "placed_rest_s (the wait for the transfer)": round(
+                run.get("placed_s", 0.0) - placed, 4),
+            "first_call_host_passes_s (norm walk, host copy)": round(
+                walk, 4),
+            "first_call_programs_s": round(total(programs, True), 4),
+            "first_call_rest_s (its own batch)": round(
+                run.get("first_batch_s", 0.0) - walk
+                - total(programs, True), 4),
+            "later_warmup_programs_s": round(total(programs, False), 4),
+            "later_warmup_rest_s (their batches)": round(
+                run.get("warmed_s", 0.0) - run.get("first_batch_s", 0.0)
+                - total(programs, False), 4),
+        }
+        out["setup"] = {"setup_s": run.get("setup_s"), "rows": rows}
+        if run.get("setup_s"):
+            left = run["setup_s"] - sum(rows.values())
+            out["setup"]["unaccounted_s"] = round(left, 4)
+            out["setup"]["accounted_share"] = round(
+                1 - left / run["setup_s"], 4)
+    return out
 
 
 def read_jsonl(path: str) -> List[dict]:
@@ -146,18 +272,14 @@ def gaps_of(busy: Sequence[Interval], window: Interval) -> List[Interval]:
 def attribute(gaps: Sequence[Interval],
               spans: Sequence[Tuple[str, float, float]]) -> Dict[str, float]:
     """Idle time per span name: every moment of every gap goes to the
-    innermost (shortest) span that covers it, else to ``outside``.
-    ``spans`` are ``(name, start, end)``."""
-    cuts = sorted({t for _, lo, hi in spans for t in (lo, hi)})
-    out: Dict[str, float] = defaultdict(float)
-    for g_lo, g_hi in gaps:
-        edges = [g_lo] + [t for t in cuts if g_lo < t < g_hi] + [g_hi]
-        for lo, hi in zip(edges, edges[1:]):
-            mid = (lo + hi) / 2
-            inner = min((s for s in spans if s[1] <= mid < s[2]),
-                        key=lambda s: s[2] - s[1], default=None)
-            out[inner[0] if inner else "outside"] += hi - lo
-    return dict(out)
+    innermost span that covers it (``tracereduce.attribute``: the
+    shortest, the later start of two equally long), else to
+    ``outside``.  ``spans`` are ``(name, start, end)``."""
+    by = tracereduce.attribute(
+        gaps, [(name, lo, hi - lo) for name, lo, hi in spans])
+    if tracereduce.OUTSIDE in by:
+        by["outside"] = by.pop(tracereduce.OUTSIDE)
+    return by
 
 
 def _varint(buf: bytes, at: int) -> Tuple[int, int]:
@@ -244,7 +366,7 @@ def idle_report(path: str, window_span: str, outer_span: str,
     data = ProfileData.from_serialized_xspace(raw)
     busy: List[Interval] = []
     host: List[Tuple[str, float, float]] = []
-    by_scope: Dict[str, List[Interval]] = defaultdict(list)
+    scoped: List[Tuple[str, float, float]] = []  # (scope, start, end)
     example = None
     for plane in data.planes:
         device = plane.name == device_plane
@@ -254,7 +376,7 @@ def idle_report(path: str, window_span: str, outer_span: str,
                 if device and line.name == "XLA Ops":
                     busy.append((lo, hi))
                     scope = innermost_scope(scopes.get(e.name, ""))
-                    by_scope[scope].append((lo, hi))
+                    scoped.append((scope, lo, hi))
                     if example is None and scope == "knn.final_select":
                         example = {
                             "event": e.name[:120],
@@ -293,10 +415,15 @@ def idle_report(path: str, window_span: str, outer_span: str,
         f"{CALL}_share_of_{outer_span}": round(
             sum(hi - lo for _, lo, hi in calls)
             / sum(hi - lo for _, lo, hi in outers), 5) if outers else None,
+        # each op's OWN time (a loop's body ops lie inside its %while:
+        # their time is theirs, not the loop's too), so the rows sum to
+        # the device's busy time
         "device_ms_per_call_by_scope": {
-            k: round(sum(hi - lo for lo, hi in clipped(v, (lo_w, hi_w)))
-                     / 1e6 / max(len(calls), 1), 4)
-            for k, v in sorted(by_scope.items())},
+            k: round(1e3 * v / max(len(calls), 1), 4)
+            for k, v in sorted(tracereduce._self_times(
+                [(scope, max(lo, lo_w), min(hi, hi_w))
+                 for scope, lo, hi in scoped
+                 if min(hi, hi_w) > max(lo, lo_w)]).items())},
         "scoped_event_example": example,
     }
 
@@ -307,6 +434,9 @@ def main(argv=None) -> int:
     sp = sub.add_parser("spans")
     sp.add_argument("jsonl")
     sp.add_argument("--skip-calls", type=int, default=0)
+    up = sub.add_parser("startup")
+    up.add_argument("jsonl")
+    up.add_argument("--run-log", help="the benchmark run's output")
     ip = sub.add_parser("idle")
     ip.add_argument("trace")
     ip.add_argument("--window-span", default="bench.trace_window")
@@ -316,6 +446,12 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.cmd == "spans":
         out = stage_table(read_jsonl(args.jsonl), args.skip_calls)
+    elif args.cmd == "startup":
+        run = None
+        if args.run_log:
+            with open(args.run_log) as f:
+                run = run_log_setup(f.read())
+        out = startup_table(read_jsonl(args.jsonl), run)
     else:
         out = idle_report(args.trace, args.window_span, args.outer_span,
                           args.device_plane)

@@ -282,9 +282,11 @@ def test_the_cell_runs_through_the_harness_on_four_devices(
             lastline.required_metrics(BENCH, CELL, traced)}
     assert set(out["metrics"]) == want
     if traced:
+        # these are there; a later PR may list more for the cell (its
+        # first host-side metrics)
         assert {"merge_ms", "pallas_knn_shard_roofline", "kernel_ms",
                 "tail_ms", "fallback_pct", "rank_corrected_pct",
-                "idle_pct.sweep"} == want
+                "idle_pct.sweep"} <= want
         assert "pallas_knn_roofline" not in want
     else:
         assert want == {"sweep_qps", "setup_s"}
