@@ -1290,7 +1290,9 @@ def main() -> None:
         _jax.block_until_ready(qp)
         h2d = time.perf_counter() - t0
         # the operand tail is precision-shaped (int8: the quantized
-        # placement; f32: the scalar norm bound) — ONE home,
+        # placement; f32: the scalar norm bound, and at the default
+        # precision the resident row operands the setup above resolved:
+        # ask after it) — ONE home,
         # ShardedKNN._pallas_operands, so this probe can never call the
         # program with the wrong arity
         ops_tail = prog._pallas_operands(KNOBS["precision"])

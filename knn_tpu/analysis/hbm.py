@@ -198,8 +198,61 @@ def plan_join(
     }
 
 
+#: what the largest device program loaded beside RESIDENT row operands
+#: may set aside for its temporaries, as a multiple of one chip's placed
+#: rows (their own bytes, ``nbytes``).  Read from XLA's
+#: ``memory_analysis`` of every program a benchmark cell loads, compiled
+#: for a v5e (the chip's ``bytes_reserved`` read the same numbers to the
+#: megabyte where both were taken: PERF.md section 4, PR 39): the
+#: largest is 2.61, the repair's exact re-select over 2.5M x 201 rows
+#: the device holds column-major (a row-major copy of them, 201 columns
+#: in 256 lanes, and a copy padded to the exact path's tile); 2.19 the
+#: re-select at 1M x 960; 1.64 the certified program there once it is
+#: handed its operands; 0.75 to 1.4 every other.
+ROWS_PROGRAM_TEMP_FACTOR = 2.7
+
+#: the share of the device's memory the resident row operands may fill
+#: with everything else counted: an eighth stays spare, the margin
+#: ``analysis.vmem`` keeps of VMEM
+RESIDENT_FILL = 7 / 8
+
+
+def row_operand_bytes(rows_p: int, dim_p: int, with_lo: bool) -> int:
+    """Bytes ONE chip keeps for the "bf16x3" kernel's resident row
+    operands (``ShardedKNN._row_operands``): the bf16 high half of its
+    ``rows_p x dim_p`` padded rows, the low half ``with_lo``, and the
+    float32 row norms."""
+    return int(rows_p) * (int(dim_p) * 2 * (2 if with_lo else 1) + 4)
+
+
+def resident_operands_fit(form_bytes: int, placed_bytes: int,
+                          memory_stats: dict) -> bool:
+    """Whether one chip has room to KEEP ``form_bytes`` of row operands
+    beside its ``placed_bytes`` of rows, by the device's own
+    ``memory_stats()``: what the client holds there now
+    (``bytes_in_use``: the rows and whatever else the process placed),
+    the form, and the temporaries of the largest device program must
+    fit ``RESIDENT_FILL`` of ``bytes_limit``.  The runtime sets aside
+    ONE region for the loaded programs' temporaries, as large as the
+    largest needs (``bytes_reserved``; it is 0 until a program has
+    run), so the temporaries are that reading or, where it is larger,
+    ``ROWS_PROGRAM_TEMP_FACTOR`` times the placed rows.  A backend that
+    reports no ``bytes_limit`` (the CPU) has no such bound: True."""
+    limit = int(memory_stats.get("bytes_limit") or 0)
+    if not limit:
+        return True
+    held = max(int(memory_stats.get("bytes_in_use") or 0), placed_bytes)
+    temporaries = max(int(memory_stats.get("bytes_reserved") or 0),
+                      int(ROWS_PROGRAM_TEMP_FACTOR * placed_bytes))
+    return held + form_bytes + temporaries <= RESIDENT_FILL * limit
+
+
 __all__ = [
     "AUX_BYTES_PER_ROW",
+    "ROWS_PROGRAM_TEMP_FACTOR",
+    "RESIDENT_FILL",
+    "row_operand_bytes",
+    "resident_operands_fit",
     "placement_bytes",
     "rows_for_budget",
     "plan_segments",

@@ -134,6 +134,7 @@ SELECT_MERGE_CALLS = "knn_tpu_select_merge_calls_total"
 KERNEL_TERMS = "knn_tpu_kernel_terms_total"
 KERNEL_DIM_CHUNKS = "knn_tpu_kernel_dim_chunks_total"
 FINAL_SELECT_CALLS = "knn_tpu_final_select_calls_total"
+KERNEL_OPERANDS = "knn_tpu_kernel_operands_total"
 
 # --- host-RAM shard tier (knn_tpu.parallel.sharded) --------------------
 HOSTTIER_SWEEPS = "knn_tpu_hosttier_sweeps_total"
@@ -311,8 +312,10 @@ CATALOG = {
         "the program's name: 'certified' (the one-pass program), "
         "'reselect' (the repair's widened exact select), 'range' (the "
         "range completion), 'counted' and 'count' (the counted "
-        "selectors' two passes).  Moved once a call, by the call's "
-        "account (obs.trace.CallAccount)."),
+        "selectors' two passes), 'operands' (the build of the "
+        "resident row operands: 1 a placement and geometry, in the "
+        "call that first resolved it).  Moved once a call, by the "
+        "call's account (obs.trace.CallAccount)."),
     TUNING_RESOLVES: (
         "counter", (), "tuning.resolve() invocations."),
     TUNING_CACHE_HITS: (
@@ -463,6 +466,16 @@ CATALOG = {
         "final_select_geometry: an exact final select at a shape it "
         "was timed at), 'xla' lax.top_k and the gather after it (every "
         "other shape, and final_select='approx')."),
+    KERNEL_OPERANDS: (
+        "counter", ("source",),
+        "Batches of search_certified(selector='pallas'), by where their "
+        "kernel's row operands (the bf16 halves of the padded rows and "
+        "the row norms) came from: 'resident' the placement's own, "
+        "built once on the device and handed to the program "
+        "(ShardedKNN._row_operands: the default precision, where "
+        "analysis.hbm.resident_operands_fit finds the device has "
+        "room), 'per_call' the program's prologue, over the whole "
+        "corpus in every call."),
     MERGE_STRAGGLER_GAP: (
         "gauge", (),
         "Max-minus-min per-host local search wall time of the last "

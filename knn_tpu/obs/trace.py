@@ -50,7 +50,7 @@ import threading
 import time
 import types
 import uuid
-from collections import deque
+from collections import defaultdict, deque
 from typing import Optional
 
 from knn_tpu.obs import ident, names, registry
@@ -316,10 +316,12 @@ class CallAccount:
     parts (a stage's phases over the sub-batches), for the same
     once-a-call record.  :meth:`close` records everything through
     :func:`record_span`: ``<root>.exposed``, ``<root>.inflight.<program>``
-    for every program named at construction, which are the programs the
-    call may launch (0.0 where one did not run, so a reader never finds
-    a series missing), one span for every summed
-    piece, and ``knn_tpu_program_launches_total``."""
+    for every program named at construction, which are the programs
+    any call of its kind may launch (0.0 where one did not run, so a
+    reader never finds a series missing) and for any other the call did
+    launch (a placement's one-time build: in the call that made it and
+    in no other), one span for every summed piece, and
+    ``knn_tpu_program_launches_total``."""
 
     __slots__ = ("root", "_t0", "_idle_from", "_busy_from", "_out",
                  "_n_out", "_since", "inflight", "launches", "exposed",
@@ -329,11 +331,12 @@ class CallAccount:
         self.root = root
         self._t0 = self._idle_from = time.perf_counter()
         self._busy_from = 0.0
-        self._out = dict.fromkeys(programs, 0)  # outstanding, by program
+        # outstanding, by program
+        self._out = defaultdict(int, dict.fromkeys(programs, 0))
         self._n_out = 0  # outstanding, all programs
         self._since = {}
-        self.inflight = dict.fromkeys(programs, 0.0)
-        self.launches = dict.fromkeys(programs, 0)
+        self.inflight = defaultdict(float, dict.fromkeys(programs, 0.0))
+        self.launches = defaultdict(int, dict.fromkeys(programs, 0))
         self.exposed = self.union = self.between = 0.0
         self.before_first = None
         self.sums = dict.fromkeys(pieces, 0.0)

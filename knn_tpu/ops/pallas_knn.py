@@ -336,7 +336,7 @@ RANK_SLACK = 2.0 ** -18
 #: stage without leaning on names XLA numbers (``%fusion.2``).  The
 #: fifth, ``knn.certify_pack``, and the cross-shard ``knn.merge`` inside
 #: it are parallel.sharded's.
-SCOPE_OPERAND_PREP = "knn.operand_prep"  # per-call row pad + bf16 split
+SCOPE_OPERAND_PREP = "knn.operand_prep"  # row pad + bf16 split + norms
 SCOPE_KERNEL = "knn.kernel"              # the _bin_candidates call
 SCOPE_FINAL_SELECT = "knn.final_select"  # top-(m+2) over the candidates
 SCOPE_SELECT_MERGE = "knn.select_merge"  # its bin-merge (inside the above)
@@ -1000,6 +1000,44 @@ def _pad_axis(x, multiple: int, axis: int, fill: float = 0.0):
     return pad_to_multiple(x, multiple, axis, fill=fill)[0]
 
 
+def _pad_rows(db: jax.Array, tile_n: int) -> jax.Array:
+    """The rows as the kernel tiles them: float32, PAD_VAL rows up to a
+    whole tile, zero columns up to whole dim chunks."""
+    with jax.named_scope(SCOPE_OPERAND_PREP):
+        db = _pad_axis(db.astype(jnp.float32), tile_n, 0, fill=PAD_VAL)
+        return _pad_axis(db, DIM_CHUNK, 1)
+
+
+def _split_rows(db: jax.Array, with_lo: bool) -> Tuple[jax.Array, ...]:
+    """The bf16 halves of the padded rows the kernel streams: the high
+    half, and with ``with_lo`` the bf16 of what the cast left."""
+    with jax.named_scope(SCOPE_OPERAND_PREP):
+        th = db.astype(jnp.bfloat16)
+        if not with_lo:
+            return (th,)
+        return th, (db - th.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _row_norms(db: jax.Array) -> jax.Array:
+    """Full-dim float32 squared norms of the padded rows."""
+    with jax.named_scope(SCOPE_OPERAND_PREP):
+        return jnp.sum(db * db, axis=-1)
+
+
+def row_operands(db: jax.Array, *, tile_n: int,
+                 with_lo: bool) -> Tuple[jax.Array, ...]:
+    """``(th[, tl], norms)`` of one db (shard): the row operands the
+    "bf16x3" kernel streams at tile ``tile_n``, by the very pieces
+    :func:`_bin_candidates` forms them from in every call that hands it
+    none (ONE arithmetic, so a caller that keeps them beside the rows
+    and passes them as ``db_prepared`` gets that call's outputs bit for
+    bit).  ``th`` and ``tl`` are bf16 ``[rows_p, dim_p]``, ``norms`` f32
+    ``[rows_p]``; ``with_lo`` is whether ``"hl"`` is among the launch's
+    ``terms`` (the rows' low half is streamed at all)."""
+    db = _pad_rows(db, tile_n)
+    return (*_split_rows(db, with_lo), _row_norms(db))
+
+
 @functools.partial(
     jax.jit, static_argnames=("block_q", "tile_n", "survivors",
                               "precision", "interpret", "grid_order",
@@ -1023,6 +1061,7 @@ def _bin_candidates(
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
     terms: str = BF16X3_TERMS[0],
     dim_chunk: Optional[int] = None,
+    db_prepared: Optional[Tuple[jax.Array, ...]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Kernel launch on padded shapes.  Returns
 
@@ -1072,15 +1111,32 @@ def _bin_candidates(
     outputs are bitwise-identical across ``kernel`` where the width is
     the same (up to 128 columns, where the rule keeps 128, and wherever
     one width is handed to all three); across widths they agree to the
-    f32 accumulation order (``kernel_tolerance`` covers it)."""
+    f32 accumulation order (``kernel_tolerance`` covers it).
+
+    ``db_prepared`` ("bf16x3" only) is :func:`row_operands` of ``db`` at
+    this ``tile_n`` and these ``terms``, made once by a caller whose
+    rows stay (ShardedKNN's resident placement): the launch streams them
+    and forms nothing of the corpus's size, no padded copy, no cast, no
+    norms.  ``None`` forms them here, as every call always did."""
     queries = _pad_axis(queries.astype(jnp.float32), block_q, 0)
     queries = _pad_axis(queries, DIM_CHUNK, 1)
     n_rows = db.shape[0]
-    with jax.named_scope(SCOPE_OPERAND_PREP):
-        db = _pad_axis(db.astype(jnp.float32), tile_n, 0, fill=PAD_VAL)
-        db = _pad_axis(db, DIM_CHUNK, 1)
     qp, dim = queries.shape
-    n_tiles = db.shape[0] // tile_n
+    if db_prepared is None:
+        db = _pad_rows(db, tile_n)
+        rows_p = db.shape[0]
+    else:
+        *row_parts, row_norms = db_prepared
+        rows_p = _round_up(n_rows, tile_n)
+        want = [(rows_p, dim)] * (1 + ("hl" in terms)) + [(rows_p,)]
+        if precision != "bf16x3" or [
+                x.shape for x in db_prepared] != want:
+            raise ValueError(
+                f"db_prepared of shapes {[x.shape for x in db_prepared]} "
+                f"is not row_operands of {n_rows} rows at tile_n={tile_n}, "
+                f"terms={terms!r} ({want}), or precision={precision!r} "
+                f"streams no such operands")
+    n_tiles = rows_p // tile_n
     _, survivors, out_w, bound_w = _geometry(tile_n, survivors)
 
     if precision not in PRECISIONS:
@@ -1133,14 +1189,13 @@ def _bin_candidates(
     if precision in ("bf16x3", "bf16x3f"):
         # the high/low split of the db happens ONCE in XLA; the kernel
         # streams bf16 tiles and never re-derives them per query block
-        with jax.named_scope(SCOPE_OPERAND_PREP):
-            th = db.astype(jnp.bfloat16)
-            if "hl" in terms:
-                tl = (db - th.astype(jnp.float32)).astype(jnp.bfloat16)
+        if db_prepared is None:
+            row_parts = _split_rows(db, "hl" in terms)
         if precision == "bf16x3":
-            db_inputs = [th, tl] if "hl" in terms else [th]
+            db_inputs = list(row_parts)
             chunk_w = dim_chunk
         else:
+            th, tl = row_parts
             # per dim chunk c the fused contraction reads [th_c|tl_c|th_c]
             th3 = th.reshape(db.shape[0], nd, dim_chunk)
             tl3 = tl.reshape(db.shape[0], nd, dim_chunk)
@@ -1230,10 +1285,10 @@ def _bin_candidates(
     else:
         # full-dim db row norms, f32, broadcast to 8 sublanes so the
         # kernel reads them as a lane-major [8, tile_n] block
+        if db_prepared is None:
+            row_norms = _row_norms(db)
         with jax.named_scope(SCOPE_OPERAND_PREP):
-            tnorm = jnp.broadcast_to(
-                jnp.sum(db * db, axis=-1)[None, :], (8, db.shape[0])
-            )
+            tnorm = jnp.broadcast_to(row_norms[None, :], (8, rows_p))
     out_shape = [
         jax.ShapeDtypeStruct((qp, n_tiles * out_w), jnp.float32),
         jax.ShapeDtypeStruct((qp, n_tiles * out_w), jnp.int32),
@@ -1416,6 +1471,7 @@ def local_certified_candidates(
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
     terms: str = BF16X3_TERMS[0],
     dim_chunk: Optional[int] = None,
+    db_prepared: Optional[Tuple[jax.Array, ...]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The whole device-side certified coarse pass against one db (shard):
 
@@ -1455,7 +1511,9 @@ def local_certified_candidates(
     arm follows the same contract (``db_pq`` plugs its placement in);
     the rescore's precision-independence is what makes ALL quantized arms bitwise-equal to the exact reference
     whenever their candidates cover the true top-k — and certified
-    fallback material otherwise."""
+    fallback material otherwise.  ``db_prepared`` likewise plugs in the
+    "bf16x3" kernel's row operands kept beside ``t``
+    (:func:`row_operands`): stage 1 then reads nothing of ``t``."""
     if interpret is None:
         interpret = not default_backend_is_tpu()
     cd, ci, bounds = local_coarse_candidates(
@@ -1463,7 +1521,7 @@ def local_certified_candidates(
         precision=precision, interpret=interpret,
         final_select=final_select, grid_order=grid_order, kernel=kernel,
         db_int8=db_int8, offset=offset, db_pq=db_pq, terms=terms,
-        dim_chunk=dim_chunk,
+        dim_chunk=dim_chunk, db_prepared=db_prepared,
     )
     return local_select_rescore(
         q, t, cd, ci, bounds, m, final_select=final_select,
@@ -1496,13 +1554,15 @@ def local_coarse_candidates(
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
     terms: str = BF16X3_TERMS[0],
     dim_chunk: Optional[int] = None,
+    db_prepared: Optional[Tuple[jax.Array, ...]] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Stage 1 of :func:`local_certified_candidates` — the db-streaming
     coarse pass alone: resolve the effective tile, launch the kernel,
     trim the query padding.  Returns the packed candidates
     ``(cd [Q, W], ci [Q, W], bounds [Q, T*B])``; stage 2
     (:func:`local_select_rescore`) is everything after the kernel.
-    ``dim_chunk`` goes to the kernel as given (``_bin_candidates``)."""
+    ``dim_chunk`` and ``db_prepared`` go to the kernel as given
+    (``_bin_candidates``)."""
     if interpret is None:
         interpret = not default_backend_is_tpu()
     if final_select not in ("exact", "approx"):
@@ -1527,6 +1587,7 @@ def local_coarse_candidates(
             grid_order=grid_order, kernel=kernel, db_int8=db_int8,
             offset=offset, keep=m + 2 if kernel == "fused" else None,
             db_pq=db_pq, terms=terms, dim_chunk=dim_chunk,
+            db_prepared=db_prepared,
         )
     n_q = q.shape[0]
     return cd[:n_q], ci[:n_q], bounds[:n_q]

@@ -668,6 +668,11 @@ class ShardedKNN:
         #: the pq arm's placements, same lazy discipline, keyed by the
         #: (dsub, ncodes) codebook geometry so two grids can coexist
         self._pq_cache: dict = {}
+        #: the default precision's placement, same lazy discipline: the
+        #: "bf16x3" kernel's row operands at ONE (tile, low half wanted)
+        #: geometry at a time, ``parts`` None where the device had no
+        #: room for them (_row_operands)
+        self._operands_cache: Optional[dict] = None
         db_shards = hosts * chips
         pre_placed = (
             isinstance(train, jax.Array)
@@ -867,6 +872,10 @@ class ShardedKNN:
         # what runs the last program's final top-(m+2) on each shard:
         # "pallas" or "xla" (_pallas_setup)
         self._final_select_stage = "xla"
+        # where the last program's kernel gets its row operands:
+        # "resident" (the placement's, _row_operands) or "per_call"
+        # (formed in the program, every call) (_pallas_setup)
+        self._operands_source = "per_call"
         #: lazily built serving engines, keyed by ladder spec
         #: (buckets, min_bucket, max_bucket) — search_bucketed; the lock
         #: keeps concurrent cold calls from double-building an engine
@@ -1460,13 +1469,96 @@ class ShardedKNN:
                 }
         return self._pq_cache[key]
 
+    def _row_operands(self, tile: int, with_lo: bool, *,
+                      trace_id: Optional[str] = None,
+                      acct=obs.trace.NOOP_ACCOUNT,
+                      memory_stats: Optional[dict] = None,
+                      ) -> Optional[tuple]:
+        """The "bf16x3" kernel's row operands as a RESIDENT placement:
+        ``(th[, tl], norms)`` of every shard's rows at the resolved tile
+        ``tile`` (ops.pallas_knn.row_operands: the bf16 high half, the
+        low half where ``with_lo``, the float32 row norms as a vector),
+        each db-sharded as the rows are, or ``None`` where the device
+        has no room to keep them and the program forms them in every
+        call as it always did.
+
+        Built LAZILY, the first time a certified call resolves this
+        geometry (inside ``certified.prepare``, so in a caller's warm-up
+        and never once the geometry stands), ON THE DEVICE from the
+        placed rows by one small program (``_row_operands_program``: the
+        kernel prologue's own operations, so every operand is that
+        prologue's bit for bit).  The rows never change while the
+        placement lives, so neither do these.  ONE form at a time: a
+        call that resolves another geometry drops the old form before
+        it builds anew.  The f32 placement (``self._tp``) stays: the
+        rescore gather, the repair's re-select and the range completion
+        read it.
+
+        Whether they are kept is read off the device, by no knob
+        (analysis.hbm.resident_operands_fit over the device's own
+        ``memory_stats()`` — ``memory_stats`` stands in for that reading
+        where a test is to tell the rule the device is full; the first
+        addressable chip's, and under several processes its
+        ``bytes_limit`` alone, so that all of them decide alike), and
+        decided once a geometry: the decision is cached with the form.
+        The build is the call account's program ``operands`` and one
+        ``placement.operands`` event."""
+        key = (int(tile), bool(with_lo))
+        held = self._operands_cache
+        if held is not None and held["key"] == key:
+            return held["parts"]
+        from knn_tpu.analysis import hbm
+        from knn_tpu.ops.pallas_knn import DIM_CHUNK
+
+        with self._engines_lock:
+            held = self._operands_cache
+            if held is not None and held["key"] == key:
+                return held["parts"]
+            self._operands_cache = None  # the old form goes first
+            shards = self.db_shards
+            rows_p = -(-self._shard_rows() // tile) * tile
+            dim_p = -(-self._tp.shape[1] // DIM_CHUNK) * DIM_CHUNK
+            form_bytes = hbm.row_operand_bytes(rows_p, dim_p, with_lo)
+            if memory_stats is None:
+                dev = self._tp.addressable_shards[0].device
+                memory_stats = dev.memory_stats() or {}
+                if jax.process_count() > 1:
+                    # the build and the program are collective, so every
+                    # process must decide alike: only what equal chips
+                    # read equal counts
+                    memory_stats = {
+                        "bytes_limit": memory_stats.get("bytes_limit")}
+            parts = None
+            if hbm.resident_operands_fit(
+                    form_bytes, self._tp.nbytes // shards, memory_stats):
+                build = _row_operands_program(self.mesh, *key)
+                begun = _hooks.first_call_begin()
+                parts = build(self._tp)
+                acct.launched("operands")
+                _hooks.first_call_end(begun, build, "operands", trace_id,
+                                      rows=int(self._tp.shape[0]))
+                # seconds: the wait for the pass itself (its program's
+                # first call is the record above)
+                t0 = time.perf_counter()
+                jax.block_until_ready(parts)
+                acct.ready("operands")
+                obs.emit_event(
+                    "placement.operands", rows=rows_p * shards,
+                    tile=key[0], parts="th+tl" if with_lo else "th",
+                    bytes=form_bytes * shards,
+                    seconds=time.perf_counter() - t0)
+            self._operands_cache = {"key": key, "parts": parts}
+        return parts
+
     def _pallas_operands(self, precision: str) -> tuple:
         """The operand tail of the pallas certified program after
         ``(queries, db)`` — ONE home shared by :meth:`_certify_pallas`
         and bench.py's phase breakdown so neither can call the program
         with the wrong arity: int8 passes the quantized placement;
         pq passes (codes, codebooks, consts);
-        the f32 precisions pass the scalar db-norm bound; an
+        the f32 precisions pass the scalar db-norm bound, and "bf16x3"
+        after it the resident row operands where the program
+        :meth:`_pallas_setup` last built takes them (ask after it); an
         inner-product placement appends its augmentation slack to any
         of them."""
         if precision == "int8":
@@ -1478,6 +1570,8 @@ class ShardedKNN:
             tail = (plq["codes"], plq["books"], plq["consts"])
         else:
             tail = (np.float32(self._db_norm_max()),)
+            if precision == "bf16x3" and self._operands_source == "resident":
+                tail += self._operands_cache["parts"]
         if self._dot_aug:
             # _certify_pack_spmd's aug_slack, rounded up to float32
             tail += (np.nextafter(np.float32(self._dot_slack()),
@@ -1707,7 +1801,8 @@ class ShardedKNN:
                     device_d = return_distances and not dot
                     prog, m_prog, w, interpret = self._pallas_setup(
                         m - self.k, include_distances=device_d,
-                        terms=terms, batch_rows=bs, **knobs)
+                        terms=terms, batch_rows=bs, trace_id=tid,
+                        acct=acct, **knobs)
                     ops_tail = self._pallas_operands(knobs["precision"])
             call.set("queries", n_q)
             call.set("batches", len(batches))
@@ -1814,6 +1909,12 @@ class ShardedKNN:
                 obs.counter(_mn.FINAL_SELECT_CALLS,
                             stage=self._final_select_stage).inc(
                     len(batches))
+                # where the kernel got its row operands: the resident
+                # placement, or the program's own prologue in every call
+                # (_row_operands)
+                merged["operands"] = self._operands_source
+                obs.counter(_mn.KERNEL_OPERANDS,
+                            source=self._operands_source).inc(len(batches))
             for key, value in merged.items():
                 call.set(key, value)
             stats = {
@@ -1831,7 +1932,8 @@ class ShardedKNN:
                     "mxu_passes": merged["mxu_passes"],
                     "dim_chunk": merged["dim_chunk"],
                     "dim_chunks": merged["dim_chunks"],
-                    "final_select_stage": merged["final_select_stage"]}
+                    "final_select_stage": merged["final_select_stage"],
+                    "operands": merged["operands"]}
                 stats["tuning"] = tune_info
             # mirror the quality signals into the telemetry registry —
             # the per-call stats dict stays the API, the registry
@@ -2277,7 +2379,9 @@ class ShardedKNN:
                       grid_order: str = "query_major",
                       kernel: str = "tiled",
                       terms: str = "hh+hl+lh",
-                      batch_rows: Optional[int] = None):
+                      batch_rows: Optional[int] = None,
+                      trace_id: Optional[str] = None,
+                      acct=obs.trace.NOOP_ACCOUNT):
         """(program, m, analysis_window, interpret) for the one-pass
         certified path — the ONE home of the kernel-geometry margin cap
         and the packed-output window, shared by :meth:`_certify_pallas`
@@ -2298,7 +2402,14 @@ class ShardedKNN:
         out, a full ``block_q`` — and the parts ``terms`` streams), and
         handed to the program as the kernel's static ``dim_chunk``: what
         ``search_certified`` reports (``self._dim_chunking``) is what the
-        kernel was given, not a second reading of the shape."""
+        kernel was given, not a second reading of the shape.
+
+        The default precision's row operands are resolved HERE as well
+        (:meth:`_row_operands` at the resolved tile and ``terms``; the
+        first resolution builds them, under the caller's ``trace_id``
+        and call account ``acct``): the program is built to take them
+        as arguments where they are kept, :meth:`_pallas_operands` then
+        hands them over, and ``self._operands_source`` says which."""
         from knn_tpu.ops.pallas_knn import (
             BLOCK_Q,
             TILE_N,
@@ -2360,6 +2471,9 @@ class ShardedKNN:
             self._tp.shape[1], tile_n=eff_tile, block_q=bq,
             precision=precision, kernel=kernel, terms=terms,
             survivors=survivors)
+        resident = precision == "bf16x3" and self._row_operands(
+            eff_tile, "hl" in terms, trace_id=trace_id, acct=acct)
+        self._operands_source = "resident" if resident else "per_call"
         # the program gets setup's RESOLVED tile, not the raw request:
         # m was capped so that width(eff_tile) >= m+2, which makes the
         # kernel's own effective_tile(min_width=m+2) a fixpoint — the
@@ -2376,6 +2490,7 @@ class ShardedKNN:
             quant_offset=quant_offset, dcn_merge=self.dcn_merge,
             interpret=interpret, terms=terms, augmented=self._dot_aug,
             dim_chunk=self._dim_chunking[0],
+            resident_parts=len(resident) - 1 if resident else 0,
         )
         return prog, m, _analysis_window(self.k, m), interpret
 
@@ -2636,6 +2751,7 @@ def _pallas_certified_program(
     terms: str = "hh+hl+lh",
     augmented: bool = False,
     dim_chunk: Optional[int] = None,
+    resident_parts: int = 0,
 ):
     """ONE-pass sharded self-certifying coarse select + device rank +
     device certificate (ops.pallas_knn.local_certified_candidates per
@@ -2683,7 +2799,15 @@ def _pallas_certified_program(
     the program is the one it always was, operation for operation.
 
     ``dim_chunk`` is the kernel's static of that name, ``_pallas_setup``'s
-    resolution (None: the kernel reads its own launch's shape)."""
+    resolution (None: the kernel reads its own launch's shape).
+
+    ``resident_parts`` ("bf16x3"; never a caller's choice either) is how
+    many bf16 halves of the rows the operand tail carries after the
+    scalar, the row norms after them (``ShardedKNN._row_operands``: 1 =
+    ``th``, 2 = ``th`` and ``tl``), each db-sharded: the kernel streams
+    them and the program forms nothing of the corpus's size.  0: the
+    kernel's prologue forms them in every call, the program it always
+    was."""
     from knn_tpu.ops.pallas_knn import (
         BLOCK_Q,
         TILE_N,
@@ -2699,7 +2823,7 @@ def _pallas_certified_program(
         aug_slack = None
         if augmented:
             *tail, aug_slack = tail
-        db_q, db_pq, consts, db_norm_max = _split_operand_tail(
+        db_q, db_pq, consts, db_norm_max, db_rows = _split_operand_tail(
             precision, tail)
         d32, li, lb = local_certified_candidates(
             q, t, m, tile_n=eff_tile, survivors=survivors,
@@ -2707,7 +2831,7 @@ def _pallas_certified_program(
             final_recall_target=final_recall_target,
             grid_order=grid_order, kernel=kernel, interpret=interpret,
             db_int8=db_q, db_pq=db_pq, offset=quant_offset, terms=terms,
-            dim_chunk=dim_chunk,
+            dim_chunk=dim_chunk, db_prepared=db_rows,
         )
         return _certify_pack_spmd(
             q, t, d32, li, lb, consts=consts, db_norm_max=db_norm_max,
@@ -2724,7 +2848,7 @@ def _pallas_certified_program(
             spmd,
             mesh=mesh,
             in_specs=(P(QUERY_AXIS), P(db_axes(mesh)),
-                      *_tail_specs(precision, mesh),
+                      *_tail_specs(precision, mesh, resident_parts),
                       *((P(),) if augmented else ())),
             out_specs=P(QUERY_AXIS),
             check_vma=False,
@@ -2732,37 +2856,68 @@ def _pallas_certified_program(
     )
     _hooks.mark_built(
         prog, f"m={m},k={k},tile={eff_tile},terms={terms},"
-              f"dim_chunk={dim_chunk},precision={precision}")
+              f"dim_chunk={dim_chunk},precision={precision},"
+              f"operands={'resident' if resident_parts else 'per_call'}")
     return prog
 
 
-def _tail_specs(precision: str, mesh: Mesh):
+def _tail_specs(precision: str, mesh: Mesh, resident_parts: int = 0):
     """shard_map in_specs of the precision-shaped operand tail
     (ShardedKNN._pallas_operands): int8 = the quantized placement
     (db-sharded values/scales/norms + replicated bound consts), pq =
     db-sharded codes + replicated codebooks + replicated per-subspace
-    bound consts, f32 = the replicated scalar db-norm bound."""
+    bound consts, f32 = the replicated scalar db-norm bound, and after
+    it the resident row operands where the program takes them
+    (``resident_parts`` db-sharded bf16 halves and the row norms)."""
     dbp = db_axes(mesh)
     if precision == "int8":
         return (P(dbp), P(dbp), P(dbp), P())
     if precision == "pq":
         return (P(dbp), P(), P())
-    return (P(),)
+    return (P(),) + (P(dbp),) * (resident_parts + 1 if resident_parts
+                                 else 0)
 
 
 def _split_operand_tail(precision: str, tail):
-    """(db_quant, db_pq, consts, db_norm_max) from the operand tail —
-    the per-precision unpacking every pallas-certified program shares.
-    ``db_quant`` is the (values, scales, norms) triple of the int8 arm;
-    ``db_pq`` is (codes, codebooks)."""
+    """(db_quant, db_pq, consts, db_norm_max, db_rows) from the operand
+    tail — the per-precision unpacking every pallas-certified program
+    shares.  ``db_quant`` is the (values, scales, norms) triple of the
+    int8 arm; ``db_pq`` is (codes, codebooks); ``db_rows`` is the
+    resident ``(th[, tl], norms)`` of the default precision, None where
+    the tail carries none."""
     if precision == "int8":
         tq, ts, tnr, consts = tail
-        return (tq, ts, tnr), None, consts, None
+        return (tq, ts, tnr), None, consts, None, None
     if precision == "pq":
         codes, books, consts = tail
-        return None, (codes, books), consts, None
-    (db_norm_max,) = tail
-    return None, None, None, db_norm_max
+        return None, (codes, books), consts, None, None
+    db_norm_max, *db_rows = tail
+    return None, None, None, db_norm_max, tuple(db_rows) or None
+
+
+@functools.lru_cache(maxsize=8)
+def _row_operands_program(mesh: Mesh, tile: int, with_lo: bool):
+    """The program that builds ``ShardedKNN._row_operands``' form, once
+    a placement and geometry: every shard runs the kernel prologue's own
+    operations on its rows (ops.pallas_knn.row_operands: pad to the
+    tile with PAD_VAL rows and to whole dim chunks, the bf16 split, the
+    norms), so each shard pads its OWN rows and the outputs are
+    db-sharded as the rows are."""
+    from knn_tpu.ops.pallas_knn import row_operands
+
+    dbp = db_axes(mesh)
+    prog = jax.jit(
+        jax.shard_map(
+            functools.partial(row_operands, tile_n=tile, with_lo=with_lo),
+            mesh=mesh,
+            in_specs=P(dbp),
+            out_specs=(P(dbp),) * (2 + with_lo),
+            check_vma=False,
+        )
+    )
+    _hooks.mark_built(
+        prog, f"tile={tile},parts={'th+tl' if with_lo else 'th'}")
+    return prog
 
 
 #: device scope of the certify/pack tail; its four siblings (operand

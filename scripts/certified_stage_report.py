@@ -180,7 +180,8 @@ def startup_table(events: Iterable[dict], run: dict = None) -> dict:
             placement.append({
                 "event": e["name"], "seconds": round(e["seconds"], 4),
                 "in_first_call": not first_call_over,
-                **{k: e[k] for k in ("rows", "dim", "bytes") if k in e}})
+                **{k: e[k] for k in ("rows", "dim", "bytes", "tile", "parts")
+                   if k in e}})
         elif e.get("type") != "span":
             continue
         elif e["span"].startswith(FIRST_CALL):
@@ -209,6 +210,8 @@ def startup_table(events: Iterable[dict], run: dict = None) -> dict:
         walk = sum(r["seconds"] for r in placement
                    if r["event"] in ("placement.norm_walk",
                                      "placement.host_copy"))
+        built = sum(r["seconds"] for r in placement
+                    if r["event"] == "placement.operands")
         rows = {
             "drawn_s": run.get("drew_s", 0.0),
             "placement_host_passes_s": round(placed, 4),
@@ -216,9 +219,11 @@ def startup_table(events: Iterable[dict], run: dict = None) -> dict:
                 run.get("placed_s", 0.0) - placed, 4),
             "first_call_host_passes_s (norm walk, host copy)": round(
                 walk, 4),
+            "first_call_operands_s (the device's pass that builds the "
+            "resident row operands)": round(built, 4),
             "first_call_programs_s": round(total(programs, True), 4),
             "first_call_rest_s (its own batch)": round(
-                run.get("first_batch_s", 0.0) - walk
+                run.get("first_batch_s", 0.0) - walk - built
                 - total(programs, True), 4),
             "later_warmup_programs_s": round(total(programs, False), 4),
             "later_warmup_rest_s (their batches)": round(

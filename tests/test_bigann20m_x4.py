@@ -137,8 +137,8 @@ def certified_hlo(db_shards: int, merge):
     db, q = corpus("ragged_8d_0to7")
     prog = ShardedKNN(db, mesh=mesh(db_shards), k=K, merge=merge)
     qp, _ = prog._place_queries(q)
-    tail = prog._pallas_operands("bf16x3")
     one, _, _, _ = prog._pallas_setup(28, None, "bf16x3")
+    tail = prog._pallas_operands("bf16x3")  # of the program just set up
     return one.lower(qp, prog._tp, *tail).compile().as_text()
 
 

@@ -280,11 +280,11 @@ def lowered(placed, corpus):
     the grid-tiled one the cells run and the one-launch one whose chip
     time is still to be taken (a trace splits either by these scopes)."""
     qp, _ = placed._place_queries(corpus[1])
-    tail = placed._pallas_operands("bf16x3")
     out = {}
     for kernel in ("tiled", "streaming"):
         prog, _, _, _ = placed._pallas_setup(28, None, "bf16x3",
                                              kernel=kernel)
+        tail = placed._pallas_operands("bf16x3")  # of that program
         out[kernel] = prog.lower(qp, placed._tp, *tail).as_text(
             debug_info=True)
     return out
@@ -465,7 +465,9 @@ def _holds_the_identity(exposed, inflight, call):
         exposed["call_s"], abs=1e-6)
     assert exposed["before_first_launch_s"] + exposed["between_s"] + exposed[
         "after_last_ready_s"] == pytest.approx(exposed["dur_s"], abs=2e-6)
-    assert 0 <= call["dur_s"] - exposed["call_s"] < 5e-3
+    # (a few lines of Python; on a loaded machine a descheduled thread
+    # makes them milliseconds)
+    assert 0 <= call["dur_s"] - exposed["call_s"] < 5e-2
     # programs in flight one after another: the union is their sum
     assert sum(e["dur_s"] for e in inflight.values()) == pytest.approx(
         exposed["inflight_union_s"], abs=1e-5)
@@ -659,35 +661,59 @@ def _first_calls(program=None):
 
 
 def test_a_programs_first_call_is_recorded_once(corpus):
-    # a k no other test of this process places: the builder's cache
-    # misses, so this process has not called the program yet
-    prog = ShardedKNN(corpus[0], mesh=make_mesh(1, 1), k=K + 3)
+    # a k and a row count no other test of this process places: both
+    # builders' caches miss, so this process has called neither the
+    # certified program nor the one that builds the resident row operands
+    db = corpus[0][:2900]
+    prog = ShardedKNN(db, mesh=make_mesh(1, 1), k=K + 3)
     prog.search_certified(corpus[1], selector="pallas")
     (first,) = _first_calls("certified")
+    (built,) = _first_calls("operands")
     assert first["span"] == "program.first_call.certified"
-    assert first["traces"] >= 1 and first["backend_compiles"] >= 1
-    assert first["trace_s"] > 0 and first["compile_s"] > 0
-    # each event's OWN seconds: together at most the bracket
-    assert first["trace_s"] + first["lower_s"] + first[
-        "compile_s"] <= first["dur_s"] + 1e-3
+    assert built["span"] == "program.first_call.operands"
+    for rec in (first, built):
+        assert rec["traces"] >= 1 and rec["backend_compiles"] >= 1
+        # each event's OWN seconds, the change over the bracket: none is
+        # longer than the bracket, and lowering and the backend's compile,
+        # which follow one another, are together at most the bracket.
+        # Tracing is not added to them: the interpreted kernels are traced
+        # WHILE the program is lowered, JAX counts those seconds under
+        # both events, and on a loaded machine the overlap outgrows what
+        # the bracket holds besides (the launch itself)
+        for own in ("trace_s", "lower_s", "compile_s"):
+            assert 0 < rec[own] <= rec["dur_s"] + 1e-3
+        assert rec["lower_s"] + rec["compile_s"] <= rec["dur_s"] + 1e-3
+        # the suite runs with the persistent cache off
+        assert (rec["cache_hits"], rec["cache_misses"]) == (0, 0)
     assert f"k={K + 3}," in first["key"] and "terms=" in first["key"]
+    assert "operands=resident" in first["key"]
     assert first["key"].endswith(f",rows={N_QUERIES}")
-    # the suite runs with the persistent cache off
-    assert (first["cache_hits"], first["cache_misses"]) == (0, 0)
-    (dispatch,) = [e for e in _spans() if e["span"] == "certified.dispatch"]
-    assert first["dur_s"] <= dispatch["dur_s"]
-    assert first["trace_id"] == dispatch["trace_id"]
+    tile = prog._operands_cache["key"][0]
+    assert built["key"] == f"tile={tile},parts=th+tl,rows=2900"
+    # each launch has its own bracket, the build's inside the prepare
+    # stage and the program's inside the dispatch: the program's record
+    # holds none of the build's compile
+    by = {e["span"]: e for e in _spans()}
+    assert built["dur_s"] <= by["certified.prepare"]["dur_s"]
+    assert first["dur_s"] <= by["certified.dispatch"]["dur_s"]
+    assert built["dur_s"] + first["dur_s"] <= by["certified.call"]["dur_s"]
+    assert built["trace_id"] == first["trace_id"] == by[
+        "certified.dispatch"]["trace_id"]
     # in the registry as any span is
     series = {s["labels"]["span"] for s in
               obs.snapshot()[mn.SPAN_SECONDS]["series"]}
-    assert "program.first_call.certified" in series
+    assert {"program.first_call.certified",
+            "program.first_call.operands"} <= series
     obs.reset_event_log(None)
     prog.search_certified(corpus[1], selector="pallas")
     assert _first_calls() == []
-    # another placement of the same shape is handed the same program
-    again = ShardedKNN(corpus[0], mesh=make_mesh(1, 1), k=K + 3)
+    # another placement of the same shape is handed the same programs:
+    # it builds its own operands (a launch) with a program already called
+    again = ShardedKNN(db, mesh=make_mesh(1, 1), k=K + 3)
     again.search_certified(corpus[1], selector="pallas")
-    assert _first_calls("certified") == []
+    assert _first_calls() == []
+    assert [e["launches"] for e in _spans("certified.inflight.operands")
+            ] == [1]
 
 
 def test_a_new_widen_or_a_new_row_count_is_a_new_reselect_program(tied):
@@ -806,8 +832,10 @@ def test_the_stage_report_tables_the_start_up(corpus, report, tmp_path):
             ) == ("certified", "off", True)
     assert program["traces"] >= 1 and f"k={K + 5}," in program["key"]
     assert [p["event"] for p in table["placement"]] == [
-        "placement.device_put", "placement.norm_walk"]
-    assert [p["in_first_call"] for p in table["placement"]] == [True, True]
+        "placement.device_put", "placement.norm_walk", "placement.operands"]
+    assert [p["in_first_call"] for p in table["placement"]] == [True] * 3
+    assert (table["placement"][2]["tile"], table["placement"][2]["parts"]
+            ) == (3072, "th+tl")
     setup = table["setup"]
     assert sum(setup["rows"].values()) + setup[
         "unaccounted_s"] == pytest.approx(3.5, abs=1e-3)

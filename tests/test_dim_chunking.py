@@ -367,7 +367,7 @@ def test_the_event_says_what_the_kernel_was_given(fresh_registry, rng,
     assert (call["dim_chunk"], call["dim_chunks"]) == want
     knobs = {kk: v for kk, v in stats["pallas_knobs"].items()
              if kk not in ("interpret", "terms", "mxu_passes", "dim_chunk",
-                           "dim_chunks", "final_select_stage")}
+                           "dim_chunks", "final_select_stage", "operands")}
     if kernel == "tiled":
         grids = program_grids(placed, q[:batch], batch_rows=batch,
                               terms=stats["terms"], **knobs)
@@ -375,10 +375,12 @@ def test_the_event_says_what_the_kernel_was_given(fresh_registry, rng,
     # the width handed down reaches the kernel as given: a program built
     # with another runs another, whatever the shape would have said
     from knn_tpu.parallel.sharded import _pallas_certified_program
+    rows = placed._operands_cache  # the resident form the call resolved
+    assert rows["key"][0] == tile_n
     forced = _pallas_certified_program(
         placed.mesh, 20, 5, placed.merge, tile_n, "bf16x3",
         n_train=placed.n_train, kernel="tiled", interpret=True,
-        dim_chunk=pk.DIM_CHUNK)
+        dim_chunk=pk.DIM_CHUNK, resident_parts=len(rows["parts"]) - 1)
     qp, _ = placed._place_queries(q[:batch])
     traced = jax.make_jaxpr(forced)(qp, placed._tp,
                                     *placed._pallas_operands("bf16x3"))

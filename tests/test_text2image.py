@@ -312,6 +312,45 @@ def test_the_final_select_stage_compiles_at_the_cells_widths(
         compile_stage()
 
 
+@pytest.mark.parametrize("dim,terms", [
+    (128, "hh"),            # both BIGANN cells: byte rows, th alone
+    (256, "hh"),            # ssnpp2m5
+    (201, "hh+hl+lh")])     # text2image2m5: float rows, th and tl
+def test_the_kernel_takes_resident_row_operands_at_the_cells_widths(
+        one_chip, dim, terms):
+    """The kernel launch handed the placement's row operands as
+    arguments (PR 39), compiled for a described v5e at the cells' widths
+    and two row tiles: Mosaic takes the bf16 halves as they arrive, and
+    nothing outside the kernel casts or reduces an array of the rows'
+    size (the interpreted tests cannot say what the compiler makes of
+    operands that are parameters)."""
+    import jax.numpy as jnp
+
+    from knn_tpu.ops import pallas_knn as pk
+
+    rows = 2 * pk.TILE_N - 100
+    rows_p, dim_p = 2 * pk.TILE_N, -(-dim // pk.DIM_CHUNK) * pk.DIM_CHUNK
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    halves = 1 + ("hl" in terms)
+    prepared = (*[aval((rows_p, dim_p), jnp.bfloat16)] * halves,
+                aval((rows_p,), jnp.float32))
+    text = pk._bin_candidates.lower(
+        aval((4096, dim), jnp.float32), aval((rows, dim), jnp.float32),
+        block_q=256, tile_n=pk.TILE_N, survivors=None,
+        precision="bf16x3", interpret=False, terms=terms, dim_chunk=dim_p,
+        db_prepared=prepared).compile().as_text()
+    assert "%_bin_candidates" in text
+    # (at two tiles an operand is small enough for the compiler to move
+    # it to VMEM whole, an asynchronous copy: no cast and no reduction)
+    made = [ln for ln in text.splitlines()
+            if re.match(rf"\s*%\S+ = (bf16|f32)\[{rows_p}(,{dim_p})?\]", ln)
+            and not re.search(r" (parameter|copy-start|copy-done)\(", ln)]
+    assert made == []
+
+
 # --- the plain reference ------------------------------------------------------
 def test_the_oracle_is_a_float64_argsort():
     db, q = mix(70_000, 24, dim=24)  # two blocks of rows

@@ -303,10 +303,11 @@ def test_version_6_winner_naming_a_removed_knob_is_never_used(
     assert stats["tuning"]["source"] == "default"
     # beside the knobs: what the program resolved for itself, from the
     # backend (interpret), from the data (terms, mxu_passes) and from
-    # the launch's shape (dim_chunk, dim_chunks, final_select_stage)
+    # the launch's shape (dim_chunk, dim_chunks, final_select_stage) and
+    # from the device's memory (operands)
     assert {kk: v for kk, v in stats["pallas_knobs"].items()
             if kk not in ("interpret", "terms", "mxu_passes", "dim_chunk",
-                          "dim_chunks", "final_select_stage")
+                          "dim_chunks", "final_select_stage", "operands")
             } == tuning.DEFAULT_KNOBS
     assert (stats["pallas_knobs"]["dim_chunk"],
             stats["pallas_knobs"]["dim_chunks"]) == (128, 1)
@@ -331,7 +332,7 @@ def test_default_knobs_are_the_kernel_shaping_arguments():
         "return_sqrt", "_under") == set(tuning.DEFAULT_KNOBS)
     assert kwargs_of(
         ShardedKNN._pallas_setup, "margin", "include_distances", "terms",
-        "batch_rows") == set(tuning.DEFAULT_KNOBS)
+        "batch_rows", "trace_id", "acct") == set(tuning.DEFAULT_KNOBS)
 
 
 def test_standard_grid_includes_int8_candidate():
