@@ -294,6 +294,60 @@ def width_leg(jax, mesh, name: str, cfg: dict) -> None:
         check_equal("certified vs oracle", i[:ORACLE_WIDTH], oracle)
 
 
+def filtered_leg(jax, mesh) -> None:
+    """One filtered certified batch (PR 40): SIFT-shaped rows with a bag
+    of tags a row, one or two tags a query, against a float64 scan of
+    each query's valid rows; a tag a tenth of the rows hold keeps a
+    bitmap, the rare ones lists, and some queries have no valid row."""
+    cfg = dict(SIFT, k=10)
+    with Leg(f"filtered leg: {cfg['n']:,} x {cfg['dim']} l2, k=10, a bag "
+             f"of tags a row, one certified batch under filter_tags"):
+        db, q = make_data(cfg["n"], cfg["dim"], NQ)
+        rng = np.random.default_rng(40)
+        n, vocabulary = cfg["n"], 50_000
+        p = np.arange(1, vocabulary + 1) ** -0.8
+        tags = rng.choice(vocabulary, size=6 * n, p=p / p.sum())
+        keys = np.unique(np.repeat(np.arange(n, dtype=np.int64), 6) << 32
+                         | tags)
+        indptr = np.concatenate([[0], np.cumsum(np.bincount(
+            keys >> 32, minlength=n))])
+        tags = (keys & 0xFFFFFFFF).astype(np.int32)
+        ft = np.stack([tags[indptr[rng.integers(0, n, NQ)]],
+                       rng.integers(0, vocabulary, NQ)], axis=1)
+        ft[::2, 1] = -1
+        prog = place(jax, db, mesh, cfg, row_tags=(indptr, tags))
+        t0 = time.perf_counter()
+        d, i, stats = prog.search_certified(q, selector="pallas",
+                                            filter_tags=ft)
+        told = stats["filter"]
+        say(f"  filtered (first call: places the tag index, compiles): "
+            f"{time.perf_counter() - t0:.2f} s; {told}; index "
+            f"{prog._tag_index_cache['stats']}")
+        if stats["pallas_knobs"]["interpret"] is not False:
+            raise AssertionError("filtered: kernel ran in interpret mode")
+        if not (told["bitmap_lookups"] and told["list_lookups"]
+                and told["empty"] and told["short"]):
+            raise AssertionError(f"filtered: the batch lacks a form: {told}")
+        row_of = np.repeat(np.arange(n), np.diff(indptr))
+        for at in range(ORACLE_WIDTH):
+            ok = np.ones(n, bool)
+            for t in ft[at]:
+                if t >= 0:
+                    has = np.zeros(n, bool)
+                    has[row_of[tags == t]] = True
+                    ok &= has
+            rows = np.flatnonzero(ok)
+            dist = ((db[rows].astype(np.float64) - q[at]) ** 2).sum(-1)
+            want = rows[np.lexsort((rows, dist))][:10]
+            want = np.concatenate([want, np.full(10 - want.size, -1)])
+            if not np.array_equal(i[at], want):
+                raise AssertionError(
+                    f"filtered: query {at} tags {ft[at]}: got {i[at]}, "
+                    f"the valid rows' float64 scan gives {want}")
+        say(f"  {ORACLE_WIDTH} queries equal the float64 scan of their "
+            f"valid rows")
+
+
 # --- four chips --------------------------------------------------------------
 def check_placement(prog, mesh, rows_per_shard: int) -> None:
     """Nothing piled on device 0: the db shards and the replicated
@@ -380,6 +434,7 @@ def main(argv=None) -> int:
         sweep_and_serving_legs(jax, mesh)
         width_leg(jax, mesh, "GIST", GIST)
         width_leg(jax, mesh, "GloVe", GLOVE)
+        filtered_leg(jax, mesh)
 
     say(f"all legs passed; XLA compile {_COMPILE['backend_compile_s']:.1f} "
         f"s over {_COMPILE['compiles']} programs, persistent cache "

@@ -135,6 +135,8 @@ KERNEL_TERMS = "knn_tpu_kernel_terms_total"
 KERNEL_DIM_CHUNKS = "knn_tpu_kernel_dim_chunks_total"
 FINAL_SELECT_CALLS = "knn_tpu_final_select_calls_total"
 KERNEL_OPERANDS = "knn_tpu_kernel_operands_total"
+FILTER_QUERIES = "knn_tpu_filter_queries_total"
+FILTER_LIST_IDS = "knn_tpu_filter_list_ids_total"
 
 # --- host-RAM shard tier (knn_tpu.parallel.sharded) --------------------
 HOSTTIER_SWEEPS = "knn_tpu_hosttier_sweeps_total"
@@ -466,6 +468,19 @@ CATALOG = {
         "final_select_geometry: an exact final select at a shape it "
         "was timed at), 'xla' lax.top_k and the gather after it (every "
         "other shape, and final_select='approx')."),
+    FILTER_QUERIES: (
+        "counter", ("outcome",),
+        "Queries of search_certified(filter_tags=...), by how many of "
+        "the rows their tags allow came back: 'full' (k rows), 'short' "
+        "(1 to k-1: fewer than k rows hold every tag), 'empty' (none "
+        "does).  Every outcome exists from the first filtered call, at "
+        "0 where nothing took it."),
+    FILTER_LIST_IDS: (
+        "counter", (),
+        "Row ids named by the LISTED tag lookups of "
+        "search_certified(filter_tags=...) (a tag too rare for a "
+        "bitmap, ops.tagfilter.bitmap_min_rows): what the program "
+        "filter_mask sets one bit apiece for."),
     KERNEL_OPERANDS: (
         "counter", ("source",),
         "Batches of search_certified(selector='pallas'), by where their "

@@ -192,6 +192,7 @@ def repair_uncertified(
     db_norm_max: Optional[float] = None,
     dot_shift: Optional[float] = None,
     dot_slack: float = 0.0,
+    valid_rows_fn=None,
 ) -> dict:
     """Shared fallback repair for both certified pipelines (single-device
     :func:`knn_search_certified` and the sharded
@@ -225,6 +226,15 @@ def repair_uncertified(
     ``|q|^2 + M + 2 s_k + tol + dot_slack < v_w`` proves s(u) > s_k
     with dot_slack / 2 to spare.
 
+    ``valid_rows_fn(position) -> ascending row ids`` (a filtered call,
+    parallel.sharded: ``select_fn`` then selects among each query's
+    valid rows only, +inf and the sentinel once they run out): a
+    widened selection that ran out (``v_w`` = +inf) holds EVERY valid
+    row, so nothing is excluded and step 2 proves the repair whatever
+    the k-th distance, +inf included; step 3's scan reads the query's
+    valid rows alone (:func:`host_exact_knn` over that gather) and pads
+    a short answer with +inf and the int64 sentinel.
+
     ``select_fn(q_bad [B,D], widen) -> (f32 scores [B, widen] ascending,
     candidate indices [B, widen])``.
     Mutates ``d``/``i`` in place at rows ``bad``; returns a stats dict:
@@ -255,11 +265,24 @@ def repair_uncertified(
         d_k = q_norm + dot_shift + 2.0 * d_k
         tol = tol + dot_slack
     v_w = fs[:, -1]  # exclusion value of the widened f32 selection
-    still = np.flatnonzero(d_k + tol >= v_w)
+    unproven = d_k + tol >= v_w
+    if valid_rows_fn is not None:
+        unproven &= np.isfinite(v_w)
+    still = np.flatnonzero(unproven)
     host_exact = 0
     if still.size:
         sb = bad[still]
-        d[sb], i[sb] = host_exact_knn(db_np, q_np[sb], k, metric=metric)
+        if valid_rows_fn is None:
+            d[sb], i[sb] = host_exact_knn(db_np, q_np[sb], k, metric=metric)
+        else:
+            for pos in sb:
+                rows = valid_rows_fn(pos)
+                d[pos], i[pos] = np.inf, np.iinfo(np.int64).max
+                if rows.size:
+                    hd, hi = host_exact_knn(db_np[rows], q_np[pos][None], k,
+                                            metric=metric)
+                    d[pos, : hd.shape[1]] = hd[0]
+                    i[pos, : hd.shape[1]] = rows[hi[0]]
         host_exact = int(sb.size)
     genuine = int((i[bad] != orig_i).any(axis=-1).sum())
     out = {

@@ -431,6 +431,43 @@ def _geometry(
     return BIN_W, survivors, survivors * BIN_W, BIN_W
 
 
+def valid_words_per_tile(tile_n: int) -> int:
+    """Words a query of one row tile's validity: a word holds one lane
+    of 32 consecutive 128-row groups, so whole vregs of 128 words."""
+    return -(-(tile_n // BIN_W) // 32) * BIN_W
+
+
+def valid_word_position(rows, tile_n: int):
+    """``(column, bit)`` of (shard-local) row indices in a query's
+    validity words at row tile ``tile_n`` (numpy or jax integers): tile
+    ``t`` owns columns ``[t * w, (t + 1) * w)``, ``w =
+    valid_words_per_tile(tile_n)``, and within it bit ``g % 32`` of word
+    ``(g // 32) * 128 + lane`` is row ``g * 128 + lane`` of the tile.
+    Wherever ``tile_n % 4096 == 0`` that is bit ``G % 32`` of word
+    ``(G // 32) * 128 + lane`` for the row's GLOBAL group ``G``, the
+    same whatever the tile."""
+    w = valid_words_per_tile(tile_n)
+    t, r = rows // tile_n, rows % tile_n
+    g = r // BIN_W
+    return t * w + (g // 32) * BIN_W + r % BIN_W, g % 32
+
+
+def pack_valid_words(valid: np.ndarray, tile_n: int) -> np.ndarray:
+    """Host packing of a bool ``[queries, rows]`` validity matrix into
+    the kernel's words (uint32 ``[queries, n_tiles * w]``), rows past
+    the matrix invalid: the plain statement of the layout, for tests
+    and small callers."""
+    n_q, n = valid.shape
+    n_tiles = -(-n // tile_n)
+    out = np.zeros((n_q, n_tiles * valid_words_per_tile(tile_n)), np.uint32)
+    col, bit = valid_word_position(np.arange(n), tile_n)
+    for q in range(n_q):
+        on = np.flatnonzero(valid[q])
+        np.bitwise_or.at(out[q], col[on],
+                         np.uint32(1) << bit[on].astype(np.uint32))
+    return out
+
+
 def effective_tile(
     rows: int, tile_n: int, survivors: Optional[int], min_width: int,
 ) -> int:
@@ -532,10 +569,15 @@ def _pq_onehot_qt(lut, codes_u8, *, tile_n: int, pq_shape):
 
 def _kernel(q_ref, *refs, tile_n: int, survivors: int, nd: int,
             precision: str, ti_axis: int = 1, pq_shape=None,
-            terms: str = BF16X3_TERMS[0]):
+            terms: str = BF16X3_TERMS[0], masked: bool = False):
     ti = pl.program_id(ti_axis)  # 1 = query_major grid, 0 = db_major
     di = pl.program_id(2)
     q = q_ref[:]
+    vw_ref = None
+    if masked:
+        # the batch's validity words of this (query block, row tile)
+        # cell come first after the queries (``valid_words``)
+        vw_ref, *refs = refs
     dn = (((1,), (1,)), ((), ()))
     if precision == "bf16x3":
         # db high/low bf16 parts arrive PRECOMPUTED (one XLA pass per
@@ -601,7 +643,8 @@ def _kernel(q_ref, *refs, tile_n: int, survivors: int, nd: int,
             qt_acc = ((qt_acc.astype(jnp.float32) * qsc_ref[:, 0:1])
                       * aux_ref[8:9, :])
         cd, ci, bound = _emit_select_grouped(
-            ti, qt_acc, tn_ref[:], tile_n=tile_n, survivors=survivors)
+            ti, qt_acc, tn_ref[:], tile_n=tile_n, survivors=survivors,
+            valid_words=None if vw_ref is None else vw_ref[:])
         d_ref[:] = cd
         i_ref[:] = ci
         b_ref[:] = bound
@@ -627,7 +670,8 @@ def _kernel(q_ref, *refs, tile_n: int, survivors: int, nd: int,
         write(qt_ref[:])
 
 
-def _emit_select_grouped(ti, qt, tn, *, tile_n: int, survivors: int):
+def _emit_select_grouped(ti, qt, tn, *, tile_n: int, survivors: int,
+                         valid_words=None):
     """Survivor/bound emission from an accumulated score tile: returns
     ``(cand_d, cand_i, bounds)`` for the caller to write (the tiled
     kernel stores them to its per-cell output blocks; the streaming
@@ -651,11 +695,11 @@ def _emit_select_grouped(ti, qt, tn, *, tile_n: int, survivors: int):
     after the kernel (module docstring)."""
     s = tn[0:1, :] - 2.0 * qt  # [BQ, T], ||q||^2 dropped
     return _emit_select_grouped_scores(
-        ti, s, tile_n=tile_n, survivors=survivors)
+        ti, s, tile_n=tile_n, survivors=survivors, valid_words=valid_words)
 
 
 def _emit_select_grouped_scores(ti, s, *, tile_n: int, survivors: int,
-                                payload=None):
+                                payload=None, valid_words=None):
     """The grouped emitter on a PRECOMPUTED score tile ``s`` — split out
     so the fused kernel (which needs ``s`` for its early-out predicate
     before deciding whether to run the select at all) shares the EXACT
@@ -669,13 +713,28 @@ def _emit_select_grouped_scores(ti, s, *, tile_n: int, survivors: int,
     or, where ``s`` is itself an array of candidates with their indices
     in ``payload`` (same shape, int32, sentinel where +inf: the final
     select's bin-merge, ``_select_merge``), the payload riding with
-    it.  Returns ``(cd, ci [BQ, survivors * 128], bound [BQ, 128])``."""
+    it.  Returns ``(cd, ci [BQ, survivors * 128], bound [BQ, 128])``.
+
+    ``valid_words`` (int32 ``[BQ, valid_words_per_tile(tile_n)]``, this
+    tile's block of a batch's per-query validity words; None: every row
+    is a candidate and not one operation is added) turns the score of a
+    row whose bit is 0 into +inf BEFORE the insertion network: bit
+    ``g % 32`` of word ``[query, (g // 32) * 128 + lane]`` is the
+    validity of the tile's row ``g * 128 + lane``, so the test is one
+    ``and`` with a constant and one compare on the group's own
+    ``[BQ, 128]`` vreg, with no cross-lane move.  A masked row is then
+    never a candidate (+inf never displaces: strict ``<``) and never
+    lowers a bin's bound, so the soundness contract holds over the
+    VALID rows: every valid row not emitted scores >= its bin's bound,
+    and a bin with at most ``survivors`` valid rows has bound +inf."""
     bq = s.shape[0]
     n_groups = tile_n // BIN_W
     lane = lax.broadcasted_iota(jnp.int32, (bq, BIN_W), 1)
     inf = jnp.full((bq, BIN_W), jnp.inf, jnp.float32)
     none = jnp.full((bq, BIN_W), 0 if payload is None else _I32MAX,
                     jnp.int32)
+    if valid_words is not None:
+        zero = lax.full((bq, BIN_W), 0, jnp.int32)
     vals = [inf] * (survivors + 1)  # running sorted smallest per lane
     gidx = [none] * survivors       # group index of each survivor
     # lax primitives, not their jnp twins, inside the unrolled loop: it
@@ -684,6 +743,13 @@ def _emit_select_grouped_scores(ti, s, *, tile_n: int, survivors: int,
     # — seconds of each process's first call (root PERF.md, PR 29)
     for g in range(n_groups):
         cur_v = lax.slice_in_dim(s, g * BIN_W, (g + 1) * BIN_W, axis=1)
+        if valid_words is not None:
+            word = lax.slice_in_dim(valid_words, g // 32 * BIN_W,
+                                    (g // 32 + 1) * BIN_W, axis=1)
+            bit = lax.full((bq, BIN_W), np.int32(
+                np.uint32(1 << g % 32).view(np.int32)), jnp.int32)
+            cur_v = lax.select(lax.ne(lax.bitwise_and(word, bit), zero),
+                               cur_v, inf)
         cur_g = (lax.full((bq, BIN_W), g, jnp.int32) if payload is None
                  else lax.slice_in_dim(payload, g * BIN_W, (g + 1) * BIN_W,
                                        axis=1))
@@ -1062,6 +1128,7 @@ def _bin_candidates(
     terms: str = BF16X3_TERMS[0],
     dim_chunk: Optional[int] = None,
     db_prepared: Optional[Tuple[jax.Array, ...]] = None,
+    valid_words: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Kernel launch on padded shapes.  Returns
 
@@ -1117,7 +1184,22 @@ def _bin_candidates(
     this ``tile_n`` and these ``terms``, made once by a caller whose
     rows stay (ShardedKNN's resident placement): the launch streams them
     and forms nothing of the corpus's size, no padded copy, no cast, no
-    norms.  ``None`` forms them here, as every call always did."""
+    norms.  ``None`` forms them here, as every call always did.
+
+    ``valid_words`` (uint32 or int32 ``[queries, n_tiles *
+    valid_words_per_tile(tile_n)]``, or wider: columns past those are
+    never read; ``kernel="tiled"`` only) is a
+    per-query predicate over the rows, in the layout of
+    :func:`valid_word_position` at this ``tile_n``: a row whose bit is 0
+    scores +inf for that query before the bin-select
+    (``_emit_select_grouped_scores``), so it is no candidate and lowers
+    no bound.  Row padding must be marked invalid by the caller; query
+    padding is (zero words).  ``None`` is the launch it always was,
+    operation for operation."""
+    if valid_words is not None and kernel != "tiled":
+        raise ValueError(
+            f"kernel={kernel!r} takes no per-query validity words: only "
+            f"the tiled kernel's body applies them; use kernel='tiled'")
     queries = _pad_axis(queries.astype(jnp.float32), block_q, 0)
     queries = _pad_axis(queries, DIM_CHUNK, 1)
     n_rows = db.shape[0]
@@ -1308,10 +1390,19 @@ def _bin_candidates(
         )
 
     db_major = grid_order == "db_major"
+    words, wpt = [], valid_words_per_tile(tile_n)
+    if valid_words is not None:
+        if valid_words.shape[1] < n_tiles * wpt:
+            raise ValueError(
+                f"valid_words of shape {valid_words.shape} is not "
+                f"{n_tiles} tiles of {wpt} words a query "
+                f"(valid_word_position at tile_n={tile_n})")
+        words = [_pad_axis(lax.bitcast_convert_type(
+            valid_words, jnp.int32), block_q, 0)]
     body = functools.partial(
         _kernel, tile_n=tile_n, survivors=survivors, nd=nd,
         precision=precision, ti_axis=0 if db_major else 1,
-        pq_shape=pq_shape, terms=terms,
+        pq_shape=pq_shape, terms=terms, masked=bool(words),
     )
     # the query operand block: one dim-chunk slice per grid step for the
     # feature-chunked arms; PQ's LUT has no chunk loop (nd == 1) and
@@ -1345,7 +1436,8 @@ def _bin_candidates(
                              for x in db_inputs),
                 aux_rows=aux_rows,
                 q_block=block_q * q_block_w * queries_in.dtype.itemsize,
-                q_extra=len(q_extra) * block_q * BIN_W * 4),
+                q_extra=(len(q_extra) * BIN_W + len(words) * wpt)
+                * block_q * 4),
         )
     db_specs = [pl.BlockSpec((tile_n, chunk_w), t_idx) for _ in db_inputs]
     if db_major:
@@ -1358,6 +1450,7 @@ def _bin_candidates(
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_q, q_block_w), q_idx),
+            *[pl.BlockSpec((block_q, wpt), o_idx) for _ in words],
             *db_specs,
             *extra_specs,
             pl.BlockSpec((aux_rows, tile_n), n_idx),
@@ -1380,7 +1473,7 @@ def _bin_candidates(
         ],
         interpret=interpret,
         **kwargs,
-    )(queries_in, *db_inputs, *q_extra, tnorm)
+    )(queries_in, *words, *db_inputs, *q_extra, tnorm)
 
 
 def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
@@ -1472,6 +1565,7 @@ def local_certified_candidates(
     terms: str = BF16X3_TERMS[0],
     dim_chunk: Optional[int] = None,
     db_prepared: Optional[Tuple[jax.Array, ...]] = None,
+    valid_words: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The whole device-side certified coarse pass against one db (shard):
 
@@ -1513,7 +1607,16 @@ def local_certified_candidates(
     whenever their candidates cover the true top-k — and certified
     fallback material otherwise.  ``db_prepared`` likewise plugs in the
     "bf16x3" kernel's row operands kept beside ``t``
-    (:func:`row_operands`): stage 1 then reads nothing of ``t``."""
+    (:func:`row_operands`): stage 1 then reads nothing of ``t``.
+
+    ``valid_words`` (``_bin_candidates``) restricts each query to the
+    rows its words mark: ``idx`` then names valid rows only (sentinel
+    and +inf once they run out) and ``lb`` bounds the valid rows not
+    selected; ``lb`` is +inf exactly when every valid row of the shard
+    is among the candidates (no kernel bin held more than ``survivors``
+    of them, no merge bin more than ``SELECT_MERGE_SURVIVORS``, and
+    fewer than m+2 survived in all), so a short or empty valid set
+    certifies by that alone.  Stages 2 and 3 are untouched."""
     if interpret is None:
         interpret = not default_backend_is_tpu()
     cd, ci, bounds = local_coarse_candidates(
@@ -1522,6 +1625,7 @@ def local_certified_candidates(
         final_select=final_select, grid_order=grid_order, kernel=kernel,
         db_int8=db_int8, offset=offset, db_pq=db_pq, terms=terms,
         dim_chunk=dim_chunk, db_prepared=db_prepared,
+        valid_words=valid_words,
     )
     return local_select_rescore(
         q, t, cd, ci, bounds, m, final_select=final_select,
@@ -1555,14 +1659,15 @@ def local_coarse_candidates(
     terms: str = BF16X3_TERMS[0],
     dim_chunk: Optional[int] = None,
     db_prepared: Optional[Tuple[jax.Array, ...]] = None,
+    valid_words: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Stage 1 of :func:`local_certified_candidates` — the db-streaming
     coarse pass alone: resolve the effective tile, launch the kernel,
     trim the query padding.  Returns the packed candidates
     ``(cd [Q, W], ci [Q, W], bounds [Q, T*B])``; stage 2
     (:func:`local_select_rescore`) is everything after the kernel.
-    ``dim_chunk`` and ``db_prepared`` go to the kernel as given
-    (``_bin_candidates``)."""
+    ``dim_chunk``, ``db_prepared`` and ``valid_words`` go to the kernel
+    as given (``_bin_candidates``)."""
     if interpret is None:
         interpret = not default_backend_is_tpu()
     if final_select not in ("exact", "approx"):
@@ -1587,7 +1692,7 @@ def local_coarse_candidates(
             grid_order=grid_order, kernel=kernel, db_int8=db_int8,
             offset=offset, keep=m + 2 if kernel == "fused" else None,
             db_pq=db_pq, terms=terms, dim_chunk=dim_chunk,
-            db_prepared=db_prepared,
+            db_prepared=db_prepared, valid_words=valid_words,
         )
     n_q = q.shape[0]
     return cd[:n_q], ci[:n_q], bounds[:n_q]

@@ -66,6 +66,11 @@ from knn_tpu.parallel.mesh import (
 
 _INT_SENTINEL = jnp.iinfo(jnp.int32).max
 
+#: queries a masked re-select takes at a time (the repair of a
+#: ``filter_tags`` call): one compiled shape whatever the fallbacks, and
+#: a block's unpacked validity is this many bytes a shard row
+_MASKED_RESELECT_ROWS = 64
+
 #: Module-level jitted rescale so repeated jobs hit the jit cache.
 _minmax_apply_jit = jax.jit(minmax_apply)
 
@@ -620,6 +625,7 @@ class ShardedKNN:
         num_classes: Optional[int] = None,
         n_train: Optional[int] = None,
         hbm_budget_bytes: Optional[int] = None,
+        row_tags=None,
     ):
         # merge strategies resolve explicit > env (KNN_TPU_MERGE /
         # KNN_TPU_DCN_MERGE) > the SCALING.json-measured crossover table
@@ -673,6 +679,10 @@ class ShardedKNN:
         #: geometry at a time, ``parts`` None where the device had no
         #: room for them (_row_operands)
         self._operands_cache: Optional[dict] = None
+        #: the tag index, same lazy discipline: the rows' bags by tag on
+        #: the host (once) and the device form at ONE row tile at a time
+        #: (_tag_index); None without ``row_tags``
+        self._tag_index_cache: Optional[dict] = None
         db_shards = hosts * chips
         pre_placed = (
             isinstance(train, jax.Array)
@@ -842,6 +852,13 @@ class ShardedKNN:
         self._rows_lo_zero = False
         self.train_tile = train_tile
         self.n_train = n_train
+        #: ``(indptr, tags)``, CSR row -> sorted tag ids: what a
+        #: ``filter_tags`` query of search_certified is held against
+        self._row_tags = None
+        if row_tags is not None:
+            from knn_tpu.ops.tagfilter import check_bags
+
+            self._row_tags = check_bags(*row_tags, n_train)
         #: user-facing query/input dim — dot placements append one norm-
         #: augmentation column, so the PLACED width is ``dim_in + 1``
         self.dim_in = int(tp.shape[1]) - (1 if self._dot_aug else 0)
@@ -876,6 +893,9 @@ class ShardedKNN:
         # "resident" (the placement's, _row_operands) or "per_call"
         # (formed in the program, every call) (_pallas_setup)
         self._operands_source = "per_call"
+        # the row tile the last program's kernel runs (_pallas_setup):
+        # the layout a batch's validity words are made in
+        self._kernel_tile = 0
         #: lazily built serving engines, keyed by ladder spec
         #: (buckets, min_bucket, max_bucket) — search_bucketed; the lock
         #: keeps concurrent cold calls from double-building an engine
@@ -1550,6 +1570,88 @@ class ShardedKNN:
             self._operands_cache = {"key": key, "parts": parts}
         return parts
 
+    def _tag_index(self, tile: int) -> dict:
+        """The rows' tag bags as a RESIDENT placement, the fifth lazy
+        one: built the first time a ``filter_tags`` call resolves the
+        row tile ``tile`` (inside ``certified.prepare``, so in a
+        caller's warm-up), each shard over its own rows
+        (ops.tagfilter.place_arrays): a bitmap in the kernel's words'
+        layout for every tag that at least one row in
+        ``tagfilter.BITMAP_ROW_SHARE`` of a shard holds, the listed
+        rows (CSR by tag) of every other.  That split is the rule of
+        ``tagfilter.bitmap_min_rows`` on a list's length and nothing
+        sets it.  The host keeps the bags by tag (``host``: one sort,
+        once a placement) for the repair's exact scan; the device form
+        is kept at ONE tile at a time, as the row operands are.  One
+        ``placement.tag_index`` event a build.
+
+        Returns ``{"tile", "host": (inv_indptr, inv_rows), "slots",
+        "counts"`` (the host's slot table and the tags' list lengths),
+        ``"device": (slots, bitmaps, list_ptr, list_rows), "list_cap",
+        "stats"}``."""
+        held = self._tag_index_cache
+        if held is not None and held["tile"] == tile:
+            return held
+        from knn_tpu.ops import tagfilter
+
+        with self._engines_lock:
+            held = self._tag_index_cache
+            if held is not None and held["tile"] == tile:
+                return held
+            t0 = time.perf_counter()
+            host = (held["host"] if held is not None
+                    else tagfilter.invert_bags(*self._row_tags))
+            self._tag_index_cache = None  # the old form goes first
+            shards = self.db_shards
+            arrays = tagfilter.place_arrays(
+                *host, n_train=self.n_train, shards=shards,
+                shard_rows=self._shard_rows(), tile_n=tile)
+            dbp = db_axes(self.mesh)
+            device = (replicate(arrays["slots"], self.mesh),) + tuple(
+                shard(arrays[key].reshape((-1,) + arrays[key].shape[2:]),
+                      self.mesh, dbp)
+                for key in ("bitmaps", "list_ptr", "list_rows"))
+            jax.block_until_ready(device)
+            stats = {key: arrays[key] for key in (
+                "tags", "pairs", "bitmap_tags", "list_ids", "bytes")}
+            obs.emit_event("placement.tag_index", tile=int(tile), **stats,
+                           seconds=time.perf_counter() - t0)
+            held = {"tile": tile, "host": host, "slots": arrays["slots"],
+                    "counts": np.diff(host[0]), "device": device,
+                    "list_cap": arrays["list_cap"], "stats": stats}
+            self._tag_index_cache = held
+        return held
+
+    def _filter_words(self, ft: np.ndarray, index: dict, interpret: bool,
+                      trace_id, acct):
+        """``mask(lo, take, rows)`` for a ``filter_tags`` call: launches
+        the program ``filter_mask`` on ``ft[lo:lo + take]`` padded to
+        ``rows`` queries (then to the query shards) with a tag no row
+        holds, and returns the words still in flight.  The host side of
+        the launch is the span ``certified.filter_mask``; the flight is
+        the call account's to close."""
+        prog = _filter_mask_program(self.mesh, index["tile"],
+                                    index["list_cap"], interpret)
+        no_row = np.iinfo(np.int32).max
+
+        def mask(lo: int, take: int, rows: int):
+            with obs.span("certified.filter_mask", trace_id,
+                          parent=_CALL_SPAN, queries=take):
+                tags = np.full((rows, 2), no_row, np.int32)
+                tags[:take] = ft[lo : lo + take]
+                tp, _ = pad_to_multiple(
+                    tags, self.mesh.shape[QUERY_AXIS], fill=no_row)
+                tp = shard(tp, self.mesh, QUERY_AXIS)
+                begun = _hooks.first_call_begin()
+                words = _retry_transient(
+                    lambda: prog(tp, *index["device"]), "filter_mask dispatch")
+                acct.launched("filter_mask")
+                _hooks.first_call_end(begun, prog, "filter_mask", trace_id,
+                                      rows=tp.shape[0])
+            return words
+
+        return mask
+
     def _pallas_operands(self, precision: str) -> tuple:
         """The operand tail of the pallas certified program after
         ``(queries, db)`` — ONE home shared by :meth:`_certify_pallas`
@@ -1596,6 +1698,7 @@ class ShardedKNN:
         kernel: Optional[str] = None,
         tune_cache: Optional[str] = None,
         return_sqrt: bool = False,
+        filter_tags=None,
         _under: Optional[tuple] = None,
     ):
         """Exact lexicographic top-k via the certified pipeline, sharded.
@@ -1681,6 +1784,28 @@ class ShardedKNN:
         ``margin`` to push the fallback rate below 1%).  The resolved
         knob set and its provenance land in
         ``stats["pallas_knobs"]`` / ``stats["tuning"]``.
+
+        ``filter_tags`` (int ``[queries, 2]``, -1 for an absent tag; a
+        placement built with ``row_tags``; l2, ``selector="pallas"``,
+        ``kernel="tiled"``) holds each query to the rows whose bag
+        holds EVERY tag it names: the answer is the first k, in
+        lexicographic (float64 squared-L2 distance, index) order, of
+        those rows alone, padded with index -1 and distance +inf where
+        fewer than k qualify; no returned row lacks a tag.  The
+        predicate is applied INSIDE the kernel, between the MXU product
+        and the bin-select: the program ``filter_mask`` turns the
+        batch's tag ids into per-query validity words from the tag
+        index on the device (:meth:`_tag_index`), the certified program
+        takes them as one more operand, and a masked row is neither a
+        candidate nor part of any bound, so the certificate is the one
+        it was over the query's valid rows (``_certify_pack_spmd``).
+        The repair's re-select lays the same words over its exact
+        distances and its host scan reads the query's valid rows only.
+        The other selectors, kernels and metrics refuse it.
+        ``stats["filter"]`` says what the batch was: ``filter``
+        (``tags`` / ``none``), ``bitmap_lookups``, ``list_lookups``,
+        ``list_ids``, and how many answers came back ``short`` (1 to
+        k-1 rows) and ``empty``.  ``None`` is the call it always was.
         """
         self._require_resident("search_certified")
         if self.metric == "cosine":
@@ -1717,6 +1842,19 @@ class ShardedKNN:
                 "metrics only")
         if selector not in SELECTORS:
             raise ValueError(f"unknown selector {selector!r}; expected {SELECTORS}")
+        if filter_tags is not None:
+            if self._row_tags is None:
+                raise ValueError(
+                    "filter_tags needs the rows' tag bags: construct "
+                    "ShardedKNN with row_tags=(indptr, tags)")
+            if selector != "pallas" or self.metric not in (
+                    "l2", "sql2", "euclidean"):
+                raise ValueError(
+                    f"filter_tags is applied inside the certified kernel: "
+                    f"selector='pallas' on an l2 placement only (got "
+                    f"selector={selector!r}, metric={self.metric!r}); the "
+                    f"counted selectors' two passes and the cosine and dot "
+                    f"placements take no validity words")
         from knn_tpu.ops.certified import repair_uncertified
 
         # a call that is another's first pass runs under that call's
@@ -1727,8 +1865,15 @@ class ShardedKNN:
         with obs.span(_CALL_SPAN, tid, selector=selector,
                       **({"parent": parent} if parent else {})) as call:
             if _under is None:
-                acct = _call_account(selector)
+                acct = _call_account(
+                    selector, *(("filter_mask",) if filter_tags is not None
+                                else ()))
             q_np = np.asarray(queries, dtype=np.float32)
+            ft = mask = index = None
+            if filter_tags is not None:
+                from knn_tpu.ops import tagfilter
+
+                ft = tagfilter.check_filter_tags(filter_tags, q_np.shape[0])
             map_s = {"before_s": 0.0, "after_s": 0.0}
             if dot:
                 with obs.trace.phase(map_s, "before_s", _METRIC_SPAN):
@@ -1799,11 +1944,25 @@ class ShardedKNN:
                     # an inner-product call's scores are made on the
                     # host (below): no distance block leaves the device
                     device_d = return_distances and not dot
+                    if ft is not None and knobs["kernel"] != "tiled":
+                        raise ValueError(
+                            f"filter_tags: kernel={knobs['kernel']!r} takes "
+                            f"no per-query validity words, only the tiled "
+                            f"kernel's body applies them; use "
+                            f"kernel='tiled'")
                     prog, m_prog, w, interpret = self._pallas_setup(
                         m - self.k, include_distances=device_d,
                         terms=terms, batch_rows=bs, trace_id=tid,
-                        acct=acct, **knobs)
+                        acct=acct, **knobs,
+                        **({"masked": True} if ft is not None else {}))
                     ops_tail = self._pallas_operands(knobs["precision"])
+                    if ft is not None:
+                        # the tag index in the resolved tile's layout
+                        # (built by the first call that needs it), and
+                        # the maker of each batch's words
+                        index = self._tag_index(self._kernel_tile)
+                        mask = self._filter_words(ft, index, interpret,
+                                                  tid, acct)
             call.set("queries", n_q)
             call.set("batches", len(batches))
             call.set("metric", self.metric)
@@ -1821,6 +1980,7 @@ class ShardedKNN:
                     ops_tail=ops_tail, precision=knobs["precision"],
                     trace_id=tid, want_distances=device_d,
                     rank_metric="dot" if dot else "l2", acct=acct,
+                    **({"mask": mask} if mask is not None else {}),
                 )
             else:
                 bad = self._certify_counted(
@@ -1858,15 +2018,59 @@ class ShardedKNN:
                     acct.ready("reselect")
                     return fs[:n_b], np.asarray(fi)[:n_b]
 
+            def _select_masked(qb, widen):
+                # the same re-select held to the flagged queries' words:
+                # blocks of _MASKED_RESELECT_ROWS queries (their unpacked
+                # validity is rows x shard rows bytes), each block's
+                # words made anew by filter_mask from its tag ids
+                exact = _masked_reselect_program(
+                    self.mesh, widen, self.merge, self.n_train,
+                    self.train_tile, index["tile"], self.dcn_merge)
+                nonlocal merge_bytes
+                fs, fi = [], []
+                with obs.span("certified.repair.reselect", tid,
+                              parent="certified.repair", widen=widen,
+                              rows=qb.shape[0]):
+                    for lo in range(0, qb.shape[0], _MASKED_RESELECT_ROWS):
+                        part = qb[lo : lo + _MASKED_RESELECT_ROWS]
+                        pad = _MASKED_RESELECT_ROWS - part.shape[0]
+                        bq, _ = self._place_queries(
+                            np.pad(part, ((0, pad), (0, 0))))
+                        merge_bytes += self._record_merge_bytes(
+                            bq.shape[0], widen)
+                        words = select_mask(lo, part.shape[0],
+                                            _MASKED_RESELECT_ROWS)
+                        begun = _hooks.first_call_begin()
+                        ps, pi = exact(bq, self._tp, words)
+                        acct.launched("reselect")
+                        _hooks.first_call_end(begun, exact, "reselect", tid,
+                                              rows=bq.shape[0])
+                        ps = np.asarray(ps)
+                        acct.ready("filter_mask")
+                        acct.ready("reselect")
+                        fs.append(ps[: part.shape[0]])
+                        fi.append(np.asarray(pi)[: part.shape[0]])
+                return np.concatenate(fs), np.concatenate(fi)
+
+            valid_rows_fn = None
+            if ft is not None:
+                select_mask = self._filter_words(ft[bad], index, interpret,
+                                                 tid, acct)
+                inv = index["host"]
+
+                def valid_rows_fn(pos):
+                    return tagfilter.valid_rows(*inv, self.n_train, *ft[pos])
+
             with obs.span("certified.repair", tid, parent=_CALL_SPAN,
                           fallback_queries=int(bad.size)) as sp:
                 repair = repair_uncertified(
                     d, i, self.k, m, bad, q_np, db_np,
-                    select_fn=_select,
+                    select_fn=_select if ft is None else _select_masked,
                     max_widen=min(self.n_train, shard_rows),
                     db_norm_max=db_norm_max,
                     dot_shift=self._dot_shift if dot else None,
                     dot_slack=self._dot_slack(),
+                    **({} if ft is None else {"valid_rows_fn": valid_rows_fn}),
                 )
                 sp.set("host_exact_queries",
                        repair.get("host_exact_queries", 0))
@@ -1923,6 +2127,31 @@ class ShardedKNN:
                 **repair,
                 **merged,
             }
+            # what the call was held to: nothing, or each query's tags
+            # (the lookups by the form their tag is kept in, the ids the
+            # listed ones named, the answers that ran out of valid rows)
+            told = {"filter": "none"}
+            if ft is not None:
+                # rows past the valid ones come back as the sentinel at
+                # +inf: the contract's padding is index -1
+                gone = i >= self.n_train
+                i[gone] = -1
+                d[gone] = np.inf
+                found = self.k - gone.sum(axis=1)
+                told = {"filter": "tags",
+                        **tagfilter.lookup_forms(
+                            index["slots"], index["counts"], ft),
+                        "short": int(((found > 0) & (found < self.k)).sum()),
+                        "empty": int((found == 0).sum())}
+                for outcome, n_out in (
+                        ("full", n_q - told["short"] - told["empty"]),
+                        ("short", told["short"]), ("empty", told["empty"])):
+                    obs.counter(_mn.FILTER_QUERIES, outcome=outcome).inc(
+                        n_out)
+                obs.counter(_mn.FILTER_LIST_IDS).inc(told["list_ids"])
+            for key, value in told.items():
+                call.set(key, value)
+            stats["filter"] = told
             if selector == "pallas":
                 stats["rank_corrected_queries"] = n_corrected
                 # interpret: the value _pallas_setup resolved and the
@@ -2381,7 +2610,8 @@ class ShardedKNN:
                       terms: str = "hh+hl+lh",
                       batch_rows: Optional[int] = None,
                       trace_id: Optional[str] = None,
-                      acct=obs.trace.NOOP_ACCOUNT):
+                      acct=obs.trace.NOOP_ACCOUNT,
+                      masked: bool = False):
         """(program, m, analysis_window, interpret) for the one-pass
         certified path — the ONE home of the kernel-geometry margin cap
         and the packed-output window, shared by :meth:`_certify_pallas`
@@ -2409,7 +2639,11 @@ class ShardedKNN:
         first resolution builds them, under the caller's ``trace_id``
         and call account ``acct``): the program is built to take them
         as arguments where they are kept, :meth:`_pallas_operands` then
-        hands them over, and ``self._operands_source`` says which."""
+        hands them over, and ``self._operands_source`` says which.
+
+        ``masked`` builds the program that takes a batch's validity
+        words after that tail (a ``filter_tags`` call); the resolved row
+        tile, whose layout the words are in, is ``self._kernel_tile``."""
         from knn_tpu.ops.pallas_knn import (
             BLOCK_Q,
             TILE_N,
@@ -2442,6 +2676,7 @@ class ShardedKNN:
         # kernel's real candidate width
         eff_tile = effective_tile(shard_rows, tile_n or TILE_N, survivors,
                                   min(self.k + margin, shard_rows) + 2)
+        self._kernel_tile = eff_tile
         _, _, out_w, _ = _geometry(eff_tile, survivors)
         # m is bounded by the db, the per-shard rows, and the kernel's
         # per-shard candidate width minus the two slots the exclusion
@@ -2491,13 +2726,14 @@ class ShardedKNN:
             interpret=interpret, terms=terms, augmented=self._dot_aug,
             dim_chunk=self._dim_chunking[0],
             resident_parts=len(resident) - 1 if resident else 0,
+            **({"masked": True} if masked else {}),
         )
         return prog, m, _analysis_window(self.k, m), interpret
 
     def _certify_pallas(
         self, batches, bs, d, i, q_np, db_np, *, prog, w, ops_tail,
         precision, trace_id=None, want_distances=True,
-        rank_metric="l2", acct=obs.trace.NOOP_ACCOUNT,
+        rank_metric="l2", acct=obs.trace.NOOP_ACCOUNT, mask=None,
     ):
         """One-pass certificate, host side.  The device already ranked the
         candidates, flagged uncertified rows, and marked near-tie pairs
@@ -2516,7 +2752,14 @@ class ShardedKNN:
         launch and fetch, and is handed what ``unpack_certified`` and
         ``rank_correct_runs`` say of their own insides (the copies; the
         buffers, the re-score and the ordering), summed over the batches
-        for the call's once-a-call records."""
+        for the call's once-a-call records.
+
+        ``mask`` (a ``filter_tags`` call: :meth:`_filter_words`' maker
+        of a batch's validity words from its slice of the tag ids)
+        launches the program ``filter_mask`` ahead of each batch's
+        certified program and hands it the words as its last operand;
+        the host waits for the words only once the certified program
+        is queued behind them, to close ``filter_mask``'s flight."""
         from knn_tpu.ops.refine import rank_correct_runs
 
         k = self.k
@@ -2584,21 +2827,27 @@ class ShardedKNN:
         # stage 1: dispatch every batch (async on device)
         outs = []
         for lo, chunk, pad in batches:
+            tail = ops_tail
+            if mask is not None:
+                tail += (mask(lo, bs - pad, bs),)
             with obs.span("certified.dispatch", trace_id,
                           parent=_CALL_SPAN, h2d_bytes=chunk.nbytes):
                 qp, _ = self._place_queries(chunk)
                 begun = _hooks.first_call_begin()
-                outs.append((qp, _retry_transient(
-                    lambda q=qp: prog(q, self._tp, *ops_tail),
+                outs.append((qp, tail, _retry_transient(
+                    lambda q=qp, tail=tail: prog(q, self._tp, *tail),
                     "pallas dispatch")))
                 acct.launched("certified")
                 _hooks.first_call_end(begun, prog, "certified", trace_id,
                                       rows=qp.shape[0])
 
         # stage 2: per batch — fetch + repair, in dispatch order
-        for (lo, chunk, pad), (qp, packed) in zip(batches, outs):
+        for (lo, chunk, pad), (qp, tail, packed) in zip(batches, outs):
+            if mask is not None:
+                jax.block_until_ready(tail[-1])
+                acct.ready("filter_mask")
             repair(lo, pad, packed,
-                   lambda q=qp: prog(q, self._tp, *ops_tail))
+                   lambda q=qp, tail=tail: prog(q, self._tp, *tail))
         return np.flatnonzero(bad_mask), n_corrected
 
     def predict_certified(
@@ -2752,6 +3001,7 @@ def _pallas_certified_program(
     augmented: bool = False,
     dim_chunk: Optional[int] = None,
     resident_parts: int = 0,
+    masked: bool = False,
 ):
     """ONE-pass sharded self-certifying coarse select + device rank +
     device certificate (ops.pallas_knn.local_certified_candidates per
@@ -2807,7 +3057,16 @@ def _pallas_certified_program(
     ``th``, 2 = ``th`` and ``tl``), each db-sharded: the kernel streams
     them and the program forms nothing of the corpus's size.  0: the
     kernel's prologue forms them in every call, the program it always
-    was."""
+    was.
+
+    ``masked`` (a ``filter_tags`` call; never a caller's choice) appends
+    the batch's validity words as the LAST operand, ``[queries, db
+    shards x words]`` sharded over both axes, each shard's block in the
+    kernel's layout at ``tile_n`` (ops.tagfilter.mask_words): the kernel
+    scores a row whose bit is 0 at +inf before the bin-select, and
+    ``_certify_pack_spmd`` reads a bound of +inf as "every valid row is
+    a candidate".  Without it the program is the one it always was,
+    operation for operation."""
     from knn_tpu.ops.pallas_knn import (
         BLOCK_Q,
         TILE_N,
@@ -2820,7 +3079,9 @@ def _pallas_certified_program(
     w = _analysis_window(k, m)
 
     def spmd(q, t, *tail):
-        aug_slack = None
+        aug_slack = words = None
+        if masked:
+            *tail, words = tail
         if augmented:
             *tail, aug_slack = tail
         db_q, db_pq, consts, db_norm_max, db_rows = _split_operand_tail(
@@ -2832,6 +3093,7 @@ def _pallas_certified_program(
             grid_order=grid_order, kernel=kernel, interpret=interpret,
             db_int8=db_q, db_pq=db_pq, offset=quant_offset, terms=terms,
             dim_chunk=dim_chunk, db_prepared=db_rows,
+            **({"valid_words": words} if masked else {}),
         )
         return _certify_pack_spmd(
             q, t, d32, li, lb, consts=consts, db_norm_max=db_norm_max,
@@ -2840,7 +3102,7 @@ def _pallas_certified_program(
             dcn_merge=dcn_merge,
             include_distances=include_distances,
             pq_dsub=None if db_pq is None else int(db_pq[1].shape[2]),
-            aug_slack=aug_slack,
+            aug_slack=aug_slack, **({"masked": True} if masked else {}),
         )
 
     prog = jax.jit(
@@ -2849,7 +3111,8 @@ def _pallas_certified_program(
             mesh=mesh,
             in_specs=(P(QUERY_AXIS), P(db_axes(mesh)),
                       *_tail_specs(precision, mesh, resident_parts),
-                      *((P(),) if augmented else ())),
+                      *((P(),) if augmented else ()),
+                      *((P(QUERY_AXIS, db_axes(mesh)),) if masked else ())),
             out_specs=P(QUERY_AXIS),
             check_vma=False,
         )
@@ -2857,7 +3120,8 @@ def _pallas_certified_program(
     _hooks.mark_built(
         prog, f"m={m},k={k},tile={eff_tile},terms={terms},"
               f"dim_chunk={dim_chunk},precision={precision},"
-              f"operands={'resident' if resident_parts else 'per_call'}")
+              f"operands={'resident' if resident_parts else 'per_call'}"
+              + (",masked" if masked else ""))
     return prog
 
 
@@ -2920,6 +3184,71 @@ def _row_operands_program(mesh: Mesh, tile: int, with_lo: bool):
     return prog
 
 
+@functools.lru_cache(maxsize=8)
+def _filter_mask_program(mesh: Mesh, tile: int, list_cap: int,
+                         interpret: bool):
+    """The program ``filter_mask``: a batch's ``[queries, 2]`` tag ids
+    to its validity words, ``[queries, db shards x words]`` sharded over
+    both axes, every shard making its own block from its own tag index
+    (ops.tagfilter.mask_words over ``ShardedKNN._tag_index``'s arrays:
+    the replicated slot table, then the shard's bitmaps, list offsets
+    and list rows)."""
+    from knn_tpu.ops.tagfilter import mask_words
+
+    dbp = db_axes(mesh)
+    prog = jax.jit(
+        jax.shard_map(
+            functools.partial(mask_words, tile_n=tile, list_cap=list_cap,
+                              interpret=interpret),
+            mesh=mesh,
+            in_specs=(P(QUERY_AXIS), P(), P(dbp), P(dbp), P(dbp)),
+            out_specs=P(QUERY_AXIS, dbp),
+            check_vma=False,
+        )
+    )
+    _hooks.mark_built(prog, f"tile={tile},list_cap={list_cap}")
+    return prog
+
+
+@functools.lru_cache(maxsize=32)
+def _masked_reselect_program(mesh: Mesh, k: int, merge: str, n_train: int,
+                             train_tile: Optional[int], tile_n: int,
+                             dcn_merge: Optional[str] = None):
+    """The repair's widened exact re-select (``_knn_program(...,
+    "exact")``) held to a batch's validity words: every shard lays its
+    block of the words over its exact float32 distances
+    (ops.tagfilter.masked_topk), then the usual merge.  A query with
+    fewer than ``k`` valid rows gets them all, then +inf and the
+    sentinel."""
+    from knn_tpu.ops.tagfilter import masked_topk, words_to_valid
+
+    hosts, chips = db_topology(mesh)
+
+    def spmd(q, t, words):
+        db_idx = _db_shard_index(hosts, chips)
+        d, i = masked_topk(
+            q, t, k,
+            words_to_valid(words, tile_n=tile_n, n_rows=t.shape[0]),
+            train_tile=train_tile,
+            n_valid=jnp.clip(n_train - db_idx * t.shape[0], 0, t.shape[0]))
+        gi = jnp.where(i == _INT_SENTINEL, _INT_SENTINEL,
+                       i + db_idx * t.shape[0])
+        return _merge_shards(d, gi, k, hosts, chips, merge, dcn_merge)
+
+    dbp = db_axes(mesh)
+    prog = jax.jit(
+        jax.shard_map(
+            spmd,
+            mesh=mesh,
+            in_specs=(P(QUERY_AXIS), P(dbp), P(QUERY_AXIS, dbp)),
+            out_specs=(P(QUERY_AXIS), P(QUERY_AXIS)),
+            check_vma=False,
+        )
+    )
+    _hooks.mark_built(prog, f"k={k},selector=exact,tile={train_tile},masked")
+    return prog
+
+
 #: device scope of the certify/pack tail; its four siblings (operand
 #: prep, kernel, final select, rescore) are ops.pallas_knn's SCOPE_*
 SCOPE_CERTIFY_PACK = "knn.certify_pack"
@@ -2933,7 +3262,8 @@ SCOPE_MERGE = "knn.merge"
 def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
                        precision, quant_offset, m, k, w, merge, n_train,
                        hosts, chips, include_distances,
-                       dcn_merge=None, pq_dsub=None, aug_slack=None):
+                       dcn_merge=None, pq_dsub=None, aug_slack=None,
+                       masked: bool = False):
     """The certify/pack tail of the pallas certified program, from one
     shard's ranked candidates ``(d32, li, lb)`` to the packed host-facing
     int32 array: merge, rank analysis, certificate, packing.
@@ -2955,7 +3285,32 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
       more than e above D'(c) <= d_k (1 + r) of every row c the host may
       keep, hence below each in inner product;
     - merge-drop: a dropped candidate has D' >= d32[:, m] (1 - r), and
-      ``d_k + r d_k + e < d32[:, m] (1 - r)`` says the same of it."""
+      ``d_k + r d_k + e < d32[:, m] (1 - r)`` says the same of it.
+
+    ``masked`` (the candidates came from a kernel that held each query
+    to its validity words): the certificate is the same in form, read
+    over the query's VALID rows.  A masked row scored +inf before the
+    bin-select, so it is in neither the candidates nor any bound: ``lb``
+    bounds the valid rows outside the candidates, and every inequality
+    above is the one it was, over fewer rows.  What changes is that the
+    valid rows can RUN OUT, and +inf then means "nothing there", on both
+    sides:
+
+    - ``lb`` = +inf says no kernel bin, no merge bin and not the final
+      select left a valid row out (each gives +inf only when it kept all
+      it saw), so every valid row of every shard is among the
+      candidates and nothing is excluded: the query certifies whatever
+      its k-th distance, +inf included (fewer than k valid rows: the
+      answer is all of them, padded).  So ``reach >= lb`` flags only
+      where ``lb`` is finite;
+    - the merge dropped a real candidate only if the (m+1)-th kept one
+      is real: ``d32[:, m]`` = +inf says all that was dropped was
+      padding, and the merge-drop test likewise holds only where it is
+      finite;
+    - a window that runs into padding needs no repair: a (row, +inf)
+      pair is never tight and always a provable boundary, so the test
+      that every one of the first k+1 is finite (which without a mask
+      says "this shard gave junk") is left out."""
     from knn_tpu.ops.pallas_knn import RANK_SLACK
 
     db_shards = hosts * chips
@@ -2995,7 +3350,9 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
     has_stop = big_after.any(axis=-1)
     stop = jnp.where(has_stop, jnp.argmax(big_after, axis=-1), w - 1)
     # rows without a provable boundary (or junk near it) rerun exactly
-    unresolved = (~has_stop) | ~jnp.isfinite(dw[:, : k + 1]).all(-1)
+    unresolved = ~has_stop
+    if not masked:
+        unresolved = unresolved | ~jnp.isfinite(dw[:, : k + 1]).all(-1)
     tight_use = tight & (pair < stop[:, None]) & ~unresolved[:, None]
 
     # --- device certificate ----------------------------------------
@@ -3032,13 +3389,18 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
     if aug_slack is not None:
         reach = reach + aug_slack
     bad = reach >= lb
+    if masked:
+        bad = bad & jnp.isfinite(lb)
     if db_shards > 1:
         # merge-dropped candidates have direct-diff f32 distance
         # >= the (m+1)-th kept; require true-distance clearance
         kept = d_k + RANK_SLACK * d_k
         if aug_slack is not None:
             kept = kept + aug_slack
-        bad = bad | (kept >= d32[:, m] * (1.0 - RANK_SLACK))
+        dropped = kept >= d32[:, m] * (1.0 - RANK_SLACK)
+        if masked:
+            dropped = dropped & jnp.isfinite(d32[:, m])
+        bad = bad | dropped
     bad = bad | unresolved
     cols = [
         gi[:, :w],

@@ -1,0 +1,79 @@
+"""Digests of the UNFILTERED certified program at the benchmark's cells'
+shapes: the sha256 of its jaxpr (the kernel's body included), traced
+from abstract arguments on the CPU, so nothing of a corpus's size is
+made.  ``tests/fixtures/unfiltered_program_digests.json`` holds what the
+tree BEFORE per-query validity (PR 40's parent) gives;
+``tests/test_yfcc_filter.py`` holds this tree to it, so that a call
+without ``filter_tags`` is the program it always was, operation for
+operation.  To record anew after a change that is MEANT to alter the
+unfiltered program (say so in the PR):
+
+    JAX_PLATFORMS=cpu PYTHONPATH=<tree> python tests/program_digest.py \\
+        > tests/fixtures/unfiltered_program_digests.json
+"""
+
+import hashlib
+import json
+import re
+
+#: cell -> (db shards, rows a shard, placed columns, k, terms, halves of
+#: the rows kept resident, inner-product placement)
+CELLS = {
+    "bigann5m.sweep": (1, 5_000_000, 128, 100, "hh", 1, False),
+    "gist1m.sweep": (1, 1_000_000, 960, 100, "hh+hl+lh", 0, False),
+    "bigann20m-x4.sweep": (4, 5_000_000, 128, 100, "hh", 1, False),
+    "text2image2m5.sweep_ip": (1, 2_500_000, 201, 10, "hh+hl+lh", 2, True),
+    "ssnpp2m5.sweep_range": (1, 2_500_000, 256, 100, "hh", 1, False),
+}
+QUERIES, MARGIN = 4096, 28
+
+
+def jaxpr_text(cell: str) -> str:
+    import jax
+    import jax.numpy as jnp
+
+    from knn_tpu.ops import pallas_knn as pk
+    from knn_tpu.parallel import make_mesh
+    from knn_tpu.parallel import sharded as sh
+
+    shards, rows, dim, k, terms, parts, dot = CELLS[cell]
+    mesh = make_mesh(1, shards, devices=jax.devices()[:shards])
+    m = k + MARGIN
+    chunk, _ = pk.dim_chunking(dim, tile_n=pk.TILE_N, block_q=pk.BLOCK_Q,
+                               precision="bf16x3", kernel="tiled",
+                               terms=terms, survivors=None)
+    prog = sh._pallas_certified_program(
+        mesh, m, k, "ring", pk.TILE_N, "bf16x3", n_train=rows * shards,
+        interpret=True, terms=terms, augmented=dot, dim_chunk=chunk,
+        resident_parts=parts)
+    rows_p = -(-rows // pk.TILE_N) * pk.TILE_N
+    dim_p = -(-dim // pk.DIM_CHUNK) * pk.DIM_CHUNK
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    tail = [aval((), jnp.float32)]
+    if parts:
+        tail += [aval((shards * rows_p, dim_p), jnp.bfloat16)] * parts
+        tail += [aval((shards * rows_p,), jnp.float32)]
+    if dot:
+        tail += [aval((), jnp.float32)]
+    text = str(jax.make_jaxpr(prog)(
+        aval((QUERIES, dim), jnp.float32),
+        aval((shards * rows, dim), jnp.float32), *tail))
+    # addresses, and the order a frozenset happens to print in
+    text = re.sub(r"0x[0-9a-f]+", "0x", text)
+    return re.sub(r"frozenset\(\{([^}]*)\}\)", lambda m: "frozenset({%s})"
+                  % ", ".join(sorted(m.group(1).split(", "))), text)
+
+
+def digests() -> dict:
+    return {cell: hashlib.sha256(jaxpr_text(cell).encode()).hexdigest()
+            for cell in CELLS}
+
+
+if __name__ == "__main__":
+    import jax
+
+    jax.config.update("jax_num_cpu_devices", 8)
+    print(json.dumps(digests(), indent=1))

@@ -351,6 +351,68 @@ def test_the_kernel_takes_resident_row_operands_at_the_cells_widths(
     assert made == []
 
 
+# --- PR 40: the validity mask at yfcc2m5's shape, compiled ---------------------
+CELL_ROWS, CELL_DIM, CELL_QUERIES = 2_500_000, 192, 4096
+
+
+def test_the_masked_kernel_compiles_at_the_filter_cells_shape(one_chip):
+    """The kernel with a batch's validity words as one more operand
+    (``yfcc2m5.sweep_filter``: one 256-column chunk, resident row
+    operands, 512 words a query a tile), for a described v5e: what
+    Mosaic makes of the word blocks, interpret mode cannot say."""
+    import jax.numpy as jnp
+
+    from knn_tpu.ops import pallas_knn as pk
+
+    rows_p = -(-CELL_ROWS // pk.TILE_N) * pk.TILE_N
+    words = rows_p // pk.TILE_N * pk.valid_words_per_tile(pk.TILE_N)
+    assert words == rows_p // 32 == 78_336
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = pk._bin_candidates.lower(
+        aval((CELL_QUERIES, CELL_DIM), jnp.float32),
+        aval((CELL_ROWS, CELL_DIM), jnp.float32),
+        block_q=256, tile_n=pk.TILE_N, survivors=None, precision="bf16x3",
+        interpret=False, terms="hh", dim_chunk=256,
+        db_prepared=(aval((rows_p, 256), jnp.bfloat16),
+                     aval((rows_p,), jnp.float32)),
+        valid_words=aval((CELL_QUERIES, words), jnp.int32),
+    ).compile().as_text()
+    assert "%_bin_candidates" in text
+
+
+def test_the_mask_program_compiles_at_the_filter_cells_shape(one_chip):
+    """``filter_mask`` (ops.tagfilter.mask_words: scalar-prefetched
+    slots and list lengths, the listed ids as SMEM blocks, a dynamic
+    trip count, a dynamic sublane row a bit) at the cell's shape and the
+    rule's own list capacity."""
+    import jax.numpy as jnp
+
+    from knn_tpu.ops import pallas_knn as pk
+    from knn_tpu.ops import tagfilter
+
+    rows_p = -(-CELL_ROWS // pk.TILE_N) * pk.TILE_N
+    cap = tagfilter.list_capacity(rows_p)
+    assert (tagfilter.bitmap_min_rows(rows_p), cap) == (612, 640)
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    vocabulary, maps, ids = 200_386, 4_700, 12_400_000
+    fn = jax.jit(lambda ft, slots, bitmaps, ptr, rows: tagfilter.mask_words(
+        ft, slots, bitmaps, ptr, rows, tile_n=pk.TILE_N, list_cap=cap,
+        interpret=False))
+    text = fn.lower(
+        aval((CELL_QUERIES, 2), jnp.int32), aval((vocabulary,), jnp.int32),
+        aval((tagfilter.SLOT_TAGS + maps, -(-rows_p // 32 // 128 // 8) * 8,
+              128), jnp.int32),
+        aval((vocabulary + 1,), jnp.int32), aval((ids + cap,), jnp.int32),
+    ).compile().as_text()
+    assert "%filter_mask" in text
+
+
 # --- the plain reference ------------------------------------------------------
 def test_the_oracle_is_a_float64_argsort():
     db, q = mix(70_000, 24, dim=24)  # two blocks of rows

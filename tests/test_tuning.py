@@ -329,10 +329,11 @@ def test_default_knobs_are_the_kernel_shaping_arguments():
     assert kwargs_of(
         ShardedKNN.search_certified, "queries", "margin", "selector",
         "batch_size", "return_distances", "recall_target", "tune_cache",
-        "return_sqrt", "_under") == set(tuning.DEFAULT_KNOBS)
+        "return_sqrt", "filter_tags", "_under") == set(tuning.DEFAULT_KNOBS)
     assert kwargs_of(
         ShardedKNN._pallas_setup, "margin", "include_distances", "terms",
-        "batch_rows", "trace_id", "acct") == set(tuning.DEFAULT_KNOBS)
+        "batch_rows", "trace_id", "acct", "masked") == set(
+            tuning.DEFAULT_KNOBS)
 
 
 def test_standard_grid_includes_int8_candidate():
