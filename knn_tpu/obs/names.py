@@ -135,6 +135,7 @@ KERNEL_TERMS = "knn_tpu_kernel_terms_total"
 KERNEL_DIM_CHUNKS = "knn_tpu_kernel_dim_chunks_total"
 FINAL_SELECT_CALLS = "knn_tpu_final_select_calls_total"
 KERNEL_OPERANDS = "knn_tpu_kernel_operands_total"
+CERTIFIED_SUB_BATCH_CALLS = "knn_tpu_certified_sub_batch_calls_total"
 FILTER_QUERIES = "knn_tpu_filter_queries_total"
 FILTER_LIST_IDS = "knn_tpu_filter_list_ids_total"
 
@@ -491,6 +492,17 @@ CATALOG = {
         "analysis.hbm.resident_operands_fit finds the device has "
         "room), 'per_call' the program's prologue, over the whole "
         "corpus in every call."),
+    CERTIFIED_SUB_BATCH_CALLS: (
+        "counter", ("why",),
+        "Calls of search_certified(selector='pallas'), by why their "
+        "sub-batch is what it is (analysis.subbatch.certified_sub_batch): "
+        "'resident' cut by the rule into SUB_BATCHES launches, so that "
+        "the host repairs one while the device runs the next; one batch "
+        "because every launch would re-form the row operands "
+        "('per_call_operands'), because the placed rows' width is no "
+        "whole number of 128-column tiles and every launch would copy "
+        "them ('layout_copy'), or because the call is too few queries "
+        "('small'); 'explicit' the caller's batch_size."),
     MERGE_STRAGGLER_GAP: (
         "gauge", (),
         "Max-minus-min per-host local search wall time of the last "

@@ -314,7 +314,12 @@ class CallAccount:
 
     :meth:`add` sums any other piece of the call that is measured in
     parts (a stage's phases over the sub-batches), for the same
-    once-a-call record.  :meth:`close` records everything through
+    once-a-call record.  ``stages`` (``{span: its parent}``) are the
+    pieces that are the call's own stages, closed once a sub-batch
+    (:func:`stage`): scopes that do not overlap, so their sum is a
+    child of the call like any other and is recorded with ``parent``,
+    where every other record of the account names the call as
+    ``account_of``.  :meth:`close` records everything through
     :func:`record_span`: ``<root>.exposed``, ``<root>.inflight.<program>``
     for every program named at construction, which are the programs
     any call of its kind may launch (0.0 where one did not run, so a
@@ -325,9 +330,10 @@ class CallAccount:
 
     __slots__ = ("root", "_t0", "_idle_from", "_busy_from", "_out",
                  "_n_out", "_since", "inflight", "launches", "exposed",
-                 "union", "before_first", "between", "sums", "sum_attrs")
+                 "union", "before_first", "between", "sums", "sum_attrs",
+                 "stages")
 
-    def __init__(self, root: str, programs, pieces=()):
+    def __init__(self, root: str, programs, pieces=(), stages=None):
         self.root = root
         self._t0 = self._idle_from = time.perf_counter()
         self._busy_from = 0.0
@@ -339,7 +345,8 @@ class CallAccount:
         self.launches = defaultdict(int, dict.fromkeys(programs, 0))
         self.exposed = self.union = self.between = 0.0
         self.before_first = None
-        self.sums = dict.fromkeys(pieces, 0.0)
+        self.stages = dict(stages or {})
+        self.sums = dict.fromkeys((*self.stages, *pieces), 0.0)
         self.sum_attrs = {}
 
     def launched(self, program: str) -> None:
@@ -372,12 +379,13 @@ class CallAccount:
             self.union += now - self._busy_from
             self._idle_from = now
 
-    def add(self, piece: str, seconds: float, **attr_seconds) -> None:
+    def add(self, piece: str, seconds: float, **attr_sums) -> None:
         self.sums[piece] = self.sums.get(piece, 0.0) + seconds
-        if attr_seconds:
+        if attr_sums:
             kept = self.sum_attrs.setdefault(piece, {})
-            for k, v in attr_seconds.items():
-                kept[k] = kept.get(k, 0.0) + v
+            for k, v in attr_sums.items():
+                # a count stays whole: no 0.0 to start from
+                kept[k] = kept[k] + v if k in kept else v
 
     def close(self, trace_id: Optional[str], of: str) -> None:
         """Record the account (class docstring).  ``of`` is the call's
@@ -409,7 +417,9 @@ class CallAccount:
             registry.counter(names.PROGRAM_LAUNCHES, program=program).inc(
                 self.launches[program])
         for piece, seconds in self.sums.items():
-            record_span(piece, trace_id, seconds, account_of=of,
+            whose = ({"parent": self.stages[piece]} if piece in self.stages
+                     else {"account_of": of})
+            record_span(piece, trace_id, seconds, **whose,
                         **self.sum_attrs.get(piece, {}))
 
 
@@ -422,7 +432,7 @@ class _NoopAccount:
     def ready(self, program: str) -> None:
         pass
 
-    def add(self, piece: str, seconds: float, **attr_seconds) -> None:
+    def add(self, piece: str, seconds: float, **attr_sums) -> None:
         pass
 
     def close(self, trace_id, of: str) -> None:
@@ -432,12 +442,12 @@ class _NoopAccount:
 NOOP_ACCOUNT = _NoopAccount()
 
 
-def call_account(root: str, programs, pieces=()):
+def call_account(root: str, programs, pieces=(), stages=None):
     """A :class:`CallAccount` whose clock starts now, or the shared inert
     one when the subsystem is off."""
     if not registry.enabled():
         return NOOP_ACCOUNT
-    return CallAccount(root, programs, pieces)
+    return CallAccount(root, programs, pieces, stages)
 
 
 @contextlib.contextmanager
@@ -465,3 +475,31 @@ def span(name: str, trace_id: Optional[str] = None, **attrs):
         _CURRENT.reset(token)
         record_span(name, sp.trace_id, time.perf_counter() - t0,
                     **sp.attrs)
+
+
+@contextlib.contextmanager
+def stage(acct, name: str, **attrs):
+    """One occurrence of a stage that a call closes once a SUB-BATCH: the
+    scope of :func:`span` (the current :class:`Span` for code below to
+    ``.set`` on, the ``knn.<name>`` profiler annotation, one an
+    occurrence, so a device idle gap is still laid against each
+    stretch), but no record of its own.  Its length and its attributes,
+    which are all counts or seconds, are ADDED to the call's account
+    ``acct`` (:meth:`CallAccount.add`), which records the stage ONCE
+    when the call ends: the series ``knn_tpu_span_seconds{span=name}``
+    counts calls whatever the sub-batches, and a call of one sub-batch
+    records what a :func:`span` would have.  (The body is
+    :func:`span`'s but for its last line; that one is on the serving
+    path's every request and stays one generator deep.)"""
+    if not registry.enabled():
+        yield NOOP_SPAN
+        return
+    sp = Span(name, None, dict(attrs))
+    token = _CURRENT.set(sp)
+    t0 = time.perf_counter()
+    try:
+        with _annotation(name):
+            yield sp
+    finally:
+        _CURRENT.reset(token)
+        acct.add(name, time.perf_counter() - t0, **sp.attrs)

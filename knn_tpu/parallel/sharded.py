@@ -518,6 +518,12 @@ _ACCOUNT_ROOT = "certified"
 _UNPACK_COPIES = "certified.unpack.copies"
 _PALLAS_PIECES = (_refine.PHASE_BUFFERS, _refine.PHASE_SCORE,
                   _refine.PHASE_ORDER, _UNPACK_COPIES)
+#: the stages such a call closes once a SUB-BATCH (``obs.trace.stage``):
+#: each is summed by the call's account too and recorded once a call, a
+#: child of the call, so its series counts calls however the call was cut
+_PALLAS_STAGES = ("certified.dispatch", "certified.device_wait",
+                  "certified.d2h", "certified.unpack",
+                  "certified.rank_correct")
 
 
 def _call_account(selector: str, *more: str):
@@ -526,23 +532,25 @@ def _call_account(selector: str, *more: str):
     it is the first pass of), and the pieces it sums."""
     if selector == "pallas":
         return obs.trace.call_account(
-            _ACCOUNT_ROOT, ("certified", "reselect") + more, _PALLAS_PIECES)
+            _ACCOUNT_ROOT, ("certified", "reselect") + more, _PALLAS_PIECES,
+            dict.fromkeys(_PALLAS_STAGES, _CALL_SPAN))
     return obs.trace.call_account(
         _ACCOUNT_ROOT, ("counted", "count", "reselect") + more)
 
 
-def _staged_fetch(trace_id, acct=obs.trace.NOOP_ACCOUNT):
+def _staged_fetch(acct=obs.trace.NOOP_ACCOUNT):
     """A ``fetch`` for :func:`_fetch_or_redispatch` that reads a
-    certified batch's packed output in two stages at the one point where
-    the host blocks anyway: ``certified.device_wait`` until the device
-    has finished the batch (which the call's account ``acct`` is told),
-    then ``certified.d2h`` for the copy."""
+    certified sub-batch's packed output in two stages at the one point
+    where the host blocks anyway: ``certified.device_wait`` until the
+    device has finished it (which the call's account ``acct`` is told),
+    then ``certified.d2h`` for the copy; both are the account's to
+    record, once a call."""
 
     def fetch(out):
-        with obs.span("certified.device_wait", trace_id, parent=_CALL_SPAN):
+        with obs.trace.stage(acct, "certified.device_wait"):
             jax.block_until_ready(out)
             acct.ready("certified")
-        with obs.span("certified.d2h", trace_id, parent=_CALL_SPAN) as sp:
+        with obs.trace.stage(acct, "certified.d2h") as sp:
             arr = np.asarray(out)
             sp.set("d2h_bytes", arr.nbytes)
         return arr
@@ -893,6 +901,9 @@ class ShardedKNN:
         # "resident" (the placement's, _row_operands) or "per_call"
         # (formed in the program, every call) (_pallas_setup)
         self._operands_source = "per_call"
+        # the last pallas call's sub-batch and why it is that
+        # (_pallas_setup, analysis.subbatch): (rows, why)
+        self._sub_batch = (None, "explicit")
         # the row tile the last program's kernel runs (_pallas_setup):
         # the layout a batch's validity words are made in
         self._kernel_tile = 0
@@ -1763,10 +1774,23 @@ class ShardedKNN:
 
         ``batch_size`` streams the queries in fixed-size batches with the
         device stages pipelined against the host stages: every batch's
-        coarse select is dispatched up front (one compiled shape), so the
-        host refine / device->host transfer of batch b overlaps the
-        device work of batches > b.  None = one batch (all queries at
-        once).
+        device program is dispatched up front (one compiled shape, the
+        last batch padded to it), so the host's share of batch b (the
+        refine, or the copy down, unpack and tie repair) overlaps the
+        device work of batches > b; the fallback repair runs once, over
+        the whole call's flagged queries.  The answer does not depend
+        on it.  None: the counted selectors run one batch (all queries
+        at once); ``selector="pallas"`` asks the rule of what the call
+        can see (``analysis.subbatch.certified_sub_batch``, no knob):
+        ``SUB_BATCHES`` equal sub-batches of whole query blocks where a
+        further launch costs the device next to nothing (resident row
+        operands, placed rows a whole number of 128-column tiles wide)
+        and the call holds that many sub-batches of 1,024 queries; one
+        batch everywhere else, so a call of a few hundred queries is
+        the launch it always was.  ``stats["batches"]`` says how many
+        ran and ``stats["sub_batch"]`` why (``resident`` the rule's cut;
+        ``per_call_operands``, ``layout_copy``, ``small`` what kept it
+        to one; ``explicit`` this argument).
 
         Pallas-selector tuning knobs (``tile_n``, ``block_q``,
         ``survivors``, ``precision``, ``final_select``,
@@ -1902,18 +1926,13 @@ class ShardedKNN:
                 if batch_size is not None and batch_size < 1:
                     raise ValueError(
                         f"batch_size must be >= 1, got {batch_size}")
+                # the counted selectors: one batch unless the caller
+                # cuts; the pallas selector's is setup's to resolve
                 bs = n_q if batch_size is None else batch_size
                 # the db-side term of the certificate tolerance is
                 # query-independent and cached across calls (a float64
                 # pass over all N rows)
                 db_norm_max = self._db_norm_max()
-                batches = []
-                for lo in range(0, n_q, bs):
-                    chunk = q_np[lo : lo + bs]
-                    pad = bs - chunk.shape[0]
-                    if pad:  # one compiled shape for the tail too
-                        chunk = np.pad(chunk, ((0, pad), (0, 0)))
-                    batches.append((lo, chunk, pad))
 
                 d = np.empty((n_q, self.k))
                 i = np.empty((n_q, self.k), dtype=np.int64)
@@ -1952,9 +1971,12 @@ class ShardedKNN:
                             f"kernel='tiled'")
                     prog, m_prog, w, interpret = self._pallas_setup(
                         m - self.k, include_distances=device_d,
-                        terms=terms, batch_rows=bs, trace_id=tid,
-                        acct=acct, **knobs,
+                        terms=terms, batch_rows=batch_size, call_rows=n_q,
+                        trace_id=tid, acct=acct, **knobs,
                         **({"masked": True} if ft is not None else {}))
+                    # the sub-batch: the caller's, or the rule's reading
+                    # of what setup resolved (analysis.subbatch)
+                    bs, sub_why = self._sub_batch
                     ops_tail = self._pallas_operands(knobs["precision"])
                     if ft is not None:
                         # the tag index in the resolved tile's layout
@@ -1963,6 +1985,13 @@ class ShardedKNN:
                         index = self._tag_index(self._kernel_tile)
                         mask = self._filter_words(ft, index, interpret,
                                                   tid, acct)
+                batches = []
+                for lo in range(0, n_q, bs):
+                    chunk = q_np[lo : lo + bs]
+                    pad = bs - chunk.shape[0]
+                    if pad:  # one compiled shape for the tail too
+                        chunk = np.pad(chunk, ((0, pad), (0, 0)))
+                    batches.append((lo, chunk, pad))
             call.set("queries", n_q)
             call.set("batches", len(batches))
             call.set("metric", self.metric)
@@ -2119,11 +2148,18 @@ class ShardedKNN:
                 merged["operands"] = self._operands_source
                 obs.counter(_mn.KERNEL_OPERANDS,
                             source=self._operands_source).inc(len(batches))
+                # how many launches the call was cut into and why
+                # (analysis.subbatch.REASONS): the rule's cut, what kept
+                # the rule from cutting, or the caller's batch_size
+                merged["sub_batch"] = sub_why
+                obs.counter(_mn.CERTIFIED_SUB_BATCH_CALLS,
+                            why=sub_why).inc()
             for key, value in merged.items():
                 call.set(key, value)
             stats = {
                 "fallback_queries": int(bad.size),
                 "certified": n_q - int(bad.size),
+                "batches": len(batches),
                 **repair,
                 **merged,
             }
@@ -2162,7 +2198,8 @@ class ShardedKNN:
                     "dim_chunk": merged["dim_chunk"],
                     "dim_chunks": merged["dim_chunks"],
                     "final_select_stage": merged["final_select_stage"],
-                    "operands": merged["operands"]}
+                    "operands": merged["operands"],
+                    "sub_batch": sub_why, "batches": len(batches)}
                 stats["tuning"] = tune_info
             # mirror the quality signals into the telemetry registry —
             # the per-call stats dict stays the API, the registry
@@ -2609,6 +2646,7 @@ class ShardedKNN:
                       kernel: str = "tiled",
                       terms: str = "hh+hl+lh",
                       batch_rows: Optional[int] = None,
+                      call_rows: Optional[int] = None,
                       trace_id: Optional[str] = None,
                       acct=obs.trace.NOOP_ACCOUNT,
                       masked: bool = False):
@@ -2641,6 +2679,15 @@ class ShardedKNN:
         as arguments where they are kept, :meth:`_pallas_operands` then
         hands them over, and ``self._operands_source`` says which.
 
+        And the SUB-BATCH of a call of ``call_rows`` queries, which
+        follows from them: ``batch_rows`` where the caller names one,
+        else what ``analysis.subbatch.certified_sub_batch`` reads off
+        the operands' source, the placed rows' width, the query block
+        and the mesh's query shards.  ``self._sub_batch`` is ``(rows,
+        why)``; the query block and the dim chunks above are resolved at
+        those rows.  Without ``call_rows`` (the probes that launch the
+        program themselves) it is ``batch_rows`` as given.
+
         ``masked`` builds the program that takes a batch's validity
         words after that tail (a ``filter_tags`` call); the resolved row
         tile, whose layout the words are in, is ``self._kernel_tile``."""
@@ -2656,6 +2703,7 @@ class ShardedKNN:
             select_merge_geometry,
         )
 
+        from knn_tpu.analysis.subbatch import certified_sub_batch
         from knn_tpu.utils.config import CERTIFIED_PRECISIONS
 
         if precision not in CERTIFIED_PRECISIONS:
@@ -2698,17 +2746,24 @@ class ShardedKNN:
         self._final_select_stage = (
             "pallas" if final_select == "exact" and final_select_geometry(
                 self._select_widths[1], m) is not None else "xla")
+        resident = precision == "bf16x3" and self._row_operands(
+            eff_tile, "hl" in terms, trace_id=trace_id, acct=acct)
+        self._operands_source = "resident" if resident else "per_call"
         bq = block_q or BLOCK_Q
+        q_shards = self.mesh.shape[QUERY_AXIS]
+        self._sub_batch = (
+            (batch_rows, "explicit") if call_rows is None
+            else certified_sub_batch(
+                call_rows, batch_size=batch_rows,
+                operands=self._operands_source, width=self._tp.shape[1],
+                block_q=bq, query_shards=q_shards))
+        batch_rows = self._sub_batch[0]
         if batch_rows is not None:
-            bq = effective_block_q(
-                bq, -(-batch_rows // self.mesh.shape[QUERY_AXIS]))
+            bq = effective_block_q(bq, -(-batch_rows // q_shards))
         self._dim_chunking = dim_chunking(
             self._tp.shape[1], tile_n=eff_tile, block_q=bq,
             precision=precision, kernel=kernel, terms=terms,
             survivors=survivors)
-        resident = precision == "bf16x3" and self._row_operands(
-            eff_tile, "hl" in terms, trace_id=trace_id, acct=acct)
-        self._operands_source = "resident" if resident else "per_call"
         # the program gets setup's RESOLVED tile, not the raw request:
         # m was capped so that width(eff_tile) >= m+2, which makes the
         # kernel's own effective_tile(min_width=m+2) a fixpoint — the
@@ -2745,14 +2800,18 @@ class ShardedKNN:
         inner-product placement's runs are ordered by inner product, not
         by the augmented difference).  Returns (flagged query
         indices, rank-corrected query count).  ``prog`` and ``w`` are
-        :meth:`_pallas_setup`'s, ``ops_tail`` :meth:`_pallas_operands`'s;
-        each batch's stages are spans of the caller's ``trace_id``
-        (``certified.dispatch``, ``.device_wait``, ``.d2h``, ``.unpack``,
-        ``.rank_correct``).  The call's account ``acct`` is told of every
-        launch and fetch, and is handed what ``unpack_certified`` and
-        ``rank_correct_runs`` say of their own insides (the copies; the
-        buffers, the re-score and the ordering), summed over the batches
-        for the call's once-a-call records.
+        :meth:`_pallas_setup`'s, ``ops_tail`` :meth:`_pallas_operands`'s.
+        Every sub-batch's program is launched before the first is
+        fetched, so the host's share of sub-batch b (the copy down, the
+        unpack, the tie repair) runs while the device is on b+1.  Each
+        sub-batch's stages (``certified.dispatch``, ``.device_wait``,
+        ``.d2h``, ``.unpack``, ``.rank_correct``) are profiler
+        annotations one an occurrence and ONE record a call, the sum
+        over the sub-batches (``obs.trace.stage``): the call's account
+        ``acct`` keeps them, is told of every launch and fetch, and is
+        handed what ``unpack_certified`` and ``rank_correct_runs`` say of
+        their own insides (the copies; the buffers, the re-score and the
+        ordering), summed likewise.
 
         ``mask`` (a ``filter_tags`` call: :meth:`_filter_words`' maker
         of a batch's validity words from its slice of the tag ids)
@@ -2763,7 +2822,7 @@ class ShardedKNN:
         from knn_tpu.ops.refine import rank_correct_runs
 
         k = self.k
-        fetch = _staged_fetch(trace_id, acct)
+        fetch = _staged_fetch(acct)
         if precision in ("int8", "pq") and obs.enabled():
             # the per-query certified quantization bound ε — the quality
             # signal the device certificate computes and discards
@@ -2793,14 +2852,12 @@ class ShardedKNN:
             take = bs - pad
             packed_np = _fetch_or_redispatch(packed, redo, "pallas fetch",
                                              fetch=fetch)
-            with obs.span("certified.unpack", trace_id,
-                          parent=_CALL_SPAN) as sp:
+            with obs.trace.stage(acct, "certified.unpack") as sp:
                 gi_np, tight_np, bad_np, dk_np = unpack_certified(
                     packed_np[:take], k, w, want_distances
                 )
             acct.add(_UNPACK_COPIES, sp.attrs.get("copies_s", 0.0))
-            with obs.span("certified.rank_correct", trace_id,
-                          parent=_CALL_SPAN) as sp:
+            with obs.trace.stage(acct, "certified.rank_correct") as sp:
                 own = {}  # the caller's share of the buffers
                 with obs.trace.phase(own, "buffers_s",
                                      _refine.PHASE_BUFFERS):
@@ -2824,14 +2881,14 @@ class ShardedKNN:
             i[lo : lo + take] = ic
             bad_mask[lo : lo + take] = bad_np
 
-        # stage 1: dispatch every batch (async on device)
+        # stage 1: dispatch every sub-batch (async on device)
         outs = []
         for lo, chunk, pad in batches:
             tail = ops_tail
             if mask is not None:
                 tail += (mask(lo, bs - pad, bs),)
-            with obs.span("certified.dispatch", trace_id,
-                          parent=_CALL_SPAN, h2d_bytes=chunk.nbytes):
+            with obs.trace.stage(acct, "certified.dispatch",
+                                 h2d_bytes=chunk.nbytes):
                 qp, _ = self._place_queries(chunk)
                 begun = _hooks.first_call_begin()
                 outs.append((qp, tail, _retry_transient(
@@ -2841,7 +2898,8 @@ class ShardedKNN:
                 _hooks.first_call_end(begun, prog, "certified", trace_id,
                                       rows=qp.shape[0])
 
-        # stage 2: per batch — fetch + repair, in dispatch order
+        # stage 2: per sub-batch — fetch + repair, in dispatch order, the
+        # later ones' programs on the device meanwhile
         for (lo, chunk, pad), (qp, tail, packed) in zip(batches, outs):
             if mask is not None:
                 jax.block_until_ready(tail[-1])

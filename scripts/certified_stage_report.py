@@ -7,7 +7,10 @@ tracing (docs/OBSERVABILITY.md "Span lifecycle"), in three sub-commands:
     python scripts/certified_stage_report.py idle <trace dir or .xplane.pb> [--device-plane /device:TPU:N]
 
 ``spans`` reads a ``KNN_TPU_OBS_LOG`` file: per ``certified.*`` stage
-the mean ms a call and a batch, the self time of ``certified.call`` (its
+the mean ms a call and a batch (a stage that closes once a sub-batch is
+ONE record a call, the sum of its scopes: ``spans`` counts calls, and
+``per_batch`` is that sum over the call's launches), the self time of
+``certified.call`` (its
 length less its children's) and the share of it the children cover;
 beside them the call's once-a-call account (``account_ms``: the exposed
 seconds, the seconds in flight by device program, the insides of

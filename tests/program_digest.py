@@ -10,6 +10,13 @@ unfiltered program (say so in the PR):
 
     JAX_PLATFORMS=cpu PYTHONPATH=<tree> python tests/program_digest.py \\
         > tests/fixtures/unfiltered_program_digests.json
+
+``tests/fixtures/sub_batch_program_digests.json`` holds the same
+program's digests at the SUB-BATCH rows a default call may be cut into
+(``SUB_BATCH_ROWS`` of a cell's 4,096 queries), from the tree before the
+default sub-batch was a rule (PR 42's parent, where only an explicit
+``batch_size`` gave them); ``tests/test_sub_batch.py`` holds the rule's
+cut to them.  Recorded by the same command with ``--sub-batches``.
 """
 
 import hashlib
@@ -26,9 +33,11 @@ CELLS = {
     "ssnpp2m5.sweep_range": (1, 2_500_000, 256, 100, "hh", 1, False),
 }
 QUERIES, MARGIN = 4096, 28
+#: the rows of a cell's call cut in two and in four
+SUB_BATCH_ROWS = (2048, 1024)
 
 
-def jaxpr_text(cell: str) -> str:
+def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
     import jax
     import jax.numpy as jnp
 
@@ -59,7 +68,7 @@ def jaxpr_text(cell: str) -> str:
     if dot:
         tail += [aval((), jnp.float32)]
     text = str(jax.make_jaxpr(prog)(
-        aval((QUERIES, dim), jnp.float32),
+        aval((queries, dim), jnp.float32),
         aval((shards * rows, dim), jnp.float32), *tail))
     # addresses, and the order a frozenset happens to print in
     text = re.sub(r"0x[0-9a-f]+", "0x", text)
@@ -67,13 +76,24 @@ def jaxpr_text(cell: str) -> str:
                   % ", ".join(sorted(m.group(1).split(", "))), text)
 
 
+def digest(cell: str, queries: int = QUERIES) -> str:
+    return hashlib.sha256(jaxpr_text(cell, queries).encode()).hexdigest()
+
+
 def digests() -> dict:
-    return {cell: hashlib.sha256(jaxpr_text(cell).encode()).hexdigest()
+    return {cell: digest(cell) for cell in CELLS}
+
+
+def sub_batch_digests() -> dict:
+    return {cell: {str(rows): digest(cell, rows) for rows in SUB_BATCH_ROWS}
             for cell in CELLS}
 
 
 if __name__ == "__main__":
+    import sys
+
     import jax
 
     jax.config.update("jax_num_cpu_devices", 8)
-    print(json.dumps(digests(), indent=1))
+    print(json.dumps(sub_batch_digests() if "--sub-batches" in sys.argv
+                     else digests(), indent=1))

@@ -78,6 +78,8 @@ def _spans(prefix="certified."):
             if e.get("type") == "span" and e["span"].startswith(prefix)]
 
 
+#: the stages a call closes once a SUB-BATCH: a profiler annotation an
+#: occurrence, ONE record a call (the sum, through the call's account)
 PER_BATCH = ("certified.dispatch", "certified.device_wait", "certified.d2h",
              "certified.unpack", "certified.rank_correct")
 #: the account's records: one a call whatever the sub-batches, every one
@@ -92,7 +94,9 @@ PHASES = {"knn.certified.rank_correct.buffers",
           "knn.certified.rank_correct.order"}
 
 
-def _expected(batches: int, reselects: int = 0) -> Counter:
+def _expected(batches: int = 1, reselects: int = 0) -> Counter:
+    """The records of one call, whatever its sub-batches; with
+    ``batches`` the scopes it opens (its profiler annotations)."""
     want = Counter({"certified.call": 1, "certified.prepare": 1,
                     "certified.repair": 1})
     for name in PER_BATCH:
@@ -114,7 +118,7 @@ def test_a_certified_call_emits_exactly_its_stage_spans(placed, corpus, kw,
     d, i, stats = placed.search_certified(corpus[1], selector="pallas", **kw)
     assert stats["fallback_queries"] == 0  # so no re-select is expected
     spans = _spans()
-    assert Counter(e["span"] for e in spans) == _expected(batches)
+    assert Counter(e["span"] for e in spans) == _expected()
 
     tids = {e.get("trace_id") for e in spans}
     assert len(tids) == 1 and None not in tids
@@ -134,23 +138,22 @@ def test_a_certified_call_emits_exactly_its_stage_spans(placed, corpus, kw,
     assert sum(e["dur_s"] for e in children) <= call["dur_s"] + 1e-4
 
     assert by["certified.prepare"]["first_call"] is False
-    assert by["certified.dispatch"]["h2d_bytes"] == (
-        N_QUERIES // batches * 32 * 4)
+    # a stage's record is the call's: the sum over its sub-batches
+    assert by["certified.dispatch"]["h2d_bytes"] == N_QUERIES * 32 * 4
     assert by["certified.d2h"]["d2h_bytes"] > 0
-    corrections = [e for e in spans if e["span"] == "certified.rank_correct"]
-    assert sum(e["queries_corrected"] for e in corrections) == stats[
-        "rank_corrected_queries"]
-    for e in corrections:
-        # a tight pair involves two positions; at 32 columns one block
-        # holds 32,768 members, so a batch here is one block or none
-        assert e["members"] >= 2 * e["queries_corrected"]
-        assert e["blocks"] == (1 if e["members"] else 0)
+    (corrected,) = [e for e in spans
+                    if e["span"] == "certified.rank_correct"]
+    assert corrected["queries_corrected"] == stats["rank_corrected_queries"]
+    # a tight pair involves two positions; at 32 columns one block
+    # holds 32,768 members, so a batch here is one block or none
+    assert corrected["members"] >= 2 * corrected["queries_corrected"]
+    assert 0 <= corrected["blocks"] <= batches
     assert by["certified.repair"]["fallback_queries"] == 0
     assert by["certified.repair"]["host_exact_queries"] == 0
     # the same spans feed the histogram an operator scrapes
     series = {s["labels"]["span"]: s["value"]["count"]
               for s in obs.snapshot()[mn.SPAN_SECONDS]["series"]}
-    assert series["certified.device_wait"] == batches
+    assert {series[name] for name in PER_BATCH} == {1}
     assert series["certified.call"] == 1
     assert {series[name] for name in ONCE_A_CALL} == {1}
 
@@ -322,7 +325,7 @@ def test_the_stage_report_reads_the_jsonl_log(placed, corpus, report,
     obs.reset_event_log(None)
     table = report.stage_table(report.read_jsonl(str(log)), skip_calls=1)
     assert (table["calls"], table["batches"]) == (2, 6)
-    assert set(table["stages_ms"]) == set(_expected(3)) - set(ONCE_A_CALL)
+    assert set(table["stages_ms"]) == set(_expected()) - set(ONCE_A_CALL)
     # the account's records beside the stages, each a mean per CALL
     assert set(table["account_ms"]) == set(ONCE_A_CALL)
     exposed = table["account_ms"]["certified.exposed"]
@@ -331,7 +334,10 @@ def test_the_stage_report_reads_the_jsonl_log(placed, corpus, report,
         exposed["call_ms"], abs=1e-3)
     assert exposed["call_ms"] <= table["stages_ms"]["certified.call"][
         "per_call"]
-    assert table["stages_ms"]["certified.d2h"]["spans"] == 6
+    # a stage is one record a call, its per_batch mean over the launches
+    d2h = table["stages_ms"]["certified.d2h"]
+    assert d2h["spans"] == 2
+    assert d2h["per_batch"] == pytest.approx(d2h["per_call"] / 3, abs=1e-4)
     assert table["stages_ms"]["certified.call"]["spans"] == 2
     assert 0 < table["children_share_of_call"] <= 1
     assert table["call_self_ms_per_call"] >= 0
@@ -562,8 +568,9 @@ def test_obs_off_leaves_a_range_call_its_answers_and_no_record(
 def test_the_accounts_records_count_calls_and_the_stages_batches(
         placed, corpus, batch_size, batches):
     """Why the account: cut a call into sub-batches and every stage
-    span closes that many times (a reader of its mean reads 1/n with no
-    work saved), while every record of the account stays one a call."""
+    scope closes that many times (a series of scopes would read 1/n
+    with no work saved); every record of the account, the stages' sums
+    among them, stays one a call."""
     for _ in range(2):
         placed.search_certified(corpus[1], selector="pallas",
                                 batch_size=batch_size)
@@ -572,7 +579,7 @@ def test_the_accounts_records_count_calls_and_the_stages_batches(
     for name in ONCE_A_CALL:
         assert series[name]["count"] == 2, name
     for name in PER_BATCH:
-        assert series[name]["count"] == 2 * batches, name
+        assert series[name]["count"] == 2, name
     spans = _spans()
     for name, of in (("certified.rank_correct", "certified.rank_correct."),
                      ("certified.unpack", "certified.unpack.")):
@@ -581,7 +588,7 @@ def test_the_accounts_records_count_calls_and_the_stages_batches(
         whole = sum(e["dur_s"] for e in spans if e["span"] == name)
         # the pieces are inside their stage (its own overhead is left)
         assert 0 < parts <= whole + 1e-4
-    exposed, inflight = _account(spans[-len(_expected(batches)):])
+    exposed, inflight = _account(spans[-sum(_expected().values()):])
     assert inflight["certified"]["launches"] == batches
 
 
@@ -628,8 +635,7 @@ def test_two_threads_calls_keep_separate_accounts(placed, corpus):
     assert len(calls) == 2
     for tid, call_span in calls.items():
         mine = [e for e in spans if e["trace_id"] == tid]
-        assert Counter(e["span"] for e in mine) == _expected(
-            call_span["batches"])
+        assert Counter(e["span"] for e in mine) == _expected()
         exposed, inflight = _account(mine)
         assert inflight["certified"]["launches"] == call_span["batches"]
         assert exposed["dur_s"] + exposed[

@@ -367,7 +367,8 @@ def test_the_event_says_what_the_kernel_was_given(fresh_registry, rng,
     assert (call["dim_chunk"], call["dim_chunks"]) == want
     knobs = {kk: v for kk, v in stats["pallas_knobs"].items()
              if kk not in ("interpret", "terms", "mxu_passes", "dim_chunk",
-                           "dim_chunks", "final_select_stage", "operands")}
+                           "dim_chunks", "final_select_stage", "operands",
+                           "sub_batch", "batches")}
     if kernel == "tiled":
         grids = program_grids(placed, q[:batch], batch_rows=batch,
                               terms=stats["terms"], **knobs)

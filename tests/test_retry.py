@@ -148,10 +148,11 @@ class _FlakyReady:
 @pytest.mark.parametrize("stage", ["certified.device_wait", "certified.d2h"])
 def test_certified_pallas_staged_fetch_retries_either_stage(
         data, monkeypatch, no_backoff, stage):
-    """The fetch is two spans at one blocking point; a transient failure
+    """The fetch is two stages at one blocking point; a transient failure
     in either still goes through ``_fetch_or_redispatch``: the batch is
     dispatched again, the answer is exact, and the failed attempt's
-    span is on record beside the good one."""
+    scope is in the stage's one record of the call (its seconds in the
+    sum, its profiler annotation beside the good one's)."""
     db, q = data
     real = sh._pallas_certified_program
     state = {"tripped": False, "calls": 0}
@@ -169,6 +170,14 @@ def test_certified_pallas_staged_fetch_retries_either_stage(
         return wrapper
 
     monkeypatch.setattr(sh, "_pallas_certified_program", flaky_pallas_program)
+    scopes = Counter()
+    real_add = obs.trace.CallAccount.add
+
+    def add(self, piece, seconds, **attrs):
+        scopes[piece] += 1
+        real_add(self, piece, seconds, **attrs)
+
+    monkeypatch.setattr(obs.trace.CallAccount, "add", add)
     obs.reset(enabled=True)
     obs.reset_event_log(None)
     try:
@@ -182,9 +191,11 @@ def test_certified_pallas_staged_fetch_retries_either_stage(
         obs.reset_event_log(from_env=True)
     np.testing.assert_array_equal(i, ref_i)
     assert state["tripped"] and state["calls"] == 2  # dispatched again
-    assert spans[stage] == 2
-    assert spans["certified.device_wait"] + spans["certified.d2h"] == (
+    # the failed attempt's scope and the good one's, one record of both
+    assert scopes[stage] == 2 and spans[stage] == 1
+    assert scopes["certified.device_wait"] + scopes["certified.d2h"] == (
         4 if stage == "certified.d2h" else 3)
+    assert spans["certified.device_wait"] == spans["certified.d2h"] == 1
     assert spans["certified.dispatch"] == 1 and spans["certified.call"] == 1
 
 

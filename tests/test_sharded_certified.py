@@ -162,8 +162,15 @@ def test_pallas_batched_matches_unbatched(rng, batch_size, ties):
     np.testing.assert_array_equal(i1, i0)
     np.testing.assert_array_equal(d1, d0)
     for key in ("fallback_queries", "certified", "rank_corrected_queries",
-                "pallas_knobs", "select_width"):
+                "select_width"):
         assert s1[key] == s0[key], key
+    # the knobs too, but for how the call was cut, which is the point
+    cut = ("sub_batch", "batches")
+    assert ({k: v for k, v in s1["pallas_knobs"].items() if k not in cut}
+            == {k: v for k, v in s0["pallas_knobs"].items() if k not in cut})
+    assert (s0["sub_batch"], s0["batches"]) == ("layout_copy", 1)  # 12 wide
+    assert (s1["sub_batch"], s1["batches"]) == (
+        "explicit", -(-queries.shape[0] // batch_size))
     assert s1["certified"] + s1["fallback_queries"] == queries.shape[0]
 
 

@@ -304,10 +304,12 @@ def test_version_6_winner_naming_a_removed_knob_is_never_used(
     # beside the knobs: what the program resolved for itself, from the
     # backend (interpret), from the data (terms, mxu_passes) and from
     # the launch's shape (dim_chunk, dim_chunks, final_select_stage) and
-    # from the device's memory (operands)
+    # from the device's memory (operands), and how the call was cut
+    # (sub_batch, batches: analysis.subbatch)
     assert {kk: v for kk, v in stats["pallas_knobs"].items()
             if kk not in ("interpret", "terms", "mxu_passes", "dim_chunk",
-                          "dim_chunks", "final_select_stage", "operands")
+                          "dim_chunks", "final_select_stage", "operands",
+                          "sub_batch", "batches")
             } == tuning.DEFAULT_KNOBS
     assert (stats["pallas_knobs"]["dim_chunk"],
             stats["pallas_knobs"]["dim_chunks"]) == (128, 1)
@@ -332,7 +334,7 @@ def test_default_knobs_are_the_kernel_shaping_arguments():
         "return_sqrt", "filter_tags", "_under") == set(tuning.DEFAULT_KNOBS)
     assert kwargs_of(
         ShardedKNN._pallas_setup, "margin", "include_distances", "terms",
-        "batch_rows", "trace_id", "acct", "masked") == set(
+        "batch_rows", "call_rows", "trace_id", "acct", "masked") == set(
             tuning.DEFAULT_KNOBS)
 
 
