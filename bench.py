@@ -1309,8 +1309,9 @@ def main() -> None:
         gi, tight, badf, dk = unpack_certified(packed[:NQ], K, w, True)
         t0 = time.perf_counter()
         # the certified space's arrays: for cosine that is the unit-
-        # normalized pair (prog's host train is the placed/normalized db)
-        rank_correct_runs(gi, tight, K, pb_queries, prog._host_train(),
+        # normalized pair (the rows as placed; prog's host copy is the
+        # rows as given since PR 43)
+        rank_correct_runs(gi, tight, K, pb_queries, prog._placed_host(),
                           d32k=dk.astype(np.float64))
         host = time.perf_counter() - t0
         mb = packed.nbytes / 1e6

@@ -74,6 +74,8 @@ CERTIFIED_FALSE_ALARMS = "knn_tpu_certified_fallback_false_alarms_total"
 CERTIFIED_HOST_EXACT = "knn_tpu_certified_host_exact_queries_total"
 CERTIFIED_RANK_CORRECTED = "knn_tpu_certified_rank_corrected_queries_total"
 CERTIFIED_METRIC_QUERIES = "knn_tpu_certified_metric_queries_total"
+CERTIFIED_SLACK_QUERIES = "knn_tpu_certified_slack_queries_total"
+RANK_CORRECT_MEMBERS = "knn_tpu_rank_correct_members_total"
 CERTIFIED_QUANT_BOUND = "knn_tpu_certified_quant_bound"
 RANGE_QUERIES = "knn_tpu_range_queries_total"
 RANGE_RESULTS = "knn_tpu_range_results_total"
@@ -292,6 +294,24 @@ CATALOG = {
         "Queries processed by ShardedKNN.search_certified, by the "
         "placement's metric (l2 / cosine / dot): which contract the "
         "answers were held to."),
+    CERTIFIED_SLACK_QUERIES: (
+        "counter", ("outcome",),
+        "Queries of search_certified(selector='pallas') on a COSINE "
+        "placement, by what its certificate said: 'certified'; "
+        "'uncertified_by_slack' (it holds without the placement's pair "
+        "slack, COS_UNIT_SLACK for the rounding of the unit rows, and "
+        "fails with it: what the exactness of the rows as given costs "
+        "in repairs); 'uncertified' (every other flagged query: the "
+        "certificate fails without the slack too, the tie window has no "
+        "provable boundary, or a zero row is among the candidates).  "
+        "Every outcome exists from the first such call, at 0 where "
+        "nothing took it."),
+    RANK_CORRECT_MEMBERS: (
+        "counter", (),
+        "Candidate rows the host's float64 rank correction gathered and "
+        "re-scored (ops.refine.rank_correct_runs' members, summed over "
+        "the sub-batches of search_certified(selector='pallas') calls): "
+        "what a pair slack widens and a wide row makes dear."),
     CERTIFIED_QUANT_BOUND: (
         "histogram", (),
         "Per-query int8 certified quantization error bound epsilon "

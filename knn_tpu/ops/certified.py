@@ -48,7 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from knn_tpu.ops.refine import refine_exact
+from knn_tpu.ops.refine import norms_rows, refine_exact
 from knn_tpu.ops.topk import knn_search_tiled
 
 
@@ -140,17 +140,25 @@ def certification_tolerance(
 
 def host_exact_knn(
     db_np: np.ndarray, q_np: np.ndarray, k: int, *, tile: Optional[int] = None,
-    q_chunk: int = 8, metric: str = "l2",
+    q_chunk: int = 8, metric: str = "l2", norms=None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Unconditional last-resort exact KNN: tiled float64 direct-difference
     full scan on host (no expanded-square cancellation, no approximation,
     no certificate needed).  O(Q*N*D) host FLOPs — only for the handful of
     queries that fail re-certification after the widened fallback.
     ``metric="dot"`` scans by the negated float64 inner product instead
-    (each product of two float32 values is exact in float64)."""
+    (each product of two float32 values is exact in float64), and
+    ``metric="cosine"`` by ``1 - q.t / (|q| |t|)`` from the same
+    products (``norms``: ``(query norms [Q], row norms [N])`` in float64
+    where the caller keeps them, ops.refine.row_norms_f64's otherwise;
+    a zero norm has cosine 0 to everything)."""
+    from knn_tpu.ops.refine import cosine_distance, row_norms_f64
+
     n = db_np.shape[0]
     n_q = q_np.shape[0]
     k = min(k, n)
+    if metric == "cosine" and norms is None:
+        norms = row_norms_f64(q_np), row_norms_f64(db_np)
     if tile is None:
         # bound the [q_chunk, tile, D] float64 broadcast temporaries at a
         # fixed ~128 MB budget regardless of dimensionality
@@ -164,6 +172,11 @@ def host_exact_knn(
             t = db_np[lo : lo + tile].astype(np.float64)
             if metric == "dot":
                 dt = -(qf[:, None, :] * t[None, :, :]).sum(-1)
+            elif metric == "cosine":
+                dt = cosine_distance(
+                    (qf[:, None, :] * t[None, :, :]).sum(-1),
+                    norms[0][qlo : qlo + q_chunk, None]
+                    * norms[1][None, lo : lo + tile])
             else:
                 dt = ((qf[:, None, :] - t[None, :, :]) ** 2).sum(-1)
             it = np.broadcast_to(
@@ -190,8 +203,11 @@ def repair_uncertified(
     select_fn,
     max_widen: int,
     db_norm_max: Optional[float] = None,
-    dot_shift: Optional[float] = None,
-    dot_slack: float = 0.0,
+    metric: str = "l2",
+    pair_slack: float = 0.0,
+    dot_shift: float = 0.0,
+    rank_queries: Optional[np.ndarray] = None,
+    norms=None,
     valid_rows_fn=None,
 ) -> dict:
     """Shared fallback repair for both certified pipelines (single-device
@@ -213,18 +229,33 @@ def repair_uncertified(
        queries whose k-th/widen-th gap is inside the f32 tolerance
        (heavy duplicate ties) — structurally rare.
 
-    ``dot_shift`` (inner-product placements, parallel.sharded: rows and
-    queries arrive norm-augmented, ``dot_shift`` = M, the largest squared
-    row norm): the refine and the host scan rank by the float64 NEGATED
-    INNER PRODUCT s = -q.t on the arrays as given (the query's appended
-    column is an exact zero), so ``d`` holds s at the repaired rows.  The
-    selection still scores in the augmented squared-L2 space, where a
-    row's exact value is D' = |q|^2 + M + 2 s + c_t with |c_t| <=
-    ``dot_slack`` / 2 (the appended column's float32 rounding).  So step
-    2 compares there: a row NOT selected has D'(u) >= v_w - tol, hence
-    2 s(u) >= v_w - tol - dot_slack / 2 - |q|^2 - M, and
-    ``|q|^2 + M + 2 s_k + tol + dot_slack < v_w`` proves s(u) > s_k
-    with dot_slack / 2 to spare.
+    ``metric="dot"`` (inner-product placements, parallel.sharded: rows
+    and queries arrive norm-augmented, ``dot_shift`` = M, the largest
+    squared row norm): the refine and the host scan rank by the float64
+    NEGATED INNER PRODUCT s = -q.t on the arrays as given (the query's
+    appended column is an exact zero), so ``d`` holds s at the repaired
+    rows.  The selection still scores in the augmented squared-L2 space,
+    where a row's exact value is D' = |q|^2 + M + 2 s + c_t with |c_t|
+    <= ``pair_slack`` / 2 (the appended column's float32 rounding).  So
+    step 2 compares there: a row NOT selected has D'(u) >= v_w - tol,
+    hence 2 s(u) >= v_w - tol - pair_slack / 2 - |q|^2 - M, and
+    ``|q|^2 + M + 2 s_k + tol + pair_slack < v_w`` proves s(u) > s_k
+    with pair_slack / 2 to spare.
+
+    ``metric="cosine"`` (cosine placements: ``q_np`` holds the float32
+    UNIT queries the selection runs on, ``rank_queries`` the queries AS
+    GIVEN, ``db_np`` the rows as given, ``norms`` their float64 norms):
+    the refine and the host scan rank by the float64 cosine distance c
+    = 1 - q.t / (|q| |t|) of the values as given, so ``d`` holds c at
+    the repaired rows.  The selection scores the placed unit rows,
+    where a row's exact value is D' = 2 c + p_t with |p_t| <
+    ``pair_slack`` (the normalisation's float32 rounding on both sides,
+    parallel.sharded.COS_UNIT_SLACK).  A row NOT selected has D'(u) >=
+    v_w - tol, hence 2 c(u) > v_w - tol - pair_slack, and ``2 c_k + tol
+    + pair_slack < v_w`` proves c(u) > c_k.  A row of zero norm is
+    placed as it is, at D' = |q^|^2 <= 1 + 2^-22, and has c = 1: left
+    out of a selection that this inequality proves, it has v_w <= D' +
+    tol and so 2 c_k < 1 + 2^-22, c_k < c.
 
     ``valid_rows_fn(position) -> ascending row ids`` (a filtered call,
     parallel.sharded: ``select_fn`` then selects among each query's
@@ -252,18 +283,21 @@ def repair_uncertified(
     widen = min(max(2 * m, m + 64), max_widen)
     fs, fi = select_fn(q_np[bad], widen)
     fs = np.asarray(fs, dtype=np.float64)
-    metric = "l2" if dot_shift is None else "dot"
-    fd2, fi2 = refine_exact(db_np, q_np[bad], np.asarray(fi), k, metric)
+    rank_q = q_np if rank_queries is None else rank_queries
+    fd2, fi2 = refine_exact(db_np, rank_q[bad], np.asarray(fi), k, metric,
+                            norms_rows(norms, bad))
     d[bad], i[bad] = fd2, fi2
     q_norm = (q_np[bad].astype(np.float64) ** 2).sum(-1)
     tol = certification_tolerance(
         q_np[bad], db_np, db_norm_max=db_norm_max, q_norm=q_norm
     )
+    # the k-th value in the selection's own space (docstring)
     d_k = fd2[:, k - 1]
-    if dot_shift is not None:
-        # the k-th score in the selection's own space (docstring)
+    if metric == "dot":
         d_k = q_norm + dot_shift + 2.0 * d_k
-        tol = tol + dot_slack
+    elif metric == "cosine":
+        d_k = 2.0 * d_k
+    tol = tol + pair_slack
     v_w = fs[:, -1]  # exclusion value of the widened f32 selection
     unproven = d_k + tol >= v_w
     if valid_rows_fn is not None:
@@ -273,13 +307,14 @@ def repair_uncertified(
     if still.size:
         sb = bad[still]
         if valid_rows_fn is None:
-            d[sb], i[sb] = host_exact_knn(db_np, q_np[sb], k, metric=metric)
+            d[sb], i[sb] = host_exact_knn(db_np, rank_q[sb], k, metric=metric,
+                                          norms=norms_rows(norms, sb))
         else:
             for pos in sb:
                 rows = valid_rows_fn(pos)
                 d[pos], i[pos] = np.inf, np.iinfo(np.int64).max
                 if rows.size:
-                    hd, hi = host_exact_knn(db_np[rows], q_np[pos][None], k,
+                    hd, hi = host_exact_knn(db_np[rows], rank_q[pos][None], k,
                                             metric=metric)
                     d[pos, : hd.shape[1]] = hd[0]
                     i[pos, : hd.shape[1]] = rows[hi[0]]

@@ -63,9 +63,51 @@ def _shared_pool() -> ThreadPoolExecutor:
         return _pool
 
 
-def _pairwise_f64(queries: np.ndarray, cand: np.ndarray, metric: str) -> np.ndarray:
+def norms_of_f64(rows64: np.ndarray, out: np.ndarray) -> np.ndarray:
+    """``out[j]`` = the Euclidean norm of ``rows64[j]`` (float64 [b, D],
+    widened float32 values: the squares are exact, each row's sum is its
+    own, pairwise).  Returns the squares' buffer for the caller to
+    reuse.  THE norm of the cosine contract: placements, batches and
+    the host's scorers all divide by these numbers."""
+    sq = rows64 * rows64
+    np.sum(sq, axis=-1, out=out)
+    np.sqrt(out, out=out)
+    return sq
+
+
+def row_norms_f64(x: np.ndarray) -> np.ndarray:
+    """[n] float64 Euclidean norms of the rows of ``x`` [n, D]
+    (:func:`norms_of_f64`), a block of rows at a time
+    (:func:`_block_rows`) so no temporary grows with ``n``."""
+    out = np.empty(x.shape[0])
+    block = _block_rows(x.shape[1])
+    for lo in range(0, x.shape[0], block):
+        norms_of_f64(x[lo : lo + block].astype(np.float64),
+                     out[lo : lo + block])
+    return out
+
+
+def norms_rows(norms, sel):
+    """A cosine call's ``(query norms, db row norms)`` held to the
+    queries ``sel`` (a slice or positions); None stays None."""
+    return None if norms is None else (norms[0][sel], norms[1])
+
+
+def cosine_distance(dots: np.ndarray, den: np.ndarray) -> np.ndarray:
+    """``1 - dots / den`` in place in ``dots``: the cosine distance from
+    float64 inner products and the products of the two norms.  A row or
+    a query of zero norm (``den`` 0; its inner product is an exact 0)
+    has cosine 0 to everything, distance 1."""
+    np.divide(dots, den, out=dots, where=den > 0)
+    return np.subtract(1.0, dots, out=dots)
+
+
+def _pairwise_f64(queries: np.ndarray, cand: np.ndarray, metric: str,
+                  norms=None) -> np.ndarray:
     """[Q, m] float64 distances between each query and its own candidate
-    rows (cand is [Q, m, D])."""
+    rows (cand is [Q, m, D]).  ``norms`` (cosine only): ``(query norms
+    [Q], candidate norms [Q, m])`` in float64 where the caller keeps
+    them; taken from the arrays here otherwise."""
     q = queries.astype(np.float64)[:, None, :]
     c = cand.astype(np.float64)
     m = metric.lower()
@@ -75,9 +117,13 @@ def _pairwise_f64(queries: np.ndarray, cand: np.ndarray, metric: str) -> np.ndar
     if m in ("l1", "manhattan"):
         return np.abs(c - q).sum(-1)
     if m == "cosine":
-        qn = q / np.maximum(np.linalg.norm(q, axis=-1, keepdims=True), 1e-24)
-        cn = c / np.maximum(np.linalg.norm(c, axis=-1, keepdims=True), 1e-24)
-        return 1.0 - np.einsum("qmd,qmd->qm", cn, qn)
+        # q.t / (|q| |t|) of the values as given: every product exact,
+        # nothing normalised before the sum
+        qn, cn = norms if norms is not None else (
+            np.sqrt(np.einsum("qmd,qmd->qm", q, q))[:, 0],
+            np.sqrt(np.einsum("qmd,qmd->qm", c, c)))
+        return cosine_distance(np.einsum("qmd,qmd->qm", c, q),
+                               qn[:, None] * cn)
     if m == "dot":
         return -np.einsum("qmd,qmd->qm", c, q)
     raise ValueError(f"unknown metric {metric!r}")
@@ -85,22 +131,28 @@ def _pairwise_f64(queries: np.ndarray, cand: np.ndarray, metric: str) -> np.ndar
 
 def _score_members(db_np: np.ndarray, queries_np: np.ndarray,
                    cand: np.ndarray, rows: np.ndarray, metric: str,
-                   out: np.ndarray) -> float:
+                   out: np.ndarray, norms=None) -> float:
     """``out[j]`` = the float64 ``metric`` value between query
     ``rows[j]`` and db row ``cand[j]``: squared L2 by direct difference,
-    or the negated inner product (``"dot"``).  f32 -> f64 is exact, so
-    the in-place arithmetic on the widened rows equals widening both
-    sides first; each member's sum is its own.  Returns the moment the
-    rows had been gathered and widened (``time.perf_counter``): before
-    it the gather, after it the arithmetic."""
+    the negated inner product (``"dot"``), or the cosine distance
+    ``1 - q.t / (|q| |t|)`` (``"cosine"``; ``norms`` = ``(query norms
+    [Q], db row norms [N])`` in float64, :func:`row_norms_f64`'s).
+    f32 -> f64 is exact, so the in-place arithmetic on the widened rows
+    equals widening both sides first; each member's sum is its own.
+    Returns the moment the rows had been gathered and widened
+    (``time.perf_counter``): before it the gather, after it the
+    arithmetic."""
     acc = db_np[cand].astype(np.float64)
     gathered = time.perf_counter()
-    if metric == "dot":
+    if metric in ("dot", "cosine"):
         # products of two float32 values are exact in float64; only the
         # sum rounds (pairwise: under (D+1) * 2^-53 * sum |q_i t_i|)
         acc *= queries_np[rows]
         np.sum(acc, axis=-1, out=out)
-        np.negative(out, out=out)
+        if metric == "dot":
+            np.negative(out, out=out)
+        else:
+            cosine_distance(out, norms[0][rows] * norms[1][cand])
     else:
         acc -= queries_np[rows]
         np.einsum("nd,nd->n", acc, acc, out=out)
@@ -194,6 +246,7 @@ def rank_correct_runs(
     db_np: np.ndarray,
     d32k: Optional[np.ndarray] = None,
     metric: str = "l2",
+    norms=None,
 ) -> Tuple[Optional[np.ndarray], np.ndarray, int]:
     """Float64 repair of a device-ranked candidate list from the near-tie
     mask ALONE — no distance matrix crosses the device->host link.
@@ -209,13 +262,18 @@ def rank_correct_runs(
     slack while corrections move less than a third of it.
 
     ``metric`` is what the members are re-scored and re-sorted by:
-    squared L2 between the arrays as given, or ``"dot"``, the negated
-    float64 inner product.  A dot placement's rows and queries arrive
-    norm-augmented (parallel.sharded): the query's appended column is an
-    exact zero, so that product IS the inner product of the original
-    columns, and the run is ordered by (-q.t, index) of the problem as
-    posed, not by the augmented difference, whose appended column was
-    rounded to float32.
+    squared L2 between the arrays as given, ``"dot"``, the negated
+    float64 inner product, or ``"cosine"``, ``1 - q.t / (|q| |t|)`` in
+    float64.  A dot placement's rows and queries arrive norm-augmented
+    (parallel.sharded): the query's appended column is an exact zero,
+    so that product IS the inner product of the original columns, and
+    the run is ordered by (-q.t, index) of the problem as posed, not by
+    the augmented difference, whose appended column was rounded to
+    float32.  A cosine placement hands over the rows and queries AS
+    GIVEN (not the float32 unit rows the device ranked) with ``norms``
+    = ``(query norms [Q], db row norms [N])`` in float64
+    (:func:`row_norms_f64`), so the run is ordered by the cosine of the
+    problem as posed, not by the distance of the rounded unit rows.
 
     ``d32k`` [Q, k] float64 (optional): the device's top-k distances;
     when given, corrected positions < k get their exact float64 values
@@ -274,7 +332,7 @@ def rank_correct_runs(
         t0 = time.perf_counter()
         gathered = _score_members(
             db_np, queries_np, safe[lo : lo + block],
-            rows[lo : lo + block], metric, d64[lo : lo + block])
+            rows[lo : lo + block], metric, d64[lo : lo + block], norms)
         return gathered - t0, time.perf_counter() - gathered
 
     with obs.trace.phase(secs, "score_s", PHASE_SCORE):
@@ -314,6 +372,7 @@ def refine_exact(
     cand_idx: np.ndarray,
     k: int,
     metric: str = "l2",
+    norms=None,
 ) -> Tuple[np.ndarray, np.ndarray]:
     """(distances [Q, k] float64, indices [Q, k] int64): the exact
     lexicographic (distance, index) top-k among each query's candidates.
@@ -321,6 +380,8 @@ def refine_exact(
     ``cand_idx`` is [Q, m] with m >= k, from the coarse device pass.
     Duplicate or sentinel (>= len(db)) candidate indices are tolerated:
     duplicates keep one copy ranked by index, sentinels rank last.
+    ``norms`` (``metric="cosine"`` only, optional): ``(query norms [Q],
+    db row norms [N])`` in float64 where the caller keeps them.
     """
     cand_idx = np.asarray(cand_idx, dtype=np.int64)
     n_q, m = cand_idx.shape
@@ -332,9 +393,11 @@ def refine_exact(
     d = np.empty((n_q, m))
     chunk = _block_rows(m * db.shape[1])
     for lo in range(0, n_q, chunk):
+        part = safe_idx[lo : lo + chunk]
         d[lo : lo + chunk] = _pairwise_f64(
-            queries[lo : lo + chunk], db[safe_idx[lo : lo + chunk]], metric
-        )
+            queries[lo : lo + chunk], db[part], metric,
+            None if norms is None else (norms[0][lo : lo + chunk],
+                                        norms[1][part]))
     d = np.where(valid, d, np.inf)
     # kill duplicate candidates (keep lowest occurrence by (d, idx) order)
     srt = np.lexsort((cand_idx, d), axis=-1)

@@ -504,8 +504,9 @@ _CALL_SPAN = "certified.call"
 #: ``certified.range_complete`` and ``certified.range_pack``
 _RANGE_SPAN = "certified.range_call"
 #: what a metric other than l2 adds to a call on either side of the l2
-#: machinery: ONE span a call, the sum of both sides (inner product only
-#: today: the zero column before, the float64 scores after)
+#: machinery: ONE span a call, the sum of both sides (inner product: the
+#: zero column before, the float64 scores after; cosine: the batch's
+#: float64 norms and its float32 unit rows before, nothing after)
 _METRIC_SPAN = "certified.metric_map"
 
 
@@ -606,6 +607,67 @@ def _row_normalize_f64(x: np.ndarray) -> np.ndarray:
     return (x / np.maximum(n, 1e-300)).astype(np.float32)
 
 
+#: cosine placements: the most by which the exact squared distances D'
+#: of two PLACED rows (float32 unit rows, against the float32 unit
+#: query) can disagree with the order of the cosines of the rows and the
+#: query AS GIVEN; absolute, since everything placed has unit length.
+#: With u = q/|q| and v = t/|t| exact, :func:`_unit_rows` places q^ =
+#: u + e_q and t^ = v + e_t: every entry is the float64 quotient (off by
+#: under 2^-32 relative for any width under 2^20) rounded once to
+#: float32, so |e| <= x := 2^-24 (1 + 2^-8), the room covering the
+#: float64 part and an entry under 2^-126 (rounded, or flushed by the
+#: device, by at most 2^-126).  With c(t) = 1 - u.v the cosine distance,
+#:   D'(t) = |u - v|^2 + 2 (u - v).(e_q - e_t) + |e_q - e_t|^2
+#:         = 2 c(t) + p_t,   |p_t| <= 2 * 2 * 2x + 4x^2 < 2^-21 (1 + 2^-7)
+#: for one row, and for two rows the query's own rounding meets both:
+#:   p_a - p_b = 2 (v_b - v_a).e_q - 2 (u - v_a).e_a + 2 (u - v_b).e_b
+#:               + |e_q - e_a|^2 - |e_q - e_b|^2,
+#:   |p_a - p_b| <= 12x + 4x^2 < 0.76 * 2^-20.
+#: So two placed rows with D'(a) - D'(b) > 2^-20 have c(a) > c(b), and a
+#: row with D'(t) > 2 c + 2^-20 has c(t) > c for any float64 c.  A row
+#: of zero norm has no direction: it is placed as it is (D' = |q^|^2,
+#: about 1) and ranked by the host at cosine 0 (search_certified).
+COS_UNIT_SLACK = 2.0 ** -20
+
+
+def _unit_rows(x: np.ndarray):
+    """Cosine placement of float32 rows ``x`` [n, d]: ``(unit rows [n, d]
+    float32, norms [n] float64, the largest squared norm of a unit row
+    as rounded, whether every rounded value is bf16-exact)``.  The norm
+    is ops.refine.norms_of_f64's (so the host's float64 cosines divide
+    by the very numbers the placement divided by), the quotient is taken
+    in float64 and rounded ONCE to float32; a row of zero norm stays
+    zero.  A block of rows at a time (ops.refine._block_rows; the only
+    float64 temporaries), the blocks shared among the re-score pool's
+    threads where there are several: each writes its own rows."""
+    from knn_tpu.ops.pallas_knn import lo_halves_zero
+
+    x = np.asarray(x, np.float32)
+    n, d = x.shape
+    unit = np.empty((n, d), np.float32)
+    norms = np.empty(n)
+    block = _refine._block_rows(d)
+    starts = range(0, n, block)
+
+    def fill(lo: int):
+        rows = x[lo : lo + block].astype(np.float64)
+        nb = norms[lo : lo + block]
+        sq = _refine.norms_of_f64(rows, nb)
+        np.divide(rows, nb[:, None], out=rows, where=nb[:, None] > 0)
+        out = unit[lo : lo + block]
+        out[...] = rows
+        np.multiply(out, out, out=sq, dtype=np.float64)
+        return float(sq.sum(-1).max()), lo_halves_zero(out)
+
+    if len(starts) > 1:
+        # list(): reading every result re-raises a worker's exception
+        parts = list(_refine._shared_pool().map(fill, starts))
+    else:
+        parts = [fill(lo) for lo in starts]
+    return (unit, norms, max((m for m, _ in parts), default=0.0),
+            all(z for _, z in parts))
+
+
 class ShardedKNN:
     """A placed distributed-KNN program: the database is padded, sharded
     along the db axis, and transferred **once** at construction; every
@@ -662,6 +724,10 @@ class ShardedKNN:
         obs.install_compile_hook()
         metric = metric.lower()  # dispatch below compares lowercase names
         self._cosine_unit = False  # db rows normalized at placement?
+        #: cosine placements: the float64 norms of the rows as given, and
+        #: the (ascending) indices of the rows that have none
+        self._cos_norms: Optional[np.ndarray] = None
+        self._cos_zero_rows = np.empty(0, np.int64)
         self._dot_aug = False  # db rows norm-augmented at placement?
         self._dot_shift = 0.0  # M = max f64 squared row norm (dot only)
         #: uint8 source rows (SIFT-style bvecs payloads): kept so an int8
@@ -698,6 +764,9 @@ class ShardedKNN:
                 NamedSharding(mesh, P(db_axes(mesh))), train.ndim
             )
         )
+        # a cosine placement's rows as given, and what the walk that made
+        # their unit rows saw of those (below)
+        given, placed_norm_max, lo_zero = None, None, False
         if pre_placed:
             # already a db-sharded global array (e.g. assembled across
             # hosts by parallel.multihost.shard_across_hosts) — use the
@@ -729,9 +798,25 @@ class ShardedKNN:
                 # exact machinery available to cosine (search_certified),
                 # and pairwise_cosine's internal re-normalization is
                 # idempotent so plain search is unchanged.  Zero rows keep
-                # themselves (norm clamped).
-                train = _row_normalize_f64(train)
+                # themselves.  The device finds the candidates and proves
+                # none is missing among the UNIT rows, with
+                # COS_UNIT_SLACK added wherever it compares two of them
+                # for the rounding of the normalisation; the host ranks
+                # by the float64 cosine of the rows AS GIVEN, which it
+                # keeps with their norms in the unit rows' place once
+                # those are on the device (below).
+                t_unit = time.perf_counter()
+                given = np.asarray(train, np.float32)
+                train, self._cos_norms, placed_norm_max, lo_zero = (
+                    _unit_rows(given))
+                self._cos_zero_rows = np.flatnonzero(self._cos_norms == 0)
                 self._cosine_unit = True
+                obs.emit_event(
+                    "placement.cosine_normalize", rows=int(train.shape[0]),
+                    dim=int(train.shape[1]),
+                    zero_rows=int(self._cos_zero_rows.size),
+                    slack=COS_UNIT_SLACK,
+                    seconds=time.perf_counter() - t_unit)
             elif metric == "dot" and isinstance(train, np.ndarray):
                 # MIPS -> L2 by norm augmentation, ONCE at placement
                 # (_augment_dot): against a query with a zero appended,
@@ -851,13 +936,14 @@ class ShardedKNN:
         self.metric = metric
         # an inner-product placement has just taken every row's norm
         # (the appended column is no bf16-exact value, so its kernel
-        # forms every product); any other walks its rows at the first
-        # certified call (_db_norm_max)
+        # forms every product), and a cosine placement every unit row's;
+        # any other walks its rows at the first certified call
+        # (_db_norm_max)
         self._db_norm_max_cache: Optional[float] = (
-            dot_norm_max if self._dot_aug else None)
+            dot_norm_max if self._dot_aug else placed_norm_max)
         # whether every placed row value is bf16-exact as float32: the
         # same walk's (_db_norm_max), read by _kernel_terms
-        self._rows_lo_zero = False
+        self._rows_lo_zero = lo_zero
         self.train_tile = train_tile
         self.n_train = n_train
         #: ``(indptr, tags)``, CSR row -> sorted tag ids: what a
@@ -885,6 +971,13 @@ class ShardedKNN:
             obs.emit_event("placement.device_put", rows=int(tp.shape[0]),
                            bytes=int(tp.nbytes),
                            seconds=time.perf_counter() - t0)
+            if self._cosine_unit:
+                # the unit rows live on the device from here on (the
+                # transfer keeps its own hold on them until it is done);
+                # what the host ranks by is the rows as given
+                # (_host_train).  A host-tier placement streams the unit
+                # rows from the host and keeps those.
+                self._train_host = given
         #: (k, placed query rows) -> dispatch count: every distinct pair is
         #: one traced/compiled XLA program shape (compile_cache_stats)
         self._dispatch_shapes: dict = {}
@@ -1334,10 +1427,20 @@ class ShardedKNN:
         )
 
     # -- certified-exact path (ops.certified, distributed) -----------------
+    def _placed_host(self) -> np.ndarray:
+        """The (unpadded) rows AS PLACED, on the host: what a quantized
+        placement is made from.  :meth:`_host_train` itself but for a
+        cosine placement, whose host copy is the rows as given: its unit
+        rows are read back from the device."""
+        if self._cosine_unit and self._tp is not None:
+            return np.asarray(self._tp)[: self.n_train]
+        return self._host_train()
+
     def _host_train(self) -> np.ndarray:
         """Host copy of the (unpadded) database for float64 refinement;
         fetched from the mesh once and cached when the caller didn't keep
-        a host array around."""
+        a host array around.  A cosine placement's is the rows AS GIVEN
+        (``_cos_norms`` beside them), not the unit rows it placed."""
         if self._train_host is None:
             if not self._tp.is_fully_addressable:
                 raise ValueError(
@@ -1409,7 +1512,7 @@ class ShardedKNN:
             with self._engines_lock:
                 if self._int8_cache is not None:
                     return self._int8_cache
-                host = self._host_train()
+                host = self._placed_host()
                 if self._uint8_train is not None:
                     qr = qz.from_uint8(self._uint8_train)
                     original = self._uint8_train
@@ -1478,7 +1581,7 @@ class ShardedKNN:
             with self._engines_lock:
                 if key in self._pq_cache:
                     return self._pq_cache[key]
-                host = self._host_train()
+                host = self._placed_host()
                 res = pqm.train_pq(host, mesh=self.mesh, dsub=dsub,
                                    ncodes=ncodes)
                 rows = self._tp.shape[0]
@@ -1672,8 +1775,8 @@ class ShardedKNN:
         the f32 precisions pass the scalar db-norm bound, and "bf16x3"
         after it the resident row operands where the program
         :meth:`_pallas_setup` last built takes them (ask after it); an
-        inner-product placement appends its augmentation slack to any
-        of them."""
+        inner-product or cosine placement appends its pair slack
+        (:meth:`_pair_slack`) to any of them."""
         if precision == "int8":
             pl = self._int8_placement()
             tail = (pl["values"], pl["scales"], pl["norms"],
@@ -1685,17 +1788,22 @@ class ShardedKNN:
             tail = (np.float32(self._db_norm_max()),)
             if precision == "bf16x3" and self._operands_source == "resident":
                 tail += self._operands_cache["parts"]
-        if self._dot_aug:
+        if self._dot_aug or self._cosine_unit:
             # _certify_pack_spmd's aug_slack, rounded up to float32
-            tail += (np.nextafter(np.float32(self._dot_slack()),
+            tail += (np.nextafter(np.float32(self._pair_slack()),
                                   np.float32(np.inf)),)
         return tail
 
-    def _dot_slack(self) -> float:
-        """``DOT_AUG_SLACK * M`` of an inner-product placement: what
-        every comparison of two placed rows' augmented distances allows
-        for the appended column's rounding."""
-        return DOT_AUG_SLACK * self._dot_shift
+    def _pair_slack(self) -> float:
+        """What every comparison of two placed rows' squared distances
+        allows for what the placement itself rounded, in the placed
+        space's units: 0 for l2 (the rows are placed as given),
+        ``DOT_AUG_SLACK * M`` for an inner-product placement (the
+        appended column), ``COS_UNIT_SLACK`` for a cosine placement (the
+        unit rows and the unit query)."""
+        if self._dot_aug:
+            return DOT_AUG_SLACK * self._dot_shift
+        return COS_UNIT_SLACK if self._cosine_unit else 0.0
 
     def search_certified(
         self, queries, *, margin: int = 28, selector: str = "approx",
@@ -1713,12 +1821,43 @@ class ShardedKNN:
         _under: Optional[tuple] = None,
     ):
         """Exact lexicographic top-k via the certified pipeline, sharded.
-        Returns (dists_f64, idx, stats).  L2, cosine and dot (the
-        certificate is a squared-L2 bound; cosine runs it on unit
-        vectors — rows are normalized at placement, queries here — and
-        is exact for the f32-row-normalized problem, distances returned
-        as 1-similarity).  L1 has no squared-L2-style bound and stays
-        uncertified.
+        Returns (dists_f64, idx, stats).  L2, cosine and dot: the
+        certificate is a squared-L2 bound, which cosine runs on unit
+        rows and dot on norm-augmented ones (below).  L1 has no
+        squared-L2-style bound and stays uncertified.
+
+        **cosine** has l2's contract: the INDICES equal float64 brute
+        force in lexicographic (1 - q.t / (|q| |t|), index) order over
+        the float32 rows and queries AS GIVEN, whatever the selector.  A
+        row or a query of zero norm has cosine 0 (distance 1) to
+        everything.  The device runs the l2 machinery on float32 UNIT
+        rows (normalised at construction with float64 norms, the
+        queries here, ``_unit_rows``) to find the candidates and to
+        prove none is missing; every inequality that compares two rows
+        there allows ``COS_UNIT_SLACK`` (2^-20, absolute) for the
+        rounding of the normalisation on both sides, and whatever it
+        cannot tell apart the host ranks by the float64 cosine of the
+        values as given (``rank_correct_runs``, ``refine_exact``,
+        ``repair_uncertified``), never by the distance of the rounded
+        unit rows.  The host keeps the rows as given and one float64
+        norm a row, not a second copy of unit rows.  A zero row is
+        placed as it is (the device sees it at half its distance), so a
+        query that has one among its candidates is repaired like an
+        uncertified one.  The returned DISTANCES are cosine distances c
+        = 1 - cos: the counted selectors' are float64 values of the rows
+        as given; the pallas selector's are the device's float32
+        direct-difference values of the unit rows, halved, within
+        ``2^-18 * (c + 1/8)`` of c (2^-18 c the device's own relative
+        error, ops.pallas_knn.RANK_SLACK; 2^-21 covers the
+        normalisation's 2^-22 twice), float64 of the rows as given
+        wherever the host re-scored (near-tied and repaired entries).
+        The span ``certified.metric_map``, one a call, is what the
+        metric adds before the l2 machinery (``before_s``: the batch's
+        float64 norms and its unit rows; ``after_s`` 0: nothing is
+        scored after the repair).  ``stats["pair_slack"]`` is the slack
+        the call ran with, and ``stats["slack_fallback_queries"]``
+        (pallas selector) the queries whose certificate holds without it
+        and fails with it.
 
         **dot / MIPS** has l2's contract: the INDICES equal float64
         brute force in lexicographic (-q.t, index) order over the
@@ -1833,19 +1972,22 @@ class ShardedKNN:
         """
         self._require_resident("search_certified")
         if self.metric == "cosine":
-            # runs the l2 certificate on unit vectors (db rows were
-            # normalized at placement): EXACT for the f32-row-normalized
-            # problem; returned distances are converted back to cosine
-            # values (1 - q^.t^ = ||q^-t^||^2 / 2) below.  L1 stays
+            # runs the l2 certificate on the unit rows placed at
+            # construction, COS_UNIT_SLACK allowed for their rounding
+            # wherever two rows are compared; the host ranks by the
+            # float64 cosine of the rows as given (docstring).  L1 stays
             # uncertified: the count-below / exclusion-bound certificates
             # are squared-L2 inequalities and |q-t|_1 admits no
             # gram-matrix form to bound (SURVEY §7 step 1).
             if not self._cosine_unit:
                 raise ValueError(
-                    "cosine search_certified needs the database normalized "
-                    "at placement; construct ShardedKNN from a host array "
-                    "(pre-placed arrays arrive already sharded, so "
-                    "row-normalize them and use metric='l2' instead)"
+                    "cosine search_certified ranks by the float64 cosine "
+                    "of the rows as given, which it keeps on the host "
+                    "beside the unit rows it places: construct ShardedKNN "
+                    "from a host array (a pre-placed array arrives already "
+                    "sharded and leaves neither; normalising it yourself "
+                    "and using metric='l2' answers for the rounded unit "
+                    "rows, not for the rows as given)"
                 )
         elif self.metric == "dot":
             # MIPS runs the l2 certificate in the norm-augmented space
@@ -1886,6 +2028,11 @@ class ShardedKNN:
         # (_under, private); any other keeps one of its own
         tid, parent, acct = _under or (obs.new_trace_id(), None, None)
         dot = self.metric == "dot"
+        cosine = self.metric == "cosine"
+        # what the host ranks by: the placement's own metric, on the
+        # values as given
+        rank_metric = self.metric if dot or cosine else "l2"
+        slack = self._pair_slack()
         with obs.span(_CALL_SPAN, tid, selector=selector,
                       **({"parent": parent} if parent else {})) as call:
             if _under is None:
@@ -1906,15 +2053,20 @@ class ShardedKNN:
                     q_np = np.concatenate(
                         [q_np, np.zeros((q_np.shape[0], 1), np.float32)],
                         axis=1)
+            # the queries the host ranks with and, for cosine, the
+            # float64 norms on both sides: (queries', rows')
+            host_q, norms = q_np, None
+            if cosine:
+                with obs.trace.phase(map_s, "before_s", _METRIC_SPAN):
+                    # the unit queries matching the placed unit rows
+                    q_np, q_norms, _, _ = _unit_rows(host_q)
+                    norms = (q_norms, self._cos_norms)
             with obs.span("certified.prepare", tid, parent=_CALL_SPAN,
                           first_call=self._db_norm_max_cache is None):
-                if self.metric == "cosine":
-                    q_np = _row_normalize_f64(q_np)
                 # every certified stage runs in squared-L2 space (for
                 # cosine: on the unit vectors placed at construction /
                 # normalized above; for dot: on the norm-augmented vectors)
-                cert_metric = ("l2" if self.metric in ("cosine", "dot")
-                               else self.metric)
+                cert_metric = "l2" if dot or cosine else self.metric
                 n_q = q_np.shape[0]
                 shard_rows = self._shard_rows()
                 # margin is bounded by both the db size and the per-shard
@@ -1995,6 +2147,7 @@ class ShardedKNN:
             call.set("queries", n_q)
             call.set("batches", len(batches))
             call.set("metric", self.metric)
+            call.set("pair_slack", slack)
             # what the cross-shard merges of this call move: every batch
             # is one program whose merge keeps m+1 columns a query (the
             # pallas program, setup's m) or m (the counted coarse
@@ -2004,18 +2157,20 @@ class ShardedKNN:
                 len(batches) * (-(-bs // q_shards) * q_shards),
                 m_prog + 1 if selector == "pallas" else m)
             if selector == "pallas":
-                bad, n_corrected = self._certify_pallas(
+                bad, n_corrected, n_by_slack = self._certify_pallas(
                     batches, bs, d, i, q_np, db_np, prog=prog, w=w,
                     ops_tail=ops_tail, precision=knobs["precision"],
                     trace_id=tid, want_distances=device_d,
-                    rank_metric="dot" if dot else "l2", acct=acct,
+                    rank_metric=rank_metric, host_q=host_q, norms=norms,
+                    acct=acct,
                     **({"mask": mask} if mask is not None else {}),
                 )
             else:
                 bad = self._certify_counted(
                     batches, bs, m, d, i, q_np, db_np, db_norm_max,
                     selector, recall_target=recall_target,
-                    metric=cert_metric, dot=dot, trace_id=tid, acct=acct,
+                    metric=cert_metric, rank_metric=rank_metric,
+                    host_q=host_q, norms=norms, trace_id=tid, acct=acct,
                 )
 
             def _select(qb, widen):
@@ -2096,9 +2251,9 @@ class ShardedKNN:
                     d, i, self.k, m, bad, q_np, db_np,
                     select_fn=_select if ft is None else _select_masked,
                     max_widen=min(self.n_train, shard_rows),
-                    db_norm_max=db_norm_max,
-                    dot_shift=self._dot_shift if dot else None,
-                    dot_slack=self._dot_slack(),
+                    db_norm_max=db_norm_max, metric=rank_metric,
+                    pair_slack=slack, dot_shift=self._dot_shift,
+                    rank_queries=host_q, norms=norms,
                     **({} if ft is None else {"valid_rows_fn": valid_rows_fn}),
                 )
                 sp.set("host_exact_queries",
@@ -2160,6 +2315,8 @@ class ShardedKNN:
                 "fallback_queries": int(bad.size),
                 "certified": n_q - int(bad.size),
                 "batches": len(batches),
+                "metric": self.metric,
+                "pair_slack": slack,
                 **repair,
                 **merged,
             }
@@ -2220,10 +2377,17 @@ class ShardedKNN:
                 repair.get("host_exact_queries", 0))
             if selector == "pallas":
                 obs.counter(_mn.CERTIFIED_RANK_CORRECTED).inc(n_corrected)
-            if return_distances and self.metric == "cosine":
-                # unit-vector squared L2 -> cosine distance values, exactly
-                # (matches pairwise_cosine's 1 - similarity convention)
-                d *= 0.5
+                if cosine:
+                    # the one program that tells a certificate the pair
+                    # slack failed from one that fails without it
+                    stats["slack_fallback_queries"] = n_by_slack
+                    call.set("slack_fallback_queries", n_by_slack)
+                    for outcome, n_out in (
+                            ("certified", n_q - int(bad.size)),
+                            ("uncertified", int(bad.size) - n_by_slack),
+                            ("uncertified_by_slack", n_by_slack)):
+                        obs.counter(_mn.CERTIFIED_SLACK_QUERIES,
+                                    outcome=outcome).inc(n_out)
             if return_distances and dot:
                 # pairwise_dot values (negative inner product) of the
                 # rows the indices name, in float64 on the host: the
@@ -2235,9 +2399,10 @@ class ShardedKNN:
 
                 with obs.trace.phase(map_s, "after_s", _METRIC_SPAN):
                     d = exact_scores(db_np, q_np, i, "dot")
-            if dot:
+            if dot or cosine:
                 obs.record_span(_METRIC_SPAN, tid, sum(map_s.values()),
-                                parent=_CALL_SPAN, metric="dot", **map_s)
+                                parent=_CALL_SPAN, metric=self.metric,
+                                **map_s)
             if return_distances and return_sqrt:
                 # true Euclidean values (knn_mpi.cpp:48 / sklearn
                 # convention); indices and certification are unaffected
@@ -2499,8 +2664,9 @@ class ShardedKNN:
 
     def _certify_counted(
         self, batches, bs, m, d, i, q_np, db_np, db_norm_max, selector,
-        recall_target: Optional[float] = None, metric: Optional[str] = None,
-        dot: bool = False, trace_id=None, acct=obs.trace.NOOP_ACCOUNT,
+        *, host_q, norms, recall_target: Optional[float] = None,
+        metric: Optional[str] = None, rank_metric: str = "l2",
+        trace_id=None, acct=obs.trace.NOOP_ACCOUNT,
     ):
         """Two-pass certificate: coarse select + refine, then the
         distributed count-below program proves completeness.  Returns the
@@ -2521,15 +2687,26 @@ class ShardedKNN:
         tol almost always exists inside the margin window, so the
         adaptive form certifies those queries instead.
 
-        ``dot`` (an inner-product placement: rows and queries
-        norm-augmented, ``metric`` l2): the refine ranks by the float64
-        negated inner product s, and the thresholds are made from
-        ``|q|^2 + M + 2 s``, s in the count program's own space.  A
+        ``rank_metric="dot"`` (an inner-product placement: rows and
+        queries norm-augmented, ``metric`` l2): the refine ranks by the
+        float64 negated inner product s, and the thresholds are made
+        from ``|q|^2 + M + 2 s``, s in the count program's own space.  A
         placed row's exact augmented distance is that plus c_t, |c_t| <=
-        ``_dot_slack()`` / 2 (``DOT_AUG_SLACK``), so the tolerance grows
-        by ``_dot_slack()``: a row the count found at or above a
+        ``_pair_slack()`` / 2 (``DOT_AUG_SLACK``), so the tolerance
+        grows by ``_pair_slack()``: a row the count found at or above a
         threshold is then above every refined candidate below it in
-        inner product too."""
+        inner product too.
+
+        ``rank_metric="cosine"`` (a cosine placement: ``q_np`` the unit
+        queries the programs run on, ``host_q`` the queries as given,
+        ``norms`` the float64 norms of both sides): the refine ranks by
+        the float64 cosine distance c of the values as given, and the
+        thresholds are made from ``2 c``.  A placed row's exact distance
+        is that plus p_t, |p_t| < ``_pair_slack()``
+        (``COS_UNIT_SLACK``), and the tolerance grows by it likewise.  A
+        query with a zero row among its candidates is flagged: the
+        count program sees that row at |q^|^2, not at the 2 its cosine
+        distance of 1 stands for."""
         from knn_tpu.ops.certified import certification_tolerance
         from knn_tpu.ops.refine import refine_exact
 
@@ -2557,6 +2734,7 @@ class ShardedKNN:
         # stage 2: per batch — sync its candidates, float64 host refine
         # (overlapping later batches' device work), dispatch its count
         count_out = []
+        zero_hit = []  # cosine: queries with a zero row among the candidates
         for (lo, chunk, pad), (qp, (_, ci)) in zip(batches, coarse_out):
             take = bs - pad
             ci = _fetch_or_redispatch(
@@ -2565,18 +2743,23 @@ class ShardedKNN:
             acct.ready("counted")
             m_avail = ci.shape[1]
             # refine ALL candidates: ranks k..m feed the gap search
-            d_m, i_m = refine_exact(db_np, q_np[lo : lo + take], ci, m_avail,
-                                    "dot" if dot else "l2")
+            d_m, i_m = refine_exact(
+                db_np, host_q[lo : lo + take], ci, m_avail, rank_metric,
+                _refine.norms_rows(norms, slice(lo, lo + take)))
+            d[lo : lo + take], i[lo : lo + take] = d_m[:, :k], i_m[:, :k]
             tol = certification_tolerance(
                 q_np[lo : lo + take], db_np, db_norm_max=db_norm_max
-            )
-            if dot:
+            ) + self._pair_slack()
+            # the refined values in the count program's own space
+            if rank_metric == "dot":
                 q_norm = (q_np[lo : lo + take].astype(np.float64) ** 2
                           ).sum(-1)
                 d_m = q_norm[:, None] + self._dot_shift + 2.0 * d_m
-                tol = tol + self._dot_slack()
-            d_b, i_b = d_m[:, :k], i_m[:, :k]
-            d[lo : lo + take], i[lo : lo + take] = d_b, i_b
+            elif rank_metric == "cosine":
+                d_m = 2.0 * d_m
+                if self._cos_zero_rows.size:
+                    zero_hit.append(lo + np.flatnonzero(np.isin(
+                        ci, self._cos_zero_rows).any(axis=1)))
             # first rank j in [k, m_avail) whose gap d[j] - d[j-1]
             # exceeds 2*tol (js = that j, or k when none does — the
             # fixed-threshold behavior)
@@ -2633,7 +2816,9 @@ class ShardedKNN:
                 obs.histogram(_mn.CERTIFIED_MARGIN, path="sharded"
                               ).observe_many(
                     ((mid[ok] - d_k[ok]) / denom).tolist())
-        return np.concatenate(flagged) if flagged else np.empty(0, np.int64)
+        flagged += zero_hit
+        return (np.unique(np.concatenate(flagged)) if flagged
+                else np.empty(0, np.int64))
 
     def _pallas_setup(self, margin: int, tile_n: Optional[int],
                       precision: str,
@@ -2778,16 +2963,18 @@ class ShardedKNN:
             final_recall_target=final_recall_target,
             grid_order=grid_order, kernel=kernel,
             quant_offset=quant_offset, dcn_merge=self.dcn_merge,
-            interpret=interpret, terms=terms, augmented=self._dot_aug,
+            interpret=interpret, terms=terms,
+            augmented=self._dot_aug or self._cosine_unit,
             dim_chunk=self._dim_chunking[0],
             resident_parts=len(resident) - 1 if resident else 0,
             **({"masked": True} if masked else {}),
+            **({"slack_outcome": True} if self._cosine_unit else {}),
         )
         return prog, m, _analysis_window(self.k, m), interpret
 
     def _certify_pallas(
         self, batches, bs, d, i, q_np, db_np, *, prog, w, ops_tail,
-        precision, trace_id=None, want_distances=True,
+        precision, host_q, norms, trace_id=None, want_distances=True,
         rank_metric="l2", acct=obs.trace.NOOP_ACCOUNT, mask=None,
     ):
         """One-pass certificate, host side.  The device already ranked the
@@ -2796,10 +2983,20 @@ class ShardedKNN:
         indices, the bit-packed tight-pair mask, and the bad flags (plus
         the top-k distance block when ``want_distances``) — nothing wider
         crosses the slow device->host link — then repairs tie runs in
-        float64 (ops.refine.rank_correct_runs, by ``rank_metric``: an
-        inner-product placement's runs are ordered by inner product, not
-        by the augmented difference).  Returns (flagged query
-        indices, rank-corrected query count).  ``prog`` and ``w`` are
+        float64 (ops.refine.rank_correct_runs, by ``rank_metric`` on
+        ``host_q``, the queries the host ranks with (the placed ``q_np``
+        but for cosine): an inner-product placement's runs are
+        ordered by inner product, not by the augmented difference; a
+        cosine placement's by the cosine of the rows and queries as
+        given, ``norms`` their float64 norms, not by the distance of the
+        unit rows, and the device's distances are halved into cosine
+        distances before the host patches its own in).  A cosine
+        placement's program also says which of its uncertified queries
+        the pair slack alone failed (bit 1 of the flag word), and a
+        query with a zero row among its candidates is flagged here: the
+        device saw that row at half its distance.  Returns (flagged
+        query indices, rank-corrected query count, queries failed by the
+        slack alone).  ``prog`` and ``w`` are
         :meth:`_pallas_setup`'s, ``ops_tail`` :meth:`_pallas_operands`'s.
         Every sub-batch's program is launched before the first is
         fetched, so the host's share of sub-batch b (the copy down, the
@@ -2843,12 +3040,13 @@ class ShardedKNN:
                                         offset=pl["offset"])
             obs.histogram(_mn.CERTIFIED_QUANT_BOUND).observe_many(eps)
         bad_mask = np.zeros(q_np.shape[0], dtype=bool)
-        n_corrected = 0
+        n_corrected = n_by_slack = 0
+        cosine = rank_metric == "cosine"
 
         def repair(lo, pad, packed, redo):
             """ONE fetch of the packed output, then float64 tie-run
             repair."""
-            nonlocal n_corrected
+            nonlocal n_corrected, n_by_slack
             take = bs - pad
             packed_np = _fetch_or_redispatch(packed, redo, "pallas fetch",
                                              fetch=fetch)
@@ -2863,9 +3061,12 @@ class ShardedKNN:
                                      _refine.PHASE_BUFFERS):
                     d32k = (None if dk_np is None
                             else dk_np.astype(np.float64))
+                    if cosine and d32k is not None:
+                        d32k *= 0.5  # unit rows: |q^ - t^|^2 = 2 (1 - cos)
                 dc, ic, n_c = rank_correct_runs(
-                    gi_np, tight_np, k, q_np[lo : lo + take], db_np,
+                    gi_np, tight_np, k, host_q[lo : lo + take], db_np,
                     d32k=d32k, metric=rank_metric,
+                    norms=_refine.norms_rows(norms, slice(lo, lo + take)),
                 )
                 sp.set("queries_corrected", n_c)
             told = sp.attrs  # what rank_correct_runs said of its insides
@@ -2876,9 +3077,15 @@ class ShardedKNN:
                      arith_s=told.get("arith_s", 0.0))
             acct.add(_refine.PHASE_ORDER, told.get("order_s", 0.0))
             n_corrected += n_c
+            obs.counter(_mn.RANK_CORRECT_MEMBERS).inc(told.get("members", 0))
             if dc is not None:
                 d[lo : lo + take] = dc
             i[lo : lo + take] = ic
+            if cosine:
+                n_by_slack += int(failed_by_slack(packed_np[:take], w).sum())
+                if self._cos_zero_rows.size:
+                    bad_np = bad_np | np.isin(
+                        gi_np, self._cos_zero_rows).any(axis=1)
             bad_mask[lo : lo + take] = bad_np
 
         # stage 1: dispatch every sub-batch (async on device)
@@ -2906,7 +3113,7 @@ class ShardedKNN:
                 acct.ready("filter_mask")
             repair(lo, pad, packed,
                    lambda q=qp, tail=tail: prog(q, self._tp, *tail))
-        return np.flatnonzero(bad_mask), n_corrected
+        return np.flatnonzero(bad_mask), n_corrected, n_by_slack
 
     def predict_certified(
         self, queries, *, margin: int = 28, selector: str = "approx",
@@ -3060,6 +3267,7 @@ def _pallas_certified_program(
     dim_chunk: Optional[int] = None,
     resident_parts: int = 0,
     masked: bool = False,
+    slack_outcome: bool = False,
 ):
     """ONE-pass sharded self-certifying coarse select + device rank +
     device certificate (ops.pallas_knn.local_certified_candidates per
@@ -3080,7 +3288,9 @@ def _pallas_certified_program(
                               RANK_SLACK and sit before the top-k set
                               boundary's first big gap,
       [W+nw]            i32   bad flag: uncertified OR boundary-
-                              unresolvable rows (repair reruns exactly),
+                              unresolvable rows (repair reruns exactly);
+                              ``slack_outcome`` adds 2 where the pair
+                              slack alone failed the certificate,
       [W+nw+1, +k)      f32-bitcast  ranked direct-difference top-k
                               distances (``include_distances`` only —
                               label/index consumers skip the columns).
@@ -3101,10 +3311,15 @@ def _pallas_certified_program(
     distances d32 are space-independent up to RANK_SLACK, which the
     derivation already budgets).
 
-    ``augmented`` (an inner-product placement's rows, never a caller's
-    choice) appends one replicated scalar to the operand tail, whatever
-    the precision: ``_certify_pack_spmd``'s ``aug_slack``.  Without it
-    the program is the one it always was, operation for operation.
+    ``augmented`` (a placement with a pair slack, inner product or
+    cosine: ``ShardedKNN._pair_slack``; never a caller's choice) appends
+    one replicated scalar to the operand tail, whatever the precision:
+    ``_certify_pack_spmd``'s ``aug_slack``.  Without it the program is
+    the one it always was, operation for operation.  ``slack_outcome``
+    (a cosine placement's program alone, beside ``augmented``) has the
+    certificate evaluated once more without the slack and the
+    difference packed as bit 1 of the flag word (``failed_by_slack``
+    reads it); without it the program is the one it was.
 
     ``dim_chunk`` is the kernel's static of that name, ``_pallas_setup``'s
     resolution (None: the kernel reads its own launch's shape).
@@ -3161,6 +3376,7 @@ def _pallas_certified_program(
             include_distances=include_distances,
             pq_dsub=None if db_pq is None else int(db_pq[1].shape[2]),
             aug_slack=aug_slack, **({"masked": True} if masked else {}),
+            **({"slack_outcome": True} if slack_outcome else {}),
         )
 
     prog = jax.jit(
@@ -3179,7 +3395,8 @@ def _pallas_certified_program(
         prog, f"m={m},k={k},tile={eff_tile},terms={terms},"
               f"dim_chunk={dim_chunk},precision={precision},"
               f"operands={'resident' if resident_parts else 'per_call'}"
-              + (",masked" if masked else ""))
+              + (",masked" if masked else "")
+              + (",slack_outcome" if slack_outcome else ""))
     return prog
 
 
@@ -3321,29 +3538,36 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
                        precision, quant_offset, m, k, w, merge, n_train,
                        hosts, chips, include_distances,
                        dcn_merge=None, pq_dsub=None, aug_slack=None,
-                       masked: bool = False):
+                       masked: bool = False, slack_outcome: bool = False):
     """The certify/pack tail of the pallas certified program, from one
     shard's ranked candidates ``(d32, li, lb)`` to the packed host-facing
     int32 array: merge, rank analysis, certificate, packing.
 
-    ``aug_slack`` (a traced scalar; inner-product placements only, None
-    and no operation otherwise) is ``DOT_AUG_SLACK * M``: the most by
-    which the exact augmented distances D' of two placed rows can
-    disagree with the order of their inner products (``_augment_dot``
-    derives it).  With e = aug_slack, r = RANK_SLACK and |d32 - D'| <=
-    r D' / 3 (ops.pallas_knn.RANK_SLACK), the three inequalities below
-    carry it:
+    ``aug_slack`` (a traced scalar; placements with a pair slack only,
+    ``ShardedKNN._pair_slack``; None and no operation otherwise) is the
+    most by which the exact placed distances D' of two placed rows can
+    disagree with the order of the metric the host ranks by:
+    ``DOT_AUG_SLACK * M`` for inner products (``_augment_dot`` derives
+    it), ``COS_UNIT_SLACK`` for cosines (derived beside it).  With e =
+    aug_slack, r = RANK_SLACK and |d32 - D'| <= r D' / 3
+    (ops.pallas_knn.RANK_SLACK), the three inequalities below carry it:
 
     - near-tie: a pair with d32 gap > r d_hi + e has D' gap > r d_hi / 3
-      + e > e, so its inner products are ordered as the device ranked
-      them; every other pair is marked tight and re-scored by the host
-      in float64 INNER PRODUCT (ops.refine.rank_correct_runs);
+      + e > e, so its inner products (cosines) are ordered as the device
+      ranked them; every other pair is marked tight and re-scored by the
+      host in float64 INNER PRODUCT (COSINE of the rows as given)
+      (ops.refine.rank_correct_runs);
     - exclusion: a row outside the candidates has kernel score >= lb,
       so D' - |q|^2 >= lb - tol; ``s_k + r d_k + tol + e < lb`` puts it
       more than e above D'(c) <= d_k (1 + r) of every row c the host may
-      keep, hence below each in inner product;
+      keep, hence below each in inner product (cosine);
     - merge-drop: a dropped candidate has D' >= d32[:, m] (1 - r), and
       ``d_k + r d_k + e < d32[:, m] (1 - r)`` says the same of it.
+
+    ``slack_outcome`` (a cosine placement's program): the exclusion and
+    merge-drop tests are evaluated once more with e = 0, and a query
+    that fails only with the slack gets 2 added to its flag word, so the
+    host can count what the slack costs (``failed_by_slack``).
 
     ``masked`` (the candidates came from a kernel that held each query
     to its validity words): the certificate is the same in form, read
@@ -3449,22 +3673,30 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
     bad = reach >= lb
     if masked:
         bad = bad & jnp.isfinite(lb)
+    if slack_outcome:
+        bare = reach - aug_slack >= lb  # the certificate without the slack
     if db_shards > 1:
         # merge-dropped candidates have direct-diff f32 distance
         # >= the (m+1)-th kept; require true-distance clearance
         kept = d_k + RANK_SLACK * d_k
+        if slack_outcome:
+            bare = bare | (kept >= d32[:, m] * (1.0 - RANK_SLACK))
         if aug_slack is not None:
             kept = kept + aug_slack
         dropped = kept >= d32[:, m] * (1.0 - RANK_SLACK)
         if masked:
             dropped = dropped & jnp.isfinite(d32[:, m])
         bad = bad | dropped
+    if slack_outcome:
+        by_slack = bad & ~bare & ~unresolved
     bad = bad | unresolved
     cols = [
         gi[:, :w],
         lax.bitcast_convert_type(_pack_bits_u32(tight_use), jnp.int32),
         bad.astype(jnp.int32)[:, None],
     ]
+    if slack_outcome:
+        cols[2] = cols[2] + 2 * by_slack.astype(jnp.int32)[:, None]
     if include_distances:
         cols.append(lax.bitcast_convert_type(d32[:, :k], jnp.int32))
     return jnp.concatenate(cols, axis=1)
@@ -3493,6 +3725,13 @@ def unpack_certified(
             ).view(np.float32)
     obs.current_span().set("copies_s", copies.get("s", 0.0))
     return gi, tight, bad, dk
+
+
+def failed_by_slack(packed: np.ndarray, w: int) -> np.ndarray:
+    """[Q] bool from a COSINE program's packed output: the queries whose
+    certificate holds without the pair slack and fails with it (bit 1 of
+    the flag word, ``_certify_pack_spmd``'s ``slack_outcome``)."""
+    return (np.asarray(packed)[:, w + -(-(w - 1) // 32)] & 2) != 0
 
 
 @functools.lru_cache(maxsize=32)

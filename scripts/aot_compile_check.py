@@ -91,6 +91,9 @@ SHAPES = {
     # one chip of ssnpp-10M: 256 byte-valued columns, range search over
     # a top-100 first pass (benchmark/configs/ssnpp2m5.json)
     "ssnpp2m5": (2_500_000, 256, 100),
+    # VectorDBBench's 500K x 1,536 cosine case whole on one chip: unit
+    # rows, 12 dim chunks a row tile (benchmark/configs/openai500k.json)
+    "openai500k": (500_000, 1536, 100),
     "wide512": (1_000_000, 512, 100),
     "wide640": (1_000_000, 640, 100),
 }
@@ -98,6 +101,10 @@ SHAPES = {
 #: certified program takes the augmentation's slack as one more scalar
 #: and sends no distance block back
 AUGMENTED = ("text2image2m5",)
+#: shapes whose rows are unit rows (metric "cosine"): the program takes
+#: the normalisation's slack as one more scalar too, keeps its distance
+#: block and packs one more bit (``slack_outcome``)
+COSINE = ("openai500k",)
 #: shapes answered by ``range_search_certified``: its completion's
 #: program is compiled too
 RANGE = ("ssnpp2m5",)
@@ -156,6 +163,8 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
         kw["terms"] = terms
     if shape in AUGMENTED:
         kw.update(augmented=True, include_distances=False)
+    if shape in COSINE:
+        kw.update(augmented=True, slack_outcome=True)
     prog = _pallas_certified_program(
         mesh, k + MARGIN, k, merge, knobs["tile_n"] or TILE_N,
         knobs["precision"], n_train=n, interpret=False, **kw)
@@ -165,7 +174,8 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
         (rows, d), jnp.float32, sharding=NamedSharding(mesh, P(DB_AXIS)))
     norm = jax.ShapeDtypeStruct(
         (), jnp.float32, sharding=NamedSharding(mesh, P()))
-    return prog, (q, db, norm) + ((norm,) if shape in AUGMENTED else ())
+    return prog, (q, db, norm) + (
+        (norm,) if shape in AUGMENTED + COSINE else ())
 
 
 def _shard_width(shape: str, db_shards: int) -> int:

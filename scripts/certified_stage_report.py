@@ -209,6 +209,7 @@ def startup_table(events: Iterable[dict], run: dict = None) -> dict:
 
         placed = sum(r["seconds"] for r in placement
                      if r["event"] in ("placement.dot_augment",
+                                       "placement.cosine_normalize",
                                        "placement.device_put"))
         walk = sum(r["seconds"] for r in placement
                    if r["event"] in ("placement.norm_walk",

@@ -325,9 +325,19 @@ def test_zero_recompile_steady_state(rng):
                [8000, 8001])
     eng.search(q)
     idx.search(q)
-    jax_compiles0 = sum(
-        s["value"] for s in obs.snapshot().get(
-            mn.JAX_COMPILES, {}).get("series", []))
+    def jax_compiles():
+        # every compile event but the trace's: JAX times
+        # ``jaxpr_trace_duration`` around the jaxpr cache's LOOKUP, so a
+        # dispatch that takes the Python path records one with nothing
+        # traced, lowered or compiled (an eager ``x[:n]`` does for good
+        # once an earlier test of the process first made its index
+        # conversion where the C++ fast path could not be kept)
+        return sum(
+            s["value"] for s in obs.snapshot().get(
+                mn.JAX_COMPILES, {}).get("series", [])
+            if "jaxpr_trace" not in s["labels"]["event"])
+
+    jax_compiles0 = jax_compiles()
     engine_compiles0 = eng.stats()["compile_count"]
     for j in range(6):  # stays inside the 64-row first rung
         idx.insert(rng.normal(size=(3, DIM)).astype(np.float32),
@@ -336,9 +346,7 @@ def test_zero_recompile_steady_state(rng):
             idx.delete([9000 + 10 * j])
         eng.search(q)
         idx.search(q)
-    jax_compiles1 = sum(
-        s["value"] for s in obs.snapshot().get(
-            mn.JAX_COMPILES, {}).get("series", []))
+    jax_compiles1 = jax_compiles()
     assert eng.stats()["compile_count"] == engine_compiles0
     assert jax_compiles1 == jax_compiles0, (
         f"XLA compiled during steady-state mutation "

@@ -330,6 +330,11 @@ V5E = 16909336064     # bytes_limit of one v5e chip, as the chip reads it
     # R9, 10M x 128 on one chip: the in-program form still fits (14.4 GB)
     ("bigann10m", 10_000_000, 128, 5_120_000_000, False, 5_120_027_136,
      False),
+    # 500K x 1,536 unit rows with both halves (PR 43): 3.072 + 3.123 +
+    # 2.7 x 3.072 = 14.49 of 14.80 GB, kept with 0.31 GB to spare (the
+    # chip read `resident`; its bytes_in_use before the first program
+    # is reckoned as the other cells' read: placed + 27,136)
+    ("openai500k", 500_000, 1536, 3_072_000_000, True, 3_072_027_136, True),
 ])
 def test_the_rule_at_every_cells_bytes(cell, rows, dim, placed, with_lo,
                                        in_use, keeps):

@@ -1,5 +1,5 @@
 """The default sub-batch of a certified call (``analysis/subbatch.py``):
-the rule alone, deviceless, over the benchmark's six configurations; a
+the rule alone, deviceless, over the benchmark's seven configurations; a
 call the rule cuts against the same call uncut, bit for bit, on the CPU
 with the kernel interpreted, at the smallest shape the rule cuts; and
 what the call records of it: every stage series once a call, the sum of
@@ -56,16 +56,18 @@ CELLS = {
     "gist1m.sweep": (960, "per_call"),
     "text2image2m5.sweep_ip": (201, "resident"),
     "yfcc2m5.sweep_filter": (192, "resident"),
+    "openai500k.sweep_cos": (1536, "resident"),
 }
 
 
 @pytest.mark.parametrize("operands", ["resident", "per_call"])
 @pytest.mark.parametrize("cell", sorted(CELLS))
-def test_the_rule_over_the_six_configurations(cell, operands):
+def test_the_rule_over_the_seven_configurations(cell, operands):
     """4,096 queries, one query shard (the four-chip cell's mesh is
     1x4: its shards are the rows'), the default query block: the three
-    byte-row cells are cut into SUB_BATCHES equal parts where their
-    operands are resident, and nothing else is cut."""
+    byte-row cells and the 1,536-column cosine cell are cut into
+    SUB_BATCHES equal parts where their operands are resident, and
+    nothing else is cut."""
     width, _ = CELLS[cell]
     rows, why = rule(width=width, operands=operands)
     if operands == "per_call":
@@ -80,8 +82,10 @@ def test_the_rule_over_the_six_configurations(cell, operands):
 def test_the_cells_as_the_chip_runs_them():
     cut = {cell for cell, (width, operands) in CELLS.items()
            if rule(width=width, operands=operands)[1] == "resident"}
+    # since PR 43 the first wide-row cell among them: 12 whole column
+    # tiles of unit rows with resident operands
     assert cut == {"bigann5m.sweep", "bigann20m-x4.sweep",
-                   "ssnpp2m5.sweep_range"}
+                   "ssnpp2m5.sweep_range", "openai500k.sweep_cos"}
     assert rule(width=960, operands="per_call")[1] == "per_call_operands"
     # gist1m joins by the rule alone once its operands are kept and its
     # rows go to the kernel as placed (1,024 columns), with no edit

@@ -226,7 +226,7 @@ def test_placement_takes_norms_in_blocks_and_makes_one_copy():
     assert placed_max == (norms + out[:, 16].astype(np.float64) ** 2).max()
     prog = ShardedKNN(db, mesh=mesh(), k=K, metric="dot")
     assert prog._db_norm_max() == placed_max and not prog._rows_lo_zero
-    assert prog._dot_slack() == sh.DOT_AUG_SLACK * shift
+    assert prog._pair_slack() == sh.DOT_AUG_SLACK * shift
 
 
 # --- the final select's bin-merge at this cell's geometry, compiled ----------
@@ -349,6 +349,31 @@ def test_the_kernel_takes_resident_row_operands_at_the_cells_widths(
             if re.match(rf"\s*%\S+ = (bf16|f32)\[{rows_p}(,{dim_p})?\]", ln)
             and not re.search(r" (parameter|copy-start|copy-done)\(", ln)]
     assert made == []
+
+
+def test_the_resident_low_half_is_a_rounding_the_compiler_may_not_skip(
+        one_chip):
+    """``row_operands`` compiled for a described v5e (PR 43): the rows'
+    low half is taken against ``reduce-precision``, not against the
+    cast's own round trip, which the TPU compiler keeps in float32
+    inside one fusion (on the chip that low half read all zero, and the
+    kernel's score was off by more than its tolerance at 1,536 unit
+    columns).  The in-program form keeps the text it had."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from knn_tpu.ops import pallas_knn as pk
+
+    rows = jax.ShapeDtypeStruct((pk.TILE_N, 1536), jnp.float32,
+                                sharding=one_chip)
+    text = jax.jit(functools.partial(
+        pk.row_operands, tile_n=pk.TILE_N, with_lo=True)).lower(
+        rows).compile().as_text()
+    assert "reduce-precision(" in text
+    old = jax.jit(lambda x: pk._split_rows(x, True)).lower(
+        rows).compile().as_text()
+    assert "reduce-precision(" not in old
 
 
 # --- PR 40: the validity mask at yfcc2m5's shape, compiled ---------------------
@@ -635,10 +660,10 @@ def _break_ranking(monkeypatch):
     rows, as the parent did, wherever it ranks."""
     real_members, real_refine = refine._score_members, refine.refine_exact
 
-    def members(db_np, queries_np, cand, rows, metric, out):
+    def members(db_np, queries_np, cand, rows, metric, out, norms=None):
         return real_members(db_np, queries_np, cand, rows, "l2", out)
 
-    def refine_l2(db, queries, cand_idx, k, metric="l2"):
+    def refine_l2(db, queries, cand_idx, k, metric="l2", norms=None):
         return real_refine(db, queries, cand_idx, k, "l2")
 
     from knn_tpu.ops import certified
@@ -648,7 +673,8 @@ def _break_ranking(monkeypatch):
     monkeypatch.setattr(certified, "refine_exact", refine_l2)
     monkeypatch.setattr(
         certified, "host_exact_knn",
-        lambda db, q, k, metric="l2", _real=certified.host_exact_knn:
+        lambda db, q, k, metric="l2", norms=None,
+        _real=certified.host_exact_knn:
         _real(db, q, k))
 
 

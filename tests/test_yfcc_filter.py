@@ -269,8 +269,9 @@ def every_query_flagged(monkeypatch):
     real = ShardedKNN._certify_pallas
 
     def flag_all(self, batches, bs, d, i, q_np, *a, **kw):
-        _, n_corrected = real(self, batches, bs, d, i, q_np, *a, **kw)
-        return np.arange(q_np.shape[0]), n_corrected
+        _, n_corrected, by_slack = real(self, batches, bs, d, i, q_np, *a,
+                                        **kw)
+        return np.arange(q_np.shape[0]), n_corrected, by_slack
 
     monkeypatch.setattr(ShardedKNN, "_certify_pallas", flag_all)
 
