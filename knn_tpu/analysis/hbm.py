@@ -208,7 +208,12 @@ def plan_join(
 #: the device holds column-major (a row-major copy of them, 201 columns
 #: in 256 lanes, and a copy padded to the exact path's tile); 2.19 the
 #: re-select at 1M x 960; 1.64 the certified program there once it is
-#: handed its operands; 0.75 to 1.4 every other.
+#: handed its operands; 0.75 to 1.4 every other.  Since PR 44 only a
+#: pre-placed array of such a width lies so: rows ``ShardedKNN`` lays
+#: out itself are placed in whole lane tiles, where the same programs
+#: read 1.05 (both re-selects) and 1.17 at the most (the certified
+#: program at 1M x 1,024 with its operands formed in the call); the
+#: factor has not been taken down to that (PERF.md section 7).
 ROWS_PROGRAM_TEMP_FACTOR = 2.7
 
 #: the share of the device's memory the resident row operands may fill

@@ -17,7 +17,10 @@ the rule cuts only where that is next to nothing:
 2. the placed rows are a whole number of 128-column lane tiles wide.
    Where they are not, XLA copies all rows into the kernel's layout in
    every launch (7 ms of 2.5M x 201, 12 ms of 1M x 960, PERF.md §6
-   PR 39);
+   PR 39).  Since PR 44 ``ShardedKNN`` places the rows it lays out
+   itself in whole lane tiles whatever their width, so who can still
+   reach this is a PRE-PLACED ``jax.Array`` of another width, which is
+   used as it is handed in;
 3. the call has ``SUB_BATCHES`` sub-batches in it, each whole query
    blocks on every query shard of the mesh, of at least
    ``SUB_BATCH_MIN_ROWS`` queries and ``SUB_BATCH_MIN_BLOCKS`` such

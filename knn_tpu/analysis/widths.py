@@ -35,6 +35,17 @@ from typing import Dict, Optional, Tuple
 #: pinned by test)
 DIM_CHUNK = 128
 
+
+def lane_tiled(width: int) -> int:
+    """The columns a placement lays rows of ``width`` columns out in:
+    the next whole number of 128-column lane tiles (192 -> 256,
+    960 -> 1,024; 128, 256 and 1,536 as they are).  A narrower f32
+    array lies column-major on a TPU, and every program that reads it
+    row-major copies the whole of it first
+    (parallel.sharded.ShardedKNN)."""
+    return int(width) + -int(width) % DIM_CHUNK
+
+
 #: db stream width per element by kernel matmul precision.  "pq" is
 #: deliberately ABSENT — its row width is ``ceil(d / dsub)`` bytes,
 #: shape-dependent, served by :func:`db_row_bytes`.
@@ -137,5 +148,5 @@ __all__ = [
     "QUERY_ELEM_BYTES", "QUERY_ELEM_BYTES_DEFAULT", "DB_PARTS",
     "AUX_BYTES_PER_ROW", "PQ_DSUB_DEFAULT", "PQ_NCODES_DEFAULT",
     "pq_nsub", "db_row_bytes", "aux_rows_for", "query_elem_bytes",
-    "pq_lut_bytes", "pq_lut_flops",
+    "pq_lut_bytes", "pq_lut_flops", "lane_tiled",
 ]

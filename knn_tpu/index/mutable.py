@@ -465,9 +465,12 @@ class MutableIndex:
             if tp is not None and tp["key"] == key:
                 return tp
         capacity = self._capacity_for(snap.tail_len)
-        arr = np.full((capacity, self.dim), PAD_VAL, np.float32)
+        # at the width the main placement's batches are placed at (whole
+        # lane tiles: zero columns past ``dim``, ShardedKNN's docstring)
+        arr = np.zeros((capacity, snap.main._placed_width), np.float32)
+        arr[snap.tail_len:, : self.dim] = PAD_VAL
         if snap.tail_len:
-            arr[: snap.tail_len] = snap.tail
+            arr[: snap.tail_len, : self.dim] = snap.tail
         placed = {
             "key": key,
             "capacity": capacity,

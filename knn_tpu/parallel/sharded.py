@@ -41,6 +41,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from knn_tpu import obs
+from knn_tpu.analysis.widths import lane_tiled
 from knn_tpu.obs import jax_hooks as _hooks
 from knn_tpu.obs import names as _mn
 from knn_tpu.ops import refine as _refine
@@ -678,6 +679,20 @@ class ShardedKNN:
 
     The reference has no equivalent: its train set is re-broadcast every
     process launch (knn_mpi.cpp:224-225).
+
+    ``_tp`` is the placed rows, a ``jax.Array`` ``[rows, placed width]``
+    sharded along the db axis (None under the host-RAM tier).  The placed
+    width is the width given (a dot placement's with its augmentation
+    column) where that is a whole number of 128-column lane tiles, and
+    the next such number where it is not (192 -> 256, 960 -> 1,024), the
+    columns past the given width all zero: a narrower array lies
+    column-major on a TPU and every program that reads it row-major
+    copies the whole of it first, in every call.  Zero columns add
+    nothing to a squared difference, an absolute difference, an inner
+    product or a norm, so no answer moves; queries are widened to match
+    where they are placed (:meth:`_place_queries`), the host's copies
+    stay at the given width (:meth:`_host_train`), and ``dim_in`` is the
+    caller's.  A pre-placed ``jax.Array`` is used as it is handed in.
     """
 
     def __init__(
@@ -954,8 +969,17 @@ class ShardedKNN:
 
             self._row_tags = check_bags(*row_tags, n_train)
         #: user-facing query/input dim — dot placements append one norm-
-        #: augmentation column, so the PLACED width is ``dim_in + 1``
+        #: augmentation column, so the rows to place are ``dim_in + 1``
+        #: wide
         self.dim_in = int(tp.shape[1]) - (1 if self._dot_aug else 0)
+        #: the columns of the rows as given to the placement, and as
+        #: placed (every placed batch's too): whole 128-column lane tiles
+        #: where this placement lays the rows out itself (class
+        #: docstring), the width handed in where it does not (a
+        #: pre-placed array, the host-RAM tier's segments)
+        self._given_width = self._placed_width = int(tp.shape[1])
+        if not pre_placed and self._host_tier is None:
+            self._placed_width = lane_tiled(self._given_width)
         self._dtype_key = (
             None if compute_dtype is None else jnp.dtype(compute_dtype).name
         )
@@ -968,8 +992,19 @@ class ShardedKNN:
             # transfer on its way, and the wait for it is the caller's
             t0 = time.perf_counter()
             self._tp = shard(tp, mesh, db_axes(mesh))
+            if self._placed_width != self._given_width:
+                # whole lane tiles, once and on the device: the rows go
+                # over as they are, one small program writes them out
+                # with zero columns after them (the copy every call
+                # made, made here), and the compact array is dropped
+                widen = _lane_tile_program(mesh, self._placed_width)
+                begun = _hooks.first_call_begin()
+                self._tp = widen(self._tp)
+                _hooks.first_call_end(begun, widen, "lane_tile",
+                                      rows=int(tp.shape[0]))
             obs.emit_event("placement.device_put", rows=int(tp.shape[0]),
-                           bytes=int(tp.nbytes),
+                           bytes=int(tp.nbytes), width=self._given_width,
+                           placed_width=self._placed_width,
                            seconds=time.perf_counter() - t0)
             if self._cosine_unit:
                 # the unit rows live on the device from here on (the
@@ -1074,17 +1109,22 @@ class ShardedKNN:
         return out
 
     def _place_queries(self, queries):
+        """``(placed, rows)``: a batch padded to the query shards and
+        sent to them, at the PLACED width: a batch that arrives at the
+        caller's width (or, from search_certified, with a dot
+        placement's zero column already appended) gets zero columns up
+        to ``_placed_width``: the dot placement's augmentation column
+        (q'.t' == q.t) and the lane-tile columns of the class docstring
+        alike."""
         if not isinstance(queries, jax.Array):
             queries = np.asarray(queries)
-            if (self._dot_aug and queries.ndim == 2
-                    and queries.shape[1] == self.dim_in):
-                # dot placements are norm-augmented: queries ride with a
-                # zero column (q'.t' == q.t).  Already-augmented callers
-                # (search_certified) arrive at width dim_in + 1 and pass
-                # through untouched.
-                queries = np.concatenate(
-                    [np.asarray(queries, np.float32),
-                     np.zeros((queries.shape[0], 1), np.float32)], axis=1)
+            if self._dot_aug:
+                queries = np.asarray(queries, np.float32)
+        if (queries.ndim == 2
+                and self.dim_in <= queries.shape[1] < self._placed_width):
+            xp = jnp if isinstance(queries, jax.Array) else np
+            queries = xp.pad(queries, (
+                (0, 0), (0, self._placed_width - queries.shape[1])))
         qp, n_q = pad_to_multiple(queries, self.mesh.shape[QUERY_AXIS])
         return shard(qp, self.mesh, QUERY_AXIS), n_q
 
@@ -1433,8 +1473,15 @@ class ShardedKNN:
         cosine placement, whose host copy is the rows as given: its unit
         rows are read back from the device."""
         if self._cosine_unit and self._tp is not None:
-            return np.asarray(self._tp)[: self.n_train]
+            return self._fetch_rows()
         return self._host_train()
+
+    def _fetch_rows(self) -> np.ndarray:
+        """The (unpadded) rows read back from the device, cut to the
+        width they were given at."""
+        rows = np.asarray(self._tp)[: self.n_train]
+        return (rows if self._given_width == rows.shape[1]
+                else np.ascontiguousarray(rows[:, : self._given_width]))
 
     def _host_train(self) -> np.ndarray:
         """Host copy of the (unpadded) database for float64 refinement;
@@ -1449,7 +1496,7 @@ class ShardedKNN:
                     "construct ShardedKNN from a host array instead"
                 )
             t0 = time.perf_counter()
-            self._train_host = np.asarray(self._tp)[: self.n_train]
+            self._train_host = self._fetch_rows()
             obs.emit_event("placement.host_copy", rows=int(self.n_train),
                            seconds=time.perf_counter() - t0)
         return self._train_host
@@ -1642,7 +1689,6 @@ class ShardedKNN:
         if held is not None and held["key"] == key:
             return held["parts"]
         from knn_tpu.analysis import hbm
-        from knn_tpu.ops.pallas_knn import DIM_CHUNK
 
         with self._engines_lock:
             held = self._operands_cache
@@ -1651,7 +1697,7 @@ class ShardedKNN:
             self._operands_cache = None  # the old form goes first
             shards = self.db_shards
             rows_p = -(-self._shard_rows() // tile) * tile
-            dim_p = -(-self._tp.shape[1] // DIM_CHUNK) * DIM_CHUNK
+            dim_p = lane_tiled(self._tp.shape[1])
             form_bytes = hbm.row_operand_bytes(rows_p, dim_p, with_lo)
             if memory_stats is None:
                 dev = self._tp.addressable_shards[0].device
@@ -2097,7 +2143,7 @@ class ShardedKNN:
                     from knn_tpu import tuning
 
                     knobs, tune_info = tuning.resolve_full(
-                        self.n_train, self._tp.shape[1], self.k,
+                        self.n_train, self._given_width, self.k,
                         metric=cert_metric, dtype=self._dtype_key,
                         cache_path=tune_cache,
                         overrides=dict(
@@ -3359,6 +3405,12 @@ def _pallas_certified_program(
             *tail, aug_slack = tail
         db_q, db_pq, consts, db_norm_max, db_rows = _split_operand_tail(
             precision, tail)
+        q_cert = q
+        if db_q is not None and db_q[0].shape[1] < q.shape[1]:
+            # the quantized rows keep the width given, and their
+            # certificate's query norm is taken in THEIR shifted space:
+            # a lane-tile column of a placed batch is 0, not the offset
+            q_cert = q[:, : db_q[0].shape[1]]
         d32, li, lb = local_certified_candidates(
             q, t, m, tile_n=eff_tile, survivors=survivors,
             block_q=eff_bq, final_select=final_select, precision=precision,
@@ -3369,7 +3421,7 @@ def _pallas_certified_program(
             **({"valid_words": words} if masked else {}),
         )
         return _certify_pack_spmd(
-            q, t, d32, li, lb, consts=consts, db_norm_max=db_norm_max,
+            q_cert, t, d32, li, lb, consts=consts, db_norm_max=db_norm_max,
             precision=precision, quant_offset=quant_offset, m=m, k=k, w=w,
             merge=merge, n_train=n_train, hosts=hosts, chips=chips,
             dcn_merge=dcn_merge,
@@ -3432,6 +3484,52 @@ def _split_operand_tail(precision: str, tail):
         return None, (codes, books), consts, None, None
     db_norm_max, *db_rows = tail
     return None, None, None, db_norm_max, tuple(db_rows) or None
+
+
+#: rows the lane-tile program writes at a time: what it keeps beside its
+#: input and its output is one block in each layout (67 MB at 256
+#: columns), not a second copy of the corpus
+_LANE_TILE_BLOCK_ROWS = 65536
+
+
+def _lane_tiled_rows(rows: jax.Array, width: int) -> jax.Array:
+    """One shard's rows with zero columns after them up to ``width``,
+    written a block of rows at a time into the output in place (the last
+    block laid back over the one before it where the rows are no whole
+    number of blocks)."""
+    n, dim = rows.shape
+    block = min(n, _LANE_TILE_BLOCK_ROWS)
+
+    def write(i, out):
+        lo = jnp.minimum(i * block, n - block)
+        part = lax.dynamic_slice(rows, (lo, 0), (block, dim))
+        return lax.dynamic_update_slice(
+            out, jnp.pad(part, ((0, 0), (0, width - dim))), (lo, 0))
+
+    return lax.fori_loop(0, -(-n // block), write,
+                         jnp.zeros((n, width), rows.dtype))
+
+
+@functools.lru_cache(maxsize=8)
+def _lane_tile_program(mesh: Mesh, width: int):
+    """The program that lays a placement's rows out in whole lane tiles,
+    once a placement (``ShardedKNN.__init__``): every shard writes its
+    rows with zero columns after them up to ``width``, db-sharded as the
+    rows are.  A width that is no multiple of 128 lies column-major on a
+    TPU, so this is the copy into the row-major form that every program
+    reading the rows made in every call, made once."""
+    dbp = db_axes(mesh)
+    prog = jax.jit(
+        jax.shard_map(
+            functools.partial(_lane_tiled_rows, width=width),
+            mesh=mesh,
+            in_specs=P(dbp),
+            out_specs=P(dbp),
+            check_vma=False,
+        )
+    )
+    _hooks.mark_built(prog, f"width={width}")
+    return prog
 
 
 @functools.lru_cache(maxsize=8)

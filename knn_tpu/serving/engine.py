@@ -201,8 +201,9 @@ class ServingEngine:
             # instead of a cryptic NoneType AttributeError below
             program._require_resident("ServingEngine")
         #: user-facing request dim (what submit validates/pads against);
-        #: dot placements are norm-augmented, so the PLACED width below
-        #: is one wider — _place_queries appends the zero column
+        #: the PLACED width below is wider wherever the rows lie in whole
+        #: lane tiles or carry a dot placement's norm column:
+        #: _place_queries appends the zero columns
         self._dim = int(getattr(program, "dim_in", program._tp.shape[1]))
         self._placed_dim = int(program._tp.shape[1])
         self._lock = threading.Lock()

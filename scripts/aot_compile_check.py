@@ -21,8 +21,9 @@ before Mosaic is asked; compiles the whole certified program with the
 kernel's one-product form (``--terms hh``: what a byte corpus and a
 byte batch run, ``ops.pallas_knn.BF16X3_TERMS``) at 5M x 128 on one
 chip and at 20M x 128 on the 1x4 mesh, printing what each keeps on a
-chip; compiles the inner-product cell's program (2.5M x 201 placed
-columns, k=10: one more operand, no distance block) and the range
+chip; compiles the inner-product cell's program (2.5M x 201 columns
+placed in 256, as ``ShardedKNN`` lays them out since PR 44; k=10: one
+more operand, no distance block) and the range
 cell's first pass (2.5M x 256, one product, ``ssnpp2m5``); compiles the
 final select's bin-merge kernel at a 5M-row chip's candidate width
 (``bigann20m``), at ``text2image2m5``'s (39,168 columns at m+2 = 40:
@@ -148,11 +149,13 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from knn_tpu.analysis.widths import lane_tiled
     from knn_tpu.ops.pallas_knn import TILE_N
     from knn_tpu.parallel.mesh import DB_AXIS, QUERY_AXIS
     from knn_tpu.parallel.sharded import _pallas_certified_program
 
     n, d, k = SHAPES[shape]
+    d = lane_tiled(d)  # as ShardedKNN places the rows and every batch
     qs, ds = mesh_shape
     mesh = Mesh(np.asarray(devices[:qs * ds]).reshape(qs, ds),
                 (QUERY_AXIS, DB_AXIS))
@@ -406,7 +409,7 @@ def default_cases():
     # chunk, one product
     cases.append(("ssnpp2m5 program mesh=1x1 terms=hh", "ssnpp2m5", {},
                   "compiles", (1, 1), "hh"))
-    # the inner-product cell's program: 201 placed columns, k=10
+    # the inner-product cell's program: 201 columns placed in 256, k=10
     cases.append(("text2image2m5 program mesh=1x1", "text2image2m5", {},
                   "compiles", (1, 1), None))
     # gist1m's: the final select's Pallas stage over the kernel's own

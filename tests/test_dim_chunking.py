@@ -292,7 +292,8 @@ def test_inner_product_at_201_columns_runs_one_chunk(fresh_registry, rng,
     prog = ShardedKNN(
         db, mesh=make_mesh(1, shards, devices=jax.devices()[:shards]),
         k=10, metric="dot")
-    assert prog._tp.shape[1] == 201
+    # placed in whole lane tiles; the host's copy keeps the 201 given
+    assert (prog._tp.shape[1], prog._host_train().shape[1]) == (256, 201)
     d, i, stats = prog.search_certified(q, selector="pallas", tile_n=TILE,
                                         batch_size=3)
     scores = -(q.astype(np.float64) @ db.astype(np.float64).T)

@@ -48,8 +48,10 @@ def test_one_row_over_budget_streams_two_sweeps(rng):
     st = prog.hosttier_stats()
     assert st is not None and st["sweeps"] == 2
     d, i = prog.search(q)
-    np.testing.assert_array_equal(i, np.asarray(ref_i))
-    np.testing.assert_array_equal(d, np.asarray(ref_d))
+    # the tier streams the rows at the width given, the resident
+    # placement lays them out in whole lane tiles (16 columns in 128):
+    # same neighbours, f32 distances within rounding
+    assert_same_neighbors(d, i, ref_d, ref_i, q, db)
 
 
 def test_many_times_over_budget_matches_byte_model_and_is_bitwise(rng):
@@ -124,7 +126,9 @@ def test_host_tier_k_override_and_cosine(rng):
     rd, ri = ref.search(q, k=5)
     d, i = tier.search(q, k=5)
     np.testing.assert_array_equal(i, np.asarray(ri))
-    np.testing.assert_array_equal(d, np.asarray(rd))
+    # two widths (above): cosine distances of unit rows, within rounding
+    np.testing.assert_allclose(d, np.asarray(rd), rtol=0,
+                               atol=8 * np.finfo(np.float32).eps)
 
 
 def test_resident_only_paths_refuse_host_tier(rng):

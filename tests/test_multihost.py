@@ -54,17 +54,25 @@ def test_shard_across_hosts_places_db_sharded(rng):
     np.testing.assert_array_equal(np.asarray(arr), local)
 
 
-def test_sharded_knn_accepts_pre_placed_global_array(rng):
+@pytest.mark.parametrize("dim", [12, 128])
+def test_sharded_knn_accepts_pre_placed_global_array(rng, dim):
     mesh = make_mesh(4, 2)
-    db = rng.normal(size=(128, 12)).astype(np.float32)
-    q = rng.normal(size=(20, 12)).astype(np.float32)
-    ref_d, ref_i = ShardedKNN(db, mesh=mesh, k=7).search(q)
+    db = rng.normal(size=(128, dim)).astype(np.float32)
+    q = rng.normal(size=(20, dim)).astype(np.float32)
+    ref = ShardedKNN(db, mesh=mesh, k=7)
+    ref_d, ref_i = ref.search(q)
 
     placed = shard_across_hosts(db, mesh, DB_AXIS)
     prog = ShardedKNN(placed, mesh=mesh, k=7)
+    # a pre-placed array is used as it is handed in; a host array is
+    # laid out in whole 128-column lane tiles
+    assert (prog._tp.shape[1], ref._tp.shape[1]) == (dim, 128)
     d, i = prog.search(q)
-    np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_i))
-    np.testing.assert_array_equal(np.asarray(d), np.asarray(ref_d))
+    if dim % 128:  # two widths, two programs: f32 within rounding
+        assert_same_neighbors(d, i, ref_d, ref_i, q, db)
+    else:
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(ref_i))
+        np.testing.assert_array_equal(np.asarray(d), np.asarray(ref_d))
 
 
 def test_replicated_placement_flows_through_normal_path(rng):
@@ -147,8 +155,10 @@ def test_multihost_real_processes_bitwise_parity(rng, tmp_path):
                              num_processes=n_proc, process_id=pid)
         assert jax.process_count() == n_proc
         rng = np.random.default_rng(0)
-        db = (rng.random((64, 8)) * 10).astype(np.float32)
-        q = (rng.random((6, 8)) * 10).astype(np.float32)
+        # 128 columns: whole lane tiles, so the pre-placed slices here
+        # and the host array below are one placed width, one program
+        db = (rng.random((64, 128)) * 10).astype(np.float32)
+        q = (rng.random((6, 128)) * 10).astype(np.float32)
         mesh = multihost.global_mesh(1, n_proc)
         sl = multihost.process_row_slice(64)
         placed = multihost.shard_across_hosts(db[sl], mesh, DB_AXIS)
@@ -167,8 +177,8 @@ def test_multihost_real_processes_bitwise_parity(rng, tmp_path):
 
     # bitwise parity with the single-process placement (same seeded data)
     data_rng = np.random.default_rng(0)
-    db = (data_rng.random((64, 8)) * 10).astype(np.float32)
-    q = (data_rng.random((6, 8)) * 10).astype(np.float32)
+    db = (data_rng.random((64, 128)) * 10).astype(np.float32)
+    q = (data_rng.random((6, 128)) * 10).astype(np.float32)
     ref_d, ref_i = ShardedKNN(db, mesh=make_mesh(1, 2), k=5).search(q)
     np.testing.assert_array_equal(
         np.asarray(results[0]["i"]), np.asarray(ref_i))

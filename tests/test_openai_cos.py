@@ -319,7 +319,9 @@ def test_placement_makes_unit_rows_in_blocks_and_keeps_the_rows_as_given():
     prog = ShardedKNN(db, mesh=mesh(), k=K, metric="cosine")
     assert prog._db_norm_max() == placed_max and not prog._rows_lo_zero
     assert prog._host_train() is db  # no second copy on the host
-    np.testing.assert_array_equal(np.asarray(prog._tp)[:20_000], unit)
+    placed = np.asarray(prog._tp)[:20_000]  # 200 columns in 256 lanes
+    np.testing.assert_array_equal(placed[:, :200], unit)
+    assert placed.shape[1] == 256 and not placed[:, 200:].any()
     np.testing.assert_array_equal(prog._placed_host(), unit)
 
 

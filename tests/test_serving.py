@@ -323,7 +323,7 @@ def test_warmup_prebuilds_int8_placement_when_winner_says_so(
     cache = str(tmp_path / "warm_tune.json")
     monkeypatch.setenv(tuning.CACHE_ENV, cache)
     key = tuning.cache_key(
-        "cpu", prog.n_train, prog._tp.shape[1], prog.k, prog.metric, None)
+        "cpu", prog.n_train, prog.dim_in, prog.k, prog.metric, None)
     tuning.TuneCache(cache).put(
         key, {"knobs": {**tuning.DEFAULT_KNOBS, "precision": "int8"}})
     engine = ServingEngine(prog, buckets=BUCKETS)

@@ -168,7 +168,7 @@ def test_pallas_batched_matches_unbatched(rng, batch_size, ties):
     cut = ("sub_batch", "batches")
     assert ({k: v for k, v in s1["pallas_knobs"].items() if k not in cut}
             == {k: v for k, v in s0["pallas_knobs"].items() if k not in cut})
-    assert (s0["sub_batch"], s0["batches"]) == ("layout_copy", 1)  # 12 wide
+    assert (s0["sub_batch"], s0["batches"]) == ("small", 1)
     assert (s1["sub_batch"], s1["batches"]) == (
         "explicit", -(-queries.shape[0] // batch_size))
     assert s1["certified"] + s1["fallback_queries"] == queries.shape[0]

@@ -25,6 +25,7 @@ import program_digest  # noqa: E402  (tests/)
 
 from knn_tpu import obs  # noqa: E402
 from knn_tpu.analysis import subbatch  # noqa: E402
+from knn_tpu.analysis.widths import lane_tiled  # noqa: E402
 from knn_tpu.obs import names as mn  # noqa: E402
 from knn_tpu.obs import trace as obs_trace  # noqa: E402
 from knn_tpu.ops import certified, pallas_knn as pk  # noqa: E402
@@ -48,7 +49,9 @@ def rule(queries=4096, *, batch_size=None, operands="resident", width=128,
 
 
 # --- the rule, deviceless -----------------------------------------------------
-#: cell -> (placed columns, the operands' source on the chip)
+#: cell -> (the rows' columns as the placement is GIVEN them, the
+#: operands' source on the chip); ``lane_tiled`` of the first is what
+#: the rule reads, the placed width (PR 44)
 CELLS = {
     "bigann5m.sweep": (128, "resident"),
     "bigann20m-x4.sweep": (128, "resident"),
@@ -60,19 +63,24 @@ CELLS = {
 }
 
 
+@pytest.mark.parametrize("as_placed", [True, False])
 @pytest.mark.parametrize("operands", ["resident", "per_call"])
 @pytest.mark.parametrize("cell", sorted(CELLS))
-def test_the_rule_over_the_seven_configurations(cell, operands):
+def test_the_rule_over_the_seven_configurations(cell, operands, as_placed):
     """4,096 queries, one query shard (the four-chip cell's mesh is
-    1x4: its shards are the rows'), the default query block: the three
-    byte-row cells and the 1,536-column cosine cell are cut into
-    SUB_BATCHES equal parts where their operands are resident, and
-    nothing else is cut."""
+    1x4: its shards are the rows'), the default query block.  At the
+    width a placement lays the rows out in (whole lane tiles) every
+    cell is cut into SUB_BATCHES equal parts where its operands are
+    resident; at the width GIVEN, which a pre-placed array keeps, the
+    three cells of 192, 201 and 960 columns are one batch."""
     width, _ = CELLS[cell]
+    if as_placed:
+        width = lane_tiled(width)
     rows, why = rule(width=width, operands=operands)
     if operands == "per_call":
         assert (rows, why) == (4096, "per_call_operands")
     elif width % 128:
+        assert not as_placed
         assert (rows, why) == (4096, "layout_copy")
     else:
         assert (rows, why) == (4096 // N, "resident")
@@ -81,15 +89,17 @@ def test_the_rule_over_the_seven_configurations(cell, operands):
 
 def test_the_cells_as_the_chip_runs_them():
     cut = {cell for cell, (width, operands) in CELLS.items()
-           if rule(width=width, operands=operands)[1] == "resident"}
-    # since PR 43 the first wide-row cell among them: 12 whole column
-    # tiles of unit rows with resident operands
-    assert cut == {"bigann5m.sweep", "bigann20m-x4.sweep",
-                   "ssnpp2m5.sweep_range", "openai500k.sweep_cos"}
-    assert rule(width=960, operands="per_call")[1] == "per_call_operands"
-    # gist1m joins by the rule alone once its operands are kept and its
-    # rows go to the kernel as placed (1,024 columns), with no edit
-    assert rule(width=1024)[1] == "resident"
+           if rule(width=lane_tiled(width), operands=operands)[1]
+           == "resident"}
+    # since PR 44 every cell whose operands are resident: the rows of
+    # text2image2m5 (201 columns) and yfcc2m5 (192) are placed in 256
+    assert cut == set(CELLS) - {"gist1m.sweep"}
+    assert rule(width=lane_tiled(960), operands="per_call")[1] == (
+        "per_call_operands")
+    # gist1m joins by the rule alone once its operands are kept
+    assert rule(width=lane_tiled(960))[1] == "resident"
+    # and what the placement does not lay out keeps its width's reading
+    assert {rule(width=w)[1] for w in (192, 201, 960)} == {"layout_copy"}
 
 
 @pytest.mark.parametrize("queries,want", [
@@ -161,6 +171,7 @@ def test_the_program_of_every_launch_is_the_parents(cell):
     the one the parent's tree traces at those rows (there an explicit
     ``batch_size`` of as many)."""
     width, operands = CELLS[cell]
+    width = lane_tiled(width)
     assert width == program_digest.CELLS[cell][2]
     rows, why = rule(program_digest.QUERIES, width=width, operands=operands)
     got = program_digest.digest(cell, rows)
@@ -243,13 +254,33 @@ def test_a_padded_tail_and_a_small_call(placed, corpus, queries):
     same_answer(got, one)
 
 
-def test_a_width_of_no_whole_tiles_is_one_batch():
+@pytest.mark.parametrize("metric,dim,pre_placed,want", [
+    ("l2", 192, False, (N, "resident")),
+    ("dot", 200, False, (N, "resident")),   # 201 placed-given columns
+    ("l2", 201, True, (1, "layout_copy")),
+])
+def test_a_width_of_no_whole_tiles(metric, dim, pre_placed, want):
+    """Rows the placement lays out itself are placed in whole lane
+    tiles, so a default 4,096-query call on them is cut as any other
+    (PR 44); a pre-placed array is used at the width it is handed in,
+    and there the rule still reads ``layout_copy``."""
     rng = np.random.default_rng(3)
-    db = rng.integers(0, 256, size=(600, 96)).astype(np.float32)
-    q = rng.integers(0, 256, size=(N_Q, 96)).astype(np.float32)
-    prog = ShardedKNN(db, mesh=make_mesh(1, 1), k=K)
-    _, _, stats = prog.search_certified(q, selector="pallas")
-    assert (stats["batches"], stats["sub_batch"]) == (1, "layout_copy")
+    db = rng.integers(0, 256, size=(600, dim)).astype(np.float32)
+    q = rng.integers(0, 256, size=(N_Q, dim)).astype(np.float32)
+    mesh = make_mesh(1, 1)
+    train = db
+    if pre_placed:
+        from knn_tpu.parallel.collectives import shard
+        from knn_tpu.parallel.mesh import db_axes
+
+        train = shard(db, mesh, db_axes(mesh))
+    prog = ShardedKNN(train, mesh=mesh, k=K, metric=metric)
+    assert prog._tp.shape[1] == (dim if pre_placed else 256)
+    _, i, stats = prog.search_certified(q, selector="pallas")
+    assert stats["operands"] == "resident"
+    assert (stats["batches"], stats["sub_batch"]) == want
+    one = prog.search_certified(q[:64], selector="pallas", batch_size=64)
+    np.testing.assert_array_equal(i[:64], one[1])
 
 
 def test_operands_formed_in_the_program_are_one_batch(corpus):

@@ -438,6 +438,93 @@ def test_the_mask_program_compiles_at_the_filter_cells_shape(one_chip):
     assert "%filter_mask" in text
 
 
+def _users_of_the_rows(text, rows, width):
+    """``(opcode, elements of its largest output, the line)`` of every
+    instruction of a compiled module that takes the placed rows (the
+    parameter ``f32[rows, width]`` of the entry computation) as an
+    operand, and the layout the compiler gave that parameter."""
+    text = text[text.index("\nENTRY "):]
+    text = text[:text.index("\n}")]
+    (name, layout), = re.findall(
+        rf"(%[\w.-]+) = f32\[{rows},{width}\](\{{[^}}]*\}}) parameter\(",
+        text)
+    users = []
+    for ln in text.splitlines():
+        if not re.search(rf"[(, ]{re.escape(name)}[,)]", ln):
+            continue
+        shapes, opcode = re.search(r"= (.*?) ([a-z][\w-]*)\(", ln).groups()
+        users.append((opcode, max(
+            int(np.prod([int(x) for x in dims.split(",")]))
+            for dims in re.findall(r"\[([\d,]+)\]", shapes)), ln.strip()))
+    return layout, users
+
+
+@pytest.mark.parametrize("program,rows,given", [
+    ("certified", 2_500_000, 201),   # text2image2m5: 200 + the norm column
+    ("reselect", 1_000_000, 960)])   # gist1m: the repair's exact re-select
+def test_no_call_copies_the_placed_rows(one_chip, program, rows, given):
+    """The regression guard the chip cannot be in tier-1 (PR 44): rows
+    of 201 or 960 columns lie column-major on a v5e, and every program
+    that reads them row-major starts with a ``copy`` of all of them
+    (7.2 ms of a 116 ms batch at ``text2image2m5``, 12.2 and 2 x 6.1 at
+    ``gist1m``, the ledger's PR 43 lines); placed in whole lane tiles
+    (``analysis.widths.lane_tiled``, what ``ShardedKNN`` places at) the
+    parameter is row-major and nothing writes an array of its size: the
+    certified program's one reader is the rescore's gather, the
+    re-select's is its pad of the ROWS to whole ``train_tile``s (ROADMAP
+    A9: no column of it)."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from knn_tpu.analysis.widths import lane_tiled
+    from knn_tpu.ops import pallas_knn as pk
+    from knn_tpu.parallel.mesh import DB_AXIS, QUERY_AXIS
+
+    (device,) = one_chip.device_set
+    m = Mesh(np.asarray([device]).reshape(1, 1), (QUERY_AXIS, DB_AXIS))
+
+    def aval(shape, dtype, *spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(m, P(*spec)))
+
+    def compiled(width):
+        if program == "reselect":
+            prog = sh._knn_program(m, 256, "l2", "ring", rows, 131072, None,
+                                   "exact", dcn_merge=None)
+            tail, queries = (), 16
+        else:
+            rows_p = -(-rows // pk.TILE_N) * pk.TILE_N
+            prog = sh._pallas_certified_program(
+                m, K + 28, K, "ring", pk.TILE_N, "bf16x3", n_train=rows,
+                interpret=False, augmented=True, include_distances=False,
+                dim_chunk=256, resident_parts=2)
+            tail = (aval((), jnp.float32),
+                    *[aval((rows_p, 256), jnp.bfloat16, DB_AXIS)] * 2,
+                    aval((rows_p,), jnp.float32, DB_AXIS),
+                    aval((), jnp.float32))
+            queries = 4096
+        return prog.lower(aval((queries, width), jnp.float32, QUERY_AXIS),
+                          aval((rows, width), jnp.float32, DB_AXIS),
+                          *tail).compile().as_text()
+
+    # as given: column-major, and a copy of all of it before anything
+    layout, users = _users_of_the_rows(compiled(given), rows, given)
+    assert layout.startswith("{0,1")
+    assert [op for op, size, _ in users if size >= rows * given] == ["copy"]
+    # as placed
+    width = lane_tiled(given)
+    layout, users = _users_of_the_rows(compiled(width), rows, width)
+    assert layout.startswith("{1,0")
+    assert not [ln for op, _, ln in users if op.startswith("copy")]
+    whole = [(op, ln) for op, size, ln in users if size >= rows * width]
+    if program == "certified":
+        assert whole == [] and "pad" not in [op for op, _, _ in users]
+        assert [op for op, _, _ in users] == ["fusion"]  # the gather
+    else:
+        ((op, ln),) = whole
+        assert op == "pad" and re.search(r"padding=0_\d+x0_0[,} ]", ln)
+
+
 # --- the plain reference ------------------------------------------------------
 def test_the_oracle_is_a_float64_argsort():
     db, q = mix(70_000, 24, dim=24)  # two blocks of rows
