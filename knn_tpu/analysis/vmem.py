@@ -38,33 +38,45 @@ bq256 (SIFT tiled / GIST tiled / SIFT streaming, MiB): "highest" needs
 <=8 / 55.66 / 100.06 against 39.56 / 71.56 / 115.81 — an upper bound,
 by up to 2.7x; and the "pq" one-hot expansion is not modeled at all.
 
-THE WIDTH OF A DIM CHUNK is part of the launch geometry and has its
-one rule here (:func:`dim_chunking`, PR 32): where a row tile's whole
-padded width fits the device beside everything else the launch keeps,
-with :func:`limit_bytes`' eighth to spare, the tile is ONE chunk (one
-grid step, no accumulator scratch, the select in the matmul's own
-step); otherwise the chunk is ``DIM_CHUNK`` columns, the padding grain.
-The kernel, :func:`launch_estimate` and ``obs.roofline`` all ask it.
-The one-chunk geometries it chooses were probed like the rest (bf16x3,
-bq256, tile 16384; the least limit that compiles, by bisection, in MiB
-against the model): 256 columns 59 / 66.75, 384 79 / 83.0, 512 99 /
-99.25, beside 48 / 50.5 at 128 — the model over by 0.3% to 13%, 7.75
-MiB at the most, inside the eighth (8.3) its limit adds.  It was NOT
-re-fitted: what Mosaic keeps beside the declared buffers is 1.8, 1.5,
-1.7 and 2.0 score tiles at 128, 256, 384 and 512 columns, no function
-of the width a multiplier could follow, and ``live = 2`` bounds all
-four.  (Under its need Mosaic may schedule otherwise and name another
-size: 98.32 MiB at a limit of 58 for the 256-column chunk, which
-compiles from 59 up — the probe bisects on the limit, it does not trust
-the size.)  With the low row half dropped (``terms`` "hh": one part)
-the model is an upper bound by far: 18 reported against 50.75 at 256
-columns, 70 against 100.25 at 1,024.  The rule is the TILED kernel's
-alone: the streaming and fused kernels hold a whole query block, both
-row buffers and every tile's output at once, so one wide chunk that the
-tiled kernel has room for overruns them (1M rows of 512 columns at
-bq128: 120.2 and 124.4 MiB modeled, and Mosaic refuses both; of 640,
-136 and 140) where their 128-column chunks compile at about 80.  They
-keep ``DIM_CHUNK`` at every width, the parent's programs.
+HOW A ROW TILE IS CUT is part of the launch geometry and has its one
+rule here (:func:`row_blocking`, PR 46; :func:`dim_chunking` since PR
+32).  Under the tiled kernel a tile is never cut by columns: every grid
+step multiplies the WHOLE padded width in one product, so no partial
+product outlives a step.  Where the tile's row blocks at that width fit
+the device beside everything else the launch keeps, with
+:func:`limit_bytes`' eighth to spare, the tile is ONE step (no scratch,
+the select in the matmul's own step: 128 to 512 columns at the default
+tile and query block); where they do not (``gist1m``'s 1,024 columns,
+``openai500k``'s 1,536) the grid's third axis walks ROW BLOCKS of the
+tile, the largest that divides the tile into whole 128-row groups and
+fits, and only the bin-select's running arrays (``select_state``, 640
+KiB at a query block of 256) are carried between steps.  The kernel,
+:func:`launch_estimate` and ``obs.roofline`` all ask the rule.  Until
+PR 46 such a tile was cut into ``DIM_CHUNK``-column chunks whose
+``[block_q, tile_n]`` partial product (16 MiB) was read, added to and
+stored back at every step: 25.5 us a 128-column pass-set at ``gist1m``
+against 16.7 in the one-step form (root PERF.md section 6, PR 45).
+The one-step geometries were probed like the rest (bf16x3, bq256, tile
+16384; the least limit that compiles, by bisection, in MiB against the
+model): 256 columns 59 / 66.75, 384 79 / 83.0, 512 99 / 99.25, beside
+48 / 50.5 at 128 — the model over by 0.3% to 13%, 7.75 MiB at the
+most, inside the eighth (8.3) its limit adds.  It was NOT re-fitted:
+what Mosaic keeps beside the declared buffers is 1.8, 1.5, 1.7 and 2.0
+score tiles at 128, 256, 384 and 512 columns, no function of the width
+a multiplier could follow, and ``live = 2`` bounds all four.  (Under
+its need Mosaic may schedule otherwise and name another size: 98.32
+MiB at a limit of 58 for the 256-column chunk, which compiles from 59
+up — the probe bisects on the limit, it does not trust the size.)
+With the low row half dropped (``terms`` "hh": one part) the model is
+an upper bound by far: 18 reported against 50.75 at 256 columns, 70
+against 100.25 at 1,024.  The row-cut geometries' readings are in
+:func:`row_blocking`.  The rule is the TILED kernel's alone: the
+streaming and fused kernels hold a whole query block, both row buffers
+and every tile's output at once, so one wide chunk that the tiled
+kernel has room for overruns them (1M rows of 512 columns at bq128:
+120.2 and 124.4 MiB modeled, and Mosaic refuses both; of 640, 136 and
+140) where their 128-column chunks compile at about 80.  They keep
+``DIM_CHUNK`` columns at every width, the parent's programs.
 
 Geometry constants mirror ``ops.pallas_knn`` (TILE_N/BLOCK_Q/BIN_W/
 DIM_CHUNK/MAX_CARRY_DEPTH), pinned by tests/test_analysis.py.  The
@@ -197,30 +209,44 @@ def kernel_bytes(
     *, kernel: str, block_q: int, tile_n: int, n_tiles: int, nd: int,
     out_w: int, bound_w: int, db_block: int, aux_rows: int,
     q_block: int, q_extra: int = 0, carry_depth: int = 0,
+    row_block: Optional[int] = None, dim_padded: int = 0,
 ) -> Dict[str, int]:
     """Per-buffer VMEM bytes of ONE launch from the kernel's RESOLVED
     geometry — what ``ops.pallas_knn`` sizes its scoped-VMEM request
     from, and what :func:`launch_estimate` prices a knob set with.
 
-    ``db_block`` is one db tile across all its parts, ``q_block`` one
-    query operand block (tiled: one dim-chunk slice; streaming/fused:
-    the full-dim block), ``q_extra`` the quantized arms' query-scale
-    block, ``carry_depth`` the fused arm's armed carry depth (0 =
-    disarmed).
+    ``db_block`` is what one grid step holds of the rows across all
+    their parts (a whole tile, or one ``row_block`` of it), ``q_block``
+    one query operand block (tiled: the whole padded width, or one dim
+    chunk where a caller handed the launch a narrower one;
+    streaming/fused: the full-dim block), ``q_extra`` the quantized
+    arms' query-scale block, ``carry_depth`` the fused arm's armed
+    carry depth (0 = disarmed), ``row_block`` the rows of a tiled
+    launch's step (None: the whole tile) and ``dim_padded`` the columns
+    such a step multiplies (read where the tile is cut by rows alone).
 
     - **tiled**: every grid-mapped operand and output block is
-      double-buffered by the Pallas pipeline; the multi-chunk
-      accumulator scratch lives once.
+      double-buffered by the Pallas pipeline; a tile cut by rows keeps
+      the select's running arrays (``select_state``: the survivors + 1
+      value and survivors index arrays of ``[block_q, 128]``) in a
+      scratch that lives once, as the multi-chunk accumulator of a
+      launch handed a dim chunk does.
     - **streaming/fused**: the kernel OWNS its db double buffering (two
       scratch slots per part + aux); the full-width candidate output
       block is a pipelined (double-buffered) output all the same.
-    - ``score_tiles``: the [block_q, tile_n] f32 tiles Mosaic keeps
-      live around the select (partial dots, the score, one temporary)
-      — 2 at one dim chunk, 3 when chunks accumulate; fitted to the
-      compiler's reported need (module docstring), not declared by the
-      kernel."""
-    score = block_q * tile_n * 4
-    aux_block = aux_rows * tile_n * 4
+    - ``score_tiles``: the [block_q, rows of a step] f32 tiles Mosaic
+      keeps live around the select (partial dots, the score, one
+      temporary) — 2 at one dim chunk, 3 when chunks accumulate; fitted
+      to the compiler's reported need (module docstring), not declared
+      by the kernel.
+    - ``row_part_live``: a step of a tile cut by rows multiplies 1,024
+      columns and more in one product, and beside the pipeline's
+      buffers Mosaic keeps what one more bf16 copy of a row part's
+      block would take (``[row_block, dim_padded]``: fitted like the
+      score tiles, :func:`row_blocking`)."""
+    rows = int(row_block or tile_n)
+    score = block_q * rows * 4
+    aux_block = aux_rows * rows * 4
     live = 2 if nd == 1 else 3
     if kernel == "tiled":
         return {
@@ -230,6 +256,10 @@ def kernel_bytes(
             "outputs_x2": 2 * block_q * (2 * out_w + bound_w) * 4,
             "score_tiles": live * score,
             "accum_scratch": score if nd > 1 else 0,
+            "select_state": (block_q * (2 * out_w + bound_w) * 4
+                             if rows < tile_n else 0),
+            "row_part_live": (rows * int(dim_padded) * 2
+                              if rows < tile_n else 0),
         }
     if kernel not in ("streaming", "fused"):
         raise ValueError(
@@ -248,51 +278,121 @@ def kernel_bytes(
 
 
 def dim_chunking(
-    dim_padded: int, *, tile_n: int, block_q: int,
-    precision: str = "bf16x3", kernel: str = "tiled",
-    db_parts: Optional[int] = None,
-    out_w: int = SURVIVORS_GROUPED_DEFAULT * BIN_W,
-    budget_bytes: Optional[int] = None,
+    dim_padded: int, *, kernel: str = "tiled", precision: str = "bf16x3",
 ) -> Tuple[int, int]:
     """``(chunk_w, nd)``: the columns of one dim chunk and the chunks a
-    row tile is cut into, from what a launch can see of itself — the
-    padded width, the tile, the query block, the precision's db parts
-    (``db_parts``: the parts actually streamed, where the bf16x3 split
-    drops its low half; None = the precision's own).  No knob.
-
-    ONE chunk (``chunk_w = dim_padded``) wherever the tiled kernel's
-    one-chunk geometry, by the model, plus :func:`limit_bytes`' eighth
-    fits ``budget_bytes`` (None = the target device's); ``DIM_CHUNK``
-    columns otherwise — GIST's 1,024 would be 128 MiB of row blocks
-    alone — and under ``kernel`` "streaming" or "fused" always (module
-    docstring: what the tiled kernel has room for, they do not).
-    ``pq`` has no chunk loop and a width of 128 or less is one chunk
-    already.  The model is calibrated for bf16x3 and an upper bound for
-    the other arms (module docstring), so what it lets through fits
-    there too."""
+    row tile's WIDTH is cut into.  The tiled kernel never cuts it (one
+    product over the whole padded width a step; a tile too large for
+    VMEM at that width is cut by rows, :func:`row_blocking`); the
+    streaming and fused kernels keep ``DIM_CHUNK`` columns at every
+    width (module docstring: what the tiled kernel has room for, they
+    do not).  ``pq`` has no chunk loop and a width of 128 or less is
+    one chunk already.  No knob."""
     dim_padded = int(dim_padded)
     if dim_padded % DIM_CHUNK:
         raise ValueError(
             f"dim_padded={dim_padded} is no multiple of {DIM_CHUNK}")
-    if precision == "pq" or dim_padded <= DIM_CHUNK:
+    if precision == "pq" or dim_padded <= DIM_CHUNK or kernel == "tiled":
         return dim_padded, 1
-    if kernel != "tiled":
-        return DIM_CHUNK, dim_padded // DIM_CHUNK
+    return DIM_CHUNK, dim_padded // DIM_CHUNK
+
+
+#: the rows a validity word spans: bit ``g % 32`` of a word is a lane of
+#: 128-row group ``g`` (ops.pallas_knn.valid_word_position), so a masked
+#: step of this many rows (or a whole multiple) reads whole 128-word
+#: blocks at fixed bits
+MASK_WORD_ROWS = 32 * BIN_W
+
+
+#: the most rows of one step of a tile cut by rows.  Timed on the v5e
+#: at ``gist1m``'s shape (1,024 columns, two row parts, tile 16,384,
+#: query block 256; root PERF.md section 6, PR 45): 8,192 rows a step,
+#: the largest that fits, cost 22.2 us a 128-column pass-set of a tile
+#: where 4,096 cost 19.4, 2,048 19.5 and 1,024 19.9, and the parent's
+#: own one-step kernel over tiles of 8,192 / 4,096 / 2,048 rows read
+#: 19.0 / 16.8 / 16.9 alike
+ROW_BLOCK_MAX = 4096
+
+
+def row_blocking(
+    dim_padded: int, *, tile_n: int, block_q: int,
+    precision: str = "bf16x3", kernel: str = "tiled",
+    db_parts: Optional[int] = None,
+    out_w: int = SURVIVORS_GROUPED_DEFAULT * BIN_W,
+    masked: bool = False, budget_bytes: Optional[int] = None,
+) -> Tuple[int, int]:
+    """``(row_block, row_steps)``: the rows of a tile that one grid step
+    of the tiled kernel multiplies at the whole padded width, and the
+    steps a tile takes, from what a launch can see of itself — the
+    padded width, the tile, the query block, the precision's db parts
+    (``db_parts``: the parts actually streamed, where the bf16x3 split
+    drops its low half; None = the precision's own), whether it carries
+    per-query validity words.  No knob.
+
+    ONE step (``row_block = tile_n``) wherever the whole tile's
+    geometry, by the model, plus :func:`limit_bytes`' eighth fits
+    ``budget_bytes`` (None = the target device's): the launch it always
+    was.  Otherwise the LARGEST block of at most ``ROW_BLOCK_MAX`` rows
+    that divides the tile, is a whole number of 128-row groups and fits
+    the same way — 4,096 rows a step at GIST's 1,024 columns in two
+    parts (32 MiB of row buffers) and at ``openai500k``'s 1,536 (48
+    MiB); 2,048 from 2,560 columns up.  A masked launch's block is besides a whole
+    number of ``MASK_WORD_ROWS`` or divides it, so that a step's
+    validity words are one block at fixed or at once-shifted bits
+    (ops.pallas_knn._kernel).  Where not even one group fits, 128 rows:
+    the kernel's own budget check then names the knobs to change.
+
+    The cut geometries were probed like the rest (bf16x3, two row
+    parts, libtpu 0.0.34, deviceless for a v5e; the least limit that
+    compiles, by bisection, in MiB against the model): 1,024 columns at
+    bq256 47 / 52.1 in steps of 4,096 rows (tile 16,384 or 32,768
+    alike), 25 / 28.1 of 2,048 and 93 / 100.4 of 8,192; at bq128 44 /
+    46.2; 1,536 columns at bq256 71 / 73.1 of 4,096 and 39 / 39.1 of
+    2,048; 640 columns 32 / 36.4 — the model over by 0% to 14%.  What
+    Mosaic keeps beside the declared buffers there follows the step's
+    rows times its WIDTH (10.9, 9.8, 17.9 MiB at 4,096 x 1,024 at bq256
+    and bq128 and at 4,096 x 1,536), not the score tile: two score
+    tiles and one bf16 copy of a row part's block bound all eight
+    (``kernel_bytes``' ``row_part_live``).
+
+    The other two kernels loop over their tiles themselves and ``pq``
+    streams codes, not rows: always one step.  The model is calibrated
+    for bf16x3 and an upper bound for the other arms (module
+    docstring), so what it lets through fits there too."""
+    dim_padded, tile_n = int(dim_padded), int(tile_n)
+    if dim_padded % DIM_CHUNK or tile_n % BIN_W:
+        raise ValueError(
+            f"dim_padded={dim_padded} is no multiple of {DIM_CHUNK}, or "
+            f"tile_n={tile_n} none of {BIN_W}")
+    if kernel != "tiled" or precision == "pq":
+        return tile_n, 1
     if budget_bytes is None:
         budget_bytes = budget_for(TARGET_DEVICE_KIND)
     n_parts, chunk_w, part_b = DB_PARTS[precision]
-    need = sum(kernel_bytes(
-        kernel="tiled", block_q=block_q, tile_n=tile_n, n_tiles=1, nd=1,
-        out_w=out_w, bound_w=BIN_W,
-        db_block=((n_parts if db_parts is None else int(db_parts))
-                  * tile_n * dim_padded * (chunk_w // DIM_CHUNK) * part_b),
-        aux_rows=AUX_ROWS.get(precision, AUX_ROWS_DEFAULT),
-        q_block=block_q * dim_padded * _widths.query_elem_bytes(precision),
-        q_extra=block_q * BIN_W * 4 if precision == "int8" else 0,
-    ).values())
-    if need + need // 8 <= budget_bytes:
-        return dim_padded, 1
-    return DIM_CHUNK, dim_padded // DIM_CHUNK
+    row_bytes = ((n_parts if db_parts is None else int(db_parts))
+                 * dim_padded * (chunk_w // DIM_CHUNK) * part_b)
+    groups = tile_n // BIN_W
+    for steps in range(1, groups + 1):
+        if groups % steps:
+            continue
+        rows = tile_n // steps
+        if steps > 1 and rows > ROW_BLOCK_MAX:
+            continue
+        if masked and steps > 1 and (rows % MASK_WORD_ROWS
+                                     and MASK_WORD_ROWS % rows):
+            continue
+        need = sum(kernel_bytes(
+            kernel="tiled", block_q=block_q, tile_n=tile_n, n_tiles=1,
+            nd=1, out_w=out_w, bound_w=BIN_W, db_block=rows * row_bytes,
+            aux_rows=AUX_ROWS.get(precision, AUX_ROWS_DEFAULT),
+            q_block=block_q * dim_padded * _widths.query_elem_bytes(
+                precision),
+            q_extra=block_q * BIN_W * 4 if precision == "int8" else 0,
+            row_block=rows, dim_padded=dim_padded,
+        ).values())
+        if need + need // 8 <= budget_bytes:
+            return rows, steps
+    return BIN_W, groups
 
 
 #: the final select's Pallas stage (ops.pallas_knn._select_final): the
@@ -357,13 +457,15 @@ def launch_estimate(
     """Estimated VMEM high-water bytes of ONE kernel launch for this
     knob set at this problem shape, with the per-buffer breakdown
     (:func:`kernel_bytes` over the geometry the kernel would
-    resolve, its dim chunks by :func:`dim_chunking` against
-    ``budget_bytes``: None = the target device's)."""
+    resolve, its tiles cut by :func:`dim_chunking` and by
+    :func:`row_blocking` against ``budget_bytes``: None = the target
+    device's)."""
     precision = precision or "bf16x3"
     kernel = kernel or "tiled"
     tile, bq, n_tiles, dim_p, out_w, bound_w = _geometry(
         n, d, precision, kernel, tile_n, block_q, survivors)
-    dim_chunk, nd = dim_chunking(
+    dim_chunk, nd = dim_chunking(dim_p, kernel=kernel, precision=precision)
+    row_block, row_steps = row_blocking(
         dim_p, tile_n=tile, block_q=bq, precision=precision, kernel=kernel,
         out_w=out_w, budget_bytes=budget_bytes)
     lut_w = 0
@@ -377,7 +479,6 @@ def launch_estimate(
         lut_w = _ceil_div(
             m_sub * int(pq_ncodes or _widths.PQ_NCODES_DEFAULT),
             BIN_W) * BIN_W
-        nd = 1
     else:
         n_parts, chunk_w, part_b = DB_PARTS[precision]
         chunk_w = chunk_w // DIM_CHUNK * dim_chunk
@@ -395,16 +496,17 @@ def launch_estimate(
     breakdown = kernel_bytes(
         kernel=kernel, block_q=bq, tile_n=tile, n_tiles=n_tiles, nd=nd,
         out_w=out_w, bound_w=bound_w,
-        db_block=n_parts * tile * chunk_w * part_b,
+        db_block=n_parts * row_block * chunk_w * part_b,
         aux_rows=AUX_ROWS.get(precision, AUX_ROWS_DEFAULT),
         q_block=q_block, q_extra=bq * BIN_W * 4 if quantized else 0,
-        carry_depth=carry_depth)
+        carry_depth=carry_depth, row_block=row_block, dim_padded=dim_p)
     return {
         "total_bytes": int(sum(breakdown.values())),
         "breakdown": {kk: int(v) for kk, v in breakdown.items()},
         "geometry": {
             "tile_n": tile, "block_q": bq, "n_tiles": n_tiles,
             "dim_padded": dim_p, "dim_chunk": dim_chunk, "dim_chunks": nd,
+            "row_block": row_block, "row_steps": row_steps,
             "out_w": out_w, "bound_w": bound_w,
             "kernel": kernel, "precision": precision,
         },

@@ -475,12 +475,15 @@ CATALOG = {
         "'hh+lh' where every row is bf16-exact, 'hh' where the batch "
         "is too — 3, 2 or 1 MXU passes."),
     KERNEL_DIM_CHUNKS: (
-        "counter", ("chunks",),
-        "Batches of search_certified(selector='pallas'), by the dim "
-        "chunks their kernel cut a row tile into "
-        "(ops.pallas_knn.dim_chunking): '1' wherever the whole padded "
-        "width fits VMEM (one grid step a tile, no accumulator "
-        "scratch), else the padded width over 128."),
+        "counter", ("chunks", "row_steps"),
+        "Batches of search_certified(selector='pallas'), by how their "
+        "kernel cut a row tile: 'chunks' the dim chunks of its width "
+        "(ops.pallas_knn.dim_chunking: '1' under the tiled kernel, the "
+        "padded width over 128 under the other two), 'row_steps' the "
+        "grid steps of its rows (ops.pallas_knn.row_blocking: '1' "
+        "wherever the whole tile fits VMEM at that width, no scratch; "
+        "else the row blocks it is cut into, the bin-select's running "
+        "arrays carried between them)."),
     FINAL_SELECT_CALLS: (
         "counter", ("stage",),
         "Batches of search_certified(selector='pallas'), by what ran "

@@ -133,7 +133,16 @@ from knn_tpu.obs import names, registry, trace
 #: can read the new ``h2d_bound``.  Serving blocks are numerically
 #: unchanged; the bump re-keys the tuning cache (rl7) and calibration
 #: store (cal7) so v6 attributions self-invalidate.
-MODEL_VERSION = 7
+#: 8 = the tiled kernel never cuts a row tile's columns (PR 46: a tile
+#: too large for VMEM at its whole width is cut by ROWS,
+#: analysis.vmem.row_blocking), so its query block's mapped index
+#: moves with the query block alone and it streams ONCE a query block
+#: under ``query_major`` (``terms.hbm.bytes.queries`` = nq·d·elem, not
+#: that times the db tiles: 0.98 GB less of 66 at GIST's shape).  The
+#: other kernels, ``db_major`` and every other term are numerically
+#: unchanged; the bump re-keys the tuning cache (rl8) and calibration
+#: store (cal8).
+MODEL_VERSION = 8
 
 #: the resources a config can exhaust, in tie-break order (dcn_bound
 #: only appears on multi-host blocks, db_hosts > 1; h2d_bound only on
@@ -584,17 +593,21 @@ def pallas_cost_model(
     # --- HBM bytes ------------------------------------------------------
     # db stream passes: query_major (and the inherently query-major
     # streaming/fused kernels) re-stream the full db once per query
-    # block; db_major streams it ONCE at single-chunk dims but
-    # degenerates to query_major traffic when the innermost chunk axis
-    # cycles between query blocks (ops.pallas_knn.GRID_ORDERS)
-    # (the chunks of the full product on the modeled device: a knob
-    # set is priced before any data is seen, like ``passes`` below; a
-    # kind the VMEM table lacks, a cpu's, is priced as the target's)
-    _, nd = _vmem.dim_chunking(
+    # block; db_major streams it ONCE where a tile is one grid step but
+    # degenerates to query_major traffic when the innermost axis (a cut
+    # tile's row blocks) cycles between query blocks
+    # (ops.pallas_knn.GRID_ORDERS)
+    # (the steps of a tile on the modeled device — the tiled kernel's
+    # tile is one dim chunk at every width, and cut by rows where it
+    # does not fit VMEM whole: a knob set is priced before any data is
+    # seen, like ``passes`` below; a kind the VMEM table lacks, a
+    # cpu's, is priced as the target's.  Under db_major a cut tile's
+    # row blocks cycle with the query blocks as dim chunks did)
+    _, row_steps = _vmem.row_blocking(
         _ceil_div(d, DIM_CHUNK) * DIM_CHUNK, tile_n=tile, block_q=bq,
         precision=precision, kernel=kernel, out_w=out_w,
         budget_bytes=_vmem.VMEM_BYTES_BY_KIND.get(device_kind))
-    if grid_order == "db_major" and nd == 1 and kernel == "tiled":
+    if grid_order == "db_major" and row_steps == 1 and kernel == "tiled":
         db_passes = 1
     else:
         db_passes = q_blocks
@@ -604,19 +617,24 @@ def pallas_cost_model(
     db_stream = db_passes * opnd["db_values"]
     db_aux = db_passes * opnd["db_aux"]
     # query blocks re-fetch once per db tile (their mapped index cycles
-    # with the dim-chunk axis); int8 adds the [block_q, 128] f32
+    # with the dim-chunk axis, or under db_major with the query block)
+    # except the tiled kernel's under query_major, whose one whole-width
+    # block stays put across a query block's tiles and their row
+    # blocks; int8 adds the [block_q, 128] f32
     # per-query scale block per cell; pq's query-side operand is the
     # per-query LUT ([nq, m·ncodes] f32), re-fetched per db tile in
     # place of the raw query blocks (the raw queries are consumed ONCE
     # by the XLA LUT prologue)
+    q_fetches = (1 if kernel == "tiled" and grid_order == "query_major"
+                 else n_tiles)
     if precision == "pq":
-        queries_b = n_tiles * _widths.pq_lut_bytes(
+        queries_b = q_fetches * _widths.pq_lut_bytes(
             nq, d, dsub=eff_dsub, ncodes=eff_ncodes) + nq * d * 4
     else:
         q_elem = QUERY_ELEM_BYTES.get(precision, QUERY_ELEM_BYTES_DEFAULT)
-        queries_b = n_tiles * nq * d * q_elem
+        queries_b = q_fetches * nq * d * q_elem
     if precision == "int8":
-        queries_b += n_tiles * nq * BIN_W * 4
+        queries_b += q_fetches * nq * BIN_W * 4
     # candidate outputs: every (query block, db tile) cell writes its
     # disjoint (block_q, out_w) f32+i32 candidates and bound_w bounds
     # exactly once (the streaming kernel flushes the same total width

@@ -9,17 +9,18 @@ fuses distance + a hierarchical reduction so the [Q, N] distance matrix
 never reaches HBM, and emits everything the certified pipeline needs in
 ONE database pass:
 
-  per grid cell (query block i, db tile j, dim chunk c):
-    1. MXU:  qt += Q_ic @ T_jc^T          (f32, accumulated in VMEM scratch
-                                           across dim chunks)
-    2. MXU:  tn += 1 @ (T_jc * T_jc)^T    (db row norms, same accumulation)
-    at the last dim chunk:
+  per grid cell (query block i, db tile j):
+    1. MXU:  qt = Q_i @ T_j^T             (f32; the whole padded width in
+                                           one product)
+    2. XLA:  tn = ||T_j||^2               (db row norms, once a call)
     3. VPU:  s = tn - 2 qt                (squared L2 minus ||q||^2: the
                                            per-query constant is rank- and
                                            certificate-irrelevant)
     4. VPU:  per 128-wide bin, the s smallest values + their indices
              (candidates) AND the (s+1)-th smallest value (the *exclusion
              bound*: no non-candidate in this bin can score below it)
+  (a tile too wide for VMEM takes steps 1-3 a ROW BLOCK at a time and
+  carries step 4's running arrays between them: ``row_blocking``)
 
 The bin LAYOUT is grouped: bin b = lane b of every 128-wide column group
 of the score tile (128 bins/tile, members strided 128 apart).  The
@@ -101,17 +102,23 @@ sound exclusion bound instead of a recall target.
 Two DB-STREAMING STRATEGIES share the select/emit machinery (``kernel``,
 see ``KERNELS``):
 
-- ``"tiled"`` (default): grid = (q_blocks, db_tiles, dim_chunks); the
+- ``"tiled"`` (default): grid = (q_blocks, db_tiles, row_steps); the
   Pallas pipeline re-launches the kernel body once per train tile and
   each (query block, db tile) cell round-trips its survivor block
-  through HBM before the XLA final select.  ``dim_chunks`` is 1
-  wherever a row tile's whole padded width fits VMEM
-  (``dim_chunking``: 128, 256, 384 and 512 columns at the default
-  tile and query block): the grid's third axis has one step, no
-  accumulator scratch exists and the bin-select sits in the matmul's
-  own step.  Wider rows (GIST's 1,024) are cut into 128-column chunks
-  whose partial products add up in a VMEM scratch, the select behind
-  the last one.
+  through HBM before the XLA final select.  Every step multiplies the
+  WHOLE padded width in one product.  ``row_steps`` is 1 wherever a
+  row tile at that width fits VMEM (``row_blocking``: 128, 256, 384
+  and 512 columns at the default tile and query block): no scratch
+  exists and the bin-select sits in the matmul's own step.  A wider
+  tile (GIST's 1,024 columns, 1,536 of an embedding) is cut by ROWS:
+  the grid's third axis walks row blocks of 4,096 rows, each step runs
+  its groups through the bin-select's insertion network, and what the
+  steps of a tile share is the network's running arrays (five of
+  ``[block_q, 128]``) in a VMEM scratch (``_row_step``).  The bins,
+  the candidates and the bounds are the uncut tile's.  (Until PR 46
+  such a tile was cut by COLUMNS and its 16 MB partial product read,
+  added to and stored back at every step: 26.2 us a 128-column
+  pass-set of a tile against 16.9.)
 - ``"streaming"``: grid = (q_blocks,) — ONE kernel launch per
   (batch, shard).  The db tiles stay in HBM and stream through a
   double-buffered pair of VMEM scratch buffers via explicit async
@@ -193,11 +200,9 @@ FINAL_SELECT_UNROLL = 4
 FINAL_SELECT_MAX_WORK = 1 << 21
 FINAL_SELECT_MAX_KEEP = 2 * BIN_W
 #: the padding grain of the feature axis (columns are zero-padded to a
-#: multiple of it) and the FALLBACK width of a dim chunk: a row tile is
-#: one chunk of its whole padded width where that fits VMEM and is cut
-#: into chunks of this many columns where it does not (GIST's 960), qt
-#: accumulating in scratch across them.  Which, is ``dim_chunking``'s
-#: reading of the launch's shape, never a caller's choice
+#: multiple of it) and the width of a dim chunk under the streaming and
+#: fused kernels, qt adding up across them.  The tiled kernel's chunk
+#: is the whole padded width (``dim_chunking``); never a caller's choice
 DIM_CHUNK = 128
 #: cap on survivors per bin (tiny tile_n in tests would otherwise unroll
 #: a 128-step trace); capped cells just pad their output block
@@ -316,7 +321,10 @@ def _split_qt(q, th, tl, terms: str):
 #: 10 = the final top-(m+2) and its index gather are one Pallas stage
 #: where ``final_select_geometry`` engages (PR 35): the tail the tuner
 #: times with the kernel changed.
-KERNEL_VERSION = 10
+#: 11 = a row tile too wide for VMEM is cut by rows, not by columns (PR
+#: 46, ``row_blocking``): the kernel from 640 placed columns up (two row
+#: parts) is another program, a third faster a tile at GIST's shape.
+KERNEL_VERSION = 11
 
 #: relative slack of the device rank stage's direct-difference f32
 #: distances: per-term (q-t)^2 rounding plus the depth-7 tree reduce give
@@ -348,19 +356,19 @@ def _round_up(x: int, multiple: int) -> int:
 
 
 #: grid iteration orders.  "query_major" (default): grid =
-#: (q_blocks, db_tiles, dim_chunks) — every query block streams the
+#: (q_blocks, db_tiles, row_steps) — every query block streams the
 #: FULL db through VMEM, so db HBM traffic scales with the query-block
 #: count (16 GB per 4096-query sweep at the SIFT shape, the largest
 #: term of the measured cost model in docs/PERF.md).  "db_major": grid =
-#: (db_tiles, q_blocks, dim_chunks) — consecutive steps revisit the
+#: (db_tiles, q_blocks, row_steps) — consecutive steps revisit the
 #: same db tile (Pallas re-fetches an input block only when its mapped
-#: index changes), so AT ONE DIM CHUNK (nd == 1: SIFT's 128 columns,
-#: and every wider tile ``dim_chunking`` keeps whole)
+#: index changes), so AT ONE STEP A TILE (SIFT's 128 columns, and every
+#: wider tile ``row_blocking`` keeps whole)
 #: each db tile streams ONCE per sweep and only the small query blocks
-#: re-stream (~2 MB x n_tiles).  For multi-chunk dims the innermost
-#: chunk axis cycles between query blocks, so every chunk re-fetches
-#: per query block — db traffic identical to query_major; the variant
-#: buys nothing there (gist/glove).  Candidate/bound
+#: re-stream (~2 MB x n_tiles).  Where a tile is cut by rows the
+#: innermost axis cycles between query blocks, so every row block
+#: re-fetches per query block — db traffic identical to query_major;
+#: the variant buys nothing there (gist).  Candidate/bound
 #: outputs stay disjoint per (query block, db tile) cell in both orders
 #: — no output revisiting (module docstring) either way.
 #: db_major is opt-in until the on-hardware gate + A/B pass on it.
@@ -649,12 +657,27 @@ def _kernel(q_ref, *refs, tile_n: int, survivors: int, nd: int,
         i_ref[:] = ci
         b_ref[:] = bound
 
+    if tn_ref.shape[1] < tile_n:
+        # the blocks this step was handed are one ROW BLOCK of the tile
+        # (``_row_call``: a tile too wide for VMEM, cut by rows): the
+        # product above is whole, and what the tile's steps share is
+        # the bin-select's running arrays alone (``_row_step``)
+        if precision == "int8":
+            # ``write``'s rescale, on the step's rows
+            qt = ((qt.astype(jnp.float32) * qsc_ref[:, 0:1])
+                  * aux_ref[8:9, :])
+        _row_step(scratch[0], ti, qt, tn_ref, vw_ref, d_ref, i_ref, b_ref,
+                  tile_n=tile_n)
+        return
     if nd == 1:
         # single dim chunk: no scratch allocated, skip the VMEM
         # accumulation round-trip entirely (measured ~16% of kernel time
         # at SIFT shape)
         write(qt)
         return
+    # a dim chunk handed to the launch (tests alone: the rule never
+    # cuts the tiled kernel's columns): the partial products add up in
+    # a [BQ, T] scratch, the select behind the last
     qt_ref, = scratch
 
     @pl.when(di == 0)
@@ -668,6 +691,92 @@ def _kernel(q_ref, *refs, tile_n: int, survivors: int, nd: int,
     @pl.when(di == nd - 1)
     def _select():
         write(qt_ref[:])
+
+
+def _row_step(state_ref, ti, qt, tn_ref, vw_ref, d_ref, i_ref, b_ref, *,
+              tile_n: int):
+    """One of the steps a row tile is cut into (``row_blocking``): ``qt``
+    [BQ, rows] is the product of the step's rows, whole, and ``tn_ref``
+    their norms.  The step's 128-row groups go through the insertion
+    network of :func:`_emit_select_grouped_scores`, numbered as they
+    are in the tile (``step * groups + g``), into the running arrays
+    the steps of a tile share: ``state_ref`` f32 ``[2 * survivors + 1,
+    BQ, 128]`` holds the ``survivors + 1`` smallest values a lane has
+    seen and, as bits, the groups of the ``survivors`` kept.  Read as
+    +inf at the tile's first step, written out as the tile's ``(cand_d,
+    cand_i, bounds)`` at the last: the bins, the order of insertion and
+    so every emitted value are the uncut tile's.
+
+    ``vw_ref`` (None: no row is masked) is the step's block of validity
+    words: whole word blocks at the words' own bits where the step is a
+    whole number of ``32 * 128`` rows; else the ONE block its groups
+    share, shifted here so that the step's first group is bit 0.
+
+    The network and the emission are a COPY of the one-step emitter's,
+    not a call into a body both share: every cell's kernel traces that
+    emitter, some 1,500 binds a tile, and a frame more under it, or a
+    wider one, moves where CPython's 16 KiB frame-stack chunks end
+    under the binds (root PERF.md section 6, PRs 29 and 46: PR 45 was
+    refused for 0.9 s of ``setup_s`` in a cell whose program it did not
+    change).  tests/test_dim_chunking.py holds the two to one answer,
+    bit for bit."""
+    si = pl.program_id(2)
+    bq, rows = qt.shape
+    groups = rows // BIN_W
+    survivors = state_ref.shape[0] // 2
+    s = tn_ref[0:1, :] - 2.0 * qt  # [BQ, rows], ||q||^2 dropped
+    first = lax.mul(si, np.int32(groups))
+    lane = lax.broadcasted_iota(jnp.int32, (bq, BIN_W), 1)
+    inf = lax.full((bq, BIN_W), jnp.inf, jnp.float32)
+    if vw_ref is not None:
+        zero = lax.full((bq, BIN_W), 0, jnp.int32)
+        words = vw_ref[:]
+        if groups % 32:
+            words = lax.shift_right_logical(words, lax.broadcast(
+                lax.rem(first, np.int32(32)), words.shape))
+    # a tile's first step starts from +inf (never displaces; a group
+    # index under it is never read) by a select on the step's number,
+    # not by a branch: a ``pl.when`` here would stand between the
+    # product and the select and keep Mosaic from running one under the
+    # other (5 us of 39 a step at ``gist1m``'s shape: root PERF.md
+    # section 6, PR 45)
+    fresh = lax.eq(si, np.int32(0))
+    vals = [lax.select(fresh, inf, state_ref[j])
+            for j in range(survivors + 1)]
+    gidx = [lax.bitcast_convert_type(state_ref[survivors + 1 + j], jnp.int32)
+            for j in range(survivors)]
+    for g in range(groups):
+        cur_v = lax.slice_in_dim(s, g * BIN_W, (g + 1) * BIN_W, axis=1)
+        if vw_ref is not None:
+            word = lax.slice_in_dim(words, g // 32 * BIN_W,
+                                    (g // 32 + 1) * BIN_W, axis=1)
+            bit = lax.full((bq, BIN_W), np.int32(
+                np.uint32(1 << g % 32).view(np.int32)), jnp.int32)
+            cur_v = lax.select(lax.ne(lax.bitwise_and(word, bit), zero),
+                               cur_v, inf)
+        cur_g = lax.broadcast(lax.add(first, np.int32(g)), (bq, BIN_W))
+        for j in range(survivors):
+            less = lax.lt(cur_v, vals[j])
+            disp_v = lax.max(cur_v, vals[j])
+            disp_g = lax.select(less, gidx[j], cur_g)
+            vals[j] = lax.min(cur_v, vals[j])
+            gidx[j] = lax.select(less, cur_g, gidx[j])
+            cur_v, cur_g = disp_v, disp_g
+        vals[survivors] = lax.min(vals[survivors], cur_v)
+    for j in range(survivors + 1):
+        state_ref[j] = vals[j]
+    for j in range(survivors):
+        state_ref[survivors + 1 + j] = lax.bitcast_convert_type(
+            gidx[j], jnp.float32)
+
+    @pl.when(si == tile_n // rows - 1)
+    def _emit():
+        d_ref[:] = jnp.concatenate(vals[:survivors], axis=-1)
+        i_ref[:] = jnp.concatenate([
+            jnp.where(jnp.isfinite(vals[j]),
+                      ti * tile_n + gidx[j] * BIN_W + lane, _I32MAX)
+            for j in range(survivors)], axis=-1)
+        b_ref[:] = vals[survivors]
 
 
 def _emit_select_grouped(ti, qt, tn, *, tile_n: int, survivors: int,
@@ -1030,31 +1139,48 @@ def _vmem_limit_bytes(kernel: str, precision: str, **geometry) -> int:
     return vmem.limit_bytes(need, budget)
 
 
-def dim_chunking(dim: int, *, tile_n: int, block_q: int, precision: str,
-                 kernel: str = "tiled", terms: str = BF16X3_TERMS[0],
-                 survivors: Optional[int] = None) -> Tuple[int, int]:
+def dim_chunking(dim: int, *, precision: str,
+                 kernel: str = "tiled") -> Tuple[int, int]:
     """``(chunk_w, nd)`` of a launch over ``dim`` columns (padded here
     to the ``DIM_CHUNK`` grain): the width of one dim chunk and how
-    many a row tile has.  knn_tpu.analysis.vmem.dim_chunking is the ONE
-    home of the rule (under the tiled kernel one chunk wherever the
-    whole padded width fits the device's VMEM beside the rest of the
-    launch; 128 columns otherwise, and under the other two kernels
-    always); this hands it what the launch sees — the RESOLVED tile
-    and query block, the row parts ``terms`` leaves to stream — and the
-    budget of the device it compiles for (off-TPU, the target kind's:
-    interpret mode cuts the rows as the chip would).  Nothing sets it:
-    a bare launch asks here, and ShardedKNN asks here ONCE for its
-    program (``_pallas_setup``), hands the kernel the answer and
-    reports that answer (``dim_chunk``, ``dim_chunks`` on the
+    many a row tile's width has.  knn_tpu.analysis.vmem.dim_chunking is
+    the ONE home of the rule: the whole padded width under the tiled
+    kernel (a tile too large for VMEM at that width is cut by rows,
+    :func:`row_blocking`), 128 columns under the other two."""
+    from knn_tpu.analysis import vmem
+
+    return vmem.dim_chunking(_round_up(dim, DIM_CHUNK), kernel=kernel,
+                             precision=precision)
+
+
+def row_blocking(dim: int, *, tile_n: int, block_q: int, precision: str,
+                 kernel: str = "tiled", terms: str = BF16X3_TERMS[0],
+                 survivors: Optional[int] = None,
+                 masked: bool = False) -> Tuple[int, int]:
+    """``(row_block, row_steps)`` of a launch over ``dim`` columns
+    (padded here to the ``DIM_CHUNK`` grain): the rows of a tile that
+    one grid step multiplies at the whole width, and the steps a tile
+    takes.  knn_tpu.analysis.vmem.row_blocking is the ONE home of the
+    rule (under the tiled kernel the whole tile wherever it fits the
+    device's VMEM beside the rest of the launch, else the largest block
+    of whole 128-row groups that divides the tile and fits; under the
+    other two kernels always the whole tile); this hands it what the
+    launch sees — the RESOLVED tile and query block, the row parts
+    ``terms`` leaves to stream, whether it carries validity words — and
+    the budget of the device it compiles for (off-TPU, the target
+    kind's: interpret mode cuts the rows as the chip would).  Nothing
+    sets it: a bare launch asks here, and ShardedKNN asks here ONCE for
+    its program (``_pallas_setup``), hands the kernel the answer and
+    reports that answer (``row_block``, ``row_steps`` on the
     ``certified.call`` event)."""
     from knn_tpu.analysis import vmem
 
-    return vmem.dim_chunking(
+    return vmem.row_blocking(
         _round_up(dim, DIM_CHUNK), tile_n=tile_n, block_q=block_q,
         precision=precision, kernel=kernel,
         db_parts=(1 if precision == "bf16x3" and "hl" not in terms
                   else None),
-        out_w=_geometry(tile_n, survivors)[2],
+        out_w=_geometry(tile_n, survivors)[2], masked=masked,
         budget_bytes=vmem.budget_for(_vmem_device_kind()))
 
 
@@ -1130,7 +1256,7 @@ def row_operands(db: jax.Array, *, tile_n: int,
     jax.jit, static_argnames=("block_q", "tile_n", "survivors",
                               "precision", "interpret", "grid_order",
                               "kernel", "offset", "keep", "terms",
-                              "dim_chunk")
+                              "dim_chunk", "row_block")
 )
 def _bin_candidates(
     queries: jax.Array,
@@ -1151,6 +1277,7 @@ def _bin_candidates(
     dim_chunk: Optional[int] = None,
     db_prepared: Optional[Tuple[jax.Array, ...]] = None,
     valid_words: Optional[jax.Array] = None,
+    row_block: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Kernel launch on padded shapes.  Returns
 
@@ -1190,17 +1317,30 @@ def _bin_candidates(
     Outputs are the full sum's bit for bit on such data, and wrong on
     any other: nothing here checks.
 
-    ``dim_chunk`` is the width of one dim chunk, a multiple of
-    ``DIM_CHUNK`` that divides the padded width: :func:`dim_chunking`'s
+    ``row_block`` is the rows of a tile that one grid step of the
+    tiled kernel multiplies, at the whole padded width: a whole number
+    of 128-row groups that divides ``tile_n``.  :func:`row_blocking`'s
     reading of this launch's shape, made here where it is None and by
     the caller where a program reports it (ShardedKNN, through
-    :func:`local_certified_candidates`); tests pass one to hold the
-    multi-chunk path at shapes small enough to interpret.  Never a
-    knob.  The rule collapses a tile under ``kernel="tiled"`` alone, so
-    outputs are bitwise-identical across ``kernel`` where the width is
-    the same (up to 128 columns, where the rule keeps 128, and wherever
-    one width is handed to all three); across widths they agree to the
-    f32 accumulation order (``kernel_tolerance`` covers it).
+    :func:`local_certified_candidates`); tests and chip probes pass one
+    to hold a cut at shapes the rule leaves whole.  Never a knob.  The
+    whole tile is the launch below; a smaller block is ``_row_call``'s,
+    whose bins, candidates and bounds are the uncut tile's, bit for
+    bit: a step's product is whole, and the steps share the select's
+    running arrays alone (``_row_step``).
+
+    ``dim_chunk`` is the width of one dim chunk, a multiple of
+    ``DIM_CHUNK`` that divides the padded width: :func:`dim_chunking`'s
+    reading where it is None (the whole width under ``kernel="tiled"``,
+    128 columns under the other two: a function of the width, the
+    precision and the kernel alone, so no caller need hand it down),
+    and what tests pass to hold the three strategies to one cut of the
+    columns.  Outputs are bitwise-identical across ``kernel`` where the
+    width is the same (up to 128 columns, and wherever one width is
+    handed to all three); across widths they agree to the f32
+    accumulation order (``kernel_tolerance`` covers it).  A tile is cut
+    one way or the other: a launch of several dim chunks runs whole
+    tiles.
 
     ``db_prepared`` ("bf16x3" only) is :func:`row_operands` of ``db`` at
     this ``tile_n`` and these ``terms``, made once by a caller whose
@@ -1277,15 +1417,26 @@ def _bin_candidates(
             "early-out carry-soundness argument has not been extended "
             "to reconstruction-space scores; use 'streaming' or 'tiled'")
     if dim_chunk is None:
-        dim_chunk, nd = dim_chunking(
-            dim, tile_n=tile_n, block_q=block_q, precision=precision,
-            kernel=kernel, terms=terms, survivors=survivors)
+        dim_chunk, nd = dim_chunking(dim, precision=precision, kernel=kernel)
     elif dim_chunk % DIM_CHUNK or dim % dim_chunk:
         raise ValueError(
             f"dim_chunk={dim_chunk} must be a multiple of {DIM_CHUNK} "
             f"that divides the padded width {dim}")
     else:
         nd = dim // dim_chunk
+    if row_block is None:
+        row_block = tile_n if nd > 1 else row_blocking(
+            dim, tile_n=tile_n, block_q=block_q, precision=precision,
+            kernel=kernel, terms=terms, survivors=survivors,
+            masked=valid_words is not None)[0]
+    elif row_block % BIN_W or tile_n % row_block or (
+            row_block < tile_n and (
+                nd > 1 or kernel != "tiled" or precision == "pq")):
+        raise ValueError(
+            f"row_block={row_block} must be a whole number of {BIN_W}-row "
+            f"groups that divides tile_n={tile_n}, and the tile itself "
+            f"under kernel={kernel!r}, precision={precision!r} and "
+            f"{nd} dim chunks")
     pq_shape = None
     queries_in = queries
     q_extra = []  # int8: the per-query-row scale block rides as an input
@@ -1421,6 +1572,13 @@ def _bin_candidates(
                 f"(valid_word_position at tile_n={tile_n})")
         words = [_pad_axis(lax.bitcast_convert_type(
             valid_words, jnp.int32), block_q, 0)]
+    if row_block < tile_n:
+        return _row_call(
+            queries_in, words, db_inputs, q_extra, tnorm, out_shape,
+            block_q=block_q, tile_n=tile_n, row_block=row_block,
+            survivors=survivors, precision=precision, terms=terms,
+            db_major=db_major, interpret=interpret, chunk_w=chunk_w,
+            aux_rows=aux_rows)
     body = functools.partial(
         _kernel, tile_n=tile_n, survivors=survivors, nd=nd,
         precision=precision, ti_axis=0 if db_major else 1,
@@ -1466,7 +1624,6 @@ def _bin_candidates(
         s_idx = lambda t, q, d: (q, 0)      # noqa: E731
     else:
         s_idx = lambda q, t, d: (q, 0)      # noqa: E731
-    extra_specs = [pl.BlockSpec((block_q, BIN_W), s_idx) for _ in q_extra]
     return pl.pallas_call(
         body,
         grid=grid,
@@ -1474,7 +1631,7 @@ def _bin_candidates(
             pl.BlockSpec((block_q, q_block_w), q_idx),
             *[pl.BlockSpec((block_q, wpt), o_idx) for _ in words],
             *db_specs,
-            *extra_specs,
+            *[pl.BlockSpec((block_q, BIN_W), s_idx) for _ in q_extra],
             pl.BlockSpec((aux_rows, tile_n), n_idx),
         ],
         out_specs=[
@@ -1484,9 +1641,8 @@ def _bin_candidates(
         ],
         out_shape=out_shape,
         # the qt accumulation scratch is only touched when dim spans
-        # multiple chunks; at one chunk (dim <= 128, and every wider tile
-        # dim_chunking found room for) skipping it returns VMEM to the
-        # pipeline
+        # multiple chunks (a launch handed a dim chunk); at one chunk
+        # skipping it returns VMEM to the pipeline
         # int8 accumulates the raw int32 dot across chunks (exact);
         # the f32 paths accumulate the scaled f32 score
         scratch_shapes=[] if nd == 1 else [
@@ -1496,6 +1652,103 @@ def _bin_candidates(
         interpret=interpret,
         **kwargs,
     )(queries_in, *words, *db_inputs, *q_extra, tnorm)
+
+
+def _row_call(queries, words, db_inputs, q_extra, tnorm, out_shape, *,
+              block_q, tile_n, row_block, survivors, precision, terms,
+              db_major, interpret, chunk_w, aux_rows):
+    """The tiled ``pallas_call`` of a row tile cut by ROWS
+    (``row_blocking``: a tile too wide for VMEM at its whole padded
+    width): the grid's third axis walks the tile's ``tile_n //
+    row_block`` row blocks, every step multiplies the whole width
+    (``_kernel`` at one dim chunk; its blocks' shapes tell it that it
+    holds a part of the tile) and the bin-select's running arrays pass
+    from step to step in ONE VMEM scratch (``_row_step``).  The query
+    block's mapped index moves with neither the tile nor its row block,
+    so it is fetched once a query block under ``query_major``.  A
+    launch of one step a tile never enters here
+    (:func:`_bin_candidates`' own ``pallas_call``).
+
+    ``words`` (a list of none or one: the padded int32 validity words)
+    are cut with the rows: a step's block is the whole word blocks of
+    its groups (a whole number of ``32 * 128`` rows a step), or the one
+    block that holds them all, which ``_row_step`` shifts."""
+    qp, q_w = queries.shape
+    n_tiles = tnorm.shape[1] // tile_n
+    row_steps = tile_n // row_block
+    step_groups = row_block // BIN_W
+    out_w = out_shape[0].shape[1] // n_tiles
+    bound_w = out_shape[2].shape[1] // n_tiles
+    # the words of one step: row_blocking keeps a masked step to whole
+    # word blocks, or to a part of one
+    words_w = max(1, step_groups // 32) * BIN_W
+    if words and step_groups % 32 and 32 % step_groups:
+        raise ValueError(
+            f"row_block={row_block} of a launch with validity words is "
+            f"neither a whole number of {32 * BIN_W} rows nor divides "
+            f"them")
+    word_blocks = valid_words_per_tile(tile_n) // words_w
+
+    def axes(index_map):
+        """An index map over (query block, tile, row step) in the
+        grid's own order of its axes."""
+        if db_major:
+            return lambda t, q, x: index_map(q, t, x)
+        return index_map
+
+    q_idx = axes(lambda q, t, x: (q, 0))
+    t_idx = axes(lambda q, t, x: (t * row_steps + x, 0))
+    n_idx = axes(lambda q, t, x: (0, t * row_steps + x))
+    o_idx = axes(lambda q, t, x: (q, t))
+    w_idx = axes(lambda q, t, x: (
+        q, t * word_blocks + (x * step_groups // 32 if step_groups < 32
+                              else x)))
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            # as the one-step launch: under db_major the outer axis is
+            # the db tile and must stay sequential
+            dimension_semantics=(
+                ("arbitrary", "arbitrary", "arbitrary") if db_major
+                else ("parallel", "arbitrary", "arbitrary")),
+            vmem_limit_bytes=_vmem_limit_bytes(
+                "tiled", precision,
+                block_q=block_q, tile_n=tile_n, n_tiles=n_tiles, nd=1,
+                out_w=out_w, bound_w=bound_w,
+                db_block=sum(row_block * chunk_w * x.dtype.itemsize
+                             for x in db_inputs),
+                aux_rows=aux_rows,
+                q_block=block_q * q_w * queries.dtype.itemsize,
+                q_extra=(len(q_extra) * BIN_W + len(words) * words_w)
+                * block_q * 4, row_block=row_block,
+                dim_padded=q_w),
+        )
+    return pl.pallas_call(
+        functools.partial(
+            _kernel, tile_n=tile_n, survivors=survivors, nd=1,
+            precision=precision, ti_axis=0 if db_major else 1,
+            terms=terms, masked=bool(words)),
+        grid=((n_tiles, qp // block_q) if db_major
+              else (qp // block_q, n_tiles)) + (row_steps,),
+        in_specs=[
+            pl.BlockSpec((block_q, q_w), q_idx),
+            *[pl.BlockSpec((block_q, words_w), w_idx) for _ in words],
+            *[pl.BlockSpec((row_block, chunk_w), t_idx) for _ in db_inputs],
+            *[pl.BlockSpec((block_q, BIN_W), q_idx) for _ in q_extra],
+            pl.BlockSpec((aux_rows, row_block), n_idx),
+        ],
+        out_specs=[
+            pl.BlockSpec((block_q, out_w), o_idx),
+            pl.BlockSpec((block_q, out_w), o_idx),
+            pl.BlockSpec((block_q, bound_w), o_idx),
+        ],
+        out_shape=out_shape,
+        # the select's running arrays, shared by the steps of a tile
+        scratch_shapes=[pltpu.VMEM(
+            (2 * survivors + 1, block_q, BIN_W), jnp.float32)],
+        interpret=interpret,
+        **kwargs,
+    )(queries, *words, *db_inputs, *q_extra, tnorm)
 
 
 def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
@@ -1565,7 +1818,7 @@ def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
     static_argnames=("m", "tile_n", "block_q", "survivors",
                      "precision", "final_select", "interpret",
                      "final_recall_target", "grid_order", "kernel",
-                     "offset", "terms", "dim_chunk"),
+                     "offset", "terms", "row_block"),
 )
 def local_certified_candidates(
     q: jax.Array,
@@ -1585,7 +1838,7 @@ def local_certified_candidates(
     offset: float = 0.0,
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
     terms: str = BF16X3_TERMS[0],
-    dim_chunk: Optional[int] = None,
+    row_block: Optional[int] = None,
     db_prepared: Optional[Tuple[jax.Array, ...]] = None,
     valid_words: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -1646,7 +1899,7 @@ def local_certified_candidates(
         precision=precision, interpret=interpret,
         final_select=final_select, grid_order=grid_order, kernel=kernel,
         db_int8=db_int8, offset=offset, db_pq=db_pq, terms=terms,
-        dim_chunk=dim_chunk, db_prepared=db_prepared,
+        row_block=row_block, db_prepared=db_prepared,
         valid_words=valid_words,
     )
     return local_select_rescore(
@@ -1660,7 +1913,7 @@ def local_certified_candidates(
     static_argnames=("m", "tile_n", "block_q", "survivors",
                      "precision", "interpret", "final_select",
                      "grid_order", "kernel", "offset", "terms",
-                     "dim_chunk"),
+                     "row_block"),
 )
 def local_coarse_candidates(
     q: jax.Array,
@@ -1679,7 +1932,7 @@ def local_coarse_candidates(
     final_select: str = "exact",
     db_pq: Optional[Tuple[jax.Array, jax.Array]] = None,
     terms: str = BF16X3_TERMS[0],
-    dim_chunk: Optional[int] = None,
+    row_block: Optional[int] = None,
     db_prepared: Optional[Tuple[jax.Array, ...]] = None,
     valid_words: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
@@ -1688,7 +1941,7 @@ def local_coarse_candidates(
     trim the query padding.  Returns the packed candidates
     ``(cd [Q, W], ci [Q, W], bounds [Q, T*B])``; stage 2
     (:func:`local_select_rescore`) is everything after the kernel.
-    ``dim_chunk``, ``db_prepared`` and ``valid_words`` go to the kernel
+    ``row_block``, ``db_prepared`` and ``valid_words`` go to the kernel
     as given (``_bin_candidates``)."""
     if interpret is None:
         interpret = not default_backend_is_tpu()
@@ -1713,7 +1966,7 @@ def local_coarse_candidates(
             precision=precision, interpret=interpret,
             grid_order=grid_order, kernel=kernel, db_int8=db_int8,
             offset=offset, keep=m + 2 if kernel == "fused" else None,
-            db_pq=db_pq, terms=terms, dim_chunk=dim_chunk,
+            db_pq=db_pq, terms=terms, row_block=row_block,
             db_prepared=db_prepared, valid_words=valid_words,
         )
     n_q = q.shape[0]

@@ -1022,6 +1022,7 @@ class ShardedKNN:
         # (columns of one dim chunk, chunks a row tile is cut into) that
         # _pallas_setup handed its last program's kernel
         self._dim_chunking: Tuple[int, int] = (0, 0)
+        self._row_blocking: Tuple[int, int] = (0, 0)
         # what runs the last program's final top-(m+2) on each shard:
         # "pallas" or "xla" (_pallas_setup)
         self._final_select_stage = "xla"
@@ -2327,14 +2328,19 @@ class ShardedKNN:
                 merged["mxu_passes"] = terms.count("+") + 1
                 obs.counter(_mn.KERNEL_TERMS, terms=terms).inc(
                     len(batches))
-                # how the kernel cut a row tile's columns, as setup
-                # handed it to the program: one chunk wherever the padded
-                # width fits VMEM, 128-column chunks accumulated in
-                # scratch where it does not
+                # how the kernel cut a row tile, by setup's reading:
+                # the columns (one chunk under the tiled kernel,
+                # 128-column chunks under the other two) and the rows
+                # (the whole tile a grid step wherever it fits VMEM at
+                # that width, else the row blocks setup handed the
+                # program)
                 merged["dim_chunk"], merged["dim_chunks"] = (
                     self._dim_chunking)
+                merged["row_block"], merged["row_steps"] = (
+                    self._row_blocking)
                 obs.counter(_mn.KERNEL_DIM_CHUNKS,
-                            chunks=str(merged["dim_chunks"])).inc(
+                            chunks=str(merged["dim_chunks"]),
+                            row_steps=str(merged["row_steps"])).inc(
                     len(batches))
                 # what ran the top-(m+2) over that width: the Pallas
                 # stage or XLA's top_k and gather
@@ -2400,6 +2406,8 @@ class ShardedKNN:
                     "mxu_passes": merged["mxu_passes"],
                     "dim_chunk": merged["dim_chunk"],
                     "dim_chunks": merged["dim_chunks"],
+                    "row_block": merged["row_block"],
+                    "row_steps": merged["row_steps"],
                     "final_select_stage": merged["final_select_stage"],
                     "operands": merged["operands"],
                     "sub_batch": sub_why, "batches": len(batches)}
@@ -2895,13 +2903,17 @@ class ShardedKNN:
         :meth:`_kernel_terms`'s reading of the data, no knob: left out,
         the program forms every product and is right for any rows.
 
-        The width of a dim chunk is resolved HERE too, once
-        (ops.pallas_knn.dim_chunking over the resolved tile, the query
-        block a shard runs for batches of ``batch_rows`` queries — left
-        out, a full ``block_q`` — and the parts ``terms`` streams), and
-        handed to the program as the kernel's static ``dim_chunk``: what
-        ``search_certified`` reports (``self._dim_chunking``) is what the
-        kernel was given, not a second reading of the shape.
+        How the kernel cuts a row tile is resolved HERE too, once: its
+        rows by ops.pallas_knn.row_blocking over the resolved tile, the
+        query block a shard runs for batches of ``batch_rows`` queries
+        (left out, a full ``block_q``), the parts ``terms`` streams and
+        ``masked``, handed to the program as the kernel's static
+        ``row_block``, so that what ``search_certified`` reports
+        (``self._row_blocking``) is what the kernel was given, not a
+        second reading of the shape; its columns by
+        ops.pallas_knn.dim_chunking, a function of the width, the
+        precision and the kernel alone, which the kernel reads for
+        itself to the same answer (``self._dim_chunking``).
 
         The default precision's row operands are resolved HERE as well
         (:meth:`_row_operands` at the resolved tile and ``terms``; the
@@ -2915,7 +2927,7 @@ class ShardedKNN:
         else what ``analysis.subbatch.certified_sub_batch`` reads off
         the operands' source, the placed rows' width, the query block
         and the mesh's query shards.  ``self._sub_batch`` is ``(rows,
-        why)``; the query block and the dim chunks above are resolved at
+        why)``; the query block and the row blocks above are resolved at
         those rows.  Without ``call_rows`` (the probes that launch the
         program themselves) it is ``batch_rows`` as given.
 
@@ -2931,6 +2943,7 @@ class ShardedKNN:
             effective_block_q,
             effective_tile,
             final_select_geometry,
+            row_blocking,
             select_merge_geometry,
         )
 
@@ -2956,7 +2969,7 @@ class ShardedKNN:
         eff_tile = effective_tile(shard_rows, tile_n or TILE_N, survivors,
                                   min(self.k + margin, shard_rows) + 2)
         self._kernel_tile = eff_tile
-        _, _, out_w, _ = _geometry(eff_tile, survivors)
+        out_w = _geometry(eff_tile, survivors)[2]
         # m is bounded by the db, the per-shard rows, and the kernel's
         # per-shard candidate width minus the two slots the exclusion
         # value needs (ops.pallas_knn.local_certified_candidates)
@@ -2992,9 +3005,11 @@ class ShardedKNN:
         if batch_rows is not None:
             bq = effective_block_q(bq, -(-batch_rows // q_shards))
         self._dim_chunking = dim_chunking(
+            self._tp.shape[1], precision=precision, kernel=kernel)
+        self._row_blocking = row_blocking(
             self._tp.shape[1], tile_n=eff_tile, block_q=bq,
             precision=precision, kernel=kernel, terms=terms,
-            survivors=survivors)
+            survivors=survivors, masked=masked)
         # the program gets setup's RESOLVED tile, not the raw request:
         # m was capped so that width(eff_tile) >= m+2, which makes the
         # kernel's own effective_tile(min_width=m+2) a fixpoint — the
@@ -3011,7 +3026,7 @@ class ShardedKNN:
             quant_offset=quant_offset, dcn_merge=self.dcn_merge,
             interpret=interpret, terms=terms,
             augmented=self._dot_aug or self._cosine_unit,
-            dim_chunk=self._dim_chunking[0],
+            row_block=self._row_blocking[0],
             resident_parts=len(resident) - 1 if resident else 0,
             **({"masked": True} if masked else {}),
             **({"slack_outcome": True} if self._cosine_unit else {}),
@@ -3310,7 +3325,7 @@ def _pallas_certified_program(
     interpret: Optional[bool] = None,
     terms: str = "hh+hl+lh",
     augmented: bool = False,
-    dim_chunk: Optional[int] = None,
+    row_block: Optional[int] = None,
     resident_parts: int = 0,
     masked: bool = False,
     slack_outcome: bool = False,
@@ -3367,7 +3382,7 @@ def _pallas_certified_program(
     difference packed as bit 1 of the flag word (``failed_by_slack``
     reads it); without it the program is the one it was.
 
-    ``dim_chunk`` is the kernel's static of that name, ``_pallas_setup``'s
+    ``row_block`` is the kernel's static of that name, ``_pallas_setup``'s
     resolution (None: the kernel reads its own launch's shape).
 
     ``resident_parts`` ("bf16x3"; never a caller's choice either) is how
@@ -3417,7 +3432,7 @@ def _pallas_certified_program(
             final_recall_target=final_recall_target,
             grid_order=grid_order, kernel=kernel, interpret=interpret,
             db_int8=db_q, db_pq=db_pq, offset=quant_offset, terms=terms,
-            dim_chunk=dim_chunk, db_prepared=db_rows,
+            row_block=row_block, db_prepared=db_rows,
             **({"valid_words": words} if masked else {}),
         )
         return _certify_pack_spmd(
@@ -3445,7 +3460,7 @@ def _pallas_certified_program(
     )
     _hooks.mark_built(
         prog, f"m={m},k={k},tile={eff_tile},terms={terms},"
-              f"dim_chunk={dim_chunk},precision={precision},"
+              f"row_block={row_block},precision={precision},"
               f"operands={'resident' if resident_parts else 'per_call'}"
               + (",masked" if masked else "")
               + (",slack_outcome" if slack_outcome else ""))

@@ -10,12 +10,13 @@ With no arguments it compiles the certified coarse pass (compiled,
 4,096 queries) at the three benchmark shapes with the knobs the library
 resolves when nobody picks any (``tuning.resolve_full``, no winner
 cache), for each of the three kernels, and again at 512 columns, the
-widest rows whose tile the tiled kernel keeps as ONE dim chunk
-(``analysis.vmem.dim_chunking``; GloVe's 384 and ``text2image2m5``'s
+widest rows whose tile the tiled kernel runs as ONE grid step
+(``analysis.vmem.row_blocking``; GloVe's 384 and ``text2image2m5``'s
 256 padded columns are the other two such widths in the list), and at
-640, the narrowest it does not (the streaming and fused kernels keep
-128-column chunks at every width: both rows are there so that a rule
-which collapsed them where they have no room fails here); asks for
+640, the narrowest whose tile it cuts by rows, as it does ``gist``'s
+1,024 (the streaming and fused kernels keep 128-column chunks at every
+width: both rows are there so that a rule which collapsed them where
+they have no room fails here); asks for
 fused at block_q=256 at SIFT, which the library's own VMEM model must refuse
 before Mosaic is asked; compiles the whole certified program with the
 kernel's one-product form (``--terms hh``: what a byte corpus and a
@@ -33,7 +34,11 @@ over the rows and the compaction at ``ops.radius.range_width``); and
 compiles the final select's Pallas stage (``select_final``, PR 35)
 alone at every cell's (width, m) — 8,704 x 130, 15,872 x 130, 2,560 x
 40 — reading the least scoped-VMEM limit Mosaic takes it at against
-``analysis.vmem.final_select_bytes``.  The whole programs above carry
+``analysis.vmem.final_select_bytes``; and reads the same for the
+kernel at the two cells whose row tile is cut by rows (``gist`` and
+``openai500k``: steps of 4,096 rows at 1,024 and 1,536 columns)
+against ``analysis.vmem.launch_estimate``, at the default query block
+and at 128 (a short sub-batch's).  The whole programs above carry
 that stage and the bin-merge COMPILED (``interpret=False`` reaches
 both), ``gist`` among them, and the range cell's first pass is checked
 to hold still exactly one line with a ``uint32`` array of two
@@ -86,14 +91,14 @@ SHAPES = {
     # one chip of text2image-10M as PLACED: inner product, 200 columns
     # and the appended norm column (benchmark/configs/text2image2m5.json)
     "text2image2m5": (2_500_000, 201, 10),
-    # no data set: the widest rows whose tile is still ONE dim chunk at
-    # the default tile and query block (analysis.vmem.dim_chunking), and
-    # the narrowest whose tile is not
+    # no data set (at the end of this table): the widest rows whose
+    # tile is still ONE grid step at the default tile and query block
+    # (analysis.vmem.row_blocking), and the narrowest whose tile is cut
     # one chip of ssnpp-10M: 256 byte-valued columns, range search over
     # a top-100 first pass (benchmark/configs/ssnpp2m5.json)
     "ssnpp2m5": (2_500_000, 256, 100),
     # VectorDBBench's 500K x 1,536 cosine case whole on one chip: unit
-    # rows, 12 dim chunks a row tile (benchmark/configs/openai500k.json)
+    # rows, a row tile in four steps (benchmark/configs/openai500k.json)
     "openai500k": (500_000, 1536, 100),
     "wide512": (1_000_000, 512, 100),
     "wide640": (1_000_000, 640, 100),
@@ -120,8 +125,11 @@ def _topology_devices():
         topology_name=TOPOLOGY, platform="tpu").devices
 
 
-def _kernel_case(shape: str, knobs: dict, devices, terms=None):
-    """(fn, avals) of the compiled certified coarse pass on ONE chip."""
+def _kernel_case(shape: str, knobs: dict, devices, terms=None,
+                 row_block=None):
+    """(fn, avals) of the compiled certified coarse pass on ONE chip
+    (``row_block``: the kernel's static of that name, for a probe of a
+    cut the rule does not make; None = the rule's)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
@@ -135,6 +143,8 @@ def _kernel_case(shape: str, knobs: dict, devices, terms=None):
     kw = {kk: v for kk, v in knobs.items() if v is not None}
     if terms:
         kw["terms"] = terms
+    if row_block:
+        kw["row_block"] = row_block
     fn = jax.jit(functools.partial(
         local_certified_candidates, m=k + MARGIN, interpret=False, **kw))
     return fn, (q, db)
@@ -389,12 +399,12 @@ def default_cases():
     cases = [(f"{shape} {kernel} defaults", shape, {"kernel": kernel},
               "compiles")
              for kernel in ("tiled", "streaming", "fused")
-             # the widths whose row tile is ONE dim chunk under the
-             # tiled kernel at these knobs (analysis.vmem.dim_chunking)
+             # the widths whose row tile is ONE grid step under the
+             # tiled kernel at these knobs (analysis.vmem.row_blocking)
              # are GloVe's 384 padded columns, text2image2m5's 256
-             # (below) and the widest, 512; at 640 it is five.  The
-             # other two kernels must compile as they did at 128-column
-             # chunks
+             # (below) and the widest, 512; at 640 and at gist's 1,024
+             # it is four steps of 4,096 rows.  The other two kernels
+             # must compile as they did at 128-column chunks
              for shape in ("sift", "gist", "glove", "wide512", "wide640")]
     cases.append(("sift fused block_q=256", "sift",
                   {"kernel": "fused", "block_q": 256}, "refused"))
@@ -420,7 +430,7 @@ def default_cases():
 
 
 def run_case(name, shape, overrides, expect, mesh, terms, devices, *,
-             merge="ring", probe=False) -> bool:
+             merge="ring", probe=False, row_block=None) -> bool:
     from knn_tpu import tuning
 
     # the knobs search_certified would resolve for these overrides with
@@ -431,7 +441,7 @@ def run_case(name, shape, overrides, expect, mesh, terms, devices, *,
     def make_case():
         if mesh is not None:
             return _spmd_case(shape, knobs, devices, mesh, merge, terms)
-        return _kernel_case(shape, knobs, devices, terms)
+        return _kernel_case(shape, knobs, devices, terms, row_block)
 
     t0 = time.time()
     if probe:
@@ -474,6 +484,11 @@ def main(argv=None) -> int:
                     help="the products of the bf16x3 split the kernel "
                     "forms; the library reads this off the data "
                     "(ops.pallas_knn.BF16X3_TERMS), here it is asked for")
+    ap.add_argument("--row-block", type=int,
+                    help="the rows of a tile one grid step of the tiled "
+                    "kernel multiplies (ops.pallas_knn.row_blocking "
+                    "reads this off the shape; here it is asked for, "
+                    "for the kernel alone: no --mesh)")
     ap.add_argument("--probe", action="store_true",
                     help="report the scoped-VMEM need Mosaic names "
                     "instead of compiling at the library's own limit")
@@ -494,6 +509,7 @@ def main(argv=None) -> int:
         overrides = {k: v for k, v in overrides.items() if v is not None}
         label = " ".join(f"{k}={v}" for k, v in {
             **overrides, **({"terms": args.terms} if args.terms else {}),
+            **({"row_block": args.row_block} if args.row_block else {}),
         }.items())
         cases = [(f"{args.shape} {label or 'defaults'}", args.shape,
                   overrides, "compiles")]
@@ -504,7 +520,8 @@ def main(argv=None) -> int:
                  for name, *rest in cases]
     # a case names its own mesh and terms, or takes the command line's
     cases = [(*case, mesh, args.terms)[:6] for case in cases]
-    ok = [run_case(*case, devices, merge=args.merge, probe=args.probe)
+    ok = [run_case(*case, devices, merge=args.merge, probe=args.probe,
+                   row_block=args.row_block)
           for case in cases]
     # the final select's bin-merge kernel, where the shape's width a chip
     # engages it: a bare run asks for bigann20m over its four chips (36
@@ -555,6 +572,33 @@ def main(argv=None) -> int:
         except Exception as e:  # noqa: BLE001 — Mosaic refusal, reported
             ok.append(False)
             print(f"FAIL {name}: {str(e)[-400:]}", flush=True)
+    # the kernel where its row tile is cut by rows: the least limit
+    # Mosaic takes it at is inside the model's need plus an eighth
+    from knn_tpu import tuning
+
+    # (at the default query block, and at 128: a short sub-batch's)
+    for shape, block_q in ([] if args.shape else [
+            (shape, block_q) for shape in ("gist", "openai500k")
+            for block_q in (None, 128)]):
+        knobs, _ = tuning.resolve_full(
+            *SHAPES[shape], cache_path=os.devnull,
+            overrides={"block_q": block_q} if block_q else {})
+        geo = vmem.launch_estimate(
+            n=SHAPES[shape][0], d=SHAPES[shape][1], k=SHAPES[shape][2],
+            **{kk: knobs[kk] for kk in ("precision", "kernel", "tile_n",
+                                        "block_q", "survivors")})
+        t0 = time.time()
+        name = (f"{shape} kernel at block_q={geo['geometry']['block_q']} "
+                f"in {geo['geometry']['row_steps']} steps of "
+                f"{geo['geometry']['row_block']} rows a tile")
+        need, _, _ = _probe_need(
+            lambda: _kernel_case(shape, knobs, devices))
+        model = geo["total_bytes"]
+        fits = need is not None and need * vmem.MIB <= model + model // 8
+        ok.append(fits)
+        print(f"{'OK  ' if fits else 'FAIL'} {name}: least limit {need} "
+              f"MiB, model {model / vmem.MIB:.2f} MiB + an eighth  "
+              f"({time.time() - t0:.0f}s)", flush=True)
     # the range completion's program
     for shape in ([args.shape] if args.shape else RANGE):
         if shape not in RANGE:

@@ -16,9 +16,13 @@ score error (kernel score less the float64 score of the placed values)
 with the in-program operands, with ``row_operands`` and with numpy-made
 halves, beside ``kernel_tolerance``.  PR 43 read 1.0e-4 std and 4.7e-4 at
 most in-program (3.9 times the tolerance) and 2.6e-7 / 1.1e-6 for the
-other two.
+other two.  The tile is cut as ``ops.pallas_knn.row_blocking`` cuts it
+(four steps of 4,096 rows at this width since PR 46); ``--row-block N``
+hands the kernel another block, to time or to check a cut the rule does
+not make.
 """
 
+import argparse
 import functools
 import os
 import sys
@@ -43,6 +47,11 @@ SEED, ROWS, QUERIES = 4300000502, 65536, 256
 
 
 def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--row-block", type=int,
+                    help="the rows of a tile one grid step multiplies "
+                    "(None: the kernel's own reading of the shape)")
+    row_block = ap.parse_args().row_block
     cfg = harness.load_cell(ROOT, "openai500k.sweep_cos").config
     dim = int(cfg["dim"])
     db = datagen_mix.draw(cfg["rows"], ROWS, dim, SEED, datagen.STREAM_ROWS)
@@ -84,7 +93,7 @@ def main() -> int:
         run = jax.jit(lambda q, t, *p: pk._bin_candidates(
             q, t, block_q=256, tile_n=pk.TILE_N, survivors=None,
             precision="bf16x3", interpret=False, terms="hh+hl+lh",
-            dim_chunk=128, db_prepared=p or None))
+            db_prepared=p or None, row_block=row_block))
         cd, ci, _ = (np.asarray(x) for x in run(
             jnp.asarray(unit_q), t_dev, *prepared))
         errs = []

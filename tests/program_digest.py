@@ -17,6 +17,11 @@ program's digests at the SUB-BATCH rows a default call may be cut into
 default sub-batch was a rule (PR 42's parent, where only an explicit
 ``batch_size`` gave them); ``tests/test_sub_batch.py`` holds the rule's
 cut to them.  Recorded by the same command with ``--sub-batches``.
+
+PR 46 recorded ``gist1m.sweep``'s three digests anew and no other: its
+kernel cuts the row tile by rows where it cut the columns
+(``analysis.vmem.row_blocking``); the four cells whose tile is one step
+give the digests they gave.
 """
 
 import hashlib
@@ -52,12 +57,12 @@ def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
     shards, rows, dim, k, terms, parts, dot = CELLS[cell]
     mesh = make_mesh(1, shards, devices=jax.devices()[:shards])
     m = k + MARGIN
-    chunk, _ = pk.dim_chunking(dim, tile_n=pk.TILE_N, block_q=pk.BLOCK_Q,
+    block, _ = pk.row_blocking(dim, tile_n=pk.TILE_N, block_q=pk.BLOCK_Q,
                                precision="bf16x3", kernel="tiled",
                                terms=terms, survivors=None)
     prog = sh._pallas_certified_program(
         mesh, m, k, "ring", pk.TILE_N, "bf16x3", n_train=rows * shards,
-        interpret=True, terms=terms, augmented=dot, dim_chunk=chunk,
+        interpret=True, terms=terms, augmented=dot, row_block=block,
         resident_parts=parts)
     rows_p = -(-rows // pk.TILE_N) * pk.TILE_N
     dim_p = -(-dim // pk.DIM_CHUNK) * pk.DIM_CHUNK

@@ -579,18 +579,25 @@ def test_vmem_geometry_mirrors_pallas_kernel():
     assert vmem.BIN_W == pk.BIN_W
     assert vmem.DIM_CHUNK == pk.DIM_CHUNK
     assert vmem.MAX_CARRY_DEPTH == pk.MAX_CARRY_DEPTH
-    # DIM_CHUNK is the padding grain and the fallback width; the width a
-    # launch runs is the rule's, and the kernel asks the model's rule
+    # DIM_CHUNK is the padding grain and the other two kernels' chunk;
+    # how a launch cuts its tile is the rules', and the kernel asks the
+    # model's rules
     budget = vmem.budget_for(vmem.TARGET_DEVICE_KIND)
-    for dim in (8, 128, 201, 300, 512, 640, 960, 4096):
+    for dim in (8, 128, 201, 300, 512, 640, 960, 1536, 4096):
+        padded = -(-dim // vmem.DIM_CHUNK) * vmem.DIM_CHUNK
+        for kernel in ("tiled", "streaming"):
+            assert pk.dim_chunking(
+                dim, precision="bf16x3", kernel=kernel) == vmem.dim_chunking(
+                padded, kernel=kernel)
         for bq, tile in ((256, 16384), (128, 32768), (8, 256)):
             for terms, parts in (("hh+hl+lh", None), ("hh", 1)):
-                assert pk.dim_chunking(
-                    dim, tile_n=tile, block_q=bq, precision="bf16x3",
-                    terms=terms) == vmem.dim_chunking(
-                    -(-dim // vmem.DIM_CHUNK) * vmem.DIM_CHUNK,
-                    tile_n=tile, block_q=bq, db_parts=parts,
-                    budget_bytes=budget), (dim, bq, tile, terms)
+                for masked in (False, True):
+                    assert pk.row_blocking(
+                        dim, tile_n=tile, block_q=bq, precision="bf16x3",
+                        terms=terms, masked=masked) == vmem.row_blocking(
+                        padded, tile_n=tile, block_q=bq, db_parts=parts,
+                        masked=masked, budget_bytes=budget), (
+                        dim, bq, tile, terms, masked)
 
 
 def test_vmem_operand_widths_mirror_roofline():
@@ -663,9 +670,6 @@ def test_vmem_model_tracks_the_compiler_at_the_benchmark_shapes():
         ("sift", "tiled", 256, 16384, 47.18),
         ("sift", "tiled", 128, 32768, 62.47),
         ("sift", "tiled", 256, 32768, 93.09),
-        ("gist", "tiled", 128, 16384, 49.10),
-        ("gist", "tiled", 256, 16384, 81.94),
-        ("gist", "tiled", 128, 32768, 99.60),
         ("sift", "streaming", 128, 16384, 71.65),
         ("sift", "streaming", 256, 16384, 126.55),
         ("gist", "streaming", 128, 16384, 80.07),
@@ -673,7 +677,6 @@ def test_vmem_model_tracks_the_compiler_at_the_benchmark_shapes():
         ("sift", "fused", 128, 16384, 73.32),
         ("sift", "fused", 256, 16384, 133.75),  # > 128 MiB physical
         ("gist", "fused", 128, 16384, 82.64),
-        ("gist", "tiled", 256, 32768, 162.41),  # > 128 MiB physical
     ]
     budget = vmem.budget_for(vmem.TARGET_DEVICE_KIND)
     for shape, kernel, bq, tile, need in reported:
@@ -686,6 +689,49 @@ def test_vmem_model_tracks_the_compiler_at_the_benchmark_shapes():
         assert (est <= budget) == (need <= 128), (shape, kernel, bq)
         if est <= budget:
             assert vmem.limit_bytes(est, budget) >= need * vmem.MIB
+
+
+def test_vmem_model_bounds_the_row_cut_geometries():
+    """The geometries ``row_blocking`` cuts by rows (PR 46), probed by
+    bisection on the limit like the one-step ones below: GIST's 1,024
+    columns were eight 128-column chunks with a 16 MiB accumulator when
+    the model was fitted (81.94 MiB at bq256, 49.10 at bq128, 99.60 and
+    a refused 162.41 at tile 32,768); now a step is 4,096 rows at the
+    whole width whatever the tile.  The model may run 14% over and not
+    under, the limit it asks for must cover the need, and the rule must
+    have chosen that block."""
+    shapes = {"gist": (1_000_000, 960, 100),
+              "openai500k": (500_000, 1536, 100),
+              "wide640": (1_000_000, 640, 100)}
+    compiled_at = [
+        ("gist", 256, 16384, 4096, 47),   # refused at 46
+        ("gist", 128, 16384, 4096, 44),
+        ("gist", 128, 32768, 4096, 44),
+        ("gist", 256, 32768, 4096, 47),
+        ("openai500k", 256, 16384, 4096, 71),
+        ("wide640", 256, 16384, 4096, 32),
+    ]
+    budget = vmem.budget_for(vmem.TARGET_DEVICE_KIND)
+    for shape, bq, tile, block, need in compiled_at:
+        n, d, k = shapes[shape]
+        est = vmem.launch_estimate(n=n, d=d, k=k, block_q=bq, tile_n=tile)
+        assert est["geometry"]["dim_chunks"] == 1, shape
+        assert (est["geometry"]["row_block"],
+                est["geometry"]["row_steps"]) == (block, tile // block)
+        assert est["breakdown"]["accum_scratch"] == 0
+        total = est["total_bytes"]
+        assert need <= total / vmem.MIB <= 1.14 * need, (
+            shape, bq, tile, total / vmem.MIB, need)
+        assert vmem.limit_bytes(total, budget) >= need * vmem.MIB
+    # blocks the rule does not choose at GIST's width, by the kernel's
+    # own arithmetic: 2,048 rows (25) and 8,192 (93)
+    for block, need in ((2048, 25), (8192, 93)):
+        total = sum(vmem.kernel_bytes(
+            kernel="tiled", block_q=256, tile_n=16384, n_tiles=62, nd=1,
+            out_w=256, bound_w=128, db_block=2 * block * 1024 * 2,
+            aux_rows=8, q_block=256 * 1024 * 4, row_block=block,
+            dim_padded=1024).values())
+        assert need <= total / vmem.MIB <= 1.14 * need, (block, total)
 
 
 def test_vmem_model_bounds_the_one_chunk_geometries():

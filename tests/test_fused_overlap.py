@@ -204,9 +204,10 @@ def test_roofline_v2_select_overlap_semantics():
     # probed-bytes term (tests/test_ivf.py owns it); v6 = the sub-int8
     # compressed-tier widths (tests/test_roofline.py owns it); v7 = the
     # bulk-join amortized db-bytes + h2d terms (tests/test_join.py owns
-    # it); the select-overlap formulas above are pinned
-    # version-independently
-    assert roofline.MODEL_VERSION == 7
+    # it); v8 = the tiled kernel's query block streams once a query
+    # block (tests/test_roofline.py owns it); the select-overlap
+    # formulas above are pinned version-independently
+    assert roofline.MODEL_VERSION == 8
     # a fused config whose carry would exceed MAX_CARRY_DEPTH disarms
     # in the kernel — the model mirrors the disarm and falls back to
     # the serialized ceiling, so pruning/--best can never hold other

@@ -440,7 +440,7 @@ def test_cli_roofline_ivf_flags(capsys):
          "--ncentroids", "8"])
     assert cli.run_roofline(args) == 0
     out = capsys.readouterr().out
-    assert "probed:" in out and "roofline v7" in out
+    assert "probed:" in out and "roofline v8" in out
     # --best threads the knobs instead of silently ignoring them
     args = cli.build_roofline_parser().parse_args(
         ["--n", "1000000", "--dim", "128", "--k", "100",

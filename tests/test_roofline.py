@@ -95,6 +95,30 @@ def test_db_byte_terms_match_actual_operand_nbytes(rng, precision, kernel):
     assert m["terms"]["hbm"]["bytes"]["db_aux"] == passes * aux_b
 
 
+@pytest.mark.parametrize("kernel,grid_order,fetches", [
+    ("tiled", "query_major", 1), ("tiled", "db_major", 62),
+    ("streaming", "query_major", 62)])
+def test_the_tiled_kernels_query_block_streams_once(kernel, grid_order,
+                                                    fetches):
+    """MODEL_VERSION 8: the tiled kernel multiplies the whole padded
+    width a step (a tile too wide for VMEM is cut by rows), so under
+    ``query_major`` its query block's index moves with the query block
+    alone: the queries stream once, whatever the tiles and their row
+    blocks; the db stream does not move."""
+    kw = dict(n=1_000_000, d=960, k=100, nq=4096, block_q=256,
+              device_kind="TPU v5e")
+    b = roofline.pallas_cost_model(kernel=kernel, grid_order=grid_order,
+                                   **kw)["terms"]["hbm"]["bytes"]
+    assert b["queries"] == fetches * 4096 * 960 * 4
+    # (db_major saves no row stream here: GIST's tile is cut by rows,
+    # whose blocks cycle with the query blocks)
+    assert b["db_stream"] == 16 * 1_000_000 * 960 * 4
+    i8 = roofline.pallas_cost_model(
+        kernel=kernel, grid_order=grid_order, precision="int8",
+        **kw)["terms"]["hbm"]["bytes"]
+    assert i8["queries"] == fetches * 4096 * (960 + 128 * 4)
+
+
 def test_geometry_defaults_mirror_kernel_constants():
     """The jax-free module mirrors the kernel's geometry defaults; a
     drift here would silently mis-model every default-knob config."""

@@ -497,7 +497,7 @@ def test_no_call_copies_the_placed_rows(one_chip, program, rows, given):
             prog = sh._pallas_certified_program(
                 m, K + 28, K, "ring", pk.TILE_N, "bf16x3", n_train=rows,
                 interpret=False, augmented=True, include_distances=False,
-                dim_chunk=256, resident_parts=2)
+                row_block=pk.TILE_N, resident_parts=2)
             tail = (aval((), jnp.float32),
                     *[aval((rows_p, 256), jnp.bfloat16, DB_AXIS)] * 2,
                     aval((rows_p,), jnp.float32, DB_AXIS),

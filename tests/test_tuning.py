@@ -247,7 +247,7 @@ def test_stale_kernel_version_entry_falls_back_to_defaults(cache_path):
     # it even though "precision": "pq" is a perfectly current knob
     from knn_tpu.ops.pallas_knn import KERNEL_VERSION
 
-    assert KERNEL_VERSION == 10
+    assert KERNEL_VERSION == 11
     cache.put(base + "|kv4", {"knobs": {**tuning.DEFAULT_KNOBS,
                                         "precision": "pq",
                                         "kernel": "streaming"}})
@@ -303,16 +303,19 @@ def test_version_6_winner_naming_a_removed_knob_is_never_used(
     assert stats["tuning"]["source"] == "default"
     # beside the knobs: what the program resolved for itself, from the
     # backend (interpret), from the data (terms, mxu_passes) and from
-    # the launch's shape (dim_chunk, dim_chunks, final_select_stage) and
+    # the launch's shape (dim_chunk(s), row_block / row_steps,
+    # final_select_stage) and
     # from the device's memory (operands), and how the call was cut
     # (sub_batch, batches: analysis.subbatch)
     assert {kk: v for kk, v in stats["pallas_knobs"].items()
             if kk not in ("interpret", "terms", "mxu_passes", "dim_chunk",
-                          "dim_chunks", "final_select_stage", "operands",
+                          "dim_chunks", "row_block", "row_steps",
+                          "final_select_stage", "operands",
                           "sub_batch", "batches")
             } == tuning.DEFAULT_KNOBS
     assert (stats["pallas_knobs"]["dim_chunk"],
             stats["pallas_knobs"]["dim_chunks"]) == (128, 1)
+    assert stats["pallas_knobs"]["row_steps"] == 1
 
 
 def test_default_knobs_are_the_kernel_shaping_arguments():
