@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Proof that the system starts on the chip: drive the main path once, in
 ONE process, through the entry points a user calls, at the full width of
-the benchmark configurations (bench.py CONFIGS), on seeded random data,
+the SIFT1M, GIST1M and GloVe shapes, on seeded random data,
 and check what comes out against a float64 brute-force oracle computed
 here in numpy.
 
@@ -27,12 +27,12 @@ import warnings
 
 import numpy as np
 
-#: bench.py CONFIGS at their published shapes
+#: the three reference datasets at their published shapes
 SIFT = dict(n=1_000_000, dim=128, k=100, metric="l2")
 GIST = dict(n=1_000_000, dim=960, k=100, metric="l2")
 GLOVE = dict(n=1_183_514, dim=300, k=50, metric="cosine")
 NQ = 4096
-#: bench.py's TILE: the exact (non-Pallas) path streams the database in
+#: the exact (non-Pallas) path streams the database in
 #: row tiles of this size; left at None it would materialize the whole
 #: [4096, 1M] f32 distance block (16 GB) on a 16 GB chip
 TRAIN_TILE = 131_072
@@ -90,7 +90,7 @@ class Leg:
 
 
 def make_data(n: int, dim: int, nq: int):
-    """bench.py's generator (seed 0, uniform [0, 128)), filled in row
+    """Seed 0, uniform [0, 128), filled in row
     chunks so the float64 draw never holds more than a chunk — the
     stream, and so every value, is the same as one big draw."""
     rng = np.random.default_rng(0)

@@ -3,7 +3,7 @@
 Machine-enforces the invariants every PR has been hand-checking, as
 six registered checkers over a small framework (docs/ANALYSIS.md):
 
-- ``switch-lockstep`` — every ``KNN_TPU_*``/``KNN_BENCH_*`` env switch
+- ``switch-lockstep`` — every ``KNN_TPU_*`` env switch
   declared in the central catalog (:mod:`knn_tpu.analysis.switches`),
   documented, consumed, and test-isolated (conftest GENERATES its
   isolation from the catalog);

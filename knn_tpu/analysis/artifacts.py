@@ -1,65 +1,41 @@
 """The artifact-schema registry — ONE declarative catalog for every
-bench block the repo emits, and the generic engine that validates,
-hoists, curates, and prints them.
+artifact block the repo emits or validates, and the generic engine that
+validates them.
 
 Six PRs grew six hand-rolled ``validate_*_block`` functions (roofline,
-calibration, campaign, knee, mutation, multihost), a hand-maintained
-sentinel ``CURATED_FIELDS`` list, and six copy-pasted
-validate→refuse→hoist→print stanzas in
-``scripts/refresh_bench_artifacts.py``.  Each was one more hand-checked
-contract between an emitter (bench.py / knee.py / roofline.py / the
-campaign harness), the artifact refresher, the perf sentinel, and the
-docs — exactly the class of drift PR 10's switch/metric catalogs killed
-elsewhere.  This module applies the same cure to the artifact pipeline
-itself:
+calibration, knee, mutation, multihost, ...), each one more hand-checked
+contract between an emitter (knee.py / roofline.py / fleet.py / the
+autotuner), its validator and the docs — the class of drift the
+switch/metric catalogs killed elsewhere.  This module applies the same
+cure:
 
 - :data:`CATALOG` — one :class:`BlockSchema` per artifact block
-  (roofline, calibration, campaign, loadgen_knee, mutation, multihost,
-  sentinel verdict, tuning-cache entries, bench top-level lines,
-  MULTICHIP driver records), each declaring its fields
-  (types/required/ranges), version token, top-level hoist keys,
-  sentinel curated-field direction, emitters + fingerprints (for the
-  ``artifact-lockstep`` checker), and docs anchor;
-- :func:`validate` — the generic engine replacing the six hand
-  validators.  ``style="legacy"`` reproduces each legacy validator's
-  error strings BYTE-IDENTICALLY (the six public ``validate_*`` entry
-  points are now one-line shims over it, their refusal tests
-  unmodified); ``style="normalized"`` is the engine's one canonical
-  phrasing (``missing field: X`` / ``field X must be ..., got ...``) —
-  the normalization the calibration/campaign validators' divergent
-  styles fold into, behind the compat shims;
-- :func:`curate_line` / :func:`apply_hoists` / :func:`line_summary` —
-  the table-driven validate/refuse/hoist/print loop the refresher and
-  ``bench.py`` run instead of six copies;
-- :func:`curated_fields` — the sentinel's ``CURATED_FIELDS``, derived
-  (the hand list is gone);
-- :func:`sweep_records` / :func:`sweep_multichip` — the
-  ``perf_sentinel --lint`` history sweep: every block in every
-  checked-in ``BENCH_r*.json`` / ``TPU_BENCH_r*.jsonl`` /
-  ``MULTICHIP_r*.json`` line validated against the catalog
-  (exact-version schemas exempt blocks stamped with a strictly older
-  version token — pre-schema rounds are reported, not condemned).
+  (roofline, calibration, loadgen_knee, mutation, ivf, pq, multihost,
+  join, quality, fleet, tuning-cache entries), each declaring its
+  fields (types/required/ranges), version token, emitters +
+  fingerprints (for the ``artifact-lockstep`` checker), and docs
+  anchor;
+- :func:`validate` — the generic engine behind the public
+  ``validate_*`` entry points (one-line shims over it).
+  ``style="legacy"`` reproduces each migrated validator's error strings
+  BYTE-IDENTICALLY (their refusal tests unmodified);
+  ``style="normalized"`` is the engine's one canonical phrasing
+  (``missing field: X`` / ``field X must be ..., got ...``).
 
-Everything here is stdlib-only and jax-free: the catalog must load on
-the box that curates artifacts, not only the one with the accelerator.
-Version tokens and choice sets stay in their owning modules
-(``MODEL_VERSION`` lives with the model that bumps it) and are
-referenced lazily through :class:`Ref` — the catalog declares, it never
-duplicates.
+Everything here is stdlib-only and jax-free.  Version tokens and choice
+sets stay in their owning modules (``MODEL_VERSION`` lives with the
+model that bumps it) and are referenced lazily through :class:`Ref` —
+the catalog declares, it never duplicates.
 
-Adding a bench block is ONE schema entry here (docs/ANALYSIS.md "Adding
-a bench block"): the validator, the refresher's refusal + hoists, the
-sentinel's curated baseline, the history sweep, and the
-``artifact-lockstep`` checker all follow from the declaration.
+Adding a block is ONE schema entry here (docs/ANALYSIS.md "Adding a
+bench block"): the validator and the ``artifact-lockstep`` checker
+follow from the declaration.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import glob
 import importlib
-import json
-import os
 from typing import Dict, List, Optional, Tuple
 
 __all__ = [
@@ -69,21 +45,12 @@ __all__ = [
     "Field",
     "Gate",
     "Rule",
-    "Hoist",
-    "Curated",
     "Ref",
     "validate",
     "version_value",
     "required_keys",
     "element_required",
     "known_keys",
-    "curated_fields",
-    "apply_hoists",
-    "apply_scope_hoists",
-    "curate_line",
-    "line_summary",
-    "sweep_records",
-    "sweep_multichip",
 ]
 
 
@@ -132,9 +99,6 @@ class Field:
     kind: str = "any"  # any|int|number|str|bool|dict|list|version|nested
     required: bool = False
     nullable: bool = False
-    #: the value must additionally be truthy (legacy ``if not
-    #: block.get(...)`` semantics — campaign's ``arm``)
-    truthy: bool = False
     ge: Optional[float] = None
     gt: Optional[float] = None
     le: Optional[float] = None
@@ -143,7 +107,7 @@ class Field:
     stop_on_error: bool = False
     nonempty: bool = False
     nested: Optional[str] = None
-    element_style: str = ""  # "knee_steps" | "campaign_stages"
+    element_style: str = ""  # "knee_steps"
     element_required: Tuple[str, ...] = ()
     element_optional: Tuple[str, ...] = ()
     emit_note: str = ""
@@ -171,44 +135,10 @@ class Rule:
 
 
 @dataclasses.dataclass(frozen=True)
-class Hoist:
-    """One block field hoisted to a top-level line key (setdefault
-    semantics).  ``gate`` (default: ``src``) must be non-null — or
-    truthy with ``truthy=True`` — for the hoist to fire; ``numeric``
-    additionally requires the hoisted value to be a number.  ``bench``
-    / ``refresher`` scope which loop performs it (bench flags
-    ``roofline_estimated``; only the refresher back-fills
-    ``multihost_hosts``)."""
-
-    src: str
-    dst: str
-    gate: Optional[str] = None
-    truthy: bool = False
-    numeric: bool = False
-    bench: bool = True
-    refresher: bool = True
-
-
-@dataclasses.dataclass(frozen=True)
-class Curated:
-    """One sentinel curated field contributed by this block: the
-    hoisted top-level key, its good direction, and its rank in the
-    legacy ``CURATED_FIELDS`` order (preserved so derived == hand
-    list, element for element)."""
-
-    field: str
-    direction: str  # "higher" | "lower"
-    rank: int
-
-
-@dataclasses.dataclass(frozen=True)
 class BlockSchema:
     """One cataloged artifact block."""
 
     name: str
-    #: dotted path of the block on a bench line ("" = the line itself /
-    #: a block that never rides bench lines)
-    block_path: str
     #: docs anchor "docs/FILE.md#Heading text" — the artifact-lockstep
     #: checker requires the heading to exist
     doc: str
@@ -222,33 +152,17 @@ class BlockSchema:
     version_exact: bool = False
     #: legacy template for a non-dict block
     not_dict_legacy: Optional[str] = None
-    #: "validator": an "error" key exempts inside validate() (knee,
-    #: mutation); "curation": the refresher skips error blocks but the
-    #: validator itself does not (roofline); "parent": exempt when the
-    #: PARENT block carries "error" (calibration under roofline)
-    error_exempt: str = "none"
+    #: an "error" key exempts the block inside validate() (knee,
+    #: mutation: a degraded run's block says so and is not judged)
+    error_exempt: bool = False
     #: exact key-presence pass run first; ANY miss short-circuits
     #: (mutation's legacy contract) — also the public required list
     missing_order: Tuple[str, ...] = ()
     missing_legacy: Optional[str] = None
-    hoists: Tuple[Hoist, ...] = ()
-    curated: Tuple[Curated, ...] = ()
     #: repo-relative source files whose dict literals build this block
     emitters: Tuple[str, ...] = ()
     #: key sets identifying a dict literal as this block in an emitter
     fingerprints: Tuple[frozenset, ...] = ()
-    #: the label in the refresher's refusal message ("malformed
-    #: {refusal_label} block: ...")
-    refusal_label: str = ""
-    #: participates in the refresher's validate/refuse/hoist loop
-    curate: bool = False
-    #: participates in the perf_sentinel --lint history sweep
-    sweep: bool = False
-    #: name of the per-line print segment function (``_SUMMARIES``)
-    summary: Optional[str] = None
-    #: name of the pre-curation hook (``_PREPARES``) — roofline's
-    #: back-derivation for pre-roofline lines
-    prepare: Optional[str] = None
     #: legacy validator entry point, "module:function" (the shim)
     validator: str = ""
 
@@ -331,8 +245,6 @@ def _check_value(f: Field, value, version) -> bool:
         return isinstance(value, int)
     if f.choices is not None:
         return value in _resolve(f.choices)
-    if f.truthy and not value:
-        return False
     t = _KIND_TYPES.get(f.kind)
     if t is not None and not isinstance(value, t):
         return False
@@ -371,7 +283,7 @@ def validate(name: str, block, style: str = "normalized") -> List[str]:
                      "{name} block must be a dict, got {vtype}", style,
                      name=name, vtype=type(block).__name__)]
     errors: List[str] = []
-    if schema.error_exempt == "validator" and "error" in block:
+    if schema.error_exempt and "error" in block:
         return errors
     if schema.missing_order:
         for key in schema.missing_order:
@@ -483,18 +395,8 @@ def _elements_knee_steps(f: Field, steps: list, style: str) -> List[str]:
     return errs
 
 
-def _elements_campaign_stages(f: Field, stages: list, style: str
-                              ) -> List[str]:
-    for s in stages:
-        if not isinstance(s, dict) or not s.get("stage") or \
-                s.get("status") not in ("ok", "error", "skipped"):
-            return [f"malformed stage record {s!r}"]
-    return []
-
-
 _ELEMENT_RULES = {
     "knee_steps": _elements_knee_steps,
-    "campaign_stages": _elements_campaign_stages,
 }
 
 
@@ -529,199 +431,6 @@ _RULES = {
 }
 
 
-# --------------------------------------------------------------------------
-# hoists, curation, printing
-# --------------------------------------------------------------------------
-def apply_hoists(rec: dict, block: dict, schema: BlockSchema,
-                 scope: str) -> None:
-    """Apply one schema's ``scope`` hoists from ``block`` onto ``rec``
-    (setdefault semantics — an existing top-level value always wins)."""
-    for h in schema.hoists:
-        if scope == "bench" and not h.bench:
-            continue
-        if scope == "refresher" and not h.refresher:
-            continue
-        _, gval = _resolve_path(block, h.gate or h.src)
-        if (not gval) if h.truthy else (gval is None):
-            continue
-        _, val = _resolve_path(block, h.src)
-        if h.numeric and not isinstance(val, (int, float)):
-            continue
-        rec.setdefault(h.dst, val)
-
-
-def _block_on_line(rec: dict, schema: BlockSchema):
-    cur = rec
-    for part in schema.block_path.split("."):
-        if not isinstance(cur, dict):
-            return None
-        cur = cur.get(part)
-    return cur
-
-
-def _parent_block(rec: dict, schema: BlockSchema):
-    parts = schema.block_path.split(".")
-    if len(parts) < 2:
-        return None
-    cur = rec
-    for part in parts[:-1]:
-        if not isinstance(cur, dict):
-            return None
-        cur = cur.get(part)
-    return cur
-
-
-def _curation_exempt(rec: dict, schema: BlockSchema, block) -> bool:
-    if schema.error_exempt == "curation":
-        return isinstance(block, dict) and "error" in block
-    if schema.error_exempt == "parent":
-        parent = _parent_block(rec, schema)
-        return isinstance(parent, dict) and "error" in parent
-    return False
-
-
-def apply_scope_hoists(rec: dict, scope: str = "bench") -> None:
-    """The one hoist loop ``bench.py`` runs over its assembled line:
-    for every cataloged block present, hoist the declared keys."""
-    for schema in CATALOG:
-        if not schema.block_path or not schema.hoists:
-            continue
-        block = _block_on_line(rec, schema)
-        if isinstance(block, dict):
-            apply_hoists(rec, block, schema, scope)
-
-
-def curate_line(rec: dict) -> Optional[str]:
-    """The refresher's per-line loop: prepare (back-derive), validate
-    (legacy error strings — the refusal message is byte-stable),
-    and hoist every cataloged block on a fresh curated line.  Returns
-    the refusal message for the first malformed block, None when the
-    line curates clean."""
-    for schema in CATALOG:
-        if not schema.curate:
-            continue
-        needs_validation = True
-        if schema.prepare is not None:
-            block, needs_validation = _PREPARES[schema.prepare](rec)
-        else:
-            block = _block_on_line(rec, schema)
-        if not isinstance(block, dict):
-            continue
-        if _curation_exempt(rec, schema, block):
-            continue
-        if needs_validation:
-            errs = validate(schema.name, block, style="legacy")
-            if errs:
-                return (f"malformed {schema.refusal_label} block: "
-                        f"{'; '.join(errs)}")
-        apply_hoists(rec, block, schema, "refresher")
-    return None
-
-
-def _prepare_roofline(rec: dict):
-    """Pre-roofline lines (measured before the in-bench block existed)
-    back-derive a block from their own config fields; a derived block
-    is trusted (the model built it), never re-validated — the legacy
-    stanza's exact behavior."""
-    block = rec.get("roofline")
-    if block is not None:
-        return block, True
-    from knn_tpu.obs import roofline
-
-    derived = roofline.block_for_bench_line(rec)
-    if derived is not None:
-        rec["roofline"] = dict(derived, derived=True)
-        return rec["roofline"], False
-    return None, False
-
-
-_PREPARES = {"roofline_derive": _prepare_roofline}
-
-
-# --- per-line print segments (the refresher's readout) --------------------
-def _summary_roofline(r: dict) -> str:
-    # percent-of-roofline + bound class beside the sentinel verdict:
-    # the history says "slower than before", the model says "this far
-    # from the hardware, bound by THIS"
-    if isinstance(r.get("roofline_pct"), (int, float)):
-        return (f" roofline={r['roofline_pct'] * 100:.1f}%"
-                f"/{r.get('bound_class')}")
-    return ""
-
-
-def _summary_calibration(r: dict) -> str:
-    # the analytic model's measured residual, when the line's roofline
-    # block carries an applied calibration overlay
-    if isinstance(r.get("model_residual_pct"), (int, float)):
-        return f" calib={r['model_residual_pct']}%"
-    return ""
-
-
-def _summary_knee(r: dict) -> str:
-    # the measured serving knee (loadgen sweep), when the session ran
-    # one: max SLO-meeting sustained request rate
-    if isinstance(r.get("knee_qps"), (int, float)):
-        return f" knee={r['knee_qps']}q/s"
-    return ""
-
-
-def _summary_mutation(r: dict) -> str:
-    # the mixed-traffic admitted-read p99 (mutation mode), when the
-    # session ran one: the live-mutation tail beside read-only numbers
-    if isinstance(r.get("mutation_admitted_p99_ms"), (int, float)):
-        return f" mutation={r['mutation_admitted_p99_ms']}ms/p99"
-    return ""
-
-
-def _summary_ivf(r: dict) -> str:
-    # the probe-pruned tier (ivf mode), when the session ran one:
-    # certified qps beside the measured recall the certificate gates
-    if isinstance(r.get("ivf_qps"), (int, float)):
-        seg = f" ivf={r['ivf_qps']}q/s"
-        if isinstance(r.get("recall_at_k"), (int, float)):
-            seg += f"@recall{r['recall_at_k']}"
-        return seg
-    return ""
-
-
-def _summary_multihost(r: dict) -> str:
-    # the multi-host topology measurement, when the session ran one:
-    # host count x DCN merge strategy + host-RAM tier sweep count
-    if isinstance(r.get("multihost_hosts"), int):
-        return (f" multihost={r['multihost_hosts']}x"
-                f"{r.get('multihost_merge')}"
-                + (f"/{r['hosttier_sweeps']}sweeps"
-                   if isinstance(r.get("hosttier_sweeps"), int) else ""))
-    return ""
-
-
-_SUMMARIES = {
-    "roofline": _summary_roofline,
-    "calibration": _summary_calibration,
-    "knee": _summary_knee,
-    "mutation": _summary_mutation,
-    "ivf": _summary_ivf,
-    "multihost": _summary_multihost,
-}
-
-
-def line_summary(rec: dict) -> str:
-    """The per-line artifact readout the refresher prints beside the
-    sentinel verdict, one segment per cataloged block, catalog order —
-    byte-identical to the six inline f-strings it replaced."""
-    return "".join(_SUMMARIES[s.summary](rec) for s in CATALOG
-                   if s.summary is not None)
-
-
-def curated_fields() -> Tuple[Tuple[str, str], ...]:
-    """The sentinel's ``CURATED_FIELDS``, derived from the catalog in
-    the legacy hand-list's exact order (each block's contribution
-    carries its rank)."""
-    rows = [c for s in CATALOG for c in s.curated]
-    rows.sort(key=lambda c: c.rank)
-    return tuple((c.field, c.direction) for c in rows)
-
-
 def known_keys(name: str) -> set:
     """Every key name a schema legitimizes in an emitter's block
     literal: all declared path segments plus per-element keys — the
@@ -737,239 +446,24 @@ def known_keys(name: str) -> set:
 
 
 # --------------------------------------------------------------------------
-# the history sweep (perf_sentinel --lint)
-# --------------------------------------------------------------------------
-def sweep_records(records, style: str = "normalized"):
-    """Validate every cataloged block on every history record.  Returns
-    ``(counts, problems)``: per-schema ``validated`` /
-    ``advisory_error`` / ``version_exempt`` counts and a list of
-    ``{"schema", "metric", "source", "error"}`` violations.
-
-    Version exemption: a block whose exact-version schema finds an int
-    version token STRICTLY below the current constant predates the
-    schema — it is counted, not condemned (the validator it was emitted
-    under is gone; judging it by today's shape would flag honest
-    history).  Version-tolerant schemas (roofline accepts any int
-    ``model_version``) validate every round — their validators are
-    version-tolerant by construction."""
-    counts = {s.name: {"validated": 0, "advisory_error": 0,
-                       "version_exempt": 0}
-              for s in CATALOG if s.sweep}
-    problems: List[dict] = []
-    for rec in records:
-        if not isinstance(rec, dict):
-            continue
-        for schema in CATALOG:
-            if not schema.sweep:
-                continue
-            if schema.block_path:
-                block = _block_on_line(rec, schema)
-                if block is None:
-                    continue
-            else:
-                if schema.name != "bench_line":
-                    continue
-                block = rec
-            if isinstance(block, dict) and "error" in block and \
-                    schema.error_exempt == "curation":
-                # bench's advisory degradation ({"error": ...}) is a
-                # designed outcome, not a lint hit — the refresher's
-                # carve-out
-                counts[schema.name]["advisory_error"] += 1
-                continue
-            if _curation_exempt(rec, schema, block):
-                continue
-            if schema.version_exact and schema.version_field and \
-                    isinstance(block, dict):
-                tok = block.get(schema.version_field)
-                if isinstance(tok, int) and \
-                        tok < version_value(schema.name):
-                    counts[schema.name]["version_exempt"] += 1
-                    continue
-            counts[schema.name]["validated"] += 1
-            for err in validate(schema.name, block, style=style):
-                problems.append({
-                    "schema": schema.name,
-                    "label": schema.refusal_label or schema.name,
-                    "metric": rec.get("metric"),
-                    "source": rec.get("_source"),
-                    "error": err,
-                })
-    return counts, problems
-
-
-def sweep_multichip(repo_dir: str):
-    """Validate every checked-in ``MULTICHIP_r*.json`` driver record
-    against its schema.  Returns ``(n_validated, problems)``."""
-    n = 0
-    problems: List[dict] = []
-    for path in sorted(glob.glob(
-            os.path.join(repo_dir, "MULTICHIP_r*.json"))):
-        try:
-            with open(path) as f:
-                doc = json.load(f)
-        except (OSError, json.JSONDecodeError) as e:
-            problems.append({"schema": "multichip_record",
-                             "label": "multichip",
-                             "metric": None,
-                             "source": os.path.basename(path),
-                             "error": f"unreadable: {e}"})
-            continue
-        n += 1
-        for err in validate("multichip_record", doc):
-            problems.append({"schema": "multichip_record",
-                             "label": "multichip", "metric": None,
-                             "source": os.path.basename(path),
-                             "error": err})
-    return n, problems
-
-
-# --------------------------------------------------------------------------
 # THE CATALOG
 # --------------------------------------------------------------------------
 _RL = "knn_tpu.obs.roofline"
 _CAL = "knn_tpu.obs.calibrate"
 _XO = "knn_tpu.parallel.crossover"
 
-#: sentinel verdict vocabulary (bench embeds "error" on a failed
-#: verdict computation — a designed degradation, part of the contract)
-SENTINEL_VERDICTS = ("ok", "warn", "regress", "no_baseline", "error")
-
 CATALOG: Tuple[BlockSchema, ...] = (
-    # --- bench top-level lines -----------------------------------------
-    BlockSchema(
-        name="bench_line",
-        block_path="",
-        doc="docs/ANALYSIS.md#The artifact-schema catalog",
-        emitters=("bench.py", "scripts/refresh_bench_artifacts.py",
-                  "knn_tpu/campaign.py"),
-        fingerprints=(frozenset({"metric", "value", "unit"}),),
-        sweep=True,
-        curated=(
-            Curated("value", "higher", 0),
-            Curated("device_phase_qps", "higher", 1),
-            Curated("serving_sustained_qps", "higher", 2),
-            Curated("mfu", "higher", 3),
-            Curated("mfu_device", "higher", 4),
-        ),
-        checks=(
-            Field("metric", "str", required=True),
-            Field("value", "number", nullable=True),
-            Field("unit", "str", nullable=True),
-            Field("vs_baseline", "number", nullable=True),
-            Field("mode", "str", nullable=True),
-            Field("device_phase_qps", "number", nullable=True),
-            Field("serving_sustained_qps", "number", nullable=True),
-            Field("serving_latency_ms", "dict", nullable=True),
-            Field("obs_overhead_pct", "number", nullable=True),
-            # the artifact blocks themselves (each validated under its
-            # own schema; declared here so the emitters' line literals
-            # resolve)
-            Field("roofline", "any"),
-            Field("loadgen_knee", "any"),
-            Field("mutation", "any"),
-            Field("ivf", "any"),
-            Field("pq", "any"),
-            Field("join", "any"),
-            Field("quality", "any"),
-            Field("multihost", "any"),
-            Field("campaign", "any"),
-            Field("sentinel", "any"),
-            Field("tuning", "any"),
-            # the hoisted keys (every Hoist dst is a declared line key)
-            Field("roofline_pct", "number", nullable=True),
-            Field("bound_class", "str", nullable=True),
-            Field("roofline_estimated", "bool", nullable=True),
-            Field("model_residual_pct", "number", nullable=True),
-            Field("knee_qps", "number", nullable=True),
-            Field("mutation_admitted_p99_ms", "number", nullable=True),
-            Field("ivf_qps", "number", nullable=True),
-            Field("bytes_streamed_ratio", "number", nullable=True),
-            Field("join_rows_per_s", "number", nullable=True),
-            Field("audit_recall_at_k", "number", nullable=True),
-            Field("multihost_hosts", "int", nullable=True),
-            Field("multihost_merge", "str", nullable=True),
-            Field("multihost_qps", "number", nullable=True),
-            Field("hosttier_sweeps", "int", nullable=True),
-            # soundness gate + recall provenance
-            Field("pallas_gate_ok", "bool", nullable=True),
-            Field("gate_note", "str", nullable=True),
-            Field("gate_queries", "int", nullable=True),
-            Field("gate_rows", "int", nullable=True),
-            Field("gate_stats", "dict", nullable=True),
-            Field("session_gate_ok", "bool", nullable=True,
-                  emit_note="stamped by the round-5 session driver "
-                            "(2026-07-31, deleted in PR 21); declared "
-                            "so history lines of that round sweep "
-                            "clean, no live emitter writes it"),
-            Field("recall_at_k", "number", nullable=True),
-            Field("recall_unverified", "bool", nullable=True),
-            Field("recall_below_one", "bool", nullable=True),
-            # run shape / environment
-            Field("compute_dtype", "str", nullable=True),
-            Field("metric_fn", "str", nullable=True),
-            Field("runs", "int", nullable=True),
-            Field("qps_std", "number", nullable=True),
-            Field("qps_labels_only", "number", nullable=True),
-            Field("mfu", "number", nullable=True),
-            Field("mfu_device", "number", nullable=True),
-            Field("mfu_reason", "str", nullable=True),
-            Field("peak_flops_assumed", "number", nullable=True),
-            Field("selectors", "dict", nullable=True),
-            Field("cpu_baseline_qps", "number", nullable=True),
-            Field("cpu_baseline_cached", "bool", nullable=True),
-            Field("cpu_queries", "int", nullable=True),
-            Field("cpu_per_query_s", "number", nullable=True),
-            Field("devices", "int", nullable=True),
-            Field("device_kind", "str", nullable=True),
-            Field("backend", "str", nullable=True),
-            Field("cpu_baseline_cache_file", "str", nullable=True),
-            Field("cpu_baseline_error", "str", nullable=True),
-            Field("batch", "int", nullable=True),
-            Field("train_tile", "int", nullable=True),
-            Field("pallas_knobs", "dict", nullable=True),
-            Field("approx_knobs", "dict", nullable=True),
-            Field("precision", "str", nullable=True),
-            Field("quant_bound_max", "number", nullable=True),
-            Field("quant_scales_dtype", "str", nullable=True),
-            Field("quant_bound_error", "str", nullable=True),
-            Field("error", "str", nullable=True),
-            # curation provenance (stamped by the refresher)
-            Field("measured_round", "int", nullable=True),
-            Field("measured_at_commit", "str", nullable=True),
-            Field("stale", "bool", nullable=True),
-        ),
-    ),
     # --- roofline -------------------------------------------------------
     BlockSchema(
         name="roofline",
-        block_path="roofline",
         doc="docs/PERF.md#Roofline model",
         validator="knn_tpu.obs.roofline:validate_block",
-        emitters=("knn_tpu/obs/roofline.py", "bench.py"),
+        emitters=("knn_tpu/obs/roofline.py",),
         fingerprints=(frozenset({"model_version", "terms"}),),
         version_field="model_version",
         version_ref=Ref(_RL, "MODEL_VERSION"),
         version_exact=False,
         not_dict_legacy="roofline block is {vtype}, not dict",
-        error_exempt="curation",
-        refusal_label="roofline",
-        curate=True,
-        sweep=True,
-        summary="roofline",
-        prepare="roofline_derive",
-        hoists=(
-            Hoist("roofline_pct", "roofline_pct"),
-            # the refresher pairs bound_class with a non-null pct;
-            # bench hoists it whenever the block names one
-            Hoist("bound_class", "bound_class", gate="roofline_pct",
-                  bench=False),
-            Hoist("bound_class", "bound_class", truthy=True,
-                  refresher=False),
-            Hoist("estimated", "roofline_estimated", truthy=True,
-                  refresher=False),
-        ),
-        curated=(Curated("roofline_pct", "higher", 5),),
         checks=(
             Field("model_version", "version", required=True,
                   legacy="missing/non-int model_version"),
@@ -993,7 +487,7 @@ CATALOG: Tuple[BlockSchema, ...] = (
                   legacy="terms.vpu_select.time_s missing or negative"),
             # the MODEL_VERSION-4 cross-host merge term: present only
             # on multi-host blocks, and then every field must hold —
-            # a malformed DCN claim would poison curated baselines
+            # a malformed DCN claim must not validate
             Field("terms.dcn", "dict",
                   legacy="terms.dcn is not a dict"),
             Field("terms.dcn.time_s", "number", required=True, ge=0,
@@ -1031,33 +525,17 @@ CATALOG: Tuple[BlockSchema, ...] = (
             Field("select_overlapped", "any"),
             Field("term_times_s", "any"),
             Field("term_times_calibrated_s", "any"),
-            Field("roofline_pct_e2e", "any"),
             Field("error", "any"),
-            Field("derived", "any",
-                  emit_note="stamped by the back-derivation hook as a "
-                            "dict() keyword (dict(block, derived=True))"
-                            ", never a key literal"),
         ),
     ),
     # --- calibration (nested under roofline) ----------------------------
     BlockSchema(
         name="calibration",
-        block_path="roofline.calibration",
         doc="docs/PERF.md#Calibration & measured ceilings",
         validator="knn_tpu.obs.calibrate:validate_calibration",
         emitters=("knn_tpu/obs/roofline.py", "knn_tpu/obs/calibrate.py"),
         fingerprints=(frozenset({"applied", "factors"}),),
         not_dict_legacy="calibration is {vtype}, not dict",
-        error_exempt="parent",
-        refusal_label="calibration",
-        curate=True,
-        sweep=True,
-        summary="calibration",
-        hoists=(
-            Hoist("model_residual_pct", "model_residual_pct",
-                  gate="applied", truthy=True, numeric=True),
-        ),
-        curated=(Curated("model_residual_pct", "lower", 7),),
         checks=(
             # an absent overlay must still be EXPLICIT: applied is a
             # bool, never missing-and-implied
@@ -1093,46 +571,9 @@ CATALOG: Tuple[BlockSchema, ...] = (
             Field("error", "any"),
         ),
     ),
-    # --- campaign --------------------------------------------------------
-    BlockSchema(
-        name="campaign",
-        block_path="campaign",
-        doc="docs/PERF.md#Calibration & measured ceilings",
-        validator="knn_tpu.obs.calibrate:validate_campaign_block",
-        emitters=("knn_tpu/campaign.py",),
-        fingerprints=(frozenset({"campaign_version", "stages"}),),
-        version_field="campaign_version",
-        version_ref=Ref("knn_tpu.campaign", "CAMPAIGN_VERSION"),
-        version_exact=False,
-        not_dict_legacy="campaign block is {vtype}, not dict",
-        refusal_label="campaign",
-        curate=True,
-        sweep=True,
-        checks=(
-            Field("campaign_version", "version", required=True,
-                  legacy="missing/non-int campaign_version"),
-            Field("arm", "any", required=True, truthy=True,
-                  legacy="missing arm name"),
-            Field("stages", "list", required=True, nonempty=True,
-                  element_style="campaign_stages",
-                  element_required=("stage", "status"),
-                  element_optional=("error", "winner", "winner_ms",
-                                    "cache_key", "rehearse_note",
-                                    "qps", "device_s", "source",
-                                    "model_residual_pct", "factors",
-                                    "store", "entry_key", "sentinel",
-                                    "artifact", "note", "gates",
-                                    "trace_dir", "events", "errors"),
-                  legacy="missing stages list"),
-            Field("rehearse", "bool", required=True,
-                  legacy="missing/non-bool rehearse flag"),
-            Field("round", "any"),
-        ),
-    ),
     # --- loadgen knee ----------------------------------------------------
     BlockSchema(
         name="loadgen_knee",
-        block_path="loadgen_knee",
         doc="docs/serving.md#Load generation, admission control & "
             "brownout",
         validator="knn_tpu.loadgen.knee:validate_knee_block",
@@ -1143,13 +584,7 @@ CATALOG: Tuple[BlockSchema, ...] = (
         version_ref=Ref("knn_tpu.loadgen.knee", "BLOCK_VERSION"),
         version_exact=True,
         not_dict_legacy="knee block must be a dict, got {vtype}",
-        error_exempt="validator",
-        refusal_label="loadgen_knee",
-        curate=True,
-        sweep=True,
-        summary="knee",
-        hoists=(Hoist("knee_qps", "knee_qps"),),
-        curated=(Curated("knee_qps", "higher", 6),),
+        error_exempt=True,
         checks=(
             Field("version", "version", required=True,
                   legacy="version must be {version}, got {value!r}"),
@@ -1177,27 +612,19 @@ CATALOG: Tuple[BlockSchema, ...] = (
     # --- mutation --------------------------------------------------------
     BlockSchema(
         name="mutation",
-        block_path="mutation",
         doc="docs/serving.md#The write path",
         validator="knn_tpu.index.artifact:validate_mutation_block",
-        emitters=("bench.py",),
         fingerprints=(frozenset({"mutation_version", "write_mix"}),),
         version_field="mutation_version",
         version_ref=Ref("knn_tpu.index.artifact", "MUTATION_VERSION"),
         version_exact=True,
         not_dict_legacy="mutation block must be a dict, got {vtype}",
-        error_exempt="validator",
-        refusal_label="mutation",
-        curate=True,
-        sweep=True,
-        summary="mutation",
+        error_exempt=True,
         missing_order=("mutation_version", "write_mix", "rate_qps",
                        "duration_s", "admitted_p99_ms", "compactions",
                        "epoch", "reads", "writes",
                        "slo_breach_transitions"),
         missing_legacy="missing {key!r}",
-        hoists=(Hoist("admitted_p99_ms", "mutation_admitted_p99_ms"),),
-        curated=(Curated("mutation_admitted_p99_ms", "lower", 8),),
         checks=(
             Field("mutation_version", "version", required=True,
                   legacy="mutation_version must be {version}, got "
@@ -1251,37 +678,18 @@ CATALOG: Tuple[BlockSchema, ...] = (
     # --- ivf -------------------------------------------------------------
     BlockSchema(
         name="ivf",
-        block_path="ivf",
         doc="docs/PERF.md#IVF tier & certified recall",
         validator="knn_tpu.ivf.artifact:validate_ivf_block",
-        emitters=("bench.py",),
         fingerprints=(frozenset({"ivf_version", "nprobe"}),),
         version_field="ivf_version",
         version_ref=Ref("knn_tpu.ivf.artifact", "IVF_VERSION"),
         version_exact=True,
         not_dict_legacy="ivf block must be a dict, got {vtype}",
-        error_exempt="validator",
-        refusal_label="ivf",
-        curate=True,
-        sweep=True,
-        summary="ivf",
+        error_exempt=True,
         missing_order=("ivf_version", "ncentroids", "nprobe", "queries",
                        "k", "probe_fraction", "recall_at_k",
                        "fallback_rate", "bytes_streamed_ratio", "qps"),
         missing_legacy="missing {key!r}",
-        hoists=(
-            Hoist("qps", "ivf_qps"),
-            Hoist("bytes_streamed_ratio", "bytes_streamed_ratio"),
-        ),
-        curated=(
-            Curated("recall_at_k", "higher", 9),
-            Curated("ivf_qps", "higher", 10),
-            # the compressed-tier headline: fraction of the brute-force
-            # byte stream actually touched — the number the PQ
-            # arm exists to shrink, so the sentinel baselines it
-            # lower-is-better
-            Curated("bytes_streamed_ratio", "lower", 11),
-        ),
         checks=(
             Field("ivf_version", "version", required=True,
                   legacy="ivf_version must be {version}, got "
@@ -1329,19 +737,14 @@ CATALOG: Tuple[BlockSchema, ...] = (
     # --- pq (codebook-geometry provenance of precision="pq" lines) -------
     BlockSchema(
         name="pq",
-        block_path="pq",
         doc="docs/PERF.md#Compressed tier: PQ",
         validator="knn_tpu.ops.pq_artifact:validate_pq_block",
-        emitters=("bench.py",),
         fingerprints=(frozenset({"pq_version", "dsub"}),),
         version_field="pq_version",
         version_ref=Ref("knn_tpu.ops.pq_artifact", "PQ_VERSION"),
         version_exact=True,
         not_dict_legacy="pq block must be a dict, got {vtype}",
-        error_exempt="validator",
-        refusal_label="pq",
-        curate=True,
-        sweep=True,
+        error_exempt=True,
         missing_order=("pq_version", "dsub", "ncodes", "nsub",
                        "lut_bytes", "bound_max", "queries"),
         missing_legacy="missing {key!r}",
@@ -1375,25 +778,12 @@ CATALOG: Tuple[BlockSchema, ...] = (
     # --- multihost -------------------------------------------------------
     BlockSchema(
         name="multihost",
-        block_path="multihost",
         doc="docs/PERF.md#Multi-host merge & host-RAM tier",
         validator="knn_tpu.parallel.crossover:validate_multihost_block",
-        emitters=("bench.py",),
         fingerprints=(frozenset({"hosts", "merge"}),
                       frozenset({"sweeps", "budget_bytes",
                                  "segment_rows"})),
         not_dict_legacy="multihost block is {vtype}, not dict",
-        refusal_label="multihost",
-        curate=True,
-        sweep=True,
-        summary="multihost",
-        hoists=(
-            Hoist("hosts", "multihost_hosts", truthy=True,
-                  bench=False),
-            Hoist("merge.dcn.strategy", "multihost_merge", truthy=True,
-                  bench=False),
-            Hoist("hosttier.sweeps", "hosttier_sweeps", truthy=True),
-        ),
         checks=(
             Field("hosts", "int", required=True, ge=1,
                   legacy="hosts {value!r} is not a positive int"),
@@ -1445,29 +835,19 @@ CATALOG: Tuple[BlockSchema, ...] = (
     # --- bulk kNN-join ---------------------------------------------------
     BlockSchema(
         name="join",
-        block_path="join",
         doc="docs/PERF.md#Bulk kNN-join (MODEL_VERSION 7)",
         validator="knn_tpu.join.artifact:validate_join_block",
-        emitters=("bench.py",),
         fingerprints=(frozenset({"join_version", "superblock_rows"}),),
         version_field="join_version",
         version_ref=Ref("knn_tpu.join.artifact", "JOIN_VERSION"),
         version_exact=True,
         not_dict_legacy="join block must be a dict, got {vtype}",
-        error_exempt="validator",
-        refusal_label="join",
-        curate=True,
-        sweep=True,
+        error_exempt=True,
         missing_order=("join_version", "mode", "rows", "k",
                        "superblock_rows", "depth", "order",
                        "superblocks", "db_segments", "dispatches",
                        "rows_per_s", "overlap_ratio"),
         missing_legacy="missing {key!r}",
-        hoists=(Hoist("rows_per_s", "join_rows_per_s"),),
-        # the join headline the sentinel baselines: offline rows/s,
-        # higher is better — the number the superblock amortization
-        # exists to raise
-        curated=(Curated("join_rows_per_s", "higher", 12),),
         checks=(
             Field("join_version", "version", required=True,
                   legacy="join_version must be {version}, got "
@@ -1521,30 +901,20 @@ CATALOG: Tuple[BlockSchema, ...] = (
     # --- quality (shadow audit) ------------------------------------------
     BlockSchema(
         name="quality",
-        block_path="quality",
         doc="docs/OBSERVABILITY.md#Quality observability",
-        emitters=("bench.py",),
         fingerprints=(frozenset({"quality_version",
                                  "audit_recall_at_k"}),),
         version_field="quality_version",
         version_ref=Ref("knn_tpu.obs.audit", "QUALITY_VERSION"),
         version_exact=True,
         not_dict_legacy="quality block must be a dict, got {vtype}",
-        error_exempt="validator",
-        refusal_label="quality",
-        curate=True,
-        sweep=True,
+        error_exempt=True,
         missing_order=("quality_version", "audit_rate",
                        "audit_sampled_requests",
                        "audit_replayed_queries",
                        "audit_deficient_queries",
                        "audit_dropped_records", "audit_recall_at_k"),
         missing_legacy="missing {key!r}",
-        hoists=(Hoist("audit_recall_at_k", "audit_recall_at_k"),),
-        # the quality headline the sentinel baselines: shadow-audited
-        # recall@k against the f64 exact oracle, higher is better —
-        # the number the whole audit pipeline exists to watch
-        curated=(Curated("audit_recall_at_k", "higher", 13),),
         checks=(
             Field("quality_version", "version", required=True,
                   legacy="quality_version must be {version}, got "
@@ -1584,17 +954,14 @@ CATALOG: Tuple[BlockSchema, ...] = (
     # --- fleet observability (cross-host merge) --------------------------
     BlockSchema(
         name="fleet",
-        block_path="fleet",
         doc="docs/OBSERVABILITY.md#Fleet observability",
-        emitters=("knn_tpu/obs/fleet.py", "bench.py"),
+        emitters=("knn_tpu/obs/fleet.py",),
         fingerprints=(frozenset({"fleet_version", "member_count"}),),
         version_field="fleet_version",
         version_ref=Ref("knn_tpu.obs.fleet", "FLEET_VERSION"),
         version_exact=True,
         not_dict_legacy="fleet block must be a dict, got {vtype}",
-        error_exempt="validator",
-        refusal_label="fleet",
-        sweep=True,
+        error_exempt=True,
         # the merged cross-host headline: how many members summed in,
         # how loudly partial the merge was, who the straggler is
         checks=(
@@ -1610,30 +977,12 @@ CATALOG: Tuple[BlockSchema, ...] = (
             Field("straggler_gap_s", "number", nullable=True, ge=0),
             Field("stitched_requests", "int", required=True, ge=0),
             Field("slo_breached", "int", required=True, ge=0),
-            Field("wall_s", "any"),
             Field("error", "any"),
-        ),
-    ),
-    # --- sentinel verdict ------------------------------------------------
-    BlockSchema(
-        name="sentinel",
-        block_path="sentinel",
-        doc="docs/OBSERVABILITY.md#Regression sentinel",
-        emitters=("knn_tpu/obs/sentinel.py", "bench.py"),
-        fingerprints=(frozenset({"verdict", "baseline_key"}),),
-        sweep=True,
-        checks=(
-            Field("verdict", "str", required=True,
-                  choices=SENTINEL_VERDICTS),
-            Field("baseline_key", "str", nullable=True),
-            Field("fields", "dict", nullable=True),
-            Field("error", "str", nullable=True),
         ),
     ),
     # --- tuning-cache entries ---------------------------------------------
     BlockSchema(
         name="tuning_cache_entry",
-        block_path="",
         doc="docs/PERF.md#Streaming kernel & autotuner",
         emitters=("knn_tpu/tuning/autotune.py",),
         fingerprints=(frozenset({"knobs", "winner", "timings_ms"}),),
@@ -1669,20 +1018,6 @@ CATALOG: Tuple[BlockSchema, ...] = (
             Field("trace_dir", "str", nullable=True),
             Field("cached", "bool", nullable=True),
             Field("cache_key", "str", nullable=True),
-        ),
-    ),
-    # --- MULTICHIP driver records -----------------------------------------
-    BlockSchema(
-        name="multichip_record",
-        block_path="",
-        doc="docs/ANALYSIS.md#The artifact-schema catalog",
-        emitters=(),
-        checks=(
-            Field("n_devices", "int", required=True, ge=1),
-            Field("rc", "int", required=True),
-            Field("ok", "bool", required=True),
-            Field("skipped", "bool", required=True),
-            Field("tail", "str", required=True, nullable=True),
         ),
     ),
 )

@@ -1,38 +1,26 @@
-"""``artifact-lockstep`` — the artifact pipeline in lockstep with its
+"""``artifact-lockstep`` — the artifact blocks in lockstep with their
 schema catalog (:mod:`knn_tpu.analysis.artifacts`).
 
-Six invariants over the catalog, each one a contract some PR used to
+Four invariants over the catalog, each one a contract some PR used to
 hand-check:
 
 1. **emitter keys resolve** — every string key an emitter writes into a
    cataloged block literal (a dict literal matching one of the
    schema's fingerprints, in one of its declared emitter files)
    resolves in that schema.  An emitted-but-undeclared key is invisible
-   to the validator, the refresher, and the sentinel — half-wired by
-   construction;
+   to the validator — half-wired by construction;
 2. **schema fields are emitted** — every declared field's leaf name
    appears in at least one emitter file, or carries a written
    ``emit_note`` justification (>= 10 chars, the suppression
    discipline).  The catalog can't rot into fiction;
-3. **refresher hoist lockstep** — ``scripts/refresh_bench_artifacts.py``
-   either speaks the catalog (imports ``knn_tpu.analysis.artifacts`` /
-   calls ``curate_line``) — in which case every declared hoist is
-   performed by construction — or names every refresher-scope hoist
-   key literally.  A hand-rolled refresher that drops a declared hoist
-   goes red;
-4. **sentinel curated lockstep** — ``knn_tpu/obs/sentinel.py`` derives
-   ``CURATED_FIELDS`` from ``artifacts.curated_fields()`` (the hand
-   list can't come back), or at minimum names every curated field;
-5. **version tokens** — every declared version token resolves to an
+3. **version tokens** — every declared version token resolves to an
    int constant and is consumed by exactly one schema, whose own field
    list declares it;
-6. **docs anchors** — every block type's ``doc`` anchor names a real
-   heading in a real doc file, and every hoist destination / curated
-   field is itself a declared ``bench_line`` key (hoists land on
-   cataloged ground).
+4. **docs anchors** — every block type's ``doc`` anchor names a real
+   heading in a real doc file.
 
-Checks 1–4 and 6 only run against files that exist under the lint root
-(fixture trees stay green); check 5 judges the catalog itself.  The
+Checks 1, 2 and 4 only run against files that exist under the lint root
+(fixture trees stay green); check 3 judges the catalog itself.  The
 catalog is read from the lint ROOT's copy when present
 (``Context.load_module``) so ``--root`` judges another checkout against
 ITS declarations.
@@ -48,8 +36,6 @@ from knn_tpu.analysis import artifacts as _session_artifacts
 from knn_tpu.analysis.core import Context, Finding, checker
 
 _CATALOG_REL = os.path.join("knn_tpu", "analysis", "artifacts.py")
-_REFRESHER_REL = os.path.join("scripts", "refresh_bench_artifacts.py")
-_SENTINEL_REL = os.path.join("knn_tpu", "obs", "sentinel.py")
 
 
 def _string_constants(tree: ast.Module) -> Set[str]:
@@ -71,35 +57,8 @@ def _dict_literals(tree: ast.Module):
                 yield node, keys
 
 
-def _calls_name(tree: ast.Module, name: str) -> bool:
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Call):
-            fn = node.func
-            if (getattr(fn, "id", None) or
-                    getattr(fn, "attr", None)) == name:
-                return True
-    return False
-
-
-def _imports_artifacts(tree: ast.Module) -> bool:
-    for node in ast.walk(tree):
-        if isinstance(node, ast.ImportFrom):
-            mod = node.module or ""
-            if mod.endswith("analysis.artifacts") or (
-                    mod.endswith("analysis")
-                    and any(a.name == "artifacts"
-                            for a in node.names)):
-                return True
-        if isinstance(node, ast.Import):
-            if any(a.name.endswith("analysis.artifacts")
-                   for a in node.names):
-                return True
-    return False
-
-
 @checker("artifact-lockstep",
-         "artifact-schema catalog <-> emitters <-> refresher hoists "
-         "<-> sentinel curated fields <-> docs")
+         "artifact-schema catalog <-> emitters <-> docs")
 def check_artifacts(ctx: Context) -> List[Finding]:
     arts = ctx.load_module(_CATALOG_REL, _session_artifacts)
     findings: List[Finding] = []
@@ -110,7 +69,7 @@ def check_artifacts(ctx: Context) -> List[Finding]:
             checker="artifact-lockstep", path=path, line=0,
             message=msg, symbol=symbol, fix_hint=fix))
 
-    # --- 5. version tokens: unique, resolvable, self-declared ----------
+    # --- 3. version tokens: unique, resolvable, self-declared ----------
     seen_versions = {}
     for schema in arts.CATALOG:
         if not schema.version_field:
@@ -167,20 +126,15 @@ def check_artifacts(ctx: Context) -> List[Finding]:
                     f"emitter writes key {key!r} into a "
                     f"{'/'.join(s.name for s in owners)} block "
                     f"literal (line {node.lineno}), but no artifact "
-                    f"schema declares it — the validator, refresher, "
-                    f"and sentinel are all blind to it", key,
+                    f"schema declares it — the validator is blind "
+                    f"to it", key,
                     fix="declare the field in the block's schema "
                         "entry (knn_tpu/analysis/artifacts.py)")
 
     # --- 2. every schema field emitted somewhere, or justified ---------
     # judged only when EVERY declared emitter file is present under the
     # lint root — a fixture tree carrying one emitter must not condemn
-    # fields the absent emitters own.  Hoist destinations are emitted
-    # BY the catalog-driven hoist loops themselves (check 3 proves the
-    # refresher runs them), so they count as emitted by construction —
-    # without listing the catalog as its own emitter, which would make
-    # this check vacuous (every declared field is a string in it).
-    hoist_dsts = {h.dst for s in arts.CATALOG for h in s.hoists}
+    # fields the absent emitters own.
     for schema in arts.CATALOG:
         present = [rel for rel in schema.emitters if rel in strings_of]
         complete = bool(schema.emitters) and \
@@ -197,8 +151,6 @@ def check_artifacts(ctx: Context) -> List[Finding]:
                         f"written justification (>= 10 chars)",
                         f.path)
                 continue
-            if f.leaf in hoist_dsts:
-                continue
             if complete and f.leaf not in emitted:
                 err(_CATALOG_REL,
                     f"schema {schema.name}: field {f.path!r} is "
@@ -208,46 +160,7 @@ def check_artifacts(ctx: Context) -> List[Finding]:
                     fix="delete the field, or set emit_note with a "
                         "written justification")
 
-    # --- 3. refresher performs every declared refresher hoist ----------
-    if ctx.exists(_REFRESHER_REL):
-        tree = ctx.parse(_REFRESHER_REL)
-        if tree is not None:
-            catalog_driven = _imports_artifacts(tree) or \
-                _calls_name(tree, "curate_line")
-            if not catalog_driven:
-                literals = _string_constants(tree)
-                for schema in arts.CATALOG:
-                    for h in schema.hoists:
-                        if h.refresher and h.dst not in literals:
-                            err(_REFRESHER_REL,
-                                f"declared hoist {h.dst!r} "
-                                f"({schema.name}.{h.src}) is not "
-                                f"performed by the refresher — the "
-                                f"curated line silently loses a "
-                                f"sentinel baseline field", h.dst,
-                                fix="drive the refresher through "
-                                    "artifacts.curate_line (or hoist "
-                                    "the key explicitly)")
-
-    # --- 4. sentinel derives (or at least names) the curated fields ----
-    if ctx.exists(_SENTINEL_REL):
-        tree = ctx.parse(_SENTINEL_REL)
-        if tree is not None:
-            derived = _calls_name(tree, "curated_fields")
-            if not derived:
-                literals = _string_constants(tree)
-                for fname, _direction in arts.curated_fields():
-                    if fname not in literals:
-                        err(_SENTINEL_REL,
-                            f"curated field {fname!r} is absent from "
-                            f"the sentinel — regressions in it are "
-                            f"never baselined", fname,
-                            fix="derive CURATED_FIELDS from "
-                                "knn_tpu.analysis.artifacts."
-                                "curated_fields()")
-
-    # --- 6. docs anchors + hoists/curated land on cataloged keys -------
-    bench_known = arts.known_keys("bench_line")
+    # --- 4. docs anchors ------------------------------------------------
     for schema in arts.CATALOG:
         doc_file, anchor = schema.doc.split("#", 1)
         if ctx.exists(doc_file):
@@ -261,16 +174,4 @@ def check_artifacts(ctx: Context) -> List[Finding]:
                     f"{schema.doc!r} names no heading in {doc_file} — "
                     f"every block type must keep its documentation "
                     f"anchor", schema.name)
-        for h in schema.hoists:
-            if h.dst not in bench_known:
-                err(_CATALOG_REL,
-                    f"schema {schema.name}: hoist destination "
-                    f"{h.dst!r} is not a declared bench_line key — "
-                    f"hoists must land on cataloged ground", h.dst)
-        for c in schema.curated:
-            if c.field not in bench_known:
-                err(_CATALOG_REL,
-                    f"schema {schema.name}: curated field "
-                    f"{c.field!r} is not a declared bench_line key",
-                    c.field)
     return findings

@@ -3,7 +3,7 @@ consumed, and test-isolated.
 
 Four invariants over the catalog (knn_tpu.analysis.switches):
 
-1. every ``KNN_TPU_*``/``KNN_BENCH_*`` string literal in source is a
+1. every ``KNN_TPU_*`` string literal in source is a
    cataloged switch (or a declared family prefix — ``startswith``
    scans); an undeclared switch can't ship half-wired;
 2. every cataloged switch appears in the docs (``docs/*.md`` or
@@ -140,7 +140,7 @@ def check_switches(ctx: Context) -> List[Finding]:
             if sw.lookup(token) is not None:
                 continue
             # docs may shorten a group of switches to a prefix token
-            # (e.g. KNN_BENCH_SERVING_...) — fine while it prefixes
+            # (e.g. KNN_TPU_ADMISSION_...) — fine while it prefixes
             # real catalog rows
             if token.endswith("_") and any(
                     s.name.startswith(token) for s in sw.SWITCHES):
@@ -157,9 +157,9 @@ def check_switches(ctx: Context) -> List[Finding]:
     # serving/admission.py read their whole family wholesale
     # (``{k for k in env if k.startswith(ENV_PREFIX)}`` + computed
     # member names), so the prefix literal is the real env read.
-    # RESERVED families (the KNN_TPU_/KNN_BENCH_ root namespaces,
-    # scanned wholesale by the flight recorder and conftest) never
-    # count — through them, every switch would read as consumed and
+    # RESERVED families (the KNN_TPU_ root namespace, scanned
+    # wholesale by the flight recorder and conftest) never count —
+    # through them, every switch would read as consumed and
     # the invariant would be vacuous.
     if any(ctx.exists(r) for r in ctx.source_roots):
         family_prefixes_in_code = set()

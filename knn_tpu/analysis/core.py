@@ -44,7 +44,7 @@ SEVERITIES = ("error", "warning")
 #: tests/ is deliberately absent: negative tests seed bad names and
 #: uncataloged switches on purpose (the same exemption
 #: lint_metric_names carried since PR 4).
-SOURCE_ROOTS = ("knn_tpu", "scripts", "bench.py", "__graft_entry__.py")
+SOURCE_ROOTS = ("knn_tpu", "scripts", "__graft_entry__.py")
 
 #: default suppression-file location, relative to the repo root
 SUPPRESSIONS_PATH = os.path.join("knn_tpu", "analysis", "suppressions.json")

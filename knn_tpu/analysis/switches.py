@@ -1,10 +1,10 @@
-"""The env-switch catalog — ONE jax-free home for every ``KNN_TPU_*`` /
-``KNN_BENCH_*`` environment switch the repo reads.
+"""The env-switch catalog — ONE jax-free home for every ``KNN_TPU_*``
+environment switch the repo reads.
 
 The metric catalog (knn_tpu.obs.names) proved the pattern: declare every
 name centrally, lint source/docs/tests against the declaration, and an
 undeclared name can never ship half-wired.  Switches had no such home —
-PR 9 left ~65 switch literals scattered over bench/serving/obs/tuning
+PR 9 left ~65 switch literals scattered over serving/obs/tuning
 with only 13 isolated by ``tests/conftest.py``, so an ambient developer
 shell could silently steer most of the suite.  This catalog closes
 that: every switch is declared here with its consumer, kind, and doc
@@ -29,7 +29,7 @@ from typing import Dict, Iterable, List, Mapping, Optional, Tuple
 
 #: the shape every switch name (and family prefix) must have; the
 #: checker also uses it to find switch-shaped literals in source
-SWITCH_RE = re.compile(r"^KNN_(TPU|BENCH)_[A-Z0-9_]*$")
+SWITCH_RE = re.compile(r"^KNN_TPU_[A-Z0-9_]*$")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -72,9 +72,6 @@ SWITCHES: Tuple[Switch, ...] = (
        "every member into postmortem bundles, and conftest scrubs any "
        "ambient member before the suite runs.", family=True,
        reserved=True),
-    _s("KNN_BENCH_", "family", "bench.py", _PERF,
-       "Root bench-switch namespace (same capture/scrub contract).",
-       family=True, reserved=True),
     # --- telemetry / obs (knn_tpu.obs) ---------------------------------
     _s("KNN_TPU_OBS", "flag", "knn_tpu/obs/registry.py", _OBS,
        "0/false/off disables the telemetry subsystem (default on)."),
@@ -121,19 +118,6 @@ SWITCHES: Tuple[Switch, ...] = (
        "Calibration store JSON: per-term roofline scale factors "
        "reconciled from measured device time (atomic writes, "
        "model-version-token keys); unset = analytic model only."),
-    # --- measured-ceiling campaign (knn_tpu.campaign) ------------------
-    _s("KNN_TPU_CAMPAIGN_", "family", "knn_tpu/campaign.py", _PERF,
-       "Measured-ceiling campaign knob family (cli campaign); "
-       "namespace scrubbed by conftest.", family=True, reserved=True),
-    _s("KNN_TPU_CAMPAIGN_DIR", "path", "knn_tpu/campaign.py", _PERF,
-       "Campaign artifact directory (one validated JSONL per arm; "
-       "default artifacts/campaign)."),
-    _s("KNN_TPU_CAMPAIGN_ARMS", "spec", "knn_tpu/campaign.py", _PERF,
-       "Comma list of campaign arms to run (bf16x3_tiled, "
-       "bf16x3_streaming, int8_streaming, int8_fused)."),
-    _s("KNN_TPU_CAMPAIGN_ROUND", "int", "knn_tpu/campaign.py", _PERF,
-       "Measurement-round stamp carried into campaign artifact "
-       "provenance."),
     # --- tuning (knn_tpu.tuning) ---------------------------------------
     _s("KNN_TPU_TUNE_CACHE", "path", "knn_tpu/tuning/cache.py", _PERF,
        "Autotuner winner-cache file (default "
@@ -245,132 +229,6 @@ SWITCHES: Tuple[Switch, ...] = (
     _s("KNN_TPU_LOADGEN_", "family", "knn_tpu/loadgen/", _SERVING,
        "Reserved loadgen namespace — scrubbed by conftest so future "
        "knobs are isolated from day one.", family=True, reserved=True),
-    # --- bench.py: problem shape & run shape ---------------------------
-    _s("KNN_BENCH_CONFIG", "str", "bench.py", _PERF,
-       "Named benchmark config: sift1m (default) | glove | gist1m."),
-    _s("KNN_BENCH_MODES", "spec", "bench.py", _PERF,
-       "Comma list of modes to run (exact, certified_approx, "
-       "certified_pallas, serving, knee, multihost, mutation, ivf, "
-       "join)."),
-    _s("KNN_BENCH_MULTIHOST_HOSTS", "int", "bench.py", _PERF,
-       "Host-axis size of the multihost mode's hierarchical mesh "
-       "(default 2)."),
-    _s("KNN_BENCH_MULTIHOST_SWEEPS", "int", "bench.py", _PERF,
-       "Target host-RAM tier sweep count of the multihost mode's "
-       "budget-forced stream (default 4)."),
-    _s("KNN_BENCH_RUNS", "int", "bench.py", _PERF,
-       "Timed repetitions per mode (default 5)."),
-    _s("KNN_BENCH_N", "int", "bench.py", _PERF, "Database rows."),
-    _s("KNN_BENCH_DIM", "int", "bench.py", _PERF, "Feature dim."),
-    _s("KNN_BENCH_K", "int", "bench.py", _PERF, "Neighbor count."),
-    _s("KNN_BENCH_METRIC", "str", "bench.py", _PERF,
-       "Distance metric of the synthetic config."),
-    _s("KNN_BENCH_NQ", "int", "bench.py", _PERF, "Query count."),
-    _s("KNN_BENCH_BATCH", "int", "bench.py", _PERF,
-       "Queries per device step."),
-    _s("KNN_BENCH_TILE", "int", "bench.py", _PERF,
-       "HBM train-tile rows for the streamed distance matrix."),
-    _s("KNN_BENCH_CPU_QUERIES", "int", "bench.py", _PERF,
-       "Query count of the CPU-oracle pass."),
-    _s("KNN_BENCH_MARGIN", "int", "bench.py", _PERF,
-       "Certified-mode candidate margin."),
-    _s("KNN_BENCH_DTYPE", "str", "bench.py", _PERF,
-       "Placement compute dtype (bfloat16 | float32)."),
-    # --- bench.py: environment/bring-up --------------------------------
-    _s("KNN_BENCH_PLATFORM", "str", "bench.py", _PERF,
-       "The JAX platform to run on; unset means TPU, and a run that "
-       "finds another backend fails (cpu is what the CPU tests ask "
-       "for)."),
-    _s("KNN_BENCH_PEAK_FLOPS", "float", "bench.py", _PERF,
-       "Override the per-chip peak FLOP/s used for MFU."),
-    _s("KNN_BENCH_CPU_CACHE", "flag", "bench.py", _PERF,
-       "0 forces a fresh CPU-oracle measurement instead of the cached "
-       "one."),
-    _s("KNN_BENCH_GATE", "flag", "bench.py", _PERF,
-       "0 skips the exactness gate on huge dims."),
-    _s("KNN_BENCH_VERBOSE", "flag", "bench.py", _PERF,
-       "1 prints stage progress on stderr."),
-    _s("KNN_BENCH_TRACE", "path", "bench.py", _PERF,
-       "Write a jax.profiler trace of one extra per-mode run here."),
-    _s("KNN_BENCH_TUNE_CACHE", "path", "bench.py", _PERF,
-       "Autotuner cache the bench resolves knobs through."),
-    _s("KNN_BENCH_OBS_OVERHEAD", "flag", "bench.py", _PERF,
-       "1 A/Bs the serving sweep with telemetry off/on and emits "
-       "obs_overhead_pct."),
-    # --- bench.py: XLA-selector knobs ----------------------------------
-    _s("KNN_BENCH_APPROX_RT", "float", "bench.py", _PERF,
-       "ApproxTopK recall target of the certified_approx mode."),
-    _s("KNN_BENCH_APPROX_MARGIN", "int", "bench.py", _PERF,
-       "Margin override of the certified_approx mode."),
-    # --- bench.py: pallas knob overrides (unset = tuned/default) -------
-    _s("KNN_BENCH_PALLAS_", "family", "bench.py", _PERF,
-       "Pallas knob-override family; unset members resolve through the "
-       "autotuner cache.", family=True),
-    _s("KNN_BENCH_PALLAS_PRECISION", "str", "bench.py", _PERF,
-       "Kernel matmul precision (bf16x3 | bf16x3f | int8 | highest)."),
-    _s("KNN_BENCH_PALLAS_TILE", "int", "bench.py", _PERF,
-       "Kernel db tile rows (tile_n)."),
-    _s("KNN_BENCH_PALLAS_SURVIVORS", "int", "bench.py", _PERF,
-       "Per-bin survivor count."),
-    _s("KNN_BENCH_PALLAS_BLOCK_Q", "int", "bench.py", _PERF,
-       "Query block rows (block_q)."),
-    _s("KNN_BENCH_PALLAS_FINAL", "str", "bench.py", _PERF,
-       "Final select: exact | approx."),
-    _s("KNN_BENCH_PALLAS_FINAL_RT", "float", "bench.py", _PERF,
-       "Approx final-select recall target."),
-    _s("KNN_BENCH_PALLAS_GRID", "str", "bench.py", _PERF,
-       "Grid order: query_major | db_major."),
-    _s("KNN_BENCH_PALLAS_KERNEL", "str", "bench.py", _PERF,
-       "Db-streaming strategy: tiled | streaming | fused."),
-    _s("KNN_BENCH_PALLAS_BATCH", "int", "bench.py", _PERF,
-       "Queries per kernel launch in the pallas mode."),
-    # --- bench.py: serving sweep ---------------------------------------
-    _s("KNN_BENCH_SERVING_REQUESTS", "int", "bench.py", _PERF,
-       "Replayed request count of the serving mode."),
-    _s("KNN_BENCH_SERVING_DEPTH", "int", "bench.py", _PERF,
-       "Dispatch-ahead depth of the serving mode."),
-    _s("KNN_BENCH_SERVING_MIN_BUCKET", "int", "bench.py", _PERF,
-       "Smallest bucket rung of the serving mode's ladder."),
-    # --- bench.py: mutation sweep (opt-in mutation mode) ---------------
-    _s("KNN_BENCH_MUTATION_", "family", "bench.py", _INDEX,
-       "Mutation-sweep knob family of the opt-in mutation mode.",
-       family=True),
-    _s("KNN_BENCH_MUTATION_RATE", "float", "bench.py", _INDEX,
-       "Offered request rate (req/s) of the mixed read+write "
-       "scenario."),
-    _s("KNN_BENCH_MUTATION_SECONDS", "float", "bench.py", _INDEX,
-       "Duration of the mixed-traffic run."),
-    _s("KNN_BENCH_MUTATION_WRITE_FRACTION", "float", "bench.py",
-       _INDEX, "Fraction of scheduled requests that are writes "
-       "(split between inserts and deletes)."),
-    # --- bench.py: knee sweep ------------------------------------------
-    _s("KNN_BENCH_KNEE_", "family", "bench.py", _PERF,
-       "Knee-sweep knob family of the opt-in knee mode.", family=True),
-    _s("KNN_BENCH_KNEE_RATES", "spec", "bench.py", _PERF,
-       "Offered-rate ladder, comma-separated q/s."),
-    _s("KNN_BENCH_KNEE_STEP_S", "float", "bench.py", _PERF,
-       "Seconds per rate step."),
-    _s("KNN_BENCH_KNEE_SLO_MS", "float", "bench.py", _PERF,
-       "Admitted-p99 bound defining the knee."),
-    _s("KNN_BENCH_KNEE_TENANTS", "spec", "bench.py", _PERF,
-       "Tenant mix spec, name[:weight[:priority]],..."),
-    _s("KNN_BENCH_KNEE_SEED", "int", "bench.py", _PERF,
-       "Workload-schedule seed."),
-    # --- bench.py: bulk kNN-join sweep (opt-in join mode) --------------
-    _s("KNN_BENCH_JOIN_", "family", "bench.py", _PERF,
-       "Join-sweep knob family of the opt-in join mode.", family=True),
-    _s("KNN_BENCH_JOIN_ROWS", "int", "bench.py", _PERF,
-       "Query rows of the join line's host-resident set A (0 = sized "
-       "from NQ/BATCH)."),
-    _s("KNN_BENCH_JOIN_SUPERBLOCK", "int", "bench.py", _PERF,
-       "Superblock rows of the join sweep (0 = the engine's "
-       "resolution ladder)."),
-    _s("KNN_BENCH_JOIN_DEPTH", "int", "bench.py", _PERF,
-       "Dispatch-ahead depth of the join sweep (default 2)."),
-    # --- bench.py: shadow-audit replay (opt-in quality mode) -----------
-    _s("KNN_BENCH_QUALITY_REQUESTS", "int", "bench.py", _PERF,
-       "Serving requests of the opt-in quality mode's shadow-audit "
-       "replay (default 8; each pays one full f64 oracle scan)."),
 )
 
 #: name -> Switch for exact lookups
@@ -417,7 +275,7 @@ def isolation_names(environ: Optional[Mapping[str, str]] = None
     before the suite runs: every concrete cataloged switch with
     ``isolate=True``, plus any AMBIENT variable (from ``environ``)
     under an isolated family prefix — so a developer shell's
-    ``KNN_BENCH_PALLAS_WHATEVER=...`` is scrubbed even before it gets
+    ``KNN_TPU_IVF_WHATEVER=...`` is scrubbed even before it gets
     its own catalog row.  Generated, never hand-listed: a new catalog
     row is isolated on the next test run with zero conftest edits."""
     names = [s.name for s in SWITCHES if s.isolate and not s.family]
@@ -432,4 +290,4 @@ def isolation_names(environ: Optional[Mapping[str, str]] = None
 def tokens_in_source(text: str) -> Iterable[str]:
     """Every switch-shaped token in ``text`` (used by the checker over
     docs; source literals go through the AST instead)."""
-    return re.findall(r"\bKNN_(?:TPU|BENCH)_[A-Z0-9_]*\b", text)
+    return re.findall(r"\bKNN_TPU_[A-Z0-9_]*\b", text)

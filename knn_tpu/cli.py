@@ -29,9 +29,8 @@ runs the offline bulk kNN-join (knn_tpu.join): every row of a
 host-resident query set against the corpus through the double-buffered
 superblock stream (query h2d overlapped under device compute), or the
 certified per-superblock loop; prints plan + measured stats (rows/s,
-overlap_ratio, superblock/segment/dispatch counts) as one JSON line —
-the CLI face of bench.py's ``join`` mode (docs/PERF.md "Bulk kNN-join
-(MODEL_VERSION 7)").
+overlap_ratio, superblock/segment/dispatch counts) as one JSON line
+(docs/PERF.md "Bulk kNN-join (MODEL_VERSION 7)").
 
     python -m knn_tpu.cli metrics --port 9100
     python -m knn_tpu.cli metrics --snapshot /path/run_metrics.json --format prom
@@ -102,21 +101,6 @@ bundle (``KNN_TPU_POSTMORTEM_DIR``), a JSONL event log (the rotated
 ``/waterfallz`` endpoint.  Jax-free by construction
 (docs/OBSERVABILITY.md "Waterfalls & exemplars").
 
-    python -m knn_tpu.cli campaign --rehearse
-    python -m knn_tpu.cli campaign --round 6 --arms int8_fused,int8_streaming
-
-runs the measured-ceiling campaign (knn_tpu.campaign — ROADMAP open
-item 1 as a push-button loop): per arm, flip the on-hardware gates,
-autotune with roofline+VMEM pruning live, bench with device-trace
-capture, parse the trace (knn_tpu.obs.traceread), reconcile measured
-device time against the roofline model's terms, persist per-term
-calibration factors (knn_tpu.obs.calibrate, `KNN_TPU_CALIBRATION`),
-and write one validated campaign JSONL artifact per arm.
-``--rehearse`` runs the identical loop on CPU against host-phase
-timings and the checked-in trace fixture — the tier-1-testable proof
-of the full capture→parse→reconcile→calibrate→curate pipeline
-(docs/PERF.md "Calibration & measured ceilings").
-
     python -m knn_tpu.cli lint [--json] [--checker NAME]
 
 runs the repo-native static-analysis suite (knn_tpu.analysis,
@@ -137,8 +121,7 @@ Poisson/bursty multi-tenant workload stepped through increasing rates
 against the synthetic single-server model (jax-free) or a freshly
 built serving stack, printing the latency-vs-throughput knee artifact
 (rate steps, admitted p50/p95/p99, shed fraction, detected knee q/s)
-as one trailing JSON line — the same block bench.py's ``knee`` mode
-embeds and ``refresh_bench_artifacts.py`` curates.  Admission flags
+as one trailing JSON line.  Admission flags
 (``--max-depth``/``--shed``/``--quota``) exercise the brownout
 controls (docs/serving.md).
 
@@ -1099,7 +1082,7 @@ def run_loadgen(args: argparse.Namespace) -> int:
     """The `loadgen` subcommand: a knee sweep (or single replay run)
     against the synthetic model or a freshly built serving stack,
     printing a human summary plus ONE trailing JSON line (the knee
-    artifact — the same block bench.py's knee mode embeds)."""
+    artifact)."""
     import json
 
     import numpy as np
@@ -1345,103 +1328,6 @@ def _run_index_selftest(args: argparse.Namespace) -> int:
     return 0 if out["ok"] else 1
 
 
-def build_campaign_parser() -> argparse.ArgumentParser:
-    from knn_tpu.campaign import ARM_KNOBS, DEFAULT_ARMS
-
-    p = argparse.ArgumentParser(
-        prog="knn_tpu campaign",
-        description="Run the measured-ceiling campaign "
-        "(knn_tpu.campaign): per arm — gates, autotune (roofline+VMEM "
-        "pruning live), bench with trace capture, trace parse, "
-        "reconcile against the roofline terms, persist calibration "
-        "factors, curate one validated JSONL artifact.  --rehearse "
-        "runs the identical loop on CPU (host-phase timings + the "
-        "checked-in trace fixture) without a TPU.",
-    )
-    p.add_argument("--rehearse", action="store_true",
-                   help="CPU rehearsal: tiny synthetic shapes, "
-                   "host-phase timings, fixture trace parse — the "
-                   "tier-1-testable full loop")
-    p.add_argument("--arms", default=None, metavar="A1,A2,...",
-                   help=f"arms to run (default: "
-                   f"{','.join(DEFAULT_ARMS)} on hardware, the "
-                   f"cheapest arm in rehearsal); known: "
-                   f"{', '.join(sorted(ARM_KNOBS))}")
-    p.add_argument("--round", type=int, default=None, dest="round_no",
-                   help="measurement-round stamp for artifact "
-                   "provenance ($KNN_TPU_CAMPAIGN_ROUND equivalent)")
-    p.add_argument("--out", default=None, metavar="DIR",
-                   help="artifact directory (default: "
-                   "$KNN_TPU_CAMPAIGN_DIR or artifacts/campaign)")
-    p.add_argument("--seed", type=int, default=0,
-                   help="synthetic-data seed (rehearse)")
-    p.add_argument("--grid", default="quick",
-                   choices=("quick", "standard", "full"),
-                   help="autotuner grid level (hardware arms)")
-    p.add_argument("--trace-fixture", default=None, metavar="PATH",
-                   help="trace-viewer artifact the rehearse capture "
-                   "stage parses (default: the checked-in "
-                   "tests/fixtures/minimal.trace.json.gz)")
-    p.add_argument("--calibration", default=None, metavar="PATH",
-                   help="calibration store file "
-                   "($KNN_TPU_CALIBRATION equivalent; default: "
-                   "<out>/calibration.json)")
-    p.add_argument("--json", action="store_true",
-                   help="print the raw summary JSON only")
-    p.add_argument("--verbose", action="store_true",
-                   help="stage progress on stderr")
-    p.add_argument("--cpu-devices", type=int, default=None,
-                   metavar="N",
-                   help="force an N-virtual-device CPU backend")
-    return p
-
-
-def run_campaign_cmd(args: argparse.Namespace) -> int:
-    """The `campaign` subcommand: the stage loop per arm, a
-    human-readable per-arm summary, and ONE trailing JSON line (the
-    campaign summary — artifact paths + per-arm outcomes).  Exit 0
-    when every arm completed green, 1 otherwise."""
-    import json
-    import os
-
-    from knn_tpu import campaign
-
-    if args.calibration:
-        os.environ["KNN_TPU_CALIBRATION"] = args.calibration
-    arms = ([a.strip() for a in args.arms.split(",") if a.strip()]
-            if args.arms else None)
-    try:
-        summary = campaign.run_campaign(
-            rehearse=args.rehearse, arms=arms, out_dir=args.out,
-            round_no=args.round_no, seed=args.seed,
-            trace_fixture=args.trace_fixture, grid_level=args.grid,
-            verbose=args.verbose)
-    except ValueError as e:  # unknown arm / bad env spec
-        print(f"campaign: {e}", file=sys.stderr)
-        return 2
-    compact = {k: summary[k] for k in (
-        "campaign_version", "rehearse", "round", "out_dir", "arms",
-        "ok")}
-    if args.json:
-        print(json.dumps(compact, indent=1, sort_keys=True))
-        return 0 if summary["ok"] else 1
-    for r in summary["results"]:
-        line = r.get("line") or {}
-        att = line.get("roofline") or {}
-        cal = att.get("calibration") or {}
-        print(f"arm {r['arm']}: {'OK' if r['ok'] else 'FAILED'}  "
-              f"measured={line.get('device_phase_qps')} q/s  "
-              f"ceiling={att.get('ceiling_qps')} "
-              f"(analytic {att.get('ceiling_qps_analytic')})  "
-              f"calibrated={cal.get('applied')}  "
-              f"model_residual={line.get('model_residual_pct')}%  "
-              f"-> {r.get('artifact')}")
-        for err in r.get("errors") or []:
-            print(f"  error: {err}", file=sys.stderr)
-    print(json.dumps(compact))
-    return 0 if summary["ok"] else 1
-
-
 def build_lint_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="knn_tpu lint",
@@ -1572,10 +1458,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         return run_roofline(build_roofline_parser().parse_args(argv[1:]))
     if argv[:1] == ["waterfall"]:
         return run_waterfall(build_waterfall_parser().parse_args(argv[1:]))
-    if argv[:1] == ["campaign"]:
-        cargs = build_campaign_parser().parse_args(argv[1:])
-        _configure_backend(cargs.cpu_devices)
-        return run_campaign_cmd(cargs)
     if argv[:1] == ["loadgen"]:
         largs = build_loadgen_parser().parse_args(argv[1:])
         _configure_backend(largs.cpu_devices)

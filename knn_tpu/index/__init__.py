@@ -6,7 +6,7 @@ Two layers:
 
 - :mod:`~knn_tpu.index.artifact` — jax-free: the error vocabulary
   (:class:`MutationUnsupportedError`, :class:`MutationBudgetError`) and
-  the ``mutation`` bench-artifact validator the refresher/sentinel run;
+  the ``mutation`` artifact-block validator;
 - :mod:`~knn_tpu.index.mutable` — :class:`MutableIndex` (insert /
   delete / compact / search / search_certified over a ``ShardedKNN``
   placement + a bucket-laddered delta tail) and
@@ -14,7 +14,7 @@ Two layers:
   frontend with writes as a first-class op).
 
 ``MutableIndex``/``MutableServingEngine`` import JAX, so they resolve
-LAZILY here: the artifact refresher and the doctor CLI can import
+LAZILY here: the doctor CLI can import
 ``knn_tpu.index`` without paying (or breaking on) a backend init.
 """
 

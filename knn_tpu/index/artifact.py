@@ -1,22 +1,21 @@
 """Jax-free pieces of the mutable-index subsystem: the error vocabulary
-and the ``mutation`` bench-artifact validator.
+and the ``mutation`` artifact-block validator.
 
 These live apart from :mod:`knn_tpu.index.mutable` (which imports JAX at
-module load) so the artifact refresher, the perf sentinel, and the
+module load) so a jax-free reader and the
 multi-host refusal path can import them without paying — or breaking on
 — a backend init.  Same split as ``loadgen.knee`` / ``obs.roofline``:
-whatever validates curated artifacts must run on the box that curates
-them, not only the one with the accelerator.
+whatever validates artifacts must run on a box without the
+accelerator too.
 """
 
 from __future__ import annotations
 
 from typing import List
 
-#: version stamp of the ``mutation`` bench block (bench.py's opt-in
-#: mutation mode); bump on any schema change so the refresher refuses
-#: half-migrated lines instead of hoisting garbage — the version token
-#: the artifact-schema catalog's ``mutation`` entry consumes
+#: version stamp of the ``mutation`` block; bump on any schema change
+#: so a half-migrated block is refused — the version token the
+#: artifact-schema catalog's ``mutation`` entry consumes
 MUTATION_VERSION = 1
 
 
@@ -26,8 +25,7 @@ def _required_fields():
     return required_keys("mutation")
 
 
-#: fields every valid mutation block must carry (the refusal list the
-#: refresher prints); ``admitted_p99_ms`` may be null (an honest "no
+#: fields every valid mutation block must carry; ``admitted_p99_ms`` may be null (an honest "no
 #: admitted reads completed" beats a fabricated number) — DERIVED from
 #: the artifact-schema catalog (knn_tpu.analysis.artifacts), the one
 #: declaration the validator and the lockstep checker both read
@@ -52,11 +50,10 @@ class MutationBudgetError(RuntimeError):
 
 
 def validate_mutation_block(block) -> List[str]:
-    """Structural validation the artifact refresher runs before curating
-    a line carrying a ``mutation`` block: returns the list of
+    """Structural validation of a ``mutation`` block: returns the list of
     violations (empty = valid).  Blocks that recorded their own failure
     (an ``error`` key) are exempt — an honest error field beats a
-    refused line (the loadgen_knee discipline).  A shim over the
+    refused block (the loadgen_knee discipline).  A shim over the
     artifact-schema catalog (:mod:`knn_tpu.analysis.artifacts`, the
     ``mutation`` entry) with the legacy error strings byte-identical."""
     from knn_tpu.analysis.artifacts import validate

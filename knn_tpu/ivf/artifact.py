@@ -1,22 +1,20 @@
 """Jax-free pieces of the IVF subsystem: the version token and the
-``ivf`` bench-artifact validator.
+``ivf`` artifact-block validator.
 
 These live apart from :mod:`knn_tpu.ivf.index` (which imports JAX at
-module load) so the artifact refresher and the perf sentinel can import
+module load) so a jax-free reader can import
 them without paying — or breaking on — a backend init.  Same split as
 ``knn_tpu.index.artifact`` over ``knn_tpu.index.mutable``: whatever
-validates curated artifacts must run on the box that curates them, not
-only the one with the accelerator.
+validates artifacts must run on a box without the accelerator too.
 """
 
 from __future__ import annotations
 
 from typing import List
 
-#: version stamp of the ``ivf`` bench block (bench.py's opt-in ivf
-#: mode); bump on any schema change so the refresher refuses
-#: half-migrated lines instead of hoisting garbage — the version token
-#: the artifact-schema catalog's ``ivf`` entry consumes
+#: version stamp of the ``ivf`` block; bump on any schema change so a
+#: half-migrated block is refused — the version token the
+#: artifact-schema catalog's ``ivf`` entry consumes
 IVF_VERSION = 1
 
 
@@ -26,19 +24,17 @@ def _required_fields():
     return required_keys("ivf")
 
 
-#: fields every valid ivf block must carry (the refusal list the
-#: refresher prints) — DERIVED from the artifact-schema catalog
+#: fields every valid ivf block must carry — DERIVED from the artifact-schema catalog
 #: (knn_tpu.analysis.artifacts), the one declaration the validator and
 #: the lockstep checker both read
 IVF_REQUIRED = _required_fields()
 
 
 def validate_ivf_block(block) -> List[str]:
-    """Structural validation the artifact refresher runs before curating
-    a line carrying an ``ivf`` block: returns the list of violations
+    """Structural validation of an ``ivf`` block: returns the list of violations
     (empty = valid).  Blocks that recorded their own failure (an
     ``error`` key) are exempt — an honest error field beats a refused
-    line.  A shim over the artifact-schema catalog
+    block.  A shim over the artifact-schema catalog
     (:mod:`knn_tpu.analysis.artifacts`, the ``ivf`` entry)."""
     from knn_tpu.analysis.artifacts import validate
 

@@ -1,21 +1,20 @@
 """Jax-free pieces of the join subsystem: the version token and the
-``join`` bench-artifact validator.
+``join`` artifact-block validator.
 
 These live apart from :mod:`knn_tpu.join.engine` (whose entry points
 import JAX lazily but whose callers usually don't want a backend at
 all) for the same reason ``knn_tpu.ivf.artifact`` splits off
-``knn_tpu.ivf.index``: whatever validates curated artifacts must run on
-the box that curates them, not only the one with the accelerator.
+``knn_tpu.ivf.index``: whatever validates artifacts must run on
+a box without the accelerator too.
 """
 
 from __future__ import annotations
 
 from typing import List
 
-#: version stamp of the ``join`` bench block (bench.py's opt-in join
-#: mode); bump on any schema change so the refresher refuses
-#: half-migrated lines instead of hoisting garbage — the version token
-#: the artifact-schema catalog's ``join`` entry consumes
+#: version stamp of the ``join`` block; bump on any schema change so
+#: a half-migrated block is refused — the version token the
+#: artifact-schema catalog's ``join`` entry consumes
 JOIN_VERSION = 1
 
 
@@ -25,19 +24,17 @@ def _required_fields():
     return required_keys("join")
 
 
-#: fields every valid join block must carry (the refusal list the
-#: refresher prints) — DERIVED from the artifact-schema catalog
+#: fields every valid join block must carry — DERIVED from the artifact-schema catalog
 #: (knn_tpu.analysis.artifacts), the one declaration the validator and
 #: the lockstep checker both read
 JOIN_REQUIRED = _required_fields()
 
 
 def validate_join_block(block) -> List[str]:
-    """Structural validation the artifact refresher runs before curating
-    a line carrying a ``join`` block: returns the list of violations
+    """Structural validation of a ``join`` block: returns the list of violations
     (empty = valid).  Blocks that recorded their own failure (an
     ``error`` key) are exempt — an honest error field beats a refused
-    line.  A shim over the artifact-schema catalog
+    block.  A shim over the artifact-schema catalog
     (:mod:`knn_tpu.analysis.artifacts`, the ``join`` entry)."""
     from knn_tpu.analysis.artifacts import validate
 

@@ -15,8 +15,8 @@ tenants — was unobservable before this package.  Four pieces:
   result log with an explicit outcome (ok / rejected:* / shed:* /
   error);
 - :mod:`~knn_tpu.loadgen.knee` — the stepped-rate sweep that locates
-  the latency-vs-throughput knee and emits it as the curated bench
-  artifact the perf sentinel baselines;
+  the latency-vs-throughput knee and emits it as a validated
+  artifact block;
 - :mod:`~knn_tpu.loadgen.synthetic` — a jax-free single-server target
   with a configured capacity, so the harness itself (and the knee
   detector) is testable without hardware.
@@ -24,8 +24,8 @@ tenants — was unobservable before this package.  Four pieces:
 The controls the measured knee motivates live in
 :mod:`knn_tpu.serving.admission`: bounded queues, deadline-aware
 shedding, per-tenant quotas, starvation-safe priorities — shed, don't
-collapse.  Entry points: ``python -m knn_tpu.cli loadgen`` and
-bench.py's ``knee`` mode (docs/serving.md).
+collapse.  Entry point: ``python -m knn_tpu.cli loadgen``
+(docs/serving.md).
 
 Jax-free by construction (numpy only): generating and replaying load
 must not require the accelerator the target owns.

@@ -1,18 +1,14 @@
 """The latency-vs-throughput knee: a stepped-rate sweep that locates
 the maximum sustained rate where admitted-request tail latency still
-meets the SLO, emitted as a curated bench artifact.
+meets the SLO, emitted as a validated artifact block.
 
 TPU-KNN (arXiv:2206.14286) frames peak-FLOP serving as a
 throughput-recall-latency tradeoff; the knee is where that tradeoff
 lives for a serving deployment — below it, added load is free; above
 it, every extra offered request is paid in tail latency (or, with
-admission control on, in explicit sheds).  ROADMAP item 4 wants the
-knee RECORDED so regressions in it are judged like any other curated
-metric: :func:`knee_block` is the artifact shape
-``refresh_bench_artifacts.py`` validates (:func:`validate_knee_block`
-— malformed blocks are REFUSED at curation, the roofline-block
-discipline), and ``knee_qps`` joins the sentinel's curated fields so a
-knee that slides down reads as the regression it is.
+admission control on, in explicit sheds).  :func:`knee_block` is the
+artifact shape and :func:`validate_knee_block` its validator (the
+roofline-block discipline).
 
 The sweep is target-agnostic: a factory returning a fresh
 ``QueryQueue``-shaped target per step (fresh so one step's saturated
@@ -30,7 +26,7 @@ from typing import Callable, List, Optional, Sequence
 from knn_tpu.loadgen import driver
 from knn_tpu.loadgen.workload import WorkloadSpec, generate
 
-#: artifact schema version (bump on shape changes so the refresher can
+#: artifact schema version (bump on shape changes so a reader can
 #: tell a malformed block from an old one) — the version token the
 #: artifact-schema catalog's ``loadgen_knee`` entry consumes
 BLOCK_VERSION = 1
@@ -42,10 +38,10 @@ def _step_fields():
     return element_required("loadgen_knee", "rate_steps")
 
 
-#: fields every rate step must carry for the artifact to curate —
+#: fields every rate step must carry for the artifact to validate —
 #: DERIVED from the artifact-schema catalog (knn_tpu.analysis.
-#: artifacts), the one declaration the validator, refresher, and
-#: artifact-lockstep checker all read
+#: artifacts), the one declaration the validator and the
+#: artifact-lockstep checker both read
 STEP_FIELDS = _step_fields()
 
 
@@ -123,7 +119,7 @@ def knee_sweep(target_factory: Callable[[], object],
 
 
 def knee_block(steps: Sequence[dict], *, slo_p99_ms: float) -> dict:
-    """The curated artifact: the step table plus the detected knee —
+    """The artifact: the step table plus the detected knee —
     the highest achieved q/s among SLO-meeting steps (None when no
     step met the SLO: an honest 'knee below the lowest step' beats a
     fabricated number)."""
@@ -144,11 +140,10 @@ def knee_block(steps: Sequence[dict], *, slo_p99_ms: float) -> dict:
 
 
 def validate_knee_block(block) -> List[str]:
-    """Structural validation the artifact refresher runs before
-    curating a line carrying a ``loadgen_knee`` block: returns the
+    """Structural validation of a ``loadgen_knee`` block: returns the
     list of violations (empty = valid).  Blocks that recorded their
     own failure (an ``error`` key) are exempt — an honest error field
-    beats a refused line.  A shim over the artifact-schema catalog
+    beats a refused block.  A shim over the artifact-schema catalog
     (:mod:`knn_tpu.analysis.artifacts`, the ``loadgen_knee`` entry)
     with the legacy error strings byte-identical."""
     from knn_tpu.analysis.artifacts import validate

@@ -61,7 +61,7 @@ AUDIT_BUDGET_ENV = "KNN_TPU_AUDIT_BUDGET_ROWS_S"
 #: the quality artifact block's schema version (docs/OBSERVABILITY.md)
 QUALITY_VERSION = 1
 
-#: default oracle row budget: generous for the shapes bench/test audit,
+#: default oracle row budget: generous for the shapes tests audit,
 #: a real bound against a full-corpus scan storm in production
 DEFAULT_BUDGET_ROWS_S = 5_000_000.0
 #: pending replay records (each holds a query copy) — bounded so a
@@ -302,7 +302,7 @@ class Auditor:
 
     # --- introspection --------------------------------------------------
     def drain(self, timeout: float = 30.0) -> bool:
-        """Block until every enqueued record scored (tests, bench)."""
+        """Block until every enqueued record scored (tests)."""
         deadline = time.monotonic() + timeout
         with self._idle:
             while self._pending > 0:
@@ -317,8 +317,8 @@ class Auditor:
             return self._worker is not None and self._worker.is_alive()
 
     def summary(self) -> dict:
-        """The quality stats section (engine stats, /statusz, doctor,
-        the bench quality block) — JSON-safe, registry-free reads."""
+        """The quality stats section (engine stats, /statusz, doctor)
+        — JSON-safe, registry-free reads."""
         with self._lock:
             return {
                 "rate": self._rate,

@@ -17,8 +17,7 @@ one bounded bundle to ``KNN_TPU_POSTMORTEM_DIR``:
   plus the critical-path attribution and device-vs-roofline verdict
   over every reconstructable request,
 - the SLO report and the breach detail that fired,
-- the telemetry-relevant environment (``KNN_TPU_*`` / ``KNN_BENCH_*``
-  knobs), pid, and a schema version.
+- the telemetry-relevant environment (``KNN_TPU_*`` knobs), pid, and a schema version.
 
 Disciplines:
 
@@ -163,8 +162,7 @@ def _write_bundle(objective: str, detail: dict,
         "attribution": waterfall.attribute(wfs),
         "device_vs_roofline": waterfall.device_vs_roofline(wfs),
         "env": {k: v for k, v in sorted(os.environ.items())
-                if k.startswith(("KNN_TPU_", "KNN_BENCH_",
-                                 "JAX_PLATFORMS"))},
+                if k.startswith(("KNN_TPU_", "JAX_PLATFORMS"))},
         # the shadow audit sampler's evidence: summary + the bounded
         # ring of failing audit records — for a quality-SLO breach
         # this IS the postmortem (which requests served wrong answers,

@@ -38,8 +38,7 @@ Full provenance rides every entry (device_kind, shape key, config
 label, commit, round, source ``device_trace``/``host_phase``) so a
 curated artifact can say not just *that* the ceiling was calibrated
 but *from which measurement*.  Everything here is jax-free.
-Derivation + campaign runbook: docs/PERF.md "Calibration & measured
-ceilings".
+Derivation: docs/PERF.md "Calibration & measured ceilings".
 """
 
 from __future__ import annotations
@@ -76,7 +75,7 @@ _TERM_OF_BOUND = {"hbm_bound": "hbm", "mxu_bound": "mxu",
 FACTOR_MIN, FACTOR_MAX = 1e-3, 1e4
 
 #: stated tolerance (percent) between a calibrated ceiling and the
-#: measured qps it was fit from — the campaign's acceptance gate; the
+#: measured qps it was fit from; the
 #: reconstruction is exact up to rounding, so this bound is generous
 RESIDUAL_TOLERANCE_PCT = 2.0
 
@@ -111,7 +110,7 @@ def calibration_key(device_kind: Optional[str], n: int, d: int, k: int,
     """The shape key one calibration is valid for — the tune-cache key
     discipline: any field mismatch MUST miss (a factor fit on one
     (kind, shape, precision, kernel) point says nothing about another —
-    in particular, a campaign's tiled/streaming/fused arms at the SAME
+    in particular, the tiled/streaming/fused kernels at the SAME
     shape measure different machines and must never share an entry)."""
     kind = device_kind or "generic-cpu"
     kern = f":{kernel}" if kernel else ""
@@ -361,8 +360,7 @@ def lookup_for_block(block: dict,
 def publish(label: str, cal: dict) -> None:
     """Export one block's calibration verdict to the metrics registry
     (obs-gated, like every exporter): applied flag, entry age, and the
-    analytic model's residual — the drift signal the sentinel's
-    ``model_residual_pct`` baseline watches."""
+    analytic model's residual (the drift signal)."""
     if not registry.enabled():
         return
     applied = bool(cal.get("applied"))
@@ -415,10 +413,8 @@ def status() -> dict:
 
 
 def validate_calibration(cal) -> List[str]:
-    """Structural validation of a block's ``calibration`` field (the
-    refresher refuses malformed ones; ``perf_sentinel --lint`` sweeps
-    history with this).  Returns error strings, empty when
-    well-formed.  An absent overlay must still be EXPLICIT: the field
+    """Structural validation of a block's ``calibration`` field.
+    Returns error strings, empty when well-formed.  An absent overlay must still be EXPLICIT: the field
     is a dict with ``applied: false``, never missing-and-implied.
     A compat shim over the artifact-schema catalog
     (:mod:`knn_tpu.analysis.artifacts`, the ``calibration`` entry):
@@ -428,18 +424,6 @@ def validate_calibration(cal) -> List[str]:
     from knn_tpu.analysis.artifacts import validate
 
     return validate("calibration", cal, style="legacy")
-
-
-def validate_campaign_block(block) -> List[str]:
-    """Structural validation of a bench/curated line's ``campaign``
-    block (written by ``cli campaign``) — the refusal surface
-    ``refresh_bench_artifacts.py`` applies so a malformed campaign
-    artifact can never enter the curated history.  A compat shim over
-    the artifact-schema catalog (the ``campaign`` entry), historical
-    strings preserved like :func:`validate_calibration`."""
-    from knn_tpu.analysis.artifacts import validate
-
-    return validate("campaign", block, style="legacy")
 
 
 def reset() -> None:

@@ -100,8 +100,8 @@ def prometheus_text(snapshot: Optional[dict] = None) -> str:
 
 
 def compact_snapshot(snapshot: Optional[dict] = None) -> dict:
-    """The snapshot flattened for embedding (JobResult.metrics()["obs"],
-    bench lines): ``{name: value}`` for unlabeled series, ``{name:
+    """The snapshot flattened for embedding (JobResult.metrics()["obs"]):
+    ``{name: value}`` for unlabeled series, ``{name:
     {"k=v,...": value}}`` for labeled ones; histograms keep their
     summary dict."""
     snap = registry.snapshot() if snapshot is None else snapshot

@@ -564,10 +564,8 @@ def live_fleet_report() -> dict:
 
 def artifact_block(report: dict) -> dict:
     """The validated ``fleet`` artifact block (one BlockSchema entry in
-    knn_tpu/analysis/artifacts.py drives validator / refusal / sweep /
-    docs lockstep): the merged report's flat, bounded headline shape —
-    what bench lines and ``cli fleet --json`` carry instead of the full
-    report."""
+    knn_tpu/analysis/artifacts.py drives validator and docs
+    lockstep): the merged report's flat, bounded headline shape."""
     if not report.get("enabled", True):
         return {"fleet_version": FLEET_VERSION,
                 "member_count": 0,

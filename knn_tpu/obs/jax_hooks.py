@@ -31,7 +31,7 @@ for, shortened to what differs between deployments) and whatever the
 launching site adds (the rows of the call).
 
 :func:`install_compile_hook` is idempotent and safe to call from every
-instrumented entry point (engine construction, ``run_job``, the bench);
+instrumented entry point (engine construction, ``run_job``);
 it no-ops when the subsystem is disabled or the monitoring API is
 absent (older jaxlibs), so no caller needs a guard.
 """

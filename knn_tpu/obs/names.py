@@ -124,10 +124,6 @@ CALIBRATION_APPLIED = "knn_tpu_calibration_applied"
 CALIBRATION_AGE = "knn_tpu_calibration_age_seconds"
 CALIBRATION_RESIDUAL = "knn_tpu_calibration_residual_pct"
 
-# --- measured-ceiling campaign (knn_tpu.campaign) ----------------------
-CAMPAIGN_ARMS = "knn_tpu_campaign_arms_total"
-CAMPAIGN_STAGES = "knn_tpu_campaign_stages_total"
-
 # --- multi-host merge tree (knn_tpu.parallel.sharded / .multihost) -----
 MERGE_SELECTED = "knn_tpu_merge_strategy_selected_total"
 MERGE_BYTES = "knn_tpu_merge_bytes_total"
@@ -425,7 +421,7 @@ CATALOG = {
     ROOFLINE_EVALUATIONS: (
         "counter", (),
         "Roofline attributions published to the registry (autotuner "
-        "winners, warm-cache resolves, bench runs)."),
+        "winners, warm-cache resolves)."),
     CALIBRATION_APPLIED: (
         "gauge", ("config",),
         "1 when the labeled config's published roofline block carried "
@@ -439,16 +435,7 @@ CATALOG = {
         "gauge", ("config",),
         "Signed percent by which the ANALYTIC model mispredicted the "
         "measured device time for the labeled config (the reconciled "
-        "model_residual_pct) — the calibration-drift signal the "
-        "sentinel baselines."),
-    CAMPAIGN_ARMS: (
-        "counter", ("status",),
-        "Measured-ceiling campaign arms completed (cli campaign), by "
-        "terminal status (ok / error)."),
-    CAMPAIGN_STAGES: (
-        "counter", ("stage",),
-        "Campaign pipeline stages executed (gates / tune / bench / "
-        "capture / reconcile / calibrate / curate), across arms."),
+        "model_residual_pct) — the calibration-drift signal."),
     MERGE_SELECTED: (
         "counter", ("level", "strategy", "source"),
         "Merge-strategy resolutions at placement time, by merge level "

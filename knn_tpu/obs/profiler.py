@@ -6,7 +6,7 @@ trace shows WHERE inside the program the time actually goes (the
 round-5 finding: the remaining gap needs on-chip profiling, not
 another geometry sweep).  :func:`device_trace` wraps a code block in
 ``jax.profiler.trace`` (TensorBoard-loadable) and records the capture
-as a ``profiler.trace`` telemetry event, so the emitted bench line /
+as a ``profiler.trace`` telemetry event, so the emitted
 tuning entry can carry its trace directory.
 
 Gating — OFF by default, two ways in:
@@ -14,8 +14,7 @@ Gating — OFF by default, two ways in:
 - ``KNN_TPU_PROFILE_DIR=<dir>``: the ambient env gate.  Honored only
   while telemetry is enabled (``KNN_TPU_OBS=0`` makes it a no-op,
   like every other obs surface).
-- an explicit ``base_dir`` argument (bench's ``--trace-dir`` /
-  ``KNN_BENCH_TRACE``): an explicit flag is an explicit request and
+- an explicit ``base_dir`` argument: an explicit flag is an explicit request and
   captures regardless of the obs switch (only the telemetry event is
   skipped when obs is off).
 

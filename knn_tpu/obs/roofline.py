@@ -24,9 +24,8 @@ term by term and jax-free:
   in-kernel selects and the XLA ``lax.top_k`` / ApproxTopK paths —
   calibration constants from the measured cost model in docs/PERF.md.
 
-Each term divides by the device's peak (``PEAKS_BY_KIND`` — the single
-source of truth ``bench.py``'s ``_PEAK_BY_KIND`` is now a view over)
-to a time; the largest term names the bound class, and the combined
+Each term divides by the device's peak (``PEAKS_BY_KIND``, the single
+source of truth) to a time; the largest term names the bound class, and the combined
 time reflects whether the select can hide in the stream's shadow::
 
     non-fused / XLA:  ceiling_qps = nq / (max(t_hbm, t_mxu) + t_vpu)
@@ -43,8 +42,8 @@ assumes peak-rate execution of every term, so ``roofline_pct <= 1`` up
 to peak-table error — a pct near 1 means the config is done and the
 *model's* bound must move (different precision, grid order, geometry);
 a low pct names implementation slack.  Everything here is pure arithmetic on plain
-numbers: the bench, the artifact refresher, the sentinel lint, and the
-``cli roofline`` subcommand all run it without importing JAX.
+numbers: the autotuner and the ``cli roofline`` subcommand run it
+without importing JAX.
 
 MODEL_VERSION 3 closes the analytic/measured gap: every block consults
 the calibration overlay (:mod:`knn_tpu.obs.calibrate`, fed by the
@@ -55,7 +54,7 @@ factors and splits ``ceiling_qps`` (measured) from
 ``calibration: {applied: false}`` explicitly.
 
 Derivation, peak-table provenance, how to read ``bound_class``, and
-the calibration/campaign runbook: docs/PERF.md "Roofline model" and
+calibration: docs/PERF.md "Roofline model" and
 "Calibration & measured ceilings".
 """
 
@@ -150,8 +149,7 @@ MODEL_VERSION = 8
 BOUND_CLASSES = ("hbm_bound", "mxu_bound", "vpu_select_bound",
                  "dcn_bound", "h2d_bound")
 
-#: per-device-kind peaks (public spec sheets; bf16 column = the table
-#: bench.py carried since round 1, now living here).  ``hbm_gbps`` is
+#: per-device-kind peaks (public spec sheets).  ``hbm_gbps`` is
 #: the chip's HBM bandwidth in GB/s; ``int8_flops`` the int8 MXU rate
 #: (2x bf16 on every announced generation; v7's fp8 4614 TF/s stands in
 #: for int8 there); ``vpu_ops`` is the vector-unit element-op rate —
@@ -321,13 +319,6 @@ _LAST_MAX = 16
 _PUBLISHED: set = set()
 
 
-def bf16_peak_by_kind() -> Dict[str, float]:
-    """``{device_kind: bf16 MXU peak FLOP/s}`` — the view bench.py's
-    ``_PEAK_BY_KIND`` historically carried, now derived from the one
-    table."""
-    return {kind: rec["bf16_flops"] for kind, rec in PEAKS_BY_KIND.items()}
-
-
 def peaks_for(device_kind: Optional[str] = None,
               backend: Optional[str] = None) -> Tuple[Dict[str, float], bool]:
     """(peaks, estimated): the device's peak record, or the generic CPU
@@ -424,8 +415,8 @@ def _consult_calibration(model: dict, nq: int,
 
     if "dcn_bound" in times:
         # multi-host blocks: no calibration entry covers the DCN term
-        # yet (the campaign measures single-host arms); an explicit
-        # absent verdict beats silently mis-scaling three of four terms
+        # yet; an explicit absent verdict beats silently mis-scaling
+        # three of four terms
         model["calibration"] = {
             "applied": False,
             "note": "multi-host blocks use the analytic DCN model"}
@@ -954,11 +945,9 @@ def attribute(model: dict, measured_qps: Optional[float]) -> dict:
 
 
 def validate_block(block) -> list:
-    """Structural validation of a ``roofline`` block (bench lines,
-    curated artifacts, cache entries).  Returns a list of error
-    strings, empty when well-formed — the refresher refuses malformed
-    blocks and ``perf_sentinel --lint`` sweeps the history with this.
-    A shim over the artifact-schema catalog
+    """Structural validation of a ``roofline`` block (tuning-cache
+    entries, ``cli roofline``).  Returns a list of error strings, empty
+    when well-formed.  A shim over the artifact-schema catalog
     (:mod:`knn_tpu.analysis.artifacts`, the ``roofline`` entry) with
     the legacy error strings byte-identical."""
     from knn_tpu.analysis.artifacts import validate
@@ -1046,10 +1035,9 @@ def reset() -> None:
 
 
 def block_for_bench_line(rec: dict) -> Optional[dict]:
-    """Best-effort attribution of one bench JSON line from its own
+    """Best-effort attribution of one recorded result line from its own
     fields (metric-name shape, ``pallas_knobs``, ``device_kind``,
-    ``device_phase_qps``/``value``) — what the artifact refresher
-    curates onto lines that predate the in-bench roofline block.
+    ``device_phase_qps``/``value``).
     Returns None when the line doesn't carry enough to model."""
     m = _METRIC_RE.match(str(rec.get("metric") or ""))
     if not m:

@@ -46,8 +46,7 @@ no gauges, no events, no allocation on any caller's path.
 Objectives are configurable via ``KNN_TPU_SLO_CONFIG`` (a JSON file:
 ``[{"name": ..., "kind": ..., ...}, ...]`` replacing the defaults);
 :func:`load_objectives` validates every entry against the metric
-catalog, and ``scripts/perf_sentinel.py --lint`` runs that validation
-in CI without timing anything.
+catalog.
 """
 
 from __future__ import annotations
@@ -214,9 +213,7 @@ DEFAULT_OBJECTIVES: Tuple[Objective, ...] = (
 def load_objectives(path: Optional[str] = None) -> Tuple[Objective, ...]:
     """The configured objectives: ``path`` (or ``KNN_TPU_SLO_CONFIG``)
     names a JSON list replacing the defaults; every entry is validated
-    against the catalog.  Raises ``ValueError`` on any bad entry — the
-    lint gate (perf_sentinel --lint) runs this so a broken config fails
-    in CI, not at serve time."""
+    against the catalog.  Raises ``ValueError`` on any bad entry."""
     path = path or os.environ.get(CONFIG_ENV)
     if not path:
         objs = DEFAULT_OBJECTIVES

@@ -17,22 +17,22 @@ module parses the two measured-time sources the calibration layer
   overlapping on one track must not double-bill), so the sample is the
   chip's measured wall occupancy, directly comparable to the model's
   per-sweep term times.
-- **host-side phase records** — the ``phase_breakdown`` block a bench
-  line carries (``device_s`` measured by fenced ``perf_counter`` around
+- **host-side phase records** — a ``phase_breakdown`` block
+  (``device_s`` measured by fenced ``perf_counter`` around
   the already-compiled program) and the waterfall's device segments.
   CPU-testable: tier-1 exercises the identical reconcile loop against
-  these without a TPU (``cli campaign --rehearse``).
+  these without a TPU.
 
 Event→config matching rides the capture convention: the profiler
-writes each capture under its SANITIZED section name (the bench mode /
-tuning cache key), so :func:`read_section` resolves a section back to
+writes each capture under its SANITIZED section name (the tuning
+cache key), so :func:`read_section` resolves a section back to
 its artifact — a trace can never be reconciled against a config that
 did not produce it.  Malformed artifacts raise :class:`TraceReadError`
 LOUDLY (a silently-empty trace would calibrate the model against
 nothing and call it measured).
 
 Everything here is stdlib-only: gzip + json + glob.  No JAX import,
-ever — the campaign's rehearse mode and the offline doctor both parse
+ever — the offline doctor parses
 on machines with no accelerator runtime.
 """
 
@@ -255,7 +255,7 @@ def sample_from_trace(base_dir: str, section: str, *, nq: int) -> dict:
 
 
 def sample_from_phases(phase_breakdown: dict, *, nq: int) -> dict:
-    """A measured sample from a bench line's host-side
+    """A measured sample from a host-side
     ``phase_breakdown`` — the CPU-testable fallback source.  Only the
     fenced ``device_s`` phase enters: the h2d/d2h phases are host-link
     time and never land in a device-term residual."""

@@ -273,8 +273,8 @@ def repair_uncertified(
     pass really missed a neighbor), ``fallback_false_alarms`` (repair
     reproduced the original answer — the certificate's tolerance cried
     wolf), and ``host_exact_queries`` (escalations to the float64 host
-    scan) when nonzero.  The miss/alarm split is the measurement ADVICE.md
-    round 2 asked for: it tells the tuner whether to grow the margin
+    scan) when nonzero.  The miss/alarm split is a measurement
+    for the tuner: it tells it whether to grow the margin
     (misses) or tighten the tolerance (alarms).
     """
     if not bad.size:

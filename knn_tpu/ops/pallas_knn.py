@@ -31,8 +31,8 @@ indices (``_emit_select_grouped``).  A layout whose bins are contiguous
 128-lane spans reduces over lanes (~7 shuffle rounds for each min and
 argmin): its select dominated the kernel and it measured 1.8-3.1x
 slower at the SIFT shape on a v5e.  The compiled grouped kernel passed
-the 200k-row float64-oracle soundness gate and bench.py's embedded
-tie-stressed gate on a v5e chip.
+the 200k-row float64-oracle soundness gate and a tie-stressed
+gate on a v5e chip.
 
 Outputs per (i, j) cell are lane-aligned blocks (``s * 128`` lanes: a
 (256, 16) output block fails to lower for exactly this rule).

@@ -1,9 +1,9 @@
 """The measured ring/allgather crossover — merge-strategy selection as
 DATA, not caller folklore.
 
-``SCALING.json`` (scripts/scaling_study.py) measured both db-axis merge
-strategies at equal total work across mesh shapes and k.  The verdict
-is a crossover, not a winner: allgather's one-collective P·k candidate
+``SCALING.json`` (a study on virtual CPU devices) measured both db-axis
+merge strategies at equal total work across mesh shapes and k.  The
+verdict is a crossover, not a winner: allgather's one-collective P·k candidate
 volume wins at small shard counts and large ones whose ring would pay
 P-1 latency hops, while the ring's constant-memory (P-1)·k pipeline
 wins in between and at large k where the gathered volume dominates.
@@ -23,11 +23,10 @@ This is the jax-free home of
   ring ``Q·k·8·(P-1)``; 8 = f32 distance + i32 index per candidate),
   reused by the roofline's DCN term;
 - :func:`validate_multihost_block` — structural validation of the
-  ``multihost`` block bench.py emits and the artifact refresher
-  refuses when malformed (the roofline-block discipline).
+  ``multihost`` block (the roofline-block discipline).
 
-Everything here is plain arithmetic on plain numbers so the refresher,
-the sentinel lint, and the roofline model import it without JAX.
+Everything here is plain arithmetic on plain numbers so the roofline
+model imports it without JAX.
 """
 
 from __future__ import annotations
@@ -121,10 +120,8 @@ def merge_bytes(n_queries: int, k: int, shards: int, strategy: str) -> int:
 
 
 def validate_multihost_block(block) -> list:
-    """Structural validation of a ``multihost`` bench block.  Returns a
-    list of error strings, empty when well-formed — the artifact
-    refresher REFUSES malformed blocks (the roofline/knee discipline:
-    a corrupt block would poison curated baselines silently).  A shim
+    """Structural validation of a ``multihost`` block.  Returns a
+    list of error strings, empty when well-formed.  A shim
     over the artifact-schema catalog (:mod:`knn_tpu.analysis.
     artifacts`, the ``multihost`` entry) with the legacy error strings
     byte-identical."""

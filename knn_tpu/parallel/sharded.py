@@ -162,8 +162,8 @@ def unpack_bits_u32(words: np.ndarray, n_bits: int) -> np.ndarray:
 
 def _analysis_window(k: int, m: int) -> int:
     """Width of the device rank-analysis window: the packed program
-    output's column layout, _certify_pallas's unpack, and bench.py's
-    phase breakdown all derive from THIS — one home, or unpack_certified
+    output's column layout and _certify_pallas's unpack
+    both derive from THIS — one home, or unpack_certified
     silently slices shifted columns."""
     return min(k + 17, m + 1)
 
@@ -1815,9 +1815,9 @@ class ShardedKNN:
 
     def _pallas_operands(self, precision: str) -> tuple:
         """The operand tail of the pallas certified program after
-        ``(queries, db)`` — ONE home shared by :meth:`_certify_pallas`
-        and bench.py's phase breakdown so neither can call the program
-        with the wrong arity: int8 passes the quantized placement;
+        ``(queries, db)`` — ONE home, beside :meth:`_pallas_setup`,
+        so that no caller of the program can hand it an operand list
+        of the wrong arity: int8 passes the quantized placement;
         pq passes (codes, codebooks, consts);
         the f32 precisions pass the scalar db-norm bound, and "bf16x3"
         after it the resident row operands where the program
@@ -2892,7 +2892,7 @@ class ShardedKNN:
         """(program, m, analysis_window, interpret) for the one-pass
         certified path — the ONE home of the kernel-geometry margin cap
         and the packed-output window, shared by :meth:`_certify_pallas`
-        and bench.py's phase breakdown so they can never measure
+        and every other caller of the program so they can never run
         different programs or unpack different column layouts.
 
         ``interpret`` is the one knob nobody passes: resolved HERE

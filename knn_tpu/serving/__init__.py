@@ -30,7 +30,7 @@ latency-vs-throughput knee (knn_tpu.loadgen) motivates.
 
 Entry points: ``ShardedKNN.search_bucketed()`` for the one-liner,
 ``ServingEngine`` + ``QueryQueue`` for a long-running service,
-``--serve-buckets`` on the CLI, the ``serving`` mode in bench.py.
+``--serve-buckets`` on the CLI.
 """
 
 from knn_tpu.serving.admission import (

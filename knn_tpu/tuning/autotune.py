@@ -15,7 +15,7 @@ The public entry points:
 
 - :func:`resolve` — ONE call every knob consumer goes through
   (``ShardedKNN.search_certified``, the serving engine's stats,
-  ``pipeline``/``cli``, ``bench.py``): cached winner -> library
+  ``pipeline``/``cli``): cached winner -> library
   defaults, with explicit caller overrides beating both.
 - :func:`autotune` — run the search for one problem shape and persist
   the winner; a pre-existing cache entry short-circuits to ZERO
@@ -148,7 +148,7 @@ def resolve_full(
     place of its block_q for the streaming and fused kernels).
     ``info`` carries ``source``
     ("cache" | "default"), the cache key/path, and which knobs an
-    override pinned — the observability bench/serving surface.
+    override pinned — the observability/serving surface.
     ``profile`` selects the tuning regime's cache row (latency =
     serving, throughput = bulk join; see :func:`cache_key`) — a miss
     in either row falls back to the same ``DEFAULT_KNOBS``."""
@@ -258,8 +258,7 @@ def knob_grid(level: str = "standard",
     at the otherwise-winning geometries): a cached winner's
     final_select is therefore a MEASURED choice, never a default copied
     into the cache — consumers with their own final_select preference
-    (bench.py's historical "approx") yield to a cache hit
-    precisely because the hit measured it.
+    yield to a cache hit precisely because the hit measured it.
 
     ``profile`` (:data:`knn_tpu.tuning.cache.PROFILES`) picks the
     tuning regime.  ``"latency"`` (default) is the grid above,

@@ -42,7 +42,7 @@ def enable_compile_cache() -> str:
     :data:`COMPILE_CACHE_DIR`.  Either way the size and compile-time
     thresholds drop to zero, so the small per-bucket serving programs
     are cached next to the big kernels.  Called by ``chip_smoke.py``,
-    ``bench.py`` and ``knn_tpu.cli.main``; the test suite runs with
+    ``benchmark/system.py`` and ``knn_tpu.cli.main``; the test suite runs with
     JAX's cache switched off (tests/conftest.py).
     """
     import jax

@@ -77,8 +77,8 @@ os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
 TOPOLOGY = "v5e:2x2"
 NQ = 4096
 MARGIN = 28
-#: the benchmark shapes (bench.py CONFIGS): rows, dim, k.  GloVe's
-#: cosine runs as l2 on unit vectors, so the kernel sees the same call.
+#: the shapes of chip_smoke.py and of the benchmark's cells: rows, dim,
+#: k.  GloVe's cosine runs as l2 on unit vectors, so the kernel sees the same call.
 SHAPES = {
     "sift": (1_000_000, 128, 100),
     "gist": (1_000_000, 960, 100),

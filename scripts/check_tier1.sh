@@ -7,22 +7,15 @@
 #
 # --fast: the inner-loop subset — kernel parity (tiled vs streaming vs
 # int8 bitwise contracts) + quantization bound soundness + the autotuner
-# gate + the telemetry registry/exporters + the SLO engine, perf
-# sentinel, and roofline cost model (docs/OBSERVABILITY.md; the
-# static-analysis suite `cli lint` (docs/ANALYSIS.md: switch/metric
-# lockstep, locked-mutation, jax-hygiene, VMEM budget) and the
-# sentinel's config/roofline-block lint ride along as HARD gates so an
-# uncataloged switch, an undocumented metric, an unlocked mutation, a
-# broken SLO config, or an over-VMEM knob candidate fails here, not in
-# review; the sentinel's check-latest pass prints regression verdicts
-# WARN-ONLY) — for edit-compile-test cycles on kernel/emitter/obs code
-# (~tens of seconds instead of the full suite).  The full gate remains
-# the only gate that counts; --fast is a developer convenience
-# (docs/PERF.md).
-#
-# --strict: the full gate PLUS the perf sentinel as a HARD gate — any
-# `regress` verdict on the newest curated bench round against its
-# history fails the run (docs/OBSERVABILITY.md "Regression sentinel").
+# gate + the telemetry registry/exporters + the SLO engine and the
+# roofline cost model (docs/OBSERVABILITY.md); the static-analysis suite
+# `cli lint` (docs/ANALYSIS.md: switch/metric lockstep, locked-mutation,
+# jax-hygiene, VMEM budget) rides along as a HARD gate so an uncataloged
+# switch, an undocumented metric, an unlocked mutation or an over-VMEM
+# knob candidate fails here, not in review — for edit-compile-test
+# cycles on kernel/emitter/obs code (~tens of seconds instead of the
+# full suite).  The full gate remains the only gate that counts; --fast
+# is a developer convenience (docs/PERF.md).
 cd "$(dirname "$0")/.." || exit 1
 if [ "${1:-}" = "--multihost" ]; then
   # The real multi-process lane: every tests/test_multihost.py test,
@@ -37,14 +30,12 @@ if [ "${1:-}" = "--multihost" ]; then
 fi
 if [ "${1:-}" = "--fast" ]; then
   python -m knn_tpu.cli lint || exit 1  # the full static-analysis suite
-  python scripts/perf_sentinel.py --lint || exit 1
-  python scripts/perf_sentinel.py --check-latest || true  # warn-only here
   exec env JAX_PLATFORMS=cpu python -m pytest \
     tests/test_pallas_knn.py tests/test_pallas_streaming.py \
     tests/test_fused_overlap.py \
     tests/test_quantize.py tests/test_pq.py tests/test_tuning.py \
     tests/test_obs.py \
-    tests/test_slo.py tests/test_sentinel.py tests/test_roofline.py \
+    tests/test_slo.py tests/test_roofline.py \
     tests/test_calibrate.py \
     tests/test_loadgen.py tests/test_admission.py \
     tests/test_waterfall.py tests/test_index.py \
@@ -55,11 +46,6 @@ if [ "${1:-}" = "--fast" ]; then
     tests/test_artifact_schema.py \
     tests/test_fleet.py \
     -q -m 'not slow' -p no:cacheprovider -p no:xdist -p no:randomly
-fi
-if [ "${1:-}" = "--strict" ]; then
-  # (cli lint runs once, at the unconditional hard gate below)
-  python scripts/perf_sentinel.py --lint || exit 1
-  python scripts/perf_sentinel.py --check-latest --strict || exit 1
 fi
 python -m knn_tpu.cli lint || exit 1  # hard gate on BOTH paths (docs/ANALYSIS.md)
 set -o pipefail; rm -f /tmp/_t1.log; timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' --continue-on-collection-errors -p no:cacheprovider -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log; rc=${PIPESTATUS[0]}; echo DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c); exit $rc

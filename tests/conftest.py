@@ -16,7 +16,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 # (knn_tpu.analysis.switches — jax-free, so this import is safe before
 # the backend config below): every cataloged mutable switch plus any
 # ambient variable under a cataloged family prefix is scrubbed, so a
-# developer shell's KNN_TPU_*/KNN_BENCH_* can never silently steer the
+# developer shell's KNN_TPU_* can never silently steer the
 # suite.  Never hand-list switches here again — declare them in the
 # catalog and isolation follows on the next run (the switch-lockstep
 # checker fails the lint if this derivation is removed).  Tests that
@@ -33,7 +33,7 @@ os.environ["KNN_TPU_TUNE_CACHE"] = os.path.join(
     tempfile.mkdtemp(prefix="knn_tpu_test_tune_"), "autotune.json")
 # tests see real compiles: JAX's persistent compilation cache stays off
 # for this process and every subprocess, so an entry point under test
-# (cli.main, bench.py) that calls utils.compat.enable_compile_cache
+# (cli.main) that calls utils.compat.enable_compile_cache
 # leaves nothing in the checkout and warms nothing for the next test
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 _flags = os.environ.get("XLA_FLAGS", "")

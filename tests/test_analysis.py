@@ -227,13 +227,13 @@ def test_switch_checker_flags_phantom_doc_and_missing_doc(tmp_path):
     tree = dict(GOOD_SWITCH_TREE)
     tree["docs/SWITCHES.md"] = (
         ALL_SWITCH_NAMES.replace("KNN_TPU_OBS_LOG\n", "")
-        + "\nKNN_BENCH_PHANTOM_KNOB\n")
+        + "\nKNN_TPU_PHANTOM_KNOB\n")
     write_tree(tmp_path, tree)
     rep = run_on(tmp_path, "switch-lockstep")
     msgs = [f.message for f in rep.findings]
     assert any("KNN_TPU_OBS_LOG is missing from the docs" in m
                for m in msgs)
-    assert any("KNN_BENCH_PHANTOM_KNOB" in m and "phantom" in m
+    assert any("KNN_TPU_PHANTOM_KNOB" in m and "phantom" in m
                for m in msgs)
 
 
@@ -252,12 +252,12 @@ def test_switch_checker_flags_handlisted_conftest(tmp_path):
 def test_isolation_names_generated_from_catalog():
     names = sw.isolation_names()
     # every concrete isolate=True switch, no family prefixes
-    assert "KNN_TPU_OBS" in names and "KNN_BENCH_N" in names
+    assert "KNN_TPU_OBS" in names and "KNN_TPU_IVF_NPROBE" in names
     assert not any(n.endswith("_") for n in names)
     # ambient members of an isolated family prefix are swept in
-    env = {"KNN_BENCH_PALLAS_FUTURE_KNOB": "1", "UNRELATED": "x"}
+    env = {"KNN_TPU_IVF_FUTURE_KNOB": "1", "UNRELATED": "x"}
     names_env = sw.isolation_names(env)
-    assert "KNN_BENCH_PALLAS_FUTURE_KNOB" in names_env
+    assert "KNN_TPU_IVF_FUTURE_KNOB" in names_env
     assert "UNRELATED" not in names_env
     assert names_env == sorted(set(names_env))
 

@@ -546,8 +546,3 @@ def test_quality_block_schema_round_trip():
     bad = dict(block, audit_recall_at_k=1.5)
     assert any("audit_recall_at_k" in e
                for e in A.validate("quality", bad))
-    # the line-level hoist the sentinel curates
-    line = {"quality": block}
-    A.apply_scope_hoists(line, scope="bench")
-    assert line["audit_recall_at_k"] == 1.0
-    assert ("audit_recall_at_k", "higher") in A.curated_fields()

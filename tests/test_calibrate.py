@@ -1,26 +1,21 @@
-"""The calibrated roofline (knn_tpu.obs.{traceread,calibrate} +
-knn_tpu.campaign): trace parsing pinned against the checked-in
+"""The calibrated roofline (knn_tpu.obs.{traceread,calibrate}):
+trace parsing pinned against the checked-in
 fixture, malformed-artifact loud errors, the reconcile math (a seeded
 wrong-by-2x peak constant corrected by the overlay), the calibration
 store's version-token self-invalidation, MODEL_VERSION-3 block
 semantics (explicit ``calibration: absent`` on uncalibrated lines —
-the r05 curated line included), the campaign rehearse loop end-to-end
-on CPU, and the refresh/sentinel refusal surfaces — the acceptance
-surface of the calibrated-roofline ISSUE."""
+the r05 curated line included) — the acceptance surface of the
+calibrated-roofline ISSUE."""
 
-import glob
 import gzip
 import json
 import os
 import shutil
-import subprocess
-import sys
 
-import numpy as np
 import pytest
 
 from knn_tpu import obs
-from knn_tpu.obs import calibrate, health, roofline, sentinel, traceread
+from knn_tpu.obs import calibrate, health, roofline, traceread
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FIXTURE = os.path.join(REPO, "tests", "fixtures",
@@ -83,7 +78,7 @@ def test_read_section_matches_event_to_config(tmp_path):
 
 
 def test_read_section_ignores_stale_runs(tmp_path):
-    """Re-running a campaign into the same trace dir leaves the older
+    """Re-running a capture into the same trace dir leaves the older
     timestamped run dirs behind; merging them would ADD disjoint-epoch
     busy intervals and calibrate against a measurement the machine
     never produced — only the newest run's files may enter."""
@@ -102,7 +97,7 @@ def test_read_section_ignores_stale_runs(tmp_path):
 
 
 def test_calibration_key_separates_kernel_arms(tmp_path, monkeypatch):
-    """The campaign's tiled/streaming/fused arms at one shape measure
+    """The tiled/streaming/fused kernels at one shape measure
     different machines: their store keys must differ, and a factor fit
     on one arm must never apply to another's block."""
     keys = {kern: calibrate.key_for_block(_model(kernel=kern))
@@ -356,14 +351,6 @@ def test_validate_block_rejects_malformed_calibration():
         "factors": {"hbm": 1, "mxu": 1, "vpu_select": 1},
         "source": "vibes", "model_residual_pct": 5.0})
     assert any("source" in e for e in roofline.validate_block(bad))
-    # campaign block validation (the refresher's refusal surface)
-    assert calibrate.validate_campaign_block({
-        "campaign_version": 1, "arm": "a", "rehearse": True,
-        "stages": [{"stage": "tune", "status": "ok"}]}) == []
-    assert calibrate.validate_campaign_block({"arm": "a"})
-    assert calibrate.validate_campaign_block({
-        "campaign_version": 1, "arm": "a", "rehearse": True,
-        "stages": [{"stage": "tune", "status": "partied"}]})
 
 
 # --- registry / statusz / obs-off --------------------------------------
@@ -415,210 +402,7 @@ def test_new_switches_are_catalogued_and_isolated():
     from knn_tpu.analysis.switches import isolation_names, lookup
 
     assert lookup("KNN_TPU_CALIBRATION") is not None
-    assert lookup("KNN_TPU_CAMPAIGN_DIR") is not None
-    iso = isolation_names({"KNN_TPU_CAMPAIGN_WHATEVER": "1"})
-    assert "KNN_TPU_CALIBRATION" in iso
-    assert "KNN_TPU_CAMPAIGN_DIR" in iso
-    assert "KNN_TPU_CAMPAIGN_WHATEVER" in iso  # family scrub
-
-
-# --- sentinel: model_residual_pct is a curated field -------------------
-
-
-def test_sentinel_judges_model_residual_drift():
-    """Calibration drift: |model_residual_pct| judged lower-is-better —
-    a model that starts mispredicting again regresses even when qps
-    holds; the field reads off the top level or the block's
-    calibration, and the sign never flips the verdict."""
-    hist = []
-    for i, r in enumerate((5.0, -5.2, 4.8, 5.1)):
-        hist.append({"metric": "knn_qps_sift1m_n1000000_d128_k100",
-                     "value": 6000.0, "backend": "tpu",
-                     "measured_round": i + 1,
-                     "measured_at_commit": f"c{i}",
-                     **({"model_residual_pct": r} if i % 2 else
-                        {"roofline": {"calibration": {
-                            "applied": True,
-                            "model_residual_pct": r}}})})
-    base = sentinel.build_baselines(hist)
-    key = "knn_qps_sift1m_n1000000_d128_k100|tpu|default"
-    assert "model_residual_pct" in base[key]
-    assert base[key]["model_residual_pct"]["median"] == \
-        pytest.approx(5.05, abs=0.01)  # abs() entered the baseline
-    fresh = {"metric": "knn_qps_sift1m_n1000000_d128_k100",
-             "backend": "tpu", "value": 6000.0,
-             "model_residual_pct": -60.0}
-    v = sentinel.verdict_for_line(fresh, baselines=base)
-    assert v["fields"]["model_residual_pct"]["verdict"] == "regress"
-    fresh["model_residual_pct"] = -5.0
-    v = sentinel.verdict_for_line(fresh, baselines=base)
-    assert v["fields"]["model_residual_pct"]["verdict"] == "ok"
-
-
-# --- campaign rehearse: the full loop on CPU ---------------------------
-
-
-def test_campaign_rehearse_full_loop(tmp_path, monkeypatch, capsys):
-    """ACCEPTANCE pin: ``cli campaign --rehearse`` runs
-    capture→parse→reconcile→calibrate→curate on CPU, producing a
-    roofline block with ``calibration.applied == true`` whose
-    calibrated ceiling reproduces the host-phase measured qps within
-    the stated residual tolerance, every stage recorded, the artifact
-    validating under the refresher's own validators."""
-    from knn_tpu import cli
-    from knn_tpu.obs import names as mn
-
-    out = str(tmp_path / "camp")
-    rc = cli.main(["campaign", "--rehearse", "--out", out,
-                   "--round", "6"])
-    assert rc == 0
-    printed = capsys.readouterr().out
-    tail = json.loads(printed.strip().splitlines()[-1])
-    assert tail["ok"] is True and tail["rehearse"] is True
-    paths = glob.glob(os.path.join(out, "campaign_r06_*.jsonl"))
-    assert len(paths) == 1
-    line = json.loads(open(paths[0]).read())
-    att = line["roofline"]
-    cal = att["calibration"]
-    assert cal["applied"] is True
-    assert cal["source"] == "host_phase"
-    measured = line["device_phase_qps"]
-    assert abs(att["ceiling_qps"] - measured) / measured * 100 <= \
-        calibrate.RESIDUAL_TOLERANCE_PCT
-    assert att["roofline_pct"] == pytest.approx(1.0, abs=0.02)
-    assert att["ceiling_qps_analytic"] != att["ceiling_qps"]
-    assert isinstance(line["model_residual_pct"], (int, float))
-    # every stage ran and was recorded; capture parsed the fixture
-    stages = [s["stage"] for s in line["campaign"]["stages"]]
-    assert stages == ["gates", "tune", "bench", "capture",
-                      "reconcile", "calibrate", "curate"]
-    cap = next(s for s in line["campaign"]["stages"]
-               if s["stage"] == "capture")
-    assert cap["fixture"]["device_busy_s"] == pytest.approx(800e-6)
-    assert cap["fixture"]["device_tracks_matched"] is True
-    # the artifact validates under the refresher's refusal surface
-    assert roofline.validate_block(att) == []
-    assert calibrate.validate_calibration(cal) == []
-    assert calibrate.validate_campaign_block(line["campaign"]) == []
-    assert "sentinel" in line
-    # campaign counters rode the registry
-    snap = obs.snapshot()
-    assert snap[mn.CAMPAIGN_STAGES]["series"]
-    arm_series = {s["labels"]["status"]: s["value"]
-                  for s in snap[mn.CAMPAIGN_ARMS]["series"]}
-    assert arm_series.get("ok", 0) >= 1
-    # the store persisted under the campaign's own out dir
-    assert os.path.exists(os.path.join(out, "calibration.json"))
-
-
-def test_campaign_rejects_unknown_arm(capsys):
-    from knn_tpu import cli
-
-    rc = cli.main(["campaign", "--rehearse", "--arms", "warp_drive"])
-    assert rc == 2
-    assert "unknown arm" in capsys.readouterr().err
-
-
-# --- refresh refusal + curation ----------------------------------------
-
-
-def _refresh(tmp_path, lines):
-    # the script resolves every path relative to ITS OWN repo root, so
-    # hermetic runs copy it under tmp_path/scripts (the established
-    # test_refresh_artifacts.py discipline) — running it in place would
-    # curate (and overwrite!) the real repo's artifacts
-    sdir = tmp_path / "scripts"
-    sdir.mkdir(exist_ok=True)
-    script = sdir / "refresh_bench_artifacts.py"
-    script.write_text(open(os.path.join(
-        REPO, "scripts", "refresh_bench_artifacts.py")).read())
-    (tmp_path / "tpu_bench_lines.jsonl").write_text(
-        "".join(json.dumps(r) + "\n" for r in lines))
-    env = {**os.environ, "PYTHONPATH": REPO}
-    return subprocess.run(
-        [sys.executable, str(script), "1"], env=env,
-        capture_output=True, text=True, timeout=120)
-
-
-def _calibrated_line(tmp_path):
-    store = str(tmp_path / "store.json")
-    m = _model()
-    entry = calibrate.reconcile(
-        m, {"source": "host_phase",
-            "device_s": 2 * 4096 / m["ceiling_qps_analytic"],
-            "nq": 4096})
-    calibrate.put(calibrate.key_for_block(m), entry, path=store)
-    os.environ[calibrate.CAL_ENV] = store
-    try:
-        att = roofline.attribute(_model(), 4096 / (
-            2 * 4096 / m["ceiling_qps_analytic"]))
-    finally:
-        os.environ.pop(calibrate.CAL_ENV, None)
-    return {"metric": "knn_qps_sift1m_n1000000_d128_k100",
-            "value": 4000.0, "mode": "certified_pallas",
-            "backend": "tpu", "device_kind": "TPU v5 lite",
-            "roofline": att}
-
-
-def test_refresh_curates_calibrated_line_and_prints_calib(tmp_path):
-    """A fresh line with an applied calibration curates:
-    model_residual_pct hoisted, calib=RESIDUAL% printed beside the
-    sentinel/roofline readout."""
-    r = _refresh(tmp_path, [_calibrated_line(tmp_path)])
-    assert r.returncode == 0, r.stderr
-    assert "calib=100.0%" in r.stdout
-    out = open(tmp_path / "TPU_BENCH_r01.jsonl").read()
-    rec = json.loads(out)
-    assert rec["model_residual_pct"] == pytest.approx(100.0, abs=0.1)
-
-
-def test_refresh_refuses_malformed_calibration_and_campaign(tmp_path):
-    """ACCEPTANCE pin (refresh refusal): a malformed calibration or
-    campaign block on a FRESH line kills the refresh instead of
-    poisoning the curated history."""
-    line = _calibrated_line(tmp_path)
-    line["roofline"]["calibration"] = {"applied": True,
-                                       "factors": "lol"}
-    r = _refresh(tmp_path, [line])
-    assert r.returncode != 0
-    # roofline validation sees the embedded calibration first; either
-    # refusal surface names the calibration as the reason
-    out = r.stdout + r.stderr
-    assert "refusing to emit" in out and "calibration" in out
-    line2 = _calibrated_line(tmp_path)
-    line2["campaign"] = {"arm": "x"}  # no version/stages/rehearse
-    r = _refresh(tmp_path, [line2])
-    assert r.returncode != 0
-    assert "malformed campaign block" in (r.stdout + r.stderr)
-
-
-def test_sentinel_lint_sweeps_calibration_blocks(tmp_path):
-    """perf_sentinel --lint validates calibration/campaign blocks in
-    history: well-formed passes, malformed fails."""
-    script = os.path.join(REPO, "scripts", "perf_sentinel.py")
-
-    def lint(lines):
-        (tmp_path / "TPU_BENCH_r01.jsonl").write_text(
-            "".join(json.dumps(r) + "\n" for r in lines))
-        return subprocess.run(
-            [sys.executable, script, "--lint", "--repo",
-             str(tmp_path)],
-            capture_output=True, text=True, timeout=120)
-
-    base = {"metric": "knn_qps_x_n1000_d16_k5", "value": 10.0,
-            "backend": "tpu", "measured_round": 1,
-            "measured_at_commit": "abc"}
-    good = roofline.attribute(
-        roofline.pallas_cost_model(n=1000, d=16, k=5, nq=8), 10.0)
-    r = lint([dict(base, roofline=good)])
-    assert r.returncode == 0, r.stderr
-    assert "1 calibration, 0 campaign validated" in r.stdout
-    bad = dict(good, calibration={"applied": True, "factors": {},
-                                  "source": "host_phase",
-                                  "model_residual_pct": "much"})
-    r = lint([dict(base, roofline=bad)])
-    assert r.returncode == 1
-    assert "calibration block" in r.stderr
+    assert "KNN_TPU_CALIBRATION" in isolation_names()
 
 
 # --- profiler: a real capture parses (slow) ----------------------------

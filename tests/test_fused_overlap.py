@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from knn_tpu import tuning
-from knn_tpu.obs import roofline, sentinel
+from knn_tpu.obs import roofline
 from knn_tpu.ops.pallas_knn import (
     BIN_W,
     KERNEL_VERSION,
@@ -346,26 +346,6 @@ def test_block_q_256_promoted_with_kernel_version_bump(rng):
                for c in grid)
     assert all(not (c["kernel"] == "fused"
                     and c["final_select"] == "approx") for c in grid)
-
-
-# --- bench/sentinel satellite -------------------------------------------
-
-
-def test_sentinel_device_phase_qps_reads_winner_breakdown():
-    """device_phase_qps is a curated sentinel field; lines curated
-    before the winning-mode hoist (top-level null, rate only inside the
-    winner's phase_breakdown) still enter baselines through the
-    fallback read."""
-    assert ("device_phase_qps", "higher") in sentinel.CURATED_FIELDS
-    rec = {"metric": "knn_qps_x_n1000_d16_k5", "value": 900.0,
-           "backend": "tpu", "mode": "exact", "device_phase_qps": None,
-           "selectors": {"exact": {"phase_breakdown":
-                                   {"device_qps": 1234.5}}}}
-    assert sentinel.curated_value(rec, "device_phase_qps") == 1234.5
-    hist = [dict(rec, measured_round=i + 1, measured_at_commit=f"c{i}",
-                 value=900.0 + i) for i in range(3)]
-    base = sentinel.build_baselines(hist)
-    assert "device_phase_qps" in base["knn_qps_x_n1000_d16_k5|tpu|default"]
 
 
 # --- cli roofline --best ------------------------------------------------

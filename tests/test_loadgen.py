@@ -287,33 +287,6 @@ def test_rates_around_brackets_anchor():
         rates_around(0)
 
 
-def test_sentinel_curates_knee_qps():
-    """knee_qps is a curated sentinel field: read top-level or out of
-    the loadgen_knee block, baselined like-for-like, regressions
-    flagged."""
-    from knn_tpu.obs import sentinel
-
-    assert ("knee_qps", "higher") in sentinel.CURATED_FIELDS
-    rec = {"metric": "m", "backend": "tpu",
-           "loadgen_knee": {"knee_qps": 123.0}}
-    assert sentinel.curated_value(rec, "knee_qps") == 123.0
-    assert sentinel.curated_value({"knee_qps": 7.0}, "knee_qps") == 7.0
-    history = [
-        {"metric": "m", "backend": "tpu", "value": 1.0, "knee_qps": 100.0,
-         "measured_at_commit": f"c{i}", "measured_round": i}
-        for i in range(4)
-    ]
-    baselines = sentinel.build_baselines(history)
-    fresh = {"metric": "m", "backend": "tpu", "value": 1.0,
-             "knee_qps": 50.0}
-    verdict = sentinel.verdict_for_line(fresh, baselines=baselines)
-    assert verdict["fields"]["knee_qps"]["verdict"] == "regress"
-    good = {"metric": "m", "backend": "tpu", "value": 1.0,
-            "knee_qps": 99.0}
-    verdict = sentinel.verdict_for_line(good, baselines=baselines)
-    assert verdict["fields"]["knee_qps"]["verdict"] == "ok"
-
-
 # -- write-stream mix (knn_tpu.index satellite) ---------------------------
 def test_write_mix_deterministic_and_replayable(tmp_path):
     spec = WorkloadSpec(
@@ -417,34 +390,6 @@ def test_driver_refuses_writes_against_writeless_target():
                             insert_fraction=1.0),))
     with pytest.raises(ValueError, match="submit_write"):
         run_workload(NoWrites(), generate(spec), queries=POOL)
-
-
-def test_sentinel_curates_mutation_admitted_p99():
-    from knn_tpu.obs import sentinel
-
-    assert ("mutation_admitted_p99_ms", "lower") \
-        in sentinel.CURATED_FIELDS
-    rec = {"metric": "m", "backend": "tpu",
-           "mutation": {"admitted_p99_ms": 12.5}}
-    assert sentinel.curated_value(rec, "mutation_admitted_p99_ms") \
-        == 12.5
-    history = [
-        {"metric": "m", "backend": "tpu", "value": 1.0,
-         "mutation_admitted_p99_ms": 10.0,
-         "measured_at_commit": f"c{i}", "measured_round": i}
-        for i in range(4)
-    ]
-    baselines = sentinel.build_baselines(history)
-    # lower is better: a p99 that DOUBLES regresses, one that halves
-    # reads ok
-    worse = {"metric": "m", "backend": "tpu", "value": 1.0,
-             "mutation_admitted_p99_ms": 25.0}
-    assert sentinel.verdict_for_line(worse, baselines=baselines)[
-        "fields"]["mutation_admitted_p99_ms"]["verdict"] == "regress"
-    better = {"metric": "m", "backend": "tpu", "value": 1.0,
-              "mutation_admitted_p99_ms": 9.5}
-    assert sentinel.verdict_for_line(better, baselines=baselines)[
-        "fields"]["mutation_admitted_p99_ms"]["verdict"] == "ok"
 
 
 # -- offline bulk-join lane (bulk kNN-join satellite) ---------------------

@@ -310,7 +310,7 @@ def test_sharded_radius_cosine(data):
 
 
 def test_cityblock_alias_matches_l1(data):
-    """ADVICE r5: 'cityblock' passes radius_threshold's eager validation
+    """'cityblock' passes radius_threshold's eager validation
     but used to die inside the search dispatch — the alias must now run,
     and run IDENTICALLY to 'l1' (same threshold, same dispatch)."""
     db, q = data

@@ -224,7 +224,7 @@ def no_backoff(monkeypatch):
 
 
 def test_deterministic_failures_are_not_retried(no_backoff):
-    # ADVICE r4: a Mosaic compile error / OOM is deterministic — retrying
+    # a Mosaic compile error / OOM is deterministic — retrying
     # it only adds ~3.5 s of backoff per batch before the real error
     # surfaces.  The signature classifier must propagate it on attempt 1.
     calls = {"n": 0}

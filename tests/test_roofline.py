@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 
 from knn_tpu import obs, tuning
-from knn_tpu.obs import health, roofline, sentinel
+from knn_tpu.obs import health, roofline
 from knn_tpu.obs import names as mn
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -135,13 +135,6 @@ def test_geometry_defaults_mirror_kernel_constants():
     # grouped default survivors=2 -> the out/bound widths the candidate
     # output term assumes
     assert out_w == surv * pk.BIN_W and bound_w == pk.BIN_W
-
-
-def test_bench_peak_table_is_a_view_over_roofline():
-    import bench
-
-    assert bench._PEAK_BY_KIND == roofline.bf16_peak_by_kind()
-    assert bench._PEAK_BY_KIND["TPU v5 lite"] == 197e12
 
 
 # --- MODEL_VERSION 6: the sub-int8 compressed tier ----------------------
@@ -411,67 +404,6 @@ def test_last_reports_store_is_bounded():
     # re-publish (and re-emit events) on every resolve
     assert "cpu|n0|d16|k5|l2|float32" not in roofline.last_reports()
     assert roofline.was_published("cpu|n0|d16|k5|l2|float32")
-
-
-def test_lint_skips_advisory_error_blocks_but_fails_malformed(tmp_path):
-    """scripts/perf_sentinel.py --lint: bench's advisory
-    {"error": ...} degradation blocks are a designed outcome (never a
-    CI failure); a structurally malformed block IS one."""
-    import subprocess
-    import sys
-
-    script = os.path.join(REPO, "scripts", "perf_sentinel.py")
-
-    def lint(lines):
-        (tmp_path / "TPU_BENCH_r01.jsonl").write_text(
-            "".join(json.dumps(r) + "\n" for r in lines))
-        return subprocess.run(
-            [sys.executable, script, "--lint", "--repo", str(tmp_path)],
-            capture_output=True, text=True, timeout=120)
-
-    good = roofline.attribute(
-        roofline.pallas_cost_model(n=1000, d=16, k=5, nq=8), 10.0)
-    base = {"metric": "knn_qps_x_n1000_d16_k5", "value": 10.0,
-            "backend": "tpu", "measured_round": 1,
-            "measured_at_commit": "abc"}
-    r = lint([dict(base, roofline=good),
-              dict(base, roofline={"error": "ValueError: model gap"})])
-    assert r.returncode == 0, r.stderr
-    assert "1 validated, 1 advisory-error blocks skipped" in r.stdout
-    r = lint([dict(base, roofline={"bound_class": "gpu_bound"})])
-    assert r.returncode == 1
-    assert "roofline block" in r.stderr
-
-
-# --- sentinel integration ----------------------------------------------
-
-
-def test_sentinel_judges_roofline_pct_as_a_curated_field():
-    """The sentinel's roofline_pct family: read off the top level or
-    out of the line's roofline block, judged like any curated field —
-    regressions are measured against the model's ceiling, not only
-    against raw-qps history."""
-    hist = []
-    for i, pct in enumerate((0.13, 0.131, 0.129, 0.132)):
-        hist.append({
-            "metric": "knn_qps_sift1m_n1000000_d128_k100",
-            "value": 6000.0 + i, "backend": "tpu",
-            "measured_round": i + 1, "measured_at_commit": f"c{i}",
-            # half hoisted, half block-only: both must enter
-            **({"roofline_pct": pct} if i % 2 else
-               {"roofline": {"roofline_pct": pct}}),
-        })
-    base = sentinel.build_baselines(hist)
-    key = "knn_qps_sift1m_n1000000_d128_k100|tpu|default"
-    assert "roofline_pct" in base[key]
-    fresh = {"metric": "knn_qps_sift1m_n1000000_d128_k100",
-             "backend": "tpu", "value": 6001.0,
-             "roofline": {"roofline_pct": 0.06}}
-    v = sentinel.verdict_for_line(fresh, baselines=base)
-    assert v["fields"]["roofline_pct"]["verdict"] == "regress"
-    fresh["roofline"]["roofline_pct"] = 0.13
-    v = sentinel.verdict_for_line(fresh, baselines=base)
-    assert v["fields"]["roofline_pct"]["verdict"] == "ok"
 
 
 # --- profiler ----------------------------------------------------------

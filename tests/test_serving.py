@@ -287,10 +287,10 @@ def test_queue_rejects_bad_dim_and_survives(served):
         assert np.array_equal(np.asarray(i0), i)
 
 
-# -- trace replay (the bench's serving mode, full size) --------------------
+# -- trace replay (a serving sweep, full size) ------------------------------
 @pytest.mark.slow
 def test_trace_replay_sustained_and_bounded(served):
-    """The bench.py serving sweep's shape: a log-uniform variable-batch
+    """A serving sweep's shape: a log-uniform variable-batch
     trace replayed with dispatch-ahead — sustained q/s, tail latency,
     and the compile bound all present and consistent."""
     prog, _, _ = served

@@ -17,7 +17,7 @@ kernel interpreted, at sizes a test can hold:
 - the cell ``ssnpp2m5.sweep_range`` through the whole benchmark harness,
   traced and not, and the broken timed paths coming out
   ``correct: false``; the cell's data files;
-- the two findings ``ADVICE.md`` held on the bounded radius path.
+- two review findings on the bounded radius path.
 """
 
 import json
@@ -713,7 +713,7 @@ def test_the_completions_work_counts_the_long_queries_once():
         nbytes / 819e9)
 
 
-# --- ADVICE.md's two findings on the bounded radius path ----------------------
+# --- two review findings on the bounded radius path ---------------------------
 def test_a_cityblock_estimator_predicts_as_an_l1_one():
     """``ops/radius.py`` accepts 'cityblock' in validation and
     ``_dispatch_metric`` names it 'l1' before any dispatch: an estimator
