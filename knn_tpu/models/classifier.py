@@ -85,13 +85,23 @@ class KNNClassifier:
         kneighbors runs the sharded SPMD program (parallel.ShardedKNN).
         None = single-device jitted path (identical results).
       merge: db-axis merge strategy when meshed ('allgather' | 'ring').
-      mode: 'exact' | 'certified' (meshed, l2 or cosine) — certified runs
-        the coarse+certificate pipeline; neighbor indices (and hence
-        labels) are still exact (cosine: for the f32-row-normalized
-        problem, see ShardedKNN.search_certified).
+      mode: 'exact' | 'certified' (meshed, l2 or cosine).  Certified
+        predictions are certified CLASSES
+        (ShardedKNN.predict_certified): with ``vote='majority'`` the
+        exact ranked neighbours (float64 lexicographic order of the rows
+        as given) and the reference's first-to-reach vote over them;
+        with ``vote='softmax'`` (cosine) the class of the largest
+        float64 total of ``exp(similarity / temperature)`` over the exact
+        k nearest rows, which ``selector='pallas'`` votes and certifies
+        on the device (a certificate over the k-th / (k+1)-th boundary
+        and the class margins; the host re-votes in float64 only the
+        queries it flags).  ``kneighbors`` is search_certified's.
       selector: coarse selector for certified mode ('approx' | 'pallas' |
         'exact').  The pallas selector returns f32-accurate kneighbors
         distances (see ShardedKNN.search_certified); the others float64.
+      vote, temperature: certified mode's vote ('majority' | 'softmax')
+        and the softmax vote's temperature; passed through to
+        ShardedKNN.predict_certified.
     """
 
     def __init__(
@@ -107,9 +117,17 @@ class KNNClassifier:
         merge: str = "allgather",
         mode: str = "exact",
         selector: str = "approx",
+        vote: str = "majority",
+        temperature: Optional[float] = None,
     ):
         if mode not in ("exact", "certified"):
             raise ValueError(f"unknown mode {mode!r}")
+        if mode != "certified" and (vote != "majority"
+                                    or temperature is not None):
+            raise ValueError(
+                "vote and temperature belong to mode='certified' "
+                "(ShardedKNN.predict_certified); the exact modes run the "
+                "reference's majority vote")
         if mode == "certified" and mesh is None:
             raise ValueError("mode='certified' needs a mesh (make_mesh(1, 1) is fine)")
         if mode == "certified" and metric not in ("l2", "sql2", "euclidean",
@@ -127,6 +145,8 @@ class KNNClassifier:
         self.merge = merge
         self.mode = mode
         self.selector = selector
+        self.vote = vote
+        self.temperature = temperature
         self._train = None
         self._labels = None
         self._mins = None
@@ -203,11 +223,13 @@ class KNNClassifier:
         Q = self._prep_queries(Q)
         if self._program is not None:
             if self.mode == "certified":
-                labels, _ = self._program.predict_certified(
-                    np.asarray(Q), selector=self.selector,
+                # (labels, stats), or (classes [Q, 1], totals, stats)
+                labels, *_ = self._program.predict_certified(
+                    np.asarray(Q), vote=self.vote,
+                    temperature=self.temperature, selector=self.selector,
                     batch_size=self.batch_size,
                 )
-                return jnp.asarray(labels)
+                return jnp.asarray(labels).reshape(-1)
             return self._batched(Q, self._program.predict, 1)
         return self._batched(
             Q,

@@ -76,6 +76,7 @@ CERTIFIED_RANK_CORRECTED = "knn_tpu_certified_rank_corrected_queries_total"
 CERTIFIED_METRIC_QUERIES = "knn_tpu_certified_metric_queries_total"
 CERTIFIED_SLACK_QUERIES = "knn_tpu_certified_slack_queries_total"
 RANK_CORRECT_MEMBERS = "knn_tpu_rank_correct_members_total"
+VOTE_QUERIES = "knn_tpu_vote_queries_total"
 CERTIFIED_QUANT_BOUND = "knn_tpu_certified_quant_bound"
 RANGE_QUERIES = "knn_tpu_range_queries_total"
 RANGE_RESULTS = "knn_tpu_range_results_total"
@@ -301,6 +302,20 @@ CATALOG = {
         "certificate fails without the slack too, the tie window has no "
         "provable boundary, or a zero row is among the candidates).  "
         "Every outcome exists from the first such call, at 0 where "
+        "nothing took it."),
+    VOTE_QUERIES: (
+        "counter", ("outcome",),
+        "Queries of ShardedKNN.predict_certified(vote='softmax'), by who "
+        "answered: 'device' (the device's vote stood: its certificate "
+        "flagged nothing); 'boundary' (the k-th and (k+1)-th candidates "
+        "too close to tell apart: the host took the float64 first k of "
+        "the query's window and re-voted); 'margin' (two adjacent class "
+        "totals within 2 vote_delta: the host re-voted the first k in "
+        "float64); 'fallback' (uncertified: re-voted from the repair's "
+        "neighbours); 'host' (a counted selector's call: every query "
+        "voted on the host from float64 neighbours).  A query counts "
+        "under the first of fallback, boundary, margin that holds; every "
+        "outcome exists from the first selector='pallas' call, at 0 where "
         "nothing took it."),
     RANK_CORRECT_MEMBERS: (
         "counter", (),

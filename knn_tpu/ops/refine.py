@@ -429,3 +429,54 @@ def refine_shared_exact(
     positions = np.asarray(positions, dtype=np.int64).reshape(-1)
     cand = np.broadcast_to(positions, (queries.shape[0], positions.shape[0]))
     return refine_exact(db, queries, cand, k, metric)
+
+
+def vote_exact(labels: np.ndarray, c: np.ndarray, temperature: float,
+               classes_out: int) -> Tuple[np.ndarray, np.ndarray]:
+    """The weighted vote in float64 from scored neighbours: ``labels``
+    [F, k] and cosine distances ``c`` [F, k] in rank order; every
+    neighbour adds ``exp((1 - c) / temperature)`` to its label's total
+    (one at +inf, padding, adds nothing).  Returns (classes [F,
+    classes_out] int32, totals [F, classes_out] float64): the classes with
+    a total above 0 in lexicographic (-total, class) order, padded with
+    class -1 at total 0.  A class's total is summed one neighbour at a
+    time in rank order, so equal rows under equal labels give equal
+    totals to the bit."""
+    labels = np.asarray(labels, np.int64)
+    n_f, k = labels.shape
+    w = np.exp((1.0 - c) / float(temperature))
+    w[~np.isfinite(c)] = 0.0
+    same = labels[:, :, None] == labels[:, None, :]
+    totals = np.zeros((n_f, k))
+    for b in range(k):
+        totals += np.where(same[:, :, b], w[:, b, None], 0.0)
+    stands = ~(same & np.tri(k, k, -1, dtype=bool)).any(-1) & (totals > 0)
+    neg = np.where(stands, -totals, np.inf)
+    cls = np.where(stands, labels, np.iinfo(np.int64).max)
+    if classes_out > k:
+        pad = ((0, 0), (0, classes_out - k))
+        neg = np.pad(neg, pad, constant_values=np.inf)
+        cls = np.pad(cls, pad, constant_values=np.iinfo(np.int64).max)
+    order = np.lexsort((cls, neg), axis=-1)[:, :classes_out]
+    neg = np.take_along_axis(neg, order, axis=-1)
+    cls = np.take_along_axis(cls, order, axis=-1)
+    there = neg < np.inf
+    return (np.where(there, cls, -1).astype(np.int32),
+            np.where(there, -neg, 0.0))
+
+
+def revote_exact(db: np.ndarray, queries: np.ndarray, cand_idx: np.ndarray,
+                 labels: np.ndarray, k: int, temperature: float,
+                 classes_out: int, norms=None
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The float64 answer of the weighted vote for queries whose device
+    vote was flagged: the exact lexicographic (cosine distance, index)
+    top-k among each query's candidates ``cand_idx`` [F, m >= k]
+    (:func:`refine_exact` on the rows and queries as given, ``norms``
+    their float64 norms), then :func:`vote_exact` over those k.  Returns
+    (classes, totals, the k members' indices [F, k])."""
+    c, members = refine_exact(db, queries, cand_idx, k, "cosine", norms)
+    classes, totals = vote_exact(
+        labels[np.minimum(members, labels.shape[0] - 1)], c, temperature,
+        classes_out)
+    return classes, totals, members

@@ -202,6 +202,9 @@ _MERGES = crossover.STRATEGIES
 #: one-pass self-certifying kernel program (_pallas_certified_program) —
 #: it never reaches _local_topk/_knn_program.
 SELECTORS = ("exact", "approx", "pallas")
+#: ``predict_certified``'s votes: the reference's unweighted majority and
+#: the k-NN evaluation protocol's weighted one
+VOTES = ("majority", "softmax")
 
 
 def _local_topk(q, t, k, metric, n_train, train_tile, compute_dtype, selector,
@@ -526,16 +529,25 @@ _PALLAS_PIECES = (_refine.PHASE_BUFFERS, _refine.PHASE_SCORE,
 _PALLAS_STAGES = ("certified.dispatch", "certified.device_wait",
                   "certified.d2h", "certified.unpack",
                   "certified.rank_correct")
+#: a voted call's (``predict_certified(vote="softmax")``) host stage in
+#: ``certified.rank_correct``'s place: the float64 re-vote of the
+#: flagged queries, once a sub-batch and once more for the uncertified
+_VOTE_STAGE = "certified.vote_repair"
 
 
 def _call_account(selector: str, *more: str):
     """The account of one outermost certified call: the device programs
     a call of this ``selector`` can launch (``more``: those of the call
-    it is the first pass of), and the pieces it sums."""
+    it is the first pass of, or a voted call's second read), and the
+    pieces it sums (a voted call's ``certified.vote_repair`` among its
+    stages: it records every stage of the search's too, at 0.0 where it
+    has none, so a reader never finds a series missing)."""
     if selector == "pallas":
+        stages = _PALLAS_STAGES + (
+            (_VOTE_STAGE,) if "vote_rows" in more else ())
         return obs.trace.call_account(
             _ACCOUNT_ROOT, ("certified", "reselect") + more, _PALLAS_PIECES,
-            dict.fromkeys(_PALLAS_STAGES, _CALL_SPAN))
+            dict.fromkeys(stages, _CALL_SPAN))
     return obs.trace.call_account(
         _ACCOUNT_ROOT, ("counted", "count", "reselect") + more)
 
@@ -629,6 +641,46 @@ def _row_normalize_f64(x: np.ndarray) -> np.ndarray:
 #: of zero norm has no direction: it is placed as it is (D' = |q^|^2,
 #: about 1) and ranked by the host at cosine 0 (search_certified).
 COS_UNIT_SLACK = 2.0 ** -20
+
+#: the weighted vote on a cosine placement (``predict_certified(vote=
+#: "softmax")``): the most by which the device's cosine distance c32 =
+#: d32 / 2 of a candidate can differ from the float64 c of the rows as
+#: given.  d32 is within RANK_SLACK D' / 3 of the exact placed distance
+#: D' = 2 c + p_t, |p_t| < 2^-21 (1 + 2^-7) (above), so
+#:   |c32 - c| <= 2^-19 (2 c + 2^-20) / 3 + 2^-22 (1 + 2^-7)
+#:             <  2^-18 (c + 1/8),
+#: the bound ``search_certified`` states for its cosine distances, and
+#: with c <= 2 under ``VOTE_COS_ERR`` for every candidate.
+VOTE_COS_ERR = 2.0 ** -18 * (2.0 + 0.125)
+#: float32 roundings allowed a weight besides its distance's error, in
+#: units of 2^-24: the device's ``exp`` itself (ops.vote.exp_weight, 2
+#: ulp: float32 multiplies and adds, not the chip's transcendental unit)
+#: and the comparison of two totals (a difference and a product); the argument's own two roundings
+#: (1 / T as float32, then c32 times it, |argument| <= 2 / T) are counted
+#: in :func:`vote_delta` as ``4 * 2^-24 / T``.
+_VOTE_EXP_ULPS = 32
+#: the least temperature the device's float32 weights can hold: a
+#: device weight is exp(-c32 / T) (the factor exp(1 / T) is the host's,
+#: in float64), at least exp(-2 / T), and exp(-64) is a normal float32.
+VOTE_MIN_TEMPERATURE = 2.0 ** -5
+
+
+def vote_delta(temperature: float, k: int) -> float:
+    """The relative bound ``delta`` of the vote certificate's margin
+    test: a device total S32 of a class (a float32 sum of at most ``k``
+    weights ``exp(-c32 / T)``) is within ``delta * S`` of the float64
+    total S of the same neighbours (both without the factor ``exp(1 /
+    T)``, which the host applies in float64).  A weight's distance is off
+    by at most ``VOTE_COS_ERR``, so the weight by a factor within
+    ``exp(+-VOTE_COS_ERR / T)``; its argument's roundings add ``4 * 2^-24
+    / T``, the ``exp`` and the float32 sum of k terms ``(k +
+    _VOTE_EXP_ULPS) 2^-24``.  1.2e-4 at T = 0.07, k = 20.  Two totals a >=
+    b on the device with ``a - b > 2 delta a`` have ``A (1 - delta) > B
+    (1 + delta)`` in float64: the classes rank as the device ranked
+    them."""
+    return (float(np.expm1(VOTE_COS_ERR / temperature))
+            + 4 * 2.0 ** -24 / temperature
+            + (k + _VOTE_EXP_ULPS) * 2.0 ** -24)
 
 
 def _unit_rows(x: np.ndarray):
@@ -1042,7 +1094,7 @@ class ShardedKNN:
         #: (each build AOT-compiles executables — seconds on hardware)
         self._serving_engines: dict = {}
         self._engines_lock = threading.Lock()
-        self._labels = None
+        self._labels = self._labels_host = self._vote_labels_dev = None
         self.num_classes = num_classes
         if labels is not None:
             if num_classes is None:
@@ -1053,6 +1105,8 @@ class ShardedKNN:
                     f"labels shape {labels.shape} != (n_train,) = ({n_train},)"
                 )
             self._labels = replicate(labels, mesh)  # the reference's Bcast
+            #: the host's copy, kept: ``predict_certified`` votes from it
+            self._labels_host = labels
 
     @property
     def db_shards(self) -> int:
@@ -2888,7 +2942,7 @@ class ShardedKNN:
                       call_rows: Optional[int] = None,
                       trace_id: Optional[str] = None,
                       acct=obs.trace.NOOP_ACCOUNT,
-                      masked: bool = False):
+                      masked: bool = False, vote=None):
         """(program, m, analysis_window, interpret) for the one-pass
         certified path — the ONE home of the kernel-geometry margin cap
         and the packed-output window, shared by :meth:`_certify_pallas`
@@ -2933,7 +2987,11 @@ class ShardedKNN:
 
         ``masked`` builds the program that takes a batch's validity
         words after that tail (a ``filter_tags`` call); the resolved row
-        tile, whose layout the words are in, is ``self._kernel_tile``."""
+        tile, whose layout the words are in, is ``self._kernel_tile``.
+
+        ``vote`` (``predict_certified(vote="softmax")``: ``(1 / T,
+        classes_out, delta)``) builds :func:`_pallas_vote_program` from
+        the same resolution: the device labels follow the tail."""
         from knn_tpu.ops.pallas_knn import (
             BLOCK_Q,
             TILE_N,
@@ -3016,6 +3074,18 @@ class ShardedKNN:
         # tile the kernel runs is provably the tile this m-cap assumed
         # (ADVICE r4: the raw-tile plumbing let the two diverge on small
         # padded dbs where m is capped by n_train)
+        if vote is not None:
+            prog = _pallas_vote_program(
+                self.mesh, m, self.k, self.merge, eff_tile, precision,
+                self.n_train, vote, survivors=survivors, block_q=block_q,
+                final_select=final_select,
+                final_recall_target=final_recall_target,
+                grid_order=grid_order, kernel=kernel,
+                quant_offset=quant_offset, dcn_merge=self.dcn_merge,
+                interpret=interpret, terms=terms,
+                row_block=self._row_blocking[0],
+                resident_parts=len(resident) - 1 if resident else 0)
+            return prog, m, _analysis_window(self.k, m), interpret
         prog = _pallas_certified_program(
             self.mesh, m, self.k, self.merge, eff_tile, precision,
             n_train=self.n_train, survivors=survivors,
@@ -3177,27 +3247,446 @@ class ShardedKNN:
         return np.flatnonzero(bad_mask), n_corrected, n_by_slack
 
     def predict_certified(
-        self, queries, *, margin: int = 28, selector: str = "approx",
+        self, queries, *, vote: str = "majority",
+        temperature: Optional[float] = None, classes_out: int = 1,
+        margin: int = 28, selector: str = "approx",
         batch_size: Optional[int] = None, tile_n: Optional[int] = None,
         precision: Optional[str] = None, kernel: Optional[str] = None,
         tune_cache: Optional[str] = None,
     ):
-        """Certified-exact classification: exact neighbor sets from
-        :meth:`search_certified`, then the reference vote (ops.vote).
-        Returns (labels [Q] int32, stats).  Kernel knobs left at None
+        """Certified-exact classification.  Kernel knobs left at None
         resolve through ``knn_tpu.tuning`` exactly like
-        :meth:`search_certified`."""
+        :meth:`search_certified`.
+
+        ``vote="majority"`` (the reference's): exact neighbour lists from
+        :meth:`search_certified`, then the reference vote (ops.vote), whose
+        first-to-reach tie-break reads the ORDER of the neighbours, so it
+        keeps the ranked path.  Returns (labels [Q] int32, stats).
+
+        ``vote="softmax"`` (a cosine placement; ``temperature`` T): the
+        weighted vote of the k-NN evaluation protocol.  With c_i the
+        float64 cosine distance of row i as given and N_k(q) the first k
+        rows in lexicographic (c_i, i) order, class c's total is ``s_c =
+        sum of exp((1 - c_i) / T) over i in N_k(q) with label c``, and the
+        answer is the classes with s_c > 0 in lexicographic (-s_c, c)
+        order, the first ``classes_out``, padded with -1, and their
+        totals.  Returns (classes [Q, classes_out] int32, totals [Q,
+        classes_out] float64, stats).  The CLASSES equal that float64
+        answer for every query, whatever the selector.
+
+        With ``selector="pallas"`` the answer is made and certified ON THE
+        DEVICE, by the certified program's own tail (the placement, the
+        kernel, the select, the exclusion and merge-drop certificate and
+        the sub-batch rule are :meth:`search_certified`'s; the tail ends
+        in ``_vote_pack``): labels of the first k merged candidates
+        gathered from the replicated device labels, float32 weights
+        ``exp(-c32 / T)`` from the device's direct-difference distances
+        (the factor ``exp(1 / T)`` every weight shares is the host's, in
+        float64), class totals, the first
+        ``classes_out + 1`` of them, and one flag word a query from the
+        vote certificate (``_certify_pack_spmd``'s docstring):
+        ``boundary`` where the k-th and (k+1)-th candidates are too close
+        to tell apart, ``margin`` where two adjacent class totals are
+        within ``2 * vote_delta(T, k)`` of each other.  What comes to the
+        host in every call is ``2 classes_out + 1`` words a query; the
+        ranked candidates stay on the device, and only the flagged
+        queries' windows are read, by a second read (``window[rows]``, a
+        power-of-two bucket of rows), because one query in some tens is
+        flagged and a window is ``min(k + 17, m + 1)`` words.  An
+        unflagged query is answered by the device's classes, its totals
+        the device's float32 values (within ``vote_delta`` of the
+        float64 ones, relatively).  A flagged one is re-voted by the host
+        in float64 from the rows as given and their float64 norms: over
+        its whole window where the boundary was in doubt (the float64
+        first k of the window ARE the neighbours: every row outside it is
+        proven farther), over its first k where only a margin was; an
+        uncertified one after :func:`ops.certified.repair_uncertified`
+        gave its neighbours.  Those totals are float64.  The span
+        ``certified.vote_repair``, one a call, is that host work
+        (``queries``, ``members``).
+
+        The other selectors vote on the host from
+        :meth:`search_certified`'s float64 neighbours (totals float64).
+        Any mesh: the labels are replicated and the merge is the
+        search's.  Metrics other than cosine refuse ``vote="softmax"``: a
+        weight is ``exp(cosine similarity / T)``.
+
+        ``stats``: the search's, and ``vote``, ``temperature``,
+        ``classes_out``, ``vote_boundary_queries``,
+        ``vote_margin_queries`` (a query counts under the first of
+        fallback, boundary, margin that holds), ``vote_repaired_queries``
+        and ``vote_delta``."""
         if self._labels is None:
             raise RuntimeError("ShardedKNN built without labels; predict unavailable")
-        _, idx, stats = self.search_certified(
-            queries, margin=margin, selector=selector, batch_size=batch_size,
-            tile_n=tile_n, precision=precision, kernel=kernel,
-            tune_cache=tune_cache,
-            return_distances=False,  # labels only: skip the d transfer
-        )
-        labels_host = np.asarray(self._labels)
-        votes = majority_vote(jnp.asarray(labels_host[idx]), self.num_classes)
-        return np.asarray(votes), stats
+        if vote not in VOTES:
+            raise ValueError(f"unknown vote {vote!r}; expected {VOTES}")
+        knobs = dict(margin=margin, batch_size=batch_size, tile_n=tile_n,
+                     precision=precision, kernel=kernel,
+                     tune_cache=tune_cache)
+        if vote == "majority":
+            if temperature is not None or classes_out != 1:
+                raise ValueError(
+                    "vote='majority' is the reference's unweighted vote: it "
+                    "takes no temperature and answers one label a query")
+            _, idx, stats = self.search_certified(
+                queries, selector=selector,
+                return_distances=False,  # labels only: skip the d transfer
+                **knobs)
+            votes = majority_vote(jnp.asarray(self._labels_host[idx]),
+                                  self.num_classes)
+            return np.asarray(votes), stats
+        if self.metric != "cosine":
+            raise ValueError(
+                f"vote='softmax' weighs a neighbour by exp(cosine similarity "
+                f"/ T): a cosine placement only, this one is "
+                f"{self.metric!r} (normalise the rows yourself and the "
+                f"weights are those of the rounded unit rows)")
+        if temperature is None or not temperature >= VOTE_MIN_TEMPERATURE:
+            raise ValueError(
+                f"vote='softmax' needs temperature >= "
+                f"{VOTE_MIN_TEMPERATURE} (a device weight is exp(-c / T) in "
+                f"float32, c up to 2), got {temperature!r}")
+        if not 1 <= classes_out <= self.k:
+            raise ValueError(
+                f"classes_out must be in [1, k={self.k}], got {classes_out}")
+        temperature, classes_out = float(temperature), int(classes_out)
+        told = {"vote": vote, "temperature": temperature,
+                "classes_out": classes_out}
+        if selector == "pallas":
+            return self._vote_certified(queries, told, **knobs)
+        from knn_tpu.ops.refine import vote_exact
+
+        d, idx, stats = self.search_certified(queries, selector=selector,
+                                              **knobs)
+        classes, totals = vote_exact(self._labels_host[idx], d, temperature,
+                                     classes_out)
+        obs.counter(_mn.VOTE_QUERIES, outcome="host").inc(idx.shape[0])
+        return classes, totals, {
+            **stats, **told, "vote_boundary_queries": 0,
+            "vote_margin_queries": 0, "vote_repaired_queries": idx.shape[0]}
+
+    def _vote_labels(self):
+        """The labels the vote program gathers from: the replicated
+        device labels, a zero row's as ``-1 - label`` (the device sees
+        such a row at half its distance; ``_vote_pack`` flags a query
+        that has one in its window).  Built by the first call."""
+        if self._vote_labels_dev is None:
+            marked = self._labels_host
+            if self._cos_zero_rows.size:
+                marked = marked.copy()
+                marked[self._cos_zero_rows] = -1 - marked[self._cos_zero_rows]
+                self._vote_labels_dev = replicate(marked, self.mesh)
+            else:
+                self._vote_labels_dev = self._labels
+        return self._vote_labels_dev
+
+    def _vote_certified(self, queries, told: dict, *, margin, batch_size,
+                        tile_n, precision, kernel, tune_cache):
+        """``predict_certified(vote="softmax", selector="pallas")``: the
+        pallas branch of :meth:`search_certified` with the vote program in
+        the certified program's place (one call span, the same prepare,
+        setup, sub-batches, dispatch-all-then-fetch and fallback repair),
+        and :meth:`_vote_pallas` for its host stage.  A body of its own so
+        that ``search_certified``'s frame stays what the trace-stack
+        tripwire recorded."""
+        from knn_tpu import tuning
+        from knn_tpu.ops.certified import repair_uncertified
+        from knn_tpu.ops.refine import vote_exact
+
+        self._require_resident("predict_certified")
+        temperature, classes_out = told["temperature"], told["classes_out"]
+        vote = (1.0 / temperature, classes_out,
+                vote_delta(temperature, self.k))
+        tid = obs.new_trace_id()
+        slack = self._pair_slack()
+        with obs.span(_CALL_SPAN, tid, selector="pallas", **told) as call:
+            acct = _call_account("pallas", "vote_rows")
+            host_q = np.asarray(queries, dtype=np.float32)
+            map_s = {"before_s": 0.0, "after_s": 0.0}
+            with obs.trace.phase(map_s, "before_s", _METRIC_SPAN):
+                # the unit queries matching the placed unit rows
+                q_np, q_norms, _, _ = _unit_rows(host_q)
+                norms = (q_norms, self._cos_norms)
+            with obs.span("certified.prepare", tid, parent=_CALL_SPAN,
+                          first_call=self._db_norm_max_cache is None):
+                n_q = q_np.shape[0]
+                shard_rows = self._shard_rows()
+                m = min(self.k + margin, self.n_train, shard_rows)
+                db_np = self._host_train()
+                if batch_size is not None and batch_size < 1:
+                    raise ValueError(
+                        f"batch_size must be >= 1, got {batch_size}")
+                db_norm_max = self._db_norm_max()
+                knobs, tune_info = tuning.resolve_full(
+                    self.n_train, self._given_width, self.k, metric="l2",
+                    dtype=self._dtype_key, cache_path=tune_cache,
+                    overrides=dict(tile_n=tile_n, precision=precision,
+                                   kernel=kernel))
+                terms = self._kernel_terms(q_np, knobs["precision"])
+                prog, m_prog, w, interpret = self._pallas_setup(
+                    m - self.k, include_distances=False, terms=terms,
+                    batch_rows=batch_size, call_rows=n_q, trace_id=tid,
+                    acct=acct, vote=vote, **knobs)
+                bs, sub_why = self._sub_batch
+                ops_tail = (self._pallas_operands(knobs["precision"])
+                            + (self._vote_labels(),))
+                batches = []
+                for lo in range(0, n_q, bs):
+                    chunk = q_np[lo : lo + bs]
+                    pad = bs - chunk.shape[0]
+                    if pad:  # one compiled shape for the tail too
+                        chunk = np.pad(chunk, ((0, pad), (0, 0)))
+                    batches.append((lo, chunk, pad))
+            call.set("queries", n_q)
+            call.set("batches", len(batches))
+            call.set("metric", self.metric)
+            call.set("pair_slack", slack)
+            q_shards = self.mesh.shape[QUERY_AXIS]
+            merge_bytes = self._record_merge_bytes(
+                len(batches) * (-(-bs // q_shards) * q_shards), m_prog + 1)
+            classes = np.empty((n_q, classes_out), np.int32)
+            totals = np.empty((n_q, classes_out))
+            # neighbours of the queries the host re-votes; the rest stay
+            # on the device
+            i = np.full((n_q, self.k), -1, np.int64)
+            flags = self._vote_pallas(
+                batches, bs, classes, totals, i, host_q, db_np, norms,
+                prog=prog, w=w, ops_tail=ops_tail, told=told, trace_id=tid,
+                acct=acct)
+            bad = np.flatnonzero(flags & VOTE_BAD)
+            d = np.empty((n_q, self.k))
+
+            def _select(qb, widen):
+                # search_certified's widened exact re-select, in f32
+                exact = _knn_program(
+                    self.mesh, widen, "l2", self.merge, self.n_train,
+                    self.train_tile, None, "exact",
+                    dcn_merge=self.dcn_merge)
+                nonlocal merge_bytes
+                with obs.span("certified.repair.reselect", tid,
+                              parent="certified.repair", widen=widen,
+                              rows=qb.shape[0]):
+                    bq, _ = self._place_queries(qb)
+                    merge_bytes += self._record_merge_bytes(
+                        bq.shape[0], widen)
+                    begun = _hooks.first_call_begin()
+                    fs, fi = exact(bq, self._tp)
+                    acct.launched("reselect")
+                    _hooks.first_call_end(begun, exact, "reselect", tid,
+                                          rows=bq.shape[0])
+                    fs = np.asarray(fs)
+                    acct.ready("reselect")
+                    return fs[: qb.shape[0]], np.asarray(fi)[: qb.shape[0]]
+
+            with obs.span("certified.repair", tid, parent=_CALL_SPAN,
+                          fallback_queries=int(bad.size)) as sp:
+                repair = repair_uncertified(
+                    d, i, self.k, m, bad, q_np, db_np, select_fn=_select,
+                    max_widen=min(self.n_train, shard_rows),
+                    db_norm_max=db_norm_max, metric="cosine",
+                    pair_slack=slack, rank_queries=host_q, norms=norms)
+                sp.set("host_exact_queries",
+                       repair.get("host_exact_queries", 0))
+            if bad.size:
+                # the float64 vote over the neighbours the repair proved
+                with obs.trace.stage(acct, "certified.vote_repair",
+                                     queries=int(bad.size),
+                                     members=int(bad.size) * self.k):
+                    classes[bad], totals[bad] = vote_exact(
+                        self._labels_host[np.minimum(i[bad],
+                                                     self.n_train - 1)],
+                        d[bad], temperature, classes_out)
+            boundary = (flags & (VOTE_BAD | VOTE_BOUNDARY)) == VOTE_BOUNDARY
+            by_margin = (flags & (VOTE_BAD | VOTE_BOUNDARY | VOTE_MARGIN)
+                         ) == VOTE_MARGIN
+            n_by_slack = int(((flags & VOTE_BY_SLACK) != 0).sum())
+            counts = {"device": n_q - int(bad.size) - int(boundary.sum())
+                      - int(by_margin.sum()),
+                      "boundary": int(boundary.sum()),
+                      "margin": int(by_margin.sum()),
+                      "fallback": int(bad.size)}
+            merged = self._pallas_call_stats(terms, sub_why, len(batches),
+                                             merge_bytes)
+            stats = {
+                "fallback_queries": int(bad.size),
+                "certified": n_q - int(bad.size),
+                "batches": len(batches),
+                "metric": self.metric,
+                "pair_slack": slack,
+                **repair, **merged, **told,
+                "vote_boundary_queries": counts["boundary"],
+                "vote_margin_queries": counts["margin"],
+                "vote_repaired_queries": n_q - counts["device"],
+                "vote_delta": vote[2],
+                # the search's key: a vote ranks nothing on the host
+                "rank_corrected_queries": 0,
+                "slack_fallback_queries": n_by_slack,
+                "pallas_knobs": {
+                    **knobs, "interpret": interpret, "terms": terms,
+                    **{key: merged[key] for key in (
+                        "mxu_passes", "dim_chunk", "dim_chunks", "row_block",
+                        "row_steps", "final_select_stage", "operands",
+                        "sub_batch")},
+                    "batches": len(batches)},
+                "tuning": tune_info,
+            }
+            for key in (*merged, "vote_boundary_queries",
+                        "vote_margin_queries", "slack_fallback_queries"):
+                call.set(key, stats[key])
+            for outcome, n_out in counts.items():
+                obs.counter(_mn.VOTE_QUERIES, outcome=outcome).inc(n_out)
+            obs.counter(_mn.VOTE_QUERIES, outcome="host").inc(0)
+            obs.counter(_mn.CERTIFIED_QUERIES, selector="pallas").inc(n_q)
+            obs.counter(_mn.CERTIFIED_METRIC_QUERIES,
+                        metric=self.metric).inc(n_q)
+            obs.counter(_mn.CERTIFIED_FALLBACKS, selector="pallas").inc(
+                int(bad.size))
+            obs.counter(_mn.CERTIFIED_GENUINE_MISSES, selector="pallas").inc(
+                repair.get("fallback_genuine_misses", 0))
+            obs.counter(_mn.CERTIFIED_FALSE_ALARMS, selector="pallas").inc(
+                repair.get("fallback_false_alarms", 0))
+            obs.counter(_mn.CERTIFIED_HOST_EXACT, selector="pallas").inc(
+                repair.get("host_exact_queries", 0))
+            for outcome, n_out in (
+                    ("certified", n_q - int(bad.size)),
+                    ("uncertified", int(bad.size) - n_by_slack),
+                    ("uncertified_by_slack", n_by_slack)):
+                obs.counter(_mn.CERTIFIED_SLACK_QUERIES,
+                            outcome=outcome).inc(n_out)
+            obs.record_span(_METRIC_SPAN, tid, sum(map_s.values()),
+                            parent=_CALL_SPAN, metric=self.metric, **map_s)
+            acct.close(tid, _CALL_SPAN)
+            return classes, totals, stats
+
+    def _pallas_call_stats(self, terms: str, sub_why: str, n_batches: int,
+                           merge_bytes: int) -> dict:
+        """What :meth:`_pallas_setup` resolved for the call that just
+        ran, as ``search_certified`` reports it on the call's event and
+        in ``stats`` (the same keys, the same counters), for the vote
+        call: which merge, the select's widths, the kernel's products,
+        how it cut a row tile, what ran the final select, where the row
+        operands came from and how the call was cut."""
+        width, merged_width = self._select_widths
+        merged = {
+            "db_shards": self.db_shards, "merge": self.merge,
+            "merge_source": self.merge_source, "merge_bytes": merge_bytes,
+            "select_width": width, "select_merged_width": merged_width,
+            "terms": terms, "mxu_passes": terms.count("+") + 1,
+            "dim_chunk": self._dim_chunking[0],
+            "dim_chunks": self._dim_chunking[1],
+            "row_block": self._row_blocking[0],
+            "row_steps": self._row_blocking[1],
+            "final_select_stage": self._final_select_stage,
+            "operands": self._operands_source, "sub_batch": sub_why}
+        obs.counter(_mn.SELECT_MERGE_CALLS,
+                    engaged="true" if merged_width < width else "false"
+                    ).inc(n_batches)
+        obs.counter(_mn.KERNEL_TERMS, terms=terms).inc(n_batches)
+        obs.counter(_mn.KERNEL_DIM_CHUNKS, chunks=str(merged["dim_chunks"]),
+                    row_steps=str(merged["row_steps"])).inc(n_batches)
+        obs.counter(_mn.FINAL_SELECT_CALLS,
+                    stage=self._final_select_stage).inc(n_batches)
+        obs.counter(_mn.KERNEL_OPERANDS,
+                    source=self._operands_source).inc(n_batches)
+        obs.counter(_mn.CERTIFIED_SUB_BATCH_CALLS, why=sub_why).inc()
+        return merged
+
+    def _vote_pallas(self, batches, bs, classes, totals, i, host_q, db_np,
+                     norms, *, prog, w, ops_tail, told, trace_id, acct):
+        """The host side of a voted call, :meth:`_certify_pallas`'s
+        shape: every sub-batch's program is launched before the first is
+        fetched; per sub-batch ONE fetch of the answer (``2 classes_out +
+        1`` words a query), the unpack, and where a query is flagged the
+        second read of the flagged queries' windows and their float64
+        re-vote (``predict_certified``'s docstring), the later
+        sub-batches' programs on the device meanwhile.  Writes
+        ``classes`` and ``totals`` of every query but the uncertified
+        (the caller's repair answers those) and ``i``, the neighbours,
+        of the re-voted ones; returns every query's flag word."""
+        from knn_tpu.ops.refine import revote_exact
+
+        k, classes_out = self.k, told["classes_out"]
+        flags = np.zeros(host_q.shape[0], np.int32)
+
+        def fetch(out):
+            with obs.trace.stage(acct, "certified.device_wait"):
+                jax.block_until_ready(out)
+                acct.ready("certified")
+            with obs.trace.stage(acct, "certified.d2h") as sp:
+                arr = np.asarray(out[0])
+                sp.set("d2h_bytes", arr.nbytes)
+            return arr, out[1]
+
+        def windows(window, rows):
+            """``window[rows]`` on the host: the rows padded to a power
+            of two (one compiled shape a bucket), read in one copy."""
+            padded = np.zeros(max(8, 1 << (rows.size - 1).bit_length()),
+                              np.int32)
+            padded[: rows.size] = rows
+            with obs.trace.stage(acct, "certified.d2h") as sp:
+                take = _vote_rows_program()
+                begun = _hooks.first_call_begin()
+                got = _retry_transient(lambda: take(window, padded),
+                                       "vote rows dispatch")
+                acct.launched("vote_rows")
+                _hooks.first_call_end(begun, take, "vote_rows", trace_id,
+                                      rows=padded.size)
+                arr = np.asarray(got)
+                acct.ready("vote_rows")
+                sp.set("d2h_bytes", arr.nbytes)
+            return arr[: rows.size].astype(np.int64)
+
+        def repair(lo, pad, out, redo):
+            take = bs - pad
+            answer, window = _fetch_or_redispatch(out, redo, "vote fetch",
+                                                  fetch=fetch)
+            with obs.trace.stage(acct, "certified.unpack"):
+                cls, tot, flag = unpack_voted(answer[:take], classes_out,
+                                              told["temperature"])
+                classes[lo : lo + take] = cls
+                totals[lo : lo + take] = tot
+                flags[lo : lo + take] = flag
+            need = np.flatnonzero(
+                flag & (VOTE_BAD | VOTE_BOUNDARY | VOTE_MARGIN))
+            if not need.size:
+                return
+            cand = windows(window, need)
+            kind = flag[need] & (VOTE_BAD | VOTE_BOUNDARY)
+            # an uncertified query's first k, for the repair's account of
+            # what it changed
+            i[lo + need] = cand[:, :k]
+            members = 0
+            with obs.trace.stage(acct, "certified.vote_repair") as sp:
+                # a margin alone: the first k ARE the neighbours; a
+                # boundary: the float64 first k of the whole window
+                for sel, width in ((kind == 0, k), (kind == VOTE_BOUNDARY, w)):
+                    at = lo + need[sel]
+                    if not at.size:
+                        continue
+                    classes[at], totals[at], i[at] = revote_exact(
+                        db_np, host_q[at], cand[sel, :width],
+                        self._labels_host, k, told["temperature"],
+                        classes_out, _refine.norms_rows(norms, at))
+                    members += at.size * width
+                sp.set("queries", int(((kind & VOTE_BAD) == 0).sum()))
+                sp.set("members", members)
+
+        outs = []
+        for lo, chunk, pad in batches:
+            with obs.trace.stage(acct, "certified.dispatch",
+                                 h2d_bytes=chunk.nbytes):
+                qp, _ = self._place_queries(chunk)
+                begun = _hooks.first_call_begin()
+                outs.append((qp, _retry_transient(
+                    lambda q=qp: prog(q, self._tp, *ops_tail),
+                    "vote dispatch")))
+                acct.launched("certified")
+                _hooks.first_call_end(begun, prog, "certified", trace_id,
+                                      rows=qp.shape[0])
+        for (lo, chunk, pad), (qp, out) in zip(batches, outs):
+            repair(lo, pad, out,
+                   lambda q=qp: prog(q, self._tp, *ops_tail))
+        return flags
 
     def predict(self, queries: jax.Array) -> jax.Array:
         """Predicted labels [Q] — requires ``labels`` at construction."""
@@ -3467,6 +3956,95 @@ def _pallas_certified_program(
     return prog
 
 
+@functools.lru_cache(maxsize=32)
+def _pallas_vote_program(
+    mesh: Mesh, m: int, k: int, merge: str, tile_n: Optional[int],
+    precision: str, n_train: int, vote: tuple,
+    survivors: Optional[int] = None, block_q: Optional[int] = None,
+    final_select: str = "exact",
+    final_recall_target: Optional[float] = None,
+    grid_order: str = "query_major", kernel: str = "tiled",
+    quant_offset: float = 0.0, dcn_merge: Optional[str] = None,
+    interpret: Optional[bool] = None, terms: str = "hh+hl+lh",
+    row_block: Optional[int] = None, resident_parts: int = 0,
+):
+    """:func:`_pallas_certified_program` for a cosine placement, with the
+    tail ended in the weighted vote (``_certify_pack_spmd``'s ``vote``,
+    ``(1 / T, classes_out, delta)``): the same kernel, final select,
+    rescore, merge, rank analysis and certificate from the same operands,
+    then one more replicated operand, the device labels, LAST.  Returns
+    ``(answer, window)`` (:func:`_vote_pack`), both sharded over the
+    query axis.  A function of its own so that the search program's
+    ``spmd`` keeps its frame (tests/test_dim_chunking.py's tripwire).
+
+    Where the placement keeps no resident row operands
+    (``resident_parts`` 0: ``analysis.hbm.resident_operands_fit`` found
+    no room) the default precision's are formed HERE, in every call, by
+    the resident form's own arithmetic (ops.pallas_knn.row_operands: the
+    low half taken against ``lax.reduce_precision``), not by the
+    kernel wrapper's in-call split, whose low half the v5e's compiler
+    turns to zeros (ROADMAP A17): a vote has no ranked list for a missed
+    neighbour to show in, so its kernel must be inside
+    ``kernel_tolerance`` on the chip too."""
+    from knn_tpu.ops.pallas_knn import (
+        BLOCK_Q,
+        TILE_N,
+        local_certified_candidates,
+        row_operands,
+    )
+
+    hosts, chips = db_topology(mesh)
+    w = _analysis_window(k, m)
+
+    def spmd(q, t, *tail):
+        *tail, aug_slack, labels = tail
+        db_q, db_pq, consts, db_norm_max, db_rows = _split_operand_tail(
+            precision, tail)
+        if db_rows is None and precision == "bf16x3":
+            db_rows = row_operands(t, tile_n=tile_n or TILE_N,
+                                   with_lo="hl" in terms)
+        d32, li, lb = local_certified_candidates(
+            q, t, m, tile_n=tile_n or TILE_N, survivors=survivors,
+            block_q=block_q or BLOCK_Q, final_select=final_select,
+            precision=precision, final_recall_target=final_recall_target,
+            grid_order=grid_order, kernel=kernel, interpret=interpret,
+            db_int8=db_q, db_pq=db_pq, offset=quant_offset, terms=terms,
+            row_block=row_block, db_prepared=db_rows)
+        return _certify_pack_spmd(
+            q, t, d32, li, lb, consts=consts, db_norm_max=db_norm_max,
+            precision=precision, quant_offset=quant_offset, m=m, k=k, w=w,
+            merge=merge, n_train=n_train, hosts=hosts, chips=chips,
+            dcn_merge=dcn_merge, include_distances=False,
+            pq_dsub=None if db_pq is None else int(db_pq[1].shape[2]),
+            aug_slack=aug_slack, slack_outcome=True, vote=vote,
+            labels=labels)
+
+    prog = jax.jit(
+        jax.shard_map(
+            spmd, mesh=mesh,
+            in_specs=(P(QUERY_AXIS), P(db_axes(mesh)),
+                      *_tail_specs(precision, mesh, resident_parts),
+                      P(), P()),
+            out_specs=(P(QUERY_AXIS), P(QUERY_AXIS)),
+            check_vma=False,
+        )
+    )
+    _hooks.mark_built(
+        prog, f"m={m},k={k},tile={tile_n or TILE_N},terms={terms},"
+              f"row_block={row_block},precision={precision},"
+              f"operands={'resident' if resident_parts else 'per_call'},"
+              f"vote=softmax,classes_out={vote[1]}")
+    return prog
+
+
+@functools.lru_cache(maxsize=None)
+def _vote_rows_program():
+    """The second read of a voted sub-batch: the candidate windows of
+    the flagged queries alone (``window[rows]``), so that what crosses to
+    the host is the answer and these, not every query's candidates."""
+    return jax.jit(lambda window, rows: window[rows])
+
+
 def _tail_specs(precision: str, mesh: Mesh, resident_parts: int = 0):
     """shard_map in_specs of the precision-shaped operand tail
     (ShardedKNN._pallas_operands): int8 = the quantized placement
@@ -3651,7 +4229,8 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
                        precision, quant_offset, m, k, w, merge, n_train,
                        hosts, chips, include_distances,
                        dcn_merge=None, pq_dsub=None, aug_slack=None,
-                       masked: bool = False, slack_outcome: bool = False):
+                       masked: bool = False, slack_outcome: bool = False,
+                       vote=None, labels=None):
     """The certify/pack tail of the pallas certified program, from one
     shard's ranked candidates ``(d32, li, lb)`` to the packed host-facing
     int32 array: merge, rank analysis, certificate, packing.
@@ -3705,7 +4284,34 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
     - a window that runs into padding needs no repair: a (row, +inf)
       pair is never tight and always a provable boundary, so the test
       that every one of the first k+1 is finite (which without a mask
-      says "this shard gave junk") is left out."""
+      says "this shard gave junk") is left out.
+
+    ``vote`` (``(1 / T, classes_out, delta)``, static; a cosine
+    placement's ``predict_certified(vote="softmax")``; ``labels`` the
+    replicated device labels) ends the tail in :func:`_vote_pack` instead
+    of the packed candidates: the same merge, rank analysis and
+    certificate, then the weighted vote over the first k candidates and
+    the VOTE CERTIFICATE, which asks less of the ranking than the order
+    of all k does.  A sum over the k nearest rows is the same whatever
+    their order among themselves, so beside the exclusion and merge-drop
+    tests above (a failure is ``bad``, today's fallback) only two things
+    can change the answer:
+
+    - *membership*: which row is k-th and which (k+1)-th.  The near-tie
+      inequality is read at that ONE pair: ``tight[:, k - 1]`` flags the
+      query ``boundary``; tight pairs inside the first k are not flagged.
+      With the pair not tight every candidate from the (k+1)-th on is
+      farther, in the cosine of the rows as given, than each of the
+      first k (the gap to any later one is wider still), so the first k
+      ARE the float64 neighbours, in some order;
+    - *margin*: how close two class totals are (:func:`vote_delta`).  Two
+      adjacent totals a >= b among the first ``classes_out + 1`` with
+      ``a - b <= 2 delta a`` flag the query ``margin``; equal totals do.
+      Totals past those are under the last compared one, so cannot enter.
+
+    A zero row among the window's candidates (a label below 0 in
+    ``labels``: ``ShardedKNN._vote_labels`` marks them) is ``bad``, as
+    ``_certify_pallas`` flags it on the host for a search."""
     from knn_tpu.ops.pallas_knn import RANK_SLACK
 
     db_shards = hosts * chips
@@ -3803,6 +4409,9 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
     if slack_outcome:
         by_slack = bad & ~bare & ~unresolved
     bad = bad | unresolved
+    if vote is not None:  # a cosine program: slack_outcome is on
+        return _vote_pack(d32, gi, tight, bad, by_slack, labels,
+                          k=k, w=w, vote=vote)
     cols = [
         gi[:, :w],
         lax.bitcast_convert_type(_pack_bits_u32(tight_use), jnp.int32),
@@ -3813,6 +4422,67 @@ def _certify_pack_spmd(q, t, d32, li, lb, *, consts, db_norm_max,
     if include_distances:
         cols.append(lax.bitcast_convert_type(d32[:, :k], jnp.int32))
     return jnp.concatenate(cols, axis=1)
+
+
+#: device scope of the weighted vote and its certificate, inside the
+#: certify/pack tail's
+SCOPE_VOTE = "knn.vote"
+#: bits of a voted query's flag word (``_vote_pack``): uncertified (the
+#: search's own fallback), the k-th and (k+1)-th candidates too close to
+#: tell apart, two class totals too close, and (as bit 1 of a cosine
+#: search's word) failed by the pair slack alone
+VOTE_BAD, VOTE_BOUNDARY, VOTE_MARGIN, VOTE_BY_SLACK = 1, 2, 4, 8
+
+
+@jax.named_scope(SCOPE_VOTE)
+def _vote_pack(d32, gi, tight, bad, by_slack, labels, *, k, w, vote):
+    """The weighted vote over the first k merged candidates and its
+    certificate (``_certify_pack_spmd``'s docstring), packed for the
+    host: ``(answer [Q, 2 classes_out + 1] int32, window [Q, w] int32)``.
+    ``answer`` holds the classes (-1 past the last present), their
+    float32 totals WITHOUT the factor ``exp(1 / T)`` that every weight
+    shares (bitcast; :func:`unpack_voted` applies it in float64) and one
+    flag word; ``window`` the ranked
+    candidate indices, which stay on the device unless the host asks for
+    a flagged query's (``ShardedKNN._vote_pallas``).  ``d32`` are squared
+    distances of unit rows: a cosine distance is half of one."""
+    from knn_tpu.ops.vote import exp_weight, softmax_vote
+
+    inv_t, classes_out, delta = vote
+    in_db = gi[:, :w] != _INT_SENTINEL
+    lab_w = labels[jnp.minimum(gi[:, :w], labels.shape[0] - 1)]
+    bad = bad | ((lab_w < 0) & in_db).any(axis=-1)
+    lab = jnp.where(lab_w[:, :k] < 0, -1 - lab_w[:, :k], lab_w[:, :k])
+    # exp(-c / T): the factor exp(1 / T) every weight shares is the
+    # host's (float64), so the argument stays small and its rounding too
+    weights = jnp.where(
+        in_db[:, :k], exp_weight(-(0.5 * d32[:, :k]) * inv_t), 0.0)
+    cls, tot = softmax_vote(lab, weights, classes_out + 1)
+    hi, lo = tot[:, :-1], tot[:, 1:]
+    margin = ((lo > 0) & (hi - lo <= (2.0 * delta) * hi)).any(axis=-1)
+    flag = (VOTE_BAD * bad.astype(jnp.int32)
+            + VOTE_BOUNDARY * tight[:, k - 1].astype(jnp.int32)
+            + VOTE_MARGIN * margin.astype(jnp.int32)
+            + VOTE_BY_SLACK * by_slack.astype(jnp.int32))
+    answer = jnp.concatenate(
+        [cls[:, :classes_out],
+         lax.bitcast_convert_type(tot[:, :classes_out], jnp.int32),
+         flag[:, None]], axis=1)
+    return answer, gi[:, :w]
+
+
+def unpack_voted(answer: np.ndarray, classes_out: int, temperature: float
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Host inverse of :func:`_vote_pack`'s ``answer``: (classes [Q,
+    classes_out] int32, totals [Q, classes_out] float64: the device's
+    float32 sums of ``exp(-c32 / T)`` times ``exp(1 / T)``, flag words
+    [Q])."""
+    arr = np.ascontiguousarray(np.asarray(answer))
+    totals = np.ascontiguousarray(
+        arr[:, classes_out : 2 * classes_out]).view(np.float32)
+    return (arr[:, :classes_out].copy(),
+            totals.astype(np.float64) * np.exp(1.0 / temperature),
+            arr[:, 2 * classes_out])
 
 
 def unpack_certified(

@@ -100,6 +100,9 @@ SHAPES = {
     # VectorDBBench's 500K x 1,536 cosine case whole on one chip: unit
     # rows, a row tile in four steps (benchmark/configs/openai500k.json)
     "openai500k": (500_000, 1536, 100),
+    # the ImageNet-1k k-NN evaluation whole on one chip: cosine, k = 20,
+    # answered by the VOTE program (benchmark/configs/imagenet-knn768.json)
+    "imagenet768": (1_281_167, 768, 20),
     "wide512": (1_000_000, 512, 100),
     "wide640": (1_000_000, 640, 100),
 }
@@ -110,7 +113,12 @@ AUGMENTED = ("text2image2m5",)
 #: shapes whose rows are unit rows (metric "cosine"): the program takes
 #: the normalisation's slack as one more scalar too, keeps its distance
 #: block and packs one more bit (``slack_outcome``)
-COSINE = ("openai500k",)
+COSINE = ("openai500k", "imagenet768")
+#: shapes answered by ``predict_certified(vote="softmax")``: the vote
+#: program (the certified program's tail ended in the weighted vote over
+#: ``VOTE_CLASSES`` labels at ``VOTE_TEMPERATURE``, five classes out)
+VOTED = ("imagenet768",)
+VOTE_CLASSES, VOTE_TEMPERATURE, VOTE_CLASSES_OUT = 1000, 0.07, 5
 #: shapes answered by ``range_search_certified``: its completion's
 #: program is compiled too
 RANGE = ("ssnpp2m5",)
@@ -162,7 +170,11 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
     from knn_tpu.analysis.widths import lane_tiled
     from knn_tpu.ops.pallas_knn import TILE_N
     from knn_tpu.parallel.mesh import DB_AXIS, QUERY_AXIS
-    from knn_tpu.parallel.sharded import _pallas_certified_program
+    from knn_tpu.parallel.sharded import (
+        _pallas_certified_program,
+        _pallas_vote_program,
+        vote_delta,
+    )
 
     n, d, k = SHAPES[shape]
     d = lane_tiled(d)  # as ShardedKNN places the rows and every batch
@@ -178,17 +190,28 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
         kw.update(augmented=True, include_distances=False)
     if shape in COSINE:
         kw.update(augmented=True, slack_outcome=True)
-    prog = _pallas_certified_program(
-        mesh, k + MARGIN, k, merge, knobs["tile_n"] or TILE_N,
-        knobs["precision"], n_train=n, interpret=False, **kw)
+    if shape in VOTED:
+        del kw["augmented"], kw["slack_outcome"]
+        prog = _pallas_vote_program(
+            mesh, k + MARGIN, k, merge, knobs["tile_n"] or TILE_N,
+            knobs["precision"], n,
+            (1.0 / VOTE_TEMPERATURE, VOTE_CLASSES_OUT,
+             vote_delta(VOTE_TEMPERATURE, k)), interpret=False, **kw)
+    else:
+        prog = _pallas_certified_program(
+            mesh, k + MARGIN, k, merge, knobs["tile_n"] or TILE_N,
+            knobs["precision"], n_train=n, interpret=False, **kw)
     q = jax.ShapeDtypeStruct(
         (NQ, d), jnp.float32, sharding=NamedSharding(mesh, P(QUERY_AXIS)))
     db = jax.ShapeDtypeStruct(
         (rows, d), jnp.float32, sharding=NamedSharding(mesh, P(DB_AXIS)))
     norm = jax.ShapeDtypeStruct(
         (), jnp.float32, sharding=NamedSharding(mesh, P()))
+    labels = jax.ShapeDtypeStruct(
+        (n,), jnp.int32, sharding=NamedSharding(mesh, P()))
     return prog, (q, db, norm) + (
-        (norm,) if shape in AUGMENTED + COSINE else ())
+        (norm,) if shape in AUGMENTED + COSINE else ()) + (
+        (labels,) if shape in VOTED else ())
 
 
 def _shard_width(shape: str, db_shards: int) -> int:

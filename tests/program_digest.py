@@ -22,6 +22,10 @@ PR 46 recorded ``gist1m.sweep``'s three digests anew and no other: its
 kernel cuts the row tile by rows where it cut the columns
 (``analysis.vmem.row_blocking``); the four cells whose tile is one step
 give the digests they gave.
+
+PR 48 added the eighth row, ``imagenet-knn768.sweep_vote``, whose program
+is the vote program (``VOTED``); the seven digests of the five older
+rows are what they were.
 """
 
 import hashlib
@@ -40,7 +44,18 @@ CELLS = {
     "bigann20m-x4.sweep": (4, 5_000_000, 128, 100, "hh", 1, False),
     "text2image2m5.sweep_ip": (1, 2_500_000, 256, 10, "hh+hl+lh", 2, True),
     "ssnpp2m5.sweep_range": (1, 2_500_000, 256, 100, "hh", 1, False),
+    # the eighth cell (PR 48): its program is the VOTE program (VOTED),
+    # the operands formed in the call (``per_call`` on the chip)
+    "imagenet-knn768.sweep_vote": (1, 1_281_167, 768, 20, "hh+hl+lh", 0,
+                                   False),
 }
+#: cell -> (temperature, classes out) of a cell answered by
+#: ``predict_certified(vote="softmax")``: ``_pallas_vote_program`` is
+#: digested in ``_pallas_certified_program``'s place, with the pair slack
+#: and the replicated labels after the tail.  Recorded on PR 48's tree,
+#: which brought the program: a later edit that moves the search
+#: programs' shared tail moves this one too, and says so here
+VOTED = {"imagenet-knn768.sweep_vote": (0.07, 5)}
 QUERIES, MARGIN = 4096, 28
 #: the rows of a cell's call cut in two and in four
 SUB_BATCH_ROWS = (2048, 1024)
@@ -60,10 +75,19 @@ def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
     block, _ = pk.row_blocking(dim, tile_n=pk.TILE_N, block_q=pk.BLOCK_Q,
                                precision="bf16x3", kernel="tiled",
                                terms=terms, survivors=None)
-    prog = sh._pallas_certified_program(
-        mesh, m, k, "ring", pk.TILE_N, "bf16x3", n_train=rows * shards,
-        interpret=True, terms=terms, augmented=dot, row_block=block,
-        resident_parts=parts)
+    if cell in VOTED:
+        temperature, classes_out = VOTED[cell]
+        prog = sh._pallas_vote_program(
+            mesh, m, k, "ring", pk.TILE_N, "bf16x3", rows * shards,
+            (1.0 / temperature, classes_out,
+             sh.vote_delta(temperature, k)),
+            interpret=True, terms=terms, row_block=block,
+            resident_parts=parts)
+    else:
+        prog = sh._pallas_certified_program(
+            mesh, m, k, "ring", pk.TILE_N, "bf16x3", n_train=rows * shards,
+            interpret=True, terms=terms, augmented=dot, row_block=block,
+            resident_parts=parts)
     rows_p = -(-rows // pk.TILE_N) * pk.TILE_N
     dim_p = -(-dim // pk.DIM_CHUNK) * pk.DIM_CHUNK
 
@@ -74,8 +98,10 @@ def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
     if parts:
         tail += [aval((shards * rows_p, dim_p), jnp.bfloat16)] * parts
         tail += [aval((shards * rows_p,), jnp.float32)]
-    if dot:
+    if dot or cell in VOTED:
         tail += [aval((), jnp.float32)]
+    if cell in VOTED:
+        tail += [aval((shards * rows,), jnp.int32)]
     text = str(jax.make_jaxpr(prog)(
         aval((queries, dim), jnp.float32),
         aval((shards * rows, dim), jnp.float32), *tail))

@@ -217,16 +217,28 @@ def _filtered():
                                          filter_tags=ft, tile_n=TILE)
 
 
+def _voted():
+    rng = np.random.default_rng(48)
+    db = rng.normal(size=(3000, 48)).astype(np.float32)
+    prog = ShardedKNN(db, mesh=_mesh(), k=K, metric="cosine",
+                      labels=rng.integers(0, 30, 3000).astype(np.int32),
+                      num_classes=30)
+    q = rng.normal(size=(48, 48)).astype(np.float32)
+    return lambda: prog.predict_certified(
+        q, vote="softmax", temperature=0.07, classes_out=5,
+        selector="pallas", tile_n=TILE)
+
+
 BUILDERS = {"l2": _l2, "dot": _metric("dot"), "cosine": _metric("cosine"),
-            "range": _range, "filtered": _filtered}
+            "range": _range, "filtered": _filtered, "voted": _voted}
 #: the query kind of a cell, by its traffic file's kind and its metric
 KIND_OF_CELL = {}
 for _cell in BENCH["workloads"]:
     _traffic = _json("benchmark", "traffic", _cell["traffic"] + ".json")
     _config = _json("benchmark", "configs", _cell["config"] + ".json")
     KIND_OF_CELL[_cell["name"]] = {
-        "sweep_range": "range", "sweep_filter": "filtered"}.get(
-            _traffic["kind"], _config["metric"])
+        "sweep_range": "range", "sweep_filter": "filtered",
+        "sweep_vote": "voted"}.get(_traffic["kind"], _config["metric"])
 
 PAIRS = sorted(
     (m["name"], kind)
@@ -257,7 +269,7 @@ def deltas():
     obs.reset()
 
 
-def test_the_cells_are_the_five_kinds():
+def test_the_cells_are_the_six_kinds():
     assert set(KIND_OF_CELL.values()) == set(BUILDERS)
 
 

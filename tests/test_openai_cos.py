@@ -803,7 +803,8 @@ def test_the_configuration_is_the_source_whole():
     (cell,) = [w for w in bench["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         "openai500k", "sweep_cos", 1)
-    assert bench["workloads"][-1] == cell and bench["configs"][-1] == entry
+    # appended by PR 43, the seventh of each (PR 48 appended after them)
+    assert bench["workloads"][6] == cell and bench["configs"][6] == entry
     assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] == [
         "bigann20m-x4.sweep"]
     traffic = _json("benchmark", "traffic", "sweep_cos.json")
@@ -837,7 +838,7 @@ def test_the_held_entries_fit_the_benchmark_and_their_layer_files():
     for name in LISTED:
         assert CELL in listed[name]["workloads"], name
     (qps,) = [m for m in BENCH["end_to_end"] if m["name"] == "sweep_qps"]
-    assert qps["workloads"][-1] == CELL
+    assert qps["workloads"][6] == CELL
 
 
 def test_the_control_script_reads_the_configurations_word(root, capsys):

@@ -335,6 +335,15 @@ V5E = 16909336064     # bytes_limit of one v5e chip, as the chip reads it
     # chip read `resident`; its bytes_in_use before the first program
     # is reckoned as the other cells' read: placed + 27,136)
     ("openai500k", 500_000, 1536, 3_072_000_000, True, 3_072_027_136, True),
+    # 1,281,167 x 768 unit rows (PR 48): 3.936 + 3.981 + 2.7 x 3.936 =
+    # 18.5 GB of 14.80, so the vote program forms its operands in the
+    # call (the chip read `per_call` and `bytes_in_use` 3,947,943,424
+    # after a window, the labels and a batch in it; before the first
+    # program it is reckoned as the other cells' read); at the 1.17 the
+    # lane-tiled programs really set aside it would fit with 2.3 GB to
+    # spare (ROADMAP A18, with gist1m)
+    ("imagenet-knn768", 1_281_167, 768, 3_935_745_024, True, 3_935_772_160,
+     False),
 ])
 def test_the_rule_at_every_cells_bytes(cell, rows, dim, placed, with_lo,
                                        in_use, keeps):

@@ -337,7 +337,8 @@ def test_default_knobs_are_the_kernel_shaping_arguments():
         "return_sqrt", "filter_tags", "_under") == set(tuning.DEFAULT_KNOBS)
     assert kwargs_of(
         ShardedKNN._pallas_setup, "margin", "include_distances", "terms",
-        "batch_rows", "call_rows", "trace_id", "acct", "masked") == set(
+        "batch_rows", "call_rows", "trace_id", "acct", "masked",
+        "vote") == set(
             tuning.DEFAULT_KNOBS)
 
 
