@@ -200,20 +200,41 @@ def plan_join(
 
 #: what the largest device program loaded beside RESIDENT row operands
 #: may set aside for its temporaries, as a multiple of one chip's placed
-#: rows (their own bytes, ``nbytes``).  Read from XLA's
-#: ``memory_analysis`` of every program a benchmark cell loads, compiled
-#: for a v5e (the chip's ``bytes_reserved`` read the same numbers to the
-#: megabyte where both were taken: PERF.md section 4, PR 39): the
-#: largest is 2.61, the repair's exact re-select over 2.5M x 201 rows
-#: the device holds column-major (a row-major copy of them, 201 columns
-#: in 256 lanes, and a copy padded to the exact path's tile); 2.19 the
-#: re-select at 1M x 960; 1.64 the certified program there once it is
-#: handed its operands; 0.75 to 1.4 every other.  Since PR 44 only a
-#: pre-placed array of such a width lies so: rows ``ShardedKNN`` lays
-#: out itself are placed in whole lane tiles, where the same programs
-#: read 1.05 (both re-selects) and 1.17 at the most (the certified
-#: program at 1M x 1,024 with its operands formed in the call); the
-#: factor has not been taken down to that (PERF.md section 7).
+#: rows (their own bytes, ``nbytes``), by how the rows lie.  Both are
+#: readings of XLA's ``memory_analysis`` of the programs a benchmark
+#: cell loads, compiled for a v5e (the chip's ``bytes_reserved`` read
+#: the same numbers to the megabyte where both were taken: PERF.md
+#: section 4 "Memory").
+#:
+#: Rows in whole 128-column lane tiles (every placement ``ShardedKNN``
+#: lays out itself since PR 44, and a pre-placed array of such a width):
+#: no program copies them.  ``python scripts/aot_compile_check.py
+#: --temporaries [--shape S]`` prints the readings (PR 49, jax 0.9.0 /
+#: libtpu 0.0.34).  What a default call loads beside resident operands:
+#: the sub-batch's certified (or vote) program at 1,024 queries, 0.09
+#: to 0.54 over the benchmark's shapes, and the repair's exact
+#: re-select, 1.02 to 1.05 (its padded copy of the rows).  At the two
+#: shapes this factor decides for, ``gist`` (1M x 1,024 placed) /
+#: ``imagenet768`` (1,281,167 x 768; every narrower cell fits at 2.7
+#: too), it covers every other form of the program as well: handed its
+#: operands at an uncut 4,096 queries 1.089 / 0.339, forming them in
+#: the call 1.175 / 1.221 (1.056 / 1.063 at 1,024 queries).  A quarter
+#: over the rows is the least twentieth above all of those.  It bounds
+#: no launch: a program's temporaries grow with its QUERIES (the
+#: candidates are queries x rows / 64), so an uncut 4,096-query launch
+#: reads 1.38 over 5M x 128 and 2.18 over 500K x 1,536; those fit
+#: beside the operands as they did at 2.7 (the spare eighth, and
+#: ``bytes_reserved`` once loaded).  The exact route is ROADMAP D4.
+LANE_TILED_TEMP_FACTOR = 1.25
+
+#: Rows of any other width (a PRE-PLACED array, used as handed in;
+#: ``sub_batch: layout_copy``) lie column-major on the v5e and every
+#: program reading them copies and pads all of them: 2.61 at the most,
+#: the repair's exact re-select over 2.5M x 201 (a row-major copy, 201
+#: columns in 256 lanes, and a copy padded to the exact path's tile);
+#: 2.19 the re-select at 1M x 960; 1.64 the certified program there
+#: once it is handed its operands (PR 39's readings, from before the
+#: placement widened such rows itself).
 ROWS_PROGRAM_TEMP_FACTOR = 2.7
 
 #: the share of the device's memory the resident row operands may fill
@@ -230,33 +251,60 @@ def row_operand_bytes(rows_p: int, dim_p: int, with_lo: bool) -> int:
     return int(rows_p) * (int(dim_p) * 2 * (2 if with_lo else 1) + 4)
 
 
-def resident_operands_fit(form_bytes: int, placed_bytes: int,
-                          memory_stats: dict) -> bool:
-    """Whether one chip has room to KEEP ``form_bytes`` of row operands
-    beside its ``placed_bytes`` of rows, by the device's own
-    ``memory_stats()``: what the client holds there now
-    (``bytes_in_use``: the rows and whatever else the process placed),
-    the form, and the temporaries of the largest device program must
-    fit ``RESIDENT_FILL`` of ``bytes_limit``.  The runtime sets aside
-    ONE region for the loaded programs' temporaries, as large as the
-    largest needs (``bytes_reserved``; it is 0 until a program has
-    run), so the temporaries are that reading or, where it is larger,
-    ``ROWS_PROGRAM_TEMP_FACTOR`` times the placed rows.  A backend that
-    reports no ``bytes_limit`` (the CPU) has no such bound: True."""
-    limit = int(memory_stats.get("bytes_limit") or 0)
-    if not limit:
-        return True
-    held = max(int(memory_stats.get("bytes_in_use") or 0), placed_bytes)
+def program_temp_factor(width: int) -> float:
+    """The multiple of one chip's placed rows that the largest program
+    beside them sets aside, read off the rows' placed ``width``:
+    ``LANE_TILED_TEMP_FACTOR`` where it is whole 128-column lane tiles,
+    ``ROWS_PROGRAM_TEMP_FACTOR`` where the programs still copy the rows
+    into such tiles themselves."""
+    if _widths.lane_tiled(width) == int(width):
+        return LANE_TILED_TEMP_FACTOR
+    return ROWS_PROGRAM_TEMP_FACTOR
+
+
+def resident_operands_room(form_bytes: int, placed_bytes: int,
+                           memory_stats: dict, *, width: int) -> dict:
+    """The terms of the rule that decides whether one chip KEEPS
+    ``form_bytes`` of row operands beside its ``placed_bytes`` of rows
+    ``width`` columns wide, by the device's own ``memory_stats()``:
+    ``held``, what the client holds there now (``bytes_in_use``: the
+    rows and whatever else the process placed), ``form_bytes``, and
+    ``temporaries``, those of the largest device program, must fit
+    ``limit`` = ``RESIDENT_FILL`` of ``bytes_limit``; ``kept`` says
+    whether they do.  The runtime sets aside ONE region for the loaded
+    programs' temporaries, as large as the largest needs
+    (``bytes_reserved``; it is 0 until a program has run), so the
+    temporaries are that reading or, where it is larger,
+    :func:`program_temp_factor` of the width times the placed rows.  A
+    backend that reports no ``bytes_limit`` (the CPU) has no such
+    bound: ``limit`` 0 and ``kept``."""
+    bytes_limit = int(memory_stats.get("bytes_limit") or 0)
+    limit = int(RESIDENT_FILL * bytes_limit)
+    held = max(int(memory_stats.get("bytes_in_use") or 0), int(placed_bytes))
     temporaries = max(int(memory_stats.get("bytes_reserved") or 0),
-                      int(ROWS_PROGRAM_TEMP_FACTOR * placed_bytes))
-    return held + form_bytes + temporaries <= RESIDENT_FILL * limit
+                      int(program_temp_factor(width) * placed_bytes))
+    return {"held": held, "form_bytes": int(form_bytes),
+            "temporaries": temporaries, "limit": limit,
+            "kept": (not bytes_limit
+                     or held + form_bytes + temporaries <= limit)}
+
+
+def resident_operands_fit(form_bytes: int, placed_bytes: int,
+                          memory_stats: dict, *, width: int) -> bool:
+    """Whether the rule of :func:`resident_operands_room` keeps the
+    operands."""
+    return resident_operands_room(
+        form_bytes, placed_bytes, memory_stats, width=width)["kept"]
 
 
 __all__ = [
     "AUX_BYTES_PER_ROW",
+    "LANE_TILED_TEMP_FACTOR",
     "ROWS_PROGRAM_TEMP_FACTOR",
     "RESIDENT_FILL",
     "row_operand_bytes",
+    "program_temp_factor",
+    "resident_operands_room",
     "resident_operands_fit",
     "placement_bytes",
     "rows_for_budget",

@@ -514,9 +514,12 @@ CATALOG = {
         "the row norms) came from: 'resident' the placement's own, "
         "built once on the device and handed to the program "
         "(ShardedKNN._row_operands: the default precision, where "
-        "analysis.hbm.resident_operands_fit finds the device has "
-        "room), 'per_call' the program's prologue, over the whole "
-        "corpus in every call."),
+        "analysis.hbm.resident_operands_room finds the device has "
+        "room: the rows, the form and the largest program's "
+        "temporaries, 1.25 x rows placed in whole lane tiles and 2.7 "
+        "x any other, within 7/8 of bytes_limit; its terms are the "
+        "placement.operands event's), 'per_call' the program's "
+        "prologue, over the whole corpus in every call."),
     CERTIFIED_SUB_BATCH_CALLS: (
         "counter", ("why",),
         "Calls of search_certified(selector='pallas'), by why their "

@@ -1200,33 +1200,31 @@ def _pad_rows(db: jax.Array, tile_n: int) -> jax.Array:
         return _pad_axis(db, DIM_CHUNK, 1)
 
 
-def _split_rows(db: jax.Array, with_lo: bool, *,
-                rounded_lo: bool = False) -> Tuple[jax.Array, ...]:
+def _split_rows(db: jax.Array, with_lo: bool) -> Tuple[jax.Array, ...]:
     """The bf16 halves of the padded rows the kernel streams: the high
     half, and with ``with_lo`` the bf16 of what the cast left.
 
-    ``rounded_lo`` takes "what the cast left" against
-    ``lax.reduce_precision(db, 8, 7)``, the same value as the cast's
-    (round to nearest even) by an operation XLA may not skip.  Without
-    it the difference is ``db - f32(bf16(db))`` in ONE fusion, where the
-    TPU compiler keeps the cast's result in float32 (excess precision:
-    on the v5e a jitted ``f32(bf16(x))`` IS ``x``), so the difference
-    and with it the low half come out ZERO on the chip: the kernel's
-    ``hl`` term adds nothing and its score is off by 2^-9 / sqrt(3) of
-    sqrt(sum q_i^2 t_i^2).  Measured on 1,536-column unit rows (PERF.md
-    section 6, PR 43): up to 4.7e-4, 3.9 times ``kernel_tolerance``,
-    and one query in about 1,400 checked came back with a row missing;
-    with it 1.1e-6, numpy's own split to the bit.  The resident
-    placement (:func:`row_operands`) asks for it; the in-program form
-    keeps the text it had (the l2 programs' digests hold it), so a
-    placement whose operands stay ``per_call`` still multiplies a zero
-    low half: the next repair (PERF.md section 7)."""
+    "What the cast left" is taken against ``lax.reduce_precision(db, 8,
+    7)``, the same value as the cast's (round to nearest even) by an
+    operation XLA may not skip.  Taken against the cast's own round
+    trip, ``db - f32(bf16(db))`` in ONE fusion, the TPU compiler keeps
+    the cast's result in float32 (excess precision: on the v5e a jitted
+    ``f32(bf16(x))`` IS ``x``), so the difference and with it the low
+    half come out ZERO on the chip: the kernel's ``hl`` term adds
+    nothing and its score is off by 2^-9 / sqrt(3) of sqrt(sum q_i^2
+    t_i^2).  Measured on 1,536-column unit rows (PERF.md section 6,
+    PR 43): up to 4.7e-4, 3.9 times ``kernel_tolerance``, and one query
+    in about 1,400 checked came back with a row missing; against the
+    rounding 1.1e-6, numpy's own split to the bit.  The resident
+    placement (:func:`row_operands`) has taken it so since PR 43,
+    :func:`_bin_candidates`' in-call split since PR 49 (until then a
+    placement whose operands stayed ``per_call`` multiplied a zero low
+    half on the chip)."""
     with jax.named_scope(SCOPE_OPERAND_PREP):
         th = db.astype(jnp.bfloat16)
         if not with_lo:
             return (th,)
-        back = (lax.reduce_precision(db, exponent_bits=8, mantissa_bits=7)
-                if rounded_lo else th.astype(jnp.float32))
+        back = lax.reduce_precision(db, exponent_bits=8, mantissa_bits=7)
         return th, (db - back).astype(jnp.bfloat16)
 
 
@@ -1243,13 +1241,11 @@ def row_operands(db: jax.Array, *, tile_n: int,
     :func:`_bin_candidates` forms them from in every call that hands it
     none (ONE arithmetic, so a caller that keeps them beside the rows
     and passes them as ``db_prepared`` gets that call's outputs bit for
-    bit wherever the compiler rounds the cast, which the CPU does; on
-    the chip only this form's low half is what it says,
-    :func:`_split_rows`).  ``th`` and ``tl`` are bf16 ``[rows_p,
+    bit; :func:`_split_rows`).  ``th`` and ``tl`` are bf16 ``[rows_p,
     dim_p]``, ``norms`` f32 ``[rows_p]``; ``with_lo`` is whether ``"hl"`` is among the launch's
     ``terms`` (the rows' low half is streamed at all)."""
     db = _pad_rows(db, tile_n)
-    return (*_split_rows(db, with_lo, rounded_lo=True), _row_norms(db))
+    return (*_split_rows(db, with_lo), _row_norms(db))
 
 
 @functools.partial(

@@ -535,16 +535,15 @@ _PALLAS_STAGES = ("certified.dispatch", "certified.device_wait",
 _VOTE_STAGE = "certified.vote_repair"
 
 
-def _call_account(selector: str, *more: str):
+def _call_account(selector: str, *more: str, voted: bool = False):
     """The account of one outermost certified call: the device programs
     a call of this ``selector`` can launch (``more``: those of the call
-    it is the first pass of, or a voted call's second read), and the
-    pieces it sums (a voted call's ``certified.vote_repair`` among its
-    stages: it records every stage of the search's too, at 0.0 where it
-    has none, so a reader never finds a series missing)."""
+    it is the first pass of), and the pieces it sums (a ``voted`` call's
+    ``certified.vote_repair`` among its stages: it records every stage
+    of the search's too, at 0.0 where it has none, so a reader never
+    finds a series missing)."""
     if selector == "pallas":
-        stages = _PALLAS_STAGES + (
-            (_VOTE_STAGE,) if "vote_rows" in more else ())
+        stages = _PALLAS_STAGES + ((_VOTE_STAGE,) if voted else ())
         return obs.trace.call_account(
             _ACCOUNT_ROOT, ("certified", "reselect") + more, _PALLAS_PIECES,
             dict.fromkeys(stages, _CALL_SPAN))
@@ -1731,14 +1730,16 @@ class ShardedKNN:
         read it.
 
         Whether they are kept is read off the device, by no knob
-        (analysis.hbm.resident_operands_fit over the device's own
+        (analysis.hbm.resident_operands_room over the device's own
         ``memory_stats()`` — ``memory_stats`` stands in for that reading
         where a test is to tell the rule the device is full; the first
         addressable chip's, and under several processes its
         ``bytes_limit`` alone, so that all of them decide alike), and
         decided once a geometry: the decision is cached with the form.
-        The build is the call account's program ``operands`` and one
-        ``placement.operands`` event."""
+        The build is the call account's program ``operands``; the
+        decision, either way, is one ``placement.operands`` event with
+        the terms the rule compared (``held``, ``form_bytes``,
+        ``temporaries``, ``limit``, ``kept``: one chip's bytes)."""
         key = (int(tile), bool(with_lo))
         held = self._operands_cache
         if held is not None and held["key"] == key:
@@ -1764,8 +1765,14 @@ class ShardedKNN:
                     memory_stats = {
                         "bytes_limit": memory_stats.get("bytes_limit")}
             parts = None
-            if hbm.resident_operands_fit(
-                    form_bytes, self._tp.nbytes // shards, memory_stats):
+            # the rule's terms, by how the rows lie: whole lane tiles
+            # (every placement this class lays out) or a pre-placed
+            # array's own width, whose programs still copy it
+            room = hbm.resident_operands_room(
+                form_bytes, self._tp.nbytes // shards, memory_stats,
+                width=int(self._tp.shape[1]))
+            seconds = 0.0
+            if room["kept"]:
                 build = _row_operands_program(self.mesh, *key)
                 begun = _hooks.first_call_begin()
                 parts = build(self._tp)
@@ -1777,11 +1784,12 @@ class ShardedKNN:
                 t0 = time.perf_counter()
                 jax.block_until_ready(parts)
                 acct.ready("operands")
-                obs.emit_event(
-                    "placement.operands", rows=rows_p * shards,
-                    tile=key[0], parts="th+tl" if with_lo else "th",
-                    bytes=form_bytes * shards,
-                    seconds=time.perf_counter() - t0)
+                seconds = time.perf_counter() - t0
+            obs.emit_event(
+                "placement.operands", rows=rows_p * shards,
+                tile=key[0], parts="th+tl" if with_lo else "th",
+                bytes=form_bytes * shards if parts else 0,
+                seconds=seconds, **room)
             self._operands_cache = {"key": key, "parts": parts}
         return parts
 
@@ -3289,10 +3297,11 @@ class ShardedKNN:
         to tell apart, ``margin`` where two adjacent class totals are
         within ``2 * vote_delta(T, k)`` of each other.  What comes to the
         host in every call is ``2 classes_out + 1`` words a query; the
-        ranked candidates stay on the device, and only the flagged
-        queries' windows are read, by a second read (``window[rows]``, a
-        power-of-two bucket of rows), because one query in some tens is
-        flagged and a window is ``min(k + 17, m + 1)`` words.  An
+        ranked candidates stay on the device until a sub-batch has a
+        flagged query (one in some tens is): then its windows,
+        ``min(k + 17, m + 1)`` words a query, follow in a second COPY,
+        no program (a program would queue behind the later sub-batches'
+        launches, and the host with it).  An
         unflagged query is answered by the device's classes, its totals
         the device's float32 values (within ``vote_delta`` of the
         float64 ones, relatively).  A flagged one is re-voted by the host
@@ -3400,7 +3409,7 @@ class ShardedKNN:
         tid = obs.new_trace_id()
         slack = self._pair_slack()
         with obs.span(_CALL_SPAN, tid, selector="pallas", **told) as call:
-            acct = _call_account("pallas", "vote_rows")
+            acct = _call_account("pallas", voted=True)
             host_q = np.asarray(queries, dtype=np.float32)
             map_s = {"before_s": 0.0, "after_s": 0.0}
             with obs.trace.phase(map_s, "before_s", _METRIC_SPAN):
@@ -3596,10 +3605,11 @@ class ShardedKNN:
         """The host side of a voted call, :meth:`_certify_pallas`'s
         shape: every sub-batch's program is launched before the first is
         fetched; per sub-batch ONE fetch of the answer (``2 classes_out +
-        1`` words a query), the unpack, and where a query is flagged the
-        second read of the flagged queries' windows and their float64
-        re-vote (``predict_certified``'s docstring), the later
-        sub-batches' programs on the device meanwhile.  Writes
+        1`` words a query), the unpack, and where a query is flagged a
+        second copy, of the sub-batch's candidate windows, and the
+        flagged queries' float64 re-vote (``predict_certified``'s
+        docstring), the later sub-batches' programs on the device
+        meanwhile.  Writes
         ``classes`` and ``totals`` of every query but the uncertified
         (the caller's repair answers those) and ``i``, the neighbours,
         of the re-voted ones; returns every query's flag word."""
@@ -3618,23 +3628,17 @@ class ShardedKNN:
             return arr, out[1]
 
         def windows(window, rows):
-            """``window[rows]`` on the host: the rows padded to a power
-            of two (one compiled shape a bucket), read in one copy."""
-            padded = np.zeros(max(8, 1 << (rows.size - 1).bit_length()),
-                              np.int32)
-            padded[: rows.size] = rows
+            """``window[rows]`` on the host.  The sub-batch's whole
+            window comes down in one copy (150 KB at 1,024 queries) and
+            the rows are taken here: a device program that gathered
+            them (PR 48's ``vote_rows``) queued behind every later
+            sub-batch's launch, so the first sub-batch's re-vote waited
+            for the whole call's device work and every later one ran
+            with the device idle (PERF.md section 6, PR 49)."""
             with obs.trace.stage(acct, "certified.d2h") as sp:
-                take = _vote_rows_program()
-                begun = _hooks.first_call_begin()
-                got = _retry_transient(lambda: take(window, padded),
-                                       "vote rows dispatch")
-                acct.launched("vote_rows")
-                _hooks.first_call_end(begun, take, "vote_rows", trace_id,
-                                      rows=padded.size)
-                arr = np.asarray(got)
-                acct.ready("vote_rows")
+                arr = np.asarray(window)
                 sp.set("d2h_bytes", arr.nbytes)
-            return arr[: rows.size].astype(np.int64)
+            return arr[rows].astype(np.int64)
 
         def repair(lo, pad, out, redo):
             take = bs - pad
@@ -4035,14 +4039,6 @@ def _pallas_vote_program(
               f"operands={'resident' if resident_parts else 'per_call'},"
               f"vote=softmax,classes_out={vote[1]}")
     return prog
-
-
-@functools.lru_cache(maxsize=None)
-def _vote_rows_program():
-    """The second read of a voted sub-batch: the candidate windows of
-    the flagged queries alone (``window[rows]``), so that what crosses to
-    the host is the answer and these, not every query's candidates."""
-    return jax.jit(lambda window, rows: window[rows])
 
 
 def _tail_specs(precision: str, mesh: Mesh, resident_parts: int = 0):

@@ -52,6 +52,9 @@ Flags pick one geometry instead:
         --merge ring                   # the full SPMD certified program
     python scripts/aot_compile_check.py --shape bigann5m --mesh 1x1 \\
         --terms hh                     # ... of a byte corpus and batch
+    python scripts/aot_compile_check.py --temporaries [--shape gist]
+        # what every program a cell loads sets aside, over the placed
+        # rows: the reading behind analysis.hbm.LANE_TILED_TEMP_FACTOR
 
 Prints one line per case; exits non-zero if any case did not do what
 was expected of it.
@@ -159,9 +162,13 @@ def _kernel_case(shape: str, knobs: dict, devices, terms=None,
 
 
 def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
-               terms=None):
+               terms=None, *, queries: int = NQ, resident: bool = False):
     """(fn, avals) of the full sharded certified program
-    (parallel.sharded._pallas_certified_program) on a topology mesh."""
+    (parallel.sharded._pallas_certified_program) on a topology mesh, at
+    ``queries`` queries; ``resident``: handed the placement's row
+    operands (both bf16 halves, or the high one alone under ``terms``
+    without ``hl``, and the norms) as ``ShardedKNN._row_operands`` keeps
+    them, instead of forming them in the call."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -186,6 +193,9 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
           if kk not in ("tile_n", "precision")}
     if terms:
         kw["terms"] = terms
+    tile = knobs["tile_n"] or TILE_N
+    parts = (1 + ("hl" in (terms or "hh+hl+lh"))) if resident else 0
+    kw["resident_parts"] = parts
     if shape in AUGMENTED:
         kw.update(augmented=True, include_distances=False)
     if shape in COSINE:
@@ -193,25 +203,97 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
     if shape in VOTED:
         del kw["augmented"], kw["slack_outcome"]
         prog = _pallas_vote_program(
-            mesh, k + MARGIN, k, merge, knobs["tile_n"] or TILE_N,
+            mesh, k + MARGIN, k, merge, tile,
             knobs["precision"], n,
             (1.0 / VOTE_TEMPERATURE, VOTE_CLASSES_OUT,
              vote_delta(VOTE_TEMPERATURE, k)), interpret=False, **kw)
     else:
         prog = _pallas_certified_program(
-            mesh, k + MARGIN, k, merge, knobs["tile_n"] or TILE_N,
+            mesh, k + MARGIN, k, merge, tile,
             knobs["precision"], n_train=n, interpret=False, **kw)
     q = jax.ShapeDtypeStruct(
-        (NQ, d), jnp.float32, sharding=NamedSharding(mesh, P(QUERY_AXIS)))
+        (queries, d), jnp.float32,
+        sharding=NamedSharding(mesh, P(QUERY_AXIS)))
     db = jax.ShapeDtypeStruct(
         (rows, d), jnp.float32, sharding=NamedSharding(mesh, P(DB_AXIS)))
     norm = jax.ShapeDtypeStruct(
         (), jnp.float32, sharding=NamedSharding(mesh, P()))
     labels = jax.ShapeDtypeStruct(
         (n,), jnp.int32, sharding=NamedSharding(mesh, P()))
+    rows_p = -(-(rows // ds) // tile) * tile * ds  # each shard pads its own
+    halves = jax.ShapeDtypeStruct(
+        (rows_p, d), jnp.bfloat16, sharding=NamedSharding(mesh, P(DB_AXIS)))
+    norms = jax.ShapeDtypeStruct(
+        (rows_p,), jnp.float32, sharding=NamedSharding(mesh, P(DB_AXIS)))
     return prog, (q, db, norm) + (
+        (halves,) * parts + (norms,) if parts else ()) + (
         (norm,) if shape in AUGMENTED + COSINE else ()) + (
         (labels,) if shape in VOTED else ())
+
+
+#: the shapes ``--temporaries`` reads where none is named: the two
+#: widest lane-tiled placements of the benchmark that keep both halves
+TEMPORARIES_SHAPES = ("gist", "imagenet768")
+
+
+def temporaries_table(shape: str, devices, terms=None) -> tuple:
+    """``(placed rows' bytes, [(program, temporaries' bytes)])`` on one
+    chip at ``shape``, by XLA's ``memory_analysis()``: what
+    ``analysis.hbm.LANE_TILED_TEMP_FACTOR`` is read from.  The programs
+    a cell loads beside its rows: the certified program (the vote
+    program where the shape is answered by a vote) with its row operands
+    formed in the call and resident, at a call's 4,096 queries and at a
+    sub-batch's 1,024; the repair's exact re-select at its widened k
+    over 16 flagged queries; and the two the placement runs once,
+    ``lane_tile`` (only where the given width is no whole tile) and
+    ``operands``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from knn_tpu import tuning
+    from knn_tpu.analysis.widths import lane_tiled
+    from knn_tpu.ops.pallas_knn import TILE_N
+    from knn_tpu.parallel import sharded
+    from knn_tpu.parallel.mesh import DB_AXIS, QUERY_AXIS
+
+    n, given, k = SHAPES[shape]
+    d = lane_tiled(given)
+    knobs, _ = tuning.resolve_full(n, given, k, cache_path=os.devnull)
+    name = "vote" if shape in VOTED else "certified"
+    cases = [(f"{name}, operands {'resident' if resident else 'in the call'}"
+              f", {queries} queries",
+              _spmd_case(shape, knobs, devices, (1, 1), "ring", terms,
+                         queries=queries, resident=resident))
+             for resident in (False, True) for queries in (NQ, NQ // 4)]
+    mesh = Mesh(np.asarray(devices[:1]).reshape(1, 1), (QUERY_AXIS, DB_AXIS))
+
+    def aval(shp, dtype, spec):
+        return jax.ShapeDtypeStruct(
+            shp, dtype, sharding=NamedSharding(mesh, spec))
+
+    m = k + MARGIN
+    widen = max(2 * m, m + 64)  # ops.certified.repair_uncertified
+    cases.append((
+        f"re-select, k={widen}, 16 queries",
+        (sharded._knn_program(mesh, widen, "l2", "ring", n, TRAIN_TILE, None,
+                              "exact", dcn_merge=None),
+         (aval((16, d), jnp.float32, P(QUERY_AXIS)),
+          aval((n, d), jnp.float32, P(DB_AXIS))))))
+    if d != given:
+        cases.append((
+            f"lane_tile {given} -> {d} (once a placement)",
+            (sharded._lane_tile_program(mesh, d),
+             (aval((n, given), jnp.float32, P(DB_AXIS)),))))
+    cases.append((
+        "operands (once a placement)",
+        (sharded._row_operands_program(
+            mesh, knobs["tile_n"] or TILE_N, "hl" in (terms or "hh+hl+lh")),
+         (aval((n, d), jnp.float32, P(DB_AXIS)),))))
+    return n * d * 4, [
+        (label, fn.lower(*avals).compile().memory_analysis()
+         .temp_size_in_bytes) for label, (fn, avals) in cases]
 
 
 def _shard_width(shape: str, db_shards: int) -> int:
@@ -512,6 +594,11 @@ def main(argv=None) -> int:
                     "kernel multiplies (ops.pallas_knn.row_blocking "
                     "reads this off the shape; here it is asked for, "
                     "for the kernel alone: no --mesh)")
+    ap.add_argument("--temporaries", action="store_true",
+                    help="print what every program a cell loads sets "
+                    "aside, over the placed rows' bytes (--shape, else "
+                    "gist and imagenet768): the reading behind "
+                    "analysis.hbm.LANE_TILED_TEMP_FACTOR")
     ap.add_argument("--probe", action="store_true",
                     help="report the scoped-VMEM need Mosaic names "
                     "instead of compiling at the library's own limit")
@@ -520,6 +607,33 @@ def main(argv=None) -> int:
     devices = _topology_devices()
     print(f"target: {devices[0].device_kind} x{len(devices)} "
           f"({TOPOLOGY}, deviceless)", flush=True)
+    if args.temporaries:
+        from knn_tpu.analysis import hbm
+
+        # what a default call loads beside resident operands: the
+        # sub-batch's program and the repair's re-select.  The others
+        # are printed for the reading: the in-call forms are loaded
+        # where the operands are NOT kept, and a launch of 4,096
+        # queries is an explicit batch_size's or a 16,384-query call's
+        # (its temporaries grow with the queries, not with the rows)
+        beside = (f"operands resident, {NQ // 4} queries", "re-select")
+        worst = 0.0
+        for shape in ([args.shape] if args.shape else TEMPORARIES_SHAPES):
+            placed, table = temporaries_table(shape, devices, args.terms)
+            for label, temp in table:
+                counted = any(b in label for b in beside)
+                if counted:
+                    worst = max(worst, temp / placed)
+                print(f"TEMP {shape} {label}: {temp / 1e9:.3f} GB of "
+                      f"{placed / 1e9:.3f} GB placed = {temp / placed:.3f}"
+                      f"{'  <- a default call' if counted else ''}",
+                      flush=True)
+        ok = worst <= hbm.LANE_TILED_TEMP_FACTOR
+        print(f"{'OK  ' if ok else 'FAIL'} the largest a default call "
+              f"loads beside resident operands: {worst:.3f} of the placed "
+              f"rows; analysis.hbm.LANE_TILED_TEMP_FACTOR = "
+              f"{hbm.LANE_TILED_TEMP_FACTOR}", flush=True)
+        return 0 if ok else 1
     if args.shape is None:
         cases = default_cases()
     else:

@@ -7,16 +7,18 @@ Run on the chip (a CPU run proves nothing here: the CPU rounds the cast):
     chiprun -- python3 scripts/kernel_error_probe.py
 
 65,536 unit rows and 256 unit queries of ``openai500k``'s law (1,536
-columns).  Prints (1) the rows' bf16 halves as ``_split_rows`` makes them
-under jit, without and with ``rounded_lo``, and behind an optimization
-barrier, each against numpy's split (max |tl|, the residual, equality to
-the bit); on the v5e the first reads max |tl| 0: inside one fusion the
-compiler keeps ``f32(bf16(x))`` at ``x``; (2) the compiled tiled kernel's
-score error (kernel score less the float64 score of the placed values)
-with the in-program operands, with ``row_operands`` and with numpy-made
-halves, beside ``kernel_tolerance``.  PR 43 read 1.0e-4 std and 4.7e-4 at
-most in-program (3.9 times the tolerance) and 2.6e-7 / 1.1e-6 for the
-other two.  The tile is cut as ``ops.pallas_knn.row_blocking`` cuts it
+columns).  Prints (1) the rows' bf16 halves under jit by the cast's own
+round trip (``x - f32(bf16(x))``), as ``_split_rows`` makes them (against
+``lax.reduce_precision``), and behind an optimization barrier, each
+against numpy's split (max |tl|, the residual, equality to the bit); on
+the v5e the first reads max |tl| 0: inside one fusion the compiler keeps
+``f32(bf16(x))`` at ``x``; (2) the compiled tiled kernel's score error
+(kernel score less the float64 score of the placed values) with the
+in-program operands, with ``row_operands`` and with numpy-made halves,
+beside ``kernel_tolerance``.  PR 43 read 1.0e-4 std and 4.7e-4 at most
+in-program (3.9 times the tolerance: the in-call split was the round
+trip until PR 49, so it should now read as the other two) and 2.6e-7 /
+1.1e-6 for the other two.  The tile is cut as ``ops.pallas_knn.row_blocking`` cuts it
 (four steps of 4,096 rows at this width since PR 46); ``--row-block N``
 hands the kernel another block, to time or to check a cut the rule does
 not make.
@@ -74,10 +76,13 @@ def main() -> int:
               f"th==numpy {bool((th == th_np).all())} "
               f"tl==numpy {bool((tl == tl_np).all())}", flush=True)
 
-    for rounded in (False, True):
-        split = jax.jit(functools.partial(pk._split_rows, with_lo=True,
-                                          rounded_lo=rounded))
-        show(f"_split_rows rounded_lo={rounded}", *split(t_dev))
+    def round_trip(x):
+        th = x.astype(jnp.bfloat16)
+        return th, (x - th.astype(jnp.float32)).astype(jnp.bfloat16)
+
+    show("the cast's own round trip, jitted", *jax.jit(round_trip)(t_dev))
+    show("_split_rows", *jax.jit(functools.partial(
+        pk._split_rows, with_lo=True))(t_dev))
 
     def barrier(x):
         th = lax.optimization_barrier(x.astype(jnp.bfloat16))

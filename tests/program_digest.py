@@ -26,6 +26,14 @@ give the digests they gave.
 PR 48 added the eighth row, ``imagenet-knn768.sweep_vote``, whose program
 is the vote program (``VOTED``); the seven digests of the five older
 rows are what they were.
+
+PR 49 recorded ``gist1m.sweep``'s and ``imagenet-knn768.sweep_vote``'s
+three digests each anew and no other: both cells keep their row operands
+resident on the chip since (``analysis.hbm.program_temp_factor``), so
+their rows are digested as the other cells' are, handed both halves and
+the norms (``resident_parts`` 2 where it was 0).  PR 49's PARENT gives
+the same six digests at ``resident_parts`` 2: the program is the
+parent's, it is the cell that runs another of its forms.
 """
 
 import hashlib
@@ -40,13 +48,12 @@ import re
 #: ones at the old widths)
 CELLS = {
     "bigann5m.sweep": (1, 5_000_000, 128, 100, "hh", 1, False),
-    "gist1m.sweep": (1, 1_000_000, 1024, 100, "hh+hl+lh", 0, False),
+    "gist1m.sweep": (1, 1_000_000, 1024, 100, "hh+hl+lh", 2, False),
     "bigann20m-x4.sweep": (4, 5_000_000, 128, 100, "hh", 1, False),
     "text2image2m5.sweep_ip": (1, 2_500_000, 256, 10, "hh+hl+lh", 2, True),
     "ssnpp2m5.sweep_range": (1, 2_500_000, 256, 100, "hh", 1, False),
-    # the eighth cell (PR 48): its program is the VOTE program (VOTED),
-    # the operands formed in the call (``per_call`` on the chip)
-    "imagenet-knn768.sweep_vote": (1, 1_281_167, 768, 20, "hh+hl+lh", 0,
+    # the eighth cell (PR 48): its program is the VOTE program (VOTED)
+    "imagenet-knn768.sweep_vote": (1, 1_281_167, 768, 20, "hh+hl+lh", 2,
                                    False),
 }
 #: cell -> (temperature, classes out) of a cell answered by

@@ -464,8 +464,9 @@ def test_the_rounded_low_half_is_the_casts_own_where_the_cast_rounds():
     from knn_tpu.ops import pallas_knn as pk
 
     x = sh._unit_rows(mix(2000, 4, dim=200)[0])[0]
-    th, tl = pk._split_rows(jnp.asarray(x), True)
-    th2, tl2 = pk._split_rows(jnp.asarray(x), True, rounded_lo=True)
+    th2, tl2 = pk._split_rows(jnp.asarray(x), True)
+    th = jnp.asarray(x).astype(jnp.bfloat16)   # the cast's own round trip
+    tl = (jnp.asarray(x) - th.astype(jnp.float32)).astype(jnp.bfloat16)
     np.testing.assert_array_equal(np.asarray(th), np.asarray(th2))
     np.testing.assert_array_equal(np.asarray(tl), np.asarray(tl2))
     assert np.abs(np.asarray(tl, np.float32)).max() > 0

@@ -20,6 +20,7 @@ and the certified program takes them as arguments.
   counter, the ``placement.operands`` event.
 """
 
+import functools
 import math
 import weakref
 
@@ -102,9 +103,15 @@ def _launches(program="operands"):
                if s["labels"]["program"] == program)
 
 
-def _built():
+def _decided():
+    """Every ``placement.operands`` event: one a decision of the rule,
+    kept or not."""
     return [e for e in obs.get_event_log().recent()
             if e.get("name") == "placement.operands"]
+
+
+def _built():
+    return [e for e in _decided() if e["kept"]]
 
 
 def _inflight_builds():
@@ -239,7 +246,7 @@ def test_the_other_precisions_keep_their_in_program_prep():
         _, _, stats = prog.search_certified(
             q, selector="pallas", tile_n=TILE, precision=precision)
         assert stats["operands"] == "per_call"
-    assert prog._operands_cache is None and _built() == []
+    assert prog._operands_cache is None and _decided() == []
 
 
 # --- the traced program ---------------------------------------------------
@@ -317,61 +324,123 @@ def test_prepared_operands_of_another_geometry_are_refused():
 
 # --- the rule -------------------------------------------------------------
 V5E = 16909336064     # bytes_limit of one v5e chip, as the chip reads it
+#: bytes_in_use at ``_row_operands``' decision in the two cells PR 49
+#: flipped (my chip run, PR 49, call 1, ``memory_stats()`` read in
+#: ``_row_operands``: the lane-tiled rows ONCE; at gist1m the compact
+#: 960-column source is gone by then, ``peak_bytes_in_use`` 7,936,549,376
+#: is all that is left of it, and ``bytes_reserved`` reads the
+#: ``lane_tile`` program's 536,903,680; at imagenet-knn768 the labels
+#: are the 5 MB over the rows and nothing is reserved yet)
+GIST1M_IN_USE = 4_096_303_616
+IMAGENET_IN_USE = 3_940_903_424
 
 
-@pytest.mark.parametrize("cell,rows,dim,placed,with_lo,in_use,keeps", [
-    # placed bytes and bytes_in_use as the chip read them with the rows
-    # placed and no program run yet (PERF.md section 4 "Memory")
-    ("bigann5m", 5_000_000, 128, 2_560_000_000, False, 2_560_027_136, True),
-    ("ssnpp2m5", 2_500_000, 256, 2_560_000_000, False, 2_560_027_136, True),
-    ("text2image2m5", 2_500_000, 201, 2_010_000_000, True, 2_080_000_000,
-     True),
-    ("gist1m", 1_000_000, 960, 3_840_000_000, True, 3_840_272_896, False),
-    # R9, 10M x 128 on one chip: the in-program form still fits (14.4 GB)
-    ("bigann10m", 10_000_000, 128, 5_120_000_000, False, 5_120_027_136,
-     False),
-    # 500K x 1,536 unit rows with both halves (PR 43): 3.072 + 3.123 +
-    # 2.7 x 3.072 = 14.49 of 14.80 GB, kept with 0.31 GB to spare (the
-    # chip read `resident`; its bytes_in_use before the first program
-    # is reckoned as the other cells' read: placed + 27,136)
-    ("openai500k", 500_000, 1536, 3_072_000_000, True, 3_072_027_136, True),
-    # 1,281,167 x 768 unit rows (PR 48): 3.936 + 3.981 + 2.7 x 3.936 =
-    # 18.5 GB of 14.80, so the vote program forms its operands in the
-    # call (the chip read `per_call` and `bytes_in_use` 3,947,943,424
-    # after a window, the labels and a batch in it; before the first
-    # program it is reckoned as the other cells' read); at the 1.17 the
-    # lane-tiled programs really set aside it would fit with 2.3 GB to
-    # spare (ROADMAP A18, with gist1m)
-    ("imagenet-knn768", 1_281_167, 768, 3_935_745_024, True, 3_935_772_160,
-     False),
+@pytest.mark.parametrize("cell,rows,width,with_lo,in_use,keeps", [
+    # ``width`` is the placed rows' columns (whole lane tiles wherever
+    # ShardedKNN laid them out), ``in_use`` the chip's bytes_in_use with
+    # the rows placed and no program of the call run yet (PERF.md
+    # section 4 "Memory"; gist1m's and imagenet-knn768's are what the
+    # chip read AT the decision, PR 49)
+    ("bigann5m", 5_000_000, 128, False, 2_560_027_136, True),
+    ("ssnpp2m5", 2_500_000, 256, False, 2_560_027_136, True),
+    # 201 given columns placed in 256 since PR 44
+    ("text2image2m5", 2_500_000, 256, True, 2_560_027_136, True),
+    # 960 given columns placed in 1,024: 4.10 + 4.17 + 1.25 x 4.10 =
+    # 13.4 of 14.80 GB (at 2.7: 19.3, and per_call until PR 49)
+    ("gist1m", 1_000_000, 1024, True, GIST1M_IN_USE, True),
+    # R9, 10M x 128 on one chip: 5.12 + 2.60 + 1.25 x 5.12 = 14.1 GB
+    ("bigann10m", 10_000_000, 128, False, 5_120_027_136, True),
+    # 500K x 1,536 unit rows with both halves (PR 43): kept at 2.7
+    # already with 0.31 GB to spare
+    ("openai500k", 500_000, 1536, True, 3_072_027_136, True),
+    # 1,281,167 x 768 unit rows (PR 48): 3.94 + 3.98 + 1.25 x 3.94 =
+    # 12.8 GB (at 2.7: 18.5, and per_call until PR 49)
+    ("imagenet-knn768", 1_281_167, 768, True, IMAGENET_IN_USE, True),
+    # a PRE-PLACED 1M x 960 array is used as handed in: its programs
+    # still copy and pad all of it (2.7), 3.84 + 4.17 + 10.37 = 18.4 GB
+    ("gist1m-preplaced-960", 1_000_000, 960, True, 3_840_027_136, False),
+    # the same rows with the 960-column source still alive beside the
+    # lane-tiled ones: no factor admits 7.94 + 4.17 + 5.12 = 17.2 GB,
+    # so the decision must see the rows once
+    ("gist1m-source-alive", 1_000_000, 1024, True, 7_936_027_136, False),
 ])
-def test_the_rule_at_every_cells_bytes(cell, rows, dim, placed, with_lo,
-                                       in_use, keeps):
+def test_the_rule_at_every_cells_bytes(cell, rows, width, with_lo, in_use,
+                                       keeps):
     rows_p = -(-rows // pk.TILE_N) * pk.TILE_N
     form = hbm.row_operand_bytes(
-        rows_p, -(-dim // pk.DIM_CHUNK) * pk.DIM_CHUNK, with_lo)
+        rows_p, -(-width // pk.DIM_CHUNK) * pk.DIM_CHUNK, with_lo)
     stats = {"bytes_limit": V5E, "bytes_in_use": in_use, "bytes_reserved": 0}
-    assert hbm.resident_operands_fit(form, placed, stats) is keeps
+    room = hbm.resident_operands_room(form, rows * width * 4, stats,
+                                      width=width)
+    assert room["kept"] is keeps
+    assert hbm.resident_operands_fit(form, rows * width * 4, stats,
+                                     width=width) is keeps
+    assert (room["held"], room["form_bytes"], room["limit"]) == (
+        in_use, form, int(hbm.RESIDENT_FILL * V5E))
+    assert room["temporaries"] == int(
+        (1.25 if width % 128 == 0 else 2.7) * rows * width * 4)
 
 
-def test_the_rule_reads_what_the_device_says():
+@pytest.mark.parametrize("width,factor", [
+    (128, hbm.LANE_TILED_TEMP_FACTOR), (1024, hbm.LANE_TILED_TEMP_FACTOR),
+    (960, hbm.ROWS_PROGRAM_TEMP_FACTOR), (201, hbm.ROWS_PROGRAM_TEMP_FACTOR)])
+def test_the_rule_reads_what_the_device_says(width, factor):
+    assert hbm.program_temp_factor(width) == factor
+    assert (hbm.LANE_TILED_TEMP_FACTOR, hbm.ROWS_PROGRAM_TEMP_FACTOR) == (
+        1.25, 2.7)
+    fit = functools.partial(hbm.resident_operands_fit, width=width)
     form, placed = 1_000, 4_000
     room = {"bytes_limit": 100_000, "bytes_in_use": placed}
-    assert hbm.resident_operands_fit(form, placed, room)
+    assert fit(form, placed, room)
     # no accounting (the CPU): nothing to run out of
-    assert hbm.resident_operands_fit(form, placed, {})
-    # placed + form + the factor x placed against 7/8 of the limit
-    edge = placed + form + int(hbm.ROWS_PROGRAM_TEMP_FACTOR * placed)
-    assert hbm.resident_operands_fit(
-        form, placed, {"bytes_limit": math.ceil(edge / hbm.RESIDENT_FILL)})
-    assert not hbm.resident_operands_fit(
-        form, placed,
-        {"bytes_limit": math.floor((edge - 8) / hbm.RESIDENT_FILL)})
+    assert fit(form, placed, {})
+    # placed + form + the width's factor x placed against 7/8 of the limit
+    edge = placed + form + int(factor * placed)
+    assert fit(form, placed,
+               {"bytes_limit": math.ceil(edge / hbm.RESIDENT_FILL)})
+    assert not fit(form, placed,
+                   {"bytes_limit": math.floor((edge - 8) / hbm.RESIDENT_FILL)})
     # what else the process holds there counts, and so does a loaded
     # program that sets aside more than the model says
-    assert not hbm.resident_operands_fit(
-        form, placed, {**room, "bytes_in_use": 90_000})
-    assert not hbm.resident_operands_fit(
-        form, placed, {**room, "bytes_reserved": 85_000})
+    assert not fit(form, placed, {**room, "bytes_in_use": 90_000})
+    assert not fit(form, placed, {**room, "bytes_reserved": 85_000})
     assert hbm.row_operand_bytes(16, 128, False) == 16 * (256 + 4)
     assert hbm.row_operand_bytes(16, 128, True) == 16 * (512 + 4)
+
+
+@pytest.mark.parametrize("kind", ["byte", "float"])
+def test_a_refusal_says_what_the_rule_compared(kind):
+    """The ``placement.operands`` event is the decision's, either way:
+    on a refusal it carries the terms the rule compared, as it does
+    where the operands are built."""
+    db, q, metric, terms = corpus(kind)
+    res, per = placed_pair(db, metric, 1)
+    placed = per._tp.nbytes
+    form = hbm.row_operand_bytes(-(-ROWS // TILE) * TILE, 128, "hl" in terms)
+    # a device with room for the rows and the form but not for the
+    # programs' temporaries beside them
+    tight = {"bytes_limit": int((placed + form + placed) / hbm.RESIDENT_FILL),
+             "bytes_in_use": placed + 64, "bytes_reserved": 0}
+    assert per._row_operands(TILE, "hl" in terms, memory_stats=tight) is None
+    (no,) = _decided()
+    assert {k: no[k] for k in ("held", "form_bytes", "temporaries", "limit",
+                               "kept")} == {
+        "held": placed + 64, "form_bytes": form,
+        "temporaries": int(hbm.LANE_TILED_TEMP_FACTOR * placed),
+        "limit": int(hbm.RESIDENT_FILL * tight["bytes_limit"]),
+        "kept": False}
+    assert (no["tile"], no["parts"], no["bytes"], no["seconds"]) == (
+        TILE, "th+tl" if "hl" in terms else "th", 0, 0.0)
+    assert _built() == [] and _launches() == 0
+    # decided once a geometry: the call that follows asks again, is
+    # answered from the cache and says nothing more
+    per.search_certified(q, selector="pallas", tile_n=TILE)
+    assert len(_decided()) == 1 and per._operands_source == "per_call"
+    # the same reading with the temporaries' room: kept, the same terms
+    roomy = {**tight, "bytes_limit": 2 * tight["bytes_limit"]}
+    assert res._row_operands(TILE, "hl" in terms,
+                             memory_stats=roomy) is not None
+    yes = _decided()[-1]
+    assert (yes["kept"], yes["held"], yes["form_bytes"], yes["temporaries"],
+            yes["bytes"]) == (True, placed + 64, form, no["temporaries"], form)
+    assert yes["held"] + yes["form_bytes"] + yes["temporaries"] <= yes["limit"]

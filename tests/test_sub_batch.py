@@ -56,14 +56,15 @@ CELLS = {
     "bigann5m.sweep": (128, "resident"),
     "bigann20m-x4.sweep": (128, "resident"),
     "ssnpp2m5.sweep_range": (256, "resident"),
-    "gist1m.sweep": (960, "per_call"),
+    # resident since PR 49: the rule reckons a lane-tiled placement's
+    # largest program at 1.25 x the rows, not a copied one's 2.7
+    "gist1m.sweep": (960, "resident"),
     "text2image2m5.sweep_ip": (201, "resident"),
     "yfcc2m5.sweep_filter": (192, "resident"),
     "openai500k.sweep_cos": (1536, "resident"),
-    # 3.94 GB of rows and 3.94 of halves: the rule's reckoning of the
-    # largest program's temporaries (2.7 x the rows) leaves no room
-    # (the chip read ``per_call``, PR 48)
-    "imagenet-knn768.sweep_vote": (768, "per_call"),
+    # 3.94 GB of rows and 3.98 of halves and norms (resident since
+    # PR 49, as gist1m)
+    "imagenet-knn768.sweep_vote": (768, "resident"),
 }
 
 
@@ -97,11 +98,12 @@ def test_the_cells_as_the_chip_runs_them():
            == "resident"}
     # since PR 44 every cell whose operands are resident: the rows of
     # text2image2m5 (201 columns) and yfcc2m5 (192) are placed in 256
-    assert cut == set(CELLS) - {"gist1m.sweep", "imagenet-knn768.sweep_vote"}
+    # and since PR 49 keeps gist1m's and imagenet-knn768's operands too
+    assert cut == set(CELLS)
+    # a placement a full device keeps per_call is one launch still
     assert rule(width=768, operands="per_call")[1] == "per_call_operands"
     assert rule(width=lane_tiled(960), operands="per_call")[1] == (
         "per_call_operands")
-    # gist1m joins by the rule alone once its operands are kept
     assert rule(width=lane_tiled(960))[1] == "resident"
     # and what the placement does not lay out keeps its width's reading
     assert {rule(width=w)[1] for w in (192, 201, 960)} == {"layout_copy"}
@@ -325,6 +327,44 @@ def test_a_filtered_call_is_cut_and_equal(corpus):
     same_answer(cut, one)
     assert cut[2]["filter"] == one[2]["filter"]
     assert (cut[1][ROWS + 3] == -1).all() and cut[2]["filter"]["empty"] >= 1
+    launches = {s["labels"]["program"]: s["value"] for s in
+                obs.snapshot()[mn.PROGRAM_LAUNCHES]["series"]}
+    assert launches["certified"] == N + 1
+
+
+def test_a_voted_call_is_cut_and_equal():
+    """``predict_certified(vote="softmax")``: the vote program takes the
+    resident operands as the certified program does, so a default call
+    of 4,096 queries is cut in four, and classes and totals are the
+    uncut call's (the re-vote of the flagged queries, once a call over
+    every sub-batch's, included)."""
+    rng = np.random.default_rng(49)
+    classes = 24
+    centres = rng.normal(size=(classes, 96)).astype(np.float32)
+    labels = rng.integers(0, classes, size=1500).astype(np.int32)
+    db = centres[labels] + 0.7 * rng.normal(size=(1500, 96)).astype(np.float32)
+    db[300:320] = db[299]      # equal rows under other labels: boundary
+    labels[300:320] = np.arange(20) % classes   # ties and close margins
+    q = (centres[rng.integers(0, classes, size=N_Q)]
+         + 0.9 * rng.normal(size=(N_Q, 96)).astype(np.float32))
+    q[7], q[ROWS], q[N_Q - 3] = db[299], db[305], db[310]
+    prog = ShardedKNN(db, mesh=make_mesh(1, 1), k=20, metric="cosine",
+                      labels=labels, num_classes=classes)
+    ask = dict(vote="softmax", temperature=0.07, classes_out=5,
+               selector="pallas")
+    cut = prog.predict_certified(q, **ask)
+    one = prog.predict_certified(q, batch_size=N_Q, **ask)
+    assert (cut[2]["operands"], cut[2]["batches"], cut[2]["sub_batch"]) == (
+        "resident", N, "resident")
+    assert (one[2]["batches"], one[2]["sub_batch"]) == (1, "explicit")
+    np.testing.assert_array_equal(cut[0], one[0])
+    np.testing.assert_array_equal(cut[1], one[1])  # float64 totals, bitwise
+    for key in ("vote_boundary_queries", "vote_margin_queries",
+                "vote_repaired_queries", "fallback_queries"):
+        assert cut[2][key] == one[2][key], key
+    # the corpus does what it was made for: the re-vote had work on
+    # both sides of a seam
+    assert cut[2]["vote_repaired_queries"] >= 3
     launches = {s["labels"]["program"]: s["value"] for s in
                 obs.snapshot()[mn.PROGRAM_LAUNCHES]["series"]}
     assert launches["certified"] == N + 1

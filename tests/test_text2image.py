@@ -358,7 +358,9 @@ def test_the_resident_low_half_is_a_rounding_the_compiler_may_not_skip(
     cast's own round trip, which the TPU compiler keeps in float32
     inside one fusion (on the chip that low half read all zero, and the
     kernel's score was off by more than its tolerance at 1,536 unit
-    columns).  The in-program form keeps the text it had."""
+    columns).  Since PR 49 the in-call split is the same text
+    (``_split_rows`` has one form); the round trip, spelled out here,
+    still compiles to no rounding at all."""
     import functools
 
     import jax.numpy as jnp
@@ -371,8 +373,15 @@ def test_the_resident_low_half_is_a_rounding_the_compiler_may_not_skip(
         pk.row_operands, tile_n=pk.TILE_N, with_lo=True)).lower(
         rows).compile().as_text()
     assert "reduce-precision(" in text
-    old = jax.jit(lambda x: pk._split_rows(x, True)).lower(
+    in_call = jax.jit(lambda x: pk._split_rows(x, True)).lower(
         rows).compile().as_text()
+    assert "reduce-precision(" in in_call
+
+    def round_trip(x):
+        th = x.astype(jnp.bfloat16)
+        return th, (x - th.astype(jnp.float32)).astype(jnp.bfloat16)
+
+    old = jax.jit(round_trip).lower(rows).compile().as_text()
     assert "reduce-precision(" not in old
 
 
