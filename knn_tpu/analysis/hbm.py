@@ -213,7 +213,8 @@ def plan_join(
 #: libtpu 0.0.34).  What a default call loads beside resident operands:
 #: the sub-batch's certified (or vote) program at 1,024 queries, 0.09
 #: to 0.54 over the benchmark's shapes, and the repair's exact
-#: re-select, 1.02 to 1.05 (its padded copy of the rows).  At the two
+#: re-select, under 0.001 since its scan reads the rows where they lie
+#: (PR 50; its padded copy of the rows read 1.02 to 1.05).  At the two
 #: shapes this factor decides for, ``gist`` (1M x 1,024 placed) /
 #: ``imagenet768`` (1,281,167 x 768; every narrower cell fits at 2.7
 #: too), it covers every other form of the program as well: handed its

@@ -107,12 +107,23 @@ def knn_search_tiled(
 ) -> Tuple[jax.Array, jax.Array]:
     """Exact KNN streaming over train tiles with a running top-k merge.
 
-    HBM cost is O(Q*train_tile) per step instead of O(Q*T).  Handles T not
-    divisible by ``train_tile`` by padding with +inf distances (replacing the
-    reference's divisibility ``MPI_Abort`` at knn_mpi.cpp:127-129 with
-    padding).  ``n_valid`` additionally marks trailing train rows as padding
-    (see :func:`knn_search`).  Results are identical to :func:`knn_search`
-    including lower-index tie-breaks.
+    HBM cost is O(Q*train_tile) per step instead of O(Q*T).  The rows are
+    scanned WHERE THEY LIE: every step reads one ``[train_tile, D]`` window
+    of ``train`` as given, and a T not divisible by ``train_tile``
+    (the reference's divisibility ``MPI_Abort``, knn_mpi.cpp:127-129) ends
+    in a whole tile that stops at the last row: its start is clamped to
+    ``T - train_tile``, and the rows it shares with the tile before, already
+    scored there, are masked out by their global index before the tile's
+    top-k.  Padding the operand to whole tiles instead is wrong on a placed
+    corpus: the pad is a copy of ALL rows, read and written in every call
+    (12.8 ms a batch of the certified repair's re-select at 1M x 1,024
+    float32 on a v5e, and the largest temporary of any loaded program),
+    to append less than one tile of zeros that the mask then discards.
+
+    ``n_valid`` additionally marks trailing train rows as padding (see
+    :func:`knn_search`).  A masked row comes out as ``(+inf, int32 max)``,
+    never under a real row's index.  Every finite ``(distance, index)``
+    pair equals :func:`knn_search`'s, lower-index tie-breaks included.
     """
     n_train = train.shape[0]
     if k > n_train:
@@ -124,33 +135,39 @@ def knn_search_tiled(
     limit = n_train if n_valid is None else jnp.minimum(n_train, n_valid)
 
     n_tiles = -(-n_train // train_tile)
-    padded = n_tiles * train_tile
-    if padded != n_train:
-        train = jnp.pad(train, ((0, padded - n_train), (0, 0)))
-    tiles = train.reshape(n_tiles, train_tile, train.shape[-1])
+    ragged = n_train % train_tile != 0
+    sentinel = jnp.iinfo(jnp.int32).max
 
     n_q = queries.shape[0]
     init_d = jnp.full((n_q, k), jnp.inf, dtype=jnp.float32)
-    init_i = jnp.full((n_q, k), jnp.iinfo(jnp.int32).max, dtype=jnp.int32)
+    init_i = jnp.full((n_q, k), sentinel, dtype=jnp.int32)
 
-    def step(carry, args):
+    def live(gidx, first):
+        """Rows this step scores: not padding, and (in the clamped last
+        tile) not scored by the step before."""
+        ok = gidx < limit
+        return ok & (gidx >= first) if ragged else ok
+
+    def step(carry, tile_idx):
         best_d, best_i = carry
-        tile_idx, tile = args
+        first = tile_idx * train_tile  # the first row this step has to score
+        start = jnp.minimum(first, n_train - train_tile) if ragged else first
+        tile = lax.dynamic_slice_in_dim(train, start, train_tile, axis=0)
         d = pairwise_distance(queries, tile, metric, compute_dtype=compute_dtype)
-        gidx = tile_idx * train_tile + lax.broadcasted_iota(jnp.int32, (1, train_tile), 1)
-        d = jnp.where(gidx < limit, d, jnp.inf)
+        gidx = start + lax.broadcasted_iota(jnp.int32, (1, train_tile), 1)
+        d = jnp.where(live(gidx, first), d, jnp.inf)
         if train_tile > k:
             # Reduce the tile to its local top-k *first* (exact: every
             # global top-k member inside this tile is also in the tile's
             # top-k), so the lexicographic merge sorts 2k candidates, not
             # k + train_tile.
-            td, ti = topk_smallest(d, k)
-            tgi = tile_idx * train_tile + ti  # ti are tile-local columns
-            return merge_topk(best_d, best_i, td, tgi, k), None
+            d, ti = topk_smallest(d, k)
+            gidx = start + ti  # ti are tile-local columns
+        gidx = jnp.where(live(gidx, first), gidx, sentinel)
         return merge_topk(best_d, best_i, d, jnp.broadcast_to(gidx, d.shape), k), None
 
     (best_d, best_i), _ = lax.scan(
-        step, (init_d, init_i), (jnp.arange(n_tiles, dtype=jnp.int32), tiles)
+        step, (init_d, init_i), jnp.arange(n_tiles, dtype=jnp.int32)
     )
     return best_d, best_i
 

@@ -2295,9 +2295,11 @@ class ShardedKNN:
                     dcn_merge=self.dcn_merge,
                 )
                 nonlocal merge_bytes
+                # scan_rows_copied: the exact scan reads the placed rows
+                # where they lie (ops.topk.knn_search_tiled)
                 with obs.span("certified.repair.reselect", tid,
                               parent="certified.repair", widen=widen,
-                              rows=qb.shape[0]):
+                              rows=qb.shape[0], scan_rows_copied=0):
                     bq, _ = self._place_queries(qb)
                     merge_bytes += self._record_merge_bytes(
                         bq.shape[0], widen)
@@ -3472,9 +3474,11 @@ class ShardedKNN:
                     self.train_tile, None, "exact",
                     dcn_merge=self.dcn_merge)
                 nonlocal merge_bytes
+                # scan_rows_copied: the exact scan reads the placed rows
+                # where they lie (ops.topk.knn_search_tiled)
                 with obs.span("certified.repair.reselect", tid,
                               parent="certified.repair", widen=widen,
-                              rows=qb.shape[0]):
+                              rows=qb.shape[0], scan_rows_copied=0):
                     bq, _ = self._place_queries(qb)
                     merge_bytes += self._record_merge_bytes(
                         bq.shape[0], widen)

@@ -178,6 +178,7 @@ def test_a_fallback_adds_one_reselect_under_repair(tied):
     assert re["parent"] == "certified.repair"
     assert re["trace_id"] == by["certified.call"]["trace_id"]
     assert re["rows"] == q.shape[0] and re["widen"] > K
+    assert re["scan_rows_copied"] == 0  # the rows are read where they lie
     assert re["dur_s"] <= by["certified.repair"]["dur_s"] + 1e-4
     assert by["certified.repair"]["fallback_queries"] == q.shape[0]
     assert by["certified.repair"]["host_exact_queries"] == stats.get(

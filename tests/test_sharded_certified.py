@@ -341,3 +341,62 @@ def test_certified_pallas_multitile_multichunk_sharded(rng):
                                         tile_n=256, margin=8)
     np.testing.assert_array_equal(i, ref_i)
     np.testing.assert_allclose(d, ref_d, rtol=5e-5)
+
+
+#: the exclusion values ``v_w`` (the widen-th float32 score of each flagged
+#: query's re-select) that the program BEFORE PR 50 returned on this data,
+#: one and four devices alike: it padded every shard's rows to whole
+#: ``train_tile``s and scanned equal tiles
+_PARENTS_V_W = [393.0, 432.0, 412.0, 259.0]
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (1, 4)],
+                         ids=["one_device", "four_devices"])
+def test_a_ragged_reselect_repairs_a_fallback_batch(monkeypatch, mesh_shape):
+    """The repair's exact re-select over rows that are no whole number of
+    ``train_tile``s (1,500 = 11 x 128 + 92 on one device, 375 = 2 x 128 +
+    119 a shard on four): the last tile is read whole, ending at the last
+    row, and the rows it shares with the tile before are masked.  Tie runs
+    wider than the rank window, planted at the head, across the overlap's
+    two edges and through the last row, flag their queries; small-integer
+    values make every float32 score exact, so the widened selection is the
+    float64 oracle's to the last pair and its ``v_w`` the parent's."""
+    from knn_tpu.ops import certified
+
+    rng = np.random.default_rng(50)
+    db = rng.integers(-9, 10, size=(1500, 12)).astype(np.float32)
+    queries = rng.integers(-9, 10, size=(40, 12)).astype(np.float32)
+    # one device: tiles end at 1,408 (the clamped one starts at 1,372);
+    # four: shard 0's at 256 (247), shard 3's at rows 1,381 (1,372)
+    for at, (lo, hi) in enumerate([(100, 131), (240, 271), (1390, 1421),
+                                   (1469, 1500)], start=1):
+        db[lo:hi] = db[lo - 1]
+        queries[at] = db[lo - 1]
+    seen = []
+    real = certified.repair_uncertified
+
+    def spy(*args, select_fn, **kw):
+        def recorded(qb, widen):
+            fs, fi = select_fn(qb, widen)
+            seen.append((np.asarray(fs), np.asarray(fi)))
+            return fs, fi
+        return real(*args, select_fn=recorded, **kw)
+
+    monkeypatch.setattr(certified, "repair_uncertified", spy)
+    prog = ShardedKNN(db, mesh=make_mesh(*mesh_shape), k=5, train_tile=128)
+    shard_rows = prog._tp.sharding.shard_shape(prog._tp.shape)[0]
+    assert shard_rows % 128 and shard_rows > 128
+    d, i, stats = prog.search_certified(queries, selector="pallas", margin=8,
+                                        tile_n=256)
+    ref_d, ref_i = _oracle(db, queries, 5)
+    np.testing.assert_array_equal(i, ref_i)
+    np.testing.assert_array_equal(d, ref_d)
+    assert stats["fallback_queries"] == 4
+    assert stats.get("host_exact_queries", 0) == 0  # v_w proved them all
+    ((fs, fi),) = seen
+    widen = fs.shape[1]
+    assert widen == 77  # max(2 m, m + 64) at m = 13
+    want_d, want_i = _oracle(db, queries[1:5], widen)
+    np.testing.assert_array_equal(fi, want_i)
+    np.testing.assert_array_equal(fs, want_d.astype(np.float32))
+    assert fs[:, -1].tolist() == _PARENTS_V_W

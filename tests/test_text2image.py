@@ -479,9 +479,12 @@ def test_no_call_copies_the_placed_rows(one_chip, program, rows, given):
     ``gist1m``, the ledger's PR 43 lines); placed in whole lane tiles
     (``analysis.widths.lane_tiled``, what ``ShardedKNN`` places at) the
     parameter is row-major and nothing writes an array of its size: the
-    certified program's one reader is the rescore's gather, the
-    re-select's is its pad of the ROWS to whole ``train_tile``s (ROADMAP
-    A9: no column of it)."""
+    certified program's one reader is the rescore's gather, and the
+    re-select's is its scan, which reads one ``train_tile`` window a
+    step from the rows where they lie (a pad of the ROWS to whole tiles
+    was a copy of all of them: 12.8 ms a batch at ``gist1m``, the
+    ledger's PR 49 lines), so its temporaries are a tile's distances
+    and no array of rows at all."""
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -514,24 +517,32 @@ def test_no_call_copies_the_placed_rows(one_chip, program, rows, given):
             queries = 4096
         return prog.lower(aval((queries, width), jnp.float32, QUERY_AXIS),
                           aval((rows, width), jnp.float32, DB_AXIS),
-                          *tail).compile().as_text()
+                          *tail).compile()
 
     # as given: column-major, and a copy of all of it before anything
-    layout, users = _users_of_the_rows(compiled(given), rows, given)
+    # the certified program does (the re-select's scan reads either
+    # layout in place, and hands its loop the parameter in a tuple)
+    layout, users = _users_of_the_rows(compiled(given).as_text(), rows, given)
     assert layout.startswith("{0,1")
-    assert [op for op, size, _ in users if size >= rows * given] == ["copy"]
+    assert [op for op, size, _ in users if size >= rows * given] == [
+        "copy" if program == "certified" else "tuple"]
     # as placed
     width = lane_tiled(given)
-    layout, users = _users_of_the_rows(compiled(width), rows, width)
+    placed = compiled(width)
+    layout, users = _users_of_the_rows(placed.as_text(), rows, width)
     assert layout.startswith("{1,0")
     assert not [ln for op, _, ln in users if op.startswith("copy")]
-    whole = [(op, ln) for op, size, ln in users if size >= rows * width]
+    assert "pad" not in [op for op, _, _ in users]
     if program == "certified":
-        assert whole == [] and "pad" not in [op for op, _, _ in users]
+        assert not [ln for op, size, ln in users if size >= rows * width]
         assert [op for op, _, _ in users] == ["fusion"]  # the gather
     else:
-        ((op, ln),) = whole
-        assert op == "pad" and re.search(r"padding=0_\d+x0_0[,} ]", ln)
+        # the rows enter the scan's loop as they are, and no step sets a
+        # window of them aside: 16 queries' distances to a tile, not the
+        # 537 MB of a tile's rows (nor the 4.3 GB of all, padded)
+        assert [op for op, _, _ in users] == ["tuple"]
+        assert (placed.memory_analysis().temp_size_in_bytes
+                < 131072 * width * 4 // 8)
 
 
 # --- the plain reference ------------------------------------------------------
