@@ -17,16 +17,25 @@ h2d query stream into an amortized cost too.
 
 Entry points: :func:`knn_join` (one call, any ShardedKNN placement —
 resident or host-RAM tier — or an IVFIndex), :func:`default_plan`
-(the superblock/nesting plan the engine would use, jax-free).
+(the superblock/nesting plan the engine would use, jax-free),
+:func:`knn_self_join` (the exact k-NN graph of the placed rows: every
+row a query of the corpus it is part of, its own row out by id, the
+certified path's blocks in a bounded pipeline).
 """
 
 from knn_tpu.join.artifact import JOIN_VERSION, validate_join_block
-from knn_tpu.join.engine import JOIN_MODES, default_plan, knn_join
+from knn_tpu.join.engine import (
+    JOIN_MODES,
+    default_plan,
+    knn_join,
+    knn_self_join,
+)
 
 __all__ = [
     "JOIN_MODES",
     "JOIN_VERSION",
     "default_plan",
     "knn_join",
+    "knn_self_join",
     "validate_join_block",
 ]

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import os
 import time
+from collections import deque
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -53,6 +54,16 @@ DEFAULT_SUPERBLOCK_ROWS = 4096
 DEFAULT_DEPTH = 2
 
 JOIN_MODES = ("stream", "certified")
+
+#: blocks of a bulk self-join (:func:`knn_self_join`) launched and not
+#: yet fetched, at most: block b+1's programs are queued before block
+#: b is fetched, so the host's share of b runs under b+1 on the device
+#: and the device never waits for the host.  A constant of the engine,
+#: as ``DEFAULT_DEPTH`` is the stream's: 2 is what "the device always
+#: has the next block" takes; a third block in flight adds queued
+#: answers and nothing else.  (To time the serial loop against it, set
+#: it to 1 from outside, as ``analysis.subbatch.SUB_BATCHES`` is set.)
+SELF_JOIN_DEPTH = 2
 
 _ENV_SUPERBLOCK = "KNN_TPU_JOIN_SUPERBLOCK"
 _ENV_DEPTH = "KNN_TPU_JOIN_DEPTH"
@@ -472,3 +483,140 @@ def knn_join(
         d_out = np.asarray(metric_values(jnp.asarray(d_out),
                                          program.metric))
     return d_out, i_out, stats
+
+
+def knn_self_join(
+    program, rows: Optional[Tuple[int, int]] = None, *, filter_tags=None,
+) -> Tuple[np.ndarray, np.ndarray, dict]:
+    """The exact k-NN GRAPH of the placed rows, as one bulk call: for
+    every row i of ``rows = (lo, hi)`` of ``program``'s placement (all
+    rows where left out) the first ``program.k`` rows j != i in
+    lexicographic (float64 squared L2 over the float32 rows as given,
+    j) order: ``(d [hi - lo, k] float64, i [hi - lo, k] int64,
+    stats)``.  The row itself goes BY ID: exact copies of row i stay,
+    at distance 0, in id order.
+
+    Every row is a query of the corpus it is part of, so the call is
+    the certified path's own (``selector="pallas"``: one database pass,
+    the kernel's exclusion bound the certificate) under the join's
+    schedule:
+
+    - the call is cut into blocks of ``DEFAULT_SUPERBLOCK_ROWS`` rows,
+      each the sub-batch rule's launches (4 of 1,024), and at most
+      ``SELF_JOIN_DEPTH`` blocks are in flight: block b+1's launches
+      are queued before block b is fetched, unpacked, rank-corrected
+      and, where a query was flagged, re-selected; the re-select of
+      block b is launched behind block b+1 and fetched after it.  No
+      step waits for the whole call, and what is queued on the device
+      is bounded whatever ``rows`` is;
+    - a block's queries are rows of the placed array, taken on the
+      device where they lie: nothing of them crosses the link
+      (``certified.dispatch`` says ``h2d_bytes`` 0), and the host ranks
+      and repairs with its own copy of the same rows;
+    - row ``lo + r`` is scored +inf for query ``r`` before the
+      bin-select, in the row tiles that hold the launch's rows
+      (``parallel.sharded._pallas_self_program``), so it takes no
+      candidate's place and the certificate is read over the n - 1
+      rows that are left.
+
+    Squared-L2 placements that ``ShardedKNN`` laid out itself, resident
+    on the device.  An inner-product or cosine placement, the host-RAM
+    tier, a pre-placed array and ``filter_tags`` (a graph under a
+    predicate) refuse, each with what it lacks.  The answers do not
+    depend on the depth, bit for bit.
+
+    ``stats`` carries the stream's counts (``superblocks``,
+    ``dispatches``, ``overlap_ratio``, ``rows_per_s``) and the
+    certified call's (``fallback_queries``, ``rank_corrected_queries``,
+    ``operands``, ``sub_batch``, ``pallas_knobs``, ``tuning``), and
+    ``self_excluded``: the rows whose own row was taken out, which is
+    every row answered.  Spans: ``join.block`` a block (launch to
+    answer) with the ``certified.*`` stages summed over its launches
+    as its children; a call's ``certified.exposed`` and
+    ``certified.inflight.*`` as a search call's, and ``join.exposed``,
+    the same exposed seconds by where they fell (``fill_s`` before the
+    first launch, ``drain_s`` after the last answer, ``between_s``);
+    ``join.bulk`` the call."""
+    from knn_tpu import obs
+    from knn_tpu.obs import names as mn
+    from knn_tpu.parallel.sharded import _ACCOUNT_ROOT, _overlap_ratio
+
+    if not _is_sharded(program):
+        raise ValueError(
+            "the certified self-join needs a placed ShardedKNN: an IVF "
+            "index probes a few lists a query and holds no certificate "
+            "over all rows")
+    if filter_tags is not None:
+        raise ValueError(
+            "the certified self-join holds every query to ONE exclusion, "
+            "its own row; filter_tags asks for a graph under a predicate "
+            "(validity words a query beside the own row), which is not "
+            "built yet (ROADMAP R13)")
+    lo, hi = (0, int(program.n_train)) if rows is None else map(int, rows)
+    tid = obs.new_trace_id()
+    # a certified call's account (``certified.exposed``,
+    # ``certified.inflight.*``: once a call, as a search's), without its
+    # stage sums: those are a block's (``obs.trace.BlockAccount``)
+    acct = obs.trace.call_account(_ACCOUNT_ROOT, ("certified", "reselect"))
+    t0 = time.perf_counter()
+    call = program.self_join_call(lo, hi, DEFAULT_SUPERBLOCK_ROWS,
+                                  trace_id=tid, acct=acct)
+    blocks = [(b, min(b + DEFAULT_SUPERBLOCK_ROWS, hi))
+              for b in range(lo, hi, DEFAULT_SUPERBLOCK_ROWS)]
+    inflight = obs.gauge(mn.JOIN_BLOCKS_INFLIGHT)
+    pending, unsettled, intervals = deque(), deque(), []
+
+    def settle() -> None:
+        blk = unsettled.popleft()
+        call.settle(blk)
+        intervals.append((blk.t0, time.perf_counter()))
+
+    def collect() -> None:
+        blk = pending.popleft()
+        call.collect(blk)
+        unsettled.append(blk)
+        # block b's re-select was queued behind block b+1's programs:
+        # it is fetched once b+1 is, and at depth 1 at once
+        while len(unsettled) >= SELF_JOIN_DEPTH:
+            settle()
+
+    for b_lo, b_hi in blocks:
+        pending.append(call.launch(b_lo, b_hi))
+        inflight.set(len(pending))
+        if len(pending) >= SELF_JOIN_DEPTH:
+            collect()
+    while pending:
+        collect()
+    while unsettled:
+        settle()
+    inflight.set(0)
+    wall = time.perf_counter() - t0
+    n = hi - lo
+    told = call.finish()
+    stats = {
+        "mode": "self", "k": int(program.k), "rows": n,
+        "row_range": (lo, hi),
+        "superblock_rows": DEFAULT_SUPERBLOCK_ROWS,
+        "depth": SELF_JOIN_DEPTH, "superblocks": len(blocks),
+        "db_segments": 1, "dispatches": told["launches"],
+        "overlap_ratio": round(_overlap_ratio(intervals), 4),
+        "wall_s": round(wall, 6),
+        "rows_per_s": round(n / wall, 3) if wall > 0 else float("inf"),
+        **told,
+    }
+    obs.counter(mn.JOIN_ROWS, mode="self").inc(n)
+    acct.close(tid, "join.bulk")
+    if obs.enabled():
+        # what the pipeline did not hide: the call's exposed seconds by
+        # where they fell (the fill before the first launch, the drain
+        # after the last answer, and between, where the device had run
+        # out of queued blocks)
+        fill = acct.before_first or 0.0
+        obs.record_span(
+            "join.exposed", tid, acct.exposed, parent="join.bulk",
+            blocks=len(blocks), fill_s=fill, between_s=acct.between,
+            drain_s=acct.exposed - fill - acct.between)
+    obs.record_span("join.bulk", tid, wall, rows=n, mode="self",
+                    blocks=len(blocks), self_excluded=n,
+                    fallback_queries=told["fallback_queries"])
+    return call.d, call.i, stats

@@ -137,18 +137,44 @@ class NearestNeighbors:
     # -- graphs ------------------------------------------------------------
     def kneighbors_graph(
         self, Q=None, k: Optional[int] = None, *, mode: str = "connectivity",
+        include_self: bool = True,
     ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         """CSR triple ``(data, indices, indptr)`` of the k-NN adjacency
         [n_queries, n_samples_fit].  ``mode='connectivity'`` gives 1.0
         entries, ``'distance'`` the ranking-space distances.  ``Q=None``
-        builds the fit-set self-graph (each row's neighbors INCLUDE the
-        row itself at distance 0, sklearn's include-self-free convention
-        differs — drop column j == row i downstream if needed)."""
+        builds the fit-set self-graph: every fit row a query of the fit
+        set.  As it always did, each row's neighbours then INCLUDE the
+        row itself (an exact search finds it first, at distance ~0, and
+        one of the k places is spent on it).
+
+        ``include_self=False`` (``Q=None`` only; an estimator fitted on
+        a ``mesh``, squared L2) builds the graph WITHOUT it: row i's
+        entries are the first k rows j != i in lexicographic (float64
+        squared-L2 distance, j) order, exact and certified
+        (knn_tpu.join.knn_self_join: the placed rows queried against
+        themselves in one pipelined bulk call, the row taken out by ID
+        before the select).  An exact copy of row i stays, at distance
+        0: dropping "whatever lies at distance 0" downstream would take
+        the copies out with it, and dropping column j == i from an
+        include-self graph leaves k - 1 neighbours, or k with the wrong
+        last one where k copies come before i.  ``k`` is the
+        estimator's own there."""
         self._require_fit()
         if mode not in ("connectivity", "distance"):
             raise ValueError(f"unknown mode {mode!r}")
-        Q = self._fit_X if Q is None else Q
-        d, i = self.kneighbors(Q, k)
+        if not include_self:
+            if Q is not None or self._program is None or k not in (
+                    None, self.k):
+                raise ValueError(
+                    "include_self=False builds the fit set's own graph "
+                    "with the placed program's bulk self-join: Q must be "
+                    "None, k the estimator's, and the estimator fitted on "
+                    "a mesh")
+            from knn_tpu.join import knn_self_join
+
+            d, i, _ = knn_self_join(self._program)
+        else:
+            d, i = self.kneighbors(self._fit_X if Q is None else Q, k)
         d, i = np.asarray(d), np.asarray(i)
         n_q, kk = i.shape
         data = (np.ones(n_q * kk, np.float32) if mode == "connectivity"

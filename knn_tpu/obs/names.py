@@ -136,6 +136,8 @@ FINAL_SELECT_CALLS = "knn_tpu_final_select_calls_total"
 KERNEL_OPERANDS = "knn_tpu_kernel_operands_total"
 CERTIFIED_SUB_BATCH_CALLS = "knn_tpu_certified_sub_batch_calls_total"
 FILTER_QUERIES = "knn_tpu_filter_queries_total"
+JOIN_ROWS = "knn_tpu_join_rows_total"
+JOIN_BLOCKS_INFLIGHT = "knn_tpu_join_blocks_inflight"
 FILTER_LIST_IDS = "knn_tpu_filter_list_ids_total"
 
 # --- host-RAM shard tier (knn_tpu.parallel.sharded) --------------------
@@ -531,6 +533,18 @@ CATALOG = {
         "whole number of 128-column tiles and every launch would copy "
         "them ('layout_copy'), or because the call is too few queries "
         "('small'); 'explicit' the caller's batch_size."),
+    JOIN_ROWS: (
+        "counter", ("mode",),
+        "Rows answered by the bulk join (knn_tpu.join.engine), by its "
+        "mode: 'self' the pipelined certified self-join "
+        "(knn_self_join: every row a query of the placement it is part "
+        "of, its own row out by id)."),
+    JOIN_BLOCKS_INFLIGHT: (
+        "gauge", (),
+        "Blocks of a bulk self-join launched and not yet fetched, "
+        "sampled at each block's launch: at most the engine's depth "
+        "(SELF_JOIN_DEPTH = 2), each block SUB_BATCHES certified "
+        "programs."),
     MERGE_STRAGGLER_GAP: (
         "gauge", (),
         "Max-minus-min per-host local search wall time of the last "

@@ -423,6 +423,39 @@ class CallAccount:
                         **self.sum_attrs.get(piece, {}))
 
 
+class BlockAccount:
+    """The stage sums of ONE block of a pipelined call (the bulk
+    self-join's, knn_tpu.join.engine): what :func:`stage` and ``add``
+    hand it is summed over the block's launches and recorded once when
+    the block ends, as :class:`CallAccount` records a call's, so the
+    series of a stage counts blocks, each the size of one closed-loop
+    call.  Launches and fetches are passed on to the CALL's account:
+    two blocks are in flight at once, so the seconds in flight and the
+    exposed seconds are no property of a block."""
+
+    __slots__ = ("call", "sums", "sum_attrs")
+
+    def __init__(self, call, stages):
+        self.call = call
+        self.sums = dict.fromkeys(stages, 0.0)
+        self.sum_attrs = {}
+
+    def launched(self, program: str) -> None:
+        self.call.launched(program)
+
+    def ready(self, program: str) -> None:
+        self.call.ready(program)
+
+    add = CallAccount.add
+
+    def close(self, trace_id: Optional[str], of: str) -> None:
+        """Record every summed stage, a child of the block's span
+        ``of``."""
+        for piece, seconds in self.sums.items():
+            record_span(piece, trace_id, seconds, parent=of,
+                        **self.sum_attrs.get(piece, {}))
+
+
 class _NoopAccount:
     __slots__ = ()
 
@@ -448,6 +481,14 @@ def call_account(root: str, programs, pieces=(), stages=None):
     if not registry.enabled():
         return NOOP_ACCOUNT
     return CallAccount(root, programs, pieces, stages)
+
+
+def block_account(call, stages):
+    """A :class:`BlockAccount` under the call's account ``call``, or the
+    shared inert one when the subsystem is off."""
+    if not registry.enabled():
+        return NOOP_ACCOUNT
+    return BlockAccount(call, stages)
 
 
 @contextlib.contextmanager

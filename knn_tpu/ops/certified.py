@@ -191,6 +191,21 @@ def host_exact_knn(
     return bd, bi
 
 
+def repair_widen(m: int, max_widen: int) -> int:
+    """The width of :func:`repair_uncertified`'s exact re-select: the
+    ONE home of it, for a caller that launches the re-select itself
+    ahead of the repair (the join's pipeline)."""
+    return min(max(2 * m, m + 64), max_widen)
+
+
+def _columns_without(idx: np.ndarray, own: np.ndarray) -> np.ndarray:
+    """The columns ``[B, w - 1]`` of ``idx`` ``[B, w]`` that are left, in
+    order, when each row loses the one entry equal to ``own[b]``; a row
+    that holds no such entry loses its last."""
+    keep = np.argsort(idx == own[:, None], axis=1, kind="stable")[:, :-1]
+    return np.sort(keep, axis=1)
+
+
 def repair_uncertified(
     d: np.ndarray,
     i: np.ndarray,
@@ -209,6 +224,7 @@ def repair_uncertified(
     rank_queries: Optional[np.ndarray] = None,
     norms=None,
     valid_rows_fn=None,
+    exclude: Optional[np.ndarray] = None,
 ) -> dict:
     """Shared fallback repair for both certified pipelines (single-device
     :func:`knn_search_certified` and the sharded
@@ -266,6 +282,16 @@ def repair_uncertified(
     valid rows alone (:func:`host_exact_knn` over that gather) and pads
     a short answer with +inf and the int64 sentinel.
 
+    ``exclude`` (int ``[B]``, a self-join: the row each flagged query
+    IS, parallel.sharded's ``_SelfJoinCall``) takes that one row out by
+    id, never by distance: it is dropped from the widened selection
+    before the refine (``select_fn`` selected among all rows, so the
+    ``widen``-th score still bounds every row left out, and step 2 is
+    the inequality it was over one candidate fewer), and step 3 scans
+    for k + 1 and drops it there (or the last, where k + 1 rows at the
+    same distance come before it in index order).  An exact copy of the
+    query stays, at distance 0.
+
     ``select_fn(q_bad [B,D], widen) -> (f32 scores [B, widen] ascending,
     candidate indices [B, widen])``.
     Mutates ``d``/``i`` in place at rows ``bad``; returns a stats dict:
@@ -280,11 +306,13 @@ def repair_uncertified(
     if not bad.size:
         return {"fallback_genuine_misses": 0, "fallback_false_alarms": 0}
     orig_i = i[bad].copy()
-    widen = min(max(2 * m, m + 64), max_widen)
-    fs, fi = select_fn(q_np[bad], widen)
+    fs, fi = select_fn(q_np[bad], repair_widen(m, max_widen))
     fs = np.asarray(fs, dtype=np.float64)
+    fi = np.asarray(fi)
+    if exclude is not None:
+        fi = np.take_along_axis(fi, _columns_without(fi, exclude), axis=1)
     rank_q = q_np if rank_queries is None else rank_queries
-    fd2, fi2 = refine_exact(db_np, rank_q[bad], np.asarray(fi), k, metric,
+    fd2, fi2 = refine_exact(db_np, rank_q[bad], fi, k, metric,
                             norms_rows(norms, bad))
     d[bad], i[bad] = fd2, fi2
     q_norm = (q_np[bad].astype(np.float64) ** 2).sum(-1)
@@ -306,7 +334,12 @@ def repair_uncertified(
     host_exact = 0
     if still.size:
         sb = bad[still]
-        if valid_rows_fn is None:
+        if exclude is not None:
+            hd, hi = host_exact_knn(db_np, rank_q[sb], k + 1, metric=metric)
+            left = _columns_without(hi, exclude[still])
+            d[sb] = np.take_along_axis(hd, left, axis=1)
+            i[sb] = np.take_along_axis(hi, left, axis=1)
+        elif valid_rows_fn is None:
             d[sb], i[sb] = host_exact_knn(db_np, rank_q[sb], k, metric=metric,
                                           norms=norms_rows(norms, sb))
         else:

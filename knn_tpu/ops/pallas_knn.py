@@ -1747,6 +1747,154 @@ def _row_call(queries, words, db_inputs, q_extra, tnorm, out_shape, *,
     )(queries, *words, *db_inputs, *q_extra, tnorm)
 
 
+def _self_kernel(at_ref, q_ref, *refs, tile_n: int, survivors: int,
+                 terms: str, n_held: int):
+    """The tiled "bf16x3" body over the row tiles that hold a launch's
+    OWN rows (:func:`self_tile_candidates`): the same product
+    (``_split_qt``), then the score of row ``own + r`` in query row ``r``
+    turned to +inf BEFORE the insertion network, then the network and
+    the emission every other launch runs (``_emit_select_grouped``, or
+    ``_row_step`` where the blocks handed in are one row block of the
+    tile).  The mask goes on the product, ``qt = -inf`` there, so that
+    the emitters' own ``tn - 2 qt`` reads +inf: such a row is never a
+    candidate (+inf never displaces: strict ``<``) and lowers no bin's
+    bound, so the soundness contract holds over the rows OTHER than the
+    query's own, as it does over the valid rows under validity words.
+    A body of its own, so that no frame under the search's trace stack
+    changes size (tests/test_dim_chunking.py's tripwire).
+
+    ``at_ref`` (int32 ``[2]``, scalar prefetch): the first tile of the
+    launch, by which ``ti`` numbers the emitted rows, and the
+    (shard-local) row that the launch's first query is.  The
+    ``n_held`` refs after the row norms are the first launch's whole
+    outputs, aliased to this launch's and never read here."""
+    qi, ti = pl.program_id(0), at_ref[0] + pl.program_id(1)
+    if "hl" in terms:
+        th_ref, tl_ref, tn_ref, *refs = refs
+    else:
+        (th_ref, tn_ref, *refs), tl_ref = refs, None
+    d_ref, i_ref, b_ref, *scratch = refs[n_held:]
+    qt = _split_qt(q_ref[:], th_ref[:], tl_ref, terms)
+    bq, rows = qt.shape
+    # query row r of this block is row ``at_ref[1] + qi * bq + r``, and
+    # column c of this step row ``ti * tile_n + step * rows + c``
+    own = (at_ref[1] + qi * bq - ti * tile_n - pl.program_id(2) * rows)
+    qt = jnp.where(
+        lax.broadcasted_iota(jnp.int32, qt.shape, 1)
+        - lax.broadcasted_iota(jnp.int32, qt.shape, 0) == own,
+        -jnp.inf, qt)
+    if rows < tile_n:
+        _row_step(scratch[0], ti, qt, tn_ref, None, d_ref, i_ref, b_ref,
+                  tile_n=tile_n)
+        return
+    cd, ci, bound = _emit_select_grouped(
+        ti, qt, tn_ref[:], tile_n=tile_n, survivors=survivors)
+    d_ref[:] = cd
+    i_ref[:] = ci
+    b_ref[:] = bound
+
+
+def self_tile_candidates(
+    queries: jax.Array, db_prepared: Tuple[jax.Array, ...], cd: jax.Array,
+    ci: jax.Array, bounds: jax.Array, first_row, *, block_q: int,
+    tile_n: int, survivors: Optional[int], terms: str,
+    row_block: Optional[int], interpret: bool,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """``(cd, ci, bounds)`` of :func:`local_coarse_candidates` for
+    queries that ARE rows of the db (shard) they search, with each
+    query's own row out of its bins: query ``r`` is row ``first_row +
+    r`` (a traced int32; outside ``[0, rows)`` where another shard
+    holds it), and no other row goes, whatever its distance: an exact
+    copy of the query stays a candidate.
+
+    The kernel's launch over ALL tiles is the one it always was, body
+    and operands; the tiles that hold the launch's own rows (``queries
+    // tile_n + 1`` of them at most, from ``first_row // tile_n``,
+    clamped to the tiles there are) are then selected ONCE MORE by
+    :func:`_self_kernel`, the own row at +inf before the insertion
+    network, and those tiles' candidates and bounds laid over the first
+    launch's, in place.  So self leaves before the select, in the tiles
+    where it lies, and every other tile's cell is the unmasked launch's
+    own.  A second launch and no branch in the first: a ``pl.when``
+    between the product and the select keeps Mosaic from running one
+    under the other (``_row_step``).
+
+    "bf16x3" under the tiled kernel only (what a default certified call
+    runs); ``db_prepared`` is :func:`row_operands` of the shard at
+    ``tile_n`` and ``terms``, ``row_block`` the resolved cut of a tile
+    (:func:`row_blocking`'s reading where it is None, as the first
+    launch reads it)."""
+    n_q = queries.shape[0]
+    queries = _pad_axis(queries.astype(jnp.float32), block_q, 0)
+    queries = _pad_axis(queries, DIM_CHUNK, 1)
+    qp, dim = queries.shape
+    if row_block is None:
+        row_block = row_blocking(
+            dim, tile_n=tile_n, block_q=block_q, precision="bf16x3",
+            terms=terms, survivors=survivors)[0]
+    *row_parts, row_norms = db_prepared
+    n_tiles = row_norms.shape[0] // tile_n
+    _, survivors, out_w, bound_w = _geometry(tile_n, survivors)
+    n_own = min(n_tiles, -(-n_q // tile_n) + 1)
+    first_row = jnp.asarray(first_row, jnp.int32)
+    t0 = jnp.clip(first_row // tile_n, 0, n_tiles - n_own)
+    # the own tiles' operands, cut out of the resident ones: a copy of
+    # ``n_own`` tiles, so the launch's index maps are static
+    parts = [lax.dynamic_slice_in_dim(x, t0 * tile_n, n_own * tile_n, 0)
+             for x in row_parts]
+    tnorm = jnp.broadcast_to(lax.dynamic_slice_in_dim(
+        row_norms, t0 * tile_n, n_own * tile_n, 0)[None, :],
+        (8, n_own * tile_n))
+    row_steps = tile_n // row_block
+    t_idx = lambda q, t, x, at: (t * row_steps + x, 0)  # noqa: E731
+    n_idx = lambda q, t, x, at: (0, t * row_steps + x)  # noqa: E731
+    # the own tiles' cells of the first launch's outputs, which this
+    # launch takes as operands it never reads and writes in place
+    o_idx = lambda q, t, x, at: (q, at[0] + t)          # noqa: E731
+    kwargs = {}
+    if not interpret:
+        kwargs["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_vmem_limit_bytes(
+                "tiled", "bf16x3", block_q=block_q, tile_n=tile_n,
+                n_tiles=n_own, nd=1, out_w=out_w, bound_w=bound_w,
+                db_block=sum(row_block * dim * x.dtype.itemsize
+                             for x in parts),
+                aux_rows=8, q_block=block_q * dim * 4, q_extra=0,
+                **({} if row_steps == 1
+                   else {"row_block": row_block, "dim_padded": dim})))
+    whole = [_pad_axis(x, block_q, 0) for x in (cd, ci, bounds)]
+    first_out = 2 + len(parts) + 1  # after the scalars, q, parts, norms
+    out = pl.pallas_call(
+        functools.partial(_self_kernel, tile_n=tile_n, survivors=survivors,
+                          terms=terms, n_held=len(whole)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(qp // block_q, n_own, row_steps),
+            in_specs=[
+                pl.BlockSpec((block_q, dim), lambda q, t, x, at: (q, 0)),
+                *[pl.BlockSpec((row_block, dim), t_idx) for _ in parts],
+                pl.BlockSpec((8, row_block), n_idx),
+                *[pl.BlockSpec(memory_space=pl.ANY) for _ in whole],
+            ],
+            out_specs=[
+                pl.BlockSpec((block_q, out_w), o_idx),
+                pl.BlockSpec((block_q, out_w), o_idx),
+                pl.BlockSpec((block_q, bound_w), o_idx),
+            ],
+            # the select's running arrays, shared by the steps of a tile
+            scratch_shapes=[] if row_steps == 1 else [pltpu.VMEM(
+                (2 * survivors + 1, block_q, BIN_W), jnp.float32)],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(x.shape, x.dtype) for x in whole],
+        input_output_aliases={first_out + j: j for j in range(len(whole))},
+        interpret=interpret,
+        name="self_tiles",
+        **kwargs,
+    )(jnp.stack([t0, first_row]), queries, *parts, tnorm, *whole)
+    return tuple(x[:n_q] for x in out)
+
+
 def _stream_call(queries, db_inputs, tnorm, out_shape, *, qp, dim, block_q,
                  tile_n, survivors, out_w, bound_w, n_tiles, nd, precision,
                  chunk_w, interpret, q_extra=(), aux_rows=8, fused=False,

@@ -535,6 +535,21 @@ _PALLAS_STAGES = ("certified.dispatch", "certified.device_wait",
 _VOTE_STAGE = "certified.vote_repair"
 
 
+#: one block of a bulk self-join call (knn_tpu.join.engine), launch to
+#: answer; its stages' parent
+_BLOCK_SPAN = "join.block"
+#: what a block sums over its launches and records once
+#: (``obs.trace.BlockAccount``): the search call's stages and pieces,
+#: and the repair (the re-select's launch, then its settling)
+_BLOCK_SUMS = _PALLAS_STAGES + ("certified.repair",) + _PALLAS_PIECES
+#: queries a block's re-select takes at a time: ONE compiled shape a
+#: placement whatever the count of flagged queries (a search call's
+#: re-select is compiled a count: 5-11 s each on the chip, root PERF.md
+#: section 7), run once by the placement's first self-join call, so
+#: that no block of any call meets a compile
+_SELF_RESELECT_ROWS = 32
+
+
 def _call_account(selector: str, *more: str, voted: bool = False):
     """The account of one outermost certified call: the device programs
     a call of this ``selector`` can launch (``more``: those of the call
@@ -830,6 +845,8 @@ class ShardedKNN:
                 NamedSharding(mesh, P(db_axes(mesh))), train.ndim
             )
         )
+        #: the rows were handed in already placed, at their own width
+        self._pre_placed = bool(pre_placed)
         # a cosine placement's rows as given, and what the walk that made
         # their unit rows saw of those (below)
         given, placed_norm_max, lo_zero = None, None, False
@@ -1093,6 +1110,9 @@ class ShardedKNN:
         #: (each build AOT-compiles executables — seconds on hardware)
         self._serving_engines: dict = {}
         self._engines_lock = threading.Lock()
+        #: widths at which the self-join's re-select has run once on
+        #: this placement (_SelfJoinCall: its one compiled shape)
+        self._self_reselect_warm: set = set()
         self._labels = self._labels_host = self._vote_labels_dev = None
         self.num_classes = num_classes
         if labels is not None:
@@ -2952,7 +2972,8 @@ class ShardedKNN:
                       call_rows: Optional[int] = None,
                       trace_id: Optional[str] = None,
                       acct=obs.trace.NOOP_ACCOUNT,
-                      masked: bool = False, vote=None):
+                      masked: bool = False, vote=None,
+                      own_rows: bool = False):
         """(program, m, analysis_window, interpret) for the one-pass
         certified path — the ONE home of the kernel-geometry margin cap
         and the packed-output window, shared by :meth:`_certify_pallas`
@@ -3001,7 +3022,14 @@ class ShardedKNN:
 
         ``vote`` (``predict_certified(vote="softmax")``: ``(1 / T,
         classes_out, delta)``) builds :func:`_pallas_vote_program` from
-        the same resolution: the device labels follow the tail."""
+        the same resolution: the device labels follow the tail.
+
+        ``own_rows`` (a bulk self-join's block, :class:`_SelfJoinCall`;
+        "bf16x3" under the tiled kernel) builds
+        :func:`_pallas_self_program` from the same resolution, for
+        launches of the resolved sub-batch's rows: the program takes the
+        first row of a launch where the search program takes the
+        queries."""
         from knn_tpu.ops.pallas_knn import (
             BLOCK_Q,
             TILE_N,
@@ -3084,6 +3112,17 @@ class ShardedKNN:
         # tile the kernel runs is provably the tile this m-cap assumed
         # (ADVICE r4: the raw-tile plumbing let the two diverge on small
         # padded dbs where m is capped by n_train)
+        if own_rows:
+            prog = _pallas_self_program(
+                self.mesh, m, self.k, self.merge, eff_tile, self.n_train,
+                batch_rows, survivors=survivors, block_q=block_q,
+                final_select=final_select,
+                final_recall_target=final_recall_target,
+                grid_order=grid_order, dcn_merge=self.dcn_merge,
+                interpret=interpret, terms=terms,
+                row_block=self._row_blocking[0],
+                resident_parts=len(resident) - 1 if resident else 0)
+            return prog, m, _analysis_window(self.k, m), interpret
         if vote is not None:
             prog = _pallas_vote_program(
                 self.mesh, m, self.k, self.merge, eff_tile, precision,
@@ -3255,6 +3294,40 @@ class ShardedKNN:
             repair(lo, pad, packed,
                    lambda q=qp, tail=tail: prog(q, self._tp, *tail))
         return np.flatnonzero(bad_mask), n_corrected, n_by_slack
+
+    def self_join_call(self, lo: int, hi: int, block_rows: int, *,
+                       trace_id: Optional[str] = None,
+                       acct=obs.trace.NOOP_ACCOUNT) -> "_SelfJoinCall":
+        """The state of one bulk certified SELF-join over rows ``lo ..
+        hi`` of this placement (every row a query of the placement it
+        is part of, its own row out by id), in blocks of ``block_rows``
+        for knn_tpu.join.engine's pipeline to order
+        (:class:`_SelfJoinCall`).  A squared-L2 placement this class
+        laid out itself, resident; everything else refuses, with what it
+        lacks."""
+        self._require_resident("the certified self-join")
+        if self.metric not in ("l2", "sql2", "euclidean"):
+            raise ValueError(
+                f"the certified self-join answers squared-L2 placements: "
+                f"this one is metric={self.metric!r}, whose host ranks by "
+                f"another value than the device (the inner product, the "
+                f"cosine of the rows as given) and whose queries are "
+                f"mapped before they are placed; its self-join is not "
+                f"built yet (ROADMAP R13)")
+        if self._pre_placed:
+            raise ValueError(
+                "the certified self-join takes each block's queries out "
+                "of the placed rows where they lie, in whole 128-column "
+                "lane tiles, and ranks with the host's copy of them: a "
+                "pre-placed array keeps the width it was handed in at and "
+                "leaves no host copy; construct ShardedKNN from a host "
+                "array")
+        if not 0 <= lo < hi <= self.n_train:
+            raise ValueError(
+                f"rows=({lo}, {hi}) is no range of the {self.n_train} "
+                f"placed rows")
+        return _SelfJoinCall(self, int(lo), int(hi), int(block_rows),
+                             trace_id, acct)
 
     def predict_certified(
         self, queries, *, vote: str = "majority",
@@ -3712,6 +3785,262 @@ class ShardedKNN:
         return out[:n_q]
 
 
+class _Block:
+    """One block of a self-join call in flight."""
+
+    __slots__ = ("lo", "hi", "t0", "acct", "launches", "flagged",
+                 "reselects")
+
+    def __init__(self, lo: int, hi: int, acct):
+        self.lo, self.hi, self.acct = lo, hi, acct
+        self.t0 = time.perf_counter()
+        self.launches, self.reselects = [], []
+        self.flagged = np.empty(0, np.int64)
+
+
+class _SelfJoinCall:
+    """One bulk certified self-join over rows ``lo .. hi`` of a
+    placement: what its blocks share (the program
+    :func:`_pallas_self_program`, resolved as a search call of one
+    block's rows resolves its own; the operand tail; the host's rows;
+    the answer arrays) and a block's three steps, which
+    knn_tpu.join.engine orders into its bounded pipeline:
+
+    - :meth:`launch` queues the block's programs, one a sub-batch
+      (``analysis.subbatch``: 4 of 1,024 rows for a block of 4,096).  A
+      launch's operand is its first row id, one int32: the queries are
+      the placed rows themselves, taken on the device.  The last launch
+      of a ragged block starts early enough to end at the call's last
+      row (one compiled shape; the rows it answers twice are read once);
+    - :meth:`collect` fetches each launch's packed answer, unpacks it
+      and repairs tie runs in float64 (``rank_correct_runs``) with the
+      host's copy of the same rows as the queries, then launches the
+      exact re-select of the block's flagged queries, padded to
+      ``_SELF_RESELECT_ROWS`` a launch;
+    - :meth:`settle` fetches that re-select and repairs the flagged
+      (``repair_uncertified(exclude=...)``: the query's own row goes by
+      id from the widened selection too), closes the block's account and
+      records ``join.block``.
+
+    The answer of row i is the first k rows j != i in lexicographic
+    (float64 squared L2 of the float32 rows as given, j) order; exact
+    copies of row i stay, at distance 0, in id order.  ``self.d``,
+    ``self.i`` hold it for the call's rows once every block is
+    settled."""
+
+    def __init__(self, knn: ShardedKNN, lo: int, hi: int, block_rows: int,
+                 trace_id: Optional[str], acct):
+        from knn_tpu import tuning
+        from knn_tpu.ops.certified import repair_widen
+
+        self.knn, self.lo, self.hi = knn, lo, hi
+        self.tid, self.acct = trace_id, acct
+        k, shard_rows = knn.k, knn._shard_rows()
+        knobs, self.tune_info = tuning.resolve_full(
+            knn.n_train, knn._given_width, k, metric="l2",
+            dtype=knn._dtype_key)
+        if knobs["precision"] != "bf16x3" or knobs["kernel"] != "tiled":
+            raise ValueError(
+                f"the certified self-join runs the default precision "
+                f"under the tiled kernel; this shape's tuned winner is "
+                f"precision={knobs['precision']!r}, "
+                f"kernel={knobs['kernel']!r}")
+        self.knobs = knobs
+        self.db = knn._host_train()
+        self.db_norm_max = knn._db_norm_max()
+        # a row is a query: what the walk saw of the rows holds of both
+        self.terms = knn._kernel_terms(self.db[lo : lo + 1], "bf16x3")
+        q_shards = knn.mesh.shape[QUERY_AXIS]
+        call_rows = min(block_rows, hi - lo) // q_shards * q_shards
+        if not call_rows:
+            raise ValueError(
+                f"rows=({lo}, {hi}) holds fewer rows than the mesh has "
+                f"query shards ({q_shards})")
+        self.prog, self.m, self.w, self.interpret = knn._pallas_setup(
+            min(k + 28, knn.n_train, shard_rows) - k, terms=self.terms,
+            call_rows=call_rows, trace_id=trace_id, acct=acct,
+            own_rows=True, **knobs)
+        self.bs, self.sub_why = knn._sub_batch
+        self.tail = knn._pallas_operands("bf16x3")
+        self.max_widen = min(knn.n_train, shard_rows)
+        self.widen = repair_widen(self.m, self.max_widen)
+        self.exact = _knn_program(
+            knn.mesh, self.widen, "l2", knn.merge, knn.n_train,
+            knn.train_tile, None, "exact", dcn_merge=knn.dcn_merge)
+        self.d = np.empty((hi - lo, k))
+        self.i = np.empty((hi - lo, k), dtype=np.int64)
+        self.told = {"launches": 0, "fallback_queries": 0,
+                     "rank_corrected_queries": 0, "merge_bytes": 0,
+                     "fallback_genuine_misses": 0,
+                     "fallback_false_alarms": 0, "host_exact_queries": 0}
+        if self.widen not in knn._self_reselect_warm:
+            # the re-select's one shape, once a placement: its compile
+            # falls here, before the first block, whatever gets flagged
+            jax.block_until_ready(self._reselect(np.zeros(1, np.int64)))
+            acct.ready("reselect")
+            knn._self_reselect_warm.add(self.widen)
+
+    # -- device launches ---------------------------------------------------
+    def _reselect(self, at: np.ndarray):
+        """Launch the exact top-``widen`` of the call's rows ``at``
+        (positions in the call; at most ``_SELF_RESELECT_ROWS``), every
+        row a candidate, the query's own among them."""
+        qb = np.zeros((_SELF_RESELECT_ROWS, self.db.shape[1]), np.float32)
+        qb[: at.size] = self.db[self.lo + at]
+        with obs.span("certified.repair.reselect", self.tid,
+                      parent="certified.repair", widen=self.widen,
+                      rows=int(at.size), scan_rows_copied=0):
+            bq, _ = self.knn._place_queries(qb)
+            self.told["merge_bytes"] += self.knn._record_merge_bytes(
+                bq.shape[0], self.widen)
+            begun = _hooks.first_call_begin()
+            out = self.exact(bq, self.knn._tp)
+            self.acct.launched("reselect")
+            _hooks.first_call_end(begun, self.exact, "reselect", self.tid,
+                                  rows=bq.shape[0])
+        return out
+
+    def launch(self, lo: int, hi: int) -> _Block:
+        knn, bs = self.knn, self.bs
+        blk = _Block(lo, hi, obs.trace.block_account(self.acct, _BLOCK_SUMS))
+        for start in range(lo, hi, bs):
+            # one compiled shape: a launch that would run past the
+            # call's rows starts early enough to end at the last
+            first = np.asarray([min(start, self.hi - bs)], np.int32)
+
+            def run(first=first):
+                return self.prog(first, knn._tp, *self.tail)
+
+            with obs.trace.stage(blk.acct, "certified.dispatch",
+                                 h2d_bytes=0):
+                begun = _hooks.first_call_begin()
+                packed = _retry_transient(run, "self-join dispatch")
+                self.acct.launched("certified")
+                _hooks.first_call_end(begun, self.prog, "certified",
+                                      self.tid, rows=bs)
+            blk.launches.append((start, int(first[0]), packed, run))
+            self.told["merge_bytes"] += knn._record_merge_bytes(
+                bs, self.m + 1)
+        self.told["launches"] += len(blk.launches)
+        return blk
+
+    # -- the host's share --------------------------------------------------
+    def collect(self, blk: _Block) -> None:
+        from knn_tpu.ops.refine import rank_correct_runs
+
+        k, acct = self.knn.k, blk.acct
+        fetch = _staged_fetch(acct)
+        bad = np.zeros(blk.hi - blk.lo, dtype=bool)
+        for start, first, packed, run in blk.launches:
+            arr = _fetch_or_redispatch(packed, run, "self-join fetch",
+                                       fetch=fetch)
+            # the launch's rows this block has not had from an earlier one
+            rows = min(start + self.bs, blk.hi) - start
+            own = slice(start - first, start - first + rows)
+            with obs.trace.stage(acct, "certified.unpack") as sp:
+                gi, tight, bad_np, dk = unpack_certified(
+                    arr[own], k, self.w, True)
+            acct.add(_UNPACK_COPIES, sp.attrs.get("copies_s", 0.0))
+            with obs.trace.stage(acct, "certified.rank_correct") as sp:
+                mine = {}  # the caller's share of the buffers
+                with obs.trace.phase(mine, "buffers_s",
+                                     _refine.PHASE_BUFFERS):
+                    d32k = dk.astype(np.float64)
+                dc, ic, n_c = rank_correct_runs(
+                    gi, tight, k, self.db[start : start + rows], self.db,
+                    d32k=d32k, metric="l2")
+                sp.set("queries_corrected", n_c)
+            told = sp.attrs
+            acct.add(_refine.PHASE_BUFFERS, told.get("buffers_s", 0.0)
+                     + mine.get("buffers_s", 0.0))
+            acct.add(_refine.PHASE_SCORE, told.get("score_s", 0.0),
+                     gather_s=told.get("gather_s", 0.0),
+                     arith_s=told.get("arith_s", 0.0))
+            acct.add(_refine.PHASE_ORDER, told.get("order_s", 0.0))
+            obs.counter(_mn.RANK_CORRECT_MEMBERS).inc(told.get("members", 0))
+            self.told["rank_corrected_queries"] += n_c
+            out = slice(start - self.lo, start - self.lo + rows)
+            self.d[out], self.i[out] = dc, ic
+            bad[start - blk.lo : start - blk.lo + rows] = bad_np
+        blk.launches = []  # the packed answers go
+        blk.flagged = np.flatnonzero(bad) + (blk.lo - self.lo)
+        if blk.flagged.size:
+            with obs.trace.stage(acct, "certified.repair",
+                                 fallback_queries=int(blk.flagged.size)):
+                blk.reselects = [
+                    self._reselect(blk.flagged[j : j + _SELF_RESELECT_ROWS])
+                    for j in range(0, blk.flagged.size,
+                                   _SELF_RESELECT_ROWS)]
+
+    def settle(self, blk: _Block) -> None:
+        from knn_tpu.ops.certified import repair_uncertified
+
+        n_bad = int(blk.flagged.size)
+        if n_bad:
+            with obs.trace.stage(blk.acct, "certified.repair") as sp:
+                fs, fi = [], []
+                for j, (ps, pi) in enumerate(blk.reselects):
+                    rows = min(_SELF_RESELECT_ROWS,
+                               n_bad - j * _SELF_RESELECT_ROWS)
+                    fs.append(np.asarray(ps)[:rows])
+                    self.acct.ready("reselect")
+                    fi.append(np.asarray(pi)[:rows])
+                repair = repair_uncertified(
+                    self.d, self.i, self.knn.k, self.m, blk.flagged,
+                    self.db[self.lo : self.hi], self.db,
+                    select_fn=lambda qb, widen: (
+                        np.concatenate(fs), np.concatenate(fi)),
+                    max_widen=self.max_widen, db_norm_max=self.db_norm_max,
+                    exclude=self.lo + blk.flagged)
+                sp.set("host_exact_queries",
+                       repair.get("host_exact_queries", 0))
+            blk.reselects = []
+            self.told["fallback_queries"] += n_bad
+            for key, value in repair.items():
+                self.told[key] += value
+        blk.acct.close(self.tid, _BLOCK_SPAN)
+        obs.record_span(_BLOCK_SPAN, self.tid,
+                        time.perf_counter() - blk.t0, lo=blk.lo,
+                        rows=blk.hi - blk.lo, flagged=n_bad)
+
+    def finish(self) -> dict:
+        """Once every block is settled: the call's counters, and what
+        ``search_certified``'s stats say of a call, summed over this
+        one's blocks."""
+        knn, told, n = self.knn, self.told, self.hi - self.lo
+        selector = "pallas"
+        obs.counter(_mn.CERTIFIED_QUERIES, selector=selector).inc(n)
+        obs.counter(_mn.CERTIFIED_METRIC_QUERIES, metric=knn.metric).inc(n)
+        obs.counter(_mn.CERTIFIED_FALLBACKS, selector=selector).inc(
+            told["fallback_queries"])
+        obs.counter(_mn.CERTIFIED_GENUINE_MISSES, selector=selector).inc(
+            told["fallback_genuine_misses"])
+        obs.counter(_mn.CERTIFIED_FALSE_ALARMS, selector=selector).inc(
+            told["fallback_false_alarms"])
+        obs.counter(_mn.CERTIFIED_HOST_EXACT, selector=selector).inc(
+            told["host_exact_queries"])
+        obs.counter(_mn.CERTIFIED_RANK_CORRECTED).inc(
+            told["rank_corrected_queries"])
+        obs.counter(_mn.KERNEL_TERMS, terms=self.terms).inc(told["launches"])
+        obs.counter(_mn.KERNEL_OPERANDS, source=knn._operands_source).inc(
+            told["launches"])
+        geometry = {
+            "terms": self.terms, "mxu_passes": self.terms.count("+") + 1,
+            "dim_chunk": knn._dim_chunking[0],
+            "dim_chunks": knn._dim_chunking[1],
+            "row_block": knn._row_blocking[0],
+            "row_steps": knn._row_blocking[1],
+            "final_select_stage": knn._final_select_stage,
+            "operands": knn._operands_source, "sub_batch": self.sub_why}
+        return {
+            **told, "certified": n - told["fallback_queries"],
+            "batches": told["launches"], "sub_batch_rows": self.bs,
+            "metric": knn.metric, "self_excluded": n, **geometry,
+            "pallas_knobs": {**self.knobs, "interpret": self.interpret,
+                             **geometry, "batches": told["launches"]},
+            "tuning": self.tune_info}
+
+
 def sharded_knn(
     queries: jax.Array,
     train: jax.Array,
@@ -4045,6 +4374,102 @@ def _pallas_vote_program(
     return prog
 
 
+@functools.lru_cache(maxsize=32)
+def _pallas_self_program(
+    mesh: Mesh, m: int, k: int, merge: str, tile_n: int, n_train: int,
+    rows: int, survivors: Optional[int] = None,
+    block_q: Optional[int] = None, final_select: str = "exact",
+    final_recall_target: Optional[float] = None,
+    grid_order: str = "query_major", dcn_merge: Optional[str] = None,
+    interpret: Optional[bool] = None, terms: str = "hh+hl+lh",
+    row_block: Optional[int] = None, resident_parts: int = 0,
+):
+    """:func:`_pallas_certified_program` ("bf16x3", the tiled kernel) for
+    queries that ARE rows of the placement: the launch answers rows
+    ``lo .. lo + rows`` of the placed array, and row ``lo + r`` is no
+    answer of query ``r``.  It takes no query operand: ``lo``, one
+    replicated int32 scalar, stands where the queries stood, then the
+    rows and the search program's operand tail.  Every shard takes the
+    part of the block it holds out of its own rows where they lie
+    (lane-tiled at the placed width, so no pad and no transfer), the
+    parts are summed over the db axes (each row is held once: x + 0 is
+    x), and every shard sees the block.  Row ``lo + r`` is scored +inf
+    for query ``r`` BEFORE the bin-select, in the row tiles that hold
+    the block and nowhere else (ops.pallas_knn.self_tile_candidates); the
+    final select, the rescore, the merge and the certificate are the
+    search program's, over the n - 1 rows that are left:
+    ``_certify_pack_spmd``'s three inequalities bound the rows outside
+    the candidates, and a row scored +inf is in no bound.  An exact copy
+    of the query is a row like any other: it stays, at distance 0.
+
+    A function of its own, as the vote program is, so that the search
+    program's ``spmd`` keeps its frame (tests/test_dim_chunking.py's
+    tripwire): called with no such scalar the search program is the one
+    it always was, operation for operation."""
+    from knn_tpu.ops.pallas_knn import (
+        BLOCK_Q,
+        effective_block_q,
+        local_coarse_candidates,
+        local_select_rescore,
+        row_operands,
+        self_tile_candidates,
+    )
+
+    hosts, chips = db_topology(mesh)
+    w = _analysis_window(k, m)
+    # the block's rows a query shard answers
+    take = rows // mesh.shape[QUERY_AXIS]
+    block_q = block_q or BLOCK_Q
+
+    def spmd(lo, t, *tail):
+        _, _, _, db_norm_max, db_rows = _split_operand_tail("bf16x3", tail)
+        if db_rows is None:
+            db_rows = row_operands(t, tile_n=tile_n, with_lo="hl" in terms)
+        # this shard's part of the block: query r of this query shard is
+        # (shard-local) row ``first + r``
+        first = (lo[0] + lax.axis_index(QUERY_AXIS) * take
+                 - _db_shard_index(hosts, chips) * t.shape[0])
+        at = first + lax.iota(jnp.int32, take)
+        q = jnp.where(((at >= 0) & (at < t.shape[0]))[:, None],
+                      t[jnp.clip(at, 0, t.shape[0] - 1)], 0.0)
+        if hosts * chips > 1:
+            q = lax.psum(q, (HOST_AXIS, DB_AXIS) if hosts > 1 else DB_AXIS)
+        coarse = dict(tile_n=tile_n, survivors=survivors, terms=terms,
+                      row_block=row_block, interpret=interpret)
+        cd, ci, bounds = local_coarse_candidates(
+            q, t, m, block_q=block_q, precision="bf16x3",
+            final_select=final_select, grid_order=grid_order,
+            db_prepared=db_rows, **coarse)
+        with jax.named_scope(SCOPE_SELF_TILES):
+            cd, ci, bounds = self_tile_candidates(
+                q, db_rows, cd, ci, bounds, first,
+                block_q=effective_block_q(block_q, take), **coarse)
+        d32, li, lb = local_select_rescore(
+            q, t, cd, ci, bounds, m, final_select=final_select,
+            final_recall_target=final_recall_target, interpret=interpret)
+        return _certify_pack_spmd(
+            q, t, d32, li, lb, consts=None, db_norm_max=db_norm_max,
+            precision="bf16x3", quant_offset=0.0, m=m, k=k, w=w,
+            merge=merge, n_train=n_train, hosts=hosts, chips=chips,
+            dcn_merge=dcn_merge, include_distances=True)
+
+    prog = jax.jit(
+        jax.shard_map(
+            spmd, mesh=mesh,
+            in_specs=(P(), P(db_axes(mesh)),
+                      *_tail_specs("bf16x3", mesh, resident_parts)),
+            out_specs=P(QUERY_AXIS),
+            check_vma=False,
+        )
+    )
+    _hooks.mark_built(
+        prog, f"m={m},k={k},tile={tile_n},terms={terms},"
+              f"row_block={row_block},precision=bf16x3,"
+              f"operands={'resident' if resident_parts else 'per_call'},"
+              f"self_rows={rows}")
+    return prog
+
+
 def _tail_specs(precision: str, mesh: Mesh, resident_parts: int = 0):
     """shard_map in_specs of the precision-shaped operand tail
     (ShardedKNN._pallas_operands): int8 = the quantized placement
@@ -4218,6 +4643,10 @@ def _masked_reselect_program(mesh: Mesh, k: int, merge: str, n_train: int,
 #: device scope of the certify/pack tail; its four siblings (operand
 #: prep, kernel, final select, rescore) are ops.pallas_knn's SCOPE_*
 SCOPE_CERTIFY_PACK = "knn.certify_pack"
+#: device scope of a self-join launch's second select of the row tiles
+#: that hold its own rows (ops.pallas_knn.self_tile_candidates), beside
+#: ops.pallas_knn's SCOPE_KERNEL
+SCOPE_SELF_TILES = "knn.self_tiles"
 #: device scope of the cross-shard merge (:func:`_merge_shards` wherever
 #: it runs, and the certified program's ``pmin`` of the exclusion bound):
 #: the collectives and the re-selects between them

@@ -108,6 +108,10 @@ SHAPES = {
     "imagenet768": (1_281_167, 768, 20),
     "wide512": (1_000_000, 512, 100),
     "wide640": (1_000_000, 640, 100),
+    # one chip's block of a DEEP prefix, answered by the SELF program:
+    # every row a query, its own row out (benchmark/configs/
+    # deep5m-knng.json); 96 columns placed in 128
+    "deep5m": (5_000_000, 96, 10),
 }
 #: shapes whose rows are norm-augmented at placement (metric "dot"): the
 #: certified program takes the augmentation's slack as one more scalar
@@ -122,6 +126,12 @@ COSINE = ("openai500k", "imagenet768")
 #: ``VOTE_CLASSES`` labels at ``VOTE_TEMPERATURE``, five classes out)
 VOTED = ("imagenet768",)
 VOTE_CLASSES, VOTE_TEMPERATURE, VOTE_CLASSES_OUT = 1000, 0.07, 5
+#: shapes answered by ``knn_tpu.join.knn_self_join``: the self program
+#: (no query operand: a launch's first row id stands where the queries
+#: stood, ``parallel.sharded._pallas_self_program``), at a block's
+#: launch of NQ // 4 rows, its row operands resident; after it
+#: ``--shape deep5m`` prints what one chip holds beside the placed rows
+SELF = ("deep5m",)
 #: shapes answered by ``range_search_certified``: its completion's
 #: program is compiled too
 RANGE = ("ssnpp2m5",)
@@ -179,6 +189,7 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
     from knn_tpu.parallel.mesh import DB_AXIS, QUERY_AXIS
     from knn_tpu.parallel.sharded import (
         _pallas_certified_program,
+        _pallas_self_program,
         _pallas_vote_program,
         vote_delta,
     )
@@ -200,7 +211,13 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
         kw.update(augmented=True, include_distances=False)
     if shape in COSINE:
         kw.update(augmented=True, slack_outcome=True)
-    if shape in VOTED:
+    if shape in SELF:
+        for key in ("kernel", "include_distances"):
+            kw.pop(key, None)
+        prog = _pallas_self_program(
+            mesh, k + MARGIN, k, merge, tile, n, queries, interpret=False,
+            **kw)
+    elif shape in VOTED:
         del kw["augmented"], kw["slack_outcome"]
         prog = _pallas_vote_program(
             mesh, k + MARGIN, k, merge, tile,
@@ -225,6 +242,9 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
         (rows_p, d), jnp.bfloat16, sharding=NamedSharding(mesh, P(DB_AXIS)))
     norms = jax.ShapeDtypeStruct(
         (rows_p,), jnp.float32, sharding=NamedSharding(mesh, P(DB_AXIS)))
+    if shape in SELF:
+        q = jax.ShapeDtypeStruct(
+            (1,), jnp.int32, sharding=NamedSharding(mesh, P()))
     return prog, (q, db, norm) + (
         (halves,) * parts + (norms,) if parts else ()) + (
         (norm,) if shape in AUGMENTED + COSINE else ()) + (
@@ -236,7 +256,8 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
 TEMPORARIES_SHAPES = ("gist", "imagenet768")
 
 
-def temporaries_table(shape: str, devices, terms=None) -> tuple:
+def temporaries_table(shape: str, devices, terms=None, *,
+                      self_rows: bool = False) -> tuple:
     """``(placed rows' bytes, [(program, temporaries' bytes)])`` on one
     chip at ``shape``, by XLA's ``memory_analysis()``: what
     ``analysis.hbm.LANE_TILED_TEMP_FACTOR`` is read from.  The programs
@@ -254,6 +275,7 @@ def temporaries_table(shape: str, devices, terms=None) -> tuple:
 
     from knn_tpu import tuning
     from knn_tpu.analysis.widths import lane_tiled
+    from knn_tpu.ops.certified import repair_widen
     from knn_tpu.ops.pallas_knn import TILE_N
     from knn_tpu.parallel import sharded
     from knn_tpu.parallel.mesh import DB_AXIS, QUERY_AXIS
@@ -266,7 +288,9 @@ def temporaries_table(shape: str, devices, terms=None) -> tuple:
               f", {queries} queries",
               _spmd_case(shape, knobs, devices, (1, 1), "ring", terms,
                          queries=queries, resident=resident))
-             for resident in (False, True) for queries in (NQ, NQ // 4)]
+             for resident in (False, True) for queries in (NQ, NQ // 4)
+             # a self-join's launch: a sub-batch's rows, operands resident
+             if not self_rows or (resident and queries == NQ // 4)]
     mesh = Mesh(np.asarray(devices[:1]).reshape(1, 1), (QUERY_AXIS, DB_AXIS))
 
     def aval(shp, dtype, spec):
@@ -274,12 +298,13 @@ def temporaries_table(shape: str, devices, terms=None) -> tuple:
             shp, dtype, sharding=NamedSharding(mesh, spec))
 
     m = k + MARGIN
-    widen = max(2 * m, m + 64)  # ops.certified.repair_uncertified
+    widen = repair_widen(m, n)
+    flagged = sharded._SELF_RESELECT_ROWS if self_rows else 16
     cases.append((
-        f"re-select, k={widen}, 16 queries",
+        f"re-select, k={widen}, {flagged} queries",
         (sharded._knn_program(mesh, widen, "l2", "ring", n, TRAIN_TILE, None,
                               "exact", dcn_merge=None),
-         (aval((16, d), jnp.float32, P(QUERY_AXIS)),
+         (aval((flagged, d), jnp.float32, P(QUERY_AXIS)),
           aval((n, d), jnp.float32, P(DB_AXIS))))))
     if d != given:
         cases.append((
@@ -544,6 +569,9 @@ def run_case(name, shape, overrides, expect, mesh, terms, devices, *,
         *SHAPES[shape], overrides=overrides, cache_path=os.devnull)
 
     def make_case():
+        if shape in SELF:
+            return _spmd_case(shape, knobs, devices, mesh or (1, 1), merge,
+                              terms, queries=NQ // 4, resident=True)
         if mesh is not None:
             return _spmd_case(shape, knobs, devices, mesh, merge, terms)
         return _kernel_case(shape, knobs, devices, terms, row_block)
@@ -736,6 +764,29 @@ def main(argv=None) -> int:
         print(f"{'OK  ' if fits else 'FAIL'} {name}: least limit {need} "
               f"MiB, model {model / vmem.MIB:.2f} MiB + an eighth  "
               f"({time.time() - t0:.0f}s)", flush=True)
+    # what one chip holds in a self-join cell: the placed rows, the
+    # resident row operands, and the largest program loaded beside them
+    if args.shape in SELF:
+        from knn_tpu.analysis import hbm
+        from knn_tpu.analysis.widths import lane_tiled
+        from knn_tpu.ops.pallas_knn import TILE_N
+
+        n, given, _ = SHAPES[args.shape]
+        placed, table = temporaries_table(args.shape, devices, args.terms,
+                                          self_rows=True)
+        form = hbm.row_operand_bytes(
+            -(-n // TILE_N) * TILE_N, lane_tiled(given),
+            "hl" in (args.terms or "hh+hl+lh"))
+        for label, temp in table:
+            print(f"TEMP {args.shape} {label}: {temp / 1e9:.3f} GB",
+                  flush=True)
+        most = max(temp for _, temp in table)
+        print(f"MEM  {args.shape}: rows {placed / 1e9:.3f} GB + row "
+              f"operands {form / 1e9:.3f} GB + the largest program's "
+              f"temporaries {most / 1e9:.3f} GB = "
+              f"{(placed + form + most) / 1e9:.3f} GB on one chip "
+              f"(deviceless: XLA's memory_analysis, no chip run)",
+              flush=True)
     # the range completion's program
     for shape in ([args.shape] if args.shape else RANGE):
         if shape not in RANGE:

@@ -54,8 +54,9 @@ assert len(jax.devices()) >= 8, "test harness requires 8 virtual CPU devices"
 # benchmark/tests/tinyroot.py shrinks every traffic file by a literal
 # table of driver kinds and raises KeyError on one it lacks; three files
 # here call tinyroot.make, and the benchmark's file is not theirs to
-# edit.  benchmark/tests/tiny_filter.py adds the ``sweep_filter`` entry
-# and benchmark/tests/tiny_vote.py the ``sweep_vote`` one (root PERF.md
+# edit.  benchmark/tests/tiny_filter.py adds the ``sweep_filter`` entry,
+# benchmark/tests/tiny_vote.py the ``sweep_vote`` one and
+# benchmark/tests/tiny_graph.py the ``graph_build`` one (root PERF.md
 # section 7 asks the next benchmark issue for the one-line repair, which
 # deletes this block)
 import sys
@@ -64,6 +65,7 @@ _bench_tests = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark", "tests")
 sys.path.insert(0, _bench_tests)
 import tiny_filter  # noqa: E402,F401
+import tiny_graph  # noqa: E402,F401
 import tiny_vote  # noqa: E402,F401
 
 sys.path.remove(_bench_tests)

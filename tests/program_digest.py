@@ -34,6 +34,11 @@ their rows are digested as the other cells' are, handed both halves and
 the norms (``resident_parts`` 2 where it was 0).  PR 49's PARENT gives
 the same six digests at ``resident_parts`` 2: the program is the
 parent's, it is the cell that runs another of its forms.
+
+PR 51 added the ninth row, ``deep5m-knng.build``, whose program is the
+SELF program (``SELF``: ``_pallas_self_program``, the block's first row
+id where the queries stood); the eight older rows' digests are what
+they were.
 """
 
 import hashlib
@@ -55,6 +60,9 @@ CELLS = {
     # the eighth cell (PR 48): its program is the VOTE program (VOTED)
     "imagenet-knn768.sweep_vote": (1, 1_281_167, 768, 20, "hh+hl+lh", 2,
                                    False),
+    # the ninth cell (PR 51): its program is the SELF program (SELF);
+    # 96 given columns placed in 128
+    "deep5m-knng.build": (1, 5_000_000, 128, 10, "hh+hl+lh", 2, False),
 }
 #: cell -> (temperature, classes out) of a cell answered by
 #: ``predict_certified(vote="softmax")``: ``_pallas_vote_program`` is
@@ -63,6 +71,13 @@ CELLS = {
 #: which brought the program: a later edit that moves the search
 #: programs' shared tail moves this one too, and says so here
 VOTED = {"imagenet-knn768.sweep_vote": (0.07, 5)}
+#: cells answered by ``knn_tpu.join.knn_self_join``:
+#: ``_pallas_self_program`` is digested in ``_pallas_certified_program``'s
+#: place, a launch of ``queries`` rows, one int32 (the launch's first
+#: row id) where the queries stood.  Recorded on PR 51's tree, which
+#: brought the program; it shares the search programs' kernel launch,
+#: final select, rescore and tail, so an edit that moves those moves it
+SELF = ("deep5m-knng.build",)
 QUERIES, MARGIN = 4096, 28
 #: the rows of a cell's call cut in two and in four
 SUB_BATCH_ROWS = (2048, 1024)
@@ -82,7 +97,12 @@ def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
     block, _ = pk.row_blocking(dim, tile_n=pk.TILE_N, block_q=pk.BLOCK_Q,
                                precision="bf16x3", kernel="tiled",
                                terms=terms, survivors=None)
-    if cell in VOTED:
+    if cell in SELF:
+        prog = sh._pallas_self_program(
+            mesh, m, k, "ring", pk.TILE_N, rows * shards, queries,
+            interpret=True, terms=terms, row_block=block,
+            resident_parts=parts)
+    elif cell in VOTED:
         temperature, classes_out = VOTED[cell]
         prog = sh._pallas_vote_program(
             mesh, m, k, "ring", pk.TILE_N, "bf16x3", rows * shards,
@@ -109,9 +129,10 @@ def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
         tail += [aval((), jnp.float32)]
     if cell in VOTED:
         tail += [aval((shards * rows,), jnp.int32)]
+    first = (aval((1,), jnp.int32) if cell in SELF
+             else aval((queries, dim), jnp.float32))
     text = str(jax.make_jaxpr(prog)(
-        aval((queries, dim), jnp.float32),
-        aval((shards * rows, dim), jnp.float32), *tail))
+        first, aval((shards * rows, dim), jnp.float32), *tail))
     # addresses, and the order a frozenset happens to print in
     text = re.sub(r"0x[0-9a-f]+", "0x", text)
     return re.sub(r"frozenset\(\{([^}]*)\}\)", lambda m: "frozenset({%s})"

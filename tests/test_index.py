@@ -439,8 +439,11 @@ def test_live_mixed_traffic_flat_p99_across_swaps(rng):
     eng = idx.serving_engine(buckets=(8, 16))
     eng.warmup()
     idx.start_compactor()
+    # 2.4 s of traffic: a compaction re-places the corpus and warms an
+    # engine, and beside five busy test workers two of them did not
+    # always fit into 1.2 s (1 of 3 runs failed there, 0 of 4 here)
     spec = loadgen.WorkloadSpec(
-        rate_qps=150, duration_s=1.2, seed=13,
+        rate_qps=150, duration_s=2.4, seed=13,
         tenants=(
             loadgen.TenantSpec("readers", weight=0.8,
                                batch_sizes=(1, 2, 4)),

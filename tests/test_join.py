@@ -28,7 +28,7 @@ from oracles import assert_same_neighbors
 
 from knn_tpu.analysis import hbm
 from knn_tpu.join import (JOIN_MODES, JOIN_VERSION, default_plan,
-                          knn_join, validate_join_block)
+                          knn_join, knn_self_join, validate_join_block)
 from knn_tpu.parallel import ShardedKNN, make_mesh
 
 DIM = 16
@@ -99,11 +99,31 @@ def test_stream_join_return_sqrt_matches_search(corpus):
 
 
 # -- certified mode: the bitwise oracle across precisions x kernels ------
-@pytest.mark.parametrize("precision", [None, "bf16x3", "int8"])
-def test_certified_join_oracle_across_precisions(corpus, precision):
+@pytest.mark.parametrize("precision,own_rows", [
+    (None, False), ("bf16x3", False), ("int8", False),
+    # the corpus's own rows as the queries, each row no answer of itself:
+    # the pipelined self-join (knn_self_join; tests/test_deep_knng.py)
+    # against the same oracle with the row masked out by id
+    (None, True)])
+def test_certified_join_oracle_across_precisions(corpus, precision,
+                                                 own_rows):
     db, q = corpus
-    ref_d, ref_i = _oracle(db, q, 7)
     prog = ShardedKNN(db, mesh=make_mesh(*MESH), k=7)
+    if own_rows:
+        lo, hi = 190, 260  # across the duplicates and the shard boundary
+        d64 = ((db.astype(np.float64)[None]
+                - db[lo:hi].astype(np.float64)[:, None]) ** 2).sum(-1)
+        d64[np.arange(hi - lo), np.arange(lo, hi)] = np.inf
+        ref_i = np.argsort(d64, axis=-1, kind="stable")[:, :7]
+        d, i, st = knn_self_join(prog, rows=(lo, hi))
+        np.testing.assert_array_equal(i, ref_i)
+        np.testing.assert_allclose(
+            d, np.take_along_axis(d64, ref_i, axis=-1), rtol=2.0 ** -18)
+        assert (d[10:30, 0] == 0).all()  # rows 200-219 copy rows 0-19
+        assert st["mode"] == "self" and st["self_excluded"] == hi - lo
+        assert isinstance(st["overlap_ratio"], float)  # a pipeline
+        return
+    ref_d, ref_i = _oracle(db, q, 7)
     kw = {"selector": "approx"}
     if precision is not None:
         kw["precision"] = precision

@@ -13,7 +13,9 @@ nothing there:
 - every ``program_span`` / ``program_counter`` entry of ``BENCHMARK.json``
   whose reader is a ``span`` or a ``counter``, with every query kind among
   its cells: after one tiny CPU call of that kind the registry's change
-  over the call holds the series, read by the harness's own reader.
+  over the call holds the series, read by the harness's own reader (a
+  bulk self-join call of one block among the kinds: its stages are a
+  block's, one record each).
 """
 
 import ast
@@ -39,6 +41,7 @@ from test_certified_spans import SCOPES  # noqa: E402  (tests/)
 from test_yfcc_filter import random_bags  # noqa: E402  (tests/)
 
 from knn_tpu import obs  # noqa: E402
+from knn_tpu.join import knn_self_join  # noqa: E402
 from knn_tpu.obs import names as mn  # noqa: E402
 from knn_tpu.ops import pallas_knn, radius, tagfilter  # noqa: E402
 from knn_tpu.parallel import ShardedKNN, make_mesh  # noqa: E402
@@ -229,8 +232,17 @@ def _voted():
         selector="pallas", tile_n=TILE)
 
 
+def _self():
+    rng = np.random.default_rng(51)
+    db = rng.normal(size=(3000, 32)).astype(np.float32)
+    db[1500:1520] = db[:20]  # exact copies
+    prog = ShardedKNN(db, mesh=_mesh(), k=K)
+    return lambda: knn_self_join(prog, rows=(200, 2200))
+
+
 BUILDERS = {"l2": _l2, "dot": _metric("dot"), "cosine": _metric("cosine"),
-            "range": _range, "filtered": _filtered, "voted": _voted}
+            "range": _range, "filtered": _filtered, "voted": _voted,
+            "self": _self}
 #: the query kind of a cell, by its traffic file's kind and its metric
 KIND_OF_CELL = {}
 for _cell in BENCH["workloads"]:
@@ -238,7 +250,8 @@ for _cell in BENCH["workloads"]:
     _config = _json("benchmark", "configs", _cell["config"] + ".json")
     KIND_OF_CELL[_cell["name"]] = {
         "sweep_range": "range", "sweep_filter": "filtered",
-        "sweep_vote": "voted"}.get(_traffic["kind"], _config["metric"])
+        "sweep_vote": "voted", "graph_build": "self"}.get(
+            _traffic["kind"], _config["metric"])
 
 PAIRS = sorted(
     (m["name"], kind)
@@ -269,7 +282,7 @@ def deltas():
     obs.reset()
 
 
-def test_the_cells_are_the_six_kinds():
+def test_the_cells_are_the_seven_kinds():
     assert set(KIND_OF_CELL.values()) == set(BUILDERS)
 
 

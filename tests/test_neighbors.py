@@ -54,6 +54,39 @@ def test_kneighbors_graph_shapes_and_modes(data):
     assert (sd.reshape(300, 4)[:, 0] < 1e-3).all()
 
 
+@pytest.mark.parametrize("shards", [1, 4])
+def test_kneighbors_graph_without_self_is_exact_by_id(data, shards):
+    """``include_self=False`` on a placed estimator: the bulk certified
+    self-join's graph: no row names itself, an exact copy stays at 0,
+    every list is the float64 oracle's over the other rows."""
+    import jax
+
+    X, _ = data
+    X = X.copy()
+    X[200:210] = X[:10]  # exact copies
+    nn = NearestNeighbors(k=4, mesh=make_mesh(
+        1, shards, devices=jax.devices()[:shards])).fit(X)
+    data_d, idx, ptr = nn.kneighbors_graph(mode="distance",
+                                           include_self=False)
+    idx, data_d = idx.reshape(300, 4), data_d.reshape(300, 4)
+    d64 = _oracle_d(X, X, "l2")
+    d64[np.arange(300), np.arange(300)] = np.inf
+    want = np.lexsort(
+        (np.broadcast_to(np.arange(300), d64.shape), d64), axis=-1)[:, :4]
+    np.testing.assert_array_equal(idx, want)
+    assert not (idx == np.arange(300)[:, None]).any()
+    assert (idx[:10, 0] == np.arange(200, 210)).all()
+    assert (data_d[:10, 0] == 0).all() and list(ptr[:3]) == [0, 4, 8]
+    # the default is the graph it always was: the row itself first
+    _, with_self, _ = nn.kneighbors_graph()
+    assert (with_self.reshape(300, 4)[:10, 0] == np.arange(10)).all()
+    for bad in (dict(Q=X[:5]), dict(k=3)):
+        with pytest.raises(ValueError, match="include_self=False"):
+            nn.kneighbors_graph(include_self=False, **bad)
+    with pytest.raises(ValueError, match="fitted on a mesh"):
+        NearestNeighbors(k=4).fit(X).kneighbors_graph(include_self=False)
+
+
 def test_radius_neighbors_graph_matches_oracle(data):
     X, Q = data
     d64 = _oracle_d(X, Q, "l2")

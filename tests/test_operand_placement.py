@@ -356,6 +356,9 @@ IMAGENET_IN_USE = 3_940_903_424
     # 1,281,167 x 768 unit rows (PR 48): 3.94 + 3.98 + 1.25 x 3.94 =
     # 12.8 GB (at 2.7: 18.5, and per_call until PR 49)
     ("imagenet-knn768", 1_281_167, 768, True, IMAGENET_IN_USE, True),
+    # 5M x 96 unit rows placed in 128 (PR 51), both halves: 2.56 + 2.59
+    # + 1.25 x 2.56 = 8.3 GB
+    ("deep5m-knng", 5_000_000, 128, True, 2_560_027_136, True),
     # a PRE-PLACED 1M x 960 array is used as handed in: its programs
     # still copy and pad all of it (2.7), 3.84 + 4.17 + 10.37 = 18.4 GB
     ("gist1m-preplaced-960", 1_000_000, 960, True, 3_840_027_136, False),

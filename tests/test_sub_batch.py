@@ -65,6 +65,9 @@ CELLS = {
     # 3.94 GB of rows and 3.98 of halves and norms (resident since
     # PR 49, as gist1m)
     "imagenet-knn768.sweep_vote": (768, "resident"),
+    # a block of the bulk self-join is a call of 4,096 rows to the rule
+    # (2.56 GB of rows, 2.59 of halves and norms)
+    "deep5m-knng.build": (96, "resident"),
 }
 
 
@@ -106,7 +109,8 @@ def test_the_cells_as_the_chip_runs_them():
         "per_call_operands")
     assert rule(width=lane_tiled(960))[1] == "resident"
     # and what the placement does not lay out keeps its width's reading
-    assert {rule(width=w)[1] for w in (192, 201, 960)} == {"layout_copy"}
+    assert {rule(width=w)[1] for w in (96, 192, 201, 960)} == {
+        "layout_copy"}
 
 
 @pytest.mark.parametrize("queries,want", [

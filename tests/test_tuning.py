@@ -338,7 +338,7 @@ def test_default_knobs_are_the_kernel_shaping_arguments():
     assert kwargs_of(
         ShardedKNN._pallas_setup, "margin", "include_distances", "terms",
         "batch_rows", "call_rows", "trace_id", "acct", "masked",
-        "vote") == set(
+        "vote", "own_rows") == set(
             tuning.DEFAULT_KNOBS)
 
 
