@@ -516,7 +516,8 @@ def select_merge_geometry(
     (:func:`local_select_rescore`), or ``None`` where the final select
     runs over the kernel's candidates as they are.  The candidate array
     is ``width // 128`` lane-rows; they are cut into ``groups``
-    contiguous runs of ``rows`` (the last padded with +inf) and
+    contiguous runs of ``rows`` (the last short of it where they do not
+    divide: ``_select_merge`` reads +inf past the array) and
     ``(group, lane)`` is a merge bin that keeps its
     ``SELECT_MERGE_SURVIVORS`` smallest: ``groups * 128`` bins, at least
     ``SELECT_MERGE_BINS_PER_SLOT`` for each of the m+2 slots, so
@@ -2129,6 +2130,69 @@ def _select_merge_kernel(cd_ref, ci_ref, v_ref, i_ref, b_ref, *, rows: int):
     b_ref[...] = b
 
 
+def _scores_past_the_array_inf(cd_ref, rows: int, groups: int, short: int):
+    """A (query block, group) cell's block of ``cd`` where the
+    ``groups`` runs of ``rows`` lane-rows reach ``short`` past the
+    array's ``groups * rows - short``: the last group's block hangs
+    over the array's end, and what it holds out there is unspecified
+    (NaN under the interpreter).  Those scores are made +inf here, by
+    index, before the insertion network sees them: one select on the
+    group's number for each lane-row of a block that lies past the
+    array in some group (the last ``short`` of the last group's, where
+    ``short < rows``).  Their payload needs nothing: +inf never
+    displaces, so whatever index lies there is never kept."""
+    s = cd_ref[...]
+    first = lax.mul(pl.program_id(1), np.int32(rows))
+    inf = lax.full((s.shape[0], BIN_W), jnp.inf, jnp.float32)
+    whole = max(0, rows - short)
+    cols = [lax.slice_in_dim(s, 0, whole * BIN_W, axis=1)] if whole else []
+    for r in range(whole, rows):
+        # lane-row ``first + r`` of the array: past its last?
+        past = lax.ge(first, np.int32(groups * rows - short - r))
+        cols.append(lax.select(past, inf, lax.slice_in_dim(
+            s, r * BIN_W, (r + 1) * BIN_W, axis=1)))
+    return lax.concatenate(cols, 1)
+
+
+def _select_merge_short_kernel(cd_ref, ci_ref, v_ref, i_ref, b_ref, *, grid):
+    """:func:`_select_merge_kernel` on a group grid ``(rows, groups,
+    short)`` that overshoots the array by ``short`` lane-rows: the same
+    emitter on the block's scores with the overhang masked
+    (:func:`_scores_past_the_array_inf`).  The mask is no argument of
+    the emitter, which is the distance kernel's too and one of the
+    frames its trace stack is measured by (``_row_step``), and it is
+    formed in a call that has returned before the emitter's loop binds:
+    this frame is :func:`_select_merge_kernel`'s to the slot (one static
+    argument, no local of its own), so a cell whose merge is off its
+    grid traces the loop where it always stood on CPython's frame stack
+    (root PERF.md section 6, PRs 46 and 52)."""
+    v = _scores_past_the_array_inf(cd_ref, *grid)
+    v, i, b = _emit_select_grouped_scores(
+        None, v, tile_n=grid[0] * BIN_W,
+        survivors=SELECT_MERGE_SURVIVORS, payload=ci_ref[...])
+    v_ref[...] = v
+    i_ref[...] = i
+    b_ref[...] = b
+
+
+def _select_merge_cell(groups: int, rows: int, short: int):
+    """``(kernel, index map of cd and ci)`` of :func:`_select_merge`'s
+    grid cell ``(query block, group)``: the plain kernel on the group's
+    own block where the groups tile the array (``short`` 0: the call is
+    what it always was, operation for operation), else the kernel that
+    masks the overhang, on the last block that holds any of the array:
+    the group's own but for a group that lies past the array altogether
+    (``short >= rows``: fewer lane-rows than groups squared), which
+    reads that block and masks all of it."""
+    if not short:
+        return (functools.partial(_select_merge_kernel, rows=rows),
+                lambda i, g: (i, g))
+    held = (groups * rows - short - 1) // rows
+    return (functools.partial(_select_merge_short_kernel,
+                              grid=(rows, groups, short)),
+            lambda i, g: (i, jnp.minimum(g, held)))
+
+
 def _select_merge(cd: jax.Array, ci: jax.Array, groups: int, rows: int,
                   *, interpret: bool):
     """The second bin-merge (``select_merge_geometry``): per merge bin
@@ -2139,12 +2203,18 @@ def _select_merge(cd: jax.Array, ci: jax.Array, groups: int, rows: int,
     [Q, groups * 128])``.  Strict ``<`` keeps the earlier column on ties
     and +inf (kernel padding, the last group's own) never enters: its
     slot keeps the sentinel index.  Every (query block, group) cell
-    writes its own disjoint output blocks, like the kernel's."""
-    n_q, w = cd.shape
-    pad = groups * rows * BIN_W - w
-    if pad:
-        cd = jnp.pad(cd, ((0, 0), (0, pad)), constant_values=jnp.inf)
-        ci = jnp.pad(ci, ((0, 0), (0, pad)), constant_values=_I32MAX)
+    writes its own disjoint output blocks, like the kernel's.
+
+    ``cd`` and ``ci`` are read where the kernel wrote them, at their own
+    width: where ``groups * rows`` lane-rows overshoot it the last
+    group's block hangs over the arrays' end and its cell masks the
+    overhang by index (``_select_merge_short_kernel``).  No copy of
+    either is made to pad them to the group grid: that was two passes
+    over both arrays in every launch, more than the merge itself at
+    2.5M rows and k = 10 (root PERF.md section 6, PR 52)."""
+    n_q = cd.shape[0]
+    kernel, read = _select_merge_cell(
+        groups, rows, groups * rows - cd.shape[1] // BIN_W)
     # two [block_q, rows * 128] input blocks of at most 3 MiB each,
     # double buffered by the pipeline: Mosaic's 16 MiB of scoped VMEM
     # hold them up to 3.1 MiB a block and not at 3.4 (16.14 MiB asked
@@ -2162,9 +2232,9 @@ def _select_merge(cd: jax.Array, ci: jax.Array, groups: int, rows: int,
         kwargs["compiler_params"] = pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"))
     return pl.pallas_call(
-        functools.partial(_select_merge_kernel, rows=rows),
+        kernel,
         grid=(-(-n_q // block_q), groups),
-        in_specs=[pl.BlockSpec((block_q, rows * BIN_W), cell)] * 2,
+        in_specs=[pl.BlockSpec((block_q, rows * BIN_W), read)] * 2,
         out_specs=[pl.BlockSpec((block_q, out_w), cell),
                    pl.BlockSpec((block_q, out_w), cell),
                    pl.BlockSpec((block_q, BIN_W), cell)],

@@ -1087,6 +1087,9 @@ class ShardedKNN:
         # (kernel's candidate width, width the final top-k sees) of the
         # last resolved pallas program, per shard (_pallas_setup)
         self._select_widths: Tuple[int, int] = (0, 0)
+        # the lane-rows the bin-merge's last group is short of its grid
+        # (0: the groups tile the kernel's width, or no merge runs)
+        self._select_merge_short = 0
         # (columns of one dim chunk, chunks a row tile is cut into) that
         # _pallas_setup handed its last program's kernel
         self._dim_chunking: Tuple[int, int] = (0, 0)
@@ -2401,6 +2404,9 @@ class ShardedKNN:
                 width, merged_width = self._select_widths
                 merged["select_width"] = width
                 merged["select_merged_width"] = merged_width
+                # the lane-rows the merge's last group hangs over the
+                # kernel's width and masks by index (_select_merge)
+                merged["select_merge_short"] = self._select_merge_short
                 obs.counter(
                     _mn.SELECT_MERGE_CALLS,
                     engaged="true" if merged_width < width else "false",
@@ -2493,6 +2499,7 @@ class ShardedKNN:
                     "row_block": merged["row_block"],
                     "row_steps": merged["row_steps"],
                     "final_select_stage": merged["final_select_stage"],
+                    "select_merge_short": merged["select_merge_short"],
                     "operands": merged["operands"],
                     "sub_batch": sub_why, "batches": len(batches)}
                 stats["tuning"] = tune_info
@@ -3031,6 +3038,7 @@ class ShardedKNN:
         first row of a launch where the search program takes the
         queries."""
         from knn_tpu.ops.pallas_knn import (
+            BIN_W,
             BLOCK_Q,
             TILE_N,
             _geometry,
@@ -3083,6 +3091,8 @@ class ShardedKNN:
         merge = select_merge_geometry(select_width, m)
         self._select_widths = (
             select_width, select_width if merge is None else merge[2])
+        self._select_merge_short = 0 if merge is None else (
+            merge[0] * merge[1] - select_width // BIN_W)
         self._final_select_stage = (
             "pallas" if final_select == "exact" and final_select_geometry(
                 self._select_widths[1], m) is not None else "xla")
@@ -3611,8 +3621,8 @@ class ShardedKNN:
                     **knobs, "interpret": interpret, "terms": terms,
                     **{key: merged[key] for key in (
                         "mxu_passes", "dim_chunk", "dim_chunks", "row_block",
-                        "row_steps", "final_select_stage", "operands",
-                        "sub_batch")},
+                        "row_steps", "final_select_stage",
+                        "select_merge_short", "operands", "sub_batch")},
                     "batches": len(batches)},
                 "tuning": tune_info,
             }
@@ -3657,6 +3667,7 @@ class ShardedKNN:
             "db_shards": self.db_shards, "merge": self.merge,
             "merge_source": self.merge_source, "merge_bytes": merge_bytes,
             "select_width": width, "select_merged_width": merged_width,
+            "select_merge_short": self._select_merge_short,
             "terms": terms, "mxu_passes": terms.count("+") + 1,
             "dim_chunk": self._dim_chunking[0],
             "dim_chunks": self._dim_chunking[1],
@@ -4031,6 +4042,7 @@ class _SelfJoinCall:
             "row_block": knn._row_blocking[0],
             "row_steps": knn._row_blocking[1],
             "final_select_stage": knn._final_select_stage,
+            "select_merge_short": knn._select_merge_short,
             "operands": knn._operands_source, "sub_batch": self.sub_why}
         return {
             **told, "certified": n - told["fallback_queries"],

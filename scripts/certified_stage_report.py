@@ -121,7 +121,8 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
                 # it and as its top-k scanned it (less where the
                 # bin-merge engaged)
                 select = {k: e[k] for k in (
-                    "select_width", "select_merged_width") if k in e}
+                    "select_width", "select_merged_width",
+                    "select_merge_short") if k in e}
             elif e.get("parent") == CALL:
                 children += e["dur_s"]
             for key in ("h2d_bytes", "d2h_bytes", "queries_corrected",

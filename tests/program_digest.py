@@ -39,6 +39,17 @@ PR 51 added the ninth row, ``deep5m-knng.build``, whose program is the
 SELF program (``SELF``: ``_pallas_self_program``, the block's first row
 id where the queries stood); the eight older rows' digests are what
 they were.
+
+PR 52 recorded ``text2image2m5.sweep_ip``'s, ``imagenet-knn768.sweep_vote``'s
+and ``deep5m-knng.build``'s three digests each anew and no other: the
+final select's bin-merge reads the kernel's candidate arrays where they
+lie, its last group short of its grid (306 lane-rows in 5 groups of 62,
+158 in 7 x 23, 612 in 5 x 123) masked by index in the merge's own cell,
+where two ``pad`` copied both arrays to the group grid.  The four rows
+whose groups tile the width (``bigann5m.sweep``, ``bigann20m-x4.sweep``,
+``ssnpp2m5.sweep_range``: 17 x 36 and 17 x 18) or that run no merge
+(``gist1m.sweep``) give the digests they gave: the program is the
+parent's where nothing is short.
 """
 
 import hashlib
@@ -83,7 +94,10 @@ QUERIES, MARGIN = 4096, 28
 SUB_BATCH_ROWS = (2048, 1024)
 
 
-def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
+def traced(cell: str, queries: int = QUERIES, interpret: bool = True):
+    """The cell's program traced from abstract arguments: its closed
+    jaxpr.  ``interpret`` False traces the kernels as the chip runs
+    them (the interpreter pads every array it cuts into blocks)."""
     import jax
     import jax.numpy as jnp
 
@@ -100,7 +114,7 @@ def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
     if cell in SELF:
         prog = sh._pallas_self_program(
             mesh, m, k, "ring", pk.TILE_N, rows * shards, queries,
-            interpret=True, terms=terms, row_block=block,
+            interpret=interpret, terms=terms, row_block=block,
             resident_parts=parts)
     elif cell in VOTED:
         temperature, classes_out = VOTED[cell]
@@ -108,12 +122,12 @@ def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
             mesh, m, k, "ring", pk.TILE_N, "bf16x3", rows * shards,
             (1.0 / temperature, classes_out,
              sh.vote_delta(temperature, k)),
-            interpret=True, terms=terms, row_block=block,
+            interpret=interpret, terms=terms, row_block=block,
             resident_parts=parts)
     else:
         prog = sh._pallas_certified_program(
             mesh, m, k, "ring", pk.TILE_N, "bf16x3", n_train=rows * shards,
-            interpret=True, terms=terms, augmented=dot, row_block=block,
+            interpret=interpret, terms=terms, augmented=dot, row_block=block,
             resident_parts=parts)
     rows_p = -(-rows // pk.TILE_N) * pk.TILE_N
     dim_p = -(-dim // pk.DIM_CHUNK) * pk.DIM_CHUNK
@@ -131,8 +145,12 @@ def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
         tail += [aval((shards * rows,), jnp.int32)]
     first = (aval((1,), jnp.int32) if cell in SELF
              else aval((queries, dim), jnp.float32))
-    text = str(jax.make_jaxpr(prog)(
-        first, aval((shards * rows, dim), jnp.float32), *tail))
+    return jax.make_jaxpr(prog)(
+        first, aval((shards * rows, dim), jnp.float32), *tail)
+
+
+def jaxpr_text(cell: str, queries: int = QUERIES) -> str:
+    text = str(traced(cell, queries))
     # addresses, and the order a frozenset happens to print in
     text = re.sub(r"0x[0-9a-f]+", "0x", text)
     return re.sub(r"frozenset\(\{([^}]*)\}\)", lambda m: "frozenset({%s})"

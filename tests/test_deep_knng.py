@@ -168,6 +168,8 @@ def test_a_block_of_the_cells_shape_is_four_launches():
     assert (stats["sub_batch"], stats["sub_batch_rows"]) == (
         "resident", 1024)
     assert stats["pallas_knobs"]["interpret"] is True
+    # one tile of 16,384 rows: no bin-merge, so no group of one is short
+    assert stats["pallas_knobs"]["select_merge_short"] == 0
     assert stats["tuning"]["source"] == "default"
 
 

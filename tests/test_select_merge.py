@@ -6,22 +6,34 @@ where the candidate array is at least twice the merged width.
   straight to ``local_select_rescore`` (no kernel), against the select
   over the candidates as they are;
 - engagement from shapes alone (nothing computed);
+- the merge reading ``cd`` / ``ci`` where the kernel wrote them (PR 52):
+  a last group short of its grid, masked by index, against the arrays
+  padded by hand to the group grid, and what the call reports of it
+  (``select_merge_short``);
 - an engaging corpus end to end through ``ShardedKNN.search_certified``
   on one CPU device and on a (1, 4) mesh, the kernel interpreted, with a
   collision built in that only the repair can answer.
 """
 
 import functools
+import os
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from knn_tpu import obs
-from knn_tpu.obs import names as mn
-from knn_tpu.ops import pallas_knn as pk
-from knn_tpu.parallel import ShardedKNN, make_mesh
+HERE = os.path.dirname(os.path.abspath(__file__))
+if HERE not in sys.path:
+    sys.path.insert(0, HERE)
+
+import program_digest  # noqa: E402  (tests/)
+
+from knn_tpu import obs  # noqa: E402
+from knn_tpu.obs import names as mn  # noqa: E402
+from knn_tpu.ops import pallas_knn as pk  # noqa: E402
+from knn_tpu.parallel import ShardedKNN, make_mesh  # noqa: E402
 
 I32MAX = np.iinfo(np.int32).max
 M = 128                       # k=100 + margin 28: m + 2 = 130 slots
@@ -347,3 +359,170 @@ def test_a_query_count_off_the_block_grid_merges_every_row():
         b.reshape(n_q, GROUPS, pk.BIN_W),
         want[:, :, pk.SELECT_MERGE_SURVIVORS])
     np.testing.assert_array_equal(np.take_along_axis(cd, i, axis=1), v)
+
+
+# --- the arrays read where the kernel wrote them (PR 52) ---------------------
+#: (lane-rows of the kernel's width, m, what has that remainder)
+OFF_THE_GRID = (
+    (612, 38, "deep5m-knng: 5M rows, 5 groups of 123, 3 short"),
+    (306, 38, "text2image2m5, yfcc2m5: 2.5M rows, 5 x 62, 4 short"),
+    (158, 48, "imagenet-knn768: 1.28M rows, 7 x 23, 3 short"),
+    (612, 128, "the k = 100 cells: 17 x 36, the grid fits"),
+    (270, 128, "2.2M rows at k = 100: 17 x 16, 2 short"),
+    (137, 128, "17 x 9, 16 short: the 16th group holds 2, the 17th none"))
+
+
+def padded_by_hand(cd, ci, groups, rows):
+    """The parent's form: both arrays padded to the group grid, scores
+    with +inf and indices with the sentinel, then the merge on a width
+    its groups tile (the plain kernel, the parent's program)."""
+    pad = groups * rows * pk.BIN_W - cd.shape[1]
+    return pk._select_merge(
+        jnp.pad(cd, ((0, 0), (0, pad)), constant_values=jnp.inf),
+        jnp.pad(ci, ((0, 0), (0, pad)), constant_values=I32MAX),
+        groups, rows, interpret=True)
+
+
+@pytest.mark.parametrize("masked", (True, False))
+@pytest.mark.parametrize("lane_rows,m", [c[:2] for c in OFF_THE_GRID],
+                         ids=[c[2].split(":")[0] for c in OFF_THE_GRID])
+def test_the_merge_reads_the_arrays_where_the_kernel_wrote_them(
+        lane_rows, m, masked, monkeypatch):
+    """Bit for bit the parent's three arrays, with no copy of ``cd`` or
+    ``ci``.  The overhang of the last group's block is poison here: the
+    interpreter fills what a block reads past an array with NaN and the
+    least int32, and a group that lies past the array altogether reads
+    the last block's real scores and real-looking indices.  So the
+    unmasked run (the plain kernel on the same overhanging blocks) is
+    the control: it is NOT the parent's answer wherever a group is
+    short, which is what says the mask and not luck keeps them out."""
+    width = lane_rows * pk.BIN_W
+    groups, rows, merged = pk.select_merge_geometry(width, m)
+    short = groups * rows - lane_rows
+    rng = np.random.default_rng([52, lane_rows, m])
+    n_q = 16
+    cd = rng.permutation(n_q * width).reshape(n_q, width).astype(np.float32)
+    ci = np.stack([rng.permutation(N_ROWS * 4)[:width]
+                   for _ in range(n_q)]).astype(np.int32)
+    # the kernel's own padding, and ties across the last group's edge
+    gone = rng.random(cd.shape) < 0.02
+    cd[gone], ci[gone] = np.inf, I32MAX
+    cd[:, -3 * pk.BIN_W:] = np.floor(cd[:, -3 * pk.BIN_W:] / 64)
+    if not masked:
+        plain = pk._select_merge_cell(groups, rows, 0)[0]
+        real = pk._select_merge_cell
+        monkeypatch.setattr(pk, "_select_merge_cell", lambda *a: (
+            plain, real(*a)[1]))
+    got = [np.asarray(x) for x in pk._select_merge(
+        jnp.asarray(cd), jnp.asarray(ci), groups, rows, interpret=True)]
+    want = [np.asarray(x) for x in padded_by_hand(
+        jnp.asarray(cd), jnp.asarray(ci), groups, rows)]
+    assert [x.shape for x in got] == [
+        (n_q, merged), (n_q, merged), (n_q, groups * pk.BIN_W)]
+    same = [np.array_equal(g, w) and g.dtype == w.dtype
+            for g, w in zip(got, want)]
+    if masked or not short:
+        assert same == [True, True, True]
+        assert not np.isnan(got[0]).any() and not np.isnan(got[2]).any()
+        assert (got[1] >= 0).all()
+    else:
+        assert not same[0] and not same[2]
+        # every group that holds all its lane-rows is still the parent's
+        out_w = pk.SELECT_MERGE_SURVIVORS * pk.BIN_W
+        whole = lane_rows // rows
+        np.testing.assert_array_equal(got[0][:, :whole * out_w],
+                                      want[0][:, :whole * out_w])
+
+
+def test_the_short_kernels_frame_is_the_plain_kernels():
+    """A tripwire like tests/test_dim_chunking.py's: the merge's trace
+    binds the emitter's unrolled loop (2,460 binds at 123 lane-rows a
+    group) under the merge kernel's frame, and where CPython's 16 KiB
+    frame-stack chunks end under that loop is drawn by the summed frame
+    sizes above it.  The kernel that masks the overhang forms the mask
+    in a call that has returned by then and keeps the plain kernel's
+    frame to the slot, so a cell whose merge is off its grid keeps the
+    parent's draw: a first form 14 slots larger cost
+    ``text2image2m5.sweep_ip`` 0.6 s of its ``first batch`` (root
+    PERF.md section 6, PR 52)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "frame_sizes", os.path.join(os.path.dirname(HERE), "scripts",
+                                    "frame_sizes.py"))
+    frame_sizes = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(frame_sizes)
+    assert (frame_sizes.slots(pk._select_merge_short_kernel.__code__)
+            == frame_sizes.slots(pk._select_merge_kernel.__code__))
+
+
+def pads_and_kernels(jaxpr, pads, kernels):
+    """Result shapes of every ``pad`` of a jaxpr and the names of its
+    Pallas calls, its sub-jaxprs' (the shard_map's, the jits') included;
+    a kernel's body is not walked (it holds no array of the call)."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pad":
+            pads.append(tuple(eqn.outvars[0].aval.shape))
+        if eqn.primitive.name == "pallas_call":
+            kernels.append(eqn.params["name"])
+            continue
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    pads_and_kernels(inner, pads, kernels)
+    return pads, kernels
+
+
+@pytest.mark.parametrize("cell,grid", (
+    ("text2image2m5.sweep_ip", (5, 62)), ("deep5m-knng.build", (5, 123)),
+    ("imagenet-knn768.sweep_vote", (7, 23))))
+def test_the_certified_program_pads_no_candidate_array(cell, grid):
+    """The whole program of a cell whose merge is off its grid, traced
+    as the chip runs it (the kernels not interpreted: the interpreter
+    pads every array it blocks): between the kernel and
+    ``select_merge`` nothing makes an array of the group grid's width
+    (the parent's two ``pad`` did: 2 x 643 MB of HBM traffic a launch
+    at ``deep5m-knng``)."""
+    pads, kernels = pads_and_kernels(
+        program_digest.traced(cell, 1024, interpret=False).jaxpr, [], [])
+    assert "select_merge" in kernels and "select_final" in kernels
+    assert grid[0] * grid[1] * pk.BIN_W not in [p[-1] for p in pads]
+
+
+#: (rows, lane-rows short): 21, 23 and 25 tiles of 256 at k = 10
+#: (m + 2 = 40, 5 groups: 42 lane-rows in 5 x 9, 46 in 5 x 10, 50 fit),
+#: and a shard under the merge's engagement width
+SHORT_CASES = ((5_300, 3), (5_800, 4), (6_300, 0), (2_000, 0))
+
+
+@pytest.fixture
+def fresh_registry():
+    obs.reset(enabled=True)
+    obs.reset_event_log(None)
+    yield
+    obs.reset()
+    obs.reset_event_log(from_env=True)
+
+
+@pytest.mark.parametrize("n_rows,short", SHORT_CASES)
+def test_the_call_says_how_short_the_last_group_is(fresh_registry, n_rows,
+                                                   short):
+    rng = np.random.default_rng([52, n_rows])
+    db = rng.integers(0, 256, (n_rows, 16)).astype(np.float32)
+    q = rng.integers(0, 256, (8, 16)).astype(np.float32)
+    prog = ShardedKNN(
+        db, mesh=make_mesh(1, 1, devices=jax.devices()[:1]), k=10)
+    _, i, stats = prog.search_certified(q, selector="pallas", tile_n=TILE)
+    (call,) = [e for e in obs.get_event_log().recent()
+               if e.get("span") == "certified.call"]
+    d64 = ((q[:, None, :].astype(np.float64) - db[None].astype(np.float64))
+           ** 2).sum(-1)
+    np.testing.assert_array_equal(i, np.lexsort((np.broadcast_to(
+        np.arange(n_rows), d64.shape), d64), axis=1)[:, :10])
+    width = -(-n_rows // TILE) * 2 * pk.BIN_W
+    engaged = n_rows > 5_000
+    assert stats["select_width"] == call["select_width"] == width
+    assert stats["select_merged_width"] == (2560 if engaged else width)
+    assert (stats["select_merge_short"], call["select_merge_short"],
+            stats["pallas_knobs"]["select_merge_short"]) == (short,) * 3

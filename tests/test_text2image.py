@@ -248,13 +248,18 @@ def one_chip():
 @pytest.mark.parametrize("rows_n,m,geometry", [
     (2_500_000, 38, (5, 62, 2560)),     # text2image2m5: k=10
     (5_000_000, 128, (17, 36, 8704)),   # both BIGANN cells: k=100
-    (10_000_000, 128, (17, 72, 8704))])
+    (10_000_000, 128, (17, 72, 8704)),
+    (5_000_000, 38, (5, 123, 2560)),    # deep5m-knng: k=10
+    (1_281_167, 48, (7, 23, 3584))])    # imagenet-knn768: k=20
 def test_the_bin_merge_compiles_at_the_cells_widths(one_chip, rows_n, m,
                                                     geometry):
     """On the chip the merge kernel's blocks overran Mosaic's 16 MiB of
     scoped VMEM at this cell's 62 lane-rows a merge bin (PR 31, chip call
     59): the whole program compiled deviceless carries this kernel
-    interpreted, so it is compiled here alone."""
+    interpreted, so it is compiled here alone.  Since PR 52 the last
+    group's block hangs over the arrays' end where the groups do not
+    tile the width (4 lane-rows of 310 here, 3 of 615 and of 161 in the
+    last two cases), and the compiled module holds no ``pad``."""
     import jax.numpy as jnp
 
     from knn_tpu.ops import pallas_knn as pk
@@ -263,10 +268,11 @@ def test_the_bin_merge_compiles_at_the_cells_widths(one_chip, rows_n, m,
     assert pk.select_merge_geometry(width, m) == geometry
     fn = jax.jit(lambda cd, ci: pk._select_merge(
         cd, ci, *geometry[:2], interpret=False))
-    fn.lower(
+    text = fn.lower(
         jax.ShapeDtypeStruct((4096, width), jnp.float32, sharding=one_chip),
         jax.ShapeDtypeStruct((4096, width), jnp.int32, sharding=one_chip),
-    ).compile()
+    ).compile().as_text()
+    assert "select_merge" in text and " pad(" not in text
 
 
 @pytest.mark.parametrize("width,m", [

@@ -304,14 +304,14 @@ def test_version_6_winner_naming_a_removed_knob_is_never_used(
     # beside the knobs: what the program resolved for itself, from the
     # backend (interpret), from the data (terms, mxu_passes) and from
     # the launch's shape (dim_chunk(s), row_block / row_steps,
-    # final_select_stage) and
+    # final_select_stage, select_merge_short) and
     # from the device's memory (operands), and how the call was cut
     # (sub_batch, batches: analysis.subbatch)
     assert {kk: v for kk, v in stats["pallas_knobs"].items()
             if kk not in ("interpret", "terms", "mxu_passes", "dim_chunk",
                           "dim_chunks", "row_block", "row_steps",
-                          "final_select_stage", "operands",
-                          "sub_batch", "batches")
+                          "final_select_stage", "select_merge_short",
+                          "operands", "sub_batch", "batches")
             } == tuning.DEFAULT_KNOBS
     assert (stats["pallas_knobs"]["dim_chunk"],
             stats["pallas_knobs"]["dim_chunks"]) == (128, 1)
