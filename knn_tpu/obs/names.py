@@ -76,6 +76,7 @@ CERTIFIED_RANK_CORRECTED = "knn_tpu_certified_rank_corrected_queries_total"
 CERTIFIED_METRIC_QUERIES = "knn_tpu_certified_metric_queries_total"
 CERTIFIED_SLACK_QUERIES = "knn_tpu_certified_slack_queries_total"
 RANK_CORRECT_MEMBERS = "knn_tpu_rank_correct_members_total"
+REPAIR_QUERIES = "knn_tpu_repair_queries_total"
 VOTE_QUERIES = "knn_tpu_vote_queries_total"
 CERTIFIED_QUANT_BOUND = "knn_tpu_certified_quant_bound"
 RANGE_QUERIES = "knn_tpu_range_queries_total"
@@ -325,6 +326,14 @@ CATALOG = {
         "re-scored (ops.refine.rank_correct_runs' members, summed over "
         "the sub-batches of search_certified(selector='pallas') calls): "
         "what a pair slack widens and a wide row makes dear."),
+    REPAIR_QUERIES: (
+        "counter", ("outcome",),
+        "Queries ops.certified.repair_uncertified answered, by what "
+        "settled them: 'proven' (the widened re-select's own exclusion "
+        "value proved the float64 refine exact) or 'host_scan' (the "
+        "unconditional float64 host scan, host_exact_knn).  Their sum is "
+        "the fallback queries; both outcomes exist from the first "
+        "certified call, at 0 where nothing took them."),
     CERTIFIED_QUANT_BOUND: (
         "histogram", (),
         "Per-query int8 certified quantization error bound epsilon "

@@ -519,10 +519,11 @@ def span(name: str, trace_id: Optional[str] = None, **attrs):
 
 
 @contextlib.contextmanager
-def stage(acct, name: str, **attrs):
+def stage(acct, name: str, trace_id: Optional[str] = None, **attrs):
     """One occurrence of a stage that a call closes once a SUB-BATCH: the
     scope of :func:`span` (the current :class:`Span` for code below to
-    ``.set`` on, the ``knn.<name>`` profiler annotation, one an
+    ``.set`` on and, with ``trace_id``, to record its own children
+    under; the ``knn.<name>`` profiler annotation, one an
     occurrence, so a device idle gap is still laid against each
     stretch), but no record of its own.  Its length and its attributes,
     which are all counts or seconds, are ADDED to the call's account
@@ -535,7 +536,7 @@ def stage(acct, name: str, **attrs):
     if not registry.enabled():
         yield NOOP_SPAN
         return
-    sp = Span(name, None, dict(attrs))
+    sp = Span(name, trace_id, dict(attrs))
     token = _CURRENT.set(sp)
     t0 = time.perf_counter()
     try:

@@ -48,6 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from knn_tpu import obs
+from knn_tpu.obs import names as _mn
 from knn_tpu.ops.refine import norms_rows, refine_exact
 from knn_tpu.ops.topk import knn_search_tiled
 
@@ -206,6 +208,56 @@ def _columns_without(idx: np.ndarray, own: np.ndarray) -> np.ndarray:
     return np.sort(keep, axis=1)
 
 
+#: the span a caller holds open around :func:`repair_uncertified`, and
+#: the two phases of the host's half of it: names of their profiler
+#: annotations (``knn.`` before them) and of the spans recorded once
+#: where ``certified.repair`` is recorded once (a call; a block of the
+#: bulk self-join), children of it
+REPAIR_SPAN = "certified.repair"
+PHASE_REFINE = "certified.repair.refine"
+PHASE_HOST_SCAN = "certified.repair.host_scan"
+
+
+def _tell_repair(secs: dict, rows: int, proven: int, scanned: int) -> None:
+    """Record what one :func:`repair_uncertified` did on the host, under
+    the trace id of the span the caller holds open: both phases (0.0
+    where one did not run, so a reader never finds a series missing) and
+    the queries by what settled them."""
+    tid = obs.current_span().trace_id
+    obs.record_span(PHASE_REFINE, tid, secs.get("refine_s", 0.0),
+                    parent=REPAIR_SPAN, rows=rows)
+    obs.record_span(PHASE_HOST_SCAN, tid, secs.get("host_scan_s", 0.0),
+                    parent=REPAIR_SPAN, queries=scanned)
+    obs.counter(_mn.REPAIR_QUERIES, outcome="proven").inc(proven)
+    obs.counter(_mn.REPAIR_QUERIES, outcome="host_scan").inc(scanned)
+
+
+def _host_scan(d, i, k, sb, rank_q, db_np, metric, norms, valid_rows_fn,
+               own) -> None:
+    """Step 3 of :func:`repair_uncertified`: the float64 host scan of the
+    queries ``sb`` (positions in ``d`` / ``i``), written in place, in the
+    form the call takes: ``own`` (a self-join: each query's own row id,
+    scanned for k + 1 and dropped), a filtered call's ``valid_rows_fn``
+    (each query's valid rows alone), or the plain scan."""
+    if own is not None:
+        hd, hi = host_exact_knn(db_np, rank_q[sb], k + 1, metric=metric)
+        left = _columns_without(hi, own)
+        d[sb] = np.take_along_axis(hd, left, axis=1)
+        i[sb] = np.take_along_axis(hi, left, axis=1)
+    elif valid_rows_fn is None:
+        d[sb], i[sb] = host_exact_knn(db_np, rank_q[sb], k, metric=metric,
+                                      norms=norms_rows(norms, sb))
+    else:
+        for pos in sb:
+            rows = valid_rows_fn(pos)
+            d[pos], i[pos] = np.inf, np.iinfo(np.int64).max
+            if rows.size:
+                hd, hi = host_exact_knn(db_np[rows], rank_q[pos][None], k,
+                                        metric=metric)
+                d[pos, : hd.shape[1]] = hd[0]
+                i[pos, : hd.shape[1]] = rows[hi[0]]
+
+
 def repair_uncertified(
     d: np.ndarray,
     i: np.ndarray,
@@ -232,7 +284,10 @@ def repair_uncertified(
     escalation:
 
     1. widened exact-selector re-select (``widen = min(max(2m, m+64),
-       max_widen)``) + float64 refine;
+       max_widen)``), timed by the caller's ``select_fn`` as the span
+       ``certified.repair.reselect``, + float64 refine
+       (``ops.refine.refine_exact``), timed here as the phase
+       ``certified.repair.refine``;
     2. re-certification via the widened selection's own exclusion value:
        every db row NOT selected has f32 score >= the widen-th selected
        score v_w, hence true distance >= v_w - tol — so
@@ -240,10 +295,21 @@ def repair_uncertified(
        database passes (this replaced a count-below pass plus a frequent
        float64 host scan: the count certificate false-alarmed whenever
        any point sat within tol of d_k, which at k=100/1M happens for
-       ~1 query per sweep, each costing ~1s of host scan);
+       ~1 query per sweep, each costing ~1s of host scan); no span of
+       its own: it stays the self time of the caller's
+       ``certified.repair``, with the original indices' copy and the
+       count of genuine misses;
     3. unconditional float64 host scan (:func:`host_exact_knn`) only for
        queries whose k-th/widen-th gap is inside the f32 tolerance
-       (heavy duplicate ties) — structurally rare.
+       (heavy duplicate ties) — structurally rare; timed here as the
+       phase ``certified.repair.host_scan``.
+
+    Both phases are ``knn.<phase>`` profiler annotations and are
+    recorded once a call of this function, 0.0 where one did not run,
+    as children of ``certified.repair`` under the trace id of the span
+    the caller holds open (``obs.current_span()``), with
+    ``knn_tpu_repair_queries_total{outcome}``: ``proven`` by step 2,
+    ``host_scan`` by step 3 (the ``host_exact_queries`` of the stats).
 
     ``metric="dot"`` (inner-product placements, parallel.sharded: rows
     and queries arrive norm-augmented, ``dot_shift`` = M, the largest
@@ -303,18 +369,22 @@ def repair_uncertified(
     for the tuner: it tells it whether to grow the margin
     (misses) or tighten the tolerance (alarms).
     """
+    secs = {}
     if not bad.size:
+        _tell_repair(secs, 0, 0, 0)
         return {"fallback_genuine_misses": 0, "fallback_false_alarms": 0}
     orig_i = i[bad].copy()
     fs, fi = select_fn(q_np[bad], repair_widen(m, max_widen))
     fs = np.asarray(fs, dtype=np.float64)
     fi = np.asarray(fi)
-    if exclude is not None:
-        fi = np.take_along_axis(fi, _columns_without(fi, exclude), axis=1)
     rank_q = q_np if rank_queries is None else rank_queries
-    fd2, fi2 = refine_exact(db_np, rank_q[bad], fi, k, metric,
-                            norms_rows(norms, bad))
-    d[bad], i[bad] = fd2, fi2
+    with obs.trace.phase(secs, "refine_s", PHASE_REFINE):
+        if exclude is not None:
+            fi = np.take_along_axis(fi, _columns_without(fi, exclude),
+                                    axis=1)
+        fd2, fi2 = refine_exact(db_np, rank_q[bad], fi, k, metric,
+                                norms_rows(norms, bad))
+        d[bad], i[bad] = fd2, fi2
     q_norm = (q_np[bad].astype(np.float64) ** 2).sum(-1)
     tol = certification_tolerance(
         q_np[bad], db_np, db_norm_max=db_norm_max, q_norm=q_norm
@@ -331,34 +401,21 @@ def repair_uncertified(
     if valid_rows_fn is not None:
         unproven &= np.isfinite(v_w)
     still = np.flatnonzero(unproven)
-    host_exact = 0
     if still.size:
         sb = bad[still]
-        if exclude is not None:
-            hd, hi = host_exact_knn(db_np, rank_q[sb], k + 1, metric=metric)
-            left = _columns_without(hi, exclude[still])
-            d[sb] = np.take_along_axis(hd, left, axis=1)
-            i[sb] = np.take_along_axis(hi, left, axis=1)
-        elif valid_rows_fn is None:
-            d[sb], i[sb] = host_exact_knn(db_np, rank_q[sb], k, metric=metric,
-                                          norms=norms_rows(norms, sb))
-        else:
-            for pos in sb:
-                rows = valid_rows_fn(pos)
-                d[pos], i[pos] = np.inf, np.iinfo(np.int64).max
-                if rows.size:
-                    hd, hi = host_exact_knn(db_np[rows], rank_q[pos][None], k,
-                                            metric=metric)
-                    d[pos, : hd.shape[1]] = hd[0]
-                    i[pos, : hd.shape[1]] = rows[hi[0]]
-        host_exact = int(sb.size)
+        own = None if exclude is None else exclude[still]
+        with obs.trace.phase(secs, "host_scan_s", PHASE_HOST_SCAN):
+            _host_scan(d, i, k, sb, rank_q, db_np, metric, norms,
+                       valid_rows_fn, own)
+    n_bad, host_exact = int(bad.size), int(still.size)
     genuine = int((i[bad] != orig_i).any(axis=-1).sum())
     out = {
         "fallback_genuine_misses": genuine,
-        "fallback_false_alarms": int(bad.size) - genuine,
+        "fallback_false_alarms": n_bad - genuine,
     }
     if host_exact:
         out["host_exact_queries"] = host_exact
+    _tell_repair(secs, int(fi.size), n_bad - host_exact, host_exact)
     return out
 
 

@@ -510,8 +510,23 @@ _RANGE_SPAN = "certified.range_call"
 #: what a metric other than l2 adds to a call on either side of the l2
 #: machinery: ONE span a call, the sum of both sides (inner product: the
 #: zero column before, the float64 scores after; cosine: the batch's
-#: float64 norms and its float32 unit rows before, nothing after)
+#: float64 norms and its float32 unit rows before, nothing after), and
+#: each side as a span and a profiler annotation of its own: only what
+#: lies BEFORE the first launch can go under it
 _METRIC_SPAN = "certified.metric_map"
+_METRIC_BEFORE = "certified.metric_map.before"
+_METRIC_AFTER = "certified.metric_map.after"
+#: the completion of a range call's truncated queries, and where its host
+#: time goes: the phases are summed over the completion's sub-batches by
+#: the call's account and recorded once a call, children of the span
+_RANGE_COMPLETE_SPAN = "certified.range_complete"
+_RANGE_WAIT = "certified.range_complete.wait"
+_RANGE_DECODE = "certified.range_complete.decode"
+_RANGE_SCORE = "certified.range_complete.score"
+_RANGE_HOST_SCAN = "certified.range_complete.host_scan"
+_RANGE_ORDER = "certified.range_complete.order"
+_RANGE_PHASES = (_RANGE_WAIT, _RANGE_DECODE, _RANGE_SCORE, _RANGE_HOST_SCAN,
+                 _RANGE_ORDER)
 
 
 #: root of the once-a-call records of a call's account
@@ -556,14 +571,32 @@ def _call_account(selector: str, *more: str, voted: bool = False):
     it is the first pass of), and the pieces it sums (a ``voted`` call's
     ``certified.vote_repair`` among its stages: it records every stage
     of the search's too, at 0.0 where it has none, so a reader never
-    finds a series missing)."""
+    finds a series missing; a range call's completion phases, children
+    of ``certified.range_complete``, likewise)."""
+    stages = (dict.fromkeys(_RANGE_PHASES, _RANGE_COMPLETE_SPAN)
+              if "range" in more else {})
     if selector == "pallas":
-        stages = _PALLAS_STAGES + ((_VOTE_STAGE,) if voted else ())
+        stages.update(dict.fromkeys(
+            _PALLAS_STAGES + ((_VOTE_STAGE,) if voted else ()), _CALL_SPAN))
         return obs.trace.call_account(
             _ACCOUNT_ROOT, ("certified", "reselect") + more, _PALLAS_PIECES,
-            dict.fromkeys(stages, _CALL_SPAN))
+            stages)
     return obs.trace.call_account(
-        _ACCOUNT_ROOT, ("counted", "count", "reselect") + more)
+        _ACCOUNT_ROOT, ("counted", "count", "reselect") + more,
+        stages=stages)
+
+
+def _record_metric_map(trace_id, metric: str, map_s: dict) -> None:
+    """A dot, cosine or voted call's ``certified.metric_map``: the sum of
+    both sides, a child of the call, and each side (``before_s``,
+    ``after_s`` of ``map_s``) as a child of the sum, 0.0 where a metric
+    has nothing on that side."""
+    obs.record_span(_METRIC_SPAN, trace_id, sum(map_s.values()),
+                    parent=_CALL_SPAN, metric=metric, **map_s)
+    obs.record_span(_METRIC_BEFORE, trace_id, map_s["before_s"],
+                    parent=_METRIC_SPAN, metric=metric)
+    obs.record_span(_METRIC_AFTER, trace_id, map_s["after_s"],
+                    parent=_METRIC_SPAN, metric=metric)
 
 
 def _staged_fetch(acct=obs.trace.NOOP_ACCOUNT):
@@ -2179,7 +2212,7 @@ class ShardedKNN:
                 ft = tagfilter.check_filter_tags(filter_tags, q_np.shape[0])
             map_s = {"before_s": 0.0, "after_s": 0.0}
             if dot:
-                with obs.trace.phase(map_s, "before_s", _METRIC_SPAN):
+                with obs.trace.phase(map_s, "before_s", _METRIC_BEFORE):
                     # the zero column matching the placed rows'
                     # augmentation
                     q_np = np.concatenate(
@@ -2189,7 +2222,7 @@ class ShardedKNN:
             # float64 norms on both sides: (queries', rows')
             host_q, norms = q_np, None
             if cosine:
-                with obs.trace.phase(map_s, "before_s", _METRIC_SPAN):
+                with obs.trace.phase(map_s, "before_s", _METRIC_BEFORE):
                     # the unit queries matching the placed unit rows
                     q_np, q_norms, _, _ = _unit_rows(host_q)
                     norms = (q_norms, self._cos_norms)
@@ -2542,12 +2575,10 @@ class ShardedKNN:
                 # of nearly equal numbers) is involved.
                 from knn_tpu.ops.refine import exact_scores
 
-                with obs.trace.phase(map_s, "after_s", _METRIC_SPAN):
+                with obs.trace.phase(map_s, "after_s", _METRIC_AFTER):
                     d = exact_scores(db_np, q_np, i, "dot")
             if dot or cosine:
-                obs.record_span(_METRIC_SPAN, tid, sum(map_s.values()),
-                                parent=_CALL_SPAN, metric=self.metric,
-                                **map_s)
+                _record_metric_map(tid, self.metric, map_s)
             if return_distances and return_sqrt:
                 # true Euclidean values (knn_mpi.cpp:48 / sklearn
                 # convention); indices and certification are unaffected
@@ -2760,51 +2791,63 @@ class ShardedKNN:
         done = {"width": width if tq.size else 0, "sub_batches": 0,
                 "host_scan": 0}
         found = []  # (query positions, db rows, float64 distances)
+        secs = {}  # the phases' seconds, summed over the sub-batches
         for lo in range(0, tq.size, RANGE_SUB_BATCH):
             sub = tq[lo:lo + RANGE_SUB_BATCH]
             counts, compact = first if lo == 0 else self._range_launch(
                 q_np, db_np, sub, radius_sq, acct, trace_id)
             done["sub_batches"] += 1
-            counts = np.asarray(counts)  # the host waits here
-            acct.ready("range")
-            # a query that marks no more rows than the width marks no
-            # more words than it on any shard
-            over = counts[:sub.size] > width
+            with obs.trace.phase(secs, _RANGE_WAIT, _RANGE_WAIT):
+                counts = np.asarray(counts)  # the host waits here
+                acct.ready("range")
+                # a query that marks no more rows than the width marks no
+                # more words than it on any shard
+                over = counts[:sub.size] > width
+                if not over.all():
+                    compact = np.asarray(compact)
             if not over.all():
-                compact = np.asarray(compact)
-                per = compact.shape[2] // shards
-                qs, ts = [], []
-                for s in range(shards):
-                    qi, ri = decode_words(
-                        compact[:, :, s * per:(s + 1) * per], shard_rows,
-                        tile)
-                    qs.append(qi)
-                    ts.append(ri + s * shard_rows)
-                qi, ti = np.concatenate(qs), np.concatenate(ts)
-                sel = ~over[qi]
-                qi, ti = sub[qi[sel]], ti[sel]
-                dd = exact_pair_scores(db_np, q_np, qi, ti)
-                sel = dd <= radius_sq
-                found.append((qi[sel], ti[sel], dd[sel]))
+                with obs.trace.phase(secs, _RANGE_DECODE, _RANGE_DECODE):
+                    per = compact.shape[2] // shards
+                    qs, ts = [], []
+                    for s in range(shards):
+                        qi, ri = decode_words(
+                            compact[:, :, s * per:(s + 1) * per], shard_rows,
+                            tile)
+                        qs.append(qi)
+                        ts.append(ri + s * shard_rows)
+                    qi, ti = np.concatenate(qs), np.concatenate(ts)
+                with obs.trace.phase(secs, _RANGE_SCORE, _RANGE_SCORE):
+                    sel = ~over[qi]
+                    qi, ti = sub[qi[sel]], ti[sel]
+                    dd = exact_pair_scores(db_np, q_np, qi, ti)
+                    sel = dd <= radius_sq
+                    found.append((qi[sel], ti[sel], dd[sel]))
             if over.any():
                 # last resort: more marked rows than the width holds
                 hq = sub[over]
                 done["host_scan"] += int(hq.size)
-                qi, ti, dd = host_exact_range(db_np, q_np[hq], radius_sq)
-                found.append((hq[qi], ti, dd))
+                with obs.trace.phase(secs, _RANGE_HOST_SCAN,
+                                     _RANGE_HOST_SCAN):
+                    qi, ti, dd = host_exact_range(db_np, q_np[hq], radius_sq)
+                    found.append((hq[qi], ti, dd))
         if not found:
-            return (np.empty(0, np.int64), np.empty(0, np.int64),
-                    np.empty(0), done)
-        cq, ci, cd = (np.concatenate(x) for x in zip(*found))
-        ci = ci.astype(np.int64)
-        # (distance, index) order within each query: one short sort a
-        # query, cheaper than one three-key sort over all of them
-        order = np.argsort(cq, kind="stable")
-        cq, ci, cd = cq[order], ci[order], cd[order]
-        starts = np.flatnonzero(np.diff(cq, prepend=-1))
-        for a, b in zip(starts, np.append(starts[1:], cq.size)):
-            seg = np.lexsort((ci[a:b], cd[a:b]))
-            ci[a:b], cd[a:b] = ci[a:b][seg], cd[a:b][seg]
+            cq = ci = np.empty(0, np.int64)
+            cd = np.empty(0)
+        else:
+            with obs.trace.phase(secs, _RANGE_ORDER, _RANGE_ORDER):
+                cq, ci, cd = (np.concatenate(x) for x in zip(*found))
+                ci = ci.astype(np.int64)
+                # (distance, index) order within each query: one short
+                # sort a query, cheaper than one three-key sort over all
+                # of them
+                order = np.argsort(cq, kind="stable")
+                cq, ci, cd = cq[order], ci[order], cd[order]
+                starts = np.flatnonzero(np.diff(cq, prepend=-1))
+                for a, b in zip(starts, np.append(starts[1:], cq.size)):
+                    seg = np.lexsort((ci[a:b], cd[a:b]))
+                    ci[a:b], cd[a:b] = ci[a:b][seg], cd[a:b][seg]
+        for name, seconds in secs.items():
+            acct.add(name, seconds)
         return cq, ci, cd, done
 
     def _certify_counted(
@@ -3497,7 +3540,7 @@ class ShardedKNN:
             acct = _call_account("pallas", voted=True)
             host_q = np.asarray(queries, dtype=np.float32)
             map_s = {"before_s": 0.0, "after_s": 0.0}
-            with obs.trace.phase(map_s, "before_s", _METRIC_SPAN):
+            with obs.trace.phase(map_s, "before_s", _METRIC_BEFORE):
                 # the unit queries matching the placed unit rows
                 q_np, q_norms, _, _ = _unit_rows(host_q)
                 norms = (q_norms, self._cos_norms)
@@ -3649,8 +3692,7 @@ class ShardedKNN:
                     ("uncertified_by_slack", n_by_slack)):
                 obs.counter(_mn.CERTIFIED_SLACK_QUERIES,
                             outcome=outcome).inc(n_out)
-            obs.record_span(_METRIC_SPAN, tid, sum(map_s.values()),
-                            parent=_CALL_SPAN, metric=self.metric, **map_s)
+            _record_metric_map(tid, self.metric, map_s)
             acct.close(tid, _CALL_SPAN)
             return classes, totals, stats
 
@@ -3987,28 +4029,29 @@ class _SelfJoinCall:
         from knn_tpu.ops.certified import repair_uncertified
 
         n_bad = int(blk.flagged.size)
-        if n_bad:
-            with obs.trace.stage(blk.acct, "certified.repair") as sp:
-                fs, fi = [], []
-                for j, (ps, pi) in enumerate(blk.reselects):
-                    rows = min(_SELF_RESELECT_ROWS,
-                               n_bad - j * _SELF_RESELECT_ROWS)
-                    fs.append(np.asarray(ps)[:rows])
-                    self.acct.ready("reselect")
-                    fi.append(np.asarray(pi)[:rows])
-                repair = repair_uncertified(
-                    self.d, self.i, self.knn.k, self.m, blk.flagged,
-                    self.db[self.lo : self.hi], self.db,
-                    select_fn=lambda qb, widen: (
-                        np.concatenate(fs), np.concatenate(fi)),
-                    max_widen=self.max_widen, db_norm_max=self.db_norm_max,
-                    exclude=self.lo + blk.flagged)
-                sp.set("host_exact_queries",
-                       repair.get("host_exact_queries", 0))
-            blk.reselects = []
-            self.told["fallback_queries"] += n_bad
-            for key, value in repair.items():
-                self.told[key] += value
+        # every block, flagged rows or none: the repair records its
+        # phases once a block, at 0.0 where it had nothing to do
+        with obs.trace.stage(blk.acct, "certified.repair", self.tid) as sp:
+            fs, fi = [], []
+            for j, (ps, pi) in enumerate(blk.reselects):
+                rows = min(_SELF_RESELECT_ROWS,
+                           n_bad - j * _SELF_RESELECT_ROWS)
+                fs.append(np.asarray(ps)[:rows])
+                self.acct.ready("reselect")
+                fi.append(np.asarray(pi)[:rows])
+            repair = repair_uncertified(
+                self.d, self.i, self.knn.k, self.m, blk.flagged,
+                self.db[self.lo : self.hi], self.db,
+                select_fn=lambda qb, widen: (
+                    np.concatenate(fs), np.concatenate(fi)),
+                max_widen=self.max_widen, db_norm_max=self.db_norm_max,
+                exclude=self.lo + blk.flagged)
+            sp.set("host_exact_queries",
+                   repair.get("host_exact_queries", 0))
+        blk.reselects = []
+        self.told["fallback_queries"] += n_bad
+        for key, value in repair.items():
+            self.told[key] += value
         blk.acct.close(self.tid, _BLOCK_SPAN)
         obs.record_span(_BLOCK_SPAN, self.tid,
                         time.perf_counter() - blk.t0, lo=blk.lo,

@@ -6,7 +6,8 @@ tracing (docs/OBSERVABILITY.md "Span lifecycle"), in three sub-commands:
     python scripts/certified_stage_report.py startup <events.jsonl> [--run-log <the run's output>]
     python scripts/certified_stage_report.py idle <trace dir or .xplane.pb> [--device-plane /device:TPU:N]
 
-``spans`` reads a ``KNN_TPU_OBS_LOG`` file: per ``certified.*`` stage
+``spans`` reads a ``KNN_TPU_OBS_LOG`` file: per ``certified.*`` stage,
+and per phase of one right after it with the ``parent`` it names,
 the mean ms a call and a batch (a stage that closes once a sub-batch is
 ONE record a call, the sum of its scopes: ``spans`` counts calls, and
 ``per_batch`` is that sum over the call's launches), the self time of
@@ -90,6 +91,7 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
                          f"{skip_calls} of {len(order)}")
     total: Dict[str, float] = defaultdict(float)
     count: Dict[str, int] = defaultdict(int)
+    parent: Dict[str, str] = {}
     attrs: Dict[str, float] = defaultdict(float)
     account: Dict[str, Dict[str, float]] = defaultdict(
         lambda: defaultdict(float))
@@ -111,6 +113,8 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
                 continue
             total[e["span"]] += e["dur_s"]
             count[e["span"]] += 1
+            if "parent" in e:
+                parent[e["span"]] = e["parent"]
             if e["span"] == CALL:
                 batches += e.get("batches", 1)
                 # which cross-shard merge answered and what it moved
@@ -135,9 +139,11 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
     return {
         "calls": n, "batches": batches,
         "stages_ms": {
+            # sorted by name: a phase's row follows its parent's
             name: {"per_call": ms(total[name] / n),
                    "per_batch": ms(total[name] / batches),
-                   "spans": count[name]}
+                   "spans": count[name],
+                   **({"parent": parent[name]} if name in parent else {})}
             for name in sorted(total)},
         "account_ms": {
             name: {k: round(v / n, 4) for k, v in row.items()}

@@ -89,6 +89,10 @@ ONCE_A_CALL = ("certified.exposed", "certified.inflight.certified",
                "certified.rank_correct.buffers",
                "certified.rank_correct.score",
                "certified.rank_correct.order", "certified.unpack.copies")
+#: the repair's host half (ops.certified.repair_uncertified): one record
+#: each a call, children of ``certified.repair``, 0.0 and no profiler
+#: annotation where no query fell back
+REPAIR_PHASES = ("certified.repair.refine", "certified.repair.host_scan")
 PHASES = {"knn.certified.rank_correct.buffers",
           "knn.certified.rank_correct.score",
           "knn.certified.rank_correct.order"}
@@ -101,7 +105,7 @@ def _expected(batches: int = 1, reselects: int = 0) -> Counter:
                     "certified.repair": 1})
     for name in PER_BATCH:
         want[name] = batches
-    for name in ONCE_A_CALL:
+    for name in ONCE_A_CALL + REPAIR_PHASES:
         want[name] = 1
     if reselects:
         want["certified.repair.reselect"] = reselects
@@ -128,8 +132,11 @@ def test_a_certified_call_emits_exactly_its_stage_spans(placed, corpus, kw,
     assert (call["selector"], call["queries"], call["batches"]) == (
         "pallas", N_QUERIES, batches)
     children = [e for e in spans if e["span"] != "certified.call"
-                and e["span"] not in ONCE_A_CALL]
+                and e["span"] not in ONCE_A_CALL + REPAIR_PHASES]
     assert {e["parent"] for e in children} == {"certified.call"}
+    # the repair's phases are its own children, at 0.0 with no fallback
+    assert {(by[name]["parent"], by[name]["dur_s"])
+            for name in REPAIR_PHASES} == {("certified.repair", 0.0)}
     # the account's records are sums over the call, not children of it
     account = [e for e in spans if e["span"] in ONCE_A_CALL]
     assert {e["account_of"] for e in account} == {"certified.call"}
@@ -257,7 +264,7 @@ def test_a_live_profile_holds_the_stage_annotations_nested(placed, corpus,
     # measured after the fact and make none, but rank_correct's three
     # phases each do, inside their stage, so that an idle gap under
     # knn.certified.rank_correct splits by itself
-    scoped = _expected(3) - Counter(ONCE_A_CALL)
+    scoped = _expected(3) - Counter(ONCE_A_CALL + REPAIR_PHASES)
     assert Counter(n for n, _, _ in inner if n not in PHASES) == Counter(
         {f"knn.{name}": c for name, c in scoped.items()})
     (call,) = [e for e in inner if e[0] == "knn.certified.call"]
@@ -340,6 +347,13 @@ def test_the_stage_report_reads_the_jsonl_log(placed, corpus, report,
     assert d2h["spans"] == 2
     assert d2h["per_batch"] == pytest.approx(d2h["per_call"] / 3, abs=1e-4)
     assert table["stages_ms"]["certified.call"]["spans"] == 2
+    # a phase is a row right after its parent's, and names it
+    names = list(table["stages_ms"])
+    for phase in REPAIR_PHASES:
+        row = table["stages_ms"][phase]
+        assert (row["parent"], row["spans"]) == ("certified.repair", 2)
+        assert names.index(phase) > names.index("certified.repair")
+    assert "parent" not in table["stages_ms"]["certified.call"]
     assert 0 < table["children_share_of_call"] <= 1
     assert table["call_self_ms_per_call"] >= 0
     assert table["per_batch"]["h2d_bytes"] == 32 * 32 * 4
