@@ -77,6 +77,7 @@ CERTIFIED_METRIC_QUERIES = "knn_tpu_certified_metric_queries_total"
 CERTIFIED_SLACK_QUERIES = "knn_tpu_certified_slack_queries_total"
 RANK_CORRECT_MEMBERS = "knn_tpu_rank_correct_members_total"
 REPAIR_QUERIES = "knn_tpu_repair_queries_total"
+REPAIR_REFINE_ROWS = "knn_tpu_repair_refine_rows_total"
 VOTE_QUERIES = "knn_tpu_vote_queries_total"
 CERTIFIED_QUANT_BOUND = "knn_tpu_certified_quant_bound"
 RANGE_QUERIES = "knn_tpu_range_queries_total"
@@ -334,6 +335,18 @@ CATALOG = {
         "unconditional float64 host scan, host_exact_knn).  Their sum is "
         "the fallback queries; both outcomes exist from the first "
         "certified call, at 0 where nothing took them."),
+    REPAIR_REFINE_ROWS: (
+        "counter", ("outcome",),
+        "Candidates the widened re-select handed "
+        "ops.certified.repair_uncertified (flagged queries x the width "
+        "left after a self-join's own row went), by what the float64 "
+        "refine did with them: 'refined' (gathered from the host rows "
+        "and scored: the 'rows' of the span certified.repair.refine) or "
+        "'thinned' (past the longest prefix whose float32 score can "
+        "still reach the top-k, twice the certificate's tolerance over "
+        "the k-th: never gathered).  Their sum is the span's "
+        "'selected'; both outcomes exist from the first certified call, "
+        "at 0 where nothing took them."),
     CERTIFIED_QUANT_BOUND: (
         "histogram", (),
         "Per-query int8 certified quantization error bound epsilon "

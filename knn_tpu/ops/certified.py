@@ -218,18 +218,62 @@ PHASE_REFINE = "certified.repair.refine"
 PHASE_HOST_SCAN = "certified.repair.host_scan"
 
 
-def _tell_repair(secs: dict, rows: int, proven: int, scanned: int) -> None:
+def _tell_repair(secs: dict, rows: int, selected: int, proven: int,
+                 scanned: int) -> None:
     """Record what one :func:`repair_uncertified` did on the host, under
     the trace id of the span the caller holds open: both phases (0.0
-    where one did not run, so a reader never finds a series missing) and
-    the queries by what settled them."""
+    where one did not run, so a reader never finds a series missing),
+    the candidates the re-select handed over (``selected``) by whether
+    the refine gathered them (``rows``) or :func:`_within_reach` left
+    them out, and the queries by what settled them."""
     tid = obs.current_span().trace_id
     obs.record_span(PHASE_REFINE, tid, secs.get("refine_s", 0.0),
-                    parent=REPAIR_SPAN, rows=rows)
+                    parent=REPAIR_SPAN, rows=rows, selected=selected)
     obs.record_span(PHASE_HOST_SCAN, tid, secs.get("host_scan_s", 0.0),
                     parent=REPAIR_SPAN, queries=scanned)
+    obs.counter(_mn.REPAIR_REFINE_ROWS, outcome="refined").inc(rows)
+    obs.counter(_mn.REPAIR_REFINE_ROWS, outcome="thinned").inc(
+        selected - rows)
     obs.counter(_mn.REPAIR_QUERIES, outcome="proven").inc(proven)
     obs.counter(_mn.REPAIR_QUERIES, outcome="host_scan").inc(scanned)
+
+
+#: how many tolerances past the k-th float32 score a candidate of the
+#: widened re-select can lie and still rank among the exact top-k: one
+#: for its own score's error, one for the k-th's (the proof is step 1's
+#: in :func:`repair_uncertified`).  The proof's factor, not a knob: the
+#: tests set it to +inf (every candidate refined: the path before the
+#: thinning) and to 0 (the float32 order trusted: wrong answers)
+_REACH_TOLS = 2.0
+
+
+def _within_reach(fs: np.ndarray, fi: np.ndarray, k: int, tol: np.ndarray,
+                  exclude: Optional[np.ndarray], norms) -> np.ndarray:
+    """The candidates ``[B, keep]`` of the widened re-select (``fs``
+    ascending float32 scores, ``fi`` their row ids, both ``[B, widen]``)
+    that the float64 refine has to score, ``k <= keep <= widen``: a
+    self-join's own row (``exclude``) dropped by id first, then the
+    longest prefix, over the call's queries, of candidates whose score
+    lies within ``_REACH_TOLS * tol`` (``tol`` ``[B]``, the pair slack
+    in it) of the k-th left.  Every candidate past it is provably
+    outside its query's exact top-k (:func:`repair_uncertified`, step
+    1).  A k-th score that is not finite (a filtered query whose valid
+    rows ran out) puts nothing past reach: the comparison is false.
+    ``norms`` (a cosine call's ``(query norms, row norms)``, else None):
+    a row of zero norm is selected at half its cosine distance, so the
+    k-th score is taken among the candidates of nonzero norm."""
+    if exclude is not None:
+        left = _columns_without(fi, exclude)
+        fs = np.take_along_axis(fs, left, axis=1)
+        fi = np.take_along_axis(fi, left, axis=1)
+    ranked = fs
+    if norms is not None:
+        ranked = np.sort(np.where(
+            norms[1][np.minimum(fi, norms[1].size - 1)] > 0, fs, np.inf),
+            axis=1)
+    t_k = ranked[:, min(k, fs.shape[1]) - 1]
+    reach = ~(fs > (t_k + _REACH_TOLS * tol)[:, None])
+    return fi[:, : max(k, int(np.flatnonzero(reach.any(axis=0))[-1]) + 1)]
 
 
 def _host_scan(d, i, k, sb, rank_q, db_np, metric, norms, valid_rows_fn,
@@ -286,8 +330,21 @@ def repair_uncertified(
     1. widened exact-selector re-select (``widen = min(max(2m, m+64),
        max_widen)``), timed by the caller's ``select_fn`` as the span
        ``certified.repair.reselect``, + float64 refine
-       (``ops.refine.refine_exact``), timed here as the phase
-       ``certified.repair.refine``;
+       (``ops.refine.refine_exact``) of the candidates whose float32
+       score can still reach the top-k (:func:`_within_reach`), timed
+       here as the phase ``certified.repair.refine``: with the scores
+       ``fs`` ascending, ``t_k`` the k-th of them and tol the bound on
+       a score's error that step 2 uses, a candidate j with ``fs[j] >
+       t_k + 2 tol`` is out before a row is gathered.  The k first
+       candidates are k distinct rows of true distance <= fs + tol <=
+       t_k + tol; j has true distance >= fs[j] - tol, strictly over
+       all k of them; so k rows rank strictly before j in (distance,
+       index) order whatever the indices, and the refine's top-k over
+       the candidates kept is its top-k over all of them, value for
+       value (each pair's float64 sum is its own).  The refine takes
+       the longest kept prefix over the call's flagged queries, one
+       rectangular slice; equal scores (dense ties) and a k-th score
+       that is not finite keep everything;
     2. re-certification via the widened selection's own exclusion value:
        every db row NOT selected has f32 score >= the widen-th selected
        score v_w, hence true distance >= v_w - tol — so
@@ -322,7 +379,11 @@ def repair_uncertified(
     step 2 compares there: a row NOT selected has D'(u) >= v_w - tol,
     hence 2 s(u) >= v_w - tol - pair_slack / 2 - |q|^2 - M, and
     ``|q|^2 + M + 2 s_k + tol + pair_slack < v_w`` proves s(u) > s_k
-    with pair_slack / 2 to spare.
+    with pair_slack / 2 to spare.  Step 1's band there: a first-k
+    candidate has 2 s <= t_k + tol + pair_slack / 2 - |q|^2 - M, j has
+    2 s(j) >= fs[j] - tol - pair_slack / 2 - |q|^2 - M, so ``fs[j] >
+    t_k + 2 tol + pair_slack`` puts j out, and the band taken, 2 (tol +
+    pair_slack), covers it.
 
     ``metric="cosine"`` (cosine placements: ``q_np`` holds the float32
     UNIT queries the selection runs on, ``rank_queries`` the queries AS
@@ -334,10 +395,18 @@ def repair_uncertified(
     ``pair_slack`` (the normalisation's float32 rounding on both sides,
     parallel.sharded.COS_UNIT_SLACK).  A row NOT selected has D'(u) >=
     v_w - tol, hence 2 c(u) > v_w - tol - pair_slack, and ``2 c_k + tol
-    + pair_slack < v_w`` proves c(u) > c_k.  A row of zero norm is
+    + pair_slack < v_w`` proves c(u) > c_k.  Step 1's band there: a
+    first-k candidate has 2 c < t_k + tol + pair_slack, j has 2 c(j) >
+    fs[j] - tol - pair_slack, so ``fs[j] > t_k + 2 (tol + pair_slack)``
+    puts j out: the band taken.  A row of zero norm is
     placed as it is, at D' = |q^|^2 <= 1 + 2^-22, and has c = 1: left
     out of a selection that this inequality proves, it has v_w <= D' +
-    tol and so 2 c_k < 1 + 2^-22, c_k < c.
+    tol and so 2 c_k < 1 + 2^-22, c_k < c.  SELECTED, it stands at half
+    its cosine distance, so step 1 takes ``t_k`` among the candidates
+    of nonzero norm (``norms``, which a cosine call therefore hands
+    over): those k rows have 2 c < t_k + tol + pair_slack as before,
+    and a zero row past the band has fs <= 1 + 2^-22 + tol, hence all k
+    of them 2 c < 2 = twice its own.
 
     ``valid_rows_fn(position) -> ascending row ids`` (a filtered call,
     parallel.sharded: ``select_fn`` then selects among each query's
@@ -346,14 +415,18 @@ def repair_uncertified(
     row, so nothing is excluded and step 2 proves the repair whatever
     the k-th distance, +inf included; step 3's scan reads the query's
     valid rows alone (:func:`host_exact_knn` over that gather) and pads
-    a short answer with +inf and the int64 sentinel.
+    a short answer with +inf and the int64 sentinel.  Step 1 there: a
+    ``t_k`` of +inf (fewer than k valid rows) keeps every candidate; a
+    finite one drops the +inf sentinels past reach, which rank last in
+    the refine anyway.
 
     ``exclude`` (int ``[B]``, a self-join: the row each flagged query
     IS, parallel.sharded's ``_SelfJoinCall``) takes that one row out by
     id, never by distance: it is dropped from the widened selection
     before the refine (``select_fn`` selected among all rows, so the
     ``widen``-th score still bounds every row left out, and step 2 is
-    the inequality it was over one candidate fewer), and step 3 scans
+    the inequality it was over one candidate fewer; step 1's ``t_k`` is
+    the k-th score of the candidates left), and step 3 scans
     for k + 1 and drops it there (or the last, where k + 1 rows at the
     same distance come before it in index order).  An exact copy of the
     query stays, at distance 0.
@@ -369,33 +442,32 @@ def repair_uncertified(
     for the tuner: it tells it whether to grow the margin
     (misses) or tighten the tolerance (alarms).
     """
+    if metric == "cosine" and norms is None:
+        raise ValueError("a cosine repair needs the rows' float64 norms")
     secs = {}
     if not bad.size:
-        _tell_repair(secs, 0, 0, 0)
+        _tell_repair(secs, 0, 0, 0, 0)
         return {"fallback_genuine_misses": 0, "fallback_false_alarms": 0}
     orig_i = i[bad].copy()
     fs, fi = select_fn(q_np[bad], repair_widen(m, max_widen))
     fs = np.asarray(fs, dtype=np.float64)
     fi = np.asarray(fi)
     rank_q = q_np if rank_queries is None else rank_queries
-    with obs.trace.phase(secs, "refine_s", PHASE_REFINE):
-        if exclude is not None:
-            fi = np.take_along_axis(fi, _columns_without(fi, exclude),
-                                    axis=1)
-        fd2, fi2 = refine_exact(db_np, rank_q[bad], fi, k, metric,
-                                norms_rows(norms, bad))
-        d[bad], i[bad] = fd2, fi2
     q_norm = (q_np[bad].astype(np.float64) ** 2).sum(-1)
     tol = certification_tolerance(
         q_np[bad], db_np, db_norm_max=db_norm_max, q_norm=q_norm
-    )
+    ) + pair_slack
+    with obs.trace.phase(secs, "refine_s", PHASE_REFINE):
+        fi = _within_reach(fs, fi, k, tol, exclude, norms)
+        fd2, fi2 = refine_exact(db_np, rank_q[bad], fi, k, metric,
+                                norms_rows(norms, bad))
+        d[bad], i[bad] = fd2, fi2
     # the k-th value in the selection's own space (docstring)
     d_k = fd2[:, k - 1]
     if metric == "dot":
         d_k = q_norm + dot_shift + 2.0 * d_k
     elif metric == "cosine":
         d_k = 2.0 * d_k
-    tol = tol + pair_slack
     v_w = fs[:, -1]  # exclusion value of the widened f32 selection
     unproven = d_k + tol >= v_w
     if valid_rows_fn is not None:
@@ -415,7 +487,9 @@ def repair_uncertified(
     }
     if host_exact:
         out["host_exact_queries"] = host_exact
-    _tell_repair(secs, int(fi.size), n_bad - host_exact, host_exact)
+    _tell_repair(secs, int(fi.size),
+                 n_bad * (fs.shape[1] - (exclude is not None)),
+                 n_bad - host_exact, host_exact)
     return out
 
 

@@ -687,7 +687,9 @@ ACCOUNT = {"host_exposed_ms", "reselect_inflight_ms", "rank_score_ms",
 LISTED = STAGES | ACCOUNT | {
     "kernel_ms", "pallas_knn_roofline", "tail_ms", "fallback_pct",
     "rank_corrected_pct", "idle_pct.sweep", "rank_members_per_query",
-    "slack_fallback_pct"}
+    "slack_fallback_pct",
+    # listed since PR 54 (its span is PR 53's: every parent records it)
+    "repair_refine_ms"}
 #: still held: the accepted entry with this cell appended is an edit
 NEW = {"metric_map_ms"}
 

@@ -11,6 +11,10 @@ work happens (docs/OBSERVABILITY.md "The certified call"):
 - both sides of ``certified.metric_map`` are series and annotations of
   their own beside the sum.
 
+And what the refine gathers since PR 54: of the widened re-select's
+candidates only those whose float32 score can still reach the top-k
+(``ops.certified._within_reach``), the answers the arrays they were.
+
 CPU, Pallas interpreted, tiny corpora: what is checked is which records
 exist, whose children they are and that they close on their parents,
 never how long one took.
@@ -28,7 +32,7 @@ if HERE not in sys.path:
     sys.path.insert(0, HERE)
 
 from test_certified_spans import _CountingAnnotation  # noqa: E402  (tests/)
-from test_yfcc_filter import csr  # noqa: E402  (tests/)
+from test_yfcc_filter import csr, every_query_flagged  # noqa: E402,F401  (tests/)
 
 from knn_tpu import obs, tuning  # noqa: E402
 from knn_tpu.join import engine, knn_self_join  # noqa: E402
@@ -46,15 +50,19 @@ RANGE_PHASES = tuple(f"certified.range_complete.{p}" for p in (
 
 @pytest.fixture(autouse=True)
 def _fresh_registry():
-    obs.reset(enabled=True)
-    obs.reset_event_log(None)
+    _fresh()
     yield
     obs.reset()
     obs.reset_event_log(from_env=True)
 
 
-def mesh():
-    return make_mesh(1, 1, devices=jax.devices()[:1])
+def mesh(shards=1):
+    return make_mesh(1, shards, devices=jax.devices()[:shards])
+
+
+def _fresh():
+    obs.reset(enabled=True)
+    obs.reset_event_log(None)
 
 
 def spans(name=None, prefix="certified."):
@@ -63,56 +71,71 @@ def spans(name=None, prefix="certified."):
                 e["span"] == name if name else e["span"].startswith(prefix))]
 
 
-def repair_outcomes() -> dict:
-    series = obs.snapshot().get(mn.REPAIR_QUERIES, {"series": []})["series"]
+def outcomes(name) -> dict:
+    series = obs.snapshot().get(name, {"series": []})["series"]
     return {s["labels"]["outcome"]: s["value"] for s in series}
 
 
-def tied_rows(seed=6, dim=16):
+def repair_outcomes() -> dict:
+    return outcomes(mn.REPAIR_QUERIES)
+
+
+#: which of tied_rows' eight families a call's queries lie near: two of
+#: either kind, or the four whose copies the widened re-select holds
+#: whole (a gap after the 40th candidate: the refine has rows to leave)
+MIXED, GAPPED = (0, 1, 4, 5), (4, 5, 6, 7)
+
+
+def tied_rows(seed=6, dim=16, near=MIXED):
     """Eight rows, four of them repeated 128 times (more copies than the
     widened re-select holds: its own bound proves nothing and the host
     scans) and four 40 times (past the analysis window and inside the
     widened selection: flagged, then proven), and a query near each of
-    two of either kind."""
+    the families ``near``."""
     rng = np.random.default_rng(seed)
     base = rng.normal(size=(8, dim)).astype(np.float32)
     base *= rng.uniform(0.5, 2.0, size=(8, 1)).astype(np.float32)
     db = np.concatenate([np.repeat(base[:4], 128, axis=0),
                          np.repeat(base[4:], 40, axis=0)])
-    q = base[[0, 1, 4, 5]] + np.float32(0.01)
+    q = base[list(near)] + np.float32(0.01)
     return db, q
 
 
-def _search(metric):
-    db, q = tied_rows()
-    prog = ShardedKNN(db, mesh=mesh(), k=K, metric=metric)
-    return lambda: prog.search_certified(q, selector="pallas")[-1]
+def _search(metric, near=MIXED, shards=1):
+    db, q = tied_rows(near=near)
+    prog = ShardedKNN(db, mesh=mesh(shards), k=K, metric=metric)
+    return lambda: prog.search_certified(q, selector="pallas")
 
 
-def _filtered():
-    db, q = tied_rows()
+def _filtered(near=MIXED):
+    db, q = tied_rows(near=near)
     # every row holds tag 0, a row in three tag 1 too: a filter on tag 0
     # keeps every row valid
     prog = ShardedKNN(db, mesh=mesh(), k=K, row_tags=csr(
         [[0, 1] if r % 3 == 0 else [0] for r in range(db.shape[0])]))
     ft = np.array([[0, -1]] * q.shape[0], np.int32)
     return lambda: prog.search_certified(q, selector="pallas",
-                                         filter_tags=ft)[-1]
+                                         filter_tags=ft)
 
 
-def _self(monkeypatch):
+def _self(monkeypatch, near=MIXED):
     # one block: the call's rows are fewer than a block
     monkeypatch.setitem(tuning.DEFAULT_KNOBS, "tile_n", 256)
     db, _ = tied_rows()
     prog = ShardedKNN(db, mesh=mesh(), k=K)
-    return lambda: knn_self_join(prog, rows=(500, 532))[-1]
+    # rows of the last 128-copy family and the first 40-copy one, or of
+    # the 40-copy one alone
+    rows = (500, 532) if near == MIXED else (512, 544)
+    return lambda: knn_self_join(prog, rows=rows)
 
 
+#: every form ``repair_uncertified`` is called in: (monkeypatch, near)
+#: -> a call that returns (distances, indices, stats)
 FORMS = {
-    "l2-plain": lambda mp: _search("l2"),
-    "dot-plain": lambda mp: _search("dot"),
-    "cosine-plain": lambda mp: _search("cosine"),
-    "l2-valid_rows_fn": lambda mp: _filtered(),
+    "l2-plain": lambda mp, near: _search("l2", near),
+    "dot-plain": lambda mp, near: _search("dot", near),
+    "cosine-plain": lambda mp, near: _search("cosine", near),
+    "l2-valid_rows_fn": lambda mp, near: _filtered(near),
     "l2-exclude": _self,
 }
 
@@ -121,11 +144,10 @@ FORMS = {
 @pytest.mark.parametrize("form", sorted(FORMS))
 def test_a_call_with_fallbacks_records_the_repairs_two_phases(form,
                                                               monkeypatch):
-    call = FORMS[form](monkeypatch)
+    call = FORMS[form](monkeypatch, MIXED)
     call()  # the placement's one-time passes and compiles
-    obs.reset(enabled=True)
-    obs.reset_event_log(None)
-    stats = call()
+    _fresh()
+    stats = call()[-1]
     scanned = stats.get("host_exact_queries", 0)
     assert 0 < scanned < stats["fallback_queries"], "both outcomes are taken"
     by = {}
@@ -137,6 +159,11 @@ def test_a_call_with_fallbacks_records_the_repairs_two_phases(form,
     owner = (repair if form != "l2-exclude" else spans("join.block")[0])
     assert {e["trace_id"] for e in by.values()} == {owner["trace_id"]}
     assert by[REFINE]["rows"] >= stats["fallback_queries"] * K
+    # dense ties (more copies of one row than the re-select is wide):
+    # every score is the k-th, nothing is past reach, and step 3 scans
+    assert by[REFINE]["rows"] == by[REFINE]["selected"]
+    assert outcomes(mn.REPAIR_REFINE_ROWS) == {
+        "refined": by[REFINE]["rows"], "thinned": 0}
     # host_exact_queries stays on the repair's event and is the counter's
     assert (by[HOST_SCAN]["queries"] == scanned
             == repair["host_exact_queries"])
@@ -163,6 +190,7 @@ def test_a_call_without_fallbacks_records_both_at_zero():
         assert (e["parent"], e["dur_s"]) == ("certified.repair", 0.0)
     # both outcomes exist from the placement's first call
     assert repair_outcomes() == {"proven": 0, "host_scan": 0}
+    assert outcomes(mn.REPAIR_REFINE_ROWS) == {"refined": 0, "thinned": 0}
 
 
 def test_the_self_join_records_the_repairs_phases_once_a_block(monkeypatch):
@@ -171,8 +199,7 @@ def test_the_self_join_records_the_repairs_phases_once_a_block(monkeypatch):
     db, _ = tied_rows()
     prog = ShardedKNN(db, mesh=mesh(), k=K)
     knn_self_join(prog, rows=(0, 192))
-    obs.reset(enabled=True)
-    obs.reset_event_log(None)
+    _fresh()
     # three blocks: the first two hold rows of the 128-copy families
     # alone, the third of the 40-copy ones too
     _, _, stats = knn_self_join(prog, rows=(0, 3 * 192))
@@ -186,6 +213,237 @@ def test_the_self_join_records_the_repairs_phases_once_a_block(monkeypatch):
         blocks[0]["trace_id"]}
     assert sum(repair_outcomes().values()) == stats["fallback_queries"] > 0
     assert repair_outcomes()["host_scan"] == stats["host_exact_queries"]
+
+
+# --- what the refine gathers ---------------------------------------------------
+@pytest.mark.parametrize("form", sorted(FORMS) + ["l2-plain-x4"])
+def test_the_thinned_refine_returns_the_whole_refines_arrays(form,
+                                                             monkeypatch):
+    """Queries near the 40-copy families: the re-select's first 40
+    candidates tie and the 41st lies a real gap away, so the refine
+    leaves the rest where they are, and the answers are bit for bit
+    those of a refine over every candidate (the band at +inf)."""
+    call = (_search("l2", GAPPED, shards=4) if form == "l2-plain-x4"
+            else FORMS[form](monkeypatch, GAPPED))
+    call()  # the placement's one-time passes and compiles
+    _fresh()
+    d, i, stats = call()
+    (thin,) = spans(REFINE)
+    assert stats["fallback_queries"] > 0
+    assert not stats.get("host_exact_queries")  # step 2 proved them all
+    assert (stats["fallback_queries"] * K <= thin["rows"]
+            < thin["selected"])
+    assert outcomes(mn.REPAIR_REFINE_ROWS) == {
+        "refined": thin["rows"], "thinned": thin["selected"] - thin["rows"]}
+    monkeypatch.setattr(certified, "_REACH_TOLS", np.inf)
+    _fresh()
+    d_all, i_all, stats_all = call()
+    (whole,) = spans(REFINE)
+    assert whole["rows"] == whole["selected"] == thin["selected"]
+    assert outcomes(mn.REPAIR_REFINE_ROWS) == {
+        "refined": whole["rows"], "thinned": 0}
+    np.testing.assert_array_equal(i, i_all)
+    np.testing.assert_array_equal(d, d_all)  # bitwise
+    assert {k: stats[k] for k in stats if k.startswith("fallback")} == {
+        k: stats_all[k] for k in stats_all if k.startswith("fallback")}
+
+
+@pytest.mark.parametrize("metric, spread", [
+    ("l2", 1.0), ("dot", 0.003), ("cosine", 1.0)])
+def test_a_band_some_candidates_wide_changes_no_answer(
+        metric, spread, every_query_flagged, monkeypatch):  # noqa: F811
+    """Rows far from the origin: the tolerance spans a few neighbours'
+    spacing (``spread``: what gives each metric's scores that spacing),
+    so the kept width lies strictly between k and the re-select's
+    (neither the first k alone nor everything), every query through the
+    repair; bit for bit the whole refine's answer."""
+    rng = np.random.default_rng(540)
+    db = (rng.normal(size=(3000, 16)) * spread + 100.0).astype(np.float32)
+    q = (rng.normal(size=(24, 16)) * spread + 100.0).astype(np.float32)
+    prog = ShardedKNN(db, mesh=mesh(), k=K, metric=metric)
+    d, i, stats = prog.search_certified(q, selector="pallas")
+    assert stats["fallback_queries"] == 24
+    (thin,) = spans(REFINE)
+    assert 24 * (K + 1) <= thin["rows"] < thin["selected"] - 24
+    monkeypatch.setattr(certified, "_REACH_TOLS", np.inf)
+    d_all, i_all, _ = prog.search_certified(q, selector="pallas")
+    np.testing.assert_array_equal(i, i_all)
+    np.testing.assert_array_equal(d, d_all)  # bitwise
+    if metric == "l2":
+        np.testing.assert_array_equal(
+            i, certified.host_exact_knn(db, q, K)[1])
+
+
+def swapped_pair(seed=54, rows=400, dim=8, m=16):
+    """A corpus whose k-th and (k + 1)-th neighbours of the one query
+    lie a float32 ulp of one coordinate apart, and a re-select whose
+    float32 scores (each within the certificate's tolerance of the exact
+    value, as the kernel's are) order that pair the wrong way round: the
+    true k-th neighbour comes back as the (k + 1)-th candidate."""
+    rng = np.random.default_rng(seed)
+    db = rng.normal(size=(rows, dim)).astype(np.float32)
+    q = rng.normal(size=(1, dim)).astype(np.float32)
+    exact = ((db.astype(np.float64) - q.astype(np.float64)) ** 2).sum(-1)
+    order = np.argsort(exact, kind="stable")
+    a, b = order[K - 1], order[K]
+    db[b] = db[a]
+    far = int(np.argmax(np.abs(db[a] - q[0])))
+    db[b, far] = np.nextafter(db[a, far], np.float32(np.sign(
+        db[a, far] - q[0, far]) * np.inf))  # one ulp further from q
+    exact = ((db.astype(np.float64) - q.astype(np.float64)) ** 2).sum(-1)
+    tol = certified.certification_tolerance(q, db)[0]
+    assert 0 < exact[b] - exact[a] < 0.1 * tol
+    scores = exact.copy()
+    scores[a] += 0.4 * tol
+    scores[b] -= 0.4 * tol
+
+    def select_fn(qb, widen):
+        idx = np.argsort(scores, kind="stable")[:widen]
+        return scores[idx][None].astype(np.float32), idx[None]
+
+    fs, fi = select_fn(q, certified.repair_widen(m, rows))
+    assert (fi[0, K - 1], fi[0, K]) == (b, a)  # the wrong way round
+    assert np.abs(fs[0] - exact[fi[0]]).max() <= tol  # the premise
+    assert fs[0, K + 1] - fs[0, K] > 4 * tol  # and a real gap after them
+    return db, q, m, select_fn
+
+
+@pytest.mark.parametrize("tols, exact", [(2.0, True), (0.0, False)])
+def test_a_true_neighbour_past_the_kth_score_is_still_refined(
+        tols, exact, monkeypatch):
+    """The band is the proof's: with it the pair is refined and the
+    answer is the float64 scan's; with none the float32 order is
+    trusted, the true k-th neighbour is never gathered, step 2 still
+    proves the repair, and the answer is wrong."""
+    db, q, m, select_fn = swapped_pair()
+    monkeypatch.setattr(certified, "_REACH_TOLS", tols)
+    d = np.zeros((1, K))
+    i = np.zeros((1, K), np.int64)
+    got = certified.repair_uncertified(
+        d, i, K, m, np.arange(1), q, db, select_fn=select_fn,
+        max_widen=db.shape[0])
+    assert "host_exact_queries" not in got  # proven, either way
+    want_d, want_i = certified.host_exact_knn(db, q, K)
+    assert np.array_equal(i, want_i) == exact
+    # (the scan sums a difference's squares in another order)
+    np.testing.assert_allclose(d[:, : K - 1], want_d[:, : K - 1], rtol=1e-14)
+    assert (abs(d[0, K - 1] - want_d[0, K - 1]) <= 1e-14) == exact
+    (e,) = spans(REFINE)
+    # the pair and nothing after it; or the first k alone
+    assert (e["rows"], e["selected"]) == (
+        K + 1 if exact else K, certified.repair_widen(m, db.shape[0]))
+
+
+def test_a_zero_row_among_the_first_k_does_not_set_the_band():
+    """Cosine: a row of zero norm is selected at D' = 1, half its cosine
+    distance of 1.  Six rows nearer than that, the zero row, 24 rows at
+    cosine 0.05 to 0.45 and the rest behind the query: by float32 score
+    the zero row is the 7th candidate, in truth the 31st, and the
+    widened selection's last score is over 2, so step 2 proves whatever
+    step 1 refined.  The k-th score is taken among the candidates of
+    nonzero norm, so the four rows that replace the zero row and the
+    three before it are within reach."""
+    rng = np.random.default_rng(54)
+    n, dim = 400, 16
+    axis = np.zeros(dim)
+    axis[0] = 1.0
+
+    def at(cos):
+        side = rng.normal(size=(cos.size, dim))
+        side[:, 0] = 0.0
+        side /= np.linalg.norm(side, axis=1, keepdims=True)
+        return ((cos[:, None] * axis + np.sqrt(1 - cos ** 2)[:, None] * side)
+                * rng.uniform(0.5, 2.0, size=(cos.size, 1)))
+
+    db = np.concatenate([
+        at(rng.uniform(0.6, 0.9, 6)), np.zeros((1, dim)),
+        at(rng.uniform(0.05, 0.45, 24)),
+        at(rng.uniform(-0.9, -0.1, n - 31))]).astype(np.float32)
+    db = db[rng.permutation(n)]
+    q = (3.0 * axis)[None].astype(np.float32)
+    prog = ShardedKNN(db, mesh=mesh(), k=K, metric="cosine")
+    d, i, stats = prog.search_certified(q, selector="pallas")
+    # a zero row among the candidates: flagged; proven without a scan
+    assert stats["fallback_queries"] == 1
+    assert "host_exact_queries" not in stats
+    want_d, want_i = certified.host_exact_knn(db, q, K, metric="cosine")
+    np.testing.assert_array_equal(i, want_i)
+    np.testing.assert_allclose(d, want_d, rtol=1e-14)
+    assert not (db[i[0]] == 0).all(axis=1).any()
+    (e,) = spans(REFINE)
+    assert K < e["rows"] < e["selected"]
+    with pytest.raises(ValueError, match="norms"):
+        certified.repair_uncertified(
+            d, i, K, K + 4, np.arange(1), q, db, select_fn=None,
+            max_widen=n, metric="cosine")
+
+
+def test_a_filtered_query_out_of_valid_rows_keeps_every_candidate(
+        every_query_flagged):  # noqa: F811
+    """A query with fewer than k valid rows: its k-th float32 score is
+    +inf, nothing lies past it, and the refine takes the call's whole
+    width; the answer is the valid rows in float64 order, padded."""
+    rng = np.random.default_rng(54)
+    db = rng.integers(0, 256, size=(600, 16)).astype(np.float32)
+    q = rng.integers(0, 256, size=(2, 16)).astype(np.float32)
+    few = np.sort(rng.choice(600, K - 4, replace=False))
+    prog = ShardedKNN(db, mesh=mesh(), k=K, row_tags=csr(
+        [[0, 1] if r in few else [0] for r in range(600)]))
+    every = np.array([[0, -1]] * 2, np.int32)
+    prog.search_certified(q, selector="pallas", filter_tags=every)
+    (plenty,) = spans(REFINE)
+    assert 2 * K <= plenty["rows"] < plenty["selected"]
+    _fresh()
+    d, i, stats = prog.search_certified(
+        q, selector="pallas", filter_tags=np.array([[0, -1], [1, -1]],
+                                                   np.int32))
+    assert stats["fallback_queries"] == 2
+    (e,) = spans(REFINE)
+    assert e["rows"] == e["selected"] == plenty["selected"]
+    d64 = ((db[few].astype(np.float64) - q[1]) ** 2).sum(-1)
+    order = np.lexsort((few, d64))
+    np.testing.assert_array_equal(i[1, : K - 4], few[order])
+    np.testing.assert_array_equal(d[1, : K - 4], d64[order])
+    assert (i[1, K - 4:] == -1).all() and np.isinf(d[1, K - 4:]).all()
+    want_d, want_i = certified.host_exact_knn(db, q[:1], K)
+    np.testing.assert_array_equal(i[:1], want_i)
+    np.testing.assert_array_equal(d[:1], want_d)
+
+
+_SIX = np.arange(6)[None]
+#: case -> (fs ascending, fi, exclude, norms, the candidates kept); the
+#: tolerance is 1 and k is 2
+WITHIN_REACH = {
+    # the band is two tolerances, and closed
+    "a gap": ([[0.0, 1.0, 2.5, 3.0, 3.5, 9.0]], _SIX, None, None,
+              _SIX[:, :4]),
+    "ties past the width": ([[4.0] * 6], _SIX, None, None, _SIX),
+    "no finite k-th": ([[0.0] + [np.inf] * 5], _SIX, None, None, _SIX),
+    "no wider than k": ([[0.0, 50.0]], _SIX[:, :2], None, None,
+                        _SIX[:, :2]),
+    # one rectangular slice a call
+    "the widest query": ([[0.0, 1.0, 9.0, 9.0, 9.0, 9.0],
+                          [0.0, 1.0, 2.0, 3.0, 9.0, 9.0]],
+                         np.stack([_SIX[0], _SIX[0] + 10]), None, None,
+                         np.stack([_SIX[0, :4], _SIX[0, :4] + 10])),
+    # the own row is the nearest: the k-th LEFT is 3.0, not 1.0
+    "an own row": ([[0.0, 1.0, 3.0, 4.5, 5.5, 9.0]], _SIX, np.array([0]),
+                   None, [[1, 2, 3]]),
+    # cosine: row 1 has no norm, so the k-th that counts is 3.0
+    "a zero row": ([[0.0, 1.0, 3.0, 4.5, 5.5, 9.0]], _SIX, None,
+                   (np.ones(1), np.array([2.0, 0.0, 1.0, 1.0, 1.0, 1.0])),
+                   _SIX[:, :4]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WITHIN_REACH))
+def test_within_reach(case):
+    """The helper alone."""
+    fs, fi, exclude, norms, want = WITHIN_REACH[case]
+    fs = np.asarray(fs)
+    got = certified._within_reach(fs, fi, 2, np.ones(fs.shape[0]), exclude,
+                                  norms)
+    np.testing.assert_array_equal(got, want)
 
 
 # --- the range completion -------------------------------------------------------
