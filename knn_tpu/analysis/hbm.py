@@ -298,6 +298,49 @@ def resident_operands_fit(form_bytes: int, placed_bytes: int,
         form_bytes, placed_bytes, memory_stats, width=width)["kept"]
 
 
+def certified_query_bytes(m: int, width: int, select_width: int,
+                          packed_columns: int) -> int:
+    """Bytes ONE query of a certified launch holds on its chip, whatever
+    the corpus: what the sub-batch rule multiplies by a launch's queries
+    (``analysis.subbatch.certified_sub_batch``).  Three terms, each an
+    upper reading of what XLA's ``memory_analysis`` gives for the
+    compiled program (they are not all live at once):
+
+    - the rescore's rows: the m+1 selected rows gathered at the placed
+      ``width`` in float32, and a second array of that size for the
+      differences (``ops.pallas_knn.local_select_rescore``; the traces
+      show ``f32[queries x (m+1), width]`` and a copy of it).  At m =
+      130 and 1,024 columns 1.07 MB, at m = 1,152 9.45 MB, which is the
+      whole of what a launch of k = 1,024 holds: compiled for a v5e at
+      1M x 1,024, 512 queries set aside 4.86 GB, 9.50 MB a query
+      (``scripts/aot_compile_check.py --temporaries --shape knnlm1m``;
+      PERF.md section 6, PR 55);
+    - the candidates: the kernel's scores and indices at
+      ``select_width`` columns, and as much again for the select's own
+      operands;
+    - the packed answer, ``packed_columns`` int32 words
+      (``_pallas_certified_program``), which stays until it is
+      fetched."""
+    rescore = 2 * (int(m) + 1) * _widths.lane_tiled(int(width)) * 4
+    candidates = 4 * int(select_width) * 4
+    return rescore + candidates + int(packed_columns) * 4
+
+
+def certified_launch_room(room: dict) -> int:
+    """What one chip has left for a certified launch's own arrays beside
+    its rows and their row operands, from the terms
+    :func:`resident_operands_room` read: ``limit`` less ``held`` less
+    ``form_bytes``.  The operands count whether they are kept (they are
+    held) or formed in every call (they are the launch's first
+    temporaries).  0, no bound, where the backend reports no limit (the
+    CPU) and where nothing is left (a device that refused the operands
+    for want of room: how its calls are cut is the sub-batch rule's
+    first three lines, as it was)."""
+    if not room.get("limit"):
+        return 0
+    return max(0, room["limit"] - room["held"] - room["form_bytes"])
+
+
 __all__ = [
     "AUX_BYTES_PER_ROW",
     "LANE_TILED_TEMP_FACTOR",
@@ -307,6 +350,8 @@ __all__ = [
     "program_temp_factor",
     "resident_operands_room",
     "resident_operands_fit",
+    "certified_query_bytes",
+    "certified_launch_room",
     "placement_bytes",
     "rows_for_budget",
     "plan_segments",

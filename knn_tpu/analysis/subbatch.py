@@ -29,7 +29,19 @@ the rule cuts only where that is next to nothing:
    first than the uncut call would have run).
 
 Everything else stays one batch, the program and the launch it always
-was.  Nothing sets any of this: no argument, no environment switch,
+was.
+
+And a launch has to FIT: its own arrays grow with its queries (the
+rescore gathers m+1 rows a query at the placed width, twice over:
+``analysis.hbm.certified_query_bytes``), and at k = 1,024 over 1,024
+columns 1,024 queries hold 10.2 GB beside 8.4 GB of rows and operands.
+Whatever 1 to 3 gave, a launch whose queries do not fit what the chip
+has left (``analysis.hbm.certified_launch_room``) is cut to the most
+whole query blocks that do, the call into equal launches of one
+compiled shape (``memory``).  At m = 130 no shape of the benchmark
+comes within a third of its room, and every call is cut as it was.
+
+Nothing sets any of this: no argument, no environment switch,
 nothing in ``knn_tpu.tuning``.  An explicit ``batch_size`` wins.
 """
 
@@ -78,14 +90,16 @@ SUB_BATCH_MIN_BLOCKS = 4
 
 #: why a call's sub-batch is what it is, as its event, its ``stats`` and
 #: ``knn_tpu_certified_sub_batch_calls_total{why}`` say it: cut by the
-#: rule, or one batch because of 1, 2 or 3 above, or the caller's own
+#: rule, or one batch because of 1, 2 or 3 above, or the caller's own,
+#: or cut to what the chip has room for
 REASONS = ("resident", "per_call_operands", "layout_copy", "small",
-           "explicit")
+           "explicit", "memory")
 
 
 def certified_sub_batch(
     queries: int, *, batch_size: Optional[int], operands: str, width: int,
-    block_q: int, query_shards: int,
+    block_q: int, query_shards: int, query_bytes: int = 0,
+    room_bytes: int = 0,
 ) -> Tuple[int, str]:
     """``(rows, why)``: the queries a sub-batch of one certified call of
     ``queries`` queries holds, and the entry of ``REASONS`` that says
@@ -95,14 +109,33 @@ def certified_sub_batch(
     query axis.  Where the rule cuts, ``rows`` is the call's
     ``SUB_BATCHES``-th part rounded up to whole query blocks on every
     query shard (the last sub-batch is padded to it: one compiled shape
-    a call); everywhere else it is ``queries``."""
+    a call); everywhere else it is ``queries``.
+
+    ``query_bytes`` (what one query of a launch holds on its chip,
+    ``analysis.hbm.certified_query_bytes``) and ``room_bytes`` (what a
+    chip has left, ``analysis.hbm.certified_launch_room``; 0: no bound)
+    hold that answer to the chip's memory: ``rows`` queries are ``rows /
+    query_shards`` a chip, and where they do not fit the call is cut
+    into the fewest equal launches of whole query blocks that do
+    (``memory``; never under one block a query shard)."""
     if batch_size is not None:
         return int(batch_size), "explicit"
+    grain = block_q * query_shards
+    rows, why = _by_launch_cost(queries, operands, width, grain)
+    fits = room_bytes // max(1, query_bytes) * query_shards
+    if room_bytes and rows > max(grain, fits):
+        launches = -(-queries // max(grain, fits // grain * grain))
+        return -(-queries // (launches * grain)) * grain, "memory"
+    return rows, why
+
+
+def _by_launch_cost(queries: int, operands: str, width: int, grain: int
+                    ) -> Tuple[int, str]:
+    """Rules 1 to 3 of the module docstring."""
     if operands != "resident":
         return queries, "per_call_operands"
     if width % DIM_CHUNK:  # the feature axis's padding grain, 128
         return queries, "layout_copy"
-    grain = block_q * query_shards
     least = max(SUB_BATCH_MIN_ROWS, SUB_BATCH_MIN_BLOCKS * grain)
     if queries < SUB_BATCHES * least:
         return queries, "small"

@@ -137,6 +137,8 @@ KERNEL_DIM_CHUNKS = "knn_tpu_kernel_dim_chunks_total"
 FINAL_SELECT_CALLS = "knn_tpu_final_select_calls_total"
 KERNEL_OPERANDS = "knn_tpu_kernel_operands_total"
 CERTIFIED_SUB_BATCH_CALLS = "knn_tpu_certified_sub_batch_calls_total"
+CERTIFIED_LAUNCHES = "knn_tpu_certified_launches_total"
+CERTIFIED_BIN_OVERFLOW = "knn_tpu_certified_bin_overflow_queries_total"
 FILTER_QUERIES = "knn_tpu_filter_queries_total"
 JOIN_ROWS = "knn_tpu_join_rows_total"
 JOIN_BLOCKS_INFLIGHT = "knn_tpu_join_blocks_inflight"
@@ -554,7 +556,28 @@ CATALOG = {
         "('per_call_operands'), because the placed rows' width is no "
         "whole number of 128-column tiles and every launch would copy "
         "them ('layout_copy'), or because the call is too few queries "
-        "('small'); 'explicit' the caller's batch_size."),
+        "('small'); 'explicit' the caller's batch_size; 'memory' cut "
+        "further, to the most whole query blocks a chip has room for "
+        "beside its rows (analysis.hbm.certified_query_bytes: the "
+        "rescore gathers m+1 rows a query)."),
+    CERTIFIED_LAUNCHES: (
+        "counter", ("survivor_depth", "final_select_stage"),
+        "Device programs launched by calls of search_certified("
+        "selector='pallas') (the sub-batches of every call: over "
+        "knn_tpu_certified_sub_batch_calls_total it is the launches a "
+        "call), by the survivors a kernel bin kept (ops.pallas_knn."
+        "survivor_depth: 2 at every k = 100 shape, 4 at k = 1,024 over "
+        "1M rows) and by what ran the final top-(m+2) ('pallas' / "
+        "'xla')."),
+    CERTIFIED_BIN_OVERFLOW: (
+        "counter", (),
+        "Queries of search_certified(selector='pallas') whose "
+        "certificate failed on a FULL BIN: flagged by the device, and "
+        "after the repair one kernel bin (a lane of a row tile of its "
+        "shard) is seen to hold more of the query's exact top-k than "
+        "the survivor depth, so the kernel cannot have emitted them "
+        "all.  Over the call's queries it is what "
+        "ops.pallas_knn.bin_overflow_share models from above."),
     JOIN_ROWS: (
         "counter", ("mode",),
         "Rows answered by the bulk join (knn_tpu.join.engine), by its "

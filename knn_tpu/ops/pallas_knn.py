@@ -148,6 +148,7 @@ on the chip (round 3 showed interpret-pass is not hardware-sound).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -204,6 +205,10 @@ FINAL_SELECT_MAX_KEEP = 2 * BIN_W
 #: fused kernels, qt adding up across them.  The tiled kernel's chunk
 #: is the whole padded width (``dim_chunking``); never a caller's choice
 DIM_CHUNK = 128
+#: survivors per bin where a caller names none and no rule resolved a
+#: depth (``_geometry``), and the least ``survivor_depth`` considers:
+#: every shape the chip had timed until PR 55
+DEFAULT_SURVIVORS = 2
 #: cap on survivors per bin (tiny tile_n in tests would otherwise unroll
 #: a 128-step trace); capped cells just pad their output block
 MAX_SURVIVORS = 8
@@ -427,14 +432,16 @@ def _geometry(
     apart), so the tile must be a multiple of 128.  Output blocks are
     lane-aligned: ``out_w = survivors * 128`` lanes of candidates per
     cell, ``bound_w = 128`` lanes of per-bin exclusion bounds.
-    ``survivors=None`` picks 2 (the collision-rate sweet spot, module
-    docstring); the MAX_SURVIVORS cap applies to explicit requests too
-    (each survivor is an unrolled insertion step in the kernel trace)."""
+    ``survivors=None`` picks ``DEFAULT_SURVIVORS`` (the collision-rate
+    sweet spot at k = 100, module docstring; a certified call hands in
+    the depth :func:`survivor_depth` read off its shape); the
+    MAX_SURVIVORS cap applies to explicit requests too (each survivor is
+    an unrolled insertion step in the kernel trace)."""
     if tile_n % BIN_W:
         raise ValueError(
             f"tile_n={tile_n} must be a multiple of {BIN_W} lanes")
     if survivors is None:
-        survivors = 2
+        survivors = DEFAULT_SURVIVORS
     survivors = min(survivors, MAX_SURVIVORS)
     return BIN_W, survivors, survivors * BIN_W, BIN_W
 
@@ -506,6 +513,96 @@ def effective_tile(
     while eff > BIN_W and width(eff) < min_width:
         eff = max(BIN_W, -(-(eff // 2) // BIN_W) * BIN_W)
     return eff
+
+
+#: the modelled share of queries (``bin_overflow_share``) under which
+#: :func:`survivor_depth` stops deepening.  The nine cells the benchmark
+#: had when the rule came (PR 55) read 0.0001 to 2.3 % at depth 2 (the
+#: highest ``openai500k``: 500K rows, 3,968 bins, m+2 = 130) and keep
+#: it; k = 1,024 over 1M rows (m+2 = 1,154 of 7,936 bins) reads 97 %
+#: there, 12 % at 3, 0.38 % at 4
+SURVIVOR_OVERFLOW_LIMIT = 0.05
+
+
+#: the candidates a certified call keeps beyond k where the caller names
+#: no ``margin``: what every k = 100 shape the chip has timed ran with
+DEFAULT_MARGIN = 28
+#: ... and the share of k it does not go under
+MARGIN_K_SHARE = 8
+
+
+def default_margin(k: int) -> int:
+    """The ``margin`` of a certified call whose caller names none: m =
+    k + margin candidates are kept a query, and the certificate holds
+    only where the (m+2)-th nearest row lies further beyond the k-th
+    than the kernel's tolerance (``kernel_tolerance``: 2^-14 of |q|^2 +
+    the largest |t|^2, whatever k).  The gap between neighbours a fixed
+    number of ranks apart shrinks as 1 / rank (the k-th distance moves
+    with log k in a cluster), so a fixed margin that clears the
+    tolerance at k = 100 does not at k = 1,000: on ``knnlm1m``'s rows
+    28 ranks past the 1,024-th are 2.2e-4 in the largest cluster
+    against a tolerance of 2.8e-4, and 17.9 % of the queries, all of
+    the four largest clusters', failed for that and for no full bin
+    (PERF.md section 6, PR 55).  So the margin follows k: an eighth of
+    it (128 ranks, 9.1e-4 there), and no less than ``DEFAULT_MARGIN``,
+    which k up to 231 keep."""
+    return max(DEFAULT_MARGIN, int(k) // MARGIN_K_SHARE)
+
+
+def bin_overflow_share(keep: int, bins: int, depth: int) -> float:
+    """The modelled share of queries whose certificate fails on a FULL
+    BIN: some kernel bin holds more than ``depth`` of the query's
+    ``keep`` (= m+2) nearest rows, so one of them is no candidate and
+    the bin's bound falls among them.  Rows fall into the ``bins``
+    (128 lanes a row tile) independently of their distance to a query,
+    so a bin's count is Poisson(keep / bins) and the share is ``1 -
+    exp(-bins * P(count > depth))``: an upper reading (a full bin past
+    the k-th neighbour still certifies, and db shards split the
+    nearest between them).  Counted on uniform rows in
+    ``tests/test_knnlm_topk.py``; on the chip ``fallback_pct`` read
+    0.014 / 0.11 to 0.22 / 1.06 % where this gives 0.02 / 0.1 / 2.3
+    (``bigann5m``, ``ssnpp2m5``, ``openai500k``; ledger, PR 54)."""
+    lam = keep / bins
+    # the upper tail summed upward from its first term: 1 - cdf cancels
+    # at the small rates this is asked about
+    term = math.exp(-lam) * lam ** (depth + 1) / math.factorial(depth + 1)
+    tail = 0.0
+    for j in range(depth + 2, depth + 66):
+        tail += term
+        term *= lam / j
+    return -math.expm1(-bins * tail)
+
+
+def survivor_depth(
+    rows: int, tile_n: int, survivors: Optional[int], keep: int,
+) -> Tuple[int, int, float]:
+    """``(depth, tile, share)``: the survivors a kernel bin keeps for a
+    shard of ``rows`` rows selected ``keep`` (= m+2) deep, the row tile
+    :func:`effective_tile` resolves at that depth and the share
+    :func:`bin_overflow_share` models there.  A caller's ``survivors``
+    is taken as given (capped like ``_geometry`` caps it).  Left out, it
+    follows from what the call can see and from no knob: the least depth
+    from ``DEFAULT_SURVIVORS`` to ``MAX_SURVIVORS`` whose modelled share
+    is under ``SURVIVOR_OVERFLOW_LIMIT`` (each further survivor is one
+    more insertion step an element on the VPU, and 128 more candidate
+    columns a tile for the final select), and where none is (a corpus
+    of a few bins) the depth of the least share, the shallowest on
+    equal ones."""
+    def at(depth: int) -> Tuple[int, int, float]:
+        tile = effective_tile(rows, tile_n, depth, keep)
+        return depth, tile, bin_overflow_share(
+            keep, -(-rows // tile) * BIN_W, depth)
+
+    if survivors is not None:
+        return at(min(int(survivors), MAX_SURVIVORS))
+    best = None
+    for depth in range(DEFAULT_SURVIVORS, MAX_SURVIVORS + 1):
+        got = at(depth)
+        if got[2] < SURVIVOR_OVERFLOW_LIMIT:
+            return got
+        if best is None or got[2] < best[2]:
+            best = got
+    return best
 
 
 def select_merge_geometry(

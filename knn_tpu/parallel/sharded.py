@@ -1140,6 +1140,10 @@ class ShardedKNN:
         # the row tile the last program's kernel runs (_pallas_setup):
         # the layout a batch's validity words are made in
         self._kernel_tile = 0
+        # what _pallas_setup last resolved, whole (certified_plan), and
+        # the form of it the last ``certified.plan`` event said
+        self._plan: dict = {}
+        self._plan_told: Optional[dict] = None
         #: lazily built serving engines, keyed by ladder spec
         #: (buckets, min_bucket, max_bucket) — search_bucketed; the lock
         #: keeps concurrent cold calls from double-building an engine
@@ -1846,7 +1850,10 @@ class ShardedKNN:
                 tile=key[0], parts="th+tl" if with_lo else "th",
                 bytes=form_bytes * shards if parts else 0,
                 seconds=seconds, **room)
-            self._operands_cache = {"key": key, "parts": parts}
+            # the reading stays with the form: what a launch may hold
+            # beside it is decided once a geometry too (_pallas_setup)
+            self._operands_cache = {"key": key, "parts": parts,
+                                    "room": room}
         return parts
 
     def _tag_index(self, tile: int) -> dict:
@@ -1971,7 +1978,8 @@ class ShardedKNN:
         return COS_UNIT_SLACK if self._cosine_unit else 0.0
 
     def search_certified(
-        self, queries, *, margin: int = 28, selector: str = "approx",
+        self, queries, *, margin: Optional[int] = None,
+        selector: str = "approx",
         batch_size: Optional[int] = None, tile_n: Optional[int] = None,
         precision: Optional[str] = None, return_distances: bool = True,
         survivors: Optional[int] = None,
@@ -2075,6 +2083,14 @@ class ShardedKNN:
         through a slow link, negligible when the sweep is
         compute-dominated (the published gist1m numbers differ only
         within run-to-run noise).
+
+        ``margin``: m = k + margin candidates are kept a query.  None
+        is the rule of what the placement can see
+        (ops.pallas_knn.default_margin): 28, and from k = 232 an eighth
+        of k, because the certificate needs the (m+2)-th neighbour
+        further beyond the k-th than the kernel's tolerance and
+        neighbours a fixed number of ranks apart lie closer the deeper
+        the rank.
 
         ``batch_size`` streams the queries in fixed-size batches with the
         device stages pipelined against the host stages: every batch's
@@ -2237,7 +2253,8 @@ class ShardedKNN:
                 # margin is bounded by both the db size and the per-shard
                 # rows the coarse/fallback programs select from (k itself
                 # fits: __init__ checks k <= shard_rows)
-                m = min(self.k + margin, self.n_train, shard_rows)
+                m = min(self.k + self._margin(margin), self.n_train,
+                        shard_rows)
                 db_np = self._host_train()
 
                 if batch_size is not None and batch_size < 1:
@@ -2484,6 +2501,13 @@ class ShardedKNN:
                 merged["sub_batch"] = sub_why
                 obs.counter(_mn.CERTIFIED_SUB_BATCH_CALLS,
                             why=sub_why).inc()
+                # the launches the call made, by the kernel's survivor
+                # depth and the final select's stage, and the flagged
+                # queries a full kernel bin explains (_bin_overflows)
+                merged["survivor_depth"] = self._plan["survivor_depth"]
+                merged["bin_overflow_queries"] = self._bin_overflows(
+                    i[bad])
+                self._count_launches(len(batches))
             for key, value in merged.items():
                 call.set(key, value)
             stats = {
@@ -2534,7 +2558,8 @@ class ShardedKNN:
                     "final_select_stage": merged["final_select_stage"],
                     "select_merge_short": merged["select_merge_short"],
                     "operands": merged["operands"],
-                    "sub_batch": sub_why, "batches": len(batches)}
+                    "sub_batch": sub_why, "batches": len(batches),
+                    "survivor_depth": merged["survivor_depth"]}
                 stats["tuning"] = tune_info
             # mirror the quality signals into the telemetry registry —
             # the per-call stats dict stays the API, the registry
@@ -3008,6 +3033,113 @@ class ShardedKNN:
         return (np.unique(np.concatenate(flagged)) if flagged
                 else np.empty(0, np.int64))
 
+    def _margin(self, margin: Optional[int]) -> int:
+        """A certified call's ``margin``: the caller's, or the rule of
+        what the placement can see (ops.pallas_knn.default_margin: 28
+        up to k = 231, an eighth of k from there)."""
+        if margin is not None:
+            return int(margin)
+        from knn_tpu.ops.pallas_knn import default_margin
+
+        return default_margin(self.k)
+
+    def _count_launches(self, n: int) -> None:
+        """``n`` more launches of the last resolved plan's program."""
+        obs.counter(
+            _mn.CERTIFIED_LAUNCHES,
+            survivor_depth=str(self._plan["survivor_depth"]),
+            final_select_stage=self._final_select_stage).inc(n)
+
+    def _bin_overflows(self, top: np.ndarray) -> int:
+        """How many of the flagged queries, whose exact top-k after the
+        repair is ``top`` [F, k] (global row ids; a filtered call's
+        padding and sentinels past the rows), failed their certificate
+        on a FULL BIN: some kernel bin holds more of the k than the
+        survivor depth of the last resolved plan, so at least one of
+        them was no candidate and the bin's bound lay inside the top-k.
+        A row's bin is (its shard, its row tile there, its lane): lane
+        ``r % 128`` of tile ``r // row_tile`` for shard-local row ``r``
+        (ops.pallas_knn: members strided 128 apart).  Counted, and
+        added to ``knn_tpu_certified_bin_overflow_queries_total``."""
+        tile, depth = self._plan["row_tile"], self._plan["survivor_depth"]
+        shard, local = np.divmod(top, self._shard_rows())
+        bins = (shard * -(-self._shard_rows() // tile) + local // tile
+                ) * 128 + local % 128
+        bins = np.sort(np.where((top >= 0) & (top < self.n_train), bins, -1),
+                       axis=1)
+        # a run of depth + 1 equal bins among the sorted
+        full = ((bins[:, depth:] == bins[:, :-depth])
+                & (bins[:, depth:] >= 0)).any(axis=1)
+        n = int(full.sum())
+        obs.counter(_mn.CERTIFIED_BIN_OVERFLOW).inc(n)
+        return n
+
+    def _note_plan(self, plan: dict) -> None:
+        """Keep what :meth:`_pallas_setup` just resolved
+        (:meth:`certified_plan` hands it out) and say it, as one
+        ``certified.plan`` event, when it is not what the last event of
+        this placement said: once a placement for a caller whose calls
+        are alike, once more where a call of another size or with
+        another knob resolves otherwise."""
+        self._plan = plan
+        if plan != self._plan_told:
+            self._plan_told = plan
+            obs.emit_event("certified.plan", **plan)
+
+    def certified_plan(self, n_queries: int, *, margin: Optional[int] = None,
+                       batch_size: Optional[int] = None,
+                       tune_cache: Optional[str] = None, **knobs) -> dict:
+        """What ``search_certified(queries, selector="pallas")`` would
+        run for a call of ``n_queries`` queries on this placement,
+        before any is made: a dict, resolved by the code the call
+        itself resolves with (``knn_tpu.tuning.resolve_full``, then
+        :meth:`_pallas_setup`; ``margin``, ``batch_size``,
+        ``tune_cache`` and the kernel's ``knobs`` as the call takes
+        them), so it compiles and launches nothing but what the first
+        call's ``certified.prepare`` would: the resident row operands
+        are built here where the device keeps them.  A batch is taken to
+        have inexact float32 values (``terms``: all the products the
+        rows ask for).
+
+        ``k``, ``m`` (the candidates kept a query: k + margin, capped),
+        ``row_tile``, ``survivor_depth`` and the ``overflow_share``
+        modelled at it (``ops.pallas_knn.survivor_depth``),
+        ``select_width`` (the kernel's candidate columns a shard),
+        ``select_merged_width`` and ``select_merge_stage`` (``pallas``
+        where the bin-merge engages, else ``none``),
+        ``final_select_stage`` (``pallas`` / ``xla``), ``operands``
+        (``resident`` / ``per_call``), ``dim_chunk(s)``, ``row_block`` /
+        ``row_steps``, ``queries``, ``sub_batch_rows`` and ``sub_batch``
+        (why: ``analysis.subbatch.REASONS``), ``batches`` (launches a
+        call), ``launch_bytes`` (what one launch's queries hold on a
+        chip, ``analysis.hbm.certified_query_bytes``), ``room_bytes``
+        (what the chip had left for them when the operands were placed;
+        0: the backend reports no limit) and ``interpret``.  A call's
+        ``stats["pallas_knobs"]`` reports the same values under the
+        names the two share."""
+        if self.metric not in ("l2", "sql2", "euclidean", "cosine", "dot"):
+            raise ValueError(
+                "certified_plan: search_certified supports the l2, cosine "
+                "and dot metrics only")
+        self._require_resident("certified_plan")
+        from knn_tpu import tuning
+
+        resolved, _ = tuning.resolve_full(
+            self.n_train, self._given_width, self.k,
+            metric="l2" if self.metric in ("cosine", "dot") else self.metric,
+            dtype=self._dtype_key, cache_path=tune_cache, overrides=knobs)
+        self._db_norm_max()  # the placement's walk: what the rows are
+        from knn_tpu.ops.pallas_knn import bf16x3_terms
+
+        terms = bf16x3_terms(
+            resolved["precision"] == "bf16x3" and self._rows_lo_zero, False)
+        m = min(self.k + self._margin(margin), self.n_train,
+                self._shard_rows())
+        self._pallas_setup(
+            m - self.k, include_distances=self.metric != "dot", terms=terms,
+            batch_rows=batch_size, call_rows=int(n_queries), **resolved)
+        return dict(self._plan)
+
     def _pallas_setup(self, margin: int, tile_n: Optional[int],
                       precision: str,
                       survivors: Optional[int] = None,
@@ -3088,12 +3220,13 @@ class ShardedKNN:
             default_backend_is_tpu,
             dim_chunking,
             effective_block_q,
-            effective_tile,
             final_select_geometry,
             row_blocking,
             select_merge_geometry,
+            survivor_depth,
         )
 
+        from knn_tpu.analysis import hbm
         from knn_tpu.analysis.subbatch import certified_sub_batch
         from knn_tpu.utils.config import CERTIFIED_PRECISIONS
 
@@ -3110,11 +3243,17 @@ class ShardedKNN:
             quant_offset = self._int8_placement()["offset"]
 
         shard_rows = self._shard_rows()
-        # same tile the kernel will pick (ONE home for the arithmetic:
-        # ops.pallas_knn.effective_tile), so the m-cap below matches the
-        # kernel's real candidate width
-        eff_tile = effective_tile(shard_rows, tile_n or TILE_N, survivors,
-                                  min(self.k + margin, shard_rows) + 2)
+        # the survivors a kernel bin keeps: the caller's, or the least
+        # depth whose modelled share of full-bin fallbacks is under the
+        # limit, read off the shard's rows and m+2 (ONE home for the
+        # arithmetic: ops.pallas_knn.survivor_depth), and at that depth
+        # the same tile the kernel will pick (effective_tile, inside
+        # it), so the m-cap below matches the kernel's real candidate
+        # width.  Everything below, the program too, gets the RESOLVED
+        # depth
+        survivors, eff_tile, overflow_share = survivor_depth(
+            shard_rows, tile_n or TILE_N, survivors,
+            min(self.k + margin, shard_rows) + 2)
         self._kernel_tile = eff_tile
         out_w = _geometry(eff_tile, survivors)[2]
         # m is bounded by the db, the per-shard rows, and the kernel's
@@ -3144,12 +3283,23 @@ class ShardedKNN:
         self._operands_source = "resident" if resident else "per_call"
         bq = block_q or BLOCK_Q
         q_shards = self.mesh.shape[QUERY_AXIS]
+        # what one query of a launch holds on its chip, and what the
+        # chip has left beside the rows and their operands by the
+        # reading the operands were decided on (0: no bound; a precision
+        # that keeps none has no reading)
+        w = _analysis_window(self.k, m)
+        query_bytes = hbm.certified_query_bytes(
+            m, self._tp.shape[1], select_width, w + -(-(w - 1) // 32) + 1
+            + (self.k if include_distances else 0))
+        room = hbm.certified_launch_room(
+            self._operands_cache["room"] if precision == "bf16x3" else {})
         self._sub_batch = (
             (batch_rows, "explicit") if call_rows is None
             else certified_sub_batch(
                 call_rows, batch_size=batch_rows,
                 operands=self._operands_source, width=self._tp.shape[1],
-                block_q=bq, query_shards=q_shards))
+                block_q=bq, query_shards=q_shards,
+                query_bytes=query_bytes, room_bytes=room))
         batch_rows = self._sub_batch[0]
         if batch_rows is not None:
             bq = effective_block_q(bq, -(-batch_rows // q_shards))
@@ -3159,6 +3309,27 @@ class ShardedKNN:
             self._tp.shape[1], tile_n=eff_tile, block_q=bq,
             precision=precision, kernel=kernel, terms=terms,
             survivors=survivors, masked=masked)
+        self._note_plan({
+            "k": self.k, "m": m, "row_tile": eff_tile,
+            "survivor_depth": survivors,
+            "overflow_share": overflow_share,
+            "select_width": select_width,
+            "select_merged_width": self._select_widths[1],
+            "select_merge_stage": "none" if merge is None else "pallas",
+            "select_merge_short": self._select_merge_short,
+            "final_select_stage": self._final_select_stage,
+            "operands": self._operands_source,
+            "dim_chunk": self._dim_chunking[0],
+            "dim_chunks": self._dim_chunking[1],
+            "row_block": self._row_blocking[0],
+            "row_steps": self._row_blocking[1],
+            "queries": call_rows, "sub_batch_rows": batch_rows,
+            "sub_batch": self._sub_batch[1],
+            "batches": (None if call_rows is None
+                        else -(-call_rows // batch_rows)),
+            "launch_bytes": (None if batch_rows is None else
+                             -(-batch_rows // q_shards) * query_bytes),
+            "room_bytes": room, "interpret": interpret})
         # the program gets setup's RESOLVED tile, not the raw request:
         # m was capped so that width(eff_tile) >= m+2, which makes the
         # kernel's own effective_tile(min_width=m+2) a fixpoint — the
@@ -3175,7 +3346,7 @@ class ShardedKNN:
                 interpret=interpret, terms=terms,
                 row_block=self._row_blocking[0],
                 resident_parts=len(resident) - 1 if resident else 0)
-            return prog, m, _analysis_window(self.k, m), interpret
+            return prog, m, w, interpret
         if vote is not None:
             prog = _pallas_vote_program(
                 self.mesh, m, self.k, self.merge, eff_tile, precision,
@@ -3187,7 +3358,7 @@ class ShardedKNN:
                 interpret=interpret, terms=terms,
                 row_block=self._row_blocking[0],
                 resident_parts=len(resident) - 1 if resident else 0)
-            return prog, m, _analysis_window(self.k, m), interpret
+            return prog, m, w, interpret
         prog = _pallas_certified_program(
             self.mesh, m, self.k, self.merge, eff_tile, precision,
             n_train=self.n_train, survivors=survivors,
@@ -3203,7 +3374,7 @@ class ShardedKNN:
             **({"masked": True} if masked else {}),
             **({"slack_outcome": True} if self._cosine_unit else {}),
         )
-        return prog, m, _analysis_window(self.k, m), interpret
+        return prog, m, w, interpret
 
     def _certify_pallas(
         self, batches, bs, d, i, q_np, db_np, *, prog, w, ops_tail,
@@ -3385,7 +3556,7 @@ class ShardedKNN:
     def predict_certified(
         self, queries, *, vote: str = "majority",
         temperature: Optional[float] = None, classes_out: int = 1,
-        margin: int = 28, selector: str = "approx",
+        margin: Optional[int] = None, selector: str = "approx",
         batch_size: Optional[int] = None, tile_n: Optional[int] = None,
         precision: Optional[str] = None, kernel: Optional[str] = None,
         tune_cache: Optional[str] = None,
@@ -3548,7 +3719,8 @@ class ShardedKNN:
                           first_call=self._db_norm_max_cache is None):
                 n_q = q_np.shape[0]
                 shard_rows = self._shard_rows()
-                m = min(self.k + margin, self.n_train, shard_rows)
+                m = min(self.k + self._margin(margin), self.n_train,
+                        shard_rows)
                 db_np = self._host_train()
                 if batch_size is not None and batch_size < 1:
                     raise ValueError(
@@ -3728,6 +3900,7 @@ class ShardedKNN:
         obs.counter(_mn.KERNEL_OPERANDS,
                     source=self._operands_source).inc(n_batches)
         obs.counter(_mn.CERTIFIED_SUB_BATCH_CALLS, why=sub_why).inc()
+        self._count_launches(n_batches)
         return merged
 
     def _vote_pallas(self, batches, bs, classes, totals, i, host_q, db_np,

@@ -79,7 +79,15 @@ os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
 
 TOPOLOGY = "v5e:2x2"
 NQ = 4096
-MARGIN = 28
+
+
+def margin_of(k: int) -> int:
+    """A default call's margin (ops.pallas_knn.default_margin: 28 at
+    every k = 100 shape, an eighth of k from k = 232)."""
+    from knn_tpu.ops.pallas_knn import default_margin
+
+    return default_margin(k)
+
 #: the shapes of chip_smoke.py and of the benchmark's cells: rows, dim,
 #: k.  GloVe's cosine runs as l2 on unit vectors, so the kernel sees the same call.
 SHAPES = {
@@ -112,7 +120,52 @@ SHAPES = {
     # every row a query, its own row out (benchmark/configs/
     # deep5m-knng.json); 96 columns placed in 128
     "deep5m": (5_000_000, 96, 10),
+    # one chip's slice of the kNN-LM WikiText-103 datastore at its own
+    # k (benchmark/configs/knnlm1m.json): survivor depth 4, XLA's final
+    # select, launches of 512 queries (``--shape knnlm1m --mesh 1x1``;
+    # ``--temporaries --shape knnlm1m`` reads what a launch sets aside)
+    "knnlm1m": (1_000_000, 1024, 1024),
 }
+#: bytes_limit of one v5e chip, as the chip reads it
+V5E_BYTES_LIMIT = 16909336064
+
+
+def _depth_and_tile(shape: str, db_shards: int, knobs: dict):
+    """``(survivor depth, row tile)`` of one chip's shard of ``shape``,
+    as ``ShardedKNN._pallas_setup`` resolves them."""
+    from knn_tpu.ops.pallas_knn import TILE_N, survivor_depth
+
+    n, _, k = SHAPES[shape]
+    rows = -(-n // db_shards)
+    depth, tile, _ = survivor_depth(
+        rows, knobs.get("tile_n") or TILE_N, knobs.get("survivors"),
+        min(k + margin_of(k), rows) + 2)
+    return depth, tile
+
+
+def launch_queries(shape: str, knobs: dict) -> int:
+    """The queries of one launch of a 4,096-query call on one v5e chip
+    at ``shape``: ``analysis.subbatch.certified_sub_batch`` over
+    ``analysis.hbm``'s arithmetic with both row halves resident, no
+    device asked (NQ // 4 wherever a launch of 1,024 fits)."""
+    from knn_tpu.analysis import hbm, subbatch
+    from knn_tpu.analysis.widths import lane_tiled
+    from knn_tpu.ops.pallas_knn import BLOCK_Q
+    from knn_tpu.parallel.sharded import _analysis_window
+
+    n, d, k = SHAPES[shape]
+    d, m = lane_tiled(d), k + margin_of(k)
+    depth, tile = _depth_and_tile(shape, 1, knobs)
+    w = _analysis_window(k, m)
+    room = hbm.resident_operands_room(
+        hbm.row_operand_bytes(-(-n // tile) * tile, d, True), n * d * 4,
+        {"bytes_limit": V5E_BYTES_LIMIT}, width=d)
+    return subbatch.certified_sub_batch(
+        NQ, batch_size=None, operands="resident", width=d,
+        block_q=knobs.get("block_q") or BLOCK_Q, query_shards=1,
+        query_bytes=hbm.certified_query_bytes(
+            m, d, -(-n // tile) * depth * 128, w + -(-(w - 1) // 32) + 1 + k),
+        room_bytes=hbm.certified_launch_room(room))[0]
 #: shapes whose rows are norm-augmented at placement (metric "dot"): the
 #: certified program takes the augmentation's slack as one more scalar
 #: and sends no distance block back
@@ -162,12 +215,13 @@ def _kernel_case(shape: str, knobs: dict, devices, terms=None,
     q = jax.ShapeDtypeStruct((NQ, d), jnp.float32, sharding=sh)
     db = jax.ShapeDtypeStruct((n, d), jnp.float32, sharding=sh)
     kw = {kk: v for kk, v in knobs.items() if v is not None}
+    kw["survivors"], kw["tile_n"] = _depth_and_tile(shape, 1, knobs)
     if terms:
         kw["terms"] = terms
     if row_block:
         kw["row_block"] = row_block
     fn = jax.jit(functools.partial(
-        local_certified_candidates, m=k + MARGIN, interpret=False, **kw))
+        local_certified_candidates, m=k + margin_of(k), interpret=False, **kw))
     return fn, (q, db)
 
 
@@ -185,7 +239,6 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from knn_tpu.analysis.widths import lane_tiled
-    from knn_tpu.ops.pallas_knn import TILE_N
     from knn_tpu.parallel.mesh import DB_AXIS, QUERY_AXIS
     from knn_tpu.parallel.sharded import (
         _pallas_certified_program,
@@ -204,7 +257,9 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
           if kk not in ("tile_n", "precision")}
     if terms:
         kw["terms"] = terms
-    tile = knobs["tile_n"] or TILE_N
+    # the depth and the tile as _pallas_setup resolves them: 2 and the
+    # default at every k = 100 shape
+    kw["survivors"], tile = _depth_and_tile(shape, ds, knobs)
     parts = (1 + ("hl" in (terms or "hh+hl+lh"))) if resident else 0
     kw["resident_parts"] = parts
     if shape in AUGMENTED:
@@ -215,18 +270,18 @@ def _spmd_case(shape: str, knobs: dict, devices, mesh_shape, merge: str,
         for key in ("kernel", "include_distances"):
             kw.pop(key, None)
         prog = _pallas_self_program(
-            mesh, k + MARGIN, k, merge, tile, n, queries, interpret=False,
+            mesh, k + margin_of(k), k, merge, tile, n, queries, interpret=False,
             **kw)
     elif shape in VOTED:
         del kw["augmented"], kw["slack_outcome"]
         prog = _pallas_vote_program(
-            mesh, k + MARGIN, k, merge, tile,
+            mesh, k + margin_of(k), k, merge, tile,
             knobs["precision"], n,
             (1.0 / VOTE_TEMPERATURE, VOTE_CLASSES_OUT,
              vote_delta(VOTE_TEMPERATURE, k)), interpret=False, **kw)
     else:
         prog = _pallas_certified_program(
-            mesh, k + MARGIN, k, merge, tile,
+            mesh, k + margin_of(k), k, merge, tile,
             knobs["precision"], n_train=n, interpret=False, **kw)
     q = jax.ShapeDtypeStruct(
         (queries, d), jnp.float32,
@@ -288,16 +343,17 @@ def temporaries_table(shape: str, devices, terms=None, *,
               f", {queries} queries",
               _spmd_case(shape, knobs, devices, (1, 1), "ring", terms,
                          queries=queries, resident=resident))
-             for resident in (False, True) for queries in (NQ, NQ // 4)
+             for resident in (False, True)
+             for queries in (NQ, launch_queries(shape, knobs))
              # a self-join's launch: a sub-batch's rows, operands resident
-             if not self_rows or (resident and queries == NQ // 4)]
+             if not self_rows or (resident and queries != NQ)]
     mesh = Mesh(np.asarray(devices[:1]).reshape(1, 1), (QUERY_AXIS, DB_AXIS))
 
     def aval(shp, dtype, spec):
         return jax.ShapeDtypeStruct(
             shp, dtype, sharding=NamedSharding(mesh, spec))
 
-    m = k + MARGIN
+    m = k + margin_of(k)
     widen = repair_widen(m, n)
     flagged = sharded._SELF_RESELECT_ROWS if self_rows else 16
     cases.append((
@@ -316,9 +372,19 @@ def temporaries_table(shape: str, devices, terms=None, *,
         (sharded._row_operands_program(
             mesh, knobs["tile_n"] or TILE_N, "hl" in (terms or "hh+hl+lh")),
          (aval((n, d), jnp.float32, P(DB_AXIS)),))))
-    return n * d * 4, [
-        (label, fn.lower(*avals).compile().memory_analysis()
-         .temp_size_in_bytes) for label, (fn, avals) in cases]
+    def temporaries(fn, avals):
+        """What the compiled program sets aside, or None where the
+        compiler finds no room for it on the chip at all (a launch of
+        4,096 queries at k = 1,024 asks 17.7 GB for one array)."""
+        try:
+            return (fn.lower(*avals).compile().memory_analysis()
+                    .temp_size_in_bytes)
+        except jax.errors.JaxRuntimeError as e:
+            if "RESOURCE_EXHAUSTED" not in str(e):
+                raise
+            return None
+
+    return n * d * 4, [(label, temporaries(*case)) for label, case in cases]
 
 
 def _shard_width(shape: str, db_shards: int) -> int:
@@ -345,7 +411,7 @@ def _merge_case(shape: str, db_shards: int, devices):
 
     k = SHAPES[shape][2]
     width = _shard_width(shape, db_shards)
-    geo = pk.select_merge_geometry(width, k + MARGIN)
+    geo = pk.select_merge_geometry(width, k + margin_of(k))
     if geo is None:
         return None
     sh = SingleDeviceSharding(devices[0])
@@ -366,7 +432,7 @@ def _final_case(shape: str, db_shards: int, devices):
 
     from knn_tpu.ops import pallas_knn as pk
 
-    m = SHAPES[shape][2] + MARGIN
+    m = SHAPES[shape][2] + margin_of(SHAPES[shape][2])
     width = _shard_width(shape, db_shards)
     merge = pk.select_merge_geometry(width, m)
     if merge is not None:
@@ -573,7 +639,14 @@ def run_case(name, shape, overrides, expect, mesh, terms, devices, *,
             return _spmd_case(shape, knobs, devices, mesh or (1, 1), merge,
                               terms, queries=NQ // 4, resident=True)
         if mesh is not None:
-            return _spmd_case(shape, knobs, devices, mesh, merge, terms)
+            # a call's 4,096 queries, or the launch the sub-batch rule
+            # cuts it to where that many do not fit one chip (knnlm1m:
+            # 512, its row operands resident)
+            cut = mesh == (1, 1) and launch_queries(shape, knobs) < NQ // 4
+            return _spmd_case(
+                shape, knobs, devices, mesh, merge, terms,
+                **({"queries": launch_queries(shape, knobs),
+                    "resident": True} if cut else {}))
         return _kernel_case(shape, knobs, devices, terms, row_block)
 
     t0 = time.time()
@@ -636,6 +709,7 @@ def main(argv=None) -> int:
     print(f"target: {devices[0].device_kind} x{len(devices)} "
           f"({TOPOLOGY}, deviceless)", flush=True)
     if args.temporaries:
+        from knn_tpu import tuning
         from knn_tpu.analysis import hbm
 
         # what a default call loads beside resident operands: the
@@ -644,11 +718,18 @@ def main(argv=None) -> int:
         # where the operands are NOT kept, and a launch of 4,096
         # queries is an explicit batch_size's or a 16,384-query call's
         # (its temporaries grow with the queries, not with the rows)
-        beside = (f"operands resident, {NQ // 4} queries", "re-select")
         worst = 0.0
         for shape in ([args.shape] if args.shape else TEMPORARIES_SHAPES):
             placed, table = temporaries_table(shape, devices, args.terms)
+            n, given, k = SHAPES[shape]
+            launch = launch_queries(shape, tuning.resolve_full(
+                n, given, k, cache_path=os.devnull)[0])
+            beside = (f"operands resident, {launch} queries", "re-select")
             for label, temp in table:
+                if temp is None:
+                    print(f"TEMP {shape} {label}: over the chip's memory "
+                          f"(the compiler refuses it)", flush=True)
+                    continue
                 counted = any(b in label for b in beside)
                 if counted:
                     worst = max(worst, temp / placed)

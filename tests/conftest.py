@@ -56,9 +56,10 @@ assert len(jax.devices()) >= 8, "test harness requires 8 virtual CPU devices"
 # here call tinyroot.make, and the benchmark's file is not theirs to
 # edit.  benchmark/tests/tiny_filter.py adds the ``sweep_filter`` entry,
 # benchmark/tests/tiny_vote.py the ``sweep_vote`` one and
-# benchmark/tests/tiny_graph.py the ``graph_build`` one (root PERF.md
-# section 7 asks the next benchmark issue for the one-line repair, which
-# deletes this block)
+# benchmark/tests/tiny_graph.py the ``graph_build`` one and
+# benchmark/tests/tiny_topk.py the ``sweep_topk`` one (ROADMAP R0 item 0
+# asks the next benchmark issue for the one-line repair, which deletes
+# this block)
 import sys
 
 _bench_tests = os.path.join(os.path.dirname(os.path.dirname(
@@ -66,6 +67,7 @@ _bench_tests = os.path.join(os.path.dirname(os.path.dirname(
 sys.path.insert(0, _bench_tests)
 import tiny_filter  # noqa: E402,F401
 import tiny_graph  # noqa: E402,F401
+import tiny_topk  # noqa: E402,F401
 import tiny_vote  # noqa: E402,F401
 
 sys.path.remove(_bench_tests)

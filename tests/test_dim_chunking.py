@@ -731,7 +731,8 @@ def test_the_event_says_what_the_kernel_was_given(fresh_registry, rng,
              if kk not in ("interpret", "terms", "mxu_passes", "dim_chunk",
                            "dim_chunks", "row_block", "row_steps",
                            "final_select_stage", "select_merge_short",
-                           "operands", "sub_batch", "batches")}
+                           "operands", "sub_batch", "batches",
+                           "survivor_depth")}
     if kernel == "tiled":
         grids = program_grids(placed, q[:batch], batch_rows=batch,
                               terms=stats["terms"], **knobs)

@@ -72,6 +72,9 @@ PATTERN_SCOPE = {
     " (collective-permute|all-gather|all-reduce)(-start|-done)?\\(":
         sh.SCOPE_MERGE,
     "\\bu32\\[[0-9]+,": radius.SCOPE_RANGE_COMPLETE,
+    # lax.top_k's sort of the candidates against an iota (PR 55)
+    "^%sort[.\\d]* = \\(f32\\[\\d+,31744\\]":
+        pallas_knn.SCOPE_FINAL_SELECT,
 }
 #: readings the sweep drivers sum from a certified call's ``stats``
 FROM_STATS = ("fallback_queries", "rank_corrected_queries")

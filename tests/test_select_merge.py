@@ -336,7 +336,10 @@ def test_bypassed_search_reports_equal_widths():
         1, 1, devices=jax.devices()[:1]), k=K)
     before = merge_batches()
     _, _, stats = prog.search_certified(q, selector="pallas", tile_n=TILE)
-    assert stats["select_width"] == stats["select_merged_width"] == 20 * 256
+    # 20 tiles of 128 bins at m+2 = 130: depth 2 models 5.2 % of queries
+    # on a full bin, so the rule keeps 3 (ops.pallas_knn.survivor_depth)
+    assert stats["survivor_depth"] == 3
+    assert stats["select_width"] == stats["select_merged_width"] == 20 * 384
     after = merge_batches()
     assert (after["true"] - before["true"],
             after["false"] - before["false"]) == (0, 1)

@@ -311,7 +311,8 @@ def test_version_6_winner_naming_a_removed_knob_is_never_used(
             if kk not in ("interpret", "terms", "mxu_passes", "dim_chunk",
                           "dim_chunks", "row_block", "row_steps",
                           "final_select_stage", "select_merge_short",
-                          "operands", "sub_batch", "batches")
+                          "operands", "sub_batch", "batches",
+                           "survivor_depth")
             } == tuning.DEFAULT_KNOBS
     assert (stats["pallas_knobs"]["dim_chunk"],
             stats["pallas_knobs"]["dim_chunks"]) == (128, 1)
