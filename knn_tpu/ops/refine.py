@@ -63,6 +63,16 @@ def _shared_pool() -> ThreadPoolExecutor:
         return _pool
 
 
+def pool_map(fn, parts) -> list:
+    """``[fn(part) for part in parts]``, the parts shared among the
+    re-score pool's threads where there are several; one part runs on
+    the caller's thread."""
+    if len(parts) > 1:
+        # list(): reading every result re-raises a worker's exception
+        return list(_shared_pool().map(fn, parts))
+    return [fn(part) for part in parts]
+
+
 def norms_of_f64(rows64: np.ndarray, out: np.ndarray) -> np.ndarray:
     """``out[j]`` = the Euclidean norm of ``rows64[j]`` (float64 [b, D],
     widened float32 values: the squares are exact, each row's sum is its
@@ -175,12 +185,7 @@ def exact_pair_scores(db_np: np.ndarray, queries_np: np.ndarray,
         _score_members(db_np, queries_np, cand[lo : lo + block],
                        rows[lo : lo + block], metric, out[lo : lo + block])
 
-    if len(starts) > 1:
-        # list(): reading every result re-raises a worker's exception
-        list(_shared_pool().map(score, starts))
-    else:
-        for lo in starts:
-            score(lo)
+    pool_map(score, starts)
     return out
 
 
@@ -336,11 +341,7 @@ def rank_correct_runs(
         return gathered - t0, time.perf_counter() - gathered
 
     with obs.trace.phase(secs, "score_s", PHASE_SCORE):
-        if len(starts) == 1:
-            parts = [score(0)]
-        else:
-            # list(): reading every result re-raises a worker's exception
-            parts = list(_shared_pool().map(score, starts))
+        parts = pool_map(score, starts)
     secs["gather_s"] = sum(g for g, _ in parts)
     secs["arith_s"] = sum(a for _, a in parts)
     with obs.trace.phase(secs, "order_s", PHASE_ORDER):

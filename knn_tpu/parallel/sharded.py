@@ -512,10 +512,17 @@ _RANGE_SPAN = "certified.range_call"
 #: zero column before, the float64 scores after; cosine: the batch's
 #: float64 norms and its float32 unit rows before, nothing after), and
 #: each side as a span and a profiler annotation of its own: only what
-#: lies BEFORE the first launch can go under it
+#: lies BEFORE the first launch can go under it, and a cosine call that
+#: is cut into sub-batches puts it there: ``.before`` is its FIRST
+#: sub-batch's map, ``.under`` the later ones', each made with the
+#: earlier sub-batches' launches already queued (:class:`_UnitQueries`)
 _METRIC_SPAN = "certified.metric_map"
 _METRIC_BEFORE = "certified.metric_map.before"
+_METRIC_UNDER = "certified.metric_map.under"
 _METRIC_AFTER = "certified.metric_map.after"
+#: the seconds a call's ``map_s`` holds, by the child span each is
+_METRIC_SIDES = {"before_s": _METRIC_BEFORE, "under_s": _METRIC_UNDER,
+                 "after_s": _METRIC_AFTER}
 #: the completion of a range call's truncated queries, and where its host
 #: time goes: the phases are summed over the completion's sub-batches by
 #: the call's account and recorded once a call, children of the span
@@ -586,17 +593,24 @@ def _call_account(selector: str, *more: str, voted: bool = False):
         stages=stages)
 
 
+def _metric_map_seconds() -> dict:
+    """A call's ``map_s`` before anything is mapped: the seconds of
+    ``certified.metric_map`` by side, and ``under_batches``, the
+    sub-batches mapped with a launch already queued."""
+    return {**dict.fromkeys(_METRIC_SIDES, 0.0), "under_batches": 0}
+
+
 def _record_metric_map(trace_id, metric: str, map_s: dict) -> None:
     """A dot, cosine or voted call's ``certified.metric_map``: the sum of
-    both sides, a child of the call, and each side (``before_s``,
-    ``after_s`` of ``map_s``) as a child of the sum, 0.0 where a metric
-    has nothing on that side."""
-    obs.record_span(_METRIC_SPAN, trace_id, sum(map_s.values()),
+    its sides, a child of the call, and each side (``before_s``,
+    ``under_s``, ``after_s`` of ``map_s``) as a child of the sum, 0.0
+    where a metric or a call has nothing on that side."""
+    obs.record_span(_METRIC_SPAN, trace_id,
+                    sum(map_s[key] for key in _METRIC_SIDES),
                     parent=_CALL_SPAN, metric=metric, **map_s)
-    obs.record_span(_METRIC_BEFORE, trace_id, map_s["before_s"],
-                    parent=_METRIC_SPAN, metric=metric)
-    obs.record_span(_METRIC_AFTER, trace_id, map_s["after_s"],
-                    parent=_METRIC_SPAN, metric=metric)
+    for key, span in _METRIC_SIDES.items():
+        obs.record_span(span, trace_id, map_s[key], parent=_METRIC_SPAN,
+                        metric=metric)
 
 
 def _staged_fetch(acct=obs.trace.NOOP_ACCOUNT):
@@ -730,16 +744,32 @@ def vote_delta(temperature: float, k: int) -> float:
             + (k + _VOTE_EXP_ULPS) * 2.0 ** -24)
 
 
+def _map_unit_rows(x: np.ndarray, unit: np.ndarray, norms: np.ndarray,
+                   lo: int, hi: int) -> np.ndarray:
+    """Rows ``lo .. hi`` of the cosine map of ``x`` [n, d] float32 into
+    ``unit`` [n, d] float32 and ``norms`` [n] float64.  The norm is
+    ops.refine.norms_of_f64's (so the host's float64 cosines divide by
+    the very numbers the placement divided by), the quotient is taken in
+    float64 and rounded ONCE to float32; a row of zero norm stays zero.
+    Every row's arithmetic is its own, so the values are the same bits
+    however the rows are cut into calls.  Returns the float64 squares'
+    buffer, [hi - lo, d], for the caller to reuse."""
+    rows = x[lo:hi].astype(np.float64)
+    nb = norms[lo:hi]
+    sq = _refine.norms_of_f64(rows, nb)
+    np.divide(rows, nb[:, None], out=rows, where=nb[:, None] > 0)
+    unit[lo:hi] = rows
+    return sq
+
+
 def _unit_rows(x: np.ndarray):
     """Cosine placement of float32 rows ``x`` [n, d]: ``(unit rows [n, d]
     float32, norms [n] float64, the largest squared norm of a unit row
-    as rounded, whether every rounded value is bf16-exact)``.  The norm
-    is ops.refine.norms_of_f64's (so the host's float64 cosines divide
-    by the very numbers the placement divided by), the quotient is taken
-    in float64 and rounded ONCE to float32; a row of zero norm stays
-    zero.  A block of rows at a time (ops.refine._block_rows; the only
-    float64 temporaries), the blocks shared among the re-score pool's
-    threads where there are several: each writes its own rows."""
+    as rounded, whether every rounded value is bf16-exact)``, the rows
+    and norms :func:`_map_unit_rows`'s.  A block of rows at a time
+    (ops.refine._block_rows; the only float64 temporaries), the blocks
+    shared among the re-score pool's threads where there are several:
+    each writes its own rows."""
     from knn_tpu.ops.pallas_knn import lo_halves_zero
 
     x = np.asarray(x, np.float32)
@@ -750,22 +780,109 @@ def _unit_rows(x: np.ndarray):
     starts = range(0, n, block)
 
     def fill(lo: int):
-        rows = x[lo : lo + block].astype(np.float64)
-        nb = norms[lo : lo + block]
-        sq = _refine.norms_of_f64(rows, nb)
-        np.divide(rows, nb[:, None], out=rows, where=nb[:, None] > 0)
+        sq = _map_unit_rows(x, unit, norms, lo, lo + block)
         out = unit[lo : lo + block]
-        out[...] = rows
         np.multiply(out, out, out=sq, dtype=np.float64)
         return float(sq.sum(-1).max()), lo_halves_zero(out)
 
-    if len(starts) > 1:
-        # list(): reading every result re-raises a worker's exception
-        parts = list(_refine._shared_pool().map(fill, starts))
-    else:
-        parts = [fill(lo) for lo in starts]
+    parts = _refine.pool_map(fill, starts)
     return (unit, norms, max((m for m, _ in parts), default=0.0),
             all(z for _, z in parts))
+
+
+#: float32 values below which a part of a sub-batch's map is not worth a
+#: thread of its own: handing a part to the pool costs some tens of
+#: microseconds, which is what the map of this many values takes
+_MAP_PART_ELEMS = 1 << 16
+
+
+def _even_parts(lo: int, hi: int, d: int) -> list:
+    """Rows ``lo .. hi`` of width ``d`` cut EVENLY into ``(lo, hi)``
+    parts for the re-score pool: as many parts as blocks of
+    ops.refine._block_rows (no float64 temporary outgrows one), made up
+    to whole rounds of the pool's threads, so that the map is as long
+    as its rows' share a thread and not as its largest block (1,024
+    rows of 1,536 columns are one block of 682 and one of 342, and four
+    parts of 256 here); one part where the rows are too few to share
+    (``_MAP_PART_ELEMS``)."""
+    n, threads = hi - lo, _refine._POOL_THREADS
+    parts = -(-n // _refine._block_rows(d))
+    parts = -(-parts // threads) * threads
+    parts = max(1, min(parts, n * d // _MAP_PART_ELEMS))
+    step = -(-n // parts)
+    return [(at, min(at + step, hi)) for at in range(lo, hi, step)]
+
+
+class _UnitQueries:
+    """A cosine call's queries on their way to unit rows: ``rows`` [n, d]
+    float32 and ``norms`` [n] float64 (:func:`_unit_rows`' of the whole
+    batch, to the bit) are allocated at once and FILLED in row order as
+    the call asks (:meth:`fill`), which :class:`_QueryBatches` does a
+    sub-batch at a time, each immediately before its dispatch: the
+    device is idle only for the first sub-batch's map, and the later
+    ones run on the host under the launches already queued.  What reads
+    all of ``rows`` or ``norms`` does so after the last sub-batch is
+    dispatched, or asks for all of them first (``_kernel_terms``).
+
+    The call's ``map_s`` gets the seconds: the first fill's to
+    ``before_s`` (phase ``certified.metric_map.before``: nothing of the
+    call is launched yet), every later one's to ``under_s``
+    (``certified.metric_map.under``) and one more ``under_batches``."""
+
+    def __init__(self, host_q: np.ndarray, map_s: dict):
+        self._given, self._map_s = host_q, map_s
+        self.rows = np.empty(host_q.shape, np.float32)
+        self.norms = np.empty(host_q.shape[0])
+        self._filled = 0
+
+    def _map(self, part):
+        _map_unit_rows(self._given, self.rows, self.norms, *part)
+
+    def fill(self, hi: int) -> None:
+        """Map the rows not yet mapped up to row ``hi`` (clipped to the
+        batch), shared evenly among the pool's threads."""
+        lo, hi = self._filled, min(hi, self.rows.shape[0])
+        if lo >= hi:
+            return
+        key = "under_s" if lo else "before_s"
+        with obs.trace.phase(self._map_s, key, _METRIC_SIDES[key]):
+            _refine.pool_map(self._map,
+                             _even_parts(lo, hi, self.rows.shape[1]))
+        if lo:
+            self._map_s["under_batches"] += 1
+        self._filled = hi
+
+
+class _QueryBatches:
+    """The launches of one certified call: ``(lo, chunk, pad)`` a batch
+    of ``bs`` queries, in order: the placed queries ``q_np[lo : lo +
+    bs]``, the last batch padded with ``pad`` zero rows to the one
+    compiled shape.  A sequence that cuts a batch when the loop that
+    dispatches it reaches it, and hands the same batches to every later
+    pass; with ``unit`` (a cosine call, ``q_np`` its ``unit.rows``) the
+    batch's rows are mapped first (:class:`_UnitQueries`), so a
+    sub-batch's map lies immediately before its dispatch."""
+
+    def __init__(self, q_np: np.ndarray, bs: int,
+                 unit: Optional[_UnitQueries] = None):
+        self._q, self._bs, self._unit = q_np, bs, unit
+        self._starts = range(0, q_np.shape[0], bs)
+        self._cut = []
+
+    def __len__(self) -> int:
+        return len(self._starts)
+
+    def __iter__(self):
+        for n, lo in enumerate(self._starts):
+            if n == len(self._cut):
+                if self._unit is not None:
+                    self._unit.fill(lo + self._bs)
+                chunk = self._q[lo : lo + self._bs]
+                pad = self._bs - chunk.shape[0]
+                if pad:  # one compiled shape for the tail too
+                    chunk = np.pad(chunk, ((0, pad), (0, 0)))
+                self._cut.append((lo, chunk, pad))
+            yield self._cut[n]
 
 
 class ShardedKNN:
@@ -1644,16 +1761,22 @@ class ShardedKNN:
                            seconds=time.perf_counter() - t0)
         return self._db_norm_max_cache
 
-    def _kernel_terms(self, q_np: np.ndarray, precision: str) -> str:
+    def _kernel_terms(self, q_np: np.ndarray, precision: str,
+                      unit: Optional[_UnitQueries] = None) -> str:
         """Which products of the "bf16x3" split this call's kernel forms
         (ops.pallas_knn.BF16X3_TERMS), from what the placement's walk
         saw of the rows and what ``q_np`` (the call's queries as the
         program will get them: normalized, augmented) says of itself.
-        Every other precision forms what it always did."""
+        Every other precision forms what it always did.  The queries
+        are read only where the rows' low halves are all zero, which no
+        unit-row placement of real data has: there a cosine call
+        (``unit``, ``q_np`` its rows to be) maps all of them first."""
         from knn_tpu.ops.pallas_knn import bf16x3_terms, lo_halves_zero
 
         self._db_norm_max()
         rows = precision == "bf16x3" and self._rows_lo_zero
+        if rows and unit is not None:
+            unit.fill(q_np.shape[0])
         return bf16x3_terms(rows, rows and lo_halves_zero(q_np))
 
     def _int8_placement(self) -> dict:
@@ -2025,12 +2148,16 @@ class ShardedKNN:
         normalisation's 2^-22 twice), float64 of the rows as given
         wherever the host re-scored (near-tied and repaired entries).
         The span ``certified.metric_map``, one a call, is what the
-        metric adds before the l2 machinery (``before_s``: the batch's
-        float64 norms and its unit rows; ``after_s`` 0: nothing is
-        scored after the repair).  ``stats["pair_slack"]`` is the slack
-        the call ran with, and ``stats["slack_fallback_queries"]``
-        (pallas selector) the queries whose certificate holds without it
-        and fails with it.
+        metric adds to the l2 machinery: the batch's float64 norms and
+        its unit rows, made a sub-batch at a time, each immediately
+        before its dispatch.  ``before_s`` is the FIRST sub-batch's map,
+        the one the device idles through (the whole batch's where the
+        call is one launch); ``under_s`` the later ones', made with the
+        earlier launches queued, ``under_batches`` of them; ``after_s``
+        0: nothing is scored after the repair.  ``stats["pair_slack"]``
+        is the slack the call ran with, and
+        ``stats["slack_fallback_queries"]`` (pallas selector) the
+        queries whose certificate holds without it and fails with it.
 
         **dot / MIPS** has l2's contract: the INDICES equal float64
         brute force in lexicographic (-q.t, index) order over the
@@ -2226,7 +2353,7 @@ class ShardedKNN:
                 from knn_tpu.ops import tagfilter
 
                 ft = tagfilter.check_filter_tags(filter_tags, q_np.shape[0])
-            map_s = {"before_s": 0.0, "after_s": 0.0}
+            map_s = _metric_map_seconds()
             if dot:
                 with obs.trace.phase(map_s, "before_s", _METRIC_BEFORE):
                     # the zero column matching the placed rows'
@@ -2236,12 +2363,14 @@ class ShardedKNN:
                         axis=1)
             # the queries the host ranks with and, for cosine, the
             # float64 norms on both sides: (queries', rows')
-            host_q, norms = q_np, None
+            host_q, norms, unit = q_np, None, None
             if cosine:
-                with obs.trace.phase(map_s, "before_s", _METRIC_BEFORE):
-                    # the unit queries matching the placed unit rows
-                    q_np, q_norms, _, _ = _unit_rows(host_q)
-                    norms = (q_norms, self._cos_norms)
+                # the unit queries matching the placed unit rows: room
+                # for them and their norms here, each sub-batch's filled
+                # immediately before its dispatch (_QueryBatches)
+                unit = _UnitQueries(host_q, map_s)
+                q_np, q_norms = unit.rows, unit.norms
+                norms = (q_norms, self._cos_norms)
             with obs.span("certified.prepare", tid, parent=_CALL_SPAN,
                           first_call=self._db_norm_max_cache is None):
                 # every certified stage runs in squared-L2 space (for
@@ -2252,9 +2381,10 @@ class ShardedKNN:
                 shard_rows = self._shard_rows()
                 # margin is bounded by both the db size and the per-shard
                 # rows the coarse/fallback programs select from (k itself
-                # fits: __init__ checks k <= shard_rows)
-                m = min(self.k + self._margin(margin), self.n_train,
-                        shard_rows)
+                # fits: __init__ checks k <= shard_rows), as the
+                # repair's widened re-select is
+                max_widen = min(self.n_train, shard_rows)
+                m = min(self.k + self._margin(margin), max_widen)
                 db_np = self._host_train()
 
                 if batch_size is not None and batch_size < 1:
@@ -2293,7 +2423,8 @@ class ShardedKNN:
                     # kernel geometry, the compiled program and its
                     # operand tail: resolved in this stage, so the spans
                     # below time batches only
-                    terms = self._kernel_terms(q_np, knobs["precision"])
+                    terms = self._kernel_terms(q_np, knobs["precision"],
+                                               unit)
                     # an inner-product call's scores are made on the
                     # host (below): no distance block leaves the device
                     device_d = return_distances and not dot
@@ -2319,15 +2450,10 @@ class ShardedKNN:
                         index = self._tag_index(self._kernel_tile)
                         mask = self._filter_words(ft, index, interpret,
                                                   tid, acct)
-                batches = []
-                for lo in range(0, n_q, bs):
-                    chunk = q_np[lo : lo + bs]
-                    pad = bs - chunk.shape[0]
-                    if pad:  # one compiled shape for the tail too
-                        chunk = np.pad(chunk, ((0, pad), (0, 0)))
-                    batches.append((lo, chunk, pad))
+                batches = _QueryBatches(q_np, bs, unit)
+            n_batches = len(batches)
             call.set("queries", n_q)
-            call.set("batches", len(batches))
+            call.set("batches", n_batches)
             call.set("metric", self.metric)
             call.set("pair_slack", slack)
             # what the cross-shard merges of this call move: every batch
@@ -2335,8 +2461,9 @@ class ShardedKNN:
             # pallas program, setup's m) or m (the counted coarse
             # select), over the query rows as placed
             q_shards = self.mesh.shape[QUERY_AXIS]
+            launch_rows = -(-bs // q_shards) * q_shards
             merge_bytes = self._record_merge_bytes(
-                len(batches) * (-(-bs // q_shards) * q_shards),
+                n_batches * launch_rows,
                 m_prog + 1 if selector == "pallas" else m)
             if selector == "pallas":
                 bad, n_corrected, n_by_slack = self._certify_pallas(
@@ -2434,7 +2561,7 @@ class ShardedKNN:
                 repair = repair_uncertified(
                     d, i, self.k, m, bad, q_np, db_np,
                     select_fn=_select if ft is None else _select_masked,
-                    max_widen=min(self.n_train, shard_rows),
+                    max_widen=max_widen,
                     db_norm_max=db_norm_max, metric=rank_metric,
                     pair_slack=slack, dot_shift=self._dot_shift,
                     rank_queries=host_q, norms=norms,
@@ -2460,14 +2587,14 @@ class ShardedKNN:
                 obs.counter(
                     _mn.SELECT_MERGE_CALLS,
                     engaged="true" if merged_width < width else "false",
-                ).inc(len(batches))
+                ).inc(n_batches)
                 # the products the kernel's bf16 split formed, read off
                 # the rows and the batch (_kernel_terms): 3 MXU passes,
                 # or 2 or 1 where a low half was all zero
                 merged["terms"] = terms
                 merged["mxu_passes"] = terms.count("+") + 1
                 obs.counter(_mn.KERNEL_TERMS, terms=terms).inc(
-                    len(batches))
+                    n_batches)
                 # how the kernel cut a row tile, by setup's reading:
                 # the columns (one chunk under the tiled kernel,
                 # 128-column chunks under the other two) and the rows
@@ -2481,20 +2608,20 @@ class ShardedKNN:
                 obs.counter(_mn.KERNEL_DIM_CHUNKS,
                             chunks=str(merged["dim_chunks"]),
                             row_steps=str(merged["row_steps"])).inc(
-                    len(batches))
+                    n_batches)
                 # what ran the top-(m+2) over that width: the Pallas
                 # stage or XLA's top_k and gather
                 # (ops.pallas_knn.final_select_geometry)
                 merged["final_select_stage"] = self._final_select_stage
                 obs.counter(_mn.FINAL_SELECT_CALLS,
                             stage=self._final_select_stage).inc(
-                    len(batches))
+                    n_batches)
                 # where the kernel got its row operands: the resident
                 # placement, or the program's own prologue in every call
                 # (_row_operands)
                 merged["operands"] = self._operands_source
                 obs.counter(_mn.KERNEL_OPERANDS,
-                            source=self._operands_source).inc(len(batches))
+                            source=self._operands_source).inc(n_batches)
                 # how many launches the call was cut into and why
                 # (analysis.subbatch.REASONS): the rule's cut, what kept
                 # the rule from cutting, or the caller's batch_size
@@ -2507,13 +2634,13 @@ class ShardedKNN:
                 merged["survivor_depth"] = self._plan["survivor_depth"]
                 merged["bin_overflow_queries"] = self._bin_overflows(
                     i[bad])
-                self._count_launches(len(batches))
+                self._count_launches(n_batches)
             for key, value in merged.items():
                 call.set(key, value)
             stats = {
                 "fallback_queries": int(bad.size),
                 "certified": n_q - int(bad.size),
-                "batches": len(batches),
+                "batches": n_batches,
                 "metric": self.metric,
                 "pair_slack": slack,
                 **repair,
@@ -2558,7 +2685,7 @@ class ShardedKNN:
                     "final_select_stage": merged["final_select_stage"],
                     "select_merge_short": merged["select_merge_short"],
                     "operands": merged["operands"],
-                    "sub_batch": sub_why, "batches": len(batches),
+                    "sub_batch": sub_why, "batches": n_batches,
                     "survivor_depth": merged["survivor_depth"]}
                 stats["tuning"] = tune_info
             # mirror the quality signals into the telemetry registry —
@@ -3404,7 +3531,10 @@ class ShardedKNN:
         :meth:`_pallas_setup`'s, ``ops_tail`` :meth:`_pallas_operands`'s.
         Every sub-batch's program is launched before the first is
         fetched, so the host's share of sub-batch b (the copy down, the
-        unpack, the tie repair) runs while the device is on b+1.  Each
+        unpack, the tie repair) runs while the device is on b+1; a
+        cosine call's ``batches`` (:class:`_QueryBatches`) map a
+        sub-batch's rows of ``q_np`` and ``norms`` as the dispatch loop
+        reaches it, so ``q_np`` is whole only after that loop.  Each
         sub-batch's stages (``certified.dispatch``, ``.device_wait``,
         ``.d2h``, ``.unpack``, ``.rank_correct``) are profiler
         annotations one an occurrence and ONE record a call, the sum
@@ -3424,25 +3554,6 @@ class ShardedKNN:
 
         k = self.k
         fetch = _staged_fetch(acct)
-        if precision in ("int8", "pq") and obs.enabled():
-            # the per-query certified quantization bound ε — the quality
-            # signal the device certificate computes and discards
-            # (quantize.score_error_bound_device / pq's twin):
-            # recomputed host-side (O(Q·D), noise next to the O(Q·N·D)
-            # sweep) and recorded as a distribution so a scraper sees
-            # how tight the bound ran, not just the bench's one max
-            if precision == "pq":
-                from knn_tpu.ops.pq import score_error_bound_pq
-
-                eps = score_error_bound_pq(
-                    q_np, self._pq_placement()["stats"])
-            else:
-                from knn_tpu.ops.quantize import score_error_bound
-
-                pl = self._int8_placement()
-                eps = score_error_bound(q_np, pl["stats"],
-                                        offset=pl["offset"])
-            obs.histogram(_mn.CERTIFIED_QUANT_BOUND).observe_many(eps)
         bad_mask = np.zeros(q_np.shape[0], dtype=bool)
         n_corrected = n_by_slack = 0
         cosine = rank_metric == "cosine"
@@ -3508,6 +3619,28 @@ class ShardedKNN:
                 acct.launched("certified")
                 _hooks.first_call_end(begun, prog, "certified", trace_id,
                                       rows=qp.shape[0])
+
+        if precision in ("int8", "pq") and obs.enabled():
+            # the per-query certified quantization bound ε — the quality
+            # signal the device certificate computes and discards
+            # (quantize.score_error_bound_device / pq's twin):
+            # recomputed host-side (O(Q·D), noise next to the O(Q·N·D)
+            # sweep) and recorded as a distribution so a scraper sees
+            # how tight the bound ran, not just the bench's one max; here,
+            # where every sub-batch is dispatched: a cosine call's q_np
+            # is whole only now (_QueryBatches)
+            if precision == "pq":
+                from knn_tpu.ops.pq import score_error_bound_pq
+
+                eps = score_error_bound_pq(
+                    q_np, self._pq_placement()["stats"])
+            else:
+                from knn_tpu.ops.quantize import score_error_bound
+
+                pl = self._int8_placement()
+                eps = score_error_bound(q_np, pl["stats"],
+                                        offset=pl["offset"])
+            obs.histogram(_mn.CERTIFIED_QUANT_BOUND).observe_many(eps)
 
         # stage 2: per sub-batch — fetch + repair, in dispatch order, the
         # later ones' programs on the device meanwhile
@@ -3710,17 +3843,18 @@ class ShardedKNN:
         with obs.span(_CALL_SPAN, tid, selector="pallas", **told) as call:
             acct = _call_account("pallas", voted=True)
             host_q = np.asarray(queries, dtype=np.float32)
-            map_s = {"before_s": 0.0, "after_s": 0.0}
-            with obs.trace.phase(map_s, "before_s", _METRIC_BEFORE):
-                # the unit queries matching the placed unit rows
-                q_np, q_norms, _, _ = _unit_rows(host_q)
-                norms = (q_norms, self._cos_norms)
+            map_s = _metric_map_seconds()
+            # the unit queries matching the placed unit rows, each
+            # sub-batch's filled immediately before its dispatch
+            unit = _UnitQueries(host_q, map_s)
+            q_np, q_norms = unit.rows, unit.norms
+            norms = (q_norms, self._cos_norms)
             with obs.span("certified.prepare", tid, parent=_CALL_SPAN,
                           first_call=self._db_norm_max_cache is None):
                 n_q = q_np.shape[0]
                 shard_rows = self._shard_rows()
-                m = min(self.k + self._margin(margin), self.n_train,
-                        shard_rows)
+                max_widen = min(self.n_train, shard_rows)
+                m = min(self.k + self._margin(margin), max_widen)
                 db_np = self._host_train()
                 if batch_size is not None and batch_size < 1:
                     raise ValueError(
@@ -3731,7 +3865,7 @@ class ShardedKNN:
                     dtype=self._dtype_key, cache_path=tune_cache,
                     overrides=dict(tile_n=tile_n, precision=precision,
                                    kernel=kernel))
-                terms = self._kernel_terms(q_np, knobs["precision"])
+                terms = self._kernel_terms(q_np, knobs["precision"], unit)
                 prog, m_prog, w, interpret = self._pallas_setup(
                     m - self.k, include_distances=False, terms=terms,
                     batch_rows=batch_size, call_rows=n_q, trace_id=tid,
@@ -3739,20 +3873,16 @@ class ShardedKNN:
                 bs, sub_why = self._sub_batch
                 ops_tail = (self._pallas_operands(knobs["precision"])
                             + (self._vote_labels(),))
-                batches = []
-                for lo in range(0, n_q, bs):
-                    chunk = q_np[lo : lo + bs]
-                    pad = bs - chunk.shape[0]
-                    if pad:  # one compiled shape for the tail too
-                        chunk = np.pad(chunk, ((0, pad), (0, 0)))
-                    batches.append((lo, chunk, pad))
+                batches = _QueryBatches(q_np, bs, unit)
+            n_batches = len(batches)
             call.set("queries", n_q)
-            call.set("batches", len(batches))
+            call.set("batches", n_batches)
             call.set("metric", self.metric)
             call.set("pair_slack", slack)
             q_shards = self.mesh.shape[QUERY_AXIS]
-            merge_bytes = self._record_merge_bytes(
-                len(batches) * (-(-bs // q_shards) * q_shards), m_prog + 1)
+            launch_rows = -(-bs // q_shards) * q_shards
+            merge_bytes = self._record_merge_bytes(n_batches * launch_rows,
+                                                   m_prog + 1)
             classes = np.empty((n_q, classes_out), np.int32)
             totals = np.empty((n_q, classes_out))
             # neighbours of the queries the host re-votes; the rest stay
@@ -3793,7 +3923,7 @@ class ShardedKNN:
                           fallback_queries=int(bad.size)) as sp:
                 repair = repair_uncertified(
                     d, i, self.k, m, bad, q_np, db_np, select_fn=_select,
-                    max_widen=min(self.n_train, shard_rows),
+                    max_widen=max_widen,
                     db_norm_max=db_norm_max, metric="cosine",
                     pair_slack=slack, rank_queries=host_q, norms=norms)
                 sp.set("host_exact_queries",
@@ -3816,12 +3946,12 @@ class ShardedKNN:
                       "boundary": int(boundary.sum()),
                       "margin": int(by_margin.sum()),
                       "fallback": int(bad.size)}
-            merged = self._pallas_call_stats(terms, sub_why, len(batches),
+            merged = self._pallas_call_stats(terms, sub_why, n_batches,
                                              merge_bytes)
             stats = {
                 "fallback_queries": int(bad.size),
                 "certified": n_q - int(bad.size),
-                "batches": len(batches),
+                "batches": n_batches,
                 "metric": self.metric,
                 "pair_slack": slack,
                 **repair, **merged, **told,
@@ -3838,7 +3968,7 @@ class ShardedKNN:
                         "mxu_passes", "dim_chunk", "dim_chunks", "row_block",
                         "row_steps", "final_select_stage",
                         "select_merge_short", "operands", "sub_batch")},
-                    "batches": len(batches)},
+                    "batches": n_batches},
                 "tuning": tune_info,
             }
             for key in (*merged, "vote_boundary_queries",
@@ -3907,7 +4037,8 @@ class ShardedKNN:
                      norms, *, prog, w, ops_tail, told, trace_id, acct):
         """The host side of a voted call, :meth:`_certify_pallas`'s
         shape: every sub-batch's program is launched before the first is
-        fetched; per sub-batch ONE fetch of the answer (``2 classes_out +
+        fetched (``batches`` maps a sub-batch's queries as that loop
+        reaches it, :class:`_QueryBatches`); per sub-batch ONE fetch of the answer (``2 classes_out +
         1`` words a query), the unpack, and where a query is flagged a
         second copy, of the sub-batch's candidate windows, and the
         flagged queries' float64 re-vote (``predict_certified``'s

@@ -79,6 +79,31 @@ def rng():
 
 
 @pytest.fixture
+def call_order(monkeypatch):
+    """What a certified call maps and launches, in the order it does: a
+    list of ``("map", lo, hi)`` for every range of queries a cosine
+    call (or a placement) maps to unit rows and ``("launched",
+    program)`` for every launch its account is told of."""
+    from knn_tpu import obs
+    from knn_tpu.parallel import sharded as sh
+
+    log = []
+    real_map, real_launched = sh._map_unit_rows, obs.trace.CallAccount.launched
+
+    def mapped(x, unit, norms, lo, hi):
+        log.append(("map", lo, hi))
+        return real_map(x, unit, norms, lo, hi)
+
+    def launched(self, program):
+        log.append(("launched", program))
+        return real_launched(self, program)
+
+    monkeypatch.setattr(sh, "_map_unit_rows", mapped)
+    monkeypatch.setattr(obs.trace.CallAccount, "launched", launched)
+    return log
+
+
+@pytest.fixture
 def mh_spawn(tmp_path):
     """The 2-process CPU ``jax.distributed`` subprocess harness
     (tests/mh_harness.py), pre-gated on the coordinator/KV-store probe:

@@ -275,6 +275,59 @@ def test_every_planted_case_is_answered_as_float64_answers_it(
     np.testing.assert_allclose(totals, want_t, rtol=sh.vote_delta(T, K))
 
 
+VOTE_FLAGS = ["vote_boundary_queries", "vote_margin_queries",
+              "vote_repaired_queries", "fallback_queries", "certified",
+              "slack_fallback_queries"]
+
+
+@pytest.mark.parametrize("corpus,shards,bs", [
+    ("seeded", 1, 12), ("seeded", 4, 12), ("built", 1, 2)])
+def test_a_voted_call_cut_into_sub_batches_answers_as_the_uncut_call(
+        request, corpus, shards, bs):
+    """The queries are mapped a sub-batch at a time, each before its
+    dispatch: classes, totals and every flag are the uncut call's to the
+    bit, and the float64 answer's."""
+    db, labels, q, want = request.getfixturevalue(corpus)
+    prog = place(db, labels, 40 if corpus == "seeded" else N_CLASSES, shards)
+    c1, t1, s1 = softmax(prog, q)
+    cn, tn, sn = softmax(prog, q, batch_size=bs)
+    assert (s1["batches"], sn["batches"]) == (1, -(-len(q) // bs))
+    np.testing.assert_array_equal(c1, want[0])
+    np.testing.assert_array_equal(cn, c1)
+    np.testing.assert_array_equal(tn, t1)
+    assert {f: sn[f] for f in VOTE_FLAGS} == {f: s1[f] for f in VOTE_FLAGS}
+    if corpus == "built":  # every planted flag is raised in both
+        assert s1["vote_repaired_queries"] == sum(
+            any(FLAGS[case]) for case in CASES)
+
+
+@pytest.mark.parametrize("cut", [4, 1])
+def test_a_voted_calls_later_maps_run_under_its_first_launch(
+        seeded, fresh_registry, call_order, cut):
+    db, labels, q, _ = seeded
+    prog = place(db, labels, 40)
+    softmax(prog, q)  # the placement's walk and its programs
+    obs.reset_event_log(None)
+    del call_order[:]
+    bs = len(q) // cut
+    *_, stats = softmax(prog, q, batch_size=bs)
+    assert stats["batches"] == cut
+    steps = [s for s in call_order if s != ("launched", "reselect")]
+    assert steps[: 2 * cut] == [
+        step for lo in range(0, len(q), bs)
+        for step in (("map", lo, lo + bs), ("launched", "certified"))]
+    (whole,) = [e for e in obs.get_event_log().recent()
+                if e.get("span") == "certified.metric_map"]
+    assert whole["under_batches"] == cut - 1 and whole["before_s"] > 0
+    assert (whole["under_s"] > 0) == (cut > 1) and whole["after_s"] == 0
+    assert whole["dur_s"] == pytest.approx(
+        whole["before_s"] + whole["under_s"], abs=2e-6)
+    under, = [e for e in obs.get_event_log().recent()
+              if e.get("span") == "certified.metric_map.under"]
+    assert under["parent"] == "certified.metric_map"
+    assert under["dur_s"] == pytest.approx(whole["under_s"], abs=1e-6)
+
+
 @pytest.mark.parametrize("case", CASES)
 def test_the_certificate_flags_what_it_must_and_nothing_else(built, case):
     db, labels, q, (want_c, want_t) = built

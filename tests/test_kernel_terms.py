@@ -199,7 +199,7 @@ def test_a_byte_corpus_is_searched_exactly_in_fewer_passes(
     assert (call["terms"], call["mxu_passes"]) == (want_terms, passes)
     # the same corpus with the full sum forced answers the same arrays
     real = ShardedKNN._kernel_terms
-    ShardedKNN._kernel_terms = lambda self, q_np, precision: FULL
+    ShardedKNN._kernel_terms = lambda self, q_np, precision, unit=None: FULL
     try:
         d3, i3, s3 = prog.search_certified(q, selector="pallas",
                                            tile_n=TILE, batch_size=3)

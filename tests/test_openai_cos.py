@@ -325,6 +325,184 @@ def test_placement_makes_unit_rows_in_blocks_and_keeps_the_rows_as_given():
     np.testing.assert_array_equal(prog._placed_host(), unit)
 
 
+# --- the batch's map, a sub-batch at a time ---------------------------------
+@pytest.mark.parametrize("n,bs,dim", [(4099, 1031, 300), (2500, 700, 1536)])
+def test_queries_mapped_a_sub_batch_at_a_time_are_the_whole_batchs(n, bs,
+                                                                   dim):
+    """Rows and norms filled as the launches are cut equal
+    ``_unit_rows`` of the whole batch to the bit: a row count that is
+    no multiple of the threads, a zero query in the second sub-batch, a
+    ragged last one."""
+    _, q = mix(16, n, dim=dim)
+    q[bs + 3] = 0.0
+    want_u, want_n, _, _ = sh._unit_rows(q)
+    map_s = sh._metric_map_seconds()
+    unit = sh._UnitQueries(q, map_s)
+    assert unit.rows.shape == q.shape and unit.rows.dtype == np.float32
+    batches = sh._QueryBatches(unit.rows, bs, unit)
+    assert len(batches) == -(-n // bs)
+    for at, (lo, chunk, pad) in enumerate(batches):
+        # mapped as far as this launch and no farther; the launch's rows
+        # are the map's own (a view) but for the padded tail
+        assert unit._filled == min(lo + bs, n)
+        assert map_s["under_batches"] == at
+        take = bs - pad
+        np.testing.assert_array_equal(chunk[:take], want_u[lo : lo + take])
+        assert chunk.shape == (bs, dim) and not chunk[take:].any()
+        assert pad or np.shares_memory(chunk, unit.rows)
+    assert (lo, pad) == (n - n % bs, bs - n % bs)
+    np.testing.assert_array_equal(unit.rows, want_u)
+    np.testing.assert_array_equal(unit.norms, want_n)
+    assert not unit.rows[bs + 3].any() and unit.norms[bs + 3] == 0
+    # a later pass is handed the same launches and maps nothing
+    again = list(batches)
+    assert all(a is b for a, b in zip(again, batches))
+    assert map_s["under_batches"] == len(batches) - 1
+    assert map_s["before_s"] > 0 and map_s["under_s"] > 0
+
+
+@pytest.mark.parametrize("n,dim,parts,first", [
+    (1024, 1536, 4, 256),   # openai500k's sub-batch: one block of 682 and
+    (1024, 768, 4, 256),    # one of 342 by _block_rows; imagenet's: one
+    (4096, 1536, 8, 512),   # an uncut batch: whole rounds of the threads
+    (1001, 1536, 4, 251),   # no multiple of the threads
+    (48, 48, 1, 48),        # too few values to share
+])
+def test_a_sub_batchs_rows_are_shared_evenly_among_the_threads(
+        monkeypatch, n, dim, parts, first):
+    monkeypatch.setattr(refine, "_POOL_THREADS", 4)
+    cut = sh._even_parts(100, 100 + n, dim)
+    assert len(cut) == parts and cut[0] == (100, 100 + first)
+    assert [a for a, _ in cut[1:]] == [b for _, b in cut[:-1]]
+    assert cut[-1][1] == 100 + n
+    assert max(b - a for a, b in cut) <= refine._block_rows(dim)
+    assert max(b - a for a, b in cut) - min(b - a for a, b in cut) < parts
+
+
+def test_an_l2_or_dot_calls_launches_are_cut_as_they_were():
+    q = np.arange(10 * 3, dtype=np.float32).reshape(10, 3)
+    (lo0, c0, p0), (lo1, c1, p1), (lo2, c2, p2) = sh._QueryBatches(q, 4)
+    assert (lo0, lo1, lo2, p0, p1, p2) == (0, 4, 8, 0, 0, 2)
+    assert np.shares_memory(c0, q) and np.shares_memory(c1, q)
+    np.testing.assert_array_equal(c2, np.pad(q[8:], ((0, 2), (0, 0))))
+    assert c0.nbytes == c2.nbytes == 4 * 3 * 4
+    assert len(sh._QueryBatches(q[:0], 4)) == 0
+
+
+FLAGS = ["fallback_queries", "certified", "fallback_genuine_misses",
+         "fallback_false_alarms", "host_exact_queries"]
+
+
+@pytest.mark.parametrize("shards,n,selector", [
+    (1, 3000, "pallas"), (4, 4099, "pallas"), (1, 3000, "approx"),
+    (1, 3000, "exact")])
+def test_a_call_cut_in_four_answers_as_the_uncut_call_to_the_bit(
+        shards, n, selector):
+    db, q = edged(n, 48)
+    want_i, want_c = reference_cos.oracle_topk(db, q, K)
+    prog = ShardedKNN(db, mesh=mesh(shards), k=K, metric="cosine")
+    kw = {"tile_n": TILE} if selector == "pallas" else {}
+    d1, i1, s1 = prog.search_certified(q, selector=selector, **kw)
+    d4, i4, s4 = prog.search_certified(q, selector=selector, batch_size=12,
+                                       **kw)
+    assert (s1["batches"], s4["batches"]) == (1, 4)
+    np.testing.assert_array_equal(i1, want_i)
+    np.testing.assert_array_equal(i4, i1)
+    np.testing.assert_array_equal(d4, d1)
+    flags = FLAGS + (["rank_corrected_queries", "slack_fallback_queries"]
+                     if selector == "pallas" else [])
+    assert {f: s4.get(f) for f in flags} == {f: s1.get(f) for f in flags}
+
+
+def map_spans():
+    events = obs.get_event_log().recent()
+    (whole,) = [e for e in events if e.get("span") == "certified.metric_map"]
+    sides = {e["span"].rsplit(".", 1)[1]: e for e in events
+             if e.get("span", "").startswith("certified.metric_map.")}
+    return whole, sides
+
+
+@pytest.mark.parametrize("cut", [4, 1])
+def test_only_the_first_sub_batchs_map_precedes_the_first_launch(
+        fresh_registry, call_order, cut):
+    db, q = edged(3000, 48)
+    prog = ShardedKNN(db, mesh=mesh(), k=K, metric="cosine")
+    prog.search_certified(q, selector="pallas", tile_n=TILE)  # the walk
+    obs.reset_event_log(None)
+    del call_order[:]
+    bs = 48 // cut
+    _, _, stats = prog.search_certified(q, selector="pallas", tile_n=TILE,
+                                        batch_size=bs)
+    assert stats["batches"] == cut
+    # a sub-batch's map, then its launch: the second map begins with the
+    # first launch queued
+    steps = [s for s in call_order
+             if s[0] == "map" or s == ("launched", "certified")]
+    assert steps[: 2 * cut] == [
+        step for lo in range(0, 48, bs)
+        for step in (("map", lo, lo + bs), ("launched", "certified"))]
+    whole, sides = map_spans()
+    assert set(sides) == {"before", "under", "after"}
+    assert {e["parent"] for e in sides.values()} == {"certified.metric_map"}
+    assert whole["under_batches"] == cut - 1
+    assert whole["before_s"] > 0 and whole["after_s"] == 0
+    assert (whole["under_s"] > 0) == (cut > 1)
+    assert whole["dur_s"] == pytest.approx(
+        whole["before_s"] + whole["under_s"] + whole["after_s"], abs=2e-6)
+    for side, e in sides.items():
+        assert e["dur_s"] == pytest.approx(whole[f"{side}_s"], abs=1e-6)
+
+
+def exact_unit(rng, n, dim=32):
+    """Rows of 4 or 16 entries of +-2^e, the rest zero: norms 2^(e+1)
+    and 2^(e+2), so unit entries +-1/2 and +-1/4, bf16-exact."""
+    x = np.zeros((n, dim), np.float32)
+    for r in range(n):
+        at = rng.choice(dim, size=rng.choice([4, 16]), replace=False)
+        x[r, at] = rng.choice([-1.0, 1.0], size=at.size) * 2.0 ** rng.integers(
+            -3, 4)
+    return x
+
+
+@pytest.mark.parametrize("voted", [False, True])
+def test_rows_with_zero_low_halves_read_every_query_before_the_program(
+        fresh_registry, call_order, voted):
+    """Where the placement's unit rows are all bf16-exact the kernel's
+    products follow the QUERIES' low halves: all of them are mapped
+    first, then the program is chosen.  Here only the last sub-batch has
+    a query that is not bf16-exact."""
+    rng = np.random.default_rng(56)
+    db, q = exact_unit(rng, 2000), exact_unit(rng, 48)
+    q[40:] = rng.normal(size=(8, 32)).astype(np.float32)
+    labels = (np.arange(2000) % 7).astype(np.int32)
+    prog = ShardedKNN(db, mesh=mesh(), k=K, metric="cosine",
+                      **({"labels": labels, "num_classes": 7} if voted
+                         else {}))
+    assert prog._db_norm_max() > 0 and prog._rows_lo_zero
+    del call_order[:]  # the placement's own map
+    if voted:
+        *_, stats = prog.predict_certified(
+            q, vote="softmax", temperature=0.07, classes_out=3,
+            selector="pallas", tile_n=TILE, batch_size=12)
+    else:
+        _, i, stats = prog.search_certified(q, selector="pallas",
+                                            tile_n=TILE, batch_size=12)
+        np.testing.assert_array_equal(
+            i, reference_cos.oracle_topk(db, q, K)[0])
+    assert stats["batches"] == 4
+    assert (stats["terms"], stats["mxu_passes"]) == ("hh+lh", 2)
+    steps = [s for s in call_order
+             if s[0] == "map" or s == ("launched", "certified")]
+    assert steps[:5] == [("map", 0, 48)] + [("launched", "certified")] * 4
+    whole, _ = map_spans()
+    assert (whole["under_batches"], whole["under_s"]) == (0, 0.0)
+    assert whole["before_s"] > 0
+    # with bf16-exact queries alone the kernel drops the low product too
+    *_, stats = prog.search_certified(q[:40], selector="pallas", tile_n=TILE,
+                                      batch_size=10)
+    assert stats["terms"] == "hh"
+
+
 def test_the_pair_slack_is_one_method_for_every_metric():
     db, _ = mix(2000, 4)
     shift = float((db.astype(np.float64) ** 2).sum(-1).max())
@@ -624,7 +802,12 @@ def test_the_metric_rides_the_call_its_span_and_its_counters(fresh_registry):
     for m in maps:
         assert (m["parent"], m["metric"]) == ("certified.call", "cosine")
         assert m["before_s"] > 0 and m["after_s"] == 0
-        assert m["dur_s"] == pytest.approx(m["before_s"], abs=2e-6)
+        assert m["dur_s"] == pytest.approx(m["before_s"] + m["under_s"],
+                                           abs=2e-6)
+    # the first call was cut in three: two sub-batches mapped with a
+    # launch queued; the others are one launch, all of it before
+    assert [m["under_batches"] for m in maps] == [2, 0, 0]
+    assert [m["under_s"] > 0 for m in maps] == [True, False, False]
     hist = series(mn.SPAN_SECONDS)[(("span", "certified.metric_map"),)]
     assert hist["count"] == 3
     # the members the host re-scored: the rank_correct events' own sums
