@@ -143,6 +143,8 @@ FILTER_QUERIES = "knn_tpu_filter_queries_total"
 JOIN_ROWS = "knn_tpu_join_rows_total"
 JOIN_BLOCKS_INFLIGHT = "knn_tpu_join_blocks_inflight"
 FILTER_LIST_IDS = "knn_tpu_filter_list_ids_total"
+FILTER_RANGE_QUERIES = "knn_tpu_filter_range_queries_total"
+FILTER_RANGE_VALID_ROWS = "knn_tpu_filter_range_valid_rows_total"
 
 # --- host-RAM shard tier (knn_tpu.parallel.sharded) --------------------
 HOSTTIER_SWEEPS = "knn_tpu_hosttier_sweeps_total"
@@ -533,6 +535,19 @@ CATALOG = {
         "search_certified(filter_tags=...) (a tag too rare for a "
         "bitmap, ops.tagfilter.bitmap_min_rows): what the program "
         "filter_mask sets one bit apiece for."),
+    FILTER_RANGE_QUERIES: (
+        "counter", ("outcome",),
+        "Queries of search_certified(filter_range=...), by how many of "
+        "the rows whose attribute lies in their range came back: 'full' "
+        "(k rows), 'short' (1 to k-1: fewer than k rows lie in it), "
+        "'empty' (none does).  Every outcome exists from the first such "
+        "call, at 0 where nothing took it."),
+    FILTER_RANGE_VALID_ROWS: (
+        "counter", (),
+        "Placed rows whose attribute lies in the range of a query of "
+        "search_certified(filter_range=...), summed over the queries: "
+        "over knn_tpu_filter_range_queries_total times the placed rows "
+        "it is the selectivity a run saw."),
     KERNEL_OPERANDS: (
         "counter", ("source",),
         "Batches of search_certified(selector='pallas'), by where their "

@@ -219,16 +219,18 @@ PHASE_HOST_SCAN = "certified.repair.host_scan"
 
 
 def _tell_repair(secs: dict, rows: int, selected: int, proven: int,
-                 scanned: int) -> None:
+                 scanned: int, masked: bool = False) -> None:
     """Record what one :func:`repair_uncertified` did on the host, under
     the trace id of the span the caller holds open: both phases (0.0
     where one did not run, so a reader never finds a series missing),
     the candidates the re-select handed over (``selected``) by whether
     the refine gathered them (``rows``) or :func:`_within_reach` left
-    them out, and the queries by what settled them."""
+    them out, and the queries by what settled them.  ``masked``: a
+    filtered call's repair, said on the refine's span."""
     tid = obs.current_span().trace_id
     obs.record_span(PHASE_REFINE, tid, secs.get("refine_s", 0.0),
-                    parent=REPAIR_SPAN, rows=rows, selected=selected)
+                    parent=REPAIR_SPAN, rows=rows, selected=selected,
+                    **({"masked": True} if masked else {}))
     obs.record_span(PHASE_HOST_SCAN, tid, secs.get("host_scan_s", 0.0),
                     parent=REPAIR_SPAN, queries=scanned)
     obs.counter(_mn.REPAIR_REFINE_ROWS, outcome="refined").inc(rows)
@@ -282,7 +284,8 @@ def _host_scan(d, i, k, sb, rank_q, db_np, metric, norms, valid_rows_fn,
     queries ``sb`` (positions in ``d`` / ``i``), written in place, in the
     form the call takes: ``own`` (a self-join: each query's own row id,
     scanned for k + 1 and dropped), a filtered call's ``valid_rows_fn``
-    (each query's valid rows alone), or the plain scan."""
+    (each query's valid rows alone, a cosine call's kept norms cut to
+    them), or the plain scan."""
     if own is not None:
         hd, hi = host_exact_knn(db_np, rank_q[sb], k + 1, metric=metric)
         left = _columns_without(hi, own)
@@ -296,8 +299,10 @@ def _host_scan(d, i, k, sb, rank_q, db_np, metric, norms, valid_rows_fn,
             rows = valid_rows_fn(pos)
             d[pos], i[pos] = np.inf, np.iinfo(np.int64).max
             if rows.size:
-                hd, hi = host_exact_knn(db_np[rows], rank_q[pos][None], k,
-                                        metric=metric)
+                hd, hi = host_exact_knn(
+                    db_np[rows], rank_q[pos][None], k, metric=metric,
+                    norms=None if norms is None else (
+                        norms[0][pos : pos + 1], norms[1][rows]))
                 d[pos, : hd.shape[1]] = hd[0]
                 i[pos, : hd.shape[1]] = rows[hi[0]]
 
@@ -418,7 +423,10 @@ def repair_uncertified(
     a short answer with +inf and the int64 sentinel.  Step 1 there: a
     ``t_k`` of +inf (fewer than k valid rows) keeps every candidate; a
     finite one drops the +inf sentinels past reach, which rank last in
-    the refine anyway.
+    the refine anyway.  Under ``metric="cosine"`` nothing else changes:
+    the slack bounds a PAIR of placed rows, so every inequality above
+    holds among the valid rows as it does among all; the host scan
+    hands :func:`host_exact_knn` the kept norms of the rows it gathers.
 
     ``exclude`` (int ``[B]``, a self-join: the row each flagged query
     IS, parallel.sharded's ``_SelfJoinCall``) takes that one row out by
@@ -489,7 +497,8 @@ def repair_uncertified(
         out["host_exact_queries"] = host_exact
     _tell_repair(secs, int(fi.size),
                  n_bad * (fs.shape[1] - (exclude is not None)),
-                 n_bad - host_exact, host_exact)
+                 n_bad - host_exact, host_exact,
+                 masked=valid_rows_fn is not None)
     return out
 
 

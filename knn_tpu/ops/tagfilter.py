@@ -24,6 +24,16 @@ for an absent one), sets the listed ids' bits and ANDs the two tags.
 
 The host keeps the same index (:func:`invert_bags`) for the repair's
 exact scan (:func:`valid_rows`).
+
+A second maker needs no index at all: a row carries ONE whole number
+(its id, a time stamp: ``ShardedKNN(row_attr=)``) and a query a
+half-open range ``[lo, hi)`` on it.  :func:`range_words` is that
+program's body on one shard, a Pallas kernel (``range_mask``) that
+compares every (query, row) pair and packs 32 outcomes a word, in the
+same layout; :func:`place_attr` lays a shard's attribute out so that the
+kernel reads a word row's 32 row groups as 32 consecutive sublanes
+whatever the row tile; :func:`range_valid_rows` is the host's statement
+of the predicate for the repair's scan.
 """
 
 from __future__ import annotations
@@ -74,6 +84,20 @@ SLOT_NONE, SLOT_ALL, SLOT_TAGS = 0, 1, 2
 
 #: device scope of the mask program
 SCOPE_FILTER_MASK = "knn.filter_mask"
+#: device scope of the range maker's program
+SCOPE_RANGE_MASK = "knn.range_mask"
+
+#: the largest magnitude a row's attribute may have: int32 but for its
+#: least value, which the placement keeps for "no row here" (padding
+#: past the placed rows, bits past a row tile's groups): no range a
+#: query can name holds it (:func:`range_bounds`)
+ATTR_MAX = np.iinfo(np.int32).max
+_NO_ROW = np.iinfo(np.int32).min
+#: queries a grid step of ``range_mask`` takes, and how many of them the
+#: kernel keeps in registers at a time (their bounds and the word being
+#: made: three ``[32, 128]`` arrays, twelve vregs)
+RANGE_BLOCK_Q = 1024
+_RANGE_SUB_Q = 32
 
 
 def bitmap_min_rows(rows_padded: int) -> int:
@@ -393,6 +417,139 @@ def mask_words(filter_tags, slots, bitmaps, list_ptr, list_rows, *,
         return out.reshape(n_q, word_rows * BIN_W)
 
 
+# --- the range maker ---------------------------------------------------------
+def check_row_attr(row_attr, n_rows: int) -> np.ndarray:
+    """int32 ``[n_rows]`` of a caller's ``row_attr`` (one whole number a
+    row), or ValueError: any integer array whose values lie within
+    ``+-ATTR_MAX``."""
+    attr = np.asarray(row_attr)
+    if attr.shape != (n_rows,) or not np.issubdtype(attr.dtype, np.integer):
+        raise ValueError(
+            f"row_attr must be whole numbers of shape [{n_rows}], one a "
+            f"row; got {attr.dtype} {attr.shape}")
+    if attr.size and (int(attr.min()) < -ATTR_MAX
+                      or int(attr.max()) > ATTR_MAX):
+        raise ValueError(
+            f"row_attr is compared on the device as int32: values must "
+            f"lie in [-{ATTR_MAX}, {ATTR_MAX}] (the least int32 is kept "
+            f"for 'no row'); got [{int(attr.min())}, {int(attr.max())}]")
+    return np.ascontiguousarray(attr, dtype=np.int32)
+
+
+def range_bounds(filter_range, n_q: int) -> np.ndarray:
+    """int32 ``[n_q, 2]`` INCLUSIVE bounds ``(lo, last)`` of a caller's
+    half-open ``filter_range`` (whole numbers ``[n_q, 2]``, ``[lo,
+    hi)`` a query, any magnitude), or ValueError.  A row's attribute
+    lies within ``+-ATTR_MAX``, so the bounds are clipped to that; a
+    range no row answers (``lo >= hi``, or wholly outside) becomes
+    ``(ATTR_MAX, -ATTR_MAX)``, which holds no value at all."""
+    fr = np.asarray(filter_range)
+    if fr.shape != (n_q, 2) or not np.issubdtype(fr.dtype, np.integer):
+        raise ValueError(
+            f"filter_range must be whole numbers of shape [{n_q}, 2], a "
+            f"half-open [lo, hi) on the rows' attribute a query; got "
+            f"{fr.dtype} {fr.shape}")
+    fr = fr.astype(np.int64) if fr.dtype != np.uint64 else np.minimum(
+        fr, np.uint64(ATTR_MAX) + np.uint64(1)).astype(np.int64)
+    lo = np.clip(fr[:, 0], -ATTR_MAX, ATTR_MAX + 1)
+    last = np.clip(fr[:, 1], -ATTR_MAX, ATTR_MAX + 1) - 1
+    none = lo > last
+    return np.ascontiguousarray(np.stack(
+        [np.where(none, ATTR_MAX, lo), np.where(none, -ATTR_MAX, last)],
+        axis=1), dtype=np.int32)
+
+
+def range_valid_rows(attr: np.ndarray, lo: int, last: int) -> np.ndarray:
+    """Rows (ascending, int64) whose attribute lies in the inclusive
+    ``[lo, last]`` of :func:`range_bounds`: the host's statement of the
+    predicate, for the repair's exact scan."""
+    return np.flatnonzero((attr >= lo) & (attr <= last)).astype(np.int64)
+
+
+def place_attr(attr: np.ndarray, *, shards: int, shard_rows: int,
+               tile_n: int) -> np.ndarray:
+    """The rows' attribute as each shard keeps it on the device, int32
+    ``[shards, word rows x 32, 128]`` at row tile ``tile_n`` (global row
+    = shard * shard_rows + local): sublane ``b`` of word row ``R`` holds
+    the 128 rows whose validity is bit ``b`` of that word row's 128
+    words (``valid_word_position``), so the maker reads 32 consecutive
+    sublanes a word row whatever the tile.  Wherever ``tile_n % 4096 ==
+    0`` that is the padded attribute itself, 128 rows a sublane.  Rows
+    past the placed ones and bits past a tile's groups hold the value no
+    range holds."""
+    groups = tile_n // BIN_W
+    n_tiles = -(-shard_rows // tile_n)
+    word_rows = valid_words_per_tile(tile_n) // BIN_W  # a tile's
+    out = np.full((shards, n_tiles, word_rows * 32, BIN_W), _NO_ROW,
+                  np.int32)
+    for s in range(shards):
+        own = attr[s * shard_rows:(s + 1) * shard_rows]
+        flat = np.full(n_tiles * tile_n, _NO_ROW, np.int32)
+        flat[:own.size] = own
+        out[s, :, :groups] = flat.reshape(n_tiles, groups, BIN_W)
+    return out.reshape(shards, n_tiles * word_rows * 32, BIN_W)
+
+
+def _range_kernel(lo_ref, last_ref, attr_ref, out_ref):
+    """One word row (32 row groups of 128) of one block of queries a
+    grid step: bit ``b`` of a query's word ``lane`` is ``lo <=
+    attr[b, lane] <= last``.  The queries are walked ``_RANGE_SUB_Q`` at
+    a time so that their bounds and the word being made stay in
+    registers over the 32 compares."""
+
+    def some(s, carry):
+        at = pl.multiple_of(s * _RANGE_SUB_Q, _RANGE_SUB_Q)
+        lo = lo_ref[pl.ds(at, _RANGE_SUB_Q), :]
+        last = last_ref[pl.ds(at, _RANGE_SUB_Q), :]
+
+        def bit(b, acc):
+            a = lax.broadcast_in_dim(attr_ref[pl.ds(b, 1), :], lo.shape,
+                                     (0, 1))
+            ok = lax.bitwise_and(lax.ge(a, lo), lax.le(a, last))
+            return lax.bitwise_or(acc, lax.select(
+                ok, lax.broadcast(lax.shift_left(jnp.int32(1), b),
+                                  ok.shape), lax.full_like(acc, 0)))
+
+        out_ref[pl.ds(at, _RANGE_SUB_Q), :] = lax.fori_loop(
+            0, 32, bit, lax.full_like(lo, 0), unroll=True)
+        return carry
+
+    lax.fori_loop(0, lo_ref.shape[0] // _RANGE_SUB_Q, some, 0)
+
+
+def range_words(bounds, attr_rows, *, interpret: bool) -> jax.Array:
+    """One shard's validity words of a batch, int32 ``[queries, word
+    rows x 128]`` (``ops.pallas_knn.valid_word_position`` at the tile
+    ``attr_rows`` was placed for; the bits, not the sign, are what is
+    read), from ``bounds`` int32 ``[queries, 2]`` (:func:`range_bounds`)
+    and the shard's attribute as :func:`place_attr` laid it out
+    (``[word rows x 32, 128]``): one compare a (query, row) pair, one
+    bit written.  Rows past the shard's placed ones are never set."""
+    n_q = bounds.shape[0]
+    word_rows = attr_rows.shape[0] // 32
+    block_q = min(RANGE_BLOCK_Q, -(-n_q // _RANGE_SUB_Q) * _RANGE_SUB_Q)
+    padded = -(-n_q // block_q) * block_q
+    with jax.named_scope(SCOPE_RANGE_MASK):
+        # the bounds go in as whole vregs, a query a sublane (what the
+        # queries added here get is cut off below)
+        b = jnp.pad(bounds, ((0, padded - n_q), (0, 0)))
+        lo, last = (lax.broadcast_in_dim(b[:, c], (padded, BIN_W), (0,))
+                    for c in (0, 1))
+        q_spec = pl.BlockSpec((block_q, BIN_W), lambda q, r: (q, 0))
+        out = pl.pallas_call(
+            _range_kernel,
+            grid=(padded // block_q, word_rows),
+            in_specs=[q_spec, q_spec,
+                      pl.BlockSpec((32, BIN_W), lambda q, r: (r, 0))],
+            out_specs=pl.BlockSpec((block_q, BIN_W), lambda q, r: (q, r)),
+            out_shape=jax.ShapeDtypeStruct((padded, word_rows * BIN_W),
+                                           jnp.int32),
+            interpret=interpret,
+            name="range_mask",
+        )(lo, last, attr_rows)
+        return out[:n_q]
+
+
 def words_to_valid(words, *, tile_n: int, n_rows: int) -> jax.Array:
     """bool ``[queries, n_rows]`` of a shard's validity words: the
     layout's inverse, by reshapes and shifts alone."""
@@ -468,6 +625,43 @@ def filtered_topk_reference(db, queries, filter_tags, indptr, tags, k: int):
     diff = q[:, None, :] - db[None, :, :]
     d = jnp.einsum("qnd,qnd->qn", diff, diff,
                    precision=lax.Precision.HIGHEST)
+    d = jnp.where(jnp.asarray(valid), d, jnp.inf)
+    kk = min(k, n)
+    neg, idx = lax.top_k(-d, kk)  # ties: the lower index first
+    dk = -neg
+    idx = jnp.where(jnp.isfinite(dk), idx, -1)
+    if kk < k:
+        dk = jnp.pad(dk, ((0, 0), (0, k - kk)), constant_values=jnp.inf)
+        idx = jnp.pad(idx, ((0, 0), (0, k - kk)), constant_values=-1)
+    return np.asarray(dk), np.asarray(idx)
+
+
+def range_topk_reference(db, queries, filter_range, row_attr, k: int,
+                         metric: str = "l2"):
+    """The plain statement of the range filter's contract in
+    ``jax.numpy`` float32 at ``highest`` precision, for the CPU tests:
+    validity a boolean matrix straight from the attribute (``lo <=
+    attr < hi``), the metric over every row (direct-difference squared
+    L2, or the cosine distance ``1 - q.t / (|q| |t|)``, a zero norm at
+    cosine 0), +inf where the attribute is out of range, the first k by
+    (distance, index), padded with index -1 and +inf.  ``[queries,
+    rows]`` temporaries: small inputs only."""
+    db = jnp.asarray(db, jnp.float32)
+    q = jnp.asarray(queries, jnp.float32)
+    n = db.shape[0]
+    attr = np.asarray(row_attr).astype(np.int64)[None, :]
+    fr = np.asarray(filter_range).astype(np.int64)
+    valid = (attr >= fr[:, :1]) & (attr < fr[:, 1:])
+    with jax.default_matmul_precision("highest"):
+        if metric == "cosine":
+            den = (jnp.sqrt(jnp.sum(q * q, axis=-1))[:, None]
+                   * jnp.sqrt(jnp.sum(db * db, axis=-1))[None, :])
+            d = 1.0 - jnp.where(den > 0, (q @ db.T) / jnp.where(
+                den > 0, den, 1.0), 0.0)
+        else:
+            diff = q[:, None, :] - db[None, :, :]
+            d = jnp.einsum("qnd,qnd->qn", diff, diff,
+                           precision=lax.Precision.HIGHEST)
     d = jnp.where(jnp.asarray(valid), d, jnp.inf)
     kk = min(k, n)
     neg, idx = lax.top_k(-d, kk)  # ties: the lower index first

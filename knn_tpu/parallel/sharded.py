@@ -68,7 +68,7 @@ from knn_tpu.parallel.mesh import (
 _INT_SENTINEL = jnp.iinfo(jnp.int32).max
 
 #: queries a masked re-select takes at a time (the repair of a
-#: ``filter_tags`` call): one compiled shape whatever the fallbacks, and
+#: filtered call): one compiled shape whatever the fallbacks, and
 #: a block's unpacked validity is this many bytes a shard row
 _MASKED_RESELECT_ROWS = 64
 
@@ -885,6 +885,124 @@ class _QueryBatches:
             yield self._cut[n]
 
 
+class _CallFilter:
+    """What one ``search_certified`` call holds its queries to, and the
+    ONE place that knows which maker of validity words serves it:
+    ``filter_tags`` against the placed tag index (the Pallas kernel
+    ``filter_mask``) or ``filter_range`` against the placed attribute
+    (the kernel ``range_mask``).  Which it is follows from the call's
+    arguments
+    and nothing else.  Checked at construction (a call that cannot be
+    answered raises there, with what is left); :meth:`place` resolves
+    the maker's placement at the kernel's row tile; :meth:`words` is
+    the maker of a batch's words for the dispatch loop and the repair
+    (``ShardedKNN._filter_words``, whichever the maker);
+    :meth:`valid_rows` the host's statement of the predicate;
+    :meth:`told` says what the call was."""
+
+    def __init__(self, knn: "ShardedKNN", selector: str, filter_tags,
+                 filter_range):
+        if filter_tags is not None and filter_range is not None:
+            raise ValueError(
+                "filter_tags and filter_range together are not built: "
+                "the AND of the two makers' words has no test or cell "
+                "yet; pass one")
+        self.maker = "tags" if filter_tags is not None else "range"
+        name = f"filter_{self.maker}"
+        if self.maker == "tags" and knn._row_tags is None:
+            raise ValueError(
+                "filter_tags needs the rows' tag bags: construct "
+                "ShardedKNN with row_tags=(indptr, tags)")
+        if self.maker == "range" and knn._row_attr is None:
+            raise ValueError(
+                "filter_range needs the rows' attribute: construct "
+                "ShardedKNN with row_attr=<one whole number a row>")
+        if selector != "pallas" or knn.metric == "dot":
+            raise ValueError(
+                f"{name} is applied inside the certified kernel: "
+                f"selector='pallas' on a cosine or l2 placement only "
+                f"(got selector={selector!r}, metric={knn.metric!r}); "
+                f"the counted selectors' two passes and a dot "
+                f"placement's augmented rows take no validity words")
+        self._knn, self._given = knn, (
+            filter_tags if filter_range is None else filter_range)
+
+    def check(self, n_q: int) -> None:
+        """The caller's array, held to the call's ``n_q`` queries: int32
+        ``[n_q, 2]``, tag ids or INCLUSIVE attribute bounds."""
+        from knn_tpu.ops import tagfilter
+
+        self.spec = (tagfilter.check_filter_tags(self._given, n_q)
+                     if self.maker == "tags"
+                     else tagfilter.range_bounds(self._given, n_q))
+
+    def require_tiled(self, kernel: str) -> None:
+        if kernel != "tiled":
+            raise ValueError(
+                f"filter_{self.maker}: kernel={kernel!r} takes no "
+                f"per-query validity words, only the tiled kernel's body "
+                f"applies them; use kernel='tiled'")
+
+    def place(self, tile: int, interpret: bool, trace_id, acct) -> None:
+        """The maker's placement in the layout of the resolved row tile
+        (built by the first call that needs it): the tag index or the
+        placed attribute, each what ``ShardedKNN._filter_words`` takes
+        as ``index``."""
+        knn = self._knn
+        self.index = (knn._tag_index(tile) if self.maker == "tags"
+                      else knn._attr_rows(tile))
+        self._launch = (interpret, trace_id, acct)
+
+    def words(self, sel=None):
+        """``mask(lo, take, rows)`` over the call's queries (``sel``: a
+        subset of them by position, the repair's flagged):
+        ``ShardedKNN._filter_words`` over their tag ids or bounds."""
+        return self._knn._filter_words(
+            self.spec if sel is None else self.spec[sel], self.index,
+            *self._launch)
+
+    def valid_rows(self, pos: int) -> np.ndarray:
+        """Rows (ascending) query ``pos`` of the call may return."""
+        from knn_tpu.ops import tagfilter
+
+        if self.maker == "tags":
+            return tagfilter.valid_rows(
+                *self.index["host"], self._knn.n_train, *self.spec[pos])
+        return tagfilter.range_valid_rows(self._knn._row_attr,
+                                          *self.spec[pos])
+
+    def told(self, found: np.ndarray) -> dict:
+        """What the call was held to, in ``stats["filter"]``'s form,
+        from how many rows each query's answer holds (``found``), the
+        counters told."""
+        knn, n_q = self._knn, found.shape[0]
+        told = {"filter": self.maker,
+                "short": int(((found > 0) & (found < knn.k)).sum()),
+                "empty": int((found == 0).sum())}
+        if self.maker == "tags":
+            from knn_tpu.ops import tagfilter
+
+            queries = _mn.FILTER_QUERIES
+            told.update(tagfilter.lookup_forms(
+                self.index["slots"], self.index["counts"], self.spec))
+            obs.counter(_mn.FILTER_LIST_IDS).inc(told["list_ids"])
+        else:
+            # how many placed rows the ranges hold, summed over the
+            # queries: what selectivity the call saw
+            queries = _mn.FILTER_RANGE_QUERIES
+            ordered = knn._attr_sorted()
+            told["valid_rows"] = int((
+                np.searchsorted(ordered, self.spec[:, 1], side="right")
+                - np.searchsorted(ordered, self.spec[:, 0], side="left")
+            ).clip(0).sum())
+            obs.counter(_mn.FILTER_RANGE_VALID_ROWS).inc(told["valid_rows"])
+        for outcome, n_out in (
+                ("full", n_q - told["short"] - told["empty"]),
+                ("short", told["short"]), ("empty", told["empty"])):
+            obs.counter(queries, outcome=outcome).inc(n_out)
+        return told
+
+
 class ShardedKNN:
     """A placed distributed-KNN program: the database is padded, sharded
     along the db axis, and transferred **once** at construction; every
@@ -927,6 +1045,7 @@ class ShardedKNN:
         n_train: Optional[int] = None,
         hbm_budget_bytes: Optional[int] = None,
         row_tags=None,
+        row_attr=None,
     ):
         # merge strategies resolve explicit > env (KNN_TPU_MERGE /
         # KNN_TPU_DCN_MERGE) > the SCALING.json-measured crossover table
@@ -988,6 +1107,11 @@ class ShardedKNN:
         #: the host (once) and the device form at ONE row tile at a time
         #: (_tag_index); None without ``row_tags``
         self._tag_index_cache: Optional[dict] = None
+        #: the rows' attribute on the device, same lazy discipline: at
+        #: ONE row tile's layout at a time (_attr_rows); None without
+        #: ``row_attr``
+        self._attr_rows_cache: Optional[dict] = None
+        self._attr_sorted_cache: Optional[np.ndarray] = None
         db_shards = hosts * chips
         pre_placed = (
             isinstance(train, jax.Array)
@@ -1186,6 +1310,14 @@ class ShardedKNN:
             from knn_tpu.ops.tagfilter import check_bags
 
             self._row_tags = check_bags(*row_tags, n_train)
+        #: one whole number a row (int32 ``[n_train]``, kept on the host
+        #: for the repair's scan): what a ``filter_range`` query of
+        #: search_certified is held against
+        self._row_attr = None
+        if row_attr is not None:
+            from knn_tpu.ops.tagfilter import check_row_attr
+
+            self._row_attr = check_row_attr(row_attr, n_train)
         #: user-facing query/input dim — dot placements append one norm-
         #: augmentation column, so the rows to place are ``dim_in + 1``
         #: wide
@@ -2031,26 +2163,70 @@ class ShardedKNN:
             self._tag_index_cache = held
         return held
 
+    def _attr_rows(self, tile: int) -> dict:
+        """The rows' attribute as a RESIDENT placement, the sixth lazy
+        one: int32, db-sharded, each shard its own rows' in the layout
+        the range maker reads at row tile ``tile``
+        (ops.tagfilter.place_attr), built the first time a
+        ``filter_range`` call resolves that tile (inside
+        ``certified.prepare``, so in a caller's warm-up) and kept at ONE
+        tile at a time, as the tag index is.  One ``placement.row_attr``
+        event a build.  Returns what :meth:`_filter_words` takes as
+        ``index``: ``{"maker": "range", "tile", "device": (rows,)}``."""
+        held = self._attr_rows_cache
+        if held is not None and held["tile"] == tile:
+            return held
+        from knn_tpu.ops.tagfilter import place_attr
+
+        with self._engines_lock:
+            held = self._attr_rows_cache
+            if held is not None and held["tile"] == tile:
+                return held
+            t0 = time.perf_counter()
+            self._attr_rows_cache = None  # the old form goes first
+            rows = place_attr(self._row_attr, shards=self.db_shards,
+                              shard_rows=self._shard_rows(), tile_n=tile)
+            placed = shard(rows.reshape(-1, rows.shape[2]), self.mesh,
+                           db_axes(self.mesh))
+            jax.block_until_ready(placed)
+            obs.emit_event("placement.row_attr", tile=int(tile),
+                           rows=int(self.n_train), bytes=int(rows.nbytes),
+                           seconds=time.perf_counter() - t0)
+            held = {"maker": "range", "tile": tile, "device": (placed,)}
+            self._attr_rows_cache = held
+        return held
+
     def _filter_words(self, ft: np.ndarray, index: dict, interpret: bool,
                       trace_id, acct):
-        """``mask(lo, take, rows)`` for a ``filter_tags`` call: launches
-        the program ``filter_mask`` on ``ft[lo:lo + take]`` padded to
-        ``rows`` queries (then to the query shards) with a tag no row
-        holds, and returns the words still in flight.  The host side of
-        the launch is the span ``certified.filter_mask``; the flight is
-        the call account's to close."""
-        prog = _filter_mask_program(self.mesh, index["tile"],
-                                    index["list_cap"], interpret)
-        no_row = np.iinfo(np.int32).max
+        """``mask(lo, take, rows)`` for a filtered call: launches the
+        program ``filter_mask`` (the call account's name for the maker
+        of validity words, whichever it is: the tag maker over
+        :meth:`_tag_index`'s ``index``, or the range maker over
+        :meth:`_attr_rows`') on ``ft[lo:lo + take]`` (tag ids, or
+        inclusive attribute bounds) padded to ``rows`` queries (then to
+        the query shards) with queries no row answers, and returns the
+        words still in flight.  The host side of the launch is the span
+        ``certified.filter_mask`` (``maker``: ``tags`` / ``range``);
+        the flight is the call account's to close."""
+        from knn_tpu.ops.tagfilter import ATTR_MAX
+
+        maker = index.get("maker", "tags")
+        if maker == "range":
+            prog = _range_mask_program(self.mesh, interpret)
+            none = (ATTR_MAX, -ATTR_MAX)  # bounds that hold no value
+        else:
+            prog = _filter_mask_program(self.mesh, index["tile"],
+                                        index["list_cap"], interpret)
+            none = (np.iinfo(np.int32).max,) * 2  # a tag no row holds
+        q_shards = self.mesh.shape[QUERY_AXIS]
 
         def mask(lo: int, take: int, rows: int):
             with obs.span("certified.filter_mask", trace_id,
-                          parent=_CALL_SPAN, queries=take):
-                tags = np.full((rows, 2), no_row, np.int32)
-                tags[:take] = ft[lo : lo + take]
-                tp, _ = pad_to_multiple(
-                    tags, self.mesh.shape[QUERY_AXIS], fill=no_row)
-                tp = shard(tp, self.mesh, QUERY_AXIS)
+                          parent=_CALL_SPAN, queries=take, maker=maker):
+                given = np.tile(np.asarray(none, np.int32),
+                                (-(-rows // q_shards) * q_shards, 1))
+                given[:take] = ft[lo : lo + take]
+                tp = shard(given, self.mesh, QUERY_AXIS)
                 begun = _hooks.first_call_begin()
                 words = _retry_transient(
                     lambda: prog(tp, *index["device"]), "filter_mask dispatch")
@@ -2060,6 +2236,13 @@ class ShardedKNN:
             return words
 
         return mask
+
+    def _attr_sorted(self) -> np.ndarray:
+        """The rows' attribute in ascending order (once a placement):
+        how many rows a range holds is two binary searches."""
+        if self._attr_sorted_cache is None:
+            self._attr_sorted_cache = np.sort(self._row_attr)
+        return self._attr_sorted_cache
 
     def _pallas_operands(self, precision: str) -> tuple:
         """The operand tail of the pallas certified program after
@@ -2114,6 +2297,7 @@ class ShardedKNN:
         tune_cache: Optional[str] = None,
         return_sqrt: bool = False,
         filter_tags=None,
+        filter_range=None,
         _under: Optional[tuple] = None,
     ):
         """Exact lexicographic top-k via the certified pipeline, sharded.
@@ -2257,12 +2441,13 @@ class ShardedKNN:
         ``stats["pallas_knobs"]`` / ``stats["tuning"]``.
 
         ``filter_tags`` (int ``[queries, 2]``, -1 for an absent tag; a
-        placement built with ``row_tags``; l2, ``selector="pallas"``,
-        ``kernel="tiled"``) holds each query to the rows whose bag
-        holds EVERY tag it names: the answer is the first k, in
-        lexicographic (float64 squared-L2 distance, index) order, of
-        those rows alone, padded with index -1 and distance +inf where
-        fewer than k qualify; no returned row lacks a tag.  The
+        placement built with ``row_tags``; l2 or cosine,
+        ``selector="pallas"``, ``kernel="tiled"``) holds each query to
+        the rows whose bag holds EVERY tag it names: the answer is the
+        first k, in the metric's lexicographic (float64 distance,
+        index) order, of those rows alone, padded with index -1 and
+        distance +inf where fewer than k qualify; no returned row lacks
+        a tag.  The
         predicate is applied INSIDE the kernel, between the MXU product
         and the bin-select: the program ``filter_mask`` turns the
         batch's tag ids into per-query validity words from the tag
@@ -2272,11 +2457,44 @@ class ShardedKNN:
         it was over the query's valid rows (``_certify_pack_spmd``).
         The repair's re-select lays the same words over its exact
         distances and its host scan reads the query's valid rows only.
-        The other selectors, kernels and metrics refuse it.
         ``stats["filter"]`` says what the batch was: ``filter``
-        (``tags`` / ``none``), ``bitmap_lookups``, ``list_lookups``,
-        ``list_ids``, and how many answers came back ``short`` (1 to
-        k-1 rows) and ``empty``.  ``None`` is the call it always was.
+        (``tags`` / ``range`` / ``none``), ``bitmap_lookups``,
+        ``list_lookups``, ``list_ids``, and how many answers came back
+        ``short`` (1 to k-1 rows) and ``empty``.  ``None`` is the call
+        it always was.
+
+        ``filter_range`` (int ``[queries, 2]``, a half-open ``[lo,
+        hi)`` a query; a placement built with ``row_attr``, one whole
+        number a row, compared by VALUE: the rows need be in no order)
+        holds each query to the rows whose attribute lies in its range,
+        under the same contract and through the same kernel, certificate
+        and repair; ``lo >= hi`` is a query no row answers.  What
+        differs is the maker of the words (:class:`_CallFilter`): the
+        program ``range_mask`` compares every (query, row) pair of a
+        sub-batch on the device, a shard over its own rows, and needs no
+        index.  ``stats["filter"]`` then carries ``valid_rows``, the
+        placed rows in range summed over the queries.  A causal prefix
+        ``row <= t`` is this with the row's position as its attribute,
+        ``lo = 0`` and ``hi = t + 1``.
+
+        Under **cosine** either filter runs on the unit rows with the
+        pair slack as an unfiltered call does.  The slack is a bound A
+        PAIR of placed rows: for any two rows u, v the order of their
+        exact placed distances agrees with the order of their cosines
+        as given once the distances differ by more than
+        ``COS_UNIT_SLACK``, whatever other rows exist.  Every inequality
+        of the certificate compares a kept row with ONE row outside the
+        candidates, so over the valid rows alone each is the inequality
+        it was, over fewer pairs.
+
+        What still refuses either filter, with what is left to build:
+        both at once (the AND of two word arrays), the counted
+        selectors (their two passes take no words), ``kernel=
+        "streaming"`` and ``"fused"`` (only the tiled kernel's body
+        applies the words), a dot placement (the augmented rows'
+        certificate under a mask has no test), a pre-placed array under
+        cosine, the self-join, the weighted vote and range search
+        (none of them takes the argument).
         """
         self._require_resident("search_certified")
         if self.metric == "cosine":
@@ -2316,19 +2534,11 @@ class ShardedKNN:
                 "metrics only")
         if selector not in SELECTORS:
             raise ValueError(f"unknown selector {selector!r}; expected {SELECTORS}")
-        if filter_tags is not None:
-            if self._row_tags is None:
-                raise ValueError(
-                    "filter_tags needs the rows' tag bags: construct "
-                    "ShardedKNN with row_tags=(indptr, tags)")
-            if selector != "pallas" or self.metric not in (
-                    "l2", "sql2", "euclidean"):
-                raise ValueError(
-                    f"filter_tags is applied inside the certified kernel: "
-                    f"selector='pallas' on an l2 placement only (got "
-                    f"selector={selector!r}, metric={self.metric!r}); the "
-                    f"counted selectors' two passes and the cosine and dot "
-                    f"placements take no validity words")
+        # what the call's queries are held to, if anything: checked
+        # here, before anything is timed or placed
+        ft = None
+        if filter_tags is not None or filter_range is not None:
+            ft = _CallFilter(self, selector, filter_tags, filter_range)
         from knn_tpu.ops.certified import repair_uncertified
 
         # a call that is another's first pass runs under that call's
@@ -2345,14 +2555,11 @@ class ShardedKNN:
                       **({"parent": parent} if parent else {})) as call:
             if _under is None:
                 acct = _call_account(
-                    selector, *(("filter_mask",) if filter_tags is not None
-                                else ()))
+                    selector, *(() if ft is None else ("filter_mask",)))
             q_np = np.asarray(queries, dtype=np.float32)
-            ft = mask = index = None
-            if filter_tags is not None:
-                from knn_tpu.ops import tagfilter
-
-                ft = tagfilter.check_filter_tags(filter_tags, q_np.shape[0])
+            masked, mask = ft is not None, None
+            if masked:
+                ft.check(q_np.shape[0])
             map_s = _metric_map_seconds()
             if dot:
                 with obs.trace.phase(map_s, "before_s", _METRIC_BEFORE):
@@ -2428,28 +2635,24 @@ class ShardedKNN:
                     # an inner-product call's scores are made on the
                     # host (below): no distance block leaves the device
                     device_d = return_distances and not dot
-                    if ft is not None and knobs["kernel"] != "tiled":
-                        raise ValueError(
-                            f"filter_tags: kernel={knobs['kernel']!r} takes "
-                            f"no per-query validity words, only the tiled "
-                            f"kernel's body applies them; use "
-                            f"kernel='tiled'")
+                    if masked:
+                        ft.require_tiled(knobs["kernel"])
                     prog, m_prog, w, interpret = self._pallas_setup(
                         m - self.k, include_distances=device_d,
                         terms=terms, batch_rows=batch_size, call_rows=n_q,
                         trace_id=tid, acct=acct, **knobs,
-                        **({"masked": True} if ft is not None else {}))
+                        **({"masked": True} if masked else {}))
                     # the sub-batch: the caller's, or the rule's reading
                     # of what setup resolved (analysis.subbatch)
                     bs, sub_why = self._sub_batch
                     ops_tail = self._pallas_operands(knobs["precision"])
-                    if ft is not None:
-                        # the tag index in the resolved tile's layout
-                        # (built by the first call that needs it), and
-                        # the maker of each batch's words
-                        index = self._tag_index(self._kernel_tile)
-                        mask = self._filter_words(ft, index, interpret,
-                                                  tid, acct)
+                    if masked:
+                        # the maker's placement in the resolved tile's
+                        # layout (built by the first call that needs
+                        # it), and the maker of each batch's words
+                        words_tile = self._kernel_tile
+                        ft.place(words_tile, interpret, tid, acct)
+                        mask = ft.words()
                 batches = _QueryBatches(q_np, bs, unit)
             n_batches = len(batches)
             call.set("queries", n_q)
@@ -2517,15 +2720,15 @@ class ShardedKNN:
                 # the same re-select held to the flagged queries' words:
                 # blocks of _MASKED_RESELECT_ROWS queries (their unpacked
                 # validity is rows x shard rows bytes), each block's
-                # words made anew by filter_mask from its tag ids
+                # words made anew by the call's maker
                 exact = _masked_reselect_program(
                     self.mesh, widen, self.merge, self.n_train,
-                    self.train_tile, index["tile"], self.dcn_merge)
+                    self.train_tile, words_tile, self.dcn_merge)
                 nonlocal merge_bytes
                 fs, fi = [], []
                 with obs.span("certified.repair.reselect", tid,
                               parent="certified.repair", widen=widen,
-                              rows=qb.shape[0]):
+                              rows=qb.shape[0], masked=True):
                     for lo in range(0, qb.shape[0], _MASKED_RESELECT_ROWS):
                         part = qb[lo : lo + _MASKED_RESELECT_ROWS]
                         pad = _MASKED_RESELECT_ROWS - part.shape[0]
@@ -2547,25 +2750,22 @@ class ShardedKNN:
                         fi.append(np.asarray(pi)[: part.shape[0]])
                 return np.concatenate(fs), np.concatenate(fi)
 
-            valid_rows_fn = None
-            if ft is not None:
-                select_mask = self._filter_words(ft[bad], index, interpret,
-                                                 tid, acct)
-                inv = index["host"]
-
-                def valid_rows_fn(pos):
-                    return tagfilter.valid_rows(*inv, self.n_train, *ft[pos])
-
+            # a filtered call's repair: the maker over the flagged
+            # queries alone, and the host's statement of the predicate
+            select_mask = valid_rows_fn = None
+            if masked:
+                select_mask, valid_rows_fn = ft.words(bad), ft.valid_rows
             with obs.span("certified.repair", tid, parent=_CALL_SPAN,
-                          fallback_queries=int(bad.size)) as sp:
+                          fallback_queries=int(bad.size),
+                          **({"masked": True} if masked else {})) as sp:
                 repair = repair_uncertified(
                     d, i, self.k, m, bad, q_np, db_np,
-                    select_fn=_select if ft is None else _select_masked,
+                    select_fn=_select_masked if masked else _select,
                     max_widen=max_widen,
                     db_norm_max=db_norm_max, metric=rank_metric,
                     pair_slack=slack, dot_shift=self._dot_shift,
                     rank_queries=host_q, norms=norms,
-                    **({} if ft is None else {"valid_rows_fn": valid_rows_fn}),
+                    valid_rows_fn=valid_rows_fn,
                 )
                 sp.set("host_exact_queries",
                        repair.get("host_exact_queries", 0))
@@ -2650,27 +2850,19 @@ class ShardedKNN:
             # (the lookups by the form their tag is kept in, the ids the
             # listed ones named, the answers that ran out of valid rows)
             told = {"filter": "none"}
-            if ft is not None:
+            if masked:
                 # rows past the valid ones come back as the sentinel at
                 # +inf: the contract's padding is index -1
                 gone = i >= self.n_train
                 i[gone] = -1
                 d[gone] = np.inf
-                found = self.k - gone.sum(axis=1)
-                told = {"filter": "tags",
-                        **tagfilter.lookup_forms(
-                            index["slots"], index["counts"], ft),
-                        "short": int(((found > 0) & (found < self.k)).sum()),
-                        "empty": int((found == 0).sum())}
-                for outcome, n_out in (
-                        ("full", n_q - told["short"] - told["empty"]),
-                        ("short", told["short"]), ("empty", told["empty"])):
-                    obs.counter(_mn.FILTER_QUERIES, outcome=outcome).inc(
-                        n_out)
-                obs.counter(_mn.FILTER_LIST_IDS).inc(told["list_ids"])
+                found = self.k - gone.sum(axis=1)  # rows an answer holds
+                told = ft.told(found)
             for key, value in told.items():
                 call.set(key, value)
             stats["filter"] = told
+            # which queries the repair answered, by position in the call
+            stats["fallback_positions"] = bad.tolist()
             if selector == "pallas":
                 stats["rank_corrected_queries"] = n_corrected
                 # interpret: the value _pallas_setup resolved and the
@@ -3326,7 +3518,7 @@ class ShardedKNN:
         program themselves) it is ``batch_rows`` as given.
 
         ``masked`` builds the program that takes a batch's validity
-        words after that tail (a ``filter_tags`` call); the resolved row
+        words after that tail (a filtered call); the resolved row
         tile, whose layout the words are in, is ``self._kernel_tile``.
 
         ``vote`` (``predict_certified(vote="softmax")``: ``(1 / T,
@@ -3544,12 +3736,13 @@ class ShardedKNN:
         their own insides (the copies; the buffers, the re-score and the
         ordering), summed likewise.
 
-        ``mask`` (a ``filter_tags`` call: :meth:`_filter_words`' maker
-        of a batch's validity words from its slice of the tag ids)
-        launches the program ``filter_mask`` ahead of each batch's
-        certified program and hands it the words as its last operand;
-        the host waits for the words only once the certified program
-        is queued behind them, to close ``filter_mask``'s flight."""
+        ``mask`` (a filtered call: :meth:`_filter_words`' maker of a
+        batch's validity words from its slice of the tag ids or the
+        ranges) launches the program ``filter_mask`` ahead of each
+        batch's certified program and hands it the words as its last
+        operand; the host waits for the words only once the certified
+        program is queued behind them, to close ``filter_mask``'s
+        flight."""
         from knn_tpu.ops.refine import rank_correct_runs
 
         k = self.k
@@ -4578,10 +4771,11 @@ def _pallas_certified_program(
     kernel's prologue forms them in every call, the program it always
     was.
 
-    ``masked`` (a ``filter_tags`` call; never a caller's choice) appends
+    ``masked`` (a filtered call; never a caller's choice) appends
     the batch's validity words as the LAST operand, ``[queries, db
     shards x words]`` sharded over both axes, each shard's block in the
-    kernel's layout at ``tile_n`` (ops.tagfilter.mask_words): the kernel
+    kernel's layout at ``tile_n`` (ops.tagfilter.mask_words or
+    range_words): the kernel
     scores a row whose bit is 0 at +inf before the bin-select, and
     ``_certify_pack_spmd`` reads a bound of +inf as "every valid row is
     a candidate".  Without it the program is the one it always was,
@@ -4957,6 +5151,30 @@ def _filter_mask_program(mesh: Mesh, tile: int, list_cap: int,
         )
     )
     _hooks.mark_built(prog, f"tile={tile},list_cap={list_cap}")
+    return prog
+
+
+@functools.lru_cache(maxsize=8)
+def _range_mask_program(mesh: Mesh, interpret: bool):
+    """The program ``range_mask``: a batch's ``[queries, 2]`` inclusive
+    attribute bounds to its validity words, ``[queries, db shards x
+    words]`` sharded over both axes, every shard comparing its own
+    rows' attribute (ops.tagfilter.range_words over
+    ``ShardedKNN._attr_rows``' placement, whose shape carries the row
+    tile's layout)."""
+    from knn_tpu.ops.tagfilter import range_words
+
+    dbp = db_axes(mesh)
+    prog = jax.jit(
+        jax.shard_map(
+            functools.partial(range_words, interpret=interpret),
+            mesh=mesh,
+            in_specs=(P(QUERY_AXIS), P(dbp)),
+            out_specs=P(QUERY_AXIS, dbp),
+            check_vma=False,
+        )
+    )
+    _hooks.mark_built(prog, f"maker=range,interpret={interpret}")
     return prog
 
 

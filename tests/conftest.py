@@ -56,8 +56,9 @@ assert len(jax.devices()) >= 8, "test harness requires 8 virtual CPU devices"
 # here call tinyroot.make, and the benchmark's file is not theirs to
 # edit.  benchmark/tests/tiny_filter.py adds the ``sweep_filter`` entry,
 # benchmark/tests/tiny_vote.py the ``sweep_vote`` one and
-# benchmark/tests/tiny_graph.py the ``graph_build`` one and
-# benchmark/tests/tiny_topk.py the ``sweep_topk`` one (ROADMAP R0 item 0
+# benchmark/tests/tiny_graph.py the ``graph_build`` one,
+# benchmark/tests/tiny_topk.py the ``sweep_topk`` one and
+# benchmark/tests/tiny_cosfilter.py the ``sweep_cos_filter`` one (ROADMAP R0 item 0
 # asks the next benchmark issue for the one-line repair, which deletes
 # this block)
 import sys
@@ -65,6 +66,7 @@ import sys
 _bench_tests = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark", "tests")
 sys.path.insert(0, _bench_tests)
+import tiny_cosfilter  # noqa: E402,F401
 import tiny_filter  # noqa: E402,F401
 import tiny_graph  # noqa: E402,F401
 import tiny_topk  # noqa: E402,F401

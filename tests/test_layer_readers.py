@@ -64,11 +64,13 @@ BENCH = _json("BENCHMARK.json")
 #: and those of the programs only some kinds launch
 ALL_SCOPES = SCOPES + (pallas_knn.SCOPE_SELECT_MERGE, sh.SCOPE_MERGE,
                        radius.SCOPE_RANGE_COMPLETE,
-                       tagfilter.SCOPE_FILTER_MASK)
+                       tagfilter.SCOPE_FILTER_MASK,
+                       tagfilter.SCOPE_RANGE_MASK)
 #: the device scope under which the operations a trace pattern names run
 PATTERN_SCOPE = {
     "^%_bin_candidates(\\.\\d+)? = ": pallas_knn.SCOPE_KERNEL,
     "^%filter_mask(\\.\\d+)? = ": tagfilter.SCOPE_FILTER_MASK,
+    "^%range_mask(\\.\\d+)? = ": tagfilter.SCOPE_RANGE_MASK,
     " (collective-permute|all-gather|all-reduce)(-start|-done)?\\(":
         sh.SCOPE_MERGE,
     "\\bu32\\[[0-9]+,": radius.SCOPE_RANGE_COMPLETE,
@@ -223,6 +225,20 @@ def _filtered():
                                          filter_tags=ft, tile_n=TILE)
 
 
+def _ranged():
+    """The cosine call under a range on a shuffled attribute, half the
+    queries at a range few rows lie in."""
+    rng = np.random.default_rng(57)
+    db = rng.normal(size=(3000, 48)).astype(np.float32)
+    db *= rng.uniform(0.5, 2.0, size=(3000, 1)).astype(np.float32)
+    prog = ShardedKNN(db, mesh=_mesh(), k=K, metric="cosine",
+                      row_attr=rng.permutation(3000))
+    q = rng.normal(size=(48, 48)).astype(np.float32)
+    fr = np.tile([[30, 3000], [2970, 3000]], (24, 1))
+    return lambda: prog.search_certified(q, selector="pallas",
+                                         filter_range=fr, tile_n=TILE)
+
+
 def _voted():
     rng = np.random.default_rng(48)
     db = rng.normal(size=(3000, 48)).astype(np.float32)
@@ -244,8 +260,8 @@ def _self():
 
 
 BUILDERS = {"l2": _l2, "dot": _metric("dot"), "cosine": _metric("cosine"),
-            "range": _range, "filtered": _filtered, "voted": _voted,
-            "self": _self}
+            "range": _range, "filtered": _filtered, "ranged": _ranged,
+            "voted": _voted, "self": _self}
 #: the query kind of a cell, by its traffic file's kind and its metric
 KIND_OF_CELL = {}
 for _cell in BENCH["workloads"]:
@@ -253,6 +269,7 @@ for _cell in BENCH["workloads"]:
     _config = _json("benchmark", "configs", _cell["config"] + ".json")
     KIND_OF_CELL[_cell["name"]] = {
         "sweep_range": "range", "sweep_filter": "filtered",
+        "sweep_cos_filter": "ranged",
         "sweep_vote": "voted", "graph_build": "self"}.get(
             _traffic["kind"], _config["metric"])
 
@@ -285,7 +302,7 @@ def deltas():
     obs.reset()
 
 
-def test_the_cells_are_the_seven_kinds():
+def test_the_cells_are_the_eight_kinds():
     assert set(KIND_OF_CELL.values()) == set(BUILDERS)
 
 

@@ -1023,9 +1023,11 @@ def test_the_held_entries_fit_the_benchmark_and_their_layer_files():
         "metric_map_ms"]["workloads"] + [CELL])
     for e in HELD[1:]:
         # listed since PR 53, as they stand in the held file; PR 55
-        # appended its cell to rank_members_per_query's list
+        # appended its cell to rank_members_per_query's list, PR 57 its
+        # own to both
         later = ["knnlm1m.sweep_k1024"] * (
-            e["name"] == "rank_members_per_query")
+            e["name"] == "rank_members_per_query") + [
+            "openai500k-intfilter.sweep_cos_filter"]
         assert listed[e["name"]] == dict(e, workloads=[CELL] + later)
         assert e["workloads"] == [CELL]
         assert _json("benchmark", "layers", f"{e['name']}.json")[

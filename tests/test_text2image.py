@@ -453,6 +453,65 @@ def test_the_mask_program_compiles_at_the_filter_cells_shape(one_chip):
     assert "%filter_mask" in text
 
 
+def test_the_masked_kernel_compiles_cut_by_rows_at_the_range_cells_shape(
+        one_chip):
+    """The masked kernel where the row tile is cut in four steps
+    (``openai500k-intfilter.sweep_cos_filter``: 1,536 columns, both
+    resident halves, a sub-batch's 1,024 queries): a step's block of the
+    words is a quarter of a tile's, which the one-step filter cell never
+    asks of Mosaic."""
+    import jax.numpy as jnp
+
+    from knn_tpu.ops import pallas_knn as pk
+
+    rows, dim, queries = 500_000, 1536, 1024
+    rows_p = -(-rows // pk.TILE_N) * pk.TILE_N
+    words = rows_p // pk.TILE_N * pk.valid_words_per_tile(pk.TILE_N)
+    block, steps = pk.row_blocking(
+        dim, tile_n=pk.TILE_N, block_q=256, precision="bf16x3",
+        kernel="tiled", terms="hh+hl+lh", survivors=None, masked=True)
+    assert (block, steps, words) == (4096, 4, 31 * 512)
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    text = pk._bin_candidates.lower(
+        aval((queries, dim), jnp.float32), aval((rows, dim), jnp.float32),
+        block_q=256, tile_n=pk.TILE_N, survivors=None, precision="bf16x3",
+        interpret=False, terms="hh+hl+lh", row_block=block,
+        db_prepared=(aval((rows_p, dim), jnp.bfloat16),
+                     aval((rows_p, dim), jnp.bfloat16),
+                     aval((rows_p,), jnp.float32)),
+        valid_words=aval((queries, words), jnp.int32),
+    ).compile().as_text()
+    assert "%_bin_candidates" in text
+
+
+@pytest.mark.parametrize("queries", [1024, 64])
+def test_the_range_maker_compiles_at_the_range_cells_shape(one_chip,
+                                                           queries):
+    """``range_mask`` (ops.tagfilter.range_words: a dynamic sublane
+    offset a query sub-block, a sublane broadcast a bit) for a
+    sub-batch's 1,024 queries and for the repair's block of 64, over
+    the cell's 124 word rows."""
+    import jax.numpy as jnp
+
+    from knn_tpu.ops import tagfilter
+
+    def aval(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = jax.jit(lambda b, a: tagfilter.range_words(
+        b, a, interpret=False)).lower(
+        aval((queries, 2), jnp.int32), aval((124 * 32, 128), jnp.int32),
+    ).compile()
+    assert "%range_mask" in compiled.as_text()
+    # the words and nothing of their size beside them
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == queries * 124 * 128 * 4
+    assert memory.temp_size_in_bytes < 2 * queries * 128 * 4 + (1 << 16)
+
+
 def _users_of_the_rows(text, rows, width):
     """``(opcode, elements of its largest output, the line)`` of every
     instruction of a compiled module that takes the placed rows (the

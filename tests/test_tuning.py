@@ -335,7 +335,8 @@ def test_default_knobs_are_the_kernel_shaping_arguments():
     assert kwargs_of(
         ShardedKNN.search_certified, "queries", "margin", "selector",
         "batch_size", "return_distances", "recall_target", "tune_cache",
-        "return_sqrt", "filter_tags", "_under") == set(tuning.DEFAULT_KNOBS)
+        "return_sqrt", "filter_tags", "filter_range",
+        "_under") == set(tuning.DEFAULT_KNOBS)
     assert kwargs_of(
         ShardedKNN._pallas_setup, "margin", "include_distances", "terms",
         "batch_rows", "call_rows", "trace_id", "acct", "masked",
