@@ -202,8 +202,9 @@ def _stream_resident(program, q: np.ndarray, k: int, sb_rows: int,
         d = _fetch_or_redispatch(out[0], redo, "join fetch")
         i = np.asarray(cur["out"][1])
         intervals.append((t0, time.perf_counter()))
-        d_out[lo:hi] = d[: hi - lo]
-        i_out[lo:hi] = i[: hi - lo]
+        # (positions among the placed rows become row ids here)
+        d_out[lo:hi], i_out[lo:hi] = program._answers_by_id(
+            d[: hi - lo], i[: hi - lo])
 
     for lo, hi in blocks:
         while len(pending) >= depth:

@@ -561,7 +561,28 @@ def bin_overflow_share(keep: int, bins: int, depth: int) -> float:
     nearest between them).  Counted on uniform rows in
     ``tests/test_knnlm_topk.py``; on the chip ``fallback_pct`` read
     0.014 / 0.11 to 0.22 / 1.06 % where this gives 0.02 / 0.1 / 2.3
-    (``bigann5m``, ``ssnpp2m5``, ``openai500k``; ledger, PR 54)."""
+    (``bigann5m``, ``ssnpp2m5``, ``openai500k``; ledger, PR 54).
+
+    WHEN THE INDEPENDENCE HOLDS.  Over all of a placement's rows, for
+    any placement: a row's lane and tile say nothing of its distance to
+    a query.  Under a PREDICATE it holds only where the rows the
+    predicate keeps lie over the bins as all rows do, and an attribute
+    that follows the rows' order (an id, a time stamp) breaks exactly
+    that: ``id >= 495,000`` of 500,000 kept its 5,000 rows in ONE row
+    tile of 31, 128 of the 3,968 bins this is asked about, and
+    ``fallback_pct`` read 50.5 at
+    ``openai500k-intfilter.sweep_cos_filter`` (ledger, PR 57:
+    ``bin_overflow_share(130, 128, 2)`` is 1.0).  So a placement that
+    is handed an attribute lays its rows out INTERLEAVED
+    (parallel.sharded ``_interleave_order``: one pseudo-random order a
+    row count), which makes a range's rows fall over every bin
+    whatever the attribute's order, and the same cell reads 0.77 to
+    1.81 over twelve seeds, nearly every flagged query on a full bin
+    (three of its 100 nearest in one of 3,968 bins: C(100, 3) /
+    3,968^2 = 1.03 %; my chip runs, PR 58).  A pre-placed array handed
+    an attribute
+    keeps its order, and this model is then the caller's to make true
+    (shuffle before placing)."""
     lam = keep / bins
     # the upper tail summed upward from its first term: 1 - cdf cancels
     # at the small rates this is asked about
@@ -587,7 +608,11 @@ def survivor_depth(
     more insertion step an element on the VPU, and 128 more candidate
     columns a tile for the final select), and where none is (a corpus
     of a few bins) the depth of the least share, the shallowest on
-    equal ones."""
+    equal ones.  The bins counted are the SHARD's: right wherever a
+    query's nearest rows may lie in any of them, which a filtered call
+    owes to the interleaved placement of :func:`bin_overflow_share`'s
+    docstring, not to this rule (reading the depth off the tiles a
+    range meets was tried in PR 57 and taken out)."""
     def at(depth: int) -> Tuple[int, int, float]:
         tile = effective_tile(rows, tile_n, depth, keep)
         return depth, tile, bin_overflow_share(

@@ -162,6 +162,21 @@ def invert_bags(indptr: np.ndarray, tags: np.ndarray
     return inv_indptr, inv_rows
 
 
+def bags_at(inv_indptr: np.ndarray, places: np.ndarray
+            ) -> Tuple[np.ndarray, np.ndarray]:
+    """:func:`invert_bags`' bags with their rows renamed: ``places``
+    [pairs] is ``inv_rows`` through a one-to-one map (where an
+    interleaved placement laid each row), and comes back with each
+    tag's rows ascending again: the bags by POSITION, which
+    :func:`place_arrays` lays out for the device, beside the bags by id
+    that :func:`valid_rows` reads."""
+    tag = np.repeat(np.arange(inv_indptr.size - 1, dtype=np.int64),
+                    np.diff(inv_indptr))
+    key = (tag << 32) | places.astype(np.int64)
+    key.sort()
+    return inv_indptr, (key & 0xFFFFFFFF).astype(np.int32)
+
+
 def check_filter_tags(filter_tags, n_q: int) -> np.ndarray:
     """int32 ``[n_q, 2]`` of a caller's ``filter_tags``, or ValueError."""
     ft = np.asarray(filter_tags)

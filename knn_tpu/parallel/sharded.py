@@ -744,29 +744,72 @@ def vote_delta(temperature: float, k: int) -> float:
             + (k + _VOTE_EXP_ULPS) * 2.0 ** -24)
 
 
+#: the seed of every interleaved placement's row order
+_INTERLEAVE_SEED = 0x1D5
+#: the metrics under which a placement handed ``row_attr`` is
+#: interleaved: those a filtered call runs under (_CallFilter)
+_INTERLEAVED_METRICS = ("l2", "sql2", "euclidean", "cosine")
+
+
+def _interleave_order(n: int) -> np.ndarray:
+    """The row order of an INTERLEAVED placement of ``n`` rows, int32
+    ``[n]``: position ``p`` on the device holds the caller's row
+    ``order[p]``.  ONE pseudo-random permutation a row count (a
+    fixed-seed generator: a function of ``n`` alone), so that the rows
+    any predicate on an attribute keeps fall into the kernel's bins
+    (row tile, lane) independently of the attribute's order among the
+    rows, which is what ops.pallas_knn.bin_overflow_share assumes.  A
+    stride would spread a contiguous range as well and be undone by an
+    attribute of the stride's period; a random order has no such
+    adversary."""
+    return np.random.default_rng(_INTERLEAVE_SEED).permutation(n).astype(
+        np.int32)
+
+
+def _rows_at(table: np.ndarray, rows: np.ndarray) -> np.ndarray:
+    """``table[rows]`` wherever ``rows`` names a placed row, and
+    ``rows`` itself wherever it does not (-1, a pad row, the sentinel):
+    device positions to the caller's ids through an interleaved
+    placement's order, or ids to positions through its inverse."""
+    rows = np.asarray(rows)
+    placed = (rows >= 0) & (rows < table.size)
+    return np.where(placed, table.take(rows, mode="clip"), rows)
+
+
 def _map_unit_rows(x: np.ndarray, unit: np.ndarray, norms: np.ndarray,
-                   lo: int, hi: int) -> np.ndarray:
+                   lo: int, hi: int,
+                   order: Optional[np.ndarray] = None) -> np.ndarray:
     """Rows ``lo .. hi`` of the cosine map of ``x`` [n, d] float32 into
     ``unit`` [n, d] float32 and ``norms`` [n] float64.  The norm is
     ops.refine.norms_of_f64's (so the host's float64 cosines divide by
     the very numbers the placement divided by), the quotient is taken in
     float64 and rounded ONCE to float32; a row of zero norm stays zero.
     Every row's arithmetic is its own, so the values are the same bits
-    however the rows are cut into calls.  Returns the float64 squares'
-    buffer, [hi - lo, d], for the caller to reuse."""
-    rows = x[lo:hi].astype(np.float64)
-    nb = norms[lo:hi]
+    however the rows are cut into calls.  With ``order`` (an interleaved
+    placement, :func:`_interleave_order`) ``unit[p]`` is the unit row of
+    ``x[order[p]]``, gathered a block at a time on its way to float64,
+    and ``norms`` stays in the order of ``x``.  Returns the
+    float64 squares' buffer, [hi - lo, d], for the caller to reuse."""
+    if order is None:
+        rows, nb = x[lo:hi].astype(np.float64), norms[lo:hi]
+    else:
+        rows = x[order[lo:hi]].astype(np.float64)
+        nb = np.empty(rows.shape[0])
     sq = _refine.norms_of_f64(rows, nb)
     np.divide(rows, nb[:, None], out=rows, where=nb[:, None] > 0)
     unit[lo:hi] = rows
+    if order is not None:
+        norms[order[lo:hi]] = nb
     return sq
 
 
-def _unit_rows(x: np.ndarray):
+def _unit_rows(x: np.ndarray, order: Optional[np.ndarray] = None):
     """Cosine placement of float32 rows ``x`` [n, d]: ``(unit rows [n, d]
     float32, norms [n] float64, the largest squared norm of a unit row
     as rounded, whether every rounded value is bf16-exact)``, the rows
-    and norms :func:`_map_unit_rows`'s.  A block of rows at a time
+    and norms :func:`_map_unit_rows`'s (``order``: the unit rows in an
+    interleaved placement's order, the norms in the order given).  A
+    block of rows at a time
     (ops.refine._block_rows; the only float64 temporaries), the blocks
     shared among the re-score pool's threads where there are several:
     each writes its own rows."""
@@ -778,9 +821,11 @@ def _unit_rows(x: np.ndarray):
     norms = np.empty(n)
     block = _refine._block_rows(d)
     starts = range(0, n, block)
+    # (the map's own signature where the rows keep their order)
+    ordered = () if order is None else (order,)
 
     def fill(lo: int):
-        sq = _map_unit_rows(x, unit, norms, lo, lo + block)
+        sq = _map_unit_rows(x, unit, norms, lo, lo + block, *ordered)
         out = unit[lo : lo + block]
         np.multiply(out, out, out=sq, dtype=np.float64)
         return float(sq.sum(-1).max()), lo_halves_zero(out)
@@ -898,7 +943,20 @@ class _CallFilter:
     the maker of a batch's words for the dispatch loop and the repair
     (``ShardedKNN._filter_words``, whichever the maker);
     :meth:`valid_rows` the host's statement of the predicate;
-    :meth:`told` says what the call was."""
+    :meth:`told` says what the call was.
+
+    POSITIONS ON THE DEVICE, IDS ON THE HOST (:class:`ShardedKNN`).
+    What this class hands the DEVICE is laid out as the placed rows
+    are: the attribute through ``ShardedKNN._attr_rows`` (an
+    interleaved placement's in its row order), the words bit for row
+    position.  What it hands the HOST is in the caller's ids:
+    ``spec``, :meth:`valid_rows` (ascending ids, off ``_row_attr`` as
+    given), :meth:`told`'s counts.  It applies no map itself: the
+    repair's re-select maps its result (``search_certified``), and the
+    host scan gathers ``db_np[valid_rows(pos)]`` from the rows as
+    given.  The tag index is the same two-sided thing: its ``host``
+    bags by id, its ``device`` bitmaps and lists by position
+    (``ShardedKNN._tag_index``)."""
 
     def __init__(self, knn: "ShardedKNN", selector: str, filter_tags,
                  filter_range):
@@ -996,11 +1054,45 @@ class _CallFilter:
                 - np.searchsorted(ordered, self.spec[:, 0], side="left")
             ).clip(0).sum())
             obs.counter(_mn.FILTER_RANGE_VALID_ROWS).inc(told["valid_rows"])
+        # whether the placement spread the rows the predicate keeps over
+        # the kernel's bins (ShardedKNN: an interleaved placement)
+        told["interleaved"] = knn._row_order is not None
         for outcome, n_out in (
                 ("full", n_q - told["short"] - told["empty"]),
                 ("short", told["short"]), ("empty", told["empty"])):
             obs.counter(queries, outcome=outcome).inc(n_out)
         return told
+
+
+def _tier_budget(explicit: Optional[int]) -> Optional[int]:
+    """The per-host HBM budget of the host-RAM tier: the constructor's
+    argument, else ``KNN_TPU_HOSTTIER_BUDGET_BYTES``, else None (no
+    bound: everything is placed resident)."""
+    budget = explicit
+    if budget is None:
+        import os as _os
+
+        env_b = _os.environ.get("KNN_TPU_HOSTTIER_BUDGET_BYTES", "").strip()
+        if env_b:
+            try:
+                budget = int(env_b)
+            except ValueError as e:
+                raise ValueError(
+                    f"KNN_TPU_HOSTTIER_BUDGET_BYTES={env_b!r} is not "
+                    f"an int") from e
+    if budget is not None and budget <= 0:
+        raise ValueError(f"hbm_budget_bytes must be > 0, got {budget}")
+    return budget
+
+
+def _outgrows(budget: Optional[int], hosts: int, rows: int, width: int,
+              itemsize: int) -> bool:
+    """Whether a ``[rows, width]`` placement is over the hosts' HBM
+    budgets together (None: no budget, never)."""
+    from knn_tpu.analysis import hbm
+
+    return budget is not None and hbm.placement_bytes(
+        rows, width, itemsize) > budget * hosts
 
 
 class ShardedKNN:
@@ -1027,6 +1119,50 @@ class ShardedKNN:
     where they are placed (:meth:`_place_queries`), the host's copies
     stay at the given width (:meth:`_host_train`), and ``dim_in`` is the
     caller's.  A pre-placed ``jax.Array`` is used as it is handed in.
+
+    **An interleaved placement: positions on the device, ids on the
+    host.**  Host rows handed ``row_attr`` under l2 or cosine (the
+    metrics a filtered call runs under), resident, are laid out on the
+    device in ONE fixed pseudo-random order, a function of the row
+    count alone (:func:`_interleave_order`, ``_row_order``: position
+    ``p`` holds the caller's row ``_row_order[p]``; pad rows stay at
+    the end and ``n_train`` means what it meant): the rows, the placed
+    attribute (:meth:`_attr_rows`), a quantized placement made from
+    them.  A kernel bin is (row tile, lane), and the certificate's
+    survivor depth assumes a query's nearest valid rows fall into the
+    bins independently (ops.pallas_knn.bin_overflow_share); a range on
+    an attribute that follows the rows' order (an id, a time stamp)
+    keeps rows of few tiles and broke that for every query of a narrow
+    range; in a random order the rows ANY range keeps lie over all the
+    bins.  No argument, environment variable or tuning entry: the
+    constructor's ``row_attr`` is the whole statement, a shuffled
+    attribute loses nothing by it, and the order on the device is
+    private.  Everything the HOST holds stays in the caller's order
+    (``_train_host`` / :meth:`_host_train`, ``_row_attr``,
+    ``_cos_norms``, ``_labels_host``), so every float64 stage, the tie
+    order (distance, then the caller's index) and the filter's host
+    scan work on ids as they always did.  A row index becomes an id at
+    the ONE place a path brings it to the host (:meth:`_row_ids`): the
+    unpacked window in ``_certify_pallas``, the counted selectors'
+    coarse candidates, the repair's re-select (plain and masked), the
+    range completion's decoded words, the voted call's candidate
+    windows, and the float32 top-k launches' one exit
+    (:meth:`_answers_by_id`: :meth:`search` and :meth:`radius_search`,
+    the serving engine's buckets, ``knn_join(mode="stream")``; it puts
+    equal distances in id order, and says what k columns cannot give
+    back where more copies of a row tie than there are columns left);
+    what reads a row's BIN from an answer takes the inverse first
+    (:meth:`_row_places`).  What the DEVICE gathers by position lies as
+    the rows do: the attribute, the labels (``predict``, the weighted
+    vote's program), and with ``row_tags`` beside ``row_attr`` the tag
+    index's bitmaps and lists (:meth:`_tag_index`; the host's bags stay
+    by id).  What joins the placed rows to themselves by position has
+    no map built and refuses: the self-join (:meth:`self_join_call`).
+    A placement without ``row_attr``, a dot placement,
+    a pre-placed array (it keeps its order, and with a sorted attribute
+    its full bins) and the host-RAM tier are laid out as given and hold
+    no map: ``_row_order`` is None and :meth:`_row_ids` returns its
+    argument.
     """
 
     def __init__(
@@ -1112,7 +1248,14 @@ class ShardedKNN:
         #: ``row_attr``
         self._attr_rows_cache: Optional[dict] = None
         self._attr_sorted_cache: Optional[np.ndarray] = None
+        #: an INTERLEAVED placement's row order (class docstring): device
+        #: position -> the caller's row id, and its inverse (made by the
+        #: first reader, _bin_overflows); None wherever the rows lie in
+        #: the order given
+        self._row_order: Optional[np.ndarray] = None
+        self._row_place_cache: Optional[np.ndarray] = None
         db_shards = hosts * chips
+        budget = _tier_budget(hbm_budget_bytes)
         pre_placed = (
             isinstance(train, jax.Array)
             and train.sharding.is_equivalent_to(
@@ -1148,6 +1291,20 @@ class ShardedKNN:
                 raise ValueError("n_train is only for pre-placed arrays")
             if not isinstance(train, jax.Array):
                 train = np.asarray(train)  # host padding streams shards on placement
+            # host rows handed an attribute lie INTERLEAVED wherever a
+            # filter can run on them (class docstring): resident, under
+            # l2 or cosine.  The order is drawn here, the rows follow it
+            # below (cosine: inside the unit-row map; l2: one gather)
+            t_order = time.perf_counter()
+            if (row_attr is not None and isinstance(train, np.ndarray)
+                    and metric in _INTERLEAVED_METRICS
+                    and not _outgrows(
+                        budget, hosts,
+                        -(-train.shape[0] // db_shards) * db_shards,
+                        train.shape[1],
+                        4 if metric == "cosine" else train.dtype.itemsize)):
+                self._row_order = _interleave_order(train.shape[0])
+            order_s = time.perf_counter() - t_order
             if metric == "cosine" and isinstance(train, np.ndarray):
                 # cosine distance on row-normalized vectors is squared L2
                 # (||q^-t^||^2 = 2(1-q^.t^)): normalizing ONCE at placement
@@ -1165,7 +1322,7 @@ class ShardedKNN:
                 t_unit = time.perf_counter()
                 given = np.asarray(train, np.float32)
                 train, self._cos_norms, placed_norm_max, lo_zero = (
-                    _unit_rows(given))
+                    _unit_rows(given, self._row_order))
                 self._cos_zero_rows = np.flatnonzero(self._cos_norms == 0)
                 self._cosine_unit = True
                 obs.emit_event(
@@ -1203,6 +1360,15 @@ class ShardedKNN:
             # only if pad rows score far away (ops.pallas_knn.PAD_VAL)
             from knn_tpu.ops.pallas_knn import PAD_VAL
 
+            if self._row_order is not None:
+                if not self._cosine_unit:
+                    # l2: the host keeps the rows as given (above), the
+                    # device gets them in the placement's order
+                    t_order = time.perf_counter()
+                    train = self._as_placed(train)
+                    order_s += time.perf_counter() - t_order
+                obs.emit_event("placement.interleave",
+                               rows=int(train.shape[0]), seconds=order_s)
             tp, n_train = pad_to_multiple(train, db_shards, fill=PAD_VAL)
         # --- host-RAM shard tier (the super-HBM escape hatch) ----------
         # When the placement's per-host share exceeds the HBM budget
@@ -1214,32 +1380,13 @@ class ShardedKNN:
         # sweep's candidates into a running top-k carry.  Every segment
         # pads to ONE shape, so all sweeps share one compiled program.
         self._host_tier: Optional[dict] = None
-        budget = hbm_budget_bytes
-        if budget is None:
-            import os as _os
-
-            env_b = _os.environ.get(
-                "KNN_TPU_HOSTTIER_BUDGET_BYTES", "").strip()
-            if env_b:
-                try:
-                    budget = int(env_b)
-                except ValueError as e:
-                    raise ValueError(
-                        f"KNN_TPU_HOSTTIER_BUDGET_BYTES={env_b!r} is not "
-                        f"an int") from e
-        if budget is not None and budget <= 0:
-            raise ValueError(f"hbm_budget_bytes must be > 0, got {budget}")
         if budget is not None and not isinstance(tp, np.ndarray):
             # the tier streams from HOST memory; a pre-placed /
             # device-resident array has no host rows to stream from.
             # Refuse loudly when it would not fit rather than silently
             # placing a super-budget corpus resident.
-            from knn_tpu.analysis import hbm
-
-            over = hbm.placement_bytes(
-                tp.shape[0], tp.shape[1],
-                int(jnp.dtype(tp.dtype).itemsize)) > budget * hosts
-            if over:
+            if _outgrows(budget, hosts, tp.shape[0], tp.shape[1],
+                         int(jnp.dtype(tp.dtype).itemsize)):
                 raise ValueError(
                     f"hbm_budget_bytes={budget} per host cannot hold this "
                     f"{tp.shape[0]}-row placement, and the host-RAM tier "
@@ -1249,8 +1396,7 @@ class ShardedKNN:
             from knn_tpu.analysis import hbm
 
             itemsize = int(tp.dtype.itemsize)
-            total_b = hbm.placement_bytes(tp.shape[0], tp.shape[1], itemsize)
-            if total_b > budget * hosts:
+            if _outgrows(budget, hosts, tp.shape[0], tp.shape[1], itemsize):
                 import os as _os
 
                 env_d = _os.environ.get(
@@ -1278,6 +1424,13 @@ class ShardedKNN:
                     "itemsize": itemsize,
                 }
                 obs.gauge(_mn.HOSTTIER_SEGMENT_ROWS).set(float(seg_rows))
+        if self._row_order is not None and self._host_tier is not None:
+            # the order was drawn on the word of _outgrows over the
+            # shape the rows were going to take: the tier streams its
+            # segments in the order given and maps nothing
+            raise RuntimeError(
+                f"the host-RAM tier took a {tp.shape} placement that was "
+                f"laid out interleaved as one that fits its budget")
         shard_rows = (
             self._host_tier["segment_rows"] if self._host_tier is not None
             else tp.shape[0]
@@ -1412,8 +1565,11 @@ class ShardedKNN:
                 raise ValueError(
                     f"labels shape {labels.shape} != (n_train,) = ({n_train},)"
                 )
-            self._labels = replicate(labels, mesh)  # the reference's Bcast
-            #: the host's copy, kept: ``predict_certified`` votes from it
+            # the reference's Bcast; the device gathers a neighbour's
+            # label by its position, so they lie as the rows do
+            self._labels = replicate(self._as_placed(labels), mesh)
+            #: the host's copy, kept, by row id: ``predict_certified``
+            #: votes from it
             self._labels_host = labels
 
     @property
@@ -1440,6 +1596,58 @@ class ShardedKNN:
                 f"this placement runs the host-RAM shard tier (corpus "
                 f"exceeds the {self._host_tier['budget_bytes']}-byte "
                 f"per-host HBM budget); use search(), or raise the budget")
+
+    def _row_ids(self, positions: np.ndarray) -> np.ndarray:
+        """Row indices as they leave the device -> the caller's row ids:
+        ``positions`` itself wherever the rows lie in the order given,
+        the interleaved placement's order over them otherwise (-1, pad
+        rows and the sentinel kept).  Applied ONCE a path, where its
+        indices reach the host (class docstring)."""
+        order = self._row_order
+        return positions if order is None else _rows_at(order, positions)
+
+    def _as_placed(self, per_row: np.ndarray) -> np.ndarray:
+        """A host array that holds one entry a row in the caller's
+        order (rows, bytes, the attribute), in the order the rows lie
+        on the device: itself, or a gathered copy where the placement
+        is interleaved."""
+        order = self._row_order
+        return per_row if order is None else per_row[order]
+
+    def _row_places(self, ids: np.ndarray) -> np.ndarray:
+        """The inverse of :meth:`_row_ids`: where the caller's rows
+        ``ids`` lie on the device (what reads a row's shard, tile or
+        bin from an answer)."""
+        if self._row_order is None:
+            return ids
+        if self._row_place_cache is None:
+            place = np.empty_like(self._row_order)
+            place[self._row_order] = np.arange(place.size, dtype=place.dtype)
+            self._row_place_cache = place
+        return _rows_at(self._row_place_cache, ids)
+
+    def _answers_by_id(self, d, i):
+        """The ONE exit of the float32 top-k launches (:meth:`search`
+        and through it :meth:`radius_search`, the serving engine's
+        buckets, ``knn_join(mode="stream")``): a launch's ``(d, i)``
+        [Q, k], pad queries cut off, as ``(d, ids)``.  Wherever the
+        rows lie in the order given that is the launch's own arrays.
+        On an interleaved placement (host arrays) the positions become
+        ids and each query's equal distances are put in id order; the
+        distances are the launch's, column for column.  What a launch
+        of k columns cannot give back: where MORE rows lie at exactly
+        the k-th float32 distance than there are columns left (copies
+        of one row, as a rule), the device kept those of lowest
+        position, which a placement laid out as given makes those of
+        lowest id and this one does not: the ids named are rows at
+        that distance all the same.  ``search_certified`` ranks on the
+        host and keeps the rule whole."""
+        if self._row_order is None:
+            return d, i
+        d, ids = np.asarray(d), self._row_ids(np.asarray(i))
+        order = np.lexsort((ids, d), axis=-1)
+        return (np.take_along_axis(d, order, axis=-1),
+                np.take_along_axis(ids, order, axis=-1))
 
     def _record_merge_bytes(self, n_rows: int, k: int) -> int:
         """Mirror the modeled per-level merge volume into the registry
@@ -1504,6 +1712,11 @@ class ShardedKNN:
         L2-family distances are SQUARED by default (ranking-equivalent,
         the monotone sqrt at knn_mpi.cpp:48 dropped); ``return_sqrt=True``
         returns true Euclidean values matching the reference / sklearn.
+
+        The indices are the caller's row ids on every placement; an
+        interleaved one (``row_attr``) answers with host arrays, and
+        :meth:`_answers_by_id` says which copies of a row it names
+        where more of them tie at the k-th distance than fit.
         """
         k = self.k if k is None else k
         shard_rows = self._shard_rows()
@@ -1522,11 +1735,12 @@ class ShardedKNN:
         )
         self._record_merge_bytes(qp.shape[0], k)
         d, i = _retry_transient(lambda: fn(qp, self._tp), "search dispatch")
+        d, i = self._answers_by_id(d[:n_q], i[:n_q])
         if return_sqrt:
             from knn_tpu.ops.distance import metric_values
 
             d = metric_values(d, self.metric)
-        return d[:n_q], i[:n_q]
+        return d, i
 
     def _search_host_tier(self, queries, k: int, return_sqrt: bool):
         """The host-RAM tier sweep: stream budget-sized db segments
@@ -1837,7 +2051,7 @@ class ShardedKNN:
         rows are read back from the device."""
         if self._cosine_unit and self._tp is not None:
             return self._fetch_rows()
-        return self._host_train()
+        return self._as_placed(self._host_train())
 
     def _fetch_rows(self) -> np.ndarray:
         """The (unpadded) rows read back from the device, cut to the
@@ -1930,8 +2144,8 @@ class ShardedKNN:
                     return self._int8_cache
                 host = self._placed_host()
                 if self._uint8_train is not None:
-                    qr = qz.from_uint8(self._uint8_train)
-                    original = self._uint8_train
+                    original = self._as_placed(self._uint8_train)
+                    qr = qz.from_uint8(original)
                 else:
                     qr = qz.quantize_rows_np(host)
                     original = host
@@ -2122,7 +2336,9 @@ class ShardedKNN:
         rows (CSR by tag) of every other.  That split is the rule of
         ``tagfilter.bitmap_min_rows`` on a list's length and nothing
         sets it.  The host keeps the bags by tag (``host``: one sort,
-        once a placement) for the repair's exact scan; the device form
+        once a placement) for the repair's exact scan, by row id; the
+        device form names a row by its position (the same but on an
+        interleaved placement, tagfilter.bags_at) and
         is kept at ONE tile at a time, as the row operands are.  One
         ``placement.tag_index`` event a build.
 
@@ -2144,8 +2360,13 @@ class ShardedKNN:
                     else tagfilter.invert_bags(*self._row_tags))
             self._tag_index_cache = None  # the old form goes first
             shards = self.db_shards
+            # the device's bitmaps and lists name a row by where it LIES
+            # (an interleaved placement: row_attr beside row_tags), the
+            # host's bags by its id
+            placed = host if self._row_order is None else tagfilter.bags_at(
+                host[0], self._row_places(host[1]))
             arrays = tagfilter.place_arrays(
-                *host, n_train=self.n_train, shards=shards,
+                *placed, n_train=self.n_train, shards=shards,
                 shard_rows=self._shard_rows(), tile_n=tile)
             dbp = db_axes(self.mesh)
             device = (replicate(arrays["slots"], self.mesh),) + tuple(
@@ -2184,7 +2405,9 @@ class ShardedKNN:
                 return held
             t0 = time.perf_counter()
             self._attr_rows_cache = None  # the old form goes first
-            rows = place_attr(self._row_attr, shards=self.db_shards,
+            # (the host's copy stays in the caller's order)
+            rows = place_attr(self._as_placed(self._row_attr),
+                              shards=self.db_shards,
                               shard_rows=self._shard_rows(), tile_n=tile)
             placed = shard(rows.reshape(-1, rows.shape[2]), self.mesh,
                            db_axes(self.mesh))
@@ -2495,6 +2718,19 @@ class ShardedKNN:
         certificate under a mask has no test), a pre-placed array under
         cosine, the self-join, the weighted vote and range search
         (none of them takes the argument).
+
+        Where a range's rows LIE: a placement built from host rows
+        with ``row_attr`` is interleaved (class docstring), so a range
+        on an attribute in the rows' order (``id >= N``) fills no
+        kernel bin more than any other predicate, and the indices
+        returned are the caller's as ever; ``stats["filter"]
+        ["interleaved"]`` says so, ``stats["bin_overflow_queries"]``
+        counts the flagged queries a full bin explains.  A PRE-PLACED
+        array handed ``row_attr`` keeps the order it came in: with a
+        sorted attribute a narrow range's valid rows share one row
+        tile's 128 bins and every such query is repaired (exact, and
+        ten times slower: PERF.md section 6, PRs 57 and 58); shuffle
+        the rows before placing them.
         """
         self._require_resident("search_certified")
         if self.metric == "cosine":
@@ -2714,7 +2950,7 @@ class ShardedKNN:
                     n_b = qb.shape[0]
                     fs = np.asarray(fs)
                     acct.ready("reselect")
-                    return fs[:n_b], np.asarray(fi)[:n_b]
+                    return fs[:n_b], self._row_ids(np.asarray(fi)[:n_b])
 
             def _select_masked(qb, widen):
                 # the same re-select held to the flagged queries' words:
@@ -2748,7 +2984,7 @@ class ShardedKNN:
                         acct.ready("reselect")
                         fs.append(ps[: part.shape[0]])
                         fi.append(np.asarray(pi)[: part.shape[0]])
-                return np.concatenate(fs), np.concatenate(fi)
+                return np.concatenate(fs), self._row_ids(np.concatenate(fi))
 
             # a filtered call's repair: the maker over the flagged
             # queries alone, and the host's statement of the predicate
@@ -3159,7 +3395,8 @@ class ShardedKNN:
                             tile)
                         qs.append(qi)
                         ts.append(ri + s * shard_rows)
-                    qi, ti = np.concatenate(qs), np.concatenate(ts)
+                    qi, ti = np.concatenate(qs), self._row_ids(
+                        np.concatenate(ts))
                 with obs.trace.phase(secs, _RANGE_SCORE, _RANGE_SCORE):
                     sel = ~over[qi]
                     qi, ti = sub[qi[sel]], ti[sel]
@@ -3269,9 +3506,9 @@ class ShardedKNN:
         zero_hit = []  # cosine: queries with a zero row among the candidates
         for (lo, chunk, pad), (qp, (_, ci)) in zip(batches, coarse_out):
             take = bs - pad
-            ci = _fetch_or_redispatch(
+            ci = self._row_ids(_fetch_or_redispatch(
                 ci, lambda q=qp: coarse(q, self._tp)[1], "coarse fetch"
-            )[:take]
+            )[:take])
             acct.ready("counted")
             m_avail = ci.shape[1]
             # refine ALL candidates: ranks k..m feed the gap search
@@ -3381,6 +3618,7 @@ class ShardedKNN:
         (ops.pallas_knn: members strided 128 apart).  Counted, and
         added to ``knn_tpu_certified_bin_overflow_queries_total``."""
         tile, depth = self._plan["row_tile"], self._plan["survivor_depth"]
+        top = self._row_places(top)  # a bin is where a row LIES
         shard, local = np.divmod(top, self._shard_rows())
         bins = (shard * -(-self._shard_rows() // tile) + local // tile
                 ) * 128 + local % 128
@@ -3762,6 +4000,9 @@ class ShardedKNN:
                 gi_np, tight_np, bad_np, dk_np = unpack_certified(
                     packed_np[:take], k, w, want_distances
                 )
+                # positions become the caller's ids here: everything
+                # the host does from now on ranks and scores by id
+                gi_np = self._row_ids(gi_np)
             acct.add(_UNPACK_COPIES, sp.attrs.get("copies_s", 0.0))
             with obs.trace.stage(acct, "certified.rank_correct") as sp:
                 own = {}  # the caller's share of the buffers
@@ -3856,6 +4097,15 @@ class ShardedKNN:
         laid out itself, resident; everything else refuses, with what it
         lacks."""
         self._require_resident("the certified self-join")
+        if self._row_order is not None:
+            raise ValueError(
+                "the certified self-join takes each block's queries out of "
+                "the placed rows by position and takes a query's own row "
+                "out by it; this placement was handed row_attr and its "
+                "rows lie interleaved on the device: blocks of positions "
+                "mapped back to row ids are not built (ROADMAP's table of "
+                "what refuses), construct a second ShardedKNN without "
+                "row_attr for the join")
         if self.metric not in ("l2", "sql2", "euclidean"):
             raise ValueError(
                 f"the certified self-join answers squared-L2 placements: "
@@ -4009,7 +4259,8 @@ class ShardedKNN:
             if self._cos_zero_rows.size:
                 marked = marked.copy()
                 marked[self._cos_zero_rows] = -1 - marked[self._cos_zero_rows]
-                self._vote_labels_dev = replicate(marked, self.mesh)
+                self._vote_labels_dev = replicate(self._as_placed(marked),
+                                                  self.mesh)
             else:
                 self._vote_labels_dev = self._labels
         return self._vote_labels_dev
@@ -4110,7 +4361,8 @@ class ShardedKNN:
                                           rows=bq.shape[0])
                     fs = np.asarray(fs)
                     acct.ready("reselect")
-                    return fs[: qb.shape[0]], np.asarray(fi)[: qb.shape[0]]
+                    return fs[: qb.shape[0]], self._row_ids(
+                        np.asarray(fi)[: qb.shape[0]])
 
             with obs.span("certified.repair", tid, parent=_CALL_SPAN,
                           fallback_queries=int(bad.size)) as sp:
@@ -4265,7 +4517,7 @@ class ShardedKNN:
             with obs.trace.stage(acct, "certified.d2h") as sp:
                 arr = np.asarray(window)
                 sp.set("d2h_bytes", arr.nbytes)
-            return arr[rows].astype(np.int64)
+            return self._row_ids(arr[rows].astype(np.int64))
 
         def repair(lo, pad, out, redo):
             take = bs - pad
@@ -4320,7 +4572,11 @@ class ShardedKNN:
         return flags
 
     def predict(self, queries: jax.Array) -> jax.Array:
-        """Predicted labels [Q] — requires ``labels`` at construction."""
+        """Predicted labels [Q] — requires ``labels`` at construction.
+        The k nearest by (float32 distance, position on the device)
+        vote: on an interleaved placement (``row_attr``) WHICH copies of
+        a row vote, where more of them tie at the k-th distance than
+        fit, is the placement's choice (:meth:`_answers_by_id`)."""
         if self._labels is None:
             raise RuntimeError("ShardedKNN built without labels; predict unavailable")
         self._require_resident("predict")

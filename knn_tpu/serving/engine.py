@@ -134,7 +134,11 @@ class PendingSearch:
             if self._op == "search":
                 d = np.concatenate([p[0] for p in parts])[: self._n]
                 i = np.concatenate([p[1] for p in parts])[: self._n]
-                res = (d, i)
+                # positions among the placed rows become the caller's
+                # row ids here (an interleaved placement: ShardedKNN)
+                by_id = getattr(self._engine.program, "_answers_by_id",
+                                None)
+                res = (d, i) if by_id is None else by_id(d, i)
             else:
                 res = np.concatenate(parts)[: self._n]
         except Exception:
