@@ -10,8 +10,8 @@ here in numpy.
     python chip_smoke.py --chips 4    # one 4-chip host: db- and
                                       # query-sharded meshes vs one chip
 
-It needs a TPU whose device kind the repo knows (obs.roofline
-.PEAKS_BY_KIND) and exits non-zero without one — there is no CPU branch.
+It needs a TPU whose device kind the repo knows (analysis.vmem
+.VMEM_BYTES_BY_KIND) and exits non-zero without one — there is no CPU branch.
 Every wall time it prints is a bring-up timing (compiles included, one
 run each), not a metric.  Any failed check raises; the last line of
 stdout is the JSON verdict, printed only when every leg passed.
@@ -165,7 +165,7 @@ def certified_batch(prog, q, *, expect_q: int, label: str):
     if stats["tuning"]["source"] != "default":
         raise AssertionError(
             f"{label}: knobs came from {stats['tuning']['source']!r} "
-            f"({stats['tuning']['cache_path']}), not the library defaults")
+            f"({stats['tuning'].get('cache_path')}), not the library defaults")
     if knobs["interpret"] is not False:
         raise AssertionError(f"{label}: kernel ran in interpret mode")
     if stats["certified"] + stats["fallback_queries"] != expect_q:
@@ -417,13 +417,13 @@ def main(argv=None) -> int:
         sys.exit(f"chip_smoke.py --chips {args.chips}: only {count} "
                  f"device(s) found")
 
-    from knn_tpu.obs.roofline import PEAKS_BY_KIND
+    from knn_tpu.analysis.vmem import VMEM_BYTES_BY_KIND
     from knn_tpu.parallel import make_mesh
     from knn_tpu.utils.compat import enable_compile_cache
 
-    if dev.device_kind not in PEAKS_BY_KIND:
+    if dev.device_kind not in VMEM_BYTES_BY_KIND:
         sys.exit(f"chip_smoke.py: device kind {dev.device_kind!r} is not "
-                 f"in obs.roofline.PEAKS_BY_KIND")
+                 f"in analysis.vmem.VMEM_BYTES_BY_KIND")
     _listen_to_compiles(jax)
     say(f"compile cache: {enable_compile_cache()}")
 

@@ -16,9 +16,8 @@ six registered checkers over a small framework (docs/ANALYSIS.md):
 - ``jax-hygiene`` — wall-clock reads, host syncs inside ``@hot_path``
   functions (:mod:`knn_tpu.analysis.annotations`), unhashable static
   args;
-- ``vmem-budget`` — every autotuner knob-grid candidate priced against
-  per-device-kind VMEM (:mod:`knn_tpu.analysis.vmem`; ``autotune()``
-  refuses over-budget candidates before timing);
+- ``vmem-budget`` — the default knob set priced against the target
+  device kind's VMEM (:mod:`knn_tpu.analysis.vmem`);
 - ``artifact-lockstep`` — the artifact pipeline in lockstep with its
   declarative schema catalog (:mod:`knn_tpu.analysis.artifacts`):
   every key an emitter writes into a cataloged bench block resolves in

@@ -2,16 +2,16 @@
 artifact block the repo emits or validates, and the generic engine that
 validates them.
 
-Six PRs grew six hand-rolled ``validate_*_block`` functions (roofline,
-calibration, knee, mutation, multihost, ...), each one more hand-checked
-contract between an emitter (knee.py / roofline.py / fleet.py / the
-autotuner), its validator and the docs — the class of drift the
+Hand-rolled ``validate_*_block`` functions (knee, mutation,
+multihost, ...) were each one more hand-checked
+contract between an emitter (knee.py / fleet.py / ...), its
+validator and the docs — the class of drift the
 switch/metric catalogs killed elsewhere.  This module applies the same
 cure:
 
 - :data:`CATALOG` — one :class:`BlockSchema` per artifact block
-  (roofline, calibration, loadgen_knee, mutation, ivf, pq, multihost,
-  join, quality, fleet, tuning-cache entries), each declaring its
+  (loadgen_knee, mutation, ivf, pq, multihost,
+  join, quality, fleet), each declaring its
   fields (types/required/ranges), version token, emitters +
   fingerprints (for the ``artifact-lockstep`` checker), and docs
   anchor;
@@ -23,8 +23,8 @@ cure:
   (``missing field: X`` / ``field X must be ..., got ...``).
 
 Everything here is stdlib-only and jax-free.  Version tokens and choice
-sets stay in their owning modules (``MODEL_VERSION`` lives with the
-model that bumps it) and are referenced lazily through :class:`Ref` —
+sets stay in their owning modules (``BLOCK_VERSION`` lives with the
+knee block that bumps it) and are referenced lazily through :class:`Ref` —
 the catalog declares, it never duplicates.
 
 Adding a block is ONE schema entry here (docs/ANALYSIS.md "Adding a
@@ -43,7 +43,6 @@ __all__ = [
     "BY_NAME",
     "BlockSchema",
     "Field",
-    "Gate",
     "Rule",
     "Ref",
     "validate",
@@ -61,8 +60,8 @@ __all__ = [
 class Ref:
     """A lazy pointer to a constant in its owning module (the version
     token, a choice tuple).  The catalog references the single source
-    of truth instead of copying it — ``MODEL_VERSION`` still lives with
-    the model whose bump invalidates caches."""
+    of truth instead of copying it — a version token still lives with
+    the module that bumps it."""
 
     module: str
     attr: str
@@ -96,7 +95,7 @@ class Field:
     discipline of the lint framework."""
 
     path: str
-    kind: str = "any"  # any|int|number|str|bool|dict|list|version|nested
+    kind: str = "any"  # any|int|number|str|bool|dict|list|version
     required: bool = False
     nullable: bool = False
     ge: Optional[float] = None
@@ -106,7 +105,6 @@ class Field:
     legacy: Optional[str] = None
     stop_on_error: bool = False
     nonempty: bool = False
-    nested: Optional[str] = None
     element_style: str = ""  # "knee_steps"
     element_required: Tuple[str, ...] = ()
     element_optional: Tuple[str, ...] = ()
@@ -115,14 +113,6 @@ class Field:
     @property
     def leaf(self) -> str:
         return self.path.rsplit(".", 1)[-1]
-
-
-@dataclasses.dataclass(frozen=True)
-class Gate:
-    """Stop validating the remaining checks when ``path`` is falsy —
-    an unapplied calibration carries no factors to judge."""
-
-    path: str
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,14 +132,11 @@ class BlockSchema:
     #: docs anchor "docs/FILE.md#Heading text" — the artifact-lockstep
     #: checker requires the heading to exist
     doc: str
-    #: ordered validation program: Field / Gate / Rule items
+    #: ordered validation program: Field / Rule items
     checks: Tuple = ()
+    #: a "version" field must EQUAL the constant ``version_ref`` names
     version_field: Optional[str] = None
     version_ref: Optional[Ref] = None
-    #: True: the version field must EQUAL the referenced constant;
-    #: False: any int version token is accepted (the validator is
-    #: version-tolerant, like roofline's)
-    version_exact: bool = False
     #: legacy template for a non-dict block
     not_dict_legacy: Optional[str] = None
     #: an "error" key exempts the block inside validate() (knee,
@@ -208,8 +195,7 @@ def _fmt(template: Optional[str], normalized: str, style: str,
 
 def _type_desc(f: Field, version) -> str:
     if f.kind == "version":
-        return f"version {version}" if version is not None \
-            else "an int version token"
+        return f"version {version}"
     if f.choices is not None:
         return "one of {choices}"
     if f.kind == "int":
@@ -240,9 +226,7 @@ def _check_value(f: Field, value, version) -> bool:
     """True when ``value`` satisfies the field's contract (None already
     handled by the caller)."""
     if f.kind == "version":
-        if version is not None:
-            return value == version
-        return isinstance(value, int)
+        return value == version
     if f.choices is not None:
         return value in _resolve(f.choices)
     t = _KIND_TYPES.get(f.kind)
@@ -262,8 +246,7 @@ def _check_value(f: Field, value, version) -> bool:
 
 def _field_error(schema: "BlockSchema", f: Field, value, style: str
                  ) -> str:
-    version = version_value(schema.name) \
-        if (f.kind == "version" and schema.version_exact) else None
+    version = version_value(schema.name) if f.kind == "version" else None
     choices = _resolve(f.choices) if f.choices is not None else None
     desc = _type_desc(f, version)
     normalized = ("field {path} must be " + desc + ", got {value!r}")
@@ -295,11 +278,6 @@ def validate(name: str, block, style: str = "normalized") -> List[str]:
             return errors
     state: Dict[str, str] = {}
     for check in schema.checks:
-        if isinstance(check, Gate):
-            _, gval = _resolve_path(block, check.path)
-            if not gval:
-                break
-            continue
         if isinstance(check, Rule):
             errors.extend(_RULES[check.name](block, style))
             continue
@@ -330,22 +308,15 @@ def validate(name: str, block, style: str = "normalized") -> List[str]:
             if f.nullable or not f.required:
                 state[f.path] = "ok" if (present and f.nullable) \
                     else "absent"
-                if f.nested is not None and present:
-                    errors.extend(validate(f.nested, value, style))
                 continue
             errors.append(_field_error(schema, f, value, style))
             state[f.path] = "error"
             if f.stop_on_error:
                 return errors
             continue
-        if f.nested is not None:
-            state[f.path] = "ok"
-            errors.extend(validate(f.nested, value, style))
-            continue
         if not _check_value(f, value,
                             version_value(schema.name)
-                            if (f.kind == "version"
-                                and schema.version_exact) else None):
+                            if f.kind == "version" else None):
             errors.append(_field_error(schema, f, value, style))
             state[f.path] = "error"
             if f.stop_on_error:
@@ -448,129 +419,9 @@ def known_keys(name: str) -> set:
 # --------------------------------------------------------------------------
 # THE CATALOG
 # --------------------------------------------------------------------------
-_RL = "knn_tpu.obs.roofline"
-_CAL = "knn_tpu.obs.calibrate"
 _XO = "knn_tpu.parallel.crossover"
 
 CATALOG: Tuple[BlockSchema, ...] = (
-    # --- roofline -------------------------------------------------------
-    BlockSchema(
-        name="roofline",
-        doc="docs/PERF.md#Roofline model",
-        validator="knn_tpu.obs.roofline:validate_block",
-        emitters=("knn_tpu/obs/roofline.py",),
-        fingerprints=(frozenset({"model_version", "terms"}),),
-        version_field="model_version",
-        version_ref=Ref(_RL, "MODEL_VERSION"),
-        version_exact=False,
-        not_dict_legacy="roofline block is {vtype}, not dict",
-        checks=(
-            Field("model_version", "version", required=True,
-                  legacy="missing/non-int model_version"),
-            Field("bound_class", required=True,
-                  choices=Ref(_RL, "BOUND_CLASSES"),
-                  legacy="bound_class {value!r} not in {choices}"),
-            Field("ceiling_qps", "number", required=True, gt=0,
-                  legacy="ceiling_qps {value!r} is not a positive "
-                         "number"),
-            Field("roofline_pct", "number",
-                  legacy="roofline_pct {value!r} is neither null nor "
-                         "a number"),
-            Field("terms", "dict", required=True,
-                  legacy="missing terms breakdown"),
-            Field("terms.hbm.time_s", "number", required=True, ge=0,
-                  legacy="terms.hbm.time_s missing or negative"),
-            Field("terms.mxu.time_s", "number", required=True, ge=0,
-                  legacy="terms.mxu.time_s missing or negative"),
-            Field("terms.vpu_select.time_s", "number", required=True,
-                  ge=0,
-                  legacy="terms.vpu_select.time_s missing or negative"),
-            # the MODEL_VERSION-4 cross-host merge term: present only
-            # on multi-host blocks, and then every field must hold —
-            # a malformed DCN claim must not validate
-            Field("terms.dcn", "dict",
-                  legacy="terms.dcn is not a dict"),
-            Field("terms.dcn.time_s", "number", required=True, ge=0,
-                  legacy="terms.dcn.time_s missing or negative"),
-            Field("terms.dcn.bytes", "int", required=True, ge=0,
-                  legacy="terms.dcn.bytes missing or negative"),
-            Field("terms.dcn.hosts", "int", required=True, ge=2,
-                  legacy="terms.dcn.hosts must be an int >= 2"),
-            Field("terms.dcn.strategy", required=True,
-                  choices=Ref(_XO, "STRATEGIES"),
-                  legacy="terms.dcn.strategy {value!r} not in "
-                         "{choices}"),
-            # the MODEL_VERSION-7 join h2d term: present only on join
-            # blocks (join_cost_model), and then it must be priced
-            Field("terms.h2d", "dict",
-                  legacy="terms.h2d is not a dict"),
-            Field("terms.h2d.time_s", "number", required=True, ge=0,
-                  legacy="terms.h2d.time_s missing or negative"),
-            Field("terms.h2d.bytes", "int", required=True, ge=0,
-                  legacy="terms.h2d.bytes missing or negative"),
-            # the join-shape annotations join_cost_model stamps
-            Field("join", "any"),
-            # MODEL_VERSION 3 blocks carry an explicit calibration
-            # verdict; pre-calibration history (v1/v2) legitimately
-            # lacks it, but one that IS present must be well-formed
-            Field("calibration", nested="calibration"),
-            # declared, engine-filled / advisory keys (unconstrained)
-            Field("selector", "any"),
-            Field("device_kind", "any"),
-            Field("estimated", "any"),
-            Field("peaks", "any"),
-            Field("config", "any"),
-            Field("measured_qps", "any"),
-            Field("ceiling_qps_analytic", "any"),
-            Field("select_overlapped", "any"),
-            Field("term_times_s", "any"),
-            Field("term_times_calibrated_s", "any"),
-            Field("error", "any"),
-        ),
-    ),
-    # --- calibration (nested under roofline) ----------------------------
-    BlockSchema(
-        name="calibration",
-        doc="docs/PERF.md#Calibration & measured ceilings",
-        validator="knn_tpu.obs.calibrate:validate_calibration",
-        emitters=("knn_tpu/obs/roofline.py", "knn_tpu/obs/calibrate.py"),
-        fingerprints=(frozenset({"applied", "factors"}),),
-        not_dict_legacy="calibration is {vtype}, not dict",
-        checks=(
-            # an absent overlay must still be EXPLICIT: applied is a
-            # bool, never missing-and-implied
-            Field("applied", "bool", required=True, stop_on_error=True,
-                  legacy="calibration.applied {value!r} is not a bool"),
-            Gate("applied"),
-            Field("factors", "dict", required=True,
-                  legacy="applied calibration missing factors dict"),
-            Field("factors.hbm", "number", required=True, gt=0,
-                  legacy="calibration factor {leaf} {value!r} is not "
-                         "a positive number"),
-            Field("factors.mxu", "number", required=True, gt=0,
-                  legacy="calibration factor {leaf} {value!r} is not "
-                         "a positive number"),
-            Field("factors.vpu_select", "number", required=True, gt=0,
-                  legacy="calibration factor {leaf} {value!r} is not "
-                         "a positive number"),
-            Field("source", required=True,
-                  choices=Ref(_CAL, "SOURCES"),
-                  legacy="calibration source {value!r} not in "
-                         "{choices}"),
-            Field("model_residual_pct", "number", required=True,
-                  legacy="calibration.model_residual_pct {value!r} is "
-                         "not a number"),
-            # provenance the overlay carries (unconstrained)
-            Field("method", "any"),
-            Field("age_s", "any"),
-            Field("samples", "any"),
-            Field("term_residual_pct", "any"),
-            Field("measured_at", "any"),
-            Field("provenance", "any"),
-            Field("note", "any"),
-            Field("error", "any"),
-        ),
-    ),
     # --- loadgen knee ----------------------------------------------------
     BlockSchema(
         name="loadgen_knee",
@@ -582,7 +433,6 @@ CATALOG: Tuple[BlockSchema, ...] = (
                       frozenset({"rate_qps", "within_slo"})),
         version_field="version",
         version_ref=Ref("knn_tpu.loadgen.knee", "BLOCK_VERSION"),
-        version_exact=True,
         not_dict_legacy="knee block must be a dict, got {vtype}",
         error_exempt=True,
         checks=(
@@ -617,7 +467,6 @@ CATALOG: Tuple[BlockSchema, ...] = (
         fingerprints=(frozenset({"mutation_version", "write_mix"}),),
         version_field="mutation_version",
         version_ref=Ref("knn_tpu.index.artifact", "MUTATION_VERSION"),
-        version_exact=True,
         not_dict_legacy="mutation block must be a dict, got {vtype}",
         error_exempt=True,
         missing_order=("mutation_version", "write_mix", "rate_qps",
@@ -683,7 +532,6 @@ CATALOG: Tuple[BlockSchema, ...] = (
         fingerprints=(frozenset({"ivf_version", "nprobe"}),),
         version_field="ivf_version",
         version_ref=Ref("knn_tpu.ivf.artifact", "IVF_VERSION"),
-        version_exact=True,
         not_dict_legacy="ivf block must be a dict, got {vtype}",
         error_exempt=True,
         missing_order=("ivf_version", "ncentroids", "nprobe", "queries",
@@ -742,7 +590,6 @@ CATALOG: Tuple[BlockSchema, ...] = (
         fingerprints=(frozenset({"pq_version", "dsub"}),),
         version_field="pq_version",
         version_ref=Ref("knn_tpu.ops.pq_artifact", "PQ_VERSION"),
-        version_exact=True,
         not_dict_legacy="pq block must be a dict, got {vtype}",
         error_exempt=True,
         missing_order=("pq_version", "dsub", "ncodes", "nsub",
@@ -835,12 +682,11 @@ CATALOG: Tuple[BlockSchema, ...] = (
     # --- bulk kNN-join ---------------------------------------------------
     BlockSchema(
         name="join",
-        doc="docs/PERF.md#Bulk kNN-join (MODEL_VERSION 7)",
+        doc="docs/PERF.md#Bulk kNN-join",
         validator="knn_tpu.join.artifact:validate_join_block",
         fingerprints=(frozenset({"join_version", "superblock_rows"}),),
         version_field="join_version",
         version_ref=Ref("knn_tpu.join.artifact", "JOIN_VERSION"),
-        version_exact=True,
         not_dict_legacy="join block must be a dict, got {vtype}",
         error_exempt=True,
         missing_order=("join_version", "mode", "rows", "k",
@@ -906,7 +752,6 @@ CATALOG: Tuple[BlockSchema, ...] = (
                                  "audit_recall_at_k"}),),
         version_field="quality_version",
         version_ref=Ref("knn_tpu.obs.audit", "QUALITY_VERSION"),
-        version_exact=True,
         not_dict_legacy="quality block must be a dict, got {vtype}",
         error_exempt=True,
         missing_order=("quality_version", "audit_rate",
@@ -959,7 +804,6 @@ CATALOG: Tuple[BlockSchema, ...] = (
         fingerprints=(frozenset({"fleet_version", "member_count"}),),
         version_field="fleet_version",
         version_ref=Ref("knn_tpu.obs.fleet", "FLEET_VERSION"),
-        version_exact=True,
         not_dict_legacy="fleet block must be a dict, got {vtype}",
         error_exempt=True,
         # the merged cross-host headline: how many members summed in,
@@ -978,46 +822,6 @@ CATALOG: Tuple[BlockSchema, ...] = (
             Field("stitched_requests", "int", required=True, ge=0),
             Field("slo_breached", "int", required=True, ge=0),
             Field("error", "any"),
-        ),
-    ),
-    # --- tuning-cache entries ---------------------------------------------
-    BlockSchema(
-        name="tuning_cache_entry",
-        doc="docs/PERF.md#Streaming kernel & autotuner",
-        emitters=("knn_tpu/tuning/autotune.py",),
-        fingerprints=(frozenset({"knobs", "winner", "timings_ms"}),),
-        checks=(
-            Field("knobs", "dict", required=True),
-            Field("winner", "str", required=True),
-            Field("winner_ms", "number", nullable=True),
-            Field("timings_ms", "dict", required=True),
-            Field("errors", "dict", nullable=True),
-            Field("roofline_per_candidate", "dict", nullable=True),
-            Field("gate", "str", required=True),
-            # which knob-grid regime timed the entry: "latency" (the
-            # serving default) or "throughput" (the bulk-join grid,
-            # cache-keyed with a |throughput suffix)
-            Field("profile", "str", nullable=True),
-            Field("runs", "int", required=True, ge=1),
-            Field("n_queries", "int", required=True, ge=1),
-            Field("margin", "int", nullable=True),
-            Field("device_kind", "str", nullable=True),
-            Field("backend", "str", nullable=True),
-            Field("jax_version", "str", nullable=True),
-            Field("measured_at", "str", nullable=True),
-            Field("pruning", "dict", nullable=True),
-            Field("vmem", "dict", nullable=True),
-            # the IVF autotuner's (autotune_ivf) entry rides the same
-            # shape: its per-candidate probe/fallback stats and the
-            # selector its searches ran under
-            Field("selector", "str", nullable=True),
-            Field("stats_per_candidate", "dict", nullable=True),
-            Field("roofline", nested="roofline"),
-            Field("roofline_pct", "number", nullable=True),
-            Field("bound_class", "str", nullable=True),
-            Field("trace_dir", "str", nullable=True),
-            Field("cached", "bool", nullable=True),
-            Field("cache_key", "str", nullable=True),
         ),
     ),
 )

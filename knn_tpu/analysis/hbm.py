@@ -31,8 +31,7 @@ from knn_tpu.analysis import widths as _widths
 #: f32 aux bytes the placement keeps beside each row (the squared row
 #: norm the distance programs hoist); the int8 tier would add scales,
 #: but the host-RAM tier streams the f32 placement.  A view of the ONE
-#: shared width table (analysis.widths) — the same constant the
-#: roofline's db_aux term and this module's placement arithmetic price.
+#: shared width table (analysis.widths).
 AUX_BYTES_PER_ROW = _widths.AUX_BYTES_PER_ROW
 
 

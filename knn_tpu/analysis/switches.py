@@ -81,9 +81,6 @@ SWITCHES: Tuple[Switch, ...] = (
        "Rotation cap for the JSONL sink (default 64 MiB)."),
     _s("KNN_TPU_SLO_CONFIG", "path", "knn_tpu/obs/slo.py", _OBS,
        "JSON objective list replacing the default SLOs."),
-    _s("KNN_TPU_PROFILE_DIR", "path", "knn_tpu/obs/profiler.py", _OBS,
-       "Ambient device-trace gate: bench/tune winners capture one "
-       "jax.profiler.trace run here."),
     _s("KNN_TPU_POSTMORTEM_DIR", "path", "knn_tpu/obs/blackbox.py", _OBS,
        "Arms the flight recorder: one postmortem bundle per "
        "edge-triggered SLO breach."),
@@ -113,18 +110,6 @@ SWITCHES: Tuple[Switch, ...] = (
        _OBS, "Hard oracle row budget for audit replays (rows/second "
        "token bucket, default 5e6); over-budget records are dropped "
        "and counted."),
-    # --- measured-term calibration (knn_tpu.obs.calibrate) -------------
-    _s("KNN_TPU_CALIBRATION", "path", "knn_tpu/obs/calibrate.py", _OBS,
-       "Calibration store JSON: per-term roofline scale factors "
-       "reconciled from measured device time (atomic writes, "
-       "model-version-token keys); unset = analytic model only."),
-    # --- tuning (knn_tpu.tuning) ---------------------------------------
-    _s("KNN_TPU_TUNE_CACHE", "path", "knn_tpu/tuning/cache.py", _PERF,
-       "Autotuner winner-cache file (default "
-       "~/.cache/knn_tpu/autotune.json)."),
-    _s("KNN_TPU_TUNE_PRUNE", "float", "knn_tpu/tuning/autotune.py", _OBS,
-       "Roofline-model candidate-pruning fraction in (0, 1]; unset = "
-       "exhaustive search."),
     # --- multi-host merge tree (knn_tpu.parallel.crossover) ------------
     _s("KNN_TPU_MERGE", "str", "knn_tpu/parallel/crossover.py", _PERF,
        "Override the measured ring/allgather crossover for the "

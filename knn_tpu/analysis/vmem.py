@@ -1,25 +1,19 @@
-"""Per-launch VMEM model of the Pallas kernels — the budget side of the
-roofline story.
+"""Per-launch VMEM model of the Pallas kernels.
 
-The roofline model (knn_tpu.obs.roofline) prices a knob set's TIME;
 VMEM is the resource that decides whether a config RUNS AT ALL: an
 over-VMEM knob combination is refused by Mosaic at compile time.  This
 jax-free module is the ONE home of that arithmetic:
 
 - ``ops.pallas_knn`` sizes its ``vmem_limit_bytes`` request from
   :func:`kernel_bytes` / :func:`limit_bytes`;
-- ``autotune()`` refuses over-budget candidates BEFORE timing, with
-  provenance recorded like roofline pruning;
-- ``knob_grid`` and the ``vmem-budget`` checker
-  (knn_tpu.analysis.check_vmem) keep the grid free of candidates that
-  fit NO known device.
+- the ``vmem-budget`` checker (knn_tpu.analysis.check_vmem) holds the
+  default knob set to the target device's budget.
 
 ONE rule decides who has the last word (:func:`calibrated`): where the
 model was fitted to the compiler it REFUSES what cannot fit — the
-kernel raises with the knobs to change before Mosaic is asked, the
-autotuner and the grid drop the candidate; everywhere else the model
-only describes, the kernel requests the device's whole VMEM, and
-Mosaic decides.
+kernel raises with the knobs to change before Mosaic is asked;
+everywhere else the model only describes, the kernel requests the
+device's whole VMEM, and Mosaic decides.
 
 Calibration: the buffers the kernel declares (pipelined operand and
 output blocks, scratch) are exact; what Mosaic keeps live ON TOP of
@@ -50,8 +44,8 @@ tile and query block); where they do not (``gist1m``'s 1,024 columns,
 ``openai500k``'s 1,536) the grid's third axis walks ROW BLOCKS of the
 tile, the largest that divides the tile into whole 128-row groups and
 fits, and only the bin-select's running arrays (``select_state``, 640
-KiB at a query block of 256) are carried between steps.  The kernel,
-:func:`launch_estimate` and ``obs.roofline`` all ask the rule.  Until
+KiB at a query block of 256) are carried between steps.  The kernel
+and :func:`launch_estimate` both ask the rule.  Until
 PR 46 such a tile was cut into ``DIM_CHUNK``-column chunks whose
 ``[block_q, tile_n]`` partial product (16 MiB) was read, added to and
 stored back at every step: 25.5 us a 128-column pass-set at ``gist1m``
@@ -82,8 +76,7 @@ Geometry constants mirror ``ops.pallas_knn`` (TILE_N/BLOCK_Q/BIN_W/
 DIM_CHUNK/MAX_CARRY_DEPTH), pinned by tests/test_analysis.py.  The
 per-precision operand widths live in the ONE shared table
 :mod:`knn_tpu.analysis.widths` (this module's ``DB_PARTS``/``AUX_ROWS``
-are ``is``-identity views of it, shared with ``obs.roofline`` and
-``analysis.hbm``).
+are ``is``-identity views of it, shared with ``analysis.hbm``).
 
 Capacity provenance: TPU v2/v3 cores carry ~16 MiB of VMEM; v4 and
 every later announced generation carry 128 MiB (Mosaic's own refusal on
@@ -146,11 +139,10 @@ HEADLINE_SHAPE = {"n": 1_000_000, "d": 128, "k": 100, "margin": 28}
 def budget_for(device_kind: Optional[str],
                backend: Optional[str] = None) -> Optional[int]:
     """VMEM bytes of a device kind; None when there is no VMEM to
-    budget (cpu / interpret mode) — the autotuner's gate disarms there
-    instead of refusing on a number that doesn't exist.  An explicit
-    TPU ``device_kind`` wins over ``backend``: a caller modeling (or
-    keying a cache for) a specific chip gets that chip's budget even
-    when the tune itself runs in CPU interpret mode.  A TPU whose kind
+    budget (cpu / interpret mode) — nothing is refused there on a
+    number that doesn't exist.  An explicit TPU ``device_kind`` wins
+    over ``backend``: a caller modeling a specific chip gets that
+    chip's budget even when it runs in CPU interpret mode.  A TPU whose kind
     is not in the table raises: a device the table does not know is an
     error, not a default."""
     if device_kind in VMEM_BYTES_BY_KIND:
@@ -167,7 +159,7 @@ def budget_for(device_kind: Optional[str],
 def calibrated(precision: Optional[str]) -> bool:
     """Whether the model was fitted to the compiler for this arm (module
     docstring) — the ONE switch between "the model refuses" and "Mosaic
-    decides", shared by the kernel, the autotuner gate and the grid."""
+    decides", shared by the kernel and the ``vmem-budget`` checker."""
     return (precision or "bf16x3") == "bf16x3"
 
 
@@ -195,7 +187,7 @@ def _geometry(n: int, d: int, precision: str, kernel: str,
             f"precision {precision!r} not in {sorted(DB_PARTS) + ['pq']}")
     tile = int(tile_n or TILE_N_DEFAULT)
     # the kernel pads the db to a tile multiple; an oversize tile caps
-    # at the padded row count (mirrors obs.roofline's clamp)
+    # at the padded row count
     tile = min(tile, max(BIN_W, _ceil_div(n, BIN_W) * BIN_W))
     bq = int(block_q or BLOCK_Q_DEFAULT)
     n_tiles = _ceil_div(n, tile)
@@ -544,19 +536,3 @@ def check_candidate(
         "device_kind": device_kind,
         "fits": est <= budget if checked else None,
     }
-
-
-def fits_some_kind(knobs: dict, *, n: int, d: int, k: int,
-                   margin: int = 28) -> bool:
-    """Whether the knob set fits AT LEAST ONE known device kind's VMEM
-    at this shape.  A candidate that fits nowhere is dead grid weight:
-    on every real device the kernel itself would refuse it, so
-    ``knob_grid`` drops such combinations at the headline shape and the
-    ``vmem-budget`` checker enforces the same bound.  An arm the model
-    is not :func:`calibrated` for is never excluded on it."""
-    if not calibrated(knobs.get("precision")):
-        return True
-    roomiest = max(VMEM_BYTES_BY_KIND.values())
-    est = _estimate_for(knobs, n=n, d=d, k=k, margin=margin,
-                        budget_bytes=roomiest)
-    return est <= roomiest

@@ -1,17 +1,13 @@
 """ONE shared per-precision operand byte-width table.
 
-Before PR 17 the db-operand stream widths lived three times over —
-``obs.roofline.DB_ELEM_BYTES`` (the cost model), ``analysis.vmem.DB_PARTS``
-(the launch budget), and ``analysis.hbm``'s itemsize arithmetic (the
-placement budget) — pinned against each other by tests but still three
-places to edit.  With the sub-int8 arm (PQ byte codes whose row width
-depends on ``ceil(d / dsub)``) a drifted
-mirror would mis-price exactly the byte term those arms exist to
-shrink, so the widths now live HERE and all three consumers import
-them; tests/test_analysis.py pins the identity (``is``, not ``==``) so
-a re-forked table can't reappear.
+The db-operand stream widths that ``analysis.vmem.DB_PARTS`` (the
+launch budget), ``analysis.hbm``'s itemsize arithmetic (the placement
+budget) and the IVF index's probed-bytes count read live HERE and the
+consumers import them; tests/test_analysis.py pins the identity
+(``is``, not ``==``) so a re-forked table can't reappear, and the
+widths themselves against the arrays the kernel builds.
 
-Jax-free on purpose: every consumer is a jax-free analysis/obs module.
+Jax-free on purpose: every consumer is a jax-free analysis module.
 
 Layout provenance (what the kernels actually stream,
 ``ops.pallas_knn._bin_candidates``):
@@ -63,7 +59,7 @@ AUX_ROWS_DEFAULT = 8
 
 #: query operand width per element: the int8 arm streams int8
 #: queries.  PQ is absent here too: its query-side
-#: operand is the per-query LUT, priced by :func:`pq_lut_bytes`.
+#: operand is the per-query LUT (analysis.vmem prices its block).
 QUERY_ELEM_BYTES: Dict[str, int] = {"int8": 1}
 QUERY_ELEM_BYTES_DEFAULT = 4
 
@@ -125,22 +121,18 @@ def query_elem_bytes(precision: str) -> int:
     return QUERY_ELEM_BYTES.get(precision, QUERY_ELEM_BYTES_DEFAULT)
 
 
-def pq_lut_bytes(nq: int, d: int, *, dsub: Optional[int] = None,
-                 ncodes: Optional[int] = None) -> int:
-    """Bytes of the per-query PQ lookup tables one batch carries
-    ([nq, m * ncodes] f32) — the query-side operand of the PQ arm."""
-    m = pq_nsub(d, dsub)
-    return int(nq) * m * int(ncodes or PQ_NCODES_DEFAULT) * 4
-
-
-def pq_lut_flops(nq: int, d: int, *, dsub: Optional[int] = None,
-                 ncodes: Optional[int] = None) -> float:
-    """FLOPs of building the per-query LUTs: every (query, subspace,
-    code) entry is a dsub-dim dot + norm fold, ~2·dsub flops — in total
-    ``2 · nq · ncodes · (m · dsub) >= 2 · nq · ncodes · d``."""
-    m = pq_nsub(d, dsub)
-    return 2.0 * int(nq) * int(ncodes or PQ_NCODES_DEFAULT) * m * int(
-        dsub or PQ_DSUB_DEFAULT)
+def db_operand_nbytes(n: int, d: int, precision: str, *,
+                      dsub: Optional[int] = None) -> Dict[str, int]:
+    """Bytes of the db-side operands ONE full-db stream moves — the
+    values array(s) plus the lane-major aux block — matching the arrays
+    ``ops.pallas_knn._bin_candidates`` actually builds (the property
+    test compares against their ``nbytes``).  The shape-dependent arm
+    routes through :func:`db_row_bytes`: "pq" streams
+    ``ceil(d / dsub)`` code bytes per row."""
+    return {
+        "db_values": int(n) * db_row_bytes(d, precision, dsub=dsub),
+        "db_aux": int(n) * aux_rows_for(precision) * 4,
+    }
 
 
 __all__ = [
@@ -148,5 +140,5 @@ __all__ = [
     "QUERY_ELEM_BYTES", "QUERY_ELEM_BYTES_DEFAULT", "DB_PARTS",
     "AUX_BYTES_PER_ROW", "PQ_DSUB_DEFAULT", "PQ_NCODES_DEFAULT",
     "pq_nsub", "db_row_bytes", "aux_rows_for", "query_elem_bytes",
-    "pq_lut_bytes", "pq_lut_flops", "lane_tiled",
+    "db_operand_nbytes", "lane_tiled",
 ]

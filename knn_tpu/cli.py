@@ -11,16 +11,7 @@ Usage mirrors the reference job:
 Prints the reference's two lines (``accuracy = ...`` knn_mpi.cpp:348 and
 ``Running time is ... second`` :398) plus optional structured JSON metrics.
 
-Two subcommands ride alongside the job interface:
-
-    python -m knn_tpu.cli tune --n 1000000 --dim 128 --k 100
-
-runs the deterministic kernel autotuner (knn_tpu.tuning) for that
-problem shape on whatever backend JAX exposes and persists the winning
-knob set to the on-disk cache, where every subsequent
-``search_certified``/bench run on the same device kind resolves it with
-zero re-timing — the reproducible replacement for a per-session hand
-search.
+Subcommands ride alongside the job interface:
 
     python -m knn_tpu.cli join --n 1000000 --rows 65536 --k 10
     python -m knn_tpu.cli join --mode certified --superblock 8192
@@ -30,7 +21,7 @@ host-resident query set against the corpus through the double-buffered
 superblock stream (query h2d overlapped under device compute), or the
 certified per-superblock loop; prints plan + measured stats (rows/s,
 overlap_ratio, superblock/segment/dispatch counts) as one JSON line
-(docs/PERF.md "Bulk kNN-join (MODEL_VERSION 7)").
+(docs/PERF.md "Bulk kNN-join").
 
     python -m knn_tpu.cli metrics --port 9100
     python -m knn_tpu.cli metrics --snapshot /path/run_metrics.json --format prom
@@ -45,8 +36,7 @@ the job flags ``--metrics-port`` / ``--obs-log``
     python -m knn_tpu.cli doctor --snapshot /path/run_metrics.json
 
 renders the health/self-diagnosis report (readiness, device inventory,
-engine warmup + queue worker state, SLO breaches, roofline verdicts,
-recent alerts) from a RUNNING process's ``/statusz`` endpoint or
+engine warmup + queue worker state, SLO breaches, recent alerts) from a RUNNING process's ``/statusz`` endpoint or
 offline from an atomic snapshot — the same report either way, jax-free
 by construction.  Exit code: 0 healthy, 2 not ready, 1 unreadable
 source.
@@ -76,17 +66,6 @@ includes the failing records themselves — jax-free by construction
 (docs/OBSERVABILITY.md "Quality observability").  Exit code: 0 clean,
 2 deficient or dropped audits on record, 1 unreadable source.
 
-    python -m knn_tpu.cli roofline --n 1000000 --dim 128 --k 100 \\
-        --device-kind "TPU v5 lite" [--qps 24199]
-
-renders the analytic roofline model (knn_tpu.obs.roofline) for any
-config OFFLINE and jax-free: per-term HBM-bytes / MXU-FLOP / VPU-select
-breakdown, the predicted ceiling q/s, and the bound class naming the
-resource that caps this config — with ``--qps`` it also prints the
-measured percent of roofline.  The planning companion of the bench's
-per-line ``roofline`` blocks: answer "what would int8 x streaming be
-bounded by at this shape?" before burning chip time on it.
-
     python -m knn_tpu.cli waterfall --bundle postmortem-....json
     python -m knn_tpu.cli waterfall --log events.jsonl --top 5
     python -m knn_tpu.cli waterfall --port 9100 --trace-id 3fa9c1d2e4b56a78
@@ -107,7 +86,7 @@ runs the repo-native static-analysis suite (knn_tpu.analysis,
 docs/ANALYSIS.md) over the source tree, jax-free: env-switch and
 metric-name lockstep, locked-mutation (thread-safety contracts),
 jax-hygiene (wall clocks, hot-path host syncs, unhashable static
-args), and the VMEM knob-grid budget.  Exit 0 green — with every
+args), and the default knobs' VMEM budget.  Exit 0 green — with every
 suppression in knn_tpu/analysis/suppressions.json carrying a written
 justification — 1 findings.  ``check_tier1.sh --fast`` runs it as a
 hard gate.
@@ -220,108 +199,15 @@ def build_parser() -> argparse.ArgumentParser:
         "must be set before any other JAX use in the process)",
     )
     p.add_argument(
-        "--tune-cache", default=None, metavar="PATH",
-        help="autotuner winner-cache file for --mode certified "
-        "--selector pallas (default: $KNN_TPU_TUNE_CACHE or "
-        "~/.cache/knn_tpu/autotune.json; populate it with the `tune` "
-        "subcommand)",
-    )
-    p.add_argument(
         "--pallas-precision", default=None,
         choices=CERTIFIED_PRECISIONS,
         help="kernel matmul precision for --mode certified --selector "
         "pallas; 'int8' runs the quantized MXU coarse pass (db quantized "
         "once at placement, certify threshold widened by the provable "
         "per-query bound — results stay exact by construction).  Unset = "
-        "the persisted autotuner winner / library default",
+        "the library default",
     )
     return p
-
-
-def build_tune_parser() -> argparse.ArgumentParser:
-    p = argparse.ArgumentParser(
-        prog="knn_tpu tune",
-        description="Autotune the Pallas kernel for one problem shape and "
-        "persist the winner (knn_tpu.tuning); a second run for the same "
-        "(device kind, n, dim, k, metric, dtype) resolves from the cache "
-        "with zero re-timing.",
-    )
-    p.add_argument("--n", type=int, default=100_000, help="database rows")
-    p.add_argument("--dim", type=int, default=128, help="feature dim")
-    p.add_argument("--k", type=int, default=100, help="neighbor count")
-    p.add_argument("--metric", default="l2",
-                   choices=("l2", "sql2", "euclidean"))
-    p.add_argument("--dtype", default="float32",
-                   choices=("float32", "bfloat16"),
-                   help="placement compute dtype the winner is keyed for "
-                   "(a cache-key field: the bench's headline configs place "
-                   "bfloat16, so tune with --dtype bfloat16 for them; the "
-                   "kernel's own arithmetic is f32 either way)")
-    p.add_argument("--queries", type=int, default=256,
-                   help="timing/gate query count")
-    p.add_argument("--margin", type=int, default=28, help="candidate margin")
-    p.add_argument("--grid", default="standard",
-                   choices=("quick", "standard", "full"),
-                   help="knob grid size (tuning.knob_grid)")
-    p.add_argument("--profile", default="latency",
-                   choices=("latency", "throughput"),
-                   help="tuning regime (tuning.cache.PROFILES): "
-                   "'latency' is the serving grid/key; 'throughput' "
-                   "extends the grid with the bulk-join block_q "
-                   "512/1024 ladder and keys the winner separately so "
-                   "join winners never clobber serving winners")
-    p.add_argument("--runs", type=int, default=2,
-                   help="timed repetitions per candidate (fenced)")
-    p.add_argument("--seed", type=int, default=0, help="synthetic data seed")
-    p.add_argument("--cache", default=None, metavar="PATH",
-                   help="cache file (default: $KNN_TPU_TUNE_CACHE or "
-                   "~/.cache/knn_tpu/autotune.json)")
-    p.add_argument("--force", action="store_true",
-                   help="re-search even when a cached winner exists")
-    p.add_argument("--json", default=None, metavar="PATH",
-                   help="also write the result record to this path")
-    p.add_argument("--cpu-devices", type=int, default=None, metavar="N",
-                   help="force an N-virtual-device CPU backend")
-    return p
-
-
-def run_tune(args: argparse.Namespace) -> int:
-    """The `tune` subcommand: synthetic data at the requested shape ->
-    tuning.autotune -> one human-readable summary + one JSON line
-    (winner, per-candidate timings, counters — the zero-re-timing
-    evidence rides in the counters)."""
-    import json
-
-    import numpy as np
-
-    from knn_tpu import tuning
-
-    rng = np.random.default_rng(args.seed)
-    db = (rng.random(size=(args.n, args.dim)) * 128.0).astype(np.float32)
-    queries = (rng.random(size=(args.queries, args.dim)) * 128.0).astype(
-        np.float32)
-    tuning.reset_counters()
-    entry = tuning.autotune(
-        db, queries, args.k, metric=args.metric, margin=args.margin,
-        grid_level=args.grid, runs=args.runs, cache_path=args.cache,
-        dtype=None if args.dtype == "float32" else args.dtype,
-        force=args.force, profile=args.profile,
-    )
-    record = {**entry, "counters": tuning.counters()}
-    if entry["cached"]:
-        print(f"cached winner for {record['cache_key']}: "
-              f"{entry['winner']} ({entry['winner_ms']} ms) — "
-              f"0 candidates re-timed")
-    else:
-        print(f"tuned {record['cache_key']}: winner {entry['winner']} "
-              f"({entry['winner_ms']} ms) from "
-              f"{len(entry['timings_ms'])} candidates -> "
-              f"{record['cache_path']}")
-    print(json.dumps(record))
-    if args.json:
-        with open(args.json, "w") as f:
-            json.dump(record, f, indent=2)
-    return 0
 
 
 def build_join_parser() -> argparse.ArgumentParser:
@@ -707,195 +593,6 @@ def run_audit(args: argparse.Namespace) -> int:
     return 2 if (deficient or dropped_n or failures) else 0
 
 
-def build_roofline_parser() -> argparse.ArgumentParser:
-    from knn_tpu.obs.roofline import BOUND_CLASSES, PEAKS_BY_KIND
-
-    p = argparse.ArgumentParser(
-        prog="knn_tpu roofline",
-        description="Render the analytic roofline model "
-        "(knn_tpu.obs.roofline) for one config, offline and jax-free: "
-        "per-term byte/FLOP/select breakdown, predicted ceiling q/s, "
-        f"and the bound class ({', '.join(BOUND_CLASSES)}).",
-    )
-    p.add_argument("--n", type=int, required=True, help="database rows")
-    p.add_argument("--dim", type=int, required=True, help="feature dim")
-    p.add_argument("--k", type=int, default=100, help="neighbor count")
-    p.add_argument("--nq", type=int, default=4096,
-                   help="queries per sweep (the rate's numerator)")
-    p.add_argument("--selector", default="pallas",
-                   choices=("pallas", "exact", "approx"),
-                   help="pallas = the fused kernel model (knob flags "
-                   "below); exact/approx = the XLA selector model")
-    p.add_argument("--device-kind", default=None, metavar="KIND",
-                   help="peak-table row to model against, e.g. "
-                   f"{', '.join(sorted(PEAKS_BY_KIND))}; unset/unknown "
-                   "= generic-CPU fallback peaks flagged estimated")
-    p.add_argument("--precision", default=None,
-                   choices=CERTIFIED_PRECISIONS,
-                   help="kernel matmul precision (pallas selector)")
-    p.add_argument("--kernel", default=None,
-                   choices=("tiled", "streaming", "fused"))
-    p.add_argument("--grid-order", default=None,
-                   choices=("query_major", "db_major"))
-    p.add_argument("--tile-n", type=int, default=None)
-    p.add_argument("--block-q", type=int, default=None)
-    p.add_argument("--survivors", type=int, default=None)
-    p.add_argument("--margin", type=int, default=28)
-    p.add_argument("--dtype", default=None,
-                   choices=("bfloat16", "float32"),
-                   help="placement dtype (exact/approx selectors)")
-    p.add_argument("--batch", type=int, default=None,
-                   help="queries per device step (exact/approx)")
-    p.add_argument("--devices", type=int, default=1,
-                   help="mesh size (modeled as perfect scaling)")
-    p.add_argument("--qps", type=float, default=None,
-                   help="a measured q/s to attribute: adds "
-                   "roofline_pct to the output")
-    p.add_argument("--nprobe", type=int, default=None,
-                   help="IVF lists probed per query (with --ncentroids: "
-                   "scales the streamed rows by nprobe/ncentroids and "
-                   "renders the probed-bytes term)")
-    p.add_argument("--ncentroids", type=int, default=None,
-                   help="IVF list count (required with --nprobe)")
-    p.add_argument("--pq-dsub", type=int, default=None,
-                   help="PQ dims per subspace (--precision pq; "
-                   "default 4) — the row's code bytes are "
-                   "ceil(dim/dsub)")
-    p.add_argument("--pq-ncodes", type=int, default=None,
-                   help="PQ codewords per subspace codebook "
-                   "(--precision pq; default 256)")
-    p.add_argument("--best", nargs="?", const=10, type=int, default=None,
-                   metavar="N",
-                   help="rank the FULL autotuner knob grid by modeled "
-                   "ceiling for (n, dim, k, device kind) and print the "
-                   "top N configs with their bound class — the offline "
-                   "twin of the autotuner's roofline pruning "
-                   "(KNN_TPU_TUNE_PRUNE); knob flags above are ignored")
-    p.add_argument("--json", action="store_true",
-                   help="print the raw model JSON instead of the "
-                   "human-readable rendering")
-    return p
-
-
-def _run_roofline_best(args) -> int:
-    """``cli roofline --best``: the full autotuner knob grid
-    (knn_tpu.tuning.knob_grid("full")) ranked by modeled ceiling —
-    what the in-tune pruning consults, runnable offline for planning
-    ("which configs are even worth chip time on this device kind?").
-    jax-free like the rest of the subcommand."""
-    import json
-
-    from knn_tpu import tuning
-    from knn_tpu.obs import roofline
-    from knn_tpu.tuning.autotune import _label
-
-    ranked = []
-    seen = set()
-    for cand in tuning.knob_grid("full"):
-        knobs = {**tuning.DEFAULT_KNOBS, **cand}
-        # final_select/final_recall_target don't enter the cost model:
-        # dedupe to the model-relevant knob tuple so each geometry
-        # prints once
-        mkey = (knobs["precision"], knobs["kernel"], knobs["grid_order"],
-                knobs["tile_n"], knobs["block_q"], knobs["survivors"])
-        if mkey in seen:
-            continue
-        seen.add(mkey)
-        try:
-            model = roofline.pallas_cost_model(
-                n=args.n, d=args.dim, k=args.k, nq=args.nq,
-                precision=knobs["precision"], kernel=knobs["kernel"],
-                grid_order=knobs["grid_order"],
-                tile_n=knobs["tile_n"], block_q=knobs["block_q"],
-                survivors=knobs["survivors"], margin=args.margin,
-                device_kind=args.device_kind, num_devices=args.devices,
-                nprobe=args.nprobe, ncentroids=args.ncentroids,
-                pq_dsub=args.pq_dsub, pq_ncodes=args.pq_ncodes)
-        except ValueError:
-            continue  # a combination the model refuses
-        if not model.get("ceiling_qps"):
-            continue
-        ranked.append({
-            "config": _label(knobs),
-            "ceiling_qps": model["ceiling_qps"],
-            "bound_class": model["bound_class"],
-            "select_overlapped": model["select_overlapped"],
-            "estimated": model["estimated"],
-        })
-    ranked.sort(key=lambda r: -r["ceiling_qps"])
-    top = ranked[: max(1, int(args.best))]
-    payload = {
-        "best": top,
-        "modeled": len(ranked),
-        "model_version": roofline.MODEL_VERSION,
-    }
-    if args.json:
-        # honor the subcommand's --json contract: ONE JSON document on
-        # stdout, nothing else
-        print(json.dumps(payload, indent=1, sort_keys=True))
-        return 0
-    est = " (ESTIMATED generic fallback peaks)" if top and \
-        top[0]["estimated"] else ""
-    print(f"top {len(top)} of {len(ranked)} modeled configs for "
-          f"n={args.n} d={args.dim} k={args.k} nq={args.nq} on "
-          f"{args.device_kind or 'generic-cpu'}{est}  "
-          f"[roofline v{roofline.MODEL_VERSION}]")
-    for rank, rec in enumerate(top, 1):
-        tag = " +overlap" if rec["select_overlapped"] else ""
-        print(f"  {rank:2d}. {rec['ceiling_qps']:>12,.0f} q/s  "
-              f"{rec['bound_class']:<17}{tag:<9} {rec['config']}")
-    print(json.dumps(payload))
-    return 0
-
-
-def run_roofline(args: argparse.Namespace) -> int:
-    """The `roofline` subcommand — pure arithmetic, no JAX, no device:
-    prints the rendering (or raw JSON) plus ONE trailing JSON line
-    either way, so scripts can consume it like a bench line."""
-    import json
-
-    from knn_tpu.obs import roofline
-
-    if (args.nprobe is None) != (args.ncentroids is None):
-        # fail loudly here: inside --best the grid loop swallows
-        # ValueError per-candidate and would print an empty ranking
-        print("--nprobe and --ncentroids must be set together",
-              file=sys.stderr)
-        return 2
-    if args.best is not None:
-        return _run_roofline_best(args)
-    if args.selector == "pallas":
-        model = roofline.pallas_cost_model(
-            n=args.n, d=args.dim, k=args.k, nq=args.nq,
-            precision=args.precision, kernel=args.kernel,
-            grid_order=args.grid_order,
-            tile_n=args.tile_n, block_q=args.block_q,
-            survivors=args.survivors, margin=args.margin,
-            device_kind=args.device_kind, num_devices=args.devices,
-            nprobe=args.nprobe, ncentroids=args.ncentroids,
-            pq_dsub=args.pq_dsub, pq_ncodes=args.pq_ncodes)
-    else:
-        model = roofline.xla_cost_model(
-            n=args.n, d=args.dim, k=args.k, nq=args.nq,
-            selector=args.selector, dtype=args.dtype, batch=args.batch,
-            margin=args.margin, device_kind=args.device_kind,
-            num_devices=args.devices,
-            nprobe=args.nprobe, ncentroids=args.ncentroids)
-    block = roofline.attribute(model, args.qps)
-    if args.json:
-        print(json.dumps(block, indent=1, sort_keys=True))
-        return 0
-    sys.stdout.write(roofline.render_text(block))
-    print(json.dumps({
-        "ceiling_qps": block.get("ceiling_qps"),
-        "bound_class": block.get("bound_class"),
-        "roofline_pct": block.get("roofline_pct"),
-        "estimated": block.get("estimated"),
-        "model_version": block.get("model_version"),
-    }))
-    return 0
-
-
 def build_waterfall_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="knn_tpu waterfall",
@@ -948,7 +645,6 @@ def run_waterfall(args: argparse.Namespace) -> int:
             return 1
         wfs = payload.get("waterfalls") or {}
         agg = payload.get("attribution") or waterfall.attribute(wfs)
-        dvr = payload.get("device_vs_roofline")
     elif args.bundle is not None:
         from knn_tpu.obs import blackbox
 
@@ -962,7 +658,6 @@ def run_waterfall(args: argparse.Namespace) -> int:
         # offline rendering uses the same code path as live
         wfs = waterfall.reconstruct(payload.get("events") or [])
         agg = payload.get("attribution") or waterfall.attribute(wfs)
-        dvr = payload.get("device_vs_roofline")
         if not args.json:
             # header stays off the --json stdout: that output must
             # parse as one JSON document
@@ -980,9 +675,7 @@ def run_waterfall(args: argparse.Namespace) -> int:
             return 1
         wfs = waterfall.reconstruct(events)
         agg = waterfall.attribute(wfs)
-        dvr = waterfall.device_vs_roofline(wfs)
-        payload = {"waterfalls": wfs, "attribution": agg,
-                   "device_vs_roofline": dvr}
+        payload = {"waterfalls": wfs, "attribution": agg}
     if args.json:
         print(json.dumps(payload, indent=1, sort_keys=True, default=str))
         return 0
@@ -996,7 +689,7 @@ def run_waterfall(args: argparse.Namespace) -> int:
         picked = sorted(wfs.values(),
                         key=lambda w: -(w.get("total_s") or 0.0))
         picked = picked[: max(0, args.top)]
-    print(waterfall.render_attribution(agg, dvr))
+    print(waterfall.render_attribution(agg))
     for w in picked:
         print(waterfall.render_waterfall(w))
     if not picked:
@@ -1343,7 +1036,7 @@ def build_lint_parser() -> argparse.ArgumentParser:
                    "is imported from); a root carrying its own "
                    "switch/metric catalogs is judged against those "
                    "(vmem-budget always prices the imported package's "
-                   "knob grid)")
+                   "default knobs)")
     p.add_argument("--checker", action="append", default=None,
                    metavar="NAME",
                    help="run only this checker (repeatable; default "
@@ -1410,7 +1103,6 @@ def args_to_config(args: argparse.Namespace) -> JobConfig:
         serve_buckets=args.serve_buckets,
         max_wait_ms=args.max_wait_ms,
         num_threads=args.num_threads,
-        tune_cache=args.tune_cache,
         pallas_precision=args.pallas_precision,
     )
 
@@ -1431,13 +1123,9 @@ def _configure_backend(cpu_devices: Optional[int]) -> None:
 
 def main(argv: Optional[Sequence[str]] = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
-    if argv[:1] == ["tune"]:
-        # subcommand dispatch by leading token: the legacy flat job
-        # interface (required --train/--test) stays byte-compatible for
-        # every existing caller, and `tune` gets its own parser
-        targs = build_tune_parser().parse_args(argv[1:])
-        _configure_backend(targs.cpu_devices)
-        return run_tune(targs)
+    # subcommand dispatch by leading token: the legacy flat job
+    # interface (required --train/--test) stays byte-compatible for
+    # every existing caller, and each subcommand gets its own parser
     if argv[:1] == ["join"]:
         jargs = build_join_parser().parse_args(argv[1:])
         _configure_backend(jargs.cpu_devices)
@@ -1454,8 +1142,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         return run_audit(build_audit_parser().parse_args(argv[1:]))
     if argv[:1] == ["index"]:
         return run_index(build_index_parser().parse_args(argv[1:]))
-    if argv[:1] == ["roofline"]:
-        return run_roofline(build_roofline_parser().parse_args(argv[1:]))
     if argv[:1] == ["waterfall"]:
         return run_waterfall(build_waterfall_parser().parse_args(argv[1:]))
     if argv[:1] == ["loadgen"]:

@@ -4,7 +4,7 @@ and the ``mutation`` artifact-block validator.
 These live apart from :mod:`knn_tpu.index.mutable` (which imports JAX at
 module load) so a jax-free reader and the
 multi-host refusal path can import them without paying — or breaking on
-— a backend init.  Same split as ``loadgen.knee`` / ``obs.roofline``:
+— a backend init.  Same split as ``loadgen.knee``:
 whatever validates artifacts must run on a box without the
 accelerator too.
 """

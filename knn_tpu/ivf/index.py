@@ -1,8 +1,8 @@
 """The approximate-first IVF tier with a certified escape hatch.
 
-Brute force streams every db byte past every query; the roofline says
-the winning configs are hbm_bound, so the only way past the calibrated
-ceiling is to stream fewer bytes.  This tier prunes the stream with an
+Brute force streams every db byte past every query; where the row
+stream bounds a configuration, the only way past it is to stream
+fewer bytes.  This tier prunes the stream with an
 inverted file — and unlike every off-the-shelf IVF, a per-query
 certificate DETECTS when the probe missed and repairs it with the
 existing exact fallback, so recall@k is measured and gateable, never
@@ -542,7 +542,7 @@ class IVFIndex:
     def _search_stats(self, snap, *, n_q, k, nprobe, selector, precision,
                       n_groups, rows_gathered, n_bad, misses, recall_sum,
                       wall) -> dict:
-        from knn_tpu.obs.roofline import db_operand_nbytes
+        from knn_tpu.analysis.widths import db_operand_nbytes
 
         prec = precision if precision else "default"
         per_row = sum(db_operand_nbytes(1, self.dim, prec).values())

@@ -2,15 +2,12 @@
 a corpus B, with the db stream amortized over query superblocks.
 
 Latency-bound serving re-streams the whole placed database per request
-batch, which is why the winning serving configs sit hbm_bound far under
-the calibrated ceiling (the roofline's verdict).  The join engine is
+batch.  The join engine is
 the one regime that can honor the reference's own design principle
 ("maximize compute-to-communication ratio — fewer, larger messages",
 PDF p.7 §3.1): it sweeps A in large superblocks through the EXISTING
 streaming/fused kernels and sharded programs unmodified, so db HBM
-bytes per query fall as 1/superblock_rows until the bound flips off
-hbm_bound (obs.roofline MODEL_VERSION 7's join model prices exactly
-this).  Query-side double buffering — superblock i+1's host->device
+bytes per query fall as 1/superblock_rows.  Query-side double buffering — superblock i+1's host->device
 transfer overlapping block i's device compute under the bounded-depth
 drain-oldest discipline — turns the
 h2d query stream into an amortized cost too.

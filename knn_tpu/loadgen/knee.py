@@ -7,8 +7,7 @@ throughput-recall-latency tradeoff; the knee is where that tradeoff
 lives for a serving deployment — below it, added load is free; above
 it, every extra offered request is paid in tail latency (or, with
 admission control on, in explicit sheds).  :func:`knee_block` is the
-artifact shape and :func:`validate_knee_block` its validator (the
-roofline-block discipline).
+artifact shape and :func:`validate_knee_block` its validator.
 
 The sweep is target-agnostic: a factory returning a fresh
 ``QueryQueue``-shaped target per step (fresh so one step's saturated

@@ -24,13 +24,6 @@ writes through here instead of keeping private ad-hoc counters:
   lowering, backend compile and persistent-cache lookup's count +
   seconds via ``jax.monitoring``, and the record of a device program's
   first call (which one traced, compiled or loaded, and for how long).
-- **Roofline model** (:mod:`knn_tpu.obs.roofline`): the analytic
-  per-config HBM/MXU/VPU cost model behind every ``roofline_pct`` /
-  ``bound_class`` the autotuner and /statusz report —
-  jax-free attribution of the MFU gap per config.
-- **Device trace capture** (:mod:`knn_tpu.obs.profiler`): opt-in
-  ``jax.profiler.trace`` wrapping of tuning runs
-  (``KNN_TPU_PROFILE_DIR``), for the slack the model can't name.
 - **Tail forensics** (:mod:`knn_tpu.obs.waterfall`): per-request
   latency waterfalls reconstructed from the span stream, critical-path
   attribution at p50 vs p99 per tenant/bucket, histogram->trace
@@ -69,8 +62,6 @@ from knn_tpu.obs import (  # noqa: F401
     health,
     ident,
     names,
-    profiler,
-    roofline,
     slo,
     waterfall,
 )
@@ -122,8 +113,8 @@ __all__ = [
     "get_event_log",
     "get_registry", "get_slo_engine", "health", "histogram", "ident",
     "install_compile_hook", "load_objectives", "names", "new_trace_id",
-    "profiler", "prometheus_text", "record_span", "reset",
-    "reset_event_log", "reset_slo_engine", "roofline", "slo",
+    "prometheus_text", "record_span", "reset",
+    "reset_event_log", "reset_slo_engine", "slo",
     "slo_report", "snapshot", "span", "start_metrics_server",
     "waterfall", "write_json_snapshot",
 ]

@@ -14,8 +14,8 @@ one bounded bundle to ``KNN_TPU_POSTMORTEM_DIR``:
   (built from the SAME evaluation pass that fired — no re-evaluation,
   no second transition),
 - the slowest-requests exemplar table with their inline waterfalls,
-  plus the critical-path attribution and device-vs-roofline verdict
-  over every reconstructable request,
+  plus the critical-path attribution over every reconstructable
+  request,
 - the SLO report and the breach detail that fired,
 - the telemetry-relevant environment (``KNN_TPU_*`` knobs), pid, and a schema version.
 
@@ -160,7 +160,6 @@ def _write_bundle(objective: str, detail: dict,
         "events": events,
         "slowest": slowest,
         "attribution": waterfall.attribute(wfs),
-        "device_vs_roofline": waterfall.device_vs_roofline(wfs),
         "env": {k: v for k, v in sorted(os.environ.items())
                 if k.startswith(("KNN_TPU_", "JAX_PLATFORMS"))},
         # the shadow audit sampler's evidence: summary + the bounded
@@ -169,13 +168,6 @@ def _write_bundle(objective: str, detail: dict,
         # vs what the oracle says)
         "audit": _audit_evidence(),
     }
-    # measured-term calibration state: the statusz report already
-    # carries the section (health's failure-proof probe) — hoist it
-    # top-level so postmortem readers judging "device bound vs model
-    # wrong" find it beside device_vs_roofline, without a second
-    # store read
-    payload["calibration"] = (payload["statusz"] or {}).get(
-        "calibration")
     with _seq_lock:
         _seq += 1
         seq = _seq

@@ -13,9 +13,8 @@ Rendering rules (one source: :func:`prometheus_text` over
 The HTTP server is intentionally boring: ``http.server`` threading
 daemon, ``/metrics`` (text format) + ``/metrics.json`` (the snapshot),
 no deps, no auth — bind it to localhost and let the scraper's side
-handle the rest.  The JSON snapshot writer is atomic (tmp + rename,
-the tuning-cache discipline) so a scraper of the file never reads a
-torn write.
+handle the rest.  The JSON snapshot writer is atomic (tmp + rename)
+so a scraper of the file never reads a torn write.
 """
 
 from __future__ import annotations

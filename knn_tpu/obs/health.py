@@ -20,7 +20,7 @@ the two questions a load balancer asks:
 self-diagnosis: device inventory (only when JAX is ALREADY initialized
 in the process; a status probe must never trigger a backend init),
 per-engine warmup/bucket/compile state, queue depth vs capacity and
-worker liveness, tune-cache status, active SLO breaches, and the last
+worker liveness, active SLO breaches, and the last
 N alert events from the trace ring.  :func:`write
 <knn_tpu.obs.export.write_json_snapshot>` embeds the same report in the
 atomic snapshot, so ``doctor --snapshot`` renders the identical
@@ -40,7 +40,7 @@ import time
 import weakref
 from typing import List, Optional
 
-from knn_tpu.obs import ident, names, registry, roofline, slo, trace
+from knn_tpu.obs import ident, names, registry, slo, trace
 
 #: alert events included in the report (newest last)
 REPORT_ALERTS = 20
@@ -159,14 +159,6 @@ def _engine_status(e) -> dict:
         st = e.stats()
     except Exception as ex:  # noqa: BLE001
         return {"error": f"{type(ex).__name__}: {ex}"}
-    # the resolved autotuner winner's roofline verdict (tuning.
-    # resolve_full surfaces it off the cache entry): which bound class
-    # this engine's certified path would be attacking
-    tun = st.get("tuning") or {}
-    rl = {fld: tun.get(fld)
-          for fld in ("roofline_pct", "bound_class",
-                      "roofline_ceiling_qps")
-          if tun.get(fld) is not None}
     return {
         "warmed_ops": sorted(getattr(e, "warmed_ops", ())),
         "buckets": st.get("buckets"),
@@ -176,7 +168,6 @@ def _engine_status(e) -> dict:
         "queries_total": st.get("queries_total"),
         "errors_total": st.get("errors_total"),
         "latency_ms": st.get("latency_ms"),
-        "roofline": rl or None,
     }
 
 
@@ -207,24 +198,6 @@ def _queue_status(q) -> dict:
         except Exception as ex:  # noqa: BLE001 — probe must not die on it
             out["admission"] = {"error": f"{type(ex).__name__}: {ex}"}
     return out
-
-
-def _tune_cache_status() -> dict:
-    try:
-        from knn_tpu.tuning.cache import default_cache_path
-
-        path = default_cache_path()
-        out = {"path": path, "exists": os.path.exists(path)}
-        if out["exists"]:
-            import json
-
-            with open(path) as f:
-                data = json.load(f)
-            out["entries"] = len(data.get("entries", {}))
-            out["version"] = data.get("version")
-        return out
-    except Exception as e:  # noqa: BLE001
-        return {"error": f"{type(e).__name__}: {e}"}
 
 
 def _slowest_requests() -> list:
@@ -275,18 +248,6 @@ def _quality_status() -> dict:
         return {"error": f"{type(e).__name__}: {e}"}
 
 
-def _calibration_status() -> dict:
-    """The measured-term calibration store's state (worst per-term
-    residual included) — never fatal: a broken store must not take the
-    status probe down with it."""
-    try:
-        from knn_tpu.obs import calibrate
-
-        return calibrate.status()
-    except Exception as e:  # noqa: BLE001 - introspection must not raise
-        return {"error": f"{type(e).__name__}: {e}"}
-
-
 def report(slo_section: Optional[dict] = None,
            slowest: Optional[list] = None) -> dict:
     """The full /statusz payload (see module docstring).  Everything in
@@ -318,15 +279,6 @@ def report(slo_section: Optional[dict] = None,
         "devices": _device_inventory(),
         "engines": [_engine_status(e) for e in engines],
         "queues": [_queue_status(q) for q in queues],
-        "tune_cache": _tune_cache_status(),
-        # every roofline attribution published in this process
-        # (autotuner winners, warm-cache resolves): the named gap per
-        # config, rendered by /statusz and doctor
-        "roofline": roofline.last_reports(),
-        # the measured-term calibration store: whether this process's
-        # roofline verdicts are calibrated, and the worst per-term
-        # residual on file (knn_tpu.obs.calibrate)
-        "calibration": _calibration_status(),
         "slo": slo_section,
         "active_breaches": (slo_section.get("breached", [])
                             if slo_section else []),
@@ -399,7 +351,7 @@ def report_from_snapshot(payload: dict) -> dict:
         "devices": {"available": False,
                     "reason": "not recorded in this snapshot"},
         "engines": [], "queues": [],
-        "tune_cache": {}, "roofline": {}, "calibration": {}, "slo": {},
+        "slo": {},
         "multihost": None, "index": [], "quality": {},
         "active_breaches": [], "alerts": [],
         "slowest_requests": [], "postmortems": {},
@@ -444,40 +396,6 @@ def render_text(rep: dict) -> str:
             f"(util {q.get('rows_utilization')}) "
             f"batcher={'up' if q.get('batcher_alive') else 'DOWN'} "
             f"completer={'up' if q.get('completer_alive') else 'DOWN'}")
-    tc = rep.get("tune_cache", {})
-    if tc:
-        lines.append(f"tune_cache: {tc.get('path')} "
-                     f"exists={tc.get('exists')} "
-                     f"entries={tc.get('entries')}")
-    for cfg, r in (rep.get("roofline") or {}).items():
-        pct = r.get("roofline_pct")
-        pct_s = f"{pct * 100:.1f}% of " if pct is not None else ""
-        est = " [estimated peaks]" if r.get("estimated") else ""
-        cal_s = (" [calibrated]" if r.get("calibration_applied")
-                 else "")
-        lines.append(f"roofline {cfg}: {pct_s}"
-                     f"{r.get('ceiling_qps')} q/s ceiling "
-                     f"({r.get('bound_class')}){est}{cal_s}")
-    cal = rep.get("calibration") or {}
-    if cal.get("store"):
-        worst = cal.get("worst_residual_pct")
-        worst_s = (f", worst term residual {worst}% "
-                   f"({cal.get('worst_residual_key')})"
-                   if worst is not None else "")
-        lines.append(f"calibration: {cal.get('entries')} entr"
-                     f"{'y' if cal.get('entries') == 1 else 'ies'} at "
-                     f"{cal['store']} [{cal.get('model_token')}]"
-                     f"{worst_s}")
-    elif cal.get("error"):
-        # a store that CANNOT report is not the same as no store: the
-        # operator set KNN_TPU_CALIBRATION and deserves the failure,
-        # not a claim that it is unset
-        lines.append(f"calibration: status unavailable "
-                     f"({cal['error']})")
-    elif cal:
-        lines.append("calibration: no store configured "
-                     "(KNN_TPU_CALIBRATION unset) — roofline verdicts "
-                     "are analytic only")
     for i, ix in enumerate(rep.get("index") or []):
         if "error" in ix:
             lines.append(f"index[{i}]: status unavailable "

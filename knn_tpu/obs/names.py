@@ -84,17 +84,6 @@ RANGE_QUERIES = "knn_tpu_range_queries_total"
 RANGE_RESULTS = "knn_tpu_range_results_total"
 PROGRAM_LAUNCHES = "knn_tpu_program_launches_total"
 
-# --- autotuner (knn_tpu.tuning) ----------------------------------------
-TUNING_RESOLVES = "knn_tpu_tuning_resolve_total"
-TUNING_CACHE_HITS = "knn_tpu_tuning_cache_hits_total"
-TUNING_CACHE_MISSES = "knn_tpu_tuning_cache_misses_total"
-TUNING_SEARCHES = "knn_tpu_tuning_searches_total"
-TUNING_CANDIDATES_TIMED = "knn_tpu_tuning_candidates_timed_total"
-TUNING_GATE_FAILURES = "knn_tpu_tuning_gate_failures_total"
-TUNING_CANDIDATES_PRUNED = "knn_tpu_tuning_candidates_pruned_total"
-TUNING_CANDIDATES_VMEM_REFUSED = \
-    "knn_tpu_tuning_candidates_vmem_refused_total"
-
 # --- JAX compile events (knn_tpu.obs.jax_hooks) ------------------------
 JAX_COMPILES = "knn_tpu_jax_compiles_total"
 JAX_COMPILE_SECONDS = "knn_tpu_jax_compile_seconds_total"
@@ -115,17 +104,6 @@ HEALTH_READY = "knn_tpu_health_ready"
 
 # --- flight recorder (knn_tpu.obs.blackbox) ----------------------------
 POSTMORTEMS_WRITTEN = "knn_tpu_postmortems_written_total"
-
-# --- roofline model (knn_tpu.obs.roofline) -----------------------------
-ROOFLINE_PCT = "knn_tpu_roofline_pct"
-ROOFLINE_CEILING_QPS = "knn_tpu_roofline_ceiling_qps"
-ROOFLINE_BOUND = "knn_tpu_roofline_bound"
-ROOFLINE_EVALUATIONS = "knn_tpu_roofline_evaluations_total"
-
-# --- measured-term calibration (knn_tpu.obs.calibrate) -----------------
-CALIBRATION_APPLIED = "knn_tpu_calibration_applied"
-CALIBRATION_AGE = "knn_tpu_calibration_age_seconds"
-CALIBRATION_RESIDUAL = "knn_tpu_calibration_residual_pct"
 
 # --- multi-host merge tree (knn_tpu.parallel.sharded / .multihost) -----
 MERGE_SELECTED = "knn_tpu_merge_strategy_selected_total"
@@ -378,32 +356,6 @@ CATALOG = {
         "resident row operands: 1 a placement and geometry, in the "
         "call that first resolved it).  Moved once a call, by the "
         "call's account (obs.trace.CallAccount)."),
-    TUNING_RESOLVES: (
-        "counter", (), "tuning.resolve() invocations."),
-    TUNING_CACHE_HITS: (
-        "counter", (), "Knob resolutions served from the persisted "
-        "winner cache."),
-    TUNING_CACHE_MISSES: (
-        "counter", (), "Knob resolutions that fell back to defaults."),
-    TUNING_SEARCHES: (
-        "counter", (), "autotune() runs that actually searched the "
-        "grid."),
-    TUNING_CANDIDATES_TIMED: (
-        "counter", (), "Autotuner candidates built and timed (0 on a "
-        "warm cache)."),
-    TUNING_GATE_FAILURES: (
-        "counter", (), "Autotuner candidates rejected by the bitwise "
-        "end-result gate."),
-    TUNING_CANDIDATES_PRUNED: (
-        "counter", (), "Autotuner candidates skipped before timing by "
-        "the roofline-model pruning gate (KNN_TPU_TUNE_PRUNE; every "
-        "skip is recorded in the tune entry's pruning provenance)."),
-    TUNING_CANDIDATES_VMEM_REFUSED: (
-        "counter", (), "Autotuner candidates refused before timing by "
-        "the analytic VMEM budget gate (knn_tpu.analysis.vmem): their "
-        "estimated per-launch footprint exceeds the device kind's VMEM, "
-        "so they would fail at Mosaic compile time; every refusal is "
-        "recorded in the tune entry's vmem provenance."),
     JAX_COMPILES: (
         "counter", ("event",),
         "JAX/XLA compile and compilation-cache events observed via "
@@ -449,36 +401,6 @@ CATALOG = {
         "Flight-recorder postmortem bundles written to "
         "KNN_TPU_POSTMORTEM_DIR, one per edge-triggered SLO breach "
         "transition, by the objective that fired."),
-    ROOFLINE_PCT: (
-        "gauge", ("config",),
-        "Measured throughput as a fraction of the analytic roofline "
-        "ceiling for the labeled config (knn_tpu.obs.roofline)."),
-    ROOFLINE_CEILING_QPS: (
-        "gauge", ("config",),
-        "Predicted roofline ceiling q/s for the labeled config — the "
-        "slowest of the HBM / MXU / VPU-select terms at device peaks."),
-    ROOFLINE_BOUND: (
-        "gauge", ("config", "class"),
-        "1 for the config's active bound class (hbm_bound / mxu_bound "
-        "/ vpu_select_bound), 0 for the others."),
-    ROOFLINE_EVALUATIONS: (
-        "counter", (),
-        "Roofline attributions published to the registry (autotuner "
-        "winners, warm-cache resolves)."),
-    CALIBRATION_APPLIED: (
-        "gauge", ("config",),
-        "1 when the labeled config's published roofline block carried "
-        "an APPLIED measured-term calibration overlay "
-        "(knn_tpu.obs.calibrate), 0 when it rendered analytic-only."),
-    CALIBRATION_AGE: (
-        "gauge", ("config",),
-        "Age (seconds) of the calibration entry applied to the "
-        "labeled config — how stale the measured factors are."),
-    CALIBRATION_RESIDUAL: (
-        "gauge", ("config",),
-        "Signed percent by which the ANALYTIC model mispredicted the "
-        "measured device time for the labeled config (the reconciled "
-        "model_residual_pct) — the calibration-drift signal."),
     MERGE_SELECTED: (
         "counter", ("level", "strategy", "source"),
         "Merge-strategy resolutions at placement time, by merge level "
@@ -488,8 +410,7 @@ CATALOG = {
     MERGE_BYTES: (
         "counter", ("level", "strategy"),
         "Modeled candidate bytes moved by top-k merges "
-        "(parallel.crossover.merge_bytes), by level and strategy — "
-        "the DCN volume the roofline's dcn term prices."),
+        "(parallel.crossover.merge_bytes), by level and strategy."),
     SELECT_MERGE_CALLS: (
         "counter", ("engaged",),
         "Batches of search_certified(selector='pallas'), by whether "

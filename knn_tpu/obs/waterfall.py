@@ -3,7 +3,7 @@ the forensics layer that turns the flat trace events (knn_tpu.obs.trace)
 back into "where did THIS request's time go".
 
 Every aggregate latency surface the repo has (the p99 histograms, the
-SLO burn rates, the roofline ceiling) answers "how bad is the tail";
+SLO burn rates) answers "how bad is the tail";
 none can answer "WHICH requests blew it, and on what segment".  The
 serving layer already emits everything needed — per-request trace ids,
 queue/admission/dispatch/compile/join/deliver spans, and the
@@ -22,10 +22,6 @@ engine request — this module is the reconstruction:
 - :func:`attribute` — critical-path attribution across many waterfalls:
   which segment dominates at the p50 band vs the p99 tail, overall and
   per tenant / per bucket (the grouped view the per-tenant SLOs judge).
-- :func:`device_vs_roofline` — the device segment of the tail compared
-  against the analytic roofline ceiling (knn_tpu.obs.roofline), so a
-  fat "device" segment that is really pipeline wait (implied q/s far
-  under the ceiling) reads ``queued_behind_device``, not device-bound.
 - :func:`slowest_table` — the worst recent requests by histogram
   exemplar (knn_tpu.obs.registry), each with its inline waterfall: the
   ``stats()``/``/statusz``/doctor "slowest recent requests" table.
@@ -51,7 +47,7 @@ Segment semantics (durations, never mixed-clock wall arithmetic):
 - ``device``     — the batch request span minus its dispatch and join
   spans: the in-flight window between dispatch return and result join.
   Under dispatch-ahead this INCLUDES waiting behind earlier in-flight
-  batches — :func:`device_vs_roofline` is how that is told apart.
+  batches.
 - ``join``       — time blocked on the device transfer in ``result()``.
 - ``deliver``    — batch completion to THIS member's future resolution
   (scatter + head-of-line in the completer loop).
@@ -90,11 +86,6 @@ DIRECT_SEGMENTS = ("dispatch", "compile", "device", "join")
 _EXEMPLAR_HISTS = (names.SERVING_REQUEST_LATENCY,
                    names.QUEUE_REQUEST_LATENCY,
                    names.TENANT_REQUEST_LATENCY)
-
-#: implied-device-throughput floor (fraction of the roofline ceiling)
-#: below which a dominant "device" segment is reclassified as pipeline
-#: wait — compute that slow isn't compute
-DEVICE_PCT_MIN = 0.25
 
 
 def tolerance_s(total_s: float, *, abs_s: float = TOLERANCE_ABS_S,
@@ -406,7 +397,7 @@ def attribute(waterfalls) -> dict:
     dominates at the p50 band vs the p99 tail — overall, per tenant,
     and per bucket.  The number the "why is p99 40x p50 at the knee"
     question needs: a queue_wait-dominated tail is a scheduling
-    problem, a device-dominated one a kernel (roofline) problem."""
+    problem, a device-dominated one a kernel problem."""
     ws = (list(waterfalls.values()) if isinstance(waterfalls, dict)
           else list(waterfalls))
     # batch-level engine requests are plumbing their members already
@@ -426,70 +417,6 @@ def attribute(waterfalls) -> dict:
                         for b, g in sorted(by_bucket.items(),
                                            key=lambda kv: int(kv[0]))}
     return out
-
-
-def device_vs_roofline(waterfalls, ceiling_qps: Optional[float] = None
-                       ) -> dict:
-    """Tell a device-bound tail from a queue-bound one: the p99 tail's
-    dominant segment, plus the device segment's IMPLIED throughput
-    (rows / device seconds) against the analytic roofline ceiling.  A
-    dominant device segment whose implied q/s sits far under the
-    ceiling is not compute — it is pipeline/queue wait wearing the
-    device's clothes (``queued_behind_device``).  ``ceiling_qps``
-    defaults to the best ceiling published in this process
-    (knn_tpu.obs.roofline); None disables the percent and the verdict
-    falls back to segment shares alone."""
-    ws = (list(waterfalls.values()) if isinstance(waterfalls, dict)
-          else list(waterfalls))
-    ws = [w for w in ws if w and (w["total_s"] or 0.0) > 0
-          and w.get("kind") != "batch"]
-    if ceiling_qps is None:
-        try:
-            from knn_tpu.obs import roofline
-
-            ceilings = [r.get("ceiling_qps")
-                        for r in roofline.last_reports().values()
-                        if r.get("ceiling_qps")]
-            ceiling_qps = max(ceilings) if ceilings else None
-        except Exception:  # pragma: no cover - attribution must not die
-            ceiling_qps = None
-    if not ws:
-        return {"requests": 0, "ceiling_qps": ceiling_qps,
-                "verdict": None}
-    totals = sorted(w["total_s"] for w in ws)
-    p99 = _percentile(totals, 99)
-    tail = [w for w in ws if w["total_s"] >= p99] \
-        or [max(ws, key=lambda w: w["total_s"])]
-    stats = _band_stats(tail)
-    dominant = stats["dominant"] if stats else None
-    implied = sorted(
-        w["rows"] / d for w in tail
-        if w.get("rows")
-        for d in [next((s["dur_s"] for s in w["segments"]
-                        if s["name"] == "device"), 0.0)]
-        if d > 0)
-    device_qps = (round(_percentile(implied, 50), 2) if implied else None)
-    pct = (round(device_qps / ceiling_qps, 4)
-           if device_qps and ceiling_qps else None)
-    if dominant in ("device", "join"):
-        verdict = ("queued_behind_device"
-                   if pct is not None and pct < DEVICE_PCT_MIN
-                   else "device_bound")
-    elif dominant in ("queue_wait", "admission"):
-        verdict = "queue_bound"
-    elif dominant is None:
-        verdict = None
-    else:
-        verdict = "host_bound"
-    return {
-        "requests": len(ws),
-        "tail_requests": len(tail),
-        "tail_dominant_segment": dominant,
-        "tail_device_qps": device_qps,
-        "ceiling_qps": ceiling_qps,
-        "tail_device_roofline_pct": pct,
-        "verdict": verdict,
-    }
 
 
 # -- the slowest-requests table -------------------------------------------
@@ -538,8 +465,8 @@ def slowest_table(*, top: int = 8, with_waterfalls: bool = True,
 
 def live_report(events: Optional[Sequence[dict]] = None) -> dict:
     """The full forensics payload over the live ring (or ``events``):
-    every reconstructable waterfall, the critical-path attribution, the
-    device-vs-roofline verdict, and the slowest-requests table — what
+    every reconstructable waterfall, the critical-path attribution
+    and the slowest-requests table — what
     ``/waterfallz`` serves and a postmortem bundle embeds."""
     evts = trace.get_event_log().recent() if events is None else events
     wfs = reconstruct(evts)
@@ -550,7 +477,6 @@ def live_report(events: Optional[Sequence[dict]] = None) -> dict:
         "requests": len(wfs),
         "waterfalls": wfs,
         "attribution": attribute(wfs),
-        "device_vs_roofline": device_vs_roofline(wfs),
         "slowest": slowest_table(events=evts, waterfalls=wfs),
         # cross-host waterfalls stitched from multihost.merge spans —
         # absent (None) when no DCN merge ran in this process
@@ -586,7 +512,7 @@ def render_waterfall(w: dict) -> str:
     return "\n".join(lines)
 
 
-def render_attribution(agg: dict, dvr: Optional[dict] = None) -> str:
+def render_attribution(agg: dict) -> str:
     """The aggregated critical-path story as text."""
     lines = [f"attribution over {agg.get('requests', 0)} request(s)"
              + (f" ({agg['incomplete']} incomplete)"
@@ -614,12 +540,4 @@ def render_attribution(agg: dict, dvr: Optional[dict] = None) -> str:
         _one(f"tenant {t}", bands, indent="    ")
     for b, bands in (agg.get("by_bucket") or {}).items():
         _one(f"bucket {b}", bands, indent="    ")
-    if dvr and dvr.get("verdict"):
-        pct = dvr.get("tail_device_roofline_pct")
-        lines.append(
-            f"  tail verdict: {dvr['verdict']} (dominant "
-            f"{dvr.get('tail_dominant_segment')}, device "
-            f"{dvr.get('tail_device_qps')} q/s"
-            + (f" = {pct * 100:.1f}% of {dvr.get('ceiling_qps')} q/s "
-               f"ceiling" if pct is not None else "") + ")")
     return "\n".join(lines)

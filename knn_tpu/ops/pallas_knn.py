@@ -136,9 +136,9 @@ see ``KERNELS``):
   collapse, and any width handed to both) — both run the same
   emitters on the same per-tile scores — so the downstream certified
   pipeline is unchanged and interpret-mode equality is testable
-  (tests/test_pallas_streaming.py).  Opt-in until the on-hardware gate
-  + A/B pass on it; the autotuner (knn_tpu.tuning) carries it in the default
-  knob grid so the next TPU session measures it.
+  (tests/test_pallas_streaming.py).  Opt-in (``kernel="streaming"`` in
+  the call): no cell of the benchmark runs it, and it has no time on
+  the chip.
 
 Runs in interpret mode off-TPU so the CPU test suite covers it;
 ``chip_smoke.py`` gates the *compiled* kernel against a float64 oracle
@@ -304,33 +304,6 @@ def _split_qt(q, th, tl, terms: str):
     return qt
 
 
-#: kernel/emitter code version: BUMP whenever the kernel arithmetic, the
-#: emitters, or the knob semantics change — the autotuner's persisted
-#: winner cache keys on it (tuning.cache.cache_key), so winners measured
-#: against older kernel code self-invalidate instead of silently steering
-#: a changed kernel.  3 = int8 emitter path added (PR 3); 4 = fused
-#: in-loop select arm + the r05-proven block_q=256 default promotion
-#: (tuning.DEFAULT_KNOBS) — old winners measured against block_q=128
-#: reference runs self-invalidate.  5 = sub-int8 arms (PQ LUT/one-hot
-#: scoring, PR 17): the precision knob domain widened, so winners tuned
-#: on the v4 grid self-invalidate.
-#: 6 = the final select's bin-merge (PR 28): the tuner times
-#: local_certified_candidates, whose tail changed at wide shards.
-#: 7 = the knob domain narrowed (PR 29): no select-layout or bin-width
-#: knob, no 4-bit and no single-pass DEFAULT precision — a persisted
-#: winner that names one is never looked up again.
-#: 8 = the bf16x3 product drops the terms whose low operand is all zero
-#: (PR 30, ``BF16X3_TERMS``): the tuner's timings depend on its rows.
-#: 9 = a row tile whose padded width fits VMEM is one dim chunk (PR 32,
-#: ``dim_chunking``): the kernel at 129...512 columns is another program.
-#: 10 = the final top-(m+2) and its index gather are one Pallas stage
-#: where ``final_select_geometry`` engages (PR 35): the tail the tuner
-#: times with the kernel changed.
-#: 11 = a row tile too wide for VMEM is cut by rows, not by columns (PR
-#: 46, ``row_blocking``): the kernel from 640 placed columns up (two row
-#: parts) is another program, a third faster a tile at GIST's shape.
-KERNEL_VERSION = 11
-
 #: relative slack of the device rank stage's direct-difference f32
 #: distances: per-term (q-t)^2 rounding plus the depth-7 tree reduce give
 #: |d32 - d| <= ~1.2e-6 * d; 2^-18 = 3.8e-6 is ~3x headroom.  Candidate
@@ -389,8 +362,7 @@ GRID_ORDERS = ("query_major", "db_major")
 #: exclusion-bound early-out skips a tile's whole select chain when its
 #: best possible score provably cannot enter the final top-(m+2) nor
 #: lower the exclusion bound — the select cost rides the HBM stream's
-#: shadow instead of following it (the `vpu_select_bound` attack named
-#: by the PR 6 roofline model).  Final certified results are
+#: shadow instead of following it.  Final certified results are
 #: bitwise-identical to the tiled reference: a skipped tile's candidate
 #: block pads with +inf/sentinel, and the skip predicate (strict
 #: tile-min > carry threshold, threshold an upper bound on the final
@@ -1415,7 +1387,7 @@ def _bin_candidates(
     ``precision="int8"`` adds a quantized coarse arm (ops.quantize):
     queries quantize per call in an XLA prologue (like the bf16 split);
     the db either quantizes the same way (``db_int8=None`` — the
-    convenience/test/autotune path) or arrives PRE-QUANTIZED as
+    convenience/test path) or arrives PRE-QUANTIZED as
     ``db_int8=(values int8 [N,D], scales f32 [N], row_norms f32 [N])``
     — the ShardedKNN placement path, where the f32 db never re-streams
     for the coarse pass.  ``offset`` is the translation-invariance shift
@@ -1516,8 +1488,7 @@ def _bin_candidates(
     if kernel in ("streaming", "fused") and grid_order != "query_major":
         # the streaming/fused launches have no db grid axis to reorder:
         # their tile loop is inherently query-major.  Refuse rather than
-        # silently ignore the knob (the autotuner enumerates valid
-        # combinations).
+        # silently ignore the knob.
         raise ValueError(
             f"kernel={kernel!r} streams the db inside one launch; "
             f"grid_order='db_major' does not apply")
@@ -1582,7 +1553,7 @@ def _bin_candidates(
 
         # queries quantize per call (one XLA prologue pass, like the
         # bf16 split); the db either quantizes here too (convenience /
-        # autotune path) or arrives pre-quantized from the placement
+        # test path) or arrives pre-quantized from the placement
         qi, qsc = quantize_rows(queries - offset)
         queries_in = qi
         q_extra = [jnp.broadcast_to(qsc[:, None], (qp, BIN_W))]

@@ -8,8 +8,7 @@ split the dim into ``m = ceil(d / dsub)`` subspaces, train a
 ``C``-codeword codebook per subspace, and a row becomes ``m`` bytes: at
 SIFT's d=128
 with the classic (dsub=4, C=256) point that is 32 B/row, 1/16 the f32
-stream and 1/4 int8's, which is exactly the byte term the calibrated
-roofline says is the ceiling (ISSUE 17 / ROADMAP item 4).
+stream and 1/4 int8's (ISSUE 17 / ROADMAP item 4).
 
 Training is the SEEDED DETERMINISTIC k-means the IVF tier already
 ships (``knn_tpu.ivf.kmeans.train_kmeans``): same sharded Lloyd assign

@@ -19,8 +19,7 @@ shard_across_hosts / process_row_slice.
 
 # Attribute access is lazy (PEP 562, the knn_tpu/__init__ idiom) so the
 # jax-free members — parallel.crossover's measured table, validators,
-# and byte models, consumed by the artifact refresher and the roofline
-# model — never pay (or break on) the JAX import the mesh/collective/
+# and byte models — never pay (or break on) the JAX import the mesh/collective/
 # SPMD members need.
 import importlib
 

@@ -27,7 +27,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # The measured ring/allgather crossover (SCALING.json) lives jax-free in
-# parallel.crossover so the artifact refresher and the roofline model
+# parallel.crossover so a jax-free reader
 # can read it without a backend; re-exported here because strategy
 # choice is a property of this collective surface.
 from knn_tpu.parallel.crossover import (  # noqa: F401  (re-export)

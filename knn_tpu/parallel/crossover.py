@@ -20,13 +20,12 @@ This is the jax-free home of
   (``KNN_TPU_MERGE`` / ``KNN_TPU_DCN_MERGE``) > measured table**;
 - :func:`merge_bytes` — the collective-volume model behind the
   ``merge_bytes_per_sweep`` column (allgather moves ``Q·k·8·P`` bytes,
-  ring ``Q·k·8·(P-1)``; 8 = f32 distance + i32 index per candidate),
-  reused by the roofline's DCN term;
+  ring ``Q·k·8·(P-1)``; 8 = f32 distance + i32 index per candidate);
 - :func:`validate_multihost_block` — structural validation of the
-  ``multihost`` block (the roofline-block discipline).
+  ``multihost`` block.
 
-Everything here is plain arithmetic on plain numbers so the roofline
-model imports it without JAX.
+Everything here is plain arithmetic on plain numbers, importable
+without JAX.
 """
 
 from __future__ import annotations

@@ -174,7 +174,7 @@ def _overlap_ratio(intervals) -> float:
     dispatch start to result-repair end; join.engine's superblock
     pipelines) — the honest, host-measurable overlap number: it reports
     dispatch-timeline concurrency, not device-internal overlap (which
-    needs a hardware trace; obs.profiler).  0.0 for < 2 batches."""
+    needs a hardware trace: jax.profiler).  0.0 for < 2 batches."""
     if len(intervals) < 2:
         return 0.0
     events = []
@@ -1134,7 +1134,7 @@ class ShardedKNN:
     an attribute that follows the rows' order (an id, a time stamp)
     keeps rows of few tiles and broke that for every query of a narrow
     range; in a random order the rows ANY range keeps lie over all the
-    bins.  No argument, environment variable or tuning entry: the
+    bins.  No argument, environment variable or knob: the
     constructor's ``row_attr`` is the whole statement, a shuffled
     attribute loses nothing by it, and the order on the device is
     private.  Everything the HOST holds stays in the caller's order
@@ -1651,8 +1651,8 @@ class ShardedKNN:
 
     def _record_merge_bytes(self, n_rows: int, k: int) -> int:
         """Mirror the modeled per-level merge volume into the registry
-        (crossover.merge_bytes — the same model the roofline's DCN term
-        prices); returns the bytes counted, over both levels."""
+        (crossover.merge_bytes, the measured crossover table's byte
+        model); returns the bytes counted, over both levels."""
         hosts, chips = db_topology(self.mesh)
         total = 0
         if chips > 1:
@@ -2649,12 +2649,12 @@ class ShardedKNN:
         Pallas-selector tuning knobs (``tile_n``, ``block_q``,
         ``survivors``, ``precision``, ``final_select``,
         ``grid_order``, ``final_recall_target``, ``kernel``): any knob
-        left at None resolves through ``knn_tpu.tuning.resolve`` — the
-        persisted autotuner winner for this exact
-        ``(device_kind, n, d, k, metric, dtype)`` when one exists
-        (``python -m knn_tpu.cli tune``; ``tune_cache`` overrides the
-        cache file), else the library defaults — and EXPLICIT values
-        always win over both.  ``kernel`` picks the db-streaming
+        left at None resolves through ``knn_tpu.tuning.resolve`` to
+        the library default (``tuning.DEFAULT_KNOBS``), whatever the
+        shape or the device; an EXPLICIT value wins.  No file and no
+        environment variable names a knob: ``tune_cache`` has one legal
+        value, None, and anything else raises (the winner cache it
+        named went in PR 59).  ``kernel`` picks the db-streaming
         strategy (ops.pallas_knn.KERNELS: "tiled" | the one-launch
         double-buffered "streaming").  ``recall_target`` tunes the
         counted "approx" selector's per-element ApproxTopK recall
@@ -2847,8 +2847,8 @@ class ShardedKNN:
                 tune_info = None
                 if selector == "pallas":
                     # ONE knob-resolution home (knn_tpu.tuning): explicit
-                    # args > the persisted autotuner winner for this
-                    # placement's shape > library defaults
+                    # args > library defaults, and nothing between them
+                    # (a tune_cache that is not None raises there)
                     from knn_tpu import tuning
 
                     knobs, tune_info = tuning.resolve_full(

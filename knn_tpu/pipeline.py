@@ -182,7 +182,6 @@ def _run_jax(cfg: JobConfig, timer: PhaseTimer, train, train_labels, test, val,
                 # certificate stats (and can spuriously fall back)
                 labels_out, stats = program.predict_certified(
                     chunk[:take], selector=cfg.selector,
-                    tune_cache=cfg.tune_cache,
                     precision=cfg.pallas_precision,
                 )
                 for key, v in stats.items():  # incl. host_exact_queries

@@ -327,13 +327,7 @@ class ServingEngine:
     def warmup(self, ops: Sequence[str] = ("search",)) -> Dict[str, int]:
         """AOT-compile every bucket for each requested op so no live
         request ever pays an inline compile.  Returns per-op executable
-        counts (ladder rungs sharing a placed shape share an executable).
-
-        When the autotuner's persisted winner for this placement's shape
-        resolves ``precision="int8"``, warmup also builds the quantized
-        db placement (ShardedKNN._int8_placement) — a one-time full-db
-        quantize + transfer that would otherwise land on the first live
-        certified query."""
+        counts (ladder rungs sharing a placed shape share an executable)."""
         counts = {}
         for op in ops:
             if op not in OPS:
@@ -344,14 +338,6 @@ class ServingEngine:
                 keys = list(self._execs)
             counts[op] = len({k for k in keys if k[0] == op})
             self.warmed_ops.add(op)  # /healthz readiness flips here
-        info = self._tuning_info()
-        if (info and info.get("resolved_knobs", {}).get("precision")
-                == "int8"):
-            try:
-                self.program._int8_placement()
-                counts["int8_placement"] = 1
-            except Exception:  # pragma: no cover - placement best-effort
-                pass  # a live int8 call will rebuild (and surface) it
         return counts
 
     # -- dispatch ----------------------------------------------------------
@@ -576,35 +562,6 @@ class ServingEngine:
         if tenant is not None:
             obs.counter(mn.TENANT_ERRORS, tenant=tenant).inc()
 
-    def _tuning_info(self) -> Optional[dict]:
-        """Resolved kernel knobs + provenance for this placement's shape
-        (knn_tpu.tuning — the same resolve call search_certified makes),
-        so serving observability shows whether a persisted autotuner
-        winner or the library defaults would drive the certified path
-        on this engine's placement.  Memoized; never fatal (tuning is
-        observability here, not a dispatch dependency)."""
-        cached = getattr(self, "_tuning_memo", False)
-        if cached is not False:
-            return cached
-        try:
-            from knn_tpu import tuning
-
-            p = self.program
-            # the same key search_certified resolves with: the cosine
-            # certificate runs on unit vectors and the dot/MIPS one on
-            # norm-augmented vectors, both under the l2 kernel, so
-            # their winners are keyed (and must be looked up) as l2
-            cert_metric = ("l2" if p.metric in ("cosine", "dot")
-                           else p.metric)
-            knobs, info = tuning.resolve_full(
-                p.n_train, self._dim, self.k, metric=cert_metric,
-                dtype=p._dtype_key)
-            memo = {"resolved_knobs": knobs, **info}
-        except Exception:  # pragma: no cover - backend-less stats call
-            memo = None
-        self._tuning_memo = memo
-        return memo
-
     def stats(self, *, include_slo: bool = True) -> dict:
         """Compile/dispatch accounting + request latency percentiles —
         the serving metrics JobResult/bench surface.  When telemetry is
@@ -615,7 +572,6 @@ class ServingEngine:
         ``include_slo=False`` skips that pass for callers that already
         ran their own (the health report evaluates once and reads every
         engine's raw stats alongside)."""
-        tuning_info = self._tuning_info()
         slo_section = (obs.slo_report()
                        if include_slo and obs.enabled() else None)
         # the slowest-requests exemplar table (trace ids of the worst
@@ -642,7 +598,6 @@ class ServingEngine:
                 quality = None
         with self._lock:
             return {
-                **({"tuning": tuning_info} if tuning_info else {}),
                 **({"slo": slo_section} if slo_section else {}),
                 **({"quality": quality} if quality else {}),
                 **({"slowest_requests": slowest}

@@ -83,18 +83,13 @@ class JobConfig:
     #: request may wait to be coalesced with others.  Echoed into the
     #: serving metrics; only a concurrent-request queue consults it.
     max_wait_ms: float = 2.0
-    #: autotuner winner-cache file for the certified pallas selector
-    #: (knn_tpu.tuning; populate with `python -m knn_tpu.cli tune`).
-    #: None = $KNN_TPU_TUNE_CACHE or the user default path; the job's
-    #: kernel knobs resolve from it through tuning.resolve, and the
-    #: resolved set lands in metrics()["certified_stats"]["pallas_knobs"].
-    tune_cache: Optional[str] = None
     #: explicit kernel matmul precision for the certified pallas
     #: selector (CERTIFIED_PRECISIONS): "bf16x3" | "bf16x3f" |
     #: "highest" | "int8"
     #: (the quantized MXU arm — ops.quantize) | "pq" (product-quantized
-    #: codes — ops.pq).  None = resolve through the autotuner cache /
-    #: library default; an explicit value beats both.
+    #: codes — ops.pq).  None = the library default
+    #: (tuning.DEFAULT_KNOBS); the resolved knob set lands in
+    #: metrics()["certified_stats"]["pallas_knobs"].
     pallas_precision: Optional[str] = None
     # --- native backend knobs ---
     num_threads: int = 0  # 0 = hardware concurrency

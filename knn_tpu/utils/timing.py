@@ -22,8 +22,7 @@ first-start/last-stop total silently double-count under re-entrant
 numbers.  Distinct threads timing concurrent phases are fine (their
 wall intervals legitimately overlap).
 
-For deep dives, ``knn_tpu.obs.profiler.device_trace`` is the one wrapper
-of ``jax.profiler.trace``.
+For deep dives, capture a device trace with ``jax.profiler.trace``.
 """
 
 from __future__ import annotations

@@ -8,8 +8,8 @@ chip time.  A compile is not a run — the chip has the last word
 
 With no arguments it compiles the certified coarse pass (compiled,
 4,096 queries) at the three benchmark shapes with the knobs the library
-resolves when nobody picks any (``tuning.resolve_full``, no winner
-cache), for each of the three kernels, and again at 512 columns, the
+resolves when nobody picks any (``tuning.resolve_full``: the library
+defaults), for each of the three kernels, and again at 512 columns, the
 widest rows whose tile the tiled kernel runs as ONE grid step
 (``analysis.vmem.row_blocking``; GloVe's 384 and ``text2image2m5``'s
 256 padded columns are the other two such widths in the list), and at
@@ -337,7 +337,7 @@ def temporaries_table(shape: str, devices, terms=None, *,
 
     n, given, k = SHAPES[shape]
     d = lane_tiled(given)
-    knobs, _ = tuning.resolve_full(n, given, k, cache_path=os.devnull)
+    knobs, _ = tuning.resolve_full(n, given, k)
     name = "vote" if shape in VOTED else "certified"
     cases = [(f"{name}, operands {'resident' if resident else 'in the call'}"
               f", {queries} queries",
@@ -629,10 +629,8 @@ def run_case(name, shape, overrides, expect, mesh, terms, devices, *,
              merge="ring", probe=False, row_block=None) -> bool:
     from knn_tpu import tuning
 
-    # the knobs search_certified would resolve for these overrides with
-    # no winner cache (os.devnull reads as an empty one)
-    knobs, _ = tuning.resolve_full(
-        *SHAPES[shape], overrides=overrides, cache_path=os.devnull)
+    # the knobs search_certified would resolve for these overrides
+    knobs, _ = tuning.resolve_full(*SHAPES[shape], overrides=overrides)
 
     def make_case():
         if shape in SELF:
@@ -722,8 +720,8 @@ def main(argv=None) -> int:
         for shape in ([args.shape] if args.shape else TEMPORARIES_SHAPES):
             placed, table = temporaries_table(shape, devices, args.terms)
             n, given, k = SHAPES[shape]
-            launch = launch_queries(shape, tuning.resolve_full(
-                n, given, k, cache_path=os.devnull)[0])
+            launch = launch_queries(
+                shape, tuning.resolve_full(n, given, k)[0])
             beside = (f"operands resident, {launch} queries", "re-select")
             for label, temp in table:
                 if temp is None:
@@ -827,7 +825,7 @@ def main(argv=None) -> int:
             (shape, block_q) for shape in ("gist", "openai500k")
             for block_q in (None, 128)]):
         knobs, _ = tuning.resolve_full(
-            *SHAPES[shape], cache_path=os.devnull,
+            *SHAPES[shape],
             overrides={"block_q": block_q} if block_q else {})
         geo = vmem.launch_estimate(
             n=SHAPES[shape][0], d=SHAPES[shape][1], k=SHAPES[shape][2],

@@ -6,13 +6,13 @@
 # `pytest tests/ -m slow`.
 #
 # --fast: the inner-loop subset — kernel parity (tiled vs streaming vs
-# int8 bitwise contracts) + quantization bound soundness + the autotuner
-# gate + the telemetry registry/exporters + the SLO engine and the
-# roofline cost model (docs/OBSERVABILITY.md); the static-analysis suite
+# int8 bitwise contracts) + quantization bound soundness + the knob
+# resolver + the telemetry registry/exporters + the SLO engine
+# (docs/OBSERVABILITY.md); the static-analysis suite
 # `cli lint` (docs/ANALYSIS.md: switch/metric lockstep, locked-mutation,
 # jax-hygiene, VMEM budget) rides along as a HARD gate so an uncataloged
 # switch, an undocumented metric, an unlocked mutation or an over-VMEM
-# knob candidate fails here, not in review — for edit-compile-test
+# default knob set fails here, not in review — for edit-compile-test
 # cycles on kernel/emitter/obs code (~tens of seconds instead of the
 # full suite).  The full gate remains the only gate that counts; --fast
 # is a developer convenience (docs/PERF.md).
@@ -35,8 +35,7 @@ if [ "${1:-}" = "--fast" ]; then
     tests/test_fused_overlap.py \
     tests/test_quantize.py tests/test_pq.py tests/test_tuning.py \
     tests/test_obs.py \
-    tests/test_slo.py tests/test_roofline.py \
-    tests/test_calibrate.py \
+    tests/test_slo.py tests/test_layering.py \
     tests/test_loadgen.py tests/test_admission.py \
     tests/test_waterfall.py tests/test_index.py \
     tests/test_multihost.py tests/test_hosttier.py \

@@ -25,12 +25,6 @@ from knn_tpu.analysis.switches import isolation_names
 
 for _knob in isolation_names(os.environ):
     os.environ.pop(_knob, None)
-# isolate the autotuner's persisted winner cache: a developer machine's
-# real ~/.cache/knn_tpu/autotune.json must never steer test kernels
-# (tests that exercise the cache pass explicit paths / their own env).
-# Set AFTER the scrub — this is the suite's own value, not an ambient one.
-os.environ["KNN_TPU_TUNE_CACHE"] = os.path.join(
-    tempfile.mkdtemp(prefix="knn_tpu_test_tune_"), "autotune.json")
 # tests see real compiles: JAX's persistent compilation cache stays off
 # for this process and every subprocess, so an entry point under test
 # (cli.main) that calls utils.compat.enable_compile_cache

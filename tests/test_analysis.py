@@ -1,9 +1,10 @@
 """The static-analysis suite itself (knn_tpu.analysis, docs/ANALYSIS.md):
 framework semantics (registry, suppression grammar, crash-to-finding),
 one known-bad and one known-good fixture per checker, the geometry/width
-mirror pins of the VMEM model, the autotuner's runtime VMEM gate, the
-runtime lock-order (deadlock) harness over the real serving stack, and
-the ``cli lint`` subprocess exit-code contract.
+mirror pins of the VMEM model (the row widths against the arrays the
+kernel builds), the default knobs priced at every benchmark cell's
+shape, the runtime lock-order (deadlock) harness over the real serving
+stack, and the ``cli lint`` subprocess exit-code contract.
 
 The fixture trees seed deliberate violations (uncataloged switches,
 phantom metrics, unlocked mutations) — tests/ is exempt from the lint's
@@ -17,13 +18,15 @@ import sys
 import textwrap
 import threading
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
+from cells import CONFIGS as CELL_CONFIGS, config as cell_config
 
 from knn_tpu import analysis
 from knn_tpu.analysis import switches as sw
 from knn_tpu.analysis import vmem
-from knn_tpu.analysis.check_vmem import grid_findings
+from knn_tpu.analysis.check_vmem import default_findings
 from knn_tpu.analysis.core import CHECKERS, load_suppressions
 from knn_tpu.analysis.lockorder import (
     InstrumentedLock,
@@ -600,15 +603,15 @@ def test_vmem_geometry_mirrors_pallas_kernel():
                         dim, bq, tile, terms, masked)
 
 
-def test_vmem_operand_widths_mirror_roofline():
-    from knn_tpu.obs import roofline
+def test_vmem_operand_widths_mirror_the_row_widths():
+    from knn_tpu.analysis import widths
 
-    assert set(vmem.DB_PARTS) == set(roofline.DB_ELEM_BYTES)
+    assert set(vmem.DB_PARTS) == set(widths.DB_ELEM_BYTES)
     for prec, (n_parts, chunk_w, elem_b) in vmem.DB_PARTS.items():
         per_dim = n_parts * chunk_w * elem_b / vmem.DIM_CHUNK
-        assert per_dim == roofline.DB_ELEM_BYTES[prec], prec
-    assert vmem.AUX_ROWS == roofline.AUX_ROWS
-    assert vmem.AUX_ROWS_DEFAULT == roofline.AUX_ROWS_DEFAULT
+        assert per_dim == widths.DB_ELEM_BYTES[prec], prec
+        assert widths.aux_rows_for(prec) == vmem.AUX_ROWS.get(
+            prec, vmem.AUX_ROWS_DEFAULT)
 
 
 def test_operand_width_tables_are_the_shared_widths_objects():
@@ -618,16 +621,157 @@ def test_operand_width_tables_are_the_shared_widths_objects():
     table, passes today's equality, and then diverges on the next new
     precision arm."""
     from knn_tpu.analysis import hbm, widths
-    from knn_tpu.obs import roofline
 
-    assert roofline.DB_ELEM_BYTES is widths.DB_ELEM_BYTES
-    assert roofline.AUX_ROWS is widths.AUX_ROWS
-    assert roofline.QUERY_ELEM_BYTES is widths.QUERY_ELEM_BYTES
     assert vmem.DB_PARTS is widths.DB_PARTS
     assert vmem.AUX_ROWS is widths.AUX_ROWS
-    assert vmem.DIM_CHUNK == widths.DIM_CHUNK == roofline.DIM_CHUNK
+    assert vmem.DIM_CHUNK == widths.DIM_CHUNK
     # ints are compared by value (an int re-export has no alias risk)
     assert hbm.AUX_BYTES_PER_ROW == widths.AUX_BYTES_PER_ROW
+
+
+# --- the row widths against the arrays the kernel builds ------------------
+def _actual_operand_nbytes(db, precision):
+    """Build the db-side operand arrays exactly as
+    ops.pallas_knn._bin_candidates does and return their real nbytes."""
+    n = db.shape[0]
+    if precision == "bf16x3":
+        th = db.astype(jnp.bfloat16)
+        tl = (db - th.astype(jnp.float32)).astype(jnp.bfloat16)
+        values = th.nbytes + tl.nbytes
+        aux = jnp.broadcast_to(
+            jnp.sum(db * db, axis=-1)[None, :], (8, n)).nbytes
+    elif precision == "bf16x3f":
+        th = db.astype(jnp.bfloat16)
+        tl = (db - th.astype(jnp.float32)).astype(jnp.bfloat16)
+        t3 = jnp.concatenate([th, tl, th], axis=1)
+        values = t3.nbytes
+        aux = jnp.broadcast_to(
+            jnp.sum(db * db, axis=-1)[None, :], (8, n)).nbytes
+    elif precision == "int8":
+        from knn_tpu.ops.quantize import quantize_rows
+
+        ti, ts = quantize_rows(db)
+        values = ti.nbytes
+        tn = jnp.sum(db * db, axis=-1)
+        aux = jnp.concatenate([
+            jnp.broadcast_to(tn[None, :], (8, n)),
+            jnp.broadcast_to(ts[None, :].astype(jnp.float32), (8, n)),
+        ], axis=0).nbytes
+    elif precision == "pq":
+        # the streamed operand is the [N, ceil(d/dsub)] uint8 code
+        # array (shape-determined — training moves no extra bytes)
+        # plus the 8-row pad-fill carrier
+        m_sub = -(-db.shape[1] // 4)
+        values = jnp.zeros((n, m_sub), jnp.uint8).nbytes
+        aux = jnp.broadcast_to(
+            jnp.zeros((n,), jnp.float32)[None, :], (8, n)).nbytes
+    else:  # highest streams the raw f32 rows
+        values = db.astype(jnp.float32).nbytes
+        aux = jnp.broadcast_to(
+            jnp.sum(db * db, axis=-1)[None, :], (8, n)).nbytes
+    return int(values), int(aux)
+
+
+@pytest.mark.parametrize("precision",
+                         ["bf16x3", "bf16x3f", "int8", "pq", "highest"])
+def test_db_byte_terms_match_actual_operand_nbytes(rng, precision):
+    """Property: the widths' per-pass db byte terms equal the nbytes of
+    the arrays the kernel really streams, across the f32/bf16/int8/pq
+    operand families."""
+    from knn_tpu.analysis import widths
+
+    n, d = 512, 128
+    db = jnp.asarray(rng.random((n, d), dtype=np.float32) * 128)
+    values_b, aux_b = _actual_operand_nbytes(db, precision)
+    assert widths.db_operand_nbytes(n, d, precision) == {
+        "db_values": values_b, "db_aux": aux_b}
+
+
+def _pallas_calls(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _pallas_calls(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("kernel,grid_order,row_block,fetches", [
+    ("tiled", "query_major", None, 1), ("tiled", "query_major", 128, 1),
+    ("tiled", "db_major", None, 4), ("streaming", "query_major", None, 1)])
+def test_the_tiled_kernels_query_block_streams_once(kernel, grid_order,
+                                                    row_block, fetches):
+    """The tiled kernel multiplies the whole padded width a step (a tile
+    too wide for VMEM is cut by rows), so under ``query_major`` its
+    query block's index moves with the query block alone: the queries
+    stream once, whatever the tiles and their row blocks; under
+    ``db_major`` once a row tile.  Read off the launch's own index maps:
+    how often the query operand's block index changes over the grid, in
+    the order the grid is walked."""
+    import itertools
+
+    import jax
+
+    from knn_tpu.ops import pallas_knn as pk
+
+    nq, n, d, block_q, tile_n = 64, 1024, 256, 16, 256  # 4 x 4 blocks
+    traced = jax.make_jaxpr(lambda q, db: pk._bin_candidates(
+        q, db, block_q=block_q, tile_n=tile_n, survivors=None,
+        precision="bf16x3", interpret=True, grid_order=grid_order,
+        kernel=kernel, row_block=row_block))(
+            jnp.zeros((nq, d), jnp.float32), jnp.zeros((n, d), jnp.float32))
+    (call,) = _pallas_calls(traced.jaxpr, [])
+    mapping = call.params["grid_mapping"]
+    assert len(mapping.grid) == (1 if kernel == "streaming" else 3)
+    if kernel == "tiled":
+        assert mapping.grid[2] == (1 if row_block is None
+                                   else tile_n // row_block)
+    index_map = mapping.block_mappings[0].index_map_jaxpr  # the queries
+    moved, last = 0, None
+    for step in itertools.product(*[range(g) for g in mapping.grid]):
+        at = tuple(int(v) for v in jax.core.eval_jaxpr(
+            index_map.jaxpr, index_map.consts,
+            *[np.int32(x) for x in step]))
+        moved, last = moved + (at != last), at
+    assert moved == fetches * (nq // block_q)
+
+
+def test_geometry_defaults_mirror_kernel_constants():
+    """The jax-free module mirrors the kernel's geometry defaults (the
+    constants: ``test_vmem_geometry_mirrors_pallas_kernel``); a drift
+    here would mis-price every default-knob launch."""
+    from knn_tpu.ops import pallas_knn as pk
+
+    n_bins, surv, out_w, bound_w = pk._geometry(pk.TILE_N)
+    assert surv == vmem.SURVIVORS_GROUPED_DEFAULT
+    # grouped default survivors=2 -> the out/bound widths the launch
+    # estimate's candidate output blocks assume
+    assert out_w == surv * pk.BIN_W and bound_w == pk.BIN_W
+    geo = vmem.launch_estimate(**vmem.HEADLINE_SHAPE)["geometry"]
+    assert (geo["out_w"], geo["bound_w"]) == (out_w, bound_w)
+
+
+def test_sub_int8_row_bytes_pinned():
+    """Pinned byte ratios at SIFT dims (docs/PERF.md precision
+    ladder): int8 streams a quarter of the f32 row, pq at
+    the default dsub=4 streams m = ceil(d/4) code bytes — m/(4d) of
+    the f32 row, 1/16 at d=128."""
+    from knn_tpu.analysis import widths
+
+    f32 = widths.db_row_bytes(128, "highest")
+    i8 = widths.db_row_bytes(128, "int8")
+    pq = widths.db_row_bytes(128, "pq", dsub=4)
+    assert (f32, i8, pq) == (512, 128, 32)
+    assert pq / f32 == widths.pq_nsub(128, 4) / (4 * 128) == 1 / 16
+    # int8's aux stacks 8 scale rows under the 8 norm rows every other
+    # arm streams
+    a = widths.db_operand_nbytes(1000, 128, "bf16x3")
+    b = widths.db_operand_nbytes(1000, 128, "int8")
+    assert 2 * a["db_aux"] == b["db_aux"]
 
 
 def test_launch_estimate_breakdown_and_monotonicity():
@@ -786,11 +930,9 @@ def test_vmem_model_refuses_only_where_it_is_calibrated():
     verdict = vmem.check_candidate(over, **shape, device_kind="TPU v5e")
     assert verdict["estimate_bytes"] > verdict["budget_bytes"]
     assert verdict["checked"] is False and verdict["fits"] is None
-    assert vmem.fits_some_kind(over, **shape)
     fitted = {"kernel": "fused", "block_q": 256}
     verdict = vmem.check_candidate(fitted, **shape, device_kind="TPU v5e")
     assert verdict["checked"] and verdict["fits"] is False
-    assert not vmem.fits_some_kind(fitted, **shape)
 
 
 def test_check_candidate_verdicts():
@@ -806,7 +948,7 @@ def test_check_candidate_verdicts():
 
 
 def test_default_knobs_fit_target_device():
-    from knn_tpu.tuning.autotune import DEFAULT_KNOBS
+    from knn_tpu.tuning import DEFAULT_KNOBS
 
     verdict = vmem.check_candidate(
         DEFAULT_KNOBS, **vmem.HEADLINE_SHAPE,
@@ -814,108 +956,44 @@ def test_default_knobs_fit_target_device():
     assert verdict["fits"] is True
 
 
-def test_knob_grid_carries_no_unfittable_candidate():
-    """The enumeration bound: every grid candidate fits at least one
-    known device kind's VMEM at the headline shape (the same invariant
-    the vmem-budget checker enforces statically)."""
-    from knn_tpu import tuning
-
-    for level in ("quick", "standard", "full"):
-        for cand in tuning.knob_grid(level):
-            knobs = {**tuning.DEFAULT_KNOBS, **cand}
-            assert vmem.fits_some_kind(knobs, **vmem.HEADLINE_SHAPE), (
-                level, cand)
+def test_the_cells_priced_are_the_eleven():
+    assert len(CELL_CONFIGS) == 11
 
 
-def test_vmem_checker_flags_seeded_over_budget_candidate():
-    """The known-bad fixture: a grid carrying a fits-nowhere candidate
-    must produce a vmem-budget finding (and would flip cli lint red)."""
-    from knn_tpu.tuning.autotune import DEFAULT_KNOBS
+@pytest.mark.parametrize("config", sorted(CELL_CONFIGS))
+def test_default_knobs_fit_the_target_device_at_every_cells_shape(config):
+    """What every cell runs (``require.tuning_source: "default"``) is
+    priced at ITS shape, a chip's share of the rows: until PR 59 only
+    the SIFT headline shape was."""
+    from knn_tpu.tuning import DEFAULT_KNOBS
 
-    bad = {"kernel": "streaming", "tile_n": 32768}
-    findings = grid_findings([bad], DEFAULT_KNOBS)
+    cfg = cell_config(config)
+    assert cfg["require"]["tuning_source"] == "default"
+    verdict = vmem.check_candidate(
+        DEFAULT_KNOBS, n=cfg["rows_n"] // cfg.get("db_shards", 1),
+        d=cfg["dim"], k=cfg["k"], device_kind=vmem.TARGET_DEVICE_KIND)
+    assert verdict["checked"] and verdict["fits"] is True, verdict
+    assert 32 * vmem.MIB < verdict["estimate_bytes"] < verdict[
+        "budget_bytes"] == 128 * vmem.MIB
+
+
+def test_vmem_checker_flags_seeded_over_budget_defaults():
+    """The known-bad fixture: a default knob set that overruns the
+    target device must produce a vmem-budget finding (and would flip
+    cli lint red); the real one produces none."""
+    from knn_tpu.tuning import DEFAULT_KNOBS
+
+    bad = {**DEFAULT_KNOBS, "kernel": "streaming", "tile_n": 32768}
+    findings = default_findings(bad)
     assert findings and findings[0].checker == "vmem-budget"
-    assert "over EVERY known device kind" in findings[0].message
-    # the clean grid produces none
-    assert grid_findings([{}], DEFAULT_KNOBS) == []
-
-
-def test_vmem_checker_red_when_grid_regresses(tmp_path, monkeypatch):
-    """Seeded regression, checker level: an over-VMEM candidate smuggled
-    into knob_grid flips the vmem-budget checker (hence cli lint)
-    nonzero."""
-    import importlib
-
-    at = importlib.import_module("knn_tpu.tuning.autotune")
-
-    real = at.knob_grid
-
-    def rigged(level="standard"):
-        out = real(level)
-        out.append({**at.DEFAULT_KNOBS, "kernel": "streaming",
-                    "tile_n": 32768})
-        return out
-
-    monkeypatch.setattr(at, "knob_grid", rigged)
-    rep = analysis.run(REPO, names=["vmem-budget"])
-    assert not rep.ok
-    assert any(f.checker == "vmem-budget" for f in rep.findings)
+    assert findings[0].symbol == "DEFAULT_KNOBS"
+    assert "over TPU v5e's" in findings[0].message
+    assert default_findings(DEFAULT_KNOBS) == []
 
 
 def test_vmem_checker_green_on_repo():
     rep = analysis.run(REPO, names=["vmem-budget"])
     assert rep.ok, [f.message for f in rep.findings]
-
-
-# --- the autotuner's runtime VMEM gate ----------------------------------
-@pytest.fixture
-def tune_data():
-    rng = np.random.default_rng(7)
-    db = (rng.random((700, 16)) * 64).astype(np.float32)
-    q = (rng.random((8, 16)) * 64).astype(np.float32)
-    return db, q
-
-
-def test_autotune_refuses_over_budget_candidate_before_timing(
-        tune_data, tmp_path):
-    """An over-VMEM candidate is refused with provenance BEFORE the
-    bitwise gate or any timing — it can never win, and the refusal is
-    recorded like roofline pruning."""
-    from knn_tpu import tuning
-
-    db, q = tune_data
-    tuning.reset_counters()
-    entry = tuning.autotune(
-        db, q, 5, margin=4, runs=1,
-        cache_path=str(tmp_path / "t.json"),
-        grid=[{}, {"kernel": "streaming", "block_q": 4096}],
-        device_kind="TPU v2")  # 16 MiB budget: bq4096 cannot fit
-    label = "block_q=4096,kernel=streaming"
-    assert entry["timings_ms"][label] is None
-    assert entry["errors"][label].startswith("vmem-refused:")
-    assert entry["winner"] == "defaults"
-    assert entry["vmem"]["device_kind"] == "TPU v2"
-    assert entry["vmem"]["candidates_refused"] == 1
-    assert label in entry["vmem"]["refused"]
-    refused = entry["vmem"]["refused"][label]
-    assert refused["estimate_bytes"] > refused["budget_bytes"]
-    counters = tuning.counters()
-    assert counters["candidates_vmem_refused"] == 1
-    assert counters["candidates_timed"] == 1  # only the defaults
-
-
-def test_autotune_vmem_gate_disarms_off_tpu(tune_data, tmp_path):
-    """cpu/interpret backends have no VMEM: no refusals, no vmem block
-    — the pre-gate entry shape is unchanged."""
-    from knn_tpu import tuning
-
-    db, q = tune_data
-    tuning.reset_counters()
-    entry = tuning.autotune(
-        db, q, 5, margin=4, runs=1,
-        cache_path=str(tmp_path / "t.json"), grid=[{}])
-    assert "vmem" not in entry
-    assert tuning.counters()["candidates_vmem_refused"] == 0
 
 
 # --- lock-order harness (runtime deadlock detection) --------------------

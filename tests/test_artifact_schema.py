@@ -35,13 +35,6 @@ def run_on(root, checker="artifact-lockstep"):
 
 
 # --- reference blocks ----------------------------------------------------
-def good_roofline(qps=50.0):
-    from knn_tpu.obs import roofline
-
-    return roofline.attribute(
-        roofline.pallas_cost_model(n=1000, d=16, k=5, nq=8), qps)
-
-
 GOOD_KNEE = {
     "version": 1, "slo_p99_ms": 50.0,
     "rate_steps": [{"rate_qps": 10.0, "offered": 5, "ok": 5,
@@ -75,20 +68,6 @@ def test_legacy_style_reproduces_hand_validator_strings_exactly():
     """The migrated validators' exact strings, pinned byte-for-byte —
     the shims' refusal tests elsewhere assert substrings; this is the
     stronger contract the tentpole claims."""
-    assert A.validate("roofline", "nope", style="legacy") == \
-        ["roofline block is str, not dict"]
-    assert A.validate("roofline", {"bound_class": "gpu_bound"},
-                      style="legacy")[0] == "missing/non-int model_version"
-    from knn_tpu.obs.roofline import BOUND_CLASSES
-
-    assert (f"bound_class 'gpu_bound' not in {BOUND_CLASSES}"
-            in A.validate("roofline", {"bound_class": "gpu_bound"},
-                          style="legacy"))
-    assert A.validate("calibration", None, style="legacy") == \
-        ["calibration is NoneType, not dict"]
-    assert A.validate("calibration", {"applied": "yes"},
-                      style="legacy") == \
-        ["calibration.applied 'yes' is not a bool"]
     assert A.validate("loadgen_knee", {"version": 99}, style="legacy") \
         == ["version must be 1, got 99",
             "slo_p99_ms must be a positive number, got None",
@@ -108,17 +87,9 @@ def test_shims_are_the_engine():
     output, on good and bad blocks alike."""
     from knn_tpu.index.artifact import validate_mutation_block
     from knn_tpu.loadgen.knee import validate_knee_block
-    from knn_tpu.obs import calibrate, roofline
     from knn_tpu.parallel.crossover import validate_multihost_block
 
     cases = [
-        ("roofline", roofline.validate_block,
-         [good_roofline(), {}, dict(good_roofline(), terms="x")]),
-        ("calibration", calibrate.validate_calibration,
-         [{"applied": False}, {"applied": True},
-          {"applied": True, "factors": {"hbm": 1, "mxu": 1,
-                                        "vpu_select": 1},
-           "source": "host_phase", "model_residual_pct": 2.0}]),
         ("loadgen_knee", validate_knee_block,
          [GOOD_KNEE, {"error": "boom"},
           dict(GOOD_KNEE, rate_steps=[{"rate_qps": 1.0}])]),
@@ -139,21 +110,20 @@ def test_normalized_style_is_one_uniform_phrasing():
     fold into (the compat shims keep the historical strings)."""
     errs = A.validate("mutation", {}, style="normalized")
     assert errs[0] == "missing field: mutation_version"
-    errs = A.validate("calibration",
-                      {"applied": True, "factors": "x",
-                       "source": "vibes", "model_residual_pct": "m"},
+    bad = dict(GOOD_MULTIHOST, hosts=0,
+               merge={"intra": {"strategy": "vibes", "source": "measured"},
+                      "dcn": GOOD_MULTIHOST["merge"]["dcn"]})
+    assert A.validate("multihost", bad, style="normalized")[0] == \
+        "field hosts must be a positive int, got 0"
+    errs = A.validate("multihost", dict(bad, hosts=2),
                       style="normalized")
-    assert any(e.startswith("field factors must be a dict")
-               for e in errs)
-    assert any(e.startswith("field source must be one of")
-               for e in errs)
+    assert any(e.startswith("field merge.intra.strategy must be one of")
+               for e in errs), errs
     # the legacy strings for the same block diverge in style — that is
     # exactly what the shims preserve
-    legacy = A.validate("calibration",
-                        {"applied": True, "factors": "x",
-                         "source": "vibes", "model_residual_pct": "m"},
-                        style="legacy")
-    assert "applied calibration missing factors dict" in legacy
+    assert A.validate("multihost", bad, style="legacy") == [
+        "hosts 0 is not a positive int",
+        "merge.intra.strategy 'vibes' not in ('allgather', 'ring')"]
 
 
 def test_version_tokens_resolve_and_are_owned_once():
@@ -163,8 +133,7 @@ def test_version_tokens_resolve_and_are_owned_once():
             assert s.version_field not in owners, s.name
             owners[s.version_field] = s.name
             assert isinstance(A.version_value(s.name), int)
-    assert owners == {"model_version": "roofline",
-                      "version": "loadgen_knee",
+    assert owners == {"version": "loadgen_knee",
                       "mutation_version": "mutation",
                       "ivf_version": "ivf",
                       "pq_version": "pq",
@@ -204,21 +173,6 @@ def test_step_fields_and_mutation_required_derived():
         "admitted_p99_ms", "compactions", "epoch", "reads", "writes",
         "slo_breach_transitions")
     assert MUTATION_REQUIRED == A.required_keys("mutation")
-
-
-def test_tuning_cache_entry_schema_accepts_a_real_entry_shape():
-    entry = {
-        "knobs": {"kernel": "streaming"}, "winner": "defaults",
-        "winner_ms": 1.2, "timings_ms": {"defaults": 1.2},
-        "errors": {}, "roofline_per_candidate": {},
-        "gate": "bitwise-vs-reference", "runs": 2, "n_queries": 8,
-        "margin": 4, "device_kind": "cpu", "backend": "cpu",
-        "jax_version": "0.9.0", "measured_at": "2026-08-04T00:00:00Z",
-        "roofline": good_roofline(), "roofline_pct": 0.5,
-        "bound_class": "hbm_bound",
-    }
-    assert A.validate("tuning_cache_entry", entry) == []
-    assert A.validate("tuning_cache_entry", dict(entry, runs=0))
 
 
 def test_required_nullable_field_must_be_present(monkeypatch):
@@ -301,7 +255,7 @@ def test_checker_flags_missing_docs_anchor(tmp_path):
     write_tree(tmp_path, {"docs/PERF.md": "# PERF\n\nno headings\n"})
     rep = run_on(tmp_path)
     assert not rep.ok
-    assert any("docs anchor" in f.message and f.symbol == "roofline"
+    assert any("docs anchor" in f.message and f.symbol == "join"
                for f in rep.findings)
 
 

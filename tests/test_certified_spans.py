@@ -22,7 +22,6 @@ import pytest
 from knn_tpu import obs
 from knn_tpu.obs import names as mn
 from knn_tpu.obs import trace as obs_trace
-from knn_tpu.obs.profiler import device_trace
 from knn_tpu.ops import pallas_knn
 from knn_tpu.parallel import sharded as sh
 from knn_tpu.parallel.mesh import make_mesh
@@ -242,7 +241,8 @@ def test_importing_obs_and_opening_a_span_imports_no_jax():
 # --- the profiler's clock -----------------------------------------------
 def test_a_live_profile_holds_the_stage_annotations_nested(placed, corpus,
                                                            tmp_path):
-    with device_trace("spans", base_dir=str(tmp_path)) as path:
+    path = str(tmp_path / "spans")
+    with jax.profiler.trace(path):
         with jax.profiler.TraceAnnotation("test.outer"):
             placed.search_certified(corpus[1], selector="pallas",
                                     batch_size=32)

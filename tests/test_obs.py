@@ -458,19 +458,6 @@ def test_results_bitwise_identical_obs_on_vs_off(placed, tmp_path,
     assert not (tmp_path / "pm").exists()
 
 
-# --- tuning counters -----------------------------------------------------
-def test_tuning_counters_mirrored_to_registry(tmp_path, monkeypatch):
-    from knn_tpu import tuning
-
-    monkeypatch.setenv("KNN_TPU_TUNE_CACHE",
-                       str(tmp_path / "autotune.json"))
-    before = obs.counter(mn.TUNING_RESOLVES).get()
-    miss_before = obs.counter(mn.TUNING_CACHE_MISSES).get()
-    tuning.resolve(1000, 16, 5)
-    assert obs.counter(mn.TUNING_RESOLVES).get() == before + 1
-    assert obs.counter(mn.TUNING_CACHE_MISSES).get() == miss_before + 1
-
-
 # --- compile hook --------------------------------------------------------
 def test_jax_compile_events_counted():
     if not obs.install_compile_hook():

@@ -312,27 +312,6 @@ def test_trace_replay_sustained_and_bounded(served):
         assert idx.shape == (int(s), K)
 
 
-def test_warmup_prebuilds_int8_placement_when_winner_says_so(
-        served, tmp_path, monkeypatch):
-    # a persisted autotuner winner with precision="int8" for this
-    # placement's shape makes warmup() pre-quantize + place the db, so
-    # the first live certified query never pays the one-time build
-    from knn_tpu import tuning
-
-    prog, _, q = served
-    cache = str(tmp_path / "warm_tune.json")
-    monkeypatch.setenv(tuning.CACHE_ENV, cache)
-    key = tuning.cache_key(
-        "cpu", prog.n_train, prog.dim_in, prog.k, prog.metric, None)
-    tuning.TuneCache(cache).put(
-        key, {"knobs": {**tuning.DEFAULT_KNOBS, "precision": "int8"}})
-    engine = ServingEngine(prog, buckets=BUCKETS)
-    assert prog._int8_cache is None
-    counts = engine.warmup()
-    assert counts.get("int8_placement") == 1
-    assert prog._int8_cache is not None
-
-
 # -- metric matrix through the serving surface (join-PR satellite) --------
 @pytest.mark.parametrize("metric", ["l1", "cosine", "dot"])
 def test_metric_matrix_bucketed_matches_direct_search(rng, metric):
