@@ -16,10 +16,15 @@ covers it at SIFT1M scale; the benchmark's float64 oracle
 
 The float64 temporaries are made in blocks of at most ``_BLOCK_ELEMS``
 elements (:func:`_block_rows`), so that each is served from the heap and
-none grows with the batch; :func:`rank_correct_runs` also spreads its
-blocks over a small pool of host threads, made at the first call that
-has more than one block (numpy releases the GIL in the gather, the cast,
-the subtraction and the einsum).
+none grows with the batch.  What is worth sharing goes to one small pool
+of host threads (:func:`pool_map`), made at the first call that has more
+than one part.  :func:`rank_correct_runs` with many members cuts its
+QUERIES into contiguous ranges, even in members, and a range runs every
+phase of the correction (mask, gather, re-score, sort, scatter) on one
+thread; with few members the sorts are too short to share, and it stays
+on the calling thread but for the blocks of its re-score, which the pool
+shares as it shares :func:`exact_pair_scores`' blocks of pairs.  numpy
+releases the GIL in the gather, the cast, the arithmetic and the sorts.
 """
 
 from __future__ import annotations
@@ -39,16 +44,41 @@ from knn_tpu import obs
 #: ~40% slower (measured chunk sweep, 2026-07)
 _BLOCK_ELEMS = 1 << 20
 
-#: host threads that share one call's blocks in rank_correct_runs
+#: host threads that share one call's parts (a rank correction's query
+#: ranges or blocks, a pair scorer's blocks, a query map's rows).  The
+#: chip's host has 13 cores and shares them with the runtime's transfer
+#: threads: read at 4 / 6 / 8 threads (PR 60, one traced and one 30 s
+#: run each), ``knnlm1m.sweep_k1024`` rank-corrects a batch in 439 / 426
+#: / 404 ms and answers 6,981 / 7,063 / 7,065 q/s (the device paces it
+#: from 4 on), ``gist1m.sweep`` answers 20,485 / 20,348 / 20,254 and
+#: ``imagenet-knn768.sweep_vote`` 27,858 / 27,675 / 27,618: nothing
+#: past 4, so 4 stays
 _POOL_THREADS = min(4, os.cpu_count() or 1)
+
+#: tight pairs below which a range of a rank correction is not worth a
+#: thread of its own.  On the chip's host (PR 60) ranges of about 1,000
+#: members made the correction's sorts and scatters twice as long as
+#: the single thread's at ``gist1m.sweep``, and longer with every
+#: thread; ranges of 16,000 a third as long at ``knnlm1m.sweep_k1024``
+#: (a pair is one or two members).  Presumably: on a small range those
+#: are numpy calls of a few microseconds, and threads that hand the
+#: interpreter's lock back and forth between such calls lose more than
+#: they share
+_RANGE_PAIRS = 4096
 
 _pool: Optional[ThreadPoolExecutor] = None
 _pool_lock = threading.Lock()
+#: ``.member`` is True on the pool's own threads
+_in_pool = threading.local()
 
 
 def _block_rows(row_elems: int) -> int:
     """How many rows of ``row_elems`` elements make one block."""
     return max(1, _BLOCK_ELEMS // max(1, row_elems))
+
+
+def _join_pool() -> None:
+    _in_pool.member = True
 
 
 def _shared_pool() -> ThreadPoolExecutor:
@@ -59,15 +89,18 @@ def _shared_pool() -> ThreadPoolExecutor:
         if _pool is None:
             _pool = ThreadPoolExecutor(
                 max_workers=_POOL_THREADS,
-                thread_name_prefix="knn-rank-correct")
+                thread_name_prefix="knn-rank-correct",
+                initializer=_join_pool)
         return _pool
 
 
 def pool_map(fn, parts) -> list:
     """``[fn(part) for part in parts]``, the parts shared among the
     re-score pool's threads where there are several; one part runs on
-    the caller's thread."""
-    if len(parts) > 1:
+    the caller's thread, and so does every part of a map made ON a pool
+    thread (a part of an outer map: a pool thread never waits for the
+    pool)."""
+    if len(parts) > 1 and not getattr(_in_pool, "member", False):
         # list(): reading every result re-raises a worker's exception
         return list(_shared_pool().map(fn, parts))
     return [fn(part) for part in parts]
@@ -237,10 +270,90 @@ PHASE_SCORE = "certified.rank_correct.score"
 PHASE_ORDER = "certified.rank_correct.order"
 
 
-def _tell(sp, secs: dict) -> None:
-    """Hand a stage's span the seconds of its phases."""
-    for key, value in secs.items():
-        sp.set(key, value)
+def _member_ranges(pairs: np.ndarray) -> list:
+    """The queries of one rank correction cut into contiguous ``(lo,
+    hi)`` ranges for the pool, EVEN IN MEMBERS and not in queries: tie
+    runs crowd in some queries.  ``pairs`` [Q] counts each query's tight
+    pairs, what the mask shows of its members before it is expanded (a
+    run of p pairs has p + 1 members), and a range ends at the query
+    where their running sum passes its share.  As many ranges as the
+    pool has threads, fewer where a range would hold under
+    ``_RANGE_PAIRS`` pairs, so one where the call's members are too few
+    for ranges to pay."""
+    n_q = pairs.size
+    run = np.cumsum(pairs)
+    total = int(run[-1]) if n_q else 0
+    parts = max(1, min(_POOL_THREADS, total // _RANGE_PAIRS))
+    if parts == 1:
+        return [(0, n_q)]
+    shares = total * np.arange(1, parts) / parts
+    cuts = np.unique(np.concatenate(
+        ([0], np.searchsorted(run, shares) + 1, [n_q])))
+    return list(zip(cuts[:-1].tolist(), cuts[1:].tolist()))
+
+
+def _correct_range(gi, tight, k, queries_np, db_np, d32k, metric, norms,
+                   gw, d_out) -> Tuple[dict, int]:
+    """One range of :func:`rank_correct_runs`' queries, every phase of
+    it on the calling thread.  The arguments are the call's, cut to the
+    range (views); ``gw`` [q, W] int64 and ``d_out`` [q, k] float64 or
+    None are the range's rows of the call's outputs, filled here: the
+    copies of the windowed indices and of ``d32k``, then the members of
+    each tie run re-scored a block at a time (:func:`_score_members`;
+    the blocks go through :func:`pool_map`, which shares them where the
+    range is the call's only one, on the calling thread, and runs them
+    in turn where the range is itself a part on a pool thread) and put
+    back in float64 order.  Opens no span and asks for none: returns
+    ``(seconds by phase, members)``, the phases timed where they run
+    and annotated ``knn.<phase>`` on this thread."""
+    secs = {}
+    with obs.trace.phase(secs, "order_s", PHASE_ORDER):
+        inv = np.zeros((tight.shape[0], tight.shape[1] + 1), dtype=bool)
+        inv[:, :-1] |= tight
+        inv[:, 1:] |= tight
+        rows, cols = np.nonzero(inv)
+    with obs.trace.phase(secs, "buffers_s", PHASE_BUFFERS):
+        if d_out is not None:
+            d_out[...] = d32k
+        gw[...] = gi[:, : gw.shape[1]]
+    if rows.size == 0:
+        return secs, 0
+    with obs.trace.phase(secs, "order_s", PHASE_ORDER):
+        cand = gw[rows, cols]
+        safe = np.clip(cand, 0, db_np.shape[0] - 1)
+        d64 = np.empty(rows.size)
+    block = _block_rows(db_np.shape[1])
+
+    def score(lo: int) -> Tuple[float, float]:
+        t0 = time.perf_counter()
+        gathered = _score_members(
+            db_np, queries_np, safe[lo : lo + block],
+            rows[lo : lo + block], metric, d64[lo : lo + block], norms)
+        return gathered - t0, time.perf_counter() - gathered
+
+    with obs.trace.phase(secs, "score_s", PHASE_SCORE):
+        timed = pool_map(score, range(0, rows.size, block))
+    secs["gather_s"] = sum(g for g, _ in timed)
+    secs["arith_s"] = sum(a for _, a in timed)
+    with obs.trace.phase(secs, "order_s", PHASE_ORDER):
+        d64 = np.where(cand < db_np.shape[0], d64, np.inf)
+        # maximal runs of consecutive involved positions; (rows, cols)
+        # comes position-sorted from nonzero, so each run is one
+        # contiguous block
+        new_run = np.ones(rows.size, dtype=bool)
+        new_run[1:] = (rows[1:] != rows[:-1]) | (cols[1:] != cols[:-1] + 1)
+        run_id = np.cumsum(new_run) - 1
+        # lexicographic sort within each run; runs are contiguous
+        # ascending in both the original flat order and the
+        # (run_id-primary) sorted order, so flat positions realign
+        # block-for-block
+        order = np.lexsort((cand, d64, run_id))
+        gw[rows, cols] = cand[order]
+        if d_out is not None:
+            in_k = cols < k
+            d_sorted = d64[order]
+            d_out[rows[in_k], cols[in_k]] = d_sorted[in_k]
+    return secs, int(rows.size)
 
 
 def rank_correct_runs(
@@ -285,86 +398,76 @@ def rank_correct_runs(
     patched in and the array is returned — None skips distance output
     entirely (callers that only need indices save the transfer).
 
-    The float64 distances are computed a block of members at a time
-    (:func:`_block_rows` of the row width), the blocks shared among the
-    pool's threads when there is more than one; each writes its own slice
-    of one array and each member's sum is its own, so the answer does
-    not depend on the blocking or on thread timing.  The innermost span
-    open on the calling thread (the caller's ``certified.rank_correct``)
-    is told ``members`` and ``blocks``, and where this call's seconds
-    went, in three phases that are also ``knn.<phase>`` profiler
-    annotations on this thread: ``buffers_s`` (the copies of the
-    distances and of the windowed indices), ``score_s`` (the wall time
-    of the re-score; inside it, summed over the blocks whichever thread
-    ran them, ``gather_s``, the fancy-index gather of the members' rows
-    with its widening, and ``arith_s``, the subtraction or product and
-    the sum) and ``order_s`` (everything else: mask, ``nonzero``, run
-    ids, ``lexsort``, scatter, ``unique``).
+    **What runs where.**  The calling thread counts each query's tight
+    pairs, cuts the queries into contiguous ranges even in members
+    (:func:`_member_ranges`: as many as the pool has threads, fewer
+    where a range would hold under ``_RANGE_PAIRS`` pairs) and allocates
+    the outputs; every range then runs ALL of its phases on one thread
+    of the pool (:func:`_correct_range`, through :func:`pool_map`): the
+    mask's expansion and ``nonzero``, the copies of its rows of ``d32k``
+    and of the windowed indices, the float64 re-score of its members a
+    block of at most :func:`_block_rows` at a time, the run ids,
+    ``lexsort`` and the scatter back.  A call with too few members for
+    ranges is ONE range on the calling thread, and the blocks of its
+    re-score are what the pool shares (a call of one block shares
+    nothing).  Ranges write disjoint rows of the shared outputs, a tie
+    run never crosses a query and each member's sum is its own, so the
+    answer does not depend on the cut, the blocking or thread timing.
+
+    The innermost span open on the calling thread (the caller's
+    ``certified.rank_correct``, read once, before the map) is told
+    ``members``, ``parts`` (the ranges of this call) and ``threads``
+    (the pool's width), and where this call's seconds went: three
+    phases, each a ``knn.<phase>`` profiler annotation on the thread
+    that runs it.  ``buffers_s`` (the outputs and the copies into
+    them), ``score_s`` (the re-score) and ``order_s`` (everything else:
+    mask, ``nonzero``, run ids, ``lexsort``, scatter) are shares of
+    WALL time: what the calling thread did itself, plus the map's wall
+    time split in proportion to the ranges' summed seconds of each
+    phase (a lone range's phases are wall time as they stand).
+    ``gather_s`` (the fancy-index gather of the members' rows
+    with its widening) and ``arith_s`` (the subtraction or product and
+    the sum) are sums over the blocks whichever thread ran them.
 
     Returns (d_out or None, i_out [Q, k] int64, corrected_row_count).
     """
-    n_q, m1 = gi.shape
+    n_q = gi.shape[0]
     w = tight.shape[1] + 1
     if w < k:
         raise ValueError(f"tie mask window {w} < k={k}")
-    secs = {"buffers_s": 0.0, "score_s": 0.0, "order_s": 0.0,
-            "gather_s": 0.0, "arith_s": 0.0}
     sp = obs.current_span()
-    with obs.trace.phase(secs, "order_s", PHASE_ORDER):
-        inv = np.zeros((n_q, w), dtype=bool)
-        inv[:, :-1] |= tight
-        inv[:, 1:] |= tight
-        rows, cols = np.nonzero(inv)
-        block = _block_rows(db_np.shape[1])
-        starts = range(0, rows.size, block)
-    sp.set("members", int(rows.size))
-    sp.set("blocks", len(starts))
-    with obs.trace.phase(secs, "buffers_s", PHASE_BUFFERS):
-        d_out = d32k.copy() if d32k is not None else None
-        if rows.size == 0:
-            i_out = gi[:, :k].astype(np.int64)
-        else:
-            gw = gi[:, :w].astype(np.int64).copy()
-    if rows.size == 0:
-        _tell(sp, secs)
-        return d_out, i_out, 0
-    with obs.trace.phase(secs, "order_s", PHASE_ORDER):
-        cand = gw[rows, cols]
-        safe = np.clip(cand, 0, db_np.shape[0] - 1)
-        d64 = np.empty(rows.size)
+    own = {}  # the calling thread's seconds
+    with obs.trace.phase(own, "order_s", PHASE_ORDER):
+        pairs = np.count_nonzero(tight, axis=1)
+        ranges = _member_ranges(pairs)
+    with obs.trace.phase(own, "buffers_s", PHASE_BUFFERS):
+        d_out = None if d32k is None else np.empty_like(d32k)
+        gw = np.empty((n_q, w), dtype=np.int64)
 
-    def score(lo: int) -> Tuple[float, float]:
-        t0 = time.perf_counter()
-        gathered = _score_members(
-            db_np, queries_np, safe[lo : lo + block],
-            rows[lo : lo + block], metric, d64[lo : lo + block], norms)
-        return gathered - t0, time.perf_counter() - gathered
+    def correct(cut: Tuple[int, int]) -> Tuple[dict, int]:
+        sel = slice(*cut)
+        return _correct_range(
+            gi[sel], tight[sel], k, queries_np[sel], db_np,
+            None if d32k is None else d32k[sel], metric,
+            norms_rows(norms, sel), gw[sel],
+            None if d_out is None else d_out[sel])
 
-    with obs.trace.phase(secs, "score_s", PHASE_SCORE):
-        parts = pool_map(score, starts)
-    secs["gather_s"] = sum(g for g, _ in parts)
-    secs["arith_s"] = sum(a for _, a in parts)
-    with obs.trace.phase(secs, "order_s", PHASE_ORDER):
-        d64 = np.where(cand < db_np.shape[0], d64, np.inf)
-        # maximal runs of consecutive involved positions; (rows, cols)
-        # comes position-sorted from nonzero, so each run is one
-        # contiguous block
-        new_run = np.ones(rows.size, dtype=bool)
-        new_run[1:] = (rows[1:] != rows[:-1]) | (cols[1:] != cols[:-1] + 1)
-        run_id = np.cumsum(new_run) - 1
-        # lexicographic sort within each run; runs are contiguous
-        # ascending in both the original flat order and the
-        # (run_id-primary) sorted order, so flat positions realign
-        # block-for-block
-        order = np.lexsort((cand, d64, run_id))
-        gw[rows, cols] = cand[order]
-        if d_out is not None:
-            in_k = cols < k
-            d_sorted = d64[order]
-            d_out[rows[in_k], cols[in_k]] = d_sorted[in_k]
-        n_rows = int(len(np.unique(rows)))
-    _tell(sp, secs)
-    return d_out, gw[:, :k], n_rows
+    t0 = time.perf_counter()
+    told = pool_map(correct, ranges)
+    wall = time.perf_counter() - t0
+    summed = {key: sum(secs.get(key, 0.0) for secs, _ in told)
+              for key in ("buffers_s", "score_s", "order_s",
+                          "gather_s", "arith_s")}
+    inside = summed["buffers_s"] + summed["score_s"] + summed["order_s"]
+    for key in ("buffers_s", "score_s", "order_s"):
+        sp.set(key, own.get(key, 0.0)
+               + (wall * summed[key] / inside if inside else 0.0))
+    sp.set("gather_s", summed["gather_s"])
+    sp.set("arith_s", summed["arith_s"])
+    sp.set("members", sum(members for _, members in told))
+    sp.set("parts", len(ranges))
+    sp.set("threads", _POOL_THREADS)
+    return d_out, gw[:, :k], int(np.count_nonzero(pairs))
 
 
 def refine_exact(

@@ -130,7 +130,7 @@ def stage_table(events: Iterable[dict], skip_calls: int = 0) -> dict:
             elif e.get("parent") == CALL:
                 children += e["dur_s"]
             for key in ("h2d_bytes", "d2h_bytes", "queries_corrected",
-                        "members", "blocks", "fallback_queries",
+                        "members", "parts", "threads", "fallback_queries",
                         "host_exact_queries"):
                 if key in e and e["span"] != CALL:
                     attrs[key] += e[key]

@@ -22,7 +22,7 @@ import pytest
 from knn_tpu import obs
 from knn_tpu.obs import names as mn
 from knn_tpu.obs import trace as obs_trace
-from knn_tpu.ops import pallas_knn
+from knn_tpu.ops import pallas_knn, refine
 from knn_tpu.parallel import sharded as sh
 from knn_tpu.parallel.mesh import make_mesh
 from knn_tpu.parallel.sharded import ShardedKNN
@@ -150,10 +150,11 @@ def test_a_certified_call_emits_exactly_its_stage_spans(placed, corpus, kw,
     (corrected,) = [e for e in spans
                     if e["span"] == "certified.rank_correct"]
     assert corrected["queries_corrected"] == stats["rank_corrected_queries"]
-    # a tight pair involves two positions; at 32 columns one block
-    # holds 32,768 members, so a batch here is one block or none
+    # a tight pair involves two positions; a batch here has far too few
+    # of them to be cut into ranges
     assert corrected["members"] >= 2 * corrected["queries_corrected"]
-    assert 0 <= corrected["blocks"] <= batches
+    assert corrected["parts"] == batches
+    assert corrected["threads"] == batches * refine._POOL_THREADS
     assert by["certified.repair"]["fallback_queries"] == 0
     assert by["certified.repair"]["host_exact_queries"] == 0
     # the same spans feed the histogram an operator scrapes
@@ -359,7 +360,8 @@ def test_the_stage_report_reads_the_jsonl_log(placed, corpus, report,
     assert table["per_batch"]["h2d_bytes"] == 32 * 32 * 4
     assert table["per_batch"]["members"] >= 2 * table["per_batch"][
         "queries_corrected"]
-    assert 0 <= table["per_batch"]["blocks"] <= 1
+    assert table["per_batch"]["parts"] == 1
+    assert table["per_batch"]["threads"] == refine._POOL_THREADS
     # a narrow shard: the final select ran over the kernel's candidates
     assert (table["select"]["select_width"]
             == table["select"]["select_merged_width"] > 0)

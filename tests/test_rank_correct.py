@@ -8,11 +8,14 @@ the same candidates."""
 import os
 import sys
 import threading
+import time
 import tracemalloc
 
 import numpy as np
 import pytest
 
+from knn_tpu import obs
+from knn_tpu.ops import refine
 from knn_tpu.ops.refine import _block_rows, rank_correct_runs, refine_exact
 
 SLACK = 2.0 ** -18
@@ -100,6 +103,27 @@ def test_rank_correct_runs_clean_rows_untouched(rng):
     ref_d, ref_i = refine_exact(db, queries, gi, 4)
     ok = ~unresolved
     np.testing.assert_array_equal(i[ok], ref_i[ok])
+
+
+@pytest.fixture
+def pool_of(monkeypatch):
+    """``pool_of(width, range_pairs=None)``: the re-score pool answered
+    from outside as one of ``width`` threads (made anew, shut down when
+    the test ends), and the worth-a-thread rule as ``range_pairs`` tight
+    pairs where a test's shapes are too small to cut by the rule as it
+    is."""
+    made = []
+
+    def set_to(width, range_pairs=None):
+        monkeypatch.setattr(refine, "_POOL_THREADS", width)
+        monkeypatch.setattr(refine, "_pool", None)
+        if range_pairs is not None:
+            monkeypatch.setattr(refine, "_RANGE_PAIRS", range_pairs)
+        made.append(None)
+
+    yield set_to
+    if made and refine._pool is not None:
+        refine._pool.shutdown(wait=False, cancel_futures=True)
 
 
 # --- the blocked, pooled re-score against the whole-array formula -----------
@@ -239,9 +263,14 @@ def test_rescore_temporaries_do_not_grow_with_the_batch(rng, blocks):
     assert peak < 64 << 20
 
 
-def test_callers_on_other_threads_share_the_pool(rng):
+@pytest.mark.parametrize("range_pairs", [1 << 30, 64],
+                         ids=["one_range", "cut_into_ranges"])
+def test_callers_on_other_threads_share_the_pool(rng, pool_of, range_pairs):
+    pool_of(4, range_pairs)
     gi, tight, queries, db, d32k = _tie_batch(
         3 * _block_rows(128) + 17, 128, rng)
+    assert len(refine._member_ranges(tight.sum(1))) == (
+        1 if range_pairs > 64 else 4)
     want = _whole_array(gi, tight, K_TIE, queries, db, d32k)
     callers = (os.cpu_count() or 1) + 2
     got = [None] * callers
@@ -265,3 +294,167 @@ def test_callers_on_other_threads_share_the_pool(rng):
         np.testing.assert_array_equal(d, want[0])
         np.testing.assert_array_equal(i, want[1])
         assert n_c == want[2]
+
+
+# --- the cut by query ranges: every phase on the pool's threads -------------
+N_CUT, W_CUT, K_CUT, D_CUT = 60, 24, 20, 32
+
+
+def _mask(kind, rng):
+    """A tie mask [N_CUT, W_CUT - 1] of one of the shapes the cut has to
+    get right."""
+    tight = np.zeros((N_CUT, W_CUT - 1), dtype=bool)
+    if kind == "crowded":  # most members in five queries, next to each
+        # other; a pair or none elsewhere
+        tight[20:25] = rng.random((5, W_CUT - 1)) < 0.8
+        for r in rng.choice(N_CUT, 12, replace=False):
+            tight[r, int(rng.integers(0, W_CUT - 1))] = True
+    elif kind == "one_pair":
+        tight[37, 5] = True
+    elif kind == "window_edge":  # runs that end at the window's last
+        # column, past k
+        tight[::3, -4:] = True
+        tight[1::3, K_CUT - 2 : K_CUT + 1] = True
+    else:
+        assert kind == "none"
+    return tight
+
+
+@pytest.mark.parametrize("kind", ["crowded", "none", "one_pair",
+                                  "window_edge"])
+@pytest.mark.parametrize("metric", ["l2", "dot", "cosine"])
+@pytest.mark.parametrize("width", [1, 2, 4, 8])
+def test_the_cut_by_query_ranges_answers_as_one_part_to_the_bit(
+        rng, pool_of, width, metric, kind):
+    tight = _mask(kind, rng)
+    db = rng.normal(size=(500, D_CUT)).astype(np.float32)
+    db[250:300] = db[:50]  # exactly tied distances: the index decides
+    queries = rng.normal(size=(N_CUT, D_CUT)).astype(np.float32)
+    gi = rng.integers(0, 300, size=(N_CUT, W_CUT + 3)).astype(np.int32)
+    gi[rng.random(gi.shape) < 0.03] = 507  # the kernel's sentinel
+    d32k = rng.random((N_CUT, K_CUT))
+    norms = ((refine.row_norms_f64(queries), refine.row_norms_f64(db))
+             if metric == "cosine" else None)
+    args = (gi, tight, K_CUT, queries, db)
+    pool_of(1)
+    want = rank_correct_runs(*args, d32k=d32k, metric=metric, norms=norms)
+    if metric == "l2":  # and the single part is the formula it always was
+        whole = _whole_array(*args, d32k)
+        np.testing.assert_array_equal(want[0], whole[0])
+        np.testing.assert_array_equal(want[1], whole[1])
+        assert want[2] == whole[2]
+    pool_of(width, range_pairs=1)  # a pair is worth a thread
+    pairs = tight.sum(1)
+    ranges = refine._member_ranges(pairs)
+    if kind in ("none", "one_pair"):
+        assert ranges == [(0, N_CUT)]
+    elif kind == "crowded":  # one query holds more than a range's share
+        assert min(width, 2) <= len(ranges) <= width
+    else:
+        assert len(ranges) == width
+    got = rank_correct_runs(*args, d32k=d32k, metric=metric, norms=norms)
+    assert got[0].dtype == np.float64 and got[1].dtype == np.int64
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1], want[1])
+    assert got[2] == want[2] == int((pairs > 0).sum())
+    only_i = rank_correct_runs(*args, metric=metric, norms=norms)
+    assert only_i[0] is None
+    np.testing.assert_array_equal(only_i[1], want[1])
+
+
+@pytest.mark.parametrize("shape,width,n_q,per_query,parts", [
+    # a sub-batch of knnlm1m.sweep_k1024: every query corrected, ~100
+    # pairs each: a range a thread
+    ("knnlm1m", 8, 512, (40, 200), 8),
+    ("knnlm1m", 4, 512, (40, 200), 4),
+    # half as many queries: fewer ranges than threads, each worth one
+    ("knnlm1m_short", 8, 150, (40, 200), 4),
+    # of bigann5m.sweep: half the queries corrected, a pair each; of
+    # gist1m.sweep: a few pairs a query: sorts too short to share
+    ("bigann5m", 8, 1024, (0, 1), 1),
+    ("gist1m", 4, 1024, (0, 4), 1),
+    ("no_member", 4, 512, (0, 0), 1),
+])
+def test_the_cut_is_even_in_members_and_follows_what_a_thread_is_worth(
+        rng, pool_of, shape, width, n_q, per_query, parts):
+    pool_of(width)
+    lo, hi = per_query
+    pairs = rng.integers(lo, hi + 1, size=n_q)
+    if shape == "knnlm1m":  # tie runs crowd in some queries
+        pairs[100:130] = 1100
+    ranges = refine._member_ranges(pairs)
+    assert len(ranges) == parts
+    # contiguous, in order, every query in exactly one range
+    assert ranges[0][0] == 0 and ranges[-1][1] == n_q
+    assert [a for a, _ in ranges[1:]] == [b for _, b in ranges[:-1]]
+    assert all(a < b for a, b in ranges)
+    if parts > 1:
+        held = [int(pairs[a:b].sum()) for a, b in ranges]
+        # a range ends at the query that takes it past its share: no
+        # range is further from the even share than one query's pairs
+        assert max(abs(h - pairs.sum() / parts) for h in held) <= pairs.max()
+        assert min(held) >= refine._RANGE_PAIRS - pairs.max()
+
+
+def _crowded_call(rng):
+    """``rank_correct_runs``' positional arguments for the crowded mask."""
+    tight = _mask("crowded", rng)
+    db = rng.normal(size=(500, D_CUT)).astype(np.float32)
+    queries = rng.normal(size=(N_CUT, D_CUT)).astype(np.float32)
+    gi = rng.integers(0, 500, size=(N_CUT, W_CUT)).astype(np.int32)
+    return gi, tight, K_CUT, queries, db
+
+
+def test_the_span_is_told_wall_time_shares_and_the_cut(rng, pool_of):
+    pool_of(4, range_pairs=1)
+    args = _crowded_call(rng)
+    with obs.span("certified.rank_correct") as sp:
+        t0 = time.perf_counter()
+        rank_correct_runs(*args, d32k=rng.random((N_CUT, K_CUT)))
+        wall = time.perf_counter() - t0
+    told = sp.attrs
+    assert (told["members"], told["parts"], told["threads"]) == (
+        int(_involved(args[1]).sum()), 4, 4)
+    phases = told["buffers_s"] + told["score_s"] + told["order_s"]
+    # shares of the stage's WALL time, though four threads ran them
+    assert 0 < phases <= wall
+    assert min(told[key] for key in ("buffers_s", "score_s", "order_s")) > 0
+    # sums over the threads: inside the re-score, whoever ran it
+    assert told["gather_s"] > 0 and told["arith_s"] > 0
+
+
+def test_a_workers_exception_reaches_the_caller(rng, pool_of, monkeypatch):
+    pool_of(4, range_pairs=1)
+    caller = threading.get_ident()
+    ran_on = set()
+
+    def fails_in_the_pool(*args, **kwargs):
+        ran_on.add(threading.get_ident())
+        raise FloatingPointError("a range's re-score failed")
+
+    monkeypatch.setattr(refine, "_score_members", fails_in_the_pool)
+    with pytest.raises(FloatingPointError, match="a range's re-score"):
+        rank_correct_runs(*_crowded_call(rng))
+    assert ran_on and caller not in ran_on
+
+
+def test_a_map_made_on_a_pool_thread_does_not_wait_for_the_pool(pool_of):
+    # a range's blocks go through pool_map on a pool thread: on a pool
+    # of ONE thread an inner map handed to the pool would never start
+    pool_of(1)
+    ran_on = []
+
+    def inner(part):
+        ran_on.append(threading.get_ident())
+        return part * part
+
+    def outer(part):
+        return sum(refine.pool_map(inner, [part, part + 1]))
+
+    done = []
+    runner = threading.Thread(
+        target=lambda: done.append(refine.pool_map(outer, [1, 3])))
+    runner.start()
+    runner.join(timeout=60)
+    assert not runner.is_alive() and done == [[1 + 4, 9 + 16]]
+    assert len(set(ran_on)) == 1 and threading.get_ident() not in ran_on
